@@ -476,6 +476,8 @@ async def build_core_engine(engine_spec: str, flags, mdc, events=None, drt=None)
             # the worker keeps its lease, in-flight requests fail through
             # the error prologue, and the next request respawns the
             # child (warm-started via the persistent compilation cache).
+            # One process per chip: this parent never touches jax, so
+            # the child owns the device for its lifetime.
             if getattr(flags, "remote_prefill", False):
                 raise SystemExit(
                     "--isolate-engine is incompatible with "
@@ -1760,6 +1762,15 @@ async def amain(argv: List[str]) -> None:
 
     install_signal_dump()
 
+    if src == "prefill" or (engine_spec == "jax"
+                            and not flags.isolate_engine):
+        # this process compiles: place the cache before any backend
+        # touch (an --isolate-engine parent stays off jax; its child
+        # places its own in llm/engines/subprocess_host.py)
+        from ..engine.device import configure_compile_cache
+
+        configure_compile_cache()
+
     if flags.num_nodes > 1:
         # must run before the first jax backend touch in this process so
         # jax.devices() is already global when the engine builds its mesh
@@ -1805,9 +1816,6 @@ async def amain(argv: List[str]) -> None:
 
 
 def main() -> None:
-    from ..utils.platform import apply_jax_platform_override
-
-    apply_jax_platform_override()
     try:
         asyncio.run(amain(sys.argv[1:]))
     except KeyboardInterrupt:

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.compat import shard_map
 
 logger = logging.getLogger(__name__)
 
@@ -162,7 +161,7 @@ class IciKvTransfer:
         kb = self._local_shape(self.k_shape, eff)
         vb = self._local_shape(self.v_shape, eff)
         prog = jax.jit(
-            shard_map(
+            jax.shard_map(
                 step, mesh=self.mesh,
                 in_specs=(P("peer", "pair"), P("peer", "pair"),
                           P("peer", "pair")),

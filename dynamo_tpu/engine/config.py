@@ -358,10 +358,10 @@ class EngineConfig:
     # device-finish verdict + stop-suffix rolling hash — as ONE kernel
     # dispatch instead of a string of small [B, V] XLA ops. Sampling is
     # bit-identical to the unfused ladder by construction. "auto"
-    # follows the attention route: it engages exactly when the Pallas
-    # serving kernels do (warmup probe passes), so the probe/warmup XLA
-    # fallback drops it automatically. "on" forces it (CPU tests use
-    # DYN_PALLAS_INTERPRET=1); "off" keeps the XLA tail.
+    # selects only kernels that compile under Mosaic, and this one does
+    # not lower for TPU (PERF.md kernel table) — so today "auto" keeps
+    # the XLA tail like "off"; "on" forces the kernel (CPU tests with
+    # DYN_PALLAS_INTERPRET=1; on a chip it raises the compiler's error).
     fused_epilogue: str = "auto"
     # guided decoding inside the chain: compile TrieConstraint /
     # in-bound JsonGrammar cursors to a dense device transition table

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -25,9 +24,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import models
 from ..models import llama, quant
-from ..ops.attention import _pad_minor
+from ..ops.attention import _pad_minor, pallas_interpret
 from ..telemetry.flight import CompileTracker
 from .config import EngineConfig
+from .device import check_serving_device
 from .sampling import SamplingParams, sample, top_logprobs_for
 
 logger = logging.getLogger(__name__)
@@ -87,7 +87,7 @@ def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
 
     ``fused=True`` routes the whole tail through the single-dispatch
     Pallas epilogue (ops/pallas_epilogue.py) — bit-identical by
-    construction, gated by the ``epilogue`` compile probe. With
+    construction. With
     ``finish`` (the chained burst's per-row carry tuple) the kernel also
     returns the step's (hard, cand, ring_new) finish verdicts, appended
     to the return. ``unique_slots=False`` marks call sites whose pad
@@ -112,7 +112,7 @@ def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
             last_logits, gum, scalars, counts, seen, bias, sample_slots,
             commit, extra_bias=extra_bias, finish=finish,
             max_model_len=max_model_len, alias_counts=unique_slots,
-            interpret=bool(os.environ.get("DYN_PALLAS_INTERPRET")),
+            interpret=pallas_interpret(),
         )
         next_tokens, lps, counts = outs[:3]
         kw = top_k_width(cfg.vocab_size)
@@ -201,6 +201,7 @@ class ModelRunner:
         mesh: Optional[Mesh] = None,
         model_dir: Optional[str] = None,
     ):
+        check_serving_device()
         self.config = config
         cfg = config.model
         self.arch = models.resolve(cfg)
@@ -216,9 +217,7 @@ class ModelRunner:
         # (rel logit err 0.043 vs 0.042, argmax flip 0.10 vs 0.10;
         # examples/llm/benchmarks/results/fp8_mla_accuracy.json), and
         # quantizing only the rope half halves the noise again if a
-        # future accuracy budget wants it. Kernel side: the MLA decode
-        # kernel upcasts after the DMA (its own Mosaic specialization,
-        # probed as "mla_decode_fp8").
+        # future accuracy budget wants it.
         self.kv_dtype = (
             jnp.float8_e4m3fn if config.kv_cache_dtype == "fp8"
             else self.dtype
@@ -316,9 +315,19 @@ class ModelRunner:
                         "safetensors checkpoint or set allow_random_weights"
                     )
             if params is None:
-                params = self.arch.init_params(
-                    cfg, jax.random.PRNGKey(config.seed), self.dtype
-                )
+                # jitted onto the family's own shardings so each device
+                # draws only its shard: an eager init builds every
+                # full-size array on the default device first (the 8B
+                # shape's w_gate alone is 7.5 GB in f32 — chip 0 OOMs
+                # before tp=4 ever spreads it)
+                init = functools.partial(
+                    self.arch.init_params, cfg, dtype=self.dtype)
+                key = jax.random.PRNGKey(config.seed)
+                params = jax.jit(init, out_shardings=jax.tree.map(
+                    lambda sp: NamedSharding(self.mesh, sp),
+                    self.arch.param_specs(jax.eval_shape(init, key)),
+                    is_leaf=lambda x: isinstance(x, P),
+                ))(key)
 
         if cfg.quantization:
             params = quant.quantize_params(params)
@@ -360,7 +369,7 @@ class ModelRunner:
             is_leaf=lambda x: isinstance(x, P),
         )
         self.state_sharding = NamedSharding(self.mesh, P("dp", None))
-        self._reinit_device_state()
+        self._init_device_state()
 
         # XLA compile observability: every compiled-program dispatch site
         # below runs through compiles.track(program, shape-bucket key) —
@@ -405,6 +414,7 @@ class ModelRunner:
         self.device_time = DeviceTimeTracker(
             param_bytes=self.param_bytes,
             kv_bytes_per_token=self.kv_bytes_per_token,
+            device_kind=self.mesh.devices.flat[0].device_kind,
         )
 
         self._build_step()
@@ -450,21 +460,12 @@ class ModelRunner:
         return forward, head
 
     def _fused_epilogue_enabled(self) -> bool:
-        """Resolve config.fused_epilogue at program-BUILD time: "auto"
-        follows the attention route (Pallas serving kernels proven by
-        the warmup probe ⇒ the epilogue kernel is proven by the same
-        probe pass), so the existing probe/warmup fallback — which
-        flips ``attention_impl`` to "xla" and rebuilds the programs —
-        drops the fused tail with no extra rebuild plumbing."""
-        mode = self.config.fused_epilogue
-        if mode == "off":
-            return False
-        if mode == "on":
-            return True
-        from ..ops.attention import resolve_attention_impl
-
-        return resolve_attention_impl(
-            self.config.model.attention_impl) == "pallas"
+        """Resolve config.fused_epilogue at program-BUILD time. "auto"
+        selects only kernels that compile under Mosaic, and the epilogue
+        does not lower for TPU at all (PERF.md kernel table) — so only
+        an explicit "on" engages it: CPU interpret runs, or a chip run
+        that wants the compiler's error."""
+        return self.config.fused_epilogue == "on"
 
     def _build_step(self):
         cfg = self.config.model
@@ -1837,142 +1838,51 @@ class ModelRunner:
             self.kv_cache = (k, v)
             i += len(chunk)
 
-    def warmup(self, decode_batch: Optional[int] = None) -> None:
-        """Compile the serving programs up front: the decode program per
-        KV-width bucket plus the largest prefill bucket.
-
-        The scheduler sizes decode block tables with
-        EngineConfig.kv_width_bucket, so serving touches a ladder of
-        widths, not just blocks_per_seq; compiling the ladder here keeps
-        multi-ten-second TPU compiles out of the first requests' latency
-        (the analog of GPU engines' startup capture sweeps).
-
-        Resilience, layered (a Mosaic compile can HANG, not just fail,
-        and a hung compile wedges a host's shared compile service for
-        every process — so a try/except alone is not enough):
-
-        1. Under ``attention_impl: auto`` on TPU, every Pallas kernel the
-           engine would compile is first probed standalone on tiny shapes
-           in a SUBPROCESS with a hard timeout (ops/probe.py). Timeout or
-           failure → the engine resolves to the XLA path before any
-           in-process Pallas compile ever starts.
-        2. If an in-process compile still fails at full shapes (probe
-           passed on tiny ones), the try/except falls back to XLA. The
-           donated cache/sample-state buffers may already be consumed by
-           a partially-executed step, so they are re-initialized before
-           the retry.
-        """
-        from ..ops.attention import resolve_attention_impl
-
-        cfg = self.config.model
-        if (resolve_attention_impl(cfg.attention_impl) == "pallas"
-                and resolve_attention_impl("auto") == "pallas"):
-            # probe EXPLICIT pallas too, not just auto: the wedge risk is
-            # the first Mosaic compile on a shared-compile-service host,
-            # and that risk doesn't care how the impl was selected. Only
-            # the failure handling differs — auto falls back to XLA,
-            # explicit refuses loudly instead of compiling in-process.
-            # (resolve("auto") == "pallas" ⇔ a TPU backend — CPU runs,
-            # where Mosaic can't wedge anything, skip the probe.)
-            import os
-
-            from ..ops.probe import probe_serving_kernels
-
-            timeout_s = float(os.environ.get("DYN_PALLAS_PROBE_TIMEOUT_S", "180"))
-            if not probe_serving_kernels(
-                mla=cfg.kv_lora_rank > 0,
-                softcap=bool(cfg.attn_logit_softcap),
-                fp8_kv=self.config.kv_cache_dtype == "fp8",
-                sinks=cfg.model_family == "gptoss",
-                verify=bool(self.config.spec_ngram_tokens
-                            or self.config.spec_draft_model),
-                sp_prefill=self.config.sp_size > 1,
-                epilogue=self.config.fused_epilogue != "off",
-                timeout_s=timeout_s,
-            ):
-                if cfg.attention_impl != "auto":
-                    raise RuntimeError(
-                        "attention_impl='pallas' was requested explicitly "
-                        "but the kernel probe failed or timed out; refusing "
-                        "the in-process Mosaic compile (a hung compile "
-                        "wedges this host's shared compile service). Use "
-                        "attention_impl='auto' for automatic XLA fallback."
-                    )
-                logger.warning(
-                    "pallas kernel probe failed or timed out; this engine "
-                    "serves on the XLA attention path"
-                )
-                cfg.attention_impl = "xla"
-                self._build_step()
-                self._build_burst()
-                self._build_spec_burst()
-                # the SP prefill routes attention (ring-kernel vs
-                # gather) and its sampling tail off the same impl
-                self._build_sp_prefill()
-                self.compiles.reset_seen()  # rebuilt programs recompile
-        if (cfg.attn_logit_softcap or cfg.sliding_window) and \
-                resolve_attention_impl(cfg.attention_impl) == "pallas":
-            # the Pallas kernels implement softcapping and windowed masks
-            # natively (the window rides as a scalar operand; windowed
-            # decode walks only the window's pages) — logged AFTER the
-            # probe decision so it is only ever true
-            logger.info(
-                "model uses %s: serving on the Pallas windowed/softcap "
-                "kernel variants",
-                " + ".join(
-                    n for n, on in (
-                        ("logit softcapping", cfg.attn_logit_softcap),
-                        ("sliding-window masks", cfg.sliding_window),
-                    ) if on
-                ),
-            )
-        try:
-            self._warmup_once(decode_batch)
-        except Exception:
-            if cfg.attention_impl != "auto":
-                raise
-            logger.exception(
-                "pallas warmup failed; falling back to the XLA attention "
-                "path for this engine"
-            )
-            cfg.attention_impl = "xla"
-            self._build_step()
-            self._build_burst()
-            self._build_spec_burst()
-            self._build_sp_prefill()
-            self._reinit_device_state()
-            self.compiles.reset_seen()  # rebuilt programs recompile
-            self._warmup_once(decode_batch)
-
-    def _reinit_device_state(self) -> None:
-        """(Re)build the donated device state: the paged KV cache and the
+    def _init_device_state(self) -> None:
+        """Build the donated device state: the paged KV cache and the
         per-slot sampling state (generated-token counts, prompt presence,
         OpenAI logit_bias rows — [num_slots, vocab]; see engine/sampling.py).
-
-        Called from __init__ and from the warmup fallback: a step that
-        fails DURING execution (after dispatch) has already consumed the
-        donated kv_cache/sample_state buffers, so the XLA retry needs
-        fresh arrays. Params are never donated and survive."""
+        Jitted onto its shardings, so no device ever holds more than its
+        own shard (an eager ``jnp.zeros`` lands whole on device 0)."""
         cfg = self.config
-        cache = self.arch.init_kv_cache(
-            cfg.model, cfg.num_kv_blocks, cfg.kv_block_size, self.kv_dtype
-        )
-        if cfg.pp_size > 1:
-            from ..parallel.pipeline import stage_cache
 
-            cache = stage_cache(tuple(cache), cfg.pp_size,
-                                prefix_layers=self._pp_prefix_layers)
-        self.kv_cache = tuple(
-            jax.device_put(c, self.cache_sharding) for c in cache
-        )
-        b, v = cfg.max_batch_size, cfg.model.vocab_size
-        self.sample_state = (
-            jax.device_put(jnp.zeros((b, v), jnp.int32), self.state_sharding),
-            jax.device_put(jnp.zeros((b, v), jnp.bool_), self.state_sharding),
-            jax.device_put(jnp.zeros((b, v), jnp.float32), self.state_sharding),
-        )
+        def make():
+            cache = tuple(self.arch.init_kv_cache(
+                cfg.model, cfg.num_kv_blocks, cfg.kv_block_size,
+                self.kv_dtype,
+            ))
+            if cfg.pp_size > 1:
+                from ..parallel.pipeline import stage_cache
 
-    def _warmup_once(self, decode_batch: Optional[int] = None) -> None:
+                cache = stage_cache(cache, cfg.pp_size,
+                                    prefix_layers=self._pp_prefix_layers)
+            b, v = cfg.max_batch_size, cfg.model.vocab_size
+            return cache, (jnp.zeros((b, v), jnp.int32),
+                           jnp.zeros((b, v), jnp.bool_),
+                           jnp.zeros((b, v), jnp.float32))
+
+        self.kv_cache, self.sample_state = jax.jit(make, out_shardings=(
+            (self.cache_sharding,) * 2, (self.state_sharding,) * 3,
+        ))()
+
+    def warmup(self, decode_batch: Optional[int] = None) -> None:
+        """Compile every serving program up front: the decode program
+        per KV-width bucket and the prefill program per (row bucket,
+        length bucket) the scheduler can pick.
+
+        The scheduler sizes decode block tables with
+        EngineConfig.kv_width_bucket and prefill steps with
+        prefill_row_bucket x bucket_for, so serving touches ladders of
+        shapes; compiling them here keeps multi-ten-second TPU compiles
+        out of the first requests' latency (the analog of GPU engines'
+        startup capture sweeps).
+
+        A program that fails to compile — a Pallas kernel Mosaic
+        rejects at this model's shapes — raises here with the
+        compiler's message and the engine does not start. There is no
+        path from a compile error to another attention route:
+        ``attention_impl="xla"`` is the operator's explicit choice.
+        """
         b = decode_batch or self.config.max_batch_size
         # the sample-row install program is shape-invariant and otherwise
         # compiles at the FIRST admission — a needless late compile on
@@ -2105,18 +2015,28 @@ class ModelRunner:
             # slots wrote nothing, commit=False counted nothing)
             self.kv_cache = (outs_sp[4], outs_sp[5])
             self.sample_state = (outs_sp[6], outs_sp[7], outs_sp[8])
-        # prefill-shaped programs (largest bucket, full table width) over
-        # the batched-prefill row ladder, so the flash-prefill kernel's
-        # compiles also happen — and fail — here rather than on the first
-        # real prompt burst
-        s = self.config.prefill_buckets[-1]
+        # every prefill-shaped program the scheduler can dispatch: the
+        # batched-prefill row ladder x the length buckets within the
+        # per-step token budget (scheduler.prefill_bucket_cap), at full
+        # table width — so the flash-prefill kernel's compiles happen,
+        # and fail, here rather than on the first real prompt burst
+        from .scheduler import prefill_bucket_cap
+
         w = self.config.blocks_per_seq
-        for r in self.config.prefill_row_buckets():
-            self.step(
-                np.zeros((r, s), np.int32), np.zeros((r, s), np.int32),
-                np.zeros((r, w), np.int32), np.full((r, s), -1, np.int32),
-                np.ones(r, np.int32), np.zeros(r, np.int32),
-                np.zeros(r, np.float32), np.zeros(r, np.int32),
-                np.ones(r, np.float32),
-                jax.random.PRNGKey(0),
-            )
+        buckets = self.config.prefill_buckets
+        rows = self.config.prefill_row_buckets()
+        for r in rows:
+            cap = prefill_bucket_cap(self.config, r)
+            if cap is None:
+                # over budget even at the smallest bucket: the scheduler
+                # sheds rows down to one, which still advances there
+                cap = buckets[0] if r == rows[0] else 0
+            for s in (b for b in buckets if b <= cap):
+                self.step(
+                    np.zeros((r, s), np.int32), np.zeros((r, s), np.int32),
+                    np.zeros((r, w), np.int32), np.full((r, s), -1, np.int32),
+                    np.ones(r, np.int32), np.zeros(r, np.int32),
+                    np.zeros(r, np.float32), np.zeros(r, np.int32),
+                    np.ones(r, np.float32),
+                    jax.random.PRNGKey(0),
+                )

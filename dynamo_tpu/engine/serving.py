@@ -8,6 +8,7 @@ PreprocessedRequest in, streamed EngineOutput deltas out).
 from __future__ import annotations
 
 import asyncio
+import json
 import logging
 import uuid
 from typing import Any, AsyncIterator, Optional
@@ -16,6 +17,7 @@ from ..protocols.common import EngineOutput, PreprocessedRequest
 from ..runtime.engine import AsyncEngine, Context, EngineError
 from .block_allocator import KvEventSink
 from .config import EngineConfig, ModelConfig
+from .device import device_report
 from .model_runner import ModelRunner
 from .scheduler import EngineRequest, Scheduler
 
@@ -124,8 +126,6 @@ def load_extra_engine_args(flags) -> dict:
     path = getattr(flags, "extra_engine_args", None)
     if not path:
         return {}
-    import json
-
     with open(path) as f:
         return json.load(f)
 
@@ -258,6 +258,10 @@ class JaxServingEngine(AsyncEngine):
             if draft_runner is not None:
                 futs.append(loop.run_in_executor(None, draft_runner.warmup))
             await asyncio.gather(*futs)
+        # the one line that says what this engine came up on — parsed by
+        # chip_smoke.py; bytes_in_use is after warmup, so it is what
+        # params + cache + compiled programs really hold on each device
+        logger.info("engine device: %s", json.dumps(device_report(runner.mesh)))
         scheduler.start()
         if engine_config.watchdog_stall_s > 0:
             from ..telemetry.watchdog import StallWatchdog

@@ -517,8 +517,10 @@ async def _build_child_engine(engine_path: str, engine_args: dict,
 
         from ...cli.run import load_mdc
         from ...engine.block_allocator import KvEventSink
+        from ...engine.device import configure_compile_cache
         from ...engine.serving import JaxServingEngine
 
+        configure_compile_cache()
         flags = SimpleNamespace(**(engine_args.get("flags") or {}))
         mdc = load_mdc(flags)
         sink = KvEventSink(

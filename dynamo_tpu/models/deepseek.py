@@ -36,7 +36,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import lane_pad, scatter_kv_stacked
-from ..ops.compat import shard_map
 from .llama import (
     _swiglu_mlp,
     apply_rope,
@@ -233,13 +232,16 @@ def mla_attention(
 ):
     """MLA attention dispatch over the stacked compressed caches.
 
-    Decode (S == 1) on the Pallas path uses the MLA decode kernel
-    (ops/pallas_decode.py), which indexes the layer inside HBM — no
-    per-layer gather. Other shapes (and the XLA path) gather the layer
-    and run the dense formulation. Query heads shard over "tp" under a
-    multi-device mesh; the latent caches are replicated (no head dim).
+    Decode (S == 1) under an explicit ``impl="pallas"`` uses the MLA
+    decode kernel (ops/pallas_decode.py), which indexes the layer inside
+    HBM — no per-layer gather. ``auto`` never selects it: Mosaic rejects
+    its single-head page copies on v5e (PERF.md kernel table, ROADMAP
+    Design 3), so on a chip the explicit choice raises the compiler's
+    error. Every other case gathers the layer and runs the dense
+    formulation. Query heads shard over "tp" under a multi-device mesh;
+    the latent caches are replicated (no head dim).
     """
-    from ..ops.attention import _pad_minor, resolve_attention_impl
+    from ..ops.attention import _pad_minor
 
     # caches carry lane padding; zero-padded queries score 0 against the
     # zero pad lanes, and the padded latent output is sliced back below
@@ -247,10 +249,7 @@ def mla_attention(
     q_lat = _pad_minor(q_lat, c_all.shape[-1])
     q_rope = _pad_minor(q_rope, kr_all.shape[-1])
 
-    if (
-        q_lat.shape[1] == 1
-        and resolve_attention_impl(impl) == "pallas"
-    ):
+    if q_lat.shape[1] == 1 and impl == "pallas":
         from ..ops.pallas_decode import mla_paged_decode_attention
 
         def fn(ql, qr, c, kr, bt, ctx, li):
@@ -262,7 +261,7 @@ def mla_attention(
         li_arr = jnp.asarray(li, jnp.int32)
         if mesh is not None and mesh.size > 1:
             dp = "dp" if q_lat.shape[0] % mesh.shape.get("dp", 1) == 0 else None
-            fn = shard_map(
+            fn = jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=(

@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, scatter_kv_stacked
-from ..ops.compat import axis_size
 from .llama import (  # noqa: F401  (shared cache layout + trunk pieces)
     alternating_window,
     apply_rope,
@@ -155,7 +154,7 @@ def make_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
         )
         bo = lp["bo"]
         if tp_axis is not None:
-            bo = bo / axis_size(tp_axis)
+            bo = bo / jax.lax.axis_size(tp_axis)
         delta = dense(attn.reshape(b, s, h * hd), lp["wo"]) + bo
         return delta, k_all, v_all
 

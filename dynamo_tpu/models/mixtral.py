@@ -29,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 from typing import Optional
 
 from ..engine.config import ModelConfig
-from ..ops.compat import axis_size
 from .llama import (  # shared trunk + specs
     ATTN_LAYER_SPECS,
     base_specs,
@@ -82,7 +81,7 @@ def _dispatch_combine(gate_vals, gate_idx, e: int, capacity: int,
     dispatch = slot.sum(axis=1)                                  # [T, E, C]
     combine = (slot * gate_vals[:, :, None, None]).sum(axis=1)
     if ep_axis is not None:
-        e_local = e // axis_size(ep_axis)
+        e_local = e // jax.lax.axis_size(ep_axis)
         e0 = lax.axis_index(ep_axis) * e_local
         dispatch = lax.dynamic_slice_in_dim(dispatch, e0, e_local, axis=1)
         combine = lax.dynamic_slice_in_dim(combine, e0, e_local, axis=1)
@@ -231,7 +230,7 @@ def gptoss_moe(
     y_e = expert_einsum("eci,eid->ecd", h, w_down)
     b = b_down[:, None, :]
     if tp_axis is not None:
-        b = b / axis_size(tp_axis)
+        b = b / jax.lax.axis_size(tp_axis)
     y_e = y_e + b
     return jnp.einsum("tec,ecd->td", combine.astype(x.dtype), y_e)
 
@@ -350,7 +349,7 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
                 # same trick gptoss uses for its replicated biases under
                 # manual tp). Under tp the w_sh_* columns/rows shard
                 # Megatron-style, so sh is already a genuine tp-partial.
-                sh = sh / axis_size(ep_axis)
+                sh = sh / jax.lax.axis_size(ep_axis)
             y = y + sh
         return y
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 from typing import Optional, Tuple
 
 import jax
@@ -22,7 +23,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry.registry import Counter
-from .compat import shard_map
+from .pallas_attention import paged_flash_attention
+from .pallas_decode import (
+    VERIFY_MAX_S,
+    paged_decode_attention,
+    paged_verify_attention,
+)
 
 LANE = 128  # TPU vector lane width — HBM layouts tile the minor dim to this
 
@@ -234,6 +240,38 @@ def resolve_attention_impl(impl: str) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def pallas_interpret() -> bool:
+    """``DYN_PALLAS_INTERPRET=1`` runs every kernel in the Pallas
+    interpreter, so CPU tests can drive the kernel routes through jitted
+    model forwards (models don't plumb ``interpret``). Refused on a TPU
+    backend: an interpreted kernel there would serve under the
+    decode/flash route labels without ever going through Mosaic."""
+    if not os.environ.get("DYN_PALLAS_INTERPRET"):
+        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "DYN_PALLAS_INTERPRET is set on a TPU backend: interpret mode "
+            "is for CPU tests only — unset it to compile the kernels"
+        )
+    return True
+
+
+def mosaic_rejects(route: str, has_sinks: bool, kv_dtype,
+                   kv_heads: int) -> bool:
+    """Kernel specializations Mosaic rejects on v5e (jax 0.9.0 / libtpu
+    0.0.34 — the compiler's errors are in PERF.md's kernel table and
+    under ROADMAP Design 3): the sink-bias finalize of the flash and
+    verify kernels, and the fp8-cache page copies of the decode and
+    verify kernels when one device's kv heads are not a multiple of
+    fp8's sublane tiling of 4 (kvh 8 compiles and agrees with XLA, kvh 2
+    does not). ``auto`` never selects these — the XLA route serves and
+    the route counter says so; an explicit ``pallas`` compiles them and
+    raises the compiler's error."""
+    fp8 = jnp.dtype(kv_dtype) == jnp.float8_e4m3fn
+    return ((has_sinks and route in ("flash", "verify"))
+            or (fp8 and kv_heads % 4 != 0 and route in ("decode", "verify")))
+
+
 def attention(
     q: jax.Array,            # [B, S, H, D]
     k_cache: jax.Array,      # [N_blocks, bs, KVH, D] or stacked [L, N, bs, KVH, D]
@@ -274,7 +312,21 @@ def attention(
         scale = d ** -0.5
     dk = k_cache.shape[-1]
     q = _pad_minor(q, dk)  # zero pad lanes score 0 against zero cache pad
-    if resolve_attention_impl(impl) == "xla":
+    # small-S tails (the speculative verify's K+1 positions; follows the
+    # flash kernel's affine base_pos contract, so small custom prefill
+    # buckets mask correctly too) take the fused verify kernel: ONE page
+    # walk for all S queries instead of the flash kernel's per-query-
+    # block passes over the table capacity
+    s_q = q.shape[1]
+    route = ("decode" if s_q == 1
+             else "verify" if s_q <= VERIFY_MAX_S else "flash")
+    has_sinks = sinks is not None
+    resolved = resolve_attention_impl(impl)
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    if impl == "auto" and mosaic_rejects(
+            route, has_sinks, k_cache.dtype, k_cache.shape[-2] // tp):
+        resolved = "xla"
+    if resolved == "xla":
         if stacked:
             # index the layer through the gather itself: block id n of
             # layer li lives at flat row li*N + n. dynamic_index_in_dim
@@ -291,18 +343,7 @@ def attention(
                                sliding_window=sliding_window,
                                sinks=sinks)[..., :d]
 
-    from .pallas_attention import paged_flash_attention
-    from .pallas_decode import (
-        VERIFY_MAX_S,
-        paged_decode_attention,
-        paged_verify_attention,
-    )
-
-    import os
-
-    # trace-time escape: lets model-level tests drive the full Pallas
-    # path through jitted forwards on CPU (models don't plumb interpret)
-    interpret = interpret or bool(os.environ.get("DYN_PALLAS_INTERPRET"))
+    interpret = interpret or pallas_interpret()
     if not stacked:
         k_cache, v_cache = k_cache[None], v_cache[None]
     # the window may be a traced scalar (Gemma-2 alternates windowed/full
@@ -313,21 +354,9 @@ def attention(
         if sliding_window is None
         else jnp.asarray(sliding_window, jnp.int32).reshape(1)
     )
-    decode = q.shape[1] == 1
-    has_sinks = sinks is not None
     sink_args = (sinks,) if has_sinks else ()
-    # small-S tails (the speculative verify's K+1 positions; follows the
-    # flash kernel's affine base_pos contract, so small custom prefill
-    # buckets mask correctly too) take the fused verify kernel: ONE page
-    # walk for all S queries instead of the flash kernel's per-query-
-    # block passes over the table capacity. Softcap, sinks and fp8
-    # caches are kernel specializations exactly like the bf16 base —
-    # warmup probes the matching variant kind (ops/probe.py "verify_*")
-    # before any of them may compile in-process, so a probe failure
-    # falls the whole engine back to XLA rather than landing here.
-    verify = 1 < q.shape[1] <= VERIFY_MAX_S
-    if verify:
-        record_route("verify")
+    record_route(route)
+    if route == "verify":
         fn = functools.partial(
             paged_verify_attention, scale=scale, interpret=interpret,
             softcap=softcap,
@@ -341,8 +370,7 @@ def attention(
             return fn(q, k_cache, v_cache, block_tables, vbase,
                       context_lens, li, window=win,
                       sinks=sk[0] if sk else None)
-    elif decode:
-        record_route("decode")
+    elif route == "decode":
         fn = functools.partial(
             paged_decode_attention, scale=scale, interpret=interpret,
             softcap=softcap,
@@ -355,7 +383,6 @@ def attention(
             return fn(q, k_cache, v_cache, block_tables, context_lens, li,
                       window=win, sinks=sk[0] if sk else None)
     else:
-        record_route("flash")
         fn = functools.partial(
             paged_flash_attention, scale=scale, interpret=interpret,
             softcap=softcap,
@@ -380,12 +407,12 @@ def attention(
             P(None, None, None, "tp", None),   # v_cache
             P(dp, None),                       # block_tables
         ]
-        if not decode:
+        if route != "decode":
             in_specs.append(P(dp))             # base_pos (flash + verify)
         in_specs.extend([P(dp), P(), P()])     # context_lens, layer_idx, win
         if has_sinks:
             in_specs.append(P("tp"))           # sinks follow the head shard
-        call = shard_map(
+        call = jax.shard_map(
             call,
             mesh=mesh,
             in_specs=tuple(in_specs),

@@ -28,12 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-MASK_VALUE = -1e30
-
-from .pallas_decode import (  # noqa: E402  (shared kernel-compat helpers)
-    _compiler_params,
-    _out_struct,
-)
+from .pallas_decode import MASK_VALUE, _out_struct
 
 
 def _kernel(
@@ -260,7 +255,7 @@ def paged_flash_attention(
         out_shape=_out_struct(
             (b * num_chunks, sc, kvh, g, d), q.dtype, q, k_cache,
         ),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -39,36 +39,13 @@ from jax.experimental.pallas import tpu as pltpu
 MASK_VALUE = -1e30
 
 
-def _out_vma(*arrays):
-    """Varying-manual-axes annotation for pallas out_shape: the output
-    varies over every manual mesh axis any input varies over. Needed so
-    the kernels compose with ``check_vma=True`` shard_maps (the
-    partial-manual pipeline in parallel/pipeline.py); None outside
-    shard_map tracing, preserving plain-jit behavior. Older jax builds
-    without ``jax.typeof`` get the plain-jit behavior unconditionally
-    (no vma annotation — shard_map callers there run check_vma=False)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    vma = frozenset().union(*(typeof(a).vma for a in arrays))
-    return vma or None
-
-
-# CompilerParams was TPUCompilerParams on older jax builds (the same
-# vintage that lacks jax.typeof); resolve once so every kernel compiles
-# on either
-_compiler_params = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
 def _out_struct(shape, dtype, *arrays) -> jax.ShapeDtypeStruct:
-    """out_shape with the vma annotation when the jax build supports it
-    (newer jax; required for check_vma=True shard_maps) and a plain
-    struct otherwise — older builds reject the ``vma`` kwarg outright,
-    and there the annotation has nothing to annotate anyway."""
-    vma = _out_vma(*arrays)
-    if vma is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """out_shape whose varying-manual-axes set is the union of the
+    inputs': the output varies over every manual mesh axis any input
+    varies over, so the kernels compose with ``check_vma=True``
+    shard_maps (the partial-manual pipeline in parallel/pipeline.py).
+    Empty outside shard_map tracing."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in arrays))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -382,7 +359,7 @@ def mla_paged_decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, h, r), q_lat.dtype, q_lat, c_cache),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -643,7 +620,7 @@ def paged_verify_attention(
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, s, kvh, g, d), q.dtype, q, k_cache),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -750,7 +727,7 @@ def paged_decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, kvh, g, d), q.dtype, q, k_cache),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

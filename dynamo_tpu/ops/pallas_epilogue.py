@@ -21,8 +21,11 @@ logits)`` (that IS jax's implementation) with the per-row gumbel noise
 precomputed OUTSIDE the kernel from the same ``_row_keys`` fold-in. In
 interpret mode the body lowers to the same XLA ops the dense ladder
 runs, so the token/logprob stream is bit-equal — the differential test
-asserts exact equality, and the TPU path is gated by the ``epilogue``
-compile probe (ops/probe.py) like every other Mosaic specialization.
+asserts exact equality. The kernel does NOT lower for TPU yet (its
+``(1, V)`` blocks break the (8, 128) block rule and the 1-D sort /
+cumsum body has no Mosaic lowering — PERF.md kernel table), so
+``fused_epilogue: auto`` never selects it; ``on`` is for CPU interpret
+runs and raises the compiler's error on a chip.
 
 The penalty-count commit writes through an aliased counts buffer whose
 block index is the row's sample slot (scalar-prefetched). That in-place
@@ -42,8 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .pallas_decode import _compiler_params
 
 LANE = 128
 
@@ -294,7 +295,7 @@ def fused_sampling_epilogue(
         # sequential grid: the aliased counts row of a pad row may
         # duplicate another row's slot; arbitrary (not parallel) order
         # keeps the read-modify-write of each block well-defined
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         input_output_aliases=aliases,

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_decode import MASK_VALUE, _compiler_params, _out_struct
+from .pallas_decode import MASK_VALUE, _out_struct
 
 
 def _prefix_kernel(
@@ -242,7 +242,7 @@ def paged_prefix_attention_partials(
             _out_struct((b, rows, 128), jnp.float32, q, k_cache),
             _out_struct((b, rows, 128), jnp.float32, q, k_cache),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -38,7 +38,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..models import llama
-from ..ops.compat import shard_map
 
 KVCache = Tuple[jax.Array, jax.Array]
 
@@ -287,7 +286,7 @@ def pipeline_forward(
     )
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             param_specs(params, tp=tp > 1, arch=arch),

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.compat import shard_map
 
 _NEG = -0.5 * jnp.finfo(jnp.float32).max
 
@@ -122,7 +121,7 @@ def ring_attention(
     seq = P(None, axis, head_axis, None)
     pos = P(None, axis)
     kernel = functools.partial(_ring_kernel, axis=axis, scale=scale)
-    return shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(seq, seq, seq, pos, pos),
         out_specs=seq,
@@ -180,7 +179,7 @@ def ulysses_attention(
     seq = P(None, axis, None, None)
     pos = P(None, axis)
     kernel = functools.partial(_ulysses_kernel, axis=axis, scale=scale)
-    return shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(seq, seq, seq, pos, pos),
         out_specs=seq,

@@ -11,14 +11,12 @@ all-to-all when heads divide nicely).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.compat import shard_map
 from .ring_attention import _NEG, _ring_partials, ring_attention, ulysses_attention
 
 
@@ -123,14 +121,18 @@ def sp_chunk_attention(
     rotation overlaps the interconnect with compute at exactly the long
     sequence lengths this path exists for.
     """
-    from ..ops.attention import record_route, resolve_attention_impl
+    from ..ops.attention import (
+        pallas_interpret,
+        record_route,
+        resolve_attention_impl,
+    )
 
     b, s, _h, d = q.shape
     if scale is None:
         scale = d ** -0.5
     sp = mesh.shape[axis]
-    interpret = interpret or bool(os.environ.get("DYN_PALLAS_INTERPRET"))
     if resolve_attention_impl(impl) == "pallas":
+        interpret = interpret or pallas_interpret()
         if s % sp:
             raise ValueError(
                 f"sp chunk S must divide the {axis!r} axis: S={s}, sp={sp}"
@@ -214,7 +216,7 @@ def _sp_chunk_kernel_route(
     seq = P(None, axis, head_axis, None)
     pos = P(None, axis)
     cache = P(None, None, None, head_axis, None)
-    return shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(seq, seq, seq, pos, P(None, None), cache, cache,
                   P(None), P(None)),

@@ -25,7 +25,7 @@ import math
 from typing import Deque, List, Optional
 
 from ..kv_router.protocols import ForwardPassMetrics
-from ..telemetry.device_time import DeviceTimeTracker
+from ..telemetry.device_time import HBM_PEAK_GBPS, DeviceTimeTracker
 from ..utils import faults
 from .workload import Request
 
@@ -44,7 +44,7 @@ class WorkerSpec:
     # llama-8B-bf16-ish defaults; scenarios override for other shapes
     param_bytes: float = 16e9
     kv_bytes_per_token: float = 131072.0
-    hbm_gbps: Optional[float] = None      # None → DYN_HBM_GBPS / chip default
+    hbm_gbps: Optional[float] = None      # None → the modelled chip, a v5e
     burst_steps: int = 64                 # decode tokens per dispatch burst
     # PR 14 sequence-parallel prefill: prompts past the threshold run the
     # chunked ladder and are costed by sp_prefill_read_bytes
@@ -161,7 +161,7 @@ class SimWorker:
         self.tracker = DeviceTimeTracker(
             param_bytes=spec.param_bytes,
             kv_bytes_per_token=spec.kv_bytes_per_token,
-            hbm_gbps=spec.hbm_gbps,
+            hbm_gbps=spec.hbm_gbps or HBM_PEAK_GBPS["TPU v5 lite"],
             clock=clock,
         )
         self.active: List[SimRequest] = []

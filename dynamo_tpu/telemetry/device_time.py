@@ -1,8 +1,8 @@
 """Live device-time accounting + serving-time roofline attribution.
 
 ``bench.py`` computes a roofline fraction OFFLINE (measured decode
-tokens/s over the HBM-bandwidth bound for the same model/batch) and
-banks it in BENCH_*.json; in serving, the engine was blind. This module
+tokens/s over the HBM-bandwidth bound for the same model/batch); in
+serving, the engine was blind. This module
 is the live mirror: the scheduler already observes every compiled
 program's completion — the sync path's executor host-sync, the
 dispatch-ahead pipeline's reconciliation, the persistent loop's
@@ -41,15 +41,18 @@ always real).
 from __future__ import annotations
 
 import collections
-import os
+import logging
 import time
 from typing import Callable, Deque, Optional, Tuple
 
-# single-chip HBM bandwidth bound used for the roofline denominator.
-# v5e ≈ 819 GB/s (the same constant bench.py uses); override with
-# DYN_HBM_GBPS for other chip generations.
-HBM_GBPS_ENV = "DYN_HBM_GBPS"
-DEFAULT_HBM_GBPS = 819.0
+logger = logging.getLogger(__name__)
+
+# Peak HBM bandwidth of one chip in GB/s, keyed by jax ``device_kind`` —
+# the roofline denominator here and in bench.py. A kind that is not in
+# the table has no roofline: the gauge is not exported rather than
+# computed against another chip's number.
+# "TPU v5 lite": 819 GB/s — Google Cloud documentation, "TPU v5e".
+HBM_PEAK_GBPS = {"TPU v5 lite": 819.0}
 
 # device-time histogram ladder: bursts are sub-millisecond to ~seconds
 DEVICE_TIME_BUCKETS = (
@@ -70,6 +73,7 @@ class DeviceTimeTracker:
         param_bytes: float = 0.0,
         kv_bytes_per_token: float = 0.0,
         hbm_gbps: Optional[float] = None,
+        device_kind: str = "",
         window_s: float = 60.0,
         registry=None,
         clock: Callable[[], float] = time.monotonic,
@@ -78,13 +82,11 @@ class DeviceTimeTracker:
 
         self.param_bytes = float(param_bytes)
         self.kv_bytes_per_token = float(kv_bytes_per_token)
+        # an explicit peak (tests, the simulator's modelled chip) or the
+        # table entry for the device this engine is on
         if hbm_gbps is None:
-            try:
-                hbm_gbps = float(os.environ.get(HBM_GBPS_ENV, "")
-                                 or DEFAULT_HBM_GBPS)
-            except ValueError:
-                hbm_gbps = DEFAULT_HBM_GBPS
-        self.peak_bytes_per_s = float(hbm_gbps) * 1e9
+            hbm_gbps = HBM_PEAK_GBPS.get(device_kind)
+        self.peak_bytes_per_s = float(hbm_gbps or 0.0) * 1e9
         self.window_s = window_s
         self.clock = clock
         self._last_ready_t: Optional[float] = None
@@ -119,13 +121,20 @@ class DeviceTimeTracker:
             "window — 1.0 means the device never waited for the host",
             self._busy_ratios,
         )
-        self.registry.callback_gauge(
-            "dynamo_engine_roofline_fraction",
-            "Achieved decode HBM bytes/s over the chip's peak bandwidth "
-            "(weights once + live rows' KV per step) — the serving-time "
-            "mirror of bench.py's vs_baseline",
-            self._roofline,
-        )
+        if self.peak_bytes_per_s:
+            self.registry.callback_gauge(
+                "dynamo_engine_roofline_fraction",
+                "Achieved decode HBM bytes/s over the chip's peak bandwidth "
+                "(weights once + live rows' KV per step) — the serving-time "
+                "mirror of bench.py's vs_baseline",
+                self._roofline,
+            )
+        else:
+            logger.info(
+                "no HBM peak for device kind %r: "
+                "dynamo_engine_roofline_fraction is not exported",
+                device_kind,
+            )
 
     # ---------- observations (host reconciliation seams) ----------
 
@@ -221,8 +230,6 @@ class DeviceTimeTracker:
 
     # dynrace: domain(executor)
     def _roofline(self):
-        if not self.peak_bytes_per_s:
-            return []
         # every byte-carrying observation counts: decode steps always
         # model their reads; prefill observations carry bytes only when
         # the SP ladder modelled them (dense-ladder prefill stays out —

@@ -174,13 +174,6 @@ class CompileTracker:
         every further compile stalls a real request."""
         self._serving = True
 
-    def reset_seen(self) -> None:
-        """Forget every seen key: the runner rebuilt its jitted programs
-        (e.g. the warmup Pallas→XLA fallback), so the next dispatch per
-        shape compiles again and must count again."""
-        with self._lock:
-            self._seen.clear()
-
     @property
     def serving(self) -> bool:
         return self._serving

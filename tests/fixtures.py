@@ -34,6 +34,7 @@ def build_tiny_tokenizer() -> Tokenizer:
     tok.decoder = decoders.ByteLevel()
     trainer = trainers.BpeTrainer(
         vocab_size=512,
+        show_progress=False,
         special_tokens=["<unk>", "<s>", "</s>", "<|user|>", "<|assistant|>", "<|system|>"],
     )
     tok.train_from_iterator(CORPUS, trainer)

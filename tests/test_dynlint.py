@@ -1339,7 +1339,6 @@ def test_sp_prefill_modules_pass_jit_impure_and_async_blocking():
     modules = [
         os.path.join(PACKAGE_ROOT, "parallel", "sequence.py"),
         os.path.join(PACKAGE_ROOT, "parallel", "ring_attention.py"),
-        os.path.join(PACKAGE_ROOT, "ops", "compat.py"),
         os.path.join(PACKAGE_ROOT, "llm", "embeddings.py"),
         os.path.join(PACKAGE_ROOT, "engine", "scheduler.py"),
     ]

@@ -102,16 +102,6 @@ def test_compile_tracker_counts_first_dispatch_per_key_only():
     assert "dynamo_engine_xla_compile_duration_seconds_bucket" in text
 
 
-def test_compile_tracker_reset_seen_recounts():
-    tracker = CompileTracker(flight=FlightRecorder())
-    with tracker.track("decode", "k"):
-        pass
-    tracker.reset_seen()
-    with tracker.track("decode", "k") as first:
-        assert first  # rebuilt programs compile again and must count
-    assert len(tracker.records) == 2
-
-
 def test_attention_route_counter_rides_the_dispatch_hook():
     """record_route() must attribute routes to the program whose tracked
     dispatch is on the stack (ops.attention.route_program installed as

@@ -691,10 +691,11 @@ async def test_top_logprobs_stream(hf_model_dir):
         assert abs(vals[0] - lp["logprob"]) < 1e-5
 
 
-def test_warmup_falls_back_to_xla_when_pallas_cannot_compile(hf_model_dir):
-    """attention_impl auto + a Pallas path that cannot compile on this
-    backend → warmup flips the engine to XLA instead of leaving a bomb
-    for the first request (pallas_call is uncompilable on CPU without
+def test_warmup_raises_when_a_pallas_program_cannot_compile(hf_model_dir):
+    """attention_impl auto resolving to a Pallas path that cannot
+    compile on this backend → warmup RAISES the compiler's error and
+    leaves attention_impl alone: there is no path from a compile error
+    to attention_impl="xla" (pallas_call is uncompilable on CPU without
     interpret mode, which makes this a REAL failure-path test)."""
     cfg = ModelConfig.from_model_dir(hf_model_dir)
     cfg.attention_impl = "auto"
@@ -713,19 +714,11 @@ def test_warmup_falls_back_to_xla_when_pallas_cannot_compile(hf_model_dir):
             lambda impl: "pallas" if impl == "auto" else orig(impl)
         )
         runner._build_step()
-        runner.warmup()
+        with pytest.raises(ValueError, match="interpret"):
+            runner.warmup()
     finally:
         attn_mod.resolve_attention_impl = orig
-    assert cfg.attention_impl == "xla"
-    # and the engine actually serves afterwards
-    out, *_ = runner.step(
-        np.zeros((2, 1), np.int32), np.zeros((2, 1), np.int32),
-        np.zeros((2, 8), np.int32), np.full((2, 1), -1, np.int32),
-        np.ones(2, np.int32), np.zeros(2, np.int32),
-        np.zeros(2, np.float32), np.zeros(2, np.int32),
-        np.ones(2, np.float32), jax.random.PRNGKey(0),
-    )
-    assert np.asarray(out).shape == (2,)
+    assert cfg.attention_impl == "auto"
 
 
 @pytest.mark.asyncio
