@@ -1,0 +1,135 @@
+"""Metrics from the profiler's capture of a slice of the window.
+
+Programs are told apart by what runs inside them, not by their names:
+the engine's decode and prefill steps are one jitted function at
+different shapes, so both are ``jit_step(<fingerprint>)`` in the trace.
+A metric's file gives ``with_op``, a regular expression on operation
+names; a program execution belongs to the metric when an operation that
+matches ran inside it.
+"""
+
+from __future__ import annotations
+
+import re
+
+from harness import opsbytes
+from harness.peaks import peaks_for
+from harness.rundata import RunData, failed
+
+BLOCK = 16   # the engine's kv_block_size default: a cached prefix is whole blocks
+
+
+def _matches(pattern: str, ev) -> bool:
+    # the name only: an event's full HLO line also names its operands
+    return bool(re.search(pattern, ev.name))
+
+
+def _modules_with(trace, device: int, pattern: str):
+    """Program executions on ``device`` inside which an op matching
+    ``pattern`` ran, and those ops."""
+    ops = [o for o in trace.ops[device] if _matches(pattern, o)]
+    mods, hit_ops, i = [], [], 0
+    for m in trace.modules[device]:
+        end = m.start + m.dur
+        while i < len(ops) and ops[i].start < m.start:
+            i += 1
+        j = i
+        while j < len(ops) and ops[j].start < end:
+            j += 1
+        if j > i:
+            mods.append(m)
+            hit_ops.extend(ops[i:j])
+        i = j
+    return mods, hit_ops
+
+
+def _tp(run: RunData) -> int:
+    return int(run.serve.get("tensor_parallel_size", 1))
+
+
+def _slice_requests(run: RunData):
+    """Requests whose first token arrived inside the captured slice."""
+    s0, s1 = run.trace_slice
+    return [r for r in run.records
+            if not failed(r) and s0 <= r["token_times"][0] <= s1]
+
+
+def _mean_attended_keys(run: RunData, window) -> float:
+    """Time-average over the slice of the keys one decode step attends:
+    the sum over running sequences of min(context, window)."""
+    s0, s1 = run.trace_slice
+    n, total = 64, 0.0
+    for k in range(n):
+        t = s0 + (k + 0.5) * (s1 - s0) / n
+        for r in run.records:
+            times = r["token_times"]
+            if not times or not times[0] <= t <= times[-1]:
+                continue
+            emitted = sum(c for tt, c in zip(times, r["chunk_tokens"]) if tt <= t)
+            total += opsbytes.attended(r["prompt_tokens"] + emitted, window)
+    return total / n
+
+
+def _computed_chunks(run: RunData):
+    """(start, length) of the prompt tokens each slice request had to
+    compute: all of them, or what follows the shared prefix where an
+    earlier request of its group had its first token before this one
+    was sent (the prefix cache then holds the prefix's whole blocks)."""
+    first_token = {}
+    for r in run.records:
+        if r["group"] and not failed(r):
+            g = first_token.setdefault(r["group"], [])
+            g.append(r["token_times"][0])
+    out = []
+    for r in _slice_requests(run):
+        cached = 0
+        if r["group"] and any(t < r["send"] for t in first_token[r["group"]]):
+            cached = (r.get("prefix_tokens", 0) // BLOCK) * BLOCK
+        out.append((cached, r["prompt_tokens"] - cached))
+    return out
+
+
+def read(run: RunData, args: dict):
+    trace = run.device_trace
+    if trace is None:
+        return None
+    stat = args["stat"]
+    d0 = trace.devices[0]
+    if stat == "idle_pct":
+        # the chip that waits longest
+        return 100.0 * max(trace.idle_share(d) for d in trace.devices)
+    if stat == "op_share_of_busy_pct":
+        own = sum(o.own for o in trace.ops[d0] if _matches(args["op"], o))
+        return 100.0 * own / trace.busy_s[d0] if trace.busy_s[d0] else None
+
+    mods, kernel_ops = _modules_with(trace, d0, args["with_op"])
+    if not mods:
+        return None
+    if stat == "program_ms_per_execution":
+        return 1e3 * sum(m.dur for m in mods) / len(mods), len(mods)
+    if stat == "program_ms_per_1000_prompt_tokens":
+        tokens = sum(r["prompt_tokens"] for r in _slice_requests(run))
+        return (1e6 * sum(m.dur for m in mods) / tokens, len(mods)) if tokens else None
+
+    peaks = peaks_for(run.device_kind)
+    kernel_s = sum(o.own for o in kernel_ops)
+    hf, tp = run.hf, _tp(run)
+    heads = int(hf["num_attention_heads"])
+    kv_heads = int(hf.get("num_key_value_heads", heads))
+    head_dim = int(hf.get("head_dim") or hf["hidden_size"] // heads)
+    layers = int(hf["num_hidden_layers"])
+    window = int(hf.get("sliding_window") or 0) or None
+    if not kernel_s:
+        return None
+    if stat == "decode_kernel_roofline_pct":     # HBM-bound
+        keys = _mean_attended_keys(run, window)
+        per_step = opsbytes.decode_attention_bytes(
+            [keys], max(1, kv_heads // tp), head_dim, layers, None)
+        least_s = len(mods) * per_step / peaks["hbm_bytes_per_s"]
+        return 100.0 * least_s / kernel_s, len(mods)
+    if stat == "prefill_kernel_roofline_pct":    # FLOP-bound
+        flops = opsbytes.prefill_attention_flops(
+            _computed_chunks(run), max(1, heads // tp), head_dim, layers, window)
+        least_s = flops / peaks["flops_bf16"]
+        return 100.0 * least_s / kernel_s, len(mods)
+    raise ValueError(f"device_trace reader: unknown stat {stat!r}")

@@ -1,0 +1,129 @@
+"""The reduction from a profiler capture to numbers, checked against a
+cut of a capture recorded on the v5e (phi3-chat, chip call 1 of PR 22:
+one second around a prefill step, ``Async XLA Ops`` and the events' stats
+dropped to keep it small). The expected values were worked out straight
+from the protobuf by a throw-away script, not by this code."""
+
+import json
+import os
+
+import pytest
+
+from harness import trace
+from harness.manifest import Cell
+from harness.rundata import RunData
+from readers import device_trace
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+NAME = "v5e-decode-prefill"
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    t = trace.load(os.path.join(DATA, NAME + ".xplane.pb"))
+    with open(os.path.join(DATA, NAME + ".expected.json")) as f:
+        return t, json.load(f)
+
+
+def test_busy_and_idle_are_the_union_of_op_intervals(recorded):
+    t, want = recorded
+    assert t.devices == [0]
+    assert len(t.ops[0]) == want["n_ops"] and len(t.modules[0]) == want["n_modules"]
+    assert t.busy_s[0] == pytest.approx(want["busy_s"], rel=1e-4)
+    lo, hi = t.span[0]
+    assert hi - lo == pytest.approx(want["device_span_s"], rel=1e-4)
+    assert 0.0 < t.idle_share(0) < 1.0
+    # own times partition the busy time: a loop is not counted over its body
+    assert sum(o.own for o in t.ops[0]) == pytest.approx(want["busy_s"], rel=1e-3)
+
+
+def test_programs_are_told_apart_by_the_kernel_inside(recorded):
+    t, want = recorded
+    mods, kern = device_trace._modules_with(t, 0, "paged_decode_attention")
+    assert len(mods) == want["decode_programs"]
+    assert sum(m.dur for m in mods) == pytest.approx(want["decode_program_s"], rel=1e-4)
+    inside = [k for k in kern]
+    assert sum(k.own for k in inside) <= want["decode_kernel_s"] * (1 + 1e-4)
+    mods, kern = device_trace._modules_with(t, 0, "paged_flash_attention")
+    assert len(mods) == want["prefill_programs"] >= 1
+    assert sum(m.dur for m in mods) == pytest.approx(want["prefill_program_s"], rel=1e-4)
+    assert sum(k.own for k in kern) == pytest.approx(want["flash_kernel_s"], rel=1e-4)
+    assert len(kern) == want["flash_kernel_calls"]
+
+
+def test_kernel_time_is_the_kernels_own_events(recorded):
+    t, want = recorded
+    dec = [o for o in t.ops[0] if o.name.startswith("paged_decode_attention")]
+    assert len(dec) == want["decode_kernel_calls"]
+    assert sum(o.own for o in dec) == pytest.approx(want["decode_kernel_s"], rel=1e-4)
+
+
+def test_breakdown_names_and_gaps(recorded):
+    t, _ = recorded
+    top = trace.top_ops(t, 0, 10)
+    assert len(top) == 10 and top[0][0].startswith("paged_flash_attention")
+    assert all(" = " not in name for name, _ in top)
+    gaps = trace.idle_gaps(t, 0, 5)
+    assert len(gaps) == 5 and gaps[0][1] >= gaps[-1][1] > 0
+    assert all("jit_step(" in label or "start" in label for label, _ in gaps)
+
+
+def test_metrics_from_the_recorded_trace(recorded):
+    t, want = recorded
+    hf = {"num_attention_heads": 32, "num_key_value_heads": 32, "hidden_size": 3072,
+          "num_hidden_layers": 32, "sliding_window": 2047}
+    cell = Cell("c", 1, {}, "k", {}, "m", {"drain_s": 1}, [], [])
+    # one sequence of 1000 tokens decoding all through the capture
+    rec = {"rid": "a", "group": "", "phase": "window", "due": 0.0, "send": 0.0,
+           "prompt_tokens": 1000, "max_tokens": 8, "prefix_tokens": 0,
+           "token_times": [0.0, 10.0], "chunk_tokens": [1, 1],
+           "usage": None, "done": True, "status": 200, "error": None}
+    run = RunData(cell=cell, hf=hf, serve={"tensor_parallel_size": 1}, seconds=1.0,
+                  window=(0.0, 10.0), setup_seconds=0.0, records=[rec],
+                  prom_start={}, prom_end={}, device_trace=t,
+                  trace_slice=(1.0, 2.0), device_kind="TPU v5 lite")
+    ms, n = device_trace.read(run, {"stat": "program_ms_per_execution",
+                                    "with_op": "paged_decode_attention"})
+    assert n == want["decode_programs"]
+    assert ms == pytest.approx(1e3 * want["decode_program_s"] / n, rel=1e-4)
+    share, _ = device_trace.read(run, {"stat": "decode_kernel_roofline_pct",
+                                       "with_op": "paged_decode_attention"})
+    # 1001 tokens x 0.5 MiB a token a step, at 819 GB/s, against kernel time
+    least = n * 1001 * 2 * 32 * 128 * 2 * 32 / 819e9
+    kernel = sum(k.own for k in device_trace._modules_with(
+        t, 0, "paged_decode_attention")[1])
+    assert share == pytest.approx(100 * least / kernel, rel=1e-4)
+    assert device_trace.read(run, {"stat": "idle_pct"}) == pytest.approx(
+        100 * (1 - want["busy_s"] / t.window_s), rel=1e-4)
+    run.device_kind = "some other chip"
+    with pytest.raises(KeyError, match="no published peaks"):
+        device_trace.read(run, {"stat": "decode_kernel_roofline_pct",
+                                "with_op": "paged_decode_attention"})
+
+
+def test_prefill_metrics_on_a_shard_of_a_model_without_a_window(recorded):
+    """The prefill side as the four-chip cell reads it: a chip's share of
+    the heads, no window, prompts from the client's records."""
+    t, want = recorded
+    hf = {"num_attention_heads": 32, "num_key_value_heads": 8, "hidden_size": 4096,
+          "num_hidden_layers": 32, "sliding_window": None}
+    cell = Cell("c", 4, {}, "k", {}, "m", {"drain_s": 1}, [], [])
+    rec = {"rid": "a", "group": "", "phase": "window", "due": 0.0, "send": 0.0,
+           "prompt_tokens": 300, "max_tokens": 8, "prefix_tokens": 0,
+           "token_times": [1.5, 3.0], "chunk_tokens": [1, 1],
+           "usage": None, "done": True, "status": 200, "error": None}
+    late = dict(rec, rid="b", token_times=[2.5, 3.0])      # first token after the slice
+    run = RunData(cell=cell, hf=hf, serve={"tensor_parallel_size": 4}, seconds=1.0,
+                  window=(0.0, 10.0), setup_seconds=0.0, records=[rec, late],
+                  prom_start={}, prom_end={}, device_trace=t,
+                  trace_slice=(1.0, 2.0), device_kind="TPU v5 lite")
+    ms, n = device_trace.read(run, {"stat": "program_ms_per_1000_prompt_tokens",
+                                    "with_op": "paged_flash_attention"})
+    assert n == want["prefill_programs"]
+    assert ms == pytest.approx(1e6 * want["prefill_program_s"] / 300, rel=1e-4)
+    share, _ = device_trace.read(run, {"stat": "prefill_kernel_roofline_pct",
+                                       "with_op": "paged_flash_attention"})
+    # 300 causal queries, 8 of the 32 heads on this chip, head 128, 32 layers
+    flops = 4 * (300 * 301 // 2) * 8 * 128 * 32
+    assert share == pytest.approx(100 * flops / 197e12 / want["flash_kernel_s"], rel=1e-4)
+    assert device_trace.read(run, {"stat": "op_share_of_busy_pct", "op": "all-reduce"}) == 0.0

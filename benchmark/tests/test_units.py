@@ -1,0 +1,185 @@
+"""The yardstick's arithmetic: statistics, traffic, ops/bytes, peaks,
+the manifest's shape. No jax, no server."""
+
+import json
+import os
+import re
+
+import pytest
+
+from harness import manifest, opsbytes, peaks, prom, stats, traffic
+from harness.modeldir import token_id, token_text
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+def test_percentile_interpolates():
+    assert stats.percentile([1, 2, 3, 4, 5], 50) == 3
+    assert stats.percentile([0, 10], 90) == pytest.approx(9.0)
+    assert stats.percentile([], 50) is None
+
+
+def test_union_and_gaps():
+    iv = [(0.0, 1.0), (0.5, 2.0), (3.0, 4.0)]
+    assert stats.union_length(iv) == pytest.approx(3.0)
+    assert stats.gaps_of(iv, 0.0, 5.0) == [(2.0, 3.0), (4.0, 5.0)]
+
+
+def test_traffic_is_the_seeds_and_the_work_is_fixed():
+    mix = json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", "chat.json")))
+    cell = {"loop": "open", "rate": 2.5}
+    a = traffic.build_plan(mix, cell, 32064, 7, 40.0)
+    b = traffic.build_plan(mix, cell, 32064, 7, 40.0)
+    c = traffic.build_plan(mix, cell, 32064, 8, 40.0)
+    assert [r.prompt for r in a.requests] == [r.prompt for r in b.requests]
+    assert [r.due_s for r in a.requests] != [r.due_s for r in c.requests]
+    in_window = lambda p: [r for r in p.requests if r.due_s >= 0]  # noqa: E731
+    assert len(in_window(a)) == len(in_window(c)) == 100
+    tok = lambda p: sum(len(r.prompt) for r in in_window(p))  # noqa: E731
+    # stratified draws: two seeds carry nearly the same token totals
+    assert abs(tok(a) - tok(c)) / tok(a) < 0.25
+    assert all(16 <= len(r.prompt) <= 2048 and 16 <= r.max_tokens <= 512
+               for r in a.requests)
+
+
+def test_sessions_share_their_prefix():
+    mix = json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", "docqa.json")))
+    plan = traffic.build_plan(mix, {"loop": "open", "rate": 3.0}, 32064, 1, 30.0)
+    groups = {}
+    for r in plan.requests:
+        groups.setdefault(r.group, []).append(r)
+    assert all(len(g) == 3 for g in groups.values())
+    for g in groups.values():
+        n = g[0].prefix_tokens
+        assert 1024 <= n <= 3072
+        assert g[0].prompt[:n] == g[1].prompt[:n] == g[2].prompt[:n]
+        assert g[0].prompt[n:] != g[1].prompt[n:]
+        assert g[1].due_s - g[0].due_s == pytest.approx(1.5)
+
+
+def test_unknown_traffic_kind_is_an_error():
+    for kind in ("nope", "../harness/traffic", None):
+        with pytest.raises(ValueError, match="not registered"):
+            traffic.build_plan({"kind": kind}, {}, 10, 0, 1.0)
+
+
+def test_bursty_arrivals_keep_the_count_and_the_interval():
+    import random
+    import statistics
+
+    def cv(spec):
+        at = traffic.fixed_count_arrivals(4.0, -5.0, 95.0, random.Random(3), spec)
+        assert len(at) == 400 and at == sorted(at) and -5.0 <= at[0] and at[-1] < 95.0
+        gaps = [b - a for a, b in zip(at, at[1:])]
+        return statistics.pstdev(gaps) / statistics.mean(gaps)
+
+    assert cv(None) == cv({"process": "poisson"}) == pytest.approx(1.0, abs=0.15)
+    assert cv({"process": "gamma", "cv": 2.5}) > 1.8
+    with pytest.raises(ValueError, match="arrival process"):
+        cv({"process": "nope"})
+
+
+def test_a_distribution_can_be_a_mixture():
+    import random
+
+    chat = {"dist": "lognormal", "median": 220, "sigma": 0.9, "min": 16, "max": 2048}
+    mixed = {"dist": "mixture", "parts": [
+        {"weight": 9, **chat}, {"weight": 1, "dist": "loguniform", "min": 3000, "max": 4000}]}
+    xs = traffic.stratified_lengths(mixed, 200, random.Random(1))
+    assert sum(x >= 3000 for x in xs) == 20 and all(16 <= x <= 4000 for x in xs)
+
+
+def test_ops_and_bytes_know_window_and_lane_padding():
+    # head 96 is stored in rows of 128 lanes, and a row is read whole
+    assert opsbytes.lane_padded(96) == 128 and opsbytes.lane_padded(128) == 128
+    one = opsbytes.decode_attention_bytes([1000], 32, 96, 32)
+    assert one == 2 * 1000 * 32 * 128 * 2 * 32          # 0.5 MiB a token
+    assert opsbytes.decode_attention_bytes([5000], 32, 96, 32, window=2047) \
+        == 2 * 2047 * 32 * 128 * 2 * 32
+    full = opsbytes.prefill_attention_flops([(0, 4)], 2, 8, 1)
+    assert full == 4 * (1 + 2 + 3 + 4) * 2 * 8
+    assert opsbytes.prefill_attention_flops([(0, 4)], 2, 8, 1, window=2) \
+        == 4 * (1 + 2 + 2 + 2) * 2 * 8
+    # a cached prefix is attended to, not recomputed
+    assert opsbytes.prefill_attention_flops([(2, 2)], 2, 8, 1) \
+        == 4 * (3 + 4) * 2 * 8
+
+
+def test_peaks_refuse_an_unknown_device():
+    assert peaks.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks.peaks_for("cpu")
+
+
+def test_prometheus_text():
+    text = ('# HELP x\nfoo_total{phase="decode",program="a"} 3\n'
+            'foo_total{phase="prefill",program="a"} 4\nbar 1.5\n')
+    s = prom.parse(text)
+    assert prom.value(s, "foo_total", {"phase": "decode"}) == 3
+    assert prom.value(s, "foo_total") == 7
+    assert prom.value(s, "absent") is None
+    assert prom.delta({}, s, "bar") == 1.5
+
+
+def test_token_text_round_trip():
+    assert token_id(" " + token_text(31999)) == 31999
+    with pytest.raises(ValueError):
+        token_id("hello")
+
+
+def test_manifest_names_files_and_keeps_code_free_of_names():
+    man = manifest.load_manifest()
+    assert set(man) == {"command", "paths", "run_seconds", "configs",
+                        "workloads", "end_to_end", "per_layer"}
+    e2e = {m["name"] for m in man["end_to_end"]}
+    cells = {w["name"] for w in man["workloads"]}
+    names = set(cells) | {c["name"] for c in man["configs"]} | e2e
+    for w in man["workloads"]:
+        cell = manifest.load_cell(w["name"])       # every file is there
+        assert {m.name for m in cell.end_to_end} >= {"setup_s"}
+        assert len(cell.end_to_end) >= 2 and cell.per_layer
+        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+    for m in man["per_layer"]:
+        names.add(m["name"])
+        assert m["moves"] in e2e
+        for c in m.get("workloads", cells):
+            moved = next(x for x in man["end_to_end"] if x["name"] == m["moves"])
+            assert c in moved.get("workloads", cells), (m["name"], c)
+    for n in names | {w["traffic"] for w in man["workloads"]}:
+        assert NAME.match(n), n
+    for m in man["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1
+    # one file per metric, cell and configuration. A metric's file with no
+    # manifest entry is on the shelf: a time to first token and what moves
+    # it, read by nothing until a cell's runs repeat it closely enough to
+    # judge it (test_rehearsal runs them from entries alone)
+    b = manifest.BENCH_DIR
+    listed = lambda d: {f[:-5] for f in os.listdir(os.path.join(b, d))}  # noqa: E731
+    assert listed("layer_metrics") >= {m["name"] for m in man["per_layer"]}
+    assert listed("end_to_end") >= e2e and listed("cells") == cells
+    names |= listed("layer_metrics") | listed("end_to_end")
+    assert listed("configs") == {c["name"] for c in man["configs"]}
+    assert listed("traffic") >= {w["traffic"] for w in man["workloads"]}
+    assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(1, len(cells) // 4)
+    # the harness is driven by data: no cell, configuration or metric is
+    # named in the code
+    code = [os.path.join(manifest.BENCH_DIR, "run.py")]
+    for d in ("harness", "readers", "generators"):
+        code += [os.path.join(manifest.BENCH_DIR, d, f)
+                 for f in os.listdir(os.path.join(manifest.BENCH_DIR, d))
+                 if f.endswith(".py")]
+    for path in code:
+        text = open(path).read()
+        for n in names:
+            assert not re.search(r"(?<![A-Za-z0-9_])" + re.escape(n) + r"(?![A-Za-z0-9_])",
+                                 text), (n, path)
+
+
+def test_a_per_layer_metric_listed_where_its_target_is_not_reported_is_refused(tmp_path):
+    man = manifest.load_manifest()
+    first, other = (w["name"] for w in man["workloads"][:2])
+    moved = next(m for m in man["end_to_end"] if m["name"] != "setup_s")
+    moved["workloads"] = [other]          # the first cell no longer reports it
+    json.dump(man, open(tmp_path / "BENCHMARK.json", "w"))
+    with pytest.raises(manifest.ManifestError, match="does not report"):
+        manifest.load_cell(first, root=str(tmp_path))
