@@ -1,0 +1,199 @@
+"""The readers of the program's own spans (``readers/host_spans.py``):
+arithmetic on hand-made traces, then a cut of a traced v5e run of PR 23
+that holds the spans (``data/v5e-spans.*``; how it was cut is in
+``data/v5e-spans.expected.json``)."""
+
+import json
+import os
+
+import pytest
+
+from harness import trace
+from harness.manifest import Cell
+from harness.rundata import RunData
+from harness.trace import DeviceTrace, Event
+from readers import host_spans
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+ALL_SPANS = ["sched.", "sync.", "dispatch.", "http.", "pre.", "detok."]
+
+
+EDGES = [("sched.admit", 0.0, 0.0), ("sched.admit", 10.0, 10.0)]
+
+
+def _trace(ops, host, window=(0.0, 10.0), devices=(0,), edges=True):
+    """A capture of ``window`` with the same operations on every device;
+    ``edges`` puts an empty ``sched.admit`` at both ends, so that the
+    program's spans are on record over the whole window."""
+    if edges and any(n.startswith("sched.") for n, _, _ in host):
+        host = EDGES + list(host)
+    evs = [Event("fusion.1", s, e - s, own=e - s) for s, e in ops]
+    busy = sum(e - s for s, e in ops)
+    return DeviceTrace(
+        window=window, devices=list(devices),
+        busy_s={d: busy for d in devices},
+        span={d: (ops[0][0], ops[-1][1]) for d in devices},
+        modules={d: [] for d in devices}, ops={d: list(evs) for d in devices},
+        host=[Event(n, s, e - s) for n, s, e in host])
+
+
+def _run(t):
+    cell = Cell("c", 1, {}, "k", {}, "m", {"drain_s": 1}, [], [])
+    return RunData(cell=cell, hf={}, serve={}, seconds=1.0, window=(0.0, 10.0),
+                   setup_seconds=0.0, records=[], prom_start={}, prom_end={},
+                   device_trace=t)
+
+
+# busy 0-1, 2-3, 5-6, 9-10: gaps 1-2, 3-5, 6-9 (6 s idle of 10)
+OPS = [(0.0, 1.0), (2.0, 3.0), (5.0, 6.0), (9.0, 10.0)]
+
+
+@pytest.mark.parametrize("host, spans, want", [
+    # a gap wholly inside sched.wait, nothing else named
+    ([("sched.wait", 0.9, 2.1)], ["sched.wait"], 100 * 1 / 6),
+    # one gap half covered
+    ([("sched.decode.build", 3.0, 4.0)], ALL_SPANS, 100 * 1 / 6),
+    # an uncovered gap stays uncovered: spans over busy time count nothing
+    ([("sched.decode.sync", 0.0, 1.0), ("sched.yield", 5.2, 5.8)], ALL_SPANS, 0.0),
+    # overlapping and nested spans are a union, not a sum
+    ([("sched.decode.dispatch", 3.0, 5.0), ("dispatch.decode", 3.5, 4.5),
+      ("sched.yield", 6.0, 9.0), ("http.sse_write", 6.5, 7.0)], ALL_SPANS, 100 * 5 / 6),
+    # every gap named: all of it
+    ([("sched.admit", 1.0, 2.0), ("sched.decode.build", 3.0, 5.0),
+      ("sched.wait", 6.0, 9.0)], ALL_SPANS, 100.0),
+    # the runtime's own host events are not the program's spans
+    ([("sched.admit", 0.0, 0.1), ("PjitFunction(decode_step)", 3.0, 5.0)], ALL_SPANS, 0.0),
+])
+def test_idle_share_covered_by_spans(host, spans, want):
+    run = _run(_trace(OPS, host))
+    got = host_spans.read(run, {"stat": "idle_covered_pct", "spans": spans})
+    assert got == pytest.approx(want)
+
+
+def test_idle_share_is_of_the_chip_that_waits_longest():
+    t = _trace(OPS, [("sched.wait", 6.0, 9.0)], devices=(0, 1))
+    t.ops[1] = t.ops[1][:3]                       # chip 1 idles from 6 to 10
+    t.busy_s[1] = 3.0
+    got = host_spans.read(_run(t), {"stat": "idle_covered_pct",
+                                    "spans": ["sched.wait"]})
+    assert got == pytest.approx(100 * 3 / 7)
+
+
+def test_idle_time_counts_only_where_spans_can_be_on_record():
+    """A span still open when the capture stops is never written: the
+    idle time after the last sched.* span's end (and before the first
+    one's start) is left out, not counted as unnamed."""
+    host = [("sched.decode.build", 3.0, 4.0), ("sched.yield", 6.0, 7.5)]
+    t = _trace(OPS, host, edges=False)
+    # between 3.0 and 7.5: idle 3-5 and 6-7.5 = 3.5 s, named 1 + 1.5
+    got = host_spans.read(_run(t), {"stat": "idle_covered_pct",
+                                    "spans": ALL_SPANS})
+    assert got == pytest.approx(100 * 2.5 / 3.5)
+
+
+def test_no_work_share_is_zero_when_the_loop_never_waited():
+    run = _run(_trace(OPS, [("sched.decode.build", 3.0, 4.0)]))
+    assert host_spans.read(run, {"stat": "idle_covered_pct",
+                                 "spans": ["sched.wait"]}) == 0.0
+
+
+@pytest.mark.parametrize("args", [
+    {"stat": "idle_covered_pct", "spans": ALL_SPANS},
+    {"stat": "span_mean_ms", "span": "sched.decode.build"},
+    {"stat": "sync_tail_mean_ms", "span": "sched.decode.sync"},
+])
+def test_a_program_without_spans_gives_nothing_to_read(args):
+    """The parent commit of PR 23 writes none: the line leaves the
+    metric out, and nothing raises."""
+    run = _run(_trace(OPS, [("PjitFunction(step)", 3.0, 5.0),
+                            ("np.asarray(jax.Array)", 0.0, 1.0)]))
+    assert host_spans.read(run, args) is None
+    run.device_trace = None                       # an untraced run
+    assert host_spans.read(run, args) is None
+
+
+def test_span_mean_is_over_the_events_of_that_name_only():
+    host = [("sched.decode.build", 1.0, 1.002), ("sched.decode.build", 3.0, 3.004),
+            ("sched.decode.build.x", 4.0, 5.0), ("sched.prefill.build", 4.0, 5.0)]
+    ms, n = host_spans.read(_run(_trace(OPS, host)),
+                            {"stat": "span_mean_ms", "span": "sched.decode.build"})
+    assert n == 2 and ms == pytest.approx(3.0)
+
+
+def test_sync_tail_runs_from_the_last_operation_that_ended_inside():
+    host = [
+        ("sched.decode.sync", 0.5, 1.25),    # op ends at 1.0: tail 0.25
+        ("sched.decode.sync", 2.5, 3.5),     # op ends at 3.0: tail 0.5
+        ("sched.decode.sync", 3.6, 4.0),     # nothing ended inside: left out
+        ("sched.decode.sync", 4.5, 9.5),     # 6.0 is the last inside (10.0 is after)
+        ("sched.prefill.sync", 5.5, 6.75),   # another span's
+    ]
+    t = _trace(OPS, host)
+    assert host_spans.sync_tails(t, "sched.decode.sync") == pytest.approx(
+        [0.25, 0.5, 3.5])
+    ms, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
+                                      "span": "sched.decode.sync"})
+    assert n == 3 and ms == pytest.approx(1e3 * (0.25 + 0.5 + 3.5) / 3)
+
+
+def test_sync_tail_waits_for_the_last_chip():
+    t = _trace(OPS, [("sched.decode.sync", 2.5, 3.5)], devices=(0, 1))
+    t.ops[1] = [Event("fusion.1", 2.0, 1.25, own=1.25)]   # chip 1 ends at 3.25
+    assert host_spans.sync_tails(t, "sched.decode.sync") == pytest.approx([0.25])
+
+
+def test_unknown_stat_is_an_error():
+    with pytest.raises(ValueError, match="unknown stat"):
+        host_spans.read(_run(_trace(OPS, [])), {"stat": "nope"})
+
+
+# ---- the recorded_spans cut: ten decode passes of phi3-chat on the v5e ----
+
+@pytest.fixture(scope="module")
+def recorded_spans():
+    t = trace.load(os.path.join(DATA, "v5e-spans.xplane.pb"))
+    with open(os.path.join(DATA, "v5e-spans.expected.json")) as f:
+        return t, json.load(f)
+
+
+@pytest.mark.parametrize("span", [
+    "sched.admit", "sched.decode.build", "sched.decode.dispatch",
+    "sched.decode.sync", "sched.decode.emit", "sched.yield", "sync.fetch",
+    "dispatch.decode"])
+def test_recorded_span_means(recorded_spans, span):
+    t, want = recorded_spans
+    ms, n = host_spans.read(_run(t), {"stat": "span_mean_ms", "span": span})
+    assert [ms, n] == pytest.approx(want["span_mean_ms"][span], rel=1e-6)
+
+
+def test_recorded_sync_tail(recorded_spans):
+    t, want = recorded_spans
+    ms, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
+                                      "span": "sched.decode.sync"})
+    assert [ms, n] == pytest.approx(want["sync_tail_mean_ms"], rel=1e-6)
+    # ready -> running again is a few milliseconds, not the whole fetch
+    assert 1.0 < ms < 10.0
+
+
+def test_recorded_idle_time_has_names(recorded_spans):
+    t, want = recorded_spans
+    assert len(t.ops[0]) == want["n_ops"]
+    assert t.window_s == pytest.approx(want["window_s"], rel=1e-6)
+    idle = sum(e - s for s, e in host_spans.idle_gaps_of(t, 0))
+    assert idle == pytest.approx(want["idle_s"], rel=1e-4)    # ns against ps
+    named = host_spans.read(_run(t), {"stat": "idle_covered_pct",
+                                      "spans": ALL_SPANS})
+    assert named == pytest.approx(
+        100 * want["idle_in_spans_s"] / want["idle_between_sched_spans_s"],
+        rel=1e-4)
+    assert named > 95.0
+    assert host_spans.read(_run(t), {"stat": "idle_covered_pct",
+                                     "spans": ["sched.wait"]}) == 0.0
+
+
+def test_recorded_gaps_are_labelled_by_the_programs_spans(recorded_spans):
+    t, _ = recorded_spans
+    gaps = trace.idle_gaps(t, 0, 5)
+    assert all(": sched." in label or ": dispatch." in label
+               for label, _ in gaps), gaps
+    assert all("jit_decode_step(" in label for label, _ in gaps), gaps

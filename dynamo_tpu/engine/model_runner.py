@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,6 +71,7 @@ def param_specs(params) -> Dict:
 CACHE_SPEC = P(None, None, None, "tp", None)  # [L, N, bs, KVH, D] — KV heads over tp
 
 
+@jax.named_scope("sampling")
 def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
                          sample_slots, commit, want_top, extra_bias=None,
                          fused=False, unique_slots=True, finish=None,
@@ -201,6 +203,7 @@ class ModelRunner:
         mesh: Optional[Mesh] = None,
         model_dir: Optional[str] = None,
     ):
+        t_init = time.monotonic()
         check_serving_device()
         self.config = config
         cfg = config.model
@@ -226,6 +229,11 @@ class ModelRunner:
             config.dp_size, config.tp_size, ep=config.ep_size,
             pp=config.pp_size, sp=config.sp_size,
         )
+        # the split of set-up, one stamp a phase
+        # (dynamo_engine_startup_seconds): reaching the device(s), then
+        # weights, cache pool and warm-up, each timed to the moment its
+        # arrays are on the device
+        self.startup_s = {"device_init": time.monotonic() - t_init}
         # mixed dense+MoE MLA trunk under pp: the dense prefix stays
         # replicated (params, cache, and compute) while the MoE trunk
         # stages — parallel/pipeline.py's has_prefix path
@@ -296,6 +304,7 @@ class ModelRunner:
                 f"unknown quantization {cfg.quantization!r} (only int8)"
             )
 
+        t_weights = time.monotonic()
         if params is None:
             if model_dir is not None:
                 from ..models.loader import has_checkpoint, load_checkpoint_params
@@ -369,7 +378,12 @@ class ModelRunner:
             is_leaf=lambda x: isinstance(x, P),
         )
         self.state_sharding = NamedSharding(self.mesh, P("dp", None))
+        jax.block_until_ready(self.params)
+        t_cache = time.monotonic()
+        self.startup_s["weights"] = t_cache - t_weights
         self._init_device_state()
+        jax.block_until_ready((self.kv_cache, self.sample_state))
+        self.startup_s["kv_cache"] = time.monotonic() - t_cache
 
         # XLA compile observability: every compiled-program dispatch site
         # below runs through compiles.track(program, shape-bucket key) —
@@ -379,6 +393,14 @@ class ModelRunner:
         # / prefill worker attach compiles.registry into the engine's
         # scrape and flip the serving flag when they start.
         self.compiles = CompileTracker()
+        self._startup_gauge = self.compiles.registry.gauge(
+            "dynamo_engine_startup_seconds",
+            "Wall time of each set-up phase, set once: phase="
+            "device_init|weights|kv_cache|warmup (warm-up by program is "
+            "dynamo_engine_xla_compile_duration_seconds)",
+        )
+        for phase, seconds in self.startup_s.items():
+            self._startup_gauge.set(seconds, phase=phase)
         # attention-route observability: the dispatch seams in
         # ops/attention.py / parallel/sequence.py record which kernel
         # served each trace; the tracked dispatch supplies the program
@@ -455,7 +477,8 @@ class ModelRunner:
                 )
 
         def head(hidden, params):
-            return arch.logits_from_hidden(hidden, params, cfg)
+            with jax.named_scope("lm_head"):
+                return arch.logits_from_hidden(hidden, params, cfg)
 
         return forward, head
 
@@ -537,8 +560,16 @@ class ModelRunner:
             frequency_penalty=batch_spec, repetition_penalty=batch_spec,
             keys=batch2_spec, counters=batch_spec,
         )
-        self._step = jax.jit(
-            step,
+        # one body, two names: the profiler's trace and the HLO dump
+        # then read jit_decode_step(...) and jit_prefill_step(...)
+        # (step() picks by S, as its CompileTracker label does)
+        def decode_step(*args):
+            return step(*args)
+
+        def prefill_step(*args):
+            return step(*args)
+
+        jit_kw = dict(
             donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=(
                 self.param_shardings,        # params
@@ -567,6 +598,8 @@ class ModelRunner:
                            self.state_sharding, self.state_sharding,
                            self.state_sharding),
         )
+        self._decode_step = jax.jit(decode_step, **jit_kw)
+        self._prefill_step = jax.jit(prefill_step, **jit_kw)
 
     def _build_burst(self):
         """K fused decode steps per dispatch (config.multi_step_decode).
@@ -605,9 +638,9 @@ class ModelRunner:
 
         import dataclasses as _dc
 
-        def burst(params, k_cache, v_cache, counts, seen, bias, tokens0,
-                  positions0, block_tables, samp, sample_slots, commit,
-                  want_top):
+        def decode_burst(params, k_cache, v_cache, counts, seen, bias,
+                         tokens0, positions0, block_tables, samp,
+                         sample_slots, commit, want_top):
             b = tokens0.shape[0]
             rows = jnp.arange(b)
 
@@ -643,7 +676,7 @@ class ModelRunner:
             keys=batch2_spec, counters=batch_spec,
         )
         self._burst = jax.jit(
-            burst,
+            decode_burst,
             donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=(
                 self.param_shardings,
@@ -699,11 +732,11 @@ class ModelRunner:
 
         max_len = self.config.max_model_len
 
-        def burst_df(params, k_cache, v_cache, counts, seen, bias,
-                     tokens0, positions0, gen0, done0, ring0, gstate0,
-                     block_tables, samp, sample_slots, commit, want_top,
-                     stop_ids, min_new, max_new, stop_hash, stop_hlen,
-                     gtable):
+        def decode_burst_df(params, k_cache, v_cache, counts, seen, bias,
+                            tokens0, positions0, gen0, done0, ring0,
+                            gstate0, block_tables, samp, sample_slots,
+                            commit, want_top, stop_ids, min_new, max_new,
+                            stop_hash, stop_hlen, gtable):
             b = tokens0.shape[0]
             rows = jnp.arange(b)
 
@@ -792,7 +825,7 @@ class ModelRunner:
                     k_cache, v_cache, counts, seen, bias)
 
         self._burst_df = jax.jit(
-            burst_df,
+            decode_burst_df,
             donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=(
                 self.param_shardings,
@@ -870,10 +903,10 @@ class ModelRunner:
         max_len = self.config.max_model_len
         match = self.config.spec_ngram_match
 
-        def spec_round(params, k_cache, v_cache, tokens0, positions0,
-                       gen0, done0, ring0, gstate0, block_tables, commit,
-                       stop_ids, min_new, max_new, stop_hash, stop_hlen,
-                       props):
+        def spec_verify(params, k_cache, v_cache, tokens0, positions0,
+                        gen0, done0, ring0, gstate0, block_tables, commit,
+                        stop_ids, min_new, max_new, stop_hash, stop_hlen,
+                        props):
             b = tokens0.shape[0]
             rows = jnp.arange(b)
             live0 = jnp.logical_and(commit, jnp.logical_not(done0))
@@ -956,7 +989,7 @@ class ModelRunner:
                        gen0, done0, ring0, gstate0, block_tables, commit,
                        stop_ids, min_new, max_new, stop_hash, stop_hlen):
             props = _ngram_props(ring0, match, K)
-            return spec_round(
+            return spec_verify(
                 params, k_cache, v_cache, tokens0, positions0, gen0,
                 done0, ring0, gstate0, block_tables, commit, stop_ids,
                 min_new, max_new, stop_hash, stop_hlen, props,
@@ -964,7 +997,7 @@ class ModelRunner:
 
         if cfg_e.spec_draft_model:
             self._spec_verify = jax.jit(
-                spec_round,
+                spec_verify,
                 donate_argnums=(1, 2),
                 in_shardings=common_in + (batchrow_spec,),  # props [B, K]
                 out_shardings=common_out,
@@ -1028,10 +1061,10 @@ class ModelRunner:
         forward, head = self._make_forward()
         del forward  # the SP trunk has its own
 
-        def sp_step(params, k_cache, v_cache, counts, seen, bias, tokens,
-                    positions, block_tables, slot_mapping, context_lens,
-                    chunk_start, last_idx, samp, sample_slots, commit,
-                    want_top):
+        def prefill_sp(params, k_cache, v_cache, counts, seen, bias,
+                       tokens, positions, block_tables, slot_mapping,
+                       context_lens, chunk_start, last_idx, samp,
+                       sample_slots, commit, want_top):
             hidden, (k_cache, v_cache) = llama.sp_decoder_forward(
                 params, cfg, tokens, positions, (k_cache, v_cache),
                 block_tables, slot_mapping, context_lens, chunk_start,
@@ -1054,7 +1087,7 @@ class ModelRunner:
             repetition_penalty=repl, keys=repl, counters=repl,
         )
         self._sp_prefill = jax.jit(
-            sp_step,
+            prefill_sp,
             donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=(
                 self.param_shardings,
@@ -1456,7 +1489,8 @@ class ModelRunner:
             f"b{b}_s{s}_w{block_tables.shape[1]}",
         ):
             (next_tokens, lps, top_vals, top_ids, prompt_lps, greedy_all,
-             k, v, counts, seen, bias) = self._step(
+             k, v, counts, seen, bias) = (
+                self._prefill_step if s > 1 else self._decode_step)(
                 self.params, self.kv_cache[0], self.kv_cache[1],
                 self.sample_state[0], self.sample_state[1],
                 self.sample_state[2],
@@ -1883,6 +1917,7 @@ class ModelRunner:
         path from a compile error to another attention route:
         ``attention_impl="xla"`` is the operator's explicit choice.
         """
+        t_warm = time.monotonic()
         b = decode_batch or self.config.max_batch_size
         # the sample-row install program is shape-invariant and otherwise
         # compiles at the FIRST admission — a needless late compile on
@@ -2040,3 +2075,8 @@ class ModelRunner:
                     np.ones(r, np.float32),
                     jax.random.PRNGKey(0),
                 )
+        # every program has executed once when warm-up is over: the
+        # gauge then holds compile (or cache load) plus first execution
+        jax.block_until_ready((self.kv_cache, self.sample_state))
+        self.startup_s["warmup"] = time.monotonic() - t_warm
+        self._startup_gauge.set(self.startup_s["warmup"], phase="warmup")

@@ -31,6 +31,7 @@ from ..protocols.common import (
 from ..runtime.engine import AsyncEngineContext
 from ..telemetry.flight import FlightRecorder, flight_recorder
 from ..telemetry.registry import STEP_BUCKETS, MetricsRegistry
+from ..telemetry.tracing import span
 from ..tokens import TokenSequence
 from ..utils import faults
 from .block_allocator import BlockAllocator, KvEventSink
@@ -165,6 +166,15 @@ class EngineRequest:
     context_len: int = 0          # tokens whose KV is (being) written
     pending_token: int = -1       # sampled but KV not yet written
     generated: int = 0
+    # per-request accounting for the trace record (published on the
+    # context at finish): prompt tokens found in the prefix cache and
+    # prompt tokens the prefill computed, summed over every admission
+    # (a preempted request's recompute counts), tokens decode steps
+    # made, and how often the request was preempted
+    cached_tokens: int = 0
+    computed_tokens: int = 0
+    decode_tokens: int = 0
+    preemptions: int = 0
     seq: Optional[TokenSequence] = None
     registered_blocks: int = 0
     finish: Optional[FinishReason] = None
@@ -564,6 +574,7 @@ class Scheduler:
         self.prefix_hit_tokens = 0
         self.prefix_total_tokens = 0
         self.steps = 0
+        self.passes = 0  # loop passes: the ``step`` stat of sched.* spans
         # ngram speculative decoding acceptance telemetry
         self.spec_proposed = 0
         self.spec_accepted = 0
@@ -782,9 +793,46 @@ class Scheduler:
             ),
         )
 
+        self._prefix_hit_ctr = reg.counter(
+            "dynamo_kv_prefix_hit_tokens_total",
+            "Prompt tokens served from the prefix cache at admission "
+            "(the ratio gauge above is cumulative; a window's own hit "
+            "share is the delta of this over the lookup counter's)",
+        )
+        self._prefix_lookup_ctr = reg.counter(
+            "dynamo_kv_prefix_lookup_tokens_total",
+            "Prompt tokens looked up in the prefix cache at admission",
+        )
+        self._queue_wait_hist = reg.histogram(
+            "dynamo_scheduler_queue_wait_seconds",
+            "Time a request waited in the admission queue: its queued "
+            "(or preempted) mark to its admission mark",
+        )
+
     def _observe_host_sync(self, dt: float) -> None:
         self._phase_hist.observe(dt, phase="host_sync")
         self._host_sync_s += dt
+
+    def _mark_admission(self, er: EngineRequest) -> None:
+        """The admission mark, and the wait since the request last
+        entered the queue (its ``queued`` or ``preempted`` mark)."""
+        waited_from = next(
+            (t for name, t in reversed(er.ctx.stages)
+             if name in ("queued", "preempted")), None)
+        er.ctx.add_stage("admission")
+        if waited_from is not None:
+            self._queue_wait_hist.observe(er.ctx.stages[-1][1] - waited_from)
+
+    def _count_prefix_lookup(self, er: EngineRequest,
+                             lookup_tokens: int) -> None:
+        """One admission's prefix-cache lookup: hit and looked-up
+        tokens, for the engine and for the request's trace record."""
+        self.prefix_hit_tokens += er.num_cached
+        self.prefix_total_tokens += lookup_tokens
+        self._prefix_hit_ctr.inc(er.num_cached)
+        self._prefix_lookup_ctr.inc(lookup_tokens)
+        er.cached_tokens += er.num_cached
+        er.computed_tokens += lookup_tokens - er.num_cached
 
     # ---------- public API ----------
 
@@ -1165,6 +1213,11 @@ class Scheduler:
             generated=er.generated, device_finished=er.device_frozen,
         )
         er.ctx.add_stage("completion")
+        er.ctx.counts.update(
+            cached_tokens=er.cached_tokens,
+            computed_tokens=er.computed_tokens,
+            decode_tokens=er.decode_tokens, preemptions=er.preemptions,
+        )
         if emit:
             er.out_queue.put_nowait(EngineOutput(token_ids=[], finish_reason=reason))
         er.out_queue.put_nowait(None)  # stream end sentinel
@@ -1185,6 +1238,7 @@ class Scheduler:
         self._register_completed_blocks(er)
         er.pending_token = token
         er.generated += 1
+        er.decode_tokens += 1
         # the ring tail mirrors the burst carry's suffix ring (ends with
         # the pending token) — _check_finish's stop-seq compare and the
         # next chain fill both read it
@@ -1227,84 +1281,93 @@ class Scheduler:
             # watchdog heartbeat (telemetry/watchdog.py): a wedge INSIDE
             # this pass — hung compile, dead host sync — leaves it stale
             self.last_loop_t = pass_t0
+            self.passes += 1
 
-            # drop cancelled requests (client disconnects / kills)
-            for er in list(self.waiting):
-                if er.ctx.is_stopped:
+            # the sched.* spans (telemetry/tracing.span) put this pass's
+            # seams into the profiler's trace. They follow one another
+            # and never overlap; no span wraps the whole pass, which
+            # would cover every device-idle gap and name none. Leaf
+            # spans hold no await (sched.admit's only one is the remote
+            # prefill submit of a disaggregated engine); only
+            # sched.*.sync, sched.yield and sched.wait cross one.
+            with span("sched.admit", step=self.passes):
+                # drop cancelled requests (client disconnects / kills)
+                for er in list(self.waiting):
+                    if er.ctx.is_stopped:
+                        self.waiting.remove(er)
+                        self._finish(er, FinishReason.CANCELLED)
+                for er in [s for s in self.slots if s is not None]:
+                    if er.ctx.is_stopped:
+                        if er in self.prefilling:
+                            self.prefilling.remove(er)
+                        self._sp_drop(er)
+                        self._finish(er, FinishReason.CANCELLED)
+
+                # remote prefill completions / cancellations / timeouts
+                if self.pending_remote:
+                    progressed |= self._reap_remote()
+
+                # prefix-pull completions / fallbacks / timeouts
+                if self.pending_pull:
+                    progressed |= self._reap_pulls()
+
+                # admission, pulls first: a prefix pull is only a block
+                # reservation + a transfer (no local compute), and a pulled
+                # prefix shrinks the suffix every later decision (remote
+                # prefill, local chunking) sees
+                t_adm = time.monotonic()
+                admitted = False
+                if (self.fabric is not None and not self.draining
+                        and self.fabric.may_hold_any()):
+                    for er in list(self.waiting):
+                        if len(self.pending_pull) >= self.config.max_batch_size:
+                            break
+                        if self._try_submit_pull(er):
+                            self.waiting.remove(er)
+                            progressed = admitted = True
+                if self.disagg is not None and not self.draining:
+                    for er in list(self.waiting):
+                        if (len(self.pending_remote)
+                                >= self.config.max_batch_size):
+                            break
+                        if await self._try_submit_remote(er):
+                            self.waiting.remove(er)
+                            progressed = admitted = True
+
+                # local admission: claim a slot + blocks, join the prefill
+                # batch (up to max_prefill_batch prompts prefill together).
+                # Requests held for an overlapping in-flight prefix pull
+                # (pull_hold_until) are skipped, not admitted to recompute
+                # what the pull is about to install; everyone else keeps
+                # FIFO order.
+                # both ladders honor the prefill-batch cap: SP-routed
+                # admissions pre-allocate their WHOLE prompt's blocks while
+                # the single-owner ladder serves one prompt at a time, so an
+                # unbounded sp_queue would pin the block pool idle and
+                # preempt-thrash live decode streams — oversize backlogs
+                # wait block-free in `waiting`, exactly like the dense path
+                while (self.waiting
+                       and not self.draining
+                       and len(self.prefilling) < self.config.max_prefill_batch
+                       and (len(self.sp_queue)
+                            + (1 if self.sp_active is not None else 0)
+                            < self.config.max_prefill_batch)
+                       and self._free_slot() is not None):
+                    now_h = time.monotonic()
+                    er = next((e for e in self.waiting
+                               if e.pull_hold_until <= now_h), None)
+                    if er is None:
+                        break  # everyone waiting is held on a pull
+                    try:
+                        self._start_prefill(er)
+                    except MemoryError:
+                        break  # no memory — wait for a sequence to finish
                     self.waiting.remove(er)
-                    self._finish(er, FinishReason.CANCELLED)
-            for er in [s for s in self.slots if s is not None]:
-                if er.ctx.is_stopped:
-                    if er in self.prefilling:
-                        self.prefilling.remove(er)
-                    self._sp_drop(er)
-                    self._finish(er, FinishReason.CANCELLED)
-
-            # remote prefill completions / cancellations / timeouts
-            if self.pending_remote:
-                progressed |= self._reap_remote()
-
-            # prefix-pull completions / fallbacks / timeouts
-            if self.pending_pull:
-                progressed |= self._reap_pulls()
-
-            # admission, pulls first: a prefix pull is only a block
-            # reservation + a transfer (no local compute), and a pulled
-            # prefix shrinks the suffix every later decision (remote
-            # prefill, local chunking) sees
-            t_adm = time.monotonic()
-            admitted = False
-            if (self.fabric is not None and not self.draining
-                    and self.fabric.may_hold_any()):
-                for er in list(self.waiting):
-                    if len(self.pending_pull) >= self.config.max_batch_size:
-                        break
-                    if self._try_submit_pull(er):
-                        self.waiting.remove(er)
-                        progressed = admitted = True
-            if self.disagg is not None and not self.draining:
-                for er in list(self.waiting):
-                    if (len(self.pending_remote)
-                            >= self.config.max_batch_size):
-                        break
-                    if await self._try_submit_remote(er):
-                        self.waiting.remove(er)
-                        progressed = admitted = True
-
-            # local admission: claim a slot + blocks, join the prefill
-            # batch (up to max_prefill_batch prompts prefill together).
-            # Requests held for an overlapping in-flight prefix pull
-            # (pull_hold_until) are skipped, not admitted to recompute
-            # what the pull is about to install; everyone else keeps
-            # FIFO order.
-            # both ladders honor the prefill-batch cap: SP-routed
-            # admissions pre-allocate their WHOLE prompt's blocks while
-            # the single-owner ladder serves one prompt at a time, so an
-            # unbounded sp_queue would pin the block pool idle and
-            # preempt-thrash live decode streams — oversize backlogs
-            # wait block-free in `waiting`, exactly like the dense path
-            while (self.waiting
-                   and not self.draining
-                   and len(self.prefilling) < self.config.max_prefill_batch
-                   and (len(self.sp_queue)
-                        + (1 if self.sp_active is not None else 0)
-                        < self.config.max_prefill_batch)
-                   and self._free_slot() is not None):
-                now_h = time.monotonic()
-                er = next((e for e in self.waiting
-                           if e.pull_hold_until <= now_h), None)
-                if er is None:
-                    break  # everyone waiting is held on a pull
-                try:
-                    self._start_prefill(er)
-                except MemoryError:
-                    break  # no memory — wait for a sequence to finish
-                self.waiting.remove(er)
-                progressed = admitted = True
-            if admitted:
-                self._phase_hist.observe(
-                    time.monotonic() - t_adm, phase="admission"
-                )
+                    progressed = admitted = True
+                if admitted:
+                    self._phase_hist.observe(
+                        time.monotonic() - t_adm, phase="admission"
+                    )
 
             # one prefill step (≤ max_prefill_tokens_per_step tokens,
             # split across the batch) per loop pass, interleaved with the
@@ -1452,22 +1515,29 @@ class Scheduler:
                 self._last_burst_done_t = None
                 if self.device_time is not None:
                     self.device_time.idle()
-                if not self.waiting and not any(self.slots):
-                    if self.pending_remote or self.pending_pull:
-                        # sleep but wake on remote/pull completion — the
-                        # bounded wait keeps deadline checks live even
-                        # if a stalled pull never completes its future
-                        try:
-                            await asyncio.wait_for(self.wake.wait(), timeout=0.5)
-                        except asyncio.TimeoutError:
-                            pass
+                # no runnable work: idle no scheduler change recovers
+                with span("sched.wait", step=self.passes):
+                    if not self.waiting and not any(self.slots):
+                        if self.pending_remote or self.pending_pull:
+                            # sleep but wake on remote/pull completion —
+                            # the bounded wait keeps deadline checks live
+                            # even if a stalled pull never completes its
+                            # future
+                            try:
+                                await asyncio.wait_for(
+                                    self.wake.wait(), timeout=0.5)
+                            except asyncio.TimeoutError:
+                                pass
+                        else:
+                            await self.wake.wait()
                     else:
-                        await self.wake.wait()
-                else:
-                    await asyncio.sleep(0.001)
+                        await asyncio.sleep(0.001)
             else:
                 self._step_hist.observe(time.monotonic() - pass_t0)
-                await asyncio.sleep(0)  # let I/O run between steps
+                # let I/O run between steps: HTTP, detokenizer, SSE and
+                # /metrics all run inside this span
+                with span("sched.yield", step=self.passes):
+                    await asyncio.sleep(0)
 
         # stopping: reconcile any chained or dispatch-ahead burst so no
         # sampled tokens are silently dropped and no device work is
@@ -1531,87 +1601,89 @@ class Scheduler:
         # device is ``ahead`` tokens past the host's committed state
         ahead = infl.k_steps if infl is not None else 0
 
-        for er in active:
+        with span("sched.decode.build", step=self.passes,
+                  rows=len(active)):
             # 2*K from the host context: covers the burst dispatched now
             # (positions ahead..ahead+K-1 past the committed state) and
             # keeps the invariant once reconciliation advances the host
-            ok = all(
+            reserved = all(
                 self._ensure_block_for(er, er.context_len + j)
-                for j in range(2 * k_steps)
+                for er in active for j in range(2 * k_steps)
             )
-            if not ok:
-                # KV OOM: preemption needs fully-committed host state —
-                # drain, then let the sync path preempt/decode this pass
-                self.allocator.flush_offload()
-                await self._drain_pipeline(loop)
-                live = [e for e in active if e.finish is None]
-                if live:
-                    await self._decode(loop, live, k_steps)
-                return
-        # one batched host-offload gather for this pass's evictions,
-        # before the dispatch below overwrites the evicted slots
-        self.allocator.flush_offload()
+            # one batched host-offload gather for this pass's evictions,
+            # before the dispatch below overwrites the evicted slots
+            self.allocator.flush_offload()
+            if reserved:
+                hs = self._host
+                positions0 = np.zeros(b, np.int32)
+                ctrs = np.zeros(b, np.int32)
+                commit = np.zeros(b, bool)
+                for er in active:
+                    i = er.slot
+                    hs.sync_blocks(er)
+                    positions0[i] = er.context_len + ahead
+                    ctrs[i] = er.generated + ahead
+                    commit[i] = True
+                w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+                btab = hs.btab[:, :w].copy()
+                if infl is None:
+                    # pipeline fill (first burst after a drain): tokens from host
+                    tokens0 = np.zeros(b, np.int32)
+                    for er in active:
+                        tokens0[er.slot] = er.pending_token
+                else:
+                    tokens0 = infl.last_tokens  # device-resident carry
+                want_top = any(er.logprobs_n > 0 for er in active)
 
-        hs = self._host
-        positions0 = np.zeros(b, np.int32)
-        ctrs = np.zeros(b, np.int32)
-        commit = np.zeros(b, bool)
-        for er in active:
-            i = er.slot
-            hs.sync_blocks(er)
-            positions0[i] = er.context_len + ahead
-            ctrs[i] = er.generated + ahead
-            commit[i] = True
-        w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
-        btab = hs.btab[:, :w].copy()
-        if infl is None:
-            # pipeline fill (first burst after a drain): tokens from host
-            tokens0 = np.zeros(b, np.int32)
-            for er in active:
-                tokens0[er.slot] = er.pending_token
-        else:
-            tokens0 = infl.last_tokens  # device-resident carry
-        want_top = any(er.logprobs_n > 0 for er in active)
+                # device-idle bookkeeping: if the previous burst's outputs are
+                # already materialized when this dispatch goes out, the device
+                # ran dry — charge the gap since the last host reconciliation
+                # (a host-observed approximation; 0 while the device is busy)
+                now = time.monotonic()
+                if self._last_burst_done_t is not None:
+                    if infl is None:
+                        self._bubble_hist.observe(now - self._last_burst_done_t)
+                    else:
+                        ready = getattr(infl.last_tokens, "is_ready", lambda: True)()
+                        self._bubble_hist.observe(
+                            now - self._last_burst_done_t if ready else 0.0
+                        )
+                self._last_burst_done_t = None
+        if not reserved:
+            # KV OOM: preemption needs fully-committed host state —
+            # drain, then let the sync path preempt/decode this pass
+            await self._drain_pipeline(loop)
+            live = [e for e in active if e.finish is None]
+            if live:
+                await self._decode(loop, live, k_steps)
+            return
 
-        # device-idle bookkeeping: if the previous burst's outputs are
-        # already materialized when this dispatch goes out, the device
-        # ran dry — charge the gap since the last host reconciliation
-        # (a host-observed approximation; 0 while the device is busy)
-        now = time.monotonic()
-        if self._last_burst_done_t is not None:
-            if infl is None:
-                self._bubble_hist.observe(now - self._last_burst_done_t)
-            else:
-                ready = getattr(infl.last_tokens, "is_ready", lambda: True)()
-                self._bubble_hist.observe(
-                    now - self._last_burst_done_t if ready else 0.0
-                )
-        self._last_burst_done_t = None
-
-        toks, lps, tv, ti = self.runner.decode_burst(
-            tokens0, positions0, btab, hs.temp, hs.top_k, hs.top_p,
-            min_p=hs.min_p, presence_penalty=hs.pres,
-            frequency_penalty=hs.freq, repetition_penalty=hs.rep,
-            seed_keys=hs.keys, counters=ctrs, commit=commit,
-            want_top=want_top,
-        )
-        self.steps += 1
-        self.pipeline_bursts += 1
-        self.flight.record(
-            "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
-            pipelined=True, carried=infl is not None,
-            requests=[er.request_id for er in active[:8]],
-        )
-        dt = self.device_time
-        self._inflight = _InflightBurst(
-            active=list(active), toks=toks, lps=lps, tv=tv, ti=ti,
-            k_steps=k_steps, last_tokens=toks[k_steps - 1],
-            dispatch_t=now,
-            read_bytes=dt.decode_read_bytes(
-                k_steps, sum(er.context_len for er in active),
-            ) if dt is not None else 0.0,
-            tokens=k_steps * len(active),
-        )
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(active)):
+            toks, lps, tv, ti = self.runner.decode_burst(
+                tokens0, positions0, btab, hs.temp, hs.top_k, hs.top_p,
+                min_p=hs.min_p, presence_penalty=hs.pres,
+                frequency_penalty=hs.freq, repetition_penalty=hs.rep,
+                seed_keys=hs.keys, counters=ctrs, commit=commit,
+                want_top=want_top,
+            )
+            self.steps += 1
+            self.pipeline_bursts += 1
+            self.flight.record(
+                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
+                pipelined=True, carried=infl is not None,
+                requests=[er.request_id for er in active[:8]],
+            )
+            dt = self.device_time
+            self._inflight = _InflightBurst(
+                active=list(active), toks=toks, lps=lps, tv=tv, ti=ti,
+                k_steps=k_steps, last_tokens=toks[k_steps - 1],
+                dispatch_t=now,
+                read_bytes=dt.decode_read_bytes(
+                    k_steps, sum(er.context_len for er in active),
+                ) if dt is not None else 0.0,
+                tokens=k_steps * len(active),
+            )
         if infl is not None:
             # burst k+1 is on device — reconcile burst k while it runs
             await self._apply_burst(loop, infl)
@@ -1637,108 +1709,112 @@ class Scheduler:
             # — the exact executor-side shape of a hung Mosaic compile
             # or a dead device mid-sync (utils/faults.py)
             faults.maybe_hang("decode_burst_hang")
-            if infl.spec:
-                # spec rounds carry no logprob outputs (spec-eligible
-                # rows want none) but do carry acceptance accounting
-                return (np.asarray(infl.toks), None, None, None,
-                        np.asarray(infl.nprop), np.asarray(infl.nacc))
-            return (np.asarray(infl.toks), np.asarray(infl.lps),
-                    np.asarray(infl.tv), np.asarray(infl.ti), None, None)
+            with span("sync.fetch"):
+                if infl.spec:
+                    # spec rounds carry no logprob outputs (spec-eligible
+                    # rows want none) but do carry acceptance accounting
+                    return (np.asarray(infl.toks), None, None, None,
+                            np.asarray(infl.nprop), np.asarray(infl.nacc))
+                return (np.asarray(infl.toks), np.asarray(infl.lps),
+                        np.asarray(infl.tv), np.asarray(infl.ti), None, None)
 
-        toks, lpn, tv, ti, nprop, nacc = await loop.run_in_executor(
-            None, _sync_burst)
-        self._observe_host_sync(time.monotonic() - t_sync)
-        self._last_burst_done_t = time.monotonic()
-        if self.device_time is not None and infl.dispatch_t:
-            self.device_time.observe(
-                "decode_burst_df" if infl.device_finish else "decode_burst",
-                "decode", infl.dispatch_t,
-                ready_hint if ready_hint is not None
-                else self._last_burst_done_t,
-                read_bytes=infl.read_bytes, tokens=infl.tokens,
-            )
-        for j in range(infl.k_steps):
-            for er in infl.active:
-                if er.finish is not None:
-                    continue  # finished/cancelled: over-decode discarded
-                token = int(toks[j, er.slot])
-                if infl.device_finish and token < 0:
-                    if er.chain_fp:
-                        continue  # already flagged: resumes at barrier
-                    if infl.spec and j > 0:
-                        # spec rounds pad past the acceptance length —
-                        # every LIVE row still emits its correction at
-                        # j=0, so only a j=0 pad means a frozen row
-                        continue
-                    if (er.fin_stop_hash is not None
-                            and er.finish is None):
-                        # the device's suffix-hash stop candidate froze
-                        # this row, but the host's EXACT token-suffix
-                        # check (_check_finish, ran on every emitted
-                        # token above) never fired: a hash collision.
-                        # Flag it — the chain closes at the next pass
-                        # and the row resumes byte-identically from its
-                        # committed state (no tokens were lost: frozen
-                        # rows never over-decode).
-                        er.chain_fp = True
-                        self._chain_fp = True
-                        self._note_sync_fallback("stop_false_positive")
-                        self.flight.record(
-                            "scheduler.stop_false_positive",
-                            request_id=er.request_id,
-                            trace_id=er.ctx.trace_id,
-                            generated=er.generated,
-                        )
-                        continue
-                    # -1 pad: the device froze this row at an earlier
-                    # step, whose application above set er.finish. A pad
-                    # with NO host verdict means the device mask and the
-                    # host mirror diverged — finishing the row loudly
-                    # beats decoding a frozen zombie forever.
-                    logger.error(
-                        "device froze %s without a host finish verdict "
-                        "(device_finish_mask / _check_finish mirror "
-                        "divergence?); forcing STOP", er.request_id,
-                    )
-                    er.finish = FinishReason.STOP
-                    # emit=True: unlike the normal path, no preceding
-                    # _emit carried the finish_reason — the client must
-                    # still see one before the stream sentinel
-                    self._finish_pipelined(er, emit=True)
-                    continue
-                self._advance_row(er, token)
-                if infl.device_finish and er.guided is not None:
-                    # chained guided rows: advance the host cursor
-                    # (verdicts only — the device computed the mask; the
-                    # barrier reinstalls the host mask if needed)
-                    self._guided_after_token(er, edit=False)
-                er.pipeline_span_open = True
-                self._emit(
-                    er, token,
-                    (float(lpn[j, er.slot])
-                     if (lpn is not None and er.want_logprobs) else None),
-                    (self._top_row(er, tv[j], ti[j], er.slot)
-                     if tv is not None else None),
+        with span("sched.decode.sync", step=self.passes):
+            toks, lpn, tv, ti, nprop, nacc = await loop.run_in_executor(
+                None, _sync_burst)
+        with span("sched.decode.emit", step=self.passes,
+                  rows=len(infl.active)):
+            self._observe_host_sync(time.monotonic() - t_sync)
+            self._last_burst_done_t = time.monotonic()
+            if self.device_time is not None and infl.dispatch_t:
+                self.device_time.observe(
+                    "decode_burst_df" if infl.device_finish else "decode_burst",
+                    "decode", infl.dispatch_t,
+                    ready_hint if ready_hint is not None
+                    else self._last_burst_done_t,
+                    read_bytes=infl.read_bytes, tokens=infl.tokens,
                 )
-                if er.finish is not None:
-                    if infl.device_finish:
-                        # the device's mask froze this row at exactly
-                        # this step — the host check is the mirror that
-                        # names the reason and finalizes bookkeeping
-                        er.device_frozen = True
-                        self._device_finished_ctr.inc()
-                    self._finish_pipelined(er)
-        if infl.spec and nprop is not None:
-            for er in infl.active:
-                p = int(nprop[er.slot])
-                if p <= 0:
-                    continue  # frozen rows propose nothing this round
-                a = int(nacc[er.slot])
-                self.spec_proposed += p
-                self.spec_accepted += min(a, p)
-                self._spec_proposed_ctr.inc(p)
-                self._spec_accepted_ctr.inc(min(a, p))
-                self._spec_accept_hist.observe(float(a))
+            for j in range(infl.k_steps):
+                for er in infl.active:
+                    if er.finish is not None:
+                        continue  # finished/cancelled: over-decode discarded
+                    token = int(toks[j, er.slot])
+                    if infl.device_finish and token < 0:
+                        if er.chain_fp:
+                            continue  # already flagged: resumes at barrier
+                        if infl.spec and j > 0:
+                            # spec rounds pad past the acceptance length —
+                            # every LIVE row still emits its correction at
+                            # j=0, so only a j=0 pad means a frozen row
+                            continue
+                        if (er.fin_stop_hash is not None
+                                and er.finish is None):
+                            # the device's suffix-hash stop candidate froze
+                            # this row, but the host's EXACT token-suffix
+                            # check (_check_finish, ran on every emitted
+                            # token above) never fired: a hash collision.
+                            # Flag it — the chain closes at the next pass
+                            # and the row resumes byte-identically from its
+                            # committed state (no tokens were lost: frozen
+                            # rows never over-decode).
+                            er.chain_fp = True
+                            self._chain_fp = True
+                            self._note_sync_fallback("stop_false_positive")
+                            self.flight.record(
+                                "scheduler.stop_false_positive",
+                                request_id=er.request_id,
+                                trace_id=er.ctx.trace_id,
+                                generated=er.generated,
+                            )
+                            continue
+                        # -1 pad: the device froze this row at an earlier
+                        # step, whose application above set er.finish. A pad
+                        # with NO host verdict means the device mask and the
+                        # host mirror diverged — finishing the row loudly
+                        # beats decoding a frozen zombie forever.
+                        logger.error(
+                            "device froze %s without a host finish verdict "
+                            "(device_finish_mask / _check_finish mirror "
+                            "divergence?); forcing STOP", er.request_id,
+                        )
+                        er.finish = FinishReason.STOP
+                        # emit=True: unlike the normal path, no preceding
+                        # _emit carried the finish_reason — the client must
+                        # still see one before the stream sentinel
+                        self._finish_pipelined(er, emit=True)
+                        continue
+                    self._advance_row(er, token)
+                    if infl.device_finish and er.guided is not None:
+                        # chained guided rows: advance the host cursor
+                        # (verdicts only — the device computed the mask; the
+                        # barrier reinstalls the host mask if needed)
+                        self._guided_after_token(er, edit=False)
+                    er.pipeline_span_open = True
+                    self._emit(
+                        er, token,
+                        (float(lpn[j, er.slot])
+                         if (lpn is not None and er.want_logprobs) else None),
+                        (self._top_row(er, tv[j], ti[j], er.slot)
+                         if tv is not None else None),
+                    )
+                    if er.finish is not None:
+                        if infl.device_finish:
+                            # the device's mask froze this row at exactly
+                            # this step — the host check is the mirror that
+                            # names the reason and finalizes bookkeeping
+                            er.device_frozen = True
+                            self._device_finished_ctr.inc()
+                        self._finish_pipelined(er)
+            if infl.spec and nprop is not None:
+                for er in infl.active:
+                    p = int(nprop[er.slot])
+                    if p <= 0:
+                        continue  # frozen rows propose nothing this round
+                    a = int(nacc[er.slot])
+                    self.spec_proposed += p
+                    self.spec_accepted += min(a, p)
+                    self._spec_proposed_ctr.inc(p)
+                    self._spec_accepted_ctr.inc(min(a, p))
+                    self._spec_accept_hist.observe(float(a))
 
     def _finish_pipelined(self, er: EngineRequest, emit: bool = False) -> None:
         """A pipelined row finished (possibly one burst late): truncate
@@ -2027,31 +2103,35 @@ class Scheduler:
             return None
         return active, live, members
 
-    async def _chain_reserve(self, loop, active, live, advance,
-                             sync_steps) -> bool:
+    def _chain_reserve(self, live, advance) -> bool:
         """Block headroom for the chain's next dispatch: positions a
         never-frozen row runs through ``chain_pos0 + (n+1)*advance - 1``
         — reserve one past that (the carry slot), capped at the
         model-len horizon (the device freezes rows there; blocks past it
-        are never touched). False ⇒ KV OOM: preemption needs fully-
-        committed host state, so the chain closed at a barrier and the
-        pass already fell back to one sync decode."""
+        are never touched). False ⇒ KV OOM: the caller falls back
+        through ``_chain_oom_fallback``."""
         cfg = self.config
         n = self._chain_dispatched
+        ok = True
         for er in live:
             limit = min(self._chain_pos0[er.slot] + (n + 1) * advance,
                         cfg.max_model_len - 1)
             if not self._ensure_block_for(er, limit):
-                self.allocator.flush_offload()
-                self._note_sync_fallback("kv_oom")
-                await self._chain_barrier(loop)
-                rest = [er for er in active if er.finish is None]
-                if rest:
-                    await self._decode(loop, rest, sync_steps)
-                return False
+                ok = False
+                break
             self._host.sync_blocks(er)
         self.allocator.flush_offload()
-        return True
+        return ok
+
+    async def _chain_oom_fallback(self, loop, active, sync_steps) -> None:
+        """KV OOM under a chain: preemption needs fully-committed host
+        state, so the chain closes at a barrier and the pass falls back
+        to one sync decode (which owns preemption)."""
+        self._note_sync_fallback("kv_oom")
+        await self._chain_barrier(loop)
+        rest = [er for er in active if er.finish is None]
+        if rest:
+            await self._decode(loop, rest, sync_steps)
 
     def _chain_masks(self, members, live):
         """(commit mask, block-table slice) for one chained dispatch."""
@@ -2136,70 +2216,73 @@ class Scheduler:
             return
         active, live, members = opened
         n = self._chain_dispatched
-        if not await self._chain_reserve(loop, active, live, k_steps,
-                                         k_steps):
-            return
+        with span("sched.decode.build", step=self.passes, rows=len(live)):
+            reserved = self._chain_reserve(live, k_steps)
+            if reserved:
+                hs = self._host
+                commit, btab = self._chain_masks(members, live)
+                want_top = any(er.logprobs_n > 0 for er in members)
+                # guided members ride the device transition table: ONE table per
+                # chain (_chain_block_reason enforced it), their bias rows reset
+                # to logit_bias-only so the in-program mask is not double-applied
+                # (the barrier reinstalls the host mask)
+                gtable_dev = None
+                guided_live = [er for er in live if er.guided is not None]
+                if guided_live:
+                    table = self._guided_tables[
+                        self._guided_table_key(guided_live[0])]
+                    bucket = self.runner.guided_state_bucket(table.n_states)
+                    gtable_dev = table.device(bucket)
+                    for er in guided_live:
+                        if not er.chain_bias_reset:
+                            self._set_plain_bias(er)
+                            er.chain_bias_reset = True
+                if self._chain_carry is None:
+                    (tokens0, positions0, gen0, done0, ring0,
+                     gstate0) = self._chain_fill(live, with_guided=True)
+                else:
+                    (tokens0, positions0, gen0, done0, ring0,
+                     gstate0) = self._chain_carry
 
-        hs = self._host
-        commit, btab = self._chain_masks(members, live)
-        want_top = any(er.logprobs_n > 0 for er in members)
-        # guided members ride the device transition table: ONE table per
-        # chain (_chain_block_reason enforced it), their bias rows reset
-        # to logit_bias-only so the in-program mask is not double-applied
-        # (the barrier reinstalls the host mask)
-        gtable_dev = None
-        guided_live = [er for er in live if er.guided is not None]
-        if guided_live:
-            table = self._guided_tables[
-                self._guided_table_key(guided_live[0])]
-            bucket = self.runner.guided_state_bucket(table.n_states)
-            gtable_dev = table.device(bucket)
-            for er in guided_live:
-                if not er.chain_bias_reset:
-                    self._set_plain_bias(er)
-                    er.chain_bias_reset = True
-        if self._chain_carry is None:
-            (tokens0, positions0, gen0, done0, ring0,
-             gstate0) = self._chain_fill(live, with_guided=True)
-        else:
-            (tokens0, positions0, gen0, done0, ring0,
-             gstate0) = self._chain_carry
+                self._chain_observe_bubble(tokens0)
 
-        self._chain_observe_bubble(tokens0)
-
-        toks, lps, tv, ti, carry = self.runner.decode_burst_chained(
-            tokens0, positions0, gen0, done0, btab,
-            hs.temp, hs.top_k, hs.top_p,
-            min_p=hs.min_p, presence_penalty=hs.pres,
-            frequency_penalty=hs.freq, repetition_penalty=hs.rep,
-            seed_keys=hs.keys, commit=commit, stop_ids=hs.stop_ids,
-            min_new=hs.min_new, max_new=hs.max_new,
-            ring0=ring0, gstate0=gstate0,
-            stop_hash=hs.stop_hash, stop_hlen=hs.stop_hlen,
-            gtable=gtable_dev, want_top=want_top,
-        )
-        self._chain_carry = carry
-        self._chain_dispatched += 1
-        self.steps += 1
-        self.pipeline_bursts += 1
-        self.flight.record(
-            "scheduler.burst_dispatch", k_steps=k_steps, rows=len(live),
-            pipelined=True, chained=True,
-            chain_len=self._chain_dispatched,
-            requests=[er.request_id for er in live[:8]],
-        )
-        dt = self.device_time
-        self._chain.append(_InflightBurst(
-            active=list(live), toks=toks, lps=lps, tv=tv, ti=ti,
-            k_steps=k_steps, last_tokens=None,
-            dispatch_t=time.monotonic(), device_finish=True,
-            read_bytes=dt.decode_read_bytes(
-                k_steps,
-                sum(min(self._chain_pos0[er.slot] + n * k_steps,
-                        cfg.max_model_len) for er in live),
-            ) if dt is not None else 0.0,
-            tokens=k_steps * len(live),
-        ))
+        if not reserved:
+            return await self._chain_oom_fallback(loop, active, k_steps)
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(live)):
+            toks, lps, tv, ti, carry = self.runner.decode_burst_chained(
+                tokens0, positions0, gen0, done0, btab,
+                hs.temp, hs.top_k, hs.top_p,
+                min_p=hs.min_p, presence_penalty=hs.pres,
+                frequency_penalty=hs.freq, repetition_penalty=hs.rep,
+                seed_keys=hs.keys, commit=commit, stop_ids=hs.stop_ids,
+                min_new=hs.min_new, max_new=hs.max_new,
+                ring0=ring0, gstate0=gstate0,
+                stop_hash=hs.stop_hash, stop_hlen=hs.stop_hlen,
+                gtable=gtable_dev, want_top=want_top,
+            )
+            self._chain_carry = carry
+            self._chain_dispatched += 1
+            self.steps += 1
+            self.pipeline_bursts += 1
+            self.flight.record(
+                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(live),
+                pipelined=True, chained=True,
+                chain_len=self._chain_dispatched,
+                requests=[er.request_id for er in live[:8]],
+            )
+            dt = self.device_time
+            self._chain.append(_InflightBurst(
+                active=list(live), toks=toks, lps=lps, tv=tv, ti=ti,
+                k_steps=k_steps, last_tokens=None,
+                dispatch_t=time.monotonic(), device_finish=True,
+                read_bytes=dt.decode_read_bytes(
+                    k_steps,
+                    sum(min(self._chain_pos0[er.slot] + n * k_steps,
+                            cfg.max_model_len) for er in live),
+                ) if dt is not None else 0.0,
+                tokens=k_steps * len(live),
+            ))
         await self._chain_drain(loop, members)
 
     async def _decode_chained_spec(self, loop,
@@ -2229,69 +2312,73 @@ class Scheduler:
         # positions (accepted prefix + correction); near-horizon rounds
         # never dispatch (_spec_chain_reason barriers them first)
         n = self._chain_dispatched
-        if not await self._chain_reserve(loop, active, live, S, 1):
-            return
+        with span("sched.decode.build", step=self.passes, rows=len(live)):
+            reserved = self._chain_reserve(live, S)
+            if reserved:
+                hs = self._host
+                commit, btab = self._chain_masks(members, live)
+                if self._chain_carry is None:
+                    (tokens0, positions0, gen0, done0, ring0,
+                     gstate0) = self._chain_fill(live, with_guided=False)
+                else:
+                    (tokens0, positions0, gen0, done0, ring0,
+                     gstate0) = self._chain_carry
 
-        hs = self._host
-        commit, btab = self._chain_masks(members, live)
-        if self._chain_carry is None:
-            (tokens0, positions0, gen0, done0, ring0,
-             gstate0) = self._chain_fill(live, with_guided=False)
-        else:
-            (tokens0, positions0, gen0, done0, ring0,
-             gstate0) = self._chain_carry
+        if not reserved:
+            return await self._chain_oom_fallback(loop, active, 1)
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(live)):
+            props = None
+            if self.draft is not None:
+                # draft round chained off the SAME carry: its burst consumes
+                # the target's device-resident tokens/positions and its
+                # commit mask is gated by the device done carry — no host
+                # barrier anywhere in the draft → verify round trip
+                import jax.numpy as jnp
 
-        props = None
-        if self.draft is not None:
-            # draft round chained off the SAME carry: its burst consumes
-            # the target's device-resident tokens/positions and its
-            # commit mask is gated by the device done carry — no host
-            # barrier anywhere in the draft → verify round trip
-            import jax.numpy as jnp
+                commit_dev = jnp.logical_and(
+                    jnp.asarray(commit),
+                    jnp.logical_not(jnp.asarray(done0, jnp.bool_)),
+                )
+                dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(b)
+                dtoks, *_ = self.draft.decode_burst(
+                    tokens0, positions0, btab, dtemp, dtop_k, dtop_p,
+                    commit=commit_dev, want_top=False, **dkw,
+                )
+                props = jnp.transpose(dtoks[:P])  # [B, P] device proposals
+                self.steps += 1
 
-            commit_dev = jnp.logical_and(
-                jnp.asarray(commit),
-                jnp.logical_not(jnp.asarray(done0, jnp.bool_)),
+            self._chain_observe_bubble(tokens0)
+
+            toks, nprop, nacc, carry = self.runner.decode_burst_spec(
+                tokens0, positions0, gen0, done0, ring0, gstate0, btab,
+                commit=commit, stop_ids=hs.stop_ids, min_new=hs.min_new,
+                max_new=hs.max_new, stop_hash=hs.stop_hash,
+                stop_hlen=hs.stop_hlen, proposals=props,
             )
-            dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(b)
-            dtoks, *_ = self.draft.decode_burst(
-                tokens0, positions0, btab, dtemp, dtop_k, dtop_p,
-                commit=commit_dev, want_top=False, **dkw,
-            )
-            props = jnp.transpose(dtoks[:P])  # [B, P] device proposals
+            self._chain_carry = carry
+            self._chain_dispatched += 1
             self.steps += 1
-
-        self._chain_observe_bubble(tokens0)
-
-        toks, nprop, nacc, carry = self.runner.decode_burst_spec(
-            tokens0, positions0, gen0, done0, ring0, gstate0, btab,
-            commit=commit, stop_ids=hs.stop_ids, min_new=hs.min_new,
-            max_new=hs.max_new, stop_hash=hs.stop_hash,
-            stop_hlen=hs.stop_hlen, proposals=props,
-        )
-        self._chain_carry = carry
-        self._chain_dispatched += 1
-        self.steps += 1
-        self.pipeline_bursts += 1
-        self.flight.record(
-            "scheduler.burst_dispatch", k_steps=S, rows=len(live),
-            pipelined=True, chained=True, spec=True,
-            chain_len=self._chain_dispatched,
-            requests=[er.request_id for er in live[:8]],
-        )
-        dt = self.device_time
-        self._chain.append(_InflightBurst(
-            active=list(live), toks=toks, lps=None, tv=None, ti=None,
-            k_steps=S, last_tokens=None,
-            dispatch_t=time.monotonic(), device_finish=True,
-            spec=True, nprop=nprop, nacc=nacc,
-            read_bytes=dt.decode_read_bytes(
-                1,
-                sum(min(self._chain_pos0[er.slot] + n * S + S,
-                        cfg.max_model_len) for er in live),
-            ) if dt is not None else 0.0,
-            tokens=len(live),
-        ))
+            self.pipeline_bursts += 1
+            self.flight.record(
+                "scheduler.burst_dispatch", k_steps=S, rows=len(live),
+                pipelined=True, chained=True, spec=True,
+                chain_len=self._chain_dispatched,
+                requests=[er.request_id for er in live[:8]],
+            )
+            dt = self.device_time
+            self._chain.append(_InflightBurst(
+                active=list(live), toks=toks, lps=None, tv=None, ti=None,
+                k_steps=S, last_tokens=None,
+                dispatch_t=time.monotonic(), device_finish=True,
+                spec=True, nprop=nprop, nacc=nacc,
+                read_bytes=dt.decode_read_bytes(
+                    1,
+                    sum(min(self._chain_pos0[er.slot] + n * S + S,
+                            cfg.max_model_len) for er in live),
+                ) if dt is not None else 0.0,
+                tokens=len(live),
+            ))
         await self._chain_drain(loop, members)
 
     def _set_plain_bias(self, er: EngineRequest) -> None:
@@ -2645,9 +2732,8 @@ class Scheduler:
             er.block_ids = []
             er.num_cached = 0
             return False
-        self.prefix_hit_tokens += er.num_cached
-        self.prefix_total_tokens += len(er.prompt)
-        er.ctx.add_stage("admission")
+        self._count_prefix_lookup(er, len(er.prompt))
+        self._mark_admission(er)
         self.flight.record(
             "scheduler.remote_submit", request_id=er.request_id,
             trace_id=er.ctx.trace_id, prompt_tokens=len(er.prompt),
@@ -2741,7 +2827,7 @@ class Scheduler:
         off instead of restarting (vLLM recompute-preemption semantics)."""
         slot = self._free_slot()
         assert slot is not None
-        er.ctx.add_stage("admission")
+        self._mark_admission(er)
         self.flight.record(
             "scheduler.admission", request_id=er.request_id,
             trace_id=er.ctx.trace_id, slot=slot,
@@ -2770,8 +2856,7 @@ class Scheduler:
         else:
             er.block_ids, er.num_cached = self.allocator.allocate_prompt(tokens_all)
         if not er.remote_attempted:  # remote fallback already counted itself
-            self.prefix_hit_tokens += er.num_cached
-            self.prefix_total_tokens += len(tokens_all)
+            self._count_prefix_lookup(er, len(tokens_all))
         er.prefill_tokens = tokens_all
         er.prefill_pos = er.num_cached
         er.context_len = er.num_cached
@@ -2870,52 +2955,56 @@ class Scheduler:
         loop serves decode), register the previously completed chunk's
         blocks into the prefix cache, and on the final chunk run the
         early decode handoff + drain."""
-        st = self.sp_active
-        while st is None and self.sp_queue:
-            er = self.sp_queue.pop(0)
+        with span("sched.prefill.build", step=self.passes, rows=1):
+            st = self.sp_active
+            while st is None and self.sp_queue:
+                er = self.sp_queue.pop(0)
+                if er.finish is not None or er.ctx.is_stopped:
+                    continue
+                st = self.sp_active = _SpPrefill(er=er, t0=time.monotonic())
+            if st is None:
+                return False
+            er = st.er
             if er.finish is not None or er.ctx.is_stopped:
-                continue
-            st = self.sp_active = _SpPrefill(er=er, t0=time.monotonic())
-        if st is None:
-            return False
-        er = st.er
-        if er.finish is not None or er.ctx.is_stopped:
-            self.sp_active = None
-            if er.finish is None:
-                self._finish(er, FinishReason.CANCELLED)
-            return True
-        total = len(er.prefill_tokens)
-        start = er.prefill_pos
-        end = min(start + self.runner.sp_chunk_tokens, total)
-        final = end >= total
-        t_disp = time.monotonic()
-        outs = self.runner.sp_prefill_chunk(
-            er.prefill_tokens[:end], start, er.block_ids,
-            temperature=er.temperature, top_k=er.top_k, top_p=er.top_p,
-            min_p=er.min_p, presence_penalty=er.presence_penalty,
-            frequency_penalty=er.frequency_penalty,
-            repetition_penalty=er.repetition_penalty,
-            seed_keys=er.base_key, counters=er.generated,
-            sample_slot=er.slot, commit=final,
-            want_top=final and er.logprobs_n > 0,
-        )
-        self.steps += 1
-        st.chunks += 1
-        self._sp_chunks_c.inc()
-        self._sp_tokens_c.inc(end - start)
-        er.prefill_pos = end
-        er.context_len = end
-        # chunk-commit seam: the chunk's blocks become matchable (and KV
-        # events publish, feeding fabric ownership) as soon as the write
-        # is SCHEDULED — device dispatch order guarantees it lands
-        # before any later program reads it, the same contract the dense
-        # ladder and the disagg streamed transfer rely on
-        self._register_completed_blocks(er)
-        self.flight.record(
-            "scheduler.sp_chunk", request_id=er.request_id,
-            trace_id=er.ctx.trace_id, start=start, end=end, final=final,
-            chunk=st.chunks,
-        )
+                self.sp_active = None
+                if er.finish is None:
+                    self._finish(er, FinishReason.CANCELLED)
+                return True
+            total = len(er.prefill_tokens)
+            start = er.prefill_pos
+            end = min(start + self.runner.sp_chunk_tokens, total)
+            final = end >= total
+        with span("sched.prefill.dispatch", step=self.passes, rows=1,
+                  tokens=end - start):
+            t_disp = time.monotonic()
+            outs = self.runner.sp_prefill_chunk(
+                er.prefill_tokens[:end], start, er.block_ids,
+                temperature=er.temperature, top_k=er.top_k, top_p=er.top_p,
+                min_p=er.min_p, presence_penalty=er.presence_penalty,
+                frequency_penalty=er.frequency_penalty,
+                repetition_penalty=er.repetition_penalty,
+                seed_keys=er.base_key, counters=er.generated,
+                sample_slot=er.slot, commit=final,
+                want_top=final and er.logprobs_n > 0,
+            )
+        with span("sched.prefill.emit", step=self.passes, rows=1):
+            self.steps += 1
+            st.chunks += 1
+            self._sp_chunks_c.inc()
+            self._sp_tokens_c.inc(end - start)
+            er.prefill_pos = end
+            er.context_len = end
+            # chunk-commit seam: the chunk's blocks become matchable (and KV
+            # events publish, feeding fabric ownership) as soon as the write
+            # is SCHEDULED — device dispatch order guarantees it lands
+            # before any later program reads it, the same contract the dense
+            # ladder and the disagg streamed transfer rely on
+            self._register_completed_blocks(er)
+            self.flight.record(
+                "scheduler.sp_chunk", request_id=er.request_id,
+                trace_id=er.ctx.trace_id, start=start, end=end, final=final,
+                chunk=st.chunks,
+            )
         if not final:
             return True
         st.final_dispatch_t = t_disp
@@ -2944,114 +3033,120 @@ class Scheduler:
         bs = cfg.kv_block_size
         ctx0 = er.context_len  # the first sampled token's position
         k_steps = cfg.multi_step_decode
-        burst = None
-        can_burst = (
-            self.runner._burst is not None
-            and er.guided is None
-            and er.max_new > 1
-            and ctx0 + k_steps + 1 <= cfg.max_model_len
-            and all(self._ensure_block_for(er, ctx0 + j)
-                    for j in range(k_steps))
-        )
-        # allocator contract (same as every dense dispatch site): any
-        # host-offload gathers the block growth above deferred must
-        # materialize BEFORE the burst overwrites the evicted slots
-        self.allocator.flush_offload()
+        with span("sched.decode.build", step=self.passes, rows=1):
+            burst = None
+            can_burst = (
+                self.runner._burst is not None
+                and er.guided is None
+                and er.max_new > 1
+                and ctx0 + k_steps + 1 <= cfg.max_model_len
+                and all(self._ensure_block_for(er, ctx0 + j)
+                        for j in range(k_steps))
+            )
+            # allocator contract (same as every dense dispatch site): any
+            # host-offload gathers the block growth above deferred must
+            # materialize BEFORE the burst overwrites the evicted slots
+            self.allocator.flush_offload()
+            if can_burst:
+                hs.sync_blocks(er)
+                w = cfg.kv_width_bucket(len(er.block_ids))
+                btab = hs.btab[:, :w].copy()
+                import jax.numpy as jnp
+                tok0 = jnp.zeros(b, jnp.int32).at[er.slot].set(next_tokens[0])
+                pos0 = np.zeros(b, np.int32)
+                pos0[er.slot] = ctx0
+                ctrs = np.zeros(b, np.int32)
+                ctrs[er.slot] = er.generated + 1  # after the prefill token
+                commit = np.zeros(b, bool)
+                commit[er.slot] = True
         if can_burst:
-            hs.sync_blocks(er)
-            w = cfg.kv_width_bucket(len(er.block_ids))
-            btab = hs.btab[:, :w].copy()
-            import jax.numpy as jnp
-            tok0 = jnp.zeros(b, jnp.int32).at[er.slot].set(next_tokens[0])
-            pos0 = np.zeros(b, np.int32)
-            pos0[er.slot] = ctx0
-            ctrs = np.zeros(b, np.int32)
-            ctrs[er.slot] = er.generated + 1  # after the prefill token
-            commit = np.zeros(b, bool)
-            commit[er.slot] = True
-            t_burst = time.monotonic()
-            burst = self.runner.decode_burst(
-                tok0, pos0, btab, hs.temp, hs.top_k, hs.top_p,
-                min_p=hs.min_p, presence_penalty=hs.pres,
-                frequency_penalty=hs.freq, repetition_penalty=hs.rep,
-                seed_keys=hs.keys, counters=ctrs, commit=commit,
-                want_top=er.logprobs_n > 0,
-            )
-            self.steps += 1
-            self._sp_exposed_h.observe(t_burst - st.final_dispatch_t)
-            self.flight.record(
-                "scheduler.sp_handoff", request_id=er.request_id,
-                trace_id=er.ctx.trace_id, k_steps=k_steps,
-            )
+            with span("sched.decode.dispatch", step=self.passes, rows=1):
+                t_burst = time.monotonic()
+                burst = self.runner.decode_burst(
+                    tok0, pos0, btab, hs.temp, hs.top_k, hs.top_p,
+                    min_p=hs.min_p, presence_penalty=hs.pres,
+                    frequency_penalty=hs.freq, repetition_penalty=hs.rep,
+                    seed_keys=hs.keys, counters=ctrs, commit=commit,
+                    want_top=er.logprobs_n > 0,
+                )
+                self.steps += 1
+                self._sp_exposed_h.observe(t_burst - st.final_dispatch_t)
+                self.flight.record(
+                    "scheduler.sp_handoff", request_id=er.request_id,
+                    trace_id=er.ctx.trace_id, k_steps=k_steps,
+                )
 
         def _sync():
-            out = [np.asarray(next_tokens), np.asarray(lps),
-                   np.asarray(top_vals), np.asarray(top_ids)]
-            if burst is not None:
-                out.extend(np.asarray(x) for x in burst)
-            return out
+            with span("sync.fetch"):
+                out = [np.asarray(next_tokens), np.asarray(lps),
+                       np.asarray(top_vals), np.asarray(top_ids)]
+                if burst is not None:
+                    out.extend(np.asarray(x) for x in burst)
+                return out
 
         t_sync = time.monotonic()
-        synced = await loop.run_in_executor(None, _sync)
-        t_done = time.monotonic()
-        self._observe_host_sync(t_done - t_sync)
-        if burst is None:
-            self._sp_exposed_h.observe(t_done - st.final_dispatch_t)
-        if self.device_time is not None:
-            self.device_time.observe(
-                "prefill_sp", "prefill", st.final_dispatch_t, t_done,
-                read_bytes=self.device_time.sp_prefill_read_bytes(
-                    st.chunks, er.context_len,
-                    kernel=self._sp_kernel_route(),
-                ),
-            )
-            if burst is not None:
+        with span("sched.prefill.sync", step=self.passes):
+            synced = await loop.run_in_executor(None, _sync)
+        with span("sched.prefill.emit", step=self.passes, rows=1):
+            t_done = time.monotonic()
+            self._observe_host_sync(t_done - t_sync)
+            if burst is None:
+                self._sp_exposed_h.observe(t_done - st.final_dispatch_t)
+            if self.device_time is not None:
                 self.device_time.observe(
-                    "decode_burst", "decode", t_burst, t_done,
-                    read_bytes=self.device_time.decode_read_bytes(
-                        k_steps, er.context_len,
+                    "prefill_sp", "prefill", st.final_dispatch_t, t_done,
+                    read_bytes=self.device_time.sp_prefill_read_bytes(
+                        st.chunks, er.context_len,
+                        kernel=self._sp_kernel_route(),
                     ),
-                    tokens=k_steps,
                 )
-        self.flight.record(
-            "scheduler.sp_drain", request_id=er.request_id,
-            trace_id=er.ctx.trace_id, chunks=st.chunks,
-            handoff=burst is not None,
-        )
-        toks_pf, lps_pf, tv_pf, ti_pf = synced[:4]
-        er.ctx.add_stage("prefill")
-        token = int(toks_pf[0])
-        er.pending_token = token
-        er.generated += 1
-        er.ring_tail.append(token)
-        er.finish = self._check_finish(er, token)
-        self._guided_after_token(er)
-        self._emit(
-            er, token,
-            float(lps_pf[0]) if er.want_logprobs else None,
-            self._top_row(er, tv_pf, ti_pf, 0),
-        )
-        if er.finish is not None:
-            # trailing burst tokens (if any) are pure over-decode into
-            # the request's own blocks — freed with the request
-            self._finish(er, er.finish, emit=False)
-            return
-        if burst is None:
-            return
-        toks_b, lps_b, tv_b, ti_b = synced[4:]
-        for j in range(k_steps):
-            if er.finish is not None or er.ctx.is_stopped:
-                break
-            tok_j = int(toks_b[j, er.slot])
-            self._advance_row(er, tok_j)
+                if burst is not None:
+                    self.device_time.observe(
+                        "decode_burst", "decode", t_burst, t_done,
+                        read_bytes=self.device_time.decode_read_bytes(
+                            k_steps, er.context_len,
+                        ),
+                        tokens=k_steps,
+                    )
+            self.flight.record(
+                "scheduler.sp_drain", request_id=er.request_id,
+                trace_id=er.ctx.trace_id, chunks=st.chunks,
+                handoff=burst is not None,
+            )
+            toks_pf, lps_pf, tv_pf, ti_pf = synced[:4]
+            er.ctx.add_stage("prefill")
+            token = int(toks_pf[0])
+            er.pending_token = token
+            er.generated += 1
+            er.ring_tail.append(token)
+            er.finish = self._check_finish(er, token)
             self._guided_after_token(er)
             self._emit(
-                er, tok_j,
-                float(lps_b[j, er.slot]) if er.want_logprobs else None,
-                self._top_row(er, tv_b[j], ti_b[j], er.slot),
+                er, token,
+                float(lps_pf[0]) if er.want_logprobs else None,
+                self._top_row(er, tv_pf, ti_pf, 0),
             )
             if er.finish is not None:
+                # trailing burst tokens (if any) are pure over-decode into
+                # the request's own blocks — freed with the request
                 self._finish(er, er.finish, emit=False)
+                return
+            if burst is None:
+                return
+            toks_b, lps_b, tv_b, ti_b = synced[4:]
+            for j in range(k_steps):
+                if er.finish is not None or er.ctx.is_stopped:
+                    break
+                tok_j = int(toks_b[j, er.slot])
+                self._advance_row(er, tok_j)
+                self._guided_after_token(er)
+                self._emit(
+                    er, tok_j,
+                    float(lps_b[j, er.slot]) if er.want_logprobs else None,
+                    self._top_row(er, tv_b[j], ti_b[j], er.slot),
+                )
+                if er.finish is not None:
+                    self._finish(er, er.finish, emit=False)
 
     async def _prefill_chunk(self, loop, ers: List[EngineRequest]) -> None:
         """ONE batched prefill step: every prefilling request advances a
@@ -3059,177 +3154,186 @@ class Scheduler:
         two ladder, lengths to the common bucket); rows that finish their
         prompt sample/emit. The token budget splits across rows."""
         cfg = self.config
-        rows = cfg.prefill_row_bucket(len(ers))
-        # the ITL bound is on COMPUTED positions = padded rows x padded
-        # bucket, so cap the bucket at the largest that keeps
-        # rows * bucket within budget (padding included), not just the
-        # per-row take (prefill_bucket_cap — shared with the disagg
-        # prefill worker's streamed chunking)
-        cap = prefill_bucket_cap(cfg, rows)
-        # a full batch can exceed the budget even at the smallest
-        # bucket — admit fewer rows this step instead of overrunning
-        # (the tail of `ers` stays in self.prefilling for next pass)
-        while cap is None and rows > cfg.PREFILL_ROW_BUCKETS[0]:
-            rows = max(r for r in cfg.PREFILL_ROW_BUCKETS if r < rows)
-            ers = ers[:rows]
+        with span("sched.prefill.build", step=self.passes, rows=len(ers)):
+            rows = cfg.prefill_row_bucket(len(ers))
+            # the ITL bound is on COMPUTED positions = padded rows x padded
+            # bucket, so cap the bucket at the largest that keeps
+            # rows * bucket within budget (padding included), not just the
+            # per-row take (prefill_bucket_cap — shared with the disagg
+            # prefill worker's streamed chunking)
             cap = prefill_bucket_cap(cfg, rows)
-        # budget < one row at the smallest bucket: best-effort floor
-        # (a single row must still advance or prefill livelocks)
-        bucket_cap = cap if cap is not None else cfg.prefill_buckets[0]
-        plan = []  # (er, start, end, take, final)
-        for er in ers:
-            total = len(er.prefill_tokens)
-            take = min(total - er.prefill_pos, bucket_cap)
-            end = er.prefill_pos + take
-            plan.append((er, er.prefill_pos, end, take, end >= total))
-        bucket = cfg.bucket_for(max(p[3] for p in plan))  # <= bucket_cap
+            # a full batch can exceed the budget even at the smallest
+            # bucket — admit fewer rows this step instead of overrunning
+            # (the tail of `ers` stays in self.prefilling for next pass)
+            while cap is None and rows > cfg.PREFILL_ROW_BUCKETS[0]:
+                rows = max(r for r in cfg.PREFILL_ROW_BUCKETS if r < rows)
+                ers = ers[:rows]
+                cap = prefill_bucket_cap(cfg, rows)
+            # budget < one row at the smallest bucket: best-effort floor
+            # (a single row must still advance or prefill livelocks)
+            bucket_cap = cap if cap is not None else cfg.prefill_buckets[0]
+            plan = []  # (er, start, end, take, final)
+            for er in ers:
+                total = len(er.prefill_tokens)
+                take = min(total - er.prefill_pos, bucket_cap)
+                end = er.prefill_pos + take
+                plan.append((er, er.prefill_pos, end, take, end >= total))
+            bucket = cfg.bucket_for(max(p[3] for p in plan))  # <= bucket_cap
 
-        tokens = np.zeros((rows, bucket), np.int32)
-        positions = np.zeros((rows, bucket), np.int32)
-        btab = np.zeros((rows, cfg.blocks_per_seq), np.int32)
-        slot_map = np.full((rows, bucket), -1, np.int32)
-        ctx_lens = np.ones(rows, np.int32)
-        last_idx = np.zeros(rows, np.int32)
-        temp = np.zeros(rows, np.float32)
-        top_k = np.zeros(rows, np.int32)
-        top_p = np.ones(rows, np.float32)
-        min_p = np.zeros(rows, np.float32)
-        pres = np.zeros(rows, np.float32)
-        freq = np.zeros(rows, np.float32)
-        rep = np.ones(rows, np.float32)
-        keys = np.zeros((rows, 2), np.uint32)
-        ctrs = np.zeros(rows, np.int32)
-        sample_slots = np.zeros(rows, np.int32)
-        commit = np.zeros(rows, bool)
-        targets = np.zeros((rows, bucket), np.int32)
-        n_tgts = [0] * len(plan)
-        want_prompt = False
+            tokens = np.zeros((rows, bucket), np.int32)
+            positions = np.zeros((rows, bucket), np.int32)
+            btab = np.zeros((rows, cfg.blocks_per_seq), np.int32)
+            slot_map = np.full((rows, bucket), -1, np.int32)
+            ctx_lens = np.ones(rows, np.int32)
+            last_idx = np.zeros(rows, np.int32)
+            temp = np.zeros(rows, np.float32)
+            top_k = np.zeros(rows, np.int32)
+            top_p = np.ones(rows, np.float32)
+            min_p = np.zeros(rows, np.float32)
+            pres = np.zeros(rows, np.float32)
+            freq = np.zeros(rows, np.float32)
+            rep = np.ones(rows, np.float32)
+            keys = np.zeros((rows, 2), np.uint32)
+            ctrs = np.zeros(rows, np.int32)
+            sample_slots = np.zeros(rows, np.int32)
+            commit = np.zeros(rows, bool)
+            targets = np.zeros((rows, bucket), np.int32)
+            n_tgts = [0] * len(plan)
+            want_prompt = False
 
-        for i, (er, start, end, take, final) in enumerate(plan):
-            t, p, bt, sm, cl, li = build_prefill_arrays(
-                cfg, er.prefill_tokens[:end], start, er.block_ids,
-                bucket=bucket,
-            )
-            tokens[i], positions[i] = t[0], p[0]
-            btab[i], slot_map[i] = bt[0], sm[0]
-            ctx_lens[i], last_idx[i] = cl[0], li[0]
-            (temp[i], top_k[i], top_p[i], min_p[i], pres[i], freq[i],
-             rep[i]) = (er.temperature, er.top_k, er.top_p, er.min_p,
-                        er.presence_penalty, er.frequency_penalty,
-                        er.repetition_penalty)
-            keys[i] = er.base_key
-            ctrs[i] = er.generated
-            sample_slots[i] = er.slot
-            commit[i] = final
-            if er.want_prompt_lps and not er.prompt_lps_emitted:
-                # target at bucket index j (absolute position start+j) is
-                # the NEXT prompt token; only prompt positions count (a
-                # resumed request's generation tokens are not prompt)
-                want_prompt = True
-                nxt = er.prefill_tokens[start + 1 : end + 1]
-                targets[i, : len(nxt)] = nxt
-                n_tgts[i] = max(0, min(take, len(er.prompt) - 1 - start))
+            for i, (er, start, end, take, final) in enumerate(plan):
+                t, p, bt, sm, cl, li = build_prefill_arrays(
+                    cfg, er.prefill_tokens[:end], start, er.block_ids,
+                    bucket=bucket,
+                )
+                tokens[i], positions[i] = t[0], p[0]
+                btab[i], slot_map[i] = bt[0], sm[0]
+                ctx_lens[i], last_idx[i] = cl[0], li[0]
+                (temp[i], top_k[i], top_p[i], min_p[i], pres[i], freq[i],
+                 rep[i]) = (er.temperature, er.top_k, er.top_p, er.min_p,
+                            er.presence_penalty, er.frequency_penalty,
+                            er.repetition_penalty)
+                keys[i] = er.base_key
+                ctrs[i] = er.generated
+                sample_slots[i] = er.slot
+                commit[i] = final
+                if er.want_prompt_lps and not er.prompt_lps_emitted:
+                    # target at bucket index j (absolute position start+j) is
+                    # the NEXT prompt token; only prompt positions count (a
+                    # resumed request's generation tokens are not prompt)
+                    want_prompt = True
+                    nxt = er.prefill_tokens[start + 1 : end + 1]
+                    targets[i, : len(nxt)] = nxt
+                    n_tgts[i] = max(0, min(take, len(er.prompt) - 1 - start))
 
-        t0 = time.monotonic()
-        next_tokens, lps, top_vals, top_ids, plps, _ = self.runner.step(
-            tokens, positions, btab, slot_map, ctx_lens, last_idx,
-            temp, top_k, top_p,
-            min_p=min_p, presence_penalty=pres, frequency_penalty=freq,
-            repetition_penalty=rep, seed_keys=keys, counters=ctrs,
-            sample_slots=sample_slots, commit=commit,
-            want_top=any(er.logprobs_n > 0 for er, *_ in plan),
-            targets=targets, want_prompt=want_prompt,
-        )
-        self.steps += 1
-        if self.draft is not None:
-            # mirror the chunk on the draft model: same tokens, same
-            # slots, same (shared) block ids — so the draft cache holds
-            # the full context every speculative round assumes. Sampling
-            # is inert (commit all-False; nothing reads the outputs).
-            dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(rows)
-            self.draft.step(
+        with span("sched.prefill.dispatch", step=self.passes,
+                  rows=rows, tokens=rows * bucket):
+            t0 = time.monotonic()
+            next_tokens, lps, top_vals, top_ids, plps, _ = self.runner.step(
                 tokens, positions, btab, slot_map, ctx_lens, last_idx,
-                dtemp, dtop_k, dtop_p,
-                sample_slots=sample_slots,
-                commit=np.zeros(rows, bool), want_top=False, **dkw,
+                temp, top_k, top_p,
+                min_p=min_p, presence_penalty=pres, frequency_penalty=freq,
+                repetition_penalty=rep, seed_keys=keys, counters=ctrs,
+                sample_slots=sample_slots, commit=commit,
+                want_top=any(er.logprobs_n > 0 for er, *_ in plan),
+                targets=targets, want_prompt=want_prompt,
             )
+            self.steps += 1
+            if self.draft is not None:
+                # mirror the chunk on the draft model: same tokens, same
+                # slots, same (shared) block ids — so the draft cache holds
+                # the full context every speculative round assumes. Sampling
+                # is inert (commit all-False; nothing reads the outputs).
+                dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(rows)
+                self.draft.step(
+                    tokens, positions, btab, slot_map, ctx_lens, last_idx,
+                    dtemp, dtop_k, dtop_p,
+                    sample_slots=sample_slots,
+                    commit=np.zeros(rows, bool), want_top=False, **dkw,
+                )
 
-        finals = []
-        for i, (er, start, end, take, final) in enumerate(plan):
-            if n_tgts[i] > 0:
-                # keep the DEVICE row; one host conversion at the end
-                er.prompt_lp_parts.append((plps[i : i + 1], n_tgts[i]))
-            er.prefill_pos = end
-            er.context_len = end
-            # prefix blocks become matchable (and KV events publish) as
-            # soon as each chunk's KV is scheduled — device ordering
-            # guarantees the write lands before any later step reads it
-            self._register_completed_blocks(er)
-            logger.debug("prefill chunk %s [%d:%d)/%d %.1fms",
-                         er.request_id, start, end,
-                         len(er.prefill_tokens),
-                         1e3 * (time.monotonic() - t0))
-            if final:
-                finals.append(i)
+        # what follows a dispatch on the host: the chunk's blocks become
+        # matchable; the first-token emit comes after the sync below
+        with span("sched.prefill.emit", step=self.passes, rows=len(plan)):
+            finals = []
+            for i, (er, start, end, take, final) in enumerate(plan):
+                if n_tgts[i] > 0:
+                    # keep the DEVICE row; one host conversion at the end
+                    er.prompt_lp_parts.append((plps[i : i + 1], n_tgts[i]))
+                er.prefill_pos = end
+                er.context_len = end
+                # prefix blocks become matchable (and KV events publish) as
+                # soon as each chunk's KV is scheduled — device ordering
+                # guarantees the write lands before any later step reads it
+                self._register_completed_blocks(er)
+                logger.debug("prefill chunk %s [%d:%d)/%d %.1fms",
+                             er.request_id, start, end,
+                             len(er.prefill_tokens),
+                             1e3 * (time.monotonic() - t0))
+                if final:
+                    finals.append(i)
         if not finals:
             return
 
         def _to_host():
-            # every device→host transfer off the event loop: final-row
-            # outputs plus any accumulated prompt-logprob rows (an
-            # echo+logprobs prompt may hold many chunk rows)
-            plists = {
-                i: [
-                    float(x)
-                    for row, cnt in plan[i][0].prompt_lp_parts
-                    for x in np.asarray(row)[0, :cnt]
-                ]
-                for i in finals
-                if plan[i][0].prompt_lp_parts
-            }
-            return (np.asarray(next_tokens), np.asarray(lps),
-                    np.asarray(top_vals), np.asarray(top_ids), plists)
+            with span("sync.fetch"):
+                # every device→host transfer off the event loop: final-row
+                # outputs plus any accumulated prompt-logprob rows (an
+                # echo+logprobs prompt may hold many chunk rows)
+                plists = {
+                    i: [
+                        float(x)
+                        for row, cnt in plan[i][0].prompt_lp_parts
+                        for x in np.asarray(row)[0, :cnt]
+                    ]
+                    for i in finals
+                    if plan[i][0].prompt_lp_parts
+                }
+                return (np.asarray(next_tokens), np.asarray(lps),
+                        np.asarray(top_vals), np.asarray(top_ids), plists)
 
         t_sync = time.monotonic()
-        toks, lpn, tv, ti, plists = await loop.run_in_executor(None, _to_host)
-        self._observe_host_sync(time.monotonic() - t_sync)
-        if self.device_time is not None:
-            # non-final chunks never sync; their device time folds into
-            # this observation via the serialized-interval estimator
-            self.device_time.observe(
-                "prefill", "prefill", t0, time.monotonic(),
-            )
-        for i in finals:
-            er = plan[i][0]
-            self.prefilling.remove(er)
-            er.ctx.add_stage("prefill")
-            prompt_lps = None
-            if er.want_prompt_lps and not er.prompt_lps_emitted:
-                # OpenAI/vLLM convention: the first prompt token has no
-                # conditioning prefix — its entry is None
-                prompt_lps = [None] + plists.get(i, [])
-                er.prompt_lps_emitted = True
-            er.prompt_lp_parts = []
-            if er.max_new == 0:
-                # prompt-scoring request (echo + logprobs + max_tokens=0):
-                # the prefill ran for its logits; no token is emitted
-                er.finish = FinishReason.LENGTH
-                er.out_queue.put_nowait(EngineOutput(
-                    token_ids=[], finish_reason=er.finish,
-                    prompt_logprobs=prompt_lps,
-                ))
-                self._finish(er, er.finish, emit=False)
-                continue
-            token = int(toks[i])
-            er.pending_token = token
-            er.generated += 1  # += not =: resumed requests keep their count
-            er.ring_tail.append(token)
-            er.finish = self._check_finish(er, token)
-            self._guided_after_token(er)
-            self._emit(er, token, float(lpn[i]) if er.want_logprobs else None,
-                       self._top_row(er, tv, ti, i), prompt_lps=prompt_lps)
-            if er.finish is not None:
-                self._finish(er, er.finish, emit=False)
+        with span("sched.prefill.sync", step=self.passes):
+            toks, lpn, tv, ti, plists = await loop.run_in_executor(None, _to_host)
+        with span("sched.prefill.emit", step=self.passes, rows=len(finals)):
+            self._observe_host_sync(time.monotonic() - t_sync)
+            if self.device_time is not None:
+                # non-final chunks never sync; their device time folds into
+                # this observation via the serialized-interval estimator
+                self.device_time.observe(
+                    "prefill", "prefill", t0, time.monotonic(),
+                )
+            for i in finals:
+                er = plan[i][0]
+                self.prefilling.remove(er)
+                er.ctx.add_stage("prefill")
+                prompt_lps = None
+                if er.want_prompt_lps and not er.prompt_lps_emitted:
+                    # OpenAI/vLLM convention: the first prompt token has no
+                    # conditioning prefix — its entry is None
+                    prompt_lps = [None] + plists.get(i, [])
+                    er.prompt_lps_emitted = True
+                er.prompt_lp_parts = []
+                if er.max_new == 0:
+                    # prompt-scoring request (echo + logprobs + max_tokens=0):
+                    # the prefill ran for its logits; no token is emitted
+                    er.finish = FinishReason.LENGTH
+                    er.out_queue.put_nowait(EngineOutput(
+                        token_ids=[], finish_reason=er.finish,
+                        prompt_logprobs=prompt_lps,
+                    ))
+                    self._finish(er, er.finish, emit=False)
+                    continue
+                token = int(toks[i])
+                er.pending_token = token
+                er.generated += 1  # += not =: resumed requests keep their count
+                er.ring_tail.append(token)
+                er.finish = self._check_finish(er, token)
+                self._guided_after_token(er)
+                self._emit(er, token, float(lpn[i]) if er.want_logprobs else None,
+                           self._top_row(er, tv, ti, i), prompt_lps=prompt_lps)
+                if er.finish is not None:
+                    self._finish(er, er.finish, emit=False)
 
     def _spec_eligible(self, er: EngineRequest) -> bool:
         """Speculative verify preserves the exact stream only for greedy,
@@ -3347,23 +3451,32 @@ class Scheduler:
         """
         cfg = self.config
         b = cfg.max_batch_size
-        w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
-        tokens0 = np.zeros(b, np.int32)
-        positions0 = np.zeros(b, np.int32)
-        btab = np.zeros((b, w), np.int32)
-        commit = np.zeros(b, bool)
-        for er in active:
-            i = er.slot
-            tokens0[i] = er.pending_token
-            positions0[i] = er.context_len
-            btab[i, : len(er.block_ids)] = er.block_ids
-            commit[i] = True
-        temp, top_k, top_p, kw = self._inert_sampling(b)
-        toksK, *_ = self.draft.decode_burst(
-            tokens0, positions0, btab, temp, top_k, top_p,
-            commit=commit, want_top=False, **kw,
-        )
-        tk = await loop.run_in_executor(None, lambda: np.asarray(toksK))
+        with span("sched.decode.build", step=self.passes, rows=len(active)):
+            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            tokens0 = np.zeros(b, np.int32)
+            positions0 = np.zeros(b, np.int32)
+            btab = np.zeros((b, w), np.int32)
+            commit = np.zeros(b, bool)
+            for er in active:
+                i = er.slot
+                tokens0[i] = er.pending_token
+                positions0[i] = er.context_len
+                btab[i, : len(er.block_ids)] = er.block_ids
+                commit[i] = True
+            temp, top_k, top_p, kw = self._inert_sampling(b)
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(active)):
+            toksK, *_ = self.draft.decode_burst(
+                tokens0, positions0, btab, temp, top_k, top_p,
+                commit=commit, want_top=False, **kw,
+            )
+
+        def _sync_draft():
+            with span("sync.fetch"):
+                return np.asarray(toksK)
+
+        with span("sched.decode.sync", step=self.passes):
+            tk = await loop.run_in_executor(None, _sync_draft)
         self.steps += 1
         return {
             er.slot: [int(t) for t in tk[:K, er.slot]] for er in active
@@ -3397,29 +3510,32 @@ class Scheduler:
             return await self._decode(loop, active, 1)
 
         props: dict = {}
-        if self.draft is None:
-            # ngram proposals first: when nothing matches anywhere
-            # (non-repetitive output), the K+1-wide verify would be pure
-            # per-step overhead — run the normal decode (incl. its fused
-            # burst) instead
-            for er in active:
-                history = list(er.seq.token_ids) + [er.pending_token]
-                props[er.slot] = ngram_propose(
-                    history, cfg.spec_ngram_match, K
-                )
-            if not any(props.values()):
-                return await self._decode(loop, active, cfg.multi_step_decode)
-
-        for er in list(active):
-            ok = all(
-                self._ensure_block_for(er, er.context_len + j)
-                for j in range(S)
-            )
-            if not ok:
-                logger.warning("KV OOM: preempting %s", er.request_id)
-                self._preempt(er)
-                active.remove(er)
-        self.allocator.flush_offload()
+        with span("sched.decode.build", step=self.passes, rows=len(active)):
+            if self.draft is None:
+                # ngram proposals first: when nothing matches anywhere
+                # (non-repetitive output), the K+1-wide verify would be
+                # pure per-step overhead — run the normal decode (incl.
+                # its fused burst) instead
+                for er in active:
+                    history = list(er.seq.token_ids) + [er.pending_token]
+                    props[er.slot] = ngram_propose(
+                        history, cfg.spec_ngram_match, K
+                    )
+            verify = self.draft is not None or any(props.values())
+            if verify:
+                for er in list(active):
+                    ok = all(
+                        self._ensure_block_for(er, er.context_len + j)
+                        for j in range(S)
+                    )
+                    if not ok:
+                        logger.warning("KV OOM: preempting %s",
+                                       er.request_id)
+                        self._preempt(er)
+                        active.remove(er)
+                self.allocator.flush_offload()
+        if not verify:
+            return await self._decode(loop, active, cfg.multi_step_decode)
         if not active:
             return
 
@@ -3429,80 +3545,90 @@ class Scheduler:
             # the mirror cache land in valid slots)
             props = await self._draft_propose(loop, active, K)
 
-        w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
-        tokens = np.zeros((b, S), np.int32)
-        positions = np.zeros((b, S), np.int32)
-        slot_map = np.full((b, S), -1, np.int32)
-        btab = np.zeros((b, w), np.int32)
-        ctx_lens = np.ones(b, np.int32)
-        last_idx = np.zeros(b, np.int32)
+        with span("sched.decode.build", step=self.passes, rows=len(active)):
+            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            tokens = np.zeros((b, S), np.int32)
+            positions = np.zeros((b, S), np.int32)
+            slot_map = np.full((b, S), -1, np.int32)
+            btab = np.zeros((b, w), np.int32)
+            ctx_lens = np.ones(b, np.int32)
+            last_idx = np.zeros(b, np.int32)
 
-        for er in active:
-            i = er.slot
-            pos0 = er.context_len
-            prop = props[i]
-            row = [er.pending_token] + prop
-            tokens[i, : len(row)] = row
-            positions[i] = pos0 + np.arange(S)
-            for j in range(S):
-                pj = pos0 + j
-                slot_map[i, j] = er.block_ids[pj // bs] * bs + pj % bs
-            btab[i, : len(er.block_ids)] = er.block_ids
-            # causal masking is by absolute position, so padding rows'
-            # junk keys (past their proposal) are invisible to every
-            # valid query at an earlier position
-            ctx_lens[i] = pos0 + S
-            last_idx[i] = len(row) - 1
+            for er in active:
+                i = er.slot
+                pos0 = er.context_len
+                prop = props[i]
+                row = [er.pending_token] + prop
+                tokens[i, : len(row)] = row
+                positions[i] = pos0 + np.arange(S)
+                for j in range(S):
+                    pj = pos0 + j
+                    slot_map[i, j] = er.block_ids[pj // bs] * bs + pj % bs
+                btab[i, : len(er.block_ids)] = er.block_ids
+                # causal masking is by absolute position, so padding rows'
+                # junk keys (past their proposal) are invisible to every
+                # valid query at an earlier position
+                ctx_lens[i] = pos0 + S
+                last_idx[i] = len(row) - 1
 
-        zf, zi = np.zeros(b, np.float32), np.zeros(b, np.int32)
-        t_dispatch = time.monotonic()
-        *_, greedy_all = self.runner.step(
-            tokens, positions, btab, slot_map, ctx_lens, last_idx,
-            zf, zi, np.ones(b, np.float32),
-            min_p=zf, presence_penalty=zf, frequency_penalty=zf,
-            repetition_penalty=np.ones(b, np.float32),
-            seed_keys=np.zeros((b, 2), np.uint32), counters=zi,
-            sample_slots=np.arange(b, dtype=np.int32),
-            commit=np.zeros(b, bool),  # greedy chain: counts never consulted
-            want_top=False, want_greedy=True,
-        )
-        t_sync = time.monotonic()
-        ga = await loop.run_in_executor(None, lambda: np.asarray(greedy_all))
-        self._observe_host_sync(time.monotonic() - t_sync)
-        if self.device_time is not None:
-            # the verify forward is one decode-shaped step over S
-            # positions: weights once + each row's (ctx + S) KV
-            self.device_time.observe(
-                "spec_verify", "decode", t_dispatch, time.monotonic(),
-                read_bytes=self.device_time.decode_read_bytes(
-                    1, sum(er.context_len + S for er in active),
-                ),
-                tokens=len(active),
+            zf, zi = np.zeros(b, np.float32), np.zeros(b, np.int32)
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(active)):
+            t_dispatch = time.monotonic()
+            *_, greedy_all = self.runner.step(
+                tokens, positions, btab, slot_map, ctx_lens, last_idx,
+                zf, zi, np.ones(b, np.float32),
+                min_p=zf, presence_penalty=zf, frequency_penalty=zf,
+                repetition_penalty=np.ones(b, np.float32),
+                seed_keys=np.zeros((b, 2), np.uint32), counters=zi,
+                sample_slots=np.arange(b, dtype=np.int32),
+                commit=np.zeros(b, bool),  # greedy chain: counts never consulted
+                want_top=False, want_greedy=True,
             )
-        self.steps += 1
+        t_sync = time.monotonic()
 
-        for er in active:
-            if er.finish is not None:
-                continue
-            i = er.slot
-            prop = props[i]
-            a = 0
-            while a < len(prop) and int(ga[i, a]) == prop[a]:
-                a += 1
-            self.spec_proposed += len(prop)
-            self.spec_accepted += a
-            self._spec_proposed_ctr.inc(len(prop))
-            self._spec_accepted_ctr.inc(a)
-            # emit accepted prefix + the correction token, with the same
-            # pending-token discipline as every other decode path
-            for j in range(a + 1):
+        def _sync_verify():
+            with span("sync.fetch"):
+                return np.asarray(greedy_all)
+
+        with span("sched.decode.sync", step=self.passes):
+            ga = await loop.run_in_executor(None, _sync_verify)
+        with span("sched.decode.emit", step=self.passes, rows=len(active)):
+            self._observe_host_sync(time.monotonic() - t_sync)
+            if self.device_time is not None:
+                # the verify forward is one decode-shaped step over S
+                # positions: weights once + each row's (ctx + S) KV
+                self.device_time.observe(
+                    "spec_verify", "decode", t_dispatch, time.monotonic(),
+                    read_bytes=self.device_time.decode_read_bytes(
+                        1, sum(er.context_len + S for er in active),
+                    ),
+                    tokens=len(active),
+                )
+            self.steps += 1
+
+            for er in active:
                 if er.finish is not None:
-                    break
-                token = int(ga[i, j])
-                self._advance_row(er, token)
-                self._emit(er, token, None, None)
-                if er.finish is not None:
-                    self._finish(er, er.finish, emit=False)
+                    continue
+                i = er.slot
+                prop = props[i]
+                a = 0
+                while a < len(prop) and int(ga[i, a]) == prop[a]:
+                    a += 1
+                self.spec_proposed += len(prop)
+                self.spec_accepted += a
+                self._spec_proposed_ctr.inc(len(prop))
+                self._spec_accepted_ctr.inc(a)
+                # emit accepted prefix + the correction token, with the same
+                # pending-token discipline as every other decode path
+                for j in range(a + 1):
+                    if er.finish is not None:
+                        break
+                    token = int(ga[i, j])
+                    self._advance_row(er, token)
+                    self._emit(er, token, None, None)
+                    if er.finish is not None:
+                        self._finish(er, er.finish, emit=False)
 
     async def _decode(self, loop, active: List[EngineRequest],
                       k_steps: int = 1) -> None:
@@ -3510,178 +3636,185 @@ class Scheduler:
         b = cfg.max_batch_size
         bs = cfg.kv_block_size
 
-        # a K-step burst writes K tokens of KV per row before the host
-        # sees any of them, so every row needs blocks for all K positions
-        # up front, and no row may run past the block-table/model-len
-        # horizon mid-burst (such rows finish within one burst anyway —
-        # fall back to per-token stepping for everyone this pass)
-        if k_steps > 1 and any(
-            er.context_len + k_steps + 1 > cfg.max_model_len for er in active
-        ):
-            k_steps = 1
-        if self.draft is not None:
-            # plain decode must keep the draft's mirror cache current
-            # (the next speculative round assumes draft KV for every
-            # position < context); the mirror runs per-token, so pin the
-            # target to per-token too — with a draft configured, the
-            # fused burst's role is played by speculation itself
-            k_steps = 1
-        if any(er.guided is not None for er in active):
-            # guided rows rewrite their mask between tokens on the host;
-            # a fused burst would sample K tokens against one stale mask.
-            # NOTE this pins the WHOLE batch (all rows share one
-            # dispatch), so concurrent unguided requests also lose the
-            # burst while any guided request is active — documented in
-            # docs/models.md. Splitting guided rows into their own
-            # dispatch would pay two program launches per step, worse
-            # than the amortization it saves at serving batch sizes.
-            k_steps = 1
-
-        # make sure each active sequence has blocks for its next position
-        # (all k_steps of them under a burst)
-        for er in list(active):
-            ok = all(
-                self._ensure_block_for(er, er.context_len + j)
-                for j in range(k_steps)
-            )
-            if not ok:
-                # out of memory: evict the youngest request back to waiting
-                # (simple preemption — recompute later)
-                logger.warning("KV OOM: preempting %s", er.request_id)
-                self._preempt(er)
-                active.remove(er)
-        # one batched host-offload gather for every eviction this step,
-        # before the step below overwrites the evicted slots
-        self.allocator.flush_offload()
-        if not active:
-            return
-
-        # KV-width bucketing: the block table (and so the gather/page walk
-        # behind attention) is sized to the LIVE context, rounded up a
-        # power-of-two ladder — short-context decode doesn't pay the
-        # max_model_len table width (one compiled program per bucket)
-        w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
-
-        # sampling params and the block table come from the persistent
-        # host state (mutated only on membership / block growth); only
-        # the genuinely per-pass scalars are rebuilt here
-        hs = self._host
-        tokens = np.zeros((b, 1), np.int32)
-        positions = np.zeros((b, 1), np.int32)
-        slot_map = np.full((b, 1), -1, np.int32)
-        ctx_lens = np.ones(b, np.int32)
-        last_idx = np.zeros(b, np.int32)
-        ctrs = np.zeros(b, np.int32)
-        commit = np.zeros(b, bool)
-
-        for er in active:
-            i = er.slot
-            pos = er.context_len
-            hs.sync_blocks(er)
-            tokens[i, 0] = er.pending_token
-            positions[i, 0] = pos
-            slot_map[i, 0] = er.block_ids[pos // bs] * bs + pos % bs
-            ctx_lens[i] = pos + 1
-            ctrs[i] = er.generated
-            commit[i] = True
-        # .copy(), not a view: the persistent table mutates across passes
-        # while a dispatched program's host→device transfer may still be
-        # in flight — the step must capture a stable snapshot
-        btab = hs.btab[:, :w].copy()
-
-        # the [B, V] top-k sort only runs when some active request
-        # asked for alternatives (ADVICE r2: fixed decode-path cost)
-        want_top = any(er.logprobs_n > 0 for er in active)
-
-        # synchronous path: the device has been idle since the previous
-        # burst's host sync completed — that gap IS the bubble the
-        # dispatch-ahead pipeline exists to close
-        if self._last_burst_done_t is not None:
-            self._bubble_hist.observe(
-                time.monotonic() - self._last_burst_done_t
-            )
-            self._last_burst_done_t = None
-
-        self.flight.record(
-            "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
-            pipelined=False,
-            requests=[er.request_id for er in active[:8]],
-        )
-        t_dispatch = time.monotonic()
-        if k_steps > 1:
-            next_tokens, lps, top_vals, top_ids = self.runner.decode_burst(
-                tokens[:, 0], positions[:, 0], btab,
-                hs.temp, hs.top_k, hs.top_p,
-                min_p=hs.min_p, presence_penalty=hs.pres,
-                frequency_penalty=hs.freq,
-                repetition_penalty=hs.rep, seed_keys=hs.keys, counters=ctrs,
-                commit=commit, want_top=want_top,
-            )
-        else:
-            next_tokens, lps, top_vals, top_ids, *_ = self.runner.step(
-                tokens, positions, btab, slot_map, ctx_lens, last_idx,
-                hs.temp, hs.top_k, hs.top_p,
-                min_p=hs.min_p, presence_penalty=hs.pres,
-                frequency_penalty=hs.freq,
-                repetition_penalty=hs.rep, seed_keys=hs.keys, counters=ctrs,
-                sample_slots=np.arange(b, dtype=np.int32), commit=commit,
-                want_top=want_top,
-            )
+        with span("sched.decode.build", step=self.passes,
+                  rows=len(active)):
+            # a K-step burst writes K tokens of KV per row before the host
+            # sees any of them, so every row needs blocks for all K positions
+            # up front, and no row may run past the block-table/model-len
+            # horizon mid-burst (such rows finish within one burst anyway —
+            # fall back to per-token stepping for everyone this pass)
+            if k_steps > 1 and any(
+                er.context_len + k_steps + 1 > cfg.max_model_len for er in active
+            ):
+                k_steps = 1
             if self.draft is not None:
-                # mirror the step on the draft (inert sampling): the
-                # speculative rounds assume the draft cache covers every
-                # position the target has decoded
-                dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(b)
-                self.draft.step(
-                    tokens, positions, btab, slot_map, ctx_lens, last_idx,
-                    dtemp, dtop_k, dtop_p,
-                    sample_slots=np.arange(b, dtype=np.int32),
-                    commit=np.zeros(b, bool), want_top=False, **dkw,
+                # plain decode must keep the draft's mirror cache current
+                # (the next speculative round assumes draft KV for every
+                # position < context); the mirror runs per-token, so pin the
+                # target to per-token too — with a draft configured, the
+                # fused burst's role is played by speculation itself
+                k_steps = 1
+            if any(er.guided is not None for er in active):
+                # guided rows rewrite their mask between tokens on the host;
+                # a fused burst would sample K tokens against one stale mask.
+                # NOTE this pins the WHOLE batch (all rows share one
+                # dispatch), so concurrent unguided requests also lose the
+                # burst while any guided request is active — documented in
+                # docs/models.md. Splitting guided rows into their own
+                # dispatch would pay two program launches per step, worse
+                # than the amortization it saves at serving batch sizes.
+                k_steps = 1
+
+            # make sure each active sequence has blocks for its next position
+            # (all k_steps of them under a burst)
+            for er in list(active):
+                ok = all(
+                    self._ensure_block_for(er, er.context_len + j)
+                    for j in range(k_steps)
                 )
+                if not ok:
+                    # out of memory: evict the youngest request back to waiting
+                    # (simple preemption — recompute later)
+                    logger.warning("KV OOM: preempting %s", er.request_id)
+                    self._preempt(er)
+                    active.remove(er)
+            # one batched host-offload gather for every eviction this step,
+            # before the step below overwrites the evicted slots
+            self.allocator.flush_offload()
+            if not active:
+                return
+
+            # KV-width bucketing: the block table (and so the gather/page walk
+            # behind attention) is sized to the LIVE context, rounded up a
+            # power-of-two ladder — short-context decode doesn't pay the
+            # max_model_len table width (one compiled program per bucket)
+            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+
+            # sampling params and the block table come from the persistent
+            # host state (mutated only on membership / block growth); only
+            # the genuinely per-pass scalars are rebuilt here
+            hs = self._host
+            tokens = np.zeros((b, 1), np.int32)
+            positions = np.zeros((b, 1), np.int32)
+            slot_map = np.full((b, 1), -1, np.int32)
+            ctx_lens = np.ones(b, np.int32)
+            last_idx = np.zeros(b, np.int32)
+            ctrs = np.zeros(b, np.int32)
+            commit = np.zeros(b, bool)
+
+            for er in active:
+                i = er.slot
+                pos = er.context_len
+                hs.sync_blocks(er)
+                tokens[i, 0] = er.pending_token
+                positions[i, 0] = pos
+                slot_map[i, 0] = er.block_ids[pos // bs] * bs + pos % bs
+                ctx_lens[i] = pos + 1
+                ctrs[i] = er.generated
+                commit[i] = True
+            # .copy(), not a view: the persistent table mutates across passes
+            # while a dispatched program's host→device transfer may still be
+            # in flight — the step must capture a stable snapshot
+            btab = hs.btab[:, :w].copy()
+
+            # the [B, V] top-k sort only runs when some active request
+            # asked for alternatives (ADVICE r2: fixed decode-path cost)
+            want_top = any(er.logprobs_n > 0 for er in active)
+
+            # synchronous path: the device has been idle since the previous
+            # burst's host sync completed — that gap IS the bubble the
+            # dispatch-ahead pipeline exists to close
+            if self._last_burst_done_t is not None:
+                self._bubble_hist.observe(
+                    time.monotonic() - self._last_burst_done_t
+                )
+                self._last_burst_done_t = None
+
+            self.flight.record(
+                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
+                pipelined=False,
+                requests=[er.request_id for er in active[:8]],
+            )
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(active)):
+            t_dispatch = time.monotonic()
+            if k_steps > 1:
+                next_tokens, lps, top_vals, top_ids = self.runner.decode_burst(
+                    tokens[:, 0], positions[:, 0], btab,
+                    hs.temp, hs.top_k, hs.top_p,
+                    min_p=hs.min_p, presence_penalty=hs.pres,
+                    frequency_penalty=hs.freq,
+                    repetition_penalty=hs.rep, seed_keys=hs.keys, counters=ctrs,
+                    commit=commit, want_top=want_top,
+                )
+            else:
+                next_tokens, lps, top_vals, top_ids, *_ = self.runner.step(
+                    tokens, positions, btab, slot_map, ctx_lens, last_idx,
+                    hs.temp, hs.top_k, hs.top_p,
+                    min_p=hs.min_p, presence_penalty=hs.pres,
+                    frequency_penalty=hs.freq,
+                    repetition_penalty=hs.rep, seed_keys=hs.keys, counters=ctrs,
+                    sample_slots=np.arange(b, dtype=np.int32), commit=commit,
+                    want_top=want_top,
+                )
+                if self.draft is not None:
+                    # mirror the step on the draft (inert sampling): the
+                    # speculative rounds assume the draft cache covers every
+                    # position the target has decoded
+                    dtemp, dtop_k, dtop_p, dkw = self._inert_sampling(b)
+                    self.draft.step(
+                        tokens, positions, btab, slot_map, ctx_lens, last_idx,
+                        dtemp, dtop_k, dtop_p,
+                        sample_slots=np.arange(b, dtype=np.int32),
+                        commit=np.zeros(b, bool), want_top=False, **dkw,
+                    )
         t_sync = time.monotonic()
 
         def _sync_step():
             faults.maybe_hang("decode_burst_hang")  # chaos site (see above)
-            return (np.asarray(next_tokens), np.asarray(lps),
-                    np.asarray(top_vals), np.asarray(top_ids))
+            with span("sync.fetch"):
+                return (np.asarray(next_tokens), np.asarray(lps),
+                        np.asarray(top_vals), np.asarray(top_ids))
 
-        toks, lpn, tv, ti = await loop.run_in_executor(None, _sync_step)
-        self._observe_host_sync(time.monotonic() - t_sync)
-        self._last_burst_done_t = time.monotonic()
-        if self.device_time is not None:
-            self.device_time.observe(
-                "decode_burst" if k_steps > 1 else "decode", "decode",
-                t_dispatch, self._last_burst_done_t,
-                read_bytes=self.device_time.decode_read_bytes(
-                    k_steps, sum(er.context_len for er in active),
-                ),
-                tokens=k_steps * len(active),
-            )
-        self.steps += 1
-        if k_steps == 1:
-            # [B] → [1, B] so the emit loop below is one shape
-            toks, lpn = toks[None], lpn[None]
-            tv, ti = tv[None], ti[None]
-
-        # emit in step order; a request that finishes at step j has its
-        # trailing burst tokens (sampled ahead on device) discarded —
-        # their KV went into this request's own still-unregistered or
-        # over-allocated blocks, which are freed with the request, so
-        # nothing another sequence can observe was touched
-        for j in range(k_steps):
-            for er in active:
-                if er.finish is not None:
-                    continue
-                token = int(toks[j, er.slot])
-                self._advance_row(er, token)
-                self._guided_after_token(er)
-                self._emit(
-                    er, token,
-                    float(lpn[j, er.slot]) if er.want_logprobs else None,
-                    self._top_row(er, tv[j], ti[j], er.slot),
+        with span("sched.decode.sync", step=self.passes):
+            toks, lpn, tv, ti = await loop.run_in_executor(None, _sync_step)
+        with span("sched.decode.emit", step=self.passes, rows=len(active)):
+            self._observe_host_sync(time.monotonic() - t_sync)
+            self._last_burst_done_t = time.monotonic()
+            if self.device_time is not None:
+                self.device_time.observe(
+                    "decode_burst" if k_steps > 1 else "decode", "decode",
+                    t_dispatch, self._last_burst_done_t,
+                    read_bytes=self.device_time.decode_read_bytes(
+                        k_steps, sum(er.context_len for er in active),
+                    ),
+                    tokens=k_steps * len(active),
                 )
-                if er.finish is not None:
-                    self._finish(er, er.finish, emit=False)
+            self.steps += 1
+            if k_steps == 1:
+                # [B] → [1, B] so the emit loop below is one shape
+                toks, lpn = toks[None], lpn[None]
+                tv, ti = tv[None], ti[None]
+
+            # emit in step order; a request that finishes at step j has its
+            # trailing burst tokens (sampled ahead on device) discarded —
+            # their KV went into this request's own still-unregistered or
+            # over-allocated blocks, which are freed with the request, so
+            # nothing another sequence can observe was touched
+            for j in range(k_steps):
+                for er in active:
+                    if er.finish is not None:
+                        continue
+                    token = int(toks[j, er.slot])
+                    self._advance_row(er, token)
+                    self._guided_after_token(er)
+                    self._emit(
+                        er, token,
+                        float(lpn[j, er.slot]) if er.want_logprobs else None,
+                        self._top_row(er, tv[j], ti[j], er.slot),
+                    )
+                    if er.finish is not None:
+                        self._finish(er, er.finish, emit=False)
 
     def _preempt(self, er: EngineRequest) -> None:
         """Return a request to the waiting queue, releasing its blocks.
@@ -3690,6 +3823,7 @@ class Scheduler:
         the request re-prefills ``prompt + resume_tokens`` and the stream
         continues where it stopped (never restarts or diverges)."""
         self._preemptions.inc()
+        er.preemptions += 1
         self.flight.record(
             "scheduler.preemption", request_id=er.request_id,
             trace_id=er.ctx.trace_id, generated=er.generated,

@@ -44,7 +44,7 @@ from ..runtime.engine import (
     EngineError,
 )
 from ..runtime.network import ResponseStreamError
-from ..telemetry.tracing import TraceRecorder
+from ..telemetry.tracing import TraceRecorder, span
 from .metrics import ServiceMetrics
 
 logger = logging.getLogger(__name__)
@@ -290,7 +290,8 @@ class HttpService:
     ) -> web.StreamResponse:
         try:
             body = await request.json()
-            api_req = request_cls.model_validate(body)
+            with span("http.ingress"):
+                api_req = request_cls.model_validate(body)
         except (json.JSONDecodeError, ValueError) as e:
             return self._error(400, f"invalid request: {e}")
 
@@ -525,13 +526,17 @@ class HttpService:
                     comment=ann.comment[0] if ann.comment else None,
                 ))
                 return False
-            d = _as_dict(chunk)
-            if _has_payload(d):
-                n = _payload_tokens(d)
-                timer.token(n)
-                if charge is not None:
-                    charge(n)
-            await resp.write(sse.encode_event(d))
+            # the write yields only under backpressure (the socket's
+            # buffer above its high-water mark): the span is then as long
+            # as the wait, and other tasks' spans nest inside it
+            with span("http.sse_write"):
+                d = _as_dict(chunk)
+                if _has_payload(d):
+                    n = _payload_tokens(d)
+                    timer.token(n)
+                    if charge is not None:
+                        charge(n)
+                await resp.write(sse.encode_event(d))
             return False
 
         try:

@@ -18,6 +18,7 @@ from ..protocols.common import (
 )
 from ..runtime.engine import AsyncEngine, Context
 from ..runtime.pipeline import Operator
+from ..telemetry.tracing import span
 from .tokenizer import HFTokenizer
 
 
@@ -154,21 +155,23 @@ class Backend(Operator):
                 texts: List[str] = []
                 emitted_ids: List[int] = []
                 finish: Optional[FinishReason] = out.finish_reason
-                for tid in out.token_ids:
-                    text, tok_finish = decoder.step(tid)
-                    emitted_ids.append(tid)
-                    if text is not None:
-                        texts.append(text)
-                    if tok_finish is not None:
-                        finish = tok_finish
-                        break
-                    if max_tokens is not None and decoder.generated >= max_tokens:
-                        finish = finish or FinishReason.LENGTH
-                        break
-                if finish is not None and finish not in (FinishReason.STOP,):
-                    tail = decoder.flush()
-                    if tail:
-                        texts.append(tail)
+                with span("detok.step", tokens=len(out.token_ids)):
+                    for tid in out.token_ids:
+                        text, tok_finish = decoder.step(tid)
+                        emitted_ids.append(tid)
+                        if text is not None:
+                            texts.append(text)
+                        if tok_finish is not None:
+                            finish = tok_finish
+                            break
+                        if (max_tokens is not None
+                                and decoder.generated >= max_tokens):
+                            finish = finish or FinishReason.LENGTH
+                            break
+                    if finish is not None and finish not in (FinishReason.STOP,):
+                        tail = decoder.flush()
+                        if tail:
+                            texts.append(tail)
                 yield BackendOutput(
                     token_ids=emitted_ids,
                     text="".join(texts) if texts else None,

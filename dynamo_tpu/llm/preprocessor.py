@@ -46,6 +46,7 @@ from ..protocols.openai import (
 )
 from ..runtime.engine import AsyncEngine, Context, EngineError
 from ..runtime.pipeline import Operator
+from ..telemetry.tracing import span
 from .model_card import ModelDeploymentCard
 from .tokenizer import HFTokenizer
 
@@ -177,7 +178,8 @@ class OpenAIPreprocessor(Operator):
     def _tokenize(self, prompt: str) -> List[int]:
         if self.tokenizer is None:
             raise EngineError(f"no tokenizer available for {self.mdc.display_name}")
-        return self.tokenizer.encode(prompt)
+        with span("pre.tokenize", chars=len(prompt)):
+            return self.tokenizer.encode(prompt)
 
     @staticmethod
     def _validate_tool_choice(req: ChatCompletionRequest) -> None:

@@ -420,7 +420,8 @@ def forward(
     (ops/pallas_decode.py mla_paged_decode_attention); prefill and the
     XLA path run the dense gather formulation (mla_paged_attention)."""
     b, s = tokens.shape
-    hidden = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        hidden = params["embed"][tokens]
     attn_fn = make_mla_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens,
         mesh=mesh,

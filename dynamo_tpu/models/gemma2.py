@@ -172,11 +172,13 @@ def run_layers(hidden, kv_cache, layers, cfg, attn_fn, mlp, li0: int = 0):
 
     def layer_step(carry, lp):
         hidden, k_all, v_all, li = carry
-        x = rms_norm(hidden, lp["ln1"], eps)
-        delta, k_all, v_all = attn_fn(x, lp, k_all, v_all, li)
-        hidden = hidden + rms_norm(delta, lp["ln_post_attn"], eps)
-        x = rms_norm(hidden, lp["ln_pre_mlp"], eps)
-        hidden = hidden + rms_norm(mlp(x, lp), lp["ln_post_mlp"], eps)
+        with jax.named_scope("attn"):
+            x = rms_norm(hidden, lp["ln1"], eps)
+            delta, k_all, v_all = attn_fn(x, lp, k_all, v_all, li)
+            hidden = hidden + rms_norm(delta, lp["ln_post_attn"], eps)
+        with jax.named_scope("mlp"):
+            x = rms_norm(hidden, lp["ln_pre_mlp"], eps)
+            hidden = hidden + rms_norm(mlp(x, lp), lp["ln_post_mlp"], eps)
         return (hidden, k_all, v_all, li + 1), None
 
     (hidden, k_all, v_all, li), _ = jax.lax.scan(
@@ -198,7 +200,8 @@ def forward(
     return_hidden: bool = False,
 ) -> Tuple[jax.Array, KVCache]:
     b, s = tokens.shape
-    hidden = embed_tokens(params, tokens)
+    with jax.named_scope("embed"):
+        hidden = embed_tokens(params, tokens)
     attn_fn = make_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens, mesh
     )

@@ -201,7 +201,8 @@ def forward(
     return_hidden: bool = False,
 ) -> Tuple[jax.Array, KVCache]:
     b, s = tokens.shape
-    hidden = embed_tokens(params, tokens)
+    with jax.named_scope("embed"):
+        hidden = embed_tokens(params, tokens)
     attn_fn = make_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens, mesh
     )

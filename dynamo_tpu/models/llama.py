@@ -465,7 +465,8 @@ def sp_decoder_forward(
     engine samples from the last valid position via logits_from_hidden,
     exactly like the dense step program's return_hidden path."""
     b, s = tokens.shape
-    hidden = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        hidden = params["embed"][tokens]
     attn_fn = make_sp_gqa_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens,
         chunk_start, mesh, sp_axis=sp_axis, head_axis=head_axis,
@@ -495,11 +496,16 @@ def run_layers(
 
     def layer_step(carry, layer_params):
         hidden, k_all, v_all, li = carry
-        x = rms_norm(hidden, layer_params["ln1"], cfg.rms_norm_eps)
-        delta, k_all, v_all = attn_fn(x, layer_params, k_all, v_all, li)
-        hidden = hidden + delta
-        x = rms_norm(hidden, layer_params["ln2"], cfg.rms_norm_eps)
-        hidden = hidden + mlp_fn(x, layer_params)
+        # named scopes are metadata on the lowered operations (the
+        # profiler's capture and the HLO dump show them); the compiled
+        # code is the same with and without them
+        with jax.named_scope("attn"):
+            x = rms_norm(hidden, layer_params["ln1"], cfg.rms_norm_eps)
+            delta, k_all, v_all = attn_fn(x, layer_params, k_all, v_all, li)
+            hidden = hidden + delta
+        with jax.named_scope("mlp"):
+            x = rms_norm(hidden, layer_params["ln2"], cfg.rms_norm_eps)
+            hidden = hidden + mlp_fn(x, layer_params)
         return (hidden, k_all, v_all, li + 1), None
 
     (hidden, k_all, v_all, li), _ = jax.lax.scan(
@@ -546,7 +552,8 @@ def decoder_forward(
     full-S lm head is the dominant prefill matmul otherwise).
     """
     b, s = tokens.shape
-    hidden = params["embed"][tokens]  # [B, S, D]
+    with jax.named_scope("embed"):
+        hidden = params["embed"][tokens]  # [B, S, D]
     attn_fn = make_gqa_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens, mesh
     )
@@ -555,7 +562,8 @@ def decoder_forward(
     )
     if return_hidden:
         return hidden, kv_cache
-    return lm_logits(hidden, params, cfg), kv_cache
+    with jax.named_scope("lm_head"):
+        return lm_logits(hidden, params, cfg), kv_cache
 
 
 def embed_forward(

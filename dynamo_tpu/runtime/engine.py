@@ -52,6 +52,10 @@ class AsyncEngineContext:
         # stitch.remote_span_set dict with offsets relative to THIS
         # process's clock
         self.remote_spans: list = []
+        # what the engine counted for this request, published when it
+        # finishes (cached/computed prompt tokens, decode tokens,
+        # preemptions): the trace record carries them
+        self.counts: dict = {}
         self._stopped = asyncio.Event()
         self._killed = asyncio.Event()
 
@@ -88,6 +92,8 @@ class AsyncEngineContext:
             # a choice served by a remote worker collected that worker's
             # span set — it belongs to the parent trace like the stages
             self.remote_spans.extend(child.remote_spans)
+            for key, n in child.counts.items():
+                self.counts[key] = self.counts.get(key, 0) + n
         self.stages.sort(key=lambda s: s[1])
 
     def stop_generating(self) -> None:

@@ -37,6 +37,8 @@ import time
 from contextlib import contextmanager, nullcontext
 from typing import List, Optional
 
+from .tracing import span
+
 logger = logging.getLogger(__name__)
 
 FLIGHT_DIR_ENV = "DYN_FLIGHT_DIR"
@@ -183,7 +185,11 @@ class CompileTracker:
         """Wrap ONE dispatch of ``program`` at shape-bucket ``key``;
         records a compile iff this (program, key) was never dispatched."""
         hook = self.dispatch_cm
-        with hook(program) if hook is not None else nullcontext():
+        # the profiler's trace gets the program's stable name: the
+        # runtime's own host spans (PjitFunction, shard_args, the
+        # transfers) nest inside it
+        with span("dispatch." + program, key=key), (
+                hook(program) if hook is not None else nullcontext()):
             with self._lock:
                 first = (program, key) not in self._seen
                 if first:

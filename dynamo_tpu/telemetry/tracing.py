@@ -11,6 +11,11 @@ Completed traces land in a bounded ring buffer, queryable at
 ``GET /debug/requests/{id}``, and are optionally appended as JSONL to the
 file named by ``DYN_TRACE_JSONL`` (one object per request — the
 machine-shippable sibling of ``DYN_LOGGING_JSONL``).
+
+:func:`span` is the other half: the program's seams written into the
+JAX profiler's own trace (``/host:CPU`` plane, the device planes'
+clock), so a capture lays the host's work beside the device's. The
+names are a fixed set, listed in ``docs/observability.md``.
 """
 
 from __future__ import annotations
@@ -23,9 +28,31 @@ import queue
 import threading
 import time
 import weakref
+from contextlib import nullcontext
 from typing import Callable, Dict, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
+
+_annotation = None  # jax.profiler.TraceAnnotation, or False without jax
+
+
+def span(name: str, **stats):
+    """One named span in the profiler's trace: a
+    ``jax.profiler.TraceAnnotation`` and nothing else (about a
+    microsecond while no capture runs). ``name`` is one of the fixed,
+    dotted, lower-case names of docs/observability.md; whatever varies
+    (a pass number, rows, tokens, a program, a shape key) goes into
+    ``stats``, which the capture keeps as the event's stats. Processes
+    without jax (a frontend alone) get a null context."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:
+            _annotation = False
+    if _annotation is False:
+        return nullcontext()
+    return _annotation(name, **stats)
 
 TRACE_JSONL_ENV = "DYN_TRACE_JSONL"
 TRACE_TTL_ENV = "DYN_TRACE_TTL_S"
@@ -213,9 +240,11 @@ class TraceRecorder:
     ) -> dict:
         """Record one completed request. ``ctx`` (the request's
         AsyncEngineContext, optional) contributes the cross-process
-        pieces: the wall anchor of the first mark (``t0_wall``) and any
+        pieces: the wall anchor of the first mark (``t0_wall``), any
         remote span sets collected from downstream hops — what
-        ``GET /debug/trace/{id}`` stitches into one timeline."""
+        ``GET /debug/trace/{id}`` stitches into one timeline — and the
+        engine's counts for the request (``cached_tokens``,
+        ``computed_tokens``, ``decode_tokens``, ``preemptions``)."""
         end = end if end is not None else time.monotonic()
         spans = span_breakdown(stages, end)
         trace = {
@@ -226,10 +255,16 @@ class TraceRecorder:
             "total_s": round(end - stages[0][1], 6) if stages else 0.0,
             "spans": spans,
         }
-        if ctx is not None and stages:
-            trace["t0_wall"] = ctx.wall(stages[0][1])
-            if ctx.remote_spans:
-                trace["remote"] = list(ctx.remote_spans)
+        if stages:
+            # the first mark on this machine's monotonic clock: a load
+            # generator on the same machine stamps with the same clock
+            trace["t0_monotonic"] = stages[0][1]
+        if ctx is not None:
+            trace.update(ctx.counts)
+            if stages:
+                trace["t0_wall"] = ctx.wall(stages[0][1])
+                if ctx.remote_spans:
+                    trace["remote"] = list(ctx.remote_spans)
         with self._store_lock:
             self._traces[request_id] = trace  # a reused id replaces its trace
             self._traces.move_to_end(request_id)

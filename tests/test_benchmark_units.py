@@ -1,0 +1,44 @@
+"""The benchmark's own unit tests, in tier-1.
+
+``benchmark/tests`` holds the yardstick's arithmetic (statistics,
+traffic, operations and bytes, the manifest's shape) and the reduction
+from a profiler capture to numbers, checked against cuts of v5e
+captures. They need no server and no device, so they run here too, each
+case under its own name: this module loads those files and takes their
+tests and fixtures as its own. The three-minute CPU rehearsal of the
+whole command (``benchmark/tests/test_rehearsal.py``) stays out.
+"""
+
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+FILES = ("test_units.py", "test_trace_reduction.py", "test_host_spans.py",
+         "test_scope_ops.py")
+
+
+def _adopt(filename: str) -> None:
+    """Execute one file of benchmark/tests as a module (with the import
+    path benchmark/tests/conftest.py gives it) and bind its tests and
+    fixtures here, so that pytest collects each under this file."""
+    for p in (ROOT, BENCH):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    name = "benchmark_tests_" + filename[:-3]
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(BENCH, "tests", filename))
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    for key, value in vars(module).items():
+        is_fixture = (hasattr(value, "_pytestfixturefunction")
+                      or type(value).__name__ == "FixtureFunctionDefinition")
+        if key.startswith("test_") or is_fixture:
+            assert key not in globals(), f"{filename}: {key} defined twice"
+            globals()[key] = value
+
+
+for _file in FILES:
+    _adopt(_file)

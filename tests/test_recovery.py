@@ -763,23 +763,27 @@ async def test_stream_rebind_lets_source_relay_exit():
             if out.finish_reason is not None:
                 finish = out.finish_reason
 
-    async def watch_relay():
-        # how many tokens the CLIENT had when the source's relay duty
-        # ended — the handoff must land mid-stream, not at its end
-        while not controller._relays:
-            await asyncio.sleep(0.002)
-        relay = next(iter(controller._relays))
-        await asyncio.wait({relay})
-        return len(toks)
+    # how many tokens the CLIENT had when the source's relay duty
+    # ended — the handoff must land mid-stream, not at its end. Taken at
+    # the relay task's own completion: the relay lives a millisecond or
+    # two, and a poll of controller._relays can miss it altogether.
+    relay_done = asyncio.get_running_loop().create_future()
+    hold = controller._hold
+
+    def hold_and_watch(relay):
+        relay.add_done_callback(
+            lambda _t: relay_done.done() or relay_done.set_result(len(toks)))
+        hold(relay)
+
+    controller._hold = hold_and_watch
 
     loop = asyncio.get_running_loop()
     task = loop.create_task(consume())
-    watcher = loop.create_task(watch_relay())
     while len(toks) < 6:  # the stream is live on the source
         await asyncio.sleep(0.01)
     summary = await controller.drain(hard=False, reason="admin")
     assert summary["migrated"] == 1 and summary["failed"] == 0
-    relay_done_at_token = await asyncio.wait_for(watcher, timeout=60)
+    relay_done_at_token = await asyncio.wait_for(relay_done, timeout=60)
     await asyncio.wait_for(task, timeout=60)
 
     # _baseline drives its own event loop — run it in a thread

@@ -1,0 +1,503 @@
+"""The program's seams in the profiler's trace (ISSUE 23).
+
+A tiny engine served over HTTP, and the scheduler over a fake runner on
+every decode path, each under ONE real ``jax.profiler`` capture with the
+benchmark's options (host tracer 2, Python tracer off). The CPU backend
+writes ``TraceAnnotation`` spans to the ``/host:CPU`` plane as the TPU
+backend does, so what these tests read is what ``benchmark/harness/
+trace.py`` reads on the chip. A scenario runs once a module; the tests
+are assertions on what it recorded.
+"""
+
+import asyncio
+import glob
+import json
+import os
+import re
+import socket
+import time
+
+import pytest
+
+from fixtures import make_model_dir
+
+SCHED = ("sched.admit", "sched.prefill.build", "sched.prefill.dispatch",
+         "sched.prefill.sync", "sched.prefill.emit", "sched.decode.build",
+         "sched.decode.dispatch", "sched.decode.sync", "sched.decode.emit",
+         "sched.yield", "sched.wait")
+CROSS_AWAIT = ("sched.prefill.sync", "sched.decode.sync", "sched.yield",
+               "sched.wait")
+FRONTEND = ("http.ingress", "pre.tokenize", "detok.step", "http.sse_write")
+PREFIX = ("the quick brown fox jumps over the lazy dog and keeps running "
+          "through the quiet forest until the river bends ")
+
+
+def _capture_start(trace_dir):
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
+def _capture_stop(trace_dir):
+    """Stop the capture; every host event as a dict, plus the planes'
+    names."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for tid, line in enumerate(plane.lines):
+            for e in line.events:
+                events.append({
+                    "name": e.name, "tid": tid, "start": e.start_ns,
+                    "end": e.start_ns + e.duration_ns,
+                    "stats": {k: v for k, v in e.stats},
+                })
+    return events
+
+
+def _named(events, prefix):
+    return sorted((e for e in events if e["name"].startswith(prefix)),
+                  key=lambda e: e["start"])
+
+
+# ---------------------------------------------------------------------
+# scenario A: in=http out=jax at tiny widths, the default (synchronous)
+# decode path, two requests that share a prefix
+# ---------------------------------------------------------------------
+
+def _free_port():
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+async def _served_scenario(tmp):
+    import aiohttp
+
+    from dynamo_tpu.cli.run import build_engine, build_parser, run_http
+
+    model_dir = make_model_dir(tmp, name="tiny-spans", context_length=256,
+                               config_overrides={
+                                   "hidden_size": 64, "intermediate_size": 128,
+                                   "num_hidden_layers": 2,
+                                   "num_attention_heads": 4,
+                                   "num_key_value_heads": 2})
+    extra = os.path.join(str(tmp), "extra.json")
+    with open(extra, "w") as f:
+        json.dump({"dtype": "float32", "prefill_buckets": [32, 64],
+                   "max_prefill_batch": 1, "seed": 7}, f)
+    port = _free_port()
+    flags = build_parser().parse_args([
+        "--model-path", model_dir, "--model-name", "tiny",
+        "--allow-random-weights", "--http-host", "127.0.0.1",
+        "--http-port", str(port), "--max-model-len", "128",
+        "--max-batch-size", "4", "--num-kv-blocks", "96",
+        "--kv-block-size", "8", "--extra-engine-args", extra])
+    engine, mdc = await build_engine("jax", flags)
+    task = asyncio.ensure_future(run_http(flags, engine, mdc))
+    base = f"http://127.0.0.1:{port}"
+    out = {"runner": engine.core_engine.runner}
+
+    async def complete(session, rid, prompt, n):
+        chunks = 0
+        async with session.post(
+                f"{base}/v1/completions",
+                json={"model": "tiny", "prompt": prompt, "max_tokens": n,
+                      "temperature": 0, "stream": True,
+                      "nvext": {"ignore_eos": True}},
+                headers={"X-Request-Id": rid}) as r:
+            assert r.status == 200, await r.text()
+            async for line in r.content:
+                chunks += line.startswith(b"data: {")
+        return chunks
+
+    async def metrics(session):
+        async with session.get(f"{base}/metrics") as r:
+            return await r.text()
+
+    try:
+        async with aiohttp.ClientSession() as session:
+            for _ in range(200):
+                try:
+                    await metrics(session)
+                    break
+                except aiohttp.ClientError:
+                    await asyncio.sleep(0.05)
+            await complete(session, "warm", "hello there", 4)
+            out["metrics_before"] = await metrics(session)
+            trace_dir = os.path.join(str(tmp), "profile")
+            _capture_start(trace_dir)
+            t0 = time.monotonic()
+            # the second request finds the first one's prefix cached
+            await complete(session, "first", PREFIX + "alpha", 12)
+            await complete(session, "second", PREFIX + "beta gamma", 12)
+            await asyncio.sleep(0.05)   # an idle pass: sched.wait
+            out["t"] = (t0, time.monotonic())
+            out["events"] = _capture_stop(trace_dir)
+            out["metrics_after"] = await metrics(session)
+            async with session.get(f"{base}/debug/requests/second") as r:
+                out["debug_second"] = await r.json()
+    finally:
+        task.cancel()
+        try:
+            await task
+        except asyncio.CancelledError:
+            pass
+        await engine.core_engine.close()
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("spans")
+    jsonl = os.path.join(str(tmp), "traces.jsonl")
+    old = os.environ.get("DYN_TRACE_JSONL")
+    os.environ["DYN_TRACE_JSONL"] = jsonl
+    try:
+        out = asyncio.run(asyncio.wait_for(_served_scenario(tmp), 300))
+    finally:
+        if old is None:
+            os.environ.pop("DYN_TRACE_JSONL")
+        else:
+            os.environ["DYN_TRACE_JSONL"] = old
+    deadline = time.monotonic() + 5.0   # the writer thread's last lines
+    while time.monotonic() < deadline:
+        try:
+            with open(jsonl) as f:
+                out["jsonl"] = {json.loads(line)["request_id"]: json.loads(line)
+                                for line in f}
+        except FileNotFoundError:
+            out["jsonl"] = {}
+        if "second" in out["jsonl"]:
+            break
+        time.sleep(0.05)
+    return out
+
+
+def _loop_tid(events):
+    """The event loop's thread: the line that holds sched.admit."""
+    return next(e["tid"] for e in events if e["name"] == "sched.admit")
+
+
+@pytest.mark.parametrize("name", SCHED + FRONTEND + (
+    "sync.fetch", "dispatch.decode", "dispatch.prefill"))
+def test_every_span_of_the_table_is_in_the_capture(served, name):
+    assert any(e["name"] == name for e in served["events"]), name
+
+
+def test_span_names_are_a_fixed_set(served):
+    ours = {e["name"] for e in served["events"]
+            if e["name"].startswith(("sched.", "sync.", "dispatch.",
+                                     "http.", "pre.", "detok."))}
+    allowed = set(SCHED + FRONTEND) | {"sync.fetch"}
+    assert all(n in allowed or n.startswith("dispatch.") for n in ours), ours
+    # what varies is a stat, never part of the name
+    assert all(n == n.lower() and " " not in n and not any(
+        c.isdigit() for c in n) for n in ours), ours
+
+
+def test_sched_spans_carry_their_pass_number(served):
+    steps = [e["stats"].get("step") for e in _named(served["events"],
+                                                    "sched.decode.build")]
+    assert steps and all(isinstance(s, int) for s in steps)
+    assert steps == sorted(steps) and len(set(steps)) == len(steps)
+    d = next(e for e in served["events"] if e["name"] == "dispatch.decode")
+    assert str(d["stats"]["key"]).startswith("b4_s1_w")
+
+
+def test_sched_spans_do_not_overlap_on_the_loop_thread(served):
+    tid = _loop_tid(served["events"])
+    spans = [e for e in _named(served["events"], "sched.")
+             if e["tid"] == tid]
+    assert len(spans) > 40
+    for a, b in zip(spans, spans[1:]):
+        assert a["end"] <= b["start"], (a, b)
+
+
+def test_no_span_wraps_a_whole_pass(served):
+    """Every sched.* span of one pass carries that pass's number, and
+    the first of a pass starts after the last of the pass before ended:
+    nothing covers a pass from outside."""
+    tid = _loop_tid(served["events"])
+    by_pass = {}
+    for e in _named(served["events"], "sched."):
+        if e["tid"] == tid:
+            by_pass.setdefault(e["stats"]["step"], []).append(e)
+    assert len(by_pass) > 10
+    ours = [e for e in served["events"] if e["tid"] == tid
+            and e["name"].startswith(("sched.", "dispatch."))]
+    for n, spans in by_pass.items():
+        if len(spans) < 2:   # a pass the capture's edge cut
+            continue
+        lo, hi = spans[0]["start"], spans[-1]["end"]
+        cover = [e for e in ours if e["start"] <= lo and e["end"] >= hi]
+        assert not cover, (n, cover)
+
+
+def test_leaf_spans_hold_no_await(served):
+    """Nothing else of the program runs on the loop thread inside a leaf
+    span; only the .sync spans, sched.yield and sched.wait cross an
+    await, and what other tasks do meanwhile nests inside those."""
+    tid = _loop_tid(served["events"])
+    loop = [e for e in served["events"] if e["tid"] == tid and
+            e["name"].startswith(("sched.", "http.", "pre.", "detok."))]
+    leaves = [e for e in loop if e["name"].startswith("sched.")
+              and e["name"] not in CROSS_AWAIT]
+    others = [e for e in loop if not e["name"].startswith("sched.")]
+    assert others
+    for leaf in leaves:
+        inside = [o["name"] for o in others
+                  if leaf["start"] < o["start"] < leaf["end"]]
+        assert not inside, (leaf["name"], inside)
+    # and the frontend's work does land inside sched.yield / sched.*.sync
+    crossing = [e for e in loop if e["name"] in CROSS_AWAIT]
+    assert any(c["start"] <= o["start"] and o["end"] <= c["end"]
+               for o in others for c in crossing)
+
+
+def test_sched_spans_cover_the_loops_time(served):
+    tid = _loop_tid(served["events"])
+    spans = [e for e in _named(served["events"], "sched.")
+             if e["tid"] == tid]
+    total = spans[-1]["end"] - spans[0]["start"]
+    covered = sum(e["end"] - e["start"] for e in spans)   # disjoint: above
+    assert covered >= 0.95 * total, covered / total
+
+
+def test_sync_fetch_is_on_an_executor_thread_inside_the_sync_span(served):
+    tid = _loop_tid(served["events"])
+    fetches = _named(served["events"], "sync.fetch")
+    syncs = [e for e in served["events"]
+             if e["name"] in ("sched.decode.sync", "sched.prefill.sync")]
+    assert fetches and all(f["tid"] != tid for f in fetches)
+    for f in fetches:
+        assert any(s["start"] <= f["start"] and f["end"] <= s["end"]
+                   for s in syncs), f
+
+
+def test_dispatch_span_nests_in_the_schedulers_and_holds_the_runtimes(served):
+    ev = served["events"]
+    disp = next(e for e in _named(ev, "dispatch.decode"))
+    outer = [e for e in ev if e["name"] == "sched.decode.dispatch"
+             and e["start"] <= disp["start"] and disp["end"] <= e["end"]]
+    assert outer
+    inner = {e["name"] for e in ev if e["tid"] == disp["tid"]
+             and disp["start"] <= e["start"] and e["end"] <= disp["end"]}
+    assert any(n.startswith("PjitFunction(") for n in inner), inner
+
+
+def test_compiled_programs_have_a_name_each(served):
+    """What the TPU's ``XLA Modules`` line would show: the jitted
+    functions' names, as the runtime's own host spans carry them."""
+    names = {e["name"] for e in served["events"]}
+    assert "PjitFunction(decode_step)" in names
+    assert "PjitFunction(prefill_step)" in names
+    assert "PjitFunction(step)" not in names
+    runner = served["runner"]
+    assert runner._decode_step.__name__ == "decode_step"
+    assert runner._prefill_step.__name__ == "prefill_step"
+
+
+def _prom(text, name, labels=""):
+    for line in text.splitlines():
+        if line.startswith(name + labels + " ") or (
+                labels and line.startswith(name + "{")
+                and all(p in line for p in labels.strip("{}").split(","))):
+            return float(line.rsplit(" ", 1)[1])
+    return None
+
+
+def _delta(served, name, labels=""):
+    return (_prom(served["metrics_after"], name, labels)
+            - (_prom(served["metrics_before"], name, labels) or 0.0))
+
+
+def test_prefix_counters_move_by_the_pairs_tokens(served):
+    second = served["jsonl"]["second"]
+    first = served["jsonl"]["first"]
+    looked = _delta(served, "dynamo_kv_prefix_lookup_tokens_total")
+    hit = _delta(served, "dynamo_kv_prefix_hit_tokens_total")
+    assert looked == (first["cached_tokens"] + first["computed_tokens"]
+                      + second["cached_tokens"] + second["computed_tokens"])
+    assert hit == first["cached_tokens"] + second["cached_tokens"]
+    # whole blocks of the shared prefix, and only for the second request
+    assert first["cached_tokens"] == 0
+    assert second["cached_tokens"] >= 16 and second["cached_tokens"] % 8 == 0
+    assert second["computed_tokens"] > 0
+
+
+def test_queue_wait_histogram_counts_each_admission(served):
+    assert _delta(served, "dynamo_scheduler_queue_wait_seconds_count") == 2
+    s = _delta(served, "dynamo_scheduler_queue_wait_seconds_sum")
+    assert 0 <= s < served["t"][1] - served["t"][0]
+
+
+@pytest.mark.parametrize("phase", ["device_init", "weights", "kv_cache",
+                                   "warmup"])
+def test_startup_gauge_has_each_phase(served, phase):
+    v = _prom(served["metrics_after"], "dynamo_engine_startup_seconds",
+              '{phase="%s"}' % phase)
+    assert v is not None and v >= 0
+    assert v == pytest.approx(served["runner"].startup_s[phase])
+    assert _prom(served["metrics_after"], "dynamo_engine_xla_compiles_total",
+                 '{phase="late"') is None   # warm-up swept every shape
+
+
+@pytest.mark.parametrize("field", ["cached_tokens", "computed_tokens",
+                                   "preemptions", "decode_tokens",
+                                   "t0_monotonic"])
+def test_request_record_has_the_new_fields(served, field):
+    for rec in (served["jsonl"]["second"], served["debug_second"]):
+        assert field in rec, rec
+    rec = served["jsonl"]["second"]
+    assert rec["preemptions"] == 0
+    assert rec["decode_tokens"] == 11          # 12 tokens, the first from prefill
+    assert served["t"][0] <= rec["t0_monotonic"] <= served["t"][1]
+
+
+# ---------------------------------------------------------------------
+# scenario B: the scheduler's other decode paths, over the fake runner
+# of tests/test_decode_pipeline.py (no compile, no model)
+# ---------------------------------------------------------------------
+
+PATHS = {
+    "sync": dict(depth=1),
+    "burst": dict(depth=1, k=4),
+    "pipelined": dict(depth=2, device_finish="off"),
+    "chained": dict(depth=2),
+    "spec_sync": dict(depth=1, spec=True),
+    "spec_chained": dict(depth=2, spec=True),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def path_events(request, tmp_path_factory):
+    import test_decode_pipeline as dp
+
+    kw = dict(PATHS[request.param])
+    if kw.pop("spec", False):
+        # an 8-token vocabulary and a repetitive prompt, so that the
+        # ngram proposer has matches and the verify path runs
+        config = dp._spec_config(kw.pop("depth"))
+        reqs = [dp._request([1, 2, 1, 2, 1, 2], 24)]
+    else:
+        config = dp._config(kw.pop("depth"), k=kw.pop("k", 1), **kw)
+        reqs = [dp._request(p, 21) for p in dp.PROMPTS]
+    box = {}
+    trace_dir = str(tmp_path_factory.mktemp("path-" + request.param))
+    _capture_start(trace_dir)
+    try:
+        dp._run(config, reqs, hooks=lambda s: box.update(sched=s))
+    finally:
+        events = _capture_stop(trace_dir)
+    return request.param, events, box["sched"]
+
+
+def test_every_decode_path_writes_the_same_names(path_events):
+    path, events, sched = path_events
+    names = {e["name"] for e in events}
+    for want in ("sched.admit", "sched.prefill.build",
+                 "sched.prefill.dispatch", "sched.prefill.sync",
+                 "sched.prefill.emit", "sched.decode.build",
+                 "sched.decode.dispatch", "sched.decode.sync",
+                 "sched.decode.emit", "sync.fetch", "sched.yield"):
+        assert want in names, (path, want)
+    if path in ("pipelined", "chained", "spec_chained"):
+        assert sched.pipeline_bursts > 0, path
+    if path.startswith("spec"):
+        assert sched.spec_proposed > 0, path
+
+
+def test_every_decode_path_keeps_sched_spans_apart(path_events):
+    path, events, _ = path_events
+    tid = _loop_tid(events)
+    spans = [e for e in _named(events, "sched.") if e["tid"] == tid]
+    for a, b in zip(spans, spans[1:]):
+        assert a["end"] <= b["start"], (path, a, b)
+    # the fake runner answers in microseconds, so the few lines between
+    # two spans weigh far more than on a real engine (95 % there, above);
+    # a seam left without a span would still show
+    total = spans[-1]["end"] - spans[0]["start"]
+    assert sum(e["end"] - e["start"] for e in spans) >= 0.75 * total, path
+
+
+# ---------------------------------------------------------------------
+# the scopes and the names are metadata: same compiled code
+# ---------------------------------------------------------------------
+
+def _lower_tiny_step(strip):
+    """The tiny decode step lowered and compiled, with the named scopes
+    as they are or made inert."""
+    import contextlib
+
+    import jax
+    import numpy as np
+
+    from dynamo_tpu.engine import model_runner as mr
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+
+    real, real_tail = jax.named_scope, mr._sample_and_logprobs
+    if strip:
+        jax.named_scope = lambda name: contextlib.nullcontext()
+        mr._sample_and_logprobs = real_tail.__wrapped__   # the decorator's
+    try:
+        cfg = EngineConfig(
+            model=ModelConfig(vocab_size=256, hidden_size=32,
+                              intermediate_size=64, num_layers=2,
+                              num_heads=2, num_kv_heads=1),
+            max_batch_size=2, max_model_len=64, kv_block_size=8,
+            num_kv_blocks=16, dtype="float32", allow_random_weights=True)
+        r = mr.ModelRunner(cfg)
+        b, w = 2, cfg.kv_width_buckets()[0]
+        z2 = np.zeros((b, 1), np.int32)
+        samp = mr.SamplingParams(
+            temperature=np.zeros(b, np.float32), top_k=np.zeros(b, np.int32),
+            top_p=np.ones(b, np.float32), min_p=np.zeros(b, np.float32),
+            presence_penalty=np.zeros(b, np.float32),
+            frequency_penalty=np.zeros(b, np.float32),
+            repetition_penalty=np.ones(b, np.float32),
+            keys=np.zeros((b, 2), np.uint32), counters=np.zeros(b, np.int32))
+        lowered = r._decode_step.lower(
+            r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state,
+            z2, z2, np.zeros((b, w), np.int32), z2 - 1,
+            np.ones(b, np.int32), np.zeros(b, np.int32), samp,
+            np.arange(b, dtype=np.int32), np.zeros(b, bool),
+            np.asarray(False), z2, np.asarray(False), np.asarray(False))
+        return lowered.as_text(debug_info=True), lowered.compile()
+    finally:
+        jax.named_scope, mr._sample_and_logprobs = real, real_tail
+
+
+def test_named_scopes_change_no_compiled_code():
+    text, with_scopes = _lower_tiny_step(strip=False)
+    bare_text, without = _lower_tiny_step(strip=True)
+    for scope in ("embed", "attn", "mlp", "lm_head", "sampling"):
+        assert re.search(rf'["/]{scope}/', text), scope
+        assert not re.search(rf'["/]{scope}/', bare_text), scope
+    assert with_scopes.cost_analysis() == without.cost_analysis()
+    a, b = with_scopes.memory_analysis(), without.memory_analysis()
+    for key in ("argument_size_in_bytes", "output_size_in_bytes",
+                "temp_size_in_bytes", "generated_code_size_in_bytes"):
+        assert getattr(a, key) == getattr(b, key), key
+
+    def instructions(compiled):
+        return sorted(re.findall(r" = \S+ ([\w\-]+)\(", compiled.as_text()))
+
+    assert instructions(with_scopes) == instructions(without)
+
+
+def test_span_helper_is_a_null_context_without_jax(monkeypatch):
+    from dynamo_tpu.telemetry import tracing
+
+    monkeypatch.setattr(tracing, "_annotation", False)
+    with tracing.span("sched.admit", step=1):
+        pass
