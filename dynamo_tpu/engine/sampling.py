@@ -17,6 +17,12 @@ standard the reference's engines implement (vLLM):
 - presence_penalty: subtracted once from every token that has been generated.
 - frequency_penalty: subtracted per occurrence of a generated token.
 - min_p: after temperature scaling, tokens with prob < min_p * max_prob drop.
+
+The filters run in the order penalties → temperature → top-k → min-p →
+top-p, in float32. ``filter_logits`` does the last three with one
+values-only sort and one cutoff value a row (no argsort, no [B, V] gather
+or scatter); entries exactly equal to the cutoff are all kept, by top-p as
+by top-k.
 """
 
 from __future__ import annotations
@@ -99,6 +105,56 @@ def _row_keys(params: SamplingParams) -> jax.Array:
     return jax.vmap(fold)(params.keys, params.counters)
 
 
+def filter_logits(
+    scaled: jax.Array,  # [..., V] f32 temperature-scaled logits
+    top_k: jax.Array,   # [...] i32; 0 → disabled
+    top_p: jax.Array,   # [...] f32; 1.0 → disabled
+    min_p: jax.Array,   # [...] f32; 0.0 → disabled
+) -> jax.Array:
+    """top-k → min-p → top-p: ``scaled`` with every dropped entry at -inf.
+
+    Each filter keeps a prefix of the row sorted by descending value, so
+    the three together come to one number a row: the smallest kept
+    logit. ONE values-only sort gives the sorted row; the masks, both
+    softmaxes and the cumulative sum run on it in place; the cutoff is a
+    masked min; and the mask in vocabulary order is ``scaled >= cutoff``.
+    No argsort, no [B, V] gather and no [B, V] scatter, which a TPU does
+    one element at a time (PERF.md §6, PR 24). Entries exactly equal to
+    the cutoff are ALL kept, by top-p as by top-k. Rows of any rank:
+    ``sample`` passes [B, V], the Pallas epilogue's kernel body one row
+    [V] with 0-d parameters.
+    """
+    v = scaled.shape[-1]
+    # values alone, so stability means nothing, and asking for it makes
+    # the compiler carry an iota through the sort: 1.05 against 0.61 ms
+    # at [32, 32064] on a v5e
+    sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1, stable=False), axis=-1)
+
+    # top-k: mask everything below the k-th largest (k=0 → no-op)
+    k_idx = jnp.clip(top_k - 1, 0, v - 1)[..., None]
+    kth = jnp.take_along_axis(sorted_desc, k_idx, axis=-1)
+    kept = jnp.where(
+        (top_k[..., None] > 0) & (sorted_desc < kth), -jnp.inf, sorted_desc
+    )
+
+    # min-p: drop tokens whose prob is below min_p * max_prob. Computed on
+    # the already-top-k-masked logits, like the engines the reference wraps.
+    probs = jax.nn.softmax(kept, axis=-1)
+    kept = jnp.where(
+        probs < min_p[..., None] * probs.max(axis=-1, keepdims=True),
+        -jnp.inf, kept,
+    )
+
+    # top-p (nucleus): keep the prefix whose exclusive cumulative prob is
+    # under p (so the top token always stays); entries top-k or min-p
+    # dropped carry no probability and never set the cutoff
+    probs = jax.nn.softmax(kept, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs < top_p[..., None]) & (kept > -jnp.inf)
+    cutoff = jnp.min(jnp.where(keep, kept, jnp.inf), axis=-1, keepdims=True)
+    return jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+
+
 def sample(
     logits: jax.Array,  # [B, V] f32
     params: SamplingParams,
@@ -107,7 +163,6 @@ def sample(
     bias: Optional[jax.Array] = None,     # [B, V] f32 OpenAI logit_bias rows
 ) -> jax.Array:
     """Returns sampled token ids [B]."""
-    b, v = logits.shape
     logits = logits.astype(jnp.float32)
     if bias is not None:
         logits = logits + bias
@@ -129,29 +184,7 @@ def sample(
     temp = jnp.maximum(params.temperature, 1e-6)[:, None]
     scaled = logits / temp
 
-    # top-k: mask everything below the k-th largest (k=0 → no-op)
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # descending
-    k_idx = jnp.clip(params.top_k - 1, 0, v - 1)
-    kth = jnp.take_along_axis(sorted_logits, k_idx[:, None], axis=1)
-    topk_mask = (params.top_k[:, None] > 0) & (scaled < kth)
-    scaled = jnp.where(topk_mask, -jnp.inf, scaled)
-
-    # min-p: drop tokens whose prob is below min_p * max_prob. Computed on
-    # the already-top-k-masked logits, like the engines the reference wraps.
-    probs_all = jax.nn.softmax(scaled, axis=-1)
-    minp_mask = probs_all < params.min_p[:, None] * probs_all.max(axis=-1, keepdims=True)
-    scaled = jnp.where(minp_mask, -jnp.inf, scaled)
-
-    # top-p (nucleus): mask the tail whose cumulative prob exceeds p
-    sort_idx = jnp.argsort(scaled, axis=-1)[:, ::-1]
-    sorted_scaled = jnp.take_along_axis(scaled, sort_idx, axis=-1)
-    probs = jax.nn.softmax(sorted_scaled, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = cum - probs < params.top_p[:, None]  # always keep the top token
-    keep = jnp.zeros_like(keep_sorted).at[
-        jnp.arange(b)[:, None], sort_idx
-    ].set(keep_sorted)
-    scaled = jnp.where(keep, scaled, -jnp.inf)
+    scaled = filter_logits(scaled, params.top_k, params.top_p, params.min_p)
 
     row_keys = _row_keys(params)
     sampled = jax.vmap(lambda k, l: jax.random.categorical(k, l))(row_keys, scaled)

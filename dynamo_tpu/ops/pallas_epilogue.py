@@ -12,10 +12,12 @@ next step's launch; at chained-burst cadence the launch overhead of the
 tail is a visible slice of inter-token latency. This kernel runs the
 whole tail as one ``pallas_call`` over a batch-row grid.
 
-Bit-identity is by CONSTRUCTION, not by tolerance: the kernel body
-executes the exact jnp op sequence of ``engine/sampling.sample`` (same
-sort/argsort/cumsum/scatter calls, same masking order, same f32 math) on
-each row, and the categorical draw uses the identity
+Bit-identity is by CONSTRUCTION, not by tolerance: on each row the
+kernel body runs the penalty ops of ``engine/sampling.sample`` in the
+same order and f32 math, and then CALLS the filter that ``sample``
+calls (``engine/sampling.filter_logits``: one values-only sort, the
+top-k / min-p / top-p masks on the sorted row, a cutoff value, entries
+tied with the cutoff all kept), and the categorical draw uses the identity
 ``jax.random.categorical(key, logits) == argmax(gumbel(key, shape) +
 logits)`` (that IS jax's implementation) with the per-row gumbel noise
 precomputed OUTSIDE the kernel from the same ``_row_keys`` fold-in. In
@@ -46,6 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..engine.sampling import _HASH_P, STOP_SEQ_MAX_LEN, filter_logits
+
 LANE = 128
 
 
@@ -73,7 +77,8 @@ def _epilogue_kernel(
     if with_finish:
         hard_ref, cand_ref, rout_ref = rest
 
-    # ---- exact op-for-op mirror of engine/sampling.sample on one row ----
+    # ---- engine/sampling.sample on one row: its penalty ops mirrored,
+    # its filter called ----
     raw = logits_ref[0].astype(jnp.float32)
     rb = bias_ref[0]
     if has_extra:
@@ -95,23 +100,9 @@ def _epilogue_kernel(
     temp = jnp.maximum(fpar_ref[0, 0], 1e-6)
     scaled = logits / temp
 
-    tk = ipar_ref[0, 0]
-    sorted_desc = jnp.sort(scaled)[::-1]
-    kth = sorted_desc[jnp.clip(tk - 1, 0, v - 1)]
-    scaled = jnp.where((tk > 0) & (scaled < kth), -jnp.inf, scaled)
-
-    probs_all = jax.nn.softmax(scaled)
-    scaled = jnp.where(
-        probs_all < fpar_ref[0, 2] * probs_all.max(), -jnp.inf, scaled
+    scaled = filter_logits(
+        scaled, ipar_ref[0, 0], fpar_ref[0, 1], fpar_ref[0, 2]
     )
-
-    sort_idx = jnp.argsort(scaled)[::-1]
-    sorted_scaled = scaled[sort_idx]
-    probs = jax.nn.softmax(sorted_scaled)
-    cum = jnp.cumsum(probs)
-    keep_sorted = cum - probs < fpar_ref[0, 1]
-    keep = jnp.zeros((v,), jnp.bool_).at[sort_idx].set(keep_sorted)
-    scaled = jnp.where(keep, scaled, -jnp.inf)
 
     # categorical(key, l) IS argmax(gumbel(key) + l); the gumbel row was
     # drawn outside from the identical _row_keys fold-in
@@ -196,8 +187,6 @@ def fused_sampling_epilogue(
     lps [B] f32, counts)`` — plus ``(hard [B] bool, cand [B] bool,
     ring_new [B, W])`` when ``finish`` is given. Token/logprob values are
     bit-identical to the unfused ``sample`` + ``log_softmax`` ladder."""
-    from ..engine.sampling import _HASH_P, STOP_SEQ_MAX_LEN
-
     b, v = last_logits.shape
     ns = counts.shape[0]
     has_extra = extra_bias is not None
