@@ -4,18 +4,27 @@ The manifest at the root of the checkout is the single list of cells,
 configurations and metrics. Everything that belongs to one of them sits
 in a file of its own, found here by name, so that a later PR adds a
 cell, a mix, a configuration or a metric as new files plus one manifest
-entry and edits nothing that exists.
+entry and edits nothing that exists. An architecture comes the same way:
+what is specific to it is code (its plain reference, what its attention
+must read and multiply), so a configuration's file names a module for
+each, and a new architecture is new modules.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import os
+import re
 from typing import Any, Dict, List
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+# key of a configuration's file -> the directory its module is found in
+ARCHITECTURE_MODULES = {"reference": "references",
+                        "attention_cost": "attention_costs"}
+MODULE_NAME = re.compile(r"^[A-Za-z][A-Za-z0-9_]{0,63}$")
 
 
 class ManifestError(Exception):
@@ -41,6 +50,31 @@ def _entry(entries: List[dict], name: str, what: str) -> dict:
     raise ManifestError(
         f"BENCHMARK.json has no {what} named {name!r} "
         f"(it has: {', '.join(e['name'] for e in entries)})")
+
+
+def module_names(directory: str) -> List[str]:
+    return sorted(f[:-3] for f in os.listdir(os.path.join(BENCH_DIR, directory))
+                  if f.endswith(".py") and MODULE_NAME.match(f[:-3]))
+
+
+def architecture_module_name(config: Dict[str, Any], config_name: str,
+                             key: str) -> str:
+    """``<directory>.<name>`` of the module a configuration's file names
+    under ``key``. No name, or a name with no file, is an error and
+    never a default."""
+    directory = ARCHITECTURE_MODULES[key]
+    name, there = config.get(key), module_names(directory)
+    if name not in there:
+        raise ManifestError(
+            f"configuration {config_name!r}: its file has to name a module of "
+            f"benchmark/{directory}/ under {key!r} and gives {name!r} "
+            f"(there are: {', '.join(there)})")
+    return f"{directory}.{name}"
+
+
+def architecture_module(config: Dict[str, Any], config_name: str, key: str):
+    return importlib.import_module(
+        architecture_module_name(config, config_name, key))
 
 
 def _in_cell(metric: dict, cell: str) -> bool:
@@ -111,9 +145,12 @@ def load_cell(name: str, root: str = ROOT, rehearsal: bool = False) -> Cell:
         raise ManifestError(
             f"cell {name!r} lists per-layer metrics {adrift} but does not "
             "report the end-to-end metric they move")
+    config = _load_json(os.path.join(root, c["file"]))
+    for key in ARCHITECTURE_MODULES:     # refused before anything is served
+        architecture_module_name(config, w["config"], key)
     return Cell(
         name=name, chips=int(w["chips"]), cell=cell,
-        config_name=w["config"], config=_load_json(os.path.join(root, c["file"])),
+        config_name=w["config"], config=config,
         traffic_name=w["traffic"],
         traffic=_overlaid(_load_json(os.path.join(
             BENCH_DIR, "traffic", w["traffic"] + ".json")), rehearsal),

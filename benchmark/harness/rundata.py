@@ -26,6 +26,7 @@ class RunData:
     device_trace: Any = None           # harness.trace.DeviceTrace
     trace_slice: Optional[Tuple[float, float]] = None   # monotonic, of the capture
     device_kind: str = ""
+    cache_itemsize: int = 2            # bytes of one element of the served cache
 
     @classmethod
     def from_client(cls, got: dict, **fields) -> "RunData":

@@ -17,6 +17,7 @@ import os
 import socket
 from typing import Any, Dict, Tuple
 
+from .manifest import ARCHITECTURE_MODULES
 from .modeldir import write_model_dir
 
 # what a configuration's "serve" group may set, and where each goes
@@ -25,6 +26,7 @@ CLI_SETTINGS = {
     "max_batch_size": "--max-batch-size",
     "num_kv_blocks": "--num-kv-blocks",
     "tensor_parallel_size": "--tensor-parallel-size",
+    "expert_parallel_size": "--expert-parallel-size",
 }
 ENGINE_SETTINGS = ("prefill_buckets", "max_prefill_tokens_per_step",
                    "max_prefill_batch")
@@ -35,8 +37,8 @@ PERFORMANCE_SWITCHES = (
     "dtype",
 )
 # keys of a configuration's file that are not the model's published config
-NOT_MODEL_KEYS = ("serve", "assumed", "source", "stands_for", "notes",
-                  "rehearsal")
+NOT_MODEL_KEYS = ("serve", "assumed", "reduced", "source", "stands_for",
+                  "notes", "rehearsal", *ARCHITECTURE_MODULES)
 
 
 def hf_config_of(config: Dict[str, Any]) -> Dict[str, Any]:
@@ -59,11 +61,15 @@ def build_flags(config: Dict[str, Any], name: str, work_dir: str, seed: int,
     extra: Dict[str, Any] = {"seed": int(seed)}
     if rehearsal:
         # the CPU rehearsal overlays tiny widths and interpret-mode
-        # kernels; it prints DRY RUN and never a result (run.py)
+        # kernels; it prints DRY RUN and never a result (run.py). A
+        # family whose kernels take no notice of DYN_PALLAS_INTERPRET
+        # (the latent cache's decode kernel) rehearses on the route its
+        # group names: "attention_impl": "auto", the CPU's XLA route
         over = config.get("rehearsal", {})
         hf.update(over.get("model", {}))
         serve.update(over.get("serve", {}))
-        extra.update({"attention_impl": "pallas", "dtype": "float32"})
+        extra.update({"attention_impl": over.get("attention_impl", "pallas"),
+                      "dtype": "float32"})
     bad = [k for k in serve if k in PERFORMANCE_SWITCHES]
     unknown = [k for k in serve
                if k not in CLI_SETTINGS and k not in ENGINE_SETTINGS]

@@ -37,6 +37,8 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
+# the program's own spans that can hold an idle gap (docs/observability.md)
+PROGRAM_SPANS = ("sched.", "sync.", "dispatch.")
 
 
 @dataclasses.dataclass
@@ -160,13 +162,23 @@ def _program(name: str) -> str:
 
 
 def idle_gaps(trace: DeviceTrace, device: int, n: int = 5) -> List[list]:
-    """The n longest stretches in which no operation ran on the device.
-    The program puts no spans of its own into the profiler's trace yet,
-    so a gap is labelled by what the trace does have: the programs that
-    ran before and after it, and the runtime's host span that took most
-    time inside it (``python`` where the runtime was not in a span: the
-    scheduler's own code)."""
+    """The n longest stretches in which no operation ran on the device,
+    each labelled by the programs that ran before and after it and by
+    what the host was doing in it: the program's own span with most time
+    inside the gap (``sched.*``, ``sync.*``, ``dispatch.*``:
+    ``telemetry/tracing.span``) where one overlaps it, else the
+    runtime's host event name with most time inside it. The runtime's
+    names are summed over threads, and on four chips several threads
+    write the same one, so they come second: they would outweigh the one
+    scheduler span that holds the gap. ``python`` where the host was in
+    no such span: the scheduler's own code, or a program from before the
+    spans. The device planes of a capture run on for a tenth of a second
+    after the host plane's last event, so gaps are looked for only where
+    the host is on record: past that, every gap would read ``python``."""
     t0, t1 = trace.window
+    if trace.host:
+        t0 = max(t0, min(h.start for h in trace.host))
+        t1 = min(t1, max(h.start + h.dur for h in trace.host))
     busy = [(o.start, o.start + o.dur) for o in trace.ops[device]]
     gaps = sorted(gaps_of(busy, t0, t1), key=lambda g: g[0] - g[1])[:n]
     # helper programs of a few microseconds (a dtype cast) say nothing
@@ -175,11 +187,14 @@ def idle_gaps(trace: DeviceTrace, device: int, n: int = 5) -> List[list]:
     for s, e in gaps:
         before = [m for m in mods if m.start + m.dur <= s + 1e-9]
         after = [m for m in mods if m.start >= e - 1e-9]
-        inside: Dict[str, float] = {}
+        own: Dict[str, float] = {}
+        runtime: Dict[str, float] = {}
         for h in trace.host:
             c = min(e, h.start + h.dur) - max(s, h.start)
             if c > 0:
+                inside = own if h.name.startswith(PROGRAM_SPANS) else runtime
                 inside[h.name] = inside.get(h.name, 0.0) + c
+        inside = own or runtime
         host = max(inside, key=inside.get) if inside else "python"
         if inside and inside[host] < 0.25 * (e - s):
             host = "python, then " + host

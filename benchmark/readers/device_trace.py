@@ -6,13 +6,18 @@ different shapes, so both are ``jit_step(<fingerprint>)`` in the trace.
 A metric's file gives ``with_op``, a regular expression on operation
 names; a program execution belongs to the metric when an operation that
 matches ran inside it.
+
+What the attention of a configuration must read and multiply is not
+known here: the configuration's file names a module of
+``benchmark/attention_costs`` for it. This reader keeps the trace's
+side: which executions, the kernel's time, the peaks, the division.
 """
 
 from __future__ import annotations
 
 import re
 
-from harness import opsbytes
+from harness.manifest import architecture_module
 from harness.peaks import peaks_for
 from harness.rundata import RunData, failed
 
@@ -54,19 +59,23 @@ def _slice_requests(run: RunData):
             if not failed(r) and s0 <= r["token_times"][0] <= s1]
 
 
-def _mean_attended_keys(run: RunData, window) -> float:
-    """Time-average over the slice of the keys one decode step attends:
-    the sum over running sequences of min(context, window)."""
+def _mean_decode_step_bytes(run: RunData, cost) -> float:
+    """Time-average over the slice of the bytes one decode step must
+    read on one device: at each of 64 instants, ``cost``'s answer for
+    the contexts of the sequences running then."""
     s0, s1 = run.trace_slice
-    n, total = 64, 0.0
+    n, total = 64, 0
     for k in range(n):
         t = s0 + (k + 0.5) * (s1 - s0) / n
+        contexts = []
         for r in run.records:
             times = r["token_times"]
             if not times or not times[0] <= t <= times[-1]:
                 continue
             emitted = sum(c for tt, c in zip(times, r["chunk_tokens"]) if tt <= t)
-            total += opsbytes.attended(r["prompt_tokens"] + emitted, window)
+            contexts.append(r["prompt_tokens"] + emitted)
+        total += cost.decode_step_bytes(run.hf, _tp(run), run.cache_itemsize,
+                                        contexts)
     return total / n
 
 
@@ -113,23 +122,16 @@ def read(run: RunData, args: dict):
 
     peaks = peaks_for(run.device_kind)
     kernel_s = sum(o.own for o in kernel_ops)
-    hf, tp = run.hf, _tp(run)
-    heads = int(hf["num_attention_heads"])
-    kv_heads = int(hf.get("num_key_value_heads", heads))
-    head_dim = int(hf.get("head_dim") or hf["hidden_size"] // heads)
-    layers = int(hf["num_hidden_layers"])
-    window = int(hf.get("sliding_window") or 0) or None
     if not kernel_s:
         return None
+    cost = architecture_module(run.cell.config, run.cell.config_name,
+                               "attention_cost")
     if stat == "decode_kernel_roofline_pct":     # HBM-bound
-        keys = _mean_attended_keys(run, window)
-        per_step = opsbytes.decode_attention_bytes(
-            [keys], max(1, kv_heads // tp), head_dim, layers, None)
+        per_step = _mean_decode_step_bytes(run, cost)
         least_s = len(mods) * per_step / peaks["hbm_bytes_per_s"]
         return 100.0 * least_s / kernel_s, len(mods)
     if stat == "prefill_kernel_roofline_pct":    # FLOP-bound
-        flops = opsbytes.prefill_attention_flops(
-            _computed_chunks(run), max(1, heads // tp), head_dim, layers, window)
+        flops = cost.prefill_flops(run.hf, _tp(run), _computed_chunks(run))
         least_s = flops / peaks["flops_bf16"]
         return 100.0 * least_s / kernel_s, len(mods)
     raise ValueError(f"device_trace reader: unknown stat {stat!r}")

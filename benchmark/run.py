@@ -110,6 +110,9 @@ def _route_rows(samples: dict) -> dict:
 async def amain(args) -> int:
     rehearsal = args.cpu_rehearsal
     cell = manifest.load_cell(args.workload, rehearsal=rehearsal)
+    ref_name = cell.config["reference"]
+    reference = manifest.architecture_module(cell.config, cell.config_name,
+                                             "reference")
     work = os.path.join(WORK, cell.name)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
@@ -155,25 +158,29 @@ async def amain(args) -> int:
 
     got, t0, trace_slice = await drive(plan, work, on_window)
 
-    # the reference, on the chip, after the drain and outside the window
+    # the reference the configuration names, on the chip, after the drain
+    # and outside the window
     from harness.reference import check_probes
 
     t_ref = time.monotonic()
     ref = await loop.run_in_executor(
-        None, check_probes, runner.params, hf, got["probes"], token_id)
-    say(f"reference: {ref['tokens_compared']} tokens, max |dlogp| "
-        f"{ref['max_abs_err']:.5f}, mean {ref['mean_abs_err']:.5f} "
+        None, check_probes, reference, runner.params, hf, got["probes"], token_id)
+    say(f"reference: {ref_name}, {ref['tokens_compared']} tokens, max |dlogp| "
+        f"{ref['max_abs_err']:.5f} (limit {reference.LOGPROB_ATOL:g}), mean "
+        f"{ref['mean_abs_err']:.5f} (limit {reference.LOGPROB_MEAN_ATOL:g}) "
         f"({time.monotonic() - t_ref:.1f} s){'' if ref['ok'] else ' FAILED: ' + '; '.join(ref['reasons'])}")
 
     stats = [d.memory_stats() or {} for d in runner.mesh.devices.flat]
     peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
     in_use = [s.get("bytes_in_use") for s in stats]
+    cache_itemsize = runner.kv_cache[0].dtype.itemsize
     await server.stop(serving)
     await engine.core_engine.close()
 
     run = RunData.from_client(
         got, cell=cell, hf=hf, serve=vars(flags), seconds=args.seconds,
         setup_seconds=t0 - T_START, trace_slice=trace_slice, device_kind=kind,
+        cache_itemsize=cache_itemsize,
         request_traces=_read_jsonl(trace_jsonl) if args.trace else {})
     device = {"platform": platform, "kind": kind, "count": cell.chips,
               "memory_peak_bytes": peak}
@@ -231,8 +238,8 @@ async def amain(args) -> int:
               "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
-    result["reference"] = {k: ref[k] for k in
-                           ("max_abs_err", "mean_abs_err", "tokens_compared")}
+    result["reference"] = {"name": ref_name, **{k: ref[k] for k in (
+        "max_abs_err", "mean_abs_err", "tokens_compared")}}
     result["compiles_in_window"] = late
     if rehearsal:
         say("DRY RUN " + json.dumps(result))

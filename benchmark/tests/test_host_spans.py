@@ -3,6 +3,7 @@ arithmetic on hand-made traces, then a cut of a traced v5e run of PR 23
 that holds the spans (``data/v5e-spans.*``; how it was cut is in
 ``data/v5e-spans.expected.json``)."""
 
+import dataclasses
 import json
 import os
 
@@ -197,3 +198,42 @@ def test_recorded_gaps_are_labelled_by_the_programs_spans(recorded_spans):
     assert all(": sched." in label or ": dispatch." in label
                for label, _ in gaps), gaps
     assert all("jit_decode_step(" in label for label, _ in gaps), gaps
+
+
+def test_a_gap_goes_to_the_programs_span_before_the_runtimes_threads():
+    """On four chips several runtime threads write an event of one name
+    inside a gap; summed, they outweigh the one scheduler span that
+    holds it. The program's span is the label all the same."""
+    runtime = [("PjitFunction(decode_step)", 3.1, 4.9)] * 4
+    host = runtime + [("sched.decode.dispatch", 3.0, 5.0), ("dispatch.decode", 3.2, 4.8),
+                      ("sched.yield", 6.0, 6.5)]
+    gaps = trace.idle_gaps(_trace(OPS, host), 0, 3)
+    assert [round(s, 6) for _, s in gaps] == [3.0, 2.0, 1.0]
+    # 6-9: the span with most time inside covers a sixth of it
+    assert gaps[0][0].endswith(": python, then sched.yield")
+    assert gaps[1][0].endswith(": sched.decode.dispatch")
+    assert gaps[2][0].endswith(": python")                      # 1-2: nothing on record
+    # a program from before the spans keeps the runtime's label
+    marks = [("ThreadpoolListener", 0.0, 0.0), ("ThreadpoolListener", 10.0, 10.0)]
+    gaps = trace.idle_gaps(_trace(OPS, runtime + marks), 0, 3)
+    assert gaps[1][0].endswith(": PjitFunction(decode_step)")
+    # gaps are looked for where the host is on record: the device planes
+    # run on after the host plane's last event, and nothing could name that
+    gaps = trace.idle_gaps(_trace(OPS, host, edges=False), 0, 3)   # host 3.0-6.5
+    assert [(label.split(": ")[1], round(s, 6)) for label, s in gaps] == [
+        ("sched.decode.dispatch", 2.0), ("sched.yield", 0.5)]
+
+
+def test_recorded_gaps_keep_the_programs_spans_under_more_runtime_threads(recorded_spans):
+    """The recorded one-chip capture with every host event that is not
+    the scheduler's written four times over (the runtime's, as the
+    threads of a four-chip host write them, and the frontend's leaves):
+    every gap is still labelled by the scheduler's span that holds it.
+    Summed by name alone, they took the label."""
+    t, _ = recorded_spans
+    runtime = [h for h in t.host if not h.name.startswith(trace.PROGRAM_SPANS)]
+    assert runtime
+    crowded = dataclasses.replace(t, host=t.host + 3 * runtime)
+    want = trace.idle_gaps(t, 0, 5)
+    assert trace.idle_gaps(crowded, 0, 5) == want
+    assert all(": sched." in label or ": dispatch." in label for label, _ in want), want

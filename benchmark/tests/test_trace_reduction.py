@@ -6,16 +6,20 @@ from the protobuf by a throw-away script, not by this code."""
 
 import json
 import os
+import shutil
 
 import pytest
 
-from harness import trace
+import attention_costs
+from harness import manifest, trace
 from harness.manifest import Cell
 from harness.rundata import RunData
 from readers import device_trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 NAME = "v5e-decode-prefill"
+# the configuration's file names the module that knows its attention's shape
+K_AND_V = {"attention_cost": "per_head_kv"}
 
 
 @pytest.fixture(scope="module")
@@ -72,7 +76,7 @@ def test_metrics_from_the_recorded_trace(recorded):
     t, want = recorded
     hf = {"num_attention_heads": 32, "num_key_value_heads": 32, "hidden_size": 3072,
           "num_hidden_layers": 32, "sliding_window": 2047}
-    cell = Cell("c", 1, {}, "k", {}, "m", {"drain_s": 1}, [], [])
+    cell = Cell("c", 1, {}, "k", K_AND_V, "m", {"drain_s": 1}, [], [])
     # one sequence of 1000 tokens decoding all through the capture
     rec = {"rid": "a", "group": "", "phase": "window", "due": 0.0, "send": 0.0,
            "prompt_tokens": 1000, "max_tokens": 8, "prefix_tokens": 0,
@@ -107,7 +111,7 @@ def test_prefill_metrics_on_a_shard_of_a_model_without_a_window(recorded):
     t, want = recorded
     hf = {"num_attention_heads": 32, "num_key_value_heads": 8, "hidden_size": 4096,
           "num_hidden_layers": 32, "sliding_window": None}
-    cell = Cell("c", 4, {}, "k", {}, "m", {"drain_s": 1}, [], [])
+    cell = Cell("c", 4, {}, "k", K_AND_V, "m", {"drain_s": 1}, [], [])
     rec = {"rid": "a", "group": "", "phase": "window", "due": 0.0, "send": 0.0,
            "prompt_tokens": 300, "max_tokens": 8, "prefix_tokens": 0,
            "token_times": [1.5, 3.0], "chunk_tokens": [1, 1],
@@ -127,3 +131,80 @@ def test_prefill_metrics_on_a_shard_of_a_model_without_a_window(recorded):
     flops = 4 * (300 * 301 // 2) * 8 * 128 * 32
     assert share == pytest.approx(100 * flops / 197e12 / want["flash_kernel_s"], rel=1e-4)
     assert device_trace.read(run, {"stat": "op_share_of_busy_pct", "op": "all-reduce"}) == 0.0
+
+
+# ---- the reader against its parent, and against another cost module ----
+
+with open(os.path.join(DATA, NAME + ".readers.expected.json")) as _f:
+    PARENT = json.load(_f)
+
+
+def _case_run(t, case, config=K_AND_V):
+    tp = case["tensor_parallel_size"]
+    cell = Cell("c", tp, {}, "k", config, "m", {"drain_s": 1}, [], [])
+    return RunData(cell=cell, hf=case["hf"], serve={"tensor_parallel_size": tp},
+                   seconds=1.0, window=(0.0, 10.0), setup_seconds=0.0,
+                   records=case["records"], prom_start={}, prom_end={},
+                   device_trace=t, trace_slice=tuple(PARENT["trace_slice"]),
+                   device_kind="TPU v5 lite")
+
+
+@pytest.mark.parametrize("stat", sorted(PARENT["stats"]))
+@pytest.mark.parametrize("case", sorted(PARENT["cases"]))
+def test_the_recorded_trace_reduces_as_it_did_before_the_cost_moved(recorded, case, stat):
+    """Every statistic of the reader, to the last digit, as the parent
+    of PR 25 computed it with the shape knowledge inside the reader."""
+    t, _ = recorded
+    case = PARENT["cases"][case]
+    got = device_trace.read(_case_run(t, case), PARENT["stats"][stat])
+    want = case["parent"][stat]
+    if stat == "decode_kernel_roofline_pct":
+        # the one difference: the parent cut the averaged keys to a whole
+        # number; the bytes are now averaged as they are (0 to 1 key more)
+        keys = case["parent"]["mean_attended_keys"]
+        want = [want[0] * keys / int(keys), want[1]]
+        if keys == int(keys):
+            assert list(got) == want
+        assert list(got) == pytest.approx(want, rel=1e-12)
+    else:
+        assert (list(got) if isinstance(got, tuple) else got) == want
+
+
+THROWAWAY_COST = '''
+from attention_costs import per_head_kv
+
+def decode_step_bytes(hf, tensor_parallel_size, cache_itemsize, context_lens):
+    return per_head_kv.decode_step_bytes(
+        hf, tensor_parallel_size, cache_itemsize, context_lens) // 2
+
+def prefill_flops(hf, tensor_parallel_size, chunks):
+    return 3 * per_head_kv.prefill_flops(hf, tensor_parallel_size, chunks)
+'''
+
+
+def test_the_roofline_shares_follow_the_cost_module_the_configuration_names(
+        recorded, tmp_path, monkeypatch):
+    """A module that charges half the bytes halves the decode kernel's
+    share; the trace's side (executions, kernel time, peak) is the same."""
+    t, _ = recorded
+    # the benchmark's directory of cost modules, with one more file in it
+    there = tmp_path / "attention_costs"
+    shutil.copytree(attention_costs.__path__[0], there,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (there / "throwaway_half.py").write_text(THROWAWAY_COST)
+    monkeypatch.setattr(manifest, "BENCH_DIR", str(tmp_path))
+    monkeypatch.setattr(attention_costs, "__path__",
+                        [*attention_costs.__path__, str(there)])
+    case = PARENT["cases"]["many_sequences_window_one_chip"]
+    half = {"attention_cost": "throwaway_half"}
+    for stat, factor in (("decode_kernel_roofline_pct", 0.5),
+                         ("prefill_kernel_roofline_pct", 3.0)):
+        args = PARENT["stats"][stat]
+        full, n = device_trace.read(_case_run(t, case), args)
+        got, n_half = device_trace.read(_case_run(t, case, half), args)
+        assert n_half == n and got == pytest.approx(factor * full, rel=1e-6)
+    # the statistics that need no shape never ask for the module
+    assert device_trace.read(_case_run(t, case, {}), PARENT["stats"]["idle_pct"]) > 0
+    # and a configuration that names none is refused where one is needed
+    with pytest.raises(manifest.ManifestError, match="attention_cost"):
+        device_trace.read(_case_run(t, case, {}), PARENT["stats"]["decode_kernel_roofline_pct"])

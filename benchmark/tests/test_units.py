@@ -7,7 +7,8 @@ import re
 
 import pytest
 
-from harness import manifest, opsbytes, peaks, prom, stats, traffic
+from attention_costs import per_head_kv
+from harness import manifest, peaks, prom, stats, traffic
 from harness.modeldir import token_id, token_text
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -91,18 +92,54 @@ def test_a_distribution_can_be_a_mixture():
 
 def test_ops_and_bytes_know_window_and_lane_padding():
     # head 96 is stored in rows of 128 lanes, and a row is read whole
-    assert opsbytes.lane_padded(96) == 128 and opsbytes.lane_padded(128) == 128
-    one = opsbytes.decode_attention_bytes([1000], 32, 96, 32)
+    assert per_head_kv.lane_padded(96) == 128 and per_head_kv.lane_padded(128) == 128
+    one = per_head_kv.decode_attention_bytes([1000], 32, 96, 32)
     assert one == 2 * 1000 * 32 * 128 * 2 * 32          # 0.5 MiB a token
-    assert opsbytes.decode_attention_bytes([5000], 32, 96, 32, window=2047) \
+    assert per_head_kv.decode_attention_bytes([5000], 32, 96, 32, window=2047) \
         == 2 * 2047 * 32 * 128 * 2 * 32
-    full = opsbytes.prefill_attention_flops([(0, 4)], 2, 8, 1)
+    full = per_head_kv.prefill_attention_flops([(0, 4)], 2, 8, 1)
     assert full == 4 * (1 + 2 + 3 + 4) * 2 * 8
-    assert opsbytes.prefill_attention_flops([(0, 4)], 2, 8, 1, window=2) \
+    assert per_head_kv.prefill_attention_flops([(0, 4)], 2, 8, 1, window=2) \
         == 4 * (1 + 2 + 2 + 2) * 2 * 8
     # a cached prefix is attended to, not recomputed
-    assert opsbytes.prefill_attention_flops([(2, 2)], 2, 8, 1) \
+    assert per_head_kv.prefill_attention_flops([(2, 2)], 2, 8, 1) \
         == 4 * (3 + 4) * 2 * 8
+
+
+MHA_96_WINDOW = {"num_attention_heads": 32, "num_key_value_heads": 32,
+                 "hidden_size": 3072, "num_hidden_layers": 32,
+                 "sliding_window": 2047}
+GQA_128 = {"num_attention_heads": 32, "num_key_value_heads": 8, "head_dim": 128,
+           "hidden_size": 4096, "num_hidden_layers": 32, "sliding_window": None}
+
+
+@pytest.mark.parametrize("hf, tp, itemsize, contexts, want", [
+    # the whole shape comes from the published keys: head 3072 / 32 = 96 -> 128
+    (MHA_96_WINDOW, 1, 2, [1000], 2 * 1000 * 32 * 128 * 2 * 32),
+    # each sequence is cut to the window on its own; no sequence, no bytes
+    (MHA_96_WINDOW, 1, 2, [5000, 100], 2 * (2047 + 100) * 32 * 128 * 2 * 32),
+    (MHA_96_WINDOW, 1, 2, [], 0),
+    # a device holds its share of the kv heads, and never less than one
+    (GQA_128, 4, 2, [1000, 24], 2 * 1024 * 2 * 128 * 2 * 32),
+    (GQA_128, 16, 2, [1000], 2 * 1000 * 1 * 128 * 2 * 32),
+    # an 8-bit cache is half the bytes
+    (GQA_128, 4, 1, [1000], 2 * 1000 * 2 * 128 * 1 * 32),
+])
+def test_the_per_head_cost_module_reads_k_and_v_of_a_devices_heads(
+        hf, tp, itemsize, contexts, want):
+    assert per_head_kv.decode_step_bytes(hf, tp, itemsize, contexts) == want
+
+
+@pytest.mark.parametrize("hf, tp, chunks, want", [
+    (MHA_96_WINDOW, 1, [(0, 4)], 4 * (1 + 2 + 3 + 4) * 32 * 96 * 32),
+    # the window bounds the keys a query multiplies
+    (MHA_96_WINDOW, 1, [(3000, 2)], 4 * (2047 + 2047) * 32 * 96 * 32),
+    # a device's share of the query heads; a cached prefix is attended, not recomputed
+    (GQA_128, 4, [(2, 2), (0, 1)], 4 * (3 + 4 + 1) * 8 * 128 * 32),
+    (GQA_128, 4, [], 0),
+])
+def test_the_per_head_cost_module_multiplies_a_devices_heads(hf, tp, chunks, want):
+    assert per_head_kv.prefill_flops(hf, tp, chunks) == want
 
 
 def test_peaks_refuse_an_unknown_device():
@@ -145,6 +182,12 @@ def test_manifest_names_files_and_keeps_code_free_of_names():
         for c in m.get("workloads", cells):
             moved = next(x for x in man["end_to_end"] if x["name"] == m["moves"])
             assert c in moved.get("workloads", cells), (m["name"], c)
+        # a metric that tells programs apart by a kernel inside them has
+        # something to read only where that kernel runs: it lists its
+        # cells, so that a configuration with other kernels can come
+        spec = json.load(open(os.path.join(
+            manifest.BENCH_DIR, "layer_metrics", m["name"] + ".json")))
+        assert "with_op" not in spec.get("args", {}) or "workloads" in m, m["name"]
     for n in names | {w["traffic"] for w in man["workloads"]}:
         assert NAME.match(n), n
     for m in man["end_to_end"]:
@@ -161,18 +204,57 @@ def test_manifest_names_files_and_keeps_code_free_of_names():
     assert listed("configs") == {c["name"] for c in man["configs"]}
     assert listed("traffic") >= {w["traffic"] for w in man["workloads"]}
     assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(1, len(cells) // 4)
+    # every configuration names a module that is there for each
+    # architecture-specific part
+    modules = set()
+    for key, d in manifest.ARCHITECTURE_MODULES.items():
+        there = manifest.module_names(d)
+        assert there and all(NAME.match(n) for n in there)
+        modules |= set(there)
+        for c in man["configs"]:
+            assert json.load(open(os.path.join(manifest.ROOT, c["file"])))[key] in there
     # the harness is driven by data: no cell, configuration or metric is
-    # named in the code
-    code = [os.path.join(manifest.BENCH_DIR, "run.py")]
-    for d in ("harness", "readers", "generators"):
-        code += [os.path.join(manifest.BENCH_DIR, d, f)
-                 for f in os.listdir(os.path.join(manifest.BENCH_DIR, d))
-                 if f.endswith(".py")]
-    for path in code:
+    # named in the code, and no architecture's module in the code that
+    # finds it by name
+    def python_files(*dirs):
+        return [os.path.join(b, d, f) for d in dirs
+                for f in os.listdir(os.path.join(b, d)) if f.endswith(".py")]
+
+    finders = [os.path.join(b, "run.py"),
+               *python_files("harness", "readers", "generators")]
+    for path in finders + python_files(*manifest.ARCHITECTURE_MODULES.values()):
         text = open(path).read()
-        for n in names:
+        for n in names | (modules if path in finders else set()):
             assert not re.search(r"(?<![A-Za-z0-9_])" + re.escape(n) + r"(?![A-Za-z0-9_])",
                                  text), (n, path)
+
+
+@pytest.mark.parametrize("key", sorted(manifest.ARCHITECTURE_MODULES))
+@pytest.mark.parametrize("given", ["missing", "unknown", "a_path"])
+def test_a_configuration_has_to_name_its_architectures_modules(tmp_path, key, given):
+    """No default: a configuration whose file does not name a module
+    that is there, for its reference or for its attention's cost, is
+    refused with the key and the names that exist."""
+    man = manifest.load_manifest()
+    w = man["workloads"][0]
+    c = next(c for c in man["configs"] if c["name"] == w["config"])
+    config = json.load(open(os.path.join(manifest.ROOT, c["file"])))
+    if given == "missing":
+        del config[key]
+    else:
+        config[key] = {"unknown": "no_such_module", "a_path": "../harness/stats"}[given]
+    c["file"] = "config.json"
+    json.dump(config, open(tmp_path / "config.json", "w"))
+    json.dump(man, open(tmp_path / "BENCHMARK.json", "w"))
+    there = manifest.module_names(manifest.ARCHITECTURE_MODULES[key])
+    with pytest.raises(manifest.ManifestError) as e:
+        manifest.load_cell(w["name"], root=str(tmp_path))
+    assert repr(key) in str(e.value) and all(n in str(e.value) for n in there)
+    assert w["config"] in str(e.value)
+    # and with the name in place the same files load
+    config[key] = there[0]
+    json.dump(config, open(tmp_path / "config.json", "w"))
+    assert manifest.load_cell(w["name"], root=str(tmp_path)).config[key] == there[0]
 
 
 def test_a_per_layer_metric_listed_where_its_target_is_not_reported_is_refused(tmp_path):
