@@ -31,6 +31,7 @@ from ..telemetry.flight import CompileTracker
 from ..telemetry.registry import Counter
 from .config import EngineConfig
 from .device import check_serving_device
+from . import step_inputs
 from .sampling import SamplingParams, sample, top_logprobs_for
 
 logger = logging.getLogger(__name__)
@@ -583,10 +584,12 @@ class ModelRunner:
         moe = self.moe_counts is not None
         forward, head = self._make_forward(counted=moe)
 
-        def step(params, k_cache, v_cache, counts, seen, bias, tokens,
-                 positions, block_tables, slot_mapping, context_lens,
-                 last_idx, samp, sample_slots, commit, want_top,
-                 targets, want_prompt, want_greedy, *moe_counts):
+        def step(s, params, k_cache, v_cache, counts, seen, bias, packed,
+                 *moe_counts):
+            # every per-pass input arrives in one array (step_inputs.py)
+            (tokens, positions, block_tables, slot_mapping, context_lens,
+             last_idx, samp, sample_slots, commit, want_top, targets,
+             want_prompt, want_greedy) = step_inputs.unpack(packed, s)
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), tokens, positions,
                 block_tables, slot_mapping, context_lens,
@@ -643,26 +646,21 @@ class ModelRunner:
                 # when /metrics is rendered
                 row = jnp.concatenate(
                     [moe_step[0], jnp.ones((1,), jnp.int32)])
-                out += (moe_counts[0].at[int(tokens.shape[1] > 1)].add(row),)
+                out += (moe_counts[0].at[int(s > 1)].add(row),)
             return out
 
-        samp_spec = SamplingParams(
-            temperature=batch_spec, top_k=batch_spec, top_p=batch_spec,
-            min_p=batch_spec, presence_penalty=batch_spec,
-            frequency_penalty=batch_spec, repetition_penalty=batch_spec,
-            keys=batch2_spec, counters=batch_spec,
-        )
         # one body, two names: the profiler's trace and the HLO dump
         # then read jit_decode_step(...) and jit_prefill_step(...)
-        # (step() picks by S, as its CompileTracker label does)
+        # (step() picks by S, as its CompileTracker label does). The
+        # packed array's width is F + W + 4·S, so prefill takes S as a
+        # static argument; decode's is 1
         def decode_step(*args):
-            return step(*args)
+            return step(1, *args)
 
-        def prefill_step(*args):
-            return step(*args)
+        def prefill_step(s, *args):
+            return step(s, *args)
 
         jit_kw = dict(
-            donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=(
                 self.param_shardings,        # params
                 self.cache_sharding,         # k
@@ -670,19 +668,7 @@ class ModelRunner:
                 self.state_sharding,         # counts
                 self.state_sharding,         # seen
                 self.state_sharding,         # bias
-                batch2_spec,                 # tokens [B, S]
-                batch2_spec,                 # positions
-                batch2_spec,                 # block_tables
-                batch2_spec,                 # slot_mapping
-                batch_spec,                  # context_lens
-                batch_spec,                  # last_idx
-                samp_spec,                   # SamplingParams pytree
-                batch_spec,                  # sample_slots
-                batch_spec,                  # commit
-                repl,                        # want_top scalar
-                batch2_spec,                 # targets [B, S]
-                repl,                        # want_prompt scalar
-                repl,                        # want_greedy scalar
+                batch2_spec,                 # packed [B, F + W + 4·S]
             ) + ((repl,) if moe else ()),    # routed experts' counters
             out_shardings=(batch_spec, batch_spec, batch2_spec, batch2_spec,
                            batch2_spec, batch2_spec,
@@ -690,8 +676,12 @@ class ModelRunner:
                            self.state_sharding, self.state_sharding,
                            self.state_sharding) + ((repl,) if moe else ()),
         )
-        self._decode_step = jax.jit(decode_step, **jit_kw)
-        self._prefill_step = jax.jit(prefill_step, **jit_kw)
+        self._packed_sharding = batch2_spec
+        self._decode_step = jax.jit(
+            decode_step, donate_argnums=(1, 2, 3, 4, 5), **jit_kw)
+        self._prefill_step = jax.jit(
+            prefill_step, static_argnums=(0,),
+            donate_argnums=(2, 3, 4, 5, 6), **jit_kw)
 
     def _build_burst(self):
         """K fused decode steps per dispatch (config.multi_step_decode).
@@ -1542,66 +1532,34 @@ class ModelRunner:
         broadcast into per-row keys with the row index as fold-in counter.
         The scheduler passes per-request ``seed_keys``/``counters`` instead.
         """
-        b = tokens.shape[0]
+        b, s = tokens.shape
         if seed_keys is None:
-            if key is None:
-                key = jax.random.PRNGKey(0)
-            seed_keys = np.tile(
-                np.asarray(jax.random.key_data(key), np.uint32)[None, :], (b, 1)
-            )
-        if counters is None:
-            counters = np.arange(b, dtype=np.int32)
-        samp = SamplingParams(
-            temperature=jnp.asarray(temperature, jnp.float32),
-            top_k=jnp.asarray(top_k, jnp.int32),
-            top_p=jnp.asarray(top_p, jnp.float32),
-            min_p=jnp.asarray(
-                min_p if min_p is not None else np.zeros(b), jnp.float32),
-            presence_penalty=jnp.asarray(
-                presence_penalty if presence_penalty is not None else np.zeros(b),
-                jnp.float32),
-            frequency_penalty=jnp.asarray(
-                frequency_penalty if frequency_penalty is not None else np.zeros(b),
-                jnp.float32),
-            repetition_penalty=jnp.asarray(
-                repetition_penalty if repetition_penalty is not None else np.ones(b),
-                jnp.float32),
-            keys=jnp.asarray(seed_keys, jnp.uint32),
-            counters=jnp.asarray(counters, jnp.int32),
+            # PRNGKey(0)'s two words are zero
+            seed_keys = (np.zeros(2, np.uint32) if key is None else
+                         np.asarray(jax.random.key_data(key), np.uint32))
+        # one fresh host array a pass, put once with the sharding the
+        # program expects: the jitted call then places nothing again
+        buf = step_inputs.pack(
+            tokens, positions, block_tables, slot_mapping, targets,
+            keys=seed_keys, want_top=want_top, want_prompt=want_prompt,
+            want_greedy=want_greedy, context_lens=context_lens,
+            last_idx=last_idx, sample_slots=sample_slots, counters=counters,
+            commit=commit, top_k=top_k, temperature=temperature, top_p=top_p,
+            min_p=min_p, presence_penalty=presence_penalty,
+            frequency_penalty=frequency_penalty,
+            repetition_penalty=repetition_penalty,
         )
-        if sample_slots is None:
-            sample_slots = np.arange(b, dtype=np.int32)
-        if commit is None:
-            commit = np.zeros(b, bool)
-        if targets is None:
-            targets = np.zeros_like(tokens)
-        s = tokens.shape[1]
         with self.compiles.track(
             "prefill" if s > 1 else "decode",
-            f"b{b}_s{s}_w{block_tables.shape[1]}",
+            f"b{b}_s{s}_w{block_tables.shape[1]}", arrays=1,
         ):
             moe = () if self.moe_counts is None else (self.moe_counts,)
+            args = (self.params, *self.kv_cache, *self.sample_state,
+                    jax.device_put(buf, self._packed_sharding), *moe)
             (next_tokens, lps, top_vals, top_ids, prompt_lps, greedy_all,
              k, v, counts, seen, bias, *moe) = (
-                self._prefill_step if s > 1 else self._decode_step)(
-                self.params, self.kv_cache[0], self.kv_cache[1],
-                self.sample_state[0], self.sample_state[1],
-                self.sample_state[2],
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(slot_mapping, jnp.int32),
-                jnp.asarray(context_lens, jnp.int32),
-                jnp.asarray(last_idx, jnp.int32),
-                samp,
-                jnp.asarray(sample_slots, jnp.int32),
-                jnp.asarray(commit, jnp.bool_),
-                jnp.asarray(bool(want_top), jnp.bool_),
-                jnp.asarray(targets, jnp.int32),
-                jnp.asarray(bool(want_prompt), jnp.bool_),
-                jnp.asarray(bool(want_greedy), jnp.bool_),
-                *moe,
-            )
+                self._prefill_step(s, *args) if s > 1
+                else self._decode_step(*args))
         self.kv_cache = (k, v)
         self.sample_state = (counts, seen, bias)
         if moe:

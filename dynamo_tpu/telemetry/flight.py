@@ -181,14 +181,16 @@ class CompileTracker:
         return self._serving
 
     @contextmanager
-    def track(self, program: str, key: str):
+    def track(self, program: str, key: str, **stats):
         """Wrap ONE dispatch of ``program`` at shape-bucket ``key``;
-        records a compile iff this (program, key) was never dispatched."""
+        records a compile iff this (program, key) was never dispatched.
+        ``stats`` go onto the ``dispatch.<program>`` span beside ``key``
+        (``arrays``: how many host arrays the call sends)."""
         hook = self.dispatch_cm
         # the profiler's trace gets the program's stable name: the
         # runtime's own host spans (PjitFunction, shard_args, the
         # transfers) nest inside it
-        with span("dispatch." + program, key=key), (
+        with span("dispatch." + program, key=key, **stats), (
                 hook(program) if hook is not None else nullcontext()):
             with self._lock:
                 first = (program, key) not in self._seen

@@ -295,6 +295,15 @@ def test_dispatch_span_nests_in_the_schedulers_and_holds_the_runtimes(served):
     assert any(n.startswith("PjitFunction(") for n in inner), inner
 
 
+def test_a_step_dispatch_says_how_many_host_arrays_it_sent(served):
+    """One packed array a step (engine/step_inputs.py): the span's
+    ``arrays`` stat is that mechanism's counter."""
+    for name in ("dispatch.decode", "dispatch.prefill"):
+        spans = _named(served["events"], name)
+        assert spans
+        assert {int(e["stats"]["arrays"]) for e in spans} == {1}, name
+
+
 def test_compiled_programs_have_a_name_each(served):
     """What the TPU's ``XLA Modules`` line would show: the jitted
     functions' names, as the runtime's own host spans carry them."""
@@ -459,19 +468,12 @@ def _lower_tiny_step(strip):
         r = mr.ModelRunner(cfg)
         b, w = 2, cfg.kv_width_buckets()[0]
         z2 = np.zeros((b, 1), np.int32)
-        samp = mr.SamplingParams(
-            temperature=np.zeros(b, np.float32), top_k=np.zeros(b, np.int32),
-            top_p=np.ones(b, np.float32), min_p=np.zeros(b, np.float32),
-            presence_penalty=np.zeros(b, np.float32),
-            frequency_penalty=np.zeros(b, np.float32),
-            repetition_penalty=np.ones(b, np.float32),
-            keys=np.zeros((b, 2), np.uint32), counters=np.zeros(b, np.int32))
-        lowered = r._decode_step.lower(
-            r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state,
+        packed = mr.step_inputs.pack(
             z2, z2, np.zeros((b, w), np.int32), z2 - 1,
-            np.ones(b, np.int32), np.zeros(b, np.int32), samp,
-            np.arange(b, dtype=np.int32), np.zeros(b, bool),
-            np.asarray(False), z2, np.asarray(False), np.asarray(False))
+            keys=np.zeros((b, 2), np.uint32), want_top=False,
+            context_lens=1, last_idx=0, top_k=0, temperature=0.0, top_p=1.0)
+        lowered = r._decode_step.lower(
+            r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state, packed)
         return lowered.as_text(debug_info=True), lowered.compile()
     finally:
         jax.named_scope, mr._sample_and_logprobs = real, real_tail
