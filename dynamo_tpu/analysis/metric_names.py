@@ -38,8 +38,7 @@ PREFIX = "dynamo_"
 # "fraction" added with the live roofline gauge: unlike "ratio" (a
 # part-of-whole share of counted things), a fraction names achieved-
 # over-bound against a PHYSICAL limit — dynamo_engine_roofline_fraction
-# is achieved HBM bytes/s over the chip's peak, the serving-time mirror
-# of bench.py's vs_baseline)
+# is achieved HBM bytes/s over the chip's peak)
 UNIT_SUFFIXES = (
     "total", "seconds", "bytes", "tokens", "blocks",
     "requests", "slots", "ratio", "info", "depth", "replicas", "length",

@@ -98,24 +98,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode steps fused per device dispatch (tokens "
                         "stream in bursts of K; 1 = per-token)")
     p.add_argument("--decode-pipeline-depth", type=int, default=1,
-                   help="dispatch-ahead decode: 2 double-buffers bursts "
-                        "(burst k+1 dispatches while the host streams "
-                        "burst k's tokens); 0/1 = strictly synchronous")
-    p.add_argument("--device-finish", choices=["auto", "on", "off"],
-                   default="auto",
-                   help="device-resident finish detection: the decode "
-                        "burst carries a per-row done mask (eos/stop/"
-                        "max-token checks inside the scan; finished rows "
-                        "freeze), so bursts chain back-to-back and "
-                        "completed rows drain asynchronously. auto = "
-                        "follow --decode-pipeline-depth >= 2")
-    p.add_argument("--fused-epilogue", choices=["auto", "on", "off"],
-                   default="auto",
-                   help="fused sampling epilogue: the per-burst sampling "
-                        "tail (penalties, top-k/p/min-p, count commit, "
-                        "finish mask, stop-suffix hash) runs as ONE "
-                        "Pallas dispatch; bit-identical stream. auto = "
-                        "ride the Pallas attention route")
+                   help="2 = the persistent decode loop: the burst "
+                        "carries a per-row done mask (eos/stop/max-token "
+                        "checks inside the scan; finished rows freeze), "
+                        "so bursts chain back-to-back off the device "
+                        "carry while the host streams earlier bursts' "
+                        "tokens; 0/1 = strictly synchronous")
     p.add_argument("--guided-table-max-states", type=int, default=256,
                    help="unrestricted chain: state bound for compiling "
                         "guided grammars to device transition tables "

@@ -434,11 +434,11 @@ class BlockAllocator:
     def rollback_tail(self, block_ids: List[int], keep: int) -> List[int]:
         """Release the over-allocated tail of a sequence's block list.
 
-        The dispatch-ahead decode pipeline reserves block headroom for
-        2x the burst depth before every dispatch; a finish (eos/stop/
-        max-token/cancel) detected one burst late leaves the row holding
-        blocks whose only contents are over-decoded positions the host
-        never committed. Those tail blocks are by construction anonymous
+        The decode chain reserves block headroom against its own
+        dispatch count before every dispatch; a row that finishes
+        (eos/stop/max-token/cancel) deep into a chain holds blocks for
+        positions it froze before reaching, which the host never
+        committed. Those tail blocks are by construction anonymous
         (registration only ever covers positions below the host
         ``context_len``), so releasing them returns them straight to the
         free list. Returns the retained prefix.

@@ -325,47 +325,24 @@ class EngineConfig:
     # waiting, and 1 (default) keeps strict per-token dispatch. Sampling
     # is bit-identical either way (same per-row PRNG fold-in counters).
     multi_step_decode: int = 1
-    # dispatch-ahead decode: with depth 2, burst k+1 is dispatched before
-    # burst k's sampled tokens are synced to the host (JAX dispatch is
-    # async; the carry tokens are already device-resident), so the host's
-    # detokenize/stream/finish-check work for burst k overlaps burst
-    # k+1's device compute instead of leaving the TPU idle. Finishes
-    # (eos/stop/max-token/cancel) are detected one burst late and the
-    # over-decoded rows retro-invalidated (tokens truncated, KV blocks
-    # rolled back); block headroom for 2*K positions is reserved before
-    # every dispatch so the in-flight burst can never OOM. Guided
-    # decoding, speculative decoding, and prefill work force the
-    # synchronous path per pass. 0/1 = today's strictly-synchronous
-    # behavior, 2 = double-buffered (the only pipelined depth).
-    decode_pipeline_depth: int = 1
-    # device-resident finish detection (the persistent decode loop):
-    # "auto" | "on" | "off". When enabled, the fused decode burst carries
-    # a per-row ``done`` mask and evaluates EOS / hidden-stop /
+    # the persistent decode loop: at depth 2 the fused decode burst
+    # carries a per-row ``done`` mask and evaluates EOS / hidden-stop /
     # max-tokens / model-len checks INSIDE the scan — finished rows
     # freeze (no further sampling or KV writes, padded emission) instead
     # of ending the burst, so the scheduler dispatches bursts
-    # back-to-back off the device-resident carry and drains completed
-    # rows asynchronously, compacting batch membership only at natural
-    # barriers (admission, preemption, KV-OOM, drain). The carry also
-    # holds speculative state (trailing-token ring), bounded guided
-    # grammar state (guided_device_table below), and the stop-string
-    # suffix-hash ring (device_stop_strings below), so spec / guided /
-    # stop-string / n>1 traffic chains too; the remaining sync-path
-    # fallbacks are counted per pass in
-    # dynamo_engine_sync_fallback_total{reason}. "auto" engages with
-    # decode_pipeline_depth >= 2; "on" requires it.
-    device_finish: str = "auto"
-    # the fused Pallas sampling epilogue (ops/pallas_epilogue.py): run
-    # the whole per-step decode tail — penalties, top-k/top-p/min-p
-    # sampling, count commit, and (in the chained burst) the
-    # device-finish verdict + stop-suffix rolling hash — as ONE kernel
-    # dispatch instead of a string of small [B, V] XLA ops. Sampling is
-    # bit-identical to the unfused ladder by construction. "auto"
-    # selects only kernels that compile under Mosaic, and this one does
-    # not lower for TPU (PERF.md kernel table) — so today "auto" keeps
-    # the XLA tail like "off"; "on" forces the kernel (CPU tests with
-    # DYN_PALLAS_INTERPRET=1; on a chip it raises the compiler's error).
-    fused_epilogue: str = "auto"
+    # back-to-back off the device-resident carry (JAX dispatch is async)
+    # and drains completed rows asynchronously: the host's
+    # detokenize/stream/finish-check work overlaps the next burst's
+    # device compute. Batch membership compacts only at natural barriers
+    # (admission, preemption, KV-OOM, drain). The carry also holds
+    # speculative state (trailing-token ring), bounded guided grammar
+    # state (guided_device_table below), and the stop-string suffix-hash
+    # ring (device_stop_strings below), so spec / guided / stop-string /
+    # n>1 traffic chains too; a pass the chain refuses runs the
+    # synchronous path and is counted in
+    # dynamo_engine_sync_fallback_total{reason}. 0/1 = strictly
+    # synchronous, 2 = the chain (the only other depth).
+    decode_pipeline_depth: int = 1
     # guided decoding inside the chain: compile TrieConstraint /
     # in-bound JsonGrammar cursors to a dense device transition table
     # (state x token -> next state) so the per-token mask is computed
@@ -380,7 +357,7 @@ class EngineConfig:
     # (StopConditions.stop_token_seqs); candidate rows freeze on device,
     # the host confirms exactly on drain, and hash-collision false
     # positives resume byte-identically. Off -> stop-string rows keep
-    # the per-burst sync pipeline.
+    # the synchronous path.
     device_stop_strings: bool = True
     # n-gram (prompt-lookup) speculative decoding: propose up to K tokens
     # per decode step by matching the context's trailing n-gram against
@@ -478,31 +455,10 @@ class EngineConfig:
         # a burst must fit comfortably inside one sequence's block budget;
         # 64 already amortizes dispatch overhead past the point of returns
         self.multi_step_decode = max(1, min(self.multi_step_decode, 64))
-        # depth > 2 buys nothing: with one burst in flight the host is
-        # already fully overlapped, and reconciliation lag grows with
-        # every extra stage — clamp instead of failing
+        # depth > 2 names nothing more: the chain bounds its own bursts
+        # in flight (Scheduler.CHAIN_MAX_INFLIGHT) — clamp instead of
+        # failing
         self.decode_pipeline_depth = max(0, min(self.decode_pipeline_depth, 2))
-        if self.device_finish not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unknown device_finish {self.device_finish!r} "
-                "(auto | on | off)"
-            )
-        if self.fused_epilogue not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unknown fused_epilogue {self.fused_epilogue!r} "
-                "(auto | on | off)"
-            )
-        if self.device_finish == "on" and self.decode_pipeline_depth < 2:
-            # the chained dispatch only exists under the dispatch-ahead
-            # pipeline; an explicit "on" that silently never engaged
-            # would be worse than failing here
-            raise ValueError(
-                "device_finish='on' requires decode_pipeline_depth >= 2 "
-                "(the persistent loop rides the dispatch-ahead pipeline)"
-            )
-        # (speculation + device_finish used to be mutually exclusive —
-        # the chain now runs propose-verify rounds off the same device
-        # carry, so spec engines chain too)
         self.guided_table_max_states = max(2, self.guided_table_max_states)
         # one frame in flight is the serial floor; beyond two buys nothing
         # (the wire is busy continuously at 2) and unbounds host buffers
@@ -579,13 +535,10 @@ class EngineConfig:
         return math.ceil(self.max_model_len / self.kv_block_size)
 
     @property
-    def device_finish_enabled(self) -> bool:
-        """Resolved device-resident finish detection: explicit on/off,
-        auto follows the dispatch-ahead pipeline."""
-        if self.device_finish == "on":
-            return True
-        return (self.device_finish == "auto"
-                and self.decode_pipeline_depth >= 2)
+    def chain_enabled(self) -> bool:
+        """The persistent decode loop (chained bursts, device-resident
+        finish detection) is what ``decode_pipeline_depth >= 2`` means."""
+        return self.decode_pipeline_depth >= 2
 
     def bucket_for(self, length: int) -> int:
         for b in self.prefill_buckets:

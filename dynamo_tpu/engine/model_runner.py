@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import models
 from ..models import llama, quant
-from ..ops.attention import _pad_minor, pallas_interpret
+from ..ops.attention import _pad_minor
 from ..telemetry.flight import CompileTracker
 from ..telemetry.registry import Counter
 from .config import EngineConfig
@@ -89,9 +89,7 @@ class _DeviceFedCounter(Counter):
 
 @jax.named_scope("sampling")
 def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
-                         sample_slots, commit, want_top, extra_bias=None,
-                         fused=False, unique_slots=True, finish=None,
-                         max_model_len=0):
+                         sample_slots, commit, want_top, extra_bias=None):
     """The per-token tail shared by the single step and every scan
     iteration of the fused burst: penalty-aware sampling, the sampled
     token's logprob, gated top-K alternatives, and the committed-count
@@ -101,60 +99,10 @@ def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
     ``extra_bias`` is an additive [B, V] term computed in-program (the
     chained burst's device-guided mask); the sync path expresses the
     same mask through the persistent ``bias`` buffer instead, so adding
-    it here keeps the two paths' logits — and logprobs — bit-equal.
-
-    ``fused=True`` routes the whole tail through the single-dispatch
-    Pallas epilogue (ops/pallas_epilogue.py) — bit-identical by
-    construction. With
-    ``finish`` (the chained burst's per-row carry tuple) the kernel also
-    returns the step's (hard, cand, ring_new) finish verdicts, appended
-    to the return. ``unique_slots=False`` marks call sites whose pad
-    rows may share a live row's sample slot (the batched prefill step):
-    the count commit then stays a scatter-add outside the kernel."""
+    it here keeps the two paths' logits — and logprobs — bit-equal."""
     from .sampling import top_k_width
 
     b = last_logits.shape[0]
-    if fused:
-        from ..ops.pallas_epilogue import fused_sampling_epilogue
-        from .sampling import _row_keys
-
-        v = last_logits.shape[1]
-        row_keys = _row_keys(samp)
-        gum = jax.vmap(
-            lambda kk: jax.random.gumbel(kk, (v,), jnp.float32)
-        )(row_keys)
-        scalars = (samp.temperature, samp.top_k, samp.top_p, samp.min_p,
-                   samp.presence_penalty, samp.frequency_penalty,
-                   samp.repetition_penalty)
-        outs = fused_sampling_epilogue(
-            last_logits, gum, scalars, counts, seen, bias, sample_slots,
-            commit, extra_bias=extra_bias, finish=finish,
-            max_model_len=max_model_len, alias_counts=unique_slots,
-            interpret=pallas_interpret(),
-        )
-        next_tokens, lps, counts = outs[:3]
-        kw = top_k_width(cfg.vocab_size)
-
-        def _top(_):
-            row_bias = bias[sample_slots]
-            if extra_bias is not None:
-                row_bias = row_bias + extra_bias
-            logp = jax.nn.log_softmax(
-                (last_logits + row_bias).astype(jnp.float32), axis=-1
-            )
-            return top_logprobs_for(last_logits, logp)
-
-        top_vals, top_ids = jax.lax.cond(
-            want_top,
-            _top,
-            lambda _: (jnp.zeros((b, kw), jnp.float32),
-                       jnp.zeros((b, kw), jnp.int32)),
-            0,
-        )
-        return (next_tokens, lps, top_vals, top_ids, counts) + tuple(
-            outs[3:]
-        )
-    assert finish is None, "finish fusion requires fused=True"
     row_counts = counts[sample_slots]
     row_seen = seen[sample_slots]
     row_bias = bias[sample_slots]
@@ -431,8 +379,8 @@ class ModelRunner:
                 _attn_ops.ATTENTION_ROUTE_COUNTER)
 
         # live device-time + roofline accounting (telemetry/device_time.py):
-        # the byte model mirrors bench.py's — per decode step the device
-        # streams every param leaf once plus each live row's KV context.
+        # the byte model: per decode step the device streams every param
+        # leaf once plus each live row's KV context.
         # kv_bytes_per_token is EXACT for any cache layout (GQA, MLA
         # latent, fp8, pp-staged): total cache bytes over total token
         # capacity. The scheduler feeds observations at its existing
@@ -566,18 +514,9 @@ class ModelRunner:
 
         return forward, head
 
-    def _fused_epilogue_enabled(self) -> bool:
-        """Resolve config.fused_epilogue at program-BUILD time. "auto"
-        selects only kernels that compile under Mosaic, and the epilogue
-        does not lower for TPU at all (PERF.md kernel table) — so only
-        an explicit "on" engages it: CPU interpret runs, or a chip run
-        that wants the compiler's error."""
-        return self.config.fused_epilogue == "on"
-
     def _build_step(self):
         cfg = self.config.model
         mesh = self.mesh
-        fused = self._fused_epilogue_enabled()
         batch_spec = NamedSharding(mesh, P("dp"))
         batch2_spec = NamedSharding(mesh, P("dp", None))
         repl = NamedSharding(mesh, P())
@@ -631,12 +570,9 @@ class ModelRunner:
             last_logits = head(
                 hidden[jnp.arange(b), last_idx], params
             )  # [B, V]
-            # pad rows of a partial batch default to sample slot 0 and
-            # may alias a live row's slot — the fused kernel keeps its
-            # commit outside (unique_slots=False)
             next_tokens, lps, top_vals, top_ids, counts = _sample_and_logprobs(
                 cfg, last_logits, samp, counts, seen, bias, sample_slots,
-                commit, want_top, fused=fused, unique_slots=False,
+                commit, want_top,
             )
             out = (next_tokens, lps, top_vals, top_ids, prompt_lps,
                    greedy_all, k_cache, v_cache, counts, seen, bias)
@@ -701,16 +637,15 @@ class ModelRunner:
         self._burst_df = None
         if (K <= 1 and self.config.decode_pipeline_depth < 2
                 and self.config.sp_size <= 1):
-            # the dispatch-ahead pipeline always runs through the burst
-            # program (its carry keeps sampled tokens device-resident),
-            # so pipelining with multi_step_decode=1 compiles a K=1 scan
+            # the chain always runs through the burst program (its
+            # carry keeps sampled tokens device-resident), so depth 2
+            # with multi_step_decode=1 compiles a K=1 scan
             # — and so does the SP engine's early decode handoff, which
             # chains the first burst off the final chunk's device token
             return
         cfg = self.config.model
         mesh = self.mesh
         bs = self.config.kv_block_size
-        fused = self._fused_epilogue_enabled()
         batch_spec = NamedSharding(mesh, P("dp"))
         batch2_spec = NamedSharding(mesh, P("dp", None))
         repl = NamedSharding(mesh, P())
@@ -740,7 +675,7 @@ class ModelRunner:
                 samp_i = _dc.replace(samp, counters=samp.counters + step_i)
                 nt, lp, tv, ti, counts = _sample_and_logprobs(
                     cfg, head(hidden[:, 0], params), samp_i, counts, seen,
-                    bias, sample_slots, commit, want_top, fused=fused,
+                    bias, sample_slots, commit, want_top,
                 )
                 return (k_cache, v_cache, counts, nt, pos + 1), (nt, lp, tv, ti)
 
@@ -778,7 +713,7 @@ class ModelRunner:
                            self.state_sharding),
         )
 
-        if not self.config.device_finish_enabled:
+        if not self.config.chain_enabled:
             return
 
         # ---- the device-finish (persistent-loop) variant ----
@@ -845,34 +780,19 @@ class ModelRunner:
                 gmask = jnp.where(
                     guided[:, None] & (grow < 0), -1e9, 0.0
                 ).astype(jnp.float32)
-                if fused:
-                    # the finish checks ride INSIDE the epilogue kernel:
-                    # the whole per-step tail is one dispatch
-                    nt, lp, tv, ti, counts, hard, cand, ring_n = (
-                        _sample_and_logprobs(
-                            cfg, head(hidden[:, 0], params), samp_i,
-                            counts, seen, bias, sample_slots, live,
-                            want_top, extra_bias=gmask, fused=True,
-                            finish=(gen, pos, min_new, max_new, stop_ids,
-                                    ring, stop_hash, stop_hlen),
-                            max_model_len=max_len,
-                        )
-                    )
-                    gen_n = gen + live.astype(jnp.int32)
-                else:
-                    nt, lp, tv, ti, counts = _sample_and_logprobs(
-                        cfg, head(hidden[:, 0], params), samp_i, counts,
-                        seen, bias, sample_slots, live, want_top,
-                        extra_bias=gmask,
-                    )
-                    gen_n = gen + live.astype(jnp.int32)
-                    ring_n = ring_push(ring, nt, live)
-                    hard = device_finish_mask(
-                        nt, gen_n, pos, stop_ids, min_new, max_new, max_len
-                    )
-                    cand = stop_candidate_mask(
-                        ring_n, gen_n, min_new, stop_hash, stop_hlen
-                    )
+                nt, lp, tv, ti, counts = _sample_and_logprobs(
+                    cfg, head(hidden[:, 0], params), samp_i, counts,
+                    seen, bias, sample_slots, live, want_top,
+                    extra_bias=gmask,
+                )
+                gen_n = gen + live.astype(jnp.int32)
+                ring_n = ring_push(ring, nt, live)
+                hard = device_finish_mask(
+                    nt, gen_n, pos, stop_ids, min_new, max_new, max_len
+                )
+                cand = stop_candidate_mask(
+                    ring_n, gen_n, min_new, stop_hash, stop_hlen
+                )
                 # grammar advance on the sampled token: DONE (state 0)
                 # completes the constraint; a reject (< 0) is
                 # unreachable through the mask but freezes defensively —
@@ -965,7 +885,7 @@ class ModelRunner:
         cfg_e = self.config
         K = (cfg_e.spec_draft_tokens if cfg_e.spec_draft_model
              else cfg_e.spec_ngram_tokens)
-        if K <= 0 or not cfg_e.device_finish_enabled:
+        if K <= 0 or not cfg_e.chain_enabled:
             return
         cfg = self.config.model
         mesh = self.mesh
@@ -1137,7 +1057,6 @@ class ModelRunner:
             w += 1
         self._sp_bucket = S
         self._sp_width = w
-        fused = self._fused_epilogue_enabled()
         repl = NamedSharding(mesh, P())
         seq_spec = NamedSharding(mesh, P(None, "sp"))
         forward, head = self._make_forward()
@@ -1157,7 +1076,7 @@ class ModelRunner:
             next_tokens, lps, top_vals, top_ids, counts = (
                 _sample_and_logprobs(
                     cfg, last_logits, samp, counts, seen, bias,
-                    sample_slots, commit, want_top, fused=fused,
+                    sample_slots, commit, want_top,
                 )
             )
             return (next_tokens, lps, top_vals, top_ids, k_cache, v_cache,

@@ -120,9 +120,7 @@ def filter_logits(
     masked min; and the mask in vocabulary order is ``scaled >= cutoff``.
     No argsort, no [B, V] gather and no [B, V] scatter, which a TPU does
     one element at a time (PERF.md §6, PR 24). Entries exactly equal to
-    the cutoff are ALL kept, by top-p as by top-k. Rows of any rank:
-    ``sample`` passes [B, V], the Pallas epilogue's kernel body one row
-    [V] with 0-d parameters.
+    the cutoff are ALL kept, by top-p as by top-k.
     """
     v = scaled.shape[-1]
     # values alone, so stability means nothing, and asking for it makes

@@ -217,10 +217,10 @@ class EngineRequest:
     # telemetry: monotonic time of the last token emission (0 = none yet);
     # drives the inter-token-latency histogram and the first_token span
     last_emit_t: float = 0.0
-    # dispatch-ahead decode emitted tokens for this request since the
-    # last trace mark — a ``decode_pipeline`` stage is stamped when the
-    # pipelined segment ends (finish or drain), so span attribution
-    # separates overlapped decode from the synchronous tail
+    # the chain emitted tokens for this request since the last trace
+    # mark — a ``decode_pipeline`` stage is stamped when the chained
+    # segment ends (finish or barrier), so span attribution separates
+    # overlapped decode from the synchronous tail
     pipeline_span_open: bool = False
     # device-resident finish detection: the admission-time classification
     # (hoisted out of the per-token hot path — _check_finish consults
@@ -438,12 +438,12 @@ class _PendingPull:
 
 @dataclasses.dataclass
 class _InflightBurst:
-    """One dispatched-but-unreconciled decode burst (pipeline depth 2).
+    """One dispatched-but-unreconciled chained burst (pipeline depth 2).
 
-    Everything the host needs to reconcile the burst AFTER the next one
-    is already on device: the device-resident output arrays (synced in
-    one executor hop — the loop's only host sync) and the carry
-    (``last_tokens``) the next burst consumes without a host round-trip.
+    Everything the host needs to reconcile the burst AFTER later ones
+    are already on device: the device-resident output arrays (synced in
+    one executor hop — the loop's only host sync). Rows the device froze
+    read -1 pads, which ``_apply_burst`` skips.
     """
 
     active: List["EngineRequest"]  # rows committed at dispatch
@@ -452,12 +452,8 @@ class _InflightBurst:
     tv: object                     # device [K, B, KW] top alternatives
     ti: object
     k_steps: int
-    last_tokens: object            # device [B]: the next burst's tokens0
-    # chained (device-finish) bursts: dispatch timestamp for the
-    # drain-lag histogram, and the flag that switches _apply_burst to
-    # frozen-row semantics (-1 pads skipped, device-finish counted)
+    # dispatch timestamp for the drain-lag histogram
     dispatch_t: float = 0.0
-    device_finish: bool = False
     # device-time accounting (telemetry/device_time.py): HBM bytes this
     # burst must stream and the tokens it samples, fixed at dispatch
     read_bytes: float = 0.0
@@ -578,14 +574,11 @@ class Scheduler:
         # ngram speculative decoding acceptance telemetry
         self.spec_proposed = 0
         self.spec_accepted = 0
-        # dispatch-ahead decode pipeline (config.decode_pipeline_depth=2):
-        # the burst dispatched but not yet reconciled on the host, the
-        # device-idle bookkeeping behind the bubble histogram, and a
-        # dispatch counter for tests/metrics
-        self._inflight: Optional[_InflightBurst] = None
+        # the device-idle bookkeeping behind the bubble histogram, and a
+        # chained-dispatch counter for tests/metrics
         self._last_burst_done_t: Optional[float] = None
         self.pipeline_bursts = 0
-        # persistent decode loop (config.device_finish): chained bursts
+        # persistent decode loop (config.decode_pipeline_depth=2): bursts
         # dispatched off the device-resident carry, reconciled by the
         # async row drain. Membership is FIXED for a chain's lifetime
         # (finished rows freeze on device); it compacts only at the
@@ -664,10 +657,10 @@ class Scheduler:
         )
         reg.callback_gauge(
             "dynamo_engine_decode_pipeline_depth",
-            "Decode dispatch depth in effect: 2 while a burst is in "
-            "flight ahead of host reconciliation, else 1",
+            "Decode dispatch depth in effect: 2 while a chain is open "
+            "(bursts in flight ahead of host reconciliation), else 1",
             # dynrace: domain(executor)
-            lambda: 2 if (self._inflight is not None or self._chain) else 1,
+            lambda: 2 if self._chain else 1,
         )
         self._device_finished_ctr = reg.counter(
             "dynamo_engine_device_finished_rows_total",
@@ -946,13 +939,10 @@ class Scheduler:
                 pass
             except Exception:
                 logger.exception("scheduler loop raised during seize")
-        if self._inflight is not None or self._chain:
+        if self._chain:
             self.flight.record(
-                "scheduler.burst_abandon",
-                inflight=self._inflight is not None,
-                chained=len(self._chain),
+                "scheduler.burst_abandon", chained=len(self._chain),
             )
-        self._inflight = None
         self._chain.clear()
         self._chain_members = []
         self._chain_carry = None
@@ -1096,9 +1086,8 @@ class Scheduler:
         if self.config.spec_ngram_tokens or self.draft is not None:
             out["spec_proposed_tokens"] = self.spec_proposed
             out["spec_accepted_tokens"] = self.spec_accepted
-        if self.config.decode_pipeline_depth >= 2:
+        if self.config.chain_enabled:
             out["decode_pipeline_bursts"] = self.pipeline_bursts
-        if self.config.device_finish_enabled:
             out["decode_burst_chain_length"] = (
                 self._chain_dispatched or self._last_chain_len
             )
@@ -1426,64 +1415,46 @@ class Scheduler:
                 )
                 spec_now = (speculating and runner_idle
                             and all(self._spec_eligible(er) for er in active))
-                chain_on = (self.config.device_finish_enabled
-                            and self.config.decode_pipeline_depth >= 2)
-                spec_reason = (
-                    self._spec_chain_reason(active, runner_idle)
-                    if (spec_now and chain_on) else None
-                )
-                if spec_now and chain_on and spec_reason is None:
-                    # persistent loop, speculative: chain propose-verify
-                    # rounds off the device-resident carry — no host
-                    # barrier between draft/target rounds
-                    await self._decode_chained_spec(loop, active)
-                elif not spec_now and self._chain_ok(active, runner_idle):
-                    # persistent loop: chain the next burst off the
-                    # device-resident carry; finished rows freeze on
-                    # device and drain asynchronously
-                    await self._decode_chained(loop, active)
-                else:
-                    # the chain did not engage this pass: attribute the
-                    # sync fallback to its reason (acceptance criterion:
-                    # every remaining sync pass is named)
-                    if chain_on:
-                        reason = (
-                            spec_reason if spec_now
-                            else self._chain_block_reason(
-                                active, runner_idle)
-                        )
-                        if reason:
-                            self._note_sync_fallback(reason)
-                    if not spec_now and self._pipeline_ok(
-                            active, runner_idle):
-                        # dispatch-ahead: burst k+1 goes to the device
-                        # before burst k's tokens are synced on the host
-                        await self._chain_barrier(loop)
-                        active = [er for er in active if er.finish is None]
-                        if active:
-                            await self._decode_pipelined(loop, active)
+                # depth 2: why can this pass NOT chain off the device
+                # carry? (None = it can)
+                chain_on = self.config.chain_enabled
+                refused = None
+                if chain_on:
+                    refused = (
+                        self._spec_chain_reason(active) if spec_now
+                        else self._chain_block_reason(active, runner_idle)
+                    )
+                if chain_on and refused is None:
+                    if spec_now:
+                        # persistent loop, speculative: chain
+                        # propose-verify rounds off the device-resident
+                        # carry — no host barrier between draft/target
+                        # rounds
+                        await self._decode_chained_spec(loop, active)
                     else:
-                        await self._chain_barrier(loop)
-                        active = [er for er in active if er.finish is None]
-                        if self._inflight is not None:
-                            # sync barrier: reconcile the in-flight burst
-                            # before any non-pipelined dispatch
-                            # (membership, masks, or the program shape
-                            # is changing)
-                            await self._drain_pipeline(loop)
-                            active = [er for er in active
-                                      if er.finish is None]
-                        if not active:
-                            pass
-                        elif spec_now:
-                            # speculative verify (ngram or draft-model
-                            # proposals) on the host sync path
-                            await self._decode_spec(loop, active)
-                        else:
-                            k_steps = self.config.multi_step_decode
-                            if k_steps > 1 and not runner_idle:
-                                k_steps = 1
-                            await self._decode(loop, active, k_steps)
+                        # persistent loop: chain the next burst off the
+                        # device-resident carry; finished rows freeze on
+                        # device and drain asynchronously
+                        await self._decode_chained(loop, active)
+                else:
+                    if refused:
+                        # the chain did not engage this pass: attribute
+                        # the sync fallback to its reason (every
+                        # remaining sync pass at depth 2 is named)
+                        self._note_sync_fallback(refused)
+                    await self._chain_barrier(loop)
+                    active = [er for er in active if er.finish is None]
+                    if not active:
+                        pass
+                    elif spec_now:
+                        # speculative verify (ngram or draft-model
+                        # proposals) on the host sync path
+                        await self._decode_spec(loop, active)
+                    else:
+                        k_steps = self.config.multi_step_decode
+                        if k_steps > 1 and not runner_idle:
+                            k_steps = 1
+                        await self._decode(loop, active, k_steps)
                 self._phase_hist.observe(
                     max(0.0, time.monotonic() - t_dec - self._host_sync_s),
                     phase="decode",
@@ -1494,12 +1465,6 @@ class Scheduler:
                 # chain was still dispatching: reconcile the queue and
                 # close the chain (frozen rows' pads apply as no-ops)
                 await self._chain_barrier(loop)
-                progressed = True
-            elif self._inflight is not None:
-                # every pipelined row finished or was cancelled while its
-                # successor burst was in flight: reconcile the orphan (all
-                # rows skip at apply — pure over-decode, nothing emits)
-                await self._drain_pipeline(loop)
                 progressed = True
 
             # materialize staged host-tier offloads now that this pass's
@@ -1539,164 +1504,17 @@ class Scheduler:
                 with span("sched.yield", step=self.passes):
                     await asyncio.sleep(0)
 
-        # stopping: reconcile any chained or dispatch-ahead burst so no
-        # sampled tokens are silently dropped and no device work is
-        # abandoned
+        # stopping: reconcile any chained burst so no sampled tokens are
+        # silently dropped and no device work is abandoned
         await self._chain_barrier(loop)
-        await self._drain_pipeline(loop)
 
-    # ---------- dispatch-ahead decode (pipeline depth 2) ----------
-
-    def _pipeline_ok(self, active: List[EngineRequest],
-                     runner_idle: bool) -> bool:
-        """May this pass decode dispatch-ahead?
-
-        Guided decoding (per-token host mask edits), speculative decoding
-        (both proposal sources), ``n>1`` fan-out, prefill/admission work,
-        and rows within two bursts of the model-len horizon all force the
-        existing synchronous path — selected per-pass, never mid-burst.
-        A batch-membership surprise (a row active now that was not in the
-        dispatched burst) drains defensively.
-        """
-        cfg = self.config
-        if cfg.decode_pipeline_depth < 2 or not runner_idle:
-            return False
-        if self.draft is not None or cfg.spec_ngram_tokens > 0:
-            return False
-        K = cfg.multi_step_decode
-        for er in active:
-            if er.guided is not None:
-                return False
-            n = er.req.sampling_options.n
-            if n is not None and n > 1:
-                return False
-            if er.context_len + 2 * K + 1 > cfg.max_model_len:
-                return False
-        infl = self._inflight
-        if infl is not None:
-            live = {id(er) for er in infl.active if er.finish is None}
-            if live != {id(er) for er in active}:
-                return False
-        return True
-
-    async def _decode_pipelined(self, loop,
-                                active: List[EngineRequest]) -> None:
-        """One pipelined pass: dispatch burst k+1, then reconcile burst k
-        on the host while k+1 executes on device.
-
-        The carry (burst k's last sampled tokens) is already device-
-        resident inside the burst program's outputs, so burst k+1
-        consumes it without a host round-trip; the host then syncs,
-        detokenizes, streams, and finish-checks burst k's tokens during
-        burst k+1's device time. Block headroom for ``2*K`` positions is
-        reserved before every dispatch, so the in-flight burst can never
-        write to an unallocated slot; if reservation fails, the pipeline
-        drains (sync barrier) and the synchronous path — which owns
-        preemption — takes the pass.
-        """
-        cfg = self.config
-        b = cfg.max_batch_size
-        k_steps = cfg.multi_step_decode
-        infl = self._inflight
-        # device is ``ahead`` tokens past the host's committed state
-        ahead = infl.k_steps if infl is not None else 0
-
-        with span("sched.decode.build", step=self.passes,
-                  rows=len(active)):
-            # 2*K from the host context: covers the burst dispatched now
-            # (positions ahead..ahead+K-1 past the committed state) and
-            # keeps the invariant once reconciliation advances the host
-            reserved = all(
-                self._ensure_block_for(er, er.context_len + j)
-                for er in active for j in range(2 * k_steps)
-            )
-            # one batched host-offload gather for this pass's evictions,
-            # before the dispatch below overwrites the evicted slots
-            self.allocator.flush_offload()
-            if reserved:
-                hs = self._host
-                positions0 = np.zeros(b, np.int32)
-                ctrs = np.zeros(b, np.int32)
-                commit = np.zeros(b, bool)
-                for er in active:
-                    i = er.slot
-                    hs.sync_blocks(er)
-                    positions0[i] = er.context_len + ahead
-                    ctrs[i] = er.generated + ahead
-                    commit[i] = True
-                w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
-                btab = hs.btab[:, :w].copy()
-                if infl is None:
-                    # pipeline fill (first burst after a drain): tokens from host
-                    tokens0 = np.zeros(b, np.int32)
-                    for er in active:
-                        tokens0[er.slot] = er.pending_token
-                else:
-                    tokens0 = infl.last_tokens  # device-resident carry
-                want_top = any(er.logprobs_n > 0 for er in active)
-
-                # device-idle bookkeeping: if the previous burst's outputs are
-                # already materialized when this dispatch goes out, the device
-                # ran dry — charge the gap since the last host reconciliation
-                # (a host-observed approximation; 0 while the device is busy)
-                now = time.monotonic()
-                if self._last_burst_done_t is not None:
-                    if infl is None:
-                        self._bubble_hist.observe(now - self._last_burst_done_t)
-                    else:
-                        ready = getattr(infl.last_tokens, "is_ready", lambda: True)()
-                        self._bubble_hist.observe(
-                            now - self._last_burst_done_t if ready else 0.0
-                        )
-                self._last_burst_done_t = None
-        if not reserved:
-            # KV OOM: preemption needs fully-committed host state —
-            # drain, then let the sync path preempt/decode this pass
-            await self._drain_pipeline(loop)
-            live = [e for e in active if e.finish is None]
-            if live:
-                await self._decode(loop, live, k_steps)
-            return
-
-        with span("sched.decode.dispatch", step=self.passes,
-                  rows=len(active)):
-            toks, lps, tv, ti = self.runner.decode_burst(
-                tokens0, positions0, btab, hs.temp, hs.top_k, hs.top_p,
-                min_p=hs.min_p, presence_penalty=hs.pres,
-                frequency_penalty=hs.freq, repetition_penalty=hs.rep,
-                seed_keys=hs.keys, counters=ctrs, commit=commit,
-                want_top=want_top,
-            )
-            self.steps += 1
-            self.pipeline_bursts += 1
-            self.flight.record(
-                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
-                pipelined=True, carried=infl is not None,
-                requests=[er.request_id for er in active[:8]],
-            )
-            dt = self.device_time
-            self._inflight = _InflightBurst(
-                active=list(active), toks=toks, lps=lps, tv=tv, ti=ti,
-                k_steps=k_steps, last_tokens=toks[k_steps - 1],
-                dispatch_t=now,
-                read_bytes=dt.decode_read_bytes(
-                    k_steps, sum(er.context_len for er in active),
-                ) if dt is not None else 0.0,
-                tokens=k_steps * len(active),
-            )
-        if infl is not None:
-            # burst k+1 is on device — reconcile burst k while it runs
-            await self._apply_burst(loop, infl)
-            if all(er.finish is not None for er in self._inflight.active):
-                # burst k finished every row: k+1 is pure over-decode —
-                # reconcile it now instead of leaving an orphan in flight
-                await self._drain_pipeline(loop)
+    # ---------- persistent decode loop (decode_pipeline_depth=2) ----------
 
     async def _apply_burst(self, loop, infl: _InflightBurst,
                            ready_hint: Optional[float] = None) -> None:
-        """Host half of the pipeline: sync the burst's sampled tokens
-        (the decode loop's ONLY host sync), emit/stream them, run finish
-        checks, and retro-invalidate rows that finished one burst late.
+        """Host half of the chain: sync one burst's sampled tokens (the
+        decode loop's ONLY host sync), emit/stream them, and run the
+        finish checks that mirror the device's freeze verdicts.
 
         ``ready_hint`` is the moment an ``is_ready`` probe saw the
         outputs materialized (the async row drain) — the device-time
@@ -1727,8 +1545,7 @@ class Scheduler:
             self._last_burst_done_t = time.monotonic()
             if self.device_time is not None and infl.dispatch_t:
                 self.device_time.observe(
-                    "decode_burst_df" if infl.device_finish else "decode_burst",
-                    "decode", infl.dispatch_t,
+                    "decode_burst_df", "decode", infl.dispatch_t,
                     ready_hint if ready_hint is not None
                     else self._last_burst_done_t,
                     read_bytes=infl.read_bytes, tokens=infl.tokens,
@@ -1736,9 +1553,9 @@ class Scheduler:
             for j in range(infl.k_steps):
                 for er in infl.active:
                     if er.finish is not None:
-                        continue  # finished/cancelled: over-decode discarded
+                        continue  # finished/cancelled: frozen pads discarded
                     token = int(toks[j, er.slot])
-                    if infl.device_finish and token < 0:
+                    if token < 0:
                         if er.chain_fp:
                             continue  # already flagged: resumes at barrier
                         if infl.spec and j > 0:
@@ -1780,10 +1597,10 @@ class Scheduler:
                         # emit=True: unlike the normal path, no preceding
                         # _emit carried the finish_reason — the client must
                         # still see one before the stream sentinel
-                        self._finish_pipelined(er, emit=True)
+                        self._finish_chained(er, emit=True)
                         continue
                     self._advance_row(er, token)
-                    if infl.device_finish and er.guided is not None:
+                    if er.guided is not None:
                         # chained guided rows: advance the host cursor
                         # (verdicts only — the device computed the mask; the
                         # barrier reinstalls the host mask if needed)
@@ -1797,13 +1614,12 @@ class Scheduler:
                          if tv is not None else None),
                     )
                     if er.finish is not None:
-                        if infl.device_finish:
-                            # the device's mask froze this row at exactly
-                            # this step — the host check is the mirror that
-                            # names the reason and finalizes bookkeeping
-                            er.device_frozen = True
-                            self._device_finished_ctr.inc()
-                        self._finish_pipelined(er)
+                        # the device's mask froze this row at exactly
+                        # this step — the host check is the mirror that
+                        # names the reason and finalizes bookkeeping
+                        er.device_frozen = True
+                        self._device_finished_ctr.inc()
+                        self._finish_chained(er)
             if infl.spec and nprop is not None:
                 for er in infl.active:
                     p = int(nprop[er.slot])
@@ -1816,16 +1632,16 @@ class Scheduler:
                     self._spec_accepted_ctr.inc(min(a, p))
                     self._spec_accept_hist.observe(float(a))
 
-    def _finish_pipelined(self, er: EngineRequest, emit: bool = False) -> None:
-        """A pipelined row finished (possibly one burst late): truncate
-        the over-decoded tokens (never emitted), roll the headroom blocks
-        holding only over-decoded KV back into the allocator, stamp the
+    def _finish_chained(self, er: EngineRequest, emit: bool = False) -> None:
+        """A chained row finished: roll the headroom blocks the chain
+        reserved ahead of it (``_chain_reserve``; the frozen row never
+        wrote them) back into the allocator, stamp the
         ``decode_pipeline`` span, and free the slot.
 
-        The in-flight burst's writes to the rolled-back blocks are
-        harmless: the blocks are anonymous (never registered), and device
-        dispatch ordering lands those writes before any later program's
-        writes to a reallocated slot.
+        Rolling back under queued bursts is harmless: the blocks are
+        anonymous (never registered), frozen rows write no KV, and
+        device dispatch ordering lands earlier writes before any later
+        program's writes to a reallocated slot.
         """
         bs = self.config.kv_block_size
         keep = -(-er.context_len // bs)  # blocks covering committed KV
@@ -1845,29 +1661,6 @@ class Scheduler:
         # fallback passes emit=True — nothing was emitted there.
         self._finish(er, er.finish, emit=emit)
 
-    async def _drain_pipeline(self, loop) -> None:
-        """Sync barrier: reconcile the in-flight burst (if any) so every
-        synchronous consumer — preemption, prefill interleave, spec or
-        guided decode, shutdown — sees fully-committed host state."""
-        infl, self._inflight = self._inflight, None
-        if infl is None:
-            return
-        self.flight.record(
-            "scheduler.burst_drain", k_steps=infl.k_steps,
-            rows=len(infl.active),
-        )
-        await self._apply_burst(loop, infl)
-        for er in infl.active:
-            # still-live rows close their pipelined span here so the
-            # synchronous tail that follows is attributed separately
-            # (finished rows were stamped by _finish_pipelined; cancelled
-            # rows already carry their completion mark)
-            if er.finish is None and er.pipeline_span_open:
-                er.ctx.add_stage("decode_pipeline")
-                er.pipeline_span_open = False
-
-    # ---------- persistent decode loop (config.device_finish) ----------
-
     # bursts allowed in flight ahead of the async drain: beyond this the
     # dispatcher waits out the oldest sync (the device has CHAIN_MAX
     # bursts queued — it cannot run dry while the host catches up), so
@@ -1876,10 +1669,6 @@ class Scheduler:
 
     def _note_sync_fallback(self, reason: str) -> None:
         self._sync_fallback_ctr.inc(reason=reason)
-
-    def _chain_ok(self, active: List[EngineRequest],
-                  runner_idle: bool) -> bool:
-        return self._chain_block_reason(active, runner_idle) is None
 
     def _chain_block_reason(self, active: List[EngineRequest],
                             runner_idle: bool) -> Optional[str]:
@@ -1890,9 +1679,6 @@ class Scheduler:
         what remains is named here and counted per sync pass
         (dynamo_engine_sync_fallback_total{reason})."""
         cfg = self.config
-        if not (cfg.device_finish_enabled
-                and cfg.decode_pipeline_depth >= 2):
-            return "disabled"
         if not runner_idle:
             return "not_idle"
         if not active:
@@ -1931,17 +1717,12 @@ class Scheduler:
                 return "membership"
         return None
 
-    def _spec_chain_reason(self, active: List[EngineRequest],
-                           runner_idle: bool) -> Optional[str]:
+    def _spec_chain_reason(
+            self, active: List[EngineRequest]) -> Optional[str]:
         """Why can this pass NOT chain propose-verify rounds? (Callers
         established spec_now: speculation configured, runner idle, every
         row spec-eligible — greedy, penalty-free, unguided.)"""
         cfg = self.config
-        if not (cfg.device_finish_enabled
-                and cfg.decode_pipeline_depth >= 2):
-            return "disabled"
-        if not runner_idle:
-            return "not_idle"
         if self._chain_fp:
             return "stop_false_positive"
         if not getattr(self.runner, "spec_burst_ready",
@@ -2072,19 +1853,11 @@ class Scheduler:
         self._drain_lag_hist.observe(time.monotonic() - infl.dispatch_t)
 
     async def _chain_prologue(self, loop, active, kind):
-        """The shared open/validate ladder of a chained pass: reconcile
-        a predating plain dispatch-ahead burst, barrier on a chain-KIND
-        switch (plain ↔ spec program families), open the chain if none
-        is, and resolve the live member list. Returns ``(active, live,
-        members)`` or None — None means every fallback already ran and
-        the caller just returns."""
-        if self._inflight is not None:
-            # a plain dispatch-ahead burst predates this chain: reconcile
-            # it first so the chain starts from fully-committed state
-            await self._drain_pipeline(loop)
-            active = [er for er in active if er.finish is None]
-            if not active:
-                return None
+        """The shared open/validate ladder of a chained pass: barrier
+        on a chain-KIND switch (plain ↔ spec program families), open the
+        chain if none is, and resolve the live member list. Returns
+        ``(active, live, members)`` or None — None means every fallback
+        already ran and the caller just returns."""
         if self._chain_members and self._chain_kind not in (None, kind):
             await self._chain_barrier(loop)
             active = [er for er in active if er.finish is None]
@@ -2164,10 +1937,10 @@ class Scheduler:
         return tokens0, positions0, gen0, done0, ring0, gstate0
 
     def _chain_observe_bubble(self, tokens0) -> None:
-        """Device-idle bookkeeping (same approximation as the pipelined
-        path): a carry already materialized at dispatch time means the
-        device ran dry since the last reconciliation. Must run BEFORE
-        the dispatch consumes ``self._chain_carry``."""
+        """Device-idle bookkeeping (a host-observed approximation): a
+        carry already materialized at dispatch time means the device ran
+        dry since the last reconciliation. Must run BEFORE the dispatch
+        consumes ``self._chain_carry``."""
         now = time.monotonic()
         if self._last_burst_done_t is not None:
             if self._chain_carry is None:
@@ -2267,15 +2040,14 @@ class Scheduler:
             self.pipeline_bursts += 1
             self.flight.record(
                 "scheduler.burst_dispatch", k_steps=k_steps, rows=len(live),
-                pipelined=True, chained=True,
+                chained=True,
                 chain_len=self._chain_dispatched,
                 requests=[er.request_id for er in live[:8]],
             )
             dt = self.device_time
             self._chain.append(_InflightBurst(
                 active=list(live), toks=toks, lps=lps, tv=tv, ti=ti,
-                k_steps=k_steps, last_tokens=None,
-                dispatch_t=time.monotonic(), device_finish=True,
+                k_steps=k_steps, dispatch_t=time.monotonic(),
                 read_bytes=dt.decode_read_bytes(
                     k_steps,
                     sum(min(self._chain_pos0[er.slot] + n * k_steps,
@@ -2362,15 +2134,14 @@ class Scheduler:
             self.pipeline_bursts += 1
             self.flight.record(
                 "scheduler.burst_dispatch", k_steps=S, rows=len(live),
-                pipelined=True, chained=True, spec=True,
+                chained=True, spec=True,
                 chain_len=self._chain_dispatched,
                 requests=[er.request_id for er in live[:8]],
             )
             dt = self.device_time
             self._chain.append(_InflightBurst(
                 active=list(live), toks=toks, lps=None, tv=None, ti=None,
-                k_steps=S, last_tokens=None,
-                dispatch_t=time.monotonic(), device_finish=True,
+                k_steps=S, dispatch_t=time.monotonic(),
                 spec=True, nprop=nprop, nacc=nacc,
                 read_bytes=dt.decode_read_bytes(
                     1,
@@ -2416,7 +2187,7 @@ class Scheduler:
             await self._apply_chain_head(loop)
         if self._chain_members:
             self.flight.record(
-                "scheduler.burst_drain", chained=True, bursts=bursts,
+                "scheduler.burst_drain", bursts=bursts,
                 rows=len(self._chain_members),
             )
             for er in self._chain_members:
@@ -3723,7 +3494,7 @@ class Scheduler:
 
             # synchronous path: the device has been idle since the previous
             # burst's host sync completed — that gap IS the bubble the
-            # dispatch-ahead pipeline exists to close
+            # chain exists to close
             if self._last_burst_done_t is not None:
                 self._bubble_hist.observe(
                     time.monotonic() - self._last_burst_done_t
@@ -3732,7 +3503,6 @@ class Scheduler:
 
             self.flight.record(
                 "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
-                pipelined=False,
                 requests=[er.request_id for er in active[:8]],
             )
         with span("sched.decode.dispatch", step=self.passes,

@@ -79,8 +79,6 @@ def engine_config_from_mdc(mdc, flags=None, extra=None) -> EngineConfig:
         num_kv_blocks=getattr(flags, "num_kv_blocks", None) or 2048,
         multi_step_decode=getattr(flags, "multi_step_decode", 1) or 1,
         decode_pipeline_depth=getattr(flags, "decode_pipeline_depth", 1) or 1,
-        device_finish=getattr(flags, "device_finish", "auto") or "auto",
-        fused_epilogue=getattr(flags, "fused_epilogue", "auto") or "auto",
         # no `or 2` fallback: an explicit 0 must clamp to 1 (serial), not
         # silently flip back to double-buffered
         disagg_stream_depth=(
@@ -524,7 +522,7 @@ class JaxServingEngine(AsyncEngine):
                 self._json_grammars.pop(evictable.pop(0), None)
             self._json_grammars[key] = grammar  # resolve future → value
         else:
-            self._json_grammars.pop(key)
+            self._json_grammars.pop(key, None)
             self._json_grammars[key] = grammar  # LRU touch
         return JsonConstraint(grammar)
 

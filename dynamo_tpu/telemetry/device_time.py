@@ -1,12 +1,10 @@
 """Live device-time accounting + serving-time roofline attribution.
 
-``bench.py`` computes a roofline fraction OFFLINE (measured decode
-tokens/s over the HBM-bandwidth bound for the same model/batch); in
-serving, the engine was blind. This module
-is the live mirror: the scheduler already observes every compiled
-program's completion — the sync path's executor host-sync, the
-dispatch-ahead pipeline's reconciliation, the persistent loop's
-``is_ready`` row drain — so each observation feeds a
+The serving engine's own roofline fraction (achieved decode HBM bytes/s
+over the chip's peak for the live batch). The scheduler already
+observes every compiled program's completion — the sync path's
+executor host-sync, the persistent loop's ``is_ready`` row drain — so
+each observation feeds a
 :class:`DeviceTimeTracker` that derives, with **zero added host syncs
 on the hot path**:
 
@@ -19,8 +17,7 @@ on the hot path**:
   chip's peak for the decode phase: every decode step must stream the
   weights once plus each live row's KV context, so
   ``bytes = steps × (param_bytes + Σ ctx_i × kv_bytes_per_token)`` and
-  ``fraction = (bytes / busy_s) / peak`` — the exact serving-time twin
-  of bench.py's ``vs_baseline``.
+  ``fraction = (bytes / busy_s) / peak``.
 
 Busy time uses a serialized-interval estimator: the device executes its
 queue in order, so for observations arriving in completion order the
@@ -48,7 +45,7 @@ from typing import Callable, Deque, Optional, Tuple
 logger = logging.getLogger(__name__)
 
 # Peak HBM bandwidth of one chip in GB/s, keyed by jax ``device_kind`` —
-# the roofline denominator here and in bench.py. A kind that is not in
+# the roofline denominator. A kind that is not in
 # the table has no roofline: the gauge is not exported rather than
 # computed against another chip's number.
 # "TPU v5 lite": 819 GB/s — Google Cloud documentation, "TPU v5e".
@@ -125,8 +122,7 @@ class DeviceTimeTracker:
             self.registry.callback_gauge(
                 "dynamo_engine_roofline_fraction",
                 "Achieved decode HBM bytes/s over the chip's peak bandwidth "
-                "(weights once + live rows' KV per step) — the serving-time "
-                "mirror of bench.py's vs_baseline",
+                "(weights once + live rows' KV per step)",
                 self._roofline,
             )
         else:

@@ -28,8 +28,8 @@ that makes this safe is concentrated here:
 
 :class:`LoopbackIciTransfer` is the in-process stand-in with the same
 interface — the loopback differentials (tests/test_transfer_plane.py)
-and the ``xla:k8:ici-pull`` bench lever run the full negotiation,
-framing, and poison discipline on CPU without a second host.
+run the full negotiation, framing, and poison discipline on CPU without
+a second host.
 """
 
 from __future__ import annotations

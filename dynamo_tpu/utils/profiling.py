@@ -14,8 +14,8 @@ part of the serving surface:
   exposes it at ``GET /debug/profile`` when ``--profile-dir`` is set, so
   an operator can grab a trace of live traffic with one curl.
 
-Both are thin wrappers so non-serving code (bench.py, tests) can reuse
-the same entry points.
+Both are thin wrappers so non-serving code (tests) can reuse the same
+entry points.
 """
 
 from __future__ import annotations
@@ -35,7 +35,7 @@ _server_started = False
 # jax.profiler.trace is NOT reentrant: a second trace starting while one
 # is active crashes mid-capture (and can corrupt the first capture's
 # output). Every capture path — GET /debug/profile, an incident bundle's
-# --incident-profile-s window, bench harnesses — funnels through this
+# --incident-profile-s window — funnels through this
 # process-wide lock; a loser gets CaptureBusyError (→ a clean 409 /
 # "skipped" note) instead of a crash.
 _capture_lock = threading.Lock()

@@ -91,21 +91,6 @@ def _sp_prefix_case():
     return jax.jit(paged_prefix_attention_partials), args, None
 
 
-def _epilogue_case():
-    from dynamo_tpu.ops.pallas_epilogue import fused_sampling_epilogue
-
-    b, v = 8, 128256
-    f32 = lambda x: jnp.full((b,), x, jnp.float32)  # noqa: E731
-    scalars = (f32(1), jnp.zeros((b,), jnp.int32), f32(1), f32(0), f32(0),
-               f32(0), f32(1))
-    args = (jnp.ones((b, v), jnp.float32), jnp.zeros((b, v), jnp.float32),
-            scalars, jnp.zeros((b, v), jnp.int32),
-            jnp.zeros((b, v), jnp.bool_), jnp.zeros((b, v), jnp.float32),
-            jnp.arange(b, dtype=jnp.int32), jnp.ones((b,), jnp.bool_))
-    return jax.jit(lambda *a: fused_sampling_epilogue(
-        *a, max_model_len=2048)), args, None
-
-
 FP8 = jnp.float8_e4m3fn
 SINKS = dict(sinks=jnp.ones((4,), jnp.float32), sliding_window=jnp.int32(16))
 SOFTCAP = dict(softcap=50.0, sliding_window=jnp.int32(16))
@@ -144,7 +129,6 @@ CASES.update({
     "mla decode": (False, _mla_case),
     "mla decode fp8-kv": (False, lambda: _mla_case(FP8)),
     "sp paged-prefix partials": (False, _sp_prefix_case),
-    "fused sampling epilogue v128256": (False, _epilogue_case),
 })
 
 

@@ -135,18 +135,6 @@ def test_auto_routes_rejected_specializations_to_xla(monkeypatch):
         assert trace("auto") == want
 
 
-def test_fused_epilogue_auto_selects_nothing():
-    from dynamo_tpu.engine.model_runner import ModelRunner
-
-    picks = {
-        mode: ModelRunner._fused_epilogue_enabled(
-            type("R", (), {"config": EngineConfig(
-                model=ModelConfig(), fused_epilogue=mode)})())
-        for mode in ("auto", "on", "off")
-    }
-    assert picks == {"auto": False, "on": True, "off": False}
-
-
 # ---------- the default-route kernels lower for TPU ----------
 
 
@@ -215,43 +203,22 @@ def test_roofline_gauge_only_for_a_known_device_kind():
     assert "dynamo_engine_roofline_fraction" not in unknown.registry.render()
 
 
-# ---------- bench.py prints only what it just measured ----------
+# ---------- benchmark/run.py prints only what a chip measured ----------
 
 
-@pytest.fixture
-def bench(monkeypatch):
-    monkeypatch.delenv("BENCH_SMOKE", raising=False)
-    return _load(os.path.join(REPO, "bench.py"), "bench")
-
-
-def test_bench_child_refuses_a_cpu_backend(bench, monkeypatch):
-    monkeypatch.setattr(device, "configure_compile_cache", lambda: "")
-    with pytest.raises(SystemExit) as e:
-        bench._bench_device()
-    assert e.value.code == bench.NO_CHIP_RC
-    monkeypatch.setenv("BENCH_SMOKE", "1")
-    assert bench._bench_device().platform == "cpu"
-
-
-def test_bench_exits_nonzero_without_a_chip(bench, monkeypatch, capsys):
-    monkeypatch.setattr(
-        bench.subprocess, "run", lambda *a, **kw: subprocess.CompletedProcess(
-            a, bench.NO_CHIP_RC, stdout="", stderr="bench: backend is 'cpu'"))
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code == bench.NO_CHIP_RC
-    assert "{" not in capsys.readouterr().out
-
-
-def test_bench_exits_nonzero_without_a_result(bench, monkeypatch, capsys):
-    monkeypatch.setenv("BENCH_TOTAL_BUDGET_S", "1")
-    monkeypatch.setattr(
-        bench.subprocess, "run", lambda *a, **kw: subprocess.CompletedProcess(
-            a, 1, stdout="", stderr="boom"))
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code not in (0, None)
-    assert "{" not in capsys.readouterr().out
+@pytest.mark.parametrize("asks", ["JAX_PLATFORMS=cpu",
+                                  "DYN_PALLAS_INTERPRET=1"])
+def test_benchmark_refuses_to_run_without_a_chip(asks):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "DYN_PALLAS_INTERPRET")}
+    env.update([asks.split("=")])
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "phi3-chat", "--seed", "0", "--seconds", "1"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert "{" not in out.stdout
 
 
 # ---------- chip_smoke.py ----------

@@ -1,13 +1,13 @@
-"""Dispatch-ahead decode pipeline (EngineConfig.decode_pipeline_depth=2)
+"""The persistent decode loop (EngineConfig.decode_pipeline_depth=2)
 edge cases, driven by a deterministic fake runner.
 
-The fake models exactly the carry semantics the pipeline relies on —
-``next = f(prev_token, position)`` — so the synchronous and pipelined
+The fake models exactly the carry semantics the chain relies on —
+``next = f(prev_token, position)`` — so the synchronous and chained
 schedulers must produce byte-identical streams through every edge:
-finishes detected one burst late, preemption forcing a drain, and the
-guided/spec/``n>1`` fallbacks. The real-model differential lives in
-tests/test_multi_step.py; this file isolates the SCHEDULER's pipeline
-logic from the numerics.
+rows frozen on device mid-burst, preemption forcing a barrier, and the
+guided/spec/``n>1``/refused-batch fallbacks. The real-model differential
+lives in tests/test_multi_step.py; this file isolates the SCHEDULER's
+chain logic from the numerics.
 """
 
 import asyncio
@@ -33,7 +33,7 @@ class FakeRunner:
     bias-row argmax of ``-(|id - target|)`` with ``target = (prev * 7 +
     pos * 13 + 1) % vocab`` — a pure function of the carry and the
     slot's installed mask, so any scheduling (per-token, fused burst,
-    dispatch-ahead, chained, guided via bias rows OR via the device
+    chained, guided via bias rows OR via the device
     transition table, preempt + re-prefill resume) must reproduce the
     same stream. With a zero bias row the argmax IS ``target`` (the
     original rule); a guided mask steers it to the nearest allowed id
@@ -409,29 +409,29 @@ def _streams(depth, max_tokens=21, eos=None, k=4, sched_out=None, **cfg_kw):
 
 
 def test_differential_greedy_streams_identical():
-    """Pipelined greedy decode must emit byte-identical streams vs sync —
+    """Depth-2 greedy decode must emit byte-identical streams vs sync —
     token ids, logprob carriers, and finish reasons."""
     box = {}
     want = _streams(1)
     got = _streams(2, sched_out=box)
     assert got == want
-    assert box["sched"].pipeline_bursts > 0, "pipeline never engaged"
-    assert box["sched"]._inflight is None
+    assert box["sched"].pipeline_bursts > 0, "chain never engaged"
+    assert not box["sched"]._chain and not box["sched"]._chain_members
 
 
-def test_eos_one_burst_late_stream_identical():
-    """EOS lands mid-burst and is detected one burst late under depth 2:
-    the over-decoded rows must be truncated so the stream (and finish
-    reason) is identical to the sync path, and every rolled-back block
-    must return to the allocator."""
+def test_eos_on_last_step_of_burst_stream_identical():
+    """EOS lands on the LAST step of a burst while later bursts are
+    already queued behind it: the row freezes there, the stream (and
+    finish reason) is identical to the sync path, and every block
+    reserved ahead of it returns to the allocator."""
     # find the greedy continuation, then make its 6th token the eos: with
-    # K=4 it lands in burst 2 while burst 3 is already in flight
-    plain = _streams(1, max_tokens=24)
+    # K=2 it is the last step of burst 3
+    plain = _streams(1, max_tokens=24, k=2)
     eos = [plain[0][0][5]]
-    want = _streams(1, max_tokens=24, eos=eos)
+    want = _streams(1, max_tokens=24, eos=eos, k=2)
     assert want[0][1] == "eos" and len(want[0][0]) <= 6
     box = {}
-    got = _streams(2, max_tokens=24, eos=eos, sched_out=box)
+    got = _streams(2, max_tokens=24, eos=eos, k=2, sched_out=box)
     assert got == want
     sched = box["sched"]
     assert sched.pipeline_bursts > 0
@@ -442,11 +442,11 @@ def test_single_step_pipeline_identical():
     assert _streams(2, k=1) == _streams(1, k=1)
 
 
-def test_preemption_drains_pipeline_and_stream_continues():
-    """KV OOM under dispatch-ahead must force a sync barrier (drain)
-    before preemption — and the resumed streams still total max_tokens
-    with the identical prefix, matching the unconstrained run."""
-    want = _streams(1, max_tokens=24, num_kv_blocks=64)
+def test_preemption_closes_single_step_chain_and_stream_continues():
+    """KV OOM under the K=1 chain must close it at a barrier before
+    preemption — and the resumed streams still total max_tokens with the
+    identical prefix, matching the unconstrained run."""
+    want = _streams(1, max_tokens=24, k=1, num_kv_blocks=64)
 
     preempts = []
 
@@ -454,8 +454,8 @@ def test_preemption_drains_pipeline_and_stream_continues():
         orig = sched._preempt
 
         def spy(er):
-            # the pipeline must be fully reconciled when preemption runs
-            assert sched._inflight is None, \
+            # the chain must be fully reconciled when preemption runs
+            assert not sched._chain, \
                 "preempted with a burst still in flight"
             preempts.append(er.request_id)
             orig(er)
@@ -463,9 +463,9 @@ def test_preemption_drains_pipeline_and_stream_continues():
         sched._preempt = spy
 
     # (3 prompts + 24 new tokens) doesn't fit in 10 blocks even at the
-    # sync path's K-position reservation, so the pipelined OOM first
-    # degrades to sync (drain) and the sync path then preempts
-    config = _config(2, num_kv_blocks=10)
+    # sync path's one-position reservation, so the chain's OOM first
+    # degrades to sync (barrier) and the sync path then preempts
+    config = _config(2, k=1, num_kv_blocks=10)
     reqs = [_request(p, 24) for p in PROMPTS]
     box = {}
 
@@ -475,7 +475,7 @@ def test_preemption_drains_pipeline_and_stream_continues():
 
     got = _run(config, reqs, hooks=hooks)
     assert preempts, "test is vacuous: no preemption happened"
-    assert box["sched"].pipeline_bursts > 0, "pipeline never engaged"
+    assert box["sched"].pipeline_bursts > 0, "chain never engaged"
     assert got == want
 
 
@@ -487,15 +487,15 @@ def _pipeline_stays_cold(config, reqs):
 
     out = _run(config, reqs, hooks=grab)
     sched = box["sched"]
-    assert sched.pipeline_bursts == 0, "pipelined dispatch on a sync-only shape"
-    assert sched._inflight is None
+    assert sched.pipeline_bursts == 0, "chained dispatch on a sync-only shape"
+    assert not sched._chain
     return out
 
 
 def test_guided_requests_force_sync_path_when_table_disabled():
     """With guided_device_table off, guided rows keep the per-token host
-    mask path (no chain, no pipeline burst) and the fallback counter
-    names the reason."""
+    mask path (no chained burst) and the fallback counter names the
+    reason."""
     config = _config(2, guided_device_table=False)
     sampling = SamplingOptions(
         temperature=0.0,
@@ -605,37 +605,23 @@ def test_prefill_arrival_drains_then_resumes_pipeline():
     assert got[1] == want[1][0]
 
 
-def test_near_horizon_rows_fall_back_to_sync():
-    """Rows within two bursts of max_model_len must decode synchronously
-    (the burst would write past the block-table horizon) and still end
-    with finish reason length at the same point as the sync path."""
-    want = _streams(1, max_tokens=200, max_model_len=32)
-    box = {}
-    got = _streams(2, max_tokens=200, max_model_len=32,
-                   device_finish="off", sched_out=box)
-    assert got == want
-    assert all(f == "length" for _, f in got)
-    assert box["sched"]._inflight is None
-
-
 # --------------------------------------------------------------------------
-# device-resident finish detection (config.device_finish) — the
-# persistent decode loop: chained bursts, frozen rows, async row drain
+# device-resident finish detection — the persistent decode loop:
+# chained bursts, frozen rows, async row drain
 # --------------------------------------------------------------------------
 
 
 def test_device_finish_differential_streams_identical():
-    """Streams must be byte-identical with device-finish on vs off —
-    token ids, logprob carriers, finish reasons — and the chained path
-    must actually engage: bursts dispatched between host barriers > 1
-    (the host barrier is no longer per burst)."""
+    """Streams must be byte-identical chained (depth 2) vs sync (depth
+    1) — token ids, logprob carriers, finish reasons — and the chained
+    path must actually engage: bursts dispatched between host barriers
+    > 1 (the host barrier is no longer per burst)."""
     want = _streams(1)
-    off_box, on_box = {}, {}
-    off = _streams(2, device_finish="off", sched_out=off_box)
-    on = _streams(2, sched_out=on_box)  # auto: enabled at depth 2
-    assert off == want
+    sync_box, on_box = {}, {}
+    assert _streams(1, sched_out=sync_box) == want
+    on = _streams(2, sched_out=on_box)
     assert on == want
-    assert off_box["sched"].runner.chained_calls == 0
+    assert sync_box["sched"].runner.chained_calls == 0
     sched = on_box["sched"]
     assert sched.runner.chained_calls > 1
     assert sched._last_chain_len > 1, "host barrier still per burst"
@@ -645,9 +631,8 @@ def test_device_finish_differential_streams_identical():
 
 
 def test_device_finish_eos_mid_burst_freezes_row():
-    """EOS landing mid-burst under device finish: the row freezes ON
-    DEVICE at exactly the stop token (no over-decode at all — nothing
-    emits after it), the stream matches the sync path byte-for-byte,
+    """EOS landing mid-burst: the row freezes ON DEVICE at exactly the
+    stop token (no over-decode at all — nothing emits after it), the stream matches the sync path byte-for-byte,
     and the reserved headroom blocks all roll back."""
     plain = _streams(1, max_tokens=24)
     eos = [plain[0][0][5]]  # lands mid-burst at K=4
@@ -665,7 +650,7 @@ def test_device_finish_eos_mid_burst_freezes_row():
 def test_device_finish_max_tokens_at_burst_boundary():
     """max_tokens an exact multiple of K: the LENGTH finish lands on the
     last step of a burst — the device mask must freeze the row there
-    (not one burst late) and the stream must match the sync path."""
+    and the stream must match the sync path."""
     for mt in (8, 12):  # K=4 boundaries
         want = _streams(1, max_tokens=mt)
         box = {}
@@ -676,41 +661,65 @@ def test_device_finish_max_tokens_at_burst_boundary():
         assert box["sched"].allocator.used == 0
 
 
-def test_stop_string_rows_forced_to_sync_path():
-    """Stop STRINGS need the backend's host-side post-check (the jail) —
-    such rows are classified not-device-checkable at admission and the
-    chain must never engage; the PR 3 per-burst-reconciled pipeline
-    serves them instead, with the stream unchanged."""
-    config = _config(2)
+def _refused_batch(case):
+    """A batch with a row the chain cannot check, and that row's
+    ``EngineRequest.classify_finish`` reason."""
+    from dynamo_tpu.engine.sampling import STOP_SEQ_MAX_LEN
 
-    def reqs():
-        out = []
-        for p in PROMPTS:
-            req = PreprocessedRequest(
-                token_ids=list(p),
-                stop_conditions=StopConditions(max_tokens=12, ignore_eos=True,
-                                               stop=["never-matches"]),
-                sampling_options=SamplingOptions(temperature=0.0),
-                eos_token_ids=[],
-            )
-            out.append(EngineRequest(
-                request_id=uuid.uuid4().hex, prompt=list(p), req=req,
-                ctx=AsyncEngineContext(), out_queue=asyncio.Queue(),
-            ))
-        return out
-    rs = reqs()
-    assert all(not er.device_checkable for er in rs)
-    box = {}
+    eos17 = list(range(400, 417))  # one id past STOP_ID_WIDTH
+    if case == "stop_ids_overflow":
+        return case, [_request(p, 12, eos=eos17) for p in PROMPTS]
+    if case == "stop_seqs_overflow":
+        long_seq = list(range(300, 301 + STOP_SEQ_MAX_LEN))
+        return case, [_stop_seq_request(p, 12, [long_seq]) for p in PROMPTS]
+    if case == "stop_seqs_unavailable":
+        # stop STRINGS with no canonical token sequences shipped: only
+        # the backend's host-side post-check can see them
+        return case, [_stop_seq_request(p, 12, [], stop=["never-matches"])
+                      for p in PROMPTS]
+    assert case == "mixed"
+    return "stop_ids_overflow", [
+        _request(PROMPTS[0], 12), _request(PROMPTS[1], 12, eos=eos17),
+    ]
 
-    def grab(s):
-        box["sched"] = s
 
-    got = _run(config, rs, hooks=grab)
-    sched = box["sched"]
-    assert sched.runner.chained_calls == 0, "chained a stop-string row"
-    assert sched.pipeline_bursts > 0, "PR 3 pipeline should still engage"
-    want = _run(_config(1), reqs())
+@pytest.mark.parametrize("case", [
+    "stop_ids_overflow", "stop_seqs_overflow", "stop_seqs_unavailable",
+    "mixed",
+])
+def test_depth2_refused_batch_decodes_sync(case):
+    """Depth 2 means the chain and nothing else: a batch with a row the
+    chain cannot check decodes on the synchronous path — never two
+    bursts outstanding — with the depth-1 stream and the refusal counted
+    under the row's reason."""
+    reason, rs = _refused_batch(case)
+    assert reason in {er.chain_fallback for er in rs}
+    want = _run(_config(1), _refused_batch(case)[1])
+    box = {"outstanding": 0, "peak": 0}
+
+    def hooks(sched):
+        box["sched"] = sched
+        burst, synced = sched.runner.decode_burst, sched._observe_host_sync
+
+        def dispatch(*a, **kw):
+            box["outstanding"] += 1
+            box["peak"] = max(box["peak"], box["outstanding"])
+            return burst(*a, **kw)
+
+        def sync(dt):
+            box["outstanding"] = 0
+            synced(dt)
+
+        sched.runner.decode_burst = dispatch
+        sched._observe_host_sync = sync
+
+    got = _run(_config(2), rs, hooks=hooks)
     assert got == want
+    sched = box["sched"]
+    assert reason in _fallback_reasons(sched)
+    assert sched.runner.chained_calls == 0, "chained an uncheckable row"
+    assert sched.pipeline_bursts == 0
+    assert box["peak"] == 1, "a burst was dispatched ahead of a host sync"
 
 
 def test_preemption_kv_oom_drains_chain_before_membership_changes():
@@ -728,7 +737,6 @@ def test_preemption_kv_oom_drains_chain_before_membership_changes():
             assert not sched._chain, "preempted with chained bursts in flight"
             assert not sched._chain_members, \
                 "preempted before the chain membership barrier"
-            assert sched._inflight is None
             preempts.append(er.request_id)
             orig(er)
 
@@ -749,8 +757,8 @@ def test_preemption_kv_oom_drains_chain_before_membership_changes():
 
 
 def test_device_finish_near_horizon_rows_stay_chained():
-    """Under device finish, rows near max_model_len do NOT fall back to
-    sync (the PR 3 behavior): the device's LENGTH check (pos + 2 >=
+    """Rows near max_model_len do NOT fall back to sync: the device's
+    LENGTH check (pos + 2 >=
     max_model_len — the in-scan mirror of _check_finish's context_len +
     1 bound) freezes them at exactly the horizon, headroom reservation
     caps at max_model_len - 1, and the streams still match the sync
@@ -762,18 +770,17 @@ def test_device_finish_near_horizon_rows_stay_chained():
     assert all(f == "length" for _, f in got)
     sched = box["sched"]
     assert sched.runner.chained_calls > 0, \
-        "near-horizon rows forced sync under device finish"
+        "near-horizon rows forced sync"
     # every LENGTH finish at the horizon was detected on device
     assert sum(sched._device_finished_ctr.values.values()) == len(PROMPTS)
     assert sched.allocator.used == 0
     assert not sched._chain and not sched._chain_members
 
 
-def test_late_drain_retro_invalidation_rolls_back_blocks():
+def test_late_drain_rolls_back_reserved_headroom():
     """The chain reserves block headroom against its own dispatch count,
     so a row finishing deep into a chain holds blocks covering positions
-    it froze before reaching — the drain's retro-invalidation must roll
-    that tail back into the allocator (rollback_tail observed with a
+    it froze before reaching — the drain must roll that tail back into the allocator (rollback_tail observed with a
     shrinking keep) and leak nothing."""
     rollbacks = []
 
@@ -815,7 +822,7 @@ def _run_chained(with_tracker):
     through it). Returns (streams, sync_count, tracker_or_None)."""
     from dynamo_tpu.telemetry.device_time import DeviceTimeTracker
 
-    config = _config(2)  # device_finish auto → on at depth 2
+    config = _config(2)
     reqs = [_request(p, 21) for p in PROMPTS]
     syncs = []
     box = {}

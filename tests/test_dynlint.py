@@ -626,14 +626,13 @@ def test_dynamo_tpu_lints_clean_modulo_baseline():
 
 
 def test_kernel_campaign_ops_modules_are_jit_impure_clean():
-    """The kernel-campaign modules — the SP paged prefix walk, the
-    fused sampling epilogue, and the decode kernels they share helpers
-    with — must carry ZERO jit-impure findings, with no baseline
+    """The kernel-campaign modules — the SP paged prefix walk and the
+    decode kernels it shares helpers with — must carry ZERO jit-impure
+    findings, with no baseline
     allowance: host-effect Python inside these traced bodies would fire
     once per Mosaic specialization compile and skew every differential."""
     mods = [
         os.path.join(PACKAGE_ROOT, "ops", "pallas_sp.py"),
-        os.path.join(PACKAGE_ROOT, "ops", "pallas_epilogue.py"),
         os.path.join(PACKAGE_ROOT, "ops", "pallas_decode.py"),
     ]
     found = lint_paths(mods, get_rules(["jit-impure"]))
@@ -676,13 +675,13 @@ def test_scoped_paths_produce_baseline_stable_keys():
 
 
 # --------------------------------------------------------------------------
-# dispatch-ahead decode pipeline: the hot loop's purity contract
+# the decode chain: the hot loop's purity contract
 # --------------------------------------------------------------------------
 
 
 @pytest.mark.dynlint
 def test_decode_pipeline_modules_pass_jit_impure_and_async_blocking():
-    """The pipelined decode path lives or dies on two properties dynlint
+    """The chained decode path lives or dies on two properties dynlint
     polices: no host syncs inside the traced burst program (jit-impure)
     and no blocking calls on the scheduler's event loop (async-blocking
     — the executor-side token sync must be the only host sync in the

@@ -269,6 +269,32 @@ def test_json_engine_end_to_end_parses():
         assert g.run_piece(g.initial(), text) is not None
 
 
+def test_json_grammar_released_twice_is_no_key_error():
+    """A follower that awaited a concurrent build touches the key's LRU
+    slot when it wakes — after other specs' builds may have evicted it
+    (the 32-entry bound). The second release of the key must not raise
+    on the serving path."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.guided import JsonConstraint
+
+    async def run():
+        spec = {"type": "json_object"}
+        key = _json.dumps(spec, sort_keys=True)
+        build = asyncio.get_running_loop().create_future()
+        eng = SimpleNamespace(_json_grammars={key: build})
+        follower = asyncio.ensure_future(
+            JaxServingEngine._json_constraint(eng, spec))
+        await asyncio.sleep(0)          # the follower awaits the build
+        build.set_result(JsonGrammar(PIECES))
+        eng._json_grammars.pop(key)     # evicted before the follower wakes
+        return await follower, key, eng
+
+    cursor, key, eng = asyncio.run(run())
+    assert isinstance(cursor, JsonConstraint)
+    assert key in eng._json_grammars
+
+
 def test_json_engine_sampled_conformance():
     """Sampled decoding (several seeds) stays inside the grammar."""
     async def run():

@@ -345,40 +345,6 @@ async def test_profile_endpoint_absent_without_dir():
         await service.stop()
 
 
-@pytest.mark.asyncio
-async def test_loadgen_sweep_against_echo_service():
-    """The benchmark load generator (examples/llm/benchmarks/loadgen.py —
-    the reference's genai-perf sweep analog) runs a 2-level sweep against
-    the echo engine and reports sane stats (GPU/TPU-free, same pattern
-    as the reference's CI: fake engines behind the real frontend)."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "loadgen",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))),
-            "examples", "llm", "benchmarks", "loadgen.py"),
-    )
-    loadgen = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(loadgen)
-
-    service = await start_echo_service()
-    try:
-        prompt = loadgen.build_prompt(8, None)
-        levels = await loadgen.sweep(
-            f"http://127.0.0.1:{service.port}", "echo", prompt,
-            osl=8, requests=6, levels=[1, 3],
-        )
-    finally:
-        await service.stop()
-    assert [lv["concurrency"] for lv in levels] == [1, 3]
-    for lv in levels:
-        assert lv["ok"] == 6 and lv["errors"] == 0
-        assert lv["req_per_s"] > 0
-        assert lv["ttft_p50_ms"] >= 0 and lv["ttft_p95_ms"] >= lv["ttft_p50_ms"]
-
-
 def test_metrics_callback_gauges_render():
     """Engine metrics registered as callback gauges appear on /metrics
     renders, pulled fresh each time; a failing callback renders nothing

@@ -860,3 +860,64 @@ def test_extra_engine_args_override_model_and_engine_fields(hf_model_dir):
 
     with pytest.raises(ValueError, match="no ModelConfig or EngineConfig"):
         engine_config_from_mdc(mdc, extra={"not_a_field": 1})
+
+
+def test_cli_defaults_are_the_dataclass_defaults(hf_model_dir):
+    """The benchmark's cells run the CLI's defaults and tier-1 runs the
+    dataclass's: ``engine_config_from_mdc`` joins them through
+    ``getattr(flags, name, default)``, which would hide a flag that has
+    gone or a default that drifted."""
+    import dataclasses
+
+    from dynamo_tpu.cli.run import build_parser
+    from dynamo_tpu.engine.serving import engine_config_from_mdc
+
+    mdc = ModelDeploymentCard.from_local_path(hf_model_dir)
+    bare = engine_config_from_mdc(mdc)
+    cli = engine_config_from_mdc(mdc, flags=build_parser().parse_args([]))
+    for f in dataclasses.fields(EngineConfig):
+        assert getattr(cli, f.name) == getattr(bare, f.name), f.name
+
+
+@pytest.mark.parametrize("flag", ["--device-finish", "--fused-epilogue"])
+def test_removed_decode_switch_is_an_argparse_error(flag, capsys):
+    from dynamo_tpu.cli.run import build_parser
+
+    with pytest.raises(SystemExit) as e:
+        build_parser().parse_args([flag, "on"])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
+
+
+def _benchmark_performance_switches():
+    """The names benchmark/harness/server.py refuses in a configuration's
+    ``serve`` group (read, not copied: the list is the benchmark's)."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness.server import PERFORMANCE_SWITCHES
+
+    return PERFORMANCE_SWITCHES
+
+
+@pytest.mark.parametrize("key", _benchmark_performance_switches())
+def test_a_performance_switch_is_a_config_field_or_refused(hf_model_dir, key):
+    """Every switch the benchmark keeps at its default still means
+    something: it names a field of the program's configuration, or — a
+    switch whose path was removed — the engine refuses it by name
+    instead of ignoring it."""
+    import dataclasses
+
+    from dynamo_tpu.engine.serving import engine_config_from_mdc
+
+    fields = {f.name for c in (ModelConfig, EngineConfig)
+              for f in dataclasses.fields(c)}
+    if key in fields:
+        return
+    mdc = ModelDeploymentCard.from_local_path(hf_model_dir)
+    with pytest.raises(ValueError, match="no ModelConfig or EngineConfig"):
+        engine_config_from_mdc(mdc, extra={key: "on"})

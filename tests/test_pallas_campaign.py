@@ -1,6 +1,6 @@
 """Interpret-mode differentials for the kernel campaign.
 
-Three kernels, each checked against the engine's pre-existing XLA
+Two kernels, each checked against the engine's pre-existing XLA
 formulation (the same strategy as tests/test_pallas_decode.py):
 
 - the sequence-parallel ring-prefill's paged prefix walk
@@ -9,11 +9,7 @@ formulation (the same strategy as tests/test_pallas_decode.py):
   materializes the gathered [1, W·bs, KVH, D] prefix;
 - the verify kernel's softcap / sinks / fp8-KV specializations
   (ops/pallas_decode.paged_verify_attention) vs the gather/softmax
-  reference;
-- the fused sampling epilogue (ops/pallas_epilogue.py) vs the dense
-  ladder in engine/sampling.py — BIT-identical, not allclose: the
-  kernel replicates the ladder's exact op sequence so the Pallas and
-  XLA engines emit the same tokens from the same seeds.
+  reference.
 """
 
 import jax
@@ -24,7 +20,6 @@ import pytest
 from dynamo_tpu.engine import sampling as S
 from dynamo_tpu.ops.attention import paged_attention
 from dynamo_tpu.ops.pallas_decode import paged_verify_attention
-from dynamo_tpu.ops.pallas_epilogue import fused_sampling_epilogue
 from dynamo_tpu.parallel.mesh import make_mesh
 from dynamo_tpu.parallel.sequence import sp_chunk_attention
 
@@ -206,157 +201,3 @@ def test_verify_fp8_kv_matches_xla_reference(variant):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
     )
-
-
-# --------------------------------------------------------------------------
-# fused sampling epilogue: bit-identical to the dense ladder
-# --------------------------------------------------------------------------
-
-_B, _V, _NS = 6, 64, 8
-_MAX_LEN = 512
-
-
-def _epilogue_case():
-    rng = np.random.default_rng(1)
-    last_logits = jnp.asarray(rng.normal(size=(_B, _V)) * 4, jnp.float32)
-    counts = jnp.asarray(rng.integers(0, 3, size=(_NS, _V)), jnp.int32)
-    seen = jnp.asarray(rng.integers(0, 2, size=(_NS, _V)), jnp.bool_)
-    bias = jnp.asarray(rng.normal(size=(_NS, _V)) * 0.5, jnp.float32)
-    # one row per regime: greedy, top-k, top-p, min-p + penalties,
-    # top-k + repetition, greedy again
-    params = S.SamplingParams(
-        temperature=jnp.asarray([0.0, 0.7, 1.0, 1.3, 0.9, 0.0], jnp.float32),
-        top_k=jnp.asarray([0, 5, 0, 0, 3, 0], jnp.int32),
-        top_p=jnp.asarray([1.0, 1.0, 0.9, 1.0, 0.8, 1.0], jnp.float32),
-        min_p=jnp.asarray([0.0, 0.0, 0.0, 0.2, 0.05, 0.0], jnp.float32),
-        presence_penalty=jnp.asarray(
-            [0.0, 0.5, 0.0, 1.1, 0.0, 0.0], jnp.float32),
-        frequency_penalty=jnp.asarray(
-            [0.0, 0.0, 0.3, 0.2, 0.0, 0.0], jnp.float32),
-        repetition_penalty=jnp.asarray(
-            [1.0, 1.2, 1.0, 1.05, 1.3, 1.0], jnp.float32),
-        keys=jnp.asarray(rng.integers(0, 2**32, size=(_B, 2)), jnp.uint32),
-        counters=jnp.asarray(rng.integers(0, 100, size=(_B,)), jnp.int32),
-    )
-    scalars = (
-        params.temperature, params.top_k, params.top_p, params.min_p,
-        params.presence_penalty, params.frequency_penalty,
-        params.repetition_penalty,
-    )
-    # the engine precomputes the gumbel field outside the kernel —
-    # argmax(gumbel + logits) IS jax.random.categorical's sampler, so
-    # sharing row keys keeps the token stream identical to the ladder
-    row_keys = S._row_keys(params)
-    gum = jax.vmap(
-        lambda kk: jax.random.gumbel(kk, (_V,), jnp.float32))(row_keys)
-    return rng, last_logits, counts, seen, bias, params, scalars, gum
-
-
-def _epilogue_reference(case, slots, commit, extra=None, finish=None):
-    _, last_logits, counts, seen, bias, params, _, _ = case
-    row_bias = bias[slots]
-    if extra is not None:
-        row_bias = row_bias + extra
-    nt = S.sample(last_logits, params, counts[slots], seen[slots], row_bias)
-    logp = jax.nn.log_softmax((last_logits + row_bias).astype(jnp.float32))
-    lps = logp[jnp.arange(_B), nt]
-    cnt_out = counts.at[slots, nt].add(commit.astype(jnp.int32))
-    if finish is None:
-        return nt, lps, cnt_out
-    gen, pos, min_new, max_new, stop_ids, ring, sh, sl = finish
-    gen_n = gen + commit.astype(jnp.int32)
-    hard = S.device_finish_mask(
-        nt, gen_n, pos, stop_ids, min_new, max_new, _MAX_LEN)
-    ring_n = S.ring_push(ring, nt, commit)
-    cand = S.stop_candidate_mask(ring_n, gen_n, min_new, sh, sl)
-    return nt, lps, cnt_out, hard, cand, ring_n
-
-
-def _assert_bit_identical(got, ref):
-    assert len(got) == len(ref)
-    for i, (g, r) in enumerate(zip(got, ref)):
-        np.testing.assert_array_equal(
-            np.asarray(g), np.asarray(r), err_msg=f"output {i}")
-
-
-def test_epilogue_bit_identical_plain_and_guided():
-    """Mixed sampling regimes in one batch, aliased in-kernel count
-    commit; then the guided-decoding extra-bias operand on top."""
-    case = _epilogue_case()
-    rng, last_logits, counts, seen, bias, _, scalars, gum = case
-    slots = jnp.asarray([3, 0, 5, 1, 7, 2], jnp.int32)  # unique
-    commit = jnp.asarray([1, 1, 0, 1, 1, 0], jnp.bool_)
-
-    got = fused_sampling_epilogue(
-        last_logits, gum, scalars, counts, seen, bias, slots, commit,
-        max_model_len=_MAX_LEN, interpret=True,
-    )
-    _assert_bit_identical(got, _epilogue_reference(case, slots, commit))
-
-    extra = jnp.where(
-        jnp.asarray(rng.integers(0, 4, size=(_B, _V))) == 0, -1e9, 0.0,
-    ).astype(jnp.float32)
-    got = fused_sampling_epilogue(
-        last_logits, gum, scalars, counts, seen, bias, slots, commit,
-        extra_bias=extra, max_model_len=_MAX_LEN, interpret=True,
-    )
-    _assert_bit_identical(
-        got, _epilogue_reference(case, slots, commit, extra=extra))
-
-
-def test_epilogue_bit_identical_finish_fusion():
-    """The chained-burst tail: device_finish_mask, the suffix-ring push
-    and the rolling-hash stop-sequence candidate mask all fused behind
-    sampling — against the unfused engine/sampling.py ops."""
-    case = _epilogue_case()
-    rng, last_logits, counts, seen, bias, _, scalars, gum = case
-    slots = jnp.asarray([3, 0, 5, 1, 7, 2], jnp.int32)
-    commit = jnp.asarray([1, 1, 0, 1, 1, 0], jnp.bool_)
-
-    gen = jnp.asarray(rng.integers(0, 40, size=(_B,)), jnp.int32)
-    pos = jnp.asarray(rng.integers(0, 500, size=(_B,)), jnp.int32)
-    min_new = jnp.asarray([0, 0, 5, 0, 60, 0], jnp.int32)
-    max_new = jnp.asarray([39, 100, 100, 2, 100, 100], jnp.int32)
-    stop_ids = jnp.full((_B, S.STOP_ID_WIDTH), -1, jnp.int32)
-    stop_ids = stop_ids.at[:, 0].set(7)  # token 7 is an eos everywhere
-    ring = jnp.asarray(
-        np.stack([
-            S.ring_init(rng.integers(0, _V, size=20).tolist())
-            for _ in range(_B)
-        ]),
-        jnp.int32,
-    )
-    # per-row watched suffixes whose hash prefix matches the live ring
-    # tail, so a sampled continuation CAN complete them
-    sh = np.zeros((_B, S.STOP_SEQ_WIDTH), np.uint32)
-    sl = np.zeros((_B, S.STOP_SEQ_WIDTH), np.int32)
-    for r in range(_B):
-        sh[r, 0] = S.stop_seq_hash([int(ring[r, -1]), 11])
-        sl[r, 0] = 2
-        sh[r, 1] = S.stop_seq_hash([int(t) for t in ring[r, -3:]])
-        sl[r, 1] = 3
-    fin = (gen, pos, min_new, max_new, stop_ids, ring,
-           jnp.asarray(sh), jnp.asarray(sl))
-
-    got = fused_sampling_epilogue(
-        last_logits, gum, scalars, counts, seen, bias, slots, commit,
-        finish=fin, max_model_len=_MAX_LEN, interpret=True,
-    )
-    _assert_bit_identical(
-        got, _epilogue_reference(case, slots, commit, finish=fin))
-
-
-def test_epilogue_bit_identical_duplicate_slots():
-    """The batched-prefill step's pad rows share slot 0 — the aliased
-    in-kernel commit would double-count them, so that path runs
-    alias_counts=False (the commit scatters outside the kernel) and
-    must still be bit-identical."""
-    case = _epilogue_case()
-    _, last_logits, counts, seen, bias, _, scalars, gum = case
-    slots = jnp.asarray([0, 2, 0, 0, 4, 0], jnp.int32)
-    commit = jnp.asarray([1, 1, 0, 0, 1, 0], jnp.bool_)
-    got = fused_sampling_epilogue(
-        last_logits, gum, scalars, counts, seen, bias, slots, commit,
-        alias_counts=False, max_model_len=_MAX_LEN, interpret=True,
-    )
-    _assert_bit_identical(got, _epilogue_reference(case, slots, commit))

@@ -146,10 +146,8 @@ def test_sp_stream_matches_dense_ladder(model_dir):
 def test_sp_pallas_kernel_route_stream_matches_xla(model_dir, monkeypatch):
     """The whole-engine kernel-campaign differential: an SP engine
     serving on the Pallas route (interpret mode on CPU — the paged
-    prefix-walk kernel inside sp_chunk_attention AND the fused sampling
-    epilogue, selected explicitly: fused_epilogue=auto never picks a
-    kernel that does not lower for TPU) must emit the same decode
-    stream as the XLA-route engine, greedy and seeded."""
+    prefix-walk kernel inside sp_chunk_attention) must emit the same
+    decode stream as the XLA-route engine, greedy and seeded."""
     monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     from dynamo_tpu.llm.model_card import ModelDeploymentCard as MDC
     from dynamo_tpu.ops import attention as attn
@@ -164,7 +162,6 @@ def test_sp_pallas_kernel_route_stream_matches_xla(model_dir, monkeypatch):
         mdc = MDC.from_local_path(model_dir)
         cfg = _config(model_dir, sp=8)
         cfg.model.attention_impl = impl
-        cfg.fused_epilogue = "on" if impl == "pallas" else "auto"
         engine = await JaxServingEngine.create(
             mdc, engine_config=cfg, warmup=False,
         )
@@ -177,17 +174,14 @@ def test_sp_pallas_kernel_route_stream_matches_xla(model_dir, monkeypatch):
                            max_tokens=8),
         ]
         chunks = sum(engine.scheduler._sp_chunks_c.values.values())
-        fused = engine.scheduler.runner._fused_epilogue_enabled()
         used = engine.scheduler.allocator.used
         await engine.close()
-        return res, chunks, fused, used
+        return res, chunks, used
 
     base_kernel = routed("sp_ring_kernel")
-    xla_res, x_chunks, x_fused, x_used = asyncio.run(go("xla"))
-    assert not x_fused  # auto keeps the XLA tail
+    xla_res, x_chunks, x_used = asyncio.run(go("xla"))
     assert routed("sp_ring_kernel") == base_kernel
-    pal_res, p_chunks, p_fused, p_used = asyncio.run(go("pallas"))
-    assert p_fused     # "on" fuses the tail (interpret mode here)
+    pal_res, p_chunks, p_used = asyncio.run(go("pallas"))
     assert routed("sp_ring_kernel") > base_kernel
     assert xla_res == pal_res
     assert x_chunks >= 2 and p_chunks >= 2

@@ -126,7 +126,7 @@ def test_draft_stream_identical_to_plain_greedy(target_dir, draft_dir):
 
 
 def test_draft_chained_rounds_stream_identical(target_dir, draft_dir):
-    """ISSUE 13: with device finish + dispatch-ahead, draft/target
+    """ISSUE 13: at decode_pipeline_depth=2, draft/target
     rounds interleave off the SAME device carry (no host barrier
     between rounds) — the stream must still equal plain greedy, the
     chain must actually run (>1 round between host barriers), and

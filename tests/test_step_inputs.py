@@ -247,7 +247,7 @@ def _per_array_reference(runner, args, kw):
         return mr._sample_and_logprobs(
             cfg, logits, samp, counts, seen, bias,
             jnp.asarray(kw["sample_slots"]), jnp.asarray(kw["commit"]),
-            jnp.asarray(bool(kw.get("want_top", True))), unique_slots=False)
+            jnp.asarray(bool(kw.get("want_top", True))))
 
     return run(runner.params, *runner.kv_cache, *runner.sample_state)
 

@@ -381,8 +381,8 @@ def test_request_record_has_the_new_fields(served, field):
 PATHS = {
     "sync": dict(depth=1),
     "burst": dict(depth=1, k=4),
-    "pipelined": dict(depth=2, device_finish="off"),
     "chained": dict(depth=2),
+    "chained_k4": dict(depth=2, k=4),
     "spec_sync": dict(depth=1, spec=True),
     "spec_chained": dict(depth=2, spec=True),
 }
@@ -420,7 +420,7 @@ def test_every_decode_path_writes_the_same_names(path_events):
                  "sched.decode.dispatch", "sched.decode.sync",
                  "sched.decode.emit", "sync.fetch", "sched.yield"):
         assert want in names, (path, want)
-    if path in ("pipelined", "chained", "spec_chained"):
+    if path in ("chained", "chained_k4", "spec_chained"):
         assert sched.pipeline_bursts > 0, path
     if path.startswith("spec"):
         assert sched.spec_proposed > 0, path
