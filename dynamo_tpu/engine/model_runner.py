@@ -88,7 +88,7 @@ class _DeviceFedCounter(Counter):
 
 
 @jax.named_scope("sampling")
-def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
+def _sample_and_logprobs(cfg, mesh, last_logits, samp, counts, seen, bias,
                          sample_slots, commit, want_top, extra_bias=None):
     """The per-token tail shared by the single step and every scan
     iteration of the fused burst: penalty-aware sampling, the sampled
@@ -109,7 +109,7 @@ def _sample_and_logprobs(cfg, last_logits, samp, counts, seen, bias,
     if extra_bias is not None:
         row_bias = row_bias + extra_bias
     next_tokens = sample(last_logits, samp, row_counts, row_seen,
-                         bias=row_bias)
+                         bias=row_bias, mesh=mesh)
     logp = jax.nn.log_softmax(
         (last_logits + row_bias).astype(jnp.float32), axis=-1
     )
@@ -571,8 +571,8 @@ class ModelRunner:
                 hidden[jnp.arange(b), last_idx], params
             )  # [B, V]
             next_tokens, lps, top_vals, top_ids, counts = _sample_and_logprobs(
-                cfg, last_logits, samp, counts, seen, bias, sample_slots,
-                commit, want_top,
+                cfg, mesh, last_logits, samp, counts, seen, bias,
+                sample_slots, commit, want_top,
             )
             out = (next_tokens, lps, top_vals, top_ids, prompt_lps,
                    greedy_all, k_cache, v_cache, counts, seen, bias)
@@ -674,8 +674,8 @@ class ModelRunner:
                 )
                 samp_i = _dc.replace(samp, counters=samp.counters + step_i)
                 nt, lp, tv, ti, counts = _sample_and_logprobs(
-                    cfg, head(hidden[:, 0], params), samp_i, counts, seen,
-                    bias, sample_slots, commit, want_top,
+                    cfg, mesh, head(hidden[:, 0], params), samp_i, counts,
+                    seen, bias, sample_slots, commit, want_top,
                 )
                 return (k_cache, v_cache, counts, nt, pos + 1), (nt, lp, tv, ti)
 
@@ -781,7 +781,7 @@ class ModelRunner:
                     guided[:, None] & (grow < 0), -1e9, 0.0
                 ).astype(jnp.float32)
                 nt, lp, tv, ti, counts = _sample_and_logprobs(
-                    cfg, head(hidden[:, 0], params), samp_i, counts,
+                    cfg, mesh, head(hidden[:, 0], params), samp_i, counts,
                     seen, bias, sample_slots, live, want_top,
                     extra_bias=gmask,
                 )
@@ -1075,7 +1075,7 @@ class ModelRunner:
             last_logits = head(hidden[jnp.arange(b), last_idx], params)
             next_tokens, lps, top_vals, top_ids, counts = (
                 _sample_and_logprobs(
-                    cfg, last_logits, samp, counts, seen, bias,
+                    cfg, mesh, last_logits, samp, counts, seen, bias,
                     sample_slots, commit, want_top,
                 )
             )

@@ -19,10 +19,13 @@ standard the reference's engines implement (vLLM):
 - min_p: after temperature scaling, tokens with prob < min_p * max_prob drop.
 
 The filters run in the order penalties → temperature → top-k → min-p →
-top-p, in float32. ``filter_logits`` does the last three with one
-values-only sort and one cutoff value a row (no argsort, no [B, V] gather
-or scatter); entries exactly equal to the cutoff are all kept, by top-p as
-by top-k.
+top-p, in float32. ``filter_logits`` does the last three with one cutoff
+value a row and no sort (nor argsort, [B, V] gather or scatter): the
+cutoff is searched for, a few bits of its float32 image a pass, each pass
+a count or a masked sum along the unsorted row, so the cost is a function
+of B · V alone (1.07 ms at [64, 163840] on a v5e where the sort it
+replaced was 6.6 ms and the filter around it 7.4; PERF.md §6, PR 29).
+Entries exactly equal to the cutoff are all kept, by top-p as by top-k.
 """
 
 from __future__ import annotations
@@ -33,6 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..protocols.common import SamplingOptions
 
@@ -105,6 +109,81 @@ def _row_keys(params: SamplingParams) -> jax.Array:
     return jax.vmap(fold)(params.keys, params.counters)
 
 
+# the order-preserving integer image of a float32: flip the sign bit of a
+# non-negative value and every bit of a negative one, and unsigned order
+# is float order. -inf is the smallest image of a number; the images
+# under it, and those over +inf's, are NaN bit patterns.
+_SIGN = np.uint32(0x80000000)
+_KEY_NEG_INF = np.uint32(0x007FFFFF)
+
+# bits of the cutoff's image settled by one pass over the row: a pass
+# tests 2**bits - 1 thresholds, so a search takes 32 / bits passes. Timed
+# alone on a v5e at [64, 163840], top-p rows (PERF.md §6, PR 29): 1 bit
+# 2.09 ms (a pass streams the row at 590 GB/s), 2 bits 1.19, 4 bits 1.07
+# (fifteen thresholds a pass are bound by the vector unit, no longer by
+# memory); with top-k rows too 3.91 / 2.12 / 1.77
+_SEARCH_BITS = 4
+
+
+def _key_value(key: jax.Array) -> jax.Array:
+    """The float32 whose image is ``key``. Keys under -inf's read -inf, so
+    a predicate "x >= value" stays monotone over all 2**32 keys; keys over
+    +inf's read NaN, which no entry reaches."""
+    bits = jnp.where(key >= _SIGN, key ^ _SIGN, ~key)
+    value = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return jnp.where(key <= _KEY_NEG_INF, -jnp.inf, value)
+
+
+def _pin(x: jax.Array, mesh: Mesh, *spec) -> jax.Array:
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, PartitionSpec(*spec)))
+
+
+def _whole_rows(logits: jax.Array, mesh: Optional[Mesh]) -> bool:
+    """Whether ``sample`` lays [B, V] logits out with whole rows on a
+    device: on a mesh that shards them (the head makes them with the batch
+    over "dp" and the vocabulary over "tp") and whose devices divide the
+    rows."""
+    if mesh is None:
+        return False
+    devices = mesh.shape["dp"] * mesh.shape["tp"]
+    return devices > 1 and logits.shape[0] % devices == 0
+
+
+def _largest_threshold(holds, rows: tuple) -> jax.Array:
+    """The largest float32 ``t`` a row for which ``holds(t)`` is true, an
+    array of shape ``rows``.
+
+    ``holds`` takes thresholds ``[*rows, J]``, J candidates a row, and
+    returns bools of that shape; it has to be monotone: true
+    up to some value, false above it. The image of ``t`` is built from its
+    top bit down, ``_SEARCH_BITS`` at a time: the next digit is the number
+    of candidates that hold."""
+    digits = jnp.arange(1, 1 << _SEARCH_BITS, dtype=jnp.uint32)
+
+    def settle(i, key):
+        shift = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
+        ok = holds(_key_value(key[..., None] | (digits << shift)))
+        return key | (ok.sum(-1).astype(jnp.uint32) << shift)
+
+    # a loop and not its 32 / bits copies: the loop's operand is the row as
+    # it lies in memory, so no pass can take the row's producers (bias,
+    # penalties, temperature) into its fusion and read their inputs too;
+    # unrolled it is 0.05 ms faster at [64, 163840], alone
+    key = jax.lax.fori_loop(
+        0, 32 // _SEARCH_BITS, settle, jnp.zeros(rows, jnp.uint32))
+    return _key_value(key)
+
+
+def _each(reduce, thresholds: jax.Array) -> jax.Array:
+    """``reduce(t)`` for each of the J thresholds a row, ``[..., J]`` →
+    ``[..., J]``. J reductions of one ``[B, V]`` row, which the compiler
+    makes one fusion that reads the row once; a single reduction of a
+    ``[B, J, V]`` mask it splits, and stores what the J share."""
+    return jnp.stack(
+        [reduce(t) for t in jnp.moveaxis(thresholds, -1, 0)], -1)
+
+
 def filter_logits(
     scaled: jax.Array,  # [..., V] f32 temperature-scaled logits
     top_k: jax.Array,   # [...] i32; 0 → disabled
@@ -113,44 +192,64 @@ def filter_logits(
 ) -> jax.Array:
     """top-k → min-p → top-p: ``scaled`` with every dropped entry at -inf.
 
-    Each filter keeps a prefix of the row sorted by descending value, so
-    the three together come to one number a row: the smallest kept
-    logit. ONE values-only sort gives the sorted row; the masks, both
-    softmaxes and the cumulative sum run on it in place; the cutoff is a
-    masked min; and the mask in vocabulary order is ``scaled >= cutoff``.
-    No argsort, no [B, V] gather and no [B, V] scatter, which a TPU does
-    one element at a time (PERF.md §6, PR 24). Entries exactly equal to
-    the cutoff are ALL kept, by top-p as by top-k.
+    Each filter keeps the entries at or above one value, so the three
+    together come to one number a row, the smallest kept logit, and the
+    mask in vocabulary order is ``scaled >= cutoff``. Nothing is sorted:
+    each value is the largest threshold that still satisfies a monotone
+    predicate, found by ``_largest_threshold`` in a fixed number of
+    reductions over the unsorted row whatever the data.
+
+    - top-k: the k-th largest value is the largest ``t`` with
+      ``count(x >= t) >= k`` (integer counts: exact). Searched only when
+      some row asks.
+    - min-p: ``prob < min_p * max_prob`` is ``x < max + log(min_p)``.
+    - top-p: an entry stays iff the probability strictly above it is under
+      ``top_p`` (so the top token always stays), which makes the cutoff
+      the largest ``t`` with ``mass(x >= t) >= top_p * mass(alive)``, the
+      mass ``exp(x - max)`` recomputed in the pass over the entries top-k
+      and min-p left alive. ``top_p >= 1`` keeps all of them.
+
+    Entries exactly equal to the cutoff are ALL kept, by top-p as by
+    top-k. -inf entries lie below every threshold and carry no mass. A
+    pass is one fusion that reads the ``[B, V]`` row once. Alone on a
+    v5e, top-p rows: 1.07 ms at ``[64, 163840]`` where the one
+    values-only sort this replaced, with its softmaxes and cumulative
+    sum, took 7.41 (1.77 with top-k rows too), under 0.2 against 0.74 at
+    ``[32, 32064]`` and under 0.2 against 0.30 at ``[16, 32768]``
+    (PERF.md §6, PR 29).
     """
     v = scaled.shape[-1]
-    # values alone, so stability means nothing, and asking for it makes
-    # the compiler carry an iota through the sort: 1.05 against 0.61 ms
-    # at [32, 32064] on a v5e
-    sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1, stable=False), axis=-1)
+    row_max = scaled.max(axis=-1)
 
-    # top-k: mask everything below the k-th largest (k=0 → no-op)
-    k_idx = jnp.clip(top_k - 1, 0, v - 1)[..., None]
-    kth = jnp.take_along_axis(sorted_desc, k_idx, axis=-1)
-    kept = jnp.where(
-        (top_k[..., None] > 0) & (sorted_desc < kth), -jnp.inf, sorted_desc
+    def kth_largest():
+        def count(t):
+            return (scaled >= t[..., None]).sum(-1, dtype=jnp.int32)
+
+        k = jnp.clip(top_k, 1, v)[..., None]
+        t = _largest_threshold(
+            lambda ts: _each(count, ts) >= k, row_max.shape)
+        return jnp.where(top_k > 0, t, -jnp.inf)
+
+    floor = jax.lax.cond(
+        jnp.any(top_k > 0), kth_largest,
+        lambda: jnp.full(row_max.shape, -jnp.inf, jnp.float32),
     )
+    # min_p 0 → log 0 = -inf: nothing dropped
+    floor = jnp.maximum(floor, row_max + jnp.log(min_p))
 
-    # min-p: drop tokens whose prob is below min_p * max_prob. Computed on
-    # the already-top-k-masked logits, like the engines the reference wraps.
-    probs = jax.nn.softmax(kept, axis=-1)
-    kept = jnp.where(
-        probs < min_p[..., None] * probs.max(axis=-1, keepdims=True),
-        -jnp.inf, kept,
-    )
+    def alive_mass(ts):                               # [..., J] → [..., J]
+        e = jnp.exp(scaled - row_max[..., None])
+        return _each(
+            lambda t: jnp.where(scaled >= t[..., None], e, 0.0).sum(-1),
+            jnp.maximum(ts, floor[..., None]))
 
-    # top-p (nucleus): keep the prefix whose exclusive cumulative prob is
-    # under p (so the top token always stays); entries top-k or min-p
-    # dropped carry no probability and never set the cutoff
-    probs = jax.nn.softmax(kept, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs < top_p[..., None]) & (kept > -jnp.inf)
-    cutoff = jnp.min(jnp.where(keep, kept, jnp.inf), axis=-1, keepdims=True)
-    return jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+    need = top_p[..., None] * alive_mass(floor[..., None])
+    nucleus = _largest_threshold(
+        lambda ts: alive_mass(ts) >= need, row_max.shape)
+    cutoff = jnp.where(top_p >= 1.0, floor, jnp.maximum(nucleus, floor))
+    # the top token stays whatever top_p says
+    cutoff = jnp.where(cutoff <= row_max, cutoff, row_max)
+    return jnp.where(scaled >= cutoff[..., None], scaled, -jnp.inf)
 
 
 def sample(
@@ -159,9 +258,25 @@ def sample(
     counts: Optional[jax.Array] = None,   # [B, V] i32 generated-token counts
     seen: Optional[jax.Array] = None,     # [B, V] bool prompt-token presence
     bias: Optional[jax.Array] = None,     # [B, V] f32 OpenAI logit_bias rows
+    mesh: Optional[Mesh] = None,          # the step's mesh, where it has one
 ) -> jax.Array:
     """Returns sampled token ids [B]."""
     logits = logits.astype(jnp.float32)
+    # The filter's search reduces along the vocabulary in every pass, and
+    # along a vocabulary that stays sharded each pass would all-reduce. So
+    # the whole tail runs on whole rows: one all-to-all of the logits (the
+    # penalty state is replicated over "tp", so its rows cost nothing).
+    # Both ends are pinned, because the partitioner carries a layout it is
+    # given as far as it can: whole rows alone reach back into the head's
+    # product, which then gathers its weights on every device (2.5 ms of a
+    # tp=4 Mistral step, PERF.md §6, PR 29), and row-sharded tokens reach
+    # forward into the counts' update, which then all-reduces [B, V].
+    whole_rows = _whole_rows(logits, mesh)
+    if whole_rows:
+        params, counts, seen, bias = jax.tree_util.tree_map(
+            lambda x: _pin(x, mesh, "dp", *[None] * (x.ndim - 1)),
+            (params, counts, seen, bias))        # as they come; None stays
+        logits = _pin(_pin(logits, mesh, "dp", "tp"), mesh, ("dp", "tp"), None)
     if bias is not None:
         logits = logits + bias
 
@@ -186,7 +301,9 @@ def sample(
 
     row_keys = _row_keys(params)
     sampled = jax.vmap(lambda k, l: jax.random.categorical(k, l))(row_keys, scaled)
-    return jnp.where(params.temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    tokens = jnp.where(
+        params.temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    return _pin(tokens, mesh, "dp") if whole_rows else tokens
 
 
 # ---- device-resident finish detection (the persistent decode loop) ----

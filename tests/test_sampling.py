@@ -3,12 +3,15 @@ semantics, plus a structural guard on what the filter may cost.
 
 The reference is written independently of ``sample``: float64, one row at
 a time, sort INDICES, walk the cumulative sum, mask BY INDEX. ``sample``
-does none of that (one values-only sort and a cutoff value: see
-``filter_logits``), so agreement here is agreement of semantics. Float32
+does none of that (no sort at all: a cutoff value found by searching for
+it, see ``filter_logits``), so agreement here is agreement of semantics. Float32
 against float64 can only disagree where a decision sits on its threshold,
 so the reference also returns how far every decision was from its
 threshold and the cases assert that their data keeps clear of them.
 """
+
+import collections
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,8 +184,9 @@ def _case(regime, v, seed):
     return rows, logits, bias, counts, seen, params
 
 
-@pytest.mark.parametrize("v", [17, 64, 32064])
-@pytest.mark.parametrize("regime", sorted(_REGIMES))
+@pytest.mark.parametrize("regime,v", [
+    (regime, v) for regime in sorted(_REGIMES) for v in (17, 64, 32064)
+] + [("top_p", 163840), ("all_three", 163840)])
 def test_sample_matches_numpy_reference(regime, v):
     """The kept set and, with the same keys, the drawn token of every row
     agree with the reference, whatever its batchmates ask for."""
@@ -267,20 +271,135 @@ def test_seeded_stream_repeats_and_ignores_batchmates():
     assert len(set(first)) > 1          # a stream, not a constant
 
 
-def _walk(jaxpr):
+def _kept(row, top_k, top_p, min_p):
+    got = np.asarray(_FILTER(
+        jnp.asarray(row, jnp.float32)[None], jnp.asarray([top_k], jnp.int32),
+        jnp.asarray([top_p], jnp.float32), jnp.asarray([min_p], jnp.float32),
+    ))[0]
+    kept = np.isfinite(got)
+    np.testing.assert_array_equal(got[kept], np.asarray(row, np.float32)[kept])
+    return sorted(np.flatnonzero(kept).tolist())
+
+
+def _row_of(probs, at, v=17, fill=-np.inf, shift=0.0):
+    row = np.full(v, fill, np.float64)
+    row[list(at)] = np.log(probs) + shift
+    return row
+
+
+_EVERY = list(range(17))
+# what a search for the cutoff can get wrong and a sort cannot:
+# (row, top_k, top_p, min_p, the indices that stay)
+_EDGES = {
+    # one value, so every entry ties with the cutoff
+    "equal_logits_top_p": (np.full(17, 0.3), 0, 0.5, 0.0, _EVERY),
+    "equal_logits_top_k": (np.full(17, 0.3), 3, 1.0, 0.0, _EVERY),
+    "equal_logits_min_p": (np.full(17, -4.0), 0, 1.0, 0.9, _EVERY),
+    # -inf entries (a guided mask, logit_bias) lie below every threshold
+    # and carry no mass: 0 < 0.7, 0.5 < 0.7, 0.8 >= 0.7
+    "neg_inf_under_top_p": (
+        _row_of([0.5, 0.3, 0.15, 0.05], [11, 2, 7, 14]), 0, 0.7, 0.0, [2, 11]),
+    "neg_inf_under_top_k": (
+        _row_of([0.5, 0.3, 0.15, 0.05], [11, 2, 7, 14]), 3, 1.0, 0.0,
+        [2, 7, 11]),
+    "neg_inf_top_k_past_the_finite": (
+        _row_of([0.5, 0.3, 0.15, 0.05], [11, 2, 7, 14]), 9, 1.0, 0.0,
+        [2, 7, 11, 14]),
+    # the others' exp(x - max) is 0 in float32
+    "one_token_holds_all_the_mass": (
+        _row_of([1.0], [4], fill=-200.0, shift=50.0), 0, 0.9, 0.0, [4]),
+    "one_token_holds_all_the_mass_no_filter": (
+        _row_of([1.0], [4], fill=-200.0, shift=50.0), 0, 1.0, 0.0, _EVERY),
+    # the top token stays though its probability alone is over top_p
+    "top_p_under_the_top_probability": (
+        _row_of([0.6, 0.2, 0.1, 0.1], [9, 0, 16, 3], fill=-30.0), 0, 0.3,
+        0.0, [9]),
+    # the image of a float32 changes its form at the sign: cutoffs on
+    # either side of zero, and on it (0.0 and -0.0 are one value)
+    "straddling_zero_top_k_cutoff_zero": (
+        np.array([-1.5, 0.0, 1.5, -0.5, -0.0, 0.5, -2.5] + [-9.0] * 10),
+        3, 1.0, 0.0, [1, 2, 4, 5]),
+    "straddling_zero_top_k_cutoff_negative": (
+        np.array([-1.5, 0.0, 1.5, -0.5, -0.0, 0.5, -2.5] + [-9.0] * 10),
+        5, 1.0, 0.0, [1, 2, 3, 4, 5]),
+    "straddling_zero_top_k_cutoff_positive": (
+        np.array([-1.5, 0.0, 1.5, -0.5, -0.0, 0.5, -2.5] + [-9.0] * 10),
+        2, 1.0, 0.0, [2, 5]),
+    # log p + 1: 0.08, -0.20, -0.61, -1.30; 0 < 0.6, 0.4 < 0.6, 0.7 >= 0.6
+    "straddling_zero_top_p": (
+        _row_of([0.4, 0.3, 0.2, 0.1], [5, 12, 1, 8], shift=1.0), 0, 0.6, 0.0,
+        [5, 12]),
+    "straddling_zero_min_p": (
+        _row_of([0.4, 0.3, 0.2, 0.1], [5, 12, 1, 8], shift=1.0), 0, 1.0, 0.6,
+        [5, 12]),
+    "top_k_equal_to_v": (np.linspace(-3.0, 3.0, 17), 17, 1.0, 0.0, _EVERY),
+    "top_k_over_v": (np.linspace(-3.0, 3.0, 17), 1000, 1.0, 0.0, _EVERY),
+    # no filter: every finite entry stays, those without mass in float32
+    # and the most negative float32 too
+    "no_filter_wide_range": (
+        np.linspace(-300.0, 40.0, 17), 0, 1.0, 0.0, _EVERY),
+    "no_filter_lowest_float": (
+        np.array([np.finfo(np.float32).min, 3.0e38, -1e-45, 1e-45]
+                 + [0.5] * 13), 0, 1.0, 0.0, _EVERY),
+    "no_filter_with_neg_inf": (
+        _row_of([0.5, 0.3, 0.15, 0.05], [11, 2, 7, 14]), 0, 1.0, 0.0,
+        [2, 7, 11, 14]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EDGES))
+def test_filter_edges(case):
+    row, top_k, top_p, min_p, stays = _EDGES[case]
+    assert _kept(row, top_k, top_p, min_p) == stays
+
+
+def test_filter_rows_do_not_see_each_other():
+    """Every edge row at once, one batch, each with its own filters."""
+    cases = [_EDGES[c] for c in sorted(_EDGES)]
+    got = np.asarray(_FILTER(
+        jnp.asarray(np.stack([c[0] for c in cases]), jnp.float32),
+        jnp.asarray([c[1] for c in cases], jnp.int32),
+        jnp.asarray([c[2] for c in cases], jnp.float32),
+        jnp.asarray([c[3] for c in cases], jnp.float32),
+    ))
+    for r, c in enumerate(cases):
+        assert sorted(np.flatnonzero(np.isfinite(got[r])).tolist()) == c[4], r
+
+
+_REDUCTIONS = ("reduce_", "arg", "cum")
+
+
+def _walk(jaxpr, times=1):
+    """Every equation with the number of times it runs: a ``scan``'s body
+    its length, a ``while``'s an unknown number (None)."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        yield eqn, times
+        inner = times
+        if eqn.primitive.name == "scan":
+            inner = None if times is None else times * eqn.params["length"]
+        elif eqn.primitive.name == "while":
+            inner = None
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _walk(sub)
+            yield from _walk(sub, inner)
 
 
-def test_filter_costs_one_sort_and_no_full_gather_or_scatter():
-    """What made the pass 19 ms of every decode step on a v5e cannot come
-    back unseen by the CPU-only tests: at the served shape ``sample``
-    traces to exactly one sort, of values alone, and to no gather that
-    fetches, and no scatter that writes, as many elements as the logits
-    have (a TPU does those one element at a time)."""
-    b, v = 32, 32064
+# the search settles 4 bits of the cutoff's image a pass with fifteen
+# thresholds (one fusion on the chip): 8 passes x 15 reductions for top-p,
+# as many for top-k behind its cond, and the row maximum, the alive mass,
+# the greedy argmax and the draw's argmax beside them
+_MAX_FULL_REDUCTIONS = 2 * 8 * 15 + 8
+
+
+@pytest.mark.parametrize("b,v", [(32, 32064), (64, 163840)])
+def test_sample_sorts_nothing_and_reads_the_logits_a_bounded_number_of_times(
+        b, v):
+    """What made the pass 19 ms of every decode step on a v5e, and then
+    6.6 ms at a 164 k vocabulary, cannot come back unseen by the CPU-only
+    tests: at the served shapes ``sample`` traces to NO sort, to no gather
+    that fetches and no scatter that writes as many elements as the
+    logits have (a TPU does those one element at a time), and the
+    reductions over the whole ``[b, v]`` array, each times the trips of
+    the loops around it, stay under a stated number. Trace only."""
     sd = jax.ShapeDtypeStruct
     params = jax.tree_util.tree_map(
         lambda a: sd((b,) + a.shape[1:], a.dtype), S.SamplingParams.zeros(1))
@@ -288,15 +407,119 @@ def test_filter_costs_one_sort_and_no_full_gather_or_scatter():
         sd((b, v), jnp.float32), params, sd((b, v), jnp.int32),
         sd((b, v), jnp.bool_), sd((b, v), jnp.float32),
     )
-    eqns = list(_walk(jaxpr.jaxpr))
-    sorts = [e for e in eqns if e.primitive.name == "sort"]
-    assert len(sorts) == 1
-    assert len(sorts[0].invars) == 1, "a values-only sort carries no indices"
-    for e in eqns:
+    full_reductions = 0
+    for e, times in _walk(jaxpr.jaxpr):
         name = e.primitive.name
+        assert name != "sort", e
         if name == "gather":
             _, indices = e.invars
             assert e.outvars[0].aval.size < b * v, e
             assert indices.aval.size < b * v, e
         if name.startswith("scatter"):
             assert e.invars[2].aval.size < b * v, e
+        if name.startswith(_REDUCTIONS) and e.invars[0].aval.size >= b * v:
+            assert times is not None, f"in a loop of unknown length: {e}"
+            full_reductions += times
+    assert 0 < full_reductions <= _MAX_FULL_REDUCTIONS, full_reductions
+
+
+_COLLECTIVE = re.compile(
+    r" = (.*?) (all-reduce|all-gather|all-to-all|collective-permute"
+    r"|reduce-scatter)(?:-start)?\(")
+_CALLS = re.compile(
+    r"(?:calls|to_apply|body|condition|branch_computations"
+    r"|true_computation|false_computation)=\{?([^,}\s][^}\s]*(?:, [^}\s]+)*)")
+_Collective = collections.namedtuple(
+    "_Collective", "kind elements op_name looped")
+
+
+def _collectives(hlo):
+    """Every collective of a compiled module: its kind, the elements of
+    its result, the traced operation it came from, and whether it sits in
+    a while loop's body or in a computation a body calls."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+
+    def reach(k, seen):
+        if k in seen or k not in comps:
+            return seen
+        seen.add(k)
+        for line in comps[k]:
+            for group in _CALLS.findall(line):
+                for callee in group.split(", "):
+                    reach(callee.lstrip("%"), seen)
+        return seen
+
+    looped = set()
+    for body in re.findall(r"body=%?([\w.\-]+)", hlo):
+        reach(body, looped)
+    found = []
+    for k, lines in comps.items():
+        for line in lines:
+            m = _COLLECTIVE.search(line)
+            if m:
+                shapes = re.findall(r"\w+\[([\d,]*)\]", m.group(1))
+                op = re.search(r'op_name="([^"]*)"', line)
+                found.append(_Collective(
+                    m.group(2),
+                    sum(int(np.prod([int(d) for d in dims.split(",") if d]))
+                        for dims in shapes),
+                    op.group(1) if op else "", k in looped))
+    return found
+
+
+def test_decode_step_at_tp4_moves_the_logits_once():
+    """The whole decode step of a small model at tp=4 (four of the virtual
+    devices), as the runner compiles it: the head makes the logits with
+    the vocabulary over "tp", the penalty state is replicated over it.
+    The search reduces along the vocabulary in every pass, so ``sample``
+    lays whole rows on a device; left to the partitioner the vocabulary
+    stays sharded and each pass all-reduces. What the first form of that
+    did on the chip is held off here too: whole rows, pinned at one end
+    only, reached back into the head's product, which gathered its
+    [hidden, V] weights on every device (2.5 ms of a Mistral step), and
+    forward into the counts' update, which all-reduced [B, V].
+
+    So: no collective that sampling makes sits inside a loop, and all the
+    step's collectives together move under 1.5 B·V elements (one
+    all-to-all of a device's logits, B·V / 4, and the top-logprobs
+    branch's gather of B·V; the one-sort step this replaced moved 2.9
+    B·V here, counted on its lowering of this same call)."""
+    from dynamo_tpu.engine import model_runner as mr
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+
+    b, v = 64, 4096
+    runner = mr.ModelRunner(EngineConfig(
+        model=ModelConfig(vocab_size=v, hidden_size=256,
+                          intermediate_size=512, num_layers=2,
+                          num_heads=8, num_kv_heads=4),
+        max_batch_size=b, max_model_len=128, kv_block_size=8,
+        num_kv_blocks=256, dtype="float32", allow_random_weights=True,
+        prefill_buckets=[8, 16], tp_size=4))
+    step, called = runner._decode_step, {}
+    runner._decode_step = lambda *args: called.setdefault(
+        "out", step(*called.setdefault("args", args)))
+    w = runner.config.kv_width_buckets()[0]
+    zeros = lambda *shape: np.zeros(shape, np.int32)
+    runner.step(
+        zeros(b, 1), zeros(b, 1), zeros(b, w), zeros(b, 1),
+        np.ones(b, np.int32), zeros(b), np.full(b, 0.7, np.float32),
+        zeros(b), np.full(b, 0.9, np.float32),
+        seed_keys=np.zeros((b, 2), np.uint32), counters=zeros(b),
+        sample_slots=np.arange(b, dtype=np.int32), commit=np.ones(b, bool))
+    hlo = step.lower(*jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        called["args"])).compile().as_text()
+
+    found = _collectives(hlo)
+    sampling = [c for c in found if "/sampling/" in c.op_name]
+    assert sampling, "the all-to-all of the logits carries the scope"
+    assert not [c for c in sampling if c.looped]
+    assert any(c.looped for c in found)     # the layers' all-reduces are
+    assert sum(c.elements for c in found) < 1.5 * b * v, found

@@ -245,7 +245,7 @@ def _per_array_reference(runner, args, kw):
                             jnp.asarray(slots), jnp.asarray(ctx))
         logits = head(hidden[jnp.arange(b), jnp.asarray(last_idx)], params)
         return mr._sample_and_logprobs(
-            cfg, logits, samp, counts, seen, bias,
+            cfg, runner.mesh, logits, samp, counts, seen, bias,
             jnp.asarray(kw["sample_slots"]), jnp.asarray(kw["commit"]),
             jnp.asarray(bool(kw.get("want_top", True))))
 
