@@ -82,6 +82,27 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
     v_head_dim: int = 0
+    # Falcon-H1 (models/falcon_h1.py, model_family "falcon_h1"): a Mamba-2
+    # state-space mixer beside attention in every layer. mamba_d_ssm > 0
+    # says the family keeps recurrent state by slot. The multipliers are
+    # the published fixed µP scalars; 1.0 everywhere else (key_multiplier
+    # is applied by llama.qkv_prologue).
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5   # z, x, B, C, dt
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)   # gate, down
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -148,6 +169,20 @@ class ModelConfig:
                 "Qwen2-MoE checkpoints (gated shared expert) are not "
                 "supported; Qwen3-MoE and Mixtral load"
             )
+        falcon_h1 = config.get("model_type") == "falcon_h1"
+        recurrent_keys = sorted(
+            k for k in config
+            if k.startswith(("mamba_", "ssm_")) or k in RECURRENT_CONFIG_KEYS)
+        if recurrent_keys and not falcon_h1:
+            # a trunk with recurrent layers this program has no family
+            # for would fall through to llama and serve nonsense
+            raise NotImplementedError(
+                f"model_type {config.get('model_type')!r} carries recurrent-"
+                f"layer keys ({', '.join(recurrent_keys[:4])}, ...) and no "
+                "family here implements it (falcon_h1 is the one state-space "
+                "family: models/falcon_h1.py)"
+            )
+        mamba = _falcon_h1_fields(config) if falcon_h1 else {}
         n_group = config.get("n_group", 1) or 1
         topk_group = config.get("topk_group", 1) or 1
         if config.get("topk_method") == "greedy":
@@ -186,7 +221,8 @@ class ModelConfig:
                 "num_key_value_heads", config.get("num_attention_heads", 16)
             ),
             head_dim=config.get("head_dim"),
-            rope_theta=config.get("rope_theta", 10000.0),
+            # float: Falcon-H1 publishes 1e11 as an integer, past int32
+            rope_theta=float(config.get("rope_theta", 10000.0)),
             rope_scaling=rope_scaling,
             # Qwen2-family checkpoints carry qkv biases but their HF config
             # has no attention_bias key — infer from the architecture name
@@ -213,6 +249,7 @@ class ModelConfig:
             model_family=(
                 "gemma2" if "gemma2" in arch
                 else "gptoss" if "gptoss" in arch
+                else "falcon_h1" if falcon_h1
                 else ""
             ),
             attn_logit_softcap=config.get("attn_logit_softcapping") or 0.0,
@@ -234,6 +271,7 @@ class ModelConfig:
             qk_rope_head_dim=config.get("qk_rope_head_dim", 0) or 0,
             qk_nope_head_dim=config.get("qk_nope_head_dim", 0) or 0,
             v_head_dim=config.get("v_head_dim", 0) or 0,
+            **mamba,
         )
 
     @classmethod
@@ -245,6 +283,55 @@ class ModelConfig:
             return model_config_from_gguf(read_gguf(model_dir))
         with open(os.path.join(model_dir, "config.json")) as f:
             return cls.from_hf_config(json.load(f))
+
+
+# keys of other published trunks with recurrent or linear-attention layers
+RECURRENT_CONFIG_KEYS = (
+    "layers_block_type", "hybrid_override_pattern", "linear_num_value_heads",
+    "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
+    "conv_kernel", "d_state",
+)
+
+
+def _falcon_h1_fields(config: dict) -> dict:
+    """ModelConfig's Falcon-H1 fields from the published keys; what the
+    family module does not compute is refused here, before any weight
+    is made."""
+    only = {
+        "mamba_rms_norm": True, "mamba_norm_before_gate": False,
+        "mamba_proj_bias": False, "mamba_conv_bias": True,
+        "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+        "rope_scaling": None, "attn_layer_indices": None,
+    }
+    for key, value in only.items():
+        if config.get(key, value) != value:
+            raise NotImplementedError(
+                f"falcon_h1 with {key}={config[key]!r} (models/falcon_h1.py "
+                f"computes {key}={value!r} only)")
+    d_ssm = int(config["mamba_d_ssm"])
+    heads, d_head = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    groups = int(config.get("mamba_n_groups", 1))
+    if heads * d_head != d_ssm or heads % groups:
+        raise ValueError(
+            f"falcon_h1: mamba_d_ssm {d_ssm} != mamba_n_heads {heads} x "
+            f"mamba_d_head {d_head}, or mamba_n_groups {groups} does not "
+            "divide the heads")
+    ssm_m = tuple(float(m) for m in config.get("ssm_multipliers", (1.0,) * 5))
+    mlp_m = tuple(float(m) for m in config.get("mlp_multipliers", (1.0, 1.0)))
+    if len(ssm_m) != 5 or len(mlp_m) != 2:
+        raise ValueError("falcon_h1: ssm_multipliers has 5 entries (z, x, B, "
+                         "C, dt) and mlp_multipliers 2 (gate, down)")
+    scalars = ("embedding_multiplier", "lm_head_multiplier",
+               "attention_in_multiplier", "attention_out_multiplier",
+               "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier")
+    return dict(
+        mamba_d_ssm=d_ssm, mamba_n_heads=heads, mamba_d_head=d_head,
+        mamba_d_state=int(config["mamba_d_state"]), mamba_n_groups=groups,
+        mamba_d_conv=int(config.get("mamba_d_conv", 4)),
+        mamba_chunk_size=int(config.get("mamba_chunk_size", 128)),
+        ssm_multipliers=ssm_m, mlp_multipliers=mlp_m,
+        **{k: float(config.get(k, 1.0)) for k in scalars},
+    )
 
 
 def default_prefill_buckets(max_len: int) -> List[int]:

@@ -172,6 +172,23 @@ class ModelRunner:
         self.config = config
         cfg = config.model
         self.arch = models.resolve(cfg)
+        # a family with per-sequence state that is not pages (models/
+        # falcon_h1.py): the state is sized by slot, rides in the cache
+        # pytree, and every path that moves pages without it is refused
+        self.recurrent = bool(getattr(self.arch, "RECURRENT_STATE", False))
+        for path, on in (
+            ("spec_ngram_tokens", config.spec_ngram_tokens > 0),
+            ("spec_draft_model", bool(config.spec_draft_model)),
+            ("sp_size", config.sp_size > 1),
+            ("pp_size", config.pp_size > 1),
+            ("tp_size", config.tp_size > 1),
+            ("host_kv_blocks", config.host_kv_blocks > 0),
+            ("prefix_pull", config.prefix_pull),
+            ("multi_step_decode", config.multi_step_decode > 1),
+            ("decode_pipeline_depth", config.chain_enabled),
+        ):
+            if on:
+                self.refuse_without_state(path)
         self.dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
         if config.kv_cache_dtype not in ("auto", "fp8"):
             raise ValueError(
@@ -394,9 +411,17 @@ class ModelRunner:
             ))
 
         self.param_bytes = _leaf_bytes(self.params)
-        self.kv_bytes_per_token = _leaf_bytes(self.kv_cache) / max(
+        pages = (tuple(side.kv for side in self.kv_cache)
+                 if self.recurrent else self.kv_cache)
+        self.kv_bytes_per_token = _leaf_bytes(pages) / max(
             1, config.num_kv_blocks * config.kv_block_size
         )
+        if self.recurrent:
+            self.compiles.registry.gauge(
+                "dynamo_engine_recurrent_state_bytes",
+                "Device bytes of the recurrent state records held by slot "
+                "beside the paged cache (all layers, all slots)",
+            ).set(_leaf_bytes(tuple(side.state for side in self.kv_cache)))
         self.device_time = DeviceTimeTracker(
             param_bytes=self.param_bytes,
             kv_bytes_per_token=self.kv_bytes_per_token,
@@ -413,6 +438,19 @@ class ModelRunner:
         # batched cacheless embedding programs, compiled per (rows,
         # bucket) on first use (the /v1/embeddings workload)
         self._embed_progs: Dict[Tuple[int, int], Any] = {}
+
+    def refuse_without_state(self, path: str) -> None:
+        """Raise, by name, for a path that would move, share or roll back
+        a sequence's pages without its recurrent state; nothing for a
+        family whose only per-sequence state is pages."""
+        if not self.recurrent:
+            return
+        family = self.arch.__name__.rsplit(".", 1)[-1]
+        raise ValueError(
+            f"{path} is refused for the {family} family, which keeps "
+            f"recurrent state by slot beside the paged cache: "
+            f"{self.arch.RECURRENT_REFUSALS[path]}"
+        )
 
     # ---------- routed experts' counters ----------
 
@@ -501,6 +539,14 @@ class ModelRunner:
                     params, cfg, tokens, positions, cache, bt, slots, ctx,
                     mesh=mesh,
                 )
+        elif self.recurrent:
+            # the trunk also needs each row's slot: its state record
+            def forward(params, cache, tokens, positions, bt, slots, ctx,
+                        state_slots):
+                return arch.forward(
+                    params, cfg, tokens, positions, cache, bt, slots, ctx,
+                    mesh=mesh, return_hidden=True, state_slots=state_slots,
+                )
         else:
             def forward(params, cache, tokens, positions, bt, slots, ctx):
                 return arch.forward(
@@ -532,6 +578,7 @@ class ModelRunner:
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), tokens, positions,
                 block_tables, slot_mapping, context_lens,
+                *((sample_slots,) if self.recurrent else ()),
             )
             b = tokens.shape[0]
             # the full-S [B, S, V] head exists ONLY inside this gated
@@ -1806,6 +1853,7 @@ class ModelRunner:
         numpy) and the streamed prefill pipeline's chunk-sized frames,
         which pair it with ``blocks_to_host`` off-loop.
         """
+        self.refuse_without_state("migration")
         ids = list(block_ids)
         ks, vs = [], []
         i = 0
@@ -1831,6 +1879,7 @@ class ModelRunner:
         Accepts numpy OR already-device-resident jax arrays (callers that
         must not block the event loop stage with ``jax.device_put`` first).
         """
+        self.refuse_without_state("migration")
         ids = list(block_ids)
         assert k_blocks.shape[1] == len(ids), (k_blocks.shape, len(ids))
         kb_all = jnp.asarray(k_blocks)
@@ -1868,6 +1917,8 @@ class ModelRunner:
             cache = tuple(self.arch.init_kv_cache(
                 cfg.model, cfg.num_kv_blocks, cfg.kv_block_size,
                 self.kv_dtype,
+                **({"num_slots": cfg.max_batch_size} if self.recurrent
+                   else {}),
             ))
             if cfg.pp_size > 1:
                 from ..parallel.pipeline import stage_cache

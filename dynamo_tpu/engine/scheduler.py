@@ -479,6 +479,15 @@ class Scheduler:
     ):
         self.runner = runner
         self.config = config
+        # a family with recurrent state by slot (models/falcon_h1.py): a
+        # prefix hit would hand a sequence pages without the state that
+        # followed them, so hits are blanked and no block is registered;
+        # a sequence starts, and resumes after preemption, by prefilling
+        # from position 0, where the trunk zeroes its slot's state
+        # (FakeRunner test doubles carry no flag)
+        self.recurrent = bool(getattr(runner, "recurrent", False))
+        if self.recurrent and disagg is not None:
+            runner.refuse_without_state("remote_prefill")
         self.disagg = disagg
         # flight recorder: the process-wide engine-event ring every layer
         # records into (telemetry/flight.py); injectable for tests
@@ -700,6 +709,17 @@ class Scheduler:
         self._preemptions = reg.counter(
             "dynamo_scheduler_preemptions_total",
             "Requests evicted back to the waiting queue on KV OOM",
+        )
+        # a family with recurrent state by slot (both stay 0 otherwise)
+        self._state_resets = reg.counter(
+            "dynamo_engine_recurrent_state_resets_total",
+            "Sequences that started, or resumed after preemption, at "
+            "position 0: the slot's recurrent state was zeroed",
+        )
+        self._prefix_blanked = reg.counter(
+            "dynamo_engine_prefix_hits_blanked_total",
+            "Admissions whose prefix-cache hit was blanked because the "
+            "family keeps recurrent state the cached pages do not carry",
         )
         # sequence-parallel long-context prefill (docs/long_context.md)
         self._sp_chunks_c = reg.counter(
@@ -1003,6 +1023,8 @@ class Scheduler:
         re-prefills ``prompt + resume_tokens`` and continues the stream.
         Returns False (caller frees the blocks and nacks) when no slot
         is free at install time."""
+        if self.recurrent:
+            self.runner.refuse_without_state("migration")
         self._prepare_request(er)
         if er.base_key is None:
             # source predates per-request keys (or state was trimmed):
@@ -1251,7 +1273,11 @@ class Scheduler:
         """Hash-register blocks whose KV is complete (matchable + KV events).
 
         ``er.seq`` mirrors exactly the tokens whose KV sits in cache, so its
-        frozen blocks line up 1:1 with ``er.block_ids``."""
+        frozen blocks line up 1:1 with ``er.block_ids``. Nothing is
+        registered for a family with recurrent state: a later sequence
+        could take the pages but not the state that followed them."""
+        if self.recurrent:
+            return
         n_complete = min(er.context_len // self.config.kv_block_size, len(er.seq.blocks))
         for i in range(er.registered_blocks, n_complete):
             blk = er.seq.blocks[i]
@@ -2615,15 +2641,21 @@ class Scheduler:
             # scattered the pulled run, and registered it (num_cached
             # covers local + pulled) — only the tail prefills below
             er.pull_ready = False
-        elif er.want_prompt_lps and not er.prompt_lps_emitted:
+        elif self.recurrent or (
+                er.want_prompt_lps and not er.prompt_lps_emitted):
             # every prompt position must run through the model — a prefix
-            # cache hit would skip its logits. Blank the probe's hits so
+            # cache hit would skip its logits (prompt logprobs) or its
+            # part of the recurrent state. Blank the probe's hits so
             # allocation proceeds with zero cached tokens. (A resumed
             # request that already emitted them uses the cache normally.)
             probe = self.allocator.probe_prefix(tokens_all)
             er.block_ids, er.num_cached = self.allocator.allocate_prompt(
                 tokens_all, probe=(probe[0], [], [])
             )
+            if self.recurrent:      # (counted once the blocks are had)
+                self._state_resets.inc()
+                if probe[1] or probe[2]:
+                    self._prefix_blanked.inc()
         else:
             er.block_ids, er.num_cached = self.allocator.allocate_prompt(tokens_all)
         if not er.remote_attempted:  # remote fallback already counted itself

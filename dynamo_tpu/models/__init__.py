@@ -24,6 +24,18 @@ def resolve(cfg: ModelConfig):
                 "which requires dynamo_tpu/models/deepseek.py"
             ) from e
         return deepseek
+    if cfg.model_family == "falcon_h1":
+        from . import falcon_h1
+
+        return falcon_h1
+    if cfg.mamba_d_ssm > 0:
+        # recurrent state with no family to keep it: llama would serve
+        # the attention half alone
+        raise NotImplementedError(
+            f"mamba_d_ssm={cfg.mamba_d_ssm} needs a family that keeps "
+            f"recurrent state; model_family {cfg.model_family!r} has none "
+            "(models/falcon_h1.py is selected by model_type falcon_h1)"
+        )
     if cfg.model_family == "gptoss":
         from . import gptoss
 

@@ -354,6 +354,8 @@ def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis):
     if "q_norm" in layer_params:  # Qwen3-family per-head norms, pre-rope
         q = rms_norm(q, layer_params["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, layer_params["k_norm"], cfg.rms_norm_eps)
+    if cfg.key_multiplier != 1.0:  # Falcon-H1's fixed µP scalar, pre-rope
+        k = (k.astype(jnp.float32) * cfg.key_multiplier).astype(k.dtype)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
                    seq_basis=seq_basis)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling,

@@ -16,7 +16,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 FILES = ("test_units.py", "test_trace_reduction.py", "test_host_spans.py",
-         "test_scope_ops.py", "test_moonlight_units.py")
+         "test_scope_ops.py", "test_moonlight_units.py",
+         "test_falcon_h1_units.py")
 
 
 def _adopt(filename: str) -> None:

@@ -111,3 +111,77 @@ def test_moonlight_decode_step_reads_the_cache_in_place(
     assert len(re.findall(r"mla_cache/[^\n]*tpu_custom_call|tpu_custom_call[^\n]*mla_cache", text)) >= 1
     assert text.count("tpu_custom_call") >= 4      # the kernel + three products
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+# Falcon-H1-34B's attention branch as one chip serves it (benchmark/
+# configs/falcon-h1-34b.json): 20 query heads over 4 kv heads of 128, a
+# query group of 5, which no other configuration runs
+def test_decode_and_flash_kernels_compile_at_falcon_h1s_heads(
+        one_chip, no_compile_cache):
+    from dynamo_tpu.ops.attention import attention
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, blocks, page, h, kvh, d = 6, 3072, 16, 20, 4, 128
+    cache = s((layers, blocks, page, kvh, d), jnp.bfloat16)
+
+    def f(q, k, v, bt, pos, ctx, li):
+        return attention(q, k, v, bt, pos, ctx, impl="pallas", layer_idx=li)
+
+    for b, sq, w in ((64, 1, 256), (4, 256, 256), (1, 2048, 256)):
+        compiled = jax.jit(f).lower(
+            s((b, sq, h, d), jnp.bfloat16), cache, cache, s((b, w), jnp.int32),
+            s((b, sq), jnp.int32), s((b,), jnp.int32), s((), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text(), (b, sq)
+
+
+def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole trunk of a decode step at the benchmark's size, on the
+    routes the chip takes: the decode kernel is in it, and neither the
+    recurrent state (1.62 GB at 64 slots) nor the pages (0.60 GB) are
+    copied: the program's temporaries stay far under either."""
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import falcon_h1
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "falcon-h1-34b.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: falcon_h1.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    b, w = serve["max_batch_size"], 256
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: falcon_h1.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
+                                        jnp.bfloat16, num_slots=b)))
+    assert k_side.state.shape == (6, 64, 32, 128, 256)
+    assert k_side.state.dtype == jnp.float32
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, tokens, positions, bt, slots, ctx):
+        return falcon_h1.forward(params, cfg, tokens, positions,
+                                 (k_side, v_side), bt, slots, ctx,
+                                 return_hidden=True)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(b, 1), i32(b, 1), i32(b, w), i32(b, 1),
+        i32(b)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    print("decode step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    # one layer's state of all slots is 268 MB: not even that is copied
+    assert mem.temp_size_in_bytes < 128 * 2 ** 20
+    # embedding 2.67 GB + six layers 5.16 + state 1.62 + pages 0.60 (the
+    # head, 2.67 GB more, is the step's and not the trunk's)
+    assert 9.9e9 < mem.argument_size_in_bytes < 10.3e9
