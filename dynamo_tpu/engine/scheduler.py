@@ -617,6 +617,10 @@ class Scheduler:
         # loop wedged INSIDE a pass (hung compile, dead device sync) goes
         # stale while a healthy-but-waiting loop stays fresh
         self.last_loop_t = time.monotonic()
+        # this pass has dispatched a program whose result it has not
+        # fetched / has taken its turn for the frontend (sched.yield)
+        self._inflight = False
+        self._turn_taken = False
         self._build_instruments()
         if disagg is not None and getattr(disagg, "registry", None) is not None:
             self.registry.attach(disagg.registry)
@@ -648,9 +652,11 @@ class Scheduler:
             "(host_sync time is carved out of its enclosing phase)",
             buckets=STEP_BUCKETS,
         )
-        # device→host sync time accumulated inside the current
-        # prefill/decode phase window — subtracted from that window's
-        # observation so summing phase series never double-counts
+        # device→host sync time (and the frontend's turn, which a pass
+        # takes inside the window since it comes before the fetch)
+        # accumulated inside the current prefill/decode phase window —
+        # subtracted from that window's observation so summing phase
+        # series never double-counts
         self._host_sync_s = 0.0
         self._itl_hist = reg.histogram(
             "dynamo_scheduler_inter_token_latency_seconds",
@@ -822,9 +828,67 @@ class Scheduler:
             "(or preempted) mark to its admission mark",
         )
 
+        self._yield_ctr = reg.counter(
+            "dynamo_scheduler_yield_seconds_total",
+            "Time the loop spent inside sched.yield, its one turn a "
+            "progressed pass for everything else on the event loop "
+            "(HTTP, tokenizer, detokenizer, SSE, /metrics)",
+        )
+        self._yield_inflight_ctr = reg.counter(
+            "dynamo_scheduler_yield_inflight_seconds_total",
+            "The part of dynamo_scheduler_yield_seconds_total spent "
+            "while a dispatched program's result was not yet fetched: "
+            "frontend work the device's own step hides",
+        )
+
     def _observe_host_sync(self, dt: float) -> None:
         self._phase_hist.observe(dt, phase="host_sync")
         self._host_sync_s += dt
+
+    async def _frontend_turn(self) -> None:
+        """``sched.yield``: the pass's one turn for everything else on
+        the event loop. ``_emit`` only queues a token; the request's own
+        task (detokenizer, SSE write) and the HTTP ingress of new
+        requests run here. Taken between the pass's last dispatch and
+        the wait for its result (``_fetch``), the turn costs the device
+        nothing; a pass that fetched nothing takes it at its end."""
+        if self._turn_taken:
+            return
+        self._turn_taken = True
+        inflight = self._inflight or bool(self._chain)
+        t0 = time.monotonic()
+        with span("sched.yield", step=self.passes, inflight=int(inflight)):
+            await asyncio.sleep(0)
+        dt = time.monotonic() - t0
+        self._host_sync_s += dt   # no phase's own time: carved out of it
+        self._yield_ctr.inc(dt)
+        if inflight:
+            self._yield_inflight_ctr.inc(dt)
+
+    def _decode_follows(self, finishing: List[EngineRequest]) -> bool:
+        """Will this pass dispatch a decode step after the prefill it
+        is in? Yes if a row decodes already, or a row of ``finishing``
+        (its prompt ends here) has more than its first token to give.
+        The frontend's turn then waits for that dispatch."""
+        return any(er.fin_max_new - er.generated > 1 for er in finishing) \
+            or any(s is not None and s not in self.prefilling
+                   and not self._is_sp(s) for s in self.slots)
+
+    async def _fetch(self, loop, name: str, to_host, turn: bool = True):
+        """Wait for the result of the pass's latest dispatch: the
+        frontend's turn first (``turn``: unless a later dispatch of this
+        pass will take it), then ``to_host`` on an executor thread under
+        the span ``name``. Returns (result, the moment the wait began):
+        ``sched.*.sync`` and the host_sync phase are time blocked on the
+        device, with the frontend's work already done."""
+        if turn:
+            await self._frontend_turn()
+        t_sync = time.monotonic()
+        with span(name, step=self.passes):
+            out = await loop.run_in_executor(None, to_host)
+        # programs run in dispatch order: nothing older is pending either
+        self._inflight = False
+        return out, t_sync
 
     def _mark_admission(self, er: EngineRequest) -> None:
         """The admission mark, and the wait since the request last
@@ -1297,6 +1361,7 @@ class Scheduler:
             # this pass — hung compile, dead host sync — leaves it stale
             self.last_loop_t = pass_t0
             self.passes += 1
+            self._inflight = self._turn_taken = False
 
             # the sched.* spans (telemetry/tracing.span) put this pass's
             # seams into the profiler's trace. They follow one another
@@ -1305,6 +1370,10 @@ class Scheduler:
             # spans hold no await (sched.admit's only one is the remote
             # prefill submit of a disaggregated engine); only
             # sched.*.sync, sched.yield and sched.wait cross one.
+            # A pass is admit, build, dispatch, yield, sync, emit: the
+            # frontend's turn (sched.yield) comes while the device
+            # computes what the pass dispatched, and at the pass's end
+            # only where it fetched nothing (_frontend_turn).
             with span("sched.admit", step=self.passes):
                 # drop cancelled requests (client disconnects / kills)
                 for er in list(self.waiting):
@@ -1524,11 +1593,12 @@ class Scheduler:
                     else:
                         await asyncio.sleep(0.001)
             else:
+                # a pass that fetched nothing (only reaped or admitted,
+                # a prompt's middle chunk, the chain's bursts already in
+                # flight) has not given the frontend its turn yet: the
+                # loop never spins without one
+                await self._frontend_turn()
                 self._step_hist.observe(time.monotonic() - pass_t0)
-                # let I/O run between steps: HTTP, detokenizer, SSE and
-                # /metrics all run inside this span
-                with span("sched.yield", step=self.passes):
-                    await asyncio.sleep(0)
 
         # stopping: reconcile any chained burst so no sampled tokens are
         # silently dropped and no device work is abandoned
@@ -2790,6 +2860,7 @@ class Scheduler:
                 sample_slot=er.slot, commit=final,
                 want_top=final and er.logprobs_n > 0,
             )
+            self._inflight = True
         with span("sched.prefill.emit", step=self.passes, rows=1):
             self.steps += 1
             st.chunks += 1
@@ -2887,9 +2958,9 @@ class Scheduler:
                     out.extend(np.asarray(x) for x in burst)
                 return out
 
-        t_sync = time.monotonic()
-        with span("sched.prefill.sync", step=self.passes):
-            synced = await loop.run_in_executor(None, _sync)
+        synced, t_sync = await self._fetch(
+            loop, "sched.prefill.sync", _sync,
+            turn=not self._decode_follows([er]))
         with span("sched.prefill.emit", step=self.passes, rows=1):
             t_done = time.monotonic()
             self._observe_host_sync(t_done - t_sync)
@@ -3054,6 +3125,7 @@ class Scheduler:
                     sample_slots=sample_slots,
                     commit=np.zeros(rows, bool), want_top=False, **dkw,
                 )
+            self._inflight = True
 
         # what follows a dispatch on the host: the chunk's blocks become
         # matchable; the first-token emit comes after the sync below
@@ -3095,9 +3167,9 @@ class Scheduler:
                 return (np.asarray(next_tokens), np.asarray(lps),
                         np.asarray(top_vals), np.asarray(top_ids), plists)
 
-        t_sync = time.monotonic()
-        with span("sched.prefill.sync", step=self.passes):
-            toks, lpn, tv, ti, plists = await loop.run_in_executor(None, _to_host)
+        (toks, lpn, tv, ti, plists), t_sync = await self._fetch(
+            loop, "sched.prefill.sync", _to_host,
+            turn=not self._decode_follows([plan[i][0] for i in finals]))
         with span("sched.prefill.emit", step=self.passes, rows=len(finals)):
             self._observe_host_sync(time.monotonic() - t_sync)
             if self.device_time is not None:
@@ -3273,13 +3345,15 @@ class Scheduler:
                 tokens0, positions0, btab, temp, top_k, top_p,
                 commit=commit, want_top=False, **kw,
             )
+            self._inflight = True
 
         def _sync_draft():
             with span("sync.fetch"):
                 return np.asarray(toksK)
 
-        with span("sched.decode.sync", step=self.passes):
-            tk = await loop.run_in_executor(None, _sync_draft)
+        # the verify dispatch below is the pass's last: the turn is its
+        tk, _ = await self._fetch(
+            loop, "sched.decode.sync", _sync_draft, turn=False)
         self.steps += 1
         return {
             er.slot: [int(t) for t in tk[:K, er.slot]] for er in active
@@ -3388,14 +3462,14 @@ class Scheduler:
                 commit=np.zeros(b, bool),  # greedy chain: counts never consulted
                 want_top=False, want_greedy=True,
             )
-        t_sync = time.monotonic()
+            self._inflight = True
 
         def _sync_verify():
             with span("sync.fetch"):
                 return np.asarray(greedy_all)
 
-        with span("sched.decode.sync", step=self.passes):
-            ga = await loop.run_in_executor(None, _sync_verify)
+        ga, t_sync = await self._fetch(
+            loop, "sched.decode.sync", _sync_verify)
         with span("sched.decode.emit", step=self.passes, rows=len(active)):
             self._observe_host_sync(time.monotonic() - t_sync)
             if self.device_time is not None:
@@ -3570,7 +3644,7 @@ class Scheduler:
                         sample_slots=np.arange(b, dtype=np.int32),
                         commit=np.zeros(b, bool), want_top=False, **dkw,
                     )
-        t_sync = time.monotonic()
+            self._inflight = True
 
         def _sync_step():
             faults.maybe_hang("decode_burst_hang")  # chaos site (see above)
@@ -3578,8 +3652,8 @@ class Scheduler:
                 return (np.asarray(next_tokens), np.asarray(lps),
                         np.asarray(top_vals), np.asarray(top_ids))
 
-        with span("sched.decode.sync", step=self.passes):
-            toks, lpn, tv, ti = await loop.run_in_executor(None, _sync_step)
+        (toks, lpn, tv, ti), t_sync = await self._fetch(
+            loop, "sched.decode.sync", _sync_step)
         with span("sched.decode.emit", step=self.passes, rows=len(active)):
             self._observe_host_sync(time.monotonic() - t_sync)
             self._last_burst_done_t = time.monotonic()

@@ -10,8 +10,11 @@ whole command (``benchmark/tests/test_rehearsal.py``) stays out.
 """
 
 import importlib.util
+import json
 import os
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -43,3 +46,39 @@ def _adopt(filename: str) -> None:
 
 for _file in FILES:
     _adopt(_file)
+
+
+# ---------------------------------------------------------------------
+# a metric that arrived as files only (ISSUE 32): its case is here, its
+# two /metrics samples are data under benchmark/tests/data
+# ---------------------------------------------------------------------
+
+def _cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell_name", _cells())
+def test_yield_inflight_share_from_two_metrics_samples(cell_name):
+    from harness import manifest, prom
+    from harness.rundata import RunData, read_metric
+
+    with open(os.path.join(BENCH, "tests", "data",
+                           "yield-inflight.metrics.json")) as f:
+        data = json.load(f)
+    cell = manifest.load_cell(cell_name)
+    metric = next(m for m in cell.per_layer if m.name == "yield_inflight_share")
+    assert (metric.unit, metric.better, metric.moves) == (
+        "%", "higher", "itl_p50_ms")
+
+    def run(start, end):
+        return RunData(cell=cell, hf={}, serve={}, seconds=51.0,
+                       window=(0.0, 51.0), setup_seconds=0.0, records=[],
+                       prom_start=prom.parse(start), prom_end=prom.parse(end))
+
+    value, _ = read_metric(metric, run(data["start"], data["end"]))
+    assert value == pytest.approx(data["expected_pct"])
+    # a program from before the counters, or a window without a turn:
+    # nothing to read, and nothing raised
+    assert read_metric(metric, run(data["parent"], data["parent"])) == (None, 0)
+    assert read_metric(metric, run(data["end"], data["end"])) == (None, 0)

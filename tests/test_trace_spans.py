@@ -273,6 +273,74 @@ def test_sched_spans_cover_the_loops_time(served):
     assert covered >= 0.95 * total, covered / total
 
 
+def _passes(events, tid):
+    """The loop thread's sched.* spans by pass number, in time order."""
+    by_pass = {}
+    for e in _named(events, "sched."):
+        if e["tid"] == tid:
+            by_pass.setdefault(e["stats"]["step"], []).append(e)
+    return by_pass
+
+
+def _assert_one_turn_a_pass(events, sync_path, what):
+    """sched.yield once a pass that progressed and never twice; on a
+    synchronous decode path right after the pass's last dispatch and
+    before the wait for its result, with ``inflight=1`` (ISSUE 32)."""
+    by_pass = _passes(events, _loop_tid(events))
+    first, last = min(by_pass), max(by_pass)
+    hidden = 0
+    for n, spans in by_pass.items():
+        if n in (first, last):     # a pass the capture's edge cut
+            continue
+        names = [e["name"] for e in spans]
+        if "sched.wait" in names:
+            assert "sched.yield" not in names, (what, n, names)
+            continue
+        assert names.count("sched.yield") == 1, (what, n, names)
+        i = names.index("sched.yield")
+        if sync_path and "sched.decode.dispatch" in names:
+            assert names[i - 1] == "sched.decode.dispatch", (what, names)
+            assert names[i + 1:] == ["sched.decode.sync",
+                                     "sched.decode.emit"], (what, names)
+            assert spans[i]["stats"]["inflight"] == 1, (what, n)
+            hidden += 1
+    return hidden
+
+
+def test_the_turn_comes_once_a_pass_between_dispatch_and_sync(served):
+    assert _assert_one_turn_a_pass(served["events"], True, "served") > 15
+
+
+def test_tokens_are_written_while_the_next_step_is_in_flight(served):
+    """The SSE writes of a decode pass's tokens lie inside the next
+    pass's sched.yield, which starts after that pass's dispatch."""
+    ev = served["events"]
+    tid = _loop_tid(ev)
+    turns = [e for e in _named(ev, "sched.yield")
+             if e["tid"] == tid and e["stats"].get("inflight") == 1]
+    writes = [e for e in _named(ev, "http.sse_write") if e["tid"] == tid]
+    inside = [w for w in writes
+              if any(t["start"] <= w["start"] and w["end"] <= t["end"]
+                     for t in turns)]
+    assert len(writes) > 15 and len(inside) >= 0.7 * len(writes), (
+        len(inside), len(writes))
+    dispatches = _named(ev, "sched.decode.dispatch")
+    for t in turns:
+        assert any(d["stats"]["step"] == t["stats"]["step"]
+                   and d["end"] <= t["start"] for d in dispatches), t
+
+
+def test_yield_counters_say_the_turn_was_hidden(served):
+    total = _delta(served, "dynamo_scheduler_yield_seconds_total")
+    hidden = _delta(served, "dynamo_scheduler_yield_inflight_seconds_total")
+    assert 0 < hidden <= total
+    # two requests of 12 tokens: nearly every turn follows a dispatch
+    assert hidden >= 0.5 * total, (hidden, total)
+    spans = sum(e["end"] - e["start"]
+                for e in _named(served["events"], "sched.yield")) / 1e9
+    assert spans <= total * 1.05 + 1e-3     # the counter times the span
+
+
 def test_sync_fetch_is_on_an_executor_thread_inside_the_sync_span(served):
     tid = _loop_tid(served["events"])
     fetches = _named(served["events"], "sync.fetch")
@@ -437,6 +505,14 @@ def test_every_decode_path_keeps_sched_spans_apart(path_events):
     # a seam left without a span would still show
     total = spans[-1]["end"] - spans[0]["start"]
     assert sum(e["end"] - e["start"] for e in spans) >= 0.75 * total, path
+
+
+def test_every_decode_path_takes_one_turn_a_pass(path_events):
+    path, events, _ = path_events
+    sync_path = path in ("sync", "burst", "spec_sync")
+    hidden = _assert_one_turn_a_pass(events, sync_path, path)
+    if sync_path:
+        assert hidden > 3, path
 
 
 # ---------------------------------------------------------------------
