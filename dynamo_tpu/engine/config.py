@@ -103,6 +103,15 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = (1.0,) * 5   # z, x, B, C, dt
     mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)   # gate, down
+    # manifold-constrained hyper-connections (models/mhc.py, model_type
+    # "xing4_0"): hc_mult > 1 residual streams, mixed around every
+    # sublayer by per-token matrices that hc_sinkhorn_iters Sinkhorn
+    # iterations make doubly stochastic. hc_mult == 1 is the plain
+    # residual path of every other family.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -183,6 +192,18 @@ class ModelConfig:
                 "family: models/falcon_h1.py)"
             )
         mamba = _falcon_h1_fields(config) if falcon_h1 else {}
+        xing4 = config.get("model_type") == "xing4_0"
+        hc_keys = sorted(k for k in config if k.startswith(HC_KEY_PREFIXES))
+        if hc_keys and not xing4:
+            # a changed residual path this program has no family for
+            # would be served with a plain one, and wrong tokens
+            raise NotImplementedError(
+                f"model_type {config.get('model_type')!r} carries hyper-"
+                f"connection keys ({', '.join(hc_keys[:4])}) and no family "
+                "here implements its residual path (xing4_0 is the one "
+                "family with mixed residual streams: models/mhc.py)"
+            )
+        hc = _xing4_fields(config) if xing4 else {}
         n_group = config.get("n_group", 1) or 1
         topk_group = config.get("topk_group", 1) or 1
         if config.get("topk_method") == "greedy":
@@ -272,6 +293,7 @@ class ModelConfig:
             qk_nope_head_dim=config.get("qk_nope_head_dim", 0) or 0,
             v_head_dim=config.get("v_head_dim", 0) or 0,
             **mamba,
+            **hc,
         )
 
     @classmethod
@@ -291,6 +313,27 @@ RECURRENT_CONFIG_KEYS = (
     "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
     "conv_kernel", "d_state",
 )
+
+
+# keys of a published config with a changed residual path
+HC_KEY_PREFIXES = ("hc_", "mhc_", "hyper_connection")
+
+
+def _xing4_fields(config: dict) -> dict:
+    """ModelConfig's hyper-connection fields from the published keys of
+    ``model_type: xing4_0`` (latent attention with mixed residual
+    streams: models/deepseek.py over models/mhc.py)."""
+    if not (config.get("kv_lora_rank") or 0) > 0:
+        raise NotImplementedError(
+            "xing4_0 without kv_lora_rank: the mixed residual streams are "
+            "served over latent attention only (models/deepseek.py)")
+    return dict(
+        hc_mult=int(config.get("hc_mult", 1)),
+        hc_sinkhorn_iters=int(config.get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(config.get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(config.get("mhc_h_res_clamp_min", -30.0)),
+                      float(config.get("mhc_h_res_clamp_max", 30.0))),
+    )
 
 
 def _falcon_h1_fields(config: dict) -> dict:

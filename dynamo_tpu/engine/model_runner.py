@@ -224,6 +224,13 @@ class ModelRunner:
                 and cfg.num_experts > 0)
             else 0
         )
+        if config.pp_size > 1 and cfg.hc_mult > 1:
+            raise NotImplementedError(
+                f"pp_size {config.pp_size} is refused with hc_mult "
+                f"{cfg.hc_mult}: a pipeline stage hands [B, S, D] to the "
+                "next (parallel/pipeline.py), and the residual streams of "
+                "models/mhc.py are [B, S, n D]"
+            )
         if config.pp_size > 1:
             from ..models import deepseek as _deepseek
             from ..models import gemma2 as _gemma2
@@ -422,6 +429,13 @@ class ModelRunner:
                 "Device bytes of the recurrent state records held by slot "
                 "beside the paged cache (all layers, all slots)",
             ).set(_leaf_bytes(tuple(side.state for side in self.kv_cache)))
+        self.compiles.registry.gauge(
+            "dynamo_engine_model_info",
+            "1 for the architecture this engine serves: family= the "
+            "implementing module of models/, hc_mult= residual streams a "
+            "token (1: the plain residual path)",
+        ).set(1.0, family=self.arch.__name__.rsplit(".", 1)[-1],
+              hc_mult=str(cfg.hc_mult))
         self.device_time = DeviceTimeTracker(
             param_bytes=self.param_bytes,
             kv_bytes_per_token=self.kv_bytes_per_token,

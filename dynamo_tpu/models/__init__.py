@@ -24,6 +24,14 @@ def resolve(cfg: ModelConfig):
                 "which requires dynamo_tpu/models/deepseek.py"
             ) from e
         return deepseek
+    if cfg.hc_mult > 1:
+        # mixed residual streams with no family to mix them: every
+        # other trunk would add to one stream and serve wrong tokens
+        raise NotImplementedError(
+            f"hc_mult={cfg.hc_mult} needs a trunk that carries the residual "
+            "streams of models/mhc.py; only latent attention (model_type "
+            "xing4_0, models/deepseek.py) does"
+        )
     if cfg.model_family == "falcon_h1":
         from . import falcon_h1
 

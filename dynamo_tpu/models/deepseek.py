@@ -51,6 +51,7 @@ from .llama import (
     rms_norm,
     run_layers,
 )
+from . import mhc
 from .mixtral import make_moe_mlp_fn, split_expert_stacks
 from .quant import dense
 
@@ -188,6 +189,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         group["w_gate"] = w(keys[3], (n_dense, d_model, inter), d_model)
         group["w_up"] = w(keys[4], (n_dense, d_model, inter), d_model)
         group["w_down"] = w(keys[5], (n_dense, inter, d_model), inter)
+        if cfg.hc_mult > 1:
+            group.update(mhc.init_params(
+                cfg, n_dense, jax.random.fold_in(key, 12)))
         params["dense_layers"] = group
 
     if n_moe > 0:
@@ -209,6 +213,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             moe["w_sh_gate"] = w(sk[0], (n_moe, d_model, sh), d_model)
             moe["w_sh_up"] = w(sk[1], (n_moe, d_model, sh), d_model)
             moe["w_sh_down"] = w(sk[2], (n_moe, sh, d_model), sh)
+        if cfg.hc_mult > 1:
+            moe.update(mhc.init_params(cfg, n_moe, jax.random.fold_in(key, 13)))
         params["layers"] = moe
     return params
 
@@ -221,6 +227,8 @@ _MLA_ATTN_SPECS = {
     "wo": P(None, "tp", None),
     "wq": P(None, None, "tp"),
     "w_dq": P(), "ln_q": P(), "w_uq": P(None, None, "tp"),
+    # the mixing tensors of models/mhc.py: replicated, as the streams are
+    **{k: P() for k in mhc.PARAM_KEYS},
 }
 
 
@@ -507,6 +515,10 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     b, s = tokens.shape
     with jax.named_scope("embed"):
         hidden = params["embed"][tokens]
+    if cfg.hc_mult > 1:
+        # the trunk carries hc_mult residual streams [B, S, n D]
+        # (models/mhc.py); every caller keeps [B, S, D]
+        hidden = mhc.fan_out(hidden, cfg)
     attn_fn = make_mla_attn_fn(
         cfg, b, s, positions, slot_mapping, block_tables, context_lens,
         mesh=mesh,
@@ -527,6 +539,8 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
             li0=li,
         )
         stats = aux.sum(axis=0)
+    if cfg.hc_mult > 1:
+        hidden = mhc.read_out(hidden, cfg)
     return hidden, kv_cache, stats
 
 

@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, lane_pad, scatter_kv_stacked
+from . import mhc
 from .quant import dense
 
 Params = Dict[str, Any]
@@ -495,8 +496,14 @@ def run_layers(
     contiguous across groups. Returns (hidden, kv_cache, next_li, aux):
     an mlp_fn may return ``(y, aux)`` (the routed experts' counters) and
     ``aux`` is then every layer's, stacked; None for a plain mlp_fn.
+
+    With ``cfg.hc_mult > 1`` ``hidden`` is the family's residual streams
+    [B, S, n D] and each sublayer reads and writes them through
+    models/mhc.py; otherwise the two ``hidden + delta`` below are all
+    there is.
     """
     k_all, v_all = kv_cache
+    mixed = cfg.hc_mult > 1
 
     def layer_step(carry, layer_params):
         hidden, k_all, v_all, li = carry
@@ -504,14 +511,22 @@ def run_layers(
         # profiler's capture and the HLO dump show them); the compiled
         # code is the same with and without them
         with jax.named_scope("attn"):
-            x = rms_norm(hidden, layer_params["ln1"], cfg.rms_norm_eps)
+            x = hidden
+            if mixed:
+                x, coeffs = mhc.read(hidden, layer_params, "attn", cfg)
+            x = rms_norm(x, layer_params["ln1"], cfg.rms_norm_eps)
             delta, k_all, v_all = attn_fn(x, layer_params, k_all, v_all, li)
-            hidden = hidden + delta
+            hidden = (mhc.write(hidden, delta, coeffs, cfg) if mixed
+                      else hidden + delta)
         with jax.named_scope("mlp"):
-            x = rms_norm(hidden, layer_params["ln2"], cfg.rms_norm_eps)
+            x = hidden
+            if mixed:
+                x, coeffs = mhc.read(hidden, layer_params, "mlp", cfg)
+            x = rms_norm(x, layer_params["ln2"], cfg.rms_norm_eps)
             out = mlp_fn(x, layer_params)
             delta, aux = out if isinstance(out, tuple) else (out, None)
-            hidden = hidden + delta
+            hidden = (mhc.write(hidden, delta, coeffs, cfg) if mixed
+                      else hidden + delta)
         return (hidden, k_all, v_all, li + 1), aux
 
     (hidden, k_all, v_all, li), aux = jax.lax.scan(

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..engine.config import ModelConfig
+from . import mhc
 
 logger = logging.getLogger(__name__)
 
@@ -272,10 +273,12 @@ def load_gemma2_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -> 
 
 
 def _stack_group(
-    staging: Dict[str, Dict], n_layers: int, n_experts: int, dtype, label: str
+    staging: Dict[str, Dict], n_layers: int, n_experts: int, dtype, label: str,
+    keep_f32=(),
 ) -> Dict:
     """Stack a staged layer group into [L, ...] (or [L, E, ...] for keys
-    indexed by (layer, expert) tuples), validating completeness."""
+    indexed by (layer, expert) tuples), validating completeness. Keys in
+    ``keep_f32`` stay float32 whatever ``dtype`` is."""
     out = {}
     for key, by_idx in staging.items():
         if not by_idx:
@@ -296,7 +299,8 @@ def _stack_group(
             ])
         else:
             arr = np.stack([by_idx[i] for i in range(n_layers)])
-        out[key] = jnp.asarray(arr, dtype=dtype)
+        out[key] = jnp.asarray(
+            arr, dtype=jnp.float32 if key in keep_f32 else dtype)
     return out
 
 
@@ -503,6 +507,52 @@ def _rope_deinterleave(n: int) -> np.ndarray:
     return np.concatenate([np.arange(0, n, 2), np.arange(1, n, 2)])
 
 
+# The names of a sublayer's mixing tensors (models/mhc.py) in a
+# ``model_type: xing4_0`` checkpoint, under ``model.layers.<i>.``.
+# ASSUMED: no checkpoint of the family was at hand when this was
+# written (benchmark/configs/xing4-29b-a4b.json, "assumed"); a published
+# checkpoint's names are an edit of this table and of nothing else.
+# Linear weights are [out, in] as everywhere in HF checkpoints.
+XING4_MHC_MODULES = {"attn": "attn_hc", "mlp": "mlp_hc"}
+XING4_MHC_TENSORS = {
+    # name under the module -> (engine part, position in that part)
+    "phi_pre.weight": ("phi", 0), "phi_post.weight": ("phi", 1),
+    "phi_res.weight": ("phi", 2),
+    "b_pre": ("b", 0), "b_post": ("b", 1), "b_res": ("b", 2),
+    "alpha_pre": ("alpha", 0), "alpha_post": ("alpha", 1),
+    "alpha_res": ("alpha", 2),
+}
+
+
+def _mhc_tensor(rest: str):
+    """``(sublayer, part, position)`` of a mixing tensor's name, or None."""
+    module, _, leaf = rest.partition(".")
+    for sub, name in XING4_MHC_MODULES.items():
+        if module == name and leaf in XING4_MHC_TENSORS:
+            return (sub,) + XING4_MHC_TENSORS[leaf]
+    return None
+
+
+def _join_mhc(staging: Dict[str, Dict]) -> None:
+    """pre | post | res of every staged mixing tensor -> the engine's one
+    ``hc_<sub>_phi`` [n D, 2n + n^2], ``_b`` [2n + n^2], ``_alpha`` [3]."""
+    for group in staging.values():
+        for key in [k for k in group if isinstance(k, tuple)]:
+            sub, part = key
+            by_layer = group.pop(key)
+            for li, three in by_layer.items():
+                if sorted(three) != [0, 1, 2]:
+                    raise ValueError(
+                        f"incomplete checkpoint: layer {li} hc_{sub}_{part} "
+                        f"has parts {sorted(three)} of pre, post, res")
+                flat = [np.asarray(three[i], np.float32) for i in range(3)]
+                if part == "phi":          # [out, n D] each -> [n D, out]
+                    joined = np.concatenate([t.T for t in flat], axis=1)
+                else:
+                    joined = np.concatenate([t.reshape(-1) for t in flat])
+                group.setdefault(f"hc_{sub}_{part}", {})[li] = joined
+
+
 def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -> Dict:
     """HF DeepSeek-V2/V3 MLA (+ optional MoE) checkpoint → param pytree.
 
@@ -515,7 +565,14 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -
     - rope columns of the q projection and ``w_kr`` are de-interleaved
       (see _rope_deinterleave);
     - MoE layers restack at ``idx - first_k_dense_replace``; V3's
-      ``e_score_correction_bias`` loads as ``router_bias``.
+      ``e_score_correction_bias`` loads as ``router_bias``;
+    - tensors of ``model.layers.<num_hidden_layers>`` onward are the
+      multi-token-prediction modules (``num_nextn_predict_layers``): a
+      draft head the main model's logits do not depend on and no path
+      here runs; they are recognised, left out and logged once;
+    - ``model_type: xing4_0``: each sublayer's mixing tensors
+      (XING4_MHC_TENSORS) join into ``hc_<sub>_phi / _b / _alpha``,
+      float32 whatever ``dtype`` is.
     """
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     r, h, vd = cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim
@@ -538,6 +595,7 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -
         t[..., nope:] = t[..., nope + perm]
         return t.reshape(t.shape[0], -1)
 
+    skipped_mtp = []
     for name, tensor in _iter_safetensors(model_dir):
         name = name.removeprefix("model.")
         if name == "embed_tokens.weight":
@@ -553,10 +611,17 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -
             continue
         _, idx, rest = name.split(".", 2)
         idx = int(idx)
+        if idx >= cfg.num_layers:
+            skipped_mtp.append(name)
+            continue
         group = "dense_layers" if idx < n_dense else "layers"
         li = idx if idx < n_dense else idx - n_dense
 
-        if rest == "input_layernorm.weight":
+        mhc_part = _mhc_tensor(rest) if cfg.hc_mult > 1 else None
+        if mhc_part is not None:
+            sub, part, at = mhc_part
+            staging[group].setdefault((sub, part), {}).setdefault(li, {})[at] = tensor
+        elif rest == "input_layernorm.weight":
             put(group, "ln1", li, tensor)
         elif rest == "post_attention_layernorm.weight":
             put(group, "ln2", li, tensor)
@@ -607,6 +672,12 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -
         else:
             logger.debug("skipping unmapped tensor %s", name)
 
+    if skipped_mtp:
+        logger.info(
+            "left out %d tensors of layers >= %d (multi-token-prediction "
+            "modules, e.g. %s): no path here runs them",
+            len(skipped_mtp), cfg.num_layers, skipped_mtp[0])
+    _join_mhc(staging)
     params: Dict = {
         "embed": jnp.asarray(top["embed"], dtype=dtype),
         "final_norm": jnp.asarray(top["final_norm"], dtype=dtype),
@@ -615,10 +686,13 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig, dtype=jnp.bfloat16) -
         params["lm_head"] = jnp.asarray(top["lm_head"], dtype=dtype)
     if n_dense > 0:
         params["dense_layers"] = _stack_group(
-            staging["dense_layers"], n_dense, 0, dtype, "dense_layers"
+            staging["dense_layers"], n_dense, 0, dtype, "dense_layers",
+            keep_f32=mhc.PARAM_KEYS,
         )
     if n_moe > 0:
-        params["layers"] = _stack_group(staging["layers"], n_moe, e, dtype, "layers")
+        params["layers"] = _stack_group(
+            staging["layers"], n_moe, e, dtype, "layers",
+            keep_f32=mhc.PARAM_KEYS)
     return params
 
 

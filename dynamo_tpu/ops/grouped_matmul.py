@@ -34,10 +34,22 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 # batch's rows, 512 doubles the work of a tile two experts share)
 _ROW_ALIGN = 16
 _MAX_ROW_TILE = 128
-# weight tile [tk, tn]: the contraction whole (2048 or 1408 here), 512
-# output columns: 2 MiB a buffer in bfloat16, inside the default scoped
-# VMEM when double-buffered
-_K_TILE = 2048
+# weight tile [tk, tn]: the contraction whole at each of the three
+# widths the benchmark's configurations have (Moonlight 2048 and 1408,
+# Xing4.0 3584 and 1024), 512 output columns: at most 3.5 MiB a buffer
+# in bfloat16, 9.3 MiB with the row and output tiles double-buffered,
+# inside the default scoped VMEM of 16 MiB. A contraction wider than
+# _K_TILE is visited in tiles of it, the last one masked. Chosen on the
+# v5e at [rows, 3584] x [64, 3584, 1024], a product at 256 / 8192 rows
+# (my chip run, PR 33): the contraction whole 0.671 / 1.090 ms; 2048
+# (what this file had: two visits, the second masked to 1536) 0.832 /
+# 1.635; 1792 0.833 / 1.625; 3584 whole with 256 columns 0.751 / 1.209,
+# with 128 0.751 / 1.403; 1792 x 1024 0.739 / 1.468. 2048 and 1408 get
+# the tiles they had: (2048, 512) 0.542 / 0.972, (1408, 512) 0.578 /
+# 1.035. The down-projection [rows, 1024] x [64, 1024, 3584] keeps 512
+# columns, 0.698 / 1.396; 896 read 0.666 / 1.222 and 1792 0.683 / 1.156,
+# left to a perf_opt that can claim it (PERF.md section 7).
+_K_TILE = 3584
 _N_TILE = 512
 
 

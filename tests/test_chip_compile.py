@@ -185,3 +185,115 @@ def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
     # embedding 2.67 GB + six layers 5.16 + state 1.62 + pages 0.60 (the
     # head, 2.67 GB more, is the step's and not the trunk's)
     assert 9.9e9 < mem.argument_size_in_bytes < 10.3e9
+
+
+# Xing4.0-29B-A4B as one chip serves it (benchmark/configs/
+# xing4-29b-a4b.json): the MLA decode kernel at 32 heads over 7 layers
+# of latent cache, the grouped products at a contraction of 3584 (whole:
+# ops/grouped_matmul._tiling) and of 1024, and the Sinkhorn kernel at a
+# decode step's 64 tokens and a prefill step's 8192
+def test_mla_decode_kernel_compiles_at_xing4s_32_heads(one_chip, no_compile_cache):
+    from dynamo_tpu.models.deepseek import mla_softmax_scale
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "xing4-29b-a4b.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f))
+    # YaRN, factor 64, mscale_all_dim 1: (0.1 ln 64 + 1)^2 = 2.005 on the scale
+    assert mla_softmax_scale(cfg) == pytest.approx(192 ** -0.5 * 1.41589 ** 2, rel=1e-4)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, blocks, page, r, rd, b, heads, width = 7, 3072, 16, 512, 128, 64, 32, 256
+
+    def f(ql, qr, c, kr, bt, ctx, li):
+        return mla_paged_decode_attention(ql, qr, c, kr, bt, ctx, layer_idx=li,
+                                          scale=mla_softmax_scale(cfg))
+
+    compiled = jax.jit(f).lower(
+        s((b, 1, heads, r), jnp.bfloat16), s((b, 1, heads, rd), jnp.bfloat16),
+        s((layers, blocks, 1, page, r), jnp.bfloat16),
+        s((layers, blocks, 1, page, rd), jnp.bfloat16),
+        s((b, width), jnp.int32), s((b,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [256, 8192])
+@pytest.mark.parametrize("k,n", [(3584, 1024), (1024, 3584)])
+def test_grouped_products_compile_at_xing4s_expert_shapes(
+        one_chip, no_compile_cache, monkeypatch, rows, k, n):
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    # the tiles the chip timing chose; Moonlight's widths keep theirs
+    assert gm._tiling(rows, 3584, 1024) == (128, 3584, 512)
+    assert gm._tiling(rows, 1024, 3584) == (128, 1024, 512)
+    assert gm._tiling(rows, 2048, 1408) == (128, 2048, 512)
+    assert gm._tiling(rows, 1408, 2048) == (128, 1408, 512)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        s((rows, k), jnp.bfloat16), s((6, 64, k, n), jnp.bfloat16),
+        s((64,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [64, 8192])
+def test_sinkhorn_kernel_compiles_at_xing4s_steps(
+        one_chip, no_compile_cache, monkeypatch, tokens):
+    from dynamo_tpu.ops.sinkhorn import sinkhorn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(lambda z: sinkhorn(z, 4, 20, 1e-6, (-30.0, 30.0))).lower(
+        jax.ShapeDtypeStruct((16, tokens), jnp.float32, sharding=one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_xing4_decode_step_mixes_its_streams_in_a_few_operations(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole trunk of a decode step at the benchmark's size: the MLA
+    decode kernel, the three grouped products and the two Sinkhorn
+    kernels of a layer are in it, the cache is read where it lies, and
+    the mixing around a sublayer stays a handful of operations (left to
+    XLA the Sinkhorn iterations alone were 79)."""
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import deepseek
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "xing4-29b-a4b.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: deepseek.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    c, kr = jax.tree.map(s, jax.eval_shape(
+        lambda: deepseek.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
+                                       jnp.bfloat16)))
+    b, w = serve["max_batch_size"], 256
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, c, kr, tokens, positions, bt, slots, ctx):
+        return deepseek.forward_counted(params, cfg, tokens, positions,
+                                        (c, kr), bt, slots, ctx)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, c, kr, i32(b, 1), i32(b, 1), i32(b, w), i32(b, 1), i32(b)).compile()
+    text = compiled.as_text()
+    # dense group: MLA + 2 Sinkhorn; expert group: MLA + 2 Sinkhorn + 3 products
+    assert text.count("tpu_custom_call") == 9
+    assert len(re.findall(r"custom-call\([^\n]*mhc_sinkhorn/mhc_sinkhorn", text)) == 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+    # weights without the head 10.14 GB + cache 0.44 GB
+    assert 10.4e9 < mem.argument_size_in_bytes < 10.8e9
