@@ -28,7 +28,10 @@ window).
 Which row is which slot, and what is valid, is read from what the step
 already gets: a token whose cache slot is −1 (a pad position, a pad row,
 an idle decode row) is no token, so its Δ is 0 and the state passes it
-unchanged. A decode step's row *i* is slot *i*. A prefill row names its
+unchanged. A decode step's row *i* is slot *i*, and the rows that hold a
+token have their SSM records advanced where they lie, by one kernel a
+layer (``ops/ssm.ssm_decode_step``: one read and one write of a live
+row's state, nothing for an idle one). A prefill row names its
 slot (``state_slots``, the row's sampling slot), starts from zeros when
 its first position is 0 and from its slot's state otherwise (the next
 chunk of one prompt), and writes the state back as of its last valid
@@ -52,7 +55,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
-from ..ops.ssm import ssd_chunked_scan, ssm_decode_update
+from ..ops.ssm import live_row_list, ssd_chunked_scan, ssm_decode_step
 from . import llama
 from .llama import (ATTN_LAYER_SPECS, base_specs, lm_logits,
                     make_gqa_attn_fn, rms_norm)
@@ -266,8 +269,10 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
     valid = slot_mapping >= 0                       # [B, S] real tokens
     n_valid = valid.sum(axis=1).astype(jnp.int32)   # [B]
     decode = s == 1
-    if not decode:
-        live = valid[:, 0]
+    live = valid[:, 0]
+    if decode:      # the same for every layer: made once, outside the scan
+        row_list = live_row_list(live)
+    else:
         fresh = positions[:, 0] == 0
 
     # A prefill step has few rows (the row ladder stops at 8), so each
@@ -322,17 +327,18 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
         dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["dt_bias"])
         dt = jnp.where(valid[..., None], dt, 0.0)   # no token: state passes
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
-        h0 = read(ssm_all, li)
         if decode:
+            # the kernel updates the live rows' records where they lie
             with jax.named_scope("ssm_state"):
-                y, h1 = ssm_decode_update(
-                    xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], lp["D"], h0)
+                y, ssm_all = ssm_decode_step(
+                    xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], lp["D"],
+                    ssm_all, li, live, row_list)
                 y = y[:, None]
-                ssm_all = write(ssm_all, li, h1)
         else:
             with jax.named_scope("ssm_scan"):
                 y, h1 = ssd_chunked_scan(
-                    xs, dt, a, bm, cm, lp["D"], h0, cfg.mamba_chunk_size)
+                    xs, dt, a, bm, cm, lp["D"], read(ssm_all, li),
+                    cfg.mamba_chunk_size)
                 ssm_all = write(ssm_all, li, h1)
         y = y.reshape(b, s, d_ssm).astype(x.dtype)
         y = _gated_norm(y, z, lp["ssm_norm"], g, cfg.rms_norm_eps)

@@ -1,4 +1,5 @@
-"""The Mamba-2 state-space recurrence, two forms of one equation.
+"""The Mamba-2 state-space recurrence: one equation, its plain form, the
+chunked form prefill runs and the kernel decode runs.
 
 Per head, with state ``h ∈ R^{P×N}``, scalar decay rate ``A < 0`` and
 step ``Δ_t ≥ 0``::
@@ -11,9 +12,14 @@ step ``Δ_t ≥ 0``::
 state as it was and adds nothing: that is how the caller marks pad
 positions and idle rows.
 
-- ``ssm_decode_update``: one token: the elementwise update of the state
-  and the read-out against ``C`` (on the chip XLA makes two fusions of
-  it a layer, three passes over the state: PERF.md section 5).
+- ``ssm_decode_update``: one token, the equation as written, in plain
+  ``jnp``. No served program calls it (on the chip XLA makes two fusions
+  a layer of it, three passes over every slot's state: PERF.md section
+  6); it is the oracle the other two are held to.
+- ``ssm_decode_step``: one token for the rows of a decode step, on the
+  stacked records where they lie: a Pallas kernel that reads a live
+  row's state once, updates it, reads it out against ``C`` and writes it
+  once, and moves nothing for a row without a token.
 - ``ssd_chunked_scan``: a run of ``S`` tokens from a given state, in the
   chunked (state-space duality) form: inside a chunk of ``Q`` tokens the
   outputs are matrix products against a ``Q × Q`` decay-masked score
@@ -21,9 +27,13 @@ positions and idle rows.
   above, not an approximation of it; ``tests/test_falcon_h1_reference.py``
   holds it to the token-by-token form.
 
-XLA on every platform: no kernel, no switch. The state and the decays
-stay float32; the products take their operands in the activations' dtype
-and accumulate in float32.
+The chunked scan is XLA on every platform. The decode kernel is one
+route too, as ops/grouped_matmul.py: compiled on the chip, in the Pallas
+interpreter elsewhere, so the CPU tests walk what the chip runs. The
+state and the decays stay float32 (the kernel's arithmetic is float32 on
+the vector unit, no matrix-unit product that would round the state);
+the chunked products take their operands in the activations' dtype and
+accumulate in float32.
 """
 
 from __future__ import annotations
@@ -32,6 +42,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _heads_of_groups(m: jax.Array, heads: int) -> jax.Array:
@@ -59,6 +71,130 @@ def ssm_decode_update(
          + (dt[:, :, None] * x)[..., None] * bh[:, :, None, :])
     y = jnp.sum(h * ch[:, :, None, :], axis=-1) + d.astype(f32)[None, :, None] * x
     return y, h
+
+
+# state a block of the decode kernel: [heads, P, N] of one row, read
+# into VMEM once and written from it once. 2 MiB is 16 of Falcon-H1's 32
+# heads (128 x 256 float32), a group's worth, 8 MiB with both directions
+# double-buffered, inside the v5e's default scoped VMEM of 16 MiB. On the
+# chip, six layers of [64, 32, 128, 256] with 34 rows live: 2.75 ms at
+# 2 MiB, 2.86 at 1 MiB, 3.13 at 512 KiB (my chip run, PR 34): a step of
+# the grid costs 0.35 us beside the 2.6 us a MiB takes each way
+_STATE_BLOCK_BYTES = 2 << 20
+
+
+def _head_block(heads_per_group: int, head_bytes: int) -> int:
+    """Heads a block: the largest divisor of a group's heads (so that a
+    block reads one row of B and one of C) whose state fits
+    ``_STATE_BLOCK_BYTES``."""
+    fit = max(1, _STATE_BLOCK_BYTES // head_bytes)
+    return max(k for k in range(1, min(heads_per_group, fit) + 1)
+               if heads_per_group % k == 0)
+
+
+def live_row_list(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """live [B] bool -> (rows [B] int32, n int32): the numbers of the rows
+    that hold a token, in order, in ``rows[:n]`` (``B - 1`` after them):
+    what ``ssm_decode_step``'s grid walks. The same for every layer of a
+    step, so the caller makes it once."""
+    b = live.shape[0]
+    seen = jnp.cumsum(live.astype(jnp.int32))                 # [B]
+    # the (i+1)-th live row is the first with seen > i
+    rows = (seen[None, :] <= jnp.arange(b)[:, None]).sum(axis=1)
+    return jnp.minimum(rows, b - 1).astype(jnp.int32), seen[-1]
+
+
+def _decode_kernel(layer_ref, rows_ref, xdt_ref, decay_ref, bc_ref, h_ref,
+                   y_ref, o_ref):
+    """One block of heads of one live row: xdt [P, hb] (Δ·x, heads on
+    lanes so that a head's column spreads over the state's lanes), decay
+    [1, hb], bc [2, N] (the group's B and C), h / o [hb, P, N], y [P, hb]."""
+    del layer_ref, rows_ref
+    b_row, c_row = bc_ref[0:1, :], bc_ref[1:2, :]
+    for j in range(h_ref.shape[0]):
+        h = (h_ref[j].astype(jnp.float32) * decay_ref[:, j:j + 1]
+             + xdt_ref[:, j:j + 1] * b_row)
+        o_ref[j] = h.astype(o_ref.dtype)
+        y_ref[:, j:j + 1] = jnp.sum(h * c_row, axis=-1, keepdims=True)
+
+
+def ssm_decode_step(
+    x: jax.Array,        # [B, H, P]
+    dt: jax.Array,       # [B, H] float32, 0 where the row has no token
+    a: jax.Array,        # [H] float32, negative
+    bm: jax.Array,       # [B, G, N]
+    cm: jax.Array,       # [B, G, N]
+    d: jax.Array,        # [H]
+    records: jax.Array,  # [L, slots, H, P, N]; row i of the step is slot i
+    layer: jax.Array,    # int32 scalar, traced
+    live: jax.Array,     # [B] bool: the rows that hold a token
+    row_list,            # live_row_list(live)
+) -> Tuple[jax.Array, jax.Array]:
+    """(y [B, H, P] float32, zero in a row without a token; the records
+    with layer ``layer`` of the live rows advanced by one token).
+
+    ``ssm_decode_update`` on ``records[layer, :B]``, where the records
+    lie: the buffer is the kernel's input and its output
+    (``input_output_aliases``), and the layer is picked by the index map
+    from a prefetched scalar, so nothing slices a layer out or puts one
+    back. The grid is (live row, block of heads) over the compacted list
+    of live rows, its first bound the number of them, known on the device
+    only: a row without a token is no step of the grid, so nothing is
+    fetched, computed or written back for it, and with no live row the
+    kernel does nothing. A row without a token, a slot past ``B`` and
+    every other layer come out bit for bit as they went in. The
+    arithmetic is float32 whatever the records' dtype; the state is
+    rounded to it once, on the way out."""
+    b, heads, p = x.shape
+    g, n_state = bm.shape[-2:]
+    per_group = heads // g
+    f32 = jnp.float32
+    hb = _head_block(per_group, p * n_state * records.dtype.itemsize)
+    nb = heads // hb
+    rows, n = row_list
+    x = x.astype(f32)
+    xdt = (dt[:, :, None] * x).reshape(b, nb, hb, p).transpose(0, 1, 3, 2)
+    decay = jnp.exp(dt * a).reshape(b, nb, 1, hb)
+    bc = jnp.stack([bm.astype(f32), cm.astype(f32)], axis=2)   # [B, G, 2, N]
+
+    def by_row(i, j, layer_ref, rows_ref):
+        return rows_ref[i], j, 0, 0
+
+    def by_group(i, j, layer_ref, rows_ref):
+        return rows_ref[i], j * hb // per_group, 0, 0
+
+    def state(i, j, layer_ref, rows_ref):
+        return layer_ref[0], rows_ref[i], j, 0, 0
+
+    y, records = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n, nb),
+            in_specs=[
+                pl.BlockSpec((None, None, p, hb), by_row),
+                pl.BlockSpec((None, None, 1, hb), by_row),
+                pl.BlockSpec((None, None, 2, n_state), by_group),
+                pl.BlockSpec((None, None, hb, p, n_state), state),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, p, hb), by_row),
+                pl.BlockSpec((None, None, hb, p, n_state), state),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((b, nb, p, hb), f32),
+                   jax.ShapeDtypeStruct(records.shape, records.dtype)],
+        # operands count the two prefetched scalars: the records in, the
+        # records out
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=jax.default_backend() != "tpu",
+        name="ssm_decode_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows, xdt, decay, bc, records)
+    y = y.transpose(0, 1, 3, 2).reshape(b, heads, p)
+    y = y + d.astype(f32)[None, :, None] * x
+    # a row the grid never visited is memory nobody wrote
+    return jnp.where(live[:, None, None], y, 0.0), records
 
 
 def ssd_chunked_scan(

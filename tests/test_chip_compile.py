@@ -136,12 +136,45 @@ def test_decode_and_flash_kernels_compile_at_falcon_h1s_heads(
         assert "tpu_custom_call" in compiled.as_text(), (b, sq)
 
 
+# Falcon-H1-34B's mixer state as one chip serves it: 6 layers x 64 slots
+# of [32, 128, 256], a group's 16 heads a block; float32 as the
+# configuration keeps it, and bfloat16 as a records buffer may come
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
+        one_chip, no_compile_cache, monkeypatch, state_dtype):
+    from dynamo_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, slots, h, p, n, g = 6, 64, 32, 128, 256, 2
+    f32, act = jnp.float32, jnp.bfloat16
+
+    def f(x, dt, a, bm, cm, d, records, li, live):
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li, live,
+                                   ssm.live_row_list(live))
+
+    compiled = jax.jit(f, donate_argnums=(6,)).lower(
+        s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
+        s((slots, g, n), act), s((slots, g, n), act), s((h,), f32),
+        s((layers, slots, h, p, n), jnp.dtype(state_dtype)), s((), jnp.int32),
+        s((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the records are the kernel's output where they lay: no second buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
         one_chip, no_compile_cache, monkeypatch):
     """The whole trunk of a decode step at the benchmark's size, on the
-    routes the chip takes: the decode kernel is in it, and neither the
-    recurrent state (1.62 GB at 64 slots) nor the pages (0.60 GB) are
-    copied: the program's temporaries stay far under either."""
+    routes the chip takes: the attention decode kernel and the mixer's
+    state kernel (ops/ssm.ssm_decode_step, its records aliased in to
+    out) are in it, and neither the recurrent state (1.62 GB at 64
+    slots) nor the pages (0.60 GB) are copied: no operation but the
+    kernel and the loop that carries it makes an array of the state's
+    shape, and the program's temporaries stay far under either."""
     from dynamo_tpu.engine.config import ModelConfig
     from dynamo_tpu.models import falcon_h1
 
@@ -177,11 +210,26 @@ def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
         i32(b)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # every operation that takes or makes the state: the loop over the
+    # layers and what hands the buffer on, and the kernel; never a copy,
+    # a fusion, a dynamic-update-slice or a scatter of it
+    state = "f32[6,64,32,128,256]"
+    ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1), ln)
+           for ln in text.splitlines()[1:] if state in ln and " = " in ln]
+    assert {op for op, _ in ops} <= {
+        "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+        "custom-call"}, {op for op, _ in ops}
+    kernel = [ln for op, ln in ops if op == "custom-call"]
+    assert len(kernel) == 1 and "ssm_decode_step" in kernel[0].split(" = ")[0]
+    # result 1 is operand 6 (the grid's bound, two scalars, four blocks)
+    assert re.search(r"output_to_operand_aliasing=\{[^=]*\{1\}: \(6, \{\}\)",
+                     kernel[0]), kernel[0][-800:]
     mem = compiled.memory_analysis()
     print("decode step: arguments", mem.argument_size_in_bytes,
           "temporaries", mem.temp_size_in_bytes)
-    # one layer's state of all slots is 268 MB: not even that is copied
-    assert mem.temp_size_in_bytes < 128 * 2 ** 20
+    # one layer's state of all slots is 268 MB; the step's temporaries
+    # are a few activations (1.1 MB at PR 34)
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
     # embedding 2.67 GB + six layers 5.16 + state 1.62 + pages 0.60 (the
     # head, 2.67 GB more, is the step's and not the trunk's)
     assert 9.9e9 < mem.argument_size_in_bytes < 10.3e9

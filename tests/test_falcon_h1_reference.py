@@ -520,6 +520,59 @@ def test_scopes_in_the_lowered_programs():
     assert "ssm/ssm_scan" in prefill and "ssm_state" not in prefill
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (the layer
+    scan's body, a nested jit), not a kernel's own body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner)
+
+
+def test_decode_program_donates_the_records_and_updates_them_in_place():
+    """The decode program hands the SSM records to the kernel as its input
+    and its output, and nothing else makes an array of their shape: no
+    slice of a layer, no ``.at[li, :b].set``. Compiled with the cache
+    donated, the records' argument is aliased to the records' result.
+    (What the compiler then copies is a backend's business: off the TPU
+    the interpreter's loop over the grid carries the buffer and copies
+    it; for the v5e, ``tests/test_chip_compile.py`` asserts that the
+    compiled program at the benchmark's size has no copy of the state.)"""
+    cfg, params = _params(jnp.float32)
+    cache = falcon_h1.init_kv_cache(cfg, 8, BLOCK, jnp.float32, num_slots=SLOTS)
+    shape = cache[0].state.shape
+    args = (jnp.zeros((SLOTS, 1), jnp.int32), jnp.zeros((SLOTS, 1), jnp.int32),
+            cache, jnp.zeros((SLOTS, 8), jnp.int32),
+            jnp.zeros((SLOTS, 1), jnp.int32), jnp.ones((SLOTS,), jnp.int32))
+
+    def step(*a):
+        return falcon_h1.forward(params, cfg, *a)
+
+    eqns = list(_equations(jax.make_jaxpr(step)(*args).jaxpr))
+    makers = {e.primitive.name for e in eqns
+              if any(getattr(v.aval, "shape", None) == shape for v in e.outvars)}
+    assert makers == {"pallas_call", "scan"}, makers      # scan: the carry
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"
+               and e.params["name"] == "ssm_decode_step"]
+    assert len(kernels) == 1                              # one, in the scan's body
+    # operand 5 (after the two prefetched scalars) is result 1; the
+    # equation's inputs start with the grid's bound, the number of live rows
+    assert tuple(kernels[0].params["input_output_aliases"]) == ((5, 1),)
+    assert kernels[0].invars[1 + 5].aval.shape == shape
+    assert kernels[0].outvars[1].aval.shape == shape
+
+    text = jax.jit(step, donate_argnums=(2,)).lower(*args).compile().as_text()
+    header = text.split("\n", 1)[0]
+    layout = header.split("entry_computation_layout={(", 1)[1].split(", ")
+    param = next(i for i, t in enumerate(layout)
+                 if t.startswith("f32[%s]" % ",".join(map(str, shape))))
+    assert f"({param}, {{}}, may-alias)" in header, header[:400]
+
+
 def test_random_weights_serve_logits_of_a_few_units():
     """``init_params`` divides each matrix by the multipliers beside it:
     the served logits spread by ``LOGIT_STD``, where plain fan-in weights

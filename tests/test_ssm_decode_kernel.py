@@ -1,0 +1,140 @@
+"""``ops/ssm.ssm_decode_step``, the decode kernel of the Mamba-2 state
+update, against the equation's plain form ``ssm_decode_update``: the
+live rows' records advanced by one token where they lie, everything else
+bit for bit as it was. Here the kernel runs in the Pallas interpreter
+(the route every backend but the TPU takes); ``tests/test_chip_compile.py``
+compiles it for the v5e at the benchmark's shape.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.ops import ssm
+
+LAYERS = 3
+
+
+def _inputs(h, p, n, g, slots, live, seed=0, dtype=jnp.float32):
+    """A decode step's operands for ``len(live)`` rows (Δ = 0 in the rows
+    that hold no token, as the trunk makes it) and random records."""
+    rs = np.random.RandomState(seed)
+    live = np.asarray(live, bool)
+    b = len(live)
+    dt = np.exp(rs.uniform(np.log(1e-3), np.log(0.5), (b, h))) * live[:, None]
+    args = (rs.randn(b, h, p), dt, -rs.uniform(1, 16, h), rs.randn(b, g, n),
+            rs.randn(b, g, n), rs.randn(h))
+    records = jnp.asarray(rs.randn(LAYERS, slots, h, p, n), jnp.float32)
+    return ([jnp.asarray(t, jnp.float32) for t in args],
+            records.astype(dtype), live)
+
+
+@jax.jit
+def _step(args, records, layer, live):
+    return ssm.ssm_decode_step(*args, records, layer, live,
+                               ssm.live_row_list(live))
+
+
+def _oracle(args, records, layer, b):
+    return ssm.ssm_decode_update(*args, records[layer, :b].astype(jnp.float32))
+
+
+# (H, P, N, G, slots, live mask, layer)
+CASES = {
+    # the tiny trunk's mixer (tests/test_falcon_h1_reference.py): 6 heads
+    # in 2 groups, so a block is a group's 3 heads
+    "no_live_row": (6, 8, 16, 2, 4, [0, 0, 0, 0], 1),
+    "every_row_live": (6, 8, 16, 2, 4, [1, 1, 1, 1], 0),
+    "live_row_after_a_run_of_idle_ones": (6, 8, 16, 2, 6, [0, 0, 0, 0, 1, 0], 1),
+    "first_row_only_last_layer": (6, 8, 16, 2, 4, [1, 0, 0, 0], 2),
+    "idle_rows_between_live_ones": (6, 8, 16, 2, 5, [1, 0, 1, 0, 1], 2),
+    "fewer_rows_than_slots": (6, 8, 16, 2, 7, [0, 1, 1], 1),
+    # a state of whole (8, 128) tiles, one group, one head a group
+    "one_group_of_eight_heads": (8, 8, 128, 1, 3, [0, 1, 0], 2),
+    "a_head_a_group": (4, 16, 128, 4, 2, [1, 1], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_is_the_plain_update_on_the_live_rows_and_touches_nothing_else(case):
+    h, p, n, g, slots, live, layer = CASES[case]
+    args, records, live = _inputs(h, p, n, g, slots, live, seed=len(case))
+    b = len(live)
+    y, new = _step(args, records, jnp.int32(layer), jnp.asarray(live))
+    want_y, want_h = _oracle(args, records, layer, b)
+    y, new, before = np.asarray(y), np.asarray(new), np.asarray(records)
+    np.testing.assert_allclose(y[live], np.asarray(want_y)[live],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(new[layer, :b][live], np.asarray(want_h)[live],
+                               rtol=1e-5, atol=1e-5)
+    # a row without a token: no output, and its record as it went in;
+    # so the slots past the step's rows, and every other layer
+    assert not y[~live].any()
+    np.testing.assert_array_equal(new[layer, :b][~live], before[layer, :b][~live])
+    np.testing.assert_array_equal(new[layer, b:], before[layer, b:])
+    others = [i for i in range(LAYERS) if i != layer]
+    np.testing.assert_array_equal(new[others], before[others])
+
+
+def test_forty_steps_in_a_row_stay_on_the_plain_update():
+    """The kernel's state fed back to it 40 times against the oracle's fed
+    back to the oracle: the difference does not grow with the steps."""
+    h, p, n, g, slots = 6, 8, 16, 2, 4
+    live = np.array([1, 0, 1, 1], bool)
+    _, records, _ = _inputs(h, p, n, g, slots, live, seed=1)
+    state = records[1, :4]
+    errs = []
+    for t in range(40):
+        args, _, _ = _inputs(h, p, n, g, slots, live, seed=100 + t)
+        y, records = _step(args, records, jnp.int32(1), jnp.asarray(live))
+        want_y, state = ssm.ssm_decode_update(*args, state)
+        errs.append(max(float(jnp.abs(y - want_y)[live].max()),
+                        float(jnp.abs(records[1, :4] - state).max())))
+    assert max(errs) < 1e-5
+    assert max(errs[30:]) <= 2 * max(errs[:10]) + 1e-6
+
+
+def test_bfloat16_records_are_taken_as_found_and_computed_in_float32():
+    """A records buffer in bfloat16 (the wrong program of
+    test_falcon_h1_reference's ``bf16_state``) is read, updated in
+    float32 and rounded once on the way out."""
+    args, records, live = _inputs(6, 8, 16, 2, 4, [0, 1, 1, 0], seed=5,
+                                  dtype=jnp.bfloat16)
+    y, new = _step(args, records, jnp.int32(2), jnp.asarray(live))
+    assert new.dtype == jnp.bfloat16 and y.dtype == jnp.float32
+    want_y, want_h = _oracle(args, records, 2, 4)
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(want_y)[live],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(new[2].astype(jnp.float32))[live],
+        np.asarray(want_h.astype(jnp.bfloat16).astype(jnp.float32))[live])
+    np.testing.assert_array_equal(
+        np.asarray(new.astype(jnp.float32))[:, ~live],
+        np.asarray(records.astype(jnp.float32))[:, ~live])
+
+
+@pytest.mark.parametrize("live,rows,n", [
+    ([0, 1, 0, 1], [1, 3], 2),
+    ([1, 1, 1], [0, 1, 2], 3),
+    ([0, 0, 0], [], 0),
+    ([0, 0, 1, 0, 0], [2], 1),
+    ([1], [0], 1),
+])
+def test_live_row_list_is_the_live_rows_in_order(live, rows, n):
+    got_rows, got_n = ssm.live_row_list(jnp.asarray(live, bool))
+    assert got_rows.dtype == jnp.int32 and got_n.dtype == jnp.int32
+    assert int(got_n) == n and got_rows[:n].tolist() == rows
+    # what follows them is a row's number too: an index map may read it
+    assert got_rows.shape == (len(live),) and int(got_rows.max()) < len(live)
+
+
+@pytest.mark.parametrize("per_group,head_bytes,want", [
+    (16, 128 * 256 * 4, 16),    # Falcon-H1-34B: a group's heads, 2 MiB
+    (16, 128 * 512 * 4, 8),     # a state twice as wide: half of them
+    (3, 8 * 16 * 4, 3),         # the tiny trunk: a group's three heads
+    (5, 2 << 20, 1),            # a head as large as a block
+    (12, (2 << 20) // 5, 4),    # room for five: the divisor below it
+])
+def test_head_block_divides_a_group_and_fits_the_block(per_group, head_bytes, want):
+    assert ssm._head_block(per_group, head_bytes) == want
