@@ -665,9 +665,10 @@ class Scheduler:
         )
         self._bubble_hist = reg.histogram(
             "dynamo_engine_decode_pipeline_bubble_seconds",
-            "Host-observed device-idle gap between consecutive decode "
-            "bursts (0 when the next burst was dispatched while the "
-            "previous one was still executing on device)",
+            "The gap the device sees between consecutive decode bursts, "
+            "by the host's clock: the previous burst's tokens on the host "
+            "(t_ready) to the next dispatch (0 when the next burst was "
+            "dispatched while the previous one was still executing)",
             buckets=STEP_BUCKETS,
         )
         reg.callback_gauge(
@@ -841,6 +842,19 @@ class Scheduler:
             "frontend work the device's own step hides",
         )
 
+        self._fetch_ctr = reg.counter(
+            "dynamo_scheduler_fetch_seconds_total",
+            "A synchronous wait for a device result (_fetch), by part: "
+            "ready_wait = blocked after the frontend's turn until the "
+            "tokens are on the host, copy = the arrays after them, hop = "
+            "from the executor thread back to the scheduler's loop; "
+            "kind=decode|prefill. The three sum to the host_sync phase",
+        )
+        self._fetches_ctr = reg.counter(
+            "dynamo_scheduler_fetches_total",
+            "Synchronous waits for a device result, kind=decode|prefill",
+        )
+
     def _observe_host_sync(self, dt: float) -> None:
         self._phase_hist.observe(dt, phase="host_sync")
         self._host_sync_s += dt
@@ -874,21 +888,66 @@ class Scheduler:
             or any(s is not None and s not in self.prefilling
                    and not self._is_sp(s) for s in self.slots)
 
-    async def _fetch(self, loop, name: str, to_host, turn: bool = True):
-        """Wait for the result of the pass's latest dispatch: the
-        frontend's turn first (``turn``: unless a later dispatch of this
-        pass will take it), then ``to_host`` on an executor thread under
-        the span ``name``. Returns (result, the moment the wait began):
-        ``sched.*.sync`` and the host_sync phase are time blocked on the
-        device, with the frontend's work already done."""
+    async def _fetch(self, loop, kind: str, arrays, turn: bool = True,
+                     tokens_at: int = 0, chaos: Optional[str] = None):
+        """Wait for the result of the pass's latest dispatch and bring it
+        to the host: the frontend's turn first (``turn``: unless a later
+        dispatch of this pass will take it), then one ``np.asarray``
+        after another over the device ``arrays`` on an executor thread,
+        under ``sched.<kind>.sync``. Every synchronous result passes
+        here, so the wait is written once, in its parts:
+
+        - ``sync.ready``: until the tokens (``arrays[tokens_at]``; a
+          prompt-scoring prefill copies its accumulated rows first, as
+          it always did) are on the host. Its end is ``t_ready``, the
+          program's "result ready" stamp;
+        - ``sync.copy``: the arrays after them, what one packed result
+          would save;
+        - the hop back from the executor thread to the loop, where this
+          coroutine queues behind whatever frontend task is running. It
+          crosses threads, so the capture has it as ``sched.*.sync`` end
+          minus ``sync.fetch`` end and the program as a counter.
+
+        ``dynamo_scheduler_fetch_seconds_total`` has the same three by
+        the host's clock (``ready_wait`` from the moment the wait began,
+        so it holds what was left of the device's step); they sum to the
+        host_sync phase, stamped at the same two moments: time blocked
+        on the device, with the frontend's work already done. ``chaos``
+        names a fault site (utils/faults.py) that wedges the executor
+        thread first. Returns (host arrays, ``t_ready``)."""
         if turn:
             await self._frontend_turn()
+        head, rest = arrays[:tokens_at + 1], arrays[tokens_at + 1:]
+
+        def _nbytes(xs):
+            return sum(getattr(x, "nbytes", 0) for x in xs)
+
+        def _to_host():
+            if chaos is not None:
+                faults.maybe_hang(chaos)
+            with span("sync.fetch"):
+                with span("sync.ready", bytes=_nbytes(head)):
+                    out = [np.asarray(x) for x in head]
+                t_ready = time.monotonic()
+                if rest:
+                    with span("sync.copy", arrays=len(rest),
+                              bytes=_nbytes(rest)):
+                        out.extend(np.asarray(x) for x in rest)
+                return out, t_ready, time.monotonic()
+
         t_sync = time.monotonic()
-        with span(name, step=self.passes):
-            out = await loop.run_in_executor(None, to_host)
+        with span(f"sched.{kind}.sync", step=self.passes):
+            out, t_ready, t_copied = await loop.run_in_executor(
+                None, _to_host)
+            t_resumed = time.monotonic()
         # programs run in dispatch order: nothing older is pending either
         self._inflight = False
-        return out, t_sync
+        self._observe_host_sync(t_resumed - t_sync)
+        self._fetches_ctr.inc(kind=kind)
+        self._fetch_ctr.inc(t_ready - t_sync, part="ready_wait", kind=kind)
+        self._fetch_ctr.inc(t_copied - t_ready, part="copy", kind=kind)
+        self._fetch_ctr.inc(t_resumed - t_copied, part="hop", kind=kind)
+        return out, t_ready
 
     def _mark_admission(self, er: EngineRequest) -> None:
         """The admission mark, and the wait since the request last
@@ -1614,36 +1673,30 @@ class Scheduler:
 
         ``ready_hint`` is the moment an ``is_ready`` probe saw the
         outputs materialized (the async row drain) — the device-time
-        observation below prefers it over the post-sync stamp so drain
-        lag and D2H copy time are not charged as device compute."""
-        t_sync = time.monotonic()
-
-        def _sync_burst():
-            # chaos site: DYN_FAULT=decode_burst_hang wedges THIS thread
-            # — the exact executor-side shape of a hung Mosaic compile
-            # or a dead device mid-sync (utils/faults.py)
-            faults.maybe_hang("decode_burst_hang")
-            with span("sync.fetch"):
-                if infl.spec:
-                    # spec rounds carry no logprob outputs (spec-eligible
-                    # rows want none) but do carry acceptance accounting
-                    return (np.asarray(infl.toks), None, None, None,
-                            np.asarray(infl.nprop), np.asarray(infl.nacc))
-                return (np.asarray(infl.toks), np.asarray(infl.lps),
-                        np.asarray(infl.tv), np.asarray(infl.ti), None, None)
-
-        with span("sched.decode.sync", step=self.passes):
-            toks, lpn, tv, ti, nprop, nacc = await loop.run_in_executor(
-                None, _sync_burst)
+        observation below prefers it over the fetch's own ``t_ready`` so
+        drain lag is not charged as device compute."""
+        # chaos site: DYN_FAULT=decode_burst_hang wedges the executor
+        # thread — the exact executor-side shape of a hung Mosaic compile
+        # or a dead device mid-sync (utils/faults.py). The chain's
+        # bursts are in flight already: the turn is not this fetch's.
+        # Spec rounds carry no logprob outputs (spec-eligible rows want
+        # none) but do carry acceptance accounting.
+        arrays = ([infl.toks, infl.nprop, infl.nacc] if infl.spec
+                  else [infl.toks, infl.lps, infl.tv, infl.ti])
+        got, t_ready = await self._fetch(
+            loop, "decode", arrays, turn=False, chaos="decode_burst_hang")
+        lpn = tv = ti = nprop = nacc = None
+        if infl.spec:
+            toks, nprop, nacc = got
+        else:
+            toks, lpn, tv, ti = got
         with span("sched.decode.emit", step=self.passes,
                   rows=len(infl.active)):
-            self._observe_host_sync(time.monotonic() - t_sync)
-            self._last_burst_done_t = time.monotonic()
+            self._last_burst_done_t = t_ready
             if self.device_time is not None and infl.dispatch_t:
                 self.device_time.observe(
                     "decode_burst_df", "decode", infl.dispatch_t,
-                    ready_hint if ready_hint is not None
-                    else self._last_burst_done_t,
+                    ready_hint if ready_hint is not None else t_ready,
                     read_bytes=infl.read_bytes, tokens=infl.tokens,
                 )
             for j in range(infl.k_steps):
@@ -2950,20 +3003,11 @@ class Scheduler:
                     trace_id=er.ctx.trace_id, k_steps=k_steps,
                 )
 
-        def _sync():
-            with span("sync.fetch"):
-                out = [np.asarray(next_tokens), np.asarray(lps),
-                       np.asarray(top_vals), np.asarray(top_ids)]
-                if burst is not None:
-                    out.extend(np.asarray(x) for x in burst)
-                return out
-
-        synced, t_sync = await self._fetch(
-            loop, "sched.prefill.sync", _sync,
+        synced, t_done = await self._fetch(
+            loop, "prefill",
+            [next_tokens, lps, top_vals, top_ids, *(burst or ())],
             turn=not self._decode_follows([er]))
         with span("sched.prefill.emit", step=self.passes, rows=1):
-            t_done = time.monotonic()
-            self._observe_host_sync(t_done - t_sync)
             if burst is None:
                 self._sp_exposed_h.observe(t_done - st.final_dispatch_t)
             if self.device_time is not None:
@@ -2975,8 +3019,10 @@ class Scheduler:
                     ),
                 )
                 if burst is not None:
+                    # the burst's own arrays came with the copies, after
+                    # the first token: the loop's stamp bounds its end
                     self.device_time.observe(
-                        "decode_burst", "decode", t_burst, t_done,
+                        "decode_burst", "decode", t_burst, time.monotonic(),
                         read_bytes=self.device_time.decode_read_bytes(
                             k_steps, er.context_len,
                         ),
@@ -3150,34 +3196,29 @@ class Scheduler:
         if not finals:
             return
 
-        def _to_host():
-            with span("sync.fetch"):
-                # every device→host transfer off the event loop: final-row
-                # outputs plus any accumulated prompt-logprob rows (an
-                # echo+logprobs prompt may hold many chunk rows)
-                plists = {
-                    i: [
-                        float(x)
-                        for row, cnt in plan[i][0].prompt_lp_parts
-                        for x in np.asarray(row)[0, :cnt]
-                    ]
-                    for i in finals
-                    if plan[i][0].prompt_lp_parts
-                }
-                return (np.asarray(next_tokens), np.asarray(lps),
-                        np.asarray(top_vals), np.asarray(top_ids), plists)
-
-        (toks, lpn, tv, ti, plists), t_sync = await self._fetch(
-            loop, "sched.prefill.sync", _to_host,
-            turn=not self._decode_follows([plan[i][0] for i in finals]))
+        # every device→host transfer off the event loop: any accumulated
+        # prompt-logprob rows (an echo+logprobs prompt may hold many chunk
+        # rows) first, as they always were, then the final rows' outputs
+        lp_rows = [row for i in finals
+                   for row, _ in plan[i][0].prompt_lp_parts]
+        synced, t_ready = await self._fetch(
+            loop, "prefill",
+            [*lp_rows, next_tokens, lps, top_vals, top_ids],
+            turn=not self._decode_follows([plan[i][0] for i in finals]),
+            tokens_at=len(lp_rows))
+        toks, lpn, tv, ti = synced[len(lp_rows):]
         with span("sched.prefill.emit", step=self.passes, rows=len(finals)):
-            self._observe_host_sync(time.monotonic() - t_sync)
+            host_rows = iter(synced[:len(lp_rows)])
+            plists = {
+                i: [float(x) for _, cnt in plan[i][0].prompt_lp_parts
+                    for x in next(host_rows)[0, :cnt]]
+                for i in finals
+                if plan[i][0].prompt_lp_parts
+            }
             if self.device_time is not None:
                 # non-final chunks never sync; their device time folds into
                 # this observation via the serialized-interval estimator
-                self.device_time.observe(
-                    "prefill", "prefill", t0, time.monotonic(),
-                )
+                self.device_time.observe("prefill", "prefill", t0, t_ready)
             for i in finals:
                 er = plan[i][0]
                 self.prefilling.remove(er)
@@ -3347,13 +3388,8 @@ class Scheduler:
             )
             self._inflight = True
 
-        def _sync_draft():
-            with span("sync.fetch"):
-                return np.asarray(toksK)
-
         # the verify dispatch below is the pass's last: the turn is its
-        tk, _ = await self._fetch(
-            loop, "sched.decode.sync", _sync_draft, turn=False)
+        (tk,), _ = await self._fetch(loop, "decode", [toksK], turn=False)
         self.steps += 1
         return {
             er.slot: [int(t) for t in tk[:K, er.slot]] for er in active
@@ -3464,19 +3500,13 @@ class Scheduler:
             )
             self._inflight = True
 
-        def _sync_verify():
-            with span("sync.fetch"):
-                return np.asarray(greedy_all)
-
-        ga, t_sync = await self._fetch(
-            loop, "sched.decode.sync", _sync_verify)
+        (ga,), t_ready = await self._fetch(loop, "decode", [greedy_all])
         with span("sched.decode.emit", step=self.passes, rows=len(active)):
-            self._observe_host_sync(time.monotonic() - t_sync)
             if self.device_time is not None:
                 # the verify forward is one decode-shaped step over S
                 # positions: weights once + each row's (ctx + S) KV
                 self.device_time.observe(
-                    "spec_verify", "decode", t_dispatch, time.monotonic(),
+                    "spec_verify", "decode", t_dispatch, t_ready,
                     read_bytes=self.device_time.decode_read_bytes(
                         1, sum(er.context_len + S for er in active),
                     ),
@@ -3599,8 +3629,8 @@ class Scheduler:
             want_top = any(er.logprobs_n > 0 for er in active)
 
             # synchronous path: the device has been idle since the previous
-            # burst's host sync completed — that gap IS the bubble the
-            # chain exists to close
+            # burst's tokens reached the host (t_ready) — that gap IS the
+            # bubble the chain exists to close
             if self._last_burst_done_t is not None:
                 self._bubble_hist.observe(
                     time.monotonic() - self._last_burst_done_t
@@ -3646,21 +3676,15 @@ class Scheduler:
                     )
             self._inflight = True
 
-        def _sync_step():
-            faults.maybe_hang("decode_burst_hang")  # chaos site (see above)
-            with span("sync.fetch"):
-                return (np.asarray(next_tokens), np.asarray(lps),
-                        np.asarray(top_vals), np.asarray(top_ids))
-
-        (toks, lpn, tv, ti), t_sync = await self._fetch(
-            loop, "sched.decode.sync", _sync_step)
+        (toks, lpn, tv, ti), t_ready = await self._fetch(
+            loop, "decode", [next_tokens, lps, top_vals, top_ids],
+            chaos="decode_burst_hang")  # chaos site (see _apply_burst)
         with span("sched.decode.emit", step=self.passes, rows=len(active)):
-            self._observe_host_sync(time.monotonic() - t_sync)
-            self._last_burst_done_t = time.monotonic()
+            self._last_burst_done_t = t_ready
             if self.device_time is not None:
                 self.device_time.observe(
                     "decode_burst" if k_steps > 1 else "decode", "decode",
-                    t_dispatch, self._last_burst_done_t,
+                    t_dispatch, t_ready,
                     read_bytes=self.device_time.decode_read_bytes(
                         k_steps, sum(er.context_len for er in active),
                     ),

@@ -27,12 +27,16 @@ positive) is a bubble — the device genuinely ran dry. Under chained
 dispatch the intervals overlap and the estimator correctly collapses
 them instead of double-counting.
 
-Measurement points are the host's EXISTING synchronization seams; the
-only approximation is that a ready time is observed when the host
-reconciles (is_ready probe or executor sync), which can trail the true
-device completion by the drain lag. That skews busy UP and bubbles DOWN
-— conservative in the direction that matters (a reported bubble is
-always real).
+Measurement points are the host's EXISTING synchronization seams. A
+ready time is the moment the program's tokens reached the host: on a
+synchronous path ``t_ready``, stamped on the executor thread by
+``Scheduler._fetch`` at the end of ``sync.ready`` (before the other
+arrays are copied and before the hop back to the loop), on the chained
+path the ``is_ready`` probe. It trails the true device completion by
+the transfer (and the drain lag), and a dispatch time leads the
+device's start by the dispatch call. Both skew busy UP and bubbles
+DOWN — conservative in the direction that matters (a reported bubble
+is always real).
 """
 
 from __future__ import annotations
@@ -107,15 +111,17 @@ class DeviceTimeTracker:
         self.registry = registry or MetricsRegistry()
         self._time_hist = self.registry.histogram(
             "dynamo_engine_device_time_seconds",
-            "Per-dispatch device-busy duration at the host's "
-            "reconciliation seams, labelled program= and phase="
+            "Per-dispatch device-busy duration, dispatch to the result's "
+            "tokens on the host (t_ready), labelled program= and phase="
             "prefill|decode (the _sum series is cumulative device time)",
             buckets=DEVICE_TIME_BUCKETS,
         )
         self.registry.callback_gauge(
             "dynamo_engine_device_busy_ratio",
             "Device busy / (busy + bubble) per phase over the rolling "
-            "window — 1.0 means the device never waited for the host",
+            "window; busy ends when a result's tokens reach the host, so "
+            "the copies after them and the hop back to the loop are "
+            "bubble — 1.0 means the device never waited for the host",
             self._busy_ratios,
         )
         if self.peak_bytes_per_s:
@@ -164,8 +170,10 @@ class DeviceTimeTracker:
     def observe(self, program: str, phase: str, dispatch_t: float,
                 ready_t: float, read_bytes: float = 0.0,
                 tokens: int = 0) -> float:
-        """One program completion: dispatch and host-observed ready
-        times (monotonic). Returns the busy seconds attributed."""
+        """One program completion: dispatch time and the moment its
+        tokens reached the host (monotonic; ``Scheduler._fetch``'s
+        ``t_ready`` or an ``is_ready`` probe). Returns the busy seconds
+        attributed."""
         last = self._last_ready_t
         start = dispatch_t if last is None else max(dispatch_t, last)
         busy = max(0.0, ready_t - start)

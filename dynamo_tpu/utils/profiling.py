@@ -73,6 +73,32 @@ def enable_profiler_server(port: int) -> None:
     logger.info("jax profiler server on port %d (TensorBoard-capturable)", port)
 
 
+def start_capture(trace_dir: str) -> None:
+    """Start the profiler's trace into ``trace_dir`` and lay the
+    program's clock on the capture's: the first event written is the
+    span ``clock.mark`` with the stat ``monotonic_ns``, the value of
+    ``time.monotonic_ns()`` at the span's start. Every
+    ``time.monotonic()`` stamp of the program (the scheduler's fetch
+    parts, the request trace's marks, a load generator's ``t0``) is then
+    at ``mark.start + (stamp - monotonic_ns)`` on the capture's axis.
+    The caller holds the capture lock and stops the trace."""
+    import jax
+
+    from ..telemetry.tracing import span
+
+    # the Python tracer hooks every call of the scheduler's loop,
+    # the thing an operator wants to see undisturbed (11 MB a second
+    # of capture with it on; about 6 MB for 4 s without). The host
+    # tracer keeps the program's own spans (telemetry/tracing.span)
+    # and the runtime's, on the device planes' clock.
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    with span("clock.mark", monotonic_ns=time.monotonic_ns()):
+        pass
+
+
 def capture_trace(out_dir: str, seconds: float) -> str:
     """Record a profiler trace window; returns the trace directory.
 
@@ -91,15 +117,7 @@ def capture_trace(out_dir: str, seconds: float) -> str:
         # exist_ok=False on purpose: a collision must fail loudly instead
         # of silently merging two captures into one directory
         os.makedirs(trace_dir)
-        # the Python tracer hooks every call of the scheduler's loop,
-        # the thing an operator wants to see undisturbed (11 MB a second
-        # of capture with it on; about 6 MB for 4 s without). The host
-        # tracer keeps the program's own spans (telemetry/tracing.span)
-        # and the runtime's, on the device planes' clock.
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        start_capture(trace_dir)
         try:
             time.sleep(seconds)
         finally:

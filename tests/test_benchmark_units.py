@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 FILES = ("test_units.py", "test_trace_reduction.py", "test_host_spans.py",
          "test_scope_ops.py", "test_moonlight_units.py",
-         "test_falcon_h1_units.py", "test_xing4_units.py")
+         "test_falcon_h1_units.py", "test_xing4_units.py",
+         "test_sync_parts.py")
 
 
 def _adopt(filename: str) -> None:

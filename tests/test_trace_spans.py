@@ -28,17 +28,17 @@ SCHED = ("sched.admit", "sched.prefill.build", "sched.prefill.dispatch",
 CROSS_AWAIT = ("sched.prefill.sync", "sched.decode.sync", "sched.yield",
                "sched.wait")
 FRONTEND = ("http.ingress", "pre.tokenize", "detok.step", "http.sse_write")
+FETCH = ("sync.fetch", "sync.ready", "sync.copy")
 PREFIX = ("the quick brown fox jumps over the lazy dog and keeps running "
           "through the quiet forest until the river bends ")
 
 
 def _capture_start(trace_dir):
-    import jax
+    """As ``capture_trace`` and GET /debug/profile start one: the
+    benchmark's options, and ``clock.mark`` as the first event."""
+    from dynamo_tpu.utils import profiling
 
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    profiling.start_capture(trace_dir)
 
 
 def _capture_stop(trace_dir):
@@ -188,8 +188,8 @@ def _loop_tid(events):
     return next(e["tid"] for e in events if e["name"] == "sched.admit")
 
 
-@pytest.mark.parametrize("name", SCHED + FRONTEND + (
-    "sync.fetch", "dispatch.decode", "dispatch.prefill"))
+@pytest.mark.parametrize("name", SCHED + FRONTEND + FETCH + (
+    "dispatch.decode", "dispatch.prefill", "clock.mark"))
 def test_every_span_of_the_table_is_in_the_capture(served, name):
     assert any(e["name"] == name for e in served["events"]), name
 
@@ -197,9 +197,11 @@ def test_every_span_of_the_table_is_in_the_capture(served, name):
 def test_span_names_are_a_fixed_set(served):
     ours = {e["name"] for e in served["events"]
             if e["name"].startswith(("sched.", "sync.", "dispatch.",
-                                     "http.", "pre.", "detok."))}
-    allowed = set(SCHED + FRONTEND) | {"sync.fetch"}
+                                     "http.", "pre.", "detok.", "clock."))}
+    allowed = set(SCHED + FRONTEND + FETCH) | {"clock.mark"}
     assert all(n in allowed or n.startswith("dispatch.") for n in ours), ours
+    # ISSUE 35 added exactly these three to the set
+    assert {"sync.ready", "sync.copy", "clock.mark"} <= ours
     # what varies is a stat, never part of the name
     assert all(n == n.lower() and " " not in n and not any(
         c.isdigit() for c in n) for n in ours), ours
@@ -352,6 +354,118 @@ def test_sync_fetch_is_on_an_executor_thread_inside_the_sync_span(served):
                    for s in syncs), f
 
 
+def _assert_fetch_parts(events, what):
+    """Every ``sync.fetch`` holds ``sync.ready`` and then, where more
+    than the tokens were fetched, ``sync.copy``: on its own executor
+    thread, one after the other, inside the pass's ``sched.*.sync``; and
+    no part lies outside a fetch (ISSUE 35). Returns (fetches, copies)."""
+    tid = _loop_tid(events)
+    syncs = [e for e in events
+             if e["name"] in ("sched.decode.sync", "sched.prefill.sync")]
+    parts = [e for e in events if e["name"] in ("sync.ready", "sync.copy")]
+    fetches = _named(events, "sync.fetch")
+    assert fetches, what
+    copies = 0
+    for f in fetches:
+        assert f["tid"] != tid, (what, f)
+        inside = sorted((e for e in parts if e["tid"] == f["tid"]
+                         and f["start"] <= e["start"] and e["end"] <= f["end"]),
+                        key=lambda e: e["start"])
+        names = [e["name"] for e in inside]
+        assert names in (["sync.ready"], ["sync.ready", "sync.copy"]), (
+            what, names)
+        assert inside[0]["stats"]["bytes"] > 0, (what, inside[0])
+        if len(inside) == 2:
+            assert inside[0]["end"] <= inside[1]["start"], (what, inside)
+            assert inside[1]["stats"]["arrays"] >= 1, (what, inside[1])
+            assert inside[1]["stats"]["bytes"] > 0, (what, inside[1])
+            copies += 1
+        holds = [s for s in syncs
+                 if s["start"] <= f["start"] and f["end"] <= s["end"]]
+        assert len(holds) == 1 and holds[0]["tid"] == tid, (what, f, holds)
+    assert len(parts) == len(fetches) + copies, what
+    return len(fetches), copies
+
+
+def test_a_fetch_is_written_in_its_parts(served):
+    """The default path fetches the tokens, then the log-probabilities
+    and the two top-K arrays: three copies a fetch."""
+    fetches, copies = _assert_fetch_parts(served["events"], "served")
+    assert fetches > 20 and copies == fetches
+    assert {e["stats"]["arrays"] for e in served["events"]
+            if e["name"] == "sync.copy"} == {3}
+
+
+def _prom_sum(text, name):
+    """Every sample of ``name``, whatever its labels, summed."""
+    return sum(float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+               if line.startswith((name + " ", name + "{")))
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_fetch_counters_count_every_wait(served, kind):
+    label = 'kind="%s"' % kind
+    n = _delta(served, "dynamo_scheduler_fetches_total", "{%s}" % label)
+    spans = [e for e in served["events"]
+             if e["name"] == "sched.%s.sync" % kind]
+    assert n == len(spans) > 0
+    for part in ("ready_wait", "copy", "hop"):
+        v = _delta(served, "dynamo_scheduler_fetch_seconds_total",
+                   '{%s,part="%s"}' % (label, part))
+        assert v > 0, (kind, part)
+    # copy by the host's clock encloses the sync.copy spans
+    copy = _delta(served, "dynamo_scheduler_fetch_seconds_total",
+                  '{%s,part="copy"}' % label)
+    inside = sum(c["end"] - c["start"] for c in served["events"]
+                 if c["name"] == "sync.copy" and any(
+                     s["start"] <= c["start"] and c["end"] <= s["end"]
+                     for s in spans)) / 1e9
+    assert copy >= inside > 0
+
+
+def test_fetch_parts_sum_to_the_host_sync_phase(served):
+    """ready_wait + copy + hop is the host_sync phase: both are stamped
+    at the same two moments, to within 0.1 ms a pass."""
+    name = "dynamo_scheduler_fetch_seconds_total"
+    parts = (_prom_sum(served["metrics_after"], name)
+             - _prom_sum(served["metrics_before"], name))
+    phase = _delta(served, "dynamo_scheduler_phase_duration_seconds_sum",
+                   '{phase="host_sync"}')
+    n = (_prom_sum(served["metrics_after"], "dynamo_scheduler_fetches_total")
+         - _prom_sum(served["metrics_before"],
+                     "dynamo_scheduler_fetches_total"))
+    assert n > 20 and phase > 0
+    assert abs(parts - phase) <= 1e-4 * n, (parts, phase, n)
+
+
+def test_clock_mark_lays_the_programs_clock_on_the_captures(served):
+    """``clock.mark`` is the capture's first event of the program and
+    carries ``time.monotonic_ns()`` of its start: a monotonic stamp of
+    the program is at mark.start + (stamp - monotonic_ns)."""
+    ev = served["events"]
+    marks = _named(ev, "clock.mark")
+    assert len(marks) == 1
+    mark = marks[0]
+    ours = [e for e in ev if e["name"].startswith(
+        ("sched.", "sync.", "dispatch.", "http.", "pre.", "detok."))]
+    assert mark["start"] <= min(e["start"] for e in ours)
+
+    def on_capture(t_monotonic):
+        return mark["start"] + (t_monotonic * 1e9
+                                - mark["stats"]["monotonic_ns"])
+
+    t0, t1 = served["t"]              # taken right after the start, and
+    assert 0 <= on_capture(t0) - mark["end"] < 50e6     # before the stop
+    assert max(e["end"] for e in ours) <= on_capture(t1) + 1e6
+    # a request's own record, laid on the capture: its first mark falls
+    # inside that request's http.ingress span
+    rec = served["jsonl"]["second"]
+    at = on_capture(rec["t0_monotonic"])
+    ingress = _named(ev, "http.ingress")
+    assert any(i["start"] - 1e6 <= at <= i["end"] + 1e6 for i in ingress), (
+        at, [(i["start"], i["end"]) for i in ingress])
+
+
 def test_dispatch_span_nests_in_the_schedulers_and_holds_the_runtimes(served):
     ev = served["events"]
     disp = next(e for e in _named(ev, "dispatch.decode"))
@@ -492,6 +606,40 @@ def test_every_decode_path_writes_the_same_names(path_events):
         assert sched.pipeline_bursts > 0, path
     if path.startswith("spec"):
         assert sched.spec_proposed > 0, path
+
+
+def test_every_decode_path_writes_a_fetch_in_its_parts(path_events):
+    """All six sites go through the one ``_fetch``: whichever path, a
+    fetch is ``sync.ready`` then ``sync.copy`` on an executor thread
+    inside the pass's ``sched.*.sync``; a fetch of the tokens alone
+    (a synchronous verify step's greedy rows) has no copy."""
+    path, events, sched = path_events
+    fetches, copies = _assert_fetch_parts(events, path)
+    assert fetches > 3 and copies >= 1, path
+    if path == "spec_sync":
+        assert copies < fetches, path
+    else:
+        assert copies == fetches, path
+    # the capture covers the scheduler's whole life: each fetch counted
+    assert sum(sched._fetches_ctr.values.values()) == fetches, path
+
+
+def test_every_decode_path_splits_host_sync_into_its_parts(path_events):
+    """ready_wait + copy + hop over the run is the host_sync phase's
+    sum, to within 0.1 ms a pass, and each part is counted under the
+    kind of the pass that waited."""
+    path, _, sched = path_events
+    parts = sched._fetch_ctr.values
+    kinds = {dict(k)["kind"] for k in sched._fetches_ctr.values}
+    assert kinds == {"decode", "prefill"}, path
+    assert {(dict(k)["kind"], dict(k)["part"]) for k in parts} == {
+        (kind, part) for kind in kinds
+        for part in ("ready_wait", "copy", "hop")}, path
+    assert all(v >= 0 for v in parts.values()), path
+    n = sum(sched._fetches_ctr.values.values())
+    phase = sched._phase_hist.sums[(("phase", "host_sync"),)]
+    assert sched._phase_hist.totals[(("phase", "host_sync"),)] == n, path
+    assert abs(sum(parts.values()) - phase) <= 1e-4 * n, path
 
 
 def test_every_decode_path_keeps_sched_spans_apart(path_events):

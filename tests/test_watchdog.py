@@ -97,8 +97,26 @@ def test_stale_heartbeat_with_pending_work_trips_decode_stall_once():
 
 def test_frozen_steps_with_queued_work_trips_no_throughput():
     # fresh heartbeat (the loop spins) but the dispatch counter never
-    # moves while requests queue: starved admission
-    wd = _run_watchdog(lambda: _probe(steps=42, depth=2), cycles=16)
+    # moves while requests queue: starved admission. The sampler's check
+    # is stepped on a clock of the test's own, twenty samples 30 ms
+    # apart: on a loaded machine a real 30 ms sleep can outlast stall_s
+    # and add an event_loop_lag trip that is the machine's, not the
+    # probe's
+    clock = {"now": 1000.0}
+    wd = StallWatchdog(
+        lambda: _probe(heartbeat=clock["now"], steps=42, depth=2),
+        interval_s=0.03, stall_s=0.1, flight=FlightRecorder())
+
+    async def go():
+        for _ in range(20):
+            clock["now"] += 0.03
+            await wd._check(clock["now"])
+
+    loop = asyncio.new_event_loop()
+    try:
+        loop.run_until_complete(go())
+    finally:
+        loop.close()
     assert [t["reason"] for t in wd.trips] == ["no_throughput"]
 
 
