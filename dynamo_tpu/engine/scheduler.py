@@ -436,6 +436,21 @@ class _PendingPull:
     deadline: float                 # monotonic fallback deadline
 
 
+def _request_transfer(arrays) -> int:
+    """Ask the runtime for each array's copy to the host now
+    (``copy_to_host_async``), so that the transfer queues behind the
+    program that makes the array instead of starting when ``np.asarray``
+    first asks, after the program has ended. Returns how many arrays had
+    the method (a host stand-in has none and is read as it is)."""
+    n = 0
+    for x in arrays:
+        start = getattr(x, "copy_to_host_async", None)
+        if start is not None:
+            start()
+            n += 1
+    return n
+
+
 @dataclasses.dataclass
 class _InflightBurst:
     """One dispatched-but-unreconciled chained burst (pipeline depth 2).
@@ -464,6 +479,8 @@ class _InflightBurst:
     spec: bool = False
     nprop: object = None           # device [B] proposal counts
     nacc: object = None            # device [B] accepted-token counts
+    # output arrays whose copy to the host was requested at dispatch
+    prefetched: int = 0
 
 
 class Scheduler:
@@ -854,6 +871,13 @@ class Scheduler:
             "dynamo_scheduler_fetches_total",
             "Synchronous waits for a device result, kind=decode|prefill",
         )
+        self._prefetched_ctr = reg.counter(
+            "dynamo_scheduler_fetch_prefetched_total",
+            "Arrays of those waits whose copy to the host was requested "
+            "before the wait began (at the dispatch), kind=decode|prefill: "
+            "over arrays a fetch x dynamo_scheduler_fetches_total it is "
+            "1.0 where every result is a device array",
+        )
 
     def _observe_host_sync(self, dt: float) -> None:
         self._phase_hist.observe(dt, phase="host_sync")
@@ -889,20 +913,29 @@ class Scheduler:
                    and not self._is_sp(s) for s in self.slots)
 
     async def _fetch(self, loop, kind: str, arrays, turn: bool = True,
-                     tokens_at: int = 0, chaos: Optional[str] = None):
+                     tokens_at: int = 0, chaos: Optional[str] = None,
+                     prefetched: Optional[int] = None):
         """Wait for the result of the pass's latest dispatch and bring it
-        to the host: the frontend's turn first (``turn``: unless a later
-        dispatch of this pass will take it), then one ``np.asarray``
-        after another over the device ``arrays`` on an executor thread,
-        under ``sched.<kind>.sync``. Every synchronous result passes
-        here, so the wait is written once, in its parts:
+        to the host. The copy of every array is requested first
+        (``_request_transfer``): a synchronous caller comes here straight
+        from its dispatch, so the transfers queue behind the step on the
+        device's side, with the frontend's turn and the rest of the step
+        between the request and the wait; a chained burst made the
+        request at its own dispatch and passes the count
+        (``prefetched``). Then the frontend's turn (``turn``: unless a
+        later dispatch of this pass will take it), then one
+        ``np.asarray`` after another over the device ``arrays`` on an
+        executor thread, under ``sched.<kind>.sync``. Every synchronous
+        result passes here, so the wait is written once, in its parts:
 
         - ``sync.ready``: until the tokens (``arrays[tokens_at]``; a
           prompt-scoring prefill copies its accumulated rows first, as
-          it always did) are on the host. Its end is ``t_ready``, the
+          it always did) are on the host: the landing of a transfer that
+          was already queued, not its start. Its end is ``t_ready``, the
           program's "result ready" stamp;
-        - ``sync.copy``: the arrays after them, what one packed result
-          would save;
+        - ``sync.copy``: the arrays after them, which the early request
+          leaves on the host or close to it; what is left of it is what
+          one packed result would save;
         - the hop back from the executor thread to the loop, where this
           coroutine queues behind whatever frontend task is running. It
           crosses threads, so the capture has it as ``sched.*.sync`` end
@@ -912,9 +945,14 @@ class Scheduler:
         the host's clock (``ready_wait`` from the moment the wait began,
         so it holds what was left of the device's step); they sum to the
         host_sync phase, stamped at the same two moments: time blocked
-        on the device, with the frontend's work already done. ``chaos``
-        names a fault site (utils/faults.py) that wedges the executor
-        thread first. Returns (host arrays, ``t_ready``)."""
+        on the device, with the frontend's work already done.
+        ``dynamo_scheduler_fetch_prefetched_total`` and the stat
+        ``prefetched`` of ``sync.fetch`` count the arrays whose copy was
+        requested before the wait began. ``chaos`` names a fault site
+        (utils/faults.py) that wedges the executor thread first.
+        Returns (host arrays, ``t_ready``)."""
+        if prefetched is None:
+            prefetched = _request_transfer(arrays)
         if turn:
             await self._frontend_turn()
         head, rest = arrays[:tokens_at + 1], arrays[tokens_at + 1:]
@@ -925,7 +963,7 @@ class Scheduler:
         def _to_host():
             if chaos is not None:
                 faults.maybe_hang(chaos)
-            with span("sync.fetch"):
+            with span("sync.fetch", prefetched=prefetched):
                 with span("sync.ready", bytes=_nbytes(head)):
                     out = [np.asarray(x) for x in head]
                 t_ready = time.monotonic()
@@ -944,6 +982,7 @@ class Scheduler:
         self._inflight = False
         self._observe_host_sync(t_resumed - t_sync)
         self._fetches_ctr.inc(kind=kind)
+        self._prefetched_ctr.inc(prefetched, kind=kind)
         self._fetch_ctr.inc(t_ready - t_sync, part="ready_wait", kind=kind)
         self._fetch_ctr.inc(t_copied - t_ready, part="copy", kind=kind)
         self._fetch_ctr.inc(t_resumed - t_copied, part="hop", kind=kind)
@@ -1678,13 +1717,16 @@ class Scheduler:
         # chaos site: DYN_FAULT=decode_burst_hang wedges the executor
         # thread — the exact executor-side shape of a hung Mosaic compile
         # or a dead device mid-sync (utils/faults.py). The chain's
-        # bursts are in flight already: the turn is not this fetch's.
+        # bursts are in flight already, and so are their transfers
+        # (requested at the burst's dispatch): neither the turn nor the
+        # request is this fetch's.
         # Spec rounds carry no logprob outputs (spec-eligible rows want
         # none) but do carry acceptance accounting.
         arrays = ([infl.toks, infl.nprop, infl.nacc] if infl.spec
                   else [infl.toks, infl.lps, infl.tv, infl.ti])
         got, t_ready = await self._fetch(
-            loop, "decode", arrays, turn=False, chaos="decode_burst_hang")
+            loop, "decode", arrays, turn=False, chaos="decode_burst_hang",
+            prefetched=infl.prefetched)
         lpn = tv = ti = nprop = nacc = None
         if infl.spec:
             toks, nprop, nacc = got
@@ -2203,6 +2245,7 @@ class Scheduler:
                             cfg.max_model_len) for er in live),
                 ) if dt is not None else 0.0,
                 tokens=k_steps * len(live),
+                prefetched=_request_transfer((toks, lps, tv, ti)),
             ))
         await self._chain_drain(loop, members)
 
@@ -2298,6 +2341,7 @@ class Scheduler:
                             cfg.max_model_len) for er in live),
                 ) if dt is not None else 0.0,
                 tokens=len(live),
+                prefetched=_request_transfer((toks, nprop, nacc)),
             ))
         await self._chain_drain(loop, members)
 

@@ -70,6 +70,117 @@ def _named(events, prefix):
 
 
 # ---------------------------------------------------------------------
+# the early request (ISSUE 36): a recording stand-in for every array a
+# runner hands back, and one log of what the scheduler did with it
+# ---------------------------------------------------------------------
+
+class Recorded:
+    """A device array's stand-in (as tests/test_kv_reuse.py's SlowD2H):
+    it writes into the shared ``log`` when its copy to the host was
+    requested and when it was read, and passes both on to the array it
+    wraps, a jax one or the fake runner's numpy."""
+
+    def __init__(self, arr, log):
+        self.arr, self.log, self.nbytes = arr, log, arr.nbytes
+
+    def copy_to_host_async(self):
+        self.log.append(("request", id(self)))
+        start = getattr(self.arr, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+    def is_ready(self):
+        return getattr(self.arr, "is_ready", lambda: True)()
+
+    def __array__(self, dtype=None, copy=None):
+        import numpy as np
+
+        self.log.append(("read", id(self)))
+        return np.asarray(self.arr)
+
+
+# the outputs a scheduler may fetch, by the runner's method (``step``'s
+# fifth are the prompt's rows, which the scheduler slices on the device)
+OUTPUTS = {"step": (0, 1, 2, 3, 5), "decode_burst": (0, 1, 2, 3),
+           "decode_burst_chained": (0, 1, 2, 3),
+           "decode_burst_spec": (0, 1, 2)}
+
+
+def _record(sched):
+    """Wrap the outputs of ``sched.runner`` in ``Recorded`` and write
+    the scheduler's own moments beside theirs: ("dispatch", method,
+    ids), ("turn",) where ``sched.yield`` is about to open, ("fetch",
+    kind, ids) where ``_fetch`` is entered. Returns the log; the
+    wrapped objects are kept alive in it so that no id is used twice."""
+    log = []
+
+    def wrap_method(name, which):
+        real = getattr(sched.runner, name)
+
+        def method(*a, **kw):
+            out = list(real(*a, **kw))
+            for i in which:
+                out[i] = Recorded(out[i], log)
+            log.append(("dispatch", name, [out[i] for i in which]))
+            return tuple(out)
+
+        setattr(sched.runner, name, method)
+
+    for name, which in OUTPUTS.items():
+        if hasattr(sched.runner, name):
+            wrap_method(name, which)
+    turn, fetch = sched._frontend_turn, sched._fetch
+
+    async def _frontend_turn():
+        if not sched._turn_taken:
+            log.append(("turn",))
+        await turn()
+
+    async def _fetch(loop, kind, arrays, *a, **kw):
+        log.append(("fetch", kind, [id(x) for x in arrays]))
+        return await fetch(loop, kind, arrays, *a, **kw)
+
+    sched._frontend_turn, sched._fetch = _frontend_turn, _fetch
+    return log
+
+
+def _assert_requested_early(log, what):
+    """Every array a fetch read had its copy requested exactly once,
+    before the first read; a chained burst's at its own dispatch, with
+    nothing of the scheduler's in between, every other one's where
+    ``_fetch`` is entered, before the frontend's turn. Returns (fetches,
+    arrays fetched, arrays requested at a chained dispatch, fetches
+    whose requests the turn followed at once)."""
+    made_by, at = {}, {}
+    for i, entry in enumerate(log):
+        if entry[0] == "dispatch":
+            for x in entry[2]:
+                made_by[id(x)] = (entry[1], i, len(entry[2]))
+        elif entry[0] in ("request", "read"):
+            at.setdefault((entry[0], entry[1]), []).append(i)
+    fetches = [(i, e) for i, e in enumerate(log) if e[0] == "fetch"]
+    arrays = chained = turned = 0
+    for i, (_, kind, ids) in fetches:
+        ids = [x for x in ids if x in made_by]   # not the sliced rows
+        assert ids, (what, kind)
+        turned += log[i + len(ids) + 1][0] == "turn"
+        for x in ids:
+            arrays += 1
+            requests, reads = at.get(("request", x)), at.get(("read", x))
+            assert requests and len(requests) == 1, (what, kind, requests)
+            assert reads and requests[0] < reads[0], (what, kind)
+            method, made, n = made_by[x]
+            if method in ("decode_burst_chained", "decode_burst_spec"):
+                chained += 1
+                assert made < requests[0] <= made + n < i, (what, method)
+            else:
+                # right behind the fetch's entry: the turn, where this
+                # fetch takes it, opens after every request
+                assert i < requests[0] <= i + len(ids), (what, method)
+    return len(fetches), arrays, chained, turned
+
+
+# ---------------------------------------------------------------------
 # scenario A: in=http out=jax at tiny widths, the default (synchronous)
 # decode path, two requests that share a prefix
 # ---------------------------------------------------------------------
@@ -105,7 +216,8 @@ async def _served_scenario(tmp):
     engine, mdc = await build_engine("jax", flags)
     task = asyncio.ensure_future(run_http(flags, engine, mdc))
     base = f"http://127.0.0.1:{port}"
-    out = {"runner": engine.core_engine.runner}
+    out = {"runner": engine.core_engine.runner,
+           "log": _record(engine.core_engine.scheduler)}
 
     async def complete(session, rid, prompt, n):
         chunks = 0
@@ -134,6 +246,7 @@ async def _served_scenario(tmp):
                     await asyncio.sleep(0.05)
             await complete(session, "warm", "hello there", 4)
             out["metrics_before"] = await metrics(session)
+            del out["log"][:]
             trace_dir = os.path.join(str(tmp), "profile")
             _capture_start(trace_dir)
             t0 = time.monotonic()
@@ -396,6 +509,28 @@ def test_a_fetch_is_written_in_its_parts(served):
             if e["name"] == "sync.copy"} == {3}
 
 
+def test_every_result_is_requested_at_its_dispatch(served):
+    """ISSUE 36: the four arrays of a step are asked for where ``_fetch``
+    is entered, straight after the dispatch and before ``sched.yield``
+    opens; the executor thread's ``np.asarray`` finds the request made."""
+    fetches, arrays, chained, turned = _assert_requested_early(
+        served["log"], "served")
+    assert fetches > 20 and arrays == 4 * fetches and chained == 0
+    assert turned > 15
+    stats = [e["stats"]["prefetched"]
+             for e in _named(served["events"], "sync.fetch")]
+    assert len(stats) == fetches and set(stats) == {4}
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_prefetched_counter_is_arrays_times_fetches(served, kind):
+    label = '{kind="%s"}' % kind
+    n = _delta(served, "dynamo_scheduler_fetches_total", label)
+    assert n > 0
+    assert _delta(served, "dynamo_scheduler_fetch_prefetched_total",
+                  label) == 4 * n
+
+
 def _prom_sum(text, name):
     """Every sample of ``name``, whatever its labels, summed."""
     return sum(float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
@@ -570,11 +705,13 @@ PATHS = {
 }
 
 
-@pytest.fixture(scope="module", params=sorted(PATHS))
-def path_events(request, tmp_path_factory):
+def _path_run(path, record):
+    """One run of the scheduler over the fake runner on ``path``: the
+    clients' streams, the scheduler, and the log of ``_record`` or, with
+    ``record`` false, the fake's plain numpy results and no log."""
     import test_decode_pipeline as dp
 
-    kw = dict(PATHS[request.param])
+    kw = dict(PATHS[path])
     if kw.pop("spec", False):
         # an 8-token vocabulary and a repetitive prompt, so that the
         # ngram proposer has matches and the verify path runs
@@ -584,13 +721,30 @@ def path_events(request, tmp_path_factory):
         config = dp._config(kw.pop("depth"), k=kw.pop("k", 1), **kw)
         reqs = [dp._request(p, 21) for p in dp.PROMPTS]
     box = {}
+
+    def hooks(sched):
+        box.update(sched=sched, log=_record(sched) if record else None)
+
+    box["streams"] = dp._run(config, reqs, hooks=hooks)
+    return box
+
+
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def path_run(request, tmp_path_factory):
     trace_dir = str(tmp_path_factory.mktemp("path-" + request.param))
     _capture_start(trace_dir)
+    box = {}
     try:
-        dp._run(config, reqs, hooks=lambda s: box.update(sched=s))
+        box = _path_run(request.param, record=True)
     finally:
-        events = _capture_stop(trace_dir)
-    return request.param, events, box["sched"]
+        box["events"] = _capture_stop(trace_dir)
+    box["path"] = request.param
+    return box
+
+
+@pytest.fixture(scope="module")
+def path_events(path_run):
+    return path_run["path"], path_run["events"], path_run["sched"]
 
 
 def test_every_decode_path_writes_the_same_names(path_events):
@@ -661,6 +815,43 @@ def test_every_decode_path_takes_one_turn_a_pass(path_events):
     hidden = _assert_one_turn_a_pass(events, sync_path, path)
     if sync_path:
         assert hidden > 3, path
+
+
+def _fetched(sched):
+    """(fetches, arrays prefetched) over the scheduler's life."""
+    return (sum(sched._fetches_ctr.values.values()),
+            sum(sched._prefetched_ctr.values.values()))
+
+
+def test_every_decode_path_requests_each_result_once_and_early(path_run):
+    """ISSUE 36, over all six fetch sites: exactly one request an array,
+    before the first read; a synchronous path's where ``_fetch`` is
+    entered, before the turn; a chained burst's at the burst's dispatch,
+    one or more bursts before its fetch."""
+    path, sched = path_run["path"], path_run["sched"]
+    fetches, arrays, chained, turned = _assert_requested_early(
+        path_run["log"], path)
+    assert (fetches, arrays) == _fetched(sched), path
+    if path in ("chained", "chained_k4", "spec_chained"):
+        assert chained > 0, path
+    else:
+        assert chained == 0 and turned > 3, path
+    stats = [e["stats"]["prefetched"]
+             for e in _named(path_run["events"], "sync.fetch")]
+    assert len(stats) == fetches and sum(stats) == arrays, path
+
+
+def test_every_decode_path_takes_a_plain_numpy_result(path_run):
+    """A stand-in without ``copy_to_host_async`` (the fake runner's
+    numpy, a test's array) passes through unasked and counts 0; the
+    clients' tokens are the recorded run's."""
+    plain = _path_run(path_run["path"], record=False)
+    assert plain["streams"] == path_run["streams"]
+    fetches, prefetched = _fetched(plain["sched"])
+    assert fetches == _fetched(path_run["sched"])[0] > 3
+    assert prefetched == 0
+    assert set(plain["sched"]._prefetched_ctr.values) == set(
+        plain["sched"]._fetches_ctr.values)
 
 
 # ---------------------------------------------------------------------
