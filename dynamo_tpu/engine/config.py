@@ -112,6 +112,30 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # MiniCPM-SALA (models/minicpm_sala.py, model_type "minicpm_sala"):
+    # mixer_types names each layer's token mixer, "lightning-attn" (gated
+    # linear attention, state by slot, no pages) or "minicpm4" (InfLLM-V2
+    # block-sparse attention, pages and compressed keys, no state). Empty
+    # for every other family. depth_of / first_layer say which layers of
+    # the published trunk these are when it is served cut in depth (the
+    # residual's scale and the decays are functions of the published
+    # depth and index); the sparse_* fields are the published
+    # sparse_config, in tokens.
+    mixer_types: Tuple[str, ...] = ()
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0
+    depth_of: int = 0
+    first_layer: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -179,19 +203,24 @@ class ModelConfig:
                 "supported; Qwen3-MoE and Mixtral load"
             )
         falcon_h1 = config.get("model_type") == "falcon_h1"
+        sala = config.get("model_type") == "minicpm_sala"
         recurrent_keys = sorted(
             k for k in config
-            if k.startswith(("mamba_", "ssm_")) or k in RECURRENT_CONFIG_KEYS)
+            if k in RECURRENT_CONFIG_KEYS
+            or (not sala and (k.startswith(("mamba_", "ssm_", "lightning_"))
+                              or k == "mixer_types")))
         if recurrent_keys and not falcon_h1:
             # a trunk with recurrent layers this program has no family
             # for would fall through to llama and serve nonsense
             raise NotImplementedError(
                 f"model_type {config.get('model_type')!r} carries recurrent-"
                 f"layer keys ({', '.join(recurrent_keys[:4])}, ...) and no "
-                "family here implements it (falcon_h1 is the one state-space "
-                "family: models/falcon_h1.py)"
+                "family here implements it (falcon_h1 is the state-space "
+                "family, models/falcon_h1.py; minicpm_sala the linear-"
+                "attention one, models/minicpm_sala.py)"
             )
-        mamba = _falcon_h1_fields(config) if falcon_h1 else {}
+        mamba = (_falcon_h1_fields(config) if falcon_h1
+                 else _minicpm_sala_fields(config) if sala else {})
         xing4 = config.get("model_type") == "xing4_0"
         hc_keys = sorted(k for k in config if k.startswith(HC_KEY_PREFIXES))
         if hc_keys and not xing4:
@@ -271,6 +300,7 @@ class ModelConfig:
                 "gemma2" if "gemma2" in arch
                 else "gptoss" if "gptoss" in arch
                 else "falcon_h1" if falcon_h1
+                else "minicpm_sala" if sala
                 else ""
             ),
             attn_logit_softcap=config.get("attn_logit_softcapping") or 0.0,
@@ -334,6 +364,57 @@ def _xing4_fields(config: dict) -> dict:
         hc_res_clamp=(float(config.get("mhc_h_res_clamp_min", -30.0)),
                       float(config.get("mhc_h_res_clamp_max", 30.0))),
     )
+
+
+def _minicpm_sala_fields(config: dict) -> dict:
+    """ModelConfig's MiniCPM-SALA fields from the published keys; what
+    the family module does not compute is refused here, before any weight
+    is made. ``sparse_config`` (the MiniCPM4 family's published group)
+    and ``depth_cut`` (``{"of_layers", "first_layer"}``: which layers of
+    the published trunk a cut configuration holds) are optional groups."""
+    only = {
+        "attn_use_rope": False, "lightning_use_rope": True, "qk_norm": True,
+        "use_output_gate": True, "use_output_norm": True,
+        "attn_use_output_gate": True, "attention_bias": False,
+        "rope_scaling": None, "hidden_act": "silu",
+        "lightning_scale": "1/sqrt(d)",
+    }
+    for key, value in only.items():
+        if config.get(key, value) != value:
+            raise NotImplementedError(
+                f"minicpm_sala with {key}={config[key]!r} "
+                f"(models/minicpm_sala.py computes {key}={value!r} only)")
+    mixers = tuple(config.get("mixer_types") or ())
+    layers = int(config["num_hidden_layers"])
+    unknown = sorted(set(mixers) - {"lightning-attn", "minicpm4"})
+    if len(mixers) != layers or unknown:
+        raise ValueError(
+            f"minicpm_sala: mixer_types has {len(mixers)} entries for "
+            f"{layers} layers, unknown kinds {unknown} (lightning-attn | "
+            "minicpm4)")
+    heads = int(config.get("lightning_nh", config["num_attention_heads"]))
+    if int(config.get("lightning_nkv", heads)) != heads:
+        raise NotImplementedError(
+            "minicpm_sala with lightning_nkv != lightning_nh: the state is "
+            "kept a head (models/minicpm_sala.py)")
+    cut = config.get("depth_cut") or {}
+    sparse = config.get("sparse_config") or {}
+    fields = dict(
+        mixer_types=mixers, lightning_heads=heads,
+        lightning_head_dim=int(config.get("lightning_head_dim",
+                                          config.get("head_dim", 128))),
+        scale_emb=float(config.get("scale_emb", 1.0)),
+        scale_depth=float(config.get("scale_depth", 1.0)),
+        dim_model_base=int(config.get("dim_model_base",
+                                      config["hidden_size"])),
+        depth_of=int(cut.get("of_layers", layers)),
+        first_layer=int(cut.get("first_layer", 0)),
+    )
+    for key in ("kernel_size", "kernel_stride", "block_size", "topk",
+                "init_blocks", "window_size", "dense_len"):
+        if key in sparse:
+            fields[f"sparse_{key}"] = int(sparse[key])
+    return fields
 
 
 def _falcon_h1_fields(config: dict) -> dict:

@@ -443,6 +443,7 @@ class ModelRunner:
         )
 
         self._init_moe_counters()
+        self._init_family_counters()
         self._build_step()
         self._build_burst()
         self._build_spec_burst()
@@ -523,6 +524,40 @@ class ModelRunner:
             "dynamo_moe_routed_rows_total",
             "(token, chosen expert) rows of real tokens, summed over MoE "
             "layers and steps, by phase")
+
+    def _init_family_counters(self) -> None:
+        """Counters a family keeps in its cache pytree (``STEP_COUNTERS``:
+        (name, help) of each, ``step_counts(kv_cache)`` the int32
+        accumulators; models/minicpm_sala.py counts what its sparse
+        layers kept). The trunk adds to them inside the step, so nothing
+        is fetched or dispatched for them; /metrics reads them when it
+        is rendered, as the routed experts' counters are read. int32
+        wraps; the reader takes differences modulo 2**32."""
+        named = getattr(self.arch, "STEP_COUNTERS", None)
+        if not named:
+            return
+        last = np.zeros(len(named), np.int64)
+        lock = threading.Lock()
+
+        def refresh():
+            with lock:
+                try:
+                    now = np.asarray(
+                        self.arch.step_counts(self.kv_cache)).astype(np.int64)
+                except RuntimeError:
+                    # the cache is donated to the step being dispatched:
+                    # the next rendering reads what that step leaves
+                    return
+                delta = (now - last) & 0xFFFFFFFF
+                last[...] = now
+                for counter, d in zip(counters, delta):
+                    counter.inc(float(d))
+
+        reg = self.compiles.registry
+        (name, help_), rest = named[0], named[1:]
+        first = _DeviceFedCounter(name, help_, refresh)
+        reg.register(first)
+        counters = [first] + [reg.counter(n, h) for n, h in rest]
 
     # ---------- the unified step program ----------
 

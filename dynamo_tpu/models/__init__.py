@@ -36,6 +36,19 @@ def resolve(cfg: ModelConfig):
         from . import falcon_h1
 
         return falcon_h1
+    if cfg.model_family == "minicpm_sala":
+        from . import minicpm_sala
+
+        return minicpm_sala
+    if cfg.mixer_types:
+        # layers of two kinds with no family to tell them apart: llama
+        # would run dense rotary attention in every one
+        raise NotImplementedError(
+            f"mixer_types ({len(cfg.mixer_types)} entries) needs a family "
+            f"that keeps a cache a kind of layer; model_family "
+            f"{cfg.model_family!r} has none (models/minicpm_sala.py is "
+            "selected by model_type minicpm_sala)"
+        )
     if cfg.mamba_d_ssm > 0:
         # recurrent state with no family to keep it: llama would serve
         # the attention half alone

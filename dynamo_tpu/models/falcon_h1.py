@@ -256,6 +256,46 @@ def _gated_norm(y, z, weight, groups: int, eps: float):
     return _grouped_rms_norm(y * jax.nn.silu(z), weight, groups, eps)
 
 
+def slot_records(b: int, decode: bool, live, state_slots, fresh):
+    """``(read, write)`` over records stacked ``[L, slots, ...]``:
+    ``read(records, li)`` is layer ``li``'s records of the step's rows
+    (zeros for a prefill row that is ``fresh``: its first position is 0),
+    ``write(records, li, rows)`` puts the rows' new records back. A
+    decode step's row *i* is slot *i*; a prefill row names its slot
+    (``state_slots``), and one that is no sequence (not ``live``) puts
+    back what is there (its slot number may be a live row's)."""
+
+    # A prefill step has few rows (the row ladder stops at 8), so each
+    # row's record is sliced out and put back on its own: a gather or a
+    # scatter over the records makes XLA copy a layer's worth, or all.
+    def record(records, li, slot):
+        start = (li, slot) + (0,) * (records.ndim - 2)
+        return jax.lax.dynamic_slice(
+            records, start, (1, 1) + records.shape[2:])
+
+    def read(records, li):
+        if decode:      # row i is slot i
+            return jax.lax.dynamic_index_in_dim(
+                records, li, 0, keepdims=False)[:b]
+        rows = jnp.concatenate(
+            [record(records, li, state_slots[i])[0] for i in range(b)])
+        zero = fresh.reshape((b,) + (1,) * (rows.ndim - 1))
+        return jnp.where(zero, jnp.zeros_like(rows), rows)
+
+    def write(records, li, rows):
+        rows = rows.astype(records.dtype)
+        if decode:
+            return records.at[li, :b].set(rows)
+        for i in range(b):
+            new = jnp.where(live[i], rows[i][None, None],
+                            record(records, li, state_slots[i]))
+            records = jax.lax.dynamic_update_slice(
+                records, new, (li, state_slots[i]) + (0,) * (rows.ndim - 1))
+        return records
+
+    return read, write
+
+
 def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
                 state_slots):
     """The mixer of one layer: ``ssm_fn(n1, layer_params, ssm_all,
@@ -270,41 +310,10 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
     n_valid = valid.sum(axis=1).astype(jnp.int32)   # [B]
     decode = s == 1
     live = valid[:, 0]
-    if decode:      # the same for every layer: made once, outside the scan
-        row_list = live_row_list(live)
-    else:
-        fresh = positions[:, 0] == 0
-
-    # A prefill step has few rows (the row ladder stops at 8), so each
-    # row's record is sliced out and put back on its own: a gather or a
-    # scatter over the records makes XLA copy a layer's worth, or all.
-    def record(records, li, slot):
-        start = (li, slot) + (0,) * (records.ndim - 2)
-        return jax.lax.dynamic_slice(
-            records, start, (1, 1) + records.shape[2:])
-
-    def read(records, li):
-        """This layer's records of the rows' slots."""
-        if decode:      # row i is slot i
-            return jax.lax.dynamic_index_in_dim(
-                records, li, 0, keepdims=False)[:b]
-        rows = jnp.concatenate(
-            [record(records, li, state_slots[i])[0] for i in range(b)])
-        zero = fresh.reshape((b,) + (1,) * (rows.ndim - 1))
-        return jnp.where(zero, jnp.zeros_like(rows), rows)
-
-    def write(records, li, rows):
-        rows = rows.astype(records.dtype)
-        if decode:
-            return records.at[li, :b].set(rows)
-        for i in range(b):
-            # a row that is no sequence puts back what is there (its
-            # slot number may be a live row's)
-            new = jnp.where(live[i], rows[i][None, None],
-                            record(records, li, state_slots[i]))
-            records = jax.lax.dynamic_update_slice(
-                records, new, (li, state_slots[i]) + (0,) * (rows.ndim - 1))
-        return records
+    # the same for every layer: made once, outside the scan
+    row_list = live_row_list(live) if decode else None
+    read, write = slot_records(b, decode, live, state_slots,
+                               None if decode else positions[:, 0] == 0)
 
     def ssm_fn(x, lp, ssm_all, conv_all, li):
         u = _scaled(dense(_scaled(x, cfg.ssm_in_multiplier), lp["ssm_in"]), mup)

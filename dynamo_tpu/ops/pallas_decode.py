@@ -638,7 +638,8 @@ def paged_verify_attention(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "pages_per_chunk", "interpret", "softcap"),
+    static_argnames=("scale", "pages_per_chunk", "interpret", "softcap",
+                     "one_head"),
 )
 def paged_decode_attention(
     q: jax.Array,            # [B, 1, H, D] (post-RoPE)
@@ -653,6 +654,7 @@ def paged_decode_attention(
     softcap: float = 0.0,    # Gemma-2: logits ← cap·tanh(logits/cap)
     window=None,             # sliding window (int or traced scalar); None = off
     sinks=None,              # [H] per-head sink logits (GPT-OSS); None = off
+    one_head: bool = False,  # the caches are [L, N, page, D]: a kv head a page
 ) -> jax.Array:
     """Single-token paged attention; returns [B, 1, H, D].
 
@@ -661,9 +663,17 @@ def paged_decode_attention(
     kernel starts its page walk at the window's first live chunk."""
     b, s, h, d = q.shape
     assert s == 1, "decode kernel is specialized to one query token"
-    if k_cache.ndim == 4:
-        k_cache, v_cache = k_cache[None], v_cache[None]
-    _, _, block_size, kvh, _ = k_cache.shape
+    if one_head:
+        # [L, N, page, D]: a page holds one kv head and has no head axis
+        # (a unit axis there is a slice Mosaic's tiling refuses); every
+        # query head of a row attends to it
+        _, _, block_size, _ = k_cache.shape
+        kvh, page_shape = 1, (block_size, d)
+    else:
+        if k_cache.ndim == 4:
+            k_cache, v_cache = k_cache[None], v_cache[None]
+        _, _, block_size, kvh, _ = k_cache.shape
+        page_shape = (block_size, kvh, d)
     g = h // kvh
     if scale is None:
         scale = d ** -0.5
@@ -700,12 +710,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kvh, g, d), lambda i, *_: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, kvh, d), k_cache.dtype
-            ),
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, kvh, d), v_cache.dtype
-            ),
+            pltpu.VMEM((2, pages_per_chunk) + page_shape, k_cache.dtype),
+            pltpu.VMEM((2, pages_per_chunk) + page_shape, v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )

@@ -12,6 +12,13 @@ step ``Δ_t ≥ 0``::
 state as it was and adds nothing: that is how the caller marks pad
 positions and idle rows.
 
+Lightning linear attention (models/minicpm_sala.py) is the same
+recurrence with ``Δ = 1`` at a token, a decay that is a constant of the
+head (``A = ln λ_h``), a group a head (``G = H``: the key plays ``B``,
+the scaled query ``C``, the value ``x``) and ``D = 0``:
+``S_t = λ_h S_{t−1} + v_t ⊗ k_t``, ``o_t = S_t q_t``. Both families run
+the three functions below; nothing here knows which one calls.
+
 - ``ssm_decode_update``: one token, the equation as written, in plain
   ``jnp``. No served program calls it (on the chip XLA makes two fusions
   a layer of it, three passes over every slot's state: PERF.md section
@@ -38,6 +45,7 @@ accumulate in float32.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -83,13 +91,16 @@ def ssm_decode_update(
 _STATE_BLOCK_BYTES = 2 << 20
 
 
-def _head_block(heads_per_group: int, head_bytes: int) -> int:
-    """Heads a block: the largest divisor of a group's heads (so that a
-    block reads one row of B and one of C) whose state fits
-    ``_STATE_BLOCK_BYTES``."""
+def _head_block(heads: int, heads_per_group: int, head_bytes: int) -> int:
+    """Heads a block: the most whose state fits ``_STATE_BLOCK_BYTES``
+    among the divisors of a group's heads (a block then reads one row of
+    B and one of C) and, where a whole group fits with room to spare (a
+    group a head: 64 KiB of lightning attention's state), the whole
+    groups that divide the heads (a block then reads a row a group)."""
     fit = max(1, _STATE_BLOCK_BYTES // head_bytes)
-    return max(k for k in range(1, min(heads_per_group, fit) + 1)
-               if heads_per_group % k == 0)
+    return max(k for k in range(1, min(heads, fit) + 1)
+               if heads_per_group % k == 0
+               or (k % heads_per_group == 0 and heads % k == 0))
 
 
 def live_row_list(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -105,13 +116,15 @@ def live_row_list(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def _decode_kernel(layer_ref, rows_ref, xdt_ref, decay_ref, bc_ref, h_ref,
-                   y_ref, o_ref):
+                   y_ref, o_ref, *, heads_per_group: int):
     """One block of heads of one live row: xdt [P, hb] (Δ·x, heads on
     lanes so that a head's column spreads over the state's lanes), decay
-    [1, hb], bc [2, N] (the group's B and C), h / o [hb, P, N], y [P, hb]."""
+    [1, hb], bc [gb, 2, N] (B and C of the block's groups: one, or
+    ``hb / heads_per_group`` whole ones), h / o [hb, P, N], y [P, hb]."""
     del layer_ref, rows_ref
-    b_row, c_row = bc_ref[0:1, :], bc_ref[1:2, :]
     for j in range(h_ref.shape[0]):
+        jg = j // heads_per_group
+        b_row, c_row = bc_ref[jg, 0:1, :], bc_ref[jg, 1:2, :]
         h = (h_ref[j].astype(jnp.float32) * decay_ref[:, j:j + 1]
              + xdt_ref[:, j:j + 1] * b_row)
         o_ref[j] = h.astype(o_ref.dtype)
@@ -149,8 +162,8 @@ def ssm_decode_step(
     g, n_state = bm.shape[-2:]
     per_group = heads // g
     f32 = jnp.float32
-    hb = _head_block(per_group, p * n_state * records.dtype.itemsize)
-    nb = heads // hb
+    hb = _head_block(heads, per_group, p * n_state * records.dtype.itemsize)
+    nb, gb = heads // hb, max(1, hb // per_group)   # blocks; groups a block
     rows, n = row_list
     x = x.astype(f32)
     xdt = (dt[:, :, None] * x).reshape(b, nb, hb, p).transpose(0, 1, 3, 2)
@@ -161,20 +174,20 @@ def ssm_decode_step(
         return rows_ref[i], j, 0, 0
 
     def by_group(i, j, layer_ref, rows_ref):
-        return rows_ref[i], j * hb // per_group, 0, 0
+        return rows_ref[i], j * hb // per_group // gb, 0, 0
 
     def state(i, j, layer_ref, rows_ref):
         return layer_ref[0], rows_ref[i], j, 0, 0
 
     y, records = pl.pallas_call(
-        _decode_kernel,
+        functools.partial(_decode_kernel, heads_per_group=per_group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n, nb),
             in_specs=[
                 pl.BlockSpec((None, None, p, hb), by_row),
                 pl.BlockSpec((None, None, 1, hb), by_row),
-                pl.BlockSpec((None, None, 2, n_state), by_group),
+                pl.BlockSpec((None, gb, 2, n_state), by_group),
                 pl.BlockSpec((None, None, hb, p, n_state), state),
             ],
             out_specs=[

@@ -1,0 +1,162 @@
+#!/usr/bin/env python3
+"""Correctness probes longer than the benchmark's own, on the chip.
+
+    python3 scripts/long_probes.py --workload <cell> --lengths 9400,16000 --seed <n>
+
+``benchmark/harness/drive.py`` sends three short probes and one of 2200
+tokens. A configuration whose mechanism starts past that (MiniCPM-SALA's
+block selection, past ``dense_len`` 8192) is held to its reference there
+by this script: the cell's configuration served as ``benchmark/run.py``
+serves it (the same flags, weights from ``--seed``), prompts of the
+given lengths sent one at a time through ``/v1/completions`` (greedy, 16
+tokens, with log-probabilities), and ``harness/reference.check_probes``
+with the limits of the configuration's reference module. One JSON line;
+exit 0 when every probe is inside the limits.
+
+``--engine-args '{"kv_cache_dtype": "fp8"}'`` lays engine settings over
+the configuration's (the reading of a precision below the stated one,
+which has to come out as not correct: PERF.md section 6). ``--fault``
+serves a deliberately wrong program against the unchanged reference,
+for the readings that say what the limits can tell apart: ``bf16_state``
+(MiniCPM-SALA's lightning state held in bfloat16), ``half_topk`` (half the picked
+blocks dropped: ``sparse_config.topk`` halved in the served model's
+``config.json`` only). Where the reference module has ``limits_for``, a
+probe is held to the pair it gives for the probe's context; otherwise to
+the module's one pair.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import json
+import os
+import shutil
+import sys
+import time
+import types
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+sys.path[:0] = [BENCH, ROOT]
+
+PROBE_TOKENS = 16
+FAULTS = ("bf16_state", "half_topk")
+
+
+def serve_wrongly(fault: str, model_dir: str) -> None:
+    """Make the program about to be served wrong in one named way; the
+    reference keeps the configuration as it is."""
+    if fault == "half_topk":
+        path = os.path.join(model_dir, "config.json")
+        with open(path) as f:
+            config = json.load(f)
+        config["sparse_config"]["topk"] //= 2
+        with open(path, "w") as f:
+            json.dump(config, f)
+    elif fault == "bf16_state":
+        import dataclasses
+
+        import jax.numpy as jnp
+
+        from dynamo_tpu.models import minicpm_sala as family
+
+        init = family.init_kv_cache
+
+        def init_kv_cache(*args, **kwargs):
+            k, v = init(*args, **kwargs)
+            return dataclasses.replace(k, state=k.state.astype(jnp.bfloat16)), v
+
+        family.init_kv_cache = init_kv_cache
+
+
+async def amain(args) -> int:
+    import aiohttp
+
+    from harness import manifest, server
+    from harness.loadgen import _probes as send_probes
+    from harness.modeldir import token_id
+    from harness.reference import check_probes
+    from harness.traffic import probe_prompts
+
+    cell = manifest.load_cell(args.workload)
+    reference = manifest.architecture_module(cell.config, cell.config_name,
+                                             "reference")
+    work = os.path.join(ROOT, ".bench_work", cell.name + "-long-probes")
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
+    port = server.free_port()
+    flags, hf = server.build_flags(cell.config, cell.config_name, work,
+                                   args.seed, port, rehearsal=False)
+    if args.engine_args:
+        with open(flags.extra_engine_args) as f:
+            extra = json.load(f)
+        extra.update(json.loads(args.engine_args))
+        with open(flags.extra_engine_args, "w") as f:
+            json.dump(extra, f)
+    if args.fault:
+        serve_wrongly(args.fault, flags.model_path)
+    if server.tpu_devices(cell.chips) is None:
+        print("long_probes: no TPU here", file=sys.stderr)
+        return 3
+    t0 = time.monotonic()
+    engine, serving = await server.start(flags)
+    runner = engine.core_engine.runner
+    print(f"serving after {time.monotonic() - t0:.1f} s", flush=True)
+    lengths = [int(n) for n in args.lengths.split(",")]
+    probes = []
+    async with aiohttp.ClientSession(
+            timeout=aiohttp.ClientTimeout(total=600)) as session:
+        for prompt in probe_prompts(lengths, int(hf["vocab_size"]), args.seed):
+            t1 = time.monotonic()      # one at a time, as the harness sends them
+            probes += await send_probes(session, {
+                "base_url": f"http://127.0.0.1:{port}", "model": cell.config_name,
+                "probes": [prompt], "probe_tokens": PROBE_TOKENS})
+            print(f"probe of {len(prompt)} tokens: HTTP {probes[-1]['status']}, "
+                  f"{time.monotonic() - t1:.2f} s", flush=True)
+    out = {"workload": cell.name, "seed": args.seed, "lengths": lengths,
+           "engine_args": json.loads(args.engine_args or "{}"),
+           "fault": args.fault, "probes": []}
+    ok = True
+    loop = asyncio.get_running_loop()
+    limits_for = getattr(reference, "limits_for", None)
+    for probe in probes:      # one at a time: each has its own limits' verdict
+        t1 = time.monotonic()
+        atol, mean_atol = (
+            limits_for(hf, len(probe["prompt"]) + PROBE_TOKENS) if limits_for
+            else (reference.LOGPROB_ATOL, reference.LOGPROB_MEAN_ATOL))
+        held_to = types.SimpleNamespace(
+            build=reference.build, LOGPROB_ATOL=atol, LOGPROB_MEAN_ATOL=mean_atol)
+        ref = await loop.run_in_executor(
+            None, check_probes, held_to, runner.params, hf, [probe], token_id)
+        ok = ok and ref["ok"]
+        out["probes"].append({
+            "prompt_tokens": len(probe["prompt"]), "ok": ref["ok"],
+            "max_abs_err": ref["max_abs_err"], "mean_abs_err": ref["mean_abs_err"],
+            "limits": {"max": atol, "mean": mean_atol},
+            "tokens_compared": ref["tokens_compared"], "reasons": ref["reasons"],
+            "reference_s": round(time.monotonic() - t1, 1)})
+    stats = [d.memory_stats() or {} for d in runner.mesh.devices.flat]
+    out["memory_peak_bytes"] = max(
+        (s.get("peak_bytes_in_use", 0) for s in stats), default=0)
+    await server.stop(serving)
+    await engine.core_engine.close()
+    print(json.dumps(out), flush=True)
+    return 0 if ok else 1
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--lengths", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--engine-args", default="")
+    ap.add_argument("--fault", choices=FAULTS, default=None)
+    return asyncio.run(amain(ap.parse_args()))
+
+
+if __name__ == "__main__":
+    rc = main()
+    sys.stdout.flush()
+    sys.stderr.flush()
+    os._exit(rc)

@@ -345,3 +345,106 @@ def test_xing4_decode_step_mixes_its_streams_in_a_few_operations(
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
     # weights without the head 10.14 GB + cache 0.44 GB
     assert 10.4e9 < mem.argument_size_in_bytes < 10.8e9
+
+
+# MiniCPM-SALA as one chip serves it (benchmark/configs/
+# minicpm-sala-9b.json): the state kernel at a group a head (32 heads of
+# [128, 128] float32, a whole row a block), and the trunk's decode and
+# prefill steps at a table 1152 pages wide
+def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
+        one_chip, no_compile_cache, monkeypatch):
+    from dynamo_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, slots, h, d = 9, 24, 32, 128
+    f32, act = jnp.float32, jnp.bfloat16
+    assert ssm._head_block(h, 1, d * d * 4) == 32
+
+    def f(x, dt, a, bm, cm, skip, records, li, live):
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, skip, records, li, live,
+                                   ssm.live_row_list(live))
+
+    compiled = jax.jit(f, donate_argnums=(6,)).lower(
+        s((slots, h, d), act), s((slots, h), f32), s((h,), f32),
+        s((slots, h, d), act), s((slots, h, d), act), s((h,), f32),
+        s((layers, slots, h, d, d), f32), s((), jnp.int32),
+        s((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def _sala_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import minicpm_sala
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "minicpm-sala-9b.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: minicpm_sala.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: minicpm_sala.init_kv_cache(
+            cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+            num_slots=serve["max_batch_size"])))
+    assert k_side.state.shape == (9, 24, 32, 128, 128)
+    assert k_side.kv.shape == (3, 26880 * 2, 16, 128)
+    assert v_side.state.shape == (3, 26880 * 2, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, toks, positions, bt, slots, ctx, ss):
+        return minicpm_sala.forward(params, cfg, toks, positions,
+                                    (k_side, v_side), bt, slots, ctx,
+                                    return_hidden=True, state_slots=ss)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(rows, tokens), i32(rows, tokens),
+        i32(rows, width), i32(rows, tokens), i32(rows), i32(rows)).compile()
+
+
+@pytest.mark.parametrize("width", [512, 1152])
+def test_minicpm_sala_decode_step_keeps_state_and_pages_in_place(
+        one_chip, no_compile_cache, monkeypatch, width):
+    """A decode step at the benchmark's size on the routes the chip
+    takes: the state kernel over the nine lightning layers and the paged
+    decode kernel over the kept pages of the three attention layers
+    (none selected at a table no wider than dense_len), with neither the
+    state (0.45 GB) nor the pages (1.32 GB) copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _sala_step(one_chip, 24, 1, width)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    # the selection's top-k and the sort that compacts the kept pages
+    selects = re.search(r"(sort|topk|TopK)[^\n]*sparse_select", text)
+    assert bool(selects) == (width > 512)
+    mem = compiled.memory_analysis()
+    print("decode step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+    # embedding 0.60 GB + twelve layers 6.66 + state 0.45 + pages 1.32 +
+    # page means 0.08
+    assert 9.0e9 < mem.argument_size_in_bytes < 9.3e9
+
+
+def test_minicpm_sala_prefill_chunk_fits_beside_the_model(
+        one_chip, no_compile_cache, monkeypatch):
+    """A 2048-token chunk at the full table width: the masked dense
+    product's tile of scores and the chunked scan's matrices are the
+    step's temporaries, and they fit in what the model leaves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _sala_step(one_chip, 1, 2048, 1152)
+    mem = compiled.memory_analysis()
+    print("prefill step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 3 * 2 ** 30

@@ -281,18 +281,25 @@ def test_idle_rows_and_pad_rows_leave_every_other_state_untouched():
     assert np.abs(ssm1[:, 2]).max() > 0
 
 
-@pytest.mark.parametrize("seed,s,chunk", [(0, 64, 16), (1, 40, 16), (2, 7, 128)])
-def test_chunked_scan_is_the_recurrence(seed, s, chunk):
+@pytest.mark.parametrize("seed,s,chunk,g", [
+    (0, 64, 16, 2), (1, 40, 16, 2), (2, 7, 128, 2),
+    # lightning attention's form (models/minicpm_sala.py): a group a
+    # head, Δ = 1 at a token, a constant decay a head
+    (3, 64, 16, 6), (4, 50, 32, 6)])
+def test_chunked_scan_is_the_recurrence(seed, s, chunk, g):
     """``ops/ssm.ssd_chunked_scan`` from a given state, with pad positions
     (Δ = 0) inside and at the end of the run, against
     ``ssm_decode_update`` applied token by token."""
     rs = np.random.RandomState(seed)
-    b, h, p, g, n = 2, 6, 8, 2, 16
+    b, h, p, n = 2, 6, 8, 16
     x = rs.randn(b, s, h, p).astype(np.float32)
     dt = np.exp(rs.uniform(np.log(1e-3), np.log(0.5), (b, s, h))).astype(np.float32)
+    a = -rs.uniform(1, 16, h).astype(np.float32)
+    if g == h:
+        dt[:] = 1.0
+        a = -(2.0 ** (-8.0 * (np.arange(h) + 1) / h)).astype(np.float32)
     dt[0, s // 2:] = 0.0          # row 0: its second half is padding
     dt[1, 3] = 0.0
-    a = -rs.uniform(1, 16, h).astype(np.float32)
     bm = rs.randn(b, s, g, n).astype(np.float32)
     cm = rs.randn(b, s, g, n).astype(np.float32)
     d = rs.randn(h).astype(np.float32)

@@ -53,6 +53,11 @@ CASES = {
     # a state of whole (8, 128) tiles, one group, one head a group
     "one_group_of_eight_heads": (8, 8, 128, 1, 3, [0, 1, 0], 2),
     "a_head_a_group": (4, 16, 128, 4, 2, [1, 1], 0),
+    # lightning attention's shape (models/minicpm_sala.py): as many
+    # groups as heads, so a block holds several whole groups and reads a
+    # row of B and of C for each
+    "eight_groups_of_one_head": (8, 16, 16, 8, 3, [1, 0, 1], 1),
+    "four_groups_of_two_heads": (8, 8, 16, 4, 4, [0, 1, 1, 0], 2),
 }
 
 
@@ -129,12 +134,41 @@ def test_live_row_list_is_the_live_rows_in_order(live, rows, n):
     assert got_rows.shape == (len(live),) and int(got_rows.max()) < len(live)
 
 
-@pytest.mark.parametrize("per_group,head_bytes,want", [
-    (16, 128 * 256 * 4, 16),    # Falcon-H1-34B: a group's heads, 2 MiB
-    (16, 128 * 512 * 4, 8),     # a state twice as wide: half of them
-    (3, 8 * 16 * 4, 3),         # the tiny trunk: a group's three heads
-    (5, 2 << 20, 1),            # a head as large as a block
-    (12, (2 << 20) // 5, 4),    # room for five: the divisor below it
+@pytest.mark.parametrize("heads,per_group,head_bytes,want", [
+    (32, 16, 128 * 256 * 4, 16),    # Falcon-H1-34B: a group's heads, 2 MiB
+    (32, 16, 128 * 512 * 4, 8),     # a state twice as wide: half of them
+    (6, 3, 8 * 16 * 4, 6),          # the tiny trunk: both groups of three
+    (10, 5, 2 << 20, 1),            # a head as large as a block
+    (24, 12, (2 << 20) // 5, 4),    # room for five: the divisor below it
+    (32, 1, 128 * 128 * 4, 32),     # MiniCPM-SALA: a group a head, a whole row
+    (32, 1, 1 << 20, 2),            # larger heads: two whole groups
+    (12, 2, (2 << 20) // 5, 4),     # room for five heads: two groups of two
+    (16, 4, (2 << 20) // 9, 8),     # room for nine: two groups of four
 ])
-def test_head_block_divides_a_group_and_fits_the_block(per_group, head_bytes, want):
-    assert ssm._head_block(per_group, head_bytes) == want
+def test_head_block_divides_a_group_or_holds_whole_ones(heads, per_group,
+                                                       head_bytes, want):
+    assert ssm._head_block(heads, per_group, head_bytes) == want
+
+
+def test_kernel_computes_lightning_attentions_update():
+    """Δ = 1 at a live row, a decay that is a constant of the head, a
+    group a head, no skip: ``S = λ S + v ⊗ k``, ``o = S q``."""
+    h, d, slots = 4, 16, 3
+    rs = np.random.RandomState(5)
+    live = np.array([True, False, True])
+    v, k, q = (rs.randn(slots, h, d).astype(np.float32) for _ in range(3))
+    log_decay = -(2.0 ** (-8.0 * (np.arange(h) + 1) / h)).astype(np.float32)
+    records = rs.randn(LAYERS, slots, h, d, d).astype(np.float32)
+    args = [jnp.asarray(t) for t in (
+        v, live[:, None] * np.ones((slots, h), np.float32), log_decay, k, q,
+        np.zeros(h, np.float32))]
+    o, new = map(np.asarray, _step(args, jnp.asarray(records), jnp.int32(1),
+                                   jnp.asarray(live)))
+    lam = np.exp(log_decay)[None, :, None, None]
+    want = lam * records[1] + v[..., :, None] * k[..., None, :]
+    np.testing.assert_allclose(new[1][live], want[live], rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(new[1][~live], records[1][~live])
+    np.testing.assert_array_equal(new[[0, 2]], records[[0, 2]])
+    np.testing.assert_allclose(
+        o[live], np.einsum("bhvk,bhk->bhv", want, q)[live], rtol=1e-5, atol=1e-5)
+    assert not o[~live].any()
