@@ -349,6 +349,13 @@ def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis):
         q = q + layer_params["bq"]
         k = k + layer_params["bk"]
         v = v + layer_params["bv"]
+    # the products end as [b, s, out] before the compiler sees a head
+    # axis: folded into the dot, the reshape makes the weight
+    # [heads, head_dim, D], a bitcast only of the weight transposed, and
+    # the layer loop then copies its slice out of the stack and
+    # transposes it (57 MB a Phi-3 layer) where wo and the MLP's three
+    # are read in place (scripts/layer_loop.py shows either)
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(b, s, h_heads, hd)
     k = k.reshape(b, s, kvh, hd)
     v = v.reshape(b, s, kvh, hd)

@@ -22,14 +22,28 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _layer_loop():
+    # scripts/layer_loop.py: the lowering and the reading of a loop's body
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "layer_loop", os.path.join(ROOT, "scripts", "layer_loop.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture
@@ -448,3 +462,95 @@ def test_minicpm_sala_prefill_chunk_fits_beside_the_model(
     print("prefill step: arguments", mem.argument_size_in_bytes,
           "temporaries", mem.temp_size_in_bytes)
     assert mem.temp_size_in_bytes < 3 * 2 ** 30
+
+
+# The layer loop and its weights (PR 39). A projection whose result is
+# reshaped to heads at once has the reshape folded into its dot; the
+# compiler then sees the weight as [heads, head_dim, D], which is a
+# bitcast only of the weight transposed, and every layer copies its
+# slice out of the stack (`constant_dynamic-slice_fusion.N`, 18.9 MB on
+# Phi-3) and transposes it (`copy.N`) before the product: six such
+# operations a layer for q, k and v, a sixth of the decode step, where
+# wo and the MLP's three entered their fusions as the whole stack and
+# the layer index. Phi-3 at the benchmark's size on one chip (head 96,
+# MHA, 32 rows) and the real tp=4 program of Mistral-7B over the four
+# described chips (a shard's widths: wq [4096, 1024], wk / wv
+# [4096, 256], head 128, 64 rows).
+@pytest.mark.parametrize("config", ["phi3-mini-4k", "mistral-7b-v0.3-tp4"])
+def test_decode_step_reads_every_projection_weight_where_it_lies(
+        topo, no_compile_cache, monkeypatch, config):
+    ll = _layer_loop()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = ll.lower_trunk(
+        os.path.join(ROOT, "benchmark", "configs", config + ".json"),
+        topo.devices).compile()
+    text = compiled.as_text()
+    bodies = ll.loop_bodies(text)
+    assert len(bodies) == 1, list(bodies)          # the one scan over the layers
+    ops = ll.operations(next(iter(bodies.values())))
+    assert any(op == "custom-call" and "paged_decode_attention" in name
+               for name, _, op, _, _ in ops)
+    # no `copy` and no stand-alone dynamic-slice fusion yields an array
+    # of a projection weight's element count (>= 2**20)
+    assert ll.staged_weights(text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+# two lines of the Phi-3 decode loop as the parent of PR 39 compiled it,
+# and the product that reads the stack in place: what the reader above
+# must tell apart, held here where no topology is needed
+_LOOP_TEXT = """HloModule jit_step
+
+%fused_computation.7 (param_0.1: bf16[32,3072,3072], param_1.2: s32[]) -> bf16[1,3072,3072] {
+  %param_0.1 = bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = s32[]{:T(128)} parameter(1)
+  %constant.9 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic-slice.3 = bf16[1,3072,3072]{2,1,0:T(8,128)(2,1)S(1)} dynamic-slice(%param_0.1, %param_1.2, %constant.9, %constant.9), dynamic_slice_sizes={1,3072,3072}
+}
+
+%fused_computation.101 (param_0.5: bf16[32,3072], param_1.6: bf16[32,3072,3072], param_2.7: s32[]) -> bf16[32,3072] {
+  %param_0.5 = bf16[32,3072]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %param_1.6 = bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)} parameter(1)
+  %param_2.7 = s32[]{:T(128)} parameter(2)
+  %constant.11 = s32[]{:T(128)} constant(0)
+  %dynamic-slice.5 = bf16[1,3072,3072]{2,1,0:T(8,128)(2,1)} dynamic-slice(%param_1.6, %param_2.7, %constant.11, %constant.11), dynamic_slice_sizes={1,3072,3072}
+  %bitcast.9 = bf16[3072,3072]{1,0:T(8,128)(2,1)} bitcast(%dynamic-slice.5)
+  ROOT %convolution.2 = bf16[32,3072]{1,0:T(8,128)(2,1)S(1)} convolution(%param_0.5, %bitcast.9), dim_labels=bf_io->bf
+}
+
+%body.1 (arg: (s32[], bf16[32,3072], bf16[32,3072,3072])) -> (s32[], bf16[32,3072], bf16[32,3072,3072]) {
+  %arg = (s32[]{:T(128)}, bf16[32,3072]{1,0:T(8,128)(2,1)}, bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %get-tuple-element.1 = s32[]{:T(128)} get-tuple-element(%arg), index=0
+  %get-tuple-element.2 = bf16[32,3072]{1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %get-tuple-element.3 = bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=2
+  %constant_dynamic-slice_fusion.7 = bf16[1,3072,3072]{2,1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.3, %get-tuple-element.1), kind=kLoop, calls=%fused_computation.7
+  %copy.34 = bf16[1,3072,3072]{1,2,0:T(8,128)(2,1)S(1)} copy(%constant_dynamic-slice_fusion.7)
+  %copy.36 = bf16[32,32,128]{2,1,0:T(8,128)(2,1)S(1)} copy(%get-tuple-element.2)
+  %fusion.101 = bf16[32,3072]{1,0:T(8,128)(2,1)S(1)} fusion(%get-tuple-element.2, %get-tuple-element.3, %get-tuple-element.1), kind=kOutput, calls=%fused_computation.101
+  ROOT %tuple.1 = (s32[]{:T(128)}, bf16[32,3072]{1,0:T(8,128)(2,1)}, bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)}) tuple(%get-tuple-element.1, %fusion.101, %get-tuple-element.3)
+}
+
+ENTRY %main (p: (s32[], bf16[32,3072], bf16[32,3072,3072])) -> (s32[], bf16[32,3072], bf16[32,3072,3072]) {
+  %p = (s32[]{:T(128)}, bf16[32,3072]{1,0:T(8,128)(2,1)}, bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %copy.1 = bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)} copy(%p)
+  ROOT %while.1 = (s32[]{:T(128)}, bf16[32,3072]{1,0:T(8,128)(2,1)}, bf16[32,3072,3072]{2,1,0:T(8,128)(2,1)}) while(%p), condition=%cond.1, body=%body.1
+}
+"""
+
+
+def test_layer_loop_reader_tells_a_staged_weight_from_a_streamed_one():
+    ll = _layer_loop()
+    bodies = ll.loop_bodies(_LOOP_TEXT)
+    assert list(bodies) == ["body.1"]
+    ops = {name: (result, op, n) for name, result, op, n, _ in
+           ll.operations(bodies["body.1"])}
+    assert ops["copy.34"] == (
+        "bf16[1,3072,3072]{1,2,0:T(8,128)(2,1)S(1)}", "copy", 3072 * 3072)
+    assert ops["tuple.1"][2] == 32 * 3072 * 3072      # its largest member
+    # the slice alone and the transposing copy; not the small copy, not
+    # the product that slices inside its own fusion, not a copy outside
+    # the loop
+    assert ll.staged_weights(_LOOP_TEXT) == [
+        "constant_dynamic-slice_fusion.7", "copy.34"]
+    assert ll.staged_weights(_LOOP_TEXT, least=2 ** 17) == [
+        "constant_dynamic-slice_fusion.7", "copy.34", "copy.36"]
