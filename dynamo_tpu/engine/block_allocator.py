@@ -23,6 +23,11 @@ around the engine's flat block-id space:
 - Completed blocks (prompt or generated) are registered by sequence hash
   and announced via the ``events`` callback — the same stream the KV-aware
   router indexes (kv_router/publisher.py).
+- A second kind of page (``window_pages`` > 0; models/afmoe.py): the
+  pages of layers that attend to a window are a pool of their own
+  (``WindowPool``), private to a sequence, taken as it grows and given
+  back as they fall behind the window. Everything above is the full
+  kind's; ``usage()`` is the fuller of the two.
 """
 
 from __future__ import annotations
@@ -103,6 +108,62 @@ class _ReusePool:
         return len(self._entry)
 
 
+def window_keep_from(next_pos: int, window: int, block_size: int) -> int:
+    """The first page of a context that a window layer still needs when
+    its next query is at ``next_pos``: that query attends to the keys
+    above ``next_pos`` − ``window``, later ones to later keys, so every
+    page wholly at or below ``next_pos`` − ``window`` can go."""
+    return max(0, (next_pos - window + 1) // block_size)
+
+
+class WindowPool:
+    """Pages of the window kind: a free list, nothing more. A page
+    belongs to one sequence from ``take`` to ``give`` (no hash, no
+    sharing, no tier), so what the full kind's pool does for prefixes
+    has nothing to act on here. Page 0 is never handed out: a table
+    entry that names no page of the sequence points at it, and nothing
+    writes it."""
+
+    def __init__(self, num_pages: int, registry) -> None:
+        if num_pages < 2:
+            raise ValueError(f"a window pool of {num_pages} pages holds none")
+        self.num_pages = num_pages
+        self.free: List[int] = list(range(num_pages - 1, 0, -1))  # pop() → page 1 first
+        self._allocated = registry.counter(
+            "dynamo_kv_window_pages_allocated_total",
+            "Pages of the window kind handed to sequences",
+        )
+        self._released = registry.counter(
+            "dynamo_kv_window_pages_released_total",
+            "Pages of the window kind given back while their sequence ran "
+            "on, because they fell behind its window",
+        )
+
+    @property
+    def available(self) -> int:
+        return len(self.free)
+
+    @property
+    def used(self) -> int:
+        return self.num_pages - 1 - len(self.free)
+
+    def usage(self) -> float:
+        return self.used / (self.num_pages - 1)
+
+    def take(self) -> int:
+        if not self.free:
+            raise MemoryError("window pages exhausted")
+        self._allocated.inc()
+        return self.free.pop()
+
+    def give(self, pages, behind_window: bool = False) -> None:
+        """Back to the pool; ``behind_window`` counts them as released
+        (a finished or preempted sequence's are not)."""
+        self.free.extend(pages)
+        if behind_window:
+            self._released.inc(len(pages))
+
+
 class BlockAllocator:
     def __init__(
         self,
@@ -113,6 +174,7 @@ class BlockAllocator:
         tier2=None,  # Optional[KvHostTier] — host-RAM offload tier
         registry=None,  # Optional[telemetry.MetricsRegistry]
         flight=None,  # Optional[telemetry.FlightRecorder]
+        window_pages: int = 0,  # the window kind's pool (WindowPool); 0: none
     ):
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -168,6 +230,18 @@ class BlockAllocator:
             # dynrace: domain(executor)
             lambda: self.usage(),
         )
+        self.window: Optional[WindowPool] = None
+        if window_pages:
+            self.window = WindowPool(window_pages, registry)
+            registry.callback_gauge(
+                "dynamo_kv_pool_usage_ratio",
+                "used / total pages of each kind's pool, for a model with "
+                "two kinds of page (kind=full|window); "
+                "dynamo_kv_block_usage_ratio is the fuller of the two",
+                # dynrace: domain(executor)
+                lambda: [({"kind": "full"}, self.used / self.num_blocks),
+                         ({"kind": "window"}, self.window.usage())],
+            )
 
     # ---------- accounting ----------
 
@@ -483,4 +557,5 @@ class BlockAllocator:
             self.events.on_removed(removed_hashes)
 
     def usage(self) -> float:
-        return self.used / self.num_blocks if self.num_blocks else 0.0
+        full = self.used / self.num_blocks if self.num_blocks else 0.0
+        return full if self.window is None else max(full, self.window.usage())

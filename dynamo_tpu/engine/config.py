@@ -136,6 +136,12 @@ class ModelConfig:
     sparse_init_blocks: int = 1
     sparse_window_size: int = 2048
     sparse_dense_len: int = 8192
+    # AFMoE (models/afmoe.py, model_type "afmoe"): layer_types names each
+    # layer's attention, "sliding_attention" (the last sliding_window
+    # keys, rotary embedding, pages given back behind the window) or
+    # "full_attention" (every key, no positional term). Empty for every
+    # other family (Gemma-2 and GPT-OSS alternate by the layer's index).
+    layer_types: Tuple[str, ...] = ()
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -233,6 +239,25 @@ class ModelConfig:
                 "family with mixed residual streams: models/mhc.py)"
             )
         hc = _xing4_fields(config) if xing4 else {}
+        afmoe = config.get("model_type") == "afmoe"
+        kinds = sorted(set(lt or ()))
+        afmoe_keys = [k for k in AFMOE_CONFIG_KEYS if config.get(k)]
+        if len(kinds) > 1 and not ("gptoss" in arch or "gemma" in arch):
+            afmoe_keys.insert(0, "layer_types")
+        if afmoe_keys and not afmoe:
+            # a trunk whose layers differ in kind, with dense layers
+            # before its experts, a shared expert or a scaled embedding,
+            # would be served by mixtral.py or llama.py without them
+            raise NotImplementedError(
+                f"model_type {config.get('model_type')!r} carries "
+                f"{', '.join(afmoe_keys)} and no family here implements "
+                "them under that model_type (afmoe is the family with "
+                "window and full layers by layer_types, num_dense_layers, "
+                "num_shared_experts and mup_enabled: models/afmoe.py)"
+            )
+        # the family's own names for fields the call below reads under
+        # DeepSeek's (laid over its result)
+        family = _afmoe_fields(config) if afmoe else {}
         n_group = config.get("n_group", 1) or 1
         topk_group = config.get("topk_group", 1) or 1
         if config.get("topk_method") == "greedy":
@@ -261,7 +286,7 @@ class ModelConfig:
                     "permitted groups hold fewer experts than "
                     "num_experts_per_tok"
                 )
-        return cls(
+        made = cls(
             vocab_size=config.get("vocab_size", 32000),
             hidden_size=config.get("hidden_size", 2048),
             intermediate_size=config.get("intermediate_size", 5632),
@@ -301,6 +326,7 @@ class ModelConfig:
                 else "gptoss" if "gptoss" in arch
                 else "falcon_h1" if falcon_h1
                 else "minicpm_sala" if sala
+                else "afmoe" if afmoe
                 else ""
             ),
             attn_logit_softcap=config.get("attn_logit_softcapping") or 0.0,
@@ -325,6 +351,7 @@ class ModelConfig:
             **mamba,
             **hc,
         )
+        return dataclasses.replace(made, **family) if family else made
 
     @classmethod
     def from_model_dir(cls, model_dir: str) -> "ModelConfig":
@@ -343,6 +370,47 @@ RECURRENT_CONFIG_KEYS = (
     "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
     "conv_kernel", "d_state",
 )
+
+
+# keys that only the afmoe family (models/afmoe.py) computes
+AFMOE_CONFIG_KEYS = ("num_dense_layers", "num_shared_experts", "mup_enabled")
+
+
+def _afmoe_fields(config: dict) -> dict:
+    """ModelConfig's fields from the published keys of ``model_type:
+    afmoe``; what models/afmoe.py does not compute is refused here,
+    before any weight is made."""
+    only = {"score_func": "sigmoid", "route_norm": True, "n_group": 1,
+            "topk_group": 1, "num_expert_groups": 1, "num_limited_groups": 1,
+            "rope_scaling": None, "hidden_act": "silu",
+            "attention_bias": False, "tie_word_embeddings": False}
+    for key, value in only.items():
+        if (config.get(key, value) or value) != value:
+            raise NotImplementedError(
+                f"afmoe with {key}={config[key]!r} "
+                f"(models/afmoe.py computes {key}={value!r} only)")
+    kinds = tuple(config.get("layer_types") or ())
+    layers = int(config["num_hidden_layers"])
+    unknown = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+    if len(kinds) != layers or unknown:
+        raise ValueError(
+            f"afmoe: layer_types has {len(kinds)} entries for {layers} "
+            f"layers, unknown kinds {unknown} (sliding_attention | "
+            "full_attention)")
+    window = int(config.get("sliding_window") or 0)
+    if "sliding_attention" in kinds and window <= 0:
+        raise ValueError("afmoe: sliding_attention layers need sliding_window")
+    return dict(
+        layer_types=kinds,
+        sliding_window=window,
+        first_k_dense_replace=int(config.get("num_dense_layers", 0) or 0),
+        n_shared_experts=int(config.get("num_shared_experts", 0) or 0),
+        moe_scoring_func="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=float(config.get("route_scale", 1.0) or 1.0),
+        embedding_multiplier=(math.sqrt(int(config["hidden_size"]))
+                              if config.get("mup_enabled") else 1.0),
+    )
 
 
 # keys of a published config with a changed residual path
@@ -744,6 +812,37 @@ class EngineConfig:
     @property
     def blocks_per_seq(self) -> int:
         return math.ceil(self.max_model_len / self.kv_block_size)
+
+    def window_pages_a_row(self, tokens: int = 1) -> int:
+        """Pages of the window kind a row can hold: those that overlap
+        the keys ``tokens`` consecutive queries see, [first −
+        sliding_window + 1, last]."""
+        return math.ceil((self.model.sliding_window + tokens - 1)
+                         / self.kv_block_size) + 1
+
+    def prefill_chunk_tokens(self) -> int:
+        """The most tokens one row advances in a prefill step (the
+        largest bucket inside the step's budget; scheduler.
+        prefill_bucket_cap at one row)."""
+        budget = self.max_prefill_tokens_per_step
+        allowed = [b for b in self.prefill_buckets
+                   if not budget or b <= budget]
+        return allowed[-1] if allowed else self.prefill_buckets[0]
+
+    def window_pool_pages(self) -> int:
+        """Pages of the window kind's pool, for a model whose
+        ``layer_types`` has window layers (models/afmoe.py); 0 for every
+        other. Derived, not set: page 0, which no sequence holds, what
+        every slot holds while decoding, and what the rows of one
+        prefill step hold more (a chunk's pages beside the window's; the
+        scheduler releases before it takes, so the bound holds without
+        preemption)."""
+        if "sliding_attention" not in self.model.layer_types:
+            return 0
+        decoding = self.window_pages_a_row()
+        prefilling = self.window_pages_a_row(self.prefill_chunk_tokens())
+        return (1 + self.max_batch_size * decoding
+                + self.max_prefill_batch * (prefilling - decoding))
 
     @property
     def chain_enabled(self) -> bool:

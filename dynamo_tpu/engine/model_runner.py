@@ -176,12 +176,17 @@ class ModelRunner:
         # falcon_h1.py): the state is sized by slot, rides in the cache
         # pytree, and every path that moves pages without it is refused
         self.recurrent = bool(getattr(self.arch, "RECURRENT_STATE", False))
+        # a family whose window layers keep their pages in a second pool
+        # behind a second table (models/afmoe.py): every path that moves
+        # pages by one block id is refused likewise
+        self.window_pages = bool(getattr(self.arch, "WINDOW_PAGES", False))
         for path, on in (
             ("spec_ngram_tokens", config.spec_ngram_tokens > 0),
             ("spec_draft_model", bool(config.spec_draft_model)),
             ("sp_size", config.sp_size > 1),
             ("pp_size", config.pp_size > 1),
             ("tp_size", config.tp_size > 1),
+            ("ep_size", config.ep_size > 1 and self.window_pages),
             ("host_kv_blocks", config.host_kv_blocks > 0),
             ("prefix_pull", config.prefix_pull),
             ("multi_step_decode", config.multi_step_decode > 1),
@@ -418,11 +423,23 @@ class ModelRunner:
             ))
 
         self.param_bytes = _leaf_bytes(self.params)
-        pages = (tuple(side.kv for side in self.kv_cache)
-                 if self.recurrent else self.kv_cache)
+        # (two kinds of page: the full kind's, the part that grows with
+        # the context; the window kind's is bounded by the window)
+        pages = (tuple(side.kv for side in self.kv_cache) if self.recurrent
+                 else tuple(side.full for side in self.kv_cache)
+                 if self.window_pages else self.kv_cache)
         self.kv_bytes_per_token = _leaf_bytes(pages) / max(
             1, config.num_kv_blocks * config.kv_block_size
         )
+        if self.window_pages:
+            logger.info(
+                "two kinds of page: full %d pages (%.3f GB), window %d "
+                "pages (%.3f GB; %d a decoding row, %d a row in prefill)",
+                config.num_kv_blocks, _leaf_bytes(pages) / 1e9,
+                config.window_pool_pages(),
+                _leaf_bytes(tuple(s.window for s in self.kv_cache)) / 1e9,
+                config.window_pages_a_row(),
+                config.window_pages_a_row(config.prefill_chunk_tokens()))
         if self.recurrent:
             self.compiles.registry.gauge(
                 "dynamo_engine_recurrent_state_bytes",
@@ -456,15 +473,22 @@ class ModelRunner:
 
     def refuse_without_state(self, path: str) -> None:
         """Raise, by name, for a path that would move, share or roll back
-        a sequence's pages without its recurrent state; nothing for a
-        family whose only per-sequence state is pages."""
-        if not self.recurrent:
+        a sequence's pages without its recurrent state, or by one block
+        id where the family keeps two kinds of page; nothing for a
+        family whose only per-sequence state is one kind of page."""
+        if self.recurrent:
+            keeps = "recurrent state by slot beside the paged cache"
+            why = self.arch.RECURRENT_REFUSALS[path]
+        elif self.window_pages:
+            keeps = ("its window layers' pages in a pool and a table of "
+                     "their own")
+            why = self.arch.WINDOW_REFUSALS[path]
+        else:
             return
         family = self.arch.__name__.rsplit(".", 1)[-1]
         raise ValueError(
             f"{path} is refused for the {family} family, which keeps "
-            f"recurrent state by slot beside the paged cache: "
-            f"{self.arch.RECURRENT_REFUSALS[path]}"
+            f"{keeps}: {why}"
         )
 
     # ---------- routed experts' counters ----------
@@ -1540,14 +1564,25 @@ class ModelRunner:
         targets: Optional[np.ndarray] = None,  # [B, S] next-prompt-token ids
         want_prompt: bool = False,  # compute prompt logprobs at `targets`?
         want_greedy: bool = False,  # per-position argmax (spec verify)?
+        window_tables: Optional[np.ndarray] = None,  # [B, W] the window kind's
     ) -> Tuple[jax.Array, ...]:
         """Run one compiled step; returns (next_tokens, logprobs) device arrays.
+
+        ``window_tables`` (a family with two kinds of page, models/
+        afmoe.py): the window kind's table, laid beside ``block_tables``
+        in the packed input; left out, every entry names page 0, which
+        no sequence holds (warm-up, a step that writes nothing).
 
         Legacy callers pass a single ``key`` (tests, warmup, dry runs): it is
         broadcast into per-row keys with the row index as fold-in counter.
         The scheduler passes per-request ``seed_keys``/``counters`` instead.
         """
         b, s = tokens.shape
+        width = block_tables.shape[1]
+        if self.window_pages:
+            block_tables = np.concatenate(
+                [block_tables, np.zeros_like(block_tables)
+                 if window_tables is None else window_tables], axis=1)
         if seed_keys is None:
             # PRNGKey(0)'s two words are zero
             seed_keys = (np.zeros(2, np.uint32) if key is None else
@@ -1566,7 +1601,7 @@ class ModelRunner:
         )
         with self.compiles.track(
             "prefill" if s > 1 else "decode",
-            f"b{b}_s{s}_w{block_tables.shape[1]}", arrays=1,
+            f"b{b}_s{s}_w{width}", arrays=1,
         ):
             moe = () if self.moe_counts is None else (self.moe_counts,)
             args = (self.params, *self.kv_cache, *self.sample_state,
@@ -1967,7 +2002,8 @@ class ModelRunner:
                 cfg.model, cfg.num_kv_blocks, cfg.kv_block_size,
                 self.kv_dtype,
                 **({"num_slots": cfg.max_batch_size} if self.recurrent
-                   else {}),
+                   else {"window_blocks": cfg.window_pool_pages()}
+                   if self.window_pages else {}),
             ))
             if cfg.pp_size > 1:
                 from ..parallel.pipeline import stage_cache

@@ -34,7 +34,7 @@ from ..telemetry.registry import STEP_BUCKETS, MetricsRegistry
 from ..telemetry.tracing import span
 from ..tokens import TokenSequence
 from ..utils import faults
-from .block_allocator import BlockAllocator, KvEventSink
+from .block_allocator import BlockAllocator, KvEventSink, window_keep_from
 from .config import EngineConfig
 from .model_runner import ModelRunner
 from .sampling import (
@@ -162,6 +162,11 @@ class EngineRequest:
     # runtime state
     slot: int = -1
     block_ids: List[int] = dataclasses.field(default_factory=list)
+    # a family with two kinds of page (models/afmoe.py): the window
+    # kind's pages this row holds, for the context's pages window_first,
+    # window_first + 1, ... (what lies before has been given back)
+    window_ids: deque = dataclasses.field(default_factory=deque)
+    window_first: int = 0
     num_cached: int = 0
     context_len: int = 0          # tokens whose KV is (being) written
     pending_token: int = -1       # sampled but KV not yet written
@@ -343,8 +348,13 @@ class _HostBatchState:
     outputs and the device never counts their samples.
     """
 
-    def __init__(self, cfg: EngineConfig):
+    def __init__(self, cfg: EngineConfig, window_pages: bool = False):
         b = cfg.max_batch_size
+        # the window kind's table a slot (two kinds of page only): kept
+        # entry by entry as pages are taken and given back; 0 names the
+        # page no sequence holds
+        self.wtab = (np.zeros((b, cfg.blocks_per_seq), np.int32)
+                     if window_pages else None)
         self.temp = np.zeros(b, np.float32)
         self.top_k = np.zeros(b, np.int32)
         self.top_p = np.ones(b, np.float32)
@@ -390,6 +400,8 @@ class _HostBatchState:
         self.btab[i, :n] = er.block_ids
         self.btab[i, n:] = 0
         self.synced_blocks[i] = n
+        if self.wtab is not None:
+            self.wtab[i] = 0
 
     def sync_blocks(self, er: "EngineRequest") -> None:
         """Mirror a live row's grown (or rolled-back) block list."""
@@ -503,7 +515,14 @@ class Scheduler:
         # from position 0, where the trunk zeroes its slot's state
         # (FakeRunner test doubles carry no flag)
         self.recurrent = bool(getattr(runner, "recurrent", False))
-        if self.recurrent and disagg is not None:
+        # a family with two kinds of page (models/afmoe.py): its window
+        # layers' pages come from a pool of their own, taken as a row
+        # grows and given back behind the window (_take_window,
+        # _release_window); nothing else knows the kind, so the same
+        # holds as for state: no hits, no registered block, resume from 0
+        window_pages = bool(getattr(runner, "window_pages", False))
+        self.private_pages = self.recurrent or window_pages
+        if self.private_pages and disagg is not None:
             runner.refuse_without_state("remote_prefill")
         self.disagg = disagg
         # flight recorder: the process-wide engine-event ring every layer
@@ -547,7 +566,9 @@ class Scheduler:
             config.num_kv_blocks, config.kv_block_size,
             config.enable_prefix_caching, sink, tier2=tier2,
             registry=self.registry, flight=self.flight,
+            window_pages=config.window_pool_pages() if window_pages else 0,
         )
+        self.window = self.allocator.window
         # cluster KV fabric (kv/fabric.py): cross-worker prefix pull +
         # cold-tier rehydration. Built whenever either capability is
         # configured; the CLI/discovery layer attaches the peer view
@@ -578,7 +599,7 @@ class Scheduler:
         self.sp_active: Optional[_SpPrefill] = None
         self.waiting: deque = deque()
         # persistent decode-step host arrays (see _HostBatchState)
-        self._host = _HostBatchState(config)
+        self._host = _HostBatchState(config, window_pages)
         self.pending_remote: List[EngineRequest] = []
         self.slots: List[Optional[EngineRequest]] = [None] * config.max_batch_size
         # the prefill BATCH: up to max_prefill_batch requests whose
@@ -1185,7 +1206,7 @@ class Scheduler:
         re-prefills ``prompt + resume_tokens`` and continues the stream.
         Returns False (caller frees the blocks and nacks) when no slot
         is free at install time."""
-        if self.recurrent:
+        if self.private_pages:
             self.runner.refuse_without_state("migration")
         self._prepare_request(er)
         if er.base_key is None:
@@ -1398,6 +1419,7 @@ class Scheduler:
             self.slots[er.slot] = None
         self.allocator.free_blocks(er.block_ids)
         er.block_ids = []
+        self._drop_window(er)
 
     def _advance_row(self, er: EngineRequest, token: int) -> None:
         """Commit ONE sampled token to host state: the previous pending
@@ -1429,16 +1451,59 @@ class Scheduler:
                 er.block_ids.append(self.allocator.allocate_block(flush=False))
             except MemoryError:
                 return False
+        return self.window is None or self._take_window(er, needed)
+
+    # ---------- the window kind's pages (two kinds of page) ----------
+
+    def _release_window(self, er: EngineRequest, next_pos: int) -> None:
+        """Give back the window pages no query at ``next_pos`` or later
+        can see: a window layer's query at p attends to keys above p −
+        sliding_window, so every page wholly at or below ``next_pos`` −
+        sliding_window goes. Its table entry then names page 0, which no
+        sequence holds, so a kernel that walks to it (the decode kernel
+        starts at a whole chunk of pages) reads zeros under its mask.
+        Called before a pass takes pages, so a row never holds more than
+        ``EngineConfig.window_pages_a_row`` of them."""
+        keep_from = window_keep_from(
+            next_pos, self.config.model.sliding_window,
+            self.config.kv_block_size)
+        n = min(keep_from - er.window_first, len(er.window_ids))
+        if n <= 0:
+            return
+        with span("sched.window.release", step=self.passes, pages=n):
+            self.window.give([er.window_ids.popleft() for _ in range(n)],
+                             behind_window=True)
+            self._host.wtab[er.slot, er.window_first:er.window_first + n] = 0
+            er.window_first += n
+
+    def _take_window(self, er: EngineRequest, needed: int) -> bool:
+        """Window pages up to the context's page ``needed`` − 1; False
+        (nothing taken back) where the pool runs out."""
+        held = er.window_first + len(er.window_ids)
+        if needed - held > self.window.available:
+            return False
+        for page in range(held, needed):
+            bid = self.window.take()
+            er.window_ids.append(bid)
+            self._host.wtab[er.slot, page] = bid
         return True
+
+    def _drop_window(self, er: EngineRequest) -> None:
+        """A finished or preempted row's window pages, all of them."""
+        if er.window_ids:
+            self.window.give(er.window_ids)
+        er.window_ids = deque()
+        er.window_first = 0
 
     def _register_completed_blocks(self, er: EngineRequest) -> None:
         """Hash-register blocks whose KV is complete (matchable + KV events).
 
         ``er.seq`` mirrors exactly the tokens whose KV sits in cache, so its
         frozen blocks line up 1:1 with ``er.block_ids``. Nothing is
-        registered for a family with recurrent state: a later sequence
-        could take the pages but not the state that followed them."""
-        if self.recurrent:
+        registered for a family with recurrent state or two kinds of
+        page: a later sequence could take the pages but not the state
+        that followed them, nor the window kind's pages."""
+        if self.private_pages:
             return
         n_complete = min(er.context_len // self.config.kv_block_size, len(er.seq.blocks))
         for i in range(er.registered_blocks, n_complete):
@@ -2798,6 +2863,13 @@ class Scheduler:
             prompt_tokens=len(er.prompt), resumed=bool(er.resume_tokens),
         )
         tokens_all = er.prompt + er.resume_tokens
+        if self.window is not None and self.window.available < min(
+                -(-len(tokens_all) // self.config.kv_block_size),
+                self.config.window_pages_a_row(
+                    self.config.prefill_chunk_tokens())):
+            # admission asks both pools: the window kind's must hold
+            # what this row's prefill can come to hold
+            raise MemoryError("window pages: no room for one more prefill")
         # ring tail mirrors the emitted history (a resumed request's
         # replayed tail included) so stop-seq checks and chain fills
         # continue exactly where the stream left off
@@ -2808,11 +2880,13 @@ class Scheduler:
             # scattered the pulled run, and registered it (num_cached
             # covers local + pulled) — only the tail prefills below
             er.pull_ready = False
-        elif self.recurrent or (
+        elif self.private_pages or (
                 er.want_prompt_lps and not er.prompt_lps_emitted):
             # every prompt position must run through the model — a prefix
-            # cache hit would skip its logits (prompt logprobs) or its
-            # part of the recurrent state. Blank the probe's hits so
+            # cache hit would skip its logits (prompt logprobs), its
+            # part of the recurrent state or its window pages (a family
+            # with two kinds of page registers none, so its probe finds
+            # none). Blank the probe's hits so
             # allocation proceeds with zero cached tokens. (A resumed
             # request that already emitted them uses the cache normally.)
             probe = self.allocator.probe_prefix(tokens_all)
@@ -3141,7 +3215,21 @@ class Scheduler:
                 total = len(er.prefill_tokens)
                 take = min(total - er.prefill_pos, bucket_cap)
                 end = er.prefill_pos + take
+                if self.window is not None:
+                    # two kinds of page: what fell behind this chunk's
+                    # first query goes back, then the chunk's own pages
+                    # are taken (the full kind's came with admission)
+                    self._release_window(er, er.prefill_pos)
+                    if not self._take_window(
+                            er, -(-end // cfg.kv_block_size)):
+                        logger.warning("window pages exhausted: preempting "
+                                       "%s in prefill", er.request_id)
+                        self.prefilling.remove(er)
+                        self._preempt(er)
+                        continue
                 plan.append((er, er.prefill_pos, end, take, end >= total))
+            if not plan:
+                return
             bucket = cfg.bucket_for(max(p[3] for p in plan))  # <= bucket_cap
 
             tokens = np.zeros((rows, bucket), np.int32)
@@ -3164,6 +3252,7 @@ class Scheduler:
             targets = np.zeros((rows, bucket), np.int32)
             n_tgts = [0] * len(plan)
             want_prompt = False
+            wtab = None if self.window is None else np.zeros_like(btab)
 
             for i, (er, start, end, take, final) in enumerate(plan):
                 t, p, bt, sm, cl, li = build_prefill_arrays(
@@ -3172,6 +3261,8 @@ class Scheduler:
                 )
                 tokens[i], positions[i] = t[0], p[0]
                 btab[i], slot_map[i] = bt[0], sm[0]
+                if wtab is not None:
+                    wtab[i] = self._host.wtab[er.slot]
                 ctx_lens[i], last_idx[i] = cl[0], li[0]
                 (temp[i], top_k[i], top_p[i], min_p[i], pres[i], freq[i],
                  rep[i]) = (er.temperature, er.top_k, er.top_p, er.min_p,
@@ -3201,6 +3292,7 @@ class Scheduler:
                 sample_slots=sample_slots, commit=commit,
                 want_top=any(er.logprobs_n > 0 for er, *_ in plan),
                 targets=targets, want_prompt=want_prompt,
+                **({} if wtab is None else {"window_tables": wtab}),
             )
             self.steps += 1
             if self.draft is not None:
@@ -3618,6 +3710,12 @@ class Scheduler:
 
             # make sure each active sequence has blocks for its next position
             # (all k_steps of them under a burst)
+            if self.window is not None:
+                # two kinds of page: every row first gives back what fell
+                # behind its next query, so that what one row frees
+                # another can take in this same pass
+                for er in active:
+                    self._release_window(er, er.context_len)
             for er in list(active):
                 ok = all(
                     self._ensure_block_for(er, er.context_len + j)
@@ -3706,6 +3804,8 @@ class Scheduler:
                     repetition_penalty=hs.rep, seed_keys=hs.keys, counters=ctrs,
                     sample_slots=np.arange(b, dtype=np.int32), commit=commit,
                     want_top=want_top,
+                    **({} if hs.wtab is None
+                       else {"window_tables": hs.wtab[:, :w].copy()}),
                 )
                 if self.draft is not None:
                     # mirror the step on the draft (inert sampling): the
@@ -3779,6 +3879,7 @@ class Scheduler:
             er.slot = -1
         self.allocator.free_blocks(er.block_ids)
         er.block_ids = []
+        self._drop_window(er)
         # seq mirrors tokens whose KV was written; everything past the
         # original prompt is generated output, plus the not-yet-written
         # pending token — all already emitted to the client

@@ -49,6 +49,19 @@ def resolve(cfg: ModelConfig):
             f"{cfg.model_family!r} has none (models/minicpm_sala.py is "
             "selected by model_type minicpm_sala)"
         )
+    if cfg.model_family == "afmoe":
+        from . import afmoe
+
+        return afmoe
+    if cfg.layer_types:
+        # window and full layers with no family to tell them apart:
+        # mixtral or llama would run one kind of attention in every one
+        raise NotImplementedError(
+            f"layer_types ({len(cfg.layer_types)} entries) needs a family "
+            f"that keeps pages a kind of layer; model_family "
+            f"{cfg.model_family!r} has none (models/afmoe.py is selected "
+            "by model_type afmoe)"
+        )
     if cfg.mamba_d_ssm > 0:
         # recurrent state with no family to keep it: llama would serve
         # the attention half alone

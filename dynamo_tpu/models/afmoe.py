@@ -1,0 +1,359 @@
+"""AFMoE (``model_type: afmoe``; Arcee's Trinity family): grouped-query
+attention whose layers differ in how much of the context they keep,
+over dense-then-expert feed-forwards. ``layer_types`` names every
+layer's attention, ``sliding_attention`` (the last ``sliding_window``
+keys, rotary embedding) or ``full_attention`` (every key, no positional
+term at all).
+
+With ``h`` the residual stream, ``N_*`` RMS norms with a learned weight,
+layer ``l``, ``local = layer_types[l] == "sliding_attention"``:
+
+    h0    = embed[tokens] · √hidden_size                 (mup_enabled)
+    a     = N_in(h)
+    q,k,v = a Wq, a Wk, a Wv;   g = a Wg
+    q,k   = N_q(q), N_k(k)      per head, one weight [head_dim] each
+    q,k   = rope(q, k)          if local
+    s_ij  = q_i·k_j / √head_dim,  j ≤ i,  and i − j < sliding_window if local
+    o     = softmax(s) v ⊙ sigmoid(g)                    before Wo
+    h     = h + N_post_attn(o Wo)
+    m     = N_pre_mlp(h)
+    y     = SwiGLU(m)                                    l <  num_dense_layers
+    y     = Σ_{e∈S} w_e FFN_e(m) + FFN_shared(m)         otherwise
+    h     = h + N_post_mlp(y)
+    logits = N_final(h) W_head
+
+The router is models/mixtral.py's (``route_top_k``: float32 sigmoid
+scores, ``expert_bias`` steers the choice only, the chosen scores
+renormalised and times ``route_scale``), the experts its sorted grouped
+products with the shared expert beside them (``make_moe_mlp_fn``).
+
+**Two kinds of page.** A full layer keeps every page of the context; a
+window layer needs only the pages that reach back ``sliding_window``
+tokens from the newest query. So a side of the cache is a
+``KindCache``: one stack of pages over the full layers and one over the
+window layers, each with its own pool in the allocator
+(engine/block_allocator.py: ``num_kv_blocks`` is the full kind's pool,
+the window kind's is derived, ``EngineConfig.window_pool_pages``) and
+its own block table a row. The step's table is ``[B, 2 W]``: the full
+kind's ``W`` entries, then the window kind's, both indexed by the
+context's page (position // block size). A window layer's page behind
+the window goes back to its pool while the sequence runs
+(engine/scheduler.py ``_release_window``) and its table entry then
+names page 0 of the window stack, which is never handed out and never
+written: the kernels may walk to it (the decode kernel starts at a
+whole chunk of pages), and read zeros under their masks. Where a window
+layer writes is read from its own table (``position // block``), not
+from the step's slot mapping, which is the full kind's.
+
+Nothing else knows the kind, so every path that moves or shares a
+sequence's pages by one block id is refused for this family, by name
+(``WINDOW_REFUSALS``); prefix hits are blanked, no block is registered,
+and a preempted sequence resumes by prefill from position 0.
+
+The trunk scans each run of layers of one kind (attention and
+feed-forward alike) over that run's stacked weights (``params["runs"]``;
+``layer_runs``), as models/minicpm_sala.py does.
+
+Scopes: ``attn`` with ``attn_window`` or ``attn_full`` inside (norm,
+projections, rope, scatter, kernel, gate, output), and ``kv_window`` or
+``kv_full`` around the kernel alone; ``mlp`` with mixtral's ``moe_*``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..engine.config import ModelConfig
+from ..ops.attention import attention, lane_pad, scatter_kv_stacked
+from .deepseek import random_expert_stacks
+from .llama import (_swiglu_mlp, base_specs, lm_logits, qkv_prologue,
+                    rms_norm)
+from .mixtral import make_moe_mlp_fn, split_expert_stacks
+from .quant import dense
+
+Params = Dict[str, Any]
+
+# the family's window layers give pages back while a sequence runs: the
+# engine keeps a second pool and a second table a row for them
+WINDOW_PAGES = True
+LOCAL, GLOBAL = "sliding_attention", "full_attention"
+# paths that move, share or roll back a sequence's pages by one block id
+# and do not know the kind, each refused by name at start-up
+# (ModelRunner.refuse_without_state): path -> reason
+WINDOW_REFUSALS = {
+    "spec_ngram_tokens": "a rejected proposal rolls back pages by one "
+                         "block id; the window kind's are not rolled back",
+    "spec_draft_model": "the draft's mirror cache shares the full kind's "
+                        "block ids and has no window pool",
+    "sp_size": "sequence-parallel prefill writes one prompt's pages from "
+               "every chip by the full kind's table alone",
+    "pp_size": "the pipeline stages one stack of pages; the two kinds' "
+               "stacks differ in depth",
+    "tp_size": "the two page stacks and the window table are not sharded",
+    "ep_size": "the expert stacks are kept a run of layers and not sharded",
+    "host_kv_blocks": "an offloaded block restores the full kind's page "
+                      "only",
+    "prefix_pull": "a pulled prefix brings the full kind's pages only",
+    "multi_step_decode": "the fused burst grows its tables on the device "
+                         "by one block id; no window page is released or "
+                         "taken inside it",
+    "decode_pipeline_depth": "the chained burst runs ahead of the host, "
+                             "which releases and takes the window pages",
+    "remote_prefill": "a prefill worker ships the full kind's pages only",
+    "migration": "a migrated sequence brings the full kind's pages only",
+}
+
+# standard deviation of the served logits under random weights, and of
+# q·k / sqrt(head_dim) (the query norm's weight: the per-head norms make
+# the scores' size a matter of that weight alone; at 1.0 attention is
+# spread thinly over every key and the pages' precision does not show:
+# models/falcon_h1.py ATTN_SCORE_STD)
+LOGIT_STD = 2.0
+ATTN_SCORE_STD = 3.0
+# expert_bias of random weights: small and non-zero, so that "the bias
+# steers the choice only" is exercised (as deepseek.init_params)
+EXPERT_BIAS_STD = 0.05
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class KindCache:
+    """A side of the cache: the full layers' pages and the window
+    layers', ``[layers of the kind, pages of its pool, block, KVH, D]``
+    each."""
+    full: Any
+    window: Any
+
+    @property
+    def dtype(self):
+        return self.full.dtype
+
+
+CACHE_SPEC = KindCache(full=P(), window=P())
+
+
+def layer_runs(cfg: ModelConfig) -> List[Tuple[bool, bool, int, int]]:
+    """The layers as runs of one kind: (window layer?, dense
+    feed-forward?, the run's first index among the layers of its
+    attention kind, its length)."""
+    runs, seen = [], {True: 0, False: 0}
+    dense_layers = min(cfg.first_k_dense_replace, cfg.num_layers)
+    for i, kind in enumerate(cfg.layer_types):
+        local, is_dense = kind == LOCAL, i < dense_layers
+        if runs and runs[-1][:2] == [local, is_dense]:
+            runs[-1][3] += 1
+        else:
+            runs.append([local, is_dense, seen[local], 1])
+        seen[local] += 1
+    return [tuple(r) for r in runs]
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+    """Random weights from the seed, fan-in-scaled normal as in the other
+    families. The embedding is divided by its multiplier (hidden states
+    of unit size); every norm weighs 1.0 but the query's, which weighs
+    ``ATTN_SCORE_STD``; the four norms a layer make every sublayer add a
+    vector of unit size whatever the gate's mean and ``route_scale`` do
+    to its inside; the head is drawn for logits of standard deviation
+    ``LOGIT_STD``. A layer's experts are one prototype plus a spread
+    (``deepseek.random_expert_stacks``), ``expert_bias`` small normal, float32."""
+    d, inter = cfg.hidden_size, cfg.intermediate_size
+    moe_inter = cfg.moe_intermediate_size or inter
+    h, kvh, hd, e = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_experts
+
+    def w(key, shape, fan_in, gain=1.0):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (gain * fan_in ** -0.5)).astype(dtype)
+
+    def experts(key, shape, fan_in):
+        return random_expert_stacks(key, shape, fan_in, dtype)
+
+    runs = []
+    for r, (_, is_dense, _, n) in enumerate(layer_runs(cfg)):
+        keys = jax.random.split(jax.random.fold_in(key, r + 1), 13)
+        run = {
+            "ln1": jnp.ones((n, d), dtype),
+            "wq": w(keys[0], (n, d, h * hd), d),
+            "wk": w(keys[1], (n, d, kvh * hd), d),
+            "wv": w(keys[2], (n, d, kvh * hd), d),
+            "wg": w(keys[3], (n, d, h * hd), d),
+            "wo": w(keys[4], (n, h * hd, d), h * hd),
+            "q_norm": jnp.full((n, hd), ATTN_SCORE_STD, dtype),
+            "k_norm": jnp.ones((n, hd), dtype),
+            "ln1_post": jnp.ones((n, d), dtype),
+            "ln2": jnp.ones((n, d), dtype),
+            "ln2_post": jnp.ones((n, d), dtype),
+        }
+        if is_dense:
+            run["w_gate"] = w(keys[5], (n, d, inter), d)
+            run["w_up"] = w(keys[6], (n, d, inter), d)
+            run["w_down"] = w(keys[7], (n, inter, d), inter)
+        else:
+            run["router"] = w(keys[5], (n, d, e), d)
+            run["router_bias"] = EXPERT_BIAS_STD * jax.random.normal(
+                keys[6], (n, e), jnp.float32)
+            run["w_gate"] = experts(keys[7], (n, e, d, moe_inter), d)
+            run["w_up"] = experts(keys[8], (n, e, d, moe_inter), d)
+            run["w_down"] = experts(keys[9], (n, e, moe_inter, d), moe_inter)
+            if cfg.n_shared_experts > 0:
+                sh = cfg.n_shared_experts * moe_inter
+                run["w_sh_gate"] = w(keys[10], (n, d, sh), d)
+                run["w_sh_up"] = w(keys[11], (n, d, sh), d)
+                run["w_sh_down"] = w(keys[12], (n, sh, d), sh)
+        runs.append(run)
+    k_embed, k_head = jax.random.split(jax.random.fold_in(key, 0))
+    params: Params = {
+        "embed": (jax.random.normal(k_embed, (cfg.vocab_size, d), jnp.float32)
+                  / cfg.embedding_multiplier).astype(dtype),
+        "runs": runs,
+        "final_norm": jnp.ones((d,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(k_head, (d, cfg.vocab_size), d, LOGIT_STD)
+    return params
+
+
+def param_specs(params: Params) -> Dict:
+    """Replicated: tp > 1 and ep > 1 are refused for the family."""
+    specs = base_specs(params)
+    specs["lm_head"] = P()
+    specs = {k: v for k, v in specs.items() if k in params}
+    specs["runs"] = [{k: P() for k in run} for run in params["runs"]]
+    return specs
+
+
+def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+                  dtype=jnp.bfloat16, window_blocks: int = 1):
+    """``(KindCache(k full, k window), KindCache(v full, v window))``:
+    ``num_blocks`` pages a full layer, ``window_blocks`` a window layer
+    (page 0 of those is the one no sequence holds)."""
+    n_local = sum(kind == LOCAL for kind in cfg.layer_types)
+    page = (block_size, cfg.num_kv_heads, lane_pad(cfg.head_dim))
+    full = (cfg.num_layers - n_local, num_blocks) + page
+    window = (n_local, window_blocks) + page
+    return tuple(KindCache(jnp.zeros(full, dtype), jnp.zeros(window, dtype))
+                 for _ in range(2))
+
+
+def window_slots(window_table, positions, slot_mapping, block_size: int):
+    """Where a window layer writes each token: the slot of its position
+    in the page its own table names; -1 where the step writes nothing."""
+    page = jnp.take_along_axis(window_table, positions // block_size, axis=1)
+    return jnp.where(slot_mapping >= 0,
+                     page * block_size + positions % block_size, -1)
+
+
+def _gated(o, x, lp):
+    """``o ⊙ sigmoid(a Wg)``: elementwise, before the output projection."""
+    gate = jax.nn.sigmoid(dense(x, lp["wg"]).astype(jnp.float32))
+    return o * gate.astype(o.dtype)
+
+
+def make_attn_fn(cfg: ModelConfig, b: int, s: int, positions, slots, table,
+                 context_lens, local: bool):
+    """``fn(a, layer_params, k_all, v_all, li) -> (o Wo, k_all, v_all)``
+    over the page stack of the layer's kind, ``slots`` and ``table``
+    that kind's."""
+    h, hd = cfg.num_heads, cfg.head_dim
+    kernel = "kv_window" if local else "kv_full"
+
+    def fn(x, lp, k_all, v_all, li):
+        q, k, v = qkv_prologue(cfg, x, lp, b, s, positions, context_lens,
+                               rope=local)
+        k_all, v_all = scatter_kv_stacked(k_all, v_all, k, v, slots, li)
+        with jax.named_scope(kernel):
+            o = attention(
+                q, k_all, v_all, table, positions, context_lens,
+                impl=cfg.attention_impl, layer_idx=li,
+                sliding_window=cfg.sliding_window if local else None)
+        o = _gated(o.reshape(b, s, h * hd), x, lp)
+        return dense(o, lp["wo"]), k_all, v_all
+
+    return fn
+
+
+def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
+                    slot_mapping, context_lens, mesh=None):
+    """(hidden [B, S, D], cache, int32 [2]: experts with a row and routed
+    rows summed over the expert layers), as mixtral.forward_counted.
+    ``block_tables`` is ``[B, 2 W]``: the full kind's table, then the
+    window kind's."""
+    del mesh    # one device: tp, ep, pp and sp are refused for the family
+    b, s = tokens.shape
+    w = block_tables.shape[1] // 2
+    tables = {False: block_tables[:, :w], True: block_tables[:, w:]}
+    block_size = kv_cache[0].full.shape[2]
+    slots = {False: slot_mapping,
+             True: window_slots(tables[True], positions, slot_mapping,
+                                block_size)}
+    with jax.named_scope("embed"):
+        hidden = params["embed"][tokens]
+        hidden = (hidden.astype(jnp.float32)
+                  * cfg.embedding_multiplier).astype(hidden.dtype)
+    k_side, v_side = kv_cache
+    pages = {False: (k_side.full, v_side.full),
+             True: (k_side.window, v_side.window)}
+    stats = jnp.zeros((2,), jnp.int32)
+    eps = cfg.rms_norm_eps
+
+    for (local, is_dense, start, _), run in zip(layer_runs(cfg),
+                                                params["runs"]):
+        attn_fn = make_attn_fn(cfg, b, s, positions, slots[local],
+                               tables[local], context_lens, local)
+        if is_dense:
+            scanned, mlp_fn = run, _swiglu_mlp
+        else:
+            scanned, stacks = split_expert_stacks(run)
+            mlp_fn = make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
+        scope = "attn_window" if local else "attn_full"
+
+        def layer(carry, lp, attn_fn=attn_fn, mlp_fn=mlp_fn, scope=scope):
+            hidden, k_all, v_all, li = carry
+            with jax.named_scope("attn"), jax.named_scope(scope):
+                delta, k_all, v_all = attn_fn(
+                    rms_norm(hidden, lp["ln1"], eps), lp, k_all, v_all, li)
+                hidden = hidden + rms_norm(delta, lp["ln1_post"], eps)
+            with jax.named_scope("mlp"):
+                out = mlp_fn(rms_norm(hidden, lp["ln2"], eps), lp)
+                y, aux = out if isinstance(out, tuple) else (out, None)
+                hidden = hidden + rms_norm(y, lp["ln2_post"], eps)
+            return (hidden, k_all, v_all, li + 1), aux
+
+        (hidden, k_all, v_all, _), aux = jax.lax.scan(
+            layer, (hidden, *pages[local], jnp.int32(start)), scanned)
+        pages[local] = (k_all, v_all)
+        if aux is not None:
+            stats = stats + aux.sum(axis=0)
+
+    cache = (KindCache(pages[False][0], pages[True][0]),
+             KindCache(pages[False][1], pages[True][1]))
+    return hidden, cache, stats
+
+
+def forward(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jax.Array,        # [B, S]
+    positions: jax.Array,     # [B, S]
+    kv_cache,                 # init_kv_cache's pair
+    block_tables: jax.Array,  # [B, 2 W]: full kind | window kind
+    slot_mapping: jax.Array,  # [B, S] the full kind's; −1: no token here
+    context_lens: jax.Array,  # [B]
+    mesh=None,
+    return_hidden: bool = False,
+):
+    hidden, cache, _ = forward_counted(
+        params, cfg, tokens, positions, kv_cache, block_tables,
+        slot_mapping, context_lens, mesh=mesh)
+    if return_hidden:
+        return hidden, cache
+    with jax.named_scope("lm_head"):
+        return lm_logits(hidden, params, cfg), cache
+
+
+logits_from_hidden = lm_logits

@@ -156,6 +156,18 @@ def _attn_params(cfg: ModelConfig, n_layers: int, key, w, dtype) -> Dict:
 EXPERT_SPREAD = 0.1
 
 
+def random_expert_stacks(key, shape, fan_in, dtype):
+    """[L, E, in, out]: a layer's experts are one prototype plus a spread
+    of their own (EXPERT_SPREAD), at a fan-in-scaled normal's variance
+    (shared with models/afmoe.py)."""
+    kp, ko = jax.random.split(key)
+    proto = jax.random.normal(kp, shape[:1] + (1,) + shape[2:], jnp.float32)
+    own = jax.random.normal(ko, shape, jnp.float32)
+    s = EXPERT_SPREAD
+    return (((1.0 - s * s) ** 0.5 * proto + s * own)
+            * (fan_in ** -0.5)).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     d_model = cfg.hidden_size
     inter = cfg.intermediate_size
@@ -168,14 +180,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         return (jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)).astype(dtype)
 
     def experts(key, shape, fan_in):
-        # [L, E, in, out]: a layer's experts are one prototype plus a
-        # spread of their own (EXPERT_SPREAD), same variance as w()
-        kp, ko = jax.random.split(key)
-        proto = jax.random.normal(kp, shape[:1] + (1,) + shape[2:], jnp.float32)
-        own = jax.random.normal(ko, shape, jnp.float32)
-        s = EXPERT_SPREAD
-        return (((1.0 - s * s) ** 0.5 * proto + s * own)
-                * (fan_in ** -0.5)).astype(dtype)
+        return random_expert_stacks(key, shape, fan_in, dtype)
 
     params: Params = {
         "embed": w(keys[0], (cfg.vocab_size, d_model), d_model),

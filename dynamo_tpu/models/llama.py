@@ -335,12 +335,14 @@ def gather_kv_writes(k, v, slot_mapping, axis):
     )
 
 
-def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis):
+def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis,
+                 rope: bool = True):
     """The per-layer QKV head: projections (+ Qwen2 biases), head
     reshape, Qwen3 per-head norms, RoPE. ONE implementation shared by
     the dense paged path, the sequence-parallel chunk path, and the
     cacheless embeddings trunk — the SP path's bit-identical-KV
-    contract depends on these never drifting."""
+    contract depends on these never drifting. ``rope=False``: a layer
+    with no positional term (models/afmoe.py's full-attention layers)."""
     h_heads, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = dense(x, layer_params["wq"])
     k = dense(x, layer_params["wk"])
@@ -364,6 +366,8 @@ def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis):
         k = rms_norm(k, layer_params["k_norm"], cfg.rms_norm_eps)
     if cfg.key_multiplier != 1.0:  # Falcon-H1's fixed µP scalar, pre-rope
         k = (k.astype(jnp.float32) * cfg.key_multiplier).astype(k.dtype)
+    if not rope:
+        return q, k, v
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
                    seq_basis=seq_basis)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling,

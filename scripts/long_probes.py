@@ -20,7 +20,10 @@ serves a deliberately wrong program against the unchanged reference,
 for the readings that say what the limits can tell apart: ``bf16_state``
 (MiniCPM-SALA's lightning state held in bfloat16), ``half_topk`` (half the picked
 blocks dropped: ``sparse_config.topk`` halved in the served model's
-``config.json`` only). Where the reference module has ``limits_for``, a
+``config.json`` only), ``bf16_router`` (the routed experts' router
+scores from a bfloat16 product of bfloat16 operands, as the activations
+arrive, where the stated program takes both to float32: Trinity-Mini,
+``trinity-longdoc`` at 9400 and 16 000 tokens, PR 40). Where the reference module has ``limits_for``, a
 probe is held to the pair it gives for the probe's context; otherwise to
 the module's one pair.
 """
@@ -41,7 +44,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 sys.path[:0] = [BENCH, ROOT]
 
 PROBE_TOKENS = 16
-FAULTS = ("bf16_state", "half_topk")
+FAULTS = ("bf16_state", "half_topk", "bf16_router")
 
 
 def serve_wrongly(fault: str, model_dir: str) -> None:
@@ -68,6 +71,21 @@ def serve_wrongly(fault: str, model_dir: str) -> None:
             return dataclasses.replace(k, state=k.state.astype(jnp.bfloat16)), v
 
         family.init_kv_cache = init_kv_cache
+    elif fault == "bf16_router":
+        import jax.numpy as jnp
+
+        from dynamo_tpu.models import mixtral
+
+        route = mixtral.route_top_k
+
+        def route_top_k(x, router_w, *args, **kwargs):
+            # rounded operands and a rounded product; the float32 the
+            # stated router then computes in changes neither
+            logits = jnp.dot(x.astype(jnp.bfloat16), router_w.astype(jnp.bfloat16))
+            eye = jnp.eye(router_w.shape[1], dtype=jnp.float32)
+            return route(logits.astype(jnp.float32), eye, *args, **kwargs)
+
+        mixtral.route_top_k = route_top_k
 
 
 async def amain(args) -> int:

@@ -464,6 +464,75 @@ def test_minicpm_sala_prefill_chunk_fits_beside_the_model(
     assert mem.temp_size_in_bytes < 3 * 2 ** 30
 
 
+# Trinity-Mini as one chip serves it (benchmark/configs/
+# trinity-mini-26b-a3b.json): eight layers of two kinds of attention,
+# each over its own stack of pages (two full layers over 18432 pages,
+# six window layers over the derived pool of 2193), two dense and six
+# expert feed-forwards of 128 experts of 1024, a table of 1152 pages a
+# kind
+def _trinity_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.models import afmoe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "trinity-mini-26b-a3b.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+    pool = EngineConfig(
+        model=cfg, **{k: serve[k] for k in (
+            "max_model_len", "max_batch_size", "num_kv_blocks",
+            "prefill_buckets", "max_prefill_tokens_per_step",
+            "max_prefill_batch")}).window_pool_pages()
+    assert pool == 1 + 16 * 129 + 128
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: afmoe.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: afmoe.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
+                                    jnp.bfloat16, window_blocks=pool)))
+    assert k_side.full.shape == (2, 18432, 16, 4, 128)
+    assert v_side.window.shape == (6, 2193, 16, 4, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, toks, positions, bt, slots, ctx):
+        return afmoe.forward_counted(params, cfg, toks, positions,
+                                     (k_side, v_side), bt, slots, ctx)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(rows, tokens), i32(rows, tokens),
+        i32(rows, 2 * width), i32(rows, tokens), i32(rows)).compile()
+
+
+@pytest.mark.parametrize("rows,tokens,width", [
+    (16, 1, 512), (16, 1, 1152), (1, 2048, 1152)])
+def test_trinity_step_keeps_both_page_stacks_in_place(
+        one_chip, no_compile_cache, monkeypatch, rows, tokens, width):
+    """A decode step of 16 rows and a 2048-token prefill chunk at the
+    benchmark's size on the routes the chip takes: the paged decode (or
+    flash) kernel once a kind of layer, the three grouped products of
+    the experts, and neither the full kind's pages (1.21 GB) nor the
+    window kind's (0.43 GB) copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _trinity_step(one_chip, rows, tokens, width)
+    text = compiled.as_text()
+    for scope in ("kv_window", "kv_full", "moe_experts"):
+        assert re.search(rf"tpu_custom_call[^\n]*{scope}", text), scope
+    mem = compiled.memory_analysis()
+    print(f"trinity step {rows}x{tokens}: arguments",
+          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
+    # weights 11.97 GB without the head's 0.82 (the trunk ends at the
+    # hidden state) + full pages 1.21 + window pages 0.43
+    assert 12.7e9 < mem.argument_size_in_bytes < 12.9e9
+    # a copy of either stack would be 0.4 GB or more
+    assert mem.temp_size_in_bytes < (64 if tokens == 1 else 384) * 2 ** 20
+
+
 # The layer loop and its weights (PR 39). A projection whose result is
 # reshaped to heads at once has the reshape folded into its dot; the
 # compiler then sees the weight as [heads, head_dim, D], which is a
