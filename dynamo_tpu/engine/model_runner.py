@@ -13,6 +13,7 @@ activations shards over "dp". Single-device collapses to a trivial mesh.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import threading
@@ -402,6 +403,10 @@ class ModelRunner:
         from ..ops import attention as _attn_ops
 
         self.compiles.dispatch_cm = _attn_ops.route_program
+        # the decode programs whose trace took a kernel with a list of
+        # live rows (``_track``): only for these does the scheduler
+        # count a pad row as skipped
+        self.row_list_programs: set = set()
         if (_attn_ops.ATTENTION_ROUTE_COUNTER.name
                 not in self.compiles.registry.names()):
             self.compiles.registry.register(
@@ -584,6 +589,19 @@ class ModelRunner:
         counters = [first] + [reg.counter(n, h) for n, h in rest]
 
     # ---------- the unified step program ----------
+
+    @contextlib.contextmanager
+    def _track(self, program: str, key: str, **stats):
+        """``compiles.track`` around one dispatch of a decode program,
+        keeping what its trace recorded beside the route: whether the
+        attention kernels were handed a list of live rows
+        (ops/attention.record_row_list)."""
+        from ..ops.attention import row_list_traced
+
+        with self.compiles.track(program, key, **stats) as first:
+            yield first
+            if first and row_list_traced():
+                self.row_list_programs.add(program)
 
     def _make_forward(self, counted: bool = False):
         """(trunk, head) closures both compiled programs trace: the trunk
@@ -1410,7 +1428,7 @@ class ModelRunner:
             counters=jnp.asarray(counters, jnp.int32),
         )
         b = tokens0.shape[0]
-        with self.compiles.track(
+        with self._track(
             "decode_burst", f"b{b}_w{block_tables.shape[1]}"
         ):
             (toks, lps, tvs, tis, k, v, counts, seen, bias) = self._burst(
@@ -1507,7 +1525,7 @@ class ModelRunner:
             stop_hlen = np.zeros((b, STOP_SEQ_WIDTH), np.int32)
         if gtable is None:
             gtable = self._dummy_guided_table()
-        with self.compiles.track(
+        with self._track(
             "decode_burst_df",
             f"b{b}_w{block_tables.shape[1]}_g{gtable.shape[0]}",
         ):
@@ -1599,7 +1617,7 @@ class ModelRunner:
             frequency_penalty=frequency_penalty,
             repetition_penalty=repetition_penalty,
         )
-        with self.compiles.track(
+        with self._track(
             "prefill" if s > 1 else "decode",
             f"b{b}_s{s}_w{width}", arrays=1,
         ):

@@ -880,6 +880,23 @@ class Scheduler:
             "frontend work the device's own step hides",
         )
 
+        self._decode_rows_ctr = reg.counter(
+            "dynamo_scheduler_decode_rows_total",
+            "Rows of the decode programs dispatched: max_batch_size a "
+            "step of a pass, whether or not the row held a sequence "
+            "(decode, decode_burst, decode_burst_df: the programs that "
+            "reach the decode kernels; a speculative burst verifies "
+            "several tokens a row on another route and is not counted)",
+        )
+        self._decode_rows_skipped_ctr = reg.counter(
+            "dynamo_scheduler_decode_rows_skipped_total",
+            "The part of dynamo_scheduler_decode_rows_total that held no "
+            "sequence and that the attention kernels did not walk: "
+            "counted only for a program whose trace took a decode kernel "
+            "with a list of live rows (0 on the XLA route, or for a "
+            "trunk that hands its kernels no mask)",
+        )
+
         self._fetch_ctr = reg.counter(
             "dynamo_scheduler_fetch_seconds_total",
             "A synchronous wait for a device result (_fetch), by part: "
@@ -2290,6 +2307,7 @@ class Scheduler:
                 stop_hash=hs.stop_hash, stop_hlen=hs.stop_hlen,
                 gtable=gtable_dev, want_top=want_top,
             )
+            self._count_decode_rows("decode_burst_df", len(live), k_steps)
             self._chain_carry = carry
             self._chain_dispatched += 1
             self.steps += 1
@@ -3114,6 +3132,7 @@ class Scheduler:
                     seed_keys=hs.keys, counters=ctrs, commit=commit,
                     want_top=er.logprobs_n > 0,
                 )
+                self._count_decode_rows("decode_burst", 1, k_steps)
                 self.steps += 1
                 self._sp_exposed_h.observe(t_burst - st.final_dispatch_t)
                 self.flight.record(
@@ -3673,6 +3692,20 @@ class Scheduler:
                     if er.finish is not None:
                         self._finish(er, er.finish, emit=False)
 
+    def _count_decode_rows(self, program: str, live: int,
+                           steps: int = 1) -> None:
+        """Count one dispatch of the decode program ``program`` (the
+        runner's name for it) over ``live`` rows that hold a sequence:
+        the batch's rows a step, and the pad rows among them where the
+        program's attention kernels walk a list of live rows and so
+        take no grid step for them (``ModelRunner.row_list_programs``,
+        recorded when the program was traced: call this after the
+        dispatch)."""
+        b = self.config.max_batch_size
+        self._decode_rows_ctr.inc(b * steps)
+        if program in getattr(self.runner, "row_list_programs", ()):
+            self._decode_rows_skipped_ctr.inc((b - live) * steps)
+
     async def _decode(self, loop, active: List[EngineRequest],
                       k_steps: int = 1) -> None:
         cfg = self.config
@@ -3818,6 +3851,9 @@ class Scheduler:
                         sample_slots=np.arange(b, dtype=np.int32),
                         commit=np.zeros(b, bool), want_top=False, **dkw,
                     )
+            self._count_decode_rows(
+                "decode_burst" if k_steps > 1 else "decode", len(active),
+                k_steps)
             self._inflight = True
 
         (toks, lpn, tv, ti), t_ready = await self._fetch(

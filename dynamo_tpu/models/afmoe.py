@@ -70,6 +70,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, lane_pad, scatter_kv_stacked
+from ..ops.live_rows import decode_live_rows
 from .deepseek import random_expert_stacks
 from .llama import (_swiglu_mlp, base_specs, lm_logits, qkv_prologue,
                     rms_norm)
@@ -255,10 +256,10 @@ def _gated(o, x, lp):
 
 
 def make_attn_fn(cfg: ModelConfig, b: int, s: int, positions, slots, table,
-                 context_lens, local: bool):
+                 context_lens, local: bool, live_rows):
     """``fn(a, layer_params, k_all, v_all, li) -> (o Wo, k_all, v_all)``
     over the page stack of the layer's kind, ``slots`` and ``table``
-    that kind's."""
+    that kind's; ``live_rows``: ``decode_live_rows`` of the step."""
     h, hd = cfg.num_heads, cfg.head_dim
     kernel = "kv_window" if local else "kv_full"
 
@@ -270,7 +271,8 @@ def make_attn_fn(cfg: ModelConfig, b: int, s: int, positions, slots, table,
             o = attention(
                 q, k_all, v_all, table, positions, context_lens,
                 impl=cfg.attention_impl, layer_idx=li,
-                sliding_window=cfg.sliding_window if local else None)
+                sliding_window=cfg.sliding_window if local else None,
+                live_rows=live_rows)
         o = _gated(o.reshape(b, s, h * hd), x, lp)
         return dense(o, lp["wo"]), k_all, v_all
 
@@ -300,11 +302,14 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
              True: (k_side.window, v_side.window)}
     stats = jnp.zeros((2,), jnp.int32)
     eps = cfg.rms_norm_eps
+    # the rows of a decode step that hold a token: one list for every
+    # run of layers and both kinds of page
+    live_rows = decode_live_rows(slot_mapping)
 
     for (local, is_dense, start, _), run in zip(layer_runs(cfg),
                                                 params["runs"]):
         attn_fn = make_attn_fn(cfg, b, s, positions, slots[local],
-                               tables[local], context_lens, local)
+                               tables[local], context_lens, local, live_rows)
         if is_dense:
             scanned, mlp_fn = run, _swiglu_mlp
         else:

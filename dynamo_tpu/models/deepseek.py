@@ -37,11 +37,14 @@ from jax.sharding import PartitionSpec as P
 from ..engine.config import ModelConfig
 from ..ops.attention import (
     _pad_minor,
+    batch_axis,
+    kernel_live_rows,
     lane_pad,
     pallas_interpret,
     record_route,
     resolve_attention_impl,
 )
+from ..ops.live_rows import decode_live_rows
 from .llama import (
     _swiglu_mlp,
     apply_rope,
@@ -305,7 +308,7 @@ def mla_paged_attention(
 @jax.named_scope("mla_cache")
 def mla_attention(
     q_lat, q_rope, c_all, kr_all, li, block_tables, positions, context_lens,
-    scale, impl="auto", mesh=None, interpret=False,
+    scale, impl="auto", mesh=None, interpret=False, live_rows=None,
 ):
     """MLA attention dispatch over the stacked compressed caches (the
     scope ``mla_cache``: the cache read, scores, softmax and PV, apart
@@ -319,7 +322,10 @@ def mla_attention(
     and not rows x the block table's width. Every other case (prefill,
     the CPU) gathers the layer's blocks through the table and runs the
     dense formulation. Query heads shard over "tp" under a multi-device
-    mesh; the latent caches are replicated (no head dim).
+    mesh; the latent caches are replicated (no head dim). ``live_rows``
+    (ops/live_rows.decode_live_rows, made once a step): the kernel walks
+    those rows alone and returns zeros in the others; the dense
+    formulation ignores it.
     """
     kernel = (q_lat.shape[1] == 1
               and resolve_attention_impl(impl) == "pallas")
@@ -335,16 +341,17 @@ def mla_attention(
         from ..ops.pallas_decode import mla_paged_decode_attention
 
         interpret = interpret or pallas_interpret()
+        dp = batch_axis(mesh, q_lat.shape[0])
+        live_rows = kernel_live_rows(live_rows, mesh, dp)
 
-        def fn(ql, qr, c, kr, bt, ctx, li):
+        def fn(ql, qr, c, kr, bt, ctx, li, live_rows):
             return mla_paged_decode_attention(
                 ql, qr, c, kr, bt, ctx, layer_idx=li, scale=scale,
-                interpret=interpret,
+                interpret=interpret, live_rows=live_rows,
             )
 
         li_arr = jnp.asarray(li, jnp.int32)
         if mesh is not None and mesh.size > 1:
-            dp = "dp" if q_lat.shape[0] % mesh.shape.get("dp", 1) == 0 else None
             fn = jax.shard_map(
                 fn,
                 mesh=mesh,
@@ -356,12 +363,13 @@ def mla_attention(
                     P(dp, None),               # block_tables
                     P(dp),                     # context_lens
                     P(),                       # layer idx
+                    P(),                       # live_rows (or None)
                 ),
                 out_specs=P(dp, None, "tp", None),
                 check_vma=False,
             )
         return fn(q_lat, q_rope, c_all, kr_all, block_tables,
-                  context_lens, li_arr)[..., :r]
+                  context_lens, li_arr, live_rows)[..., :r]
 
     # layer indexing through the gather (see ops/attention.attention):
     # block n of layer li is flat row li*N + n — no full-layer copy
@@ -412,6 +420,9 @@ def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     h = cfg.num_heads
     nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     scale = mla_softmax_scale(cfg)
+    # a decode step's rows that hold a token: the same for every layer,
+    # made once, outside the scan
+    live_rows = decode_live_rows(slot_mapping)
 
     def attn_fn(x, lp, c_all, kr_all, li):
         # queries (optionally through the q low-rank bottleneck);
@@ -447,6 +458,7 @@ def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
         o_lat = mla_attention(
             q_lat, q_rope, c_all, kr_all, li, block_tables, positions,
             context_lens, scale, impl=cfg.attention_impl, mesh=mesh,
+            live_rows=live_rows,
         )
         o = jnp.einsum("bshr,rhv->bshv", o_lat, lp["w_uv"])
         delta = dense(o.reshape(b, s, -1), lp["wo"])

@@ -55,7 +55,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
-from ..ops.ssm import live_row_list, ssd_chunked_scan, ssm_decode_step
+from ..ops.live_rows import decode_live_rows
+from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
 from . import llama
 from .llama import (ATTN_LAYER_SPECS, base_specs, lm_logits,
                     make_gqa_attn_fn, rms_norm)
@@ -297,10 +298,12 @@ def slot_records(b: int, decode: bool, live, state_slots, fresh):
 
 
 def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
-                state_slots):
+                state_slots, live_rows):
     """The mixer of one layer: ``ssm_fn(n1, layer_params, ssm_all,
     conv_all, li) -> (m, ssm_all, conv_all)`` over the stacked state
-    records, updated where they lie."""
+    records, updated where they lie. ``live_rows``: the step's
+    ``decode_live_rows(slot_mapping)``, the rows the decode kernel
+    walks."""
     nh, hp, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     g, kc, d_ssm = cfg.mamba_n_groups, cfg.mamba_d_conv, cfg.mamba_d_ssm
     parts = in_proj_parts(cfg)
@@ -310,8 +313,6 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
     n_valid = valid.sum(axis=1).astype(jnp.int32)   # [B]
     decode = s == 1
     live = valid[:, 0]
-    # the same for every layer: made once, outside the scan
-    row_list = live_row_list(live) if decode else None
     read, write = slot_records(b, decode, live, state_slots,
                                None if decode else positions[:, 0] == 0)
 
@@ -341,7 +342,7 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
             with jax.named_scope("ssm_state"):
                 y, ssm_all = ssm_decode_step(
                     xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], lp["D"],
-                    ssm_all, li, live, row_list)
+                    ssm_all, li, live_rows)
                 y = y[:, None]
         else:
             with jax.named_scope("ssm_scan"):
@@ -381,9 +382,14 @@ def forward(
         state_slots = jnp.arange(b, dtype=jnp.int32)
     with jax.named_scope("embed"):
         hidden = _scaled(params["embed"][tokens], cfg.embedding_multiplier)
+    # a decode step's rows that hold a token: one list for the mixer's
+    # and the attention's kernels and every layer, made outside the scan
+    live_rows = decode_live_rows(slot_mapping)
     attn_fn = make_gqa_attn_fn(
-        cfg, b, s, positions, slot_mapping, block_tables, context_lens, mesh)
-    ssm_fn = make_ssm_fn(cfg, b, s, positions, slot_mapping, state_slots)
+        cfg, b, s, positions, slot_mapping, block_tables, context_lens, mesh,
+        live_rows=live_rows)
+    ssm_fn = make_ssm_fn(cfg, b, s, positions, slot_mapping, state_slots,
+                         live_rows)
 
     def layer_step(carry, lp):
         hidden, k_all, v_all, li = carry

@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, scatter_kv_stacked
+from ..ops.live_rows import decode_live_rows
 from .llama import (  # noqa: F401  (shared cache layout)
     alternating_window,
     apply_rope,
@@ -129,6 +130,7 @@ def make_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     del tp_axis  # bias-free projections; the wo matmul is the partial
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     scale = (cfg.query_pre_attn_scalar or hd) ** -0.5
+    live_rows = decode_live_rows(slot_mapping)
 
     def attn_fn(x, lp, k_all, v_all, li):
         q = dense(x, lp["wq"]).reshape(b, s, h, hd)
@@ -148,7 +150,7 @@ def make_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
             q, k_all, v_all, block_tables, positions, context_lens,
             impl=cfg.attention_impl, mesh=mesh, layer_idx=li,
             scale=scale, softcap=cfg.attn_logit_softcap,
-            sliding_window=window,
+            sliding_window=window, live_rows=live_rows,
         )
         delta = dense(attn.reshape(b, s, h * hd), lp["wo"])
         return delta, k_all, v_all

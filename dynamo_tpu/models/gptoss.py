@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, scatter_kv_stacked
+from ..ops.live_rows import decode_live_rows
 from .llama import (  # noqa: F401  (shared cache layout + trunk pieces)
     alternating_window,
     apply_rope,
@@ -133,6 +134,7 @@ def make_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     weights), but the replicated output bias ``bo`` would be counted tp
     times, so it scales by 1/tp here."""
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    live_rows = decode_live_rows(slot_mapping)
 
     def attn_fn(x, lp, k_all, v_all, li):
         q = (dense(x, lp["wq"]) + lp["bq"]).reshape(b, s, h, hd)
@@ -151,6 +153,7 @@ def make_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
             q, k_all, v_all, block_tables, positions, context_lens,
             impl=cfg.attention_impl, mesh=mesh, layer_idx=li,
             sliding_window=window, sinks=lp["sinks"],
+            live_rows=live_rows,
         )
         bo = lp["bo"]
         if tp_axis is not None:

@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, lane_pad, scatter_kv_stacked
+from ..ops.live_rows import decode_live_rows
 from . import mhc
 from .quant import dense
 
@@ -377,7 +378,7 @@ def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis,
 
 def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
                      context_lens, mesh, kv_gather_axis=None,
-                     layer_offset=0, tp_axis=None):
+                     layer_offset=0, tp_axis=None, live_rows=None):
     """The standard attention block: QKV + RoPE, paged-KV scatter, GQA
     attention, output projection. Families with different attention (MLA,
     models/deepseek.py) plug their own via run_layers' attn_fn.
@@ -392,10 +393,18 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     ``layer_offset`` is part of the family attn-factory contract (the
     pipeline passes the stage's first GLOBAL layer index): this family
     has no per-layer-index semantics, so it is accepted and ignored —
-    Gemma-2's window alternation is the consumer."""
+    Gemma-2's window alternation is the consumer.
+
+    ``live_rows``: the step's ``decode_live_rows(slot_mapping)`` where
+    the trunk has made it for another kernel of the layer (Falcon-H1's
+    mixer); made here otherwise."""
     del layer_offset  # no global-layer-index semantics in this family
     del tp_axis  # qkv biases are tp-sharded; no replicated additive terms
     h_heads, hd = cfg.num_heads, cfg.head_dim
+    # a decode step's rows that hold a token: the same for every layer,
+    # made once, outside the scan
+    if live_rows is None:
+        live_rows = decode_live_rows(slot_mapping)
 
     def attn_fn(x, layer_params, k_all, v_all, li):
         q, k, v = qkv_prologue(cfg, x, layer_params, b, s, positions,
@@ -415,6 +424,7 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
             # mistral/phi3-style whole-model window (0 = full attention;
             # rides the XLA path — see ops/attention.py)
             sliding_window=cfg.sliding_window or None,
+            live_rows=live_rows,
         )
         delta = dense(attn.reshape(b, s, h_heads * hd), layer_params["wo"])
         return delta, k_all, v_all

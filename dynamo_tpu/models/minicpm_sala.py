@@ -61,7 +61,8 @@ from jax.sharding import PartitionSpec as P
 from ..engine.config import ModelConfig
 from ..ops import sparse_attention as sparse
 from ..ops.attention import lane_pad
-from ..ops.ssm import live_row_list, ssd_chunked_scan, ssm_decode_step
+from ..ops.live_rows import decode_live_rows
+from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
 from .falcon_h1 import (RECURRENT_REFUSALS, SlotCache,  # noqa: F401
                         _scaled, slot_records)
 from .llama import _swiglu_mlp, apply_rope, base_specs, lm_logits, rms_norm
@@ -245,14 +246,14 @@ def _gate(o, x, lp):
 
 
 def make_lightning_fn(cfg: ModelConfig, b: int, s: int, positions,
-                      slot_mapping, state_slots):
+                      slot_mapping, state_slots, live_rows):
     """``fn(n1, layer_params, state_all, li) -> (delta, state_all)`` over
-    the state records stacked over the lightning layers."""
+    the state records stacked over the lightning layers. ``live_rows``:
+    the step's ``decode_live_rows(slot_mapping)``."""
     h, hd = cfg.lightning_heads, cfg.lightning_head_dim
     valid = slot_mapping >= 0
     decode = s == 1
     live = valid[:, 0]
-    row_list = live_row_list(live) if decode else None
     read, write = slot_records(b, decode, live, state_slots,
                                None if decode else positions[:, 0] == 0)
     dt = jnp.broadcast_to(valid[..., None].astype(jnp.float32), (b, s, h))
@@ -272,7 +273,7 @@ def make_lightning_fn(cfg: ModelConfig, b: int, s: int, positions,
             with jax.named_scope("lightning_state"):
                 o, state_all = ssm_decode_step(
                     v[:, 0], dt[:, 0], a, k[:, 0], q[:, 0], no_skip,
-                    state_all, li, live, row_list)
+                    state_all, li, live_rows)
                 o = o[:, None]
         else:
             with jax.named_scope("lightning_scan"):
@@ -287,16 +288,20 @@ def make_lightning_fn(cfg: ModelConfig, b: int, s: int, positions,
 
 
 def make_sparse_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
-                   block_tables, context_lens):
+                   block_tables, context_lens, live_rows):
     """``fn(n1, layer_params, k_all, v_all, means_all, li) -> (delta,
     k_all, v_all, means_all, kept [B])`` over the pages and page means
-    stacked over the attention layers."""
+    stacked over the attention layers. ``live_rows``: the step's
+    ``decode_live_rows(slot_mapping)``."""
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     shape = sparse.sparse_shape(cfg, PAGE)
     scale = hd ** -0.5
     valid = slot_mapping >= 0
     first = positions[:, 0].astype(jnp.int32)
     last = first + valid.sum(axis=1).astype(jnp.int32)
+    # the (row, kv head) pairs of a decode step whose row holds a token:
+    # the same for every layer, made once, outside the scan
+    head_rows = sparse.head_live_rows(live_rows, kvh)
 
     def fn(x, lp, k_all, v_all, means_all, li):
         q = rms_norm(dense(x, lp["wq"]).reshape(b, s, h, hd), lp["q_norm"],
@@ -313,7 +318,8 @@ def make_sparse_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
         if s == 1:
             o, kept = sparse.decode_attention(
                 q, k_all, v_all, means_all, li, block_tables, context_lens,
-                shape, kvh, scale, impl=cfg.attention_impl)
+                shape, kvh, scale, impl=cfg.attention_impl,
+                live_rows=head_rows)
         else:
             o = sparse.prefill_attention(
                 q, k_all, v_all, means_all, li, block_tables, positions,
@@ -344,10 +350,13 @@ def forward(
         state_slots = jnp.arange(b, dtype=jnp.int32)
     with jax.named_scope("embed"):
         hidden = _scaled(params["embed"][tokens], cfg.scale_emb)
+    # a decode step's rows that hold a token: one list for both kinds
+    # of layer, made outside their scans
+    live_rows = decode_live_rows(slot_mapping)
     lightning_fn = make_lightning_fn(cfg, b, s, positions, slot_mapping,
-                                     state_slots)
+                                     state_slots, live_rows)
     sparse_fn = make_sparse_fn(cfg, b, s, positions, slot_mapping,
-                               block_tables, context_lens)
+                               block_tables, context_lens, live_rows)
     res = residual_scale(cfg)
     k_side, v_side = kv_cache
     k_pages, state = k_side.kv, k_side.state

@@ -50,6 +50,7 @@ ATTENTION_ROUTE_COUNTER = Counter(
 )
 
 _route_program = "unknown"
+_row_list_traced = False
 
 
 @contextlib.contextmanager
@@ -57,9 +58,10 @@ def route_program(name: str):
     """Label route records with the engine program being dispatched
     (installed as CompileTracker.dispatch_cm — active only while a
     tracked dispatch, and therefore its trace, is on the stack)."""
-    global _route_program
+    global _route_program, _row_list_traced
     prev = _route_program
     _route_program = name
+    _row_list_traced = False
     try:
         yield
     finally:
@@ -70,6 +72,22 @@ def record_route(route: str) -> None:
     """Stamp one route decision (called from the dispatch seams here
     and in parallel/sequence.py — trace-time Python, never traced)."""
     ATTENTION_ROUTE_COUNTER.inc(program=_route_program, route=route)
+
+
+def record_row_list() -> None:
+    """Stamp that the program being traced took a decode kernel with a
+    list of live rows: its pad rows are no grid steps. Trace-time, like
+    ``record_route``; the runner reads it off the dispatch that traced
+    (``row_list_traced``) and the scheduler counts skipped rows only for
+    such a program."""
+    global _row_list_traced
+    _row_list_traced = True
+
+
+def row_list_traced() -> bool:
+    """Whether a trace since the innermost ``route_program`` was entered
+    called ``record_row_list``."""
+    return _row_list_traced
 
 
 def lane_pad(d: int) -> int:
@@ -272,6 +290,29 @@ def mosaic_rejects(route: str, has_sinks: bool, kv_dtype,
             or (fp8 and kv_heads % 4 != 0 and route in ("decode", "verify")))
 
 
+def batch_axis(mesh, b: int) -> Optional[str]:
+    """The mesh axis a kernel route's shard_map splits its ``b`` rows
+    over: "dp" where ``b`` divides (the scheduler prefills with B=1,
+    which each dp group then computes redundantly; decode, where B =
+    max_batch_size, shards), else None."""
+    if mesh is None or b % mesh.shape.get("dp", 1) != 0:
+        return None
+    return "dp"
+
+
+def kernel_live_rows(live_rows, mesh, dp: Optional[str]):
+    """The step's live rows (ops/live_rows.LiveRows or None) as a decode
+    kernel route hands them to its kernel, a replicated operand of its
+    shard_map: as given, or None where the shard_map splits the rows
+    over more than one "dp" group (the list would have to be a shard:
+    every row is walked there). Records the fact for the program being
+    traced (``record_row_list``)."""
+    if live_rows is None or (dp is not None and mesh.shape[dp] > 1):
+        return None
+    record_row_list()
+    return live_rows
+
+
 def attention(
     q: jax.Array,            # [B, S, H, D]
     k_cache: jax.Array,      # [N_blocks, bs, KVH, D] or stacked [L, N, bs, KVH, D]
@@ -287,8 +328,14 @@ def attention(
     softcap: float = 0.0,           # Gemma-2 attention logit softcapping
     sliding_window=None,            # scalar window (int or traced); None = off
     sinks=None,                     # [H] attention-sink logits (GPT-OSS)
+    live_rows=None,                 # decode_live_rows of the step, or None
 ) -> jax.Array:
     """Paged-attention dispatch: XLA gather path or the Pallas kernels.
+
+    ``live_rows`` (ops/live_rows.decode_live_rows, made by the trunk
+    outside its layer scan): the decode kernel walks those rows alone
+    and returns zeros in the others; every other route ignores it and
+    computes every row.
 
     ``sinks`` (GPT-OSS): a per-head logit joining every softmax as a
     virtual key with no value — both Pallas kernels fold it into their
@@ -355,6 +402,7 @@ def attention(
         else jnp.asarray(sliding_window, jnp.int32).reshape(1)
     )
     sink_args = (sinks,) if has_sinks else ()
+    dp = batch_axis(mesh, q.shape[0])
     record_route(route)
     if route == "verify":
         fn = functools.partial(
@@ -375,13 +423,15 @@ def attention(
             paged_decode_attention, scale=scale, interpret=interpret,
             softcap=softcap,
         )
+        live_rows = kernel_live_rows(live_rows, mesh, dp)
         args = (q, k_cache, v_cache, block_tables, context_lens, li,
-                win) + sink_args
+                win, live_rows) + sink_args
 
         def call(q, k_cache, v_cache, block_tables, context_lens, li, win,
-                 *sk):
+                 live_rows, *sk):
             return fn(q, k_cache, v_cache, block_tables, context_lens, li,
-                      window=win, sinks=sk[0] if sk else None)
+                      window=win, sinks=sk[0] if sk else None,
+                      live_rows=live_rows)
     else:
         fn = functools.partial(
             paged_flash_attention, scale=scale, interpret=interpret,
@@ -397,10 +447,6 @@ def attention(
                       context_lens, li, window=win,
                       sinks=sk[0] if sk else None)
     if mesh is not None and mesh.size > 1:
-        # batch shards over dp only when divisible — the scheduler prefills
-        # with B=1, which each dp group then computes redundantly (decode,
-        # where B = max_batch_size, shards)
-        dp = "dp" if q.shape[0] % mesh.shape.get("dp", 1) == 0 else None
         in_specs = [
             P(dp, None, "tp", None),           # q [B, S, H, D]
             P(None, None, None, "tp", None),   # k_cache [L, N, bs, KVH, D]
@@ -410,6 +456,8 @@ def attention(
         if route != "decode":
             in_specs.append(P(dp))             # base_pos (flash + verify)
         in_specs.extend([P(dp), P(), P()])     # context_lens, layer_idx, win
+        if route == "decode":
+            in_specs.append(P())               # live_rows (or None)
         if has_sinks:
             in_specs.append(P("tp"))           # sinks follow the head shard
         call = jax.shard_map(

@@ -7,8 +7,15 @@ walk with a grid dimension sized to the block-table *capacity* W, so a
 sequence with 32 live pages still pays W=128 grid steps of machinery per
 layer (profiled at ~0.9 ms/layer on v5e for the 1B flagship — 40x the
 bandwidth bound). Here the page walk is a data-dependent ``fori_loop``
-bounded by ``ceil(context_len / page)`` inside a grid of just B steps:
-work is proportional to *live* context, not capacity.
+bounded by ``ceil(context_len / page)`` inside a grid over the rows that
+hold a token: work is proportional to *live* context, not capacity, and
+to the rows that decode, not to ``max_batch_size``. The decode and MLA
+kernels take the compacted list of live rows (ops/live_rows.py) as a
+prefetched operand and its length, a traced value, as the grid's bound:
+grid step ``i`` serves row ``rows[i]``, and a pad row of the batch (a
+whole chunk's copies, products and ``exp`` before) is no step at all.
+Its output is memory nobody wrote, which the wrappers return as zeros.
+A caller without a mask gets every row walked in order.
 
 Mechanics: the paged KV cache stays in HBM (``memory_space=ANY``); the
 kernel pulls pages VMEM-ward itself with double-buffered async copies
@@ -36,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .live_rows import LiveRows
+
 MASK_VALUE = -1e30
 
 
@@ -49,7 +58,26 @@ def _out_struct(shape, dtype, *arrays) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
+def _walked_rows(b: int, live_rows: Optional[LiveRows]):
+    """(rows [B] int32, n) a kernel's grid walks: the step's live rows,
+    or every row in order with ``n = b``, a Python int, for a caller
+    without a mask."""
+    if live_rows is None:
+        return jnp.arange(b, dtype=jnp.int32), b
+    return live_rows.rows, live_rows.n
+
+
+def _zero_unwalked(out: jax.Array, live_rows: Optional[LiveRows]) -> jax.Array:
+    """A row the grid never visited is memory nobody wrote: zeros there,
+    so that nothing unwritten reaches an MLP, a router or the sampler."""
+    if live_rows is None:
+        return out
+    live = live_rows.live
+    return jnp.where(live.reshape((-1,) + (1,) * (out.ndim - 1)), out, 0)
+
+
 def _decode_kernel(
+    rows_ref,  # scalar prefetch: the rows the grid walks [B]
     bt_ref,    # scalar prefetch: block tables [B, W] (SMEM)
     ctx_ref,   # scalar prefetch: context lens [B]
     li_ref,    # scalar prefetch: layer index [1]
@@ -64,7 +92,8 @@ def _decode_kernel(
     softcap: float,
     has_sinks: bool = False,
 ):
-    """One grid step = one batch row; a fori_loop walks only LIVE chunks.
+    """One grid step = one live batch row (``rows_ref`` names it); a
+    fori_loop walks only LIVE chunks.
 
     Compute is ONE pair of MXU dots per chunk for ALL kv heads: the chunk
     KV flattens to [chunk_t * KVH, D] and every q row scores against every
@@ -88,7 +117,7 @@ def _decode_kernel(
         sinks_ref, o_ref, k_buf, v_buf, sem = rest
     else:
         o_ref, k_buf, v_buf, sem = rest
-    b = pl.program_id(0)
+    b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     li = li_ref[0]
     npages = pl.cdiv(ctx, block_size)          # live pages (ctx >= 1 in decode)
@@ -191,6 +220,7 @@ def _decode_kernel(
 
 
 def _mla_decode_kernel(
+    rows_ref,  # scalar prefetch: the rows the grid walks [B]
     bt_ref,    # scalar prefetch: block tables [B, W]
     ctx_ref,   # scalar prefetch: context lens [B]
     li_ref,    # scalar prefetch: layer index [1]
@@ -217,7 +247,7 @@ def _mla_decode_kernel(
     (models/deepseek.init_kv_cache) — Mosaic cannot slice a page out of
     [page, 1, R], whose single head XLA pads to a sublane pair.
     """
-    b = pl.program_id(0)
+    b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     li = li_ref[0]
     npages = pl.cdiv(ctx, block_size)
@@ -313,6 +343,7 @@ def mla_paged_decode_attention(
     scale: float = 1.0,
     pages_per_chunk: int = 16,
     interpret: bool = False,
+    live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
     """DeepSeek MLA single-token attention over the compressed cache.
 
@@ -322,7 +353,8 @@ def mla_paged_decode_attention(
     measured best of 4 / 8 / 16 / 32 at Moonlight's widths on the v5e
     (nine layers, 64 rows, 17-26 k live keys: 1.26-1.44 ms against
     1.33-1.66 at 8; the gather route 3.0 / 9.0 / 17.7 ms at a table of
-    64 / 128 / 256 blocks; PERF.md, PR 26).
+    64 / 128 / 256 blocks; PERF.md, PR 26). ``live_rows``: as
+    ``paged_decode_attention``.
     """
     b, s, h, r = q_lat.shape
     assert s == 1, "decode kernel is specialized to one query token"
@@ -336,17 +368,21 @@ def mla_paged_decode_attention(
         else jnp.asarray(layer_idx, jnp.int32).reshape(1)
     )
     pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
+    rows, n = _walked_rows(b, live_rows)
+
+    def by_row(i, rows_ref, *_):
+        return rows_ref[i], 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b,),
+        num_scalar_prefetch=4,
+        grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec((1, h, rd), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, h, r), by_row),
+            pl.BlockSpec((1, h, rd), by_row),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, h, r), lambda i, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, r), by_row),
         scratch_shapes=[
             pltpu.VMEM(
                 (2, pages_per_chunk, block_size, r), c_cache.dtype
@@ -372,6 +408,7 @@ def mla_paged_decode_attention(
         ),
         interpret=interpret,
     )(
+        rows,
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         li,
@@ -380,7 +417,7 @@ def mla_paged_decode_attention(
         c_cache,
         kr_cache,
     )
-    return out.reshape(b, 1, h, r)
+    return _zero_unwalked(out.reshape(b, 1, h, r), live_rows)
 
 
 def _verify_kernel(
@@ -655,12 +692,17 @@ def paged_decode_attention(
     window=None,             # sliding window (int or traced scalar); None = off
     sinks=None,              # [H] per-head sink logits (GPT-OSS); None = off
     one_head: bool = False,  # the caches are [L, N, page, D]: a kv head a page
+    live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
     """Single-token paged attention; returns [B, 1, H, D].
 
     ``window`` may be traced (Gemma-2 alternates windowed/full layers
     inside its layer scan), so it rides as a scalar-prefetch operand; the
-    kernel starts its page walk at the window's first live chunk."""
+    kernel starts its page walk at the window's first live chunk.
+
+    ``live_rows`` (ops/live_rows.py: a trunk makes it once a step and
+    hands it to every layer): the grid walks those rows alone and every
+    other row comes back zero. Without it every row is walked."""
     b, s, h, d = q.shape
     assert s == 1, "decode kernel is specialized to one query token"
     if one_head:
@@ -694,8 +736,13 @@ def paged_decode_attention(
     qs = q.reshape(b, kvh, g, d)
     has_sinks = sinks is not None
 
+    rows, n = _walked_rows(b, live_rows)
+
+    def by_row(i, rows_ref, *_):
+        return rows_ref[i], 0, 0, 0
+
     in_specs = [
-        pl.BlockSpec((1, kvh, g, d), lambda i, *_: (i, 0, 0, 0)),
+        pl.BlockSpec((1, kvh, g, d), by_row),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -705,10 +752,10 @@ def paged_decode_attention(
         in_specs.append(pl.BlockSpec((1, kvh * g), lambda i, *_: (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b,),
+        num_scalar_prefetch=5,
+        grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, g, d), lambda i, *_: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, d), by_row),
         scratch_shapes=[
             pltpu.VMEM((2, pages_per_chunk) + page_shape, k_cache.dtype),
             pltpu.VMEM((2, pages_per_chunk) + page_shape, v_cache.dtype),
@@ -717,6 +764,7 @@ def paged_decode_attention(
     )
 
     operands = [
+        rows,
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         li,
@@ -746,4 +794,4 @@ def paged_decode_attention(
         ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, 1, h, d)
+    return _zero_unwalked(out.reshape(b, 1, h, d), live_rows)

@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from .attention import (_pad_minor, pallas_interpret, record_route,
-                        resolve_attention_impl)
+                        record_row_list, resolve_attention_impl)
+from .live_rows import LiveRows
 from .pallas_decode import paged_decode_attention
 
 # queries a tile of prefill's masked product: [KVH·G·tile, T] float32
@@ -191,17 +192,33 @@ def scatter_head_pages(k_all, v_all, k, v, slot_mapping, li):
     return put(k_all, k), put(v_all, v)
 
 
-def _walk_tables(q, k_all, v_all, li, tables, n_kept, scale: float, impl: str):
+def head_live_rows(live_rows, kvh: int):
+    """A step's live rows (ops/live_rows.LiveRows or None) as the (row,
+    kv head) pairs ``decode_attention`` walks: pair ``row · kvh + g`` is
+    live where its row is."""
+    if live_rows is None:
+        return None
+    live, rows, n = live_rows
+    pairs = rows[:, None] * kvh + jnp.arange(kvh, dtype=rows.dtype)
+    return LiveRows(jnp.repeat(live, kvh), pairs.reshape(-1), n * kvh)
+
+
+def _walk_tables(q, k_all, v_all, li, tables, n_kept, scale: float, impl: str,
+                 live_rows=None):
     """q [R, G, D], one kv head a row; tables [R, W] pages of layer
     ``li`` in the order they are walked; n_kept [R] tokens they hold ->
     [R, G, D]. The paged decode kernel on the chip (or in the
-    interpreter), a gather and a masked product in XLA elsewhere."""
+    interpreter: it walks ``live_rows`` alone, zeros in the others),
+    a gather and a masked product in XLA elsewhere."""
     r, g, d = q.shape
     if resolve_attention_impl(impl) == "pallas":
         record_route("decode")
+        if live_rows is not None:
+            record_row_list()
         return paged_decode_attention(
             q[:, None], k_all, v_all, tables, n_kept, layer_idx=li,
-            scale=scale, interpret=pallas_interpret(), one_head=True)[:, 0]
+            scale=scale, interpret=pallas_interpret(), one_head=True,
+            live_rows=live_rows)[:, 0]
     record_route("xla")
     idx = li * k_all.shape[1] + tables
     k = _flat_pages(k_all)[idx].reshape(r, -1, d).astype(q.dtype)
@@ -244,7 +261,8 @@ def _row_means(means_all, li, head_pages):
 
 def decode_attention(q, k_all, v_all, means_all, li, block_tables,
                      context_lens, shape: SparseShape, kvh: int, scale: float,
-                     impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+                     impl: str = "auto",
+                     live_rows=None) -> Tuple[jax.Array, jax.Array]:
     """One query a row. q [B, 1, H, D]; block_tables [B, W]; context_lens
     [B] (the query included) -> (out [B, 1, H, D] as wide as the cache's
     lanes, kept [B]: the tokens the row's first kv head attended to).
@@ -254,7 +272,8 @@ def decode_attention(q, k_all, v_all, means_all, li, block_tables,
     always kept, and it is the only page that can be part full), and
     the decode kernel walks that table as it walks any row's. A table no
     wider than ``dense_len`` holds no row that selects: its program has
-    no selection in it."""
+    no selection in it. ``live_rows``: the (row, kv head) pairs whose
+    row holds a token (``head_live_rows``), the pairs the kernel walks."""
     q = _pad_minor(q, k_all.shape[-1])   # the cache's lanes; pad lanes are zero
     b, _, h, d = q.shape
     w = block_tables.shape[1]
@@ -287,7 +306,8 @@ def decode_attention(q, k_all, v_all, means_all, li, block_tables,
     with jax.named_scope("sparse_attn"):
         out = _walk_tables(
             q.reshape(b * kvh, h // kvh, d), k_all, v_all, li,
-            pages.reshape(b * kvh, -1), n_kept.reshape(b * kvh), scale, impl)
+            pages.reshape(b * kvh, -1), n_kept.reshape(b * kvh), scale, impl,
+            live_rows)
     return out.reshape(b, 1, h, d), n_kept[:, 0]
 
 

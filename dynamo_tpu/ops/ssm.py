@@ -103,18 +103,6 @@ def _head_block(heads: int, heads_per_group: int, head_bytes: int) -> int:
                or (k % heads_per_group == 0 and heads % k == 0))
 
 
-def live_row_list(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """live [B] bool -> (rows [B] int32, n int32): the numbers of the rows
-    that hold a token, in order, in ``rows[:n]`` (``B - 1`` after them):
-    what ``ssm_decode_step``'s grid walks. The same for every layer of a
-    step, so the caller makes it once."""
-    b = live.shape[0]
-    seen = jnp.cumsum(live.astype(jnp.int32))                 # [B]
-    # the (i+1)-th live row is the first with seen > i
-    rows = (seen[None, :] <= jnp.arange(b)[:, None]).sum(axis=1)
-    return jnp.minimum(rows, b - 1).astype(jnp.int32), seen[-1]
-
-
 def _decode_kernel(layer_ref, rows_ref, xdt_ref, decay_ref, bc_ref, h_ref,
                    y_ref, o_ref, *, heads_per_group: int):
     """One block of heads of one live row: xdt [P, hb] (Δ·x, heads on
@@ -140,8 +128,7 @@ def ssm_decode_step(
     d: jax.Array,        # [H]
     records: jax.Array,  # [L, slots, H, P, N]; row i of the step is slot i
     layer: jax.Array,    # int32 scalar, traced
-    live: jax.Array,     # [B] bool: the rows that hold a token
-    row_list,            # live_row_list(live)
+    live_rows,           # ops/live_rows.LiveRows: the rows that hold a token
 ) -> Tuple[jax.Array, jax.Array]:
     """(y [B, H, P] float32, zero in a row without a token; the records
     with layer ``layer`` of the live rows advanced by one token).
@@ -164,7 +151,7 @@ def ssm_decode_step(
     f32 = jnp.float32
     hb = _head_block(heads, per_group, p * n_state * records.dtype.itemsize)
     nb, gb = heads // hb, max(1, hb // per_group)   # blocks; groups a block
-    rows, n = row_list
+    live, rows, n = live_rows
     x = x.astype(f32)
     xdt = (dt[:, :, None] * x).reshape(b, nb, hb, p).transpose(0, 1, 3, 2)
     decay = jnp.exp(dt * a).reshape(b, nb, 1, hb)

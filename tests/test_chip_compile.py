@@ -157,6 +157,7 @@ def test_decode_and_flash_kernels_compile_at_falcon_h1s_heads(
 def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
         one_chip, no_compile_cache, monkeypatch, state_dtype):
     from dynamo_tpu.ops import ssm
+    from dynamo_tpu.ops.live_rows import live_row_list
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -167,8 +168,8 @@ def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
     f32, act = jnp.float32, jnp.bfloat16
 
     def f(x, dt, a, bm, cm, d, records, li, live):
-        return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li, live,
-                                   ssm.live_row_list(live))
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li,
+                                   live_row_list(live))
 
     compiled = jax.jit(f, donate_argnums=(6,)).lower(
         s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
@@ -368,6 +369,7 @@ def test_xing4_decode_step_mixes_its_streams_in_a_few_operations(
 def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
         one_chip, no_compile_cache, monkeypatch):
     from dynamo_tpu.ops import ssm
+    from dynamo_tpu.ops.live_rows import live_row_list
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -379,8 +381,8 @@ def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
     assert ssm._head_block(h, 1, d * d * 4) == 32
 
     def f(x, dt, a, bm, cm, skip, records, li, live):
-        return ssm.ssm_decode_step(x, dt, a, bm, cm, skip, records, li, live,
-                                   ssm.live_row_list(live))
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, skip, records, li,
+                                   live_row_list(live))
 
     compiled = jax.jit(f, donate_argnums=(6,)).lower(
         s((slots, h, d), act), s((slots, h), f32), s((h,), f32),
@@ -545,14 +547,26 @@ def test_trinity_step_keeps_both_page_stacks_in_place(
 # MHA, 32 rows) and the real tp=4 program of Mistral-7B over the four
 # described chips (a shard's widths: wq [4096, 1024], wk / wv
 # [4096, 256], head 128, 64 rows).
+_DECODE_TRUNKS = {}
+
+
+def _decode_trunk(ll, topo, config):
+    """The decode trunk of a benchmark configuration compiled for the
+    described chips (the real tp mesh where ``serve`` asks for one), once
+    a run of this file: a quarter of a minute to a minute each."""
+    if config not in _DECODE_TRUNKS:
+        _DECODE_TRUNKS[config] = ll.lower_trunk(
+            os.path.join(ROOT, "benchmark", "configs", config + ".json"),
+            topo.devices).compile()
+    return _DECODE_TRUNKS[config]
+
+
 @pytest.mark.parametrize("config", ["phi3-mini-4k", "mistral-7b-v0.3-tp4"])
 def test_decode_step_reads_every_projection_weight_where_it_lies(
         topo, no_compile_cache, monkeypatch, config):
     ll = _layer_loop()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = ll.lower_trunk(
-        os.path.join(ROOT, "benchmark", "configs", config + ".json"),
-        topo.devices).compile()
+    compiled = _decode_trunk(ll, topo, config)
     text = compiled.as_text()
     bodies = ll.loop_bodies(text)
     assert len(bodies) == 1, list(bodies)          # the one scan over the layers
@@ -563,6 +577,39 @@ def test_decode_step_reads_every_projection_weight_where_it_lies(
     # of a projection weight's element count (>= 2**20)
     assert ll.staged_weights(text) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+# PR 41: the decode kernels' grid is the rows that hold a token. Mosaic
+# has to accept a grid whose first bound is a value of the step's (under
+# shard_map at tp=4 too), and the list has to be made once a step: the
+# kernel's custom call takes the bound (a scalar) and the list first, and
+# inside the layer loop both are elements of the loop's carried tuple,
+# not the result of an operation of the loop. Phi-3 on one chip, the
+# real tp=4 program of Mistral-7B, Moonlight's MLA kernel.
+@pytest.mark.parametrize("config,kernel", [
+    ("phi3-mini-4k", "paged_decode_attention"),
+    ("mistral-7b-v0.3-tp4", "paged_decode_attention"),
+    ("moonlight-16b-a3b", "mla_paged_decode_attention")])
+def test_decode_step_walks_a_row_list_made_outside_the_layer_loop(
+        topo, no_compile_cache, monkeypatch, config, kernel):
+    ll = _layer_loop()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _decode_trunk(ll, topo, config).as_text()
+    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
+        rows = json.load(f)["serve"]["max_batch_size"]
+    calls = 0
+    for lines in ll.loop_bodies(text).values():
+        made_here = {name: op for name, _, op, _, _ in ll.operations(lines)}
+        for line in lines:
+            if "custom-call(" not in line or f"%{kernel}." not in line.split(" = ")[0]:
+                continue
+            calls += 1
+            bound, row_list = re.search(
+                r"custom-call\(%([\w.\-]+), %([\w.\-]+),", line).groups()
+            assert f"operand_layout_constraints={{s32[], s32[{rows}]{{0}}, " in line
+            assert made_here[bound] == "get-tuple-element", (bound, made_here[bound])
+            assert made_here[row_list] == "get-tuple-element", (row_list, made_here[row_list])
+    assert calls >= 1
 
 
 # two lines of the Phi-3 decode loop as the parent of PR 39 compiled it,

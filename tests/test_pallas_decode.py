@@ -16,6 +16,7 @@ from dynamo_tpu.ops.attention import (
     paged_attention,
     scatter_kv_stacked,
 )
+from dynamo_tpu.ops.live_rows import live_row_list
 from dynamo_tpu.ops.pallas_decode import paged_decode_attention
 
 
@@ -608,3 +609,167 @@ def test_verify_padded_chunk_valid_rows_match_flash_contract():
         np.asarray(out)[:, :valid], np.asarray(ref)[:, :valid],
         rtol=2e-5, atol=2e-5,
     )
+
+
+# ---- the grid is the live rows, not the batch ----
+#
+# a pad row of the decode batch as Scheduler._decode fills it: context 1
+# and no slot. The kernels walk the compacted list of the other rows and
+# return zeros in the rows they never visited.
+
+# name: (live mask, heads, kv heads, head dim, lanes, layer, kernel kwargs)
+LIVE_ROW_CASES = {
+    "no_live_row": ([0, 0, 0, 0, 0, 0], 8, 4, 64, 64, 1, {}),
+    "every_row_live": ([1, 1, 1, 1, 1, 1], 8, 4, 64, 64, 1, {}),
+    "a_live_row_after_idle_ones": ([0, 0, 0, 1, 0, 1], 8, 4, 64, 64, 1, {}),
+    "mha_head_96_in_128_lanes": ([1, 0, 0, 1, 1, 0], 4, 4, 96, 128, 1, {}),
+    "gqa_32_over_8": ([0, 1, 1, 0, 0, 1], 32, 8, 64, 64, 1, {}),
+    "a_window": ([1, 0, 1, 1, 0, 0], 8, 4, 64, 64, 1, {"window": 20}),
+    "sinks": ([0, 1, 0, 1, 1, 0], 8, 4, 64, 64, 1, {"sinks": True}),
+    "one_head": ([1, 0, 0, 0, 1, 1], 4, 1, 64, 64, 1, {"one_head": True}),
+    "layer_0_of_3": ([0, 1, 1, 0, 1, 0], 8, 4, 64, 64, 0, {}),
+    "layer_2_of_3": ([1, 1, 0, 0, 0, 1], 8, 4, 64, 64, 2, {}),
+}
+
+
+@pytest.mark.parametrize("name", list(LIVE_ROW_CASES))
+def test_decode_walks_the_live_rows_and_zeros_the_others(name):
+    live, h, kvh, d, lanes, li, kw = LIVE_ROW_CASES[name]
+    kw = dict(kw)
+    rng = np.random.default_rng(21)
+    layers, b, bs, w = 3, len(live), 16, 8
+    q, k_cache, v_cache, bt = make_stacked_case(
+        rng, layers, b, h, kvh, lanes, bs, w)
+    if lanes != d:      # a head of 96 in 128 lanes: the pad lanes are zero
+        keep = jnp.arange(lanes) < d
+        q, k_cache, v_cache = q * keep, k_cache * keep, v_cache * keep
+    live = np.asarray(live, bool)
+    ctx = jnp.asarray(np.where(live, [1, 17, 64, 128, 38, 90], 1), jnp.int32)
+    sinks = (jnp.asarray(rng.standard_normal(h), jnp.float32)
+             if kw.pop("sinks", False) else None)
+    window = kw.pop("window", None)
+    ref = paged_attention(
+        q, k_cache[li], v_cache[li], bt, (ctx - 1)[:, None], ctx,
+        scale=d ** -0.5, sliding_window=window, sinks=sinks)
+    if kw.get("one_head"):
+        k_cache, v_cache = k_cache[:, :, :, 0], v_cache[:, :, :, 0]
+    call = dict(layer_idx=jnp.int32(li), scale=d ** -0.5, pages_per_chunk=2,
+                interpret=True, sinks=sinks,
+                window=None if window is None else jnp.int32(window), **kw)
+    out = np.asarray(paged_decode_attention(
+        q, k_cache, v_cache, bt, ctx,
+        live_rows=live_row_list(jnp.asarray(live)), **call))
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not out[~live].any()
+    if live.all():      # the same walk as a call without a mask
+        plain = paged_decode_attention(q, k_cache, v_cache, bt, ctx, **call)
+        np.testing.assert_array_equal(out, np.asarray(plain))
+
+
+@pytest.mark.parametrize("live,li", [
+    ([0, 0, 0, 0], 1), ([1, 1, 1, 1], 1), ([0, 0, 1, 0], 0), ([1, 0, 0, 1], 1)],
+    ids=["no_live_row", "every_row_live", "a_live_row_after_idle_ones",
+         "layer_1"])
+def test_mla_decode_walks_the_live_rows_and_zeros_the_others(live, li):
+    from dynamo_tpu.models.deepseek import mla_paged_attention
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    rng = np.random.default_rng(22)
+    layers, b, h, r, rd, bs, w = 2, 4, 8, 32, 16, 8, 8
+    n_blocks = b * w + 2
+    q_lat = jnp.asarray(rng.standard_normal((b, 1, h, r)), jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((b, 1, h, rd)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((layers, n_blocks, 1, bs, r)),
+                    jnp.float32)
+    kr = jnp.asarray(rng.standard_normal((layers, n_blocks, 1, bs, rd)),
+                     jnp.float32)
+    bt = jnp.asarray(rng.permutation(n_blocks)[: b * w].reshape(b, w),
+                     jnp.int32)
+    live = np.asarray(live, bool)
+    ctx = jnp.asarray(np.where(live, [1, 13, 40, 64], 1), jnp.int32)
+    ref = mla_paged_attention(q_lat, q_rope, c[li], kr[li], bt,
+                              (ctx - 1)[:, None], ctx, 0.25)
+    call = dict(layer_idx=jnp.int32(li), scale=0.25, pages_per_chunk=2,
+                interpret=True)
+    out = np.asarray(mla_paged_decode_attention(
+        q_lat, q_rope, c, kr, bt, ctx,
+        live_rows=live_row_list(jnp.asarray(live)), **call))
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not out[~live].any()
+    if live.all():
+        plain = mla_paged_decode_attention(q_lat, q_rope, c, kr, bt, ctx,
+                                           **call)
+        np.testing.assert_array_equal(out, np.asarray(plain))
+
+
+@pytest.mark.parametrize("dp,tp,walks", [(1, 4, True), (2, 4, False)],
+                         ids=["tp4", "dp2_tp4_walks_every_row"])
+def test_attention_dispatch_live_rows_on_mesh(dp, tp, walks):
+    """The list and its count enter the decode route's shard_map as
+    replicated operands; where "dp" splits the batch every row is walked
+    (the list would have to be a shard)."""
+    from dynamo_tpu.engine.model_runner import build_mesh
+    from dynamo_tpu.ops import attention as attn_ops
+    from dynamo_tpu.ops.live_rows import decode_live_rows
+
+    rng = np.random.default_rng(23)
+    layers, b, h, kvh, d, bs, w = 2, 4, 8, 4, 64, 16, 4
+    q, k_cache, v_cache, bt = make_stacked_case(rng, layers, b, h, kvh, d, bs, w)
+    slots = jnp.asarray([[-1], [7], [-1], [30]], jnp.int32)
+    live_rows = decode_live_rows(slots)
+    ctx = jnp.asarray([1, 30, 1, 5], jnp.int32)
+    positions = (ctx - 1)[:, None]
+    ref = np.asarray(paged_attention(q, k_cache[1], v_cache[1], bt, positions,
+                                     ctx))
+    with attn_ops.route_program("test"):
+        out = np.asarray(attention(
+            q, k_cache, v_cache, bt, positions, ctx, impl="pallas",
+            mesh=build_mesh(dp, tp), interpret=True, layer_idx=jnp.int32(1),
+            live_rows=live_rows))
+        assert attn_ops.row_list_traced() is walks
+    rows = np.asarray(live_rows.live)
+    np.testing.assert_allclose(out[rows], ref[rows], rtol=2e-5, atol=2e-5)
+    if walks:
+        assert not out[~rows].any()
+    else:
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every pallas_call in a jaxpr, nested ones included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids.extend(_pallas_grids(sub))
+    return grids
+
+
+def test_the_grids_first_bound_is_the_traced_count_of_live_rows():
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    rng = np.random.default_rng(24)
+    layers, b, h, kvh, d, bs, w = 2, 4, 8, 4, 64, 16, 4
+    q, k_cache, v_cache, bt = make_stacked_case(rng, layers, b, h, kvh, d, bs, w)
+    ctx = jnp.ones((b,), jnp.int32)
+
+    def gqa(live_rows):
+        return paged_decode_attention(q, k_cache, v_cache, bt, ctx,
+                                      interpret=True, live_rows=live_rows)
+
+    def mla(live_rows):
+        n_blocks = k_cache.shape[1]
+        return mla_paged_decode_attention(
+            q, q[..., :16], jnp.zeros((layers, n_blocks, 1, bs, d)),
+            jnp.zeros((layers, n_blocks, 1, bs, 16)), bt, ctx,
+            interpret=True, live_rows=live_rows)
+
+    for fn in (gqa, mla):
+        (bound,), = _pallas_grids(jax.make_jaxpr(
+            lambda live: fn(live_row_list(live)))(jnp.ones((b,), bool)).jaxpr)
+        assert not isinstance(bound, int), bound     # a value of the step's
+        (bound,), = _pallas_grids(jax.make_jaxpr(lambda: fn(None))().jaxpr)
+        assert bound == b                            # no mask: every row

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.ops import ssm
+from dynamo_tpu.ops.live_rows import live_row_list
 
 LAYERS = 3
 
@@ -32,8 +33,7 @@ def _inputs(h, p, n, g, slots, live, seed=0, dtype=jnp.float32):
 
 @jax.jit
 def _step(args, records, layer, live):
-    return ssm.ssm_decode_step(*args, records, layer, live,
-                               ssm.live_row_list(live))
+    return ssm.ssm_decode_step(*args, records, layer, live_row_list(live))
 
 
 def _oracle(args, records, layer, b):
@@ -127,7 +127,7 @@ def test_bfloat16_records_are_taken_as_found_and_computed_in_float32():
     ([1], [0], 1),
 ])
 def test_live_row_list_is_the_live_rows_in_order(live, rows, n):
-    got_rows, got_n = ssm.live_row_list(jnp.asarray(live, bool))
+    _, got_rows, got_n = live_row_list(jnp.asarray(live, bool))
     assert got_rows.dtype == jnp.int32 and got_n.dtype == jnp.int32
     assert int(got_n) == n and got_rows[:n].tolist() == rows
     # what follows them is a row's number too: an index map may read it
