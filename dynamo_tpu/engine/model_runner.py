@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import json
 import logging
 import threading
 import time
@@ -2217,4 +2218,11 @@ class ModelRunner:
         # gauge then holds compile (or cache load) plus first execution
         jax.block_until_ready((self.kv_cache, self.sample_state))
         self.startup_s["warmup"] = time.monotonic() - t_warm
+        # what the decode kernels' walk was sized to at this model's
+        # pages (ops/pallas_decode.chunk_pages: static a configuration)
+        from ..ops.pallas_decode import chunks_traced
+
+        chunks = chunks_traced()
+        if chunks:
+            logger.info("decode kernels' chunks: %s", json.dumps(chunks))
         self._startup_gauge.set(self.startup_s["warmup"], phase="warmup")

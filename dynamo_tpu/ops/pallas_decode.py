@@ -27,6 +27,19 @@ a runtime index (``layer_idx``) so the per-layer ``lax.scan`` over the
 transformer trunk needs no per-layer cache slicing (which XLA
 materializes as a copy of the whole layer).
 
+How a row is walked (``_walk``, the decode and MLA kernels): a chunk is
+sized by its bytes, not by a count of pages. ``chunk_pages`` derives the
+pages of a wide chunk at trace time from the page's bytes, the scores
+its columns add and the table's width (about 2 MB of K and V: 8 pages of
+Phi-3's 32 kv heads, 64 of Trinity-Mini's 4 or of the latent cache), and
+a row walks wide chunks while it has that many pages left, then chunks
+of the tail's 8 (16 for MLA), so a chat-length row never pays for a
+product wider than its keys. Only a row's own pages are copied: a chunk
+copies the pages of it the row owns (a loop of four-page turns, the same
+count on the start and on the wait), and what the last chunk leaves
+uncopied is cleared where it is a value. A page reaches VMEM as its
+[page * KVH, D] rows, so a chunk is the dots' operand as it lies.
+
 Reference analog: the decode-path paged-attention kernels of the GPU
 engines the reference delegates to (SURVEY.md §2.4); same role as
 vLLM's paged_attention_v2 CUDA kernel, reimagined for the TPU memory
@@ -76,6 +89,172 @@ def _zero_unwalked(out: jax.Array, live_rows: Optional[LiveRows]) -> jax.Array:
     return jnp.where(live.reshape((-1,) + (1,) * (out.ndim - 1)), out, 0)
 
 
+# A wide chunk holds about this much of the cache (K and V, or the latent
+# and its rope key): scripts/chunk_sweep.py on the v5e, PERF.md §5 "Since
+# PR 42". Under it a chunk's fixed costs weigh too much (a 262 KB chunk
+# of eight 16 KB pages reads at 42 % of 819 GB/s, 2 MB at 69 %); over
+# it nothing more is won and the slots crowd VMEM.
+CHUNK_BYTES = 2 << 20
+# ... and no more than this of float32 scores ([query rows, columns] of a
+# chunk: Phi-3's at eight pages), nor more pages than this (128 read
+# within 0.3 points of 64 at every small page and double the slots)
+SCORE_BYTES = 512 << 10
+MAX_CHUNK_PAGES = 64
+
+# (kernel, bytes a page, bytes of scores a page) -> (pages a wide chunk,
+# pages a tail chunk) of every decode kernel traced so far: what
+# ModelRunner.warmup logs
+_chunks_traced: dict = {}
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (max(n, 1).bit_length() - 1)
+
+
+def chunk_pages(page_bytes: int, score_bytes: int, tail_pages: int,
+                width: int) -> int:
+    """Pages a wide chunk of a row's walk holds, from what a wrapper sees
+    in its inputs: the bytes a page moves (``page_bytes``: every stream),
+    the float32 scores a page adds (``score_bytes``), the tail's chunk
+    and the block table's width. The largest power of two within
+    ``CHUNK_BYTES``, ``SCORE_BYTES``, ``MAX_CHUNK_PAGES`` and the table,
+    and never under the tail's: 8 at Phi-3's 262 KB a page (no wide
+    chunk: the walk is the tail's), 64 at Trinity-Mini's 32 KB, a tp=4
+    shard's 16 KB and the latent cache's 20 KB."""
+    pages = min(CHUNK_BYTES // page_bytes, SCORE_BYTES // score_bytes,
+                MAX_CHUNK_PAGES, width)
+    return max(_pow2_floor(pages), tail_pages)
+
+
+def _chunks(kernel: str, pinned: Optional[int], tail_pages: int,
+            page_bytes: int, score_bytes: int, width: int):
+    """(pages a wide chunk, pages a tail chunk) of one traced call.
+    ``pinned`` (a kernel's test, the sweep) makes every chunk that many
+    pages; nobody who serves sets it."""
+    tail = _pow2_floor(min(pinned or tail_pages, width))
+    wide = tail if pinned else chunk_pages(page_bytes, score_bytes, tail, width)
+    _chunks_traced[kernel, page_bytes, score_bytes] = (wide, tail)
+    return wide, tail
+
+
+def chunks_traced() -> list:
+    """[{kernel, page_bytes, wide_pages, tail_pages, wide_bytes}] of the
+    decode kernels traced in this process."""
+    return [
+        {"kernel": kernel, "page_bytes": page_bytes, "wide_pages": wide,
+         "tail_pages": tail, "wide_bytes": wide * page_bytes}
+        for (kernel, page_bytes, _), (wide, tail)
+        in sorted(_chunks_traced.items())
+    ]
+
+
+def _fold(carry, s_log, v):
+    """One chunk into the running softmax: ``carry`` (m, l [rows, 128]
+    lane-broadcast, acc [rows, D]), the chunk's masked scores [rows,
+    cols] in float32 and its values [cols, D]."""
+    m, l, acc = carry
+    m_new = jnp.maximum(m, jnp.max(s_log, -1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p_unn = jnp.exp(s_log - m_new[:, 0:1])                # [rows, cols]
+    l_new = alpha * l + jnp.sum(p_unn, -1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p_unn.astype(v.dtype), v,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                     # [rows, D]
+    return m_new, l_new, acc * alpha[:, 0:1] + pv
+
+
+# pages a turn of a chunk's copy loop, straight-line inside the turn: a
+# chunk's copies all unrolled (128 a site at 64 pages) took a second a
+# kernel to trace and lower, on every warm start, for every program
+_RUN = 4
+
+
+def _walk(b, bt_ref, sem, streams, *, first_page, npages, wide: int,
+          tail: int, attend, carry):
+    """A row's walk over its pages ``[first_page, npages)``: whole chunks
+    of ``wide`` pages while that many are left, then chunks of ``tail``
+    pages, two VMEM slots, chunk ``c + 1`` copied while ``c`` is computed.
+    ``streams``: (block -> its page in HBM, VMEM buffer [2, wide, ...],
+    whether the buffer is a value) a cache; ``attend(carry, slot,
+    first page, pages)`` folds one chunk into the running softmax.
+
+    Only the row's own pages are copied: chunk ``c`` holds ``pages(c)``
+    of them, ``wide``, ``tail`` or what is left of the row, the same
+    count on the start and on the wait so that a slot's semaphore counts
+    match. What the row's last chunk does not fill of its ``tail`` pages
+    it clears where the buffer is a value: those scores are masked, but
+    ``0 * NaN`` is NaN and VMEM holds whatever it held."""
+    n_wide = (npages - first_page) // wide if wide > tail else 0
+    tail0 = first_page + n_wide * wide
+    n_chunks = n_wide + pl.cdiv(npages - tail0, tail)
+
+    def first(c):       # chunk c's first page
+        return jnp.where(c < n_wide, first_page + c * wide,
+                         tail0 + (c - n_wide) * tail)
+
+    def pages(c):       # how many of the row's pages chunk c holds
+        return jnp.where(c < n_wide, wide,
+                         jnp.minimum(npages - first(c), tail))
+
+    def copies(c, do):
+        """``do`` (start or wait) every copy of the pages chunk c holds."""
+        slot, base, n = jax.lax.rem(c, 2), first(c), pages(c)
+
+        def one(i):
+            for src, buf, _ in streams:
+                do(pltpu.make_async_copy(
+                    src(bt_ref[b, base + i]), buf.at[slot, i], sem.at[slot]
+                ))
+
+        def run(r, _):
+            for j in range(_RUN):
+                one(r * _RUN + j)
+
+        jax.lax.fori_loop(0, n // _RUN, run, None)
+        jax.lax.fori_loop(n - n % _RUN, n, lambda i, _: one(i), None)
+        return slot, n
+
+    def start(c):
+        slot, n = copies(c, lambda cp: cp.start())
+
+        def clear(i, _):
+            for _, buf, is_value in streams:
+                if is_value:
+                    buf[slot, i] = jnp.zeros(buf.shape[2:], buf.dtype)
+
+        # (a wide chunk is whole: nothing to clear)
+        jax.lax.fori_loop(n, jnp.where(c < n_wide, n, tail), clear, None)
+
+    def wait(c, width: int):
+        if width == tail:
+            copies(c, lambda cp: cp.wait())
+            return
+        # a wide chunk: the slot's bytes are the sum of its pages', so one
+        # wait a stream awaits them all
+        slot = jax.lax.rem(c, 2)
+        for _, buf, _ in streams:
+            pltpu.make_async_copy(
+                buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+    def chunks(lo, hi, width: int, carry):
+        def body(c, carry):
+            @pl.when(c + 1 < n_chunks)
+            def _prefetch():
+                start(c + 1)
+
+            wait(c, width)
+            return attend(carry, jax.lax.rem(c, 2), first(c), width)
+
+        return jax.lax.fori_loop(lo, hi, body, carry)
+
+    start(0)
+    if wide > tail:
+        carry = chunks(0, n_wide, wide, carry)
+    return chunks(n_wide, n_chunks, tail, carry)
+
+
 def _decode_kernel(
     rows_ref,  # scalar prefetch: the rows the grid walks [B]
     bt_ref,    # scalar prefetch: block tables [B, W] (SMEM)
@@ -83,28 +262,34 @@ def _decode_kernel(
     li_ref,    # scalar prefetch: layer index [1]
     win_ref,   # scalar prefetch: sliding window [1] (>= ctx disables)
     q_ref,     # [1, KVH, G, D] VMEM block
-    k_hbm,     # [L, N, page, KVH, D] in HBM (ANY)
+    k_hbm,     # [L, N, page * KVH, D] in HBM (ANY): a page's (token, head) rows
     v_hbm,
     *rest,     # ([sinks_ref [1, rows] when has_sinks], o_ref, scratch...)
     scale: float,
     block_size: int,
-    pages_per_chunk: int,
+    wide_pages: int,
+    tail_pages: int,
     softcap: float,
     has_sinks: bool = False,
 ):
-    """One grid step = one live batch row (``rows_ref`` names it); a
-    fori_loop walks only LIVE chunks.
+    """One grid step = one live batch row (``rows_ref`` names it);
+    ``_walk`` copies and folds only its LIVE pages: wide chunks sized by
+    their bytes (``chunk_pages``) while the row has that many pages
+    left, chunks of ``tail_pages`` after, so a short row pays for no
+    wider product than it has keys.
 
-    Compute is ONE pair of MXU dots per chunk for ALL kv heads: the chunk
-    KV flattens to [chunk_t * KVH, D] and every q row scores against every
-    (token, head) column; a head-match mask (+ the validity mask) drives
+    Compute is ONE pair of MXU dots per chunk for ALL kv heads: a page
+    arrives as its [page * KVH, D] rows (the wrapper's view of the cache:
+    the same bytes, and a chunk is [chunk_t * KVH, D] with no relayout of
+    four-row or two-row tiles), every q row scores against every (token,
+    head) column; a head-match mask (+ the validity mask) drives
     cross-head scores to MASK_VALUE, so their softmax weight is exactly 0
     and the single probs @ V dot sums only same-head contributions. This
     trades KVH× redundant MXU flops (trivial at decode shapes) for not
     issuing KVH tiny [G, chunk] dots per chunk — decode attention is DMA
     bound; op-issue overhead was the previous kernel's limiter.
 
-    With a sliding window the walk starts at the first chunk holding a
+    With a sliding window the walk starts at the first page holding a
     visible key (the decode query sits at ctx-1, so only positions in
     [ctx - window, ctx) matter): windowed decode costs O(window) DMA,
     not O(context) — the gathered XLA path always pays full width.
@@ -121,62 +306,36 @@ def _decode_kernel(
     ctx = ctx_ref[b]
     li = li_ref[0]
     npages = pl.cdiv(ctx, block_size)          # live pages (ctx >= 1 in decode)
-    nchunks = pl.cdiv(npages, pages_per_chunk)
     # first key position the decode query (at ctx-1) can see
     win_start = jnp.maximum(ctx - win_ref[0], 0)
 
     _, kvh, g, d = q_ref.shape
     rows = kvh * g
-    chunk_t = pages_per_chunk * block_size
-    cols = chunk_t * kvh
-
-    def page_copy(chunk, slot, i, hbm, buf):
-        # pages past the live range duplicate the last live page — their
-        # key positions land >= ctx and the mask kills them.
-        p = jnp.minimum(chunk * pages_per_chunk + i, npages - 1)
-        return pltpu.make_async_copy(
-            hbm.at[li, bt_ref[b, p]], buf.at[slot, i], sem.at[slot]
-        )
-
-    def start(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, k_hbm, k_buf).start()
-            page_copy(chunk, slot, i, v_hbm, v_buf).start()
-
-    def wait(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, k_hbm, k_buf).wait()
-            page_copy(chunk, slot, i, v_hbm, v_buf).wait()
-
-    first_chunk = win_start // chunk_t         # 0 when the window is off
-    start(first_chunk, jax.lax.rem(first_chunk, 2))
     q = q_ref[0].reshape(rows, d)  # [KVH*G, D], rows ordered (head, group)
 
-    # column j of the flattened chunk is (token j // KVH, head j % KVH);
-    # row r serves head r // G — both masks are plain iota arithmetic
-    col_head = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kvh
-    row_head = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // g
-    head_match = col_head == row_head                    # loop-invariant
-    col_tok = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) // kvh
+    def columns(pages):
+        # column j of a flattened chunk is (token j // KVH, head j % KVH);
+        # row r serves head r // G — both masks are plain iota arithmetic
+        shape = (rows, pages * block_size * kvh)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        row_head = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
+        return col % kvh == row_head, col // kvh
 
-    def body(c, carry):
-        m, l, acc = carry                                 # [rows,128]x2, [rows,D]
-        slot = jax.lax.rem(c, 2)
+    # loop-invariant, one pair a chunk width
+    masks = {pages: columns(pages) for pages in {wide_pages, tail_pages}}
 
-        @pl.when(c + 1 < nchunks)
-        def _prefetch():
-            start(c + 1, jax.lax.rem(c + 1, 2))
-
-        wait(c, slot)
+    def attend(carry, slot, first_page, pages):
+        head_match, col_tok = masks[pages]
+        cols = pages * block_size * kvh
         # upcast from the cache storage dtype (fp8 serving stores e4m3;
         # the dots and the p·V product must run at the compute dtype)
-        k = k_buf[slot].reshape(cols, d).astype(q.dtype)  # [(tok, head), D]
-        v = v_buf[slot].reshape(cols, d).astype(q.dtype)
+        k = k_buf[slot, :pages].reshape(cols, d).astype(q.dtype)
+        v = v_buf[slot, :pages].reshape(cols, d).astype(q.dtype)
 
         # decode causality: the query is the newest token, so every key
         # with position < ctx is visible — a pure validity mask (plus the
         # window's lower bound; win_start == 0 when the window is off).
-        key_pos = c * chunk_t + col_tok
+        key_pos = first_page * block_size + col_tok
         mask = head_match & (key_pos < ctx) & (key_pos >= win_start)
 
         s_log = jax.lax.dot_general(
@@ -186,19 +345,7 @@ def _decode_kernel(
         ) * scale                                         # [rows, cols]
         if softcap:
             s_log = softcap * jnp.tanh(s_log / softcap)
-        s_log = jnp.where(mask, s_log, MASK_VALUE)
-
-        m_cur = jnp.max(s_log, -1, keepdims=True)         # [rows, 1]
-        m_new = jnp.maximum(m, m_cur)                     # [rows, 128]
-        alpha = jnp.exp(m - m_new)
-        p_unn = jnp.exp(s_log - m_new[:, 0:1])            # [rows, cols]
-        l_new = alpha * l + jnp.sum(p_unn, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_unn.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                 # [rows, D]
-        return m_new, l_new, acc * alpha[:, 0:1] + pv
+        return _fold(carry, jnp.where(mask, s_log, MASK_VALUE), v)
 
     # m/l ride as [rows, 128] lane-broadcast carries (the layout Mosaic
     # handles without sub-lane-width relayouts; same trick as the scratch
@@ -206,7 +353,14 @@ def _decode_kernel(
     m0 = jnp.full((rows, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((rows, 128), jnp.float32)
     acc0 = jnp.zeros((rows, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first_chunk, nchunks, body, (m0, l0, acc0))
+    m, l, acc = _walk(
+        b, bt_ref, sem,
+        [(lambda n: k_hbm.at[li, n], k_buf, False),
+         (lambda n: v_hbm.at[li, n], v_buf, True)],
+        first_page=win_start // block_size, npages=npages,
+        wide=wide_pages, tail=tail_pages, attend=attend,
+        carry=(m0, l0, acc0),
+    )
     l1 = l[:, 0:1]
     if has_sinks:
         # the sink is a virtual key with no value: denominator only.
@@ -235,63 +389,37 @@ def _mla_decode_kernel(
     *,
     scale: float,
     block_size: int,
-    pages_per_chunk: int,
+    wide_pages: int,
+    tail_pages: int,
 ):
     """MLA decode: score = q_lat·c + q_rope·k_rope, output = softmax·c.
 
-    Same double-buffered page pipeline as _decode_kernel, but the two key
-    components stream together and the value IS the latent (attention
-    weights re-read c) — so each page moves R+RD bytes once, not twice.
-    A page is [page, R]: whole (16, 128) tiles of the HBM layout, which
-    is why the latent cache keeps its one head in front of the page
+    The same walk as _decode_kernel (``_walk``: wide chunks, then the
+    tail's, only live pages copied), but the two key components stream
+    together and the value IS the latent (attention weights re-read c) —
+    so each page moves R+RD bytes once, not twice. A page is [page, R]:
+    whole (16, 128) tiles of the HBM layout, which is why the latent
+    cache keeps its one head in front of the page
     (models/deepseek.init_kv_cache) — Mosaic cannot slice a page out of
     [page, 1, R], whose single head XLA pads to a sublane pair.
     """
     b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     li = li_ref[0]
-    npages = pl.cdiv(ctx, block_size)
-    nchunks = pl.cdiv(npages, pages_per_chunk)
 
     _, h, r = ql_ref.shape
     rd = qr_ref.shape[-1]
-    chunk_t = pages_per_chunk * block_size
-
-    def page_copy(chunk, slot, i, hbm, buf):
-        p = jnp.minimum(chunk * pages_per_chunk + i, npages - 1)
-        return pltpu.make_async_copy(
-            hbm.at[li, bt_ref[b, p], 0], buf.at[slot, i], sem.at[slot]
-        )
-
-    def start(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, c_hbm, c_buf).start()
-            page_copy(chunk, slot, i, kr_hbm, kr_buf).start()
-
-    def wait(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, c_hbm, c_buf).wait()
-            page_copy(chunk, slot, i, kr_hbm, kr_buf).wait()
-
-    start(0, 0)
     ql = ql_ref[0]  # [H, R]
     qr = qr_ref[0]  # [H, RD]
 
-    def body(ch, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(ch, 2)
-
-        @pl.when(ch + 1 < nchunks)
-        def _prefetch():
-            start(ch + 1, jax.lax.rem(ch + 1, 2))
-
-        wait(ch, slot)
+    def attend(carry, slot, first_page, pages):
+        chunk_t = pages * block_size
         # upcast from the cache storage dtype (fp8 serving stores e4m3;
         # no-op for bf16) — the score dots need a uniform compute dtype
-        c = c_buf[slot].reshape(chunk_t, r).astype(ql.dtype)
-        kr = kr_buf[slot].reshape(chunk_t, rd).astype(ql.dtype)
+        c = c_buf[slot, :pages].reshape(chunk_t, r).astype(ql.dtype)
+        kr = kr_buf[slot, :pages].reshape(chunk_t, rd).astype(ql.dtype)
 
-        key_pos = ch * chunk_t + jax.lax.broadcasted_iota(
+        key_pos = first_page * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, chunk_t), 1
         )
         valid = key_pos < ctx
@@ -306,24 +434,21 @@ def _mla_decode_kernel(
                 preferred_element_type=jnp.float32,
             )
         ) * scale                                        # [H, chunk_t]
-        s_log = jnp.where(valid, s_log, MASK_VALUE)
-
-        m_new = jnp.maximum(m, jnp.max(s_log, -1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p_unn = jnp.exp(s_log - m_new[:, 0:1])
-        l_new = alpha * l + jnp.sum(p_unn, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_unn.astype(c.dtype), c,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # [H, R]
-        return m_new, l_new, acc * alpha[:, 0:1] + pv
+        # the value IS the latent: [H, R] out
+        return _fold(carry, jnp.where(valid, s_log, MASK_VALUE), c)
 
     # [H, 128] lane-broadcast running stats (see _decode_kernel)
     m0 = jnp.full((h, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((h, 128), jnp.float32)
     acc0 = jnp.zeros((h, r), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nchunks, body, (m0, l0, acc0))
+    m, l, acc = _walk(
+        b, bt_ref, sem,
+        [(lambda n: c_hbm.at[li, n, 0], c_buf, True),
+         (lambda n: kr_hbm.at[li, n, 0], kr_buf, False)],
+        first_page=0, npages=pl.cdiv(ctx, block_size),
+        wide=wide_pages, tail=tail_pages, attend=attend,
+        carry=(m0, l0, acc0),
+    )
     l1 = l[:, 0:1]
     l1 = jnp.where(l1 == 0.0, 1.0, l1)
     o_ref[0] = (acc / l1).astype(o_ref.dtype)
@@ -341,7 +466,7 @@ def mla_paged_decode_attention(
     context_lens: jax.Array, # [B] int32
     layer_idx: Optional[jax.Array] = None,
     scale: float = 1.0,
-    pages_per_chunk: int = 16,
+    pages_per_chunk: Optional[int] = None,  # tests pin it; None: chunk_pages
     interpret: bool = False,
     live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
@@ -349,12 +474,12 @@ def mla_paged_decode_attention(
 
     Returns the latent output [B, 1, H, R] (caller applies W_uv). Same
     role as models/deepseek.mla_paged_attention's decode case without the
-    per-layer gather: the layer is indexed inside HBM. 16 pages a chunk
-    measured best of 4 / 8 / 16 / 32 at Moonlight's widths on the v5e
-    (nine layers, 64 rows, 17-26 k live keys: 1.26-1.44 ms against
-    1.33-1.66 at 8; the gather route 3.0 / 9.0 / 17.7 ms at a table of
-    64 / 128 / 256 blocks; PERF.md, PR 26). ``live_rows``: as
-    ``paged_decode_attention``.
+    per-layer gather: the layer is indexed inside HBM. The tail's 16
+    pages a chunk measured best of 4 / 8 / 16 / 32 at Moonlight's widths
+    on the v5e when every chunk was that size (PERF.md, PR 26; the gather
+    route 3.0 / 9.0 / 17.7 ms at a table of 64 / 128 / 256 blocks); a
+    row with 64 pages left walks them as one chunk (``chunk_pages``;
+    PERF.md §5, PR 42). ``live_rows``: as ``paged_decode_attention``.
     """
     b, s, h, r = q_lat.shape
     assert s == 1, "decode kernel is specialized to one query token"
@@ -367,7 +492,10 @@ def mla_paged_decode_attention(
         if layer_idx is None
         else jnp.asarray(layer_idx, jnp.int32).reshape(1)
     )
-    pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
+    wide, tail = _chunks(
+        "mla_paged_decode_attention", pages_per_chunk, 16,
+        block_size * (r + rd) * c_cache.dtype.itemsize, h * block_size * 4,
+        block_tables.shape[1])
     rows, n = _walked_rows(b, live_rows)
 
     def by_row(i, rows_ref, *_):
@@ -384,12 +512,8 @@ def mla_paged_decode_attention(
         ],
         out_specs=pl.BlockSpec((1, h, r), by_row),
         scratch_shapes=[
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, r), c_cache.dtype
-            ),
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, rd), kr_cache.dtype
-            ),
+            pltpu.VMEM((2, wide, block_size, r), c_cache.dtype),
+            pltpu.VMEM((2, wide, block_size, rd), kr_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -399,7 +523,8 @@ def mla_paged_decode_attention(
             _mla_decode_kernel,
             scale=scale,
             block_size=block_size,
-            pages_per_chunk=pages_per_chunk,
+            wide_pages=wide,
+            tail_pages=tail,
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, h, r), q_lat.dtype, q_lat, c_cache),
@@ -503,7 +628,6 @@ def _verify_kernel(
     q_pos = base + row_s
 
     def body(c, carry):
-        m, l, acc = carry
         slot = jax.lax.rem(c, 2)
 
         @pl.when(c + 1 < nchunks)
@@ -527,19 +651,7 @@ def _verify_kernel(
         ) * scale
         if softcap:
             s_log = softcap * jnp.tanh(s_log / softcap)
-        s_log = jnp.where(mask, s_log, MASK_VALUE)
-
-        m_cur = jnp.max(s_log, -1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        alpha = jnp.exp(m - m_new)
-        p_unn = jnp.exp(s_log - m_new[:, 0:1])
-        l_new = alpha * l + jnp.sum(p_unn, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_unn.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc * alpha[:, 0:1] + pv
+        return _fold(carry, jnp.where(mask, s_log, MASK_VALUE), v)
 
     m0 = jnp.full((rows, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((rows, 128), jnp.float32)
@@ -686,7 +798,7 @@ def paged_decode_attention(
     context_lens: jax.Array, # [B] int32
     layer_idx: Optional[jax.Array] = None,  # scalar int32 into L (default 0)
     scale: Optional[float] = None,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: Optional[int] = None,  # tests pin it; None: chunk_pages
     interpret: bool = False,
     softcap: float = 0.0,    # Gemma-2: logits ← cap·tanh(logits/cap)
     window=None,             # sliding window (int or traced scalar); None = off
@@ -702,7 +814,11 @@ def paged_decode_attention(
 
     ``live_rows`` (ops/live_rows.py: a trunk makes it once a step and
     hands it to every layer): the grid walks those rows alone and every
-    other row comes back zero. Without it every row is walked."""
+    other row comes back zero. Without it every row is walked.
+
+    How a row is walked is derived here and nowhere else: a wide chunk's
+    pages from the bytes of a page (``chunk_pages``), eight pages a chunk
+    of the tail; a caller has nothing to set."""
     b, s, h, d = q.shape
     assert s == 1, "decode kernel is specialized to one query token"
     if one_head:
@@ -710,12 +826,19 @@ def paged_decode_attention(
         # (a unit axis there is a slice Mosaic's tiling refuses); every
         # query head of a row attends to it
         _, _, block_size, _ = k_cache.shape
-        kvh, page_shape = 1, (block_size, d)
+        kvh = 1
     else:
         if k_cache.ndim == 4:
             k_cache, v_cache = k_cache[None], v_cache[None]
         _, _, block_size, kvh, _ = k_cache.shape
-        page_shape = (block_size, kvh, d)
+    # a page as its (token, head) rows: the same bytes (XLA's tiles of
+    # [KVH, D] for KVH of 2 or 4 laid end to end are its tiles of eight
+    # rows, so the reshape is a bitcast), and a chunk in VMEM is
+    # [chunk_t * KVH, D] as the dots want it, where a buffer of
+    # [.., KVH, D] tiles had to be repacked row group by row group
+    page_shape = (block_size * kvh, d)
+    k_cache = k_cache.reshape(k_cache.shape[:2] + page_shape)
+    v_cache = v_cache.reshape(v_cache.shape[:2] + page_shape)
     g = h // kvh
     if scale is None:
         scale = d ** -0.5
@@ -729,9 +852,10 @@ def paged_decode_attention(
         if window is None
         else jnp.asarray(window, jnp.int32).reshape(1)
     )
-    # fewer in-flight copies than pages in a short context wastes nothing;
-    # more than the table width would index past it
-    pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
+    wide, tail = _chunks(
+        "paged_decode_attention", pages_per_chunk, 8,
+        2 * block_size * kvh * d * k_cache.dtype.itemsize,
+        h * block_size * kvh * 4, block_tables.shape[1])
 
     qs = q.reshape(b, kvh, g, d)
     has_sinks = sinks is not None
@@ -757,8 +881,8 @@ def paged_decode_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, kvh, g, d), by_row),
         scratch_shapes=[
-            pltpu.VMEM((2, pages_per_chunk) + page_shape, k_cache.dtype),
-            pltpu.VMEM((2, pages_per_chunk) + page_shape, v_cache.dtype),
+            pltpu.VMEM((2, wide) + page_shape, k_cache.dtype),
+            pltpu.VMEM((2, wide) + page_shape, v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -783,7 +907,8 @@ def paged_decode_attention(
             _decode_kernel,
             scale=scale,
             block_size=block_size,
-            pages_per_chunk=pages_per_chunk,
+            wide_pages=wide,
+            tail_pages=tail,
             softcap=softcap,
             has_sinks=has_sinks,
         ),

@@ -773,3 +773,210 @@ def test_the_grids_first_bound_is_the_traced_count_of_live_rows():
         assert not isinstance(bound, int), bound     # a value of the step's
         (bound,), = _pallas_grids(jax.make_jaxpr(lambda: fn(None))().jaxpr)
         assert bound == b                            # no mask: every row
+
+
+# ---- a chunk sized by its bytes, only a row's own pages copied ----
+#
+# the walk at the sizes ``chunk_pages`` derives at the shapes the
+# benchmark's cells serve (bf16 pages of 16 tokens), in the interpreter,
+# whose fresh scratch is NaN as VMEM may be: a page of a slot that was
+# neither copied nor cleared shows as NaN in the output
+
+# name: (q heads, kv heads, lanes, bytes an element, pages a wide chunk)
+CHUNK_SHAPES = {
+    "phi3": (32, 32, 128, 2, 8),
+    "trinity": (32, 4, 128, 2, 64),
+    "mistral_tp4_shard": (8, 2, 128, 2, 64),
+    "falcon_h1": (20, 4, 128, 2, 64),
+    "sala_pair": (16, 1, 128, 2, 64),
+    "phi3_fp8": (32, 32, 128, 1, 8),
+    "gqa_64_over_8": (64, 8, 128, 2, 16),
+}
+
+
+@pytest.mark.parametrize("name", list(CHUNK_SHAPES))
+def test_chunk_pages_follows_the_pages_bytes(name):
+    from dynamo_tpu.ops.pallas_decode import chunk_pages
+
+    h, kvh, d, itemsize, want = CHUNK_SHAPES[name]
+    page, scores = 2 * 16 * kvh * d * itemsize, h * 16 * kvh * 4
+    assert chunk_pages(page, scores, 8, 256) == want
+    # never wider than the table, never under the tail's chunk
+    assert chunk_pages(page, scores, 8, 24) == min(want, 16)
+    assert chunk_pages(page, scores, 4, 4) == 4
+
+
+def test_chunk_pages_of_the_latent_cache():
+    from dynamo_tpu.ops.pallas_decode import chunk_pages
+
+    # moonlight / xing4: 16 tokens of latent 512 + rope key 128, 16 heads
+    assert chunk_pages(16 * 640 * 2, 16 * 16 * 4, 16, 256) == 64
+    assert chunk_pages(16 * 640 * 2, 32 * 16 * 4, 16, 8) == 16
+
+
+def _chunk_case(rng, h, kvh, d, ctx, layers=2):
+    """bf16 caches as served, float32 queries (the kernel upcasts a page
+    to the query's dtype, so the reference over the same values in
+    float32 is held to float32's tolerance)."""
+    bs, b = 16, len(ctx)
+    w = -(-max(ctx) // bs) + 2
+    n_blocks = b * w + 2
+    q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((layers, n_blocks, bs, kvh, d)),
+                    jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((layers, n_blocks, bs, kvh, d)),
+                    jnp.bfloat16)
+    bt = jnp.asarray(rng.permutation(n_blocks)[: b * w].reshape(b, w),
+                     jnp.int32)
+    return q, k, v, bt, jnp.asarray(ctx, jnp.int32)
+
+
+# a context that ends on a chunk's first page, on its last page and one
+# page past it (the wide chunk's 64 pages or the tail's 8), and a short one
+WIDE_CTX = [64 * 16 + 5, 2 * 64 * 16 - 3, 2 * 64 * 16 + 1, 300]
+TAIL_CTX = [8 * 16 + 5, 2 * 8 * 16 - 3, 2 * 8 * 16 + 1, 40]
+# name: (shape, contexts, reference kwargs)
+WALK_CASES = {
+    "phi3": ("phi3", TAIL_CTX, {}),
+    "phi3_window_from_mid_chunk": ("phi3", TAIL_CTX, {"sliding_window": 100}),
+    "trinity": ("trinity", WIDE_CTX, {}),
+    # 2048 keys from page 34 of the third row: a wide chunk, then the tail
+    "trinity_window_from_mid_chunk": (
+        "trinity", [2600, 2049, 2048 + 64 * 16 + 7, 300],
+        {"sliding_window": 2048}),
+    "trinity_sinks": ("trinity", WIDE_CTX, {"sinks": True}),
+    "trinity_softcap": ("trinity", WIDE_CTX, {"softcap": 30.0}),
+    "mistral_tp4_shard": ("mistral_tp4_shard", WIDE_CTX, {}),
+    "falcon_h1": ("falcon_h1", WIDE_CTX, {}),
+}
+
+
+@pytest.mark.parametrize("name", list(WALK_CASES))
+def test_decode_at_the_derived_chunk_matches_xla_reference(name):
+    from dynamo_tpu.ops.pallas_decode import chunks_traced
+
+    shape, ctx, kw = WALK_CASES[name]
+    h, kvh, d, _, wide = CHUNK_SHAPES[shape]
+    rng = np.random.default_rng(42)
+    q, k, v, bt, ctx = _chunk_case(rng, h, kvh, d, ctx)
+    kw = dict(kw)
+    sinks = (jnp.asarray(rng.standard_normal(h), jnp.float32)
+             if kw.pop("sinks", False) else None)
+    ref = paged_attention(
+        q, k[1].astype(jnp.float32), v[1].astype(jnp.float32), bt,
+        (ctx - 1)[:, None], ctx, sinks=sinks, **kw)
+    window = kw.get("sliding_window")
+    out = paged_decode_attention(
+        q, k, v, bt, ctx, layer_idx=jnp.int32(1), interpret=True,
+        sinks=sinks, softcap=kw.get("softcap", 0.0),
+        window=None if window is None else jnp.int32(window))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert {"kernel": "paged_decode_attention",
+            "page_bytes": 2 * 16 * kvh * d * 2, "wide_pages": wide,
+            "tail_pages": 8,
+            "wide_bytes": wide * 2 * 16 * kvh * d * 2} in chunks_traced()
+
+
+def _mla_chunk_case(rng, ctx, h=16, r=512, rd=128, layers=2):
+    bs, b = 16, len(ctx)
+    w = -(-max(ctx) // bs) + 2
+    n_blocks = b * w + 2
+    ql = jnp.asarray(rng.standard_normal((b, 1, h, r)), jnp.float32)
+    qr = jnp.asarray(rng.standard_normal((b, 1, h, rd)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((layers, n_blocks, 1, bs, r)),
+                    jnp.bfloat16)
+    kr = jnp.asarray(rng.standard_normal((layers, n_blocks, 1, bs, rd)),
+                     jnp.bfloat16)
+    bt = jnp.asarray(rng.permutation(n_blocks)[: b * w].reshape(b, w),
+                     jnp.int32)
+    return ql, qr, c, kr, bt, jnp.asarray(ctx, jnp.int32)
+
+
+@pytest.mark.parametrize("ctx", [WIDE_CTX, [16 * 16 + 5, 2 * 16 * 16 - 3,
+                                            2 * 16 * 16 + 1, 40]],
+                         ids=["wide_chunks", "the_tail_alone"])
+def test_mla_decode_at_moonlights_chunk_matches_xla_reference(ctx):
+    from dynamo_tpu.models.deepseek import mla_paged_attention
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    ql, qr, c, kr, bt, ctx = _mla_chunk_case(np.random.default_rng(43), ctx)
+    scale = 192 ** -0.5
+    ref = mla_paged_attention(
+        ql, qr, c[1].astype(jnp.float32), kr[1].astype(jnp.float32), bt,
+        (ctx - 1)[:, None], ctx, scale)
+    out = mla_paged_decode_attention(
+        ql, qr, c, kr, bt, ctx, layer_idx=jnp.int32(1), scale=scale,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def _poison_unused(cache, bt, ctx, value, bs=16):
+    """``value`` in every page of every layer that no row's live range
+    names, and every table entry past a row's last live page pointed at
+    such a page: a walk that copied it would read it."""
+    bt, ctx = np.asarray(bt), np.asarray(ctx)
+    live_pages = -(-ctx // bs)
+    used = np.zeros(cache.shape[1], bool)
+    for row, n in zip(bt, live_pages):
+        used[row[:n]] = True
+    unused = np.flatnonzero(~used)
+    poisoned = jnp.where(
+        jnp.asarray(used).reshape((1, -1) + (1,) * (cache.ndim - 2)),
+        cache, jnp.asarray(value, cache.dtype))
+    past = np.arange(bt.shape[1])[None, :] >= live_pages[:, None]
+    return poisoned, jnp.asarray(np.where(past, unused[0], bt), jnp.int32)
+
+
+@pytest.mark.parametrize("value", [float("nan"), float("inf")],
+                         ids=["nan", "inf"])
+@pytest.mark.parametrize("shape", ["phi3", "trinity"])
+def test_decode_never_reads_a_page_the_row_does_not_own(shape, value):
+    """The value cache's unused pages hold NaN or Inf, the key cache's
+    NaN, and the table past a row's last page names one of them: the
+    output is finite and the one of the clean cache, bit for bit."""
+    h, kvh, d, _, _ = CHUNK_SHAPES[shape]
+    ctx = TAIL_CTX if shape == "phi3" else WIDE_CTX
+    q, k, v, bt, ctx = _chunk_case(np.random.default_rng(44), h, kvh, d, ctx)
+    call = dict(layer_idx=jnp.int32(0), interpret=True)
+    clean = np.asarray(paged_decode_attention(q, k, v, bt, ctx, **call))
+    k_bad, _ = _poison_unused(k, bt, ctx, float("nan"))
+    v_bad, bt_bad = _poison_unused(v, bt, ctx, value)
+    out = np.asarray(paged_decode_attention(q, k_bad, v_bad, bt_bad, ctx,
+                                            **call))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+
+
+@pytest.mark.parametrize("value", [float("nan"), float("inf")],
+                         ids=["nan", "inf"])
+def test_mla_decode_never_reads_a_page_the_row_does_not_own(value):
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    ql, qr, c, kr, bt, ctx = _mla_chunk_case(np.random.default_rng(45),
+                                             WIDE_CTX)
+    call = dict(layer_idx=jnp.int32(0), scale=192 ** -0.5, interpret=True)
+    clean = np.asarray(mla_paged_decode_attention(ql, qr, c, kr, bt, ctx,
+                                                  **call))
+    c_bad, bt_bad = _poison_unused(c, bt, ctx, value)
+    kr_bad, _ = _poison_unused(kr, bt, ctx, float("nan"))
+    out = np.asarray(mla_paged_decode_attention(ql, qr, c_bad, kr_bad,
+                                                bt_bad, ctx, **call))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+
+
+def test_decode_table_width_that_is_no_power_of_two():
+    """A table of 6 pages: the tail's chunk is the power of two under it,
+    and a row of 5 or 6 pages is a whole chunk and a part of one."""
+    rng = np.random.default_rng(46)
+    layers, b, h, kvh, d, bs, w = 2, 4, 8, 4, 64, 16, 6
+    q, k_cache, v_cache, bt = make_stacked_case(rng, layers, b, h, kvh, d, bs, w)
+    ctx = jnp.asarray([96, 65, 64, 3], jnp.int32)
+    ref = paged_attention(q, k_cache[1], v_cache[1], bt, (ctx - 1)[:, None],
+                          ctx)
+    out = paged_decode_attention(q, k_cache, v_cache, bt, ctx,
+                                 layer_idx=jnp.int32(1), interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
