@@ -113,11 +113,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-guided-device-table", action="store_true",
                    help="disable guided device tables: guided rows keep "
                         "the per-token host mask path")
-    p.add_argument("--no-device-stop-strings", action="store_true",
-                   help="disable the chain's device-approximate stop-"
-                        "string detection (suffix-hash over the carry's "
-                        "token ring): stop-string rows keep the per-"
-                        "burst sync pipeline")
     p.add_argument("--disagg-stream-depth", type=int, default=2,
                    help="streamed remote prefill: KV transfer frames in "
                         "flight on the prefill worker (2 double-buffers "

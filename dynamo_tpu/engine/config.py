@@ -187,19 +187,6 @@ class ModelConfig:
                 "MoE checkpoints with mlp_only_layers/decoder_sparse_step "
                 "(mixed dense+sparse trunks) are not supported"
             )
-        lt = config.get("layer_types")
-        if "gptoss" in arch and lt:
-            want = [
-                "sliding_attention" if i % 2 == 0 else "full_attention"
-                for i in range(len(lt))
-            ]
-            if list(lt) != want:
-                # the family module hardcodes the even-sliding alternation
-                # (models/gptoss.py window = li % 2 == 0)
-                raise NotImplementedError(
-                    "gpt-oss layer_types must alternate "
-                    "sliding/full starting sliding at layer 0"
-                )
         if config.get("shared_expert_intermediate_size"):
             # Qwen2-MoE's sigmoid-gated shared expert — reject at config
             # parse, BEFORE any multi-GB checkpoint stream starts (the
@@ -208,56 +195,12 @@ class ModelConfig:
                 "Qwen2-MoE checkpoints (gated shared expert) are not "
                 "supported; Qwen3-MoE and Mixtral load"
             )
-        falcon_h1 = config.get("model_type") == "falcon_h1"
-        sala = config.get("model_type") == "minicpm_sala"
-        recurrent_keys = sorted(
-            k for k in config
-            if k in RECURRENT_CONFIG_KEYS
-            or (not sala and (k.startswith(("mamba_", "ssm_", "lightning_"))
-                              or k == "mixer_types")))
-        if recurrent_keys and not falcon_h1:
-            # a trunk with recurrent layers this program has no family
-            # for would fall through to llama and serve nonsense
-            raise NotImplementedError(
-                f"model_type {config.get('model_type')!r} carries recurrent-"
-                f"layer keys ({', '.join(recurrent_keys[:4])}, ...) and no "
-                "family here implements it (falcon_h1 is the state-space "
-                "family, models/falcon_h1.py; minicpm_sala the linear-"
-                "attention one, models/minicpm_sala.py)"
-            )
-        mamba = (_falcon_h1_fields(config) if falcon_h1
-                 else _minicpm_sala_fields(config) if sala else {})
-        xing4 = config.get("model_type") == "xing4_0"
-        hc_keys = sorted(k for k in config if k.startswith(HC_KEY_PREFIXES))
-        if hc_keys and not xing4:
-            # a changed residual path this program has no family for
-            # would be served with a plain one, and wrong tokens
-            raise NotImplementedError(
-                f"model_type {config.get('model_type')!r} carries hyper-"
-                f"connection keys ({', '.join(hc_keys[:4])}) and no family "
-                "here implements its residual path (xing4_0 is the one "
-                "family with mixed residual streams: models/mhc.py)"
-            )
-        hc = _xing4_fields(config) if xing4 else {}
-        afmoe = config.get("model_type") == "afmoe"
-        kinds = sorted(set(lt or ()))
-        afmoe_keys = [k for k in AFMOE_CONFIG_KEYS if config.get(k)]
-        if len(kinds) > 1 and not ("gptoss" in arch or "gemma" in arch):
-            afmoe_keys.insert(0, "layer_types")
-        if afmoe_keys and not afmoe:
-            # a trunk whose layers differ in kind, with dense layers
-            # before its experts, a shared expert or a scaled embedding,
-            # would be served by mixtral.py or llama.py without them
-            raise NotImplementedError(
-                f"model_type {config.get('model_type')!r} carries "
-                f"{', '.join(afmoe_keys)} and no family here implements "
-                "them under that model_type (afmoe is the family with "
-                "window and full layers by layer_types, num_dense_layers, "
-                "num_shared_experts and mup_enabled: models/afmoe.py)"
-            )
-        # the family's own names for fields the call below reads under
-        # DeepSeek's (laid over its result)
-        family = _afmoe_fields(config) if afmoe else {}
+        # the family the config names, and its own translation of its
+        # published keys, laid over the common ones below (imported here:
+        # the family modules import jax, and this module must not)
+        from ..models import published
+
+        model_family, fields = published(config)
         n_group = config.get("n_group", 1) or 1
         topk_group = config.get("topk_group", 1) or 1
         if config.get("topk_method") == "greedy":
@@ -266,7 +209,8 @@ class ModelConfig:
             n_group = topk_group = 1
         n_experts = (config.get("num_local_experts", 0)
                      or config.get("n_routed_experts", 0)
-                     or config.get("num_experts", 0) or 0)
+                     or config.get("num_experts", 0)  # Qwen-MoE config key
+                     or 0)
         if n_group > 1:
             # the group-limited restriction only composes when the
             # expert set tiles evenly into groups and the selection can
@@ -305,10 +249,7 @@ class ModelConfig:
             rms_norm_eps=config.get("rms_norm_eps", 1e-5),
             max_position_embeddings=config.get("max_position_embeddings", 4096),
             tie_word_embeddings=config.get("tie_word_embeddings", False),
-            num_experts=config.get("num_local_experts", 0)
-            or config.get("n_routed_experts", 0)
-            or config.get("num_experts", 0)  # Qwen-MoE config key
-            or 0,
+            num_experts=n_experts,
             num_experts_per_tok=config.get("num_experts_per_tok", 2),
             moe_intermediate_size=config.get("moe_intermediate_size", 0) or 0,
             n_shared_experts=config.get("n_shared_experts", 0) or 0,
@@ -319,28 +260,16 @@ class ModelConfig:
             n_group=n_group,
             topk_group=topk_group,
             topk_method=config.get("topk_method") or "",
-            # Gemma-2 / GPT-OSS (config.json keys; sliding_window exists
-            # in other families' configs too, so gate on the architecture)
-            model_family=(
-                "gemma2" if "gemma2" in arch
-                else "gptoss" if "gptoss" in arch
-                else "falcon_h1" if falcon_h1
-                else "minicpm_sala" if sala
-                else "afmoe" if afmoe
-                else ""
-            ),
+            model_family=model_family,
             attn_logit_softcap=config.get("attn_logit_softcapping") or 0.0,
             final_logit_softcap=config.get("final_logit_softcapping") or 0.0,
             query_pre_attn_scalar=config.get("query_pre_attn_scalar", 0) or 0,
             # honored whenever the checkpoint's HF modeling honors it:
-            # gemma2 alternates it per layer; mistral/phi3-style configs
-            # apply it to every layer; qwen2 ships the key but disables
-            # it via use_sliding_window
+            # mistral/phi3-style configs apply it to every layer; qwen2
+            # ships the key but disables it via use_sliding_window
             sliding_window=(
                 (config.get("sliding_window", 0) or 0)
-                if ("gemma2" in arch
-                    or config.get("use_sliding_window", True))
-                else 0
+                if config.get("use_sliding_window", True) else 0
             ),
             # MLA (DeepSeek config.json keys)
             kv_lora_rank=config.get("kv_lora_rank", 0) or 0,
@@ -348,10 +277,8 @@ class ModelConfig:
             qk_rope_head_dim=config.get("qk_rope_head_dim", 0) or 0,
             qk_nope_head_dim=config.get("qk_nope_head_dim", 0) or 0,
             v_head_dim=config.get("v_head_dim", 0) or 0,
-            **mamba,
-            **hc,
         )
-        return dataclasses.replace(made, **family) if family else made
+        return dataclasses.replace(made, **fields) if fields else made
 
     @classmethod
     def from_model_dir(cls, model_dir: str) -> "ModelConfig":
@@ -362,168 +289,6 @@ class ModelConfig:
             return model_config_from_gguf(read_gguf(model_dir))
         with open(os.path.join(model_dir, "config.json")) as f:
             return cls.from_hf_config(json.load(f))
-
-
-# keys of other published trunks with recurrent or linear-attention layers
-RECURRENT_CONFIG_KEYS = (
-    "layers_block_type", "hybrid_override_pattern", "linear_num_value_heads",
-    "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
-    "conv_kernel", "d_state",
-)
-
-
-# keys that only the afmoe family (models/afmoe.py) computes
-AFMOE_CONFIG_KEYS = ("num_dense_layers", "num_shared_experts", "mup_enabled")
-
-
-def _afmoe_fields(config: dict) -> dict:
-    """ModelConfig's fields from the published keys of ``model_type:
-    afmoe``; what models/afmoe.py does not compute is refused here,
-    before any weight is made."""
-    only = {"score_func": "sigmoid", "route_norm": True, "n_group": 1,
-            "topk_group": 1, "num_expert_groups": 1, "num_limited_groups": 1,
-            "rope_scaling": None, "hidden_act": "silu",
-            "attention_bias": False, "tie_word_embeddings": False}
-    for key, value in only.items():
-        if (config.get(key, value) or value) != value:
-            raise NotImplementedError(
-                f"afmoe with {key}={config[key]!r} "
-                f"(models/afmoe.py computes {key}={value!r} only)")
-    kinds = tuple(config.get("layer_types") or ())
-    layers = int(config["num_hidden_layers"])
-    unknown = sorted(set(kinds) - {"sliding_attention", "full_attention"})
-    if len(kinds) != layers or unknown:
-        raise ValueError(
-            f"afmoe: layer_types has {len(kinds)} entries for {layers} "
-            f"layers, unknown kinds {unknown} (sliding_attention | "
-            "full_attention)")
-    window = int(config.get("sliding_window") or 0)
-    if "sliding_attention" in kinds and window <= 0:
-        raise ValueError("afmoe: sliding_attention layers need sliding_window")
-    return dict(
-        layer_types=kinds,
-        sliding_window=window,
-        first_k_dense_replace=int(config.get("num_dense_layers", 0) or 0),
-        n_shared_experts=int(config.get("num_shared_experts", 0) or 0),
-        moe_scoring_func="sigmoid",
-        norm_topk_prob=True,
-        routed_scaling_factor=float(config.get("route_scale", 1.0) or 1.0),
-        embedding_multiplier=(math.sqrt(int(config["hidden_size"]))
-                              if config.get("mup_enabled") else 1.0),
-    )
-
-
-# keys of a published config with a changed residual path
-HC_KEY_PREFIXES = ("hc_", "mhc_", "hyper_connection")
-
-
-def _xing4_fields(config: dict) -> dict:
-    """ModelConfig's hyper-connection fields from the published keys of
-    ``model_type: xing4_0`` (latent attention with mixed residual
-    streams: models/deepseek.py over models/mhc.py)."""
-    if not (config.get("kv_lora_rank") or 0) > 0:
-        raise NotImplementedError(
-            "xing4_0 without kv_lora_rank: the mixed residual streams are "
-            "served over latent attention only (models/deepseek.py)")
-    return dict(
-        hc_mult=int(config.get("hc_mult", 1)),
-        hc_sinkhorn_iters=int(config.get("hc_sinkhorn_iters", 20)),
-        hc_eps=float(config.get("hc_eps", 1e-6)),
-        hc_res_clamp=(float(config.get("mhc_h_res_clamp_min", -30.0)),
-                      float(config.get("mhc_h_res_clamp_max", 30.0))),
-    )
-
-
-def _minicpm_sala_fields(config: dict) -> dict:
-    """ModelConfig's MiniCPM-SALA fields from the published keys; what
-    the family module does not compute is refused here, before any weight
-    is made. ``sparse_config`` (the MiniCPM4 family's published group)
-    and ``depth_cut`` (``{"of_layers", "first_layer"}``: which layers of
-    the published trunk a cut configuration holds) are optional groups."""
-    only = {
-        "attn_use_rope": False, "lightning_use_rope": True, "qk_norm": True,
-        "use_output_gate": True, "use_output_norm": True,
-        "attn_use_output_gate": True, "attention_bias": False,
-        "rope_scaling": None, "hidden_act": "silu",
-        "lightning_scale": "1/sqrt(d)",
-    }
-    for key, value in only.items():
-        if config.get(key, value) != value:
-            raise NotImplementedError(
-                f"minicpm_sala with {key}={config[key]!r} "
-                f"(models/minicpm_sala.py computes {key}={value!r} only)")
-    mixers = tuple(config.get("mixer_types") or ())
-    layers = int(config["num_hidden_layers"])
-    unknown = sorted(set(mixers) - {"lightning-attn", "minicpm4"})
-    if len(mixers) != layers or unknown:
-        raise ValueError(
-            f"minicpm_sala: mixer_types has {len(mixers)} entries for "
-            f"{layers} layers, unknown kinds {unknown} (lightning-attn | "
-            "minicpm4)")
-    heads = int(config.get("lightning_nh", config["num_attention_heads"]))
-    if int(config.get("lightning_nkv", heads)) != heads:
-        raise NotImplementedError(
-            "minicpm_sala with lightning_nkv != lightning_nh: the state is "
-            "kept a head (models/minicpm_sala.py)")
-    cut = config.get("depth_cut") or {}
-    sparse = config.get("sparse_config") or {}
-    fields = dict(
-        mixer_types=mixers, lightning_heads=heads,
-        lightning_head_dim=int(config.get("lightning_head_dim",
-                                          config.get("head_dim", 128))),
-        scale_emb=float(config.get("scale_emb", 1.0)),
-        scale_depth=float(config.get("scale_depth", 1.0)),
-        dim_model_base=int(config.get("dim_model_base",
-                                      config["hidden_size"])),
-        depth_of=int(cut.get("of_layers", layers)),
-        first_layer=int(cut.get("first_layer", 0)),
-    )
-    for key in ("kernel_size", "kernel_stride", "block_size", "topk",
-                "init_blocks", "window_size", "dense_len"):
-        if key in sparse:
-            fields[f"sparse_{key}"] = int(sparse[key])
-    return fields
-
-
-def _falcon_h1_fields(config: dict) -> dict:
-    """ModelConfig's Falcon-H1 fields from the published keys; what the
-    family module does not compute is refused here, before any weight
-    is made."""
-    only = {
-        "mamba_rms_norm": True, "mamba_norm_before_gate": False,
-        "mamba_proj_bias": False, "mamba_conv_bias": True,
-        "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
-        "rope_scaling": None, "attn_layer_indices": None,
-    }
-    for key, value in only.items():
-        if config.get(key, value) != value:
-            raise NotImplementedError(
-                f"falcon_h1 with {key}={config[key]!r} (models/falcon_h1.py "
-                f"computes {key}={value!r} only)")
-    d_ssm = int(config["mamba_d_ssm"])
-    heads, d_head = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
-    groups = int(config.get("mamba_n_groups", 1))
-    if heads * d_head != d_ssm or heads % groups:
-        raise ValueError(
-            f"falcon_h1: mamba_d_ssm {d_ssm} != mamba_n_heads {heads} x "
-            f"mamba_d_head {d_head}, or mamba_n_groups {groups} does not "
-            "divide the heads")
-    ssm_m = tuple(float(m) for m in config.get("ssm_multipliers", (1.0,) * 5))
-    mlp_m = tuple(float(m) for m in config.get("mlp_multipliers", (1.0, 1.0)))
-    if len(ssm_m) != 5 or len(mlp_m) != 2:
-        raise ValueError("falcon_h1: ssm_multipliers has 5 entries (z, x, B, "
-                         "C, dt) and mlp_multipliers 2 (gate, down)")
-    scalars = ("embedding_multiplier", "lm_head_multiplier",
-               "attention_in_multiplier", "attention_out_multiplier",
-               "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier")
-    return dict(
-        mamba_d_ssm=d_ssm, mamba_n_heads=heads, mamba_d_head=d_head,
-        mamba_d_state=int(config["mamba_d_state"]), mamba_n_groups=groups,
-        mamba_d_conv=int(config.get("mamba_d_conv", 4)),
-        mamba_chunk_size=int(config.get("mamba_chunk_size", 128)),
-        ssm_multipliers=ssm_m, mlp_multipliers=mlp_m,
-        **{k: float(config.get(k, 1.0)) for k in scalars},
-    )
 
 
 def default_prefill_buckets(max_len: int) -> List[int]:
@@ -616,8 +381,10 @@ class EngineConfig:
     # (admission, preemption, KV-OOM, drain). The carry also holds
     # speculative state (trailing-token ring), bounded guided grammar
     # state (guided_device_table below), and the stop-string suffix-hash
-    # ring (device_stop_strings below), so spec / guided / stop-string /
-    # n>1 traffic chains too; a pass the chain refuses runs the
+    # ring (device-approximate: candidate rows freeze on device, the host
+    # confirms exactly on drain, and a hash collision resumes
+    # byte-identically), so spec / guided / stop-string / n>1 traffic
+    # chains too; a pass the chain refuses runs the
     # synchronous path and is counted in
     # dynamo_engine_sync_fallback_total{reason}. 0/1 = strictly
     # synchronous, 2 = the chain (the only other depth).
@@ -630,14 +397,6 @@ class EngineConfig:
     # host sync path explicitly (fallback reason "guided_table_bound").
     guided_device_table: bool = True
     guided_table_max_states: int = 256
-    # stop STRINGS inside the chain: device-approximate detection via a
-    # rolling suffix-hash over the burst carry's trailing-token ring
-    # against the stop strings' canonical tokenizations
-    # (StopConditions.stop_token_seqs); candidate rows freeze on device,
-    # the host confirms exactly on drain, and hash-collision false
-    # positives resume byte-identically. Off -> stop-string rows keep
-    # the synchronous path.
-    device_stop_strings: bool = True
     # n-gram (prompt-lookup) speculative decoding: propose up to K tokens
     # per decode step by matching the context's trailing n-gram against
     # its own history, then VERIFY all K+1 positions in one forward.
@@ -831,7 +590,7 @@ class EngineConfig:
 
     def window_pool_pages(self) -> int:
         """Pages of the window kind's pool, for a model whose
-        ``layer_types`` has window layers (models/afmoe.py); 0 for every
+        ``layer_types`` has window layers; 0 for every
         other. Derived, not set: page 0, which no sequence holds, what
         every slot holds while decoding, and what the rows of one
         prefill step hold more (a chunk's pages beside the window's; the

@@ -173,22 +173,20 @@ class ModelRunner:
         check_serving_device()
         self.config = config
         cfg = config.model
-        self.arch = models.resolve(cfg)
-        # a family with per-sequence state that is not pages (models/
-        # falcon_h1.py): the state is sized by slot, rides in the cache
-        # pytree, and every path that moves pages without it is refused
-        self.recurrent = bool(getattr(self.arch, "RECURRENT_STATE", False))
-        # a family whose window layers keep their pages in a second pool
-        # behind a second table (models/afmoe.py): every path that moves
-        # pages by one block id is refused likewise
-        self.window_pages = bool(getattr(self.arch, "WINDOW_PAGES", False))
+        self.family = models.family(cfg)
+        self.arch = self.family.module
+        # what the family keeps for a sequence besides one kind of page
+        # (records by slot, a second pool of pages: it rides in the cache
+        # pytree) and the paths refused for it, its module's declaration
+        self.keeps: models.SequenceState = getattr(
+            self.arch, "SEQUENCE_STATE", models.PAGES_ONLY)
         for path, on in (
             ("spec_ngram_tokens", config.spec_ngram_tokens > 0),
             ("spec_draft_model", bool(config.spec_draft_model)),
             ("sp_size", config.sp_size > 1),
             ("pp_size", config.pp_size > 1),
             ("tp_size", config.tp_size > 1),
-            ("ep_size", config.ep_size > 1 and self.window_pages),
+            ("ep_size", config.ep_size > 1),
             ("host_kv_blocks", config.host_kv_blocks > 0),
             ("prefix_pull", config.prefix_pull),
             ("multi_step_decode", config.multi_step_decode > 1),
@@ -231,44 +229,12 @@ class ModelRunner:
                 and cfg.num_experts > 0)
             else 0
         )
-        if config.pp_size > 1 and cfg.hc_mult > 1:
-            raise NotImplementedError(
-                f"pp_size {config.pp_size} is refused with hc_mult "
-                f"{cfg.hc_mult}: a pipeline stage hands [B, S, D] to the "
-                "next (parallel/pipeline.py), and the residual streams of "
-                "models/mhc.py are [B, S, n D]"
-            )
         if config.pp_size > 1:
-            from ..models import deepseek as _deepseek
-            from ..models import gemma2 as _gemma2
-            from ..models import gptoss as _gptoss
-            from ..models import mixtral as _mixtral
-
-            if self.arch not in (llama, _mixtral, _gemma2, _gptoss,
-                                 _deepseek):
+            if not self.family.staged:
                 raise NotImplementedError(
-                    "pipeline parallelism stages llama-family dense, "
-                    "mixtral MoE, gemma2, gptoss, and deepseek (MLA)"
-                )
-            if self.arch is _deepseek:
-                if config.tp_size > 1:
-                    raise NotImplementedError(
-                        "MLA over pp composes with dp/ep, not tp: the "
-                        "compressed latent cache has a single head, so "
-                        "there is no head axis for the manual-tp stage "
-                        "to shard (MLA tp runs on the GSPMD non-pp path)"
-                    )
-            if self.arch is _gptoss and config.tp_size > 1 and (
-                cfg.intermediate_size % config.tp_size
-            ):
-                # the interleaved gate/up stacks shard the 2I columns in
-                # contiguous chunks; whole gate/up pairs (and their
-                # matching w_down rows) stay together only when the
-                # expert width divides by tp
-                raise ValueError(
-                    f"gptoss intermediate_size {cfg.intermediate_size} "
-                    f"not divisible by tp {config.tp_size}"
-                )
+                    "pipeline parallelism does not stage the "
+                    f"{self.family.name} family (models.FAMILIES)")
+            getattr(self.arch, "refuse_staged", lambda config: None)(config)
             # only the STAGED trunk must tile into stages — a mixed MLA
             # trunk's dense prefix is replicated, not staged (real V3:
             # 61 layers = 3 dense + 58 staged, pp2-able)
@@ -429,36 +395,36 @@ class ModelRunner:
             ))
 
         self.param_bytes = _leaf_bytes(self.params)
-        # (two kinds of page: the full kind's, the part that grows with
-        # the context; the window kind's is bounded by the window)
-        pages = (tuple(side.kv for side in self.kv_cache) if self.recurrent
-                 else tuple(side.full for side in self.kv_cache)
-                 if self.window_pages else self.kv_cache)
+        # a side of the cache that is more than a page stack answers for
+        # its parts (models/__init__.py, CACHE_SPEC): the pages that grow
+        # with the context, and what else it holds (records by slot, or
+        # the window kind's pages, bounded by the window)
+        pages = tuple(getattr(side, "pages", side) for side in self.kv_cache)
+        rest = _leaf_bytes(
+            tuple(getattr(side, "rest", ()) for side in self.kv_cache))
         self.kv_bytes_per_token = _leaf_bytes(pages) / max(
             1, config.num_kv_blocks * config.kv_block_size
         )
-        if self.window_pages:
+        if self.keeps.window_pool:
             logger.info(
                 "two kinds of page: full %d pages (%.3f GB), window %d "
                 "pages (%.3f GB; %d a decoding row, %d a row in prefill)",
                 config.num_kv_blocks, _leaf_bytes(pages) / 1e9,
-                config.window_pool_pages(),
-                _leaf_bytes(tuple(s.window for s in self.kv_cache)) / 1e9,
+                config.window_pool_pages(), rest / 1e9,
                 config.window_pages_a_row(),
                 config.window_pages_a_row(config.prefill_chunk_tokens()))
-        if self.recurrent:
+        if self.keeps.slots:
             self.compiles.registry.gauge(
                 "dynamo_engine_recurrent_state_bytes",
                 "Device bytes of the recurrent state records held by slot "
                 "beside the paged cache (all layers, all slots)",
-            ).set(_leaf_bytes(tuple(side.state for side in self.kv_cache)))
+            ).set(rest)
         self.compiles.registry.gauge(
             "dynamo_engine_model_info",
             "1 for the architecture this engine serves: family= the "
             "implementing module of models/, hc_mult= residual streams a "
             "token (1: the plain residual path)",
-        ).set(1.0, family=self.arch.__name__.rsplit(".", 1)[-1],
-              hc_mult=str(cfg.hc_mult))
+        ).set(1.0, family=self.family.name, hc_mult=str(cfg.hc_mult))
         self.device_time = DeviceTimeTracker(
             param_bytes=self.param_bytes,
             kv_bytes_per_token=self.kv_bytes_per_token,
@@ -479,23 +445,15 @@ class ModelRunner:
 
     def refuse_without_state(self, path: str) -> None:
         """Raise, by name, for a path that would move, share or roll back
-        a sequence's pages without its recurrent state, or by one block
-        id where the family keeps two kinds of page; nothing for a
-        family whose only per-sequence state is one kind of page."""
-        if self.recurrent:
-            keeps = "recurrent state by slot beside the paged cache"
-            why = self.arch.RECURRENT_REFUSALS[path]
-        elif self.window_pages:
-            keeps = ("its window layers' pages in a pool and a table of "
-                     "their own")
-            why = self.arch.WINDOW_REFUSALS[path]
-        else:
-            return
-        family = self.arch.__name__.rsplit(".", 1)[-1]
-        raise ValueError(
-            f"{path} is refused for the {family} family, which keeps "
-            f"{keeps}: {why}"
-        )
+        a sequence's pages without what the family keeps beside them
+        (``SequenceState.refused``); nothing for a path it does not
+        refuse, and so nothing for a family that keeps one kind of page."""
+        why = self.keeps.refused.get(path)
+        if why is not None:
+            raise ValueError(
+                f"{path} is refused for the {self.family.name} family, "
+                f"which keeps {self.keeps.keeps}: {why}"
+            )
 
     # ---------- routed experts' counters ----------
 
@@ -620,31 +578,22 @@ class ModelRunner:
         if self.config.pp_size > 1:
             from ..parallel.pipeline import pipeline_forward
 
-            def forward(params, cache, tokens, positions, bt, slots, ctx):
-                return pipeline_forward(
-                    params, cfg, tokens, positions, cache, bt, slots, ctx,
-                    mesh, return_hidden=True, arch=arch,
-                )
+            trunk = functools.partial(
+                pipeline_forward, return_hidden=True, arch=arch)
         elif counted:
-            def forward(params, cache, tokens, positions, bt, slots, ctx):
-                return arch.forward_counted(
-                    params, cfg, tokens, positions, cache, bt, slots, ctx,
-                    mesh=mesh,
-                )
-        elif self.recurrent:
-            # the trunk also needs each row's slot: its state record
-            def forward(params, cache, tokens, positions, bt, slots, ctx,
-                        state_slots):
-                return arch.forward(
-                    params, cfg, tokens, positions, cache, bt, slots, ctx,
-                    mesh=mesh, return_hidden=True, state_slots=state_slots,
-                )
+            trunk = arch.forward_counted
         else:
-            def forward(params, cache, tokens, positions, bt, slots, ctx):
-                return arch.forward(
-                    params, cfg, tokens, positions, cache, bt, slots, ctx,
-                    mesh=mesh, return_hidden=True,
-                )
+            trunk = None
+
+        # state_slots: each row's slot, for a trunk that keeps records by
+        # slot (the step's; a burst's row i is slot i)
+        def forward(params, cache, tokens, positions, bt, slots, ctx,
+                    state_slots=None):
+            args = (params, cfg, tokens, positions, cache, bt, slots, ctx)
+            if trunk is not None:
+                return trunk(*args, mesh=mesh)
+            return arch.forward(*args, mesh=mesh, return_hidden=True,
+                                state_slots=state_slots)
 
         def head(hidden, params):
             with jax.named_scope("lm_head"):
@@ -669,8 +618,7 @@ class ModelRunner:
              want_prompt, want_greedy) = step_inputs.unpack(packed, s)
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), tokens, positions,
-                block_tables, slot_mapping, context_lens,
-                *((sample_slots,) if self.recurrent else ()),
+                block_tables, slot_mapping, context_lens, sample_slots,
             )
             b = tokens.shape[0]
             # the full-S [B, S, V] head exists ONLY inside this gated
@@ -1598,7 +1546,7 @@ class ModelRunner:
         """
         b, s = tokens.shape
         width = block_tables.shape[1]
-        if self.window_pages:
+        if self.keeps.window_pool:
             block_tables = np.concatenate(
                 [block_tables, np.zeros_like(block_tables)
                  if window_tables is None else window_tables], axis=1)
@@ -2019,10 +1967,8 @@ class ModelRunner:
         def make():
             cache = tuple(self.arch.init_kv_cache(
                 cfg.model, cfg.num_kv_blocks, cfg.kv_block_size,
-                self.kv_dtype,
-                **({"num_slots": cfg.max_batch_size} if self.recurrent
-                   else {"window_blocks": cfg.window_pool_pages()}
-                   if self.window_pages else {}),
+                self.kv_dtype, num_slots=cfg.max_batch_size,
+                window_blocks=cfg.window_pool_pages(),
             ))
             if cfg.pp_size > 1:
                 from ..parallel.pipeline import stage_cache

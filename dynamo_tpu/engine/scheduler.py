@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..models import PAGES_ONLY
 from ..protocols.common import (
     EngineOutput,
     FinishReason,
@@ -508,20 +509,18 @@ class Scheduler:
     ):
         self.runner = runner
         self.config = config
-        # a family with recurrent state by slot (models/falcon_h1.py): a
-        # prefix hit would hand a sequence pages without the state that
-        # followed them, so hits are blanked and no block is registered;
-        # a sequence starts, and resumes after preemption, by prefilling
-        # from position 0, where the trunk zeroes its slot's state
-        # (FakeRunner test doubles carry no flag)
-        self.recurrent = bool(getattr(runner, "recurrent", False))
-        # a family with two kinds of page (models/afmoe.py): its window
-        # layers' pages come from a pool of their own, taken as a row
-        # grows and given back behind the window (_take_window,
-        # _release_window); nothing else knows the kind, so the same
-        # holds as for state: no hits, no registered block, resume from 0
-        window_pages = bool(getattr(runner, "window_pages", False))
-        self.private_pages = self.recurrent or window_pages
+        # what the family keeps for a sequence besides one kind of page
+        # (models.SequenceState; FakeRunner test doubles keep nothing).
+        # Either kind is private to its sequence: a prefix hit would hand
+        # another sequence pages without the state that followed them, or
+        # without the window kind's pages, so hits are blanked and no
+        # block is registered; a sequence starts, and resumes after
+        # preemption, by prefilling from position 0 (where a trunk with
+        # records by slot zeroes its slot's)
+        keeps = getattr(runner, "keeps", PAGES_ONLY)
+        self.private_pages = keeps.private
+        # (the two counters of a family with records by slot)
+        self.recurrent = keeps.slots
         if self.private_pages and disagg is not None:
             runner.refuse_without_state("remote_prefill")
         self.disagg = disagg
@@ -566,7 +565,10 @@ class Scheduler:
             config.num_kv_blocks, config.kv_block_size,
             config.enable_prefix_caching, sink, tier2=tier2,
             registry=self.registry, flight=self.flight,
-            window_pages=config.window_pool_pages() if window_pages else 0,
+            # (a second pool: pages taken as a row grows and given back
+            # behind the window, _take_window / _release_window)
+            window_pages=(config.window_pool_pages()
+                          if keeps.window_pool else 0),
         )
         self.window = self.allocator.window
         # cluster KV fabric (kv/fabric.py): cross-worker prefix pull +
@@ -599,7 +601,7 @@ class Scheduler:
         self.sp_active: Optional[_SpPrefill] = None
         self.waiting: deque = deque()
         # persistent decode-step host arrays (see _HostBatchState)
-        self._host = _HostBatchState(config, window_pages)
+        self._host = _HostBatchState(config, keeps.window_pool)
         self.pending_remote: List[EngineRequest] = []
         self.slots: List[Optional[EngineRequest]] = [None] * config.max_batch_size
         # the prefill BATCH: up to max_prefill_batch requests whose
@@ -1971,8 +1973,6 @@ class Scheduler:
         for er in active:
             if not er.device_checkable:
                 return er.chain_fallback or "not_checkable"
-            if er.fin_stop_seqs and not cfg.device_stop_strings:
-                return "stop_strings_disabled"
             if er.guided is not None:
                 r = self._guided_chain_reason(er)
                 if r:
@@ -2007,8 +2007,6 @@ class Scheduler:
         for er in active:
             if not er.device_checkable:
                 return er.chain_fallback or "not_checkable"
-            if er.fin_stop_seqs and not cfg.device_stop_strings:
-                return "stop_strings_disabled"
             # conservative horizon guard: the host's committed context
             # lags the drain queue, so bound by the chain's own dispatch
             # count — the round's S-position forward must stay inside

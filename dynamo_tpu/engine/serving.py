@@ -87,14 +87,11 @@ def engine_config_from_mdc(mdc, flags=None, extra=None) -> EngineConfig:
         ),
         spec_ngram_tokens=getattr(flags, "spec_ngram_tokens", 0) or 0,
         spec_ngram_match=getattr(flags, "spec_ngram_match", 3) or 3,
-        # unrestricted chain (docs/performance.md): guided device
-        # tables + device-approximate stop strings
+        # unrestricted chain (docs/performance.md): guided device tables
         guided_device_table=not getattr(
             flags, "no_guided_device_table", False),
         guided_table_max_states=getattr(
             flags, "guided_table_max_states", 256) or 256,
-        device_stop_strings=not getattr(
-            flags, "no_device_stop_strings", False),
         # no `or` fallback: an explicit 0 must DISABLE the watchdog, not
         # silently restore the default deadline
         watchdog_stall_s=(

@@ -1,87 +1,172 @@
-"""Model registry: architecture config → implementing module.
+"""Model registry: one module and one row a family.
 
-Each model module exposes the same functional surface —
-``init_params(cfg, key, dtype)``, ``init_kv_cache(cfg, n, bs, dtype)``,
-``forward(params, cfg, ...)`` and ``param_specs(params)`` — so the engine
-(engine/model_runner.py) is architecture-agnostic. The reference's
-equivalent "model family" axis lived inside its delegated GPU engines
-(vLLM/SGLang model zoos, SURVEY.md §2.4); here the zoo is native.
+``FAMILIES`` is the table: a row names a family (``model_family``'s
+value and the module's name here), how a published config or a
+``ModelConfig`` selects it, the field that is its alone and whether the
+pipeline stages it. What else a family decides it declares in its module
+under the names below; ``engine/`` and ``parallel/`` read those and name
+no family (a ``getattr(arch, ...)`` for a name not listed here fails
+``tests/test_model_families.py``).
+
+**Required** of every module, called by ``ModelRunner``:
+``init_params(cfg, key, dtype)``, ``param_specs(params)``,
+``init_kv_cache(cfg, num_blocks, block_size, dtype, num_slots=,
+window_blocks=)`` (the engine offers every family its decode slots and
+the window pool's pages; a family takes what it keeps),
+``forward(params, cfg, tokens, positions, kv_cache, block_tables,
+slot_mapping, context_lens, mesh=, return_hidden=, state_slots=)``
+(each row's slot, for records by slot) and
+``logits_from_hidden(hidden, params, cfg)``.
+
+**Optional**, and who asks:
+
+- ``config_fields(config)``: ``ModelConfig`` fields from the family's
+  own published keys, refusing what the module does not compute;
+  required of a row selected by name (``published``, for
+  ``ModelConfig.from_hf_config``);
+- ``claimed_keys(config)`` over ``CLAIMED_KEYS`` / ``CLAIMED_PREFIXES``,
+  and ``CLAIM``: the published keys only this family computes and the
+  sentence that refuses them under another ``model_type``; required of
+  a row with a ``field`` (``published``);
+- ``SEQUENCE_STATE``: what a sequence keeps besides one kind of page and
+  the paths refused for it; default ``PAGES_ONLY`` (``ModelRunner``,
+  ``Scheduler`` through ``runner.keeps``);
+- ``CACHE_SPEC``: the sharding of a cache side that is not a bare page
+  stack (``ModelRunner``, ``scripts/layer_loop.py``); such a side
+  answers ``.pages`` (those that grow with the context) and ``.rest``
+  for the runner's byte counts, ``.dtype`` for ``benchmark/run.py``;
+- ``STEP_COUNTERS``, ``step_counts(kv_cache)``: counters the trunk keeps
+  in its cache (``ModelRunner._init_family_counters``);
+- ``forward_counted``: the trunk with the routed experts' counters
+  (``ModelRunner._make_forward`` when ``cfg.num_experts > 0``);
+- ``embed_forward``: the cacheless trunk (``ModelRunner.embed_prompts``);
+- ``refuse_staged(engine_config)``: what a ``staged`` family refuses
+  under ``pp_size > 1`` (``ModelRunner``);
+- ``pp_trunk_specs``, ``embed_tokens``, ``make_attn_fn``, ``run_layers``,
+  ``mlp_fn``, ``make_moe_mlp_fn``, ``make_mlp_fn``: a staged family's
+  pieces, llama's by default (``parallel/pipeline.py``).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import importlib
+from typing import Callable, Dict, Mapping, Optional, Tuple
+
 from ..engine.config import ModelConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class SequenceState:
+    """What a family keeps for a sequence besides one kind of page, and
+    the paths that would move, share or roll back a sequence's pages
+    without it, each refused by name at start-up
+    (``ModelRunner.refuse_without_state``)."""
+    slots: bool = False         # records by slot beside the pages
+    window_pool: bool = False   # a second pool of pages, a table of its own
+    keeps: str = ""             # the sentence a refusal names it with
+    refused: Mapping[str, str] = dataclasses.field(default_factory=dict)
+
+    @property
+    def private(self) -> bool:
+        """No other sequence can take this one's pages: prefix hits are
+        blanked, no block is registered, resume is from position 0."""
+        return self.slots or self.window_pool
+
+
+PAGES_ONLY = SequenceState()
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    name: str
+    # a published config names it by model_type or by a substring of its
+    # architectures; a family told by shape (a rule over a ModelConfig)
+    # leaves model_family blank: tests and GGUF build configs without it
+    model_types: Tuple[str, ...] = ()
+    architecture: str = ""
+    shape: Optional[Callable[[ModelConfig], bool]] = None
+    # the field no other family reads and what resolve says when it is
+    # set and the row not selected; a row with one also claims published
+    # keys (its module's claimed_keys and CLAIM)
+    field: str = ""
+    unserved: str = ""
+    staged: bool = False    # parallel/pipeline.py stages its trunk
+
+    @property
+    def module(self):
+        return importlib.import_module(f"{__name__}.{self.name}")
+
+
+# in the order resolve asks: the first row that serves a config has it
+FAMILIES = (
+    Family("deepseek", model_types=("xing4_0",),
+           shape=lambda cfg: cfg.kv_lora_rank > 0, staged=True,
+           field="hc_mult",
+           unserved="hc_mult={value} needs a trunk that carries the residual "
+                    "streams of models/mhc.py; only latent attention "
+                    "(model_type xing4_0, models/deepseek.py) does"),
+    Family("falcon_h1", model_types=("falcon_h1",), field="mamba_d_ssm",
+           unserved="mamba_d_ssm={value} needs a family that keeps recurrent "
+                    "state; model_family {family!r} has none "
+                    "(models/falcon_h1.py is selected by model_type "
+                    "falcon_h1)"),
+    Family("minicpm_sala", model_types=("minicpm_sala",), field="mixer_types",
+           unserved="mixer_types ({n} entries) needs a family that keeps a "
+                    "cache a kind of layer; model_family {family!r} has none "
+                    "(models/minicpm_sala.py is selected by model_type "
+                    "minicpm_sala)"),
+    Family("afmoe", model_types=("afmoe",), field="layer_types",
+           unserved="layer_types ({n} entries) needs a family that keeps "
+                    "pages a kind of layer; model_family {family!r} has none "
+                    "(models/afmoe.py is selected by model_type afmoe)"),
+    Family("gptoss", architecture="gptoss", staged=True),
+    Family("mixtral", shape=lambda cfg: cfg.num_experts > 0, staged=True),
+    Family("gemma2", architecture="gemma2", staged=True),
+    Family("llama", shape=lambda cfg: True, staged=True),
+)
+
+
+def family(cfg: ModelConfig) -> Family:
+    """The row that serves ``cfg``; a config whose fields belong to a
+    family that was not selected is refused by name (another trunk would
+    serve it without them, and wrong tokens)."""
+    row = next(r for r in FAMILIES if (
+        r.shape(cfg) if r.shape else cfg.model_family == r.name))
+    for other in FAMILIES:
+        if other is row or not other.field:
+            continue
+        value = getattr(cfg, other.field)
+        if value != ModelConfig.__dataclass_fields__[other.field].default:
+            raise NotImplementedError(other.unserved.format(
+                value=value, family=cfg.model_family,
+                n=len(value) if isinstance(value, tuple) else 0))
+    return row
 
 
 def resolve(cfg: ModelConfig):
     """Pick the implementing module for an architecture config."""
-    if cfg.kv_lora_rank > 0:
-        try:
-            from . import deepseek
-        except ImportError as e:  # pragma: no cover
+    return family(cfg).module
+
+
+def published(config: Mapping) -> Tuple[str, Dict]:
+    """``(model_family, fields)`` of a published config: the row it
+    names, that family's translation of its own keys
+    (``config_fields``), and a refusal by name of every key another
+    family claims (a trunk this program has no family for under that
+    ``model_type`` would fall through to another and serve nonsense)."""
+    model_type = config.get("model_type")
+    arch = str(config.get("architectures", "")).lower()
+    row = next((r for r in FAMILIES if model_type in r.model_types
+                or (r.architecture and r.architecture in arch)), None)
+    for other in FAMILIES:
+        if other is row or not other.field:
+            continue
+        keys = other.module.claimed_keys(config)
+        if keys:
             raise NotImplementedError(
-                "kv_lora_rank > 0 selects MLA attention (DeepSeek-class), "
-                "which requires dynamo_tpu/models/deepseek.py"
-            ) from e
-        return deepseek
-    if cfg.hc_mult > 1:
-        # mixed residual streams with no family to mix them: every
-        # other trunk would add to one stream and serve wrong tokens
-        raise NotImplementedError(
-            f"hc_mult={cfg.hc_mult} needs a trunk that carries the residual "
-            "streams of models/mhc.py; only latent attention (model_type "
-            "xing4_0, models/deepseek.py) does"
-        )
-    if cfg.model_family == "falcon_h1":
-        from . import falcon_h1
-
-        return falcon_h1
-    if cfg.model_family == "minicpm_sala":
-        from . import minicpm_sala
-
-        return minicpm_sala
-    if cfg.mixer_types:
-        # layers of two kinds with no family to tell them apart: llama
-        # would run dense rotary attention in every one
-        raise NotImplementedError(
-            f"mixer_types ({len(cfg.mixer_types)} entries) needs a family "
-            f"that keeps a cache a kind of layer; model_family "
-            f"{cfg.model_family!r} has none (models/minicpm_sala.py is "
-            "selected by model_type minicpm_sala)"
-        )
-    if cfg.model_family == "afmoe":
-        from . import afmoe
-
-        return afmoe
-    if cfg.layer_types:
-        # window and full layers with no family to tell them apart:
-        # mixtral or llama would run one kind of attention in every one
-        raise NotImplementedError(
-            f"layer_types ({len(cfg.layer_types)} entries) needs a family "
-            f"that keeps pages a kind of layer; model_family "
-            f"{cfg.model_family!r} has none (models/afmoe.py is selected "
-            "by model_type afmoe)"
-        )
-    if cfg.mamba_d_ssm > 0:
-        # recurrent state with no family to keep it: llama would serve
-        # the attention half alone
-        raise NotImplementedError(
-            f"mamba_d_ssm={cfg.mamba_d_ssm} needs a family that keeps "
-            f"recurrent state; model_family {cfg.model_family!r} has none "
-            "(models/falcon_h1.py is selected by model_type falcon_h1)"
-        )
-    if cfg.model_family == "gptoss":
-        from . import gptoss
-
-        return gptoss
-    if cfg.num_experts > 0:
-        from . import mixtral
-
-        return mixtral
-    if cfg.model_family == "gemma2":
-        from . import gemma2
-
-        return gemma2
-    from . import llama
-
-    return llama
+                f"model_type {model_type!r} carries "
+                + other.module.CLAIM.format(keys=", ".join(keys[:4])))
+    if row is None:
+        return "", {}
+    return "" if row.shape else row.name, row.module.config_fields(config)

@@ -47,12 +47,12 @@ from the step's slot mapping, which is the full kind's.
 
 Nothing else knows the kind, so every path that moves or shares a
 sequence's pages by one block id is refused for this family, by name
-(``WINDOW_REFUSALS``); prefix hits are blanked, no block is registered,
+(``SEQUENCE_STATE``); prefix hits are blanked, no block is registered,
 and a preempted sequence resumes by prefill from position 0.
 
 The trunk scans each run of layers of one kind (attention and
 feed-forward alike) over that run's stacked weights (``params["runs"]``;
-``layer_runs``), as models/minicpm_sala.py does.
+``kind_runs``), as models/minicpm_sala.py does.
 
 Scopes: ``attn`` with ``attn_window`` or ``attn_full`` inside (norm,
 projections, rope, scatter, kernel, gate, output), and ``kv_window`` or
@@ -62,6 +62,7 @@ projections, rope, scatter, kernel, gate, output), and ``kv_window`` or
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -71,22 +72,21 @@ from jax.sharding import PartitionSpec as P
 from ..engine.config import ModelConfig
 from ..ops.attention import attention, lane_pad, scatter_kv_stacked
 from ..ops.live_rows import decode_live_rows
+from . import SequenceState
 from .deepseek import random_expert_stacks
-from .llama import (_swiglu_mlp, base_specs, lm_logits, qkv_prologue,
-                    rms_norm)
+from .llama import (_swiglu_mlp, layer_runs, lm_logits, qkv_prologue,
+                    rms_norm, run_specs)
 from .mixtral import make_moe_mlp_fn, split_expert_stacks
 from .quant import dense
 
 Params = Dict[str, Any]
 
-# the family's window layers give pages back while a sequence runs: the
-# engine keeps a second pool and a second table a row for them
-WINDOW_PAGES = True
 LOCAL, GLOBAL = "sliding_attention", "full_attention"
-# paths that move, share or roll back a sequence's pages by one block id
-# and do not know the kind, each refused by name at start-up
-# (ModelRunner.refuse_without_state): path -> reason
-WINDOW_REFUSALS = {
+# the window layers give pages back while a sequence runs: the engine
+# keeps a second pool (init_kv_cache's window_blocks) and a second table
+# a row for them. Refused at start-up, by name: paths that move, share
+# or roll back a sequence's pages by one block id, path -> reason
+_REFUSED = {
     "spec_ngram_tokens": "a rejected proposal rolls back pages by one "
                          "block id; the window kind's are not rolled back",
     "spec_draft_model": "the draft's mirror cache shares the full kind's "
@@ -108,6 +108,69 @@ WINDOW_REFUSALS = {
     "remote_prefill": "a prefill worker ships the full kind's pages only",
     "migration": "a migrated sequence brings the full kind's pages only",
 }
+SEQUENCE_STATE = SequenceState(
+    window_pool=True, refused=_REFUSED,
+    keeps="its window layers' pages in a pool and a table of their own")
+
+# published keys only this family computes (models.published): a
+# trunk whose layers differ in kind, with dense layers before its
+# experts, a shared expert or a scaled embedding, would be served by
+# mixtral.py or llama.py without them
+CLAIMED_KEYS = ("num_dense_layers", "num_shared_experts", "mup_enabled")
+CLAIM = ("{keys} and no family here implements them under that model_type "
+         "(afmoe is the family with window and full layers by layer_types, "
+         "num_dense_layers, num_shared_experts and mup_enabled: "
+         "models/afmoe.py)")
+
+
+def claimed_keys(config: dict) -> List[str]:
+    """``CLAIMED_KEYS`` where set, and ``layer_types`` where it mixes
+    kinds (Gemma-2 and GPT-OSS publish the alternation their modules
+    compute from the layer's index)."""
+    keys = [k for k in CLAIMED_KEYS if config.get(k)]
+    arch = str(config.get("architectures", "")).lower()
+    mixed = len(set(config.get("layer_types") or ())) > 1
+    if mixed and not ("gptoss" in arch or "gemma" in arch):
+        keys.insert(0, "layer_types")
+    return keys
+
+
+def config_fields(config: dict) -> dict:
+    """ModelConfig's fields from the published keys of ``model_type:
+    afmoe``; what models/afmoe.py does not compute is refused here,
+    before any weight is made."""
+    only = {"score_func": "sigmoid", "route_norm": True, "n_group": 1,
+            "topk_group": 1, "num_expert_groups": 1, "num_limited_groups": 1,
+            "rope_scaling": None, "hidden_act": "silu",
+            "attention_bias": False, "tie_word_embeddings": False}
+    for key, value in only.items():
+        if (config.get(key, value) or value) != value:
+            raise NotImplementedError(
+                f"afmoe with {key}={config[key]!r} "
+                f"(models/afmoe.py computes {key}={value!r} only)")
+    kinds = tuple(config.get("layer_types") or ())
+    layers = int(config["num_hidden_layers"])
+    unknown = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+    if len(kinds) != layers or unknown:
+        raise ValueError(
+            f"afmoe: layer_types has {len(kinds)} entries for {layers} "
+            f"layers, unknown kinds {unknown} (sliding_attention | "
+            "full_attention)")
+    window = int(config.get("sliding_window") or 0)
+    if "sliding_attention" in kinds and window <= 0:
+        raise ValueError("afmoe: sliding_attention layers need sliding_window")
+    return dict(
+        layer_types=kinds,
+        sliding_window=window,
+        first_k_dense_replace=int(config.get("num_dense_layers", 0) or 0),
+        n_shared_experts=int(config.get("num_shared_experts", 0) or 0),
+        moe_scoring_func="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=float(config.get("route_scale", 1.0) or 1.0),
+        embedding_multiplier=(math.sqrt(int(config["hidden_size"]))
+                              if config.get("mup_enabled") else 1.0),
+    )
+
 
 # standard deviation of the served logits under random weights, and of
 # q·k / sqrt(head_dim) (the query norm's weight: the per-head norms make
@@ -132,26 +195,29 @@ class KindCache:
 
     @property
     def dtype(self):
+        """benchmark/run.py reads ``runner.kv_cache[0].dtype``."""
         return self.full.dtype
+
+    @property
+    def pages(self):
+        return self.full
+
+    @property
+    def rest(self):
+        return self.window
 
 
 CACHE_SPEC = KindCache(full=P(), window=P())
 
 
-def layer_runs(cfg: ModelConfig) -> List[Tuple[bool, bool, int, int]]:
-    """The layers as runs of one kind: (window layer?, dense
-    feed-forward?, the run's first index among the layers of its
-    attention kind, its length)."""
-    runs, seen = [], {True: 0, False: 0}
+def kind_runs(cfg: ModelConfig) -> List[Tuple[Tuple[bool, bool], int, int]]:
+    """The layers as runs of one kind (``llama.layer_runs``): ((window
+    layer?, dense feed-forward?), the run's first index among the layers
+    of its attention kind, its length)."""
     dense_layers = min(cfg.first_k_dense_replace, cfg.num_layers)
-    for i, kind in enumerate(cfg.layer_types):
-        local, is_dense = kind == LOCAL, i < dense_layers
-        if runs and runs[-1][:2] == [local, is_dense]:
-            runs[-1][3] += 1
-        else:
-            runs.append([local, is_dense, seen[local], 1])
-        seen[local] += 1
-    return [tuple(r) for r in runs]
+    kinds = [(kind == LOCAL, i < dense_layers)
+             for i, kind in enumerate(cfg.layer_types)]
+    return layer_runs(kinds, index_key=lambda kind: kind[0])
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
@@ -175,7 +241,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         return random_expert_stacks(key, shape, fan_in, dtype)
 
     runs = []
-    for r, (_, is_dense, _, n) in enumerate(layer_runs(cfg)):
+    for r, ((_, is_dense), _, n) in enumerate(kind_runs(cfg)):
         keys = jax.random.split(jax.random.fold_in(key, r + 1), 13)
         run = {
             "ln1": jnp.ones((n, d), dtype),
@@ -219,17 +285,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
-def param_specs(params: Params) -> Dict:
-    """Replicated: tp > 1 and ep > 1 are refused for the family."""
-    specs = base_specs(params)
-    specs["lm_head"] = P()
-    specs = {k: v for k, v in specs.items() if k in params}
-    specs["runs"] = [{k: P() for k in run} for run in params["runs"]]
-    return specs
+param_specs = run_specs    # tp > 1 and ep > 1 are refused for the family
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                  dtype=jnp.bfloat16, window_blocks: int = 1):
+                  dtype=jnp.bfloat16, num_slots: int = 1,
+                  window_blocks: int = 1):
     """``(KindCache(k full, k window), KindCache(v full, v window))``:
     ``num_blocks`` pages a full layer, ``window_blocks`` a window layer
     (page 0 of those is the one no sequence holds)."""
@@ -306,8 +367,8 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     # run of layers and both kinds of page
     live_rows = decode_live_rows(slot_mapping)
 
-    for (local, is_dense, start, _), run in zip(layer_runs(cfg),
-                                                params["runs"]):
+    for ((local, is_dense), start, _), run in zip(kind_runs(cfg),
+                                                  params["runs"]):
         attn_fn = make_attn_fn(cfg, b, s, positions, slots[local],
                                tables[local], context_lens, local, live_rows)
         if is_dense:
@@ -351,6 +412,7 @@ def forward(
     context_lens: jax.Array,  # [B]
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ):
     hidden, cache, _ = forward_counted(
         params, cfg, tokens, positions, kv_cache, block_tables,

@@ -55,6 +55,10 @@ from .llama import (
     run_layers,
 )
 from . import mhc
+# the one model_type this module is selected by name for is the mixed
+# residual streams': its published keys are that path's
+from .mhc import (CLAIM, CLAIMED_PREFIXES, claimed_keys,  # noqa: F401
+                  config_fields)
 from .mixtral import make_moe_mlp_fn, split_expert_stacks
 from .quant import dense
 
@@ -66,8 +70,27 @@ CACHE_SPEC = P()
 
 
 
+def refuse_staged(config) -> None:
+    """What the family refuses under ``pp_size > 1``."""
+    if config.model.hc_mult > 1:
+        raise NotImplementedError(
+            f"pp_size {config.pp_size} is refused with hc_mult "
+            f"{config.model.hc_mult}: a pipeline stage hands [B, S, D] to "
+            "the next (parallel/pipeline.py), and the residual streams of "
+            "models/mhc.py are [B, S, n D]"
+        )
+    if config.tp_size > 1:
+        raise NotImplementedError(
+            "MLA over pp composes with dp/ep, not tp: the "
+            "compressed latent cache has a single head, so "
+            "there is no head axis for the manual-tp stage "
+            "to shard (MLA tp runs on the GSPMD non-pp path)"
+        )
+
+
 def init_kv_cache(
-    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16
+    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
+    num_slots: int = 1, window_blocks: int = 1,
 ) -> KVCache:
     """Compressed cache: c_kv [L,N,1,bs,r] + k_rope [L,N,1,bs,rd].
 
@@ -508,6 +531,7 @@ def forward(
     context_lens: jax.Array,  # [B]
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ) -> Tuple[jax.Array, KVCache]:
     """Returns (logits [B, S, V], updated (c_kv, k_rope) caches). Dense
     prefix layers then MoE layers, chained through one contiguous cache.

@@ -37,7 +37,7 @@ its first position is 0 and from its slot's state otherwise (the next
 chunk of one prompt), and writes the state back as of its last valid
 token. So a preempted sequence resumes by re-prefilling from position 0
 (engine/scheduler.py), and nothing else may move a sequence's pages
-without its state: ``RECURRENT_REFUSALS`` names what the engine refuses
+without its state: ``SEQUENCE_STATE`` names what the engine refuses
 for this family.
 
 Scopes: ``attn`` (attention branch), ``ssm`` (whole mixer) with
@@ -48,7 +48,7 @@ Scopes: ``attn`` (attention branch), ``ssm`` (whole mixer) with
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,21 +57,18 @@ from jax.sharding import PartitionSpec as P
 from ..engine.config import ModelConfig
 from ..ops.live_rows import decode_live_rows
 from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
-from . import llama
+from . import SequenceState, llama
 from .llama import (ATTN_LAYER_SPECS, base_specs, lm_logits,
                     make_gqa_attn_fn, rms_norm)
 from .quant import dense
 
 Params = Dict[str, Any]
 
-# the family keeps per-sequence state that is not pages: the engine sizes
-# it by slot (init_kv_cache's num_slots) and hands the trunk each row's
-# slot (forward's state_slots)
-RECURRENT_STATE = True
-# paths that move, share or roll back a sequence's pages without its
-# state, each refused by name at start-up (ModelRunner.refuse_without_state,
-# from the runner's and the scheduler's constructors): path -> reason
-RECURRENT_REFUSALS = {
+# what a sequence keeps besides its pages: records the engine sizes by
+# slot (init_kv_cache's num_slots; forward's state_slots names each
+# row's). Refused at start-up, by name: paths that move, share or roll
+# back a sequence's pages without its state, path -> reason
+_REFUSED = {
     "spec_ngram_tokens": "a rejected proposal rolls back pages; the "
                          "recurrent state has already absorbed it",
     "spec_draft_model": "a rejected draft token rolls back pages; the "
@@ -93,6 +90,70 @@ RECURRENT_REFUSALS = {
     "remote_prefill": "a prefill worker ships pages without the state",
     "migration": "a migrated sequence brings pages without the state",
 }
+SEQUENCE_STATE = SequenceState(
+    slots=True, keeps="recurrent state by slot beside the paged cache",
+    refused=_REFUSED)
+
+# published keys only this family computes (models.published):
+# its own, and those of other published trunks with recurrent layers
+CLAIMED_KEYS = (
+    "layers_block_type", "hybrid_override_pattern", "linear_num_value_heads",
+    "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
+    "conv_kernel", "d_state",
+)
+CLAIMED_PREFIXES = ("mamba_", "ssm_")
+# (a trunk with recurrent layers this program has no family for would
+# fall through to llama and serve nonsense)
+CLAIM = ("recurrent-layer keys ({keys}, ...) and no family here implements "
+         "it (falcon_h1 is the state-space family, models/falcon_h1.py; "
+         "minicpm_sala the linear-attention one, models/minicpm_sala.py)")
+
+
+def claimed_keys(config: dict) -> List[str]:
+    return sorted(k for k in config
+                  if k in CLAIMED_KEYS or k.startswith(CLAIMED_PREFIXES))
+
+
+def config_fields(config: dict) -> dict:
+    """ModelConfig's Falcon-H1 fields from the published keys; what the
+    family module does not compute is refused here, before any weight
+    is made."""
+    only = {
+        "mamba_rms_norm": True, "mamba_norm_before_gate": False,
+        "mamba_proj_bias": False, "mamba_conv_bias": True,
+        "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+        "rope_scaling": None, "attn_layer_indices": None,
+    }
+    for key, value in only.items():
+        if config.get(key, value) != value:
+            raise NotImplementedError(
+                f"falcon_h1 with {key}={config[key]!r} (models/falcon_h1.py "
+                f"computes {key}={value!r} only)")
+    d_ssm = int(config["mamba_d_ssm"])
+    heads, d_head = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    groups = int(config.get("mamba_n_groups", 1))
+    if heads * d_head != d_ssm or heads % groups:
+        raise ValueError(
+            f"falcon_h1: mamba_d_ssm {d_ssm} != mamba_n_heads {heads} x "
+            f"mamba_d_head {d_head}, or mamba_n_groups {groups} does not "
+            "divide the heads")
+    ssm_m = tuple(float(m) for m in config.get("ssm_multipliers", (1.0,) * 5))
+    mlp_m = tuple(float(m) for m in config.get("mlp_multipliers", (1.0, 1.0)))
+    if len(ssm_m) != 5 or len(mlp_m) != 2:
+        raise ValueError("falcon_h1: ssm_multipliers has 5 entries (z, x, B, "
+                         "C, dt) and mlp_multipliers 2 (gate, down)")
+    scalars = ("embedding_multiplier", "lm_head_multiplier",
+               "attention_in_multiplier", "attention_out_multiplier",
+               "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier")
+    return dict(
+        mamba_d_ssm=d_ssm, mamba_n_heads=heads, mamba_d_head=d_head,
+        mamba_d_state=int(config["mamba_d_state"]), mamba_n_groups=groups,
+        mamba_d_conv=int(config.get("mamba_d_conv", 4)),
+        mamba_chunk_size=int(config.get("mamba_chunk_size", 128)),
+        ssm_multipliers=ssm_m, mlp_multipliers=mlp_m,
+        **{k: float(config.get(k, 1.0)) for k in scalars},
+    )
+
 
 # standard deviation of the served logits under random weights
 LOGIT_STD = 2.0
@@ -117,8 +178,17 @@ class SlotCache:
     @property
     def dtype(self):
         """The pages' element type: what a caller that asks a side of
-        the cache for its dtype means (the records keep their own)."""
+        the cache for its dtype means (the records keep their own).
+        benchmark/run.py reads ``runner.kv_cache[0].dtype``."""
         return self.kv.dtype
+
+    @property
+    def pages(self):
+        return self.kv
+
+    @property
+    def rest(self):
+        return self.state
 
 
 CACHE_SPEC = SlotCache(kv=P(None, None, None, "tp", None), state=P())
@@ -224,7 +294,8 @@ def param_specs(params: Params) -> Dict:
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                  dtype=jnp.bfloat16, num_slots: int = 1):
+                  dtype=jnp.bfloat16, num_slots: int = 1,
+                  window_blocks: int = 1):
     """``(SlotCache(k pages, SSM state [L, slots, H, P, N] float32),
     SlotCache(v pages, conv window [L, slots, d_conv − 1, conv_dim]))``.
     The conv window keeps the trunk's dtype whatever the pages' (an fp8

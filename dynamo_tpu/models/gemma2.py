@@ -55,6 +55,12 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (n * (1.0 + weight.astype(jnp.float32))).astype(x.dtype)
 
 
+def config_fields(config: dict) -> dict:
+    """The window alternates by layer whatever ``use_sliding_window``
+    says (the common translation honours that key)."""
+    return {"sliding_window": config.get("sliding_window", 0) or 0}
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     l, d_model = cfg.num_layers, cfg.hidden_size
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -200,6 +206,7 @@ def forward(
     context_lens: jax.Array,  # [B]
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ) -> Tuple[jax.Array, KVCache]:
     b, s = tokens.shape
     with jax.named_scope("embed"):

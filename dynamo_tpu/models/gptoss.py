@@ -54,6 +54,32 @@ CACHE_SPEC = P(None, None, None, "tp", None)
 logits_from_hidden = lm_logits
 
 
+def config_fields(config: dict) -> dict:
+    """Nothing beyond the common keys; ``layer_types`` is held to the
+    alternation ``forward_counted`` computes (window = li % 2 == 0)."""
+    lt = config.get("layer_types")
+    want = ["sliding_attention" if i % 2 == 0 else "full_attention"
+            for i in range(len(lt or ()))]
+    if lt and list(lt) != want:
+        raise NotImplementedError(
+            "gpt-oss layer_types must alternate "
+            "sliding/full starting sliding at layer 0"
+        )
+    return {}
+
+
+def refuse_staged(config) -> None:
+    """What the family refuses under ``pp_size > 1``: the interleaved
+    gate/up stacks shard the 2I columns in contiguous chunks; whole
+    gate/up pairs (and their matching w_down rows) stay together only
+    when the expert width divides by tp."""
+    if config.tp_size > 1 and config.model.intermediate_size % config.tp_size:
+        raise ValueError(
+            f"gptoss intermediate_size {config.model.intermediate_size} "
+            f"not divisible by tp {config.tp_size}"
+        )
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     l, d_model = cfg.num_layers, cfg.hidden_size
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -204,6 +230,7 @@ def forward(
     context_lens: jax.Array,  # [B]
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ) -> Tuple[jax.Array, KVCache]:
     hidden, kv_cache, _ = forward_counted(
         params, cfg, tokens, positions, kv_cache, block_tables,

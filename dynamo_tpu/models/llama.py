@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -288,8 +288,11 @@ def param_specs(params: Params) -> Dict:
 
 
 def init_kv_cache(
-    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16
+    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
+    num_slots: int = 1, window_blocks: int = 1,
 ) -> KVCache:
+    # (num_slots, window_blocks: what the engine offers every family; one
+    # kind of page takes neither)
     # minor dim lane-padded: physically free (XLA tiles HBM to 128 lanes)
     # and required by the manual-DMA decode kernel (ops/attention.lane_pad)
     shape = (
@@ -297,6 +300,30 @@ def init_kv_cache(
         lane_pad(cfg.head_dim),
     )
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def run_specs(params: Params) -> Dict:
+    """Every weight replicated, for a trunk kept a run of layers
+    (``params["runs"]``: ``layer_runs``) whose family refuses tp and ep."""
+    specs = {k: P() for k in base_specs(params) if k in params}
+    specs["runs"] = [{k: P() for k in run} for run in params["runs"]]
+    return specs
+
+
+def layer_runs(kinds, index_key=lambda kind: kind) -> List[Tuple[Any, int, int]]:
+    """``kinds`` (one a layer) as runs of one kind, for a trunk that
+    scans each run over its own stacked weights: (kind, the run's first
+    index among the layers that share its ``index_key``: the layers
+    stacked in one cache, its length)."""
+    runs, seen = [], {}
+    for kind in kinds:
+        key = index_key(kind)
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(key, 0), 1])
+        seen[key] = seen.get(key, 0) + 1
+    return [tuple(r) for r in runs]
 
 
 def embed_tokens(params: Params, tokens: jax.Array) -> jax.Array:
@@ -654,6 +681,7 @@ def forward(
     context_lens: jax.Array,
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ) -> Tuple[jax.Array, KVCache]:
     """Llama forward = shared trunk with the dense SwiGLU MLP."""
     return decoder_forward(

@@ -40,13 +40,43 @@ update); ``mhc_fan`` around the fan-out and the read-out.
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..engine.config import ModelConfig
 from ..ops.sinkhorn import sinkhorn
+
+# published keys of a changed residual path, refused under any other
+# model_type (models.published, through models/deepseek.py): served
+# with a plain residual path they would give wrong tokens
+CLAIMED_PREFIXES = ("hc_", "mhc_", "hyper_connection")
+CLAIM = ("hyper-connection keys ({keys}) and no family here implements its "
+         "residual path (xing4_0 is the one family with mixed residual "
+         "streams: models/mhc.py)")
+
+
+def claimed_keys(config: dict) -> List[str]:
+    return sorted(k for k in config if k.startswith(CLAIMED_PREFIXES))
+
+
+def config_fields(config: dict) -> dict:
+    """ModelConfig's hyper-connection fields from the published keys of
+    ``model_type: xing4_0`` (latent attention with mixed residual
+    streams: models/deepseek.py over models/mhc.py)."""
+    if not (config.get("kv_lora_rank") or 0) > 0:
+        raise NotImplementedError(
+            "xing4_0 without kv_lora_rank: the mixed residual streams are "
+            "served over latent attention only (models/deepseek.py)")
+    return dict(
+        hc_mult=int(config.get("hc_mult", 1)),
+        hc_sinkhorn_iters=int(config.get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(config.get("hc_eps", 1e-6)),
+        hc_res_clamp=(float(config.get("mhc_h_res_clamp_min", -30.0)),
+                      float(config.get("mhc_h_res_clamp_max", 30.0))),
+    )
+
 
 SUBLAYERS = ("attn", "mlp")
 PARAM_KEYS = tuple(f"hc_{sub}_{part}" for sub in SUBLAYERS

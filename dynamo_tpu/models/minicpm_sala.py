@@ -39,7 +39,7 @@ value pages and, as its ``state``, the compressed keys: one float32 mean
 a page ``[A, N·KVH, D]``. The trunk scans each homogeneous run of
 ``mixer_types`` over that run's stacked weights (``params["runs"]``).
 
-The family keeps recurrent state, so it inherits ``RECURRENT_REFUSALS``
+The family keeps recurrent state, so it inherits ``SEQUENCE_STATE``
 and the engine's handling (state by slot, prefix hits blanked, resume
 from position 0) from Falcon-H1.
 
@@ -52,7 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -63,15 +63,76 @@ from ..ops import sparse_attention as sparse
 from ..ops.attention import lane_pad
 from ..ops.live_rows import decode_live_rows
 from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
-from .falcon_h1 import (RECURRENT_REFUSALS, SlotCache,  # noqa: F401
+from .falcon_h1 import (CLAIM, SEQUENCE_STATE, SlotCache,  # noqa: F401
                         _scaled, slot_records)
-from .llama import _swiglu_mlp, apply_rope, base_specs, lm_logits, rms_norm
+from .llama import (_swiglu_mlp, apply_rope, layer_runs, lm_logits,
+                    rms_norm, run_specs)
 from .quant import dense
 
 Params = Dict[str, Any]
 
-RECURRENT_STATE = True
 LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
+# published keys only this family computes, refused under any other
+# model_type with Falcon-H1's sentence (models.published)
+CLAIMED_KEYS = ("mixer_types",)
+CLAIMED_PREFIXES = ("lightning_",)
+
+
+def claimed_keys(config: dict) -> List[str]:
+    return sorted(k for k in config
+                  if k in CLAIMED_KEYS or k.startswith(CLAIMED_PREFIXES))
+
+
+def config_fields(config: dict) -> dict:
+    """ModelConfig's MiniCPM-SALA fields from the published keys; what
+    the family module does not compute is refused here, before any weight
+    is made. ``sparse_config`` (the MiniCPM4 family's published group)
+    and ``depth_cut`` (``{"of_layers", "first_layer"}``: which layers of
+    the published trunk a cut configuration holds) are optional groups."""
+    only = {
+        "attn_use_rope": False, "lightning_use_rope": True, "qk_norm": True,
+        "use_output_gate": True, "use_output_norm": True,
+        "attn_use_output_gate": True, "attention_bias": False,
+        "rope_scaling": None, "hidden_act": "silu",
+        "lightning_scale": "1/sqrt(d)",
+    }
+    for key, value in only.items():
+        if config.get(key, value) != value:
+            raise NotImplementedError(
+                f"minicpm_sala with {key}={config[key]!r} "
+                f"(models/minicpm_sala.py computes {key}={value!r} only)")
+    mixers = tuple(config.get("mixer_types") or ())
+    layers = int(config["num_hidden_layers"])
+    unknown = sorted(set(mixers) - {"lightning-attn", "minicpm4"})
+    if len(mixers) != layers or unknown:
+        raise ValueError(
+            f"minicpm_sala: mixer_types has {len(mixers)} entries for "
+            f"{layers} layers, unknown kinds {unknown} (lightning-attn | "
+            "minicpm4)")
+    heads = int(config.get("lightning_nh", config["num_attention_heads"]))
+    if int(config.get("lightning_nkv", heads)) != heads:
+        raise NotImplementedError(
+            "minicpm_sala with lightning_nkv != lightning_nh: the state is "
+            "kept a head (models/minicpm_sala.py)")
+    cut = config.get("depth_cut") or {}
+    sparse = config.get("sparse_config") or {}
+    fields = dict(
+        mixer_types=mixers, lightning_heads=heads,
+        lightning_head_dim=int(config.get("lightning_head_dim",
+                                          config.get("head_dim", 128))),
+        scale_emb=float(config.get("scale_emb", 1.0)),
+        scale_depth=float(config.get("scale_depth", 1.0)),
+        dim_model_base=int(config.get("dim_model_base",
+                                      config["hidden_size"])),
+        depth_of=int(cut.get("of_layers", layers)),
+        first_layer=int(cut.get("first_layer", 0)),
+    )
+    for key in ("kernel_size", "kernel_stride", "block_size", "topk",
+                "init_blocks", "window_size", "dense_len"):
+        if key in sparse:
+            fields[f"sparse_{key}"] = int(sparse[key])
+    return fields
+
 
 # standard deviation of the served logits, and of q·k / sqrt(d) in the
 # attention layers, under random weights (the query norm's weight: the
@@ -131,19 +192,6 @@ class SalaCache(SlotCache):
 CACHE_SPEC = SalaCache(kv=P(), state=P(), counts=P())
 
 
-def layer_runs(cfg: ModelConfig) -> List[Tuple[str, int, int]]:
-    """``mixer_types`` as runs of one kind: (kind, the run's first index
-    among the layers of its kind, its length)."""
-    runs, seen = [], {LIGHTNING: 0, SPARSE: 0}
-    for kind in cfg.mixer_types:
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, seen[kind], 1])
-        seen[kind] += 1
-    return [tuple(r) for r in runs]
-
-
 def log_decays(cfg: ModelConfig) -> jax.Array:
     """[layers, H] float32: ``ln λ_h`` of every layer (used by the
     lightning ones), from the published index and depth."""
@@ -177,7 +225,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
                 * (gain * fan_in ** -0.5)).astype(dtype)
 
     runs, at = [], 0
-    for r, (kind, _, n) in enumerate(layer_runs(cfg)):
+    for r, (kind, _, n) in enumerate(layer_runs(cfg.mixer_types)):
         keys = jax.random.split(jax.random.fold_in(key, r + 1), 8)
         qw, kw = (lh * ld, lh * ld) if kind == LIGHTNING else (h * hd, kvh * hd)
         hdim = ld if kind == LIGHTNING else hd
@@ -215,17 +263,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
-def param_specs(params: Params) -> Dict:
-    """Replicated: tp > 1 is refused for a family with state by slot."""
-    specs = base_specs(params)
-    specs["lm_head"] = P()
-    specs = {k: v for k, v in specs.items() if k in params}
-    specs["runs"] = [{k: P() for k in run} for run in params["runs"]]
-    return specs
+param_specs = run_specs    # tp > 1 is refused for state by slot
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                  dtype=jnp.bfloat16, num_slots: int = 1):
+                  dtype=jnp.bfloat16, num_slots: int = 1,
+                  window_blocks: int = 1):
     """``(SalaCache(k pages, lightning state, -), SalaCache(v pages,
     page means, counters))``; see the module docstring."""
     kinds = cfg.mixer_types
@@ -385,7 +428,8 @@ def forward(
         hidden = feed_forward(hidden + _scaled(delta, res), lp)
         return (hidden, k_pages, v_pages, means, kept, li + 1), None
 
-    for (kind, start, _), run in zip(layer_runs(cfg), params["runs"]):
+    for (kind, start, _), run in zip(layer_runs(cfg.mixer_types),
+                                     params["runs"]):
         if kind == LIGHTNING:
             (hidden, state, _), _ = jax.lax.scan(
                 lightning_layer, (hidden, state, jnp.int32(start)), run)

@@ -432,6 +432,7 @@ def forward(
     context_lens: jax.Array,  # [B]
     mesh=None,
     return_hidden: bool = False,
+    state_slots=None,         # a family with records by slot reads it
 ) -> Tuple[jax.Array, KVCache]:
     """Returns (logits [B, S, V], updated kv_cache): the shared decoder
     trunk (models/llama.py) with the routed-experts MLP. Bucket-padding

@@ -29,7 +29,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import hashlib
-import inspect
 import json
 import math
 import os
@@ -76,20 +75,19 @@ def lower_trunk(config_path, devices, tokens=1, width=256):
         lambda: arch.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
     params = placed(shapes, arch.param_specs(shapes))
     rows = serve["max_batch_size"] if tokens == 1 else 1
-    takes = inspect.signature(arch.init_kv_cache).parameters
-    slots = {"num_slots": serve["max_batch_size"]} if "num_slots" in takes else {}
-    if "window_blocks" in takes:
-        # two kinds of page (models/afmoe.py): the window kind's pool as
-        # the engine derives it, and a table a kind side by side
-        from dynamo_tpu.engine.config import EngineConfig
+    # what the engine offers every family (ModelRunner._init_device_state):
+    # its decode slots and the window pool's pages as it derives them
+    from dynamo_tpu.engine.config import EngineConfig
 
-        slots["window_blocks"] = EngineConfig(model=cfg, **{
-            k: v for k, v in serve.items()
-            if k in {f.name for f in dataclasses.fields(EngineConfig)}
-        }).window_pool_pages()
-        width *= 2
+    pool = EngineConfig(model=cfg, **{
+        k: v for k, v in serve.items()
+        if k in {f.name for f in dataclasses.fields(EngineConfig)}
+    }).window_pool_pages()
+    if getattr(arch, "SEQUENCE_STATE", models.PAGES_ONLY).window_pool:
+        width *= 2      # two kinds of page: a table a kind, side by side
     cache = jax.eval_shape(lambda: arch.init_kv_cache(
-        cfg, serve["num_kv_blocks"], 16, jnp.bfloat16, **slots))
+        cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+        num_slots=serve["max_batch_size"], window_blocks=pool))
     spec = getattr(arch, "CACHE_SPEC", CACHE_SPEC)
     cache = tuple(placed(side, spec) for side in cache)
 

@@ -462,20 +462,20 @@ def test_reference_tells_wrong_programs_apart(fault, monkeypatch):
 
 
 def test_a_dense_layer_is_not_routed_and_runs_follow_the_kinds():
-    """``layer_runs``: the first ``num_dense_layers`` layers dense, the
+    """``kind_runs``: the first ``num_dense_layers`` layers dense, the
     kinds by ``layer_types`` with period four, each run's first index
     counted among the layers of its attention kind."""
     cfg = _cfg()
-    assert afmoe.layer_runs(cfg) == [
-        (True, True, 0, 2), (True, False, 2, 1), (False, False, 0, 1),
-        (True, False, 3, 3), (False, False, 1, 1)]
+    assert afmoe.kind_runs(cfg) == [
+        ((True, True), 0, 2), ((True, False), 2, 1), ((False, False), 0, 1),
+        ((True, False), 3, 3), ((False, False), 1, 1)]
     params = afmoe.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     assert ["router" in run for run in params["runs"]] == [False, True, True,
                                                           True, True]
     assert params["runs"][0]["w_gate"].shape == (2, 64, 96)
     assert params["runs"][3]["w_gate"].shape == (3, 8, 64, 32)
     assert reference.runs_of(HF["layer_types"], 2) == [
-        (r[0], r[1], r[3]) for r in afmoe.layer_runs(cfg)]
+        (*kind, n) for kind, _, n in afmoe.kind_runs(cfg)]
     k_side, _ = afmoe.init_kv_cache(cfg, 10, PAGE, jnp.bfloat16, window_blocks=7)
     assert k_side.full.shape == (2, 10, PAGE, 2, 128)
     assert k_side.window.shape == (6, 7, PAGE, 2, 128)

@@ -1,8 +1,10 @@
 """Phi-3 (fused qkv/gate_up checkpoints) and Qwen3 (per-head q/k norms)
 — both served by the llama trunk, validated logit-exact vs HF."""
 
+import inspect
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -10,6 +12,7 @@ import pytest
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu import models
 from dynamo_tpu.models import llama, resolve
 from dynamo_tpu.models.loader import load_checkpoint_params
 
@@ -247,3 +250,81 @@ async def test_qwen3_engine_greedy_matches_hf(qwen3_dir):
 
     _, hf_gen = _hf_reference(qwen3_dir, Qwen3ForCausalLM)
     assert await _engine_greedy(qwen3_dir, 8) == hf_gen
+
+
+# ---------- the table of families (models/__init__.py) ----------
+
+# a published config no row names, and nothing a family claims in it
+PLAIN_HF = {"model_type": "some_other_trunk",
+            "architectures": ["SomeOtherForCausalLM"], "vocab_size": 64,
+            "hidden_size": 32, "intermediate_size": 64,
+            "num_hidden_layers": 2, "num_attention_heads": 2}
+# a ModelConfig with the row's own field set
+# the surface as models/__init__.py states it: the names of its required
+# paragraph, and those each optional bullet gives before its colon
+_REQUIRED_DOC, _OPTIONAL_DOC = models.__doc__.split("**Required**")[1].split(
+    "**Optional**")
+_NAME = re.compile(r"``(\w+)[(`]")
+REQUIRED = set(_NAME.findall(_REQUIRED_DOC)) - {"ModelRunner"}
+OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
+            for name in _NAME.findall(bullet.split(":")[0])}
+FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64,
+                "mixer_types": ("minicpm4", "lightning-attn"),
+                "layer_types": ("sliding_attention", "full_attention")}
+
+
+@pytest.mark.parametrize("row", models.FAMILIES, ids=lambda row: row.name)
+def test_a_family_is_one_module_and_one_row(row):
+    """A case a row: the module has the required surface, in the one
+    signature the engine calls (what else the engine asks a family for
+    is held to the docstring's list below); every
+    published key the row claims is refused by name under another
+    ``model_type``; ``resolve`` refuses the row's field when the row is
+    not the one selected."""
+    module = row.module
+    assert module.__name__ == f"dynamo_tpu.models.{row.name}"
+    assert REQUIRED == {"init_params", "param_specs", "init_kv_cache",
+                        "forward", "logits_from_hidden"}
+    for name in REQUIRED:
+        assert callable(getattr(module, name)), name
+    for name in ("num_slots", "window_blocks"):
+        assert name in inspect.signature(module.init_kv_cache).parameters
+    assert "state_slots" in inspect.signature(module.forward).parameters
+    if row.model_types or row.architecture:
+        assert callable(module.config_fields)
+    if row.staged:
+        assert getattr(module, "SEQUENCE_STATE", models.PAGES_ONLY) \
+            is models.PAGES_ONLY
+    if not row.field:
+        assert not hasattr(module, "claimed_keys")
+        return
+    claimed = (tuple(getattr(module, "CLAIMED_KEYS", ()))
+               + tuple(p + "x" for p in getattr(module, "CLAIMED_PREFIXES", ())))
+    assert claimed
+    for key in claimed:
+        with pytest.raises(NotImplementedError,
+                           match=f"some_other_trunk.*{key}"):
+            ModelConfig.from_hf_config({**PLAIN_HF, key: 2})
+    assert ModelConfig.from_hf_config(PLAIN_HF).model_family == ""
+    stray = ModelConfig(**{row.field: FIELD_VALUES[row.field]})
+    assert stray.model_family == ""
+    with pytest.raises(NotImplementedError, match=row.field):
+        models.resolve(stray)
+
+
+def test_the_engine_asks_a_family_for_listed_names_only():
+    """``engine/`` and ``parallel/`` read a family's module under the
+    names ``models/__init__.py`` lists, and name no family's module."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    listed = REQUIRED | OPTIONAL | {"__name__"}
+    names = "|".join(row.name for row in models.FAMILIES if row.name != "llama")
+    for rel in ("engine/model_runner.py", "engine/scheduler.py",
+                "engine/config.py", "parallel/pipeline.py"):
+        with open(os.path.join(root, "dynamo_tpu", rel)) as f:
+            src = f.read()
+        asked = set(re.findall(r'getattr\((?:self\.)?arch,\s*"(\w+)"', src))
+        asked |= set(re.findall(r"\b(?:self\.)?arch\.(\w+)", src))
+        assert asked <= listed, (rel, asked - listed)
+        code = "\n".join(line.split("#")[0] for line in src.splitlines())
+        code = re.sub(r'"""(?:.|\n)*?"""', "", code)
+        assert not re.search(rf"models(?: import|\.)\s*(?:{names})\b", code), rel
