@@ -1,0 +1,46 @@
+"""``sdar-reasoning`` at tiny widths on the CPU (``--cpu-rehearsal``): the
+``sdar_moe`` shape of the configuration's ``rehearsal`` group (three
+layers of 8 experts, blocks of four, two denoise passes and a commit pass
+a block) served through the harness with the kernels in the interpreter
+(the flash kernel in prefill, the verify kernel in every block pass,
+both under the block mask), against ``references/sdar.py``. Like
+``test_rehearsal.py`` it says nothing about the chip and stays out of
+tier-1 (about two minutes).
+"""
+
+import pytest
+
+from harness import manifest
+from test_rehearsal import ROOT, _dry_result, _run
+
+CELL = "sdar-reasoning"
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_sdar_rehearsal(trace):
+    man = manifest.load_manifest()
+    res = _dry_result(_run(ROOT, "--workload", CELL, "--seed", "2147483659",
+                           "--seconds", "5", "--trace", str(trace),
+                           "--cpu-rehearsal"))
+    assert res["correct"] is True and res["failed"] == 0 and res["attempted"] > 0
+    assert res["reference"]["name"] == "sdar"
+    # float32 at tiny widths: the served path (pages, the kernels' walk,
+    # sorted rows of experts, a pass a state) and the reference (a masked
+    # product, every expert on every token, every state in one forward)
+    # agree far inside the limits measured for bfloat16 on the chip
+    assert res["reference"]["max_abs_err"] < 1e-3
+    assert res["compiles_in_window"] == 0
+    want = manifest.load_cell(CELL)
+    if trace:
+        got = set(res["metrics"])
+        device = {m["name"] for m in man["per_layer"] if m["source"] == "device_trace"}
+        assert not got & device
+        assert got == {m.name for m in want.per_layer} - device
+        # two denoise passes and a commit pass a block, fewer where a
+        # prompt's tail opened it; a commit row emits its block
+        assert 2.0 < res["metrics"]["block_passes_per_block"]["value"] <= 3.0
+        assert 1.0 < res["metrics"]["block_tokens_per_row_pass"]["value"] < 2.0
+        assert 33.0 <= res["metrics"]["block_commit_pass_share"]["value"] < 50.0
+        assert res["metrics"]["preemptions"]["value"] == 0
+    else:
+        assert set(res["metrics"]) == {m.name for m in want.end_to_end}

@@ -142,6 +142,18 @@ class ModelConfig:
     # "full_attention" (every key, no positional term). Empty for every
     # other family (Gemma-2 and GPT-OSS alternate by the layer's index).
     layer_types: Tuple[str, ...] = ()
+    # SDAR (models/sdar.py, model_type "sdar_moe"): generation by diffusion
+    # over blocks of block_length positions. A block's positions hold
+    # mask_token_id until a denoise pass unmasks them, denoising_steps
+    # passes a block, which positions by remasking_strategy ("sequential",
+    # "low_confidence_static", "low_confidence_dynamic" with
+    # confidence_threshold). block_length 0: one token a row a pass, as
+    # every other family decodes.
+    block_length: int = 0
+    mask_token_id: int = -1
+    denoising_steps: int = 0
+    remasking_strategy: str = ""
+    confidence_threshold: float = 0.0
 
     def __post_init__(self):
         if self.head_dim is None:

@@ -34,7 +34,8 @@ from ..telemetry.registry import Counter
 from .config import EngineConfig
 from .device import check_serving_device
 from . import step_inputs
-from .sampling import SamplingParams, sample, top_logprobs_for
+from .sampling import (SamplingParams, block_select, sample,
+                       sample_block_positions, top_logprobs_for)
 
 logger = logging.getLogger(__name__)
 
@@ -180,6 +181,27 @@ class ModelRunner:
         # pytree) and the paths refused for it, its module's declaration
         self.keeps: models.SequenceState = getattr(
             self.arch, "SEQUENCE_STATE", models.PAGES_ONLY)
+        # the family's decode unit where it is a block of positions and
+        # not one token (models.BlockUnit): the block pass is then the one
+        # decode program, and what assumes a token a row a pass is refused
+        self.unit: Optional[models.BlockUnit] = getattr(
+            self.arch, "decode_unit", lambda cfg: None)(cfg)
+        if self.unit is not None:
+            from ..ops.pallas_decode import VERIFY_MAX_S
+
+            # a block pass is one call of the verify kernel; a block never
+            # straddles a page; a prefill chunk ends at a block's edge
+            if not 1 < self.unit.length <= VERIFY_MAX_S:
+                raise ValueError(
+                    f"block length {self.unit.length}: a block pass is one "
+                    f"verify-kernel call (1 < S <= {VERIFY_MAX_S})")
+            for what, n in (("kv_block_size", config.kv_block_size),
+                            *(("a prefill bucket", b)
+                              for b in config.prefill_buckets)):
+                if n % self.unit.length:
+                    raise ValueError(
+                        f"{what} of {n} is not whole blocks of "
+                        f"{self.unit.length}")
         for path, on in (
             ("spec_ngram_tokens", config.spec_ngram_tokens > 0),
             ("spec_draft_model", bool(config.spec_draft_model)),
@@ -434,6 +456,7 @@ class ModelRunner:
         self._init_moe_counters()
         self._init_family_counters()
         self._build_step()
+        self._build_block_step()
         self._build_burst()
         self._build_spec_burst()
         self._build_sp_prefill()
@@ -453,6 +476,13 @@ class ModelRunner:
             raise ValueError(
                 f"{path} is refused for the {self.family.name} family, "
                 f"which keeps {self.keeps.keeps}: {why}"
+            )
+        why = None if self.unit is None else self.unit.refused.get(path)
+        if why is not None:
+            raise ValueError(
+                f"{path} is refused for the {self.family.name} family, "
+                f"whose decode unit is a block of {self.unit.length} "
+                f"positions: {why}"
             )
 
     # ---------- routed experts' counters ----------
@@ -706,6 +736,112 @@ class ModelRunner:
             prefill_step, static_argnums=(0,),
             donate_argnums=(2, 3, 4, 5, 6), **jit_kw)
 
+    # ---------- the block pass (a family whose decode unit is a block) ----------
+
+    def _build_block_step(self):
+        """``jit_decode_block``: one pass over ``[rows, B]`` positions of a
+        family whose decode unit is a block (``self.unit``). The trunk
+        writes the block's keys and values into its own slots (a commit
+        row's stay; a denoise row's are overwritten by its next pass) and
+        attends under the block mask; the head, sampling and the chosen
+        token's log-probability run at every position (scope
+        ``sampling``); the confidence and the choice of what this pass
+        unmasks follow (scope ``block_select``). A row whose block is
+        whole is a commit row of the same program: its quota is 0 and
+        nothing of its sampling output is taken. The packed input is the
+        step's (step_inputs.py) at ``S = B``, its ``last_idx`` column
+        carrying the row's quota and ``counters`` the pass's number within
+        its block."""
+        unit = self.unit
+        self._decode_block = None
+        if unit is None:
+            return
+        cfg = self.config.model
+        mesh = self.mesh
+        batch_spec = NamedSharding(mesh, P("dp"))
+        batch2_spec = NamedSharding(mesh, P("dp", None))
+        batch3_spec = NamedSharding(mesh, P("dp", None, None))
+        repl = NamedSharding(mesh, P())
+        moe = self.moe_counts is not None
+        forward, head = self._make_forward(counted=moe)
+        blen = unit.length
+
+        def decode_block(params, k_cache, v_cache, packed, *moe_counts):
+            inp = step_inputs.unpack(packed, blen)
+            hidden, (k_cache, v_cache), *moe_step = forward(
+                params, (k_cache, v_cache), inp.tokens, inp.positions,
+                inp.block_tables, inp.slot_mapping, inp.context_lens,
+                inp.sample_slots,
+            )
+            rows = inp.tokens.shape[0]
+            logits = head(hidden.reshape(rows * blen, -1), params)
+            x0, lps, top_vals, top_ids = sample_block_positions(
+                cfg, logits, inp.samp, inp.positions, inp.want_top,
+                unit.mask_id)
+            new_ids, taken, left = block_select(
+                inp.tokens, x0.reshape(rows, blen), lps.reshape(rows, blen),
+                inp.last_idx, unit)
+            out = (new_ids, jnp.where(taken, lps.reshape(rows, blen), 0.0),
+                   top_vals.reshape(rows, blen, -1),
+                   top_ids.reshape(rows, blen, -1), left, k_cache, v_cache)
+            if moe_counts:
+                row = jnp.concatenate(
+                    [moe_step[0], jnp.ones((1,), jnp.int32)])
+                out += (moe_counts[0].at[0].add(row),)
+            return out
+
+        self._decode_block = jax.jit(
+            decode_block, donate_argnums=(1, 2),
+            in_shardings=(self.param_shardings, self.cache_sharding,
+                          self.cache_sharding, batch2_spec)
+            + ((repl,) if moe else ()),
+            out_shardings=(batch2_spec, batch2_spec, batch3_spec,
+                           batch3_spec, batch_spec, self.cache_sharding,
+                           self.cache_sharding) + ((repl,) if moe else ()),
+        )
+
+    def decode_block(
+        self,
+        tokens: np.ndarray,        # [B, L] the block's ids, mask id where masked
+        positions: np.ndarray,     # [B, L]
+        block_tables: np.ndarray,  # [B, W]
+        slot_mapping: np.ndarray,  # [B, L]
+        context_lens: np.ndarray,  # [B] the block's end
+        quota: np.ndarray,         # [B] positions this pass unmasks; 0: commit
+        temperature: np.ndarray,
+        top_k: np.ndarray,
+        top_p: np.ndarray,
+        *,
+        min_p: Optional[np.ndarray] = None,
+        seed_keys: Optional[np.ndarray] = None,
+        counters: Optional[np.ndarray] = None,    # [B] the pass within its block
+        want_top: bool = False,
+    ) -> Tuple[jax.Array, ...]:
+        """Run one block pass; returns device arrays (the block's new ids
+        [B, L], the log-probabilities of the positions unmasked in this
+        pass [B, L] and 0 elsewhere, top alternatives [B, L, K] twice,
+        the count still masked [B])."""
+        b, s = tokens.shape
+        width = block_tables.shape[1]
+        if seed_keys is None:
+            seed_keys = np.zeros(2, np.uint32)
+        buf = step_inputs.pack(
+            tokens, positions, block_tables, slot_mapping,
+            keys=seed_keys, want_top=want_top, context_lens=context_lens,
+            last_idx=quota, counters=counters, top_k=top_k,
+            temperature=temperature, top_p=top_p, min_p=min_p,
+        )
+        with self._track("decode_block", f"b{b}_s{s}_w{width}", arrays=1):
+            moe = () if self.moe_counts is None else (self.moe_counts,)
+            new_ids, lps, top_vals, top_ids, left, k, v, *moe = (
+                self._decode_block(
+                    self.params, *self.kv_cache,
+                    jax.device_put(buf, self._packed_sharding), *moe))
+        self.kv_cache = (k, v)
+        if moe:
+            self.moe_counts = moe[0]
+        return new_ids, lps, top_vals, top_ids, left
+
     def _build_burst(self):
         """K fused decode steps per dispatch (config.multi_step_decode).
 
@@ -829,6 +965,8 @@ class ModelRunner:
         # engine/guided.compile_device_table). Rows with gstate < 0 are
         # unguided and never consult the table.
         from .sampling import (
+    block_select,
+    sample_block_positions,
             device_finish_mask,
             ring_push,
             stop_candidate_mask,
@@ -2010,7 +2148,18 @@ class ModelRunner:
         # zero rows to slot 0 is inert, admission overwrites them)
         self.set_sample_row(0, [])
         zeros2 = np.zeros((b, 1), np.int32)
-        for w in self.config.kv_width_buckets():
+        if self.unit is not None:
+            # the block pass is the family's one decode program (inert:
+            # every slot is the drop sentinel, every quota 0)
+            zb = np.zeros((b, self.unit.length), np.int32)
+            for w in self.config.kv_width_buckets():
+                self.decode_block(
+                    zb, zb, np.zeros((b, w), np.int32), np.full_like(zb, -1),
+                    np.ones(b, np.int32), np.zeros(b, np.int32),
+                    np.zeros(b, np.float32), np.zeros(b, np.int32),
+                    np.ones(b, np.float32),
+                )
+        for w in self.config.kv_width_buckets() if self.unit is None else ():
             self.step(
                 zeros2, zeros2, np.zeros((b, w), np.int32),
                 np.full((b, 1), -1, np.int32),

@@ -485,3 +485,88 @@ def top_logprobs_for(logits: jax.Array, logp: Optional[jax.Array] = None) -> tup
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     vals, ids = jax.lax.top_k(logp, top_k_width(logits.shape[-1]))
     return vals, ids.astype(jnp.int32)
+
+
+# ---- a block pass (a family whose decode unit is a block) ----
+#
+# models.BlockUnit: a row's pending unit is L ids, each a token or the
+# mask id. A pass samples every position of the block and then chooses
+# which masked positions take their sample.
+
+# a position's fold-in counter is its absolute position times this, plus
+# the pass's number within its block (at most L + 1 <= 33 passes a block)
+_PASS_STRIDE = 64
+
+
+@jax.named_scope("sampling")
+def sample_block_positions(cfg, logits: jax.Array, params: SamplingParams,
+                           positions: jax.Array, want_top: jax.Array,
+                           mask_id: int):
+    """Sampling at every position of every row's block: ``logits``
+    [R·L, V] in row-major order of ``positions`` [R, L], the row's
+    sampling parameters at each of its positions (temperature, top-k,
+    top-p and min-p; the penalties and the bias rows are refused for such
+    a family). The mask id is never a prediction: its logit is -inf
+    before sampling and before the log-softmax, so that a sampled token
+    cannot read as still masked. Returns (tokens [R·L], their
+    log-probabilities [R·L], top alternatives [R·L, K] twice, zeros
+    unless ``want_top``)."""
+    length = positions.shape[1]
+    v = logits.shape[-1]
+    logits = jnp.where(jnp.arange(v) == mask_id, -jnp.inf,
+                       logits.astype(jnp.float32))
+    at_position = jax.tree_util.tree_map(
+        lambda x: jnp.repeat(x, length, axis=0), params)
+    at_position = dataclasses.replace(
+        at_position, counters=positions.reshape(-1) * _PASS_STRIDE
+        + at_position.counters)
+    tokens = sample(logits, at_position)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    lps = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
+    kw = top_k_width(cfg.vocab_size)
+    top_vals, top_ids = jax.lax.cond(
+        want_top,
+        lambda lp_: top_logprobs_for(logits, lp_),
+        lambda lp_: (jnp.zeros((lp_.shape[0], kw), jnp.float32),
+                     jnp.zeros((lp_.shape[0], kw), jnp.int32)),
+        logp,
+    )
+    return tokens, lps, top_vals, top_ids
+
+
+@jax.named_scope("block_select")
+def block_select(ids: jax.Array, sampled: jax.Array, lps: jax.Array,
+                 quota: jax.Array, unit):
+    """Which masked positions of each row's block take their sample in
+    this pass, by ``unit.strategy`` (models.REMASKING, the published
+    rules), ``quota`` [R] of them a row (0: a commit row, or a row that
+    holds nothing):
+
+    - ``sequential``: the first ``quota`` masked positions, left to right;
+    - ``low_confidence_static``: the ``quota`` masked positions of highest
+      confidence (ties to the left);
+    - ``low_confidence_dynamic``: every masked position whose confidence
+      is over ``unit.threshold`` if there are at least ``quota`` of them,
+      else as static.
+
+    The confidence of a position is the probability of its sampled token,
+    ``exp(lps)``, computed whatever the rule. ``ids``, ``sampled``,
+    ``lps``: [R, L]. Returns (the block's new ids, the positions taken
+    [R, L] bool, the count still masked [R])."""
+    length = ids.shape[1]
+    masked = ids == unit.mask_id
+    confidence = jnp.where(masked, jnp.exp(lps), -1.0)
+    offs = jnp.arange(length)
+    order = (jnp.broadcast_to(-offs.astype(jnp.float32), confidence.shape)
+             if unit.strategy == "sequential" else confidence)
+    # a position's rank among its row's masked positions: how many of them
+    # come before it in the order (a higher key, ties to the left)
+    k_i, k_j = order[:, :, None], order[:, None, :]
+    before = masked[:, None, :] & (
+        (k_j > k_i) | ((k_j == k_i) & (offs[None, None, :] < offs[None, :, None])))
+    taken = masked & (before.sum(-1) < quota[:, None])
+    if unit.strategy == "low_confidence_dynamic":
+        over = masked & (confidence > unit.threshold)
+        taken = jnp.where((over.sum(-1) >= quota)[:, None], over, taken)
+    new_ids = jnp.where(taken, sampled, ids)
+    return new_ids, taken, (new_ids == unit.mask_id).sum(-1).astype(jnp.int32)

@@ -268,6 +268,18 @@ class EngineRequest:
     # memoized guided-table cache key (the trie key is a tuple over
     # every choice's token ids — too heavy to rebuild twice per pass)
     guided_key: Optional[tuple] = None
+    # a family whose decode unit is a block (models.BlockUnit): the block
+    # in flight at [context_len, context_len + L), each id a token or the
+    # mask id; its first ``block_first`` ids are the prompt's tail (not
+    # generated, never emitted); the passes made over it so far; and for
+    # each unmasked position its log-probability, top alternatives and
+    # the pass that unmasked it (-1: the prompt's)
+    block: List[int] = dataclasses.field(default_factory=list)
+    block_first: int = 0
+    block_pass: int = 0
+    block_lps: List = dataclasses.field(default_factory=list)
+    block_tops: List = dataclasses.field(default_factory=list)
+    block_passes: List[int] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         self.classify_finish()
@@ -518,10 +530,13 @@ class Scheduler:
         # preemption, by prefilling from position 0 (where a trunk with
         # records by slot zeroes its slot's)
         keeps = getattr(runner, "keeps", PAGES_ONLY)
+        # the family's decode unit where it is a block of positions
+        # (models.BlockUnit): the block pass is then the decode pass
+        self.unit = getattr(runner, "unit", None)
         self.private_pages = keeps.private
         # (the two counters of a family with records by slot)
         self.recurrent = keeps.slots
-        if self.private_pages and disagg is not None:
+        if (self.private_pages or self.unit is not None) and disagg is not None:
             runner.refuse_without_state("remote_prefill")
         self.disagg = disagg
         # flight recorder: the process-wide engine-event ring every layer
@@ -752,6 +767,28 @@ class Scheduler:
             "Accepted speculative tokens per propose-verify round "
             "(chained in-carry rounds; proposals that verify on-chip)",
             buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0),
+        )
+        # a family whose decode unit is a block (all stay 0 otherwise)
+        self._block_row_passes = reg.counter(
+            "dynamo_scheduler_block_row_passes_total",
+            "Rows of block passes that held a sequence, by kind=denoise "
+            "(the pass unmasked some of the block's positions and emitted "
+            "nothing) | commit (the block was whole: its keys and values "
+            "were kept and its tokens emitted)",
+        )
+        self._blocks_completed = reg.counter(
+            "dynamo_scheduler_blocks_completed_total",
+            "Blocks committed (one commit pass each)",
+        )
+        self._block_tokens = reg.counter(
+            "dynamo_scheduler_block_tokens_emitted_total",
+            "Tokens emitted by commit passes (a block's generated positions "
+            "up to a finish inside it)",
+        )
+        self._block_passes_hist = reg.histogram(
+            "dynamo_engine_block_denoise_length",
+            "Denoise passes a committed block took (a length in passes)",
+            buckets=tuple(float(i) for i in range(1, 34)),
         )
         self._preemptions = reg.counter(
             "dynamo_scheduler_preemptions_total",
@@ -1390,18 +1427,30 @@ class Scheduler:
     def _emit(self, er: EngineRequest, token: int, logprob: Optional[float],
               top: Optional[dict] = None,
               prompt_lps: Optional[list] = None) -> None:
+        self._emit_tokens(er, [token],
+                          None if logprob is None else [logprob], [top],
+                          prompt_lps)
+
+    def _emit_tokens(self, er: EngineRequest, tokens: List[int],
+                     logprobs: Optional[List[float]], tops: List,
+                     prompt_lps: Optional[list] = None) -> None:
+        """One ``EngineOutput`` of ``tokens`` (one, or a committed
+        block's). A chunk of k tokens is k gaps of a k-th each in the
+        inter-token histogram, as the benchmark's client counts it."""
         now = time.monotonic()
         if er.last_emit_t:
-            self._itl_hist.observe(now - er.last_emit_t)
+            for _ in tokens:
+                self._itl_hist.observe((now - er.last_emit_t) / len(tokens))
         else:
             er.ctx.add_stage("first_token")
         er.last_emit_t = now
         out = EngineOutput(
-            token_ids=[token],
+            token_ids=list(tokens),
             finish_reason=er.finish,
             logprobs=(
-                [TokenLogprob(token, logprob, top)]
-                if logprob is not None else None
+                [TokenLogprob(t, lp, top)
+                 for t, lp, top in zip(tokens, logprobs, tops)]
+                if logprobs is not None else None
             ),
             prompt_logprobs=prompt_lps,
         )
@@ -1447,10 +1496,22 @@ class Scheduler:
         implementation behind the synchronous decode loop, the
         speculative accept loop, and the pipeline's reconciliation —
         one copy, so the paths' streams cannot drift."""
-        er.seq.push(er.pending_token)
+        self._commit_kv(er, er.pending_token)
+        er.pending_token = token
+        self._note_token(er, token)
+
+    def _commit_kv(self, er: EngineRequest, token: int) -> None:
+        """``token``'s keys and values are written for good: the host's
+        mirror of the cache takes it and a page it completes is
+        registered."""
+        er.seq.push(token)
         er.context_len += 1
         self._register_completed_blocks(er)
-        er.pending_token = token
+
+    def _note_token(self, er: EngineRequest, token: int) -> None:
+        """``token`` is generated output: counts, the stop-string ring and
+        the finish checks see it (every decode path's tokens pass here,
+        in order)."""
         er.generated += 1
         er.decode_tokens += 1
         # the ring tail mirrors the burst carry's suffix ring (ends with
@@ -1723,6 +1784,8 @@ class Scheduler:
                     active = [er for er in active if er.finish is None]
                     if not active:
                         pass
+                    elif self.unit is not None:
+                        await self._decode_block(loop, active)
                     elif spec_now:
                         # speculative verify (ngram or draft-model
                         # proposals) on the host sync path
@@ -1954,6 +2017,10 @@ class Scheduler:
         what remains is named here and counted per sync pass
         (dynamo_engine_sync_fallback_total{reason})."""
         cfg = self.config
+        if self.unit is not None:
+            # the chain carries one pending token a row on the device;
+            # this family's rows hold a block in flight
+            return "block_unit"
         if not runner_idle:
             return "not_idle"
         if not active:
@@ -2917,6 +2984,12 @@ class Scheduler:
             er.block_ids, er.num_cached = self.allocator.allocate_prompt(tokens_all)
         if not er.remote_attempted:  # remote fallback already counted itself
             self._count_prefix_lookup(er, len(tokens_all))
+        if self.unit is not None:
+            # a block family prefills whole blocks only; the rest of the
+            # prompt opens the first block, already unmasked
+            whole = len(tokens_all) - len(tokens_all) % self.unit.length
+            self._open_block(er, tokens_all[whole:])
+            tokens_all = tokens_all[:whole]
         er.prefill_tokens = tokens_all
         er.prefill_pos = er.num_cached
         er.context_len = er.num_cached
@@ -2962,6 +3035,11 @@ class Scheduler:
             # long-context admission class: the whole mesh prefills this
             # one prompt, a sequence-sharded chunk per pass
             self.sp_queue.append(er)
+        elif self.unit is not None and er.prefill_pos >= len(er.prefill_tokens):
+            # nothing to prefill (a prompt shorter than a block, or whole
+            # blocks all found in the prefix cache): straight to decode
+            self._register_completed_blocks(er)
+            self._end_block_prefill(er)
         else:
             self.prefilling.append(er)
 
@@ -3347,6 +3425,14 @@ class Scheduler:
                 if final:
                     finals.append(i)
         if not finals:
+            return
+        if self.unit is not None:
+            # a block family's prefill samples nothing: its first tokens
+            # come from the first block's passes, so there is nothing to
+            # fetch and the rows go on to decode
+            for i in finals:
+                self.prefilling.remove(plan[i][0])
+                self._end_block_prefill(plan[i][0])
             return
 
         # every device→host transfer off the event loop: any accumulated
@@ -3894,6 +3980,163 @@ class Scheduler:
                     if er.finish is not None:
                         self._finish(er, er.finish, emit=False)
 
+    # ---------- the block pass (a family whose decode unit is a block) ----------
+
+    def _open_block(self, er: EngineRequest, opening: List[int]) -> None:
+        """The row's next block: ``opening`` (the prompt's tail, already
+        unmasked; nothing after the first block) and masks."""
+        unit = self.unit
+        er.block = list(opening) + [unit.mask_id] * (unit.length - len(opening))
+        er.block_first = len(opening)
+        er.block_pass = 0
+        er.block_lps = [None] * unit.length
+        er.block_tops = [None] * unit.length
+        er.block_passes = [-1] * unit.length
+
+    def _end_block_prefill(self, er: EngineRequest) -> None:
+        """A block family's prompt is in the cache up to its last whole
+        block: the row decodes from the next pass on (no token was
+        sampled; the first ones come with the first block's commit)."""
+        er.ctx.add_stage("prefill")
+        if er.max_new == 0:
+            er.finish = FinishReason.LENGTH
+            self._finish(er, er.finish)
+
+    def _commit_block(self, er: EngineRequest) -> None:
+        """The commit pass found ``er.block`` whole: its keys and values
+        stay, its generated tokens pass the shared commit in order
+        (counts, the stop-string ring, the finish checks) and leave in
+        one ``EngineOutput``; a finish inside the block drops the rest of
+        it. The next block opens behind it."""
+        unit = self.unit
+        for token in er.block:
+            self._commit_kv(er, token)
+        self._blocks_completed.inc()
+        self._block_passes_hist.observe(float(er.block_pass))
+        sent = []
+        for o in range(er.block_first, unit.length):
+            self._note_token(er, er.block[o])
+            sent.append(o)
+            if er.finish is not None:
+                break
+        if (er.finish is None
+                and er.context_len + unit.length > self.config.max_model_len):
+            er.finish = FinishReason.LENGTH   # no room for one more block
+        self._block_tokens.inc(len(sent))
+        self.flight.record(
+            "scheduler.block_commit", request_id=er.request_id,
+            start=er.context_len - unit.length,
+            passes=[er.block_passes[o] for o in sent],
+        )
+        if sent:
+            self._emit_tokens(
+                er, [er.block[o] for o in sent],
+                [er.block_lps[o] for o in sent] if er.want_logprobs else None,
+                [er.block_tops[o] for o in sent])
+        if er.finish is not None:
+            self._finish(er, er.finish, emit=not sent)
+        else:
+            self._open_block(er, [])
+
+    async def _decode_block(self, loop, active: List[EngineRequest]) -> None:
+        """One block pass over every decoding row (``jit_decode_block``):
+        a row whose block holds a mask is a denoise row (the pass unmasks
+        its quota of positions and nothing is emitted), a row whose block
+        is whole is a commit row (``_commit_block``). Rows of one pass
+        are at different phases. Pages are taken for the whole block
+        ahead; a block never straddles a page."""
+        cfg, unit = self.config, self.unit
+        b, bs, length = cfg.max_batch_size, cfg.kv_block_size, unit.length
+        quotas = unit.quotas()
+        n_commit = sum(unit.mask_id not in er.block for er in active)
+
+        with span("sched.decode.build", step=self.passes, rows=len(active),
+                  denoise_rows=len(active) - n_commit, commit_rows=n_commit):
+            for er in list(active):
+                if not self._ensure_block_for(er, er.context_len + length - 1):
+                    # out of memory: back to waiting, between blocks (the
+                    # block in flight is dropped; none of it was emitted)
+                    logger.warning("KV OOM: preempting %s", er.request_id)
+                    self._preempt(er)
+                    active.remove(er)
+            self.allocator.flush_offload()
+            if not active:
+                return
+            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+
+            hs = self._host
+            tokens = np.zeros((b, length), np.int32)
+            positions = np.zeros((b, length), np.int32)
+            slot_map = np.full((b, length), -1, np.int32)
+            ctx_lens = np.ones(b, np.int32)
+            quota = np.zeros(b, np.int32)
+            passes = np.zeros(b, np.int32)
+            offs = np.arange(length)
+            for er in active:
+                i, n = er.slot, er.context_len
+                hs.sync_blocks(er)
+                tokens[i] = er.block
+                positions[i] = n + offs
+                slot_map[i] = er.block_ids[n // bs] * bs + n % bs + offs
+                ctx_lens[i] = n + length
+                masked = er.block.count(unit.mask_id)
+                # a pass past the schedule's end (the dynamic rule never
+                # needs one) takes what is left
+                quota[i] = min(masked, quotas[er.block_pass]
+                               if er.block_pass < len(quotas) else masked)
+                passes[i] = er.block_pass
+            btab = hs.btab[:, :w].copy()
+            want_top = any(er.logprobs_n > 0 for er in active)
+            if self._last_burst_done_t is not None:
+                self._bubble_hist.observe(
+                    time.monotonic() - self._last_burst_done_t)
+                self._last_burst_done_t = None
+            self.flight.record(
+                "scheduler.burst_dispatch", k_steps=1, rows=len(active),
+                requests=[er.request_id for er in active[:8]],
+            )
+        with span("sched.decode.dispatch", step=self.passes,
+                  rows=len(active)):
+            t_dispatch = time.monotonic()
+            outs = self.runner.decode_block(
+                tokens, positions, btab, slot_map, ctx_lens, quota,
+                hs.temp, hs.top_k, hs.top_p, min_p=hs.min_p,
+                seed_keys=hs.keys, counters=passes, want_top=want_top,
+            )
+            self._count_decode_rows("decode_block", len(active))
+            commits = [er for er in active if not quota[er.slot]]
+            self._block_row_passes.inc(len(commits), kind="commit")
+            self._block_row_passes.inc(len(active) - len(commits),
+                                       kind="denoise")
+            self._inflight = True
+
+        (new_ids, lpn, tv, ti, _left), t_ready = await self._fetch(
+            loop, "decode", list(outs), chaos="decode_burst_hang")
+        with span("sched.decode.emit", step=self.passes, rows=len(active)):
+            self._last_burst_done_t = t_ready
+            if self.device_time is not None:
+                self.device_time.observe(
+                    "decode_block", "decode", t_dispatch, t_ready,
+                    read_bytes=self.device_time.decode_read_bytes(
+                        1, sum(er.context_len for er in active)),
+                    tokens=length * len(commits),
+                )
+            self.steps += 1
+            for er in active:
+                i = er.slot
+                if not quota[i]:
+                    self._commit_block(er)
+                    continue
+                for o in range(length):
+                    token = int(new_ids[i, o])
+                    if er.block[o] != unit.mask_id or token == unit.mask_id:
+                        continue
+                    er.block[o] = token
+                    er.block_lps[o] = float(lpn[i, o])
+                    er.block_tops[o] = self._top_row(er, tv[i], ti[i], o)
+                    er.block_passes[o] = er.block_pass
+                er.block_pass += 1
+
     def _preempt(self, er: EngineRequest) -> None:
         """Return a request to the waiting queue, releasing its blocks.
 
@@ -3920,6 +4163,14 @@ class Scheduler:
         gen = er.seq.token_ids[len(er.prompt):] if er.seq is not None else []
         if er.pending_token >= 0:
             gen = gen + [er.pending_token]
+        if self.unit is not None and er.seq is not None:
+            # a block family is preempted between blocks: the block in
+            # flight is dropped (none of it was emitted) but for the
+            # tokens that opened it, which were the prompt's or an earlier
+            # admission's
+            gen = (er.seq.token_ids + er.block[:er.block_first])[
+                len(er.prompt):]
+            er.block = []
         er.resume_tokens = list(gen)
         er.context_len = 0
         er.num_cached = 0

@@ -182,6 +182,30 @@ def build_draft_config(target: EngineConfig) -> EngineConfig:
     )
 
 
+def _refuse_for_block_unit(req: PreprocessedRequest, unit) -> None:
+    """A family whose decode unit is a block (models.BlockUnit) refuses,
+    by name, the request options that assume one token a row a pass
+    (``unit.refused``); the HTTP edge answers 400."""
+    if unit is None:
+        return
+    so = req.sampling_options
+    asked = (
+        ("presence_penalty", bool(so.presence_penalty)),
+        ("frequency_penalty", bool(so.frequency_penalty)),
+        ("repetition_penalty",
+         so.repetition_penalty not in (None, 0, 1, 1.0)),
+        ("guided_decoding", bool(so.guided_json or so.guided_choice_token_ids
+                                 or so.guided_choice)),
+        ("logit_bias", bool(so.logit_bias)),
+        ("prompt_logprobs", req.output_options.prompt_logprobs is not None),
+    )
+    for name, on in asked:
+        if on and name in unit.refused:
+            raise EngineError(
+                f"{name} is refused for a model whose decode unit is a "
+                f"block of {unit.length} positions: {unit.refused[name]}")
+
+
 class JaxServingEngine(AsyncEngine):
     def __init__(self, runner: ModelRunner, scheduler: Scheduler, config: EngineConfig):
         self.runner = runner
@@ -312,6 +336,7 @@ class JaxServingEngine(AsyncEngine):
             raise EngineError(
                 f"prompt token id {bad} outside vocab [0, {vocab})"
             )
+        _refuse_for_block_unit(req, self.scheduler.unit)
         n = req.sampling_options.n
         if n is not None and n > 1:
             # engine-level n>1 fan-out: each choice becomes an

@@ -31,6 +31,14 @@ slot_mapping, context_lens, mesh=, return_hidden=, state_slots=)``
 - ``SEQUENCE_STATE``: what a sequence keeps besides one kind of page and
   the paths refused for it; default ``PAGES_ONLY`` (``ModelRunner``,
   ``Scheduler`` through ``runner.keeps``);
+- ``decode_unit(cfg)``: a ``BlockUnit`` where the family's decode unit
+  is a block of positions and not one token: a pass over a row unmasks
+  some of the block's positions or finds it whole and keeps it (the
+  block's length, the mask id, the passes a block and the rule that
+  picks what a pass unmasks, and the paths refused for it); default
+  ``None``, one token a row a pass (``ModelRunner``, ``Scheduler``
+  through ``runner.unit``, ``engine/serving.py`` for what a request may
+  not ask of it);
 - ``CACHE_SPEC``: the sharding of a cache side that is not a bare page
   stack (``ModelRunner``, ``scripts/layer_loop.py``); such a side
   answers ``.pages`` (those that grow with the context) and ``.rest``
@@ -76,6 +84,33 @@ class SequenceState:
 
 PAGES_ONLY = SequenceState()
 
+REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockUnit:
+    """A family whose decode unit is a block (``decode_unit``): a row's
+    pending unit is ``length`` token ids at positions ``[n, n + length)``,
+    ``n`` a multiple of ``length``, each a token or ``mask_id``. A denoise
+    pass samples every masked position and unmasks some, ``length //
+    steps`` of them (the remainder to the first passes) by ``strategy``;
+    a commit pass finds the block whole, keeps its keys and values and
+    yields its tokens. ``refused``: engine settings (start-up) and request
+    options (admission) that assume one token a row a pass, path ->
+    reason."""
+    length: int
+    mask_id: int
+    steps: int
+    strategy: str               # one of REMASKING
+    threshold: float            # low_confidence_dynamic's
+    refused: Mapping[str, str] = dataclasses.field(default_factory=dict)
+
+    def quotas(self) -> Tuple[int, ...]:
+        """Positions pass ``t`` of a block unmasks at least, ``t`` in
+        ``range(steps)``."""
+        base, rem = divmod(self.length, self.steps)
+        return tuple(base + (t < rem) for t in range(self.steps))
+
 
 @dataclasses.dataclass(frozen=True)
 class Family:
@@ -120,6 +155,11 @@ FAMILIES = (
            unserved="layer_types ({n} entries) needs a family that keeps "
                     "pages a kind of layer; model_family {family!r} has none "
                     "(models/afmoe.py is selected by model_type afmoe)"),
+    Family("sdar", model_types=("sdar_moe",), field="block_length",
+           unserved="block_length={value} needs a family whose decode unit "
+                    "is a block of masked positions; model_family {family!r} "
+                    "decodes one token a row a pass (models/sdar.py is "
+                    "selected by model_type sdar_moe)"),
     Family("gptoss", architecture="gptoss", staged=True),
     Family("mixtral", shape=lambda cfg: cfg.num_experts > 0, staged=True),
     Family("gemma2", architecture="gemma2", staged=True),

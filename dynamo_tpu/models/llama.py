@@ -19,6 +19,7 @@ framework.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
@@ -428,6 +429,13 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     del layer_offset  # no global-layer-index semantics in this family
     del tp_axis  # qkv biases are tp-sharded; no replicated additive terms
     h_heads, hd = cfg.num_heads, cfg.head_dim
+    # a family that generates by diffusion over blocks (models/sdar.py):
+    # the mask is causal over blocks and full inside one, in prefill and
+    # in a block pass; the pass's kernel call alone is scope block_attn
+    block_len = max(1, cfg.block_length)
+    def kernel_scope():
+        return (jax.named_scope("block_attn") if s == cfg.block_length
+                else contextlib.nullcontext())
     # a decode step's rows that hold a token: the same for every layer,
     # made once, outside the scan
     if live_rows is None:
@@ -445,14 +453,16 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
         else:
             k_w, v_w, slots_w = k, v, slot_mapping
         k_all, v_all = scatter_kv_stacked(k_all, v_all, k_w, v_w, slots_w, li)
-        attn = attention(
-            q, k_all, v_all, block_tables, positions, context_lens,
-            impl=cfg.attention_impl, mesh=mesh, layer_idx=li,
-            # mistral/phi3-style whole-model window (0 = full attention;
-            # rides the XLA path — see ops/attention.py)
-            sliding_window=cfg.sliding_window or None,
-            live_rows=live_rows,
-        )
+        with kernel_scope():
+            attn = attention(
+                q, k_all, v_all, block_tables, positions, context_lens,
+                impl=cfg.attention_impl, mesh=mesh, layer_idx=li,
+                # mistral/phi3-style whole-model window (0 = full attention;
+                # rides the XLA path — see ops/attention.py)
+                sliding_window=cfg.sliding_window or None,
+                live_rows=live_rows,
+                **({} if block_len == 1 else {"block_len": block_len}),
+            )
         delta = dense(attn.reshape(b, s, h_heads * hd), layer_params["wo"])
         return delta, k_all, v_all
 

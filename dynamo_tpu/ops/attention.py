@@ -188,6 +188,7 @@ def paged_attention(
     softcap: float = 0.0,    # Gemma-2: logits ← cap·tanh(logits/cap)
     sliding_window=None,     # scalar (may be traced): keys within the window
     sinks=None,              # [H] per-head attention-sink logits (GPT-OSS)
+    block_len: int = 1,      # static: causal over blocks of this many, full inside
 ) -> jax.Array:
     """Reference paged attention: gather → masked softmax → weighted sum.
 
@@ -195,6 +196,11 @@ def paged_attention(
     j where j <= p and j < context_len — and, with ``sliding_window`` w,
     j > p - w. Cache position of slot s in the gathered layout is exactly
     its sequence position (block_tables are in sequence order).
+
+    ``block_len`` B > 1 (a family that generates by diffusion over
+    blocks, models/sdar.py): causal over blocks of B positions and full
+    inside one, j < (p // B + 1) · B (``block_causal``); 1 is the line
+    above, and the program is then the one it always was.
 
     ``sinks``: a learned per-head logit that joins the softmax as a
     virtual key contributing NO value — its only effect is the extra
@@ -220,7 +226,7 @@ def paged_attention(
         logits = softcap * jnp.tanh(logits / softcap)
 
     key_pos = jnp.arange(w * block_size)[None, None, :]          # [1, 1, T]
-    causal = key_pos <= q_positions[:, :, None]                   # [B, S, T]
+    causal = block_causal(key_pos, q_positions[:, :, None], block_len)  # [B, S, T]
     valid = key_pos < context_lens[:, None, None]                 # [B, 1→S, T]
     mask = causal & valid                                         # [B, S, T]
     if sliding_window is not None:
@@ -246,6 +252,17 @@ def paged_attention(
         ).astype(q.dtype)
     out = jnp.einsum("bskgt,btkd->bskgd", probs, v)
     return out.reshape(b, s, h, d)
+
+
+def block_causal(key_pos, q_pos, block_len: int):
+    """The causal mask of every attention route: key j is visible to the
+    query at p iff j <= p, or, with a static ``block_len`` B > 1, iff
+    j < (p // B + 1) · B (causal over blocks of B, full inside one). A
+    Python branch on the static B, so that at 1 the traced operations are
+    ``j <= p`` and nothing else."""
+    if block_len == 1:
+        return key_pos <= q_pos
+    return key_pos < (q_pos // block_len + 1) * block_len
 
 
 def resolve_attention_impl(impl: str) -> str:
@@ -329,8 +346,13 @@ def attention(
     sliding_window=None,            # scalar window (int or traced); None = off
     sinks=None,                     # [H] attention-sink logits (GPT-OSS)
     live_rows=None,                 # decode_live_rows of the step, or None
+    block_len: int = 1,             # static; > 1: block-causal (block_causal)
 ) -> jax.Array:
     """Paged-attention dispatch: XLA gather path or the Pallas kernels.
+
+    ``block_len`` B > 1: the mask is causal over blocks of B positions and
+    full inside one, on the XLA route, the verify kernel and the flash
+    kernel (a block pass is B queries a row, so S == 1 never carries it).
 
     ``live_rows`` (ops/live_rows.decode_live_rows, made by the trunk
     outside its layer scan): the decode kernel walks those rows alone
@@ -373,6 +395,10 @@ def attention(
     if impl == "auto" and mosaic_rejects(
             route, has_sinks, k_cache.dtype, k_cache.shape[-2] // tp):
         resolved = "xla"
+    if block_len > 1 and s_q == 1:
+        raise ValueError(
+            f"block_len={block_len} with one query a row: a block pass "
+            "carries the whole block")
     if resolved == "xla":
         if stacked:
             # index the layer through the gather itself: block id n of
@@ -388,7 +414,7 @@ def attention(
         return paged_attention(q, k_cache, v_cache, block_tables, positions,
                                context_lens, scale=scale, softcap=softcap,
                                sliding_window=sliding_window,
-                               sinks=sinks)[..., :d]
+                               sinks=sinks, block_len=block_len)[..., :d]
 
     interpret = interpret or pallas_interpret()
     if not stacked:
@@ -407,7 +433,7 @@ def attention(
     if route == "verify":
         fn = functools.partial(
             paged_verify_attention, scale=scale, interpret=interpret,
-            softcap=softcap,
+            softcap=softcap, block_len=block_len,
         )
         vbase = positions[:, 0].astype(jnp.int32)
         args = (q, k_cache, v_cache, block_tables, vbase, context_lens,
@@ -435,7 +461,7 @@ def attention(
     else:
         fn = functools.partial(
             paged_flash_attention, scale=scale, interpret=interpret,
-            softcap=softcap,
+            softcap=softcap, block_len=block_len,
         )
         base_pos = positions[:, 0].astype(jnp.int32)
         args = (q, k_cache, v_cache, block_tables, base_pos, context_lens,
@@ -476,6 +502,7 @@ def prefill_attention(
     v: jax.Array,
     valid_lens: jax.Array,  # [B] number of real (non-pad) tokens
     scale: Optional[float] = None,
+    block_len: int = 1,     # static; > 1: block-causal (block_causal)
 ) -> jax.Array:
     """Dense causal self-attention for prefill without cache reads (used when
     the whole context is the in-flight prompt — no prefix-cache hit)."""
@@ -488,7 +515,8 @@ def prefill_attention(
     logits = jnp.einsum("bskgd,btkd->bskgt", qg * scale, k)
     q_pos = jnp.arange(s)[None, :, None]
     k_pos = jnp.arange(s)[None, None, :]
-    mask = (k_pos <= q_pos) & (k_pos < valid_lens[:, None, None])
+    mask = block_causal(k_pos, q_pos, block_len) & (
+        k_pos < valid_lens[:, None, None])
     logits = jnp.where(mask[:, :, None, None, :], logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     out = jnp.einsum("bskgt,btkd->bskgd", probs, v)

@@ -45,6 +45,7 @@ def _kernel(
     block_size: int,
     softcap: float,
     has_sinks: bool = False,
+    block_len: int = 1,
 ):
     if has_sinks:
         sinks_ref, o_ref, m_scr, l_scr, acc_scr = rest
@@ -72,7 +73,13 @@ def _kernel(
 
     # page live iff it holds context AND is causally visible to the chunk
     # AND (with a window) its last key is within window of some chunk query
-    live = jnp.logical_and(page_start < ctx, page_start <= chunk_base + sc - 1)
+    if block_len == 1:
+        live = jnp.logical_and(page_start < ctx, page_start <= chunk_base + sc - 1)
+    else:
+        # the chunk's last query sees to the end of its block
+        live = jnp.logical_and(
+            page_start < ctx,
+            page_start < ((chunk_base + sc - 1) // block_len + 1) * block_len)
     live = jnp.logical_and(
         live, page_start + block_size + window > chunk_base + 1
     )
@@ -86,7 +93,9 @@ def _kernel(
         qpos = chunk_base + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_size), 0
         ) // g
-        mask = jnp.logical_and(key_pos <= qpos, key_pos < ctx)
+        causal = (key_pos <= qpos if block_len == 1
+                  else key_pos < (qpos // block_len + 1) * block_len)
+        mask = jnp.logical_and(causal, key_pos < ctx)
         mask = jnp.logical_and(mask, key_pos > qpos - window)
 
         for h in range(kvh):
@@ -140,7 +149,8 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "q_chunk", "interpret", "softcap")
+    jax.jit, static_argnames=("scale", "q_chunk", "interpret", "softcap",
+                              "block_len")
 )
 def paged_flash_attention(
     q: jax.Array,            # [B, S, H, D] (post-RoPE)
@@ -156,6 +166,8 @@ def paged_flash_attention(
     softcap: float = 0.0,    # Gemma-2: logits ← cap·tanh(logits/cap)
     window=None,             # sliding window (int or traced scalar); None = off
     sinks=None,              # [H] per-head sink logits (GPT-OSS); None = off
+    block_len: int = 1,      # static; > 1: causal over blocks of this many
+                             # positions, full inside one (models/sdar.py)
 ) -> jax.Array:
     b, s, h, d = q.shape
     if k_cache.ndim == 4:
@@ -190,7 +202,10 @@ def paged_flash_attention(
         # index to it makes trailing steps re-request the same page, which
         # the pipeline skips (no DMA) and the kernel skips (not live).
         by_ctx = jnp.maximum(ctx_ref[b_idx] - 1, 0) // block_size
-        by_causal = jnp.maximum(base_ref[b_idx] + (c + 1) * sc - 1, 0) // block_size
+        last_seen = base_ref[b_idx] + (c + 1) * sc - 1
+        if block_len > 1:
+            last_seen = (last_seen // block_len + 1) * block_len - 1
+        by_causal = jnp.maximum(last_seen, 0) // block_size
         return jnp.minimum(by_ctx, by_causal)
 
     def first_needed_page(b_idx, c, base_ref, win_ref):
@@ -250,6 +265,7 @@ def paged_flash_attention(
         functools.partial(
             _kernel, scale=scale, block_size=block_size, softcap=softcap,
             has_sinks=has_sinks,
+            **({} if block_len == 1 else {"block_len": block_len}),
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct(

@@ -561,6 +561,7 @@ def _verify_kernel(
     softcap: float,
     s_q: int,
     has_sinks: bool = False,
+    block_len: int = 1,
 ):
     """Multi-token verify attention: S query tokens per row over the
     SAME single page walk — the speculative propose-verify step's
@@ -574,7 +575,10 @@ def _verify_kernel(
     so a right-padded chunk behaves exactly like flash: pad rows score
     against the bounded valid range and the caller discards them), and
     key j is visible iff j <= base + s AND j < ctx (and inside the
-    sliding window).
+    sliding window). With a static ``block_len`` B > 1 the first term is
+    j < ((base + s) // B + 1) · B: causal over blocks of B, full inside
+    one (a block pass of models/sdar.py: every query of the block sees
+    all B of its keys).
 
     ``has_sinks`` (GPT-OSS): the per-head sink logit joins EVERY query
     position's softmax as a denominator-only virtual key — the [1,
@@ -640,7 +644,8 @@ def _verify_kernel(
 
         key_pos = c * chunk_t + col_tok
         mask = (head_match
-                & (key_pos <= q_pos)
+                & (key_pos <= q_pos if block_len == 1
+                   else key_pos < (q_pos // block_len + 1) * block_len)
                 & (key_pos < ctx)
                 & (key_pos > q_pos - win_ref[0]))
 
@@ -679,7 +684,8 @@ VERIFY_MAX_S = 32
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "pages_per_chunk", "interpret", "softcap"),
+    static_argnames=("scale", "pages_per_chunk", "interpret", "softcap",
+                     "block_len"),
 )
 def paged_verify_attention(
     q: jax.Array,            # [B, S, H, D] (post-RoPE), S small
@@ -695,6 +701,7 @@ def paged_verify_attention(
     softcap: float = 0.0,
     window=None,
     sinks=None,              # [H] per-head sink logits (GPT-OSS); None = off
+    block_len: int = 1,      # static; > 1: causal over blocks, full inside
 ) -> jax.Array:
     """S-token verify attention over the paged cache; returns
     [B, S, H, D]. The flash kernel's affine contract: query s of row b
@@ -774,6 +781,7 @@ def paged_verify_attention(
             softcap=softcap,
             s_q=s,
             has_sinks=has_sinks,
+            **({} if block_len == 1 else {"block_len": block_len}),
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, s, kvh, g, d), q.dtype, q, k_cache),

@@ -23,7 +23,10 @@ blocks dropped: ``sparse_config.topk`` halved in the served model's
 ``config.json`` only), ``bf16_router`` (the routed experts' router
 scores from a bfloat16 product of bfloat16 operands, as the activations
 arrive, where the stated program takes both to float32: Trinity-Mini,
-``trinity-longdoc`` at 9400 and 16 000 tokens, PR 40). Where the reference module has ``limits_for``, a
+``trinity-longdoc`` at 9400 and 16 000 tokens, PR 40), ``steps_4`` and
+``block_8`` (SDAR, ``sdar-reasoning``, PR 45: four denoising steps a
+block, or blocks of eight, in the served model's ``config.json`` only,
+against the reference's two and four). Where the reference module has ``limits_for``, a
 probe is held to the pair it gives for the probe's context; otherwise to
 the module's one pair.
 """
@@ -44,17 +47,22 @@ BENCH = os.path.join(ROOT, "benchmark")
 sys.path[:0] = [BENCH, ROOT]
 
 PROBE_TOKENS = 16
-FAULTS = ("bf16_state", "half_topk", "bf16_router")
+FAULTS = ("bf16_state", "half_topk", "bf16_router", "steps_4", "block_8")
 
 
 def serve_wrongly(fault: str, model_dir: str) -> None:
     """Make the program about to be served wrong in one named way; the
     reference keeps the configuration as it is."""
-    if fault == "half_topk":
+    if fault in ("half_topk", "steps_4", "block_8"):
         path = os.path.join(model_dir, "config.json")
         with open(path) as f:
             config = json.load(f)
-        config["sparse_config"]["topk"] //= 2
+        if fault == "half_topk":
+            config["sparse_config"]["topk"] //= 2
+        elif fault == "steps_4":
+            config["denoising_steps"] = 4
+        else:
+            config["block_length"] = 8
         with open(path, "w") as f:
             json.dump(config, f)
     elif fault == "bf16_state":

@@ -535,6 +535,68 @@ def test_trinity_step_keeps_both_page_stacks_in_place(
     assert mem.temp_size_in_bytes < (64 if tokens == 1 else 384) * 2 ** 20
 
 
+# SDAR-30B-A3B-Chat as one chip serves it (benchmark/configs/
+# sdar-30b-a3b-chat.json): seven layers of GQA 32 / 4 with q/k norms over
+# 128 experts of 768, 6400 pages, a table of 200; a block pass is 32 rows
+# of 4 positions through the verify kernel under the block mask, a
+# prefill chunk 1024 tokens through the flash kernel under it
+def _sdar_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import sdar
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    assert cfg.model_family == "sdar" and cfg.block_length == 4
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: sdar.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: sdar.init_kv_cache(cfg, hf["serve"]["num_kv_blocks"], 16,
+                                   jnp.bfloat16)))
+    assert k_side.shape == (7, 6400, 16, 4, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, toks, positions, bt, slots, ctx):
+        return sdar.forward_counted(params, cfg, toks, positions,
+                                    (k_side, v_side), bt, slots, ctx)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(rows, tokens), i32(rows, tokens),
+        i32(rows, width), i32(rows, tokens), i32(rows)).compile()
+
+
+@pytest.mark.parametrize("rows,tokens,width,kernel", [
+    (32, 4, 64, "paged_verify_attention"),
+    (32, 4, 200, "paged_verify_attention"),
+    (1, 1024, 200, "paged_flash_attention")])
+def test_sdar_block_pass_and_prefill_compile_under_the_block_mask(
+        one_chip, no_compile_cache, monkeypatch, rows, tokens, width, kernel):
+    """A block pass of 32 rows and a 1024-token prefill chunk at the
+    benchmark's size on the routes the chip takes: the verify (or flash)
+    kernel under the block mask, the pass's call inside scope
+    ``block_attn``, the three grouped products of the experts, and the
+    pages (1.43 GB) not copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _sdar_step(one_chip, rows, tokens, width)
+    text = compiled.as_text()
+    assert re.search(rf"tpu_custom_call[^\n]*{kernel}", text)
+    assert bool(re.search(r"tpu_custom_call[^\n]*block_attn", text)) == (tokens == 4)
+    assert re.search(r"tpu_custom_call[^\n]*moe_experts", text)
+    mem = compiled.memory_analysis()
+    print(f"sdar step {rows}x{tokens}: arguments",
+          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
+    # weights 9.97 GB without the head's 0.62 + pages 1.43
+    assert 10.6e9 < mem.argument_size_in_bytes < 10.9e9
+    assert mem.temp_size_in_bytes < (64 if tokens == 4 else 256) * 2 ** 20
+
+
 # The layer loop and its weights (PR 39). A projection whose result is
 # reshaped to heads at once has the reshape folded into its dot; the
 # compiler then sees the weight as [heads, head_dim, D], which is a

@@ -268,7 +268,7 @@ _NAME = re.compile(r"``(\w+)[(`]")
 REQUIRED = set(_NAME.findall(_REQUIRED_DOC)) - {"ModelRunner"}
 OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
             for name in _NAME.findall(bullet.split(":")[0])}
-FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64,
+FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
