@@ -189,12 +189,14 @@ class ModelRunner:
         if self.unit is not None:
             from ..ops.pallas_decode import VERIFY_MAX_S
 
-            # a block pass is one call of the verify kernel; a block never
-            # straddles a page; a prefill chunk ends at a block's edge
-            if not 1 < self.unit.length <= VERIFY_MAX_S:
+            # a block pass is one call of the verify kernel over two
+            # blocks; a block never straddles a page; a prefill chunk ends
+            # at a block's edge
+            if not 1 < self.unit.length <= VERIFY_MAX_S // 2:
                 raise ValueError(
                     f"block length {self.unit.length}: a block pass is one "
-                    f"verify-kernel call (1 < S <= {VERIFY_MAX_S})")
+                    f"verify-kernel call over two blocks "
+                    f"(S = 2 x block length <= {VERIFY_MAX_S})")
             for what, n in (("kv_block_size", config.kv_block_size),
                             *(("a prefill bucket", b)
                               for b in config.prefill_buckets)):
@@ -739,19 +741,31 @@ class ModelRunner:
     # ---------- the block pass (a family whose decode unit is a block) ----------
 
     def _build_block_step(self):
-        """``jit_decode_block``: one pass over ``[rows, B]`` positions of a
-        family whose decode unit is a block (``self.unit``). The trunk
-        writes the block's keys and values into its own slots (a commit
-        row's stay; a denoise row's are overwritten by its next pass) and
-        attends under the block mask; the head, sampling and the chosen
-        token's log-probability run at every position (scope
-        ``sampling``); the confidence and the choice of what this pass
-        unmasks follow (scope ``block_select``). A row whose block is
-        whole is a commit row of the same program: its quota is 0 and
-        nothing of its sampling output is taken. The packed input is the
-        step's (step_inputs.py) at ``S = B``, its ``last_idx`` column
-        carrying the row's quota and ``counters`` the pass's number within
-        its block."""
+        """``jit_decode_block``: one pass over ``[rows, 2B]`` consecutive
+        positions of a family whose decode unit is a block
+        (``self.unit``). A row's ``2B`` positions start at its kept
+        context's end and hold one of two things, told apart by what the
+        scheduler gave slots to (a slot of -1 writes nothing, routes to no
+        expert, and nothing of its position's output is read):
+
+        - a whole block and the block behind it, both with slots: the
+          pass writes the whole block's final keys and values, which is
+          what keeps it, and is the first denoise pass of the one behind
+          (whose positions see those keys in the same layer: the trunk
+          scatters before its kernel reads). There is no commit pass;
+        - a block being denoised and ``B`` dead positions behind it: a
+          request's first block, and any later pass of a block.
+
+        The trunk runs all ``2B`` positions under the block mask; the
+        head, sampling and the chosen token's log-probability run at the
+        ``B`` positions of the block being denoised alone (scope
+        ``sampling``), then the confidence and the choice of what this
+        pass unmasks (scope ``block_select``). A denoise pass's own keys
+        and values land in its block's slots, where its next pass
+        overwrites them. The packed input is the step's (step_inputs.py)
+        at ``S = 2B``, its ``last_idx`` column carrying the row's quota
+        (0: a row that holds nothing) and ``counters`` the pass's number
+        within its block."""
         unit = self.unit
         self._decode_block = None
         if unit is None:
@@ -767,19 +781,30 @@ class ModelRunner:
         blen = unit.length
 
         def decode_block(params, k_cache, v_cache, packed, *moe_counts):
-            inp = step_inputs.unpack(packed, blen)
+            inp = step_inputs.unpack(packed, 2 * blen)
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), inp.tokens, inp.positions,
                 inp.block_tables, inp.slot_mapping, inp.context_lens,
                 inp.sample_slots,
             )
             rows = inp.tokens.shape[0]
-            logits = head(hidden.reshape(rows * blen, -1), params)
+            # the block being denoised is the row's second half where that
+            # half is written (the first is then the whole block this pass
+            # keeps), its first half otherwise
+            behind = inp.slot_mapping[:, blen] >= 0
+
+            def denoised(x):       # [rows, 2B, ...] -> [rows, B, ...]
+                return jnp.where(
+                    behind.reshape((rows,) + (1,) * (x.ndim - 1)),
+                    x[:, blen:], x[:, :blen])
+
+            ids, positions = denoised(inp.tokens), denoised(inp.positions)
+            logits = head(denoised(hidden).reshape(rows * blen, -1), params)
             x0, lps, top_vals, top_ids = sample_block_positions(
-                cfg, logits, inp.samp, inp.positions, inp.want_top,
+                cfg, logits, inp.samp, positions, inp.want_top,
                 unit.mask_id)
             new_ids, taken, left = block_select(
-                inp.tokens, x0.reshape(rows, blen), lps.reshape(rows, blen),
+                ids, x0.reshape(rows, blen), lps.reshape(rows, blen),
                 inp.last_idx, unit)
             out = (new_ids, jnp.where(taken, lps.reshape(rows, blen), 0.0),
                    top_vals.reshape(rows, blen, -1),
@@ -802,12 +827,12 @@ class ModelRunner:
 
     def decode_block(
         self,
-        tokens: np.ndarray,        # [B, L] the block's ids, mask id where masked
-        positions: np.ndarray,     # [B, L]
+        tokens: np.ndarray,        # [B, 2L] ids, mask id where masked
+        positions: np.ndarray,     # [B, 2L] consecutive from the kept end
         block_tables: np.ndarray,  # [B, W]
-        slot_mapping: np.ndarray,  # [B, L]
-        context_lens: np.ndarray,  # [B] the block's end
-        quota: np.ndarray,         # [B] positions this pass unmasks; 0: commit
+        slot_mapping: np.ndarray,  # [B, 2L]; -1: a dead position
+        context_lens: np.ndarray,  # [B] the end of what the row writes
+        quota: np.ndarray,         # [B] positions this pass unmasks; 0: no row
         temperature: np.ndarray,
         top_k: np.ndarray,
         top_p: np.ndarray,
@@ -817,10 +842,11 @@ class ModelRunner:
         counters: Optional[np.ndarray] = None,    # [B] the pass within its block
         want_top: bool = False,
     ) -> Tuple[jax.Array, ...]:
-        """Run one block pass; returns device arrays (the block's new ids
-        [B, L], the log-probabilities of the positions unmasked in this
-        pass [B, L] and 0 elsewhere, top alternatives [B, L, K] twice,
-        the count still masked [B])."""
+        """Run one block pass (``_build_block_step`` says what a row's
+        ``2L`` positions hold); returns device arrays, all of the block
+        being denoised (its new ids [B, L], the log-probabilities of the
+        positions unmasked in this pass [B, L] and 0 elsewhere, top
+        alternatives [B, L, K] twice, the count still masked [B])."""
         b, s = tokens.shape
         width = block_tables.shape[1]
         if seed_keys is None:
@@ -2151,7 +2177,7 @@ class ModelRunner:
         if self.unit is not None:
             # the block pass is the family's one decode program (inert:
             # every slot is the drop sentinel, every quota 0)
-            zb = np.zeros((b, self.unit.length), np.int32)
+            zb = np.zeros((b, 2 * self.unit.length), np.int32)
             for w in self.config.kv_width_buckets():
                 self.decode_block(
                     zb, zb, np.zeros((b, w), np.int32), np.full_like(zb, -1),

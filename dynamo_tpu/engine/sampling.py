@@ -539,8 +539,7 @@ def block_select(ids: jax.Array, sampled: jax.Array, lps: jax.Array,
                  quota: jax.Array, unit):
     """Which masked positions of each row's block take their sample in
     this pass, by ``unit.strategy`` (models.REMASKING, the published
-    rules), ``quota`` [R] of them a row (0: a commit row, or a row that
-    holds nothing):
+    rules), ``quota`` [R] of them a row (0: a row that holds nothing):
 
     - ``sequential``: the first ``quota`` masked positions, left to right;
     - ``low_confidence_static``: the ``quota`` masked positions of highest

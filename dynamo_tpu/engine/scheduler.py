@@ -268,12 +268,15 @@ class EngineRequest:
     # memoized guided-table cache key (the trie key is a tuple over
     # every choice's token ids — too heavy to rebuild twice per pass)
     guided_key: Optional[tuple] = None
-    # a family whose decode unit is a block (models.BlockUnit): the block
-    # in flight at [context_len, context_len + L), each id a token or the
-    # mask id; its first ``block_first`` ids are the prompt's tail (not
-    # generated, never emitted); the passes made over it so far; and for
-    # each unmasked position its log-probability, top alternatives and
-    # the pass that unmasked it (-1: the prompt's)
+    # a family whose decode unit is a block (models.BlockUnit):
+    # ``unkept``, a whole block at [context_len, context_len + L) whose
+    # tokens were sent and whose final keys and values the row's next
+    # pass writes (empty: none); behind it the block in flight, each id
+    # a token or the mask id; its first ``block_first`` ids are the
+    # prompt's tail (not generated, never emitted); the passes made over
+    # it so far; and for each unmasked position its log-probability, top
+    # alternatives and the pass that unmasked it (-1: the prompt's)
+    unkept: List[int] = dataclasses.field(default_factory=list)
     block: List[int] = dataclasses.field(default_factory=list)
     block_first: int = 0
     block_pass: int = 0
@@ -772,22 +775,30 @@ class Scheduler:
         self._block_row_passes = reg.counter(
             "dynamo_scheduler_block_row_passes_total",
             "Rows of block passes that held a sequence, by kind=denoise "
-            "(the pass unmasked some of the block's positions and emitted "
-            "nothing) | commit (the block was whole: its keys and values "
-            "were kept and its tokens emitted)",
+            "(the pass unmasked some of the row's block; it may also have "
+            "kept the whole block before it) | commit (the pass only kept "
+            "a block: none does, a block is kept by the pass that first "
+            "denoises the next)",
         )
         self._blocks_completed = reg.counter(
             "dynamo_scheduler_blocks_completed_total",
-            "Blocks committed (one commit pass each)",
+            "Blocks made whole (by their last denoise pass: their tokens "
+            "left then)",
+        )
+        self._block_keeps_folded = reg.counter(
+            "dynamo_scheduler_block_keeps_folded_total",
+            "Whole blocks whose final keys and values were kept by the "
+            "first denoise pass of the block behind them (every block but "
+            "a request's last, which is never kept)",
         )
         self._block_tokens = reg.counter(
             "dynamo_scheduler_block_tokens_emitted_total",
-            "Tokens emitted by commit passes (a block's generated positions "
-            "up to a finish inside it)",
+            "Tokens emitted as blocks became whole (a block's generated "
+            "positions up to a finish inside it)",
         )
         self._block_passes_hist = reg.histogram(
             "dynamo_engine_block_denoise_length",
-            "Denoise passes a committed block took (a length in passes)",
+            "Denoise passes a whole block took (a length in passes)",
             buckets=tuple(float(i) for i in range(1, 34)),
         )
         self._preemptions = reg.counter(
@@ -3996,21 +4007,22 @@ class Scheduler:
     def _end_block_prefill(self, er: EngineRequest) -> None:
         """A block family's prompt is in the cache up to its last whole
         block: the row decodes from the next pass on (no token was
-        sampled; the first ones come with the first block's commit)."""
+        sampled; the first ones come when the first block is whole)."""
         er.ctx.add_stage("prefill")
         if er.max_new == 0:
             er.finish = FinishReason.LENGTH
             self._finish(er, er.finish)
 
-    def _commit_block(self, er: EngineRequest) -> None:
-        """The commit pass found ``er.block`` whole: its keys and values
-        stay, its generated tokens pass the shared commit in order
-        (counts, the stop-string ring, the finish checks) and leave in
-        one ``EngineOutput``; a finish inside the block drops the rest of
-        it. The next block opens behind it."""
+    def _block_whole(self, er: EngineRequest) -> None:
+        """A denoise pass left no mask in ``er.block``: its generated
+        tokens pass the shared commit in order (counts, the stop-string
+        ring, the finish checks) and leave in one ``EngineOutput``; a
+        finish inside the block drops the rest of it. A row that goes on
+        holds the block as ``unkept`` and opens the next behind it: the
+        pass that first denoises that one writes this one's final keys
+        and values (``_decode_block``). A row that finished has no
+        further pass, and its last block is never kept."""
         unit = self.unit
-        for token in er.block:
-            self._commit_kv(er, token)
         self._blocks_completed.inc()
         self._block_passes_hist.observe(float(er.block_pass))
         sent = []
@@ -4019,13 +4031,13 @@ class Scheduler:
             sent.append(o)
             if er.finish is not None:
                 break
-        if (er.finish is None
-                and er.context_len + unit.length > self.config.max_model_len):
+        if (er.finish is None and er.context_len + 2 * unit.length
+                > self.config.max_model_len):
             er.finish = FinishReason.LENGTH   # no room for one more block
         self._block_tokens.inc(len(sent))
         self.flight.record(
             "scheduler.block_commit", request_id=er.request_id,
-            start=er.context_len - unit.length,
+            start=er.context_len,
             passes=[er.block_passes[o] for o in sent],
         )
         if sent:
@@ -4036,26 +4048,39 @@ class Scheduler:
         if er.finish is not None:
             self._finish(er, er.finish, emit=not sent)
         else:
+            er.unkept = er.block
             self._open_block(er, [])
 
     async def _decode_block(self, loop, active: List[EngineRequest]) -> None:
-        """One block pass over every decoding row (``jit_decode_block``):
-        a row whose block holds a mask is a denoise row (the pass unmasks
-        its quota of positions and nothing is emitted), a row whose block
-        is whole is a commit row (``_commit_block``). Rows of one pass
-        are at different phases. Pages are taken for the whole block
-        ahead; a block never straddles a page."""
+        """One block pass over every decoding row (``jit_decode_block``,
+        ``2L`` consecutive positions a row from its kept context's end).
+        Every row's pass is a denoise pass of its block in flight: it
+        unmasks the pass's quota of positions, and where that leaves no
+        mask the block is whole and its tokens leave (``_block_whole``).
+        A row that holds a whole block not yet kept carries it in the
+        first half of its positions and the block in flight in the
+        second, all with slots: the pass writes the whole block's final
+        keys and values, and only when it returns does the block pass
+        ``_commit_kv`` (kept; a page it completes is registered). Any
+        other row (a request's first block, a later pass of a block) has
+        its block in the first half and a dead second half: no slot, so
+        no write and no expert row, and nothing read of it. Rows of one
+        pass are at different phases. Pages are taken for what the pass
+        writes; a block never straddles a page, two blocks may lie on
+        two."""
         cfg, unit = self.config, self.unit
         b, bs, length = cfg.max_batch_size, cfg.kv_block_size, unit.length
         quotas = unit.quotas()
-        n_commit = sum(unit.mask_id not in er.block for er in active)
 
         with span("sched.decode.build", step=self.passes, rows=len(active),
-                  denoise_rows=len(active) - n_commit, commit_rows=n_commit):
+                  denoise_rows=len(active), commit_rows=0,
+                  fold_rows=sum(bool(er.unkept) for er in active)):
             for er in list(active):
-                if not self._ensure_block_for(er, er.context_len + length - 1):
+                if not self._ensure_block_for(
+                        er, er.context_len + len(er.unkept) + length - 1):
                     # out of memory: back to waiting, between blocks (the
-                    # block in flight is dropped; none of it was emitted)
+                    # block in flight is dropped, none of it was emitted;
+                    # a whole block not yet kept goes with what was sent)
                     logger.warning("KV OOM: preempting %s", er.request_id)
                     self._preempt(er)
                     active.remove(er)
@@ -4065,20 +4090,24 @@ class Scheduler:
             w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
 
             hs = self._host
-            tokens = np.zeros((b, length), np.int32)
-            positions = np.zeros((b, length), np.int32)
-            slot_map = np.full((b, length), -1, np.int32)
+            tokens = np.zeros((b, 2 * length), np.int32)
+            positions = np.zeros((b, 2 * length), np.int32)
+            slot_map = np.full((b, 2 * length), -1, np.int32)
             ctx_lens = np.ones(b, np.int32)
             quota = np.zeros(b, np.int32)
             passes = np.zeros(b, np.int32)
-            offs = np.arange(length)
+            offs = np.arange(2 * length)
             for er in active:
                 i, n = er.slot, er.context_len
                 hs.sync_blocks(er)
-                tokens[i] = er.block
+                ids = er.unkept + er.block      # what the pass writes
+                tokens[i, :len(ids)] = ids
                 positions[i] = n + offs
-                slot_map[i] = er.block_ids[n // bs] * bs + n % bs + offs
-                ctx_lens[i] = n + length
+                for at in range(0, len(ids), length):
+                    p = n + at
+                    slot_map[i, at:at + length] = (
+                        er.block_ids[p // bs] * bs + p % bs + offs[:length])
+                ctx_lens[i] = n + len(ids)
                 masked = er.block.count(unit.mask_id)
                 # a pass past the schedule's end (the dynamic rule never
                 # needs one) takes what is left
@@ -4104,13 +4133,10 @@ class Scheduler:
                 seed_keys=hs.keys, counters=passes, want_top=want_top,
             )
             self._count_decode_rows("decode_block", len(active))
-            commits = [er for er in active if not quota[er.slot]]
-            self._block_row_passes.inc(len(commits), kind="commit")
-            self._block_row_passes.inc(len(active) - len(commits),
-                                       kind="denoise")
+            self._block_row_passes.inc(len(active), kind="denoise")
             self._inflight = True
 
-        (new_ids, lpn, tv, ti, _left), t_ready = await self._fetch(
+        (new_ids, lpn, tv, ti, left), t_ready = await self._fetch(
             loop, "decode", list(outs), chaos="decode_burst_hang")
         with span("sched.decode.emit", step=self.passes, rows=len(active)):
             self._last_burst_done_t = t_ready
@@ -4119,14 +4145,18 @@ class Scheduler:
                     "decode_block", "decode", t_dispatch, t_ready,
                     read_bytes=self.device_time.decode_read_bytes(
                         1, sum(er.context_len for er in active)),
-                    tokens=length * len(commits),
+                    tokens=length * sum(not left[er.slot] for er in active),
                 )
             self.steps += 1
             for er in active:
                 i = er.slot
-                if not quota[i]:
-                    self._commit_block(er)
-                    continue
+                if er.unkept:
+                    # the pass wrote the whole block's final keys and
+                    # values: kept, here and only here
+                    for token in er.unkept:
+                        self._commit_kv(er, token)
+                    er.unkept = []
+                    self._block_keeps_folded.inc()
                 for o in range(length):
                     token = int(new_ids[i, o])
                     if er.block[o] != unit.mask_id or token == unit.mask_id:
@@ -4136,6 +4166,8 @@ class Scheduler:
                     er.block_tops[o] = self._top_row(er, tv[i], ti[i], o)
                     er.block_passes[o] = er.block_pass
                 er.block_pass += 1
+                if unit.mask_id not in er.block:
+                    self._block_whole(er)
 
     def _preempt(self, er: EngineRequest) -> None:
         """Return a request to the waiting queue, releasing its blocks.
@@ -4167,10 +4199,11 @@ class Scheduler:
             # a block family is preempted between blocks: the block in
             # flight is dropped (none of it was emitted) but for the
             # tokens that opened it, which were the prompt's or an earlier
-            # admission's
-            gen = (er.seq.token_ids + er.block[:er.block_first])[
-                len(er.prompt):]
-            er.block = []
+            # admission's; a whole block not yet kept was sent, and goes
+            # with the kept ones
+            gen = (er.seq.token_ids + er.unkept
+                   + er.block[:er.block_first])[len(er.prompt):]
+            er.unkept, er.block = [], []
         er.resume_tokens = list(gen)
         er.context_len = 0
         er.num_cached = 0

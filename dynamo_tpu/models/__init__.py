@@ -94,8 +94,11 @@ class BlockUnit:
     ``n`` a multiple of ``length``, each a token or ``mask_id``. A denoise
     pass samples every masked position and unmasks some, ``length //
     steps`` of them (the remainder to the first passes) by ``strategy``;
-    a commit pass finds the block whole, keeps its keys and values and
-    yields its tokens. ``refused``: engine settings (start-up) and request
+    the pass that leaves no mask makes the block whole and its tokens
+    leave then; its final keys and values are kept by the pass that
+    first denoises the block behind it, which carries both blocks (a
+    pass is ``2 · length`` positions a row; there is no commit pass).
+    ``refused``: engine settings (start-up) and request
     options (admission) that assume one token a row a pass, path ->
     reason."""
     length: int

@@ -431,10 +431,11 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     h_heads, hd = cfg.num_heads, cfg.head_dim
     # a family that generates by diffusion over blocks (models/sdar.py):
     # the mask is causal over blocks and full inside one, in prefill and
-    # in a block pass; the pass's kernel call alone is scope block_attn
+    # in a block pass (two blocks a row: engine/model_runner.py
+    # _build_block_step); the pass's kernel call alone is scope block_attn
     block_len = max(1, cfg.block_length)
     def kernel_scope():
-        return (jax.named_scope("block_attn") if s == cfg.block_length
+        return (jax.named_scope("block_attn") if s == 2 * cfg.block_length
                 else contextlib.nullcontext())
     # a decode step's rows that hold a token: the same for every layer,
     # made once, outside the scan

@@ -16,18 +16,26 @@ itself, there is no shift by one.
 
 **The decode unit is a block** (``decode_unit``). A row's pending unit is
 ``B`` ids at ``[n, n + B)``, each a token or ``mask_token_id``. A denoise
-pass runs the trunk over the ``B`` positions (which see every kept key
-below ``n`` and all ``B`` keys of the block), samples every masked
-position and unmasks ``B // denoising_steps`` of them (the remainder to
-the first passes) by ``remasking_strategy``; an unmasked position never
-changes again. Once no mask is left a commit pass runs the trunk over the
-``B`` final tokens, and its keys and values are the ones kept. A denoise
-pass writes its keys and values into the block's own slots too, where the
-next pass overwrites them: no other sequence can read an uncommitted
-block (a page is registered only once the context has passed its end),
-so that is the same mathematics as not keeping them. A prompt of ``P``
-tokens prefills its first ``(P // B) · B`` under the block mask; the
-other ``P % B`` open the first block, already unmasked.
+pass runs the trunk over the block's positions (which see every key below
+``n`` and all ``B`` keys of the block), samples every masked position and
+unmasks ``B // denoising_steps`` of them (the remainder to the first
+passes) by ``remasking_strategy``; an unmasked position never changes
+again, so a block is whole, and its tokens leave, when its last denoise
+pass returns. What is kept of a block is the keys and values of its ``B``
+final tokens. **A block is kept by the pass that first denoises the block
+behind it; there is no commit pass.** That pass runs the ``2B`` positions
+``[n, n + 2B)`` under the mask above: the whole block's positions see what
+a pass of their own would show them, and the next block's see the whole
+block's keys of the same layer, which the trunk scatters into the cache
+before its kernel reads it. A block of ``B`` costs ``denoising_steps``
+passes. The last block of a request is never kept: nothing reads its
+keys. A denoise pass writes the keys and values of the block it denoises
+into the block's own slots too, where the next pass overwrites them: no
+other sequence can read a block that is not kept (a page is registered
+only once the kept context has passed its end), so that is the same
+mathematics as not writing them. A prompt of ``P`` tokens prefills its
+first ``(P // B) · B`` under the block mask; the other ``P % B`` open the
+first block, already unmasked.
 
 Prefix sharing stays on: a page of 16 tokens is whole blocks, so its keys
 depend on nothing past its end (``tests/test_block_decode.py`` holds a hit
@@ -132,11 +140,11 @@ def config_fields(config: dict) -> dict:
     steps = int(config.get("denoising_steps") or block)
     strategy = str(config.get("remasking_strategy")
                    or "low_confidence_dynamic")
-    if not 1 < block <= VERIFY_MAX_S or 16 % block:
+    if not 1 < block <= VERIFY_MAX_S // 2 or 16 % block:
         raise NotImplementedError(
             f"block_length={block}: a block pass is one call of the verify "
-            f"kernel (1 < S <= {VERIFY_MAX_S}) and a block never straddles "
-            "a page of 16")
+            f"kernel over two blocks (S = 2 x block_length <= "
+            f"{VERIFY_MAX_S}) and a block never straddles a page of 16")
     if not 1 <= steps <= block:
         raise ValueError(f"denoising_steps={steps} for a block of {block}")
     if strategy not in REMASKING:

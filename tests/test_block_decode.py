@@ -1,9 +1,10 @@
 """A family whose decode unit is a block (models/sdar.py; models.BlockUnit):
 the block mask on every attention route, the choice of what a pass
-unmasks, and the scheduler's block pass (commit in order, finishes inside
-a block, preemption between blocks, prefix sharing, the refusals), through
-``Scheduler``, ``JaxServingEngine`` and the HTTP service that ``cli/run``
-builds. The trunk's logits against the plain reference are
+unmasks, and the scheduler's block pass (a block's tokens leave when it is
+whole and it is kept by the pass that first denoises the next, finishes
+inside a block, preemption between blocks, prefix sharing, the refusals),
+through ``Scheduler``, ``JaxServingEngine`` and the HTTP service that
+``cli/run`` builds. The trunk's logits against the plain reference are
 ``tests/test_sdar_reference.py``'s.
 """
 
@@ -166,6 +167,53 @@ def test_block_mask_on_every_route_equals_the_dense_mask(route, block_len):
         assert np.abs(causal - want).max() > 1e-3     # the mask bites
 
 
+@pytest.mark.parametrize("block_len", [2, 4, 8])
+@pytest.mark.parametrize("route", ["xla", "verify", "flash", "attention"])
+def test_two_blocks_a_row_straddle_a_page_under_the_dense_mask(route, block_len):
+    """A block pass is ``S = 2 · block_len`` consecutive queries a row: a
+    whole block that ends at a page's edge (48) and the block behind it
+    on the next page, a pair inside one page, and a row whose second half
+    is dead (``context_lens`` ends with its first: the live half's queries
+    are held to the dense mask, the dead half's are nobody's). ``attention``
+    is the dispatch a trunk calls (its verify route, interpreted)."""
+    rng = np.random.default_rng(100 + block_len)
+    h, kvh, d = 4, 2, 128
+    s = 2 * block_len
+    base = np.asarray([48 - block_len, 16, 80 - block_len], np.int32)
+    ctx_lens = base + np.asarray([s, s, block_len], np.int32)
+    q = rng.standard_normal((3, s, h, d)).astype(np.float32)
+    q_pos = base[:, None] + np.arange(s)[None, :]
+    k_cache, v_cache, bt, dense = _paged(
+        rng, np.maximum(ctx_lens, base + s), kvh, d)
+    args = [jnp.asarray(x) for x in (q, k_cache, v_cache, bt)]
+    if route == "xla":
+        got = attn_ops.paged_attention(
+            *args, jnp.asarray(q_pos), jnp.asarray(ctx_lens),
+            block_len=block_len)
+    elif route == "verify":
+        got = paged_verify_attention(
+            *args, jnp.asarray(base), jnp.asarray(ctx_lens),
+            block_len=block_len, interpret=True)
+    elif route == "flash":
+        got = paged_flash_attention(
+            *args, jnp.asarray(base), jnp.asarray(ctx_lens),
+            block_len=block_len, q_chunk=s, interpret=True)
+    else:
+        got = attn_ops.attention(
+            *args, jnp.asarray(q_pos), jnp.asarray(ctx_lens), impl="pallas",
+            interpret=True, block_len=block_len)
+    for i, live in enumerate((s, s, block_len)):
+        want = _dense(q[i], *dense[i], q_pos[i], ctx_lens[i], block_len)
+        np.testing.assert_allclose(np.asarray(got[i])[:live], want[:live],
+                                   atol=2e-5)
+        causal = _dense(q[i], *dense[i], q_pos[i], ctx_lens[i], 1)
+        assert np.abs(causal - want)[:live].max() > 1e-3   # the mask bites
+    # the second block of a pair sees the first's keys, all of them
+    first_only = _dense(q[0], *dense[0], q_pos[0], ctx_lens[0] - block_len,
+                        block_len)
+    assert np.abs(first_only - np.asarray(got[0]))[block_len:].max() > 1e-3
+
+
 def _route_programs(block_len=None):
     """The lowered text of the four routes at one small shape; ``None``
     leaves the argument out (the call every other family makes)."""
@@ -254,7 +302,7 @@ def test_the_choice_equals_a_numpy_port_of_the_published_rules(strategy, length)
     rows = 64
     ids = np.where(rng.random((rows, length)) < 0.6, MASK,
                    rng.integers(0, 200, (rows, length))).astype(np.int32)
-    ids[0], ids[1] = MASK, 7                   # all masked; a commit row
+    ids[0], ids[1] = MASK, 7                   # all masked; no mask, quota 0
     sampled = rng.integers(0, 200, (rows, length)).astype(np.int32)
     # confidences on both sides of the threshold, with exact ties
     conf = np.round(rng.random((rows, length)), 1).astype(np.float32)
@@ -369,17 +417,26 @@ def _runner(steps=2, strategy="sequential", **over):
     return _RUNNERS[key]
 
 
+KINDS = 'dynamo_scheduler_block_row_passes_total{kind="%s"}'
+FOLDED = "dynamo_scheduler_block_keeps_folded_total"   # no line until it counts
+
+
 @pytest.mark.parametrize("steps,strategy", [
-    (2, "sequential"), (2, "low_confidence_static"),
-    (4, "low_confidence_static"), (1, "sequential"),
-    (2, "low_confidence_dynamic"), (3, "low_confidence_dynamic")])
+    (1, "sequential"), (2, "sequential"), (4, "sequential"),
+    (1, "low_confidence_static"), (2, "low_confidence_static"),
+    (4, "low_confidence_static"), (1, "low_confidence_dynamic"),
+    (2, "low_confidence_dynamic"), (3, "low_confidence_dynamic"),
+    (4, "low_confidence_dynamic")])
 def test_engine_passes_and_log_probabilities_equal_the_procedure(steps, strategy):
-    """(d) Through the scheduler and ``jit_decode_block``, four prompts
+    """(a) Through the scheduler and ``jit_decode_block``, four prompts
     with tails of 1, 2, 3 and 0 tokens, greedy: the tokens, the pass that
     unmasked each and the log-probability it was taken under are the
-    plain loop's (``references/sdar.generate``: one forward a state).
-    Passes a block are ``steps`` + 1 under both static rules and within
-    [2, B + 1] under the dynamic one; a commit emits its block whole."""
+    plain loop's (``references/sdar.generate``: one forward a state, no
+    state shared between two blocks). The engine's passes a block are
+    ``steps`` under both static rules, not ``steps`` + 1, and within
+    [1, B] under the dynamic one: no pass only keeps a block, every block
+    but a request's last is kept by the first pass of the next; a block
+    leaves whole."""
     runner = _runner(steps, strategy)
     hf = _hf(denoising_steps=steps, remasking_strategy=strategy)
     prompts = _prompts((37, 50, 3, 64))
@@ -396,28 +453,72 @@ def test_engine_passes_and_log_probabilities_equal_the_procedure(steps, strategy
         assert sum(chunks) == 13
     rows = _rows(sched)
     blocks = rows["dynamo_scheduler_blocks_completed_total"]
-    commit = rows['dynamo_scheduler_block_row_passes_total{kind="commit"}']
-    denoise = rows['dynamo_scheduler_block_row_passes_total{kind="denoise"}']
-    assert commit == blocks == 16
+    denoise = rows[KINDS % "denoise"]
+    assert blocks == 16 and KINDS % "commit" not in rows
+    assert rows.get(FOLDED, 0) == blocks - 4
     assert rows["dynamo_scheduler_block_tokens_emitted_total"] == 4 * 13
     assert rows["dynamo_engine_block_denoise_length_count"] == blocks
-    a_block = (denoise + commit) / blocks
+    assert rows["dynamo_engine_block_denoise_length_sum"] == denoise
     if strategy == "low_confidence_dynamic":
-        assert 2 <= a_block <= 5
+        assert blocks <= denoise <= 4 * blocks
     else:
-        # a prompt's tail opens the first block: fewer passes there
-        full = [b for b in range(16) if b % 4]
-        assert denoise <= steps * blocks
-        assert denoise >= steps * len(full)
-        assert a_block <= steps + 1
+        # a prompt's tail opens the first block: fewer masks there, and
+        # a pass takes its quota or what is left (tails 1, 2, 3 and 0)
+        quotas = models.BlockUnit(4, MASK, steps, strategy, 0.9).quotas()
+        first = [sum(1 for t in range(steps) if sum(quotas[:t]) < 4 - tail)
+                 for tail in (1, 2, 3, 0)]
+        assert denoise == steps * (blocks - 4) + sum(first)
     # a chunk of k tokens is k gaps of a k-th each: never one gap of 0
     assert rows["dynamo_scheduler_inter_token_latency_seconds_count"] == (
         4 * 13 - sum(g[2][0] for g in got))
 
 
+@pytest.mark.parametrize("length", [1, 2, 3, 4, 5, 17])
+def test_a_short_prompt_and_a_tail_open_the_first_block(length):
+    """(e) A prompt shorter than one block has no kept position: its
+    first block sits at position 0 with the prompt as its opening ids,
+    nothing is prefilled, and the row's positions start at 0. One of
+    exactly a block prefills it and opens a block of masks; 5 and 17
+    prefill whole blocks and open the first with a tail of one."""
+    runner = _runner()
+    prompt = _prompts((length,), seed=11)[0]
+    er = _request(prompt, 10)
+    sched, [(toks, lps, chunks, finish)], passes = _drive(
+        runner, runner.config, [er])
+    want_toks, want_lps, want_passes = reference.generate(
+        HF, runner.params, prompt, 10)
+    assert toks == want_toks and passes[er.request_id] == want_passes
+    np.testing.assert_allclose(lps, want_lps, atol=F32_ATOL)
+    tail = length % 4
+    assert chunks[0] == 4 - tail and str(finish.value) == "length"
+    rows = _rows(sched)
+    blocks = -(-(tail + 10) // 4)
+    assert rows["dynamo_scheduler_blocks_completed_total"] == blocks
+    assert rows.get(FOLDED, 0) == blocks - 1
+    # two passes a block, one where the tail leaves at most two masks
+    assert rows[KINDS % "denoise"] == 2 * blocks - (tail >= 2)
+    assert sched.allocator.used == 0
+
+
+def test_the_last_block_under_max_model_len_leaves_whole():
+    """A row whose next block would pass ``max_model_len`` finishes with
+    ``length`` when the block that still fits is whole, all of it sent:
+    positions [252, 256) of 256."""
+    runner = _runner()
+    prompt = _prompts((238,), seed=12)[0]
+    sched, [(toks, lps, chunks, finish)], _ = _drive(
+        runner, runner.config, [_request(prompt, 40)])
+    want_toks, want_lps, _ = reference.generate(HF, runner.params, prompt, 18)
+    assert toks == want_toks and chunks == [2, 4, 4, 4, 4]
+    np.testing.assert_allclose(lps, want_lps, atol=F32_ATOL)
+    assert str(finish.value) == "length" and sched.allocator.used == 0
+
+
 @pytest.mark.parametrize("max_tokens", [1, 6, 9])
 def test_max_tokens_inside_a_block_drops_the_rest_of_it(max_tokens):
-    """(e) ``max_tokens`` that is no multiple of the block."""
+    """(b) ``max_tokens`` that is no multiple of the block: the tokens
+    leave with the pass that made the block whole and no pass follows for
+    the row (two passes a block, the last block never kept)."""
     runner = _runner()
     prompts = _prompts((37, 64))
     sched, got, _ = _drive(runner, runner.config,
@@ -427,14 +528,44 @@ def test_max_tokens_inside_a_block_drops_the_rest_of_it(max_tokens):
         assert toks == want and len(lps) == max_tokens
         assert str(finish.value) == "length"
     assert sched.allocator.used == 0
+    rows = _rows(sched)
+    blocks = -(-(1 + max_tokens) // 4) + -(-max_tokens // 4)
+    assert rows["dynamo_scheduler_blocks_completed_total"] == blocks
+    assert rows[KINDS % "denoise"] == 2 * blocks and KINDS % "commit" not in rows
+    assert rows.get(FOLDED, 0) == blocks - 2
+
+
+@pytest.mark.parametrize("max_tokens,shared", [(16, 64), (17, 80), (20, 80)])
+def test_a_page_of_unkept_keys_is_never_registered(max_tokens, shared):
+    """(b) A prompt of four pages whose answer's fourth block ends the
+    fifth page at position 80. Where the request ends with that block the
+    block is never kept and the page not registered: a later request over
+    the same 80 tokens shares four pages and computes the fifth. Where the
+    request goes on, the pass that first denoises the block at 80 keeps
+    the one before it and the page is shared. Either way the later
+    request's stream is the plain loop's."""
+    runner = _runner()
+    prompt = _prompts((64,), seed=8)[0]
+    first = _request(prompt, max_tokens)
+    stream = reference.generate(HF, runner.params, prompt, 20)[0]
+    later = _request(prompt + stream[:16] + _prompts((5,), seed=9)[0], 8)
+    sched, got, _ = _drive(runner, runner.config, [first, later],
+                           staggered=True)
+    assert got[0][0] == stream[:max_tokens]
+    assert first.cached_tokens == 0 and later.cached_tokens == shared
+    want_toks, want_lps, _ = reference.generate(
+        HF, runner.params, later.prompt, 8)
+    assert got[1][0] == want_toks
+    np.testing.assert_allclose(got[1][1], want_lps, atol=F32_ATOL)
 
 
 @pytest.mark.parametrize("how", ["eos", "stop_id", "stop_seq"])
 @pytest.mark.parametrize("at", [1, 4, 8])
 def test_a_finish_inside_a_block_drops_the_rest_of_it(how, at):
-    """(e) EOS, a hidden stop id and a stop string's canonical tokens
+    """(b) EOS, a hidden stop id and a stop string's canonical tokens
     that end at offset ``at`` of the greedy stream (inside a block: the
-    prompt's tail is 1, so blocks end at offsets 2, 6, 10)."""
+    prompt's tail is 1, so blocks end at offsets 2, 6, 10). The tokens
+    leave with the pass that made the block whole; no pass follows."""
     runner = _runner()
     prompt = _prompts((41,), seed=3)[0]
     stream = reference.generate(HF, runner.params, prompt, 13)[0]
@@ -453,13 +584,21 @@ def test_a_finish_inside_a_block_drops_the_rest_of_it(how, at):
     assert toks == stream[:at + 1]
     assert str(finish.value) == ("eos" if how == "eos" else "stop")
     assert er.generated == at + 1 and sched.allocator.used == 0
+    rows = _rows(sched)
+    blocks = (at + 1) // 4 + 1
+    assert rows["dynamo_scheduler_blocks_completed_total"] == blocks
+    assert rows[KINDS % "denoise"] == 2 * blocks
+    assert rows.get(FOLDED, 0) == blocks - 1
 
 
 def test_a_preempted_row_resumes_between_blocks_with_the_same_stream():
-    """(f) Fourteen pages for four rows that come to need twenty: rows go
+    """(c) Fourteen pages for four rows that come to need twenty: rows go
     back to waiting between blocks, re-prefill prompt plus what they had
     emitted under the block mask, and every greedy stream is the
-    unpreempted one's (the plain loop's)."""
+    unpreempted one's (the plain loop's). A row runs out of pages at the
+    first pass of a block, when it holds the block before it whole, sent
+    and not kept: those tokens go into ``resume_tokens`` with the kept
+    ones."""
     runner = _runner()
     config = dataclasses.replace(runner.config, num_kv_blocks=14)
     prompts = _prompts((37, 50, 3, 64))
@@ -470,15 +609,18 @@ def test_a_preempted_row_resumes_between_blocks_with_the_same_stream():
 
         def counted(er):
             assert er.pending_token == -1
-            preempted.append((er.request_id, er.generated))
+            preempted.append((er.request_id, er.generated, len(er.unkept)))
             preempt(er)
-            # between blocks: everything emitted so far is whole blocks
+            # between blocks: everything emitted so far is whole blocks,
+            # the unkept one among them, and nothing sent is lost
             assert (len(er.prompt) + len(er.resume_tokens)) % 4 == 0
+            assert len(er.resume_tokens) == er.generated
+            assert er.unkept == [] and er.block == []
         sched._preempt = counted
 
     sched, got, _ = _drive(runner, config, [_request(p, 60) for p in prompts],
                            hook=hook)
-    assert preempted
+    assert preempted and any(unkept == 4 for _, _, unkept in preempted)
     for prompt, (toks, lps, chunks, _) in zip(prompts, got):
         want_toks, want_lps, _ = reference.generate(HF, runner.params, prompt, 60)
         assert toks == want_toks
@@ -646,7 +788,7 @@ def test_the_family_is_resolved_by_model_type_and_declares_its_unit():
 def test_scopes_in_the_lowered_block_pass():
     runner = _runner()
     b, w = 4, 8
-    zb = np.zeros((b, 4), np.int32)
+    zb = np.zeros((b, 8), np.int32)       # two blocks a row
     from dynamo_tpu.engine import step_inputs
     buf = step_inputs.pack(zb, zb, np.zeros((b, w), np.int32), zb - 1,
                            keys=np.zeros(2, np.uint32), want_top=False,
@@ -745,8 +887,8 @@ def _tokens(text):
 
 
 def test_served_stream_delivers_a_block_a_chunk(served):
-    """(h) SSE: a committed block is one chunk of up to four tokens, and
-    the stream is the plain loop's."""
+    """(h) SSE: a whole block is one chunk of up to four tokens, and the
+    stream is the plain loop's."""
     prompt = _prompts((37,))[0]
     status, raw = served.post({"prompt": prompt, "max_tokens": 13,
                                "temperature": 0, "ignore_eos": True,

@@ -538,8 +538,9 @@ def test_trinity_step_keeps_both_page_stacks_in_place(
 # SDAR-30B-A3B-Chat as one chip serves it (benchmark/configs/
 # sdar-30b-a3b-chat.json): seven layers of GQA 32 / 4 with q/k norms over
 # 128 experts of 768, 6400 pages, a table of 200; a block pass is 32 rows
-# of 4 positions through the verify kernel under the block mask, a
-# prefill chunk 1024 tokens through the flash kernel under it
+# of 8 positions (two blocks a row: the whole one it keeps and the one it
+# denoises) through the verify kernel under the block mask, a prefill
+# chunk 1024 tokens through the flash kernel under it
 def _sdar_step(one_chip, rows, tokens, width):
     from dynamo_tpu.engine.config import ModelConfig
     from dynamo_tpu.models import sdar
@@ -573,8 +574,8 @@ def _sdar_step(one_chip, rows, tokens, width):
 
 
 @pytest.mark.parametrize("rows,tokens,width,kernel", [
-    (32, 4, 64, "paged_verify_attention"),
-    (32, 4, 200, "paged_verify_attention"),
+    (32, 8, 64, "paged_verify_attention"),
+    (32, 8, 200, "paged_verify_attention"),
     (1, 1024, 200, "paged_flash_attention")])
 def test_sdar_block_pass_and_prefill_compile_under_the_block_mask(
         one_chip, no_compile_cache, monkeypatch, rows, tokens, width, kernel):
@@ -587,14 +588,14 @@ def test_sdar_block_pass_and_prefill_compile_under_the_block_mask(
     compiled = _sdar_step(one_chip, rows, tokens, width)
     text = compiled.as_text()
     assert re.search(rf"tpu_custom_call[^\n]*{kernel}", text)
-    assert bool(re.search(r"tpu_custom_call[^\n]*block_attn", text)) == (tokens == 4)
+    assert bool(re.search(r"tpu_custom_call[^\n]*block_attn", text)) == (tokens == 8)
     assert re.search(r"tpu_custom_call[^\n]*moe_experts", text)
     mem = compiled.memory_analysis()
     print(f"sdar step {rows}x{tokens}: arguments",
           mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
     # weights 9.97 GB without the head's 0.62 + pages 1.43
     assert 10.6e9 < mem.argument_size_in_bytes < 10.9e9
-    assert mem.temp_size_in_bytes < (64 if tokens == 4 else 256) * 2 ** 20
+    assert mem.temp_size_in_bytes < (64 if tokens == 8 else 256) * 2 ** 20
 
 
 # The layer loop and its weights (PR 39). A projection whose result is
