@@ -28,14 +28,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import models
 from ..models import llama, quant
-from ..ops.attention import _pad_minor
+from ..ops.attention import _pad_minor, row_list_traced
 from ..telemetry.flight import CompileTracker
 from ..telemetry.registry import Counter
 from .config import EngineConfig
 from .device import check_serving_device
 from . import step_inputs
-from .sampling import (SamplingParams, block_select, sample,
-                       sample_block_positions, top_logprobs_for)
+from .sampling import (SamplingParams, block_select, over_all_rows,
+                       over_live_rows, sample, sample_block_positions,
+                       tile_rows, top_k_width, top_logprobs_for,
+                       walks_live_rows)
 
 logger = logging.getLogger(__name__)
 
@@ -90,43 +92,106 @@ class _DeviceFedCounter(Counter):
         return super().render()
 
 
-@jax.named_scope("sampling")
+# rows a tile where the program being traced walks its sampling tail in
+# tiles of rows, else 0 (``_sample_and_logprobs`` sets it,
+# ``ModelRunner._track`` clears it before a dispatch and reads it after)
+_tiles_traced = 0
+
+
 def _sample_and_logprobs(cfg, mesh, last_logits, samp, counts, seen, bias,
-                         sample_slots, commit, want_top, extra_bias=None):
+                         sample_slots, commit, want_top, extra_bias=None,
+                         live=None):
+    """The per-token tail (``_tail_ops``) as a decode or prefill program
+    traces it, under the scope ``sampling``. Where it walks tiles of rows
+    (``sampling.tile_rows``) it is traced once for all the programs of a
+    process that hand it the same shapes (``_tail``; a decode program a
+    block-table width: the tail is the same in each, and its two walks
+    are a quarter of a second of every one's warm start); elsewhere its
+    operations lie in the program as they always did."""
+    global _tiles_traced
+    tile = tile_rows(last_logits, mesh, live)
+    args = (mesh, top_k_width(cfg.vocab_size), tile, last_logits, samp,
+            counts, seen, bias, sample_slots, commit, want_top, extra_bias,
+            live if tile else None)
+    with jax.named_scope("sampling"):
+        if not tile:
+            return _tail_ops(*args)
+        _tiles_traced = tile
+        return _tail(*args)
+
+
+def _tail_ops(mesh, kw, tile, last_logits, samp, counts, seen, bias,
+              sample_slots, commit, want_top, extra_bias, live):
     """The per-token tail shared by the single step and every scan
     iteration of the fused burst: penalty-aware sampling, the sampled
     token's logprob, gated top-K alternatives, and the committed-count
-    update. One implementation ⇒ the burst's bit-identical-stream
-    guarantee can't drift from the single-step program.
+    update. One implementation, so the burst's bit-identity guarantee
+    can't drift from the single-step path.
 
     ``extra_bias`` is an additive [B, V] term computed in-program (the
     chained burst's device-guided mask); the sync path expresses the
     same mask through the persistent ``bias`` buffer instead, so adding
-    it here keeps the two paths' logits — and logprobs — bit-equal."""
-    from .sampling import top_k_width
+    it here keeps the two paths' logits — and logprobs — bit-equal.
 
+    ``live`` ([B] bool, given with ``tile`` > 0) says which rows' token
+    is read. The ``[rows, V]`` work then runs ``tile`` rows at a time:
+    over the list of live rows while that is a tile shorter than the
+    batch (``sampling.over_live_rows``), over all rows as they lie past
+    that (``over_all_rows``); a row that is not live comes back as token
+    0 with log-probability 0 either way. Every row keeps its own key and
+    counter, no reduction crosses rows and both walks run the same
+    ``[tile, V]`` operations, so a live row's token and log-probability
+    depend neither on its batchmates nor on how many they are."""
     b = last_logits.shape[0]
-    row_counts = counts[sample_slots]
-    row_seen = seen[sample_slots]
-    row_bias = bias[sample_slots]
-    if extra_bias is not None:
-        row_bias = row_bias + extra_bias
-    next_tokens = sample(last_logits, samp, row_counts, row_seen,
-                         bias=row_bias, mesh=mesh)
-    logp = jax.nn.log_softmax(
-        (last_logits + row_bias).astype(jnp.float32), axis=-1
-    )
-    lps = jnp.take_along_axis(logp, next_tokens[:, None], axis=-1)[:, 0]
+
+    def log_probs(logits, row_bias):
+        return jax.nn.log_softmax(
+            (logits + row_bias).astype(jnp.float32), axis=-1
+        )
+
+    def tail(logits, samp, row_counts, row_seen, row_bias, extra):
+        if extra is not None:
+            row_bias = row_bias + extra
+        tokens = sample(logits, samp, row_counts, row_seen, bias=row_bias,
+                        mesh=mesh)
+        logp = log_probs(logits, row_bias)
+        return tokens, jnp.take_along_axis(
+            logp, tokens[:, None], axis=-1)[:, 0], logp
+
+    def of_rows(rows=None):
+        """The tail's inputs, of every row or of ``rows``; the penalty
+        and bias rows from the slot buffers directly."""
+        mine = (lambda x: x) if rows is None else (lambda x: x[rows])
+        slots = mine(sample_slots)
+        return (mine(last_logits), jax.tree_util.tree_map(mine, samp),
+                counts[slots], seen[slots], bias[slots],
+                None if extra_bias is None else mine(extra_bias))
+
+    if tile:
+        drawn = lambda mine: tail(*mine)[:2]
+        next_tokens, lps = jax.lax.cond(
+            walks_live_rows(live.sum(), b, tile),
+            lambda: over_live_rows(live, tile, of_rows, drawn),
+            lambda: over_all_rows(live, tile, of_rows(), drawn),
+        )
+
+        def all_logp():
+            # behind its gate, over every row at once: no walk's to share
+            row_bias = bias[sample_slots]
+            if extra_bias is not None:
+                row_bias = row_bias + extra_bias
+            return log_probs(last_logits, row_bias)
+    else:
+        next_tokens, lps, logp = tail(*of_rows())
+        all_logp = lambda: logp
     # top-K alternatives only when some active request asked (OpenAI
     # top_logprobs): the [B, V] top_k sort is fixed hot-path cost
     # otherwise. lax.cond keeps one compiled program either way.
-    kw = top_k_width(cfg.vocab_size)
     top_vals, top_ids = jax.lax.cond(
         want_top,
-        lambda lp_: top_logprobs_for(last_logits, lp_),
-        lambda lp_: (jnp.zeros((b, kw), jnp.float32),
-                     jnp.zeros((b, kw), jnp.int32)),
-        logp,
+        lambda: top_logprobs_for(last_logits, all_logp()),
+        lambda: (jnp.zeros((b, kw), jnp.float32),
+                 jnp.zeros((b, kw), jnp.int32)),
     )
     # count the sampled token as generated for its slot — but only for
     # rows whose sample the scheduler will keep (``commit``)
@@ -134,6 +199,9 @@ def _sample_and_logprobs(cfg, mesh, last_logits, samp, counts, seen, bias,
         commit.astype(jnp.int32)
     )
     return next_tokens, lps, top_vals, top_ids, counts
+
+
+_tail = jax.jit(_tail_ops, static_argnums=(0, 1, 2))
 
 
 def _ngram_props(ring: jax.Array, match: int, k: int) -> jax.Array:
@@ -398,6 +466,9 @@ class ModelRunner:
         # live rows (``_track``): only for these does the scheduler
         # count a pad row as skipped
         self.row_list_programs: set = set()
+        # and those whose trace walks the sampling tail in tiles of rows,
+        # with the rows a tile: the scheduler counts whole tiles for them
+        self.sampling_tile_programs: dict = {}
         if (_attn_ops.ATTENTION_ROUTE_COUNTER.name
                 not in self.compiles.registry.names()):
             self.compiles.registry.register(
@@ -586,13 +657,16 @@ class ModelRunner:
         """``compiles.track`` around one dispatch of a decode program,
         keeping what its trace recorded beside the route: whether the
         attention kernels were handed a list of live rows
-        (ops/attention.record_row_list)."""
-        from ..ops.attention import row_list_traced
-
+        (ops/attention.record_row_list), and the tile its sampling tail
+        walks (``_sample_and_logprobs``)."""
+        global _tiles_traced
         with self.compiles.track(program, key, **stats) as first:
+            _tiles_traced = 0
             yield first
             if first and row_list_traced():
                 self.row_list_programs.add(program)
+            if first and _tiles_traced:
+                self.sampling_tile_programs[program] = _tiles_traced
 
     def _make_forward(self, counted: bool = False):
         """(trunk, head) closures both compiled programs trace: the trunk
@@ -689,9 +763,14 @@ class ModelRunner:
             last_logits = head(
                 hidden[jnp.arange(b), last_idx], params
             )  # [B, V]
+            # a decode step reads the token of a row that has a slot (the
+            # mask the trunk's list of live rows is made from, and the
+            # scheduler's ``commit`` there); a prefill step's few rows all
+            # take the tail
             next_tokens, lps, top_vals, top_ids, counts = _sample_and_logprobs(
                 cfg, mesh, last_logits, samp, counts, seen, bias,
                 sample_slots, commit, want_top,
+                live=slot_mapping[:, 0] >= 0 if s == 1 else None,
             )
             out = (next_tokens, lps, top_vals, top_ids, prompt_lps,
                    greedy_all, k_cache, v_cache, counts, seen, bias)
@@ -924,7 +1003,7 @@ class ModelRunner:
                 samp_i = _dc.replace(samp, counters=samp.counters + step_i)
                 nt, lp, tv, ti, counts = _sample_and_logprobs(
                     cfg, mesh, head(hidden[:, 0], params), samp_i, counts,
-                    seen, bias, sample_slots, commit, want_top,
+                    seen, bias, sample_slots, commit, want_top, live=commit,
                 )
                 return (k_cache, v_cache, counts, nt, pos + 1), (nt, lp, tv, ti)
 
@@ -1034,7 +1113,7 @@ class ModelRunner:
                 nt, lp, tv, ti, counts = _sample_and_logprobs(
                     cfg, mesh, head(hidden[:, 0], params), samp_i, counts,
                     seen, bias, sample_slots, live, want_top,
-                    extra_bias=gmask,
+                    extra_bias=gmask, live=live,
                 )
                 gen_n = gen + live.astype(jnp.int32)
                 ring_n = ring_push(ring, nt, live)

@@ -5,7 +5,11 @@ All parameters are per-request arrays so one compiled program serves every
 sampling configuration in the batch (no recompiles when requests differ).
 temperature == 0 means greedy. Every request samples from its own PRNG key
 (seeded requests are bit-reproducible and isolated from their batchmates —
-reference surface: lib/llm/src/protocols/common.rs:248-316 SamplingOptions).
+reference surface: lib/llm/src/protocols/common.rs:248-316 SamplingOptions;
+where a decode program walks its rows a tile at a time, ``_walk_tiles``,
+every walk sums a row along the vocabulary within the same ``[ROW_TILE, V]``
+operations, so that holds however full the batch is: 0 units in the last
+place across loads on a v5e, scripts/pad_row_cost.py --tail).
 
 Penalty state lives on device as two [num_slots, vocab] buffers owned by the
 ModelRunner: ``counts`` (how often each token was *generated*) and ``seen``
@@ -38,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..ops.live_rows import live_row_list
 from ..protocols.common import SamplingOptions
 
 
@@ -304,6 +309,128 @@ def sample(
     tokens = jnp.where(
         params.temperature <= 0.0, greedy, sampled).astype(jnp.int32)
     return _pin(tokens, mesh, "dp") if whole_rows else tokens
+
+
+# ---- the tail over the rows that hold a token ----
+
+# Rows a tile, fixed from a timing of the tail alone on a v5e
+# (scripts/pad_row_cost.py --tail; PERF.md §5 "Since PR 47", §6). A tile's
+# gathers copy every row of their source whatever the tile holds, so a
+# tile costs its rows' share of the pass over all rows plus 0.1-0.25 ms:
+# at [64, 261120], 16 rows live, tiles of 8 / 16 / 32 take 1.36 / 1.00 /
+# 1.71 ms where all 64 rows at once take 2.94, at [64, 163840] 0.81 /
+# 0.60 / 0.98 of 1.68, at [64, 131072] 0.70 / 0.50 / 0.85 of 1.53. Eight
+# rows a tile pay the gathers twice for the same rows; 32 are one coarse
+# step for a batch a quarter full.
+ROW_TILE = 16
+
+
+def tile_rows(logits: jax.Array, mesh: Optional[Mesh], live) -> int:
+    """Rows a tile where the tail of ``logits`` [R, V] runs a tile of rows
+    at a time (``over_live_rows``, ``over_all_rows``), 0 where it runs
+    over ``[R, V]`` at once: it walks tiles where it is told which rows
+    are read, the logits lie on one device and half the rows are at least
+    a tile. On a mesh of several the tail keeps its full-row form
+    (``sample``'s whole rows are 16 a device at tp=4, and a list a device
+    would have to be made inside a ``shard_map``)."""
+    tiles = (live is not None and 2 * ROW_TILE <= logits.shape[0]
+             and (mesh is None or mesh.size == 1))
+    return ROW_TILE if tiles else 0
+
+
+def walks_live_rows(live, rows: int, tile: int):
+    """Whether a batch of ``rows`` with ``live`` of them live (traced or
+    not) is walked by its list of live rows: while the list is at least a
+    tile shorter than the batch. Every tile of the list gathers its rows
+    anew, the walk over all rows gathers once: of [64, 261120], 48 / 64
+    live, the list takes 2.66 / 3.53 ms and all rows 3.12, of [64, 163840]
+    1.69 / 2.25 and 1.86, of [64, 131072] 1.49 / 1.97 and 1.49 (my chip
+    run, PR 47)."""
+    return live <= rows - tile
+
+
+def tiled_rows(live: int, rows: int, tile: int) -> int:
+    """Rows the tail of a ``rows``-row program that walks tiles of
+    ``tile`` runs with ``live`` rows live: whole tiles of them by the
+    list, every row past it (``walks_live_rows``). The scheduler's count
+    (``dynamo_scheduler_sampling_rows_run_total``)."""
+    if walks_live_rows(live, rows, tile):
+        return -(-live // tile) * tile
+    return rows
+
+
+def _walk_tiles(r: int, trips, of_tile, tail) -> tuple:
+    """``tail`` a tile at a time, ``trips`` tiles: ``of_tile(i)`` gives
+    tile ``i``'s inputs and the rows [T] its outputs belong to (``r``:
+    to none). A tuple of ``[r, ...]`` arrays, zero where nothing was
+    written.
+
+    Whatever hands it the tiles, the tail is traced on ``[T, V]`` arrays
+    that are made before it starts (the barrier), so a row's sums along
+    the vocabulary are made the same way in every walk, and its token and
+    log-probability do not depend on how full the batch is. Without the
+    barrier a tile's making is fused into the tail's reductions, a
+    gather's pieces otherwise than a slice, and the log-probabilities of
+    the two walks differ by 1-2 units in the last place on the chip (as
+    a pass over ``[R, V]`` at once differs from either by one); the
+    pieces are also read again by every fusion that takes them, which
+    costs more than writing the tile once (16 of ``[64, 131072]``: 0.67
+    ms against 0.50; scripts/pad_row_cost.py --tail, my chip run,
+    PR 47)."""
+    def body(i, outs):
+        mine, to = of_tile(i)
+        drawn = tail(jax.lax.optimization_barrier(mine))
+        return tuple(o.at[to].set(x, mode="drop")
+                     for o, x in zip(outs, drawn))
+
+    outs = tuple(jnp.zeros((r,) + o.shape[1:], o.dtype)
+                 for o in jax.eval_shape(lambda: tail(of_tile(0)[0])))
+    return jax.lax.fori_loop(0, trips, body, outs)
+
+
+def over_live_rows(live: jax.Array, t: int, of_rows, tail) -> tuple:
+    """``tail`` on the rows where ``live`` [R] is true and zeros on the
+    others. ``of_rows(rows)`` takes ``rows`` [T] int32, numbers of rows,
+    and returns those rows of the tail's inputs; ``tail`` takes that and
+    returns a tuple of arrays ``[T, ...]``, one entry a row, none
+    depending on another row's.
+
+    The live rows are walked a tile of ``t`` at a time in a loop whose
+    trip count, ``ceil(n / t)``, is known on the device only: a row
+    without a token costs nothing, and a step's cost follows the batch
+    that is there and not ``max_batch_size``. Each tile gathers its own
+    rows: one gather of all the listed rows before the loop, its result
+    sliced a tile, was timed and lost (the ``[R, V]`` arrays are written
+    and read again: 1.55 ms against 0.91 at 16 of ``[64, 261120]``; my
+    chip run, PR 47). The last tile's rows past the list's end are its
+    padding (row ``R - 1`` again): computed, and written nowhere."""
+    r = live.shape[0]
+    listed = live_row_list(live)
+    rows = jnp.concatenate(
+        [listed.rows, jnp.full((-r % t,), r - 1, jnp.int32)])
+
+    def of_tile(i):
+        at = jax.lax.dynamic_slice_in_dim(rows, i * t, t)
+        return of_rows(at), jnp.where(i * t + jnp.arange(t) < listed.n, at, r)
+
+    return _walk_tiles(r, (listed.n + t - 1) // t, of_tile, tail)
+
+
+def over_all_rows(live: jax.Array, t: int, whole, tail) -> tuple:
+    """``over_live_rows`` for a batch too full for its list to pay:
+    ``whole`` is the tail's inputs for every row (gathered once, as a pass
+    over all rows gathers them), and a tile is ``t`` rows as they lie
+    (the last one moved back to end with the batch: its first rows are
+    the tile's before, written twice with the same values)."""
+    r = live.shape[0]
+
+    def of_tile(i):
+        at = jnp.minimum(i * t, r - t) + jnp.arange(t)
+        mine = jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, at[0], t), whole)
+        return mine, jnp.where(live[at], at, r)
+
+    return _walk_tiles(r, -(-r // t), of_tile, tail)
 
 
 # ---- device-resident finish detection (the persistent decode loop) ----

@@ -47,6 +47,7 @@ from .sampling import (
     seed_to_key,
     stop_id_row,
     stop_seq_rows,
+    tiled_rows,
 )
 
 logger = logging.getLogger(__name__)
@@ -945,6 +946,21 @@ class Scheduler:
             "counted only for a program whose trace took a decode kernel "
             "with a list of live rows (0 on the XLA route, or for a "
             "trunk that hands its kernels no mask)",
+        )
+        self._sampling_rows_ctr = reg.counter(
+            "dynamo_scheduler_sampling_rows_total",
+            "Rows of the decode programs' sampling tail (penalties, "
+            "filters, the draw, the log-probability), whether or not "
+            "read: max_batch_size a step, counted with "
+            "dynamo_scheduler_decode_rows_total",
+        )
+        self._sampling_rows_run_ctr = reg.counter(
+            "dynamo_scheduler_sampling_rows_run_total",
+            "The part of dynamo_scheduler_sampling_rows_total the tail "
+            "ran on: the live rows in whole tiles while a program whose "
+            "tail walks tiles has a tile of rows free, all of them "
+            "otherwise (a fuller batch, a mesh of several devices, a "
+            "block pass, fewer rows than two tiles)",
         )
 
         self._fetch_ctr = reg.counter(
@@ -3795,11 +3811,17 @@ class Scheduler:
         program's attention kernels walk a list of live rows and so
         take no grid step for them (``ModelRunner.row_list_programs``,
         recorded when the program was traced: call this after the
-        dispatch)."""
+        dispatch). Likewise the rows its sampling tail ran on
+        (``sampling_tile_programs``, ``sampling.tiled_rows``; a row that
+        freezes inside a burst still counts for all its steps)."""
         b = self.config.max_batch_size
         self._decode_rows_ctr.inc(b * steps)
         if program in getattr(self.runner, "row_list_programs", ()):
             self._decode_rows_skipped_ctr.inc((b - live) * steps)
+        self._sampling_rows_ctr.inc(b * steps)
+        tile = getattr(self.runner, "sampling_tile_programs", {}).get(program)
+        self._sampling_rows_run_ctr.inc(
+            (tiled_rows(live, b, tile) if tile else b) * steps)
 
     async def _decode(self, loop, active: List[EngineRequest],
                       k_steps: int = 1) -> None:

@@ -6,6 +6,8 @@ cells serve, with a few rows live and the rest as the scheduler fills
 them (``ctx_lens = 1``, no slot).
 
     python scripts/pad_row_cost.py [--repo DIR] [--out chiprun_out/pad_row_cost.json]
+    python scripts/pad_row_cost.py --tail [--tiles 8,16,32] [--repo DIR] [--out ...]
+    JAX_PLATFORMS=cpu python scripts/pad_row_cost.py --tail --hash [--repo DIR]
 
 ``--repo`` is the checkout whose ``dynamo_tpu`` is timed (a parent commit
 unpacked beside this one); a checkout whose kernels take no ``live_rows``
@@ -14,11 +16,35 @@ wall time of a step's calls over ``--reps`` dispatches and per call; the
 last line is the whole table as JSON. It measures the chip and nothing
 else: on any other backend it says so and exits 1 (the interpreter's
 answers are tests/test_pallas_decode.py's business).
+
+``--tail`` times the other thing a pad row costs instead: the sampling
+tail of a decode step alone (``model_runner._sample_and_logprobs``:
+penalty rows, the filter's search, the draw, the log-probability, the
+counts' update) at the cells' ``[rows, vocabulary]`` with a quarter, half,
+three quarters and all of the rows live, as the chat mix asks
+(temperature 0.7, top-p 0.9; a pad row as the host leaves it), once over
+every row (``live`` left out: what a checkout from before the mask does)
+and once a tile size of ``--tiles``, which stands in for
+``sampling.ROW_TILE`` in that trace (``--always``: by the list of live
+rows however full the batch is, where the program walks all rows as they
+lie in the batch's last tile: what that threshold was fixed from). It also says what
+the live rows' tokens and log-probabilities were compared with the same
+rows' in a batch with every row live (``across_loads``: a row's result
+must not depend on how full the batch is) and with the pass over every
+row at once (``against_one_pass``: another program, whose sums along the
+vocabulary are tiled otherwise): whether every token is the same, and
+the largest difference of a log-probability in units in the last place.
+This is the timing ``ROW_TILE`` was fixed from (PERF.md §5 "Since
+PR 47"). ``--tail --hash`` needs no chip and times nothing: it prints
+the sha256 of the tail's lowered text at every cell's ``[rows,
+vocabulary]``, the mask handed where the checkout takes one, to tell
+under two checkouts which configurations' tails a change reaches.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import inspect
 import json
 import os
@@ -31,6 +57,17 @@ ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 ap.add_argument("--out", default=None)
 ap.add_argument("--reps", type=int, default=60)
+ap.add_argument("--tail", action="store_true",
+                help="time the sampling tail, not the decode kernels")
+ap.add_argument("--tiles", default="8,16,32",
+                help="--tail: the tile sizes to stand in for ROW_TILE")
+ap.add_argument("--always", action="store_true",
+                help="--tail: walk the list of live rows however full the "
+                "batch is, not only while the program would "
+                "(sampling.walks_live_rows)")
+ap.add_argument("--hash", action="store_true",
+                help="--tail: print the lowered tail's sha256 a shape and "
+                "stop (no chip)")
 args = ap.parse_args()
 sys.path.insert(0, os.path.abspath(args.repo))
 
@@ -141,11 +178,166 @@ def mla_case(name, rng, reps):
     return layers, b, n_live, _time(step, (ql, qr, c, kr, bt, ctx), reps)
 
 
+# name: (rows, vocabulary) of benchmark/configs/: falcon-h1-34b,
+# moonlight-16b-a3b, xing4-29b-a4b, phi3-mini-4k
+TAIL_CASES = {
+    "falcon-h1": (64, 261120),
+    "moonlight": (64, 163840),
+    "xing4": (64, 131072),
+    "phi3": (32, 32064),
+}
+# the shapes no walk is traced at, for --hash: minicpm-sala-9b,
+# trinity-mini-26b-a3b
+UNTILED_CASES = {"sala": (24, 73448), "trinity": (16, 200192)}
+
+
+def tail_hashes():
+    """{name: sha256 of the lowered tail at that shape}, abstract
+    operands: nothing is compiled or run."""
+    import hashlib
+    import types
+
+    from dynamo_tpu.engine import model_runner, sampling
+
+    tail = model_runner._sample_and_logprobs
+    masked = "live" in inspect.signature(tail).parameters
+    sd = jax.ShapeDtypeStruct
+    found = {}
+    for name, (b, v) in {**TAIL_CASES, **UNTILED_CASES}.items():
+        samp = jax.tree_util.tree_map(
+            lambda a: sd((b,) + a.shape[1:], a.dtype),
+            sampling.SamplingParams.zeros(1))
+        text = jax.jit(lambda *a: tail(
+            types.SimpleNamespace(vocab_size=v), None, *a[:-1],
+            **({"live": a[-1]} if masked else {}))).lower(
+            sd((b, v), jnp.bfloat16), samp, sd((b, v), jnp.int32),
+            sd((b, v), jnp.bool_), sd((b, v), jnp.float32),
+            sd((b,), jnp.int32), sd((b,), jnp.bool_), sd((), jnp.bool_),
+            sd((b,), jnp.bool_)).as_text()
+        found[name] = hashlib.sha256(text.encode()).hexdigest()
+        print(found[name], f"{name:10s} [{b}, {v}]", flush=True)
+    return found
+
+
+def _time_tail(step, operands, reps):
+    """``_time`` for a program that is given the counts and gives them
+    back, as a decode step is (donated: the update is in place)."""
+    logits, samp, counts, *rest = operands
+    counts = jnp.copy(counts)
+    times = []
+    for _ in range(6):
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            toks, lps, counts = step(logits, samp, counts, *rest)
+        jax.block_until_ready(counts)
+        times.append((time.perf_counter() - t0) / reps)
+    return statistics.median(times[1:]), (toks, lps)
+
+
+def _ulps(a, b):
+    """Largest distance of two float32 arrays in units in the last place."""
+    def image(x):
+        bits = np.asarray(x, np.float32).view(np.int32).astype(np.int64)
+        return np.where(bits < 0, -(bits & 0x7FFFFFFF), bits)
+    return int(np.abs(image(a) - image(b)).max(initial=0))
+
+
+def _compared(into, got, ref, live):
+    """Fold the comparison of ``got`` with ``ref`` (tokens, logprobs) on
+    the rows ``live`` into ``into``."""
+    (toks, lps), (r_toks, r_lps) = [
+        [np.asarray(x)[live] for x in pair] for pair in (got, ref)]
+    into["tokens_equal"] &= bool(np.array_equal(toks, r_toks))
+    into["logprob_ulps"] = max(into["logprob_ulps"], _ulps(lps, r_lps))
+
+
+def tail_case(name, rng, reps, tiles):
+    """One shape: {"all rows": ms, "<T>": {"<live>": ms, ...}, ...} and
+    what every tiled pass gave the live rows beside what they get in a
+    full batch and in the pass over all rows at once."""
+    import types
+
+    from dynamo_tpu.engine import model_runner, sampling
+
+    b, v = TAIL_CASES[name]
+    tail = model_runner._sample_and_logprobs
+    cfg = types.SimpleNamespace(vocab_size=v)
+    logits = _normal(0, (b, v)) * 3.0
+    state = (jnp.zeros((b, v), jnp.int32), jnp.zeros((b, v), jnp.bool_),
+             jnp.zeros((b, v), jnp.float32))
+    slots = jnp.arange(b, dtype=jnp.int32)
+    keys = jnp.asarray(rng.integers(0, 2 ** 32, (b, 2)), jnp.uint32)
+
+    def operands(n_live):
+        live = np.zeros(b, bool)
+        live[rng.permutation(b)[:n_live]] = True
+        samp = sampling.SamplingParams.zeros(b)
+        samp = dataclasses.replace(
+            samp, keys=keys,
+            temperature=jnp.where(live, 0.7, 0.0).astype(jnp.float32),
+            top_p=jnp.where(live, 0.9, 1.0).astype(jnp.float32))
+        return logits, samp, *state, slots, jnp.asarray(live)
+
+    def program(masked):
+        # the counts come back as a step returns them, donated
+        def run(logits, samp, counts, seen, bias, slots, live):
+            kw = {"live": live} if masked else {}
+            toks, lps, _, _, counts = tail(
+                cfg, None, logits, samp, counts, seen, bias, slots, live,
+                jnp.asarray(False), **kw)
+            return toks, lps, counts
+        return jax.jit(run, donate_argnums=2)
+
+    lives = sorted({b // 4, b // 2, 3 * b // 4, b})
+    row = {"rows": b, "vocabulary": v}
+    plain = program(False)
+    wanted = {n: operands(n) for n in lives}
+    seconds, one_pass = _time_tail(plain, wanted[b], reps)
+    row["all rows"] = 1e3 * seconds
+    print(f"{name:12s} [{b}, {v}] every row    {row['all rows']:.3f} ms",
+          flush=True)
+    if "live" not in inspect.signature(tail).parameters:
+        return row
+    if args.always:
+        model_runner.walks_live_rows = lambda live, rows, tile: True
+    for t in tiles:
+        sampling.ROW_TILE = t
+        tiled = program(True)
+        row[str(t)] = cell = {"ms": {}}
+        got = {}
+        for n in lives:
+            seconds, got[n] = _time_tail(tiled, wanted[n], reps)
+            cell["ms"][str(n)] = 1e3 * seconds
+        for against in ("across_loads", "against_one_pass"):
+            cell[against] = {"tokens_equal": True, "logprob_ulps": 0}
+        for n in lives:
+            live = np.asarray(wanted[n][-1])
+            _compared(cell["across_loads"], got[n], got[b], live)
+            _compared(cell["against_one_pass"], got[n], one_pass, live)
+        print(f"{name:12s} [{b}, {v}] tiles of {t:2d}  " + "  ".join(
+            f"{n} live {ms:.3f} ms" for n, ms in cell["ms"].items())
+            + f"  across loads {cell['across_loads']}"
+            + f"  against one pass {cell['against_one_pass']}", flush=True)
+    return row
+
+
 def main():
+    if args.tail and args.hash:
+        return _finish({"repo": os.path.abspath(args.repo),
+                        "tail_hashes": tail_hashes()})
     if jax.default_backend() != "tpu":
         sys.exit(f"pad_row_cost.py times the kernels on a TPU; the backend "
                  f"here is {jax.default_backend()!r}: nothing measured")
     device = jax.devices()[0]
+    if args.tail:
+        tiles = [int(t) for t in args.tiles.split(",")]
+        table = {"repo": os.path.abspath(args.repo),
+                 "device": device.device_kind, "always": args.always,
+                 "tail": {
+                     name: tail_case(name, np.random.default_rng(7),
+                                     args.reps, tiles)
+                     for name in TAIL_CASES}}
+        return _finish(table)
     table = {"repo": os.path.abspath(args.repo), "device": device.device_kind,
              "live_rows": _takes_live_rows(pallas_decode.paged_decode_attention),
              "cases": {}}
@@ -160,6 +352,10 @@ def main():
             print(f"{name:24s} rows {b:3d} live {n_live:3d} "
                   f"step {row['step_ms']:.3f} ms  call {row['call_us']:.1f} us",
                   flush=True)
+    _finish(table)
+
+
+def _finish(table):
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:

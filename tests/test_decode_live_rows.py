@@ -9,12 +9,21 @@ handed its kernels a list of live rows, and on the XLA route it counts
 none. Every program that reaches the decode route is driven: the step,
 the fused burst and the chained burst. The kernels themselves are held
 to the XLA reference in tests/test_pallas_decode.py.
+
+The sampling tail takes the same mask: with a tile small enough for four
+slots the same engines stream what they stream without tiles, the burst
+and the chain what the single step streams (rows finish inside a burst of
+four), and ``dynamo_scheduler_sampling_rows_run_total`` stays under
+``..._rows_total`` while the batch has pad rows.
 """
 
 import asyncio
 
+import types
+
 import pytest
 
+from dynamo_tpu.engine import sampling
 from dynamo_tpu.engine.serving import JaxServingEngine
 from dynamo_tpu.llm.model_card import ModelDeploymentCard
 from dynamo_tpu.protocols.common import SamplingOptions
@@ -30,6 +39,9 @@ PROGRAMS = {
     "burst": (4, 1, "decode_burst"),
     "chain": (4, 2, "decode_burst_df"),
 }
+
+
+PROGRAMS_BY_STEPS = {(m, p): program for m, p, program in PROGRAMS.values()}
 
 
 def _serve(model_dir, impl, multi_step, pipeline):
@@ -54,8 +66,22 @@ def _serve(model_dir, impl, multi_step, pipeline):
                    sum(sched._decode_rows_skipped_ctr.values.values()))
         programs = set(engine.runner.row_list_programs)
         text = sched.registry.render()
+        total = lambda c: sum(c.values.values())
+        tail = types.SimpleNamespace(
+            programs=dict(engine.runner.sampling_tile_programs),
+            rows=total(sched._sampling_rows_ctr),
+            run=total(sched._sampling_rows_run_ctr))
+        # and one step each of a full batch and of one row, as counted
+        for live in (4, 1):
+            before = (total(sched._sampling_rows_ctr),
+                      total(sched._sampling_rows_run_ctr))
+            sched._count_decode_rows(PROGRAMS_BY_STEPS[multi_step, pipeline],
+                                     live)
+            setattr(tail, f"step_of_{live}", (
+                total(sched._sampling_rows_ctr) - before[0],
+                total(sched._sampling_rows_run_ctr) - before[1]))
         await engine.close()
-        return streams, counted, programs, text
+        return streams, counted, programs, text, tail
 
     return asyncio.new_event_loop().run_until_complete(go())
 
@@ -65,9 +91,9 @@ def test_pad_rows_are_skipped_and_live_rows_never(model_dir, monkeypatch,  # noq
                                                   name):
     monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     multi_step, pipeline, program = PROGRAMS[name]
-    xla, (x_rows, x_skipped), x_programs, x_text = _serve(
+    xla, (x_rows, x_skipped), x_programs, x_text, _ = _serve(
         model_dir, "xla", multi_step, pipeline)
-    pal, (p_rows, p_skipped), p_programs, p_text = _serve(
+    pal, (p_rows, p_skipped), p_programs, p_text, _ = _serve(
         model_dir, "pallas", multi_step, pipeline)
     assert [len(t) for t, _ in pal] == [12, 9, 14]
     assert pal == xla
@@ -79,3 +105,33 @@ def test_pad_rows_are_skipped_and_live_rows_never(model_dir, monkeypatch,  # noq
     assert p_rows % 4 == 0 and 0.5 * p_rows <= p_skipped < p_rows
     for text in (x_text, p_text):
         assert ROWS in text and SKIPPED in text
+
+
+_STEP_STREAMS = []
+
+
+@pytest.mark.parametrize("name", list(PROGRAMS))
+def test_sampling_runs_on_tiles_of_live_rows_and_streams_the_same(
+        model_dir, monkeypatch, name):  # noqa: F811
+    multi_step, pipeline, program = PROGRAMS[name]
+    # four slots are fewer than two of the real tiles: every row, as before
+    plain, _, _, text, p_tail = _serve(model_dir, "xla", multi_step, pipeline)
+    assert not p_tail.programs and p_tail.rows == p_tail.run > 0
+    assert p_tail.step_of_4 == p_tail.step_of_1 == (4, 4)
+    # tiles of two: walked while at most two of the four rows are live,
+    # which they always are here; a stream does not change for it
+    monkeypatch.setattr(sampling, "ROW_TILE", 2)
+    tiled, _, _, _, t_tail = _serve(model_dir, "xla", multi_step, pipeline)
+    assert [len(t) for t, _ in tiled] == [12, 9, 14]
+    assert tiled == plain
+    assert t_tail.programs[program] == 2
+    assert t_tail.rows % 4 == 0 and t_tail.run == t_tail.rows // 2
+    assert t_tail.step_of_4 == (4, 4) and t_tail.step_of_1 == (4, 2)
+    assert "dynamo_scheduler_sampling_rows_total" in text
+    assert "dynamo_scheduler_sampling_rows_run_total" in text
+    # a burst of four steps with rows that finish inside it (12, 9 and 14
+    # tokens) streams what the single step streams
+    if name == "step":
+        _STEP_STREAMS[:] = [tiled]
+    elif _STEP_STREAMS:
+        assert [t for t, _ in tiled] == [t for t, _ in _STEP_STREAMS[0]]

@@ -423,6 +423,210 @@ def test_sample_sorts_nothing_and_reads_the_logits_a_bounded_number_of_times(
     assert 0 < full_reductions <= _MAX_FULL_REDUCTIONS, full_reductions
 
 
+# ---- the tail over the rows that hold a token (model_runner) ----
+
+_TAIL_B, _TAIL_V, _TAIL_SLOTS = 72, 257, 80
+
+
+def _tail_case():
+    """A batch of 72 rows on 80 slots, every regime's rows in turn, each
+    row with its own key, counter, penalty rows and bias row."""
+    rng = np.random.default_rng(47)
+    kinds = [r for name in ("mixed_rows", "penalties", "all_three", "greedy")
+             for r in _REGIMES[name]]
+    rows = [kinds[i % len(kinds)] for i in range(_TAIL_B)]
+    b, v, n = _TAIL_B, _TAIL_V, _TAIL_SLOTS
+    return dict(
+        logits=jnp.asarray(rng.normal(size=(b, v)) * 3.0, jnp.bfloat16),
+        samp=_params(rows, rng.integers(0, 2**32, size=(b, 2)),
+                     rng.integers(0, 1000, size=b)),
+        counts=jnp.asarray(rng.integers(0, 3, size=(n, v)), jnp.int32),
+        seen=jnp.asarray(rng.integers(0, 2, size=(n, v)).astype(bool)),
+        bias=jnp.asarray(rng.normal(size=(n, v)) * 0.3, jnp.float32),
+        slots=jnp.asarray(rng.permutation(n)[:b], jnp.int32),
+        extra=jnp.asarray(
+            np.where(rng.random((b, v)) < 0.1, -1e9, 0.0), jnp.float32),
+    )
+
+
+def _tail(masked, guided):
+    import types
+
+    from dynamo_tpu.engine import model_runner as mr
+
+    cfg = types.SimpleNamespace(vocab_size=_TAIL_V)
+
+    def run(case, live, commit, want_top):
+        return mr._sample_and_logprobs(
+            cfg, None, case["logits"], case["samp"], case["counts"],
+            case["seen"], case["bias"], case["slots"], commit, want_top,
+            extra_bias=case["extra"] if guided else None,
+            live=live if masked else None)
+
+    return jax.jit(run)
+
+
+@pytest.fixture(scope="module")
+def tails():
+    return {(masked, guided): _tail(masked, guided)
+            for masked in (False, True) for guided in (False, True)}
+
+
+@pytest.mark.parametrize("lie", ["scattered", "contiguous"])
+@pytest.mark.parametrize("n", [0, 1, S.ROW_TILE - 1, S.ROW_TILE,
+                               S.ROW_TILE + 1, 2 * S.ROW_TILE + 1,
+                               _TAIL_B - S.ROW_TILE,
+                               _TAIL_B - S.ROW_TILE + 1, _TAIL_B])
+def test_tail_on_live_rows_is_the_tail_on_all_rows_bit_for_bit(tails, n, lie):
+    """With the mask of rows whose token is read, the tail gives those
+    rows the very tokens, log-probabilities and top alternatives it gives
+    them without one, whichever tile a row lands in (none, one, the last
+    of two or four, and in a fuller batch a tile of rows as they lie),
+    zeros to the others, and counts the token of a committed row and of
+    no other."""
+    case = _tail_case()
+    rng = np.random.default_rng(n)
+    live = np.zeros(_TAIL_B, bool)
+    start = int(rng.integers(0, _TAIL_B - n + 1))
+    live[rng.permutation(_TAIL_B)[:n] if lie == "scattered"
+         else np.arange(start, start + n)] = True
+    commit = live & (rng.random(_TAIL_B) < 0.7)      # a burst's frozen rows
+    for guided in (False, True):
+        got = tails[True, guided](case, live, commit, True)
+        ref = tails[False, guided](case, live, commit, True)
+        for name, g, r in zip(("tokens", "lps", "top_vals", "top_ids"),
+                              got, ref):
+            np.testing.assert_array_equal(
+                np.asarray(g)[live], np.asarray(r)[live], err_msg=name)
+        assert not np.asarray(got[0])[~live].any()
+        assert not np.asarray(got[1])[~live].any()
+        added = np.asarray(got[4]) - np.asarray(case["counts"])
+        want = np.zeros_like(added)
+        np.add.at(want, (np.asarray(case["slots"])[commit],
+                         np.asarray(ref[0])[commit]), 1)
+        np.testing.assert_array_equal(added, want)
+
+
+def test_decode_tail_runs_in_a_loop_over_tiles_with_a_traced_bound():
+    """At a served shape the tail with a mask traces to one ``cond`` on
+    how full the batch is, each branch one loop of tiles in which every
+    reduction along the vocabulary sees a tile's rows: the live rows'
+    tiles by their list, of unknown trip count, nothing gathered or
+    scattered at the batch's size; or every row's, the rows gathered
+    once as a pass over all rows gathers them. Both walks run the same
+    operations on ``[T, V]``, so a row's sums do not depend on how full
+    the batch is. Outside the two, and outside the gated
+    top-alternatives branch, nothing reduces over ``[B, V]``. Without a
+    mask the tail is no call and no loop: the pass over ``[B, V]`` as it
+    lay in the program before. Trace only."""
+    import types
+
+    from dynamo_tpu.engine import model_runner as mr
+
+    b, v, t = 64, 163840, S.ROW_TILE
+    sd = jax.ShapeDtypeStruct
+    params = jax.tree_util.tree_map(
+        lambda a: sd((b,) + a.shape[1:], a.dtype), S.SamplingParams.zeros(1))
+
+    def traced(masked):
+        return jax.make_jaxpr(
+            lambda *a: mr._sample_and_logprobs(
+                types.SimpleNamespace(vocab_size=v), None, *a[:-1],
+                live=a[-1] if masked else None)
+        )(sd((b, v), jnp.bfloat16), params, sd((b, v), jnp.int32),
+          sd((b, v), jnp.bool_), sd((b, v), jnp.float32), sd((b,), jnp.int32),
+          sd((b,), jnp.bool_), sd((), jnp.bool_), sd((b,), jnp.bool_)).jaxpr
+
+    def reductions(jp):
+        """(elements reduced, times or None) of every reduction in it."""
+        return [(e.invars[0].aval.size, times) for e, times in _walk(jp)
+                if e.primitive.name.startswith(_REDUCTIONS)]
+
+    plain = traced(False)
+    assert not [e for e in plain.eqns if e.primitive.name in ("jit", "pjit")
+                and e.params["name"] == "_tail_ops"]
+    # the top-k search's gate and want_top: none on how full the batch is
+    assert [e.primitive.name for e in plain.eqns].count("cond") == 2
+    assert max(size for size, _ in reductions(plain)) == b * v
+
+    # the tiled tail is traced once for all of a process's decode
+    # programs: a call of its own inside them
+    (call,) = [e for e in traced(True).eqns
+               if e.primitive.name in ("jit", "pjit")
+               and e.params["name"] == "_tail_ops"]
+    jaxpr = call.params["jaxpr"].jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 2                      # the fill, and want_top
+    outside = [e for e in jaxpr.eqns if e.primitive.name != "cond"]
+    assert not [e for e in outside if e.primitive.name == "while"]
+    assert all(e.invars[0].aval.size < b * v for e in outside
+               if e.primitive.name.startswith(_REDUCTIONS))
+    every, listed = (br.jaxpr for br in conds[0].params["branches"])
+    bodies = []
+    for walk in (every, listed):
+        # one loop (a scan where the trip count is the batch's), and in
+        # it the search on [T, V] alone
+        (loop,) = [e for e in walk.eqns
+                   if e.primitive.name in ("while", "scan")]
+        assert (loop.primitive.name == "while") == (walk is listed)
+        bodies.append(loop.params[
+            "body_jaxpr" if walk is listed else "jaxpr"].jaxpr)
+        inside = reductions(bodies[-1])
+        assert inside and max(size for size, _ in inside) == t * v
+        assert sum(1 for size, _ in inside if size == t * v) > 8
+        assert not [1 for size, _ in reductions(walk) if size > t * v]
+        for e, _ in _walk(walk):
+            if e.primitive.name.startswith("scatter"):
+                assert e.invars[2].aval.size <= t, e
+    # by the list: the tile's own rows are all that is ever gathered
+    for e, _ in _walk(listed):
+        if e.primitive.name == "gather":
+            assert e.outvars[0].aval.size <= t * v, e
+    # every row: the penalty and bias rows once, outside the loop
+    assert sorted(e.outvars[0].aval.size for e in every.eqns
+                  if e.primitive.name == "gather")[-3:] == [b * v] * 3
+    assert not [e for e, _ in _walk(bodies[0])
+                if e.primitive.name == "gather"
+                and e.outvars[0].aval.size >= t * v]
+    # and the two walks reduce the same things in the same order
+    assert ([size for size, _ in reductions(bodies[0])]
+            == [size for size, _ in reductions(bodies[1])])
+
+
+@pytest.mark.parametrize("rows,devices", [(16, 1), (24, 1), (64, 4)])
+def test_tail_that_walks_no_tiles_lowers_to_the_tail_without_a_mask(
+        rows, devices):
+    """Fewer rows than two tiles, or a mesh of several devices: the mask
+    changes nothing, and the program holds the tail's operations as it
+    held them before there was one (no call, no loop; the text is
+    compared). Lowering only."""
+    import types
+
+    from jax.sharding import Mesh
+
+    from dynamo_tpu.engine import model_runner as mr
+
+    v = 4096
+    mesh = None if devices == 1 else Mesh(
+        np.array(jax.devices()[:devices]).reshape(1, devices), ("dp", "tp"))
+    sd = jax.ShapeDtypeStruct
+    params = jax.tree_util.tree_map(
+        lambda a: sd((rows,) + a.shape[1:], a.dtype),
+        S.SamplingParams.zeros(1))
+    operands = (sd((rows, v), jnp.bfloat16), params, sd((rows, v), jnp.int32),
+                sd((rows, v), jnp.bool_), sd((rows, v), jnp.float32),
+                sd((rows,), jnp.int32), sd((rows,), jnp.bool_),
+                sd((), jnp.bool_), sd((rows,), jnp.bool_))
+
+    def text(masked):
+        return jax.jit(lambda *a: mr._sample_and_logprobs(
+            types.SimpleNamespace(vocab_size=v), mesh, *a[:-1],
+            live=a[-1] if masked else None)).lower(*operands).as_text()
+
+    assert S.tile_rows(operands[0], mesh, operands[-1]) == 0
+    assert text(True) == text(False)
+
+
 _COLLECTIVE = re.compile(
     r" = (.*?) (all-reduce|all-gather|all-to-all|collective-permute"
     r"|reduce-scatter)(?:-start)?\(")

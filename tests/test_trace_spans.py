@@ -869,11 +869,10 @@ def _lower_tiny_step(strip):
     from dynamo_tpu.engine import model_runner as mr
     from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 
-    real, real_tail = jax.named_scope, mr._sample_and_logprobs
-    if strip:
-        jax.named_scope = lambda name: contextlib.nullcontext()
-        mr._sample_and_logprobs = real_tail.__wrapped__   # the decorator's
+    real = jax.named_scope
     try:
+        if strip:
+            jax.named_scope = lambda name: contextlib.nullcontext()
         cfg = EngineConfig(
             model=ModelConfig(vocab_size=256, hidden_size=32,
                               intermediate_size=64, num_layers=2,
@@ -891,7 +890,7 @@ def _lower_tiny_step(strip):
             r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state, packed)
         return lowered.as_text(debug_info=True), lowered.compile()
     finally:
-        jax.named_scope, mr._sample_and_logprobs = real, real_tail
+        jax.named_scope = real
 
 
 def test_named_scopes_change_no_compiled_code():
