@@ -154,6 +154,24 @@ class ModelConfig:
     denoising_steps: int = 0
     remasking_strategy: str = ""
     confidence_threshold: float = 0.0
+    # Granite 4.0-H (models/granite_hybrid.py, model_type
+    # "granitemoehybrid"): layer_types names each layer's token mixer,
+    # "mamba" (Falcon-H1's Mamba-2 mixer alone: state by slot, no pages)
+    # or "attention" (GQA with no positional term: pages, no state);
+    # routed experts and a shared expert of shared_intermediate_size
+    # follow every layer. Every sublayer adds residual_multiplier times
+    # its output; the softmax scale is attention_multiplier (0: the
+    # head's 1/sqrt(head_dim) of every other family).
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    shared_intermediate_size: int = 0
+    # one expert-parallel rank's share of every expert layer, on one
+    # device and with no exchange (models/mixtral.routed_experts):
+    # num_experts counts the experts held, experts_of the published
+    # ones the router scores (0: every expert is held) and expert_rank
+    # says which share: experts [expert_rank * num_experts, + num_experts)
+    experts_of: int = 0
+    expert_rank: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -169,6 +187,13 @@ class ModelConfig:
                     f"kv_lora_rank={self.kv_lora_rank} selects MLA attention, "
                     f"which also requires {', '.join(missing)} > 0"
                 )
+        if self.experts_of and (
+                self.num_experts <= 0 or self.experts_of % self.num_experts
+                or not 0 <= self.expert_rank < self.experts_of // self.num_experts):
+            raise ValueError(
+                f"a share of {self.num_experts} experts, rank "
+                f"{self.expert_rank}, does not divide the published "
+                f"{self.experts_of}")
 
     @classmethod
     def from_hf_config(cls, config: dict) -> "ModelConfig":

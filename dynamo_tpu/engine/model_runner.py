@@ -561,10 +561,12 @@ class ModelRunner:
     # ---------- routed experts' counters ----------
 
     def _init_moe_counters(self) -> None:
-        """``dynamo_moe_{active_experts,expert_slots,routed_rows}_total``
-        by phase=decode|prefill, counted on the device: the step carries
-        a [2, 3] int32 accumulator (phase x (experts with at least one
-        row, routed rows, steps), summed over the MoE layers) the way it
+        """``dynamo_moe_{active_experts,expert_slots,routed_rows,
+        held_picks}_total`` by phase=decode|prefill, counted on the
+        device: the step carries a [2, 4] int32 accumulator (phase x
+        (experts held with at least one row, routed rows, those of them
+        that fell on an expert held, steps), summed over the MoE layers:
+        ``models/mixtral.routing_stats``) the way it
         carries the sampling ``counts``, and /metrics reads it when it
         is rendered — no fetch and no program of its own a step. int32
         wraps; the reader takes differences modulo 2**32. A
@@ -578,10 +580,10 @@ class ModelRunner:
         if cfg.num_experts <= 0 or self.config.pp_size > 1:
             return
         self.moe_counts = jax.device_put(
-            np.zeros((2, 3), np.int32), NamedSharding(self.mesh, P()))
+            np.zeros((2, 4), np.int32), NamedSharding(self.mesh, P()))
         n_moe = cfg.num_layers - min(cfg.first_k_dense_replace, cfg.num_layers)
-        slots_a_step = cfg.num_experts * n_moe
-        last = np.zeros((2, 3), np.int64)
+        slots_a_step = cfg.num_experts * n_moe     # the experts held
+        last = np.zeros((2, 4), np.int64)
         lock = threading.Lock()
 
         def refresh():
@@ -596,25 +598,31 @@ class ModelRunner:
                 for i, phase in enumerate(("decode", "prefill")):
                     active.inc(float(delta[i, 0]), phase=phase)
                     rows.inc(float(delta[i, 1]), phase=phase)
-                    slots.inc(float(delta[i, 2] * slots_a_step), phase=phase)
+                    held.inc(float(delta[i, 2]), phase=phase)
+                    slots.inc(float(delta[i, 3] * slots_a_step), phase=phase)
 
         # registered (and so rendered) first: its one read of the device
-        # brings all three up to date
+        # brings all four up to date
         reg = self.compiles.registry
         active = _DeviceFedCounter(
             "dynamo_moe_active_experts_total",
-            "Experts that had at least one row, summed over MoE layers and "
-            "steps, by phase=decode|prefill (counted on the device)",
+            "Experts held here that had at least one row, summed over MoE "
+            "layers and steps, by phase=decode|prefill (counted on the device)",
             refresh)
         reg.register(active)
         slots = reg.counter(
             "dynamo_moe_expert_slots_total",
-            "Experts x MoE layers x steps: what active_experts would be if "
-            "every step touched every expert, by phase")
+            "Experts held x MoE layers x steps: what active_experts would "
+            "be if every step touched every expert held, by phase")
         rows = reg.counter(
             "dynamo_moe_routed_rows_total",
             "(token, chosen expert) rows of real tokens, summed over MoE "
             "layers and steps, by phase")
+        held = reg.counter(
+            "dynamo_moe_held_picks_total",
+            "Those routed rows whose expert is held here: all of them "
+            "unless the engine holds one expert-parallel rank's share "
+            "(ModelConfig.experts_of), then that share's, by phase")
 
     def _init_family_counters(self) -> None:
         """Counters a family keeps in its cache pytree (``STEP_COUNTERS``:
@@ -681,6 +689,7 @@ class ModelRunner:
         cfg = self.config.model
         mesh = self.mesh
         arch = self.arch
+        keeps_slots = self.keeps.slots     # (pp_size > 1 is refused for it)
         if self.config.pp_size > 1:
             from ..parallel.pipeline import pipeline_forward
 
@@ -697,7 +706,9 @@ class ModelRunner:
                     state_slots=None):
             args = (params, cfg, tokens, positions, cache, bt, slots, ctx)
             if trunk is not None:
-                return trunk(*args, mesh=mesh)
+                # a counted trunk with records by slot is told the slots
+                by_slot = {"state_slots": state_slots} if keeps_slots else {}
+                return trunk(*args, mesh=mesh, **by_slot)
             return arch.forward(*args, mesh=mesh, return_hidden=True,
                                 state_slots=state_slots)
 
@@ -776,7 +787,7 @@ class ModelRunner:
                    greedy_all, k_cache, v_cache, counts, seen, bias)
             if moe_counts:
                 # row 0 decode, row 1 prefill: (experts with a row,
-                # routed rows, steps); carried like ``counts``, read
+                # routed rows, rows held, steps); carried like ``counts``, read
                 # when /metrics is rendered
                 row = jnp.concatenate(
                     [moe_step[0], jnp.ones((1,), jnp.int32)])

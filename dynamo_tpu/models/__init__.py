@@ -129,6 +129,10 @@ class Family:
     # keys (its module's claimed_keys and CLAIM)
     field: str = ""
     unserved: str = ""
+    # other rows' fields this family computes too: set, they do not
+    # refuse it (two families run the state-space mixer; two read
+    # layer_types)
+    reads: Tuple[str, ...] = ()
     staged: bool = False    # parallel/pipeline.py stages its trunk
 
     @property
@@ -158,6 +162,12 @@ FAMILIES = (
            unserved="layer_types ({n} entries) needs a family that keeps "
                     "pages a kind of layer; model_family {family!r} has none "
                     "(models/afmoe.py is selected by model_type afmoe)"),
+    Family("granite_hybrid", model_types=("granitemoehybrid",),
+           field="residual_multiplier", reads=("mamba_d_ssm", "layer_types"),
+           unserved="residual_multiplier={value} needs a family that scales "
+                    "what every sublayer adds; model_family {family!r} does "
+                    "not (models/granite_hybrid.py is selected by model_type "
+                    "granitemoehybrid)"),
     Family("sdar", model_types=("sdar_moe",), field="block_length",
            unserved="block_length={value} needs a family whose decode unit "
                     "is a block of masked positions; model_family {family!r} "
@@ -177,7 +187,7 @@ def family(cfg: ModelConfig) -> Family:
     row = next(r for r in FAMILIES if (
         r.shape(cfg) if r.shape else cfg.model_family == r.name))
     for other in FAMILIES:
-        if other is row or not other.field:
+        if other is row or not other.field or other.field in row.reads:
             continue
         value = getattr(cfg, other.field)
         if value != ModelConfig.__dataclass_fields__[other.field].default:
@@ -197,15 +207,19 @@ def published(config: Mapping) -> Tuple[str, Dict]:
     names, that family's translation of its own keys
     (``config_fields``), and a refusal by name of every key another
     family claims (a trunk this program has no family for under that
-    ``model_type`` would fall through to another and serve nonsense)."""
+    ``model_type`` would fall through to another and serve nonsense). A
+    key two families claim (``mamba_*``, a mixed ``layer_types``) is the
+    named row's where it claims it, and refused under a third
+    ``model_type`` by the first that does."""
     model_type = config.get("model_type")
     arch = str(config.get("architectures", "")).lower()
     row = next((r for r in FAMILIES if model_type in r.model_types
                 or (r.architecture and r.architecture in arch)), None)
+    own = set(row.module.claimed_keys(config)) if row and row.field else set()
     for other in FAMILIES:
         if other is row or not other.field:
             continue
-        keys = other.module.claimed_keys(config)
+        keys = [k for k in other.module.claimed_keys(config) if k not in own]
         if keys:
             raise NotImplementedError(
                 f"model_type {model_type!r} carries "

@@ -120,7 +120,8 @@ CLAIMED_KEYS = ("num_dense_layers", "num_shared_experts", "mup_enabled")
 CLAIM = ("{keys} and no family here implements them under that model_type "
          "(afmoe is the family with window and full layers by layer_types, "
          "num_dense_layers, num_shared_experts and mup_enabled: "
-         "models/afmoe.py)")
+         "models/afmoe.py; granite_hybrid the one whose layer_types name a "
+         "state-space mixer or attention: models/granite_hybrid.py)")
 
 
 def claimed_keys(config: dict) -> List[str]:
@@ -342,8 +343,8 @@ def make_attn_fn(cfg: ModelConfig, b: int, s: int, positions, slots, table,
 
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                     slot_mapping, context_lens, mesh=None):
-    """(hidden [B, S, D], cache, int32 [2]: experts with a row and routed
-    rows summed over the expert layers), as mixtral.forward_counted.
+    """(hidden [B, S, D], cache, int32 [3]: mixtral.routing_stats summed
+    over the expert layers), as mixtral.forward_counted.
     ``block_tables`` is ``[B, 2 W]``: the full kind's table, then the
     window kind's."""
     del mesh    # one device: tp, ep, pp and sp are refused for the family
@@ -361,7 +362,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     k_side, v_side = kv_cache
     pages = {False: (k_side.full, v_side.full),
              True: (k_side.window, v_side.window)}
-    stats = jnp.zeros((2,), jnp.int32)
+    stats = jnp.zeros((3,), jnp.int32)
     eps = cfg.rms_norm_eps
     # the rows of a decode step that hold a token: one list for every
     # run of layers and both kinds of page

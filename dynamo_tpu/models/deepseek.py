@@ -550,8 +550,8 @@ def forward(
 
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                     slot_mapping, context_lens, mesh=None):
-    """``forward(return_hidden=True)`` and a third value, int32 [2]:
-    (experts with a row, routed rows) summed over the MoE layers, zeros
+    """``forward(return_hidden=True)`` and a third value, int32 [3]:
+    mixtral.routing_stats summed over the MoE layers, zeros
     for a dense configuration (as mixtral.forward_counted)."""
     b, s = tokens.shape
     with jax.named_scope("embed"):
@@ -566,7 +566,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     )
 
     li = 0
-    stats = jnp.zeros((2,), jnp.int32)
+    stats = jnp.zeros((3,), jnp.int32)
     if "dense_layers" in params:
         hidden, kv_cache, li, _ = run_layers(
             hidden, kv_cache, params["dense_layers"], cfg, attn_fn,

@@ -105,8 +105,11 @@ CLAIMED_PREFIXES = ("mamba_", "ssm_")
 # (a trunk with recurrent layers this program has no family for would
 # fall through to llama and serve nonsense)
 CLAIM = ("recurrent-layer keys ({keys}, ...) and no family here implements "
-         "it (falcon_h1 is the state-space family, models/falcon_h1.py; "
-         "minicpm_sala the linear-attention one, models/minicpm_sala.py)")
+         "it (falcon_h1 is the state-space family with attention beside the "
+         "mixer in every layer, models/falcon_h1.py; granite_hybrid the one "
+         "whose layers are a mixer or attention by layer_types, "
+         "models/granite_hybrid.py; minicpm_sala the linear-attention one, "
+         "models/minicpm_sala.py)")
 
 
 def claimed_keys(config: dict) -> List[str]:
@@ -211,6 +214,37 @@ def mup_vector(cfg: ModelConfig) -> jax.Array:
         for w, m in zip(in_proj_parts(cfg), cfg.ssm_multipliers)])
 
 
+def init_mixer(cfg: ModelConfig, keys, l: int, dtype, in_gain=1.0,
+               out_gain: float = 1.0) -> Params:
+    """``l`` layers of the mixer's parameters from six keys (in_proj,
+    conv weight, conv bias, Δ, A, out_proj); ``in_gain`` (a scalar or a
+    vector over in_proj's columns) and ``out_gain`` times the fan-in
+    scale of the two projections. The small ones as ``init_params``
+    says (shared with models/granite_hybrid.py)."""
+    d, d_ssm, nh = cfg.hidden_size, cfg.mamba_d_ssm, cfg.mamba_n_heads
+    kc, cd = cfg.mamba_d_conv, conv_dim(cfg)
+    k_in, k_cw, k_cb, k_dt, k_a, k_out = keys
+
+    def uniform(key, shape, lo, hi):
+        return jax.random.uniform(key, shape, jnp.float32, lo, hi)
+
+    dt = jnp.exp(uniform(k_dt, (l, nh), jnp.log(1e-3), jnp.log(1e-1)))
+    bound = kc ** -0.5
+    return {
+        "ssm_in": (jax.random.normal(k_in, (l, d, sum(in_proj_parts(cfg))),
+                                     jnp.float32)
+                   * (d ** -0.5) * in_gain).astype(dtype),
+        "conv_w": uniform(k_cw, (l, kc, cd), -bound, bound).astype(dtype),
+        "conv_b": uniform(k_cb, (l, cd), -bound, bound).astype(dtype),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(uniform(k_a, (l, nh), 1.0, 16.0)),
+        "D": jnp.ones((l, nh), jnp.float32),
+        "ssm_norm": jnp.ones((l, d_ssm), dtype),
+        "ssm_out": (jax.random.normal(k_out, (l, d_ssm, d), jnp.float32)
+                    * (out_gain * d_ssm ** -0.5)).astype(dtype),
+    }
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Random weights from the seed. Every matrix is fan-in-scaled normal
     as in the other families, **divided by the fixed µP multipliers that
@@ -226,36 +260,23 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     ±d_conv^-½."""
     l, d = cfg.num_layers, cfg.hidden_size
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    inter, d_ssm, nh = cfg.intermediate_size, cfg.mamba_d_ssm, cfg.mamba_n_heads
-    kc, cd = cfg.mamba_d_conv, conv_dim(cfg)
+    inter = cfg.intermediate_size
     keys = jax.random.split(key, 16)
 
     def w(key, shape, fan_in, gain=1.0):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (gain * fan_in ** -0.5)).astype(dtype)
 
-    def uniform(key, shape, lo, hi):
-        return jax.random.uniform(key, shape, jnp.float32, lo, hi)
-
     a_in, a_out = cfg.attention_in_multiplier, cfg.attention_out_multiplier
     in_gain = 1.0 / (cfg.ssm_in_multiplier * mup_vector(cfg))       # [9248]
-    dt = jnp.exp(uniform(keys[12], (l, nh), jnp.log(1e-3), jnp.log(1e-1)))
-    bound = kc ** -0.5
     layers = {
         "ln1": jnp.ones((l, d), dtype),
         "wq": w(keys[1], (l, d, h * hd), d, ATTN_SCORE_STD / a_in),
         "wk": w(keys[2], (l, d, kvh * hd), d, 1.0 / (a_in * cfg.key_multiplier)),
         "wv": w(keys[3], (l, d, kvh * hd), d, 1.0 / a_in),
         "wo": w(keys[4], (l, h * hd, d), h * hd, 1.0 / a_out),
-        "ssm_in": (jax.random.normal(keys[9], (l, d, in_gain.shape[0]), jnp.float32)
-                   * (d ** -0.5) * in_gain).astype(dtype),
-        "conv_w": uniform(keys[10], (l, kc, cd), -bound, bound).astype(dtype),
-        "conv_b": uniform(keys[11], (l, cd), -bound, bound).astype(dtype),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "A_log": jnp.log(uniform(keys[13], (l, nh), 1.0, 16.0)),
-        "D": jnp.ones((l, nh), jnp.float32),
-        "ssm_norm": jnp.ones((l, d_ssm), dtype),
-        "ssm_out": w(keys[14], (l, d_ssm, d), d_ssm, 1.0 / cfg.ssm_out_multiplier),
+        **init_mixer(cfg, keys[9:15], l, dtype, in_gain,
+                     1.0 / cfg.ssm_out_multiplier),
         "ln2": jnp.ones((l, d), dtype),
         "w_gate": w(keys[5], (l, d, inter), d, 1.0 / cfg.mlp_multipliers[0]),
         "w_up": w(keys[6], (l, d, inter), d),

@@ -406,7 +406,8 @@ def qkv_prologue(cfg, x, layer_params, b, s, positions, seq_basis,
 
 def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
                      context_lens, mesh, kv_gather_axis=None,
-                     layer_offset=0, tp_axis=None, live_rows=None):
+                     layer_offset=0, tp_axis=None, live_rows=None,
+                     rope: bool = True, scale=None):
     """The standard attention block: QKV + RoPE, paged-KV scatter, GQA
     attention, output projection. Families with different attention (MLA,
     models/deepseek.py) plug their own via run_layers' attn_fn.
@@ -425,7 +426,11 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
 
     ``live_rows``: the step's ``decode_live_rows(slot_mapping)`` where
     the trunk has made it for another kernel of the layer (Falcon-H1's
-    mixer); made here otherwise."""
+    mixer); made here otherwise.
+
+    ``rope=False``, ``scale``: layers with no positional term and a
+    published softmax scale that is not ``head_dim ** -0.5``
+    (models/granite_hybrid.py)."""
     del layer_offset  # no global-layer-index semantics in this family
     del tp_axis  # qkv biases are tp-sharded; no replicated additive terms
     h_heads, hd = cfg.num_heads, cfg.head_dim
@@ -444,7 +449,7 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
 
     def attn_fn(x, layer_params, k_all, v_all, li):
         q, k, v = qkv_prologue(cfg, x, layer_params, b, s, positions,
-                               context_lens)
+                               context_lens, rope=rope)
 
         # in-place scatter into the stacked cache + layer-indexed kernels:
         # no per-layer cache slice is ever materialized inside the scan
@@ -462,6 +467,7 @@ def make_gqa_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
                 # rides the XLA path — see ops/attention.py)
                 sliding_window=cfg.sliding_window or None,
                 live_rows=live_rows,
+                **({} if scale is None else {"scale": scale}),
                 **({} if block_len == 1 else {"block_len": block_len}),
             )
         delta = dense(attn.reshape(b, s, h_heads * hd), layer_params["wo"])

@@ -50,6 +50,17 @@ def _dequant_fp8(arr: np.ndarray, scale: Optional[np.ndarray],
     return arr * scale
 
 
+def _bf16_numpy(t) -> np.ndarray:
+    """A torch tensor as numpy, bfloat16 kept at 2 bytes an element
+    through an ml_dtypes view (numpy itself has no bfloat16)."""
+    import ml_dtypes
+    import torch
+
+    if t.dtype == torch.bfloat16:
+        return t.view(torch.uint16).numpy().view(ml_dtypes.bfloat16)
+    return t.numpy()
+
+
 def _iter_safetensors(model_dir: str):
     """Stream (name, np.ndarray) from all shards. Goes through the torch
     framework because safetensors' numpy framework cannot represent
@@ -94,9 +105,7 @@ def _iter_safetensors(model_dir: str):
                 ):
                     continue  # consumed with (or irrelevant to) a weight
                 t = f.get_tensor(name)
-                if t.dtype == torch.bfloat16:
-                    arr = t.view(torch.uint16).numpy().view(ml_dtypes.bfloat16)
-                elif "float8" in str(t.dtype):
+                if "float8" in str(t.dtype):
                     if not warned:
                         warned = True
                         logger.warning(
@@ -116,7 +125,7 @@ def _iter_safetensors(model_dir: str):
                         inverse_blocks=inv is not None,
                     ).astype(ml_dtypes.bfloat16)
                 else:
-                    arr = t.numpy()
+                    arr = _bf16_numpy(t)
                 yield name, arr
 
 
@@ -798,6 +807,122 @@ def load_gguf_llama_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16) -> D
     return params
 
 
+# a granitemoehybrid checkpoint's tensors under ``model.layers.N.``
+# (transformers modeling_granitemoehybrid.py) -> (key, transpose)
+_GRANITE_MIXER = {
+    "mamba.in_proj.weight": ("ssm_in", True),
+    "mamba.conv1d.bias": ("conv_b", False),
+    "mamba.dt_bias": ("dt_bias", False),
+    "mamba.A_log": ("A_log", False),
+    "mamba.D": ("D", False),
+    "mamba.norm.weight": ("ssm_norm", False),
+    "mamba.out_proj.weight": ("ssm_out", True),
+}
+_GRANITE_ATTN = {
+    "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.k_proj.weight": ("wk", True),
+    "self_attn.v_proj.weight": ("wv", True),
+    "self_attn.o_proj.weight": ("wo", True),
+}
+_GRANITE_EVERY = {
+    "input_layernorm.weight": ("ln1", False),
+    "post_attention_layernorm.weight": ("ln2", False),
+    "block_sparse_moe.router.layer.weight": ("router", True),
+}
+_GRANITE_F32 = ("dt_bias", "A_log", "D")
+
+
+def load_granite_hybrid_params(model_dir: str, cfg: ModelConfig,
+                               dtype=jnp.bfloat16) -> Dict:
+    """HF ``granitemoehybrid`` checkpoint -> models/granite_hybrid.py's
+    pytree (``params["runs"]``: a dict of arrays stacked over each run of
+    ``layer_types``).
+
+    The experts are two tensors a layer, ``block_sparse_moe.input_linear
+    .weight [E, 2 I, D]`` (``[gate | up]`` on the output axis) and
+    ``output_linear.weight [E, D, I]``; where the configuration holds one
+    expert-parallel rank's share (``cfg.experts_of``) only the held
+    experts' slices are read from the file. The shared expert is
+    ``shared_mlp.input_linear.weight [2 S, D]`` and ``output_linear
+    .weight [D, S]``; the depthwise conv ``mamba.conv1d.weight [C, 1,
+    K]`` becomes ``conv_w [K, C]``; the router is read whole (every
+    published expert)."""
+    from safetensors import safe_open
+
+    from .llama import layer_runs
+
+    first = cfg.expert_rank * cfg.num_experts
+    held = slice(first, first + cfg.num_experts)
+    inter = cfg.moe_intermediate_size
+    files = sorted(glob.glob(os.path.join(model_dir, "*.safetensors")))
+    if not files:
+        raise FileNotFoundError(f"no .safetensors under {model_dir}")
+
+    array = _bf16_numpy
+    plain = {**_GRANITE_MIXER, **_GRANITE_ATTN, **_GRANITE_EVERY}
+    top: Dict[str, np.ndarray] = {}
+    layers: Dict[int, Dict[str, np.ndarray]] = {}
+    for path in files:
+        with safe_open(path, framework="pt") as f:
+            for name in f.keys():
+                short = name.removeprefix("model.")
+                if short == "embed_tokens.weight":
+                    top["embed"] = array(f.get_tensor(name))
+                elif short == "norm.weight":
+                    top["final_norm"] = array(f.get_tensor(name))
+                elif short == "lm_head.weight":
+                    top["lm_head"] = array(f.get_tensor(name)).T
+                if not short.startswith("layers."):
+                    continue
+                _, idx, rest = short.split(".", 2)
+                lp = layers.setdefault(int(idx), {})
+                if rest in plain:
+                    key, transpose = plain[rest]
+                    t = array(f.get_tensor(name))
+                    lp[key] = t.T if transpose else t
+                elif rest == "mamba.conv1d.weight":
+                    lp["conv_w"] = array(f.get_tensor(name))[:, 0, :].T
+                elif rest == "block_sparse_moe.input_linear.weight":
+                    # the held experts' slices alone leave the file
+                    t = array(f.get_slice(name)[held]).transpose(0, 2, 1)
+                    lp["w_gate"], lp["w_up"] = t[..., :inter], t[..., inter:]
+                elif rest == "block_sparse_moe.output_linear.weight":
+                    lp["w_down"] = array(f.get_slice(name)[held]).transpose(0, 2, 1)
+                elif rest == "shared_mlp.input_linear.weight":
+                    t = array(f.get_tensor(name)).T
+                    half = t.shape[1] // 2
+                    lp["w_sh_gate"], lp["w_sh_up"] = t[:, :half], t[:, half:]
+                elif rest == "shared_mlp.output_linear.weight":
+                    lp["w_sh_down"] = array(f.get_tensor(name)).T
+                else:
+                    logger.debug("skipping unmapped tensor %s", name)
+
+    every = [k for k, _ in _GRANITE_EVERY.values()] + [
+        "w_gate", "w_up", "w_down", "w_sh_gate", "w_sh_up", "w_sh_down"]
+    of_kind = {"mamba": [k for k, _ in _GRANITE_MIXER.values()] + ["conv_w"],
+               "attention": [k for k, _ in _GRANITE_ATTN.values()]}
+    runs, at = [], 0
+    for kind, _, n in layer_runs(cfg.layer_types):
+        keys = every + of_kind[kind]
+        missing = [(i, k) for i in range(at, at + n) for k in keys
+                   if k not in layers.get(i, {})]
+        if missing:
+            raise ValueError(
+                f"incomplete checkpoint: {kind} layers {at}-{at + n - 1} "
+                f"lack {missing[:4]}")
+        runs.append({k: jnp.asarray(
+            np.stack([layers[i][k] for i in range(at, at + n)]),
+            dtype=jnp.float32 if k in _GRANITE_F32 else dtype) for k in keys})
+        at += n
+    if "embed" not in top or "final_norm" not in top:
+        raise ValueError("incomplete checkpoint: embed_tokens or norm missing")
+    params = {"embed": jnp.asarray(top["embed"], dtype=dtype), "runs": runs,
+              "final_norm": jnp.asarray(top["final_norm"], dtype=dtype)}
+    if "lm_head" in top and not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(top["lm_head"], dtype=dtype)
+    return params
+
+
 def load_checkpoint_params(model_dir: str, cfg: ModelConfig, arch, dtype=jnp.bfloat16) -> Dict:
     """Dispatch to the loader for the resolved architecture module.
 
@@ -819,6 +944,7 @@ def load_checkpoint_params(model_dir: str, cfg: ModelConfig, arch, dtype=jnp.bfl
         "deepseek": load_deepseek_params,
         "gemma2": load_gemma2_params,
         "gptoss": load_gptoss_params,
+        "granite_hybrid": load_granite_hybrid_params,
     }
     if name not in loaders:
         raise NotImplementedError(

@@ -127,8 +127,22 @@ def expert_matmul(xs: jax.Array, w, group_sizes: jax.Array,
     return grouped_matmul(xs, w, group_sizes, layer=layer)
 
 
+def held_experts(n_experts: int, held=None, ep_axis: Optional[str] = None):
+    """``(first, count)`` of the experts whose weights are here, of the
+    ``n_experts`` the router scored: all of them; ``held``'s (a stated
+    share: one expert-parallel rank's on a device of its own, the other
+    ranks' experts nowhere); or, inside a shard_map over ``ep_axis``,
+    this member's part of either."""
+    first, count = (0, n_experts) if held is None else held
+    if ep_axis is not None:
+        count = count // jax.lax.axis_size(ep_axis)
+        first = first + lax.axis_index(ep_axis) * count
+    return first, count
+
+
 def routed_experts(x, gate_vals, gate_idx, valid, n_experts: int, experts,
-                   weights, ep_axis: Optional[str] = None, layer=None):
+                   weights, ep_axis: Optional[str] = None, layer=None,
+                   held=None):
     """Drop-free dispatch shared by every routed-MoE variant.
 
     The T·k (token, choice) rows are sorted by expert; ``experts(xs, eid,
@@ -142,13 +156,14 @@ def routed_experts(x, gate_vals, gate_idx, valid, n_experts: int, experts,
     sharded over that axis): routing ran over the GLOBAL expert set;
     rows of another member's experts belong to no local group, so the
     return value is a PARTIAL sum the caller psums over the axis.
+    ``held`` (``(first, count)``: the expert stacks are one rank's share
+    of the ``n_experts`` routed over, on a device of its own): the same
+    partial sum and nobody to add it to; a pick that falls on an absent
+    expert is a row of no group, and nothing stands in for it.
     ``layer``: the weights are every layer's stacks (expert_matmul)."""
     t, top_k = gate_idx.shape
     with jax.named_scope("moe_route"):
-        e_local, e0 = n_experts, 0
-        if ep_axis is not None:
-            e_local = n_experts // jax.lax.axis_size(ep_axis)
-            e0 = lax.axis_index(ep_axis) * e_local
+        e0, e_local = held_experts(n_experts, held, ep_axis)
         eid = gate_idx.reshape(-1).astype(jnp.int32) - e0            # [R]
         mine = (eid >= 0) & (eid < e_local) & jnp.repeat(valid > 0, top_k)
         # rows of no local group sort behind the last expert
@@ -250,6 +265,7 @@ def moe_mlp(
     ep_axis: Optional[str] = None,      # manual-shard_map expert axis
     mesh=None,                          # GSPMD caller's mesh
     layer=None,                         # w_* are every layer's stacks [L, E, ..]
+    held=None,                          # (first, count): w_* are a share of E
 ):
     """Top-k routed SwiGLU experts, drop-free (routed_experts) ->
     (y [T, D], routing_stats).
@@ -262,7 +278,9 @@ def moe_mlp(
     its tp reduction). ``mesh`` (GSPMD callers): on a multi-device mesh
     the expert computation runs in its own shard_map and the value is
     whole. ``layer``: the three expert weights are every layer's
-    stacks, indexed inside the kernel (expert_matmul)."""
+    stacks, indexed inside the kernel (expert_matmul). ``held``: the
+    three hold ``count`` of the router's experts, from ``first``; the
+    value is then that share's part of the routed sum (routed_experts)."""
     e = router_w.shape[1]
     with jax.named_scope("moe_route"):
         gate_vals, gate_idx = route_top_k(
@@ -274,25 +292,30 @@ def moe_mlp(
         del tp_axis  # bias-free stacks: the output is a genuine tp-partial
         return routed_experts(x, gate_vals, gate_idx, valid, e,
                               _swiglu_experts, weights, ep_axis=ep_axis,
-                              layer=layer)
+                              layer=layer, held=held)
 
     y = _dispatch(mesh, ep_axis, None, (w_gate, w_up, w_down), _SWIGLU_SPECS,
                   fn, x, gate_vals, gate_idx, valid, layer).astype(x.dtype)
-    return y, routing_stats(gate_idx, valid, e)
+    return y, routing_stats(gate_idx, valid, e, held)
 
 
 def routing_stats(gate_idx: jax.Array, valid: Optional[jax.Array],
-                  n_experts: int) -> jax.Array:
-    """int32 [2]: experts that at least one real token chose, and the
-    (token, choice) rows of real tokens (the engine's counters; a caller
-    that does not count drops it and the compiler drops the work)."""
+                  n_experts: int, held=None) -> jax.Array:
+    """int32 [3]: the experts held that at least one real token chose,
+    the (token, choice) rows of real tokens, and those of them that fell
+    on an expert held (all of them where every expert is): the engine's
+    counters; a caller that does not count drops it and the compiler
+    drops the work."""
     with jax.named_scope("moe_route"):
         t, top_k = gate_idx.shape
         live = (jnp.ones((t,), jnp.int32) if valid is None
                 else (valid > 0).astype(jnp.int32))
         hits = jnp.zeros((n_experts,), jnp.int32).at[gate_idx.reshape(-1)].add(
             jnp.repeat(live, top_k))
-        return jnp.stack([(hits > 0).sum(), hits.sum()]).astype(jnp.int32)
+        first, count = held_experts(n_experts, held)
+        here = hits[first:first + count]
+        return jnp.stack([(here > 0).sum(), hits.sum(), here.sum()]
+                         ).astype(jnp.int32)
 
 
 def _gptoss_experts(alpha: float, limit: float, tp_axis: Optional[str]):
@@ -448,8 +471,9 @@ def forward(
 
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                     slot_mapping, context_lens, mesh=None):
-    """``forward(return_hidden=True)`` and a third value, int32 [2]:
-    (experts with a row, routed rows) summed over the layers — what the
+    """``forward(return_hidden=True)`` and a third value, int32 [3]:
+    (experts with a row, routed rows, rows of an expert held:
+    routing_stats) summed over the layers — what the
     engine's step adds to its counters (ModelRunner._init_moe_counters)."""
     b, s = tokens.shape
     scanned, stacks = split_expert_stacks(params["layers"])
@@ -498,6 +522,9 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
     ``moe_layer`` in their place and the kernel indexes the layer."""
     del tp_axis
     valid = (slot_mapping.reshape(b * s) >= 0).astype(jnp.float32)
+    # one rank's share of the published experts (ModelConfig.experts_of)
+    held = ((cfg.expert_rank * cfg.num_experts, cfg.num_experts)
+            if cfg.experts_of else None)
 
     def mlp(x, layer_params):
         w = layer_params if stacks is None else stacks
@@ -512,6 +539,7 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             ep_axis=ep_axis, mesh=mesh,
             layer=None if stacks is None else layer_params["moe_layer"],
+            held=held,
         )
         y = y.reshape(b, s, -1)
         if "w_sh_gate" in layer_params:

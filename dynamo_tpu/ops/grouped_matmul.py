@@ -48,8 +48,21 @@ _MAX_ROW_TILE = 128
 # the tiles they had: (2048, 512) 0.542 / 0.972, (1408, 512) 0.578 /
 # 1.035. The down-projection [rows, 1024] x [64, 1024, 3584] keeps 512
 # columns, 0.698 / 1.396; 896 read 0.666 / 1.222 and 1792 0.683 / 1.156,
-# left to a perf_opt that can claim it (PERF.md section 7).
-_K_TILE = 3584
+# left to a perf_opt that can claim it (PERF.md section 7). A
+# contraction of 4096 (granite-4.0-h-small's experts, [rows, 4096] x
+# [36, 4096, 768] at 640 / 20 480 rows; my chip run, PR 48) is whole
+# too, 4 MiB a buffer: visited as 3584 + 512 masked it read 0.643 /
+# 1.927 ms a product, whole 0.362 / 0.862 (2048 twice 0.461 / 1.370;
+# whole with 384 columns 0.350 / 0.754, with 256 0.351 / 0.799: left as
+# above). No benchmark configuration's contraction lies between the two
+# values, so their tiles are what they were. A contraction wider than
+# _K_TILE is visited in the largest tile up to it that divides it, no
+# visit masked, where one of at least half of it does (Mixtral's down
+# product of 14336 keeps its four visits of 3584, as before PR 48; 8192
+# is two of 4096), and otherwise in tiles of _K_TILE with the last one
+# masked. Nothing wider than 4096 has been timed on the chip (no cell
+# has one); a gain there is a perf_opt's to claim.
+_K_TILE = 4096
 _N_TILE = 512
 
 
@@ -57,9 +70,16 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def _k_tile(k: int) -> int:
+    if k <= _K_TILE:
+        return k
+    whole = (t for t in range(_K_TILE, _K_TILE // 2 - 1, -128) if k % t == 0)
+    return next(whole, _K_TILE)
+
+
 def _tiling(rows: int, k: int, n: int):
     tm = min(_MAX_ROW_TILE, _round_up(rows, _ROW_ALIGN))
-    return tm, min(k, _K_TILE), min(_round_up(n, 128), _N_TILE)
+    return tm, _k_tile(k), min(_round_up(n, 128), _N_TILE)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,

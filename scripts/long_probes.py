@@ -18,7 +18,8 @@ the configuration's (the reading of a precision below the stated one,
 which has to come out as not correct: PERF.md section 6). ``--fault``
 serves a deliberately wrong program against the unchanged reference,
 for the readings that say what the limits can tell apart: ``bf16_state``
-(MiniCPM-SALA's lightning state held in bfloat16), ``half_topk`` (half the picked
+(the served family's records by slot held in bfloat16: MiniCPM-SALA's
+lightning state, Falcon-H1's SSM state), ``half_topk`` (half the picked
 blocks dropped: ``sparse_config.topk`` halved in the served model's
 ``config.json`` only), ``bf16_router`` (the routed experts' router
 scores from a bfloat16 product of bfloat16 operands, as the activations
@@ -26,9 +27,28 @@ arrive, where the stated program takes both to float32: Trinity-Mini,
 ``trinity-longdoc`` at 9400 and 16 000 tokens, PR 40), ``steps_4`` and
 ``block_8`` (SDAR, ``sdar-reasoning``, PR 45: four denoising steps a
 block, or blocks of eight, in the served model's ``config.json`` only,
-against the reference's two and four). Where the reference module has ``limits_for``, a
+against the reference's two and four), ``gate_over_all`` and
+``sqrt_scale`` (Granite 4.0-H, ``granite-batch``, PR 48: the gates a
+softmax over every published expert and not over the chosen ones, and
+the attention layers' scores scaled by ``head_dim ** -0.5`` in the
+served model's ``config.json`` only, against the published 1 /
+head_dim). Where the reference module has ``limits_for``, a
 probe is held to the pair it gives for the probe's context; otherwise to
 the module's one pair.
+
+``--controls state,router,pages`` reads, on the same probes, what the
+reference gives when part of it is computed in the precision below the
+stated one (``build(..., lower=(name,))``, where the reference module
+has ``CONTROLS``: Granite 4.0-H's state in bfloat16 from token to token,
+its router's logits a bfloat16 product, its attention layers' keys and
+values in fp8): the control's log-probabilities
+stand in the served program's place in ``check_probes``, each probe on
+its own and all together as the harness compares them. Nothing is
+decoded for it, so a control costs a reference pass a probe. The exit
+code says nothing of the controls: which of them has to come out as not
+correct, and which cannot, is the reference module's to say.
+``--lengths harness`` sends the lengths ``benchmark/run.py`` sends at
+that seed (``harness/drive.probe_lengths``).
 """
 
 from __future__ import annotations
@@ -47,13 +67,14 @@ BENCH = os.path.join(ROOT, "benchmark")
 sys.path[:0] = [BENCH, ROOT]
 
 PROBE_TOKENS = 16
-FAULTS = ("bf16_state", "half_topk", "bf16_router", "steps_4", "block_8")
+FAULTS = ("bf16_state", "half_topk", "bf16_router", "steps_4", "block_8",
+          "gate_over_all", "sqrt_scale")
 
 
 def serve_wrongly(fault: str, model_dir: str) -> None:
     """Make the program about to be served wrong in one named way; the
     reference keeps the configuration as it is."""
-    if fault in ("half_topk", "steps_4", "block_8"):
+    if fault in ("half_topk", "steps_4", "block_8", "sqrt_scale"):
         path = os.path.join(model_dir, "config.json")
         with open(path) as f:
             config = json.load(f)
@@ -61,6 +82,10 @@ def serve_wrongly(fault: str, model_dir: str) -> None:
             config["sparse_config"]["topk"] //= 2
         elif fault == "steps_4":
             config["denoising_steps"] = 4
+        elif fault == "sqrt_scale":
+            heads = config["num_attention_heads"]
+            config["attention_multiplier"] = (
+                config.get("head_dim") or config["hidden_size"] // heads) ** -0.5
         else:
             config["block_length"] = 8
         with open(path, "w") as f:
@@ -70,8 +95,10 @@ def serve_wrongly(fault: str, model_dir: str) -> None:
 
         import jax.numpy as jnp
 
-        from dynamo_tpu.models import minicpm_sala as family
+        from dynamo_tpu import models
+        from dynamo_tpu.engine.config import ModelConfig
 
+        family = models.resolve(ModelConfig.from_model_dir(model_dir))
         init = family.init_kv_cache
 
         def init_kv_cache(*args, **kwargs):
@@ -94,12 +121,48 @@ def serve_wrongly(fault: str, model_dir: str) -> None:
             return route(logits.astype(jnp.float32), eye, *args, **kwargs)
 
         mixtral.route_top_k = route_top_k
+    elif fault == "gate_over_all":
+        from dynamo_tpu.models import mixtral
+
+        route = mixtral.route_top_k
+        mixtral.route_top_k = lambda *args, **kwargs: route(
+            *args, **{**kwargs, "norm_topk": False})
+
+
+def read_control(reference, name: str, params, hf: dict, probes: list,
+                 token_id) -> dict:
+    """The reference with ``name`` computed in the precision below the
+    stated one, in the served program's place: its log-probability of
+    every returned token against the unchanged reference's, under the
+    module's limits, each probe on its own and all of them together."""
+    import functools
+
+    from harness.reference import check_probes, reference_logprobs
+
+    lowered = types.SimpleNamespace(
+        build=functools.partial(reference.build, lower=(name,)))
+    programs: dict = {}
+    stood_in = [{**p, "token_logprobs": reference_logprobs(
+        lowered, params, hf, p["prompt"],
+        [token_id(t) for t in p["tokens"]], programs).tolist()}
+        for p in probes if p.get("status") == 200]
+    kept = ("ok", "max_abs_err", "mean_abs_err", "tokens_compared")
+    out = {"together": None, "probes": []}
+    for group in (stood_in, *([p] for p in stood_in)):
+        ref = check_probes(reference, params, hf, group, token_id)
+        row = {k: ref[k] for k in kept}
+        if group is stood_in:
+            out["together"] = row
+        else:
+            out["probes"].append({"prompt_tokens": len(group[0]["prompt"]), **row})
+    return out
 
 
 async def amain(args) -> int:
     import aiohttp
 
     from harness import manifest, server
+    from harness.drive import probe_lengths
     from harness.loadgen import _probes as send_probes
     from harness.modeldir import token_id
     from harness.reference import check_probes
@@ -129,7 +192,10 @@ async def amain(args) -> int:
     engine, serving = await server.start(flags)
     runner = engine.core_engine.runner
     print(f"serving after {time.monotonic() - t0:.1f} s", flush=True)
-    lengths = [int(n) for n in args.lengths.split(",")]
+    lengths = []
+    for n in args.lengths.split(","):
+        lengths += (probe_lengths(hf, args.seed, 1.0) if n == "harness"
+                    else [int(n)])
     probes = []
     async with aiohttp.ClientSession(
             timeout=aiohttp.ClientTimeout(total=600)) as session:
@@ -162,6 +228,10 @@ async def amain(args) -> int:
             "limits": {"max": atol, "mean": mean_atol},
             "tokens_compared": ref["tokens_compared"], "reasons": ref["reasons"],
             "reference_s": round(time.monotonic() - t1, 1)})
+    for name in filter(None, args.controls.split(",")):
+        out.setdefault("controls", {})[name] = await loop.run_in_executor(
+            None, read_control, reference, name, runner.params, hf, probes,
+            token_id)
     stats = [d.memory_stats() or {} for d in runner.mesh.devices.flat]
     out["memory_peak_bytes"] = max(
         (s.get("peak_bytes_in_use", 0) for s in stats), default=0)
@@ -178,6 +248,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--engine-args", default="")
     ap.add_argument("--fault", choices=FAULTS, default=None)
+    ap.add_argument("--controls", default="")
     return asyncio.run(amain(ap.parse_args()))
 
 

@@ -613,6 +613,145 @@ def test_sdar_block_pass_and_prefill_compile_under_the_block_mask(
 _DECODE_TRUNKS = {}
 
 
+# granite-4.0-h-small as one chip serves it (benchmark/configs/
+# granite-4.0-h-small-ep2.json): ten layers, nine of them a mixer of 128
+# heads of 64 over a state of 128 with one group of B and C for all the
+# heads (64 heads a block of the state kernel, half a row's state), one
+# of them NoPE attention over its own stack of pages, 36 of 72 experts
+# of 768 held behind each, a contraction of 4096
+def test_ssm_decode_kernel_compiles_at_granites_state(
+        one_chip, no_compile_cache, monkeypatch):
+    from dynamo_tpu.ops import ssm
+    from dynamo_tpu.ops.live_rows import live_row_list
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # a block is 64 of the group's 128 heads: 2 MiB of float32 state
+    assert ssm._head_block(128, 128, 64 * 128 * 4) == 64
+    # Falcon-H1's and lightning attention's keep theirs
+    assert ssm._head_block(32, 16, 128 * 256 * 4) == 16
+    assert ssm._head_block(32, 1, 128 * 128 * 4) == 32
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, slots, h, p, n, g = 9, 64, 128, 64, 128, 1
+    f32, act = jnp.float32, jnp.bfloat16
+
+    def f(x, dt, a, bm, cm, d, records, li, live):
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li,
+                                   live_row_list(live))
+
+    compiled = jax.jit(f, donate_argnums=(6,)).lower(
+        s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
+        s((slots, g, n), act), s((slots, g, n), act), s((h,), f32),
+        s((layers, slots, h, p, n), f32), s((), jnp.int32),
+        s((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [640, 20480])
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
+def test_grouped_products_compile_at_granites_expert_shapes(
+        one_chip, no_compile_cache, monkeypatch, rows, k, n):
+    """A decode step's 64 x 10 picks and a 2048-token chunk's, over the
+    36 experts held of a run of five layers."""
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    # the contraction of 4096 whole (the chip timing's choice, PR 48)
+    assert gm._tiling(rows, 4096, 768) == (128, 4096, 512)
+    assert gm._tiling(rows, 768, 4096) == (128, 768, 512)
+    # wider than a tile: whole visits where a tile of at least half of
+    # _K_TILE divides the contraction (Mixtral's down product as before
+    # PR 48), else the last visit masked
+    assert [gm._k_tile(k) for k in (14336, 8192, 5120, 4224)] == \
+        [3584, 4096, 2560, 4096]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        s((rows, k), jnp.bfloat16), s((5, 36, k, n), jnp.bfloat16),
+        s((36,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _granite_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import granite_hybrid
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite-4.0-h-small-ep2.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: granite_hybrid.init_params(cfg, jax.random.PRNGKey(0),
+                                           jnp.bfloat16)))
+    cache = jax.tree.map(s, jax.eval_shape(
+        lambda: granite_hybrid.init_kv_cache(
+            cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+            num_slots=serve["max_batch_size"])))
+    # pages over the attention layer alone, records over the nine mixers
+    assert cache[0].kv.shape == (1, 3072, 16, 8, 128)
+    assert cache[0].state.shape == (9, 64, 128, 64, 128)
+    assert params["runs"][0]["router"].shape == (5, 4096, 72)
+    assert params["runs"][0]["w_gate"].shape == (5, 36, 4096, 768)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, tokens, positions, bt, slots, ctx, ss):
+        return granite_hybrid.forward_counted(
+            params, cfg, tokens, positions, (k_side, v_side), bt, slots, ctx,
+            state_slots=ss)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, *cache, i32(rows, tokens), i32(rows, tokens), i32(rows, width),
+        i32(rows, tokens), i32(rows), i32(rows)).compile()
+
+
+def test_granite_decode_step_updates_state_and_pages_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole trunk of a decode step at the benchmark's size: the
+    state kernel, the attention decode kernel and the grouped products
+    are in it, and neither the recurrent state (2.42 GB at 64 slots)
+    nor a run's expert stacks (3.4 GB) are copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _granite_step(one_chip, 64, 1, 256)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_decode_step" in text
+    state = "f32[9,64,128,64,128]"
+    ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1), ln)
+           for ln in text.splitlines()[1:] if state in ln and " = " in ln]
+    assert {op for op, _ in ops} <= {
+        "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+        "custom-call"}, {op for op, _ in ops}
+    mem = compiled.memory_analysis()
+    print("decode step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 12.5e9
+    assert mem.temp_size_in_bytes < 2 ** 28
+
+
+def test_granite_prefill_chunk_fits_beside_the_model(
+        one_chip, no_compile_cache, monkeypatch):
+    """A 2048-token chunk: the chunked scan's 128 heads x 256 x 256
+    decay matrices a chunk and the sorted rows of 20 480 picks are the
+    step's temporaries, and they fit in what the model leaves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _granite_step(one_chip, 1, 2048, 256)
+    mem = compiled.memory_analysis()
+    print("prefill step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 2.5 * 2 ** 30
+
+
 def _decode_trunk(ll, topo, config):
     """The decode trunk of a benchmark configuration compiled for the
     described chips (the real tp mesh where ``serve`` asks for one), once

@@ -104,18 +104,20 @@ class Served:
     as the engine drives it: a prefill step's rows name their slots and
     may be fewer, padded or idle; a decode step has one row a slot."""
 
-    def __init__(self, cfg, params, dtype, state_dtype=None):
+    def __init__(self, cfg, params, dtype, state_dtype=None, family=falcon_h1):
+        # ``family``: another module with records by slot in a SlotCache
+        # (tests/test_granite_hybrid_reference.py drives its own this way)
         self.cfg, self.vocab = cfg, cfg.vocab_size
         self.w = 48        # blocks a sequence
-        cache = falcon_h1.init_kv_cache(cfg, SLOTS * self.w, BLOCK, dtype,
-                                        num_slots=SLOTS)
+        cache = family.init_kv_cache(cfg, SLOTS * self.w, BLOCK, dtype,
+                                     num_slots=SLOTS)
         if state_dtype is not None:      # a deliberately wrong program
             cache = (dataclasses.replace(
                 cache[0], state=cache[0].state.astype(state_dtype)), cache[1])
         self.cache = cache
         self.btab = np.arange(SLOTS * self.w, dtype=np.int32).reshape(SLOTS, self.w)
         self.fwd = jax.jit(
-            lambda cache, tok, pos, bt, slot, ctx, ss: falcon_h1.forward(
+            lambda cache, tok, pos, bt, slot, ctx, ss: family.forward(
                 params, cfg, tok, pos, cache, bt, slot, ctx, state_slots=ss))
 
     def _page_slots(self, slot, positions):

@@ -269,6 +269,7 @@ REQUIRED = set(_NAME.findall(_REQUIRED_DOC)) - {"ModelRunner"}
 OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
             for name in _NAME.findall(bullet.split(":")[0])}
 FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
+                "residual_multiplier": 0.22,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
@@ -328,3 +329,71 @@ def test_the_engine_asks_a_family_for_listed_names_only():
         code = "\n".join(line.split("#")[0] for line in src.splitlines())
         code = re.sub(r'"""(?:.|\n)*?"""', "", code)
         assert not re.search(rf"models(?: import|\.)\s*(?:{names})\b", code), rel
+
+
+# ---------- claims that two families share (PR 48) ----------
+
+_MIXER = {"mamba_n_heads": 4, "mamba_d_head": 16, "mamba_d_state": 8,
+          "mamba_n_groups": 1, "mamba_d_conv": 4}
+_GRANITE = {"model_type": "granitemoehybrid", "vocab_size": 64,
+            "hidden_size": 32, "intermediate_size": 16,
+            "shared_intermediate_size": 24, "num_hidden_layers": 2,
+            "layer_types": ["mamba", "attention"], "num_attention_heads": 2,
+            "num_local_experts": 4, "num_experts_per_tok": 2,
+            "mamba_expand": 2, "residual_multiplier": 0.22,
+            "attention_multiplier": 0.0625, "logits_scaling": 16,
+            "embedding_multiplier": 12, "tie_word_embeddings": True, **_MIXER}
+_FALCON = {"model_type": "falcon_h1", "vocab_size": 64, "hidden_size": 32,
+           "intermediate_size": 48, "num_hidden_layers": 2,
+           "num_attention_heads": 2, "mamba_d_ssm": 64, **_MIXER}
+
+
+@pytest.mark.parametrize("hf,family,mixer_only", [
+    (_GRANITE, "granite_hybrid", True), (_FALCON, "falcon_h1", False)])
+def test_two_families_compute_the_state_space_keys(hf, family, mixer_only):
+    """``mamba_*`` is Falcon-H1's claim and Granite 4.0-H's, a mixed
+    ``layer_types`` afmoe's and Granite 4.0-H's: a config reaches the row
+    its ``model_type`` names, with the other's fields unset."""
+    cfg = ModelConfig.from_hf_config(hf)
+    assert cfg.model_family == family and models.family(cfg).name == family
+    assert cfg.mamba_d_ssm == 64 and cfg.mamba_n_heads == 4
+    assert bool(cfg.layer_types) is mixer_only
+    assert (cfg.residual_multiplier != 1.0) is mixer_only
+
+
+@pytest.mark.parametrize("keys,named", [
+    (_MIXER, "mamba_"),
+    ({"layer_types": ["mamba", "attention"]}, "layer_types"),
+    ({"layer_types": ["sliding_attention", "full_attention"]}, "layer_types"),
+    ({"residual_multiplier": 0.22}, "residual_multiplier"),
+    ({"expert_share": {"of_experts": 8, "rank": 0}}, "expert_share"),
+])
+def test_a_third_model_type_with_shared_keys_is_refused_by_name(keys, named):
+    """Under a ``model_type`` no row names, what two families claim is
+    still refused, and the sentence names both families that compute
+    it."""
+    with pytest.raises(NotImplementedError,
+                       match=f"some_other_trunk.*{named}") as e:
+        ModelConfig.from_hf_config({**PLAIN_HF, **keys})
+    assert "granite_hybrid" in str(e.value)
+    if named == "mamba_":
+        assert "falcon_h1" in str(e.value) and "minicpm_sala" in str(e.value)
+    # and under a family that does not compute them
+    with pytest.raises(NotImplementedError, match=named):
+        ModelConfig.from_hf_config(
+            {**PLAIN_HF, "model_type": "sdar_moe", "block_length": 4, **keys})
+
+
+def test_a_stray_shared_field_is_refused_for_every_other_row():
+    """``family()`` lets the row that reads another's field have it
+    (``Family.reads``) and no other."""
+    granite = ModelConfig.from_hf_config(_GRANITE)
+    assert models.family(granite).reads == ("mamba_d_ssm", "layer_types")
+    stray = ModelConfig(mamba_d_ssm=64, layer_types=("mamba", "attention"))
+    with pytest.raises(NotImplementedError, match="mamba_d_ssm"):
+        models.resolve(stray)
+    falcon = ModelConfig.from_hf_config(_FALCON)
+    import dataclasses
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        models.resolve(dataclasses.replace(
+            falcon, layer_types=("mamba", "attention")))

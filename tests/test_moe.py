@@ -81,8 +81,8 @@ def test_moe_all_rows_one_expert_equals_oracle(t):
     np.testing.assert_allclose(np.asarray(got), naive_moe(x, rw, wg, wu, wd, k),
                                rtol=1e-4, atol=1e-4)
     assert np.all(np.asarray(got)[0] != 0)
-    # k experts touched, T*k rows routed
-    assert list(np.asarray(stats)) == [k, t * k]
+    # k experts touched, T*k rows routed, every one on an expert held
+    assert list(np.asarray(stats)) == [k, t * k, t * k]
 
 
 @pytest.mark.parametrize("pads_first", [True, False])
