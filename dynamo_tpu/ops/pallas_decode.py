@@ -27,18 +27,19 @@ a runtime index (``layer_idx``) so the per-layer ``lax.scan`` over the
 transformer trunk needs no per-layer cache slicing (which XLA
 materializes as a copy of the whole layer).
 
-How a row is walked (``_walk``, the decode and MLA kernels): a chunk is
-sized by its bytes, not by a count of pages. ``chunk_pages`` derives the
-pages of a wide chunk at trace time from the page's bytes, the scores
-its columns add and the table's width (about 2 MB of K and V: 8 pages of
-Phi-3's 32 kv heads, 64 of Trinity-Mini's 4 or of the latent cache), and
-a row walks wide chunks while it has that many pages left, then chunks
-of the tail's 8 (16 for MLA), so a chat-length row never pays for a
-product wider than its keys. Only a row's own pages are copied: a chunk
-copies the pages of it the row owns (a loop of four-page turns, the same
-count on the start and on the wait), and what the last chunk leaves
-uncopied is cleared where it is a value. A page reaches VMEM as its
-[page * KVH, D] rows, so a chunk is the dots' operand as it lies.
+How a row is walked (``_walk``: the decode, MLA and verify kernels): a
+chunk is sized by its bytes, not by a count of pages. ``chunk_pages``
+derives the pages of a wide chunk at trace time from the page's bytes,
+the scores its columns add and the table's width (about 2 MB of K and V:
+8 pages of Phi-3's 32 kv heads, 64 of Trinity-Mini's 4 or of the latent
+cache), and a row walks wide chunks while it has that many pages left,
+then chunks of the tail's 8 (16 for MLA and for verify), so a chat-length
+row never pays for a product wider than its keys. Only a row's own pages
+are copied: a chunk copies the pages of it the row owns (a loop of
+four-page turns, the same count on the start and on the wait), and what
+the last chunk leaves uncopied is cleared where it is a value. A page
+reaches VMEM as its [page * KVH, D] rows, so a chunk is the dots' operand
+as it lies.
 
 Reference analog: the decode-path paged-attention kernels of the GPU
 engines the reference delegates to (SURVEY.md §2.4); same role as
@@ -59,6 +60,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .live_rows import LiveRows
 
 MASK_VALUE = -1e30
+# a token's place no query's bounds reach (_verify_kernel)
+OTHER_HEAD = 2 ** 30
 
 
 def _out_struct(shape, dtype, *arrays) -> jax.ShapeDtypeStruct:
@@ -546,18 +549,22 @@ def mla_paged_decode_attention(
 
 
 def _verify_kernel(
+    rows_ref,  # scalar prefetch: the rows the grid walks [B]
     bt_ref,    # scalar prefetch: block tables [B, W] (SMEM)
     ctx_ref,   # scalar prefetch: context lens [B] (incl. all S new slots)
     base_ref,  # scalar prefetch: base query position [B] (q[:, 0]'s pos)
     li_ref,    # scalar prefetch: layer index [1]
     win_ref,   # scalar prefetch: sliding window [1] (>= ctx disables)
-    q_ref,     # [1, S, KVH, G, D] VMEM block
-    k_hbm,     # [L, N, page, KVH, D] in HBM (ANY)
+    q_ref,     # [1, KVH / per, per * S * G, D] VMEM block: the (head,
+               # s, g) rows of the per kv heads of a product
+    k_hbm,     # [L, N, page * KVH, D] in HBM (ANY): a page's (token, head) rows
     v_hbm,
-    *rest,     # ([sinks_ref [1, KVH*G] when has_sinks], o_ref, scratch...)
+    *rest,     # ([sinks_ref [KVH / per, per * S * G] when has_sinks],
+               # o_ref, scratch...)
     scale: float,
     block_size: int,
-    pages_per_chunk: int,
+    wide_pages: int,
+    tail_pages: int,
     softcap: float,
     s_q: int,
     has_sinks: bool = False,
@@ -568,11 +575,22 @@ def _verify_kernel(
     attention reads each KV page once instead of the flash-prefill
     kernel's per-query-block passes over the table capacity.
 
-    Same double-buffered HBM→VMEM page pipeline as ``_decode_kernel``;
-    the q rows flatten (s, kvh, g) → rows and the mask adds the causal
-    tail: query s sits at absolute position base + s (the flash
-    kernel's affine contract — base rides as its own prefetch operand,
-    so a right-padded chunk behaves exactly like flash: pad rows score
+    The walk is ``_decode_kernel``'s (``_walk``: wide chunks sized by
+    their bytes, then the tail's, only the row's own pages copied). A
+    chunk is folded a product at a time, and a product is a kv head's
+    own wherever its keys can be read apart from the other heads'
+    (``keys``): its S * G query rows against its pages * page keys, so
+    no score is computed against another head's keys and none needs a
+    head-match mask (at S = 8 the scores of every head against every
+    head are KVH times the products, the bytes and the ``exp`` of a
+    chunk, and the MXU takes a chunk's keys in about the time HBM gives
+    them). Where it cannot (fp8's four heads a word, a head count the
+    words do not divide) the heads it holds together are one product
+    under that mask.
+
+    Query s sits at absolute position base + s (the flash kernel's
+    affine contract — base rides as its own prefetch operand, so a
+    right-padded chunk behaves exactly like flash: pad rows score
     against the bounded valid range and the caller discards them), and
     key j is visible iff j <= base + s AND j < ctx (and inside the
     sliding window). With a static ``block_len`` B > 1 the first term is
@@ -581,100 +599,133 @@ def _verify_kernel(
     all B of its keys).
 
     ``has_sinks`` (GPT-OSS): the per-head sink logit joins EVERY query
-    position's softmax as a denominator-only virtual key — the [1,
-    KVH*G] operand tiles across the S query rows at finalize.
+    position's softmax as a denominator-only virtual key.
     """
     if has_sinks:
         sinks_ref, o_ref, k_buf, v_buf, sem = rest
     else:
         o_ref, k_buf, v_buf, sem = rest
-    b = pl.program_id(0)
+    b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     base = base_ref[b]
     li = li_ref[0]
-    npages = pl.cdiv(ctx, block_size)
-    nchunks = pl.cdiv(npages, pages_per_chunk)
     # the earliest key ANY query can see (query 0's window lower bound)
     win_start = jnp.maximum(base + 1 - win_ref[0], 0)
 
-    _, s, kvh, g, d = q_ref.shape
-    rows = s * kvh * g
-    chunk_t = pages_per_chunk * block_size
-    cols = chunk_t * kvh
+    _, products, rows, d = q_ref.shape
+    kvh = k_buf.shape[2] // block_size
+    per = kvh // products          # kv heads a product
+    group = rows // (per * s_q)    # query heads a kv head
 
-    def page_copy(chunk, slot, i, hbm, buf):
-        p = jnp.minimum(chunk * pages_per_chunk + i, npages - 1)
-        return pltpu.make_async_copy(
-            hbm.at[li, bt_ref[b, p]], buf.at[slot, i], sem.at[slot]
-        )
+    # each row's query position (rows ordered (head, s, g), affine from
+    # the base operand) and the keys it sees: [q_lo, q_hi)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    q_pos = base + row // group % s_q
+    q_hi = jnp.minimum(
+        q_pos + 1 if block_len == 1
+        else (q_pos // block_len + 1) * block_len, ctx)
+    q_lo = q_pos - win_ref[0] + 1
 
-    def start(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, k_hbm, k_buf).start()
-            page_copy(chunk, slot, i, v_hbm, v_buf).start()
+    # a 32-bit word of the packed cache holds a token's rows of
+    # word_heads neighbouring heads; the word rows of a page that hold
+    # the same heads are every words-th, and a word of two 16-bit heads
+    # is taken apart (``keys``); none of it where every head is in the
+    # one product
+    word_heads = 4 // k_buf.dtype.itemsize
+    words = max(kvh // word_heads, 1)
+    halves = word_heads == 2 and per < kvh
 
-    def wait(chunk, slot):
-        for i in range(pages_per_chunk):
-            page_copy(chunk, slot, i, k_hbm, k_buf).wait()
-            page_copy(chunk, slot, i, v_hbm, v_buf).wait()
+    def tokens(pages):
+        """[rows, cols] the token a column holds in a chunk of ``pages``,
+        or none a row will ever see (``OTHER_HEAD``) where the column is
+        another head's than the row's: column j of a product's rows is
+        token j // per of its head j % per, and of a head taken out of
+        its words, token j // 2 of the chunk's first half or, at odd j,
+        of its second."""
+        shape = (rows, pages * block_size * per)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        if halves:
+            return col // 2 + col % 2 * (pages * block_size // 2)
+        if per == 1:
+            return col
+        head = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // (group * s_q)
+        return jnp.where(col % per == head, col // per, OTHER_HEAD)
 
-    first_chunk = win_start // chunk_t
-    start(first_chunk, jax.lax.rem(first_chunk, 2))
-    q = q_ref[0].reshape(rows, d)  # rows ordered (s, head, group)
+    # loop-invariant, one a chunk width
+    col_tok = {pages: tokens(pages) for pages in {wide_pages, tail_pages}}
 
-    col_head = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % kvh
-    row_flat = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
-    row_head = (row_flat % (kvh * g)) // g
-    row_s = row_flat // (kvh * g)
-    head_match = col_head == row_head                    # loop-invariant
-    col_tok = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) // kvh
-    # per-row absolute query position (affine from the base operand)
-    q_pos = base + row_s
+    def keys(buf, slot, pages, w, dtype):
+        """A chunk's rows of the heads that share word row w of a token,
+        one [pages * page * per, D] array a product. Row t * KVH + h of
+        a page is token t of head h and a 32-bit word packs ``word_heads``
+        neighbouring rows, so those heads' rows are every ``words``-th
+        word row: a strided load, which Mosaic has for 32 bits alone.
+        A word of two 16-bit heads comes apart in three bit operations a
+        head: the low halves of the chunk's first half beside those of
+        its second are the packed rows of the even head (tokens in the
+        order ``tokens`` names), the high halves the odd head's. Any
+        other word's heads stay together as the rows they are."""
+        if per == kvh:
+            return [buf[slot, :pages].reshape(
+                pages * block_size * kvh, d).astype(dtype)]
+        packed = buf.bitcast(jnp.uint32)[
+            slot, :pages, pl.ds(w, block_size, stride=words)
+        ].reshape(pages * block_size, d)
+        if not halves:
+            return [pltpu.bitcast(packed, buf.dtype).astype(dtype)]
+        a, b = jnp.split(packed, 2)
+        high = jnp.uint32(0xFFFF0000)
+        return [pltpu.bitcast(head, buf.dtype).astype(dtype)
+                for head in ((a & ~high) | (b << 16), (a >> 16) | (b & high))]
 
-    def body(c, carry):
-        slot = jax.lax.rem(c, 2)
+    def attend(carry, slot, first_page, pages):
+        # two compares a score, the same for every product: the chunk's
+        # first key comes off the rows' bounds, not onto the columns
+        first_key = first_page * block_size
+        mask = ((col_tok[pages] < q_hi - first_key)
+                & (col_tok[pages] >= q_lo - first_key))
 
-        @pl.when(c + 1 < nchunks)
-        def _prefetch():
-            start(c + 1, jax.lax.rem(c + 1, 2))
+        def fold(carry, q, k, v):
+            s_log = jax.lax.dot_general(
+                q, k,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                         # [rows, cols]
+            if softcap:
+                s_log = softcap * jnp.tanh(s_log / softcap)
+            return _fold(carry, jnp.where(mask, s_log, MASK_VALUE), v)
 
-        wait(c, slot)
-        k = k_buf[slot].reshape(cols, d).astype(q.dtype)
-        v = v_buf[slot].reshape(cols, d).astype(q.dtype)
-
-        key_pos = c * chunk_t + col_tok
-        mask = (head_match
-                & (key_pos <= q_pos if block_len == 1
-                   else key_pos < (q_pos // block_len + 1) * block_len)
-                & (key_pos < ctx)
-                & (key_pos > q_pos - win_ref[0]))
-
-        s_log = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if softcap:
-            s_log = softcap * jnp.tanh(s_log / softcap)
-        return _fold(carry, jnp.where(mask, s_log, MASK_VALUE), v)
+        # upcast from the cache storage dtype as _decode_kernel does
+        dtype = q_ref.dtype
+        chunk = [(k, v) for w in range(words)
+                 for k, v in zip(keys(k_buf, slot, pages, w, dtype),
+                                 keys(v_buf, slot, pages, w, dtype))]
+        return tuple(fold(carry[i], q_ref[0, i], k, v)
+                     for i, (k, v) in enumerate(chunk))
 
     m0 = jnp.full((rows, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((rows, 128), jnp.float32)
     acc0 = jnp.zeros((rows, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first_chunk, nchunks, body, (m0, l0, acc0))
-    l1 = l[:, 0:1]
-    if has_sinks:
-        # denominator-only virtual key, per (kvh, g) head, identical for
-        # every query position: tile the [KVH*G] sink row across the S
-        # query rows so row (s, kvh, g) sees sink[kvh*g] (see
-        # _decode_kernel — any shared shift cancels, so the keys-only
-        # running max m serves without a combined-max pass)
-        sink_rows = jnp.broadcast_to(
-            sinks_ref[0][None, :], (s, kvh * g)
-        ).reshape(rows, 1)
-        l1 = l1 + jnp.exp(sink_rows.astype(jnp.float32) - m[:, 0:1])
-    l1 = jnp.where(l1 == 0.0, 1.0, l1)
-    o_ref[0] = (acc / l1).astype(o_ref.dtype).reshape(s, kvh, g, d)
+    heads = _walk(
+        b, bt_ref, sem,
+        [(lambda n: k_hbm.at[li, n], k_buf, False),
+         (lambda n: v_hbm.at[li, n], v_buf, True)],
+        first_page=win_start // block_size,
+        npages=pl.cdiv(ctx, block_size),
+        wide=wide_pages, tail=tail_pages, attend=attend,
+        carry=((m0, l0, acc0),) * products,
+    )
+    for j, (m, l, acc) in enumerate(heads):
+        l1 = l[:, 0:1]
+        if has_sinks:
+            # denominator-only virtual key (see _decode_kernel — any
+            # shared shift cancels, so the keys-only running max m serves
+            # without a combined-max pass)
+            l1 = l1 + jnp.exp(
+                sinks_ref[j][:, None].astype(jnp.float32) - m[:, 0:1]
+            )
+        l1 = jnp.where(l1 == 0.0, 1.0, l1)
+        o_ref[0, j] = (acc / l1).astype(o_ref.dtype)
 
 
 # largest tail the verify kernel serves: beyond it the flash-prefill
@@ -696,23 +747,44 @@ def paged_verify_attention(
     context_lens: jax.Array, # [B] int32 (valid keys; may be < base + S)
     layer_idx: Optional[jax.Array] = None,
     scale: Optional[float] = None,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: Optional[int] = None,  # tests pin it; None: chunk_pages
     interpret: bool = False,
     softcap: float = 0.0,
     window=None,
     sinks=None,              # [H] per-head sink logits (GPT-OSS); None = off
     block_len: int = 1,      # static; > 1: causal over blocks, full inside
+    live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
     """S-token verify attention over the paged cache; returns
     [B, S, H, D]. The flash kernel's affine contract: query s of row b
     sits at ``base_pos[b] + s``; rows past ``context_lens`` (a padded
-    chunk) produce garbage the caller discards."""
+    chunk) produce garbage the caller discards. A row is walked as
+    ``paged_decode_attention`` walks it (``chunk_pages`` over the page's
+    bytes and the scores of a product's rows), and ``live_rows`` means
+    what it means there. The tail's chunk is sixteen pages: with S
+    positions a row a chunk's fixed costs weigh more than in decode, and
+    at SDAR's shape (S = 8, 32 KB a page) rows of 600 to 2800 keys read
+    12 % faster with 16 than with 8, and no faster with 32 (PERF.md §5,
+    PR 49)."""
     b, s, h, d = q.shape
-    assert 1 < s <= VERIFY_MAX_S, "verify kernel serves small S tails"
+    assert s <= VERIFY_MAX_S, "verify kernel serves small S tails"
     if k_cache.ndim == 4:
         k_cache, v_cache = k_cache[None], v_cache[None]
     _, _, block_size, kvh, _ = k_cache.shape
+    # a page as its (token, head) rows: see paged_decode_attention
+    page_shape = (block_size * kvh, d)
+    k_cache = k_cache.reshape(k_cache.shape[:2] + page_shape)
+    v_cache = v_cache.reshape(v_cache.shape[:2] + page_shape)
     g = h // kvh
+    # a chunk is folded a product at a time: a kv head's own rows where
+    # the kernel can read them apart from the others' (32-bit rows; 16-bit
+    # rows two to a 32-bit word of the packed cache, which comes apart),
+    # else the heads of a word together (four of fp8), or every head where
+    # the words do not divide them (_verify_kernel.keys)
+    word_heads = 4 // k_cache.dtype.itemsize
+    per = (kvh if kvh % word_heads
+           else 1 if word_heads == 2 else word_heads)  # kv heads a product
+    products = kvh // per
     if scale is None:
         scale = d ** -0.5
     li = (
@@ -725,39 +797,45 @@ def paged_verify_attention(
         if window is None
         else jnp.asarray(window, jnp.int32).reshape(1)
     )
-    pages_per_chunk = min(pages_per_chunk, block_tables.shape[1])
-    qs = q.reshape(b, s, kvh, g, d)
+    wide, tail = _chunks(
+        "paged_verify_attention", pages_per_chunk, 16,
+        2 * block_size * kvh * d * k_cache.dtype.itemsize,
+        per * s * g * block_size * per * 4, block_tables.shape[1])
+    # a product's query rows together, ordered (head, s, g)
+    by_product = (b, products, per * s * g, d)
+    qs = q.reshape(b, s, products, per, g, d).transpose(
+        0, 2, 3, 1, 4, 5).reshape(by_product)
     has_sinks = sinks is not None
 
+    rows, n = _walked_rows(b, live_rows)
+
+    def by_row(i, rows_ref, *_):
+        return rows_ref[i], 0, 0, 0
+
     in_specs = [
-        pl.BlockSpec((1, s, kvh, g, d), lambda i, *_: (i, 0, 0, 0, 0)),
+        pl.BlockSpec((1,) + by_product[1:], by_row),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     if has_sinks:
-        # [1, KVH*G] replicated to every grid step; the kernel tiles it
-        # across the S query rows itself
-        in_specs.append(pl.BlockSpec((1, kvh * g), lambda i, *_: (0, 0)))
+        # replicated to every grid step: a head's logit at each of its
+        # (s, g) rows
+        in_specs.append(pl.BlockSpec(by_product[1:3], lambda i, *_: (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(b,),
+        num_scalar_prefetch=6,
+        grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, s, kvh, g, d), lambda i, *_: (i, 0, 0, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1,) + by_product[1:], by_row),
         scratch_shapes=[
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, kvh, d), k_cache.dtype
-            ),
-            pltpu.VMEM(
-                (2, pages_per_chunk, block_size, kvh, d), v_cache.dtype
-            ),
+            pltpu.VMEM((2, wide) + page_shape, k_cache.dtype),
+            pltpu.VMEM((2, wide) + page_shape, v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
 
     operands = [
+        rows,
         block_tables.astype(jnp.int32),
         context_lens.astype(jnp.int32),
         base_pos.astype(jnp.int32),
@@ -768,29 +846,32 @@ def paged_verify_attention(
         v_cache,
     ]
     if has_sinks:
-        operands.append(
-            jnp.asarray(sinks, jnp.float32).reshape(1, kvh * g)
-        )
+        operands.append(jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32).reshape(kvh, 1, g), (kvh, s, g)
+        ).reshape(by_product[1:3]))
 
     out = pl.pallas_call(
         functools.partial(
             _verify_kernel,
             scale=scale,
             block_size=block_size,
-            pages_per_chunk=pages_per_chunk,
+            wide_pages=wide,
+            tail_pages=tail,
             softcap=softcap,
             s_q=s,
             has_sinks=has_sinks,
             **({} if block_len == 1 else {"block_len": block_len}),
         ),
         grid_spec=grid_spec,
-        out_shape=_out_struct((b, s, kvh, g, d), q.dtype, q, k_cache),
+        out_shape=_out_struct(by_product, q.dtype, q, k_cache),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, s, h, d)
+    out = out.reshape(b, products, per, s, g, d).transpose(
+        0, 3, 1, 2, 4, 5).reshape(b, s, h, d)
+    return _zero_unwalked(out, live_rows)
 
 
 @functools.partial(

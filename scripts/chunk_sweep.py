@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """How many pages a chunk the decode kernels' walk wants, on the chip:
-``paged_decode_attention`` and ``mla_paged_decode_attention`` alone,
-every row live, over pages a chunk x context x the four page sizes the
-benchmark's cells serve.
+``paged_decode_attention``, ``mla_paged_decode_attention`` and (shape
+``sdar``: a block pass's eight positions a row under the block mask)
+``paged_verify_attention`` alone, every row live, over pages a chunk x
+context x the page sizes the benchmark's cells serve.
 
     python scripts/chunk_sweep.py [--repo DIR] [--shapes phi3,trinity,...]
         [--contexts 256,2048,16384] [--pages 8,16,32,64,128]
@@ -17,7 +18,8 @@ trace, so what is timed is the kernel as served with another byte
 target; ``rule`` marks the line the limits as committed derive. This
 table is what fixed them (PERF.md §5 "Since PR 42"). ``--repo`` a
 checkout of a parent commit, whose kernels have no such limits, is
-timed with ``pages_per_chunk`` instead (every chunk that size). It
+timed with ``pages_per_chunk`` instead (every chunk that size), as is
+a verify kernel that does not take its chunks from the rule. It
 measures the chip and nothing else: on any other backend it says so
 and exits 1.
 """
@@ -25,6 +27,7 @@ and exits 1.
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import os
 import statistics
@@ -48,6 +51,10 @@ import numpy as np  # noqa: E402
 from dynamo_tpu.ops import pallas_decode  # noqa: E402
 
 HAS_RULE = hasattr(pallas_decode, "chunk_pages")
+# the verify kernel walks by the rule where nothing pins its chunks
+VERIFY_RULE = inspect.signature(pallas_decode.paged_verify_attention) \
+    .parameters["pages_per_chunk"].default is None
+VERIFY_S, VERIFY_BLOCK = 8, 4    # sdar-reasoning: two blocks of four a row
 PAGE, ROWS, CALLS, WIDTH = 16, 16, 8, 1152
 HBM_BYTES_PER_S = 819e9          # TPU v5e (benchmark/harness/peaks.py)
 # name: (kind, heads, a, c): q heads over ``a`` kv heads of ``c`` lanes
@@ -61,7 +68,13 @@ SHAPES = {
     "falcon": ("gqa", 20, 4, 128),       # 16 KB: falcon-h1-34b
     "sala": ("pair", 16, 1, 128),        # 4 KB: minicpm-sala's (row, kv head)
     "moonlight": ("mla", 16, 512, 128),  # 20 KB: moonlight-16b-a3b, xing4
+    "sdar": ("verify", 32, 4, 128),      # 16 KB: sdar-30b-a3b, S = 8
 }
+
+
+def by_rule(name):
+    """Whether this checkout's kernel at ``name`` sizes its chunks itself."""
+    return HAS_RULE and (SHAPES[name][0] != "verify" or VERIFY_RULE)
 
 
 def page_of(name):
@@ -70,6 +83,9 @@ def page_of(name):
     kind, h, a, c = SHAPES[name]
     if kind == "mla":
         return PAGE * (a + c) * 2, h * PAGE * 4, 16
+    if kind == "verify":
+        # a kv head a product: its (s, g) rows against its tokens
+        return 2 * PAGE * a * c * 2, VERIFY_S * (h // a) * PAGE * 4, 16
     return 2 * PAGE * a * c * 2, h * PAGE * a * 4, 8
 
 
@@ -105,7 +121,7 @@ def case(name, context, pages):
     bt = jnp.asarray(rng.integers(1, n_blocks, (ROWS, WIDTH)), jnp.int32)
     lis = jnp.arange(CALLS, dtype=jnp.int32) % layers
     kw = {}
-    if HAS_RULE:
+    if by_rule(name):
         # a wide chunk of exactly ``pages``, whatever the page's bytes
         pallas_decode.CHUNK_BYTES = pallas_decode.SCORE_BYTES = 1 << 40
         pallas_decode.MAX_CHUNK_PAGES = pages
@@ -113,7 +129,20 @@ def case(name, context, pages):
     else:
         kw["pages_per_chunk"] = pages
     page_bytes, _, tail = page_of(name)
-    if kind != "mla":
+    if kind == "verify":
+        kvh, d = a, c
+        shape = (layers, n_blocks, PAGE, kvh, d)
+        ops = (_normal(2, (ROWS, VERIFY_S, h, d)), _normal(0, shape),
+               _normal(1, shape), bt, jnp.asarray(ctx))
+
+        @jax.jit
+        def step(q, k, v, bt, ctx):
+            def call(q, li):
+                return pallas_decode.paged_verify_attention(
+                    q, k, v, bt, ctx - VERIFY_S, ctx, layer_idx=li,
+                    block_len=VERIFY_BLOCK, **kw), None
+            return jax.lax.scan(call, q, lis)[0]
+    elif kind != "mla":
         kvh, d = a, c
         shape = (layers, n_blocks, PAGE, kvh, d)
         if kind == "pair":
@@ -145,7 +174,7 @@ def case(name, context, pages):
     live_pages = -(-ctx // PAGE)
     seconds = _time(step, ops)
     # a wide chunk is never under the tail's
-    traced = max(pages, tail) if HAS_RULE else pages
+    traced = pages if "pages_per_chunk" in kw else max(pages, tail)
     return seconds, int(live_pages.sum()) * page_bytes, live_pages, traced
 
 
@@ -162,7 +191,8 @@ def main():
              "device": jax.devices()[0].device_kind, "rows": ROWS, "lines": []}
     shapes = args.shapes.split(",")
     # before a case moves the limits
-    rules = {name: rule_pages(name) if HAS_RULE else None for name in shapes}
+    rules = {name: rule_pages(name) if by_rule(name) else None
+             for name in shapes}
     for name in shapes:
         for context in map(int, args.contexts.split(",")):
             for pages in map(int, args.pages.split(",")):

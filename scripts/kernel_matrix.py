@@ -38,6 +38,7 @@ SHAPES = {
     "llama-3.2-1b": (8, 32, 64),        # chip_smoke leg A, one chip
     "llama-3.1-8b/tp4": (2, 8, 128),    # leg B, one tp shard of four
     "toy": (2, 4, 128),
+    "sdar-30b-a3b": (4, 32, 128),       # sdar-reasoning's block pass
 }
 
 
@@ -120,6 +121,8 @@ CASES.update({
     "verify s4": (False, lambda: _attention_case("toy", 2, 4, 4)),
     "verify s4 llama-3.2-1b": (
         False, lambda: _attention_case("llama-3.2-1b", 8, 4, 128)),
+    "verify s8 block 4 sdar-30b-a3b": (
+        False, lambda: _attention_case("sdar-30b-a3b", 8, 8, 128, block_len=4)),
     "verify softcap": (
         False, lambda: _attention_case("toy", 2, 4, 4, softcap=50.0)),
     "verify sinks": (False, lambda: _attention_case("toy", 2, 4, 4, **SINKS)),

@@ -241,10 +241,13 @@ def _route_programs(block_len=None):
 
 # sha256 of the programs above as commit 855e2e2 (the parent of the PR
 # that brought block_len) lowers them, this file's ``_route_programs()``
-# run there: a change to a kernel changes them, and is then to say so
+# run there: a change to a kernel changes them, and is then to say so.
+# The verify kernel's is its own since PR 49 took its walk through
+# ``_walk`` (e45060255abd846b before): the text at block length 1 as that
+# commit lowers it, held so that a block length of 1 stays that program
 PARENT_PROGRAMS = {
     "xla": "cf711ae27d413515",
-    "verify": "e45060255abd846b",
+    "verify": "ee1e85da2d77dab1",
     "flash": "e922bcb742055b1e",
     "prefill": "979fae04dd6263e9",
 }
@@ -802,6 +805,28 @@ def test_scopes_in_the_lowered_block_pass():
     for scope in ("attn/block_attn", "mlp", "moe_route", "moe_experts",
                   "lm_head", "sampling", "block_select"):
         assert scope in text, scope
+
+
+def test_warm_up_logs_the_verify_kernels_chunks(monkeypatch, caplog):
+    """On the kernel route the block pass walks its pages through the
+    verify kernel, whose chunks ``ModelRunner.warmup`` logs beside the
+    decode kernels': at this shape (float32 pages of 16 tokens x 2 heads
+    x 128 lanes, a table of 16) sixteen pages a chunk, wide or the
+    tail's."""
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    hf = _hf(num_hidden_layers=1)
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(hf),
+                              attention_impl="pallas")
+    runner = ModelRunner(dataclasses.replace(
+        _engine_config(hf), model=cfg, max_batch_size=2, prefill_buckets=[32]))
+    with caplog.at_level("INFO", logger="dynamo_tpu.engine.model_runner"):
+        runner.warmup()
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("decode kernels' chunks: ")]
+    page = 2 * PAGE * 2 * 128 * 4
+    assert {"kernel": "paged_verify_attention", "page_bytes": page,
+            "wide_pages": 16, "tail_pages": 16,
+            "wide_bytes": 16 * page} in json.loads(line.split(": ", 1)[1])
 
 
 # ---------- served: cli/run's engine and HTTP service ----------

@@ -598,6 +598,36 @@ def test_sdar_block_pass_and_prefill_compile_under_the_block_mask(
     assert mem.temp_size_in_bytes < (64 if tokens == 8 else 256) * 2 ** 20
 
 
+# The verify kernel reads a kv head's rows apart from the others' with a
+# strided load of the 32-bit words that pack them (PR 49): Mosaic has that
+# load for 32 bits alone and the interpreter takes any, so the groupings
+# are compiled here at SDAR's page (7 layers x 6400 pages of 16 x 4 x 128,
+# a table of 200): bfloat16 heads two to a word and taken apart, fp8
+# heads four to a word and folded together, a word row in two or four
+@pytest.mark.parametrize("kv_dtype,kvh,s,block_len", [
+    ("bfloat16", 4, 8, 4), ("bfloat16", 8, 5, 1), ("bfloat16", 2, 17, 1),
+    ("float8_e4m3fn", 8, 8, 4), ("float8_e4m3fn", 4, 4, 1)])
+def test_verify_kernel_compiles_with_its_heads_read_apart(
+        one_chip, no_compile_cache, kv_dtype, kvh, s, block_len):
+    from dynamo_tpu.ops.pallas_decode import paged_verify_attention
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, h, d, width = 32, 32, 128, 200
+    cache = sds((7, 6400, 16, kvh, d), jnp.dtype(kv_dtype))
+
+    def f(q, k, v, bt, base, ctx, li):
+        return paged_verify_attention(q, k, v, bt, base, ctx, layer_idx=li,
+                                      block_len=block_len)
+
+    compiled = jax.jit(f).lower(
+        sds((b, s, h, d), jnp.bfloat16), cache, cache,
+        sds((b, width), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # The layer loop and its weights (PR 39). A projection whose result is
 # reshaped to heads at once has the reshape folded into its dot; the
 # compiler then sees the weight as [heads, head_dim, D], which is a

@@ -611,6 +611,196 @@ def test_verify_padded_chunk_valid_rows_match_flash_contract():
     )
 
 
+# ---- the verify kernel walks a row as the decode kernel does (_walk) ----
+#
+# bf16 pages of 16 tokens as served, float32 queries (the kernel upcasts
+# a page to the query's dtype, so the XLA route over the same values in
+# float32 is held to float32's tolerance, which is also what the kernel
+# gave before it took the walk); the interpreter's fresh scratch is NaN as
+# VMEM may be, so a page neither copied nor cleared shows in the output
+
+
+def _verify_case(rng, s, h, kvh, d, ctx, dtype=jnp.bfloat16, bs=16, spare=2):
+    b, layers = len(ctx), 2
+    w = -(-max(ctx) // bs) + spare
+    n_blocks = b * w + 2
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((layers, n_blocks, bs, kvh, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((layers, n_blocks, bs, kvh, d)), dtype)
+    bt = jnp.asarray(rng.permutation(n_blocks)[: b * w].reshape(b, w),
+                     jnp.int32)
+    return q, k, v, bt, jnp.asarray(ctx, jnp.int32)
+
+
+def _verify_contexts(wide, bs=16):
+    """Rows of one page (part full), wide - 1 pages, wide pages to the
+    last key, wide + 1, and two wide chunks and a tail that does not
+    fill its sixteen pages, ending inside a page."""
+    return [9, (wide - 1) * bs - 3, wide * bs, (wide + 1) * bs - 7,
+            (2 * wide + 3) * bs - 11]
+
+
+# SDAR-30B-A3B's heads (32 over 4 of 128), S positions a row: the pages
+# a wide chunk holds follow the page's bytes and the scores of a kv
+# head's S * G rows (512 KB of them at 17 positions and 32 pages)
+VERIFY_WIDE = {1: 64, 4: 64, 8: 64, 17: 32}
+
+
+@pytest.mark.parametrize("block_len", [1, 4], ids=["causal", "block_4"])
+@pytest.mark.parametrize("s", list(VERIFY_WIDE))
+def test_verify_at_the_derived_chunk_matches_xla_reference(s, block_len):
+    """Speculation's S queries at their own lengths (query i sees the keys
+    up to base + i) and a block pass's mask, over rows that end on every
+    edge of the walk."""
+    from dynamo_tpu.ops.pallas_decode import (
+        chunks_traced, paged_verify_attention)
+
+    h, kvh, d, wide = 32, 4, 128, VERIFY_WIDE[s]
+    ctx = [c + (-c) % block_len for c in _verify_contexts(wide)]
+    q, k, v, bt, ctx = _verify_case(
+        np.random.default_rng(50 + s), s, h, kvh, d, ctx)
+    base = jnp.maximum(ctx - s, 0)
+    positions = base[:, None] + jnp.arange(s)[None, :]
+    ref = paged_attention(
+        q, k[1].astype(jnp.float32), v[1].astype(jnp.float32), bt,
+        positions, ctx, block_len=block_len)
+    out = paged_verify_attention(
+        q, k, v, bt, base, ctx, layer_idx=jnp.int32(1), interpret=True,
+        block_len=block_len)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    page = 2 * 16 * kvh * d * 2
+    assert {"kernel": "paged_verify_attention", "page_bytes": page,
+            "wide_pages": wide, "tail_pages": 16,
+            "wide_bytes": wide * page} in chunks_traced()
+
+
+# (cache dtype, kv heads, kv heads a product): a head is a product of
+# its own where its rows can be read apart from the others' (32-bit
+# rows, and 16-bit rows two to a word, which comes apart); the four fp8
+# heads of a word are folded together, and every head where the words do
+# not divide them
+VERIFY_PRODUCTS = {
+    "f32_a_head": (jnp.float32, 4, 1),
+    "bf16_a_head_of_four": (jnp.bfloat16, 4, 1),
+    "bf16_a_head_of_eight": (jnp.bfloat16, 8, 1),
+    "bf16_a_head_of_two": (jnp.bfloat16, 2, 1),
+    "f16_a_head_of_four": (jnp.float16, 4, 1),
+    "bf16_three_heads_together": (jnp.bfloat16, 3, 3),
+    "bf16_one_head": (jnp.bfloat16, 1, 1),
+    "fp8_a_word_of_four": (jnp.float8_e4m3fn, 8, 4),
+    "fp8_two_heads_together": (jnp.float8_e4m3fn, 2, 2),
+}
+
+
+@pytest.mark.parametrize("name", list(VERIFY_PRODUCTS))
+def test_verify_takes_a_words_heads_apart_where_it_can(name):
+    """Sinks and a window ride along: a head's sink reaches its own rows
+    in every grouping, and the walk starts mid-chunk."""
+    from dynamo_tpu.ops.pallas_decode import (
+        chunk_pages, chunks_traced, paged_verify_attention)
+
+    dtype, kvh, per = VERIFY_PRODUCTS[name]
+    s, g, d = 3, 2, 64
+    rng = np.random.default_rng(60 + kvh)
+    q, k, v, bt, ctx = _verify_case(rng, s, kvh * g, kvh, d,
+                                    [200, 37, 16 * 16, 5], dtype)
+    sinks = jnp.asarray(rng.standard_normal(kvh * g), jnp.float32)
+    base = ctx - s
+    positions = base[:, None] + jnp.arange(s)[None, :]
+    ref = paged_attention(
+        q, k[0].astype(jnp.float32), v[0].astype(jnp.float32), bt,
+        positions, ctx, sliding_window=150, sinks=sinks)
+    out = paged_verify_attention(
+        q, k, v, bt, base, ctx, layer_idx=jnp.int32(0), interpret=True,
+        window=jnp.int32(150), sinks=sinks)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    # the scores a page adds are a product's rows against its columns
+    page = 2 * 16 * kvh * d * jnp.dtype(dtype).itemsize
+    wide = chunk_pages(page, per * s * g * 16 * per * 4, 16, bt.shape[1])
+    assert {"kernel": "paged_verify_attention", "page_bytes": page,
+            "wide_pages": wide, "tail_pages": 16,
+            "wide_bytes": wide * page} in chunks_traced()
+
+
+@pytest.mark.parametrize("ppc", [1, 2, 4])
+def test_verify_honours_a_pinned_chunk(ppc):
+    """``pages_per_chunk`` makes every chunk that many pages (a test's,
+    the sweep's), as the decode kernels take it."""
+    from dynamo_tpu.ops.pallas_decode import (
+        chunks_traced, paged_verify_attention)
+
+    s, h, kvh, d = 4, 8, 4, 64
+    q, k, v, bt, ctx = _verify_case(np.random.default_rng(70), s, h, kvh, d,
+                                    [9, 5 * 16, 7 * 16 - 3])
+    positions = (ctx - s)[:, None] + jnp.arange(s)[None, :]
+    ref = paged_attention(q, k[1].astype(jnp.float32),
+                          v[1].astype(jnp.float32), bt, positions, ctx)
+    out = paged_verify_attention(
+        q, k, v, bt, ctx - s, ctx, layer_idx=jnp.int32(1), interpret=True,
+        pages_per_chunk=ppc)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    page = 2 * 16 * kvh * d * 2
+    assert {"kernel": "paged_verify_attention", "page_bytes": page,
+            "wide_pages": ppc, "tail_pages": ppc,
+            "wide_bytes": ppc * page} in chunks_traced()
+
+
+@pytest.mark.parametrize("value", [float("nan"), float("inf")],
+                         ids=["nan", "inf"])
+def test_verify_never_reads_a_page_the_row_does_not_own(value):
+    """As the decode kernel's: the unused pages poisoned and the table
+    past a row's last page pointed at one; the output is finite and the
+    clean cache's, bit for bit."""
+    from dynamo_tpu.ops.pallas_decode import paged_verify_attention
+
+    s, h, kvh, d = 8, 32, 4, 128
+    q, k, v, bt, ctx = _verify_case(np.random.default_rng(71), s, h, kvh, d,
+                                    _verify_contexts(VERIFY_WIDE[s]))
+    call = dict(layer_idx=jnp.int32(0), interpret=True, block_len=4)
+    base = jnp.maximum(ctx - s, 0)
+    clean = np.asarray(paged_verify_attention(q, k, v, bt, base, ctx, **call))
+    k_bad, _ = _poison_unused(k, bt, ctx, float("nan"))
+    v_bad, bt_bad = _poison_unused(v, bt, ctx, value)
+    out = np.asarray(paged_verify_attention(q, k_bad, v_bad, bt_bad, base,
+                                            ctx, **call))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+
+
+@pytest.mark.parametrize("live", [[0, 0, 0, 0], [1, 1, 1, 1], [0, 1, 0, 1]],
+                         ids=["no_live_row", "every_row", "two_of_four"])
+def test_verify_walks_the_live_rows_and_zeros_the_others(live):
+    """The row list of the decode kernels, where a caller has one: the
+    grid's bound is the traced count, the rows it names are the XLA
+    route's and every other row is zeros."""
+    from dynamo_tpu.ops.live_rows import live_row_list
+    from dynamo_tpu.ops.pallas_decode import paged_verify_attention
+
+    s, h, kvh, d = 4, 8, 4, 64
+    q, k, v, bt, ctx = _verify_case(np.random.default_rng(72), s, h, kvh, d,
+                                    [40, 9, 5 * 16, 130])
+    positions = (ctx - s)[:, None] + jnp.arange(s)[None, :]
+    ref = np.asarray(paged_attention(
+        q, k[1].astype(jnp.float32), v[1].astype(jnp.float32), bt,
+        positions, ctx))
+    rows = live_row_list(jnp.asarray(live, bool))
+
+    def call(rows):
+        return paged_verify_attention(
+            q, k, v, bt, ctx - s, ctx, layer_idx=jnp.int32(1),
+            interpret=True, live_rows=rows)
+
+    out = np.asarray(call(rows))
+    mask = np.asarray(live, bool)
+    np.testing.assert_allclose(out[mask], ref[mask], rtol=2e-5, atol=2e-5)
+    assert not out[~mask].any()
+    (grid,) = _pallas_grids(jax.make_jaxpr(call)(rows).jaxpr)
+    assert not isinstance(grid[0], int)
+
+
 # ---- the grid is the live rows, not the batch ----
 #
 # a pad row of the decode batch as Scheduler._decode fills it: context 1
