@@ -8,4 +8,10 @@ and a native JAX/XLA serving engine (paged attention, continuous batching,
 pjit/shard_map parallelism) in place of GPU engines.
 """
 
+import time as _time
+
+# the start-up timeline's first mark (telemetry/flight.py): the earliest
+# moment the program has
+T_IMPORT = _time.monotonic()
+
 __version__ = "0.1.0"

@@ -539,6 +539,15 @@ async def build_engine(engine_spec: str, flags, drt=None, events=None):
         from ..llm.tokenizer import HFTokenizer
         from ..runtime.pipeline import build_pipeline
 
+        if engine_spec == "jax" and not getattr(flags, "isolate_engine",
+                                                False):
+            # this process holds the device: bring its runtime up before
+            # the card and the tokenizer, so that the start-up timeline's
+            # ``backend`` and ``model_card`` phases are each one thing
+            # (an --isolate-engine parent stays off jax)
+            from ..engine.device import check_serving_device
+
+            check_serving_device()
         mdc = load_mdc(flags)
         tokenizer = HFTokenizer.from_model_path(flags.model_path)
         core = await build_core_engine(engine_spec, flags, mdc, events, drt=drt)
@@ -1179,6 +1188,17 @@ async def run_http(flags, engine, mdc) -> None:
         hub.start()
 
     await service.start()
+    runner = getattr(getattr(engine, "core_engine", None), "runner", None)
+    if runner is not None:
+        # an in-process jax engine: the last mark of its start-up
+        # timeline. Where DYN_TRACE_JSONL is set the whole of it goes to
+        # the sink as the record "startup" (not into the request ring,
+        # whose TTL would drop it)
+        startup = runner.startup
+        startup.mark("listening")
+        startup.within("serve", startup.seconds["scheduler"]
+                       + startup.seconds["listening"])
+        service.traces.write(startup.record())
     print(f"listening on http://{flags.http_host}:{service.port}", flush=True)
     # SIGTERM drains in-flight requests for up to the configured grace
     # period (reference WorkerConfig.graceful_shutdown_timeout, DYN_WORKER_

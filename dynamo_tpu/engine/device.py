@@ -14,6 +14,7 @@ from typing import Dict, Optional
 import jax
 
 from ..ops.attention import pallas_interpret
+from ..telemetry.flight import mark_process
 
 # <checkout>/.jax_cache (in .gitignore). The directory is part of the
 # cache key's environment: a temp name, pid or timestamp never hits.
@@ -49,7 +50,10 @@ def check_serving_device() -> None:
     accelerator; a server that then starts and answers is a chip-less
     run that looks like success. A non-TPU backend is accepted only when
     ``JAX_PLATFORMS`` asks for the CPU explicitly — names it first, which
-    is what makes it jax's default backend (tests, dry runs)."""
+    is what makes it jax's default backend (tests, dry runs).
+
+    Its first return is the start-up timeline's ``backend`` mark: jax is
+    imported and the runtime of the device is up."""
     backend = jax.default_backend()
     asked = os.environ.get("JAX_PLATFORMS", "").lower().split(",")[0].strip()
     if backend != "tpu" and asked != "cpu":
@@ -58,6 +62,7 @@ def check_serving_device() -> None:
             "visible; set JAX_PLATFORMS=cpu to run on the CPU on purpose"
         )
     pallas_interpret()  # raises when set on a TPU backend
+    mark_process("backend")
 
 
 def device_report(mesh) -> Dict:

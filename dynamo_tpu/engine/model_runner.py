@@ -18,7 +18,6 @@ import functools
 import json
 import logging
 import threading
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -29,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import models
 from ..models import llama, quant
 from ..ops.attention import _pad_minor, row_list_traced
-from ..telemetry.flight import CompileTracker
+from ..telemetry.flight import CompileTracker, StartupTimeline
 from ..telemetry.registry import Counter
 from .config import EngineConfig
 from .device import check_serving_device
@@ -238,8 +237,27 @@ class ModelRunner:
         mesh: Optional[Mesh] = None,
         model_dir: Optional[str] = None,
     ):
-        t_init = time.monotonic()
         check_serving_device()
+        # XLA compile observability: every compiled-program dispatch site
+        # below runs through compiles.track(program, shape-bucket key) —
+        # the first dispatch of a new key is the compile, and a compile
+        # after mark_serving_started() is a "late" compile (the
+        # recompile-storm signal; see telemetry/flight.py). The scheduler
+        # / prefill worker attach compiles.registry into the engine's
+        # scrape and flip the serving flag when they start. Made first:
+        # what weight init's helper jits compile is counted there too
+        # (program="untracked").
+        self.compiles = CompileTracker()
+        # the split of set-up, one mark where each phase's work ends
+        # (telemetry/flight.StartupTimeline; dynamo_engine_startup_seconds):
+        # the card and the tokenizer are done when this is entered, then
+        # reaching the device(s), weights, cache pool, the programs'
+        # Python and warm-up, each timed to the moment its arrays are on
+        # the device. ``startup_s[phase]`` is the timeline's ``seconds``.
+        self.startup = StartupTimeline(self.compiles.registry,
+                                       self.compiles.records)
+        self.startup_s = self.startup.seconds
+        self.startup.mark("model_card")
         self.config = config
         cfg = config.model
         self.family = models.family(cfg)
@@ -307,11 +325,6 @@ class ModelRunner:
             config.dp_size, config.tp_size, ep=config.ep_size,
             pp=config.pp_size, sp=config.sp_size,
         )
-        # the split of set-up, one stamp a phase
-        # (dynamo_engine_startup_seconds): reaching the device(s), then
-        # weights, cache pool and warm-up, each timed to the moment its
-        # arrays are on the device
-        self.startup_s = {"device_init": time.monotonic() - t_init}
         # mixed dense+MoE MLA trunk under pp: the dense prefix stays
         # replicated (params, cache, and compute) while the MoE trunk
         # stages — parallel/pipeline.py's has_prefix path
@@ -357,7 +370,9 @@ class ModelRunner:
                 f"unknown quantization {cfg.quantization!r} (only int8)"
             )
 
-        t_weights = time.monotonic()
+        # the mesh and the checks of the configuration: the weights'
+        # phase starts here
+        self.startup.mark("device_init")
         if params is None:
             if model_dir is not None:
                 from ..models.loader import has_checkpoint, load_checkpoint_params
@@ -432,28 +447,11 @@ class ModelRunner:
         )
         self.state_sharding = NamedSharding(self.mesh, P("dp", None))
         jax.block_until_ready(self.params)
-        t_cache = time.monotonic()
-        self.startup_s["weights"] = t_cache - t_weights
+        self.startup.mark("weights")
         self._init_device_state()
         jax.block_until_ready((self.kv_cache, self.sample_state))
-        self.startup_s["kv_cache"] = time.monotonic() - t_cache
+        self.startup.mark("kv_cache")
 
-        # XLA compile observability: every compiled-program dispatch site
-        # below runs through compiles.track(program, shape-bucket key) —
-        # the first dispatch of a new key is the compile, and a compile
-        # after mark_serving_started() is a "late" compile (the
-        # recompile-storm signal; see telemetry/flight.py). The scheduler
-        # / prefill worker attach compiles.registry into the engine's
-        # scrape and flip the serving flag when they start.
-        self.compiles = CompileTracker()
-        self._startup_gauge = self.compiles.registry.gauge(
-            "dynamo_engine_startup_seconds",
-            "Wall time of each set-up phase, set once: phase="
-            "device_init|weights|kv_cache|warmup (warm-up by program is "
-            "dynamo_engine_xla_compile_duration_seconds)",
-        )
-        for phase, seconds in self.startup_s.items():
-            self._startup_gauge.set(seconds, phase=phase)
         # attention-route observability: the dispatch seams in
         # ops/attention.py / parallel/sequence.py record which kernel
         # served each trace; the tracked dispatch supplies the program
@@ -538,6 +536,8 @@ class ModelRunner:
         # batched cacheless embedding programs, compiled per (rows,
         # bucket) on first use (the /v1/embeddings workload)
         self._embed_progs: Dict[Tuple[int, int], Any] = {}
+        # the programs are built and nothing is compiled
+        self.startup.mark("runner")
 
     def refuse_without_state(self, path: str) -> None:
         """Raise, by name, for a path that would move, share or roll back
@@ -2256,7 +2256,11 @@ class ModelRunner:
         path from a compile error to another attention route:
         ``attention_impl="xla"`` is the operator's explicit choice.
         """
-        t_warm = time.monotonic()
+        # warm-up's phase starts here: what lies between the runner's
+        # return and this (the scheduler and the engine built around it)
+        # is the phase ``engine``
+        self.startup.mark("engine")
+        first_dispatches = len(self.compiles.records)
         b = decode_batch or self.config.max_batch_size
         # the sample-row install program is shape-invariant and otherwise
         # compiles at the FIRST admission — a needless late compile on
@@ -2428,7 +2432,13 @@ class ModelRunner:
         # every program has executed once when warm-up is over: the
         # gauge then holds compile (or cache load) plus first execution
         jax.block_until_ready((self.kv_cache, self.sample_state))
-        self.startup_s["warmup"] = time.monotonic() - t_warm
+        self.startup.mark("warmup")
+        # what no first dispatch holds: the wait above for the executions
+        # queued behind the dispatches, and the host loops between them
+        dispatched = sum(r["duration_s"]
+                         for r in self.compiles.records[first_dispatches:])
+        self.startup.within(
+            "warmup_wait", max(0.0, self.startup_s["warmup"] - dispatched))
         # what the decode kernels' walk was sized to at this model's
         # pages (ops/pallas_decode.chunk_pages: static a configuration)
         from ..ops.pallas_decode import chunks_traced
@@ -2436,4 +2446,3 @@ class ModelRunner:
         chunks = chunks_traced()
         if chunks:
             logger.info("decode kernels' chunks: %s", json.dumps(chunks))
-        self._startup_gauge.set(self.startup_s["warmup"], phase="warmup")

@@ -1021,7 +1021,9 @@ class Scheduler:
                      prefetched: Optional[int] = None):
         """Wait for the result of the pass's latest dispatch and bring it
         to the host. The copy of every array is requested first
-        (``_request_transfer``): a synchronous caller comes here straight
+        (``_request_transfer``, under ``sched.<kind>.request``: the calls
+        into the runtime are the pass's work on the loop's thread, and
+        stood in no span before): a synchronous caller comes here straight
         from its dispatch, so the transfers queue behind the step on the
         device's side, with the frontend's turn and the rest of the step
         between the request and the wait; a chained burst made the
@@ -1056,7 +1058,8 @@ class Scheduler:
         (utils/faults.py) that wedges the executor thread first.
         Returns (host arrays, ``t_ready``)."""
         if prefetched is None:
-            prefetched = _request_transfer(arrays)
+            with span(f"sched.{kind}.request", step=self.passes):
+                prefetched = _request_transfer(arrays)
         if turn:
             await self._frontend_turn()
         head, rest = arrays[:tokens_at + 1], arrays[tokens_at + 1:]
@@ -1122,6 +1125,9 @@ class Scheduler:
             compiles = getattr(r, "compiles", None)
             if compiles is not None:
                 compiles.mark_serving_started()
+        startup = getattr(self.runner, "startup", None)
+        if startup is not None:
+            startup.mark("scheduler")
         if self.fabric is not None and self.fabric.cold is not None:
             # restart-warm on EVERY embedding (single-process serve,
             # tests, distributed workers): prime the cold index off-loop
@@ -1640,7 +1646,8 @@ class Scheduler:
             # spans hold no await (sched.admit's only one is the remote
             # prefill submit of a disaggregated engine); only
             # sched.*.sync, sched.yield and sched.wait cross one.
-            # A pass is admit, build, dispatch, yield, sync, emit: the
+            # A pass is admit, build, dispatch, request (of the result's
+            # copy to the host), yield, sync, emit: the
             # frontend's turn (sched.yield) comes while the device
             # computes what the pass dispatched, and at the pass's end
             # only where it fetched nothing (_frontend_turn).

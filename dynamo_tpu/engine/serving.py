@@ -282,6 +282,11 @@ class JaxServingEngine(AsyncEngine):
         # params + cache + compiled programs really hold on each device
         logger.info("engine device: %s", json.dumps(device_report(runner.mesh)))
         scheduler.start()
+        # and the one that says where its start went: the timeline from
+        # the package's import to here, every first dispatch in its parts
+        # (an HTTP frontend adds ``listening`` and writes the same record
+        # to the DYN_TRACE_JSONL sink: cli/run.run_http)
+        logger.info("engine start-up: %s", json.dumps(runner.startup.record()))
         if engine_config.watchdog_stall_s > 0:
             from ..telemetry.watchdog import StallWatchdog
 

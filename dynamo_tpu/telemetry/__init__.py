@@ -10,7 +10,12 @@ spans ride :class:`~dynamo_tpu.runtime.engine.AsyncEngineContext` and are
 queryable at ``GET /debug/requests/{id}``.
 """
 
-from .flight import CompileTracker, FlightRecorder, flight_recorder
+from .flight import (
+    CompileTracker,
+    FlightRecorder,
+    StartupTimeline,
+    flight_recorder,
+)
 from .history import LocalHistorySampler, MetricHistory
 from .hub import FleetHub
 from .incidents import IncidentConfig, IncidentRecorder
@@ -42,6 +47,7 @@ __all__ = [
     "MetricHistory",
     "MetricsRegistry",
     "StallWatchdog",
+    "StartupTimeline",
     "TraceRecorder",
     "build_flight_artifact",
     "escape_label_value",

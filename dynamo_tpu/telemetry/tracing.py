@@ -270,6 +270,13 @@ class TraceRecorder:
             self._traces.move_to_end(request_id)
             self._ingest_t[request_id] = self.clock()
             self._prune()
+        self.write(trace)
+        return trace
+
+    def write(self, trace: dict) -> None:
+        """Queue one record for the JSONL sink alone (nothing where no
+        sink is set). The engine's ``startup`` record comes this way: it
+        is no request, and the ring's TTL would drop it."""
         if self.jsonl_path and not self._stop.is_set():  # no sink after close()
             if self._writer is None:
                 self._writer = threading.Thread(
@@ -284,7 +291,6 @@ class TraceRecorder:
                     logger.warning(
                         "trace JSONL sink backed up (%d dropped so far) — "
                         "is %s hung?", self.dropped, self.jsonl_path)
-        return trace
 
     def close(self, timeout: float = 5.0) -> None:
         """Drain queued writes (bounded by ``timeout``) and close the sink.

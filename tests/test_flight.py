@@ -241,6 +241,241 @@ def test_scheduler_attaches_compile_registry_and_marks_serving():
 
 
 # --------------------------------------------------------------------------
+# a first dispatch in its parts (ISSUE 50): jax.monitoring's events while
+# the dispatch is open on this thread
+# --------------------------------------------------------------------------
+
+PARTS = ("trace_s", "lower_s", "load_s", "compile_s", "rest_s")
+
+
+def _nested_program(scale):
+    """A fresh ``jit`` that calls a fresh inner ``jit`` (no trace of
+    either is cached), over a shape unique to ``scale``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        for _ in range(20):
+            x = jnp.sin(x) * scale + jnp.cos(x)
+        return x
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x * 2.0) + scale
+
+    return outer
+
+
+def _trace_events():
+    """Every ``jaxpr_trace_duration`` jax publishes, as (fun_name, s)."""
+    import jax
+
+    seen = []
+
+    def listener(event, duration, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            seen.append((kw.get("fun_name"), duration))
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    return seen, listener
+
+
+def test_first_dispatch_parts_sum_to_its_duration():
+    import jax
+    import jax.numpy as jnp
+
+    tracker = CompileTracker(flight=FlightRecorder())
+    outer = _nested_program(3.0)
+    x = jnp.ones((16, 31))
+    seen, listener = _trace_events()
+    try:
+        with tracker.track("nested", "k0") as first:
+            assert first
+            outer(x).block_until_ready()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    (rec,) = tracker.records
+    assert all(rec[p] >= 0 for p in PARTS), rec
+    assert sum(rec[p] for p in PARTS) == pytest.approx(rec["duration_s"])
+    assert rec["trace_s"] > 0 and rec["lower_s"] > 0 and rec["compile_s"] > 0
+    # the inner jit's trace lies inside the outer's: counted once
+    by_name = dict(seen)
+    assert by_name["inner"] > 0 and by_name["outer"] > by_name["inner"]
+    assert (0.95 * by_name["outer"] <= rec["trace_s"]
+            < by_name["inner"] + by_name["outer"])
+    # the same record feeds the flight event and the counters
+    evt = tracker.flight.snapshot()[-1]["data"]
+    assert evt["cache"] == rec["cache"]
+    assert all(evt[p] == round(rec[p], 4) for p in PARTS)
+    text = tracker.registry.render()
+    for part in ("trace", "lower", "load", "compile", "rest"):
+        assert ('dynamo_engine_xla_compile_part_seconds_total{part="%s",'
+                'phase="startup",program="nested"}' % part) in text
+    assert 'key=' not in text
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """jax's persistent cache in a directory of this test's own."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs,
+           jax.config.jax_persistent_cache_min_entry_size_bytes)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    yield str(tmp_path)
+    jax.config.update("jax_compilation_cache_dir", old[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", old[1])
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", old[2])
+    cc.reset_cache()
+
+
+def test_first_dispatch_says_miss_then_hit(compile_cache):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((16, 37))
+
+    def first_dispatch():
+        tracker = CompileTracker(flight=FlightRecorder())
+        with tracker.track("cached", "k0"):
+            _nested_program(5.0)(x).block_until_ready()
+        return tracker, tracker.records[0]
+
+    tracker, cold = first_dispatch()
+    assert cold["cache"] == "miss" and cold["compile_s"] > 0, cold
+    assert cold["load_s"] == 0
+    assert ('dynamo_engine_compile_cache_total{phase="startup",'
+            'program="cached",result="miss"} 1.0') in tracker.registry.render()
+    jax.clear_caches()
+    tracker, warm = first_dispatch()
+    assert warm["cache"] == "hit" and warm["load_s"] > 0, warm
+    # the backend's timer holds the load and little else
+    assert warm["compile_s"] < 0.25 * cold["compile_s"], (warm, cold)
+    assert sum(warm[p] for p in PARTS) == pytest.approx(warm["duration_s"])
+    assert ('dynamo_engine_compile_cache_total{phase="startup",'
+            'program="cached",result="hit"} 1.0') in tracker.registry.render()
+
+
+def test_no_cache_directory_reads_off():
+    import jax
+    import jax.numpy as jnp
+
+    if jax.config.jax_compilation_cache_dir:
+        pytest.skip("this process has a compile cache placed")
+    tracker = CompileTracker(flight=FlightRecorder())
+    with tracker.track("plain", "k0"):
+        _nested_program(7.0)(jnp.ones((16, 41))).block_until_ready()
+    assert tracker.records[0]["cache"] == "off"
+    # a zero is a reading: neither result moved
+    text = tracker.registry.render()
+    for result in ("hit", "miss"):
+        assert ('dynamo_engine_compile_cache_total{phase="startup",'
+                'program="plain",result="%s"} 0.0' % result) in text
+
+
+def test_two_threads_compiling_at_once_keep_their_events_apart(compile_cache):
+    import threading
+
+    import jax.numpy as jnp
+
+    # made first: an eager ``ones`` of a new shape is a compile of its
+    # own, outside any dispatch
+    inputs = {(i, j): jnp.ones((16, 43 + 10 * i + j))
+              for i in (0, 1) for j in range(i + 1)}
+    trackers = [CompileTracker(flight=FlightRecorder()) for _ in range(2)]
+    both_open = threading.Barrier(2)
+    errors = []
+
+    def compile_on(i):
+        try:
+            with trackers[i].track(f"thread{i}", "k0"):
+                both_open.wait(timeout=30)
+                for j in range(i + 1):   # thread 1 compiles two programs
+                    _nested_program(11.0 + 10 * i + j)(
+                        inputs[i, j]).block_until_ready()
+        except Exception as e:   # noqa: BLE001
+            errors.append(e)
+
+    threads = [threading.Thread(target=compile_on, args=(i,)) for i in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    assert not errors, errors
+    recs = [t.records[0] for t in trackers]
+    for i, rec in enumerate(recs):
+        assert rec["program"] == f"thread{i}"
+        assert rec["lower_s"] > 0 and rec["compile_s"] > 0
+        # its own events alone: never more than its own wall time
+        assert sum(rec[p] for p in PARTS) == pytest.approx(rec["duration_s"])
+        assert rec["rest_s"] >= 0
+    # one program's miss on thread 0, two on thread 1, nothing outside
+    for i, t in enumerate(trackers):
+        text = t.registry.render()
+        assert ('dynamo_engine_compile_cache_total{phase="startup",'
+                'program="thread%d",result="miss"} %d.0' % (i, i + 1)) in text
+        assert ',program="untracked"}' not in text
+
+
+def test_events_outside_a_dispatch_are_untracked_and_a_seen_key_adds_nothing():
+    import jax.numpy as jnp
+
+    tracker = CompileTracker(flight=FlightRecorder())   # the tracker made last
+    program = _nested_program(13.0)
+    x = jnp.ones((16, 47))
+    program(x).block_until_ready()            # compiles outside any track()
+    text = tracker.registry.render()
+    for part in ("trace", "lower", "compile"):
+        line = ('dynamo_engine_xla_compile_part_seconds_total{part="%s",'
+                'phase="startup_untracked",program="untracked"}' % part)
+        assert line in text, text
+    # no first dispatch, so no term of phase="startup", which sums to
+    # warm-up's first dispatches
+    assert 'phase="startup",' not in text
+    assert not tracker.records
+    with tracker.track("seen", "k0") as first:
+        assert first
+        program(x).block_until_ready()        # jit's fast path: no event
+    before = tracker.registry.render()
+    tracker.mark_serving_started()
+    with tracker.track("seen", "k0") as first:
+        assert not first
+        _nested_program(17.0)(jnp.ones((16, 53))).block_until_ready()
+    # the second dispatch of a seen key opens nothing: what compiled
+    # inside it is a late compile outside track()
+    assert len(tracker.records) == 1
+    after = tracker.registry.render()
+    assert ('dynamo_engine_xla_compile_part_seconds_total{part="compile",'
+            'phase="late",program="untracked"}') in after
+    seen_lines = [ln for ln in after.splitlines() if 'program="seen"' in ln]
+    assert seen_lines == [ln for ln in before.splitlines()
+                          if 'program="seen"' in ln]
+
+
+def test_late_compile_log_line_says_its_parts(caplog):
+    import logging
+
+    import jax.numpy as jnp
+
+    tracker = CompileTracker(flight=FlightRecorder())
+    tracker.mark_serving_started()
+    with caplog.at_level(logging.WARNING, logger="dynamo_tpu.telemetry.flight"):
+        with tracker.track("late_one", "k0"):
+            _nested_program(19.0)(jnp.ones((16, 59))).block_until_ready()
+    (line,) = [r.getMessage() for r in caplog.records
+               if "late XLA compile" in r.getMessage()]
+    assert "program=late_one key=k0" in line
+    for word in ("trace", "lower", "cache load", "compile", "rest", "cache "):
+        assert word in line, line
+
+
+# --------------------------------------------------------------------------
 # satellite: profiler capture dirs can no longer collide
 # --------------------------------------------------------------------------
 

@@ -4,6 +4,8 @@ tier-1 test, so a PR registering an off-convention instrument fails CI."""
 import os
 import sys
 
+import pytest
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO_ROOT, "scripts"))
 
@@ -154,6 +156,22 @@ def test_lint_sees_the_real_instrument_catalog():
     missing = expected - names
     assert not missing, f"lint no longer sees: {sorted(missing)}"
     assert len(names) >= 115
+
+
+@pytest.mark.parametrize("name", [
+    # the start-up timeline and a first dispatch in its parts (ISSUE 50;
+    # telemetry/flight.py)
+    "dynamo_engine_startup_seconds",
+    "dynamo_engine_startup_mark_monotonic_seconds",
+    "dynamo_engine_xla_compile_part_seconds_total",
+    "dynamo_engine_compile_cache_total",
+])
+def test_lint_sees_the_startup_series(name):
+    found = [m for m in iter_registered_metrics(PACKAGE_ROOT)
+             if m.name == name]
+    assert found, name
+    assert all(m.file.endswith("flight.py") for m in found), found
+    assert not any(check_name(m) for m in found)
 
 
 def _metric(name, kind):

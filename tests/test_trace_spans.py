@@ -12,6 +12,7 @@ are assertions on what it recorded.
 import asyncio
 import glob
 import json
+import logging
 import os
 import re
 import socket
@@ -22,8 +23,9 @@ import pytest
 from fixtures import make_model_dir
 
 SCHED = ("sched.admit", "sched.prefill.build", "sched.prefill.dispatch",
-         "sched.prefill.sync", "sched.prefill.emit", "sched.decode.build",
-         "sched.decode.dispatch", "sched.decode.sync", "sched.decode.emit",
+         "sched.prefill.request", "sched.prefill.sync", "sched.prefill.emit",
+         "sched.decode.build", "sched.decode.dispatch",
+         "sched.decode.request", "sched.decode.sync", "sched.decode.emit",
          "sched.yield", "sched.wait")
 CROSS_AWAIT = ("sched.prefill.sync", "sched.decode.sync", "sched.yield",
                "sched.wait")
@@ -213,10 +215,20 @@ async def _served_scenario(tmp):
         "--http-port", str(port), "--max-model-len", "128",
         "--max-batch-size", "4", "--num-kv-blocks", "96",
         "--kv-block-size", "8", "--extra-engine-args", extra])
-    engine, mdc = await build_engine("jax", flags)
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda r: lines.append(r.getMessage())
+    serving_log = logging.getLogger("dynamo_tpu.engine.serving")
+    serving_log.addHandler(handler)
+    level, serving_log.level = serving_log.level, logging.INFO
+    try:
+        engine, mdc = await build_engine("jax", flags)
+    finally:
+        serving_log.removeHandler(handler)
+        serving_log.level = level
     task = asyncio.ensure_future(run_http(flags, engine, mdc))
     base = f"http://127.0.0.1:{port}"
-    out = {"runner": engine.core_engine.runner,
+    out = {"runner": engine.core_engine.runner, "serving_log": lines,
            "log": _record(engine.core_engine.scheduler)}
 
     async def complete(session, rid, prompt, n):
@@ -259,6 +271,8 @@ async def _served_scenario(tmp):
             out["metrics_after"] = await metrics(session)
             async with session.get(f"{base}/debug/requests/second") as r:
                 out["debug_second"] = await r.json()
+            async with session.get(f"{base}/debug/requests") as r:
+                out["debug_requests"] = await r.text()
     finally:
         task.cancel()
         try:
@@ -399,7 +413,8 @@ def _passes(events, tid):
 
 def _assert_one_turn_a_pass(events, sync_path, what):
     """sched.yield once a pass that progressed and never twice; on a
-    synchronous decode path right after the pass's last dispatch and
+    synchronous decode path right after the pass's last dispatch (and
+    the request for its result's copy, ``sched.decode.request``) and
     before the wait for its result, with ``inflight=1`` (ISSUE 32)."""
     by_pass = _passes(events, _loop_tid(events))
     first, last = min(by_pass), max(by_pass)
@@ -414,7 +429,10 @@ def _assert_one_turn_a_pass(events, sync_path, what):
         assert names.count("sched.yield") == 1, (what, n, names)
         i = names.index("sched.yield")
         if sync_path and "sched.decode.dispatch" in names:
-            assert names[i - 1] == "sched.decode.dispatch", (what, names)
+            # the request for the result's copy to the host is all
+            # that stands between the dispatch and the turn
+            assert names[i - 2:i] == ["sched.decode.dispatch",
+                                      "sched.decode.request"], (what, names)
             assert names[i + 1:] == ["sched.decode.sync",
                                      "sched.decode.emit"], (what, names)
             assert spans[i]["stats"]["inflight"] == 1, (what, n)
@@ -676,6 +694,111 @@ def test_startup_gauge_has_each_phase(served, phase):
     assert v == pytest.approx(served["runner"].startup_s[phase])
     assert _prom(served["metrics_after"], "dynamo_engine_xla_compiles_total",
                  '{phase="late"') is None   # warm-up swept every shape
+
+
+# the start-up timeline (ISSUE 50): one list of marks from the package's
+# import to the service listening, each phase the time from the mark
+# before it
+MARKS = ("import", "backend", "model_card", "device_init", "weights",
+         "kv_cache", "runner", "engine", "warmup", "scheduler", "listening")
+PARTS = ("trace", "lower", "load", "compile", "rest")
+
+
+def _mark(served, name):
+    return _prom(served["metrics_after"],
+                 "dynamo_engine_startup_mark_monotonic_seconds",
+                 '{mark="%s"}' % name)
+
+
+def _phase(served, name):
+    return _prom(served["metrics_after"], "dynamo_engine_startup_seconds",
+                 '{phase="%s"}' % name)
+
+
+def test_startup_marks_are_in_order(served):
+    import dynamo_tpu
+
+    marks = served["runner"].startup.marks
+    assert tuple(name for name, _ in marks) == MARKS
+    times = [t for _, t in marks]
+    assert times == sorted(times)
+    assert [_mark(served, name) for name in MARKS] == times
+    # the program's earliest moment, on the clock of the request records
+    assert times[0] == dynamo_tpu.T_IMPORT
+    assert times[-1] < served["t"][0]
+
+
+@pytest.mark.parametrize("phase", MARKS[1:] + ("warmup_wait", "serve"))
+def test_startup_gauge_has_the_timelines_phases(served, phase):
+    v = _phase(served, phase)
+    assert v is not None and v >= 0
+    assert v == pytest.approx(served["runner"].startup_s[phase])
+    at = dict(served["runner"].startup.marks)
+    if phase in at:   # the time from the mark before it
+        before = MARKS[MARKS.index(phase) - 1]
+        assert v == pytest.approx(at[phase] - at[before], abs=2e-6)
+
+
+def test_startup_phases_sum_to_the_timeline(served):
+    total = _mark(served, "listening") - _mark(served, "import")
+    assert sum(_phase(served, p) for p in MARKS[1:]) == pytest.approx(
+        total, abs=0.2)
+    # serve lies across two phases and warmup_wait inside one: no terms
+    assert _phase(served, "serve") == pytest.approx(
+        _phase(served, "scheduler") + _phase(served, "listening"))
+    assert _phase(served, "warmup_wait") <= _phase(served, "warmup")
+
+
+def test_startup_record_is_in_the_jsonl_and_not_in_the_ring(served):
+    import dynamo_tpu
+
+    rec = served["jsonl"]["startup"]
+    # inside the process's lifetime: its first mark is the import's
+    assert rec["t0_monotonic"] == dynamo_tpu.T_IMPORT < time.monotonic()
+    assert [s["name"] for s in rec["spans"]] == list(MARKS[1:])
+    for s in rec["spans"]:
+        assert s["duration_s"] == pytest.approx(
+            served["runner"].startup_s[s["name"]])
+    assert rec["total_s"] == pytest.approx(
+        _mark(served, "listening") - _mark(served, "import"), abs=1e-5)
+    assert {p["program"] for p in rec["programs"]} == {
+        "decode", "prefill", "sample_row"}
+    for p in rec["programs"]:
+        assert "key" in p and p["phase"] == "startup"
+        assert sum(p[part + "_s"] for part in PARTS) == pytest.approx(
+            p["duration_s"])
+    assert "second" in served["debug_requests"]
+    assert "startup" not in served["debug_requests"]
+
+
+def test_startup_is_logged_beside_the_device_line(served):
+    lines = served["serving_log"]
+    at = [i for i, ln in enumerate(lines) if ln.startswith("engine start-up: ")]
+    assert len(at) == 1
+    assert lines[at[0] - 1].startswith("engine device: ")
+    rec = json.loads(lines[at[0]][len("engine start-up: "):])
+    # the marks so far: the HTTP service adds ``listening``
+    assert [s["name"] for s in rec["spans"]] == list(MARKS[1:-1])
+    assert rec["request_id"] == "startup" and len(rec["programs"]) == 5
+
+
+def test_warmup_is_its_first_dispatches_in_parts_and_the_wait(served):
+    """Every tracked dispatch of start-up is one of warm-up's: the five
+    parts over all programs and ``warmup_wait`` are ``warmup``."""
+    text = served["metrics_after"]
+    parts = sum(
+        float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+        if line.startswith("dynamo_engine_xla_compile_part_seconds_total{")
+        and 'phase="startup"' in line)
+    assert parts > 0
+    assert parts + _phase(served, "warmup_wait") == pytest.approx(
+        _phase(served, "warmup"), abs=1e-4)
+    # what weight init's helper jits compiled is counted, apart
+    assert _prom(text, "dynamo_engine_xla_compile_part_seconds_total",
+                 '{part="compile",phase="startup_untracked",'
+                 'program="untracked"}') > 0
+    # no series carries a shape key
+    assert 'key="' not in text
 
 
 @pytest.mark.parametrize("field", ["cached_tokens", "computed_tokens",
