@@ -685,8 +685,12 @@ class EngineConfig:
 
     def kv_width_buckets(self) -> List[int]:
         """The decode block-table width ladder: powers of two from 8 up to
-        the full per-seq width (always included). One compiled decode
-        program exists per bucket; ModelRunner.warmup sweeps the ladder."""
+        the full per-seq width (always included). A decode-shaped program
+        whose trace read the table at its width (the XLA gather, block
+        selection) is compiled at every rung; one whose kernels walk a
+        row's live pages exists at the full width alone
+        (``ModelRunner.table_width``, which the scheduler asks and
+        ``ModelRunner.warmup`` follows)."""
         widths = []
         w = 8
         while w < self.blocks_per_seq:
@@ -696,10 +700,10 @@ class EngineConfig:
         return widths
 
     def kv_width_bucket(self, nblocks: int) -> int:
-        """Block-table width for a decode step covering ``nblocks`` live
-        blocks. Attention cost on the gather/page-walk side scales with
-        table width, so short contexts must not pay max_model_len's
-        width."""
+        """The ladder's smallest rung that covers ``nblocks`` live blocks:
+        the table width of a decode step whose attention gathers
+        ``[B, W]`` pages, where the cost scales with the table's width
+        and a short context must not pay max_model_len's."""
         for w in self.kv_width_buckets():
             if nblocks <= w:
                 return w

@@ -27,7 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import models
 from ..models import llama, quant
-from ..ops.attention import _pad_minor, row_list_traced
+from ..ops.attention import (_pad_minor, row_list_traced,
+                             table_width_traced)
 from ..telemetry.flight import CompileTracker, StartupTimeline
 from ..telemetry.registry import Counter
 from .config import EngineConfig
@@ -467,6 +468,13 @@ class ModelRunner:
         # and those whose trace walks the sampling tail in tiles of rows,
         # with the rows a tile: the scheduler counts whole tiles for them
         self.sampling_tile_programs: dict = {}
+        # the decode-shaped programs whose trace did work in proportion
+        # to the block table's width (ops/attention.record_table_width):
+        # these alone exist at the narrower rungs of
+        # ``EngineConfig.kv_width_buckets`` (``table_width``)
+        self.width_programs: set = set()
+        # what warm-up dispatched each of them at, and why (its last log)
+        self.warmed_widths: dict = {}
         if (_attn_ops.ATTENTION_ROUTE_COUNTER.name
                 not in self.compiles.registry.names()):
             self.compiles.registry.register(
@@ -661,18 +669,24 @@ class ModelRunner:
     # ---------- the unified step program ----------
 
     @contextlib.contextmanager
-    def _track(self, program: str, key: str, **stats):
+    def _track(self, program: str, key: str, shape: Optional[str] = None,
+               **stats):
         """``compiles.track`` around one dispatch of a decode program,
         keeping what its trace recorded beside the route: whether the
         attention kernels were handed a list of live rows
-        (ops/attention.record_row_list), and the tile its sampling tail
-        walks (``_sample_and_logprobs``)."""
+        (ops/attention.record_row_list), whether anything read the block
+        table at its width (ops/attention.record_table_width; filed
+        under ``shape`` where one tracked program has several shapes of
+        table), and the tile its sampling tail walks
+        (``_sample_and_logprobs``)."""
         global _tiles_traced
         with self.compiles.track(program, key, **stats) as first:
             _tiles_traced = 0
             yield first
             if first and row_list_traced():
                 self.row_list_programs.add(program)
+            if first and table_width_traced():
+                self.width_programs.add(shape or program)
             if first and _tiles_traced:
                 self.sampling_tile_programs[program] = _tiles_traced
 
@@ -1585,7 +1599,7 @@ class ModelRunner:
             jnp.asarray(stop_hash, jnp.uint32),
             jnp.asarray(stop_hlen, jnp.int32),
         )
-        with self.compiles.track(
+        with self._track(
             "decode_burst_spec", f"b{b}_w{block_tables.shape[1]}"
         ):
             if proposals is None:
@@ -1820,9 +1834,13 @@ class ModelRunner:
             frequency_penalty=frequency_penalty,
             repetition_penalty=repetition_penalty,
         )
+        # a decode-shaped step is sized by ``table_width``: one query a
+        # row, or the speculative verify's K + 1 (the one caller that
+        # wants every position's argmax)
+        shape = "decode" if s == 1 else "verify" if want_greedy else "prefill"
         with self._track(
             "prefill" if s > 1 else "decode",
-            f"b{b}_s{s}_w{width}", arrays=1,
+            f"b{b}_s{s}_w{width}", shape=shape, arrays=1,
         ):
             moe = () if self.moe_counts is None else (self.moe_counts,)
             args = (self.params, *self.kv_cache, *self.sample_state,
@@ -2238,17 +2256,52 @@ class ModelRunner:
             (self.cache_sharding,) * 2, (self.state_sharding,) * 3,
         ))()
 
-    def warmup(self, decode_batch: Optional[int] = None) -> None:
-        """Compile every serving program up front: the decode program
-        per KV-width bucket and the prefill program per (row bucket,
-        length bucket) the scheduler can pick.
+    def table_width(self, program: str, nblocks: int) -> int:
+        """The block-table width of a dispatch of the decode-shaped
+        ``program`` (``decode``, ``verify``, ``decode_block``,
+        ``decode_burst``, ``decode_burst_df``, ``decode_burst_spec``)
+        whose longest row holds ``nblocks`` blocks.
 
-        The scheduler sizes decode block tables with
-        EngineConfig.kv_width_bucket and prefill steps with
-        prefill_row_bucket x bucket_for, so serving touches ladders of
-        shapes; compiling them here keeps multi-ten-second TPU compiles
-        out of the first requests' latency (the analog of GPU engines'
-        startup capture sweeps).
+        A program whose trace read the table at its width (an XLA
+        gather, block selection: ``width_programs``) pays for every
+        entry, so it exists at each rung of
+        ``EngineConfig.kv_width_buckets`` and gets the smallest that
+        covers the batch. A program whose kernels walk live pages does
+        the same work at any width and exists at the full one only; so
+        does a program not yet traced, whose first dispatch, at the full
+        width, is what decides."""
+        if program in self.width_programs:
+            return self.config.kv_width_bucket(nblocks)
+        return self.config.blocks_per_seq
+
+    def _warm_widths(self, program: str):
+        """The widths warm-up dispatches ``program`` at, the caller
+        dispatching between two: the full width first, then the narrower
+        rungs if that trace read the table at its width. Notes in
+        ``warmed_widths`` what it yielded and why."""
+        full = self.config.blocks_per_seq
+        yield full
+        reads = program in self.width_programs
+        rest = ([w for w in self.config.kv_width_buckets() if w != full]
+                if reads else [])
+        yield from rest
+        self.warmed_widths[program] = {
+            "widths": rest + [full],
+            "why": "gather traced" if reads else "kernel walk"}
+
+    def warmup(self, decode_batch: Optional[int] = None) -> None:
+        """Compile every serving program up front: each decode-shaped
+        program at every block-table width the scheduler can ask for
+        (``_warm_widths``: the full width, and the narrower rungs of
+        ``EngineConfig.kv_width_buckets`` only where the program's trace
+        read the table at its width), and the prefill program per (row
+        bucket, length bucket) the scheduler can pick.
+
+        The scheduler sizes decode block tables with ``table_width`` and
+        prefill steps with prefill_row_bucket x bucket_for, so serving
+        touches ladders of shapes; compiling them here keeps
+        multi-ten-second TPU compiles out of the first requests' latency
+        (the analog of GPU engines' startup capture sweeps).
 
         A program that fails to compile — a Pallas kernel Mosaic
         rejects at this model's shapes — raises here with the
@@ -2272,14 +2325,14 @@ class ModelRunner:
             # the block pass is the family's one decode program (inert:
             # every slot is the drop sentinel, every quota 0)
             zb = np.zeros((b, 2 * self.unit.length), np.int32)
-            for w in self.config.kv_width_buckets():
+            for w in self._warm_widths("decode_block"):
                 self.decode_block(
                     zb, zb, np.zeros((b, w), np.int32), np.full_like(zb, -1),
                     np.ones(b, np.int32), np.zeros(b, np.int32),
                     np.zeros(b, np.float32), np.zeros(b, np.int32),
                     np.ones(b, np.float32),
                 )
-        for w in self.config.kv_width_buckets() if self.unit is None else ():
+        for w in self._warm_widths("decode") if self.unit is None else ():
             self.step(
                 zeros2, zeros2, np.zeros((b, w), np.int32),
                 np.full((b, 1), -1, np.int32),
@@ -2288,11 +2341,11 @@ class ModelRunner:
                 np.ones(b, np.float32),
                 jax.random.PRNGKey(0),
             )
-        # the fused multi-step decode program over the same width ladder
-        # (inert rows: commit all-False writes nothing and samples noise)
+        # the fused multi-step decode program at its widths (inert rows:
+        # commit all-False writes nothing and samples noise)
         if self._burst is not None:
             z1 = np.zeros(b, np.int32)
-            for w in self.config.kv_width_buckets():
+            for w in self._warm_widths("decode_burst"):
                 self.decode_burst(
                     z1, z1, np.zeros((b, w), np.int32),
                     np.zeros(b, np.float32), z1, np.ones(b, np.float32),
@@ -2303,7 +2356,7 @@ class ModelRunner:
                     seed_keys=np.zeros((b, 2), np.uint32), counters=z1,
                     commit=np.zeros(b, bool), want_top=False,
                 )
-        # the device-finish burst variant over the same ladder (inert:
+        # the device-finish burst variant at its widths (inert:
         # commit all-False, so no row writes KV or counts); compiling it
         # here keeps the persistent loop's first chain off the late-
         # compile path exactly like the plain burst above
@@ -2311,7 +2364,7 @@ class ModelRunner:
             from .sampling import STOP_ID_WIDTH
 
             z1 = np.zeros(b, np.int32)
-            for w in self.config.kv_width_buckets():
+            for w in self._warm_widths("decode_burst_df"):
                 self.decode_burst_chained(
                     z1, z1, z1, np.zeros(b, bool),
                     np.zeros((b, w), np.int32),
@@ -2327,7 +2380,7 @@ class ModelRunner:
                     want_top=False,
                 )
         # the chained propose-verify round (spec state in the burst
-        # carry) over the same ladder; inert like the burst warmups
+        # carry) at its widths; inert like the burst warmups
         if self._spec_ngram is not None or self._spec_verify is not None:
             from .sampling import (
                 STOP_ID_WIDTH,
@@ -2337,7 +2390,7 @@ class ModelRunner:
 
             z1 = np.zeros(b, np.int32)
             K = self._spec_k
-            for w in self.config.kv_width_buckets():
+            for w in self._warm_widths("decode_burst_spec"):
                 self.decode_burst_spec(
                     z1, z1, z1, np.zeros(b, bool),
                     np.full((b, SUFFIX_RING_W), -1, np.int32),
@@ -2354,11 +2407,11 @@ class ModelRunner:
                     ),
                 )
         # the ngram-speculative verify shape (S = K+1 on decode-width
-        # tables) over the same ladder
+        # tables) at its widths
         if self.config.spec_ngram_tokens:
             sK = self.config.spec_ngram_tokens + 1
             zs = np.zeros((b, sK), np.int32)
-            for w in self.config.kv_width_buckets():
+            for w in self._warm_widths("verify"):
                 self.step(
                     zs, zs, np.zeros((b, w), np.int32),
                     np.full((b, sK), -1, np.int32),
@@ -2446,3 +2499,5 @@ class ModelRunner:
         chunks = chunks_traced()
         if chunks:
             logger.info("decode kernels' chunks: %s", json.dumps(chunks))
+        logger.info("decode programs' table widths: %s",
+                    json.dumps(self.warmed_widths))

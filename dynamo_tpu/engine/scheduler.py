@@ -2278,13 +2278,14 @@ class Scheduler:
         if rest:
             await self._decode(loop, rest, sync_steps)
 
-    def _chain_masks(self, members, live):
-        """(commit mask, block-table slice) for one chained dispatch."""
+    def _chain_masks(self, members, live, *asked):
+        """(commit mask, block-table slice) for one chained dispatch of
+        the programs ``asked`` (``_table_width``)."""
         cfg = self.config
         commit = np.zeros(cfg.max_batch_size, bool)
         for er in members:
             commit[er.slot] = er.finish is None
-        w = cfg.kv_width_bucket(max(len(er.block_ids) for er in live))
+        w = self._table_width(live, *asked)
         return commit, self._host.btab[:, :w].copy()
 
     def _chain_fill(self, live, with_guided):
@@ -2365,7 +2366,8 @@ class Scheduler:
             reserved = self._chain_reserve(live, k_steps)
             if reserved:
                 hs = self._host
-                commit, btab = self._chain_masks(members, live)
+                commit, btab = self._chain_masks(
+                    members, live, (self.runner, "decode_burst_df"))
                 want_top = any(er.logprobs_n > 0 for er in members)
                 # guided members ride the device transition table: ONE table per
                 # chain (_chain_block_reason enforced it), their bias rows reset
@@ -2462,7 +2464,9 @@ class Scheduler:
             reserved = self._chain_reserve(live, S)
             if reserved:
                 hs = self._host
-                commit, btab = self._chain_masks(members, live)
+                commit, btab = self._chain_masks(
+                    members, live, (self.runner, "decode_burst_spec"),
+                    (self.draft, "decode_burst"))
                 if self._chain_carry is None:
                     (tokens0, positions0, gen0, done0, ring0,
                      gstate0) = self._chain_fill(live, with_guided=False)
@@ -3222,7 +3226,7 @@ class Scheduler:
             self.allocator.flush_offload()
             if can_burst:
                 hs.sync_blocks(er)
-                w = cfg.kv_width_bucket(len(er.block_ids))
+                w = self._table_width([er], (self.runner, "decode_burst"))
                 btab = hs.btab[:, :w].copy()
                 import jax.numpy as jnp
                 tok0 = jnp.zeros(b, jnp.int32).at[er.slot].set(next_tokens[0])
@@ -3641,7 +3645,7 @@ class Scheduler:
         cfg = self.config
         b = cfg.max_batch_size
         with span("sched.decode.build", step=self.passes, rows=len(active)):
-            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            w = self._table_width(active, (self.draft, "decode_burst"))
             tokens0 = np.zeros(b, np.int32)
             positions0 = np.zeros(b, np.int32)
             btab = np.zeros((b, w), np.int32)
@@ -3732,7 +3736,7 @@ class Scheduler:
             props = await self._draft_propose(loop, active, K)
 
         with span("sched.decode.build", step=self.passes, rows=len(active)):
-            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            w = self._table_width(active, (self.runner, "verify"))
             tokens = np.zeros((b, S), np.int32)
             positions = np.zeros((b, S), np.int32)
             slot_map = np.full((b, S), -1, np.int32)
@@ -3809,6 +3813,22 @@ class Scheduler:
                     self._emit(er, token, None, None)
                     if er.finish is not None:
                         self._finish(er, er.finish, emit=False)
+
+    def _table_width(self, rows: List[EngineRequest], *asked) -> int:
+        """The block-table width of one dispatch over ``rows``. ``asked``:
+        a (runner, decode-shaped program) pair for each program handed
+        the table (the target's, and the draft's where it mirrors the
+        dispatch; a runner that is None is skipped). Each runner says the
+        narrowest width it has its program at that covers the longest
+        row (``ModelRunner.table_width``: the full width unless the
+        program's trace read the table at its width), and the widest of
+        those exists for all of them. A runner that says nothing of
+        widths (a test's stand-in) gets the configuration's ladder."""
+        nblocks = max(len(er.block_ids) for er in rows)
+        return max(
+            r.table_width(program, nblocks) if hasattr(r, "table_width")
+            else self.config.kv_width_bucket(nblocks)
+            for r, program in asked if r is not None)
 
     def _count_decode_rows(self, program: str, live: int,
                            steps: int = 1) -> None:
@@ -3890,11 +3910,15 @@ class Scheduler:
             if not active:
                 return
 
-            # KV-width bucketing: the block table (and so the gather/page walk
-            # behind attention) is sized to the LIVE context, rounded up a
-            # power-of-two ladder — short-context decode doesn't pay the
-            # max_model_len table width (one compiled program per bucket)
-            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            # the table's width: full where the program's kernels walk
+            # live pages; where its trace gathered [B, W] pages the LIVE
+            # context rounded up a power-of-two ladder, so that a short
+            # context doesn't pay max_model_len's gather (one compiled
+            # program per rung)
+            w = self._table_width(
+                active,
+                (self.runner, "decode_burst" if k_steps > 1 else "decode"),
+                (self.draft, "decode"))   # its mirror (k_steps is 1 then)
 
             # sampling params and the block table come from the persistent
             # host state (mutated only on membership / block growth); only
@@ -4116,7 +4140,7 @@ class Scheduler:
             self.allocator.flush_offload()
             if not active:
                 return
-            w = cfg.kv_width_bucket(max(len(er.block_ids) for er in active))
+            w = self._table_width(active, (self.runner, "decode_block"))
 
             hs = self._host
             tokens = np.zeros((b, 2 * length), np.int32)

@@ -51,6 +51,7 @@ ATTENTION_ROUTE_COUNTER = Counter(
 
 _route_program = "unknown"
 _row_list_traced = False
+_table_width_traced = False
 
 
 @contextlib.contextmanager
@@ -58,10 +59,11 @@ def route_program(name: str):
     """Label route records with the engine program being dispatched
     (installed as CompileTracker.dispatch_cm — active only while a
     tracked dispatch, and therefore its trace, is on the stack)."""
-    global _route_program, _row_list_traced
+    global _route_program, _row_list_traced, _table_width_traced
     prev = _route_program
     _route_program = name
     _row_list_traced = False
+    _table_width_traced = False
     try:
         yield
     finally:
@@ -70,8 +72,12 @@ def route_program(name: str):
 
 def record_route(route: str) -> None:
     """Stamp one route decision (called from the dispatch seams here
-    and in parallel/sequence.py — trace-time Python, never traced)."""
+    and in parallel/sequence.py — trace-time Python, never traced). The
+    ``xla`` route gathers every page the table has room for, so it also
+    stamps ``record_table_width``."""
     ATTENTION_ROUTE_COUNTER.inc(program=_route_program, route=route)
+    if route == "xla":
+        record_table_width()
 
 
 def record_row_list() -> None:
@@ -88,6 +94,27 @@ def row_list_traced() -> bool:
     """Whether a trace since the innermost ``route_program`` was entered
     called ``record_row_list``."""
     return _row_list_traced
+
+
+def record_table_width() -> None:
+    """Stamp that an operation of the program being traced does work in
+    proportion to the block table's width ``W``, whatever the rows hold:
+    a gather of ``[B, W]`` pages, or block selection's scores and sort
+    over ``W`` entries (ops/sparse_attention.decode_attention). The
+    kernels do not: they walk ``ceil(context_len / page)`` pages a live
+    row and never read a pad entry. Trace-time, like ``record_route``;
+    the runner reads it off the dispatch that traced
+    (``table_width_traced``) and compiles a decode program at the
+    narrower widths of ``EngineConfig.kv_width_buckets`` only where it
+    was stamped."""
+    global _table_width_traced
+    _table_width_traced = True
+
+
+def table_width_traced() -> bool:
+    """Whether a trace since the innermost ``route_program`` was entered
+    called ``record_table_width``."""
+    return _table_width_traced
 
 
 def lane_pad(d: int) -> int:

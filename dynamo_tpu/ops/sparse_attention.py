@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from .attention import (_pad_minor, pallas_interpret, record_route,
-                        record_row_list, resolve_attention_impl)
+                        record_row_list, record_table_width,
+                        resolve_attention_impl)
 from .live_rows import LiveRows
 from .pallas_decode import paged_decode_attention
 
@@ -281,6 +282,9 @@ def decode_attention(q, k_all, v_all, means_all, li, block_tables,
     n = context_lens.astype(jnp.int32)
     pages = _head_pages(block_tables, kvh)                           # [B, KVH, W]
     if shape.selects(w):
+        # W page means gathered a (row, kv head), scored, and W entries
+        # sorted: the width is part of what this program does
+        record_table_width()
         with jax.named_scope("sparse_select"):
             n2 = jnp.broadcast_to(n[:, None], (b, kvh))
             p = compressed_probs(q.reshape(b, kvh, h // kvh, d),
