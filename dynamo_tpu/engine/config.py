@@ -172,6 +172,15 @@ class ModelConfig:
     # says which share: experts [expert_rank * num_experts, + num_experts)
     experts_of: int = 0
     expert_rank: int = 0
+    # Kimi Linear (models/kimi_linear.py, model_type "kimi_linear"):
+    # layer_types names each layer's token mixer, "kda" (Kimi Delta
+    # Attention: kda_num_heads heads of kda_head_dim, a float32 state
+    # [head_dim, head_dim] a head and the causal conv's last
+    # kda_conv_kernel - 1 inputs, by slot, no pages) or "mla" (latent
+    # attention with no positional term: pages, no state).
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
 
     def __post_init__(self):
         if self.head_dim is None:

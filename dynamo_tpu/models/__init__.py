@@ -142,6 +142,13 @@ class Family:
 
 # in the order resolve asks: the first row that serves a config has it
 FAMILIES = (
+    # before deepseek, whose shape rule (kv_lora_rank > 0) would take it
+    Family("kimi_linear", model_types=("kimi_linear",),
+           field="kda_num_heads", reads=("layer_types",),
+           unserved="kda_num_heads={value} needs a family with Kimi Delta "
+                    "Attention layers and their state by slot; model_family "
+                    "{family!r} has none (models/kimi_linear.py is selected "
+                    "by model_type kimi_linear)"),
     Family("deepseek", model_types=("xing4_0",),
            shape=lambda cfg: cfg.kv_lora_rank > 0, staged=True,
            field="hc_mult",

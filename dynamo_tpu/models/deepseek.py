@@ -431,8 +431,13 @@ def mla_softmax_scale(cfg) -> float:
 
 
 def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
-                     context_lens, mesh=None, kv_gather_axis=None):
+                     context_lens, mesh=None, kv_gather_axis=None,
+                     rope: bool = True):
     """MLA attention block for llama.run_layers.
+
+    ``rope=False``: layers with no positional term (models/kimi_linear.py,
+    ``mla_use_nope``): the ``qk_rope_head_dim``-wide parts of the query
+    and of the key are carried as projected, the cache line is the same.
 
     ``kv_gather_axis``: inside a manual shard_map whose batch rows shard
     over that axis while the latent cache stays replicated across it
@@ -457,14 +462,16 @@ def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
         else:
             qfull = dense(x, lp["wq"]).reshape(b, s, h, nope + rope_d)
         q_nope, q_rope = qfull[..., :nope], qfull[..., nope:]
-        q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
+        if rope:
+            q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                                cfg.rope_scaling)
 
         # compressed KV state for the new tokens
         c_kv = rms_norm(dense(x, lp["w_dkv"]), lp["ln_kv"], cfg.rms_norm_eps)
-        kr = apply_rope(
-            (x @ lp["w_kr"])[:, :, None, :], positions, cfg.rope_theta,
-            cfg.rope_scaling,
-        )[:, :, 0]  # [B, S, rd]
+        kr = x @ lp["w_kr"]  # [B, S, rd]
+        if rope:
+            kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta,
+                            cfg.rope_scaling)[:, :, 0]
 
         # in-place scatter into the stacked caches
         c_w, kr_w, slots_w = c_kv, kr, slot_mapping

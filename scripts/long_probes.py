@@ -32,16 +32,23 @@ against the reference's two and four), ``gate_over_all`` and
 softmax over every published expert and not over the chosen ones, and
 the attention layers' scores scaled by ``head_dim ** -0.5`` in the
 served model's ``config.json`` only, against the published 1 /
-head_dim). Where the reference module has ``limits_for``, a
+head_dim), ``scalar_decay``, ``rope_on_mla`` and ``beta_one`` (Kimi
+Linear, ``kimi-linear-reasoning``, PR 52: a KDA layer's decay taken as
+one scalar a head, the channel mean of ``g``; a rotary term on the latent
+layers' 64-wide parts; every token written at full strength; and
+``bf16_state`` holds its KDA state in bfloat16 as the other families'
+records). Where the reference module has ``limits_for``, a
 probe is held to the pair it gives for the probe's context; otherwise to
 the module's one pair.
 
 ``--controls state,router,pages`` reads, on the same probes, what the
 reference gives when part of it is computed in the precision below the
-stated one (``build(..., lower=(name,))``, where the reference module
-has ``CONTROLS``: Granite 4.0-H's state in bfloat16 from token to token,
-its router's logits a bfloat16 product, its attention layers' keys and
-values in fp8): the control's log-probabilities
+stated one, or wrongly (``build(..., lower=(name,))``, where the
+reference module has ``CONTROLS``: Granite 4.0-H's state in bfloat16 from
+token to token, its router's logits a bfloat16 product, its attention
+layers' keys and values in fp8; Kimi Linear's ``state``,
+``scalar_decay``, ``rope_on_mla`` and ``beta_one``, the four faults above
+made in the reference): the control's log-probabilities
 stand in the served program's place in ``check_probes``, each probe on
 its own and all together as the harness compares them. Nothing is
 decoded for it, so a control costs a reference pass a probe. The exit
@@ -68,7 +75,8 @@ sys.path[:0] = [BENCH, ROOT]
 
 PROBE_TOKENS = 16
 FAULTS = ("bf16_state", "half_topk", "bf16_router", "steps_4", "block_8",
-          "gate_over_all", "sqrt_scale")
+          "gate_over_all", "sqrt_scale", "scalar_decay", "rope_on_mla",
+          "beta_one")
 
 
 def serve_wrongly(fault: str, model_dir: str) -> None:
@@ -121,6 +129,27 @@ def serve_wrongly(fault: str, model_dir: str) -> None:
             return route(logits.astype(jnp.float32), eye, *args, **kwargs)
 
         mixtral.route_top_k = route_top_k
+    elif fault in ("scalar_decay", "beta_one"):
+        import jax.numpy as jnp
+
+        from dynamo_tpu.models import kimi_linear
+
+        def wrongly(fn):
+            def wrong(q, k, v, g, beta, *rest):
+                if fault == "scalar_decay":   # the channels' mean a head
+                    g = jnp.broadcast_to(jnp.mean(g, -1, keepdims=True), g.shape)
+                else:                         # 0 stays 0: no token there
+                    beta = jnp.where(beta > 0, 1.0, 0.0)
+                return fn(q, k, v, g, beta, *rest)
+            return wrong
+
+        for name in ("kda_decode_step", "kda_chunked_scan"):
+            setattr(kimi_linear, name, wrongly(getattr(kimi_linear, name)))
+    elif fault == "rope_on_mla":
+        from dynamo_tpu.models import deepseek, kimi_linear
+
+        kimi_linear.make_mla_attn_fn = lambda *args, **kwargs: (
+            deepseek.make_mla_attn_fn(*args, **{**kwargs, "rope": True}))
     elif fault == "gate_over_all":
         from dynamo_tpu.models import mixtral
 

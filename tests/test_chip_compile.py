@@ -782,6 +782,142 @@ def test_granite_prefill_chunk_fits_beside_the_model(
     assert mem.temp_size_in_bytes < 2.5 * 2 ** 30
 
 
+# Kimi-Linear-48B-A3B as one chip serves it (benchmark/configs/
+# kimi-linear-48b-a3b-ep16.json): all 27 layers, twenty of them Kimi
+# Delta Attention (32 heads of 128, a float32 state of 128 x 128 a head:
+# a row's 2 MiB one block of the state kernel), seven latent attention
+# over their own stack of pages, 16 of 256 experts of 1024 held behind
+# 26 of them, a contraction of 2304
+def test_kda_decode_kernel_compiles_at_kimi_linears_state(
+        one_chip, no_compile_cache, monkeypatch):
+    from dynamo_tpu.ops import kda
+    from dynamo_tpu.ops.live_rows import live_row_list
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, slots, h, k = 20, 64, 32, 128
+    f32, act = jnp.float32, jnp.bfloat16
+
+    def f(q, kk, v, g, beta, records, li, live):
+        return kda.kda_decode_step(q, kk, v, g, beta, records, li,
+                                   live_row_list(live))
+
+    compiled = jax.jit(f, donate_argnums=(5,)).lower(
+        s((slots, h, k), act), s((slots, h, k), act), s((slots, h, k), act),
+        s((slots, h, k), f32), s((slots, h), f32),
+        s((layers, slots, h, k, k), f32), s((), jnp.int32),
+        s((slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_decode_step" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
+@pytest.mark.parametrize("rows", [512, 8192])
+@pytest.mark.parametrize("k,n", [(2304, 1024), (1024, 2304)])
+def test_grouped_products_compile_at_kimi_linears_expert_shapes(
+        one_chip, no_compile_cache, monkeypatch, rows, k, n):
+    """A decode step's 64 x 8 picks and a 1024-token chunk's, over the
+    16 experts held of the 26 expert layers."""
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        s((rows, k), jnp.bfloat16), s((26, 16, k, n), jnp.bfloat16),
+        s((16,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kimi_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import kimi_linear
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "kimi-linear-48b-a3b-ep16.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: kimi_linear.init_params(cfg, jax.random.PRNGKey(0),
+                                        jnp.bfloat16)))
+    cache = jax.tree.map(s, jax.eval_shape(
+        lambda: kimi_linear.init_kv_cache(
+            cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+            num_slots=serve["max_batch_size"])))
+    # latent pages over the seven latent layers, records over the twenty
+    assert cache[0].kv.shape == (7, 8192, 1, 16, 512)
+    assert cache[0].state.shape == (20, 64, 32, 128, 128)
+    assert cache[1].state.shape == (20, 64, 3, 12288)
+    assert params["moe"]["router"].shape == (26, 2304, 256)
+    assert params["moe"]["w_gate"].shape == (26, 16, 2304, 1024)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, tokens, positions, bt, slots, ctx, ss):
+        return kimi_linear.forward_counted(
+            params, cfg, tokens, positions, (k_side, v_side), bt, slots, ctx,
+            state_slots=ss)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, *cache, i32(rows, tokens), i32(rows, tokens), i32(rows, width),
+        i32(rows, tokens), i32(rows), i32(rows)).compile()
+
+
+def test_kimi_linear_decode_step_updates_state_and_pages_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole trunk of a decode step at the benchmark's size: the KDA
+    state kernel, the latent decode kernel and the grouped products are
+    in it, and neither the state (2.68 GB at 64 slots), the latent pages
+    (0.94 GB) nor the expert stacks (5.9 GB) are copied, through the
+    scan over periods and the two loops of traced length inside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _kimi_step(one_chip, 64, 1, 256)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_decode_step" in text
+    for big in ("f32[20,64,32,128,128]", "bf16[7,8192,1,16,512]",
+                "bf16[26,16,2304,1024]"):
+        ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1),
+                ln) for ln in text.splitlines()[1:]
+               if big in ln and " = " in ln]
+        assert ops and {op for op, _ in ops} <= {
+            "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+            "custom-call", "scatter", "fusion"}, (big, {op for op, _ in ops})
+        if big.startswith("f32"):
+            assert {op for op, _ in ops} <= {
+                "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+                "custom-call"}, {op for op, _ in ops}
+    mem = compiled.memory_analysis()
+    print("decode step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 13.0e9    # the head is not the trunk's
+    assert mem.temp_size_in_bytes < 2 ** 28
+
+
+def test_kimi_linear_prefill_chunk_fits_beside_the_model(
+        one_chip, no_compile_cache, monkeypatch):
+    """A 1024-token chunk: the chunked scan's pairwise exponents of a
+    chunk of 64 (32 heads x 4 x 16 x 16 x 128) and the sorted rows of
+    8192 picks are the step's temporaries, and they fit in what the
+    model leaves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _kimi_step(one_chip, 1, 1024, 256)
+    mem = compiled.memory_analysis()
+    print("prefill step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.2 * 2 ** 30
+
+
 def _decode_trunk(ll, topo, config):
     """The decode trunk of a benchmark configuration compiled for the
     described chips (the real tp mesh where ``serve`` asks for one), once

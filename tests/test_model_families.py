@@ -269,7 +269,7 @@ REQUIRED = set(_NAME.findall(_REQUIRED_DOC)) - {"ModelRunner"}
 OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
             for name in _NAME.findall(bullet.split(":")[0])}
 FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
-                "residual_multiplier": 0.22,
+                "residual_multiplier": 0.22, "kda_num_heads": 4,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
@@ -359,6 +359,44 @@ def test_two_families_compute_the_state_space_keys(hf, family, mixer_only):
     assert cfg.mamba_d_ssm == 64 and cfg.mamba_n_heads == 4
     assert bool(cfg.layer_types) is mixer_only
     assert (cfg.residual_multiplier != 1.0) is mixer_only
+
+
+_KIMI = {"model_type": "kimi_linear", "vocab_size": 64, "hidden_size": 32,
+         "intermediate_size": 48, "moe_intermediate_size": 16,
+         "num_hidden_layers": 3, "num_attention_heads": 2,
+         "linear_attn_config": {"kda_layers": [1, 2], "full_attn_layers": [3],
+                                "num_heads": 2, "head_dim": 16,
+                                "short_conv_kernel_size": 4},
+         "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+         "v_head_dim": 16, "mla_use_nope": True, "first_k_dense_replace": 1,
+         "num_experts": 2, "expert_share": {"of_experts": 8, "rank": 3},
+         "num_experts_per_token": 2, "num_shared_experts": 1,
+         "routed_scaling_factor": 2.446}
+
+
+def test_a_latent_config_with_kda_layers_reaches_its_own_row():
+    """``kimi_linear`` has ``kv_lora_rank > 0``, deepseek's shape rule:
+    its row stands first and takes it by name, with ``expert_share``
+    (Granite's claim too) and ``num_shared_experts`` (afmoe's) its own
+    under this ``model_type``; a latent config with a stray
+    ``kda_num_heads`` is refused for deepseek."""
+    cfg = ModelConfig.from_hf_config(_KIMI)
+    assert cfg.model_family == "kimi_linear" and cfg.kv_lora_rank == 16
+    assert models.family(cfg).name == "kimi_linear"
+    assert models.FAMILIES.index(models.family(cfg)) < next(
+        i for i, r in enumerate(models.FAMILIES) if r.name == "deepseek")
+    assert cfg.layer_types == ("kda", "kda", "mla")
+    assert (cfg.num_experts, cfg.experts_of, cfg.expert_rank) == (2, 8, 3)
+    assert cfg.mamba_d_ssm == 0 and cfg.residual_multiplier == 1.0
+    import dataclasses
+    stray = dataclasses.replace(cfg, model_family="")
+    with pytest.raises(NotImplementedError, match="kda_num_heads"):
+        models.resolve(stray)
+    with pytest.raises(NotImplementedError,
+                       match="some_other_trunk.*linear_attn_config") as e:
+        ModelConfig.from_hf_config(
+            {**PLAIN_HF, "linear_attn_config": _KIMI["linear_attn_config"]})
+    assert "kimi_linear" in str(e.value)
 
 
 @pytest.mark.parametrize("keys,named", [
