@@ -2499,5 +2499,12 @@ class ModelRunner:
         chunks = chunks_traced()
         if chunks:
             logger.info("decode kernels' chunks: %s", json.dumps(chunks))
+        # and what the state kernel's body was given at this model's
+        # heads (ops/ssm.ssm_decode_step: heads a tile, tiles a block)
+        from ..ops.ssm import blocks_traced
+
+        blocks = blocks_traced()
+        if blocks:
+            logger.info("state kernel's blocks: %s", json.dumps(blocks))
         logger.info("decode programs' table widths: %s",
                     json.dumps(self.warmed_widths))

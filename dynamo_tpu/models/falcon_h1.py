@@ -18,8 +18,10 @@ With ``n1 = RMSNorm(x)``:
 **State beside the pages.** The attention branch's keys and values are
 paged as in every GQA family. The mixer's state is a second kind of
 per-sequence device state that is not paged: one fixed-size record a
-layer a *slot* (the engine's decode row), ``[H, P, N]`` in float32 (the
-recurrence feeds its own rounding back every token; not an option) plus
+layer a *slot* (the engine's decode row), the heads' ``[P, N]`` in
+float32 (the recurrence feeds its own rounding back every token; not an
+option) laid as the decode kernel walks them, ``[H / k, N, k P]``
+(``ops/ssm.state_to_record``: ``P`` on the lanes), plus
 the conv's last ``d_conv − 1`` inputs. Both ride in the cache pytree the
 programs already carry and donate: each side is a ``SlotCache(kv=pages,
 state=records)`` (the k side holds the SSM state, the v side the conv
@@ -52,11 +54,12 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.live_rows import decode_live_rows
-from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
+from ..ops.ssm import record_shape, ssd_chunked_scan, ssm_decode_step
 from . import SequenceState, llama
 from .llama import (ATTN_LAYER_SPECS, base_specs, lm_logits,
                     make_gqa_attn_fn, rms_norm)
@@ -314,17 +317,23 @@ def param_specs(params: Params) -> Dict:
     return specs
 
 
+def ssm_record_shape(cfg: ModelConfig):
+    """A slot's record of one mixer layer, as the decode kernel walks it."""
+    return record_shape(cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                        cfg.mamba_n_heads // cfg.mamba_n_groups)
+
+
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
                   window_blocks: int = 1):
-    """``(SlotCache(k pages, SSM state [L, slots, H, P, N] float32),
-    SlotCache(v pages, conv window [L, slots, d_conv − 1, conv_dim]))``.
-    The conv window keeps the trunk's dtype whatever the pages' (an fp8
-    page cache does not round the window)."""
+    """``(SlotCache(k pages, SSM state [L, slots, H / k, N, k P] float32:
+    ``ops/ssm.record_shape``), SlotCache(v pages, conv window [L, slots,
+    d_conv − 1, conv_dim]))``. The conv window keeps the trunk's dtype
+    whatever the pages' (an fp8 page cache does not round the window)."""
     k, v = llama.init_kv_cache(cfg, num_blocks, block_size, dtype)
     act = jnp.float32 if dtype == jnp.float32 else jnp.bfloat16
-    ssm = jnp.zeros((cfg.num_layers, num_slots, cfg.mamba_n_heads,
-                     cfg.mamba_d_head, cfg.mamba_d_state), jnp.float32)
+    ssm = jnp.zeros((cfg.num_layers, num_slots) + ssm_record_shape(cfg),
+                    jnp.float32)
     conv = jnp.zeros((cfg.num_layers, num_slots, cfg.mamba_d_conv - 1,
                       conv_dim(cfg)), act)
     return SlotCache(k, ssm), SlotCache(v, conv)
@@ -347,6 +356,17 @@ def _grouped_rms_norm(y, weight, groups: int, eps: float):
 def _gated_norm(y, z, weight, groups: int, eps: float):
     """``mamba_norm_before_gate: false``: the gate, then the norm."""
     return _grouped_rms_norm(y * jax.nn.silu(z), weight, groups, eps)
+
+
+def _row_major(records: jax.Array) -> jax.Array:
+    """The mixer's records held in their dimensions' own order through a
+    prefill step. The chunked scan makes a row's new state by products
+    whose results it transposes; left free, XLA's layout assignment
+    makes that free at Granite's state (two heads side by side) by
+    keeping *all the records* the other way round through the layer
+    loop, and copies them on the way in and out: 2.4 GB a step."""
+    return with_layout_constraint(
+        records, Layout(major_to_minor=tuple(range(records.ndim))))
 
 
 def slot_records(b: int, decode: bool, live, state_slots, fresh):
@@ -438,10 +458,11 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
                 y = y[:, None]
         else:
             with jax.named_scope("ssm_scan"):
+                # the scan carries the state in the records' order
                 y, h1 = ssd_chunked_scan(
-                    xs, dt, a, bm, cm, lp["D"], read(ssm_all, li),
-                    cfg.mamba_chunk_size)
-                ssm_all = write(ssm_all, li, h1)
+                    xs, dt, a, bm, cm, lp["D"],
+                    read(_row_major(ssm_all), li), cfg.mamba_chunk_size)
+                ssm_all = _row_major(write(ssm_all, li, h1))
         y = y.reshape(b, s, d_ssm).astype(x.dtype)
         y = _gated_norm(y, z, lp["ssm_norm"], g, cfg.rms_norm_eps)
         return (_scaled(dense(y, lp["ssm_out"]), cfg.ssm_out_multiplier),

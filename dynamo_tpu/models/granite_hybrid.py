@@ -34,8 +34,9 @@ layer: what the absent rank would have added is computed nowhere.
 pages and no state, the mixer layers state and no pages, so a side of
 the cache is Falcon-H1's ``SlotCache`` stacked over each kind's own
 layers: the k side ``(key pages [A, N, block, KVH, D], SSM state [M,
-slots, H, P, N] float32)``, the v side ``(value pages, conv window [M,
-slots, d_conv − 1, C])``. The trunk scans each homogeneous run of
+slots, H / 2, N, 2 P] float32)`` (two heads of 64 side by side on the
+lanes: ``ops/ssm.state_to_record``), the v side ``(value pages, conv
+window [M, slots, d_conv − 1, C])``. The trunk scans each homogeneous run of
 ``layer_types`` over that run's stacked weights (``params["runs"]``),
 the expert stacks kept whole and indexed by layer inside the kernel.
 The family keeps recurrent state, so it inherits Falcon-H1's
@@ -60,7 +61,8 @@ from ..ops.attention import lane_pad
 from ..ops.live_rows import decode_live_rows
 from . import falcon_h1
 from .deepseek import random_expert_stacks
-from .falcon_h1 import SlotCache, _scaled, conv_dim, make_ssm_fn
+from .falcon_h1 import (SlotCache, _scaled, conv_dim, make_ssm_fn,
+                        ssm_record_shape)
 from .llama import (layer_runs, lm_logits, make_gqa_attn_fn, rms_norm,
                     run_specs)
 from .mixtral import make_moe_mlp_fn, split_expert_stacks
@@ -335,8 +337,9 @@ CACHE_SPEC = SlotCache(kv=P(), state=P())
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
                   window_blocks: int = 1):
-    """``(SlotCache(k pages [A, ...], SSM state [M, slots, H, P, N]
-    float32), SlotCache(v pages, conv window [M, slots, d_conv − 1,
+    """``(SlotCache(k pages [A, ...], SSM state [M, slots, H / 2, N,
+    2 P] float32: Falcon-H1's ``ssm_record_shape``, two heads of 64 side
+    by side), SlotCache(v pages, conv window [M, slots, d_conv − 1,
     C]))``: ``A`` attention layers, ``M`` mixer layers. The conv window
     keeps the trunk's dtype whatever the pages' (Falcon-H1's)."""
     n_attn = cfg.layer_types.count(ATTENTION)
@@ -344,8 +347,7 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     pages = (n_attn, num_blocks, block_size, cfg.num_kv_heads,
              lane_pad(cfg.head_dim))
     act = jnp.float32 if dtype == jnp.float32 else jnp.bfloat16
-    ssm = jnp.zeros((n_mamba, num_slots, cfg.mamba_n_heads, cfg.mamba_d_head,
-                     cfg.mamba_d_state), jnp.float32)
+    ssm = jnp.zeros((n_mamba, num_slots) + ssm_record_shape(cfg), jnp.float32)
     conv = jnp.zeros((n_mamba, num_slots, cfg.mamba_d_conv - 1,
                       conv_dim(cfg)), act)
     return (SlotCache(jnp.zeros(pages, dtype), ssm),

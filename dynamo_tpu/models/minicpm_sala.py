@@ -34,7 +34,9 @@ lightning layers state and no pages, so each is stacked over its own
 layers only: a side of the cache is Falcon-H1's ``SlotCache(kv, state)``
 with the step's counters beside it, the k side with the key pages ``[A,
 N·KVH, page, D]`` (a kv head a page: ops/sparse_attention.py) and the
-lightning state ``[L, slots, H, d, d]`` float32, the v side with the
+lightning state ``[L, slots, H, d, d]`` float32 (a head's ``[key,
+value]``: the value's dimension on the lanes, the order
+``ops/ssm.ssm_decode_step`` walks), the v side with the
 value pages and, as its ``state``, the compressed keys: one float32 mean
 a page ``[A, N·KVH, D]``. The trunk scans each homogeneous run of
 ``mixer_types`` over that run's stacked weights (``params["runs"]``).
@@ -62,7 +64,7 @@ from ..engine.config import ModelConfig
 from ..ops import sparse_attention as sparse
 from ..ops.attention import lane_pad
 from ..ops.live_rows import decode_live_rows
-from ..ops.ssm import ssd_chunked_scan, ssm_decode_step
+from ..ops.ssm import record_shape, ssd_chunked_scan, ssm_decode_step
 from .falcon_h1 import (CLAIM, SEQUENCE_STATE, SlotCache,  # noqa: F401
                         _scaled, slot_records)
 from .llama import (_swiglu_mlp, apply_rope, layer_runs, lm_logits,
@@ -275,9 +277,11 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     n_attn, n_light = kinds.count(SPARSE), kinds.count(LIGHTNING)
     kvh = cfg.num_kv_heads
     pages = (n_attn, num_blocks * kvh, block_size, lane_pad(cfg.head_dim))
-    state = jnp.zeros((n_light, num_slots, cfg.lightning_heads,
-                       cfg.lightning_head_dim, cfg.lightning_head_dim),
-                      jnp.float32)
+    # a record as the state kernel walks it: [H, d (key), d (value)], the
+    # value's dimension on the lanes (ops/ssm.record_shape)
+    state = jnp.zeros((n_light, num_slots) + record_shape(
+        cfg.lightning_heads, cfg.lightning_head_dim, cfg.lightning_head_dim,
+        1), jnp.float32)
     means = jnp.zeros((n_attn, num_blocks * kvh, pages[-1]), jnp.float32)
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     return (SalaCache(jnp.zeros(pages, dtype), state, counts),

@@ -151,8 +151,9 @@ def test_decode_and_flash_kernels_compile_at_falcon_h1s_heads(
 
 
 # Falcon-H1-34B's mixer state as one chip serves it: 6 layers x 64 slots
-# of [32, 128, 256], a group's 16 heads a block; float32 as the
-# configuration keeps it, and bfloat16 as a records buffer may come
+# of 32 tiles [256, 128] (a head a tile, P on the lanes), a group's 16
+# heads a block; float32 as the configuration keeps it, and bfloat16 as
+# a records buffer may come
 @pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
 def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
         one_chip, no_compile_cache, monkeypatch, state_dtype):
@@ -166,6 +167,8 @@ def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
 
     layers, slots, h, p, n, g = 6, 64, 32, 128, 256, 2
     f32, act = jnp.float32, jnp.bfloat16
+    record = ssm.record_shape(h, p, n, h // g)
+    assert record == (32, 256, 128) and ssm.lane_heads(p, h // g) == 1
 
     def f(x, dt, a, bm, cm, d, records, li, live):
         return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li,
@@ -174,9 +177,17 @@ def test_ssm_decode_kernel_compiles_at_falcon_h1s_state(
     compiled = jax.jit(f, donate_argnums=(6,)).lower(
         s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
         s((slots, g, n), act), s((slots, g, n), act), s((h,), f32),
-        s((layers, slots, h, p, n), jnp.dtype(state_dtype)), s((), jnp.int32),
+        s((layers, slots) + record, jnp.dtype(state_dtype)), s((), jnp.int32),
         s((slots,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # a block is a group's 16 tiles of float32 (2 MiB), one group of B
+    # and C; of bfloat16 the row's 32 tiles, both groups
+    itemsize = jnp.dtype(state_dtype).itemsize
+    traced, = [t for t in ssm.blocks_traced() if {
+        "heads": h, "p": p, "n": n, "itemsize": itemsize}.items() <= t.items()]
+    assert (traced["tiles_per_block"], traced["groups_per_block"]) == \
+        ((16, 1) if itemsize == 4 else (32, 2))
+    assert traced["block_bytes"] == 2 << 20
     # the records are the kernel's output where they lay: no second buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
@@ -209,7 +220,7 @@ def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
     k_side, v_side = jax.tree.map(s, jax.eval_shape(
         lambda: falcon_h1.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
                                         jnp.bfloat16, num_slots=b)))
-    assert k_side.state.shape == (6, 64, 32, 128, 256)
+    assert k_side.state.shape == (6, 64, 32, 256, 128)
     assert k_side.state.dtype == jnp.float32
 
     def i32(*shape):
@@ -228,7 +239,7 @@ def test_falcon_h1_decode_step_updates_state_and_cache_in_place(
     # every operation that takes or makes the state: the loop over the
     # layers and what hands the buffer on, and the kernel; never a copy,
     # a fusion, a dynamic-update-slice or a scatter of it
-    state = "f32[6,64,32,128,256]"
+    state = "f32[6,64,32,256,128]"
     ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1), ln)
            for ln in text.splitlines()[1:] if state in ln and " = " in ln]
     assert {op for op, _ in ops} <= {
@@ -363,9 +374,10 @@ def test_xing4_decode_step_mixes_its_streams_in_a_few_operations(
 
 
 # MiniCPM-SALA as one chip serves it (benchmark/configs/
-# minicpm-sala-9b.json): the state kernel at a group a head (32 heads of
-# [128, 128] float32, a whole row a block), and the trunk's decode and
-# prefill steps at a table 1152 pages wide
+# minicpm-sala-9b.json): the state kernel at a group a head (32 tiles of
+# [128, 128] float32, a whole row a block: the body walks 32 groups of
+# one tile), and the trunk's decode and prefill steps at a table 1152
+# pages wide
 def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
         one_chip, no_compile_cache, monkeypatch):
     from dynamo_tpu.ops import ssm
@@ -378,7 +390,8 @@ def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
 
     layers, slots, h, d = 9, 24, 32, 128
     f32, act = jnp.float32, jnp.bfloat16
-    assert ssm._head_block(h, 1, d * d * 4) == 32
+    assert ssm.record_shape(h, d, d, 1) == (h, d, d)
+    assert ssm._tile_block(h, 1, d * d * 4) == 32
 
     def f(x, dt, a, bm, cm, skip, records, li, live):
         return ssm.ssm_decode_step(x, dt, a, bm, cm, skip, records, li,
@@ -391,6 +404,12 @@ def test_ssm_decode_kernel_compiles_at_lightning_attentions_state(
         s((slots,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    traced, = [t for t in ssm.blocks_traced() if {
+        "heads": h, "p": d, "n": d, "heads_per_group": 1}.items() <= t.items()]
+    assert traced == {
+        "heads": h, "p": d, "n": d, "heads_per_group": 1, "itemsize": 4,
+        "lane_heads": 1, "tiles_per_block": 32, "groups_per_block": 32,
+        "groups_per_turn": 8, "block_bytes": 2 << 20}
 
 
 def _sala_step(one_chip, rows, tokens, width):
@@ -646,7 +665,8 @@ _DECODE_TRUNKS = {}
 # granite-4.0-h-small as one chip serves it (benchmark/configs/
 # granite-4.0-h-small-ep2.json): ten layers, nine of them a mixer of 128
 # heads of 64 over a state of 128 with one group of B and C for all the
-# heads (64 heads a block of the state kernel, half a row's state), one
+# heads (two heads side by side on a tile's lanes, 32 tiles = 64 heads a
+# block of the state kernel, half a row's state), one
 # of them NoPE attention over its own stack of pages, 36 of 72 experts
 # of 768 held behind each, a contraction of 4096
 def test_ssm_decode_kernel_compiles_at_granites_state(
@@ -655,17 +675,21 @@ def test_ssm_decode_kernel_compiles_at_granites_state(
     from dynamo_tpu.ops.live_rows import live_row_list
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # a block is 64 of the group's 128 heads: 2 MiB of float32 state
-    assert ssm._head_block(128, 128, 64 * 128 * 4) == 64
+    # a tile is two heads of 64 side by side; a block is 32 of the
+    # group's 64 tiles: 64 heads, 2 MiB of float32 state
+    assert ssm.lane_heads(64, 128) == 2
+    assert ssm._tile_block(64, 64, 128 * 128 * 4) == 32
     # Falcon-H1's and lightning attention's keep theirs
-    assert ssm._head_block(32, 16, 128 * 256 * 4) == 16
-    assert ssm._head_block(32, 1, 128 * 128 * 4) == 32
+    assert ssm._tile_block(32, 16, 256 * 128 * 4) == 16
+    assert ssm._tile_block(32, 1, 128 * 128 * 4) == 32
 
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     layers, slots, h, p, n, g = 9, 64, 128, 64, 128, 1
     f32, act = jnp.float32, jnp.bfloat16
+    record = ssm.record_shape(h, p, n, h // g)
+    assert record == (64, 128, 128)
 
     def f(x, dt, a, bm, cm, d, records, li, live):
         return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li,
@@ -674,10 +698,16 @@ def test_ssm_decode_kernel_compiles_at_granites_state(
     compiled = jax.jit(f, donate_argnums=(6,)).lower(
         s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
         s((slots, g, n), act), s((slots, g, n), act), s((h,), f32),
-        s((layers, slots, h, p, n), f32), s((), jnp.int32),
+        s((layers, slots) + record, f32), s((), jnp.int32),
         s((slots,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    traced, = [t for t in ssm.blocks_traced() if {
+        "heads": h, "p": p, "n": n, "itemsize": 4}.items() <= t.items()]
+    assert traced == {
+        "heads": h, "p": p, "n": n, "heads_per_group": 128, "itemsize": 4,
+        "lane_heads": 2, "tiles_per_block": 32, "groups_per_block": 1,
+        "groups_per_turn": 1, "block_bytes": 2 << 20}
 
 
 @pytest.mark.parametrize("rows", [640, 20480])
@@ -729,7 +759,7 @@ def _granite_step(one_chip, rows, tokens, width):
             num_slots=serve["max_batch_size"])))
     # pages over the attention layer alone, records over the nine mixers
     assert cache[0].kv.shape == (1, 3072, 16, 8, 128)
-    assert cache[0].state.shape == (9, 64, 128, 64, 128)
+    assert cache[0].state.shape == (9, 64, 64, 128, 128)
     assert params["runs"][0]["router"].shape == (5, 4096, 72)
     assert params["runs"][0]["w_gate"].shape == (5, 36, 4096, 768)
 
@@ -756,7 +786,7 @@ def test_granite_decode_step_updates_state_and_pages_in_place(
     compiled = _granite_step(one_chip, 64, 1, 256)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_decode_step" in text
-    state = "f32[9,64,128,64,128]"
+    state = "f32[9,64,64,128,128]"
     ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1), ln)
            for ln in text.splitlines()[1:] if state in ln and " = " in ln]
     assert {op for op, _ in ops} <= {

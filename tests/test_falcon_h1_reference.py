@@ -306,7 +306,11 @@ def test_chunked_scan_is_the_recurrence(seed, s, chunk, g):
     cm = rs.randn(b, s, g, n).astype(np.float32)
     d = rs.randn(h).astype(np.float32)
     h0 = rs.randn(b, h, p, n).astype(np.float32)
-    y, h1 = ssm.ssd_chunked_scan(*map(jnp.asarray, (x, dt, a, bm, cm, d, h0)), chunk)
+    # the scan takes and returns records (``state_to_record``)
+    y, h1 = ssm.ssd_chunked_scan(
+        *map(jnp.asarray, (x, dt, a, bm, cm, d)),
+        ssm.state_to_record(jnp.asarray(h0), h // g), chunk)
+    h1 = ssm.record_to_state(h1, p)
     state, ys = jnp.asarray(h0), []
     for t in range(s):
         y_t, state = ssm.ssm_decode_update(

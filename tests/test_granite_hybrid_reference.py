@@ -355,7 +355,9 @@ def test_the_shares_add_up_through_ep_axis_on_virtual_devices():
 def test_mixer_kernel_at_many_heads_a_group():
     """``ssm_decode_step`` with one group of B and C for all the heads
     (H / G = 8 here, 128 at the published size) against the plain
-    ``ssm_decode_update``, idle rows untouched."""
+    ``ssm_decode_update``, idle rows untouched. The records in the
+    kernel's order: the eight heads of 16 side by side on a tile's 128
+    lanes."""
     rs = np.random.RandomState(0)
     b, heads, p, n, layers = 4, 8, 16, 16, 3
     x = jnp.asarray(rs.randn(b, heads, p), jnp.float32)
@@ -367,8 +369,11 @@ def test_mixer_kernel_at_many_heads_a_group():
     d = jnp.ones((heads,), jnp.float32)
     records = jnp.asarray(rs.randn(layers, b, heads, p, n), jnp.float32)
     slot = jnp.asarray([[0], [1], [-1], [3]], jnp.int32)
-    y, out = ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, jnp.int32(1),
+    as_laid = ssm.state_to_record(records, heads)
+    assert as_laid.shape == (layers, b, 1, n, heads * p)
+    y, out = ssm.ssm_decode_step(x, dt, a, bm, cm, d, as_laid, jnp.int32(1),
                                  decode_live_rows(slot))
+    out = ssm.record_to_state(out, p)
     want_y, want_h = ssm.ssm_decode_update(x, dt, a, bm, cm, d, records[1])
     live = np.asarray([0, 1, 3])
     np.testing.assert_allclose(np.asarray(y)[live], np.asarray(want_y)[live],
@@ -392,7 +397,10 @@ def test_chunked_scan_at_one_group_equals_the_recurrence():
     cm = jnp.asarray(rs.randn(b, s, 1, n), jnp.float32)
     d = jnp.ones((heads,), jnp.float32)
     h0 = jnp.asarray(rs.randn(b, heads, p, n), jnp.float32)
-    y, h1 = ssm.ssd_chunked_scan(x, dt, a, bm, cm, d, h0, 32)
+    # the scan takes and returns records (``state_to_record``)
+    y, h1 = ssm.ssd_chunked_scan(x, dt, a, bm, cm, d,
+                                 ssm.state_to_record(h0, heads), 32)
+    h1 = ssm.record_to_state(h1, p)
     h, ys = h0, []
     for t in range(s):
         yt, h = ssm.ssm_decode_update(x[:, t], dt[:, t], a, bm[:, t], cm[:, t],

@@ -201,17 +201,31 @@ def test_a_rows_logits_do_not_depend_on_the_tables_width(monkeypatch):
         np.testing.assert_allclose(logits(rung), at_full, atol=2e-5, rtol=0)
 
 
-def test_block_selection_reads_the_width_and_keeps_its_ladder(monkeypatch):
+def test_block_selection_reads_the_width_and_keeps_its_ladder(monkeypatch,
+                                                              caplog):
     """(e) MiniCPM-SALA under the kernel route: the pages are walked by
     the decode kernel, but where a table can hold a row past
     ``dense_len`` the program scores and sorts the table's width of
     page means, and says so; every rung is warmed, and a rung no wider
-    than ``dense_len`` still has no selection in it."""
+    than ``dense_len`` still has no selection in it. Warm-up also says
+    which parameters served the lightning layers' state kernel, as it
+    says the page walks' chunks."""
     monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     hf = {**SALA_HF, "sparse_config": {**SPARSE, "dense_len": 8 * PAGE}}
     runner = _runner(hf, "pallas", max_model_len=32 * PAGE, num_kv_blocks=80,
                      max_batch_size=2)
-    runner.warmup()
+    with caplog.at_level("INFO", logger="dynamo_tpu.engine.model_runner"):
+        runner.warmup()
+    (line,) = [r.getMessage() for r in caplog.records
+               if r.getMessage().startswith("state kernel's blocks: ")]
+    # four heads of 16 x 16, a group a head: a head a tile, the row's four
+    # tiles one block, its four groups' B and C made columns in one turn
+    nh, hd = hf["lightning_nh"], hf["lightning_head_dim"]
+    assert {"heads": nh, "p": hd, "n": hd, "heads_per_group": 1,
+            "itemsize": 4, "lane_heads": 1, "tiles_per_block": 4,
+            "groups_per_block": 4, "groups_per_turn": 4,
+            "block_bytes": nh * hd * hd * 4} \
+        in json.loads(line.split(": ", 1)[1])
     assert runner.config.kv_width_buckets() == [8, 16, 32]
     assert _decode_keys(runner, "decode") == [32, 8, 16]
     assert runner.warmed_widths == {
