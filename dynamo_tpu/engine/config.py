@@ -181,6 +181,30 @@ class ModelConfig:
     kda_num_heads: int = 0
     kda_head_dim: int = 0
     kda_conv_kernel: int = 4
+    # dots3_note (models/dots3.py, model_type "dots3_note"): layer_types
+    # names each layer's attention, both kinds latent. A
+    # "full_attention" layer (the fields every latent family reads:
+    # num_heads, kv_lora_rank, ..., rope_theta) scores every key with a
+    # learned indexer of index_n_heads heads of index_head_dim over a
+    # cache of one such key a token and attends to the index_topk best
+    # alone; a "sliding_attention" layer has a head count, ranks, head
+    # sizes and a rope base of its own (swa_*) and sees the last
+    # sliding_window keys, its pages given back behind the window.
+    # mla_lora_rescale: the constants (hidden / rank)^1/2 after the two
+    # latent norms; attention_gate "headwise": a sigmoid of one logit a
+    # head on the attention output, before W_o.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    mla_lora_rescale: bool = False
+    attention_gate: str = ""
 
     def __post_init__(self):
         if self.head_dim is None:

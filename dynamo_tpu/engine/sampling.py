@@ -189,6 +189,20 @@ def _each(reduce, thresholds: jax.Array) -> jax.Array:
         [reduce(t) for t in jnp.moveaxis(thresholds, -1, 0)], -1)
 
 
+def kth_largest(values: jax.Array, k) -> jax.Array:
+    """The ``k``-th largest entry of each row of ``values`` [..., N]
+    float32 (``k`` an int or [...] int32, at least 1), by the cutoff
+    search and no sort; -inf where a row has fewer than ``k`` entries
+    over -inf (ops/latent_select.py picks an indexer's keys with it)."""
+    k = jnp.asarray(k, jnp.int32)
+
+    def count(t):
+        return (values >= t[..., None]).sum(-1, dtype=jnp.int32)
+
+    return _largest_threshold(
+        lambda ts: _each(count, ts) >= k[..., None], values.shape[:-1])
+
+
 def filter_logits(
     scaled: jax.Array,  # [..., V] f32 temperature-scaled logits
     top_k: jax.Array,   # [...] i32; 0 → disabled

@@ -149,6 +149,13 @@ FAMILIES = (
                     "Attention layers and their state by slot; model_family "
                     "{family!r} has none (models/kimi_linear.py is selected "
                     "by model_type kimi_linear)"),
+    # before deepseek too: both kinds of its layers are latent
+    Family("dots3", model_types=("dots3_note",),
+           field="index_topk", reads=("layer_types",),
+           unserved="index_topk={value} needs a family whose full layers "
+                    "pick their keys with a learned indexer over a cache of "
+                    "its own; model_family {family!r} attends to every key "
+                    "(models/dots3.py is selected by model_type dots3_note)"),
     Family("deepseek", model_types=("xing4_0",),
            shape=lambda cfg: cfg.kv_lora_rank > 0, staged=True,
            field="hc_mult",

@@ -115,13 +115,13 @@ def init_kv_cache(
     return c, kr
 
 
-def scatter_latent_stacked(c_all, kr_all, new_c, new_kr, slot_mapping, li):
-    """Write the new tokens' latent [B,S,r] and rope key [B,S,rd] into
-    layer ``li`` of the stacked caches, in place: slot ``block*bs + off``
-    of layer li is row ``li*N*bs + slot`` of the flat [L*N*bs, r] view
-    (ops/attention.scatter_kv_stacked's contract for this layout; -1 and
-    out-of-layer slots drop)."""
-    l, n_blocks, _, block_size, _ = c_all.shape
+def scatter_rows_stacked(caches, news, slot_mapping, li):
+    """Write the new tokens' rows (``news``: [B,S,d] each) into layer
+    ``li`` of the stacked caches ([L,N,1,bs,d'] each, one geometry), in
+    place: slot ``block*bs + off`` of layer li is row ``li*N*bs + slot``
+    of the flat [L*N*bs, d'] view (ops/attention.scatter_kv_stacked's
+    contract for this layout; -1 and out-of-layer slots drop)."""
+    l, n_blocks, _, block_size, _ = caches[0].shape
     per_layer = n_blocks * block_size
     idx = slot_mapping.reshape(-1)
     flat_idx = jnp.where(
@@ -134,7 +134,14 @@ def scatter_latent_stacked(c_all, kr_all, new_c, new_kr, slot_mapping, li):
         flat = cache.reshape(l * per_layer, d)
         return flat.at[flat_idx].set(new, mode="drop").reshape(cache.shape)
 
-    return put(c_all, new_c), put(kr_all, new_kr)
+    return tuple(put(cache, new) for cache, new in zip(caches, news))
+
+
+def scatter_latent_stacked(c_all, kr_all, new_c, new_kr, slot_mapping, li):
+    """The new tokens' latent [B,S,r] and rope key [B,S,rd] into layer
+    ``li`` of the two stacked caches (``scatter_rows_stacked``)."""
+    return scatter_rows_stacked((c_all, kr_all), (new_c, new_kr),
+                                slot_mapping, li)
 
 
 def _split_layer_counts(cfg: ModelConfig) -> Tuple[int, int]:
@@ -304,6 +311,7 @@ def mla_paged_attention(
     q_positions: jax.Array,   # [B, S]
     context_lens: jax.Array,  # [B]
     scale: float,
+    sliding_window=None,      # a query sees the last so many keys alone
 ) -> jax.Array:
     """Attention over the compressed cache; returns latent output [B,S,H,r]."""
     b, s, h, r = q_lat.shape
@@ -323,6 +331,8 @@ def mla_paged_attention(
     mask = (key_pos <= q_positions[:, :, None]) & (
         key_pos < context_lens[:, None, None]
     )
+    if sliding_window is not None:
+        mask = mask & (key_pos > q_positions[:, :, None] - sliding_window)
     scores = jnp.where(mask[:, :, None, :], scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q_lat.dtype)
     return jnp.einsum("bsht,btr->bshr", probs, c)
@@ -332,6 +342,7 @@ def mla_paged_attention(
 def mla_attention(
     q_lat, q_rope, c_all, kr_all, li, block_tables, positions, context_lens,
     scale, impl="auto", mesh=None, interpret=False, live_rows=None,
+    sliding_window=None,
 ):
     """MLA attention dispatch over the stacked compressed caches (the
     scope ``mla_cache``: the cache read, scores, softmax and PV, apart
@@ -348,7 +359,9 @@ def mla_attention(
     mesh; the latent caches are replicated (no head dim). ``live_rows``
     (ops/live_rows.decode_live_rows, made once a step): the kernel walks
     those rows alone and returns zeros in the others; the dense
-    formulation ignores it.
+    formulation ignores it. ``sliding_window`` (models/dots3.py's window
+    layers): a query sees its last so many keys alone; the kernel starts
+    its walk at the window's first page.
     """
     kernel = (q_lat.shape[1] == 1
               and resolve_attention_impl(impl) == "pallas")
@@ -371,6 +384,7 @@ def mla_attention(
             return mla_paged_decode_attention(
                 ql, qr, c, kr, bt, ctx, layer_idx=li, scale=scale,
                 interpret=interpret, live_rows=live_rows,
+                sliding_window=sliding_window,
             )
 
         li_arr = jnp.asarray(li, jnp.int32)
@@ -402,7 +416,7 @@ def mla_attention(
     li_arr = jnp.asarray(li, jnp.int32)
     return mla_paged_attention(
         q_lat, q_rope, c_flat, kr_flat, block_tables + li_arr * n_blocks,
-        positions, context_lens, scale,
+        positions, context_lens, scale, sliding_window,
     )[..., :r]
 
 
@@ -430,6 +444,55 @@ def mla_softmax_scale(cfg) -> float:
     return scale
 
 
+def mla_project(cfg, x, lp, b, s, positions, rope: bool = True,
+                q_scale: float = 1.0, kv_scale: float = 1.0,
+                hold_heads: bool = False):
+    """The latent projections of one layer's new tokens -> (the query's
+    latent [B,S,qr] after its norm, None without ``w_uq``; q_nope
+    [B,S,H,nope]; q_rope [B,S,H,rd]; the key-value latent [B,S,r] after
+    its norm; the shared rope key [B,S,rd]), the two rope parts rotated
+    unless ``rope`` is false. ``q_scale`` / ``kv_scale``: constants
+    after the two latent norms (models/dots3.py,
+    ``apply_mla_qkv_lora_rescale``); 1.0 multiplies nothing.
+    ``hold_heads``: the query's product ends as [B, S, H (nope + rope)]
+    behind an ``optimization_barrier`` before the compiler sees a head
+    axis, as ``llama.qkv_prologue`` holds its three: folded into the
+    dot, the reshape makes the layer loop copy its slice of ``w_uq`` out
+    of the stack and transpose it (50 MB a full layer of
+    models/dots3.py, 201 MB for its six window layers at once).
+
+    quant.dense serves these int8 under --quantization (w_kr and the
+    absorbed w_uk/w_uv stay full precision, see quant.py keys)."""
+    h = cfg.num_heads
+    nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    cq = None
+    # queries (optionally through the q low-rank bottleneck)
+    if "w_uq" in lp:
+        cq = rms_norm(dense(x, lp["w_dq"]), lp["ln_q"], cfg.rms_norm_eps)
+        if q_scale != 1.0:
+            cq = (cq.astype(jnp.float32) * q_scale).astype(cq.dtype)
+        qfull = dense(cq, lp["w_uq"])
+    else:
+        qfull = dense(x, lp["wq"])
+    if hold_heads:
+        qfull = jax.lax.optimization_barrier(qfull)
+    qfull = qfull.reshape(b, s, h, nope + rope_d)
+    q_nope, q_rope = qfull[..., :nope], qfull[..., nope:]
+    if rope:
+        q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                            cfg.rope_scaling)
+
+    # compressed KV state for the new tokens
+    c_kv = rms_norm(dense(x, lp["w_dkv"]), lp["ln_kv"], cfg.rms_norm_eps)
+    if kv_scale != 1.0:
+        c_kv = (c_kv.astype(jnp.float32) * kv_scale).astype(c_kv.dtype)
+    kr = x @ lp["w_kr"]  # [B, S, rd]
+    if rope:
+        kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
+    return cq, q_nope, q_rope, c_kv, kr
+
+
 def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
                      context_lens, mesh=None, kv_gather_axis=None,
                      rope: bool = True):
@@ -445,33 +508,14 @@ def make_mla_attn_fn(cfg, b, s, positions, slot_mapping, block_tables,
     member's cache writes — the new latent/rope-key rows and their slots
     are all-gathered over the axis before the scatter (exactly
     llama.make_gqa_attn_fn's contract)."""
-    h = cfg.num_heads
-    nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     scale = mla_softmax_scale(cfg)
     # a decode step's rows that hold a token: the same for every layer,
     # made once, outside the scan
     live_rows = decode_live_rows(slot_mapping)
 
     def attn_fn(x, lp, c_all, kr_all, li):
-        # queries (optionally through the q low-rank bottleneck);
-        # quant.dense serves these int8 under --quantization (w_kr and
-        # the absorbed w_uk/w_uv stay full precision, see quant.py keys)
-        if "w_uq" in lp:
-            cq = rms_norm(dense(x, lp["w_dq"]), lp["ln_q"], cfg.rms_norm_eps)
-            qfull = dense(cq, lp["w_uq"]).reshape(b, s, h, nope + rope_d)
-        else:
-            qfull = dense(x, lp["wq"]).reshape(b, s, h, nope + rope_d)
-        q_nope, q_rope = qfull[..., :nope], qfull[..., nope:]
-        if rope:
-            q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
-                                cfg.rope_scaling)
-
-        # compressed KV state for the new tokens
-        c_kv = rms_norm(dense(x, lp["w_dkv"]), lp["ln_kv"], cfg.rms_norm_eps)
-        kr = x @ lp["w_kr"]  # [B, S, rd]
-        if rope:
-            kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta,
-                            cfg.rope_scaling)[:, :, 0]
+        _, q_nope, q_rope, c_kv, kr = mla_project(
+            cfg, x, lp, b, s, positions, rope=rope)
 
         # in-place scatter into the stacked caches
         c_w, kr_w, slots_w = c_kv, kr, slot_mapping

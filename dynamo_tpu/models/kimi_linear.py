@@ -200,23 +200,25 @@ GATE_STD = 0.5
 GATE_RMS = 0.54
 
 
-def _layout(cfg: ModelConfig):
+def _layout(cfg: ModelConfig, pair=(KDA, MLA)):
     """(the dense prefix's layers [(kind, index among its kind, index
     among the dense)], the periods after it as four int32 vectors: the
     first KDA layer's index among the KDA layers and how many follow, the
-    same of the latent layers behind them)."""
+    same of the latent layers behind them). ``pair``: the two kinds a
+    period is made of, in its order (models/dots3.py: a full layer, then
+    the window layers behind it)."""
     kinds = cfg.layer_types
     n_dense = min(cfg.first_k_dense_replace, len(kinds))
-    base = {KDA: 0, MLA: 0}
+    base = {kind: 0 for kind in pair}
     prefix = []
     for i, kind in enumerate(kinds[:n_dense]):
         prefix.append((kind, base[kind], i))
         base[kind] += 1
     periods = []
     for kind, start, n in layer_runs(kinds[n_dense:]):
-        if kind == KDA or not periods:   # the two kinds' runs alternate
+        if kind == pair[0] or not periods:   # the two kinds' runs alternate
             periods.append([0, 0, 0, 0])
-        at = 0 if kind == KDA else 2
+        at = 0 if kind == pair[0] else 2
         periods[-1][at:at + 2] = [base[kind] + start, n]
     return prefix, [jnp.asarray(c, jnp.int32) for c in zip(*periods)]
 

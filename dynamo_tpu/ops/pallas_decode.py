@@ -394,6 +394,7 @@ def _mla_decode_kernel(
     block_size: int,
     wide_pages: int,
     tail_pages: int,
+    sliding_window: Optional[int] = None,
 ):
     """MLA decode: score = q_lat·c + q_rope·k_rope, output = softmax·c.
 
@@ -405,10 +406,17 @@ def _mla_decode_kernel(
     cache keeps its one head in front of the page
     (models/deepseek.init_kv_cache) — Mosaic cannot slice a page out of
     [page, 1, R], whose single head XLA pads to a sublane pair.
+
+    ``sliding_window`` (a latent window layer, models/dots3.py): the
+    query at ctx - 1 sees positions [ctx - window, ctx) alone, and the
+    walk starts at the first page that holds one, as ``_decode_kernel``'s.
     """
     b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     li = li_ref[0]
+    # first key position the decode query (at ctx-1) can see
+    win_start = (0 if sliding_window is None
+                 else jnp.maximum(ctx - sliding_window, 0))
 
     _, h, r = ql_ref.shape
     rd = qr_ref.shape[-1]
@@ -426,6 +434,8 @@ def _mla_decode_kernel(
             jnp.int32, (1, chunk_t), 1
         )
         valid = key_pos < ctx
+        if sliding_window is not None:
+            valid = valid & (key_pos >= win_start)
 
         s_log = (
             jax.lax.dot_general(
@@ -448,7 +458,7 @@ def _mla_decode_kernel(
         b, bt_ref, sem,
         [(lambda n: c_hbm.at[li, n, 0], c_buf, True),
          (lambda n: kr_hbm.at[li, n, 0], kr_buf, False)],
-        first_page=0, npages=pl.cdiv(ctx, block_size),
+        first_page=win_start // block_size, npages=pl.cdiv(ctx, block_size),
         wide=wide_pages, tail=tail_pages, attend=attend,
         carry=(m0, l0, acc0),
     )
@@ -458,7 +468,8 @@ def _mla_decode_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "pages_per_chunk", "interpret")
+    jax.jit, static_argnames=("scale", "pages_per_chunk", "interpret",
+                              "sliding_window")
 )
 def mla_paged_decode_attention(
     q_lat: jax.Array,        # [B, 1, H, R] latent-absorbed queries
@@ -472,6 +483,7 @@ def mla_paged_decode_attention(
     pages_per_chunk: Optional[int] = None,  # tests pin it; None: chunk_pages
     interpret: bool = False,
     live_rows: Optional[LiveRows] = None,  # the rows that hold a token
+    sliding_window: Optional[int] = None,  # the last so many keys alone
 ) -> jax.Array:
     """DeepSeek MLA single-token attention over the compressed cache.
 
@@ -528,6 +540,7 @@ def mla_paged_decode_attention(
             block_size=block_size,
             wide_pages=wide,
             tail_pages=tail,
+            sliding_window=sliding_window,
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct((b, h, r), q_lat.dtype, q_lat, c_cache),

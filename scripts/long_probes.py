@@ -37,7 +37,11 @@ Linear, ``kimi-linear-reasoning``, PR 52: a KDA layer's decay taken as
 one scalar a head, the channel mean of ``g``; a rotary term on the latent
 layers' 64-wide parts; every token written at full strength; and
 ``bf16_state`` holds its KDA state in bfloat16 as the other families'
-records). Where the reference module has ``limits_for``, a
+records), and for dots3-note (``dots3-longdoc``, PR 54) ``half_topk``
+again (``index_topk`` halved in the served model's ``config.json``
+only: 1024 picked keys against the reference's 2048), ``window_short``
+and ``window_long`` (``sliding_window_size`` 512 or 514 there, against
+the reference's 513). Where the reference module has ``limits_for``, a
 probe is held to the pair it gives for the probe's context; otherwise to
 the module's one pair.
 
@@ -48,7 +52,10 @@ reference module has ``CONTROLS``: Granite 4.0-H's state in bfloat16 from
 token to token, its router's logits a bfloat16 product, its attention
 layers' keys and values in fp8; Kimi Linear's ``state``,
 ``scalar_decay``, ``rope_on_mla`` and ``beta_one``, the four faults above
-made in the reference): the control's log-probabilities
+made in the reference; dots3-note's ``half_topk``, ``no_relu`` (the
+indexer's scores without their ReLU), ``window_short``, ``window_long``
+and ``no_gate`` (the gate a head on the attention output left out)):
+the control's log-probabilities
 stand in the served program's place in ``check_probes``, each probe on
 its own and all together as the harness compares them. Nothing is
 decoded for it, so a control costs a reference pass a probe. The exit
@@ -76,18 +83,23 @@ sys.path[:0] = [BENCH, ROOT]
 PROBE_TOKENS = 16
 FAULTS = ("bf16_state", "half_topk", "bf16_router", "steps_4", "block_8",
           "gate_over_all", "sqrt_scale", "scalar_decay", "rope_on_mla",
-          "beta_one")
+          "beta_one", "window_short", "window_long")
 
 
 def serve_wrongly(fault: str, model_dir: str) -> None:
     """Make the program about to be served wrong in one named way; the
     reference keeps the configuration as it is."""
-    if fault in ("half_topk", "steps_4", "block_8", "sqrt_scale"):
+    if fault in ("half_topk", "steps_4", "block_8", "sqrt_scale",
+                 "window_short", "window_long"):
         path = os.path.join(model_dir, "config.json")
         with open(path) as f:
             config = json.load(f)
-        if fault == "half_topk":
+        if fault == "half_topk" and "index_topk" in config:
+            config["index_topk"] //= 2
+        elif fault == "half_topk":
             config["sparse_config"]["topk"] //= 2
+        elif fault in ("window_short", "window_long"):
+            config["sliding_window_size"] += 1 if fault == "window_long" else -1
         elif fault == "steps_4":
             config["denoising_steps"] = 4
         elif fault == "sqrt_scale":

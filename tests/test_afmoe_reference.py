@@ -105,11 +105,12 @@ class Served:
     that no sequence holds (both kinds' free pages and the two pages 0)
     is filled with ``poison[0]`` in K and ``poison[1]`` in V."""
 
-    def __init__(self, cfg, params, dtype, poison=None, pool_pages=None):
+    def __init__(self, cfg, params, dtype, poison=None, pool_pages=None,
+                 family=afmoe):
         self.cfg, self.vocab, self.poison = cfg, cfg.vocab_size, poison
         pool_pages = pool_pages or SLOTS * WIDTH + 1
-        self.cache = afmoe.init_kv_cache(cfg, SLOTS * WIDTH + 1, PAGE, dtype,
-                                         window_blocks=pool_pages)
+        self.cache = family.init_kv_cache(cfg, SLOTS * WIDTH + 1, PAGE, dtype,
+                                          window_blocks=pool_pages)
         # page 0 of the full kind is nobody's: an idle row's table points there
         self.btab = 1 + np.arange(SLOTS * WIDTH, dtype=np.int32).reshape(SLOTS, WIDTH)
         self.pool = WindowPool(pool_pages, MetricsRegistry())
@@ -123,7 +124,7 @@ class Served:
         self.peak = {"prefill": 0, "decode": 0}
         self.released = []                 # pages given back, a pass
         self.fwd = jax.jit(
-            lambda cache, tok, pos, bt, slot, ctx: afmoe.forward(
+            lambda cache, tok, pos, bt, slot, ctx: family.forward(
                 params, cfg, tok, pos, cache, bt, slot, ctx))
 
     def start(self, slot):
@@ -157,9 +158,12 @@ class Served:
                                for s, n in enumerate(self.tokens)])
         free = {"full": np.setdiff1d(np.arange(SLOTS * WIDTH + 1), held),
                 "window": np.asarray([0] + self.pool.free)}
+        # (a kind's pages may be several stacks: models/dots3.py)
         self.cache = tuple(
-            afmoe.KindCache(**{kind: getattr(side, kind).at[:, ids].set(value)
-                               for kind, ids in free.items()})
+            dataclasses.replace(side, **{
+                kind: jax.tree.map(lambda x: x.at[:, ids].set(value),
+                                   getattr(side, kind))
+                for kind, ids in free.items()})
             for side, value in zip(self.cache, self.poison))
 
     def tables(self, slot):

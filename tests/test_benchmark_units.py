@@ -23,7 +23,7 @@ FILES = ("test_units.py", "test_trace_reduction.py", "test_host_spans.py",
          "test_falcon_h1_units.py", "test_xing4_units.py",
          "test_minicpm_sala_units.py", "test_afmoe_units.py",
          "test_sync_parts.py", "test_granite_units.py",
-         "test_kimi_linear_units.py")
+         "test_kimi_linear_units.py", "test_dots3_units.py")
 
 
 def _adopt(filename: str) -> None:

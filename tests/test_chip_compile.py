@@ -948,6 +948,105 @@ def test_kimi_linear_prefill_chunk_fits_beside_the_model(
     assert mem.temp_size_in_bytes < 1.2 * 2 ** 30
 
 
+# dots3-note-prev as one chip serves it (benchmark/configs/
+# dots3-note-prev-ep16.json): nine layers F F S S S F S S S, three full
+# layers of 128 heads over a latent of 512 behind an indexer of 64 x 128
+# that picks 2048 keys, six window layers of 64 heads over a latent of
+# 1024 and a window of 513, 16 of 256 experts of 5120 x 1536 held; 36864
+# pages of the full kind, 1216 of the window kind, a table of 1152
+def test_latent_decode_kernel_compiles_at_dots3s_window_layers(
+        one_chip, no_compile_cache):
+    """The latent decode kernel at rank 1024 and 64 heads with a window
+    of 513: the walk starts at the window's first page."""
+    from dynamo_tpu.ops.pallas_decode import mla_paged_decode_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(ql, qr, c, kr, bt, ctx, li):
+        return mla_paged_decode_attention(
+            ql, qr, c, kr, bt, ctx, layer_idx=li, scale=256 ** -0.5,
+            sliding_window=513)
+
+    compiled = jax.jit(call).lower(
+        s((32, 1, 64, 1024)), s((32, 1, 64, 128)),
+        s((6, 1216, 1, 16, 1024)), s((6, 1216, 1, 16, 128)),
+        s((32, 1152), jnp.int32), s((32,), jnp.int32),
+        s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _dots3_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.models import dots3
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "dots3-note-prev-ep16.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+    pool = EngineConfig(
+        model=cfg, **{k: serve[k] for k in (
+            "max_model_len", "max_batch_size", "num_kv_blocks",
+            "prefill_buckets", "max_prefill_tokens_per_step",
+            "max_prefill_batch")}).window_pool_pages()
+    assert pool == 1 + 32 * 34 + 127
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: dots3.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
+                                    jnp.bfloat16, window_blocks=pool)))
+    # a page shape a kind, and the indexer's keys beside the full kind's
+    assert k_side.full.shape == (3, 36864, 1, 16, 512)
+    assert k_side.window.shape == (6, 1216, 1, 16, 1024)
+    assert [x.shape for x in v_side.full] == [(3, 36864, 1, 16, 128)] * 2
+    assert v_side.window.shape == (6, 1216, 1, 16, 128)
+    assert params["moe"]["router"].shape == (8, 5120, 256)
+    assert params["moe"]["w_gate"].shape == (8, 16, 5120, 1536)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, toks, positions, bt, slots, ctx):
+        return dots3.forward_counted(params, cfg, toks, positions,
+                                     (k_side, v_side), bt, slots, ctx)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(rows, tokens), i32(rows, tokens),
+        i32(rows, 2 * width), i32(rows, tokens), i32(rows)).compile()
+
+
+@pytest.mark.parametrize("rows,tokens,width", [
+    (32, 1, 1152), (1, 2048, 1152)])
+def test_dots3_step_keeps_every_page_stack_in_place(
+        one_chip, no_compile_cache, monkeypatch, rows, tokens, width):
+    """A decode step of 32 rows and a 2048-token prefill chunk at the
+    benchmark's size: the window layers' latent kernel (decode) and the
+    grouped products are in it, none of the five page stacks (1.81 GB of
+    latents, 2 x 0.45 GB of rope and indexer keys, 0.24 + 0.03 GB of the
+    window kind) is copied, and the blocked prefill's temporaries stay
+    under a tenth of the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _dots3_step(one_chip, rows, tokens, width)
+    text = compiled.as_text()
+    assert re.search(r"tpu_custom_call[^\n]*moe_experts", text)
+    assert bool(re.search(r"tpu_custom_call[^\n]*swa_latent", text)) == (tokens == 1)
+    for scope in ("dsa_index", "dsa_select", "dsa_attend"):
+        assert scope in text, scope
+    mem = compiled.memory_analysis()
+    print(f"dots3 step {rows}x{tokens}: arguments",
+          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
+    # weights 9.21 GB without the head's 0.19 (the trunk ends at the
+    # hidden state) + pages 2.99
+    assert 11.9e9 < mem.argument_size_in_bytes < 12.1e9
+    # a copy of the smallest full-kind stack would be 0.45 GB
+    assert mem.temp_size_in_bytes < (0.75 if tokens == 1 else 1.6) * 2 ** 30
+
+
 def _decode_trunk(ll, topo, config):
     """The decode trunk of a benchmark configuration compiled for the
     described chips (the real tp mesh where ``serve`` asks for one), once

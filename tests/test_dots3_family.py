@@ -1,0 +1,273 @@
+"""The dots3-note family's wrong programs, its reference's controls, the
+shares of an expert layer, what the shared latent code still lowers to
+for the other latent families, and the family's surface and refusals
+(``tests/test_dots3_reference.py`` holds the served path against the
+reference; two files so that two workers share them)."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu import models
+from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.models import afmoe, deepseek, dots3, mixtral
+from test_dots3_reference import (FULL, HF, PAGE, SWA, TOPK, WINDOW, WRONG,
+                                  Served, _cfg, _params, _reference_logprobs,
+                                  _seqs, _serve_case, reference)
+
+
+# ---------- wrong programs ----------
+
+def _wrong_topk(cfg):
+    return dataclasses.replace(cfg, index_topk=TOPK // 2)
+
+
+def _wrong_window(delta):
+    return lambda cfg: dataclasses.replace(
+        cfg, sliding_window=cfg.sliding_window + delta)
+
+
+WRONG_PROGRAMS = {
+    "half_topk": _wrong_topk,
+    "window_short": _wrong_window(-1),
+    "window_long": _wrong_window(+1),
+    "no_rescale": lambda cfg: dataclasses.replace(cfg, mla_lora_rescale=False),
+    "one_theta": lambda cfg: dataclasses.replace(
+        cfg, swa_rope_theta=cfg.rope_theta),
+}
+
+
+@pytest.mark.parametrize("fault", list(WRONG_PROGRAMS))
+def test_a_wrong_program_is_told_apart(fault):
+    """Served with half the pick, a window one key short or long, the
+    latent norms' constants left out or the full layers' rope base in the
+    window layers, the same weights read far outside the float32 limit."""
+    cfg, params = _params(jnp.float32)
+    served = Served(WRONG_PROGRAMS[fault](cfg), params, jnp.float32)
+    seq = _seqs([100 + 30], seed=len("three_chunks"))[0]
+    got = _serve_case(served, [seq], [0], 30, [40, 77], 64)[0]
+    assert np.abs(got - _reference_logprobs(params, seq)).max() > WRONG
+
+
+@pytest.mark.parametrize("control", reference.CONTROLS)
+def test_the_references_controls_compute_something_else(control):
+    """Each control the limits were set against (``build(lower=...)``)
+    moves the reference's own log-probabilities far outside the float32
+    limit, and only past the threshold it concerns."""
+    _, params = _params(jnp.float32)
+    seq = _seqs([120], seed=9)[0]
+    sound = _reference_logprobs(params, seq)
+    other = _reference_logprobs(params, seq, lower=(control,))
+    d = np.abs(other - sound).max(axis=1)
+    assert d.max() > WRONG
+    first = {"half_topk": TOPK // 2, "no_relu": TOPK, "window_short": WINDOW - 1,
+             "window_long": WINDOW, "no_gate": 0}[control]
+    assert d[:first].max(initial=0.0) < 1e-5 and d[first:first + 8].max() > 1e-4
+
+
+def test_the_second_pair_of_limits_starts_past_twice_the_pick():
+    """The harness's probes (2216 tokens at most) are held to the
+    module's one pair; ``scripts/long_probes.py`` holds a probe past
+    twice ``index_topk`` to the wider pair."""
+    hf = {"index_topk": 2048}
+    short = (reference.LOGPROB_ATOL, reference.LOGPROB_MEAN_ATOL)
+    assert reference.limits_for(hf, 2216) == reference.limits_for(hf, 4096) == short
+    long = reference.limits_for(hf, 9416)
+    assert long == reference.limits_for(hf, 4097)
+    assert long[0] >= short[0] and long[1] > short[1]
+
+
+def test_the_reference_refuses_a_control_it_does_not_have():
+    with pytest.raises(ValueError, match="lower="):
+        reference.build(HF, 8, 8, lower=("weights",))
+
+
+# ---------- the shares add up ----------
+
+def test_the_sixteen_shares_add_up_to_the_uncut_layer():
+    """For a layer of 16 experts: the routed parts of the sixteen ranks
+    that hold one expert each, plus the shared expert counted once, are
+    the uncut reference's whole layer; in the reference given the
+    shares, and in the program (``moe_mlp(held=...)``) against the same
+    uncut reference."""
+    cfg, params = _params(jnp.float32)
+    lp = {k: v[1] for k, v in params["moe"].items()}          # one layer
+    x = jax.random.normal(jax.random.PRNGKey(5), (48, cfg.hidden_size),
+                          jnp.float32)
+    whole, shared = reference.expert_layer(HF)(x, lp)
+    want = np.asarray(whole + shared)
+    parts, got, stats = [], [], []
+    for rank in range(16):
+        hf = {**HF, "n_routed_experts": 1,
+              "expert_share": {"of_experts": 16, "rank": rank}}
+        mine = {k: (v[rank:rank + 1] if k in mixtral.EXPERT_STACKS else v)
+                for k, v in lp.items()}
+        routed, again = reference.expert_layer(hf)(x, mine)
+        np.testing.assert_allclose(again, shared, atol=1e-6)   # every rank alike
+        parts.append(np.asarray(routed))
+        y, s = mixtral.moe_mlp(
+            x, lp["router"], *(lp[k][rank:rank + 1]
+                               for k in mixtral.EXPERT_STACKS),
+            cfg.num_experts_per_tok, scoring=cfg.moe_scoring_func,
+            norm_topk=cfg.norm_topk_prob,
+            routed_scaling=cfg.routed_scaling_factor,
+            router_bias=lp["router_bias"], held=(rank, 1))
+        np.testing.assert_allclose(y, parts[-1], atol=1e-4)
+        got.append(np.asarray(y))
+        stats.append(np.asarray(s))
+    assert sum(np.abs(p).max() > 1e-3 for p in parts) >= 12    # most are picked
+    np.testing.assert_allclose(sum(parts) + np.asarray(shared), want, atol=1e-5)
+    np.testing.assert_allclose(sum(got) + np.asarray(shared), want, atol=2e-4)
+    picks = x.shape[0] * cfg.num_experts_per_tok
+    assert all(s[1] == picks for s in stats)
+    assert sum(s[2] for s in stats) == picks
+
+
+# ---------- the other latent families are as they were ----------
+
+@pytest.mark.parametrize("hc_mult", [1], ids=["moonlight"])
+def test_the_latent_projections_lower_as_before_for_the_other_families(hc_mult):
+    """``deepseek.mla_project`` with the constants at 1.0 and
+    ``mla_attention`` without a window are what Moonlight's trunk (and
+    Xing4's, the same two calls under ``hc_mult``) ran before this family: scaling by an explicit 1.0 and a
+    window no context reaches change the lowered text (so the defaults
+    multiply and mask nothing), and the logits not at all."""
+    cfg = ModelConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=96, num_layers=2,
+        num_heads=4, num_kv_heads=4, kv_lora_rank=32, q_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=24, n_shared_experts=1,
+        first_k_dense_replace=1, attention_impl="xla", hc_mult=hc_mult,
+        model_family="deepseek" if hc_mult > 1 else "")
+    assert models.resolve(cfg) is deepseek
+    params = deepseek.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    t = 10
+    tokens = jnp.arange(3, 3 + t, dtype=jnp.int32)[None]
+    pos = jnp.arange(t, dtype=jnp.int32)[None]
+    table = jnp.arange(4, dtype=jnp.int32)[None]
+
+    def lowered_and_logits():
+        def forward(cache):
+            return deepseek.forward(params, cfg, tokens, pos, cache, table,
+                                    pos, jnp.asarray([t], jnp.int32))[0]
+
+        cache = deepseek.init_kv_cache(cfg, 4, PAGE, jnp.float32)
+        return (jax.jit(forward).lower(cache).as_text(),
+                np.asarray(forward(cache)))
+
+    as_now, logits = lowered_and_logits()
+    assert "multiply" in as_now
+    project, attend = deepseek.mla_project, deepseek.mla_attention
+    try:
+        deepseek.mla_project = lambda *a, **kw: project(
+            *a, **{**kw, "q_scale": 2.0, "kv_scale": 1.0})
+        text, other = lowered_and_logits()
+        assert text != as_now and np.abs(other - logits).max() > 1e-3
+        deepseek.mla_project = project
+        deepseek.mla_attention = lambda *a, **kw: attend(
+            *a, **{**kw, "sliding_window": 4})
+        text, other = lowered_and_logits()
+        assert text != as_now and np.abs(other - logits).max() > 1e-3
+        deepseek.mla_attention = lambda *a, **kw: attend(
+            *a, **{**kw, "sliding_window": None})
+        text, same = lowered_and_logits()
+        assert text == as_now
+        np.testing.assert_array_equal(same, logits)
+    finally:
+        deepseek.mla_project, deepseek.mla_attention = project, attend
+
+
+# ---------- the family's surface and what it refuses ----------
+
+def test_the_published_config_reaches_the_family():
+    cfg = ModelConfig.from_hf_config(HF)
+    assert cfg.model_family == "dots3"
+    assert models.resolve(cfg) is dots3        # not deepseek's shape rule
+    assert cfg.layer_types == tuple(HF["layer_types"])
+    assert (cfg.index_topk, cfg.index_n_heads, cfg.index_head_dim) == (32, 4, 16)
+    assert (cfg.sliding_window, cfg.swa_num_heads, cfg.swa_kv_lora_rank,
+            cfg.swa_q_lora_rank, cfg.swa_qk_nope_head_dim,
+            cfg.swa_qk_rope_head_dim, cfg.swa_v_head_dim,
+            cfg.swa_rope_theta) == (17, 2, 32, 32, 24, 8, 16, 50000.0)
+    assert cfg.mla_lora_rescale and cfg.attention_gate == "headwise"
+    assert (cfg.num_experts, cfg.experts_of, cfg.topk_method) == (16, 0, "noaux_tc")
+    window = dots3.kind_cfg(cfg, SWA)
+    assert (window.num_heads, window.kv_lora_rank, window.rope_theta) == \
+        (2, 32, 50000.0)
+    assert dots3.lora_rescale(cfg) == (2 ** 0.5, 2.0)
+    assert dots3.lora_rescale(window) == (2 ** 0.5, 2 ** 0.5)
+    prefix, periods = dots3._layout(cfg, (FULL, SWA))
+    assert prefix == [(FULL, 0, 0)]
+    assert [p.tolist() for p in periods] == [[1, 2], [1, 1], [0, 3], [3, 3]]
+    assert dots3.SEQUENCE_STATE.window_pool and dots3.SEQUENCE_STATE.private
+    # a page shape a kind: latents of 16 and 32, each in whole lanes, and
+    # the indexer's keys in the full kind's geometry
+    k_side, v_side = jax.eval_shape(
+        lambda: dots3.init_kv_cache(cfg, 9, PAGE, jnp.bfloat16, window_blocks=5))
+    assert k_side.full.shape == (3, 9, 1, PAGE, 128)
+    assert k_side.window.shape == (6, 5, 1, PAGE, 128)
+    assert [x.shape for x in v_side.full] == [(3, 9, 1, PAGE, 128)] * 2
+    assert v_side.window.shape == (6, 5, 1, PAGE, 128)
+    assert k_side.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("key,value,error", [
+    ("attention_gate_type", "elementwise", NotImplementedError),
+    ("swa_attention_gate_type", None, NotImplementedError),
+    ("scoring_func", "softmax", NotImplementedError),
+    ("topk_method", "greedy", NotImplementedError),
+    ("rope_scaling", {"type": "linear", "factor": 2.0}, NotImplementedError),
+    ("moe_layer_freq", 2, NotImplementedError),
+    ("n_group", 4, NotImplementedError),
+    ("q_lora_rank", None, NotImplementedError),
+    ("n_shared_experts", 0, NotImplementedError),
+    ("sliding_window_size", 0, NotImplementedError),
+    ("layer_types", [FULL] * 9, NotImplementedError),
+    ("layer_types", [FULL, SWA], ValueError),
+    ("expert_share", {"of_experts": 24, "rank": 0}, ValueError),
+])
+def test_what_the_module_does_not_compute_is_refused(key, value, error):
+    named = {"expert_share": "share", "n_shared_experts": "shared expert",
+             "layer_types": "layer", "sliding_window_size": "sliding_window_size"
+             }.get(key, key)
+    with pytest.raises(error, match=named):
+        ModelConfig.from_hf_config({**HF, key: value})
+
+
+def test_the_families_keys_are_refused_under_another_model_type():
+    plain = {"model_type": "some_other_trunk", "vocab_size": 128,
+             "hidden_size": 64, "num_hidden_layers": 2,
+             "num_attention_heads": 4}
+    for key in ("index_topk", "swa_kv_lora_rank", "sliding_window_size",
+                "apply_mla_qkv_lora_rescale", "attention_gate_type"):
+        with pytest.raises(NotImplementedError, match=f"some_other_trunk.*{key}") as e:
+            ModelConfig.from_hf_config({**plain, key: HF[key]})
+        assert "dots3" in str(e.value)
+    stray = dataclasses.replace(_cfg(), model_family="")
+    with pytest.raises(NotImplementedError, match="index_topk"):
+        models.resolve(stray)
+    # afmoe's configs are afmoe's still; a mixed layer_types is this
+    # family's under its own model_type only
+    assert dots3.claimed_keys({"model_type": "afmoe", "layer_types": [FULL, SWA],
+                               "sliding_window": 8}) == []
+    assert afmoe.claimed_keys(HF) == ["layer_types"]
+    assert dots3.claimed_keys(HF)[0] == "layer_types"
+
+
+@pytest.mark.parametrize("path,setting", [
+    ("ep_size", dict(ep_size=2)), ("tp_size", dict(tp_size=2)),
+    ("spec_ngram_tokens", dict(spec_ngram_tokens=2)),
+    ("multi_step_decode", dict(multi_step_decode=4)),
+    ("host_kv_blocks", dict(host_kv_blocks=8)),
+])
+def test_paths_refused_for_the_family_by_name(path, setting):
+    from dynamo_tpu.engine.model_runner import ModelRunner
+
+    with pytest.raises(ValueError, match=f"{path} is refused for the "
+                                         "dots3 family"):
+        ModelRunner(EngineConfig(model=_cfg(), max_batch_size=2,
+                                 max_model_len=64, kv_block_size=PAGE,
+                                 num_kv_blocks=16, dtype="float32", **setting))

@@ -270,6 +270,7 @@ OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
             for name in _NAME.findall(bullet.split(":")[0])}
 FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
                 "residual_multiplier": 0.22, "kda_num_heads": 4,
+                "index_topk": 32,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
@@ -397,6 +398,59 @@ def test_a_latent_config_with_kda_layers_reaches_its_own_row():
         ModelConfig.from_hf_config(
             {**PLAIN_HF, "linear_attn_config": _KIMI["linear_attn_config"]})
     assert "kimi_linear" in str(e.value)
+
+
+_DOTS3 = {"model_type": "dots3_note", "vocab_size": 64, "hidden_size": 32,
+          "intermediate_size": 48, "moe_intermediate_size": 16,
+          "num_hidden_layers": 3, "first_k_dense_replace": 1,
+          "layer_types": ["full_attention", "full_attention",
+                          "sliding_attention"],
+          "num_attention_heads": 2, "q_lora_rank": 16, "kv_lora_rank": 16,
+          "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+          "swa_num_attention_heads": 1, "swa_q_lora_rank": 16,
+          "swa_kv_lora_rank": 32, "swa_qk_nope_head_dim": 24,
+          "swa_qk_rope_head_dim": 8, "swa_v_head_dim": 16,
+          "swa_rope_theta": 50000, "sliding_window_size": 9,
+          "index_topk": 8, "index_n_heads": 2, "index_head_dim": 16,
+          "attention_gate_type": "headwise",
+          "swa_attention_gate_type": "headwise",
+          "apply_mla_qkv_lora_rescale": True, "n_routed_experts": 2,
+          "expert_share": {"of_experts": 8, "rank": 1}, "n_shared_experts": 1,
+          "num_experts_per_tok": 2, "scoring_func": "sigmoid",
+          "topk_method": "noaux_tc"}
+
+
+def test_a_latent_config_with_an_indexer_reaches_its_own_row():
+    """``dots3_note`` has ``kv_lora_rank > 0``, deepseek's shape rule,
+    and a mixed ``layer_types``, afmoe's claim: its row stands before
+    both and takes it by name, with ``expert_share`` its own under this
+    ``model_type``; a latent config with a stray ``index_topk`` is
+    refused for deepseek, and the ``swa_*`` / ``index_*`` keys under
+    another ``model_type`` by name."""
+    cfg = ModelConfig.from_hf_config(_DOTS3)
+    assert cfg.model_family == "dots3" and models.family(cfg).name == "dots3"
+    assert models.FAMILIES.index(models.family(cfg)) < next(
+        i for i, r in enumerate(models.FAMILIES) if r.name == "deepseek")
+    assert cfg.layer_types == tuple(_DOTS3["layer_types"])
+    assert (cfg.sliding_window, cfg.index_topk, cfg.swa_kv_lora_rank) == (9, 8, 32)
+    assert (cfg.num_experts, cfg.experts_of, cfg.expert_rank) == (2, 8, 1)
+    assert cfg.kda_num_heads == 0 and cfg.hc_mult == 1
+    assert models.family(cfg).module.SEQUENCE_STATE.window_pool
+    import dataclasses
+    with pytest.raises(NotImplementedError, match="index_topk"):
+        models.resolve(dataclasses.replace(cfg, model_family=""))
+    with pytest.raises(NotImplementedError,
+                       match="some_other_trunk.*index_topk") as e:
+        ModelConfig.from_hf_config({**PLAIN_HF, "index_topk": 8,
+                                    "swa_kv_lora_rank": 32})
+    assert "dots3" in str(e.value)
+    # the key under the model_type that computes a window without it
+    with pytest.raises(NotImplementedError, match="sliding_window_size"):
+        ModelConfig.from_hf_config({
+            "model_type": "afmoe", "vocab_size": 64, "hidden_size": 32,
+            "num_hidden_layers": 2, "num_attention_heads": 2,
+            "layer_types": ["sliding_attention", "full_attention"],
+            "sliding_window": 8, "sliding_window_size": 9})
 
 
 @pytest.mark.parametrize("keys,named", [
