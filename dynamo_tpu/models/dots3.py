@@ -40,20 +40,28 @@ latent and the rope key (and the indexer's key in a full layer).
 
 **Two kinds of page, a page shape a kind.** A side of the cache is a
 ``LatentKinds`` (afmoe's ``KindCache`` with the step's counters): the k
-side the latents ``(full [Lf, N, 1, page, r], window [Lw, Nw, 1, page,
-r_w])``, the v side ``(full (rope keys [Lf, N, 1, page, rd], indexer
-keys [Lf, N, 1, page, di]), window rope keys [Lw, Nw, 1, page, rd_w])``,
-every minor dimension lane-padded. The window kind's pages come from the
-allocator's second pool behind a table of their own and go back as they
-fall behind the window (models/afmoe.py says how the engine serves
-that; this family inherits its ``SEQUENCE_STATE``); the indexer's keys
-lie in the full kind's pages' geometry and are written through the same
-slots.
+side ``(full [Lf, N, 1, page, r' + rd'], window [Lw, Nw, 1, page,
+r_w'])``, the v side ``(full (indexer keys [Lf, N, 1, page, di'],),
+window rope keys [Lw, Nw, 1, page, rd_w'])``, a primed width its
+``lane_pad``. **A full layer's page holds a token's row whole**: the
+latent in lanes ``[:r]``, the rotated key in ``[r':r' + rd]``, zeros
+between and behind (640 lanes at rank 512 and a rope key of 64; with
+the indexer's 128, 1536 B a token in bfloat16).
+Nothing walks a full layer's pages: decode looks the picked tokens' rows
+up one by one and a gather costs the chip by the index far more than by
+the byte (scripts/gather_sweep.py), so a row is one lookup, not a latent
+and a rope key apart. The window kind keeps the two stacks
+``mla_paged_decode_attention`` walks (deepseek.init_kv_cache's layout).
+The window kind's pages come from the allocator's second pool behind a
+table of their own and go back as they fall behind the window
+(models/afmoe.py says how the engine serves that; this family inherits
+its ``SEQUENCE_STATE``); the indexer's keys lie in the full kind's
+pages' geometry and are written through the same slots.
 
 **Routes.** Decode, full layer: the indexer's scores of the table's
-keys, the pick and one dense absorbed product over the picked tokens'
-gathered rows (``ops/latent_select.picked_decode_attention``; XLA on
-every backend, a program of the width ladder). Decode, window layer: the
+keys, the pick, one gather of the picked tokens' rows and one dense
+absorbed product over them (``ops/latent_select.picked_decode_attention``;
+XLA on every backend, a program of the width ladder). Decode, window layer: the
 latent decode kernel from the window's first page
 (``deepseek.mla_attention(sliding_window=)``; the dense gather off the
 TPU). Prefill, both kinds: ``ops/latent_select.blocked_latent_attention``,
@@ -388,27 +396,27 @@ CACHE_SPEC = LatentKinds(full=P(), window=P(), counts=P())
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
                   window_blocks: int = 1):
-    """``(LatentKinds(latents of the full kind, of the window kind, -),
-    LatentKinds((rope keys, indexer keys) of the full kind, rope keys of
-    the window kind, counters))``: ``num_blocks`` pages a full layer,
-    ``window_blocks`` a window layer (page 0 of those is the one no
-    sequence holds), the one "head" in front of the page
-    (deepseek.init_kv_cache)."""
+    """``(LatentKinds(rows of the full kind (latent ‖ rope key), latents
+    of the window kind, -), LatentKinds((indexer keys,) of the full kind,
+    rope keys of the window kind, counters))``: ``num_blocks`` pages a
+    full layer, ``window_blocks`` a window layer (page 0 of those is the
+    one no sequence holds), the one "head" in front of the page
+    (deepseek.init_kv_cache); a page's lanes are its parts' widths, each
+    in whole lanes."""
     n_full = cfg.layer_types.count(FULL)
     wcfg = kind_cfg(cfg, WINDOW)
 
-    def pages(layers, blocks, width):
-        return jnp.zeros((layers, blocks, 1, block_size, lane_pad(width)),
-                         dtype)
+    def pages(layers, blocks, *widths):
+        return jnp.zeros((layers, blocks, 1, block_size,
+                          sum(map(lane_pad, widths))), dtype)
 
     full = (n_full, num_blocks)
     window = (cfg.num_layers - n_full, window_blocks)
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     return (
-        LatentKinds(pages(*full, cfg.kv_lora_rank),
+        LatentKinds(pages(*full, cfg.kv_lora_rank, cfg.qk_rope_head_dim),
                     pages(*window, wcfg.kv_lora_rank), counts),
-        LatentKinds((pages(*full, cfg.qk_rope_head_dim),
-                     pages(*full, cfg.index_head_dim)),
+        LatentKinds((pages(*full, cfg.index_head_dim),),
                     pages(*window, wcfg.qk_rope_head_dim), counts))
 
 
@@ -448,53 +456,56 @@ def index_projections(cfg: ModelConfig, lp, x, cq, positions):
 def make_mixer_fn(cfg: ModelConfig, kind: str, b: int, s: int, positions,
                   slots, table, valid, context_lens, live_rows):
     """The latent mixer of one layer of ``kind``: ``fn(n1, layer_params,
-    caches, li) -> (delta, caches)`` over that kind's page stacks
-    (latents, rope keys and, for a full layer, the indexer's keys),
-    ``slots`` and ``table`` that kind's."""
+    caches, li) -> (delta, caches)`` over that kind's page stacks (a full
+    layer's rows and its indexer's keys; a window layer's latents and
+    rope keys), ``slots`` and ``table`` that kind's."""
     kcfg = kind_cfg(cfg, kind)
     sq, skv = lora_rescale(kcfg)
     scale = mla_softmax_scale(kcfg)
     decode = s == 1
+    r = kcfg.kv_lora_rank
+    lat = lane_pad(r)       # the latent's lanes of a page, either kind
 
     def fn(x, lp, caches, li):
         cq, q_nope, q_rope, c_kv, kr = mla_project(
             kcfg, x, lp, b, s, positions, q_scale=sq, kv_scale=skv,
             hold_heads=True)
         new = (c_kv, kr)
-        index = None
         if kind == FULL:
             with jax.named_scope("dsa_index"):
                 qi, ki, wi = index_projections(cfg, lp, x, cq, positions)
-                new = (c_kv, kr, ki)
+            # a token's row: the latent in whole lanes, then the rotated key
+            new = (jnp.concatenate([_pad_minor(c_kv, lat), kr], -1), ki)
         caches = scatter_rows_stacked(caches, new, slots, li)
-        c_all, kr_all = caches[:2]
+        stacks, index = caches, None     # what a key is read from
         if kind == FULL:
+            stacks, keys = caches[:1], caches[1]
             # (zero lanes of a padded query score 0 against the pad)
-            index = Indexer(_pad_minor(qi, caches[2].shape[-1]), wi,
-                            caches[2], cfg.index_topk)
+            index = Indexer(_pad_minor(qi, keys.shape[-1]), wi, keys,
+                            cfg.index_topk)
 
         # absorb W_uk into the query, attend over the latent cache
-        r = kcfg.kv_lora_rank
         q_lat = _pad_minor(jnp.einsum("bshn,hnr->bshr", q_nope, lp["w_uk"]),
-                           c_all.shape[-1])
-        q_rope = _pad_minor(q_rope, kr_all.shape[-1])
+                           lat)
+        q_rope = _pad_minor(q_rope,
+                            sum(st.shape[-1] for st in stacks) - lat)
         if kind == FULL and decode:
             with jax.named_scope("mla_cache"):
                 o_lat = picked_decode_attention(
-                    q_lat, q_rope, c_all, kr_all, li, table, context_lens,
-                    scale, index)
+                    q_lat, q_rope, *stacks, li, table, context_lens, scale,
+                    index)
         elif decode:
             with jax.named_scope("swa_latent"):
                 o_lat = mla_attention(
-                    q_lat, q_rope, c_all, kr_all, li, table, positions,
+                    q_lat, q_rope, *stacks, li, table, positions,
                     context_lens, scale, impl=cfg.attention_impl,
                     live_rows=live_rows, sliding_window=cfg.sliding_window)
         else:
             with jax.named_scope("mla_cache" if kind == FULL
                                  else "swa_latent"):
                 o_lat = blocked_latent_attention(
-                    q_lat, q_rope, c_all, kr_all, li, table, positions,
-                    valid, context_lens, scale,
+                    q_lat, q_rope, stacks, li, table, positions, valid,
+                    context_lens, scale,
                     sliding_window=(cfg.sliding_window if kind == WINDOW
                                     else None),
                     index=index)
@@ -583,10 +594,10 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
         counts = counts + jnp.stack([
             jnp.minimum(keys, cfg.index_topk).sum(), keys.sum(),
             (keys > cfg.index_topk).sum(), jnp.int32(1)]).astype(jnp.int32)
-    c_f, kr_f, ki_f = pages[FULL]
+    rows_f, ki_f = pages[FULL]
     c_w, kr_w = pages[WINDOW]
-    cache = (LatentKinds(c_f, c_w, k_side.counts),
-             LatentKinds((kr_f, ki_f), kr_w, counts))
+    cache = (LatentKinds(rows_f, c_w, k_side.counts),
+             LatentKinds((ki_f,), kr_w, counts))
     return hidden, cache, stats
 
 

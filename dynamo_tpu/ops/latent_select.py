@@ -2,8 +2,9 @@
 attention in blocks (models/dots3.py).
 
 A full layer of ``dots3_note`` keeps one ``index_head_dim``-wide key a
-token beside the latent and the rope key. A query scores every earlier
-key with ``J`` small heads,
+token beside the token's row (the latent and behind it the rope key, one
+row of one page stack: models/dots3.py says why). A query scores every
+earlier key with ``J`` small heads,
 
     I(t, s) = Σ_j w_t,j · ReLU(q^I_t,j · k^I_s)          float32
 
@@ -15,8 +16,8 @@ entries equal to it the earliest, so that a row keeps ``min(k, keys)``
 keys whatever ties there are.
 
 - **decode** (one query a row, ``picked_decode_attention``): the scores
-  of the table's keys, the pick, the picked tokens' latent and rope-key
-  rows gathered out of the pages (``[B, k, r + rd]``), one dense absorbed
+  of the table's keys, the pick, the picked tokens' rows gathered out of
+  the pages (``[B, k, r' + rd']``, one lookup a key), one dense absorbed
   product over them. The work follows the block table's width (the gather
   of the indexer's keys), so the program is one of the width ladder's
   (``record_table_width``).
@@ -25,8 +26,10 @@ keys whatever ties there are.
   softmax, from the first block a query of the block can see (the
   window's, for a window layer) to the last (the causal edge): no score
   tensor is wider than a block, and the work follows the keys that are
-  there, not the table's width. A full layer's query block first scores
-  the same key blocks with the indexer and makes its pick a mask.
+  there, not the table's width. A key block is the pages of whichever
+  stacks the kind keeps, side by side (a full layer's one, a window
+  layer's latents and rope keys). A full layer's query block first
+  scores the same key blocks with the indexer and makes its pick a mask.
 
 Scopes: ``dsa_index`` (scores), ``dsa_select`` (cutoff, mask, the picked
 tokens' list), ``dsa_attend`` (the gather of the picked rows and the
@@ -174,14 +177,17 @@ def _gather_pages(cache, li, table):
     return got.reshape(table.shape[0], -1, got.shape[-1])
 
 
-def picked_decode_attention(q_lat, q_rope, c_all, kr_all, li, table,
+def picked_decode_attention(q_lat, q_rope, rows_all, li, table,
                             context_lens, scale: float, index: Indexer):
     """One query a row (q_lat [B, 1, H, r'], q_rope [B, 1, H, rd'], padded
-    to the caches' lanes) over the ``index.topk`` keys its indexer picks
-    -> latent output [B, 1, H, r']."""
+    to their lanes of a row of ``rows_all`` [L, N, 1, page, r' + rd'])
+    over the ``index.topk`` keys its indexer picks -> [B, 1, H, r' + rd']:
+    the latent output in the first ``r'`` lanes (the value product runs
+    over a gathered row whole, so that no copy of the rows' latent part
+    is made; what lies behind is not an output)."""
     record_table_width()
     b = q_lat.shape[0]
-    page = c_all.shape[3]
+    page = rows_all.shape[3]
     t = table.shape[1] * page
     k = min(index.topk, t)
     with jax.named_scope("dsa_index"):
@@ -189,40 +195,41 @@ def picked_decode_attention(q_lat, q_rope, c_all, kr_all, li, table,
                               _gather_pages(index.keys, li, table))[:, 0]
     with jax.named_scope("dsa_select"):
         valid = jnp.arange(t)[None] < context_lens[:, None]
-        # a key's row of the flat [L N page, d] view of the caches
-        _, base = _layer_pages(c_all, li)
+        # a key's row of the flat [L N page, d] view of the cache
+        _, base = _layer_pages(rows_all, li)
         at = ((table + base)[:, :, None] * page
               + jnp.arange(page)).reshape(b, t)
         rows, count = picked_list(
             pick_mask(scores, valid, index.topk), k, values=at,
-            bound=c_all.shape[0] * c_all.shape[1] * page)
+            bound=rows_all.shape[0] * rows_all.shape[1] * page)
     with jax.named_scope("dsa_attend"):
-        c = c_all.reshape(-1, c_all.shape[-1])[rows].astype(q_lat.dtype)
-        kr = kr_all.reshape(-1, kr_all.shape[-1])[rows].astype(q_lat.dtype)
-        s_log = (jnp.einsum("bhr,bkr->bhk", q_lat[:, 0], c,
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("bhd,bkd->bhk", q_rope[:, 0], kr,
-                              preferred_element_type=jnp.float32)) * scale
+        q = jnp.concatenate([q_lat, q_rope], -1)[:, 0]
+        picked = rows_all.reshape(-1, rows_all.shape[-1])[rows].astype(q.dtype)
+        s_log = jnp.einsum("bhd,bkd->bhk", q, picked,
+                           preferred_element_type=jnp.float32) * scale
         live = jnp.arange(k)[None] < count[:, None]
         s_log = jnp.where(live[:, None], s_log, MASK_VALUE)
-        probs = jax.nn.softmax(s_log, axis=-1).astype(q_lat.dtype)
-        out = jnp.einsum("bhk,bkr->bhr", probs, c)
+        probs = jax.nn.softmax(s_log, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bhk,bkd->bhd", probs, picked)
     return out[:, None]
 
 
-def blocked_latent_attention(q_lat, q_rope, c_all, kr_all, li, table,
+def blocked_latent_attention(q_lat, q_rope, stacks, li, table,
                              positions, valid, context_lens, scale: float,
                              sliding_window: Optional[int] = None,
                              index: Optional[Indexer] = None):
     """Latent attention of queries [B, S, H, r'] / [B, S, H, rd'] over the
-    pages ``table`` [B, W] names in layer ``li``, in blocks (module
-    docstring) -> latent output [B, S, H, r']. ``valid`` [B, S]: the
+    pages ``table`` [B, W] names in layer ``li`` of ``stacks``, in blocks
+    (module docstring) -> latent output [B, S, H, r']. ``stacks``: the
+    page stacks [L, N, 1, page, ·] whose lanes side by side are a key's
+    ``r' + rd'`` (one stack of whole rows, or the latents and the rope
+    keys), the latent first. ``valid`` [B, S]: the
     queries that are tokens (a pad query's output is not read). A query
     at ``p`` sees keys ``<= p`` under ``context_lens``, the last
     ``sliding_window`` of them where given, those ``index`` picks where
     given."""
     b, s, h, r = q_lat.shape
-    page = c_all.shape[3]
+    page = stacks[0].shape[3]
     w = table.shape[1]
     qb = QUERY_BLOCK if s % QUERY_BLOCK == 0 else s
     kp = min(KEY_BLOCK // page, w)                  # pages a key block
@@ -273,9 +280,9 @@ def blocked_latent_attention(q_lat, q_rope, c_all, kr_all, li, table,
 
         def fold(j, carry):
             m, l, acc = carry
-            c = keys_of(c_all, j).astype(ql.dtype)            # [B, kb, r]
-            key = jnp.concatenate(
-                [c, keys_of(kr_all, j).astype(ql.dtype)], -1)
+            got = [keys_of(stack, j).astype(ql.dtype) for stack in stacks]
+            key = jnp.concatenate(got, -1)    # (of one stack: itself)
+            c = got[0][..., :r]                               # [B, kb, r]
             s_log = jnp.einsum("bqhd,bkd->bqhk", q, key,
                                preferred_element_type=f32) * scale
             see = (visible(pos, j * kb + jnp.arange(kb)) if keep is None

@@ -67,9 +67,11 @@ def lower_trunk(config_path, devices, tokens=1, width=256):
         return NamedSharding(mesh, spec)
 
     def placed(shapes, specs):
+        # (a spec may stand for a tuple of stacks: models/dots3.py)
         return jax.tree.map(
-            lambda x, sp: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on(sp)),
-            shapes, specs)
+            lambda sp, part: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=on(sp)), part),
+            specs, shapes, is_leaf=lambda sp: isinstance(sp, P))
 
     shapes = jax.eval_shape(
         lambda: arch.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))

@@ -10,6 +10,7 @@ one file.
 """
 
 import json
+import math
 import os
 import re
 
@@ -1000,10 +1001,11 @@ def _dots3_step(one_chip, rows, tokens, width):
     k_side, v_side = jax.tree.map(s, jax.eval_shape(
         lambda: dots3.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
                                     jnp.bfloat16, window_blocks=pool)))
-    # a page shape a kind, and the indexer's keys beside the full kind's
-    assert k_side.full.shape == (3, 36864, 1, 16, 512)
+    # a page shape a kind: a full layer's page a token's row whole (512
+    # lanes of latent, 128 of rope key), the indexer's keys beside it
+    assert k_side.full.shape == (3, 36864, 1, 16, 640)
     assert k_side.window.shape == (6, 1216, 1, 16, 1024)
-    assert [x.shape for x in v_side.full] == [(3, 36864, 1, 16, 128)] * 2
+    assert [x.shape for x in v_side.full] == [(3, 36864, 1, 16, 128)]
     assert v_side.window.shape == (6, 1216, 1, 16, 128)
     assert params["moe"]["router"].shape == (8, 5120, 256)
     assert params["moe"]["w_gate"].shape == (8, 16, 5120, 1536)
@@ -1020,16 +1022,25 @@ def _dots3_step(one_chip, rows, tokens, width):
         i32(rows, 2 * width), i32(rows, tokens), i32(rows)).compile()
 
 
+# (arguments, temporaries) of the same two steps at PR 54, three page
+# stacks of 512 / 128 / 128 lanes a full layer: compiled here the same way
+_DOTS3_BEFORE_PR55 = {(32, 1): (11999302656, 179547136),
+                      (1, 2048): (11999040000, 997646848)}
+
+
 @pytest.mark.parametrize("rows,tokens,width", [
     (32, 1, 1152), (1, 2048, 1152)])
 def test_dots3_step_keeps_every_page_stack_in_place(
         one_chip, no_compile_cache, monkeypatch, rows, tokens, width):
     """A decode step of 32 rows and a 2048-token prefill chunk at the
     benchmark's size: the window layers' latent kernel (decode) and the
-    grouped products are in it, none of the five page stacks (1.81 GB of
-    latents, 2 x 0.45 GB of rope and indexer keys, 0.24 + 0.03 GB of the
+    grouped products are in it, none of the four page stacks (2.27 GB of
+    the full kind's rows, 0.45 GB of indexer keys, 0.24 + 0.03 GB of the
     window kind) is copied, and the blocked prefill's temporaries stay
-    under a tenth of the chip."""
+    under a tenth of the chip. A full layer's body looks a picked key up
+    once (decode: one gather of ``[32, 2048, 640]`` under ``dsa_attend``)
+    and a key block's pages once (prefill: ``[64, 16, 640]``), and makes
+    no copy of what it gathered."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = _dots3_step(one_chip, rows, tokens, width)
     text = compiled.as_text()
@@ -1037,13 +1048,24 @@ def test_dots3_step_keeps_every_page_stack_in_place(
     assert bool(re.search(r"tpu_custom_call[^\n]*swa_latent", text)) == (tokens == 1)
     for scope in ("dsa_index", "dsa_select", "dsa_attend"):
         assert scope in text, scope
+    # two bodies trace a full layer: layer 0 of the dense prefix, and the
+    # period's loop
+    gathered = re.findall(r"= bf16\[([0-9,]+)\]\S* gather\([^\n]*dsa_attend",
+                          text)
+    assert gathered == ["32,2048,640" if tokens == 1 else "64,16,640"] * 2
+    copied = [math.prod(map(int, dims.split(","))) for dims in re.findall(
+        r"= \w+\[([0-9,]+)\]\S* copy\([^\n]*dsa_attend", text)]
+    assert max(copied, default=0) < 32 * 2048 * 512     # the queries, at most
     mem = compiled.memory_analysis()
+    before = _DOTS3_BEFORE_PR55[rows, tokens]
     print(f"dots3 step {rows}x{tokens}: arguments",
-          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
+          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
+          f"(before PR 55: {before[0]}, {before[1]})")
     # weights 9.21 GB without the head's 0.19 (the trunk ends at the
     # hidden state) + pages 2.99
     assert 11.9e9 < mem.argument_size_in_bytes < 12.1e9
-    # a copy of the smallest full-kind stack would be 0.45 GB
+    # a copy of the smallest full-kind stack would be 0.45 GB, of the
+    # rows' 2.27
     assert mem.temp_size_in_bytes < (0.75 if tokens == 1 else 1.6) * 2 ** 30
 
 

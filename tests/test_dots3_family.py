@@ -5,6 +5,7 @@ for the other latent families, and the family's surface and refusals
 reference; two files so that two workers share them)."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -203,15 +204,48 @@ def test_the_published_config_reaches_the_family():
     assert prefix == [(FULL, 0, 0)]
     assert [p.tolist() for p in periods] == [[1, 2], [1, 1], [0, 3], [3, 3]]
     assert dots3.SEQUENCE_STATE.window_pool and dots3.SEQUENCE_STATE.private
-    # a page shape a kind: latents of 16 and 32, each in whole lanes, and
-    # the indexer's keys in the full kind's geometry
+    # a page shape a kind: a full layer's page a token's row whole (the
+    # latent of 16 and the rope key of 8, each in whole lanes) with the
+    # indexer's keys in its geometry, a window layer's latents of 32 and
+    # rope keys apart
     k_side, v_side = jax.eval_shape(
         lambda: dots3.init_kv_cache(cfg, 9, PAGE, jnp.bfloat16, window_blocks=5))
-    assert k_side.full.shape == (3, 9, 1, PAGE, 128)
+    assert k_side.full.shape == (3, 9, 1, PAGE, 256)
     assert k_side.window.shape == (6, 5, 1, PAGE, 128)
-    assert [x.shape for x in v_side.full] == [(3, 9, 1, PAGE, 128)] * 2
+    assert [x.shape for x in v_side.full] == [(3, 9, 1, PAGE, 128)]
     assert v_side.window.shape == (6, 5, 1, PAGE, 128)
     assert k_side.dtype == jnp.bfloat16
+
+
+def test_a_decode_step_looks_a_picked_key_up_once():
+    """The lowered decode step holds, under ``dsa_attend``, exactly one
+    ``gather`` a full layer's body (the dense prefix's layer 0 and the
+    period's loop: as many as gather the indexer's key pages under
+    ``dsa_index``): a picked key's row is one lookup in the one stack
+    that holds the latent and the rope key side by side. Two stacks were
+    two lookups, and a lookup costs the chip by the index
+    (scripts/gather_sweep.py)."""
+    cfg, params = _params(jnp.float32)
+    rows, width = 2, 4
+    cache = jax.eval_shape(lambda: dots3.init_kv_cache(
+        cfg, 9, PAGE, jnp.float32, window_blocks=5))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def step(cache, tokens, positions, tables, slots, context):
+        return dots3.forward(params, cfg, tokens, positions, cache, tables,
+                             slots, context)
+
+    text = jax.jit(step).lower(
+        cache, i32(rows, 1), i32(rows, 1), i32(rows, 2 * width), i32(rows, 1),
+        i32(rows)).as_text(debug_info=True)
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    gathers = [named.get(m, "") for m in re.findall(
+        r'stablehlo\.gather.*loc\((#loc\d+)\)', text)]
+    under = {scope: sum(f"/{scope}/" in name for name in gathers)
+             for scope in ("dsa_index", "dsa_attend", "dsa_select")}
+    assert under == {"dsa_index": 2, "dsa_attend": 2, "dsa_select": 0}
 
 
 @pytest.mark.parametrize("key,value,error", [

@@ -218,9 +218,10 @@ def test_small_blocks_of_queries_and_keys(monkeypatch):
 
 
 # every page no sequence holds, after every pass: a large finite value in
-# the latents (a latent is the value too: every route multiplies it by a
-# weight of exactly 0) and NaN in the rope keys and the indexer's keys,
-# whose scores every route masks by a select
+# the k side (the window kind's latents, the full kind's rows: a latent
+# is the value too, and every route multiplies it by a weight of exactly
+# 0) and NaN in the v side (the window kind's rope keys and the indexer's
+# keys, whose scores every route masks by a select)
 POISON = (1e3, float("nan"))
 
 
@@ -294,10 +295,16 @@ def test_the_picked_sets_equal_the_references():
     assert len(picks) == HF["layer_types"].count(FULL)
 
 
+def _projected(cfg, lp, x, pos):
+    """``mla_project`` of one row as a full layer calls it: (the query's
+    latent, q_nope, q_rope, the latent, the rotated key)."""
+    sq, skv = dots3.lora_rescale(cfg)
+    return deepseek.mla_project(cfg, x, lp, 1, x.shape[1], pos, q_scale=sq,
+                                kv_scale=skv)
+
+
 def mla_cq(cfg, lp, x, pos):
-    return deepseek.mla_project(cfg, x, lp, 1, x.shape[1], pos,
-                                q_scale=dots3.lora_rescale(cfg)[0],
-                                kv_scale=dots3.lora_rescale(cfg)[1])[0]
+    return _projected(cfg, lp, x, pos)[0]
 
 
 def test_pick_mask_keeps_k_whatever_ties_there_are():
@@ -323,7 +330,7 @@ def test_the_indexers_key_is_written_once_a_token_and_read_by_decode():
     cfg, params, lp, seq, x = _layer0(t=81)
     served = Served(cfg, params, jnp.float32)
     _serve_case(served, [seq[:80]], [1], 10, [], 128)
-    ki_all = np.asarray(served.cache[1].full[1])        # [3, N, 1, page, 128]
+    ki_all, = map(np.asarray, served.cache[1].full)     # [3, N, 1, page, 128]
     pages = served.btab[1, :80 // PAGE]
     held = ki_all[:, pages, 0].reshape(3, 80, -1)
     assert (np.abs(held).max(-1) > 0).all()
@@ -334,12 +341,35 @@ def test_the_indexers_key_is_written_once_a_token_and_read_by_decode():
                                atol=1e-5)
     before = served.cache
     sound = served.decode({1: (seq[80], 80)})[1]
-    zeroed = tuple(dataclasses.replace(
-        side, full=(jax.tree.map(jnp.zeros_like, side.full) if i else side.full))
-        for i, side in enumerate(before))
-    served.cache = (zeroed[0], dataclasses.replace(
-        zeroed[1], full=(before[1].full[0], zeroed[1].full[1])))
+    served.cache = (before[0], dataclasses.replace(
+        before[1], full=jax.tree.map(jnp.zeros_like, before[1].full)))
     assert np.abs(served.decode({1: (seq[80], 80)})[1] - sound).max() > WRONG
+
+
+def test_a_full_layers_row_holds_the_latent_then_the_rotated_key():
+    """After a prefill of 70 tokens in two chunks and 10 decode steps a
+    full layer's page holds, at each of the sequence's 80 positions, the
+    token's row whole: the latent in the first ``kv_lora_rank`` lanes,
+    the rotated key from ``lane_pad(kv_lora_rank)`` on (not from the
+    rank: 16 is padded to 128 here), and zeros in every other lane, since
+    decode's one score product sums over them; layer 0's the values
+    ``mla_project`` gives."""
+    cfg, params, lp, seq, x = _layer0(t=80)
+    r, rd, lat = cfg.kv_lora_rank, cfg.qk_rope_head_dim, 128
+    served = Served(cfg, params, jnp.float32)
+    _serve_case(served, [seq], [1], 10, [40], 128)
+    rows_all = np.asarray(served.cache[0].full)         # [3, N, 1, page, 256]
+    assert rows_all.shape[-1] == lat + 128
+    held = rows_all[:, served.btab[1, :80 // PAGE], 0].reshape(3, 80, -1)
+    assert (np.abs(held[..., :r]).max(-1) > 0).all()
+    assert (np.abs(held[..., lat:lat + rd]).max(-1) > 0).all()
+    assert not held[..., r:lat].any() and not held[..., lat + rd:].any()
+    assert not rows_all[:, served.btab[1, 80 // PAGE:]].any()
+    c_kv, kr = _projected(cfg, lp, x,
+                          jnp.arange(80, dtype=jnp.int32)[None])[3:]
+    np.testing.assert_allclose(held[0, :, :r], np.asarray(c_kv)[0], atol=1e-5)
+    np.testing.assert_allclose(held[0, :, lat:lat + rd], np.asarray(kr)[0],
+                               atol=1e-5)
 
 
 def test_the_steps_counters():
