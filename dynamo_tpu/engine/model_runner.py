@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import models
 from ..models import llama, quant
-from ..ops.attention import (_pad_minor, row_list_traced,
+from ..ops.attention import (pad_minor, row_list_traced,
                              table_width_traced)
 from ..telemetry.flight import CompileTracker, StartupTimeline
 from ..telemetry.registry import Counter
@@ -2118,7 +2118,7 @@ class ModelRunner:
                 blocks = blocks.reshape(
                     blocks.shape[:2] + page + blocks.shape[-1:])
                 if isinstance(c, dict):
-                    blocks = _pad_minor(blocks, c["pre"].shape[-1])
+                    blocks = pad_minor(blocks, c["pre"].shape[-1])
                     blocks = blocks.astype(c["pre"].dtype)
                     stg_shape = c["stg"].shape
                     stg = c["stg"].reshape(-1, *stg_shape[2:])
@@ -2129,7 +2129,7 @@ class ModelRunner:
                         "stg": stg.at[:, ids].set(blocks[n_pre:])
                         .reshape(stg_shape),
                     }
-                blocks = _pad_minor(blocks, c.shape[-1]).astype(c.dtype)
+                blocks = pad_minor(blocks, c.shape[-1]).astype(c.dtype)
                 if staged:
                     shape = c.shape
                     c = c.reshape(-1, *shape[2:])

@@ -8,6 +8,21 @@ under the names below; ``engine/`` and ``parallel/`` read those and name
 no family (a ``getattr(arch, ...)`` for a name not listed here fails
 ``tests/test_model_families.py``).
 
+What families share is written once. ``llama`` is the base every family
+imports; ``deepseek`` owns latent attention, ``falcon_h1`` the Mamba-2
+mixer and the records by slot, ``mixtral`` the router and the grouped
+experts. A family whose layers are of more than one kind takes from
+``models/trunk.py`` the walk over them (``walk_runs`` for weights
+stacked by run, ``walk_periods`` for weights stacked by kind behind a
+dense prefix), its ``forward`` over its ``forward_counted``
+(``forward_over``) and a side of its cache (``SlotCache``,
+``KindCache``), and writes no loop over layers of its own; a family
+imports a sibling only for what it declares over it (its
+``SEQUENCE_STATE``), and no name that crosses a module has a leading
+underscore. Its reference test is a tiny config, a reference module,
+tolerances and faults over ``tests/served.py``, the one driver of a
+family's served path (docs/models.md, Adding a family).
+
 **Required** of every module, called by ``ModelRunner``:
 ``init_params(cfg, key, dtype)``, ``param_specs(params)``,
 ``init_kv_cache(cfg, num_blocks, block_size, dtype, num_slots=,

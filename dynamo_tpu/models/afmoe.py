@@ -30,7 +30,7 @@ products with the shared expert beside them (``make_moe_mlp_fn``).
 **Two kinds of page.** A full layer keeps every page of the context; a
 window layer needs only the pages that reach back ``sliding_window``
 tokens from the newest query. So a side of the cache is a
-``KindCache``: one stack of pages over the full layers and one over the
+``trunk.KindCache``: one stack of pages over the full layers and one over the
 window layers, each with its own pool in the allocator
 (engine/block_allocator.py: ``num_kv_blocks`` is the full kind's pool,
 the window kind's is derived, ``EngineConfig.window_pool_pages``) and
@@ -52,7 +52,7 @@ and a preempted sequence resumes by prefill from position 0.
 
 The trunk scans each run of layers of one kind (attention and
 feed-forward alike) over that run's stacked weights (``params["runs"]``;
-``kind_runs``), as models/minicpm_sala.py does.
+``kind_runs``; ``trunk.walk_runs``).
 
 Scopes: ``attn`` with ``attn_window`` or ``attn_full`` inside (norm,
 projections, rope, scatter, kernel, gate, output), and ``kv_window`` or
@@ -61,7 +61,6 @@ projections, rope, scatter, kernel, gate, output), and ``kv_window`` or
 
 from __future__ import annotations
 
-import dataclasses
 import math
 from typing import Any, Dict, List, Tuple
 
@@ -73,11 +72,12 @@ from ..engine.config import ModelConfig
 from ..ops.attention import attention, lane_pad, scatter_kv_stacked
 from ..ops.live_rows import decode_live_rows
 from . import SequenceState
-from .deepseek import random_expert_stacks
-from .llama import (_swiglu_mlp, layer_runs, lm_logits, qkv_prologue,
-                    rms_norm, run_specs)
-from .mixtral import make_moe_mlp_fn, split_expert_stacks
+from .llama import (layer_runs, lm_logits, qkv_prologue, rms_norm,
+                    run_specs, swiglu_mlp)
+from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
+                      split_expert_stacks)
 from .quant import dense
+from .trunk import KindCache, forward_over, walk_runs, window_slots
 
 Params = Dict[str, Any]
 
@@ -185,29 +185,6 @@ ATTN_SCORE_STD = 3.0
 EXPERT_BIAS_STD = 0.05
 
 
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass
-class KindCache:
-    """A side of the cache: the full layers' pages and the window
-    layers', ``[layers of the kind, pages of its pool, block, KVH, D]``
-    each."""
-    full: Any
-    window: Any
-
-    @property
-    def dtype(self):
-        """benchmark/run.py reads ``runner.kv_cache[0].dtype``."""
-        return self.full.dtype
-
-    @property
-    def pages(self):
-        return self.full
-
-    @property
-    def rest(self):
-        return self.window
-
-
 CACHE_SPEC = KindCache(full=P(), window=P())
 
 
@@ -229,7 +206,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     vector of unit size whatever the gate's mean and ``route_scale`` do
     to its inside; the head is drawn for logits of standard deviation
     ``LOGIT_STD``. A layer's experts are one prototype plus a spread
-    (``deepseek.random_expert_stacks``), ``expert_bias`` small normal, float32."""
+    (``mixtral.random_expert_stacks``), ``expert_bias`` small normal, float32."""
     d, inter = cfg.hidden_size, cfg.intermediate_size
     moe_inter = cfg.moe_intermediate_size or inter
     h, kvh, hd, e = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_experts
@@ -303,14 +280,6 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                  for _ in range(2))
 
 
-def window_slots(window_table, positions, slot_mapping, block_size: int):
-    """Where a window layer writes each token: the slot of its position
-    in the page its own table names; -1 where the step writes nothing."""
-    page = jnp.take_along_axis(window_table, positions // block_size, axis=1)
-    return jnp.where(slot_mapping >= 0,
-                     page * block_size + positions % block_size, -1)
-
-
 def _gated(o, x, lp):
     """``o ⊙ sigmoid(a Wg)``: elementwise, before the output projection."""
     gate = jax.nn.sigmoid(dense(x, lp["wg"]).astype(jnp.float32))
@@ -342,12 +311,14 @@ def make_attn_fn(cfg: ModelConfig, b: int, s: int, positions, slots, table,
 
 
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
-                    slot_mapping, context_lens, mesh=None):
+                    slot_mapping, context_lens, mesh=None, state_slots=None):
     """(hidden [B, S, D], cache, int32 [3]: mixtral.routing_stats summed
     over the expert layers), as mixtral.forward_counted.
     ``block_tables`` is ``[B, 2 W]``: the full kind's table, then the
     window kind's."""
-    del mesh    # one device: tp, ep, pp and sp are refused for the family
+    # one device: tp, ep, pp and sp are refused for the family; no
+    # records by slot
+    del mesh, state_slots
     b, s = tokens.shape
     w = block_tables.shape[1] // 2
     tables = {False: block_tables[:, :w], True: block_tables[:, w:]}
@@ -360,27 +331,24 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
         hidden = (hidden.astype(jnp.float32)
                   * cfg.embedding_multiplier).astype(hidden.dtype)
     k_side, v_side = kv_cache
-    pages = {False: (k_side.full, v_side.full),
-             True: (k_side.window, v_side.window)}
     stats = jnp.zeros((3,), jnp.int32)
     eps = cfg.rms_norm_eps
     # the rows of a decode step that hold a token: one list for every
     # run of layers and both kinds of page
     live_rows = decode_live_rows(slot_mapping)
 
-    for ((local, is_dense), start, _), run in zip(kind_runs(cfg),
-                                                  params["runs"]):
+    def layer_of(local, run):
         attn_fn = make_attn_fn(cfg, b, s, positions, slots[local],
                                tables[local], context_lens, local, live_rows)
-        if is_dense:
-            scanned, mlp_fn = run, _swiglu_mlp
-        else:
+        if "router" in run:
             scanned, stacks = split_expert_stacks(run)
             mlp_fn = make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
+        else:       # a run of the dense prefix
+            scanned, mlp_fn = run, swiglu_mlp
         scope = "attn_window" if local else "attn_full"
 
-        def layer(carry, lp, attn_fn=attn_fn, mlp_fn=mlp_fn, scope=scope):
-            hidden, k_all, v_all, li = carry
+        def layer(carry, lp):
+            hidden, (k_all, v_all), li = carry
             with jax.named_scope("attn"), jax.named_scope(scope):
                 delta, k_all, v_all = attn_fn(
                     rms_norm(hidden, lp["ln1"], eps), lp, k_all, v_all, li)
@@ -389,39 +357,21 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                 out = mlp_fn(rms_norm(hidden, lp["ln2"], eps), lp)
                 y, aux = out if isinstance(out, tuple) else (out, None)
                 hidden = hidden + rms_norm(y, lp["ln2_post"], eps)
-            return (hidden, k_all, v_all, li + 1), aux
+            return (hidden, (k_all, v_all), li + 1), aux
 
-        (hidden, k_all, v_all, _), aux = jax.lax.scan(
-            layer, (hidden, *pages[local], jnp.int32(start)), scanned)
-        pages[local] = (k_all, v_all)
-        if aux is not None:
-            stats = stats + aux.sum(axis=0)
+        return scanned, layer
 
+    # (a run is of one attention kind and one kind of feed-forward; the
+    # pages are the attention kind's)
+    runs = [(local, start, n) for (local, _), start, n in kind_runs(cfg)]
+    hidden, pages, stats = walk_runs(
+        runs, params["runs"], layer_of, hidden,
+        {False: (k_side.full, v_side.full),
+         True: (k_side.window, v_side.window)}, stats)
     cache = (KindCache(pages[False][0], pages[True][0]),
              KindCache(pages[False][1], pages[True][1]))
     return hidden, cache, stats
 
 
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,        # [B, S]
-    positions: jax.Array,     # [B, S]
-    kv_cache,                 # init_kv_cache's pair
-    block_tables: jax.Array,  # [B, 2 W]: full kind | window kind
-    slot_mapping: jax.Array,  # [B, S] the full kind's; −1: no token here
-    context_lens: jax.Array,  # [B]
-    mesh=None,
-    return_hidden: bool = False,
-    state_slots=None,         # a family with records by slot reads it
-):
-    hidden, cache, _ = forward_counted(
-        params, cfg, tokens, positions, kv_cache, block_tables,
-        slot_mapping, context_lens, mesh=mesh)
-    if return_hidden:
-        return hidden, cache
-    with jax.named_scope("lm_head"):
-        return lm_logits(hidden, params, cfg), cache
-
-
+forward = forward_over(forward_counted)
 logits_from_hidden = lm_logits

@@ -36,30 +36,32 @@ from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
 from ..ops.attention import (
-    _pad_minor,
     batch_axis,
     kernel_live_rows,
     lane_pad,
+    pad_minor,
     pallas_interpret,
     record_route,
     resolve_attention_impl,
 )
 from ..ops.live_rows import decode_live_rows
 from .llama import (
-    _swiglu_mlp,
     apply_rope,
     base_specs,
     gather_kv_writes,
     lm_logits,
     rms_norm,
     run_layers,
+    swiglu_mlp,
 )
 from . import mhc
 # the one model_type this module is selected by name for is the mixed
 # residual streams': its published keys are that path's
 from .mhc import (CLAIM, CLAIMED_PREFIXES, claimed_keys,  # noqa: F401
                   config_fields)
-from .mixtral import make_moe_mlp_fn, split_expert_stacks
+# (benchmark/references/deepseek_v3.py names EXPERT_SPREAD here)
+from .mixtral import (EXPERT_SPREAD, make_moe_mlp_fn,  # noqa: F401
+                      random_expert_stacks, split_expert_stacks)
 from .quant import dense
 
 Params = Dict[str, Any]
@@ -130,7 +132,7 @@ def scatter_rows_stacked(caches, news, slot_mapping, li):
 
     def put(cache, new):
         d = cache.shape[-1]
-        new = _pad_minor(new, d).astype(cache.dtype).reshape(-1, d)
+        new = pad_minor(new, d).astype(cache.dtype).reshape(-1, d)
         flat = cache.reshape(l * per_layer, d)
         return flat.at[flat_idx].set(new, mode="drop").reshape(cache.shape)
 
@@ -176,29 +178,6 @@ def _attn_params(cfg: ModelConfig, n_layers: int, key, w, dtype) -> Dict:
     else:
         out["wq"] = w(keys[5], (l, d_model, h * (nope + rope)), d_model)
     return out
-
-
-# Random routed experts are unrelated functions, so where rounding flips
-# a near-tie of the router (the 6th and 7th of 64 scores) a token's
-# output jumps as a trained model's does not: a trained router's
-# near-ties are experts that resemble each other. Random experts are
-# therefore drawn as one prototype a layer plus this share of their own
-# (in standard deviations; 1.0 = unrelated): a flipped choice then moves
-# the output by this share of what a wrong expert would otherwise, and
-# every expert is still its own matrix in memory.
-EXPERT_SPREAD = 0.1
-
-
-def random_expert_stacks(key, shape, fan_in, dtype):
-    """[L, E, in, out]: a layer's experts are one prototype plus a spread
-    of their own (EXPERT_SPREAD), at a fan-in-scaled normal's variance
-    (shared with models/afmoe.py)."""
-    kp, ko = jax.random.split(key)
-    proto = jax.random.normal(kp, shape[:1] + (1,) + shape[2:], jnp.float32)
-    own = jax.random.normal(ko, shape, jnp.float32)
-    s = EXPERT_SPREAD
-    return (((1.0 - s * s) ** 0.5 * proto + s * own)
-            * (fan_in ** -0.5)).astype(dtype)
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
@@ -370,8 +349,8 @@ def mla_attention(
     # caches carry lane padding; zero-padded queries score 0 against the
     # zero pad lanes, and the padded latent output is sliced back below
     r = q_lat.shape[-1]
-    q_lat = _pad_minor(q_lat, c_all.shape[-1])
-    q_rope = _pad_minor(q_rope, kr_all.shape[-1])
+    q_lat = pad_minor(q_lat, c_all.shape[-1])
+    q_rope = pad_minor(q_rope, kr_all.shape[-1])
 
     if kernel:
         from ..ops.pallas_decode import mla_paged_decode_attention
@@ -432,14 +411,14 @@ def mla_softmax_scale(cfg) -> float:
     follows the canonical training-time semantics for both —
     tests/test_loaders.py pins this computed scale.
     """
-    from .llama import _yarn_mscale
+    from .llama import yarn_mscale
 
     scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     sc = cfg.rope_scaling or {}
     if (sc.get("rope_type") or sc.get("type")) == "yarn":
         mscale_all = float(sc.get("mscale_all_dim") or 0.0)
         if mscale_all:
-            m = _yarn_mscale(float(sc.get("factor", 1.0)), mscale_all)
+            m = yarn_mscale(float(sc.get("factor", 1.0)), mscale_all)
             scale = scale * m * m
     return scale
 
@@ -621,7 +600,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     if "dense_layers" in params:
         hidden, kv_cache, li, _ = run_layers(
             hidden, kv_cache, params["dense_layers"], cfg, attn_fn,
-            _swiglu_mlp, li0=li,
+            swiglu_mlp, li0=li,
         )
     if "layers" in params:  # present iff the config is MoE
         scanned, stacks = split_expert_stacks(params["layers"])

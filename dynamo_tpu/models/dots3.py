@@ -39,7 +39,7 @@ after attention (models/deepseek.py), so a token's cache line is the
 latent and the rope key (and the indexer's key in a full layer).
 
 **Two kinds of page, a page shape a kind.** A side of the cache is a
-``LatentKinds`` (afmoe's ``KindCache`` with the step's counters): the k
+``LatentKinds`` (``trunk.KindCache`` with the step's counters): the k
 side ``(full [Lf, N, 1, page, r' + rd'], window [Lw, Nw, 1, page,
 r_w'])``, the v side ``(full (indexer keys [Lf, N, 1, page, di'],),
 window rope keys [Lw, Nw, 1, page, rd_w'])``, a primed width its
@@ -70,8 +70,8 @@ blocks of queries against blocks of keys, the pick a mask a query.
 **One body a kind**, as models/kimi_linear.py: the weights are stacked
 by kind (``params["full_attention"]``, ``["sliding_attention"]``,
 ``["dense"]``, ``["moe"]``), the dense prefix is a body a layer and the
-rest one scan over periods (a run of full layers, then the run of
-window layers behind it).
+rest one scan over periods (``trunk.walk_periods``: a run of full
+layers, then the run of window layers behind it).
 
 Scopes: ``attn`` with ``attn_full`` or ``attn_window`` inside;
 ``dsa_index``, ``dsa_select`` and ``dsa_attend`` inside ``mla_cache`` of
@@ -89,18 +89,18 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..engine.config import ModelConfig
-from ..ops.attention import _pad_minor, lane_pad
+from ..ops.attention import lane_pad, pad_minor
 from ..ops.latent_select import (Indexer, blocked_latent_attention,
                                  picked_decode_attention)
 from ..ops.live_rows import decode_live_rows
 from . import afmoe
-from .afmoe import KindCache, window_slots
 from .deepseek import (mla_attention, mla_project, mla_softmax_scale,
-                       random_expert_stacks, scatter_rows_stacked)
-from .kimi_linear import _at, _layout
-from .llama import _swiglu_mlp, apply_rope, lm_logits, rms_norm
-from .mixtral import make_moe_mlp_fn, split_expert_stacks
+                       scatter_rows_stacked)
+from .llama import apply_rope, lm_logits, rms_norm
+from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
+                      split_expert_stacks)
 from .quant import dense
+from .trunk import KindCache, forward_over, walk_periods, window_slots
 
 Params = Dict[str, Any]
 
@@ -296,7 +296,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     keys are spread with a deviation near 1.3 (s_q = 5^½): far over what
     bfloat16 operands round (a few thousandths), so that a pick is a
     pick and not a tie broken by rounding. A layer's experts are one
-    prototype plus a spread (``deepseek.random_expert_stacks``), the
+    prototype plus a spread (``mixtral.random_expert_stacks``), the
     experts held drawn as the stacks they are; the router's correction
     bias small and not zero, as models/deepseek.py."""
     d = cfg.hidden_size
@@ -475,19 +475,19 @@ def make_mixer_fn(cfg: ModelConfig, kind: str, b: int, s: int, positions,
             with jax.named_scope("dsa_index"):
                 qi, ki, wi = index_projections(cfg, lp, x, cq, positions)
             # a token's row: the latent in whole lanes, then the rotated key
-            new = (jnp.concatenate([_pad_minor(c_kv, lat), kr], -1), ki)
+            new = (jnp.concatenate([pad_minor(c_kv, lat), kr], -1), ki)
         caches = scatter_rows_stacked(caches, new, slots, li)
         stacks, index = caches, None     # what a key is read from
         if kind == FULL:
             stacks, keys = caches[:1], caches[1]
             # (zero lanes of a padded query score 0 against the pad)
-            index = Indexer(_pad_minor(qi, keys.shape[-1]), wi, keys,
+            index = Indexer(pad_minor(qi, keys.shape[-1]), wi, keys,
                             cfg.index_topk)
 
         # absorb W_uk into the query, attend over the latent cache
-        q_lat = _pad_minor(jnp.einsum("bshn,hnr->bshr", q_nope, lp["w_uk"]),
+        q_lat = pad_minor(jnp.einsum("bshn,hnr->bshr", q_nope, lp["w_uk"]),
                            lat)
-        q_rope = _pad_minor(q_rope,
+        q_rope = pad_minor(q_rope,
                             sum(st.shape[-1] for st in stacks) - lat)
         if kind == FULL and decode:
             with jax.named_scope("mla_cache"):
@@ -518,12 +518,14 @@ def make_mixer_fn(cfg: ModelConfig, kind: str, b: int, s: int, positions,
 
 
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
-                    slot_mapping, context_lens, mesh=None):
+                    slot_mapping, context_lens, mesh=None, state_slots=None):
     """(hidden [B, S, D], cache, int32 [3]: ``mixtral.routing_stats``
     summed over the expert layers, the experts counted those held).
     ``block_tables`` is ``[B, 2 W]``: the full kind's table, then the
     window kind's (models/afmoe.py)."""
-    del mesh    # one device: tp, ep, pp and sp are refused for the family
+    # one device: tp, ep, pp and sp are refused for the family; no
+    # records by slot
+    del mesh, state_slots
     b, s = tokens.shape
     w = block_tables.shape[1] // 2
     tables = {FULL: block_tables[:, :w], WINDOW: block_tables[:, w:]}
@@ -542,49 +544,23 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                                   tables[kind], valid, context_lens,
                                   live_rows)
               for kind in (FULL, WINDOW)}
-    eps = cfg.rms_norm_eps
-    pages = {FULL: (k_side.full, *v_side.full),
-             WINDOW: (k_side.window, v_side.window)}
 
-    def mixer(kind, carry, i):
-        hidden, pages, stats, fi = carry
-        lp = _at(params[kind], i)
+    def mixer(kind, lp, hidden, pages, i):
         scope = "attn_full" if kind == FULL else "attn_window"
         with jax.named_scope("attn"), jax.named_scope(scope):
             delta, own = mixers[kind](
-                rms_norm(hidden, lp["ln1"], eps), lp, pages[kind], i)
-        return hidden + delta, {**pages, kind: own}, stats, fi
+                rms_norm(hidden, lp["ln1"], cfg.rms_norm_eps), lp,
+                pages[kind], i)
+        return hidden + delta, {**pages, kind: own}
 
-    carry = (hidden, pages, jnp.zeros((3,), jnp.int32), jnp.int32(0))
-    prefix, periods = _layout(cfg, (FULL, WINDOW))
-    for kind, i, di in prefix:      # the dense prefix: a body a layer
-        hidden, *rest = mixer(kind, carry, i)
-        lp = _at(params["dense"], di)
-        with jax.named_scope("mlp"):
-            hidden = hidden + _swiglu_mlp(rms_norm(hidden, lp["ln2"], eps), lp)
-        carry = (hidden, *rest)
-
-    if periods:
+    def experts():
         moe, stacks = split_expert_stacks(params["moe"])
-        moe_fn = make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
+        return moe, make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
 
-        def routed(kind, first):
-            def layer(j, carry):
-                hidden, pages, stats, fi = mixer(kind, carry, first + j)
-                lp = _at(moe, fi)
-                with jax.named_scope("mlp"):
-                    y, aux = moe_fn(rms_norm(hidden, lp["ln2"], eps), lp)
-                return hidden + y, pages, stats + aux, fi + 1
-            return layer
-
-        def period(carry, p):
-            f0, fn, w0, wn = p
-            for kind, first, n in ((FULL, f0, fn), (WINDOW, w0, wn)):
-                carry = jax.lax.fori_loop(0, n, routed(kind, first), carry)
-            return carry, None
-
-        carry, _ = jax.lax.scan(period, carry, periods)
-    hidden, pages, stats, _ = carry
+    hidden, pages, stats = walk_periods(
+        params, cfg, (FULL, WINDOW), mixer, experts, hidden,
+        {FULL: (k_side.full, *v_side.full),
+         WINDOW: (k_side.window, v_side.window)})
 
     counts = v_side.counts
     if s == 1:
@@ -601,26 +577,5 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     return hidden, cache, stats
 
 
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,        # [B, S]
-    positions: jax.Array,     # [B, S]
-    kv_cache,                 # init_kv_cache's pair
-    block_tables: jax.Array,  # [B, 2 W]: full kind | window kind
-    slot_mapping: jax.Array,  # [B, S] the full kind's; −1: no token here
-    context_lens: jax.Array,  # [B]
-    mesh=None,
-    return_hidden: bool = False,
-    state_slots=None,         # a family with records by slot reads it
-):
-    hidden, cache, _ = forward_counted(
-        params, cfg, tokens, positions, kv_cache, block_tables,
-        slot_mapping, context_lens, mesh=mesh)
-    if return_hidden:
-        return hidden, cache
-    with jax.named_scope("lm_head"):
-        return lm_logits(hidden, params, cfg), cache
-
-
+forward = forward_over(forward_counted)
 logits_from_hidden = lm_logits

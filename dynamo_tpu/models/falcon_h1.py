@@ -23,7 +23,7 @@ float32 (the recurrence feeds its own rounding back every token; not an
 option) laid as the decode kernel walks them, ``[H / k, N, k P]``
 (``ops/ssm.state_to_record``: ``P`` on the lanes), plus
 the conv's last ``d_conv − 1`` inputs. Both ride in the cache pytree the
-programs already carry and donate: each side is a ``SlotCache(kv=pages,
+programs already carry and donate: each side is a ``trunk.SlotCache(kv=pages,
 state=records)`` (the k side holds the SSM state, the v side the conv
 window).
 
@@ -49,7 +49,6 @@ Scopes: ``attn`` (attention branch), ``ssm`` (whole mixer) with
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -64,6 +63,7 @@ from . import SequenceState, llama
 from .llama import (ATTN_LAYER_SPECS, base_specs, lm_logits,
                     make_gqa_attn_fn, rms_norm)
 from .quant import dense
+from .trunk import SlotCache, scaled
 
 Params = Dict[str, Any]
 
@@ -171,30 +171,6 @@ LOGIT_STD = 2.0
 # PERF.md §6, PR 31). At 3.0 a few keys carry a row's attention and the
 # cache's precision shows.
 ATTN_SCORE_STD = 3.0
-
-
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass
-class SlotCache:
-    """One side of the cache: the pages and, beside them, the records
-    kept by slot."""
-    kv: Any      # [L, N, block, KVH, D] pages, as llama's
-    state: Any   # [L, slots, ...] one record a layer a slot
-
-    @property
-    def dtype(self):
-        """The pages' element type: what a caller that asks a side of
-        the cache for its dtype means (the records keep their own).
-        benchmark/run.py reads ``runner.kv_cache[0].dtype``."""
-        return self.kv.dtype
-
-    @property
-    def pages(self):
-        return self.kv
-
-    @property
-    def rest(self):
-        return self.state
 
 
 CACHE_SPEC = SlotCache(kv=P(None, None, None, "tp", None), state=P())
@@ -339,13 +315,6 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return SlotCache(k, ssm), SlotCache(v, conv)
 
 
-def _scaled(x: jax.Array, m) -> jax.Array:
-    """``x · m`` with the product taken in float32 and rounded once: a
-    multiplier rounded to bfloat16 first would be off by up to 0.4 %
-    everywhere (the published code multiplies the same way)."""
-    return (x.astype(jnp.float32) * m).astype(x.dtype)
-
-
 def _grouped_rms_norm(y, weight, groups: int, eps: float):
     """RMS norm over each of ``groups`` equal parts of the last axis."""
     shape = y.shape
@@ -429,7 +398,7 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
                                None if decode else positions[:, 0] == 0)
 
     def ssm_fn(x, lp, ssm_all, conv_all, li):
-        u = _scaled(dense(_scaled(x, cfg.ssm_in_multiplier), lp["ssm_in"]), mup)
+        u = scaled(dense(scaled(x, cfg.ssm_in_multiplier), lp["ssm_in"]), mup)
         z, xbc, dt_raw = (u[..., :splits[0]], u[..., splits[0]:splits[3]],
                           u[..., splits[3]:])
         with jax.named_scope("ssm_conv"):
@@ -465,15 +434,15 @@ def make_ssm_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
                 ssm_all = _row_major(write(ssm_all, li, h1))
         y = y.reshape(b, s, d_ssm).astype(x.dtype)
         y = _gated_norm(y, z, lp["ssm_norm"], g, cfg.rms_norm_eps)
-        return (_scaled(dense(y, lp["ssm_out"]), cfg.ssm_out_multiplier),
+        return (scaled(dense(y, lp["ssm_out"]), cfg.ssm_out_multiplier),
                 ssm_all, conv_all)
 
     return ssm_fn
 
 
 def _mlp(cfg: ModelConfig, x, lp):
-    gate = jax.nn.silu(_scaled(dense(x, lp["w_gate"]), cfg.mlp_multipliers[0]))
-    return _scaled(dense(gate * dense(x, lp["w_up"]), lp["w_down"]),
+    gate = jax.nn.silu(scaled(dense(x, lp["w_gate"]), cfg.mlp_multipliers[0]))
+    return scaled(dense(gate * dense(x, lp["w_up"]), lp["w_down"]),
                    cfg.mlp_multipliers[1])
 
 
@@ -494,7 +463,7 @@ def forward(
     if state_slots is None:
         state_slots = jnp.arange(b, dtype=jnp.int32)
     with jax.named_scope("embed"):
-        hidden = _scaled(params["embed"][tokens], cfg.embedding_multiplier)
+        hidden = scaled(params["embed"][tokens], cfg.embedding_multiplier)
     # a decode step's rows that hold a token: one list for the mixer's
     # and the attention's kernels and every layer, made outside the scan
     live_rows = decode_live_rows(slot_mapping)
@@ -510,9 +479,9 @@ def forward(
         with jax.named_scope("ssm"):
             m, ssm, conv = ssm_fn(n1, lp, k_all.state, v_all.state, li)
         with jax.named_scope("attn"):
-            a, k, v = attn_fn(_scaled(n1, cfg.attention_in_multiplier), lp,
+            a, k, v = attn_fn(scaled(n1, cfg.attention_in_multiplier), lp,
                               k_all.kv, v_all.kv, li)
-            a = _scaled(a, cfg.attention_out_multiplier)
+            a = scaled(a, cfg.attention_out_multiplier)
         hidden = hidden + m + a
         with jax.named_scope("mlp"):
             n2 = rms_norm(hidden, lp["ln2"], cfg.rms_norm_eps)
@@ -530,4 +499,4 @@ def forward(
 
 def logits_from_hidden(hidden: jax.Array, params: Params,
                        cfg: ModelConfig) -> jax.Array:
-    return _scaled(lm_logits(hidden, params, cfg), cfg.lm_head_multiplier)
+    return scaled(lm_logits(hidden, params, cfg), cfg.lm_head_multiplier)

@@ -32,12 +32,13 @@ layer: what the absent rank would have added is computed nowhere.
 
 **Two caches, as models/minicpm_sala.py.** The attention layers hold
 pages and no state, the mixer layers state and no pages, so a side of
-the cache is Falcon-H1's ``SlotCache`` stacked over each kind's own
+the cache is a ``trunk.SlotCache`` stacked over each kind's own
 layers: the k side ``(key pages [A, N, block, KVH, D], SSM state [M,
 slots, H / 2, N, 2 P] float32)`` (two heads of 64 side by side on the
 lanes: ``ops/ssm.state_to_record``), the v side ``(value pages, conv
 window [M, slots, d_conv − 1, C])``. The trunk scans each homogeneous run of
-``layer_types`` over that run's stacked weights (``params["runs"]``),
+``layer_types`` over that run's stacked weights (``params["runs"]``;
+``trunk.walk_runs``),
 the expert stacks kept whole and indexed by layer inside the kernel.
 The family keeps recurrent state, so it inherits Falcon-H1's
 ``SEQUENCE_STATE`` and the engine's handling of it.
@@ -60,12 +61,12 @@ from ..engine.config import ModelConfig
 from ..ops.attention import lane_pad
 from ..ops.live_rows import decode_live_rows
 from . import falcon_h1
-from .deepseek import random_expert_stacks
-from .falcon_h1 import (SlotCache, _scaled, conv_dim, make_ssm_fn,
-                        ssm_record_shape)
+from .falcon_h1 import conv_dim, make_ssm_fn, ssm_record_shape
 from .llama import (layer_runs, lm_logits, make_gqa_attn_fn, rms_norm,
                     run_specs)
-from .mixtral import make_moe_mlp_fn, split_expert_stacks
+from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
+                      split_expert_stacks)
+from .trunk import SlotCache, forward_over, scaled, walk_runs
 
 Params = Dict[str, Any]
 
@@ -255,7 +256,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     ``falcon_h1.init_mixer`` but ``A_log``, drawn for a head's horizon
     (``STATE_HORIZON``), and the conv's bias under B and C
     (``BC_CONV_BIAS``); a layer's experts one prototype plus a
-    spread (``deepseek.random_expert_stacks``), the experts held drawn as
+    spread (``mixtral.random_expert_stacks``), the experts held drawn as
     the stacks they are (a share is not a slice of a larger draw)."""
     d, inter = cfg.hidden_size, cfg.moe_intermediate_size
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -363,7 +364,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     if state_slots is None:
         state_slots = jnp.arange(b, dtype=jnp.int32)
     with jax.named_scope("embed"):
-        hidden = _scaled(params["embed"][tokens], cfg.embedding_multiplier)
+        hidden = scaled(params["embed"][tokens], cfg.embedding_multiplier)
     # a decode step's rows that hold a token: one list for the mixer's
     # and the attention's kernels and every run of layers
     live_rows = decode_live_rows(slot_mapping)
@@ -374,60 +375,38 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
         live_rows=live_rows, rope=False, scale=softmax_scale(cfg))
     res, eps = cfg.residual_multiplier, cfg.rms_norm_eps
     k_side, v_side = kv_cache
-    cache = {MAMBA: (k_side.state, v_side.state),
-             ATTENTION: (k_side.kv, v_side.kv)}
-    stats = jnp.zeros((3,), jnp.int32)
 
-    for (kind, start, _), run in zip(layer_runs(cfg.layer_types),
-                                     params["runs"]):
+    def layer_of(kind, run):
         scanned, stacks = split_expert_stacks(run)
         mlp_fn = make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
         scope, mixer = (("ssm", ssm_fn) if kind == MAMBA
                         else ("attn", attn_fn))
 
-        def layer(carry, lp, scope=scope, mixer=mixer, mlp_fn=mlp_fn):
-            hidden, k_all, v_all, li = carry
+        def layer(carry, lp):
+            hidden, (k_all, v_all), li = carry
             with jax.named_scope(scope):
                 delta, k_all, v_all = mixer(
                     rms_norm(hidden, lp["ln1"], eps), lp, k_all, v_all, li)
-            hidden = hidden + _scaled(delta, res)
+            hidden = hidden + scaled(delta, res)
             with jax.named_scope("mlp"):
                 y, aux = mlp_fn(rms_norm(hidden, lp["ln2"], eps), lp)
-                hidden = hidden + _scaled(y, res)
-            return (hidden, k_all, v_all, li + 1), aux
+                hidden = hidden + scaled(y, res)
+            return (hidden, (k_all, v_all), li + 1), aux
 
-        (hidden, k_all, v_all, _), aux = jax.lax.scan(
-            layer, (hidden, *cache[kind], jnp.int32(start)), scanned)
-        cache[kind] = (k_all, v_all)
-        stats = stats + aux.sum(axis=0)
+        return scanned, layer
 
+    hidden, cache, stats = walk_runs(
+        layer_runs(cfg.layer_types), params["runs"], layer_of, hidden,
+        {MAMBA: (k_side.state, v_side.state),
+         ATTENTION: (k_side.kv, v_side.kv)}, jnp.zeros((3,), jnp.int32))
     cache = (SlotCache(cache[ATTENTION][0], cache[MAMBA][0]),
              SlotCache(cache[ATTENTION][1], cache[MAMBA][1]))
     return hidden, cache, stats
 
 
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,        # [B, S]
-    positions: jax.Array,     # [B, S]
-    kv_cache,                 # init_kv_cache's pair
-    block_tables: jax.Array,  # [B, W]
-    slot_mapping: jax.Array,  # [B, S]; −1: no token here
-    context_lens: jax.Array,  # [B]
-    mesh=None,
-    return_hidden: bool = False,
-    state_slots=None,         # [B] each prefill row's slot; decode: row i
-):
-    hidden, cache, _ = forward_counted(
-        params, cfg, tokens, positions, kv_cache, block_tables,
-        slot_mapping, context_lens, mesh=mesh, state_slots=state_slots)
-    if return_hidden:
-        return hidden, cache
-    with jax.named_scope("lm_head"):
-        return logits_from_hidden(hidden, params, cfg), cache
-
-
 def logits_from_hidden(hidden: jax.Array, params: Params,
                        cfg: ModelConfig) -> jax.Array:
-    return _scaled(lm_logits(hidden, params, cfg), cfg.lm_head_multiplier)
+    return scaled(lm_logits(hidden, params, cfg), cfg.lm_head_multiplier)
+
+
+forward = forward_over(forward_counted, logits_from_hidden)

@@ -33,7 +33,7 @@ stacks hold rank ``cfg.expert_rank``'s. A layer adds that share's part
 of the routed sum and the whole shared expert.
 
 **Two caches, each stacked over its own layers** (models/minicpm_sala.py):
-a side of the cache is Falcon-H1's ``SlotCache``: the k side ``(latent
+a side of the cache is a ``trunk.SlotCache``: the k side ``(latent
 pages [A, N, 1, block, r], KDA state [M, slots, H, K, K] float32)``, the
 v side ``(rope-key pages [A, N, 1, block, rd], conv window [M, slots,
 taps − 1, 3 H K])``: ``A`` latent layers, ``M`` KDA layers. The state is
@@ -51,8 +51,8 @@ two take a quarter of it away at no cost the chip shows.
 
 **One body a kind.** The weights are stacked by kind (``params["kda"]``,
 ``["mla"]``, ``["dense"]``, ``["moe"]``), not by run: after the dense
-prefix the trunk is one scan over *periods* (a run of KDA layers, then a
-run of latent layers), each run a loop of traced length over its kind's
+prefix the trunk is one scan over *periods* (``trunk.walk_periods``: a run
+of KDA layers, then a run of latent layers), each run a loop of traced length over its kind's
 stack, so a program holds one KDA body and one latent body whatever the
 lists say (27 layers in 14 runs would otherwise be 14 bodies to
 compile).
@@ -77,12 +77,13 @@ from ..ops.attention import lane_pad
 from ..ops.kda import kda_chunked_scan, kda_decode_step
 from ..ops.live_rows import decode_live_rows
 from . import falcon_h1
-from .deepseek import (make_mla_attn_fn, mla_softmax_scale,
-                       random_expert_stacks)
-from .falcon_h1 import SlotCache, slot_records
-from .llama import _swiglu_mlp, layer_runs, lm_logits, rms_norm
-from .mixtral import make_moe_mlp_fn, split_expert_stacks
+from .deepseek import make_mla_attn_fn, mla_softmax_scale
+from .falcon_h1 import slot_records
+from .llama import lm_logits, rms_norm
+from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
+                      split_expert_stacks)
 from .quant import QuantizedWeight, dense
+from .trunk import SlotCache, forward_over, walk_periods
 
 Params = Dict[str, Any]
 
@@ -200,29 +201,6 @@ GATE_STD = 0.5
 GATE_RMS = 0.54
 
 
-def _layout(cfg: ModelConfig, pair=(KDA, MLA)):
-    """(the dense prefix's layers [(kind, index among its kind, index
-    among the dense)], the periods after it as four int32 vectors: the
-    first KDA layer's index among the KDA layers and how many follow, the
-    same of the latent layers behind them). ``pair``: the two kinds a
-    period is made of, in its order (models/dots3.py: a full layer, then
-    the window layers behind it)."""
-    kinds = cfg.layer_types
-    n_dense = min(cfg.first_k_dense_replace, len(kinds))
-    base = {kind: 0 for kind in pair}
-    prefix = []
-    for i, kind in enumerate(kinds[:n_dense]):
-        prefix.append((kind, base[kind], i))
-        base[kind] += 1
-    periods = []
-    for kind, start, n in layer_runs(kinds[n_dense:]):
-        if kind == pair[0] or not periods:   # the two kinds' runs alternate
-            periods.append([0, 0, 0, 0])
-        at = 0 if kind == pair[0] else 2
-        periods[-1][at:at + 2] = [base[kind] + start, n]
-    return prefix, [jnp.asarray(c, jnp.int32) for c in zip(*periods)]
-
-
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Random weights from the seed, fan-in-scaled normal as in the other
     families, each sublayer adding a vector of about unit size: a KDA
@@ -234,7 +212,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     logits of standard deviation ``LOGIT_STD``. ``A_log`` and ``dt_bias``
     for a channel's horizon (``STATE_HORIZON``), the conv uniform in
     ±taps^-½ without bias; a layer's experts one prototype plus a spread
-    (``deepseek.random_expert_stacks``), the experts held drawn as the
+    (``mixtral.random_expert_stacks``), the experts held drawn as the
     stacks they are (a share is not a slice of a larger draw); the
     router's correction bias small and not zero, as models/deepseek.py."""
     d, h, kd = cfg.hidden_size, cfg.kda_num_heads, cfg.kda_head_dim
@@ -441,12 +419,6 @@ def make_kda_fn(cfg: ModelConfig, b: int, s: int, positions, slot_mapping,
     return kda_fn
 
 
-def _at(stack: Params, i) -> Params:
-    """Layer ``i`` of a kind's stacked weights."""
-    return jax.tree.map(
-        lambda w: jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False), stack)
-
-
 def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                     slot_mapping, context_lens, mesh=None, state_slots=None):
     """(hidden [B, S, D], cache, int32 [3]: ``mixtral.routing_stats``
@@ -470,79 +442,29 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
                          live_rows)
     mla_fn = make_mla_attn_fn(cfg, b, s, positions, slot_mapping,
                               block_tables, context_lens, rope=False)
+    # (made here, ahead of the dense prefix: where a program's operations
+    # stand is part of its text, scripts/layer_loop.py --hash)
     moe, stacks = split_expert_stacks(params["moe"])
     moe_fn = make_moe_mlp_fn(cfg, b, s, slot_mapping, stacks=stacks)
     k_side, v_side = kv_cache
 
-    def normed(hidden, weight):
-        return rms_norm(hidden, weight, cfg.rms_norm_eps).astype(act)
-
-    def mixer(kind, carry, i):
-        hidden, c, kr, state, conv, stats, fi = carry
-        lp = _at(params[kind], i)
-        n1 = normed(hidden, lp["ln1"])
+    def mixer(kind, lp, hidden, cache, i):
+        c, kr, state, conv = cache
+        n1 = rms_norm(hidden, lp["ln1"], cfg.rms_norm_eps).astype(act)
         if kind == KDA:
             with jax.named_scope("kda"):
                 delta, state, conv = kda_fn(n1, lp, state, conv, i)
         else:
             with jax.named_scope("attn"):
                 delta, c, kr = mla_fn(n1, lp, c, kr, i)
-        return hidden + delta, c, kr, state, conv, stats, fi
+        return hidden + delta, (c, kr, state, conv)
 
-    def routed(kind, first):
-        def layer(j, carry):
-            hidden, c, kr, state, conv, stats, fi = mixer(
-                kind, carry, first + j)
-            lp = _at(moe, fi)
-            with jax.named_scope("mlp"):
-                y, aux = moe_fn(normed(hidden, lp["ln2"]), lp)
-            return hidden + y, c, kr, state, conv, stats + aux, fi + 1
-        return layer
-
-    carry = (hidden, k_side.kv, v_side.kv, k_side.state, v_side.state,
-             jnp.zeros((3,), jnp.int32), jnp.int32(0))
-    prefix, periods = _layout(cfg)
-    for kind, i, di in prefix:      # the dense prefix: a body a layer
-        hidden, *rest = mixer(kind, carry, i)
-        lp = _at(params["dense"], di)
-        with jax.named_scope("mlp"):
-            hidden = hidden + _swiglu_mlp(normed(hidden, lp["ln2"]), lp)
-        carry = (hidden, *rest)
-
-    def period(carry, p):
-        k0, kn, m0, mn = p
-        for kind, first, n in ((KDA, k0, kn), (MLA, m0, mn)):
-            if kind in params:
-                carry = jax.lax.fori_loop(0, n, routed(kind, first), carry)
-        return carry, None
-
-    if periods:
-        carry, _ = jax.lax.scan(period, carry, periods)
-    hidden, c, kr, state, conv, stats, _ = carry
+    hidden, (c, kr, state, conv), stats = walk_periods(
+        params, cfg, (KDA, MLA), mixer, lambda: (moe, moe_fn), hidden,
+        (k_side.kv, v_side.kv, k_side.state, v_side.state))
     return (hidden.astype(act), (SlotCache(c, state), SlotCache(kr, conv)),
             stats)
 
 
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,        # [B, S]
-    positions: jax.Array,     # [B, S]
-    kv_cache,                 # init_kv_cache's pair
-    block_tables: jax.Array,  # [B, W]
-    slot_mapping: jax.Array,  # [B, S]; −1: no token here
-    context_lens: jax.Array,  # [B]
-    mesh=None,
-    return_hidden: bool = False,
-    state_slots=None,         # [B] each prefill row's slot; decode: row i
-):
-    hidden, cache, _ = forward_counted(
-        params, cfg, tokens, positions, kv_cache, block_tables,
-        slot_mapping, context_lens, mesh=mesh, state_slots=state_slots)
-    if return_hidden:
-        return hidden, cache
-    with jax.named_scope("lm_head"):
-        return lm_logits(hidden, params, cfg), cache
-
-
+forward = forward_over(forward_counted)
 logits_from_hidden = lm_logits

@@ -45,7 +45,7 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (norm * weight.astype(jnp.float32)).astype(dtype)
 
 
-def _yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     if factor <= 1.0:
         return 1.0
     return 0.1 * mscale * math.log(factor) + 1.0
@@ -121,11 +121,11 @@ def rope_frequencies(
                 # DeepSeek variant: ratio of the two mscale curves —
                 # taken only when BOTH keys are present, exactly as
                 # transformers' _compute_yarn_parameters does
-                attention_factor = _yarn_mscale(factor, mscale) / _yarn_mscale(
+                attention_factor = yarn_mscale(factor, mscale) / yarn_mscale(
                     factor, mscale_all
                 )
             else:
-                attention_factor = _yarn_mscale(factor)
+                attention_factor = yarn_mscale(factor)
         return inv, float(attention_factor)
     if kind in ("longrope", "su"):
         # handled in apply_rope: the short/long factor choice depends on
@@ -332,7 +332,7 @@ def embed_tokens(params: Params, tokens: jax.Array) -> jax.Array:
     return params["embed"][tokens]
 
 
-def _swiglu_mlp(x: jax.Array, layer_params) -> jax.Array:
+def swiglu_mlp(x: jax.Array, layer_params) -> jax.Array:
     gate = jax.nn.silu(dense(x, layer_params["w_gate"]))
     return dense(gate * dense(x, layer_params["w_up"]), layer_params["w_down"])
 
@@ -525,7 +525,7 @@ def sp_decoder_forward(
     mesh,
     sp_axis: str = "sp",
     head_axis=None,
-    mlp_fn=_swiglu_mlp,
+    mlp_fn=swiglu_mlp,
 ) -> Tuple[jax.Array, KVCache]:
     """One sequence-parallel prefill chunk through the GQA trunk.
 
@@ -624,7 +624,7 @@ def decoder_forward(
     slot_mapping: jax.Array,  # [B, S] flat cache slot per token; -1 drops
     context_lens: jax.Array,  # [B] valid tokens incl. the ones being written
     mesh=None,                # multi-device mesh for the pallas shard_map path
-    mlp_fn=_swiglu_mlp,       # (normed_x [B,S,D], layer_params) -> [B,S,D]
+    mlp_fn=swiglu_mlp,       # (normed_x [B,S,D], layer_params) -> [B,S,D]
     return_hidden: bool = False,
 ) -> Tuple[jax.Array, KVCache]:
     """Shared decoder trunk: embed → scan(attention + mlp_fn) → logits.
@@ -680,7 +680,7 @@ def embed_forward(
 
     dummy = jnp.zeros((), jnp.float32)
     hidden, _, _, _ = run_layers(
-        hidden, (dummy, dummy), params["layers"], cfg, attn_fn, _swiglu_mlp
+        hidden, (dummy, dummy), params["layers"], cfg, attn_fn, swiglu_mlp
     )
     hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
     rows = jnp.arange(b)
@@ -703,6 +703,6 @@ def forward(
     """Llama forward = shared trunk with the dense SwiGLU MLP."""
     return decoder_forward(
         params, cfg, tokens, positions, kv_cache, block_tables,
-        slot_mapping, context_lens, mesh=mesh, mlp_fn=_swiglu_mlp,
+        slot_mapping, context_lens, mesh=mesh, mlp_fn=swiglu_mlp,
         return_hidden=return_hidden,
     )

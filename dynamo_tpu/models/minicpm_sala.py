@@ -31,7 +31,7 @@ then ``W_o``.
 
 **Two caches.** The attention layers hold pages and no state, the
 lightning layers state and no pages, so each is stacked over its own
-layers only: a side of the cache is Falcon-H1's ``SlotCache(kv, state)``
+layers only: a side of the cache is a ``trunk.SlotCache(kv, state)``
 with the step's counters beside it, the k side with the key pages ``[A,
 N·KVH, page, D]`` (a kv head a page: ops/sparse_attention.py) and the
 lightning state ``[L, slots, H, d, d]`` float32 (a head's ``[key,
@@ -65,11 +65,11 @@ from ..ops import sparse_attention as sparse
 from ..ops.attention import lane_pad
 from ..ops.live_rows import decode_live_rows
 from ..ops.ssm import record_shape, ssd_chunked_scan, ssm_decode_step
-from .falcon_h1 import (CLAIM, SEQUENCE_STATE, SlotCache,  # noqa: F401
-                        _scaled, slot_records)
-from .llama import (_swiglu_mlp, apply_rope, layer_runs, lm_logits,
-                    rms_norm, run_specs)
+from .falcon_h1 import CLAIM, SEQUENCE_STATE, slot_records  # noqa: F401
+from .llama import (apply_rope, layer_runs, lm_logits, rms_norm, run_specs,
+                    swiglu_mlp)
 from .quant import dense
+from .trunk import SlotCache, scaled, walk_runs
 
 Params = Dict[str, Any]
 
@@ -314,7 +314,7 @@ def make_lightning_fn(cfg: ModelConfig, b: int, s: int, positions,
                        positions, cfg.rope_theta)
         k = apply_rope(rms_norm(k, lp["k_norm"], cfg.rms_norm_eps),
                        positions, cfg.rope_theta)
-        q = _scaled(q, hd ** -0.5)
+        q = scaled(q, hd ** -0.5)
         a = lp["log_decay"].astype(jnp.float32)
         if decode:
             with jax.named_scope("lightning_state"):
@@ -396,7 +396,7 @@ def forward(
     if state_slots is None:
         state_slots = jnp.arange(b, dtype=jnp.int32)
     with jax.named_scope("embed"):
-        hidden = _scaled(params["embed"][tokens], cfg.scale_emb)
+        hidden = scaled(params["embed"][tokens], cfg.scale_emb)
     # a decode step's rows that hold a token: one list for both kinds
     # of layer, made outside their scans
     live_rows = decode_live_rows(slot_mapping)
@@ -406,41 +406,39 @@ def forward(
                                block_tables, context_lens, live_rows)
     res = residual_scale(cfg)
     k_side, v_side = kv_cache
-    k_pages, state = k_side.kv, k_side.state
-    v_pages, means = v_side.kv, v_side.state
-    kept = context_lens.astype(jnp.int32)
 
     def feed_forward(hidden, lp):
         with jax.named_scope("mlp"):
             n2 = rms_norm(hidden, lp["ln2"], cfg.rms_norm_eps)
-            return hidden + _scaled(_swiglu_mlp(n2, lp), res)
+            return hidden + scaled(swiglu_mlp(n2, lp), res)
 
     def lightning_layer(carry, lp):
         hidden, state, li = carry
         n1 = rms_norm(hidden, lp["ln1"], cfg.rms_norm_eps)
         with jax.named_scope("lightning"):
             delta, state = lightning_fn(n1, lp, state, li)
-        hidden = feed_forward(hidden + _scaled(delta, res), lp)
+        hidden = feed_forward(hidden + scaled(delta, res), lp)
         return (hidden, state, li + 1), None
 
     def sparse_layer(carry, lp):
-        hidden, k_pages, v_pages, means, _, li = carry
+        hidden, (k_pages, v_pages, means, _), li = carry
         n1 = rms_norm(hidden, lp["ln1"], cfg.rms_norm_eps)
         with jax.named_scope("attn"):
             delta, k_pages, v_pages, means, kept = sparse_fn(
                 n1, lp, k_pages, v_pages, means, li)
-        hidden = feed_forward(hidden + _scaled(delta, res), lp)
-        return (hidden, k_pages, v_pages, means, kept, li + 1), None
+        hidden = feed_forward(hidden + scaled(delta, res), lp)
+        return (hidden, (k_pages, v_pages, means, kept), li + 1), None
 
-    for (kind, start, _), run in zip(layer_runs(cfg.mixer_types),
-                                     params["runs"]):
-        if kind == LIGHTNING:
-            (hidden, state, _), _ = jax.lax.scan(
-                lightning_layer, (hidden, state, jnp.int32(start)), run)
-        else:
-            (hidden, k_pages, v_pages, means, kept, _), _ = jax.lax.scan(
-                sparse_layer,
-                (hidden, k_pages, v_pages, means, kept, jnp.int32(start)), run)
+    # a sparse layer hands on, behind its pages and means, the keys its
+    # rows kept: the last one's are the step's count
+    hidden, cache, _ = walk_runs(
+        layer_runs(cfg.mixer_types), params["runs"], lambda kind, run: (
+            run, lightning_layer if kind == LIGHTNING else sparse_layer),
+        hidden,
+        {LIGHTNING: k_side.state,
+         SPARSE: (k_side.kv, v_side.kv, v_side.state,
+                  context_lens.astype(jnp.int32))})
+    state, (k_pages, v_pages, means, kept) = cache[LIGHTNING], cache[SPARSE]
 
     live = slot_mapping >= 0
     if s == 1:
@@ -463,4 +461,4 @@ def logits_from_hidden(hidden: jax.Array, params: Params,
                        cfg: ModelConfig) -> jax.Array:
     width = cfg.hidden_size / (cfg.dim_model_base or cfg.hidden_size)
     # the head is linear: dividing its output is dividing its input
-    return _scaled(lm_logits(hidden, params, cfg), 1.0 / width)
+    return scaled(lm_logits(hidden, params, cfg), 1.0 / width)

@@ -398,6 +398,29 @@ def gptoss_moe(
     return y, routing_stats(gate_idx, valid, e)
 
 
+# Random routed experts are unrelated functions, so where rounding flips
+# a near-tie of the router (the 6th and 7th of 64 scores) a token's
+# output jumps as a trained model's does not: a trained router's
+# near-ties are experts that resemble each other. Random experts are
+# therefore drawn as one prototype a layer plus this share of their own
+# (in standard deviations; 1.0 = unrelated): a flipped choice then moves
+# the output by this share of what a wrong expert would otherwise, and
+# every expert is still its own matrix in memory.
+EXPERT_SPREAD = 0.1
+
+
+def random_expert_stacks(key, shape, fan_in, dtype):
+    """[L, E, in, out]: a layer's experts are one prototype plus a spread
+    of their own (EXPERT_SPREAD), at a fan-in-scaled normal's variance
+    (every family whose random experts a router picks among)."""
+    kp, ko = jax.random.split(key)
+    proto = jax.random.normal(kp, shape[:1] + (1,) + shape[2:], jnp.float32)
+    own = jax.random.normal(ko, shape, jnp.float32)
+    s = EXPERT_SPREAD
+    return (((1.0 - s * s) ** 0.5 * proto + s * own)
+            * (fan_in ** -0.5)).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     l, d_model = cfg.num_layers, cfg.hidden_size
     h, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

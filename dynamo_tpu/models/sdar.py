@@ -55,10 +55,9 @@ import jax.numpy as jnp
 from ..engine.config import ModelConfig
 from ..ops.pallas_decode import VERIFY_MAX_S
 from . import REMASKING, BlockUnit
-from .deepseek import random_expert_stacks
 from .llama import init_kv_cache  # noqa: F401  (one kind of page)
 from .mixtral import (forward, forward_counted,  # noqa: F401
-                      logits_from_hidden, param_specs)
+                      logits_from_hidden, param_specs, random_expert_stacks)
 
 Params = Dict[str, Any]
 
@@ -178,7 +177,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     families (models/afmoe.py, models/deepseek.py): an embedding of unit
     size; every projection a fan-in-scaled normal; every norm weighs 1.0
     but the query's, which weighs ``ATTN_SCORE_STD``; a layer's experts
-    are one prototype plus a spread (``deepseek.random_expert_stacks``);
+    are one prototype plus a spread (``mixtral.random_expert_stacks``);
     the head is drawn for logits of standard deviation ``LOGIT_STD``.
     The per-head q/k norms exist here whatever a checkpoint brings."""
     l, d = cfg.num_layers, cfg.hidden_size

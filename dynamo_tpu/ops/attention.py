@@ -131,7 +131,7 @@ def lane_pad(d: int) -> int:
     return -(-d // LANE) * LANE
 
 
-def _pad_minor(x: jax.Array, d: int) -> jax.Array:
+def pad_minor(x: jax.Array, d: int) -> jax.Array:
     """Zero-pad the trailing dim of x up to d (no-op if already d)."""
     if x.shape[-1] == d:
         return x
@@ -153,8 +153,8 @@ def scatter_kv(
     n_blocks, block_size, kvh, dk = k_cache.shape
     vh, dv = v_cache.shape[-2:]
     # cast at the write (fp8 KV cache stores e4m3; no-op otherwise)
-    new_k = _pad_minor(new_k, dk).astype(k_cache.dtype)
-    new_v = _pad_minor(new_v, dv).astype(v_cache.dtype)
+    new_k = pad_minor(new_k, dk).astype(k_cache.dtype)
+    new_v = pad_minor(new_v, dv).astype(v_cache.dtype)
     flat_k = k_cache.reshape(n_blocks * block_size, kvh, dk)
     flat_v = v_cache.reshape(n_blocks * block_size, vh, dv)
     idx = slot_mapping.reshape(-1)
@@ -186,8 +186,8 @@ def scatter_kv_stacked(
     """
     l, n_blocks, block_size, kvh, dk = k_all.shape
     vh, dv = v_all.shape[-2:]
-    new_k = _pad_minor(new_k, dk).astype(k_all.dtype)
-    new_v = _pad_minor(new_v, dv).astype(v_all.dtype)
+    new_k = pad_minor(new_k, dk).astype(k_all.dtype)
+    new_v = pad_minor(new_v, dv).astype(v_all.dtype)
     idx = slot_mapping.reshape(-1)
     # drop sentinel AND per-layer overflow → past-the-end: a negative index
     # would wrap (see scatter_kv), and a positive out-of-range one would land
@@ -407,7 +407,7 @@ def attention(
     if scale is None:
         scale = d ** -0.5
     dk = k_cache.shape[-1]
-    q = _pad_minor(q, dk)  # zero pad lanes score 0 against zero cache pad
+    q = pad_minor(q, dk)  # zero pad lanes score 0 against zero cache pad
     # small-S tails (the speculative verify's K+1 positions; follows the
     # flash kernel's affine base_pos contract, so small custom prefill
     # buckets mask correctly too) take the fused verify kernel: ONE page

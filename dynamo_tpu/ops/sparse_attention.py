@@ -53,7 +53,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import (_pad_minor, pallas_interpret, record_route,
+from .attention import (pad_minor, pallas_interpret, record_route,
                         record_row_list, record_table_width,
                         resolve_attention_impl)
 from .live_rows import LiveRows
@@ -187,7 +187,7 @@ def scatter_head_pages(k_all, v_all, k, v, slot_mapping, li):
 
     def put(pages_all, new):
         flat = pages_all.reshape(l * rows, d)
-        new = _pad_minor(new, d).astype(pages_all.dtype).reshape(-1, d)
+        new = pad_minor(new, d).astype(pages_all.dtype).reshape(-1, d)
         return flat.at[idx].set(new, mode="drop").reshape(pages_all.shape)
 
     return put(k_all, k), put(v_all, v)
@@ -275,7 +275,7 @@ def decode_attention(q, k_all, v_all, means_all, li, block_tables,
     wider than ``dense_len`` holds no row that selects: its program has
     no selection in it. ``live_rows``: the (row, kv head) pairs whose
     row holds a token (``head_live_rows``), the pairs the kernel walks."""
-    q = _pad_minor(q, k_all.shape[-1])   # the cache's lanes; pad lanes are zero
+    q = pad_minor(q, k_all.shape[-1])   # the cache's lanes; pad lanes are zero
     b, _, h, d = q.shape
     w = block_tables.shape[1]
     page = shape.page
@@ -326,7 +326,7 @@ def prefill_attention(q, k_all, v_all, means_all, li, block_tables,
     and V) and a tile of ``PREFILL_QUERY_TILE`` queries at a time scores
     the compressed keys, keeps its blocks query by query, and takes the
     dense product under that mask and the causal one."""
-    q = _pad_minor(q, k_all.shape[-1])   # the cache's lanes; pad lanes are zero
+    q = pad_minor(q, k_all.shape[-1])   # the cache's lanes; pad lanes are zero
     b, s, h, d = q.shape
     g = h // kvh
     w = block_tables.shape[1]

@@ -216,7 +216,7 @@ def pipeline_forward(
     embed_fn = getattr(arch, "embed_tokens", llama.embed_tokens)
     make_attn = getattr(arch, "make_attn_fn", llama.make_gqa_attn_fn)
     run_layers_fn = getattr(arch, "run_layers", llama.run_layers)
-    family_mlp = getattr(arch, "mlp_fn", llama._swiglu_mlp)
+    family_mlp = getattr(arch, "mlp_fn", llama.swiglu_mlp)
     # routed-MoE families expose a per-tick mlp factory taking the
     # manual ep axis (mixtral.make_moe_mlp_fn; gptoss.make_mlp_fn)
     moe_maker = None
@@ -343,7 +343,7 @@ def pipeline_forward(
                 )
                 injected, (k_pre, v_pre), _, _ = run_layers_fn(
                     injected, (k_pre, v_pre), params["dense_layers"],
-                    cfg, pre_attn, llama._swiglu_mlp,
+                    cfg, pre_attn, llama.swiglu_mlp,
                 )
             x_in = jnp.where(is_first, injected, x_state)
 

@@ -15,11 +15,7 @@ long and every chunk and every page of decode releases.
 
 import asyncio
 import dataclasses
-import os
-import sys
-import types
 import uuid
-from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +23,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu import models
-from dynamo_tpu.engine.block_allocator import WindowPool, window_keep_from
+from dynamo_tpu.engine.block_allocator import window_keep_from
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.model_runner import ModelRunner
 from dynamo_tpu.engine.scheduler import EngineRequest, Scheduler
@@ -41,10 +37,7 @@ from dynamo_tpu.protocols.common import (
 from dynamo_tpu.runtime.engine import AsyncEngineContext
 from dynamo_tpu.telemetry.registry import MetricsRegistry
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import afmoe as reference  # noqa: E402
 
 WINDOW = 32
@@ -77,8 +70,7 @@ F32_ATOL = 1e-3
 
 
 def _cfg(hf=HF, **over):
-    return dataclasses.replace(ModelConfig.from_hf_config(hf),
-                               **{"attention_impl": "xla", **over})
+    return served.cfg_of(hf, **over)
 
 
 def _params(dtype, seed=7, **over):
@@ -87,160 +79,17 @@ def _params(dtype, seed=7, **over):
 
 
 def _reference_logprobs(params, seq, hf=HF):
-    t_pad = -(-len(seq) // 128) * 128
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(hf, t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, hf, params, seq, pad=128)
 
 
-class Served:
-    """The family's forward over the two page stacks, driven as the
-    engine drives it. The full kind's pages are a slot's own; the window
-    kind's come from a real ``WindowPool`` through the scheduler's own
-    ``_release_window`` and ``_take_window`` (called unbound on a
-    stand-in that has what they read), released before every pass by
-    that pass's first query. ``poison``: after every pass, every page
-    that no sequence holds (both kinds' free pages and the two pages 0)
-    is filled with ``poison[0]`` in K and ``poison[1]`` in V."""
-
-    def __init__(self, cfg, params, dtype, poison=None, pool_pages=None,
-                 family=afmoe):
-        self.cfg, self.vocab, self.poison = cfg, cfg.vocab_size, poison
-        pool_pages = pool_pages or SLOTS * WIDTH + 1
-        self.cache = family.init_kv_cache(cfg, SLOTS * WIDTH + 1, PAGE, dtype,
-                                          window_blocks=pool_pages)
-        # page 0 of the full kind is nobody's: an idle row's table points there
-        self.btab = 1 + np.arange(SLOTS * WIDTH, dtype=np.int32).reshape(SLOTS, WIDTH)
-        self.pool = WindowPool(pool_pages, MetricsRegistry())
-        self.sched = types.SimpleNamespace(
-            config=types.SimpleNamespace(model=cfg, kv_block_size=PAGE),
-            window=self.pool, passes=0,
-            _host=types.SimpleNamespace(wtab=np.zeros((SLOTS, WIDTH), np.int32)))
-        self.rows = [types.SimpleNamespace(slot=s, window_ids=deque(),
-                                           window_first=0) for s in range(SLOTS)]
-        self.tokens = [0] * SLOTS          # tokens of context written a slot
-        self.peak = {"prefill": 0, "decode": 0}
-        self.released = []                 # pages given back, a pass
-        self.fwd = jax.jit(
-            lambda cache, tok, pos, bt, slot, ctx: family.forward(
-                params, cfg, tok, pos, cache, bt, slot, ctx))
-
-    def start(self, slot):
-        """A new sequence in ``slot``: the old one's pages go back."""
-        Scheduler._drop_window(self.sched, self.rows[slot])
-        self.sched._host.wtab[slot] = 0
-        self.tokens[slot] = 0
-
-    def _window_pages(self, slot, first, last, phase):
-        row, before = self.rows[slot], self.pool.available
-        Scheduler._release_window(self.sched, row, first)
-        freed = self.pool.available - before
-        assert Scheduler._take_window(self.sched, row, last // PAGE + 1)
-        self.peak[phase] = max(self.peak[phase], len(row.window_ids))
-        self.tokens[slot] = last + 1
-        return freed
-
-    def _page_slots(self, slot, positions):
-        return self.btab[slot, positions // PAGE] * PAGE + positions % PAGE
-
-    def _run(self, tok, pos, bt, slot, ctx):
-        logits, self.cache = self.fwd(
-            self.cache, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(bt),
-            jnp.asarray(slot), jnp.asarray(ctx))
-        if self.poison is not None:
-            self._poison()
-        return np.asarray(jax.nn.log_softmax(logits.astype(jnp.float32), -1))
-
-    def _poison(self):
-        held = np.concatenate([self.btab[s, :-(-n // PAGE)]
-                               for s, n in enumerate(self.tokens)])
-        free = {"full": np.setdiff1d(np.arange(SLOTS * WIDTH + 1), held),
-                "window": np.asarray([0] + self.pool.free)}
-        # (a kind's pages may be several stacks: models/dots3.py)
-        self.cache = tuple(
-            dataclasses.replace(side, **{
-                kind: jax.tree.map(lambda x: x.at[:, ids].set(value),
-                                   getattr(side, kind))
-                for kind, ids in free.items()})
-            for side, value in zip(self.cache, self.poison))
-
-    def tables(self, slot):
-        return np.concatenate([self.btab[slot], self.sched._host.wtab[slot]])
-
-    def prefill(self, rows, width):
-        """``rows``: (slot, tokens, start) or None for a pad row."""
-        b = len(rows)
-        tok = np.zeros((b, width), np.int32)
-        pos = np.zeros((b, width), np.int32)
-        slot = np.full((b, width), -1, np.int32)
-        bt = np.zeros((b, 2 * WIDTH), np.int32)
-        ctx, freed = np.ones(b, np.int32), 0
-        for i, row in enumerate(rows):
-            if row is None:
-                continue
-            s, toks, start = row
-            n = len(toks)
-            freed += self._window_pages(s, start, start + n - 1, "prefill")
-            tok[i, :n] = toks
-            pos[i, :n], pos[i, n:] = np.arange(start, start + n), start + n - 1
-            slot[i, :n] = self._page_slots(s, pos[i, :n])
-            bt[i], ctx[i] = self.tables(s), start + n
-        self.released.append(freed)
-        lp = self._run(tok, pos, bt, slot, ctx)
-        return [None if r is None else lp[i, :len(r[1])]
-                for i, r in enumerate(rows)]
-
-    def decode(self, rows):
-        """``rows``: {slot: (token, position)}; the other slots idle."""
-        tok = np.zeros((SLOTS, 1), np.int32)
-        pos = np.zeros((SLOTS, 1), np.int32)
-        slot = np.full((SLOTS, 1), -1, np.int32)
-        bt = np.zeros((SLOTS, 2 * WIDTH), np.int32)
-        freed = 0
-        for s, (t, p) in rows.items():
-            freed += self._window_pages(s, p, p, "decode")
-            tok[s, 0], pos[s, 0], bt[s] = t, p, self.tables(s)
-            slot[s, 0] = self._page_slots(s, np.asarray(p))
-        self.released.append(freed)
-        lp = self._run(tok, pos, bt, slot, pos[:, 0] + 1)
-        return {s: lp[s, 0] for s in rows}
+def Served(cfg, params, dtype, **kw):
+    """32 pages of 16 a slot behind page 0, which is nobody's; the
+    window kind's pages from a real ``WindowPool`` (``served.Served``)."""
+    return served.Served(afmoe, cfg, params, dtype, block=PAGE, width=WIDTH,
+                         slots=SLOTS, **kw)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
-
-
-def _serve_case(served, seqs, slots, n_decode, cuts, width, pad_row=False):
-    """Prefill each sequence's prompt in chunks cut at ``cuts``, all
-    sequences as rows of the same steps, then decode ``n_decode``
-    teacher-forced tokens. Returns the log-softmax at every position."""
-    lens = [len(q) - n_decode for q in seqs]
-    out = [np.zeros((len(q), served.vocab), np.float32) for q in seqs]
-    for s in slots:
-        served.start(s)
-    edges = [0] + list(cuts) + [max(lens)]
-    for lo, hi in zip(edges[:-1], edges[1:]):
-        rows, who = [], []
-        for i, q in enumerate(seqs):
-            a, b = min(lo, lens[i]), min(hi, lens[i])
-            if b > a:
-                rows.append((slots[i], q[a:b], a))
-                who.append((i, a, b))
-        if pad_row:
-            rows.insert(1, None)
-            who.insert(1, None)
-        for got, w in zip(served.prefill(rows, width), who):
-            if w is not None:
-                out[w[0]][w[1]:w[2]] = got
-    for step in range(n_decode):
-        got = served.decode({slots[i]: (q[lens[i] + step], lens[i] + step)
-                             for i, q in enumerate(seqs)})
-        for i in range(len(seqs)):
-            out[i][lens[i] + step] = got[slots[i]]
-    return out
+_seqs, _serve_case = served.seqs, served.serve_case
 
 
 CASES = {
@@ -447,7 +296,7 @@ def _wrong(fault, monkeypatch):
                 *a, sliding_window=None, **k))
     elif fault == "window_one_key_short":
         cfg = dataclasses.replace(cfg, sliding_window=WINDOW - 1)
-    return Served(cfg, served_params, jnp.float32), params
+    return Served(cfg, served_params, jnp.float32, fresh=True), params
 
 
 @pytest.mark.parametrize("fault", [

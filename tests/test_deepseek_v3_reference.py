@@ -8,23 +8,17 @@ top-3 + 2 shared, sigmoid scores, a non-zero selection bias
 (``noaux_tc``), routed scaling 2.446.
 """
 
-import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.model_runner import ModelRunner
 from dynamo_tpu.models import deepseek
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import deepseek_v3 as reference  # noqa: E402
 
 HF = {
@@ -60,8 +54,7 @@ BF16_ATOL = 0.25
 
 
 def _cfg(attention_impl="xla"):
-    cfg = ModelConfig.from_hf_config(HF)
-    return dataclasses.replace(cfg, attention_impl=attention_impl)
+    return served.cfg_of(HF, attention_impl=attention_impl)
 
 
 def _params(dtype):
@@ -74,66 +67,20 @@ def _params(dtype):
 
 def _reference_logprobs(params, seq):
     """The reference's log-probabilities at every position of ``seq``."""
-    t_pad = -(-len(seq) // 8) * 8
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(HF, t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, HF, params, seq)
 
 
-def _serve(cfg, params, prompts, n_decode, chunk, dtype):
+def _serve(cfg, params, prompts, n_decode, chunk, dtype, fresh=False):
     """Prefill the prompts (in chunks of ``chunk`` tokens, padded to it)
     and decode ``n_decode`` teacher-forced tokens through the paged
     latent cache; returns per sequence the log-softmax of the logits at
     every position. ``prompts`` carry their continuation: prompt_len
     tokens are prefilled, the next n_decode are fed one a step."""
-    b = len(prompts)
-    lens = [len(p) - n_decode for p in prompts]
-    w = 8
-    cache = deepseek.init_kv_cache(cfg, b * w, BLOCK, dtype)
-    btab = np.arange(b * w, dtype=np.int32).reshape(b, w)
-    out = [np.zeros((len(p), HF["vocab_size"]), np.float32) for p in prompts]
-    fwd = jax.jit(lambda *a: deepseek.forward(params, cfg, *a))
-
-    def run(tok, pos, slot, ctx):
-        logits, new = fwd(jnp.asarray(tok), jnp.asarray(pos), cache,
-                          jnp.asarray(btab), jnp.asarray(slot), jnp.asarray(ctx))
-        return np.asarray(jax.nn.log_softmax(logits.astype(jnp.float32), -1)), new
-
-    def slots(i, positions):
-        return btab[i, positions // BLOCK] * BLOCK + positions % BLOCK
-
-    for start in range(0, max(lens), chunk):
-        tok = np.zeros((b, chunk), np.int32)
-        pos = np.zeros((b, chunk), np.int32)
-        slot = np.full((b, chunk), -1, np.int32)
-        ctx = np.zeros(b, np.int32)
-        for i, p in enumerate(prompts):
-            n = max(0, min(chunk, lens[i] - start))
-            ctx[i] = min(lens[i], start + chunk)
-            if n:
-                tok[i, :n] = p[start:start + n]
-                pos[i, :n] = np.arange(start, start + n)
-                pos[i, n:] = start + n - 1
-                slot[i, :n] = slots(i, pos[i, :n])
-        lp, cache = run(tok, pos, slot, ctx)
-        for i in range(b):
-            n = max(0, min(chunk, lens[i] - start))
-            out[i][start:start + n] = lp[i, :n]
-    for step in range(n_decode):
-        pos = np.asarray([[n + step] for n in lens], np.int32)
-        tok = np.asarray([[p[n + step]] for p, n in zip(prompts, lens)], np.int32)
-        slot = np.stack([slots(i, pos[i]) for i in range(b)])
-        lp, cache = run(tok, pos, slot, pos[:, 0] + 1)
-        for i in range(b):
-            out[i][lens[i] + step] = lp[i, 0]
-    return out
+    return served.serve_chunks(deepseek, cfg, params, prompts, n_decode, chunk,
+                               dtype, block=BLOCK, fresh=fresh)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
+_seqs = served.seqs
 
 
 CASES = {
@@ -161,16 +108,8 @@ def test_served_path_equals_reference(case, dtype):
     c = CASES[case]
     seqs = _seqs(c["lengths"], seed=len(case))
     got = _serve(cfg, params, seqs, c["n_decode"], c["chunk"], dt)
-    worst = []
-    for seq, lp in zip(seqs, got):
-        want = _reference_logprobs(params, seq)
-        if dtype == "float32":
-            np.testing.assert_allclose(lp, want, rtol=0, atol=F32_ATOL)
-        worst.extend(np.abs(lp - want).max(axis=1))
-    if dtype == "bfloat16":
-        worst = np.asarray(worst)
-        assert np.median(worst) < BF16_MEDIAN
-        assert np.mean(worst < BF16_ATOL) >= 0.9
+    served.assert_close(got, [_reference_logprobs(params, q) for q in seqs],
+                        dtype, F32_ATOL, BF16_MEDIAN, BF16_ATOL, bf16_share=0.9)
 
 
 @pytest.mark.parametrize("rounded", ["router_logits_bf16", "cache_fp8"])
@@ -193,9 +132,10 @@ def test_reference_tells_precisions_apart(rounded):
         p2["layers"] = dict(params["layers"])
         p2["layers"]["router"] = params["layers"]["router"].astype(
             jnp.bfloat16).astype(jnp.float32)
-        got = _serve(cfg, p2, [seq], 8, 16, jnp.float32)[0]
+        got = _serve(cfg, p2, [seq], 8, 16, jnp.float32, fresh=True)[0]
     else:
-        got = _serve(cfg, params, [seq], 8, 16, jnp.float8_e4m3fn)[0]
+        got = _serve(cfg, params, [seq], 8, 16, jnp.float8_e4m3fn,
+                     fresh=True)[0]
     err = np.abs(got[:-1][idx] - want[:-1][idx]).mean()
     assert base_err < 1e-5
     assert err > 100 * base_err

@@ -14,10 +14,11 @@ import pytest
 
 from dynamo_tpu import models
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-from dynamo_tpu.models import afmoe, deepseek, dots3, mixtral
-from test_dots3_reference import (FULL, HF, PAGE, SWA, TOPK, WINDOW, WRONG,
-                                  Served, _cfg, _params, _reference_logprobs,
-                                  _seqs, _serve_case, reference)
+from dynamo_tpu.models import afmoe, deepseek, dots3, mixtral, trunk
+
+from dots3_tiny import (FULL, HF, PAGE, SWA, TOPK, WINDOW, WRONG, Served,
+                        _cfg, _params, _reference_logprobs, _seqs,
+                        _serve_case, reference)
 
 
 # ---------- wrong programs ----------
@@ -47,7 +48,8 @@ def test_a_wrong_program_is_told_apart(fault):
     latent norms' constants left out or the full layers' rope base in the
     window layers, the same weights read far outside the float32 limit."""
     cfg, params = _params(jnp.float32)
-    served = Served(WRONG_PROGRAMS[fault](cfg), params, jnp.float32)
+    served = Served(WRONG_PROGRAMS[fault](cfg), params, jnp.float32,
+                    fresh=True)
     seq = _seqs([100 + 30], seed=len("three_chunks"))[0]
     got = _serve_case(served, [seq], [0], 30, [40, 77], 64)[0]
     assert np.abs(got - _reference_logprobs(params, seq)).max() > WRONG
@@ -200,7 +202,7 @@ def test_the_published_config_reaches_the_family():
         (2, 32, 50000.0)
     assert dots3.lora_rescale(cfg) == (2 ** 0.5, 2.0)
     assert dots3.lora_rescale(window) == (2 ** 0.5, 2 ** 0.5)
-    prefix, periods = dots3._layout(cfg, (FULL, SWA))
+    prefix, periods = trunk.period_layout(cfg, (FULL, SWA))
     assert prefix == [(FULL, 0, 0)]
     assert [p.tolist() for p in periods] == [[1, 2], [1, 1], [0, 3], [3, 3]]
     assert dots3.SEQUENCE_STATE.window_pool and dots3.SEQUENCE_STATE.private

@@ -15,107 +15,19 @@ released page and a page boundary all occur inside a hundred tokens.
 """
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-from dynamo_tpu.models import deepseek, dots3, mixtral
+from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.models import deepseek, dots3
 from dynamo_tpu.ops import latent_select
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-from references import dots3 as reference  # noqa: E402
-from test_afmoe_reference import (PAGE, Served as _Served,  # noqa: E402
-                                  _serve_case)
-
-TOPK, WINDOW = 32, 17
-FULL, SWA = "full_attention", "sliding_attention"
-HF = {
-    "architectures": ["Dots3NoteForCausalLM"], "model_type": "dots3_note",
-    "vocab_size": 256, "hidden_size": 64, "intermediate_size": 96,
-    "moe_intermediate_size": 24, "num_hidden_layers": 9,
-    "layer_types": [FULL, FULL, SWA, SWA, SWA, FULL, SWA, SWA, SWA],
-    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 32,
-    "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
-    "v_head_dim": 16, "swa_num_attention_heads": 2,
-    "swa_num_key_value_heads": 2, "swa_q_lora_rank": 32,
-    "swa_kv_lora_rank": 32, "swa_qk_nope_head_dim": 24,
-    "swa_qk_rope_head_dim": 8, "swa_v_head_dim": 16, "swa_rope_theta": 50000,
-    "swa_attention_gate_type": "headwise", "attention_gate_type": "headwise",
-    "apply_mla_qkv_lora_rescale": True, "index_head_dim": 16,
-    "index_n_heads": 4, "index_topk": TOPK, "sliding_window_size": WINDOW,
-    "first_k_dense_replace": 1, "n_routed_experts": 16, "n_shared_experts": 1,
-    "num_experts_per_tok": 3, "norm_topk_prob": True,
-    "routed_scaling_factor": 1, "scoring_func": "sigmoid",
-    "topk_method": "noaux_tc", "moe_layer_freq": 1, "hidden_act": "silu",
-    "rms_norm_eps": 1e-5, "rope_theta": 80000000, "rope_scaling": None,
-    "attention_bias": False, "tie_word_embeddings": False,
-    "max_position_embeddings": 512,
-}
-# rank ``r`` of four: four of the sixteen experts held
-SHARES = {r: {**HF, "n_routed_experts": 4,
-              "expert_share": {"of_experts": 16, "rank": r}} for r in range(4)}
-# float32 on both sides: the two differ in the order of the sums (absorbed
-# paged attention in blocks against un-absorbed dense, the cutoff search
-# against a sort, sorted grouped products against every expert in turn)
-# and in nothing else; differences seen are 4e-5 in log-probability, and
-# the smallest deliberate fault below reads over 1e-2
-F32_ATOL = 1e-3
-WRONG = 5e-3
-# bfloat16 weights, activations and pages (indexer and router scores
-# float32) against the float32 reference on the same weights, the
-# largest difference over the vocabulary at one position; at a hidden
-# size of 64 rounding is coarser than on the chip
-BF16_MEDIAN = 0.4
-BF16_ATOL = 2.0
-
-
-def _cfg(hf=HF, **over):
-    return dataclasses.replace(ModelConfig.from_hf_config(hf),
-                               **{"attention_impl": "xla", **over})
-
-
-def _params(dtype, hf=HF, seed=7, **over):
-    cfg = _cfg(hf, **over)
-    return cfg, dots3.init_params(cfg, jax.random.PRNGKey(seed), dtype)
-
-
-def _share_of(params, rank, held=4):
-    """Rank ``rank``'s experts of the uncut model's sixteen."""
-    keep = slice(held * rank, held * rank + held)
-    moe = {k: (v[:, keep] if k in mixtral.EXPERT_STACKS else v)
-           for k, v in params["moe"].items()}
-    return {**params, "moe": moe}
-
-
-def _reference_logprobs(params, seq, hf=HF, lower=(), picked_out=None):
-    t_pad = -(-len(seq) // 128) * 128
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(hf, t_pad, len(seq), lower=lower,
-                         picked_out=picked_out)
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
-
-
-def Served(cfg, params, dtype, **kw):
-    """afmoe's driver of a family with two pools (the window kind's
-    pages from a real ``WindowPool`` through the scheduler's own release
-    and take), over this family's cache and forward."""
-    return _Served(cfg, params, dtype, family=dots3, **kw)
-
-
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
-
+from dots3_tiny import (BF16_ATOL, BF16_MEDIAN, F32_ATOL, FULL,  # noqa: E402
+                        HF, PAGE, SHARES, TOPK, WRONG, Served, _cfg, _params,
+                        _reference_logprobs, _seqs, _serve_case, _share_of)
 
 CASES = {
     # a prompt under the window and the pick in one chunk; decode across
@@ -214,7 +126,8 @@ def test_small_blocks_of_queries_and_keys(monkeypatch):
     monkeypatch.setattr(latent_select, "QUERY_BLOCK", 32)
     monkeypatch.setattr(latent_select, "KEY_BLOCK", 48)
     cfg, params = _params(jnp.float32)
-    _check_case("batch_unequal", Served(cfg, params, jnp.float32), params)
+    _check_case("batch_unequal", Served(cfg, params, jnp.float32, fresh=True),
+                params)
 
 
 # every page no sequence holds, after every pass: a large finite value in

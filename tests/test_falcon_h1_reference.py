@@ -12,8 +12,6 @@ in 2 groups, every published multiplier.
 
 import asyncio
 import dataclasses
-import os
-import sys
 import uuid
 
 import jax
@@ -35,10 +33,7 @@ from dynamo_tpu.protocols.common import (
 )
 from dynamo_tpu.runtime.engine import AsyncEngineContext
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import falcon_h1 as reference  # noqa: E402
 
 HF = {
@@ -80,8 +75,7 @@ BF16_ATOL = 0.5
 
 
 def _cfg(**over):
-    cfg = ModelConfig.from_hf_config(HF)
-    return dataclasses.replace(cfg, attention_impl="xla", **over)
+    return served.cfg_of(HF, **over)
 
 
 def _params(dtype, seed=7):
@@ -91,116 +85,17 @@ def _params(dtype, seed=7):
 
 def _reference_logprobs(params, seq):
     """The reference's log-probabilities at every position of ``seq``."""
-    t_pad = -(-len(seq) // 8) * 8
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(HF, t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, HF, params, seq)
 
 
-class Served:
-    """The family's forward over a paged cache of ``SLOTS`` slots, driven
-    as the engine drives it: a prefill step's rows name their slots and
-    may be fewer, padded or idle; a decode step has one row a slot."""
-
-    def __init__(self, cfg, params, dtype, state_dtype=None, family=falcon_h1):
-        # ``family``: another module with records by slot in a SlotCache
-        # (tests/test_granite_hybrid_reference.py drives its own this way)
-        self.cfg, self.vocab = cfg, cfg.vocab_size
-        self.w = 48        # blocks a sequence
-        cache = family.init_kv_cache(cfg, SLOTS * self.w, BLOCK, dtype,
-                                     num_slots=SLOTS)
-        if state_dtype is not None:      # a deliberately wrong program
-            cache = (dataclasses.replace(
-                cache[0], state=cache[0].state.astype(state_dtype)), cache[1])
-        self.cache = cache
-        self.btab = np.arange(SLOTS * self.w, dtype=np.int32).reshape(SLOTS, self.w)
-        self.fwd = jax.jit(
-            lambda cache, tok, pos, bt, slot, ctx, ss: family.forward(
-                params, cfg, tok, pos, cache, bt, slot, ctx, state_slots=ss))
-
-    def _page_slots(self, slot, positions):
-        return self.btab[slot, positions // BLOCK] * BLOCK + positions % BLOCK
-
-    def _run(self, tok, pos, bt, slot, ctx, ss):
-        logits, self.cache = self.fwd(
-            self.cache, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(bt),
-            jnp.asarray(slot), jnp.asarray(ctx), jnp.asarray(ss, jnp.int32))
-        return np.asarray(jax.nn.log_softmax(logits.astype(jnp.float32), -1))
-
-    def prefill(self, rows, width):
-        """``rows``: (slot, tokens, start) or None for a pad row; each
-        row's tokens sit at positions start.. and are padded to
-        ``width``. Returns the log-softmax at every valid position."""
-        b = len(rows)
-        tok = np.zeros((b, width), np.int32)
-        pos = np.zeros((b, width), np.int32)
-        slot = np.full((b, width), -1, np.int32)
-        bt = np.zeros((b, self.w), np.int32)
-        ctx, ss = np.ones(b, np.int32), np.zeros(b, np.int32)
-        for i, row in enumerate(rows):
-            if row is None:
-                continue
-            s, toks, start = row
-            n = len(toks)
-            tok[i, :n] = toks
-            pos[i, :n], pos[i, n:] = np.arange(start, start + n), start + n - 1
-            slot[i, :n] = self._page_slots(s, pos[i, :n])
-            bt[i], ctx[i], ss[i] = self.btab[s], start + n, s
-        lp = self._run(tok, pos, bt, slot, ctx, ss)
-        return [None if r is None else lp[i, :len(r[1])]
-                for i, r in enumerate(rows)]
-
-    def decode(self, rows):
-        """``rows``: {slot: (token, position)}; the other slots idle."""
-        tok = np.zeros((SLOTS, 1), np.int32)
-        pos = np.zeros((SLOTS, 1), np.int32)
-        slot = np.full((SLOTS, 1), -1, np.int32)
-        for s, (t, p) in rows.items():
-            tok[s, 0], pos[s, 0] = t, p
-            slot[s, 0] = self._page_slots(s, np.asarray(p))
-        lp = self._run(tok, pos, self.btab, slot, pos[:, 0] + 1,
-                       np.arange(SLOTS))
-        return {s: lp[s, 0] for s in rows}
-
-    def state(self):
-        return (np.asarray(self.cache[0].state, np.float32),
-                np.asarray(self.cache[1].state, np.float32))
+def Served(cfg, params, dtype, state_dtype=None, fresh=False):
+    """48 pages of 8 a slot, every page a slot's own."""
+    return served.Served(falcon_h1, cfg, params, dtype, block=BLOCK, width=48,
+                         slots=SLOTS, spare=False, state_dtype=state_dtype,
+                         fresh=fresh)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
-
-
-def _serve_case(served, seqs, slots, n_decode, cuts, width, pad_row=False):
-    """Prefill each sequence's prompt in chunks cut at ``cuts`` (shared
-    boundaries, clipped to each prompt), all sequences as rows of the
-    same steps, then decode ``n_decode`` teacher-forced tokens. Returns
-    the log-softmax at every position of every sequence."""
-    lens = [len(q) - n_decode for q in seqs]
-    out = [np.zeros((len(q), served.vocab), np.float32) for q in seqs]
-    edges = [0] + list(cuts) + [max(lens)]
-    for lo, hi in zip(edges[:-1], edges[1:]):
-        rows, who = [], []
-        for i, q in enumerate(seqs):
-            a, b = min(lo, lens[i]), min(hi, lens[i])
-            if b > a:
-                rows.append((slots[i], q[a:b], a))
-                who.append((i, a, b))
-        if pad_row:
-            rows.insert(1, None)
-            who.insert(1, None)
-        for got, w in zip(served.prefill(rows, width), who):
-            if w is not None:
-                out[w[0]][w[1]:w[2]] = got
-    for step in range(n_decode):
-        got = served.decode({slots[i]: (q[lens[i] + step], lens[i] + step)
-                             for i, q in enumerate(seqs)})
-        for i in range(len(seqs)):
-            out[i][lens[i] + step] = got[slots[i]]
-    return out
+_seqs, _serve_case = served.seqs, served.serve_case
 
 
 CASES = {
@@ -235,15 +130,8 @@ def test_served_path_equals_reference(case, dtype):
     slots = c.get("slots", list(range(len(seqs))))
     got = _serve_case(Served(cfg, params, dt), seqs, slots, c["n_decode"],
                       c["cuts"], c["width"], c.get("pad_row", False))
-    worst = []
-    for seq, lp in zip(seqs, got):
-        want = _reference_logprobs(params, seq)
-        if dtype == "float32":
-            np.testing.assert_allclose(lp, want, rtol=0, atol=F32_ATOL)
-        worst.extend(np.abs(lp - want).max(axis=1))
-    if dtype == "bfloat16":
-        assert np.median(worst) < BF16_MEDIAN
-        assert np.max(worst) < BF16_ATOL
+    served.assert_close(got, [_reference_logprobs(params, q) for q in seqs],
+                        dtype, F32_ATOL, BF16_MEDIAN, BF16_ATOL)
 
 
 def test_resume_after_preemption_and_slot_reuse():
@@ -357,10 +245,34 @@ def test_reference_tells_wrong_programs_apart(fault, monkeypatch):
     seq = _seqs([24 + 40], seed=6)[0]
     want = _reference_logprobs(params, seq)
     cfg, wrong_params, kw = _wrong(fault, monkeypatch)
-    got = _serve_case(Served(cfg, wrong_params, jnp.float32, **kw), [seq], [0],
-                      40, [], 32)[0]
+    got = _serve_case(Served(cfg, wrong_params, jnp.float32, fresh=True, **kw),
+                      [seq], [0], 40, [], 32)[0]
     print(fault, np.abs(got - want).max())
     assert np.abs(got - want).max() > 3 * F32_ATOL
+
+
+def test_a_fresh_program_is_traced_again_after_a_cached_run(monkeypatch):
+    """What the fault cases of every reference file stand on: after the
+    sound program has run and is kept (``served.program``), a module
+    patched into a wrong one is what a ``fresh`` driver runs; the same
+    driver without ``fresh`` would have run the kept program and told
+    nothing apart."""
+    cfg, params = _params(jnp.float32)
+    seq = _seqs([24 + 8], seed=6)[0]
+
+    def run(**kw):
+        return _serve_case(Served(cfg, params, jnp.float32, **kw), [seq], [0],
+                           8, [], 32)[0]
+
+    sound = run()
+    assert served.program(falcon_h1, cfg) is served.program(falcon_h1, cfg)
+    kept = len(served._PROGRAMS)
+    monkeypatch.setattr(falcon_h1, "_gated_norm",
+                        lambda y, z, w, g, eps: jnp.zeros_like(y))
+    np.testing.assert_array_equal(run(), sound)       # the kept program
+    patched = run(fresh=True)
+    assert np.abs(patched - sound).max() > 3 * F32_ATOL
+    assert len(served._PROGRAMS) == kept               # and it was not kept
 
 
 def _engine_config(**over):

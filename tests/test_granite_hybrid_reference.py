@@ -15,7 +15,6 @@ its square root).
 
 import dataclasses
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -29,13 +28,10 @@ from dynamo_tpu.models import granite_hybrid, llama, mixtral
 from dynamo_tpu.ops import ssm
 from dynamo_tpu.ops.live_rows import decode_live_rows
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import granite_hybrid as reference  # noqa: E402
-from test_falcon_h1_reference import (BLOCK, SLOTS,  # noqa: E402
-                                      Served as _Served, _serve_case)
+
+BLOCK, SLOTS = 8, 4
 
 HF = {
     "architectures": ["GraniteMoeHybridForCausalLM"],
@@ -78,8 +74,7 @@ BF16_ATOL = 0.8
 
 
 def _cfg(hf=HF, **over):
-    cfg = ModelConfig.from_hf_config(hf)
-    return dataclasses.replace(cfg, attention_impl="xla", **over)
+    return served.cfg_of(hf, **over)
 
 
 def _params(dtype, hf=HF, seed=7):
@@ -98,25 +93,18 @@ def _share_of(params, rank):
 
 def _reference_logprobs(params, seq, hf=HF):
     """The reference's log-probabilities at every position of ``seq``."""
-    t_pad = -(-len(seq) // 8) * 8
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(hf, t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, hf, params, seq)
 
 
-def Served(cfg, params, dtype, state_dtype=None):
-    """Falcon-H1's driver of a family with records by slot (a paged cache
-    of ``SLOTS`` slots driven as the engine drives it: prefill rows name
-    their slots and may be fewer, padded or idle; a decode step has one
-    row a slot), over this family's cache and forward."""
-    return _Served(cfg, params, dtype, state_dtype, family=granite_hybrid)
+def Served(cfg, params, dtype, state_dtype=None, fresh=False):
+    """48 pages of 8 a slot, every page a slot's own (as
+    tests/test_falcon_h1_reference.py drives Falcon-H1)."""
+    return served.Served(granite_hybrid, cfg, params, dtype, block=BLOCK, width=48,
+                         slots=SLOTS, spare=False, state_dtype=state_dtype,
+                         fresh=fresh)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
+_seqs, _serve_case = served.seqs, served.serve_case
 
 
 CASES = {
@@ -146,15 +134,8 @@ def _compare(case, dtype, hf, params_of=lambda p: p):
     slots = c.get("slots", list(range(len(seqs))))
     got = _serve_case(Served(cfg, params, dt), seqs, slots, c["n_decode"],
                       c["cuts"], c["width"], c.get("pad_row", False))
-    worst = []
-    for seq, lp in zip(seqs, got):
-        want = _reference_logprobs(params, seq, hf)
-        if dtype == "float32":
-            np.testing.assert_allclose(lp, want, rtol=0, atol=F32_ATOL)
-        worst.extend(np.abs(lp - want).max(axis=1))
-    if dtype == "bfloat16":
-        assert np.median(worst) < BF16_MEDIAN
-        assert np.max(worst) < BF16_ATOL
+    served.assert_close(got, [_reference_logprobs(params, q, hf) for q in seqs],
+                        dtype, F32_ATOL, BF16_MEDIAN, BF16_ATOL)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -239,7 +220,8 @@ def test_a_wrong_program_is_told_apart(fault, monkeypatch):
         spec["patch"](monkeypatch)
     c = CASES["decode_40"]
     seq = _seqs(c["lengths"], seed=3)[0]
-    served = Served(cfg, params, jnp.float32, spec.get("state_dtype"))
+    served = Served(cfg, params, jnp.float32, spec.get("state_dtype"),
+                    fresh=True)
     got = _serve_case(served, [seq], [0], c["n_decode"], c["cuts"], c["width"])[0]
     off = np.abs(got - _reference_logprobs(params, seq)).max()
     assert off > WRONG, off

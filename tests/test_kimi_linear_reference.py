@@ -15,8 +15,6 @@ dense feed-forward, and 2 + 1), 4 KDA heads of 16, 4 latent heads of 16
 """
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,17 +23,14 @@ import pytest
 
 from dynamo_tpu import models
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.models import deepseek, kimi_linear, llama, mixtral
+from dynamo_tpu.models import deepseek, kimi_linear, llama, mixtral, trunk
 from dynamo_tpu.ops import kda
 from dynamo_tpu.ops.live_rows import decode_live_rows
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import kimi_linear as reference  # noqa: E402
-from test_falcon_h1_reference import (BLOCK, SLOTS,  # noqa: E402
-                                      Served as _Served, _serve_case)
+
+BLOCK, SLOTS = 8, 4
 
 HF = {
     "architectures": ["KimiLinearForCausalLM"], "model_type": "kimi_linear",
@@ -79,8 +74,7 @@ BF16_ATOL = 2.5
 
 
 def _cfg(hf=HF, **over):
-    cfg = ModelConfig.from_hf_config(hf)
-    return dataclasses.replace(cfg, attention_impl="xla", **over)
+    return served.cfg_of(hf, **over)
 
 
 def _params(dtype, hf=HF, seed=7):
@@ -99,23 +93,18 @@ def _share_of(params, rank, held=4):
 
 def _reference_logprobs(params, seq, hf=HF, lower=()):
     """The reference's log-probabilities at every position of ``seq``."""
-    t_pad = -(-len(seq) // 8) * 8
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(hf, t_pad, len(seq), lower=lower)
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, hf, params, seq, lower=lower)
 
 
-def Served(cfg, params, dtype, state_dtype=None):
-    """Falcon-H1's driver of a family with records by slot, over this
-    family's cache and forward."""
-    return _Served(cfg, params, dtype, state_dtype, family=kimi_linear)
+def Served(cfg, params, dtype, state_dtype=None, fresh=False):
+    """48 pages of 8 a slot, every page a slot's own (as
+    tests/test_falcon_h1_reference.py drives Falcon-H1)."""
+    return served.Served(kimi_linear, cfg, params, dtype, block=BLOCK, width=48,
+                         slots=SLOTS, spare=False, state_dtype=state_dtype,
+                         fresh=fresh)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
+_seqs, _serve_case = served.seqs, served.serve_case
 
 
 CASES = {
@@ -145,15 +134,8 @@ def _compare(case, dtype, hf, params_of=lambda p: p):
     slots = c.get("slots", list(range(len(seqs))))
     got = _serve_case(Served(cfg, params, dt), seqs, slots, c["n_decode"],
                       c["cuts"], c["width"], c.get("pad_row", False))
-    worst = []
-    for seq, lp in zip(seqs, got):
-        want = _reference_logprobs(params, seq, hf)
-        if dtype == "float32":
-            np.testing.assert_allclose(lp, want, rtol=0, atol=F32_ATOL)
-        worst.extend(np.abs(lp - want).max(axis=1))
-    if dtype == "bfloat16":
-        assert np.median(worst) < BF16_MEDIAN
-        assert np.max(worst) < BF16_ATOL
+    served.assert_close(got, [_reference_logprobs(params, q, hf) for q in seqs],
+                        dtype, F32_ATOL, BF16_MEDIAN, BF16_ATOL)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -385,7 +367,8 @@ def test_a_wrong_program_is_told_apart(fault, monkeypatch):
         spec["patch"](monkeypatch)
     c = CASES["decode_40"]
     seq = _seqs(c["lengths"], seed=3)[0]
-    served = Served(cfg, params, jnp.float32, spec.get("state_dtype"))
+    served = Served(cfg, params, jnp.float32, spec.get("state_dtype"),
+                    fresh=True)
     got = _serve_case(served, [seq], [0], c["n_decode"], c["cuts"], c["width"])[0]
     off = np.abs(got - _reference_logprobs(params, seq)).max()
     assert off > WRONG, off
@@ -556,7 +539,7 @@ def test_the_published_config_reaches_the_family():
             cfg.norm_topk_prob, cfg.routed_scaling_factor) == \
         (3, 1, "sigmoid", True, 2.446)
     assert cfg.max_position_embeddings == 512 and cfg.q_lora_rank == 0
-    prefix, periods = kimi_linear._layout(cfg)
+    prefix, periods = trunk.period_layout(cfg, ("kda", "mla"))
     assert prefix == [("kda", 0, 0)]
     assert [p.tolist() for p in periods] == [[1, 2], [1, 2], [0, 1], [1, 1]]
     share = ModelConfig.from_hf_config(SHARES[1])

@@ -14,8 +14,6 @@ published page, kernel and block sizes with ``dense_len`` 64, a window of
 
 import asyncio
 import dataclasses
-import os
-import sys
 import uuid
 
 import jax
@@ -37,10 +35,7 @@ from dynamo_tpu.protocols.common import (
 )
 from dynamo_tpu.runtime.engine import AsyncEngineContext
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import minicpm_sala as reference  # noqa: E402
 
 SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64, "topk": 2,
@@ -73,8 +68,7 @@ F32_ATOL = 1e-3
 
 
 def _cfg(hf=HF, **over):
-    return dataclasses.replace(ModelConfig.from_hf_config(hf),
-                               attention_impl="xla", **over)
+    return served.cfg_of(hf, **over)
 
 
 def _params(dtype, seed=7, hf=HF):
@@ -83,110 +77,18 @@ def _params(dtype, seed=7, hf=HF):
 
 
 def _reference_logprobs(params, seq, hf=HF):
-    t_pad = -(-len(seq) // 128) * 128
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = reference.build(hf, t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
+    return served.reference_logprobs(reference, hf, params, seq, pad=128)
 
 
-class Served:
-    """The family's forward over a paged cache of ``SLOTS`` slots, driven
-    as the engine drives it: a prefill step's rows name their slots; a
-    decode step has one row a slot, the others idle."""
-
-    def __init__(self, cfg, params, dtype, state_dtype=None):
-        self.cfg, self.vocab = cfg, cfg.vocab_size
-        cache = minicpm_sala.init_kv_cache(cfg, SLOTS * WIDTH + 1, PAGE, dtype,
-                                           num_slots=SLOTS)
-        if state_dtype is not None:      # a deliberately wrong program
-            cache = (dataclasses.replace(
-                cache[0], state=cache[0].state.astype(state_dtype)), cache[1])
-        self.cache = cache
-        # block 0 is nobody's: an idle row's table points there
-        self.btab = 1 + np.arange(SLOTS * WIDTH, dtype=np.int32).reshape(SLOTS, WIDTH)
-        self.fwd = jax.jit(
-            lambda cache, tok, pos, bt, slot, ctx, ss: minicpm_sala.forward(
-                params, cfg, tok, pos, cache, bt, slot, ctx, state_slots=ss))
-
-    def _page_slots(self, slot, positions):
-        return self.btab[slot, positions // PAGE] * PAGE + positions % PAGE
-
-    def _run(self, tok, pos, bt, slot, ctx, ss):
-        logits, self.cache = self.fwd(
-            self.cache, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(bt),
-            jnp.asarray(slot), jnp.asarray(ctx), jnp.asarray(ss, jnp.int32))
-        return np.asarray(jax.nn.log_softmax(logits.astype(jnp.float32), -1))
-
-    def prefill(self, rows, width):
-        """``rows``: (slot, tokens, start) or None for a pad row."""
-        b = len(rows)
-        tok = np.zeros((b, width), np.int32)
-        pos = np.zeros((b, width), np.int32)
-        slot = np.full((b, width), -1, np.int32)
-        bt = np.zeros((b, WIDTH), np.int32)
-        ctx, ss = np.ones(b, np.int32), np.zeros(b, np.int32)
-        for i, row in enumerate(rows):
-            if row is None:
-                continue
-            s, toks, start = row
-            n = len(toks)
-            tok[i, :n] = toks
-            pos[i, :n], pos[i, n:] = np.arange(start, start + n), start + n - 1
-            slot[i, :n] = self._page_slots(s, pos[i, :n])
-            bt[i], ctx[i], ss[i] = self.btab[s], start + n, s
-        lp = self._run(tok, pos, bt, slot, ctx, ss)
-        return [None if r is None else lp[i, :len(r[1])]
-                for i, r in enumerate(rows)]
-
-    def decode(self, rows):
-        """``rows``: {slot: (token, position)}; the other slots idle."""
-        tok = np.zeros((SLOTS, 1), np.int32)
-        pos = np.zeros((SLOTS, 1), np.int32)
-        slot = np.full((SLOTS, 1), -1, np.int32)
-        bt = np.zeros((SLOTS, WIDTH), np.int32)
-        for s, (t, p) in rows.items():
-            tok[s, 0], pos[s, 0], bt[s] = t, p, self.btab[s]
-            slot[s, 0] = self._page_slots(s, np.asarray(p))
-        lp = self._run(tok, pos, bt, slot, pos[:, 0] + 1, np.arange(SLOTS))
-        return {s: lp[s, 0] for s in rows}
-
-    def counts(self):
-        return np.asarray(minicpm_sala.step_counts(self.cache))
+def Served(cfg, params, dtype, state_dtype=None, fresh=False):
+    """32 pages of 16 a slot behind block 0, which is nobody's: an idle
+    row's table points there."""
+    return served.Served(minicpm_sala, cfg, params, dtype, block=PAGE,
+                         width=WIDTH, slots=SLOTS, state_dtype=state_dtype,
+                         fresh=fresh)
 
 
-def _seqs(lengths, seed):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(3, HF["vocab_size"], n).tolist() for n in lengths]
-
-
-def _serve_case(served, seqs, slots, n_decode, cuts, width, pad_row=False):
-    """Prefill each sequence's prompt in chunks cut at ``cuts``, all
-    sequences as rows of the same steps, then decode ``n_decode``
-    teacher-forced tokens. Returns the log-softmax at every position."""
-    lens = [len(q) - n_decode for q in seqs]
-    out = [np.zeros((len(q), served.vocab), np.float32) for q in seqs]
-    edges = [0] + list(cuts) + [max(lens)]
-    for lo, hi in zip(edges[:-1], edges[1:]):
-        rows, who = [], []
-        for i, q in enumerate(seqs):
-            a, b = min(lo, lens[i]), min(hi, lens[i])
-            if b > a:
-                rows.append((slots[i], q[a:b], a))
-                who.append((i, a, b))
-        if pad_row:
-            rows.insert(1, None)
-            who.insert(1, None)
-        for got, w in zip(served.prefill(rows, width), who):
-            if w is not None:
-                out[w[0]][w[1]:w[2]] = got
-    for step in range(n_decode):
-        got = served.decode({slots[i]: (q[lens[i] + step], lens[i] + step)
-                             for i, q in enumerate(seqs)})
-        for i in range(len(seqs)):
-            out[i][lens[i] + step] = got[slots[i]]
-    return out
+_seqs, _serve_case = served.seqs, served.serve_case
 
 
 CASES = {
@@ -328,7 +230,8 @@ def _wrong(fault, monkeypatch):
     elif fault == "stale_page_means":
         monkeypatch.setattr(sparse, "write_page_means",
                             lambda means, *a, **k: means)
-    return Served(cfg, served_params, jnp.float32, state_dtype), params
+    return Served(cfg, served_params, jnp.float32, state_dtype,
+                  fresh=True), params
 
 
 @pytest.mark.parametrize("fault", [

@@ -10,21 +10,15 @@ blocks of 4.
 """
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.models import mixtral, sdar
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
+import served  # noqa: E402  (puts benchmark/ on the path)
 from references import sdar as reference  # noqa: E402
 
 MASK = 255
@@ -42,7 +36,7 @@ HF = {
     "block_length": 4, "mask_token_id": MASK, "denoising_steps": 2,
     "remasking_strategy": "sequential", "confidence_threshold": 0.9,
 }
-PAGE, PAGES, WIDTH = 16, 33, 8
+PAGE, SLOTS, WIDTH = 16, 4, 8      # 33 pages, page 0 nobody's
 # float32 on both sides: the two differ in the order of the sums (a walk
 # of pages against a masked product, sorted rows of experts against every
 # expert on every token) and in nothing else; differences seen are 1e-6
@@ -52,8 +46,7 @@ F32_ATOL = 1e-4
 
 
 def _cfg(impl="xla", **over):
-    return dataclasses.replace(ModelConfig.from_hf_config({**HF, **over}),
-                               attention_impl=impl)
+    return served.cfg_of({**HF, **over}, attention_impl=impl)
 
 
 _PARAMS = {}
@@ -66,33 +59,29 @@ def _params(dtype=jnp.float32, seed=7):
     return _PARAMS[dtype, seed]
 
 
-class Served:
-    """The family's forward over one sequence's pages, driven as the
+class OneSequence(served.Served):
+    """One sequence's pages, scattered over the cache, driven as the
     engine drives it: a prefill chunk, or a block pass over the block's
-    own slots."""
+    own slots. The mask token is no token's continuation: its logit is
+    left out of the softmax."""
 
-    def __init__(self, cfg, params, dtype=jnp.float32):
-        self.cfg, self.params = cfg, params
-        self.cache = sdar.init_kv_cache(cfg, PAGES, PAGE, dtype)
+    def __init__(self, cfg, params, dtype=jnp.float32, fresh=False):
+        super().__init__(sdar, cfg, params, dtype, block=PAGE, width=WIDTH,
+                         slots=SLOTS, fresh=fresh)
         rng = np.random.default_rng(1)
-        self.bt = rng.permutation(np.arange(1, PAGES))[:WIDTH].astype(np.int32)
-        self.fn = jax.jit(
-            lambda cache, toks, pos, bt, slots, ctx: sdar.forward(
-                params, cfg, toks, pos, cache, bt, slots, ctx))
+        self.btab[0] = rng.permutation(
+            np.arange(1, self.pages))[:WIDTH].astype(np.int32)
+
+    def logprobs(self, logits):
+        logits = np.array(logits, np.float32)
+        logits[..., MASK] = -np.inf
+        return np.asarray(jax.nn.log_softmax(logits, axis=-1))
 
     def run(self, ids, start):
         """Log-probabilities [len(ids), V] of ``ids`` at positions
         ``start..``, their keys and values written to their slots."""
-        n = len(ids)
-        pos = np.arange(start, start + n)
-        slots = self.bt[pos // PAGE] * PAGE + pos % PAGE
-        logits, self.cache = self.fn(
-            self.cache, jnp.asarray([ids], jnp.int32), jnp.asarray([pos], jnp.int32),
-            jnp.asarray([self.bt]), jnp.asarray([slots], jnp.int32),
-            jnp.asarray([start + n], jnp.int32))
-        logits = np.array(logits[0], np.float32)
-        logits[:, MASK] = -np.inf
-        return np.asarray(jax.nn.log_softmax(logits, axis=-1))
+        return self.prefill([(0, ids, start)], len(ids))[0]
+
 
 
 def _state(seq, n, shown):
@@ -125,7 +114,7 @@ def test_prefill_and_block_passes_equal_the_references_states(tail, steps):
     prompt_len = 36 + tail
     seq = rng.integers(3, 250, prompt_len + 8).tolist()
     whole = prompt_len - tail
-    served = Served(_cfg(), params)
+    served = OneSequence(_cfg(), params)
     got = served.run(seq[:whole], 0)
     want = _reference_state(params, seq[:whole])
     np.testing.assert_allclose(got, want, atol=F32_ATOL)
@@ -155,7 +144,7 @@ def test_chunked_prefill_and_kernels_equal_the_reference(impl, monkeypatch):
     monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     params = _params()
     seq = np.random.default_rng(3).integers(3, 250, 76).tolist()
-    served = Served(_cfg(impl), params)
+    served = OneSequence(_cfg(impl), params)
     want = _reference_state(params, seq[:68])
     at = 0
     for take in (16, 32, 20):
@@ -225,7 +214,7 @@ def test_reference_tells_wrong_programs_apart(fault):
     cfg, params = _faulty(fault)
     seq = np.random.default_rng(4).integers(3, 250, 44).tolist()
     ids = _state(seq, 40, 2)
-    served = Served(cfg, params)
+    served = OneSequence(cfg, params, fresh=True)
     served.run(ids[:40], 0)
     got = served.run(ids[40:], 40)
     want = _reference_state(_params(), ids)[40:]
@@ -257,7 +246,7 @@ def test_the_trunk_is_mixtrals_and_random_weights_follow_the_recipe():
 def test_bfloat16_served_path_stays_near_the_reference():
     params = _params(jnp.bfloat16)
     seq = np.random.default_rng(5).integers(3, 250, 44).tolist()
-    served = Served(_cfg(), params, jnp.bfloat16)
+    served = OneSequence(_cfg(), params, jnp.bfloat16)
     served.run(seq[:40], 0)
     ids = _state(seq, 40, 2)
     got = served.run(ids[40:], 40)

@@ -21,8 +21,8 @@ from dynamo_tpu import models
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.model_runner import ModelRunner
 from dynamo_tpu.models import deepseek, loader, mhc
-from test_xing4_reference import (BLOCK, F32_ATOL, HF, _cfg, _params,
-                                  _reference_logprobs, _seqs, _serve)
+from xing4_tiny import (BLOCK, F32_ATOL, HF, _cfg, _params,
+                        _reference_logprobs, _seqs, _serve)
 
 
 def test_model_runner_step_logprobs_equal_reference():

@@ -14,86 +14,18 @@ weights).
 
 import dataclasses
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.models import deepseek, llama, mhc
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "benchmark")
-if BENCH not in sys.path:
-    sys.path.insert(0, BENCH)
-from references import xing4 as reference  # noqa: E402
-# prefill in chunks, then teacher-forced decode through the paged latent
-# cache: the same walk as the other latent-attention family's tests (the
-# tiny shapes share vocabulary and block size)
-from test_deepseek_v3_reference import _seqs, _serve  # noqa: E402
-
-YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
-        "mscale_all_dim": 1, "original_max_position_embeddings": 16,
-        "type": "yarn"}
-HF = {
-    "architectures": ["Xing4ForCausalLM"], "model_type": "xing4_0",
-    "vocab_size": 256, "hidden_size": 64, "intermediate_size": 128,
-    "moe_intermediate_size": 32, "num_hidden_layers": 3,
-    "num_attention_heads": 4, "num_key_value_heads": 4, "kv_lora_rank": 32,
-    "q_lora_rank": 24, "qk_rope_head_dim": 16, "qk_nope_head_dim": 16,
-    "v_head_dim": 16, "n_routed_experts": 8, "num_experts_per_tok": 3,
-    "n_shared_experts": 1, "first_k_dense_replace": 1,
-    "scoring_func": "sigmoid", "topk_method": "noaux_tc",
-    "norm_topk_prob": True, "routed_scaling_factor": 2, "n_group": 1,
-    "topk_group": 1, "rope_theta": 10000, "rms_norm_eps": 1e-6,
-    "rope_scaling": YARN, "max_position_embeddings": 256,
-    "tie_word_embeddings": False, "num_nextn_predict_layers": 1,
-    "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
-    "mhc_h_res_clamp_min": -1.5, "mhc_h_res_clamp_max": 1.5,
-}
-BLOCK = 8
-# float32 on both sides: the two differ in the order of products
-# (absorbed against un-absorbed attention, sorted grouped products
-# against every expert on every token, the streams' norm behind the
-# projection against before it, tokens minor against tokens major) and
-# in nothing else; 1e-4 is ~10x the differences seen (8e-6) and far
-# under what a wrong coefficient does (1e-2 and up, below)
-F32_ATOL = 1e-4
-# bfloat16 weights, streams and cache (float32 coefficients) against the
-# float32 reference on the same weights: the largest difference over the
-# vocabulary at one position. Measured on this shape: median 0.05-0.08,
-# nine in ten positions under 0.2; the rest are flipped near-ties of the
-# router (as tests/test_deepseek_v3_reference.py), so the limit is on
-# the bulk
-BF16_MEDIAN = 0.15
-BF16_ATOL = 0.4
-
-
-def _cfg(attention_impl="xla", **replace):
-    cfg = ModelConfig.from_hf_config(HF)
-    return dataclasses.replace(cfg, attention_impl=attention_impl, **replace)
-
-
-def _params(dtype):
-    cfg = _cfg()
-    return cfg, deepseek.init_params(cfg, jax.random.PRNGKey(7), dtype)
-
-
-_reference_program = functools.lru_cache(maxsize=None)(
-    lambda t_pad, n_out: reference.build(HF, t_pad, n_out))
-
-
-def _reference_logprobs(params, seq):
-    t_pad = -(-len(seq) // 8) * 8
-    tokens = np.zeros(t_pad, np.int32)
-    tokens[: len(seq)] = seq
-    fn = _reference_program(t_pad, len(seq))
-    return np.asarray(fn(params, jnp.asarray(tokens),
-                         jnp.arange(len(seq), dtype=jnp.int32)))
-
+import served  # noqa: E402
+from xing4_tiny import (BF16_ATOL, BF16_MEDIAN, F32_ATOL,  # noqa: E402
+                        YARN, _cfg, _params, _reference_logprobs, _seqs,
+                        _serve, reference)
 
 # the cases of tests/test_deepseek_v3_reference.py; every one has
 # positions below and above YaRN's original length of 16 but batch_8
@@ -115,16 +47,8 @@ def test_served_path_equals_reference(case, dtype):
     c = CASES[case]
     seqs = _seqs(c["lengths"], seed=len(case))
     got = _serve(cfg, params, seqs, c["n_decode"], c["chunk"], dt)
-    worst = []
-    for seq, lp in zip(seqs, got):
-        want = _reference_logprobs(params, seq)
-        if dtype == "float32":
-            np.testing.assert_allclose(lp, want, rtol=0, atol=F32_ATOL)
-        worst.extend(np.abs(lp - want).max(axis=1))
-    if dtype == "bfloat16":
-        worst = np.asarray(worst)
-        assert np.median(worst) < BF16_MEDIAN
-        assert np.mean(worst < BF16_ATOL) >= 0.9
+    served.assert_close(got, [_reference_logprobs(params, q) for q in seqs],
+                        dtype, F32_ATOL, BF16_MEDIAN, BF16_ATOL, bf16_share=0.9)
 
 
 def test_norm_weights_of_the_query_bottleneck_and_the_latent():
@@ -181,7 +105,7 @@ def test_reference_tells_wrong_programs_apart(wrong):
                 p2[group][f"hc_{sub}_phi"] = jnp.zeros_like(params[group][f"hc_{sub}_phi"])
     else:
         cfg = dataclasses.replace(cfg, rope_scaling={**YARN, "mscale_all_dim": 0})
-    got = _serve(cfg, p2, [seq], 8, 16, jnp.float32)[0]
+    got = _serve(cfg, p2, [seq], 8, 16, jnp.float32, fresh=True)[0]
     err = np.abs(got[:-1][idx] - want[:-1][idx]).mean()
     assert base_err < 1e-5
     assert err > 100 * base_err and err > F32_ATOL
