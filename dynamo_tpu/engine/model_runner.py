@@ -231,6 +231,11 @@ def _ngram_props(ring: jax.Array, match: int, k: int) -> jax.Array:
 class ModelRunner:
     """Owns params + cache on device and the compiled step programs."""
 
+    # ``step(prev_tokens=)``: the decode program reads a row's token off
+    # the step before's output on the device, which is what lets the
+    # scheduler dispatch a step before it has read that one
+    feeds_tokens = True
+
     def __init__(
         self,
         config: EngineConfig,
@@ -742,11 +747,17 @@ class ModelRunner:
         forward, head = self._make_forward(counted=moe)
 
         def step(s, params, k_cache, v_cache, counts, seen, bias, packed,
-                 *moe_counts):
+                 *moe_counts, prev_tokens=None):
             # every per-pass input arrives in one array (step_inputs.py)
             (tokens, positions, block_tables, slot_mapping, context_lens,
              last_idx, samp, sample_slots, commit, want_top, targets,
              want_prompt, want_greedy) = step_inputs.unpack(packed, s)
+            if prev_tokens is not None:
+                # a row whose token the host had not read when it packed
+                # this step (step_inputs.FED) takes the one the step
+                # before it sampled, which never left the device
+                tokens = jnp.where(tokens == step_inputs.FED,
+                                   prev_tokens[:, None], tokens)
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), tokens, positions,
                 block_tables, slot_mapping, context_lens, sample_slots,
@@ -812,35 +823,44 @@ class ModelRunner:
         # then read jit_decode_step(...) and jit_prefill_step(...)
         # (step() picks by S, as its CompileTracker label does). The
         # packed array's width is F + W + 4·S, so prefill takes S as a
-        # static argument; decode's is 1
-        def decode_step(*args):
-            return step(1, *args)
+        # static argument; decode's is 1. The decode step alone takes the
+        # step before it's ``next_tokens`` (``[B]``, not donated: the
+        # scheduler has yet to fetch it), after the packed array
+        def decode_step(params, k_cache, v_cache, counts, seen, bias, packed,
+                        prev_tokens, *moe_counts):
+            return step(1, params, k_cache, v_cache, counts, seen, bias,
+                        packed, *moe_counts, prev_tokens=prev_tokens)
 
         def prefill_step(s, *args):
             return step(s, *args)
 
-        jit_kw = dict(
-            in_shardings=(
-                self.param_shardings,        # params
-                self.cache_sharding,         # k
-                self.cache_sharding,         # v
-                self.state_sharding,         # counts
-                self.state_sharding,         # seen
-                self.state_sharding,         # bias
-                batch2_spec,                 # packed [B, F + W + 4·S]
-            ) + ((repl,) if moe else ()),    # routed experts' counters
-            out_shardings=(batch_spec, batch_spec, batch2_spec, batch2_spec,
-                           batch2_spec, batch2_spec,
-                           self.cache_sharding, self.cache_sharding,
-                           self.state_sharding, self.state_sharding,
-                           self.state_sharding) + ((repl,) if moe else ()),
+        state = (
+            self.param_shardings,        # params
+            self.cache_sharding,         # k
+            self.cache_sharding,         # v
+            self.state_sharding,         # counts
+            self.state_sharding,         # seen
+            self.state_sharding,         # bias
+            batch2_spec,                 # packed [B, F + W + 4·S]
         )
+        counters = (repl,) if moe else ()    # routed experts' counters
+        out_shardings = (batch_spec, batch_spec, batch2_spec, batch2_spec,
+                         batch2_spec, batch2_spec,
+                         self.cache_sharding, self.cache_sharding,
+                         self.state_sharding, self.state_sharding,
+                         self.state_sharding) + counters
         self._packed_sharding = batch2_spec
+        self._tokens_sharding = batch_spec
+        # what a decode step is fed where no step ran before it, a batch size
+        self._no_prev: Dict[int, jax.Array] = {}
         self._decode_step = jax.jit(
-            decode_step, donate_argnums=(1, 2, 3, 4, 5), **jit_kw)
+            decode_step, donate_argnums=(1, 2, 3, 4, 5),
+            in_shardings=state + (batch_spec,) + counters,
+            out_shardings=out_shardings)
         self._prefill_step = jax.jit(
             prefill_step, static_argnums=(0,),
-            donate_argnums=(2, 3, 4, 5, 6), **jit_kw)
+            donate_argnums=(2, 3, 4, 5, 6),
+            in_shardings=state + counters, out_shardings=out_shardings)
 
     # ---------- the block pass (a family whose decode unit is a block) ----------
 
@@ -1800,8 +1820,17 @@ class ModelRunner:
         want_prompt: bool = False,  # compute prompt logprobs at `targets`?
         want_greedy: bool = False,  # per-position argmax (spec verify)?
         window_tables: Optional[np.ndarray] = None,  # [B, W] the window kind's
+        prev_tokens: Optional[jax.Array] = None,  # [B] the step before's
     ) -> Tuple[jax.Array, ...]:
         """Run one compiled step; returns (next_tokens, logprobs) device arrays.
+
+        ``prev_tokens`` (a decode step only): the ``next_tokens`` the
+        step dispatched before this one returned, still on the device. A
+        row whose entry of ``tokens`` is ``step_inputs.FED`` reads its
+        token there, so the host can dispatch this step before it has
+        read that one (``Scheduler._decode``). It is the same program
+        with or without: left out, the argument is a ``[B]`` of zeros
+        that no row reads.
 
         ``window_tables`` (a family with two kinds of page, models/
         afmoe.py): the window kind's table, laid beside ``block_tables``
@@ -1843,8 +1872,16 @@ class ModelRunner:
             f"b{b}_s{s}_w{width}", shape=shape, arrays=1,
         ):
             moe = () if self.moe_counts is None else (self.moe_counts,)
+            fed = ()
+            if s == 1:
+                if prev_tokens is None:
+                    if b not in self._no_prev:
+                        self._no_prev[b] = jax.device_put(
+                            np.zeros(b, np.int32), self._tokens_sharding)
+                    prev_tokens = self._no_prev[b]
+                fed = (prev_tokens,)
             args = (self.params, *self.kv_cache, *self.sample_state,
-                    jax.device_put(buf, self._packed_sharding), *moe)
+                    jax.device_put(buf, self._packed_sharding), *fed, *moe)
             (next_tokens, lps, top_vals, top_ids, prompt_lps, greedy_all,
              k, v, counts, seen, bias, *moe) = (
                 self._prefill_step(s, *args) if s > 1
@@ -2332,14 +2369,28 @@ class ModelRunner:
                     np.zeros(b, np.float32), np.zeros(b, np.int32),
                     np.ones(b, np.float32),
                 )
+        fed = None
         for w in self._warm_widths("decode") if self.unit is None else ():
-            self.step(
+            fed, *_ = self.step(
                 zeros2, zeros2, np.zeros((b, w), np.int32),
                 np.full((b, 1), -1, np.int32),
                 np.ones(b, np.int32), np.zeros(b, np.int32),
                 np.zeros(b, np.float32), np.zeros(b, np.int32),
                 np.ones(b, np.float32),
                 jax.random.PRNGKey(0),
+            )
+        if fed is not None:
+            # once more fed with its own output, as the scheduler feeds it
+            # a step ahead (the same program: no first dispatch, and the
+            # call's fast path has seen both kinds of argument)
+            self.step(
+                zeros2, zeros2,
+                np.zeros((b, self.config.blocks_per_seq), np.int32),
+                np.full((b, 1), -1, np.int32),
+                np.ones(b, np.int32), np.zeros(b, np.int32),
+                np.zeros(b, np.float32), np.zeros(b, np.int32),
+                np.ones(b, np.float32),
+                jax.random.PRNGKey(0), prev_tokens=fed,
             )
         # the fused multi-step decode program at its widths (inert rows:
         # commit all-False writes nothing and samples noise)

@@ -18,7 +18,7 @@ import dataclasses
 import logging
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -49,6 +49,7 @@ from .sampling import (
     stop_seq_rows,
     tiled_rows,
 )
+from .step_inputs import FED
 
 logger = logging.getLogger(__name__)
 
@@ -512,6 +513,30 @@ class _InflightBurst:
     prefetched: int = 0
 
 
+@dataclasses.dataclass
+class _StepInFlight:
+    """One decode dispatch (``Scheduler._decode``) whose tokens the host
+    has not read: what ``_decode_land`` needs to apply it, fixed at the
+    dispatch. While it is in flight the next step can be built and
+    dispatched on top of it (``ahead``), its continuing rows fed from
+    ``arrays[0]`` on the device."""
+
+    # the rows, each with the slot it had at dispatch (a row that has
+    # finished since may have lost it to another)
+    rows: List[Tuple["EngineRequest", int]]
+    arrays: list                   # device next_tokens, lps, top_vals, top_ids
+    k_steps: int
+    t_dispatch: float
+    prefetched: int                # arrays whose copy to the host was requested
+    read_bytes: float = 0.0        # device-time accounting, as _InflightBurst's
+    # dispatched before the step before it was read
+    ahead: bool = False
+    # dispatched behind a prefill chunk of the same pass that nobody
+    # waited for (a prompt's middle chunk): in the device's queue the
+    # chunk stands before it
+    behind_chunk: bool = False
+
+
 class Scheduler:
     def __init__(
         self,
@@ -680,6 +705,15 @@ class Scheduler:
         # fetched / has taken its turn for the frontend (sched.yield)
         self._inflight = False
         self._turn_taken = False
+        # the decode step that runs one step ahead of the host
+        # (``_decode``): dispatched, its tokens not read. Only a runner
+        # whose decode program takes the step before's tokens on the
+        # device can be run so (``ModelRunner.step(prev_tokens=)``; a
+        # test's stand-in says so itself)
+        self._ahead: Optional[_StepInFlight] = None
+        self._feeds_tokens = getattr(runner, "feeds_tokens", False)
+        # this pass dispatched a prefill chunk and did not wait for it
+        self._chunk_unread = False
         self._build_instruments()
         if disagg is not None and getattr(disagg, "registry", None) is not None:
             self.registry.attach(disagg.registry)
@@ -762,9 +796,24 @@ class Scheduler:
         self._sync_fallback_ctr = reg.counter(
             "dynamo_engine_sync_fallback_total",
             "Decode passes that fell back to the per-burst host-sync "
-            "path while the persistent chain was enabled, labelled "
+            "path while the persistent chain was enabled, or that read "
+            "their step before the next was dispatched where the step "
+            "could have run ahead (_decode), labelled "
             "reason= with the constraint that forced it (the shrunken "
             "fallback ladder: every remaining sync pass is attributed)",
+        )
+        self._ahead_ctr = reg.counter(
+            "dynamo_scheduler_decode_ahead_total",
+            "Decode steps dispatched before the step before them was "
+            "read, their continuing rows fed that step's tokens on the "
+            "device: over dynamo_scheduler_fetches_total{kind=\"decode\"} "
+            "it is the share of steps the device did not wait for",
+        )
+        self._ahead_discarded_ctr = reg.counter(
+            "dynamo_scheduler_decode_ahead_discarded_total",
+            "Rows of such steps whose token was dropped: the row had "
+            "ended (a stop token or string, a cancel) at the step before, "
+            "which the host read only after this one was dispatched",
         )
         self._spec_accept_hist = reg.histogram(
             "dynamo_engine_spec_accept_length",
@@ -997,7 +1046,8 @@ class Scheduler:
         if self._turn_taken:
             return
         self._turn_taken = True
-        inflight = self._inflight or bool(self._chain)
+        inflight = (self._inflight or bool(self._chain)
+                    or self._ahead is not None)
         t0 = time.monotonic()
         with span("sched.yield", step=self.passes, inflight=int(inflight)):
             await asyncio.sleep(0)
@@ -1026,8 +1076,9 @@ class Scheduler:
         stood in no span before): a synchronous caller comes here straight
         from its dispatch, so the transfers queue behind the step on the
         device's side, with the frontend's turn and the rest of the step
-        between the request and the wait; a chained burst made the
-        request at its own dispatch and passes the count
+        between the request and the wait; a chained burst and a decode
+        step (``_decode_dispatch``: its wait may be a pass later) made
+        the request at their own dispatch and pass the count
         (``prefetched``). Then the frontend's turn (``turn``: unless a
         later dispatch of this pass will take it), then one
         ``np.asarray`` after another over the device ``arrays`` on an
@@ -1231,10 +1282,12 @@ class Scheduler:
                 pass
             except Exception:
                 logger.exception("scheduler loop raised during seize")
-        if self._chain:
+        if self._chain or self._ahead is not None:
             self.flight.record(
                 "scheduler.burst_abandon", chained=len(self._chain),
+                ahead=int(self._ahead is not None),
             )
+        self._ahead = None
         self._chain.clear()
         self._chain_members = []
         self._chain_carry = None
@@ -1638,6 +1691,7 @@ class Scheduler:
             self.last_loop_t = pass_t0
             self.passes += 1
             self._inflight = self._turn_taken = False
+            self._chunk_unread = False
 
             # the sched.* spans (telemetry/tracing.span) put this pass's
             # seams into the profiler's trace. They follow one another
@@ -1650,7 +1704,10 @@ class Scheduler:
             # copy to the host), yield, sync, emit: the
             # frontend's turn (sched.yield) comes while the device
             # computes what the pass dispatched, and at the pass's end
-            # only where it fetched nothing (_frontend_turn).
+            # only where it fetched nothing (_frontend_turn). Where the
+            # decode step runs ahead (_decode), sync and emit are of the
+            # step the pass before dispatched, and this pass's own is
+            # left in flight.
             with span("sched.admit", step=self.passes):
                 # drop cancelled requests (client disconnects / kills)
                 for er in list(self.waiting):
@@ -1796,7 +1853,14 @@ class Scheduler:
                         self._spec_chain_reason(active) if spec_now
                         else self._chain_block_reason(active, runner_idle)
                     )
-                if chain_on and refused is None:
+                if (chain_on and refused is None) or spec_now:
+                    # these paths build from committed state
+                    await self._land_ahead(
+                        loop, "spec" if spec_now else "chain")
+                    active = [er for er in active if er.finish is None]
+                if not active:
+                    pass
+                elif chain_on and refused is None:
                     if spec_now:
                         # persistent loop, speculative: chain
                         # propose-verify rounds off the device-resident
@@ -1833,6 +1897,11 @@ class Scheduler:
                     max(0.0, time.monotonic() - t_dec - self._host_sync_s),
                     phase="decode",
                 )
+                progressed = True
+            elif self._ahead is not None:
+                # every row of the step in flight was cancelled: its
+                # tokens are read and dropped
+                await self._land_ahead(loop, "no_rows")
                 progressed = True
             elif self._chain or self._chain_members:
                 # every chained row finished or was cancelled while the
@@ -1879,8 +1948,10 @@ class Scheduler:
                 await self._frontend_turn()
                 self._step_hist.observe(time.monotonic() - pass_t0)
 
-        # stopping: reconcile any chained burst so no sampled tokens are
-        # silently dropped and no device work is abandoned
+        # stopping: reconcile the step in flight and any chained burst so
+        # no sampled tokens are silently dropped and no device work is
+        # abandoned
+        await self._land_ahead(loop, "stop")
         await self._chain_barrier(loop)
 
     # ---------- persistent decode loop (decode_pipeline_depth=2) ----------
@@ -3463,6 +3534,7 @@ class Scheduler:
                 if final:
                     finals.append(i)
         if not finals:
+            self._chunk_unread = True
             return
         if self.unit is not None:
             # a block family's prefill samples nothing: its first tokens
@@ -3473,6 +3545,13 @@ class Scheduler:
                 self._end_block_prefill(plan[i][0])
             return
 
+        if self._ahead is not None and self._ahead.behind_chunk:
+            # the decode step in flight stands between the chunk of the
+            # pass before and this one in the device's queue. Waiting for
+            # this chunk first would hold its tokens, ready a whole chunk
+            # earlier, until this chunk has run (the longest gap a client
+            # sees would be two chunks, where it was one): read it first
+            await self._land_ahead(loop, "behind_chunk")
         # every device→host transfer off the event loop: any accumulated
         # prompt-logprob rows (an echo+logprobs prompt may hold many chunk
         # rows) first, as they always were, then the final rows' outputs
@@ -3852,63 +3931,177 @@ class Scheduler:
 
     async def _decode(self, loop, active: List[EngineRequest],
                       k_steps: int = 1) -> None:
+        """One decode step over ``active`` (a fused burst of ``k_steps``
+        where the caller asks for one), **one step ahead of the host**
+        where it can be: the step this pass dispatches (k) goes to the
+        device before the step the pass before dispatched (k−1) has been
+        read, and the host's work for k−1 (the wait for its tokens, the
+        emit loop, the frontend's turn) runs under k. A pass is then
+        build(k), dispatch(k), request(k), yield, sync(k−1), emit(k−1).
+
+        Building k with k−1 in flight, a row of k−1 is taken as
+        continuing: one position and one counter on, its token
+        ``step_inputs.FED`` (the program reads it off k−1's
+        ``next_tokens``, which never left the device). A row the host
+        knows k−1 ends (``_ends_at_next``) is left out; one that turns
+        out to have ended there, by a stop token or string, has its row
+        of k dropped when k is read (``_decode_land``). A row that joins
+        from a prefill chunk brings its token from the host, as ever.
+        The host still decides every finish, block, window page and
+        admission itself, from tokens it has read: one step later.
+
+        Where the pass cannot go ahead it says why
+        (``_ahead_block_reason``; no block or window page for a
+        continuing row is ``kv_oom``) on
+        ``dynamo_engine_sync_fallback_total``: k−1 is read and applied
+        first and k built from committed state, which is what preemption
+        needs. A step that the next may run ahead of is left in flight;
+        any other is read before the pass ends, as every step was. A
+        pass whose prefill chunk ends a prompt reads that chunk's first
+        token before its decode dispatch, as ever (``_prefill_chunk``);
+        where the step in flight stands behind the chunk of the pass
+        before, it is read before that wait (``behind_chunk``), so that
+        its tokens are not held for a chunk they did not wait for."""
+        cfg = self.config
+        # a K-step burst writes K tokens of KV per row before the host
+        # sees any of them, so every row needs blocks for all K positions
+        # up front, and no row may run past the block-table/model-len
+        # horizon mid-burst (such rows finish within one burst anyway —
+        # fall back to per-token stepping for everyone this pass)
+        if k_steps > 1 and any(
+            er.context_len + k_steps + 1 > cfg.max_model_len for er in active
+        ):
+            k_steps = 1
+        if self.draft is not None:
+            # plain decode must keep the draft's mirror cache current
+            # (the next speculative round assumes draft KV for every
+            # position < context); the mirror runs per-token, so pin the
+            # target to per-token too — with a draft configured, the
+            # fused burst's role is played by speculation itself
+            k_steps = 1
+        if any(er.guided is not None for er in active):
+            # guided rows rewrite their mask between tokens on the host;
+            # a fused burst would sample K tokens against one stale mask.
+            # NOTE this pins the WHOLE batch (all rows share one
+            # dispatch), so concurrent unguided requests also lose the
+            # burst while any guided request is active — documented in
+            # docs/models.md. Splitting guided rows into their own
+            # dispatch would pay two program launches per step, worse
+            # than the amortization it saves at serving batch sizes.
+            k_steps = 1
+
+        hold = (self._ahead_block_reason(active, k_steps)
+                if self._feeds_tokens else None)
+        prev, step = self._ahead, None
+        if prev is not None and hold is None:
+            step = self._decode_dispatch(active, 1, prev)
+        fell = "kv_oom" if step is False else hold
+        if fell is not None:
+            self._note_sync_fallback(fell)
+        if prev is not None and fell is not None:
+            # k−1 is read and applied before k is built
+            self._ahead = None
+            await self._decode_land(loop, prev)
+            prev = None
+            active = [er for er in active if er.finish is None]
+        if prev is None:
+            step = self._decode_dispatch(active, k_steps, None)
+        self._ahead = step
+        if prev is not None:
+            await self._decode_land(loop, prev)   # sync(k−1), emit(k−1): under k
+        if step is not None and (hold is not None or not self._feeds_tokens):
+            self._ahead = None
+            await self._decode_land(loop, step)
+
+    def _ahead_block_reason(self, active: List[EngineRequest],
+                            k_steps: int) -> Optional[str]:
+        """Why must the host read this pass's decode step before it
+        builds the next? None: it need not, and the step may stay in
+        flight. What the pass can see in its input, nothing else."""
+        if any(er.guided is not None for er in active):
+            # the host rewrites a guided row's mask from the token
+            return "guided"
+        if self.draft is not None:
+            # the draft's mirror step takes the tokens from the host
+            return "draft_mirror"
+        if k_steps > 1:
+            # a fused burst feeds itself; the host reads K tokens a row
+            return "multi_step"
+        return None
+
+    def _ends_at_next(self, er: EngineRequest) -> bool:
+        """Does the host know, before it has read the step in flight,
+        that the token of that step is ``er``'s last? By the count and by
+        the model-length horizon, ``_check_finish``'s last two lines one
+        token on (a stop token or string it cannot know)."""
+        return (er.generated + 1 >= er.fin_max_new
+                or er.context_len + 2 >= self.config.max_model_len)
+
+    async def _land_ahead(self, loop, reason: str) -> None:
+        """Read and apply the decode step in flight, if there is one,
+        because of ``reason``: what follows builds from committed state
+        (another decode path, the loop's end; where the chain has
+        ``_chain_barrier``)."""
+        step, self._ahead = self._ahead, None
+        if step is not None:
+            self._note_sync_fallback(reason)
+            await self._decode_land(loop, step)
+
+    def _decode_dispatch(self, active: List[EngineRequest], k_steps: int,
+                         prev: Optional[_StepInFlight]):
+        """Build and dispatch one decode step (``sched.decode.build``,
+        ``.dispatch``, ``.request``) and return its ``_StepInFlight``;
+        None where no row is left to step. ``prev``: the step in flight
+        this one is built ahead of (``_decode``); a block or window page
+        that cannot be had then returns False with nothing preempted
+        (what was reserved is what the rows need once ``prev`` is
+        applied), where from committed state (``prev`` None) the row is
+        preempted."""
         cfg = self.config
         b = cfg.max_batch_size
         bs = cfg.kv_block_size
-
         with span("sched.decode.build", step=self.passes,
                   rows=len(active)):
-            # a K-step burst writes K tokens of KV per row before the host
-            # sees any of them, so every row needs blocks for all K positions
-            # up front, and no row may run past the block-table/model-len
-            # horizon mid-burst (such rows finish within one burst anyway —
-            # fall back to per-token stepping for everyone this pass)
-            if k_steps > 1 and any(
-                er.context_len + k_steps + 1 > cfg.max_model_len for er in active
-            ):
-                k_steps = 1
-            if self.draft is not None:
-                # plain decode must keep the draft's mirror cache current
-                # (the next speculative round assumes draft KV for every
-                # position < context); the mirror runs per-token, so pin the
-                # target to per-token too — with a draft configured, the
-                # fused burst's role is played by speculation itself
-                k_steps = 1
-            if any(er.guided is not None for er in active):
-                # guided rows rewrite their mask between tokens on the host;
-                # a fused burst would sample K tokens against one stale mask.
-                # NOTE this pins the WHOLE batch (all rows share one
-                # dispatch), so concurrent unguided requests also lose the
-                # burst while any guided request is active — documented in
-                # docs/models.md. Splitting guided rows into their own
-                # dispatch would pay two program launches per step, worse
-                # than the amortization it saves at serving batch sizes.
-                k_steps = 1
-
+            inflight = ({id(er) for er, _ in prev.rows}
+                        if prev is not None else ())
+            # (row, its position, its counter, is its token on the
+            # device): a row of the step in flight stands one token on,
+            # and is left out where that token is known to be its last
+            rows = []
+            for er in active:
+                on = int(id(er) in inflight)
+                if not (on and self._ends_at_next(er)):
+                    rows.append((er, er.context_len + on,
+                                 er.generated + on, bool(on)))
             # make sure each active sequence has blocks for its next position
             # (all k_steps of them under a burst)
             if self.window is not None:
                 # two kinds of page: every row first gives back what fell
                 # behind its next query, so that what one row frees
                 # another can take in this same pass
-                for er in active:
-                    self._release_window(er, er.context_len)
-            for er in list(active):
+                for er, pos, *_ in rows:
+                    self._release_window(er, pos)
+            for row in list(rows):
+                er, pos, *_ = row
                 ok = all(
-                    self._ensure_block_for(er, er.context_len + j)
+                    self._ensure_block_for(er, pos + j)
                     for j in range(k_steps)
                 )
+                if not ok and prev is not None:
+                    self.allocator.flush_offload()
+                    return False
                 if not ok:
                     # out of memory: evict the youngest request back to waiting
                     # (simple preemption — recompute later)
                     logger.warning("KV OOM: preempting %s", er.request_id)
                     self._preempt(er)
-                    active.remove(er)
+                    rows.remove(row)
             # one batched host-offload gather for every eviction this step,
             # before the step below overwrites the evicted slots
             self.allocator.flush_offload()
-            if not active:
-                return
+            if not rows:
+                return None
+            live = [er for er, *_ in rows]
 
             # the table's width: full where the program's kernels walk
             # live pages; where its trace gathered [B, W] pages the LIVE
@@ -3916,7 +4109,7 @@ class Scheduler:
             # context doesn't pay max_model_len's gather (one compiled
             # program per rung)
             w = self._table_width(
-                active,
+                live,
                 (self.runner, "decode_burst" if k_steps > 1 else "decode"),
                 (self.draft, "decode"))   # its mirror (k_steps is 1 then)
 
@@ -3932,15 +4125,14 @@ class Scheduler:
             ctrs = np.zeros(b, np.int32)
             commit = np.zeros(b, bool)
 
-            for er in active:
+            for er, pos, gen, fed in rows:
                 i = er.slot
-                pos = er.context_len
                 hs.sync_blocks(er)
-                tokens[i, 0] = er.pending_token
+                tokens[i, 0] = FED if fed else er.pending_token
                 positions[i, 0] = pos
                 slot_map[i, 0] = er.block_ids[pos // bs] * bs + pos % bs
                 ctx_lens[i] = pos + 1
-                ctrs[i] = er.generated
+                ctrs[i] = gen
                 commit[i] = True
             # .copy(), not a view: the persistent table mutates across passes
             # while a dispatched program's host→device transfer may still be
@@ -3949,23 +4141,29 @@ class Scheduler:
 
             # the [B, V] top-k sort only runs when some active request
             # asked for alternatives (ADVICE r2: fixed decode-path cost)
-            want_top = any(er.logprobs_n > 0 for er in active)
+            want_top = any(er.logprobs_n > 0 for er in live)
 
             # synchronous path: the device has been idle since the previous
             # burst's tokens reached the host (t_ready) — that gap IS the
-            # bubble the chain exists to close
-            if self._last_burst_done_t is not None:
+            # bubble running ahead (or the chain) exists to close; a step
+            # dispatched behind one in flight left the device none
+            if prev is not None:
+                self._bubble_hist.observe(0.0)
+            elif self._last_burst_done_t is not None:
                 self._bubble_hist.observe(
                     time.monotonic() - self._last_burst_done_t
                 )
-                self._last_burst_done_t = None
+            self._last_burst_done_t = None
 
             self.flight.record(
-                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(active),
-                requests=[er.request_id for er in active[:8]],
+                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(rows),
+                requests=[er.request_id for er in live[:8]],
             )
+            read_bytes = (self.device_time.decode_read_bytes(
+                k_steps, sum(pos for _, pos, *_ in rows))
+                if self.device_time is not None else 0.0)
         with span("sched.decode.dispatch", step=self.passes,
-                  rows=len(active)):
+                  rows=len(rows), ahead=int(prev is not None)):
             t_dispatch = time.monotonic()
             if k_steps > 1:
                 next_tokens, lps, top_vals, top_ids = self.runner.decode_burst(
@@ -3987,6 +4185,8 @@ class Scheduler:
                     want_top=want_top,
                     **({} if hs.wtab is None
                        else {"window_tables": hs.wtab[:, :w].copy()}),
+                    **({} if prev is None
+                       else {"prev_tokens": prev.arrays[0]}),
                 )
                 if self.draft is not None:
                     # mirror the step on the draft (inert sampling): the
@@ -4000,29 +4200,55 @@ class Scheduler:
                         commit=np.zeros(b, bool), want_top=False, **dkw,
                     )
             self._count_decode_rows(
-                "decode_burst" if k_steps > 1 else "decode", len(active),
+                "decode_burst" if k_steps > 1 else "decode", len(rows),
                 k_steps)
             self._inflight = True
+            if prev is not None:
+                self._ahead_ctr.inc()
+        # the copy to the host is asked for at the dispatch (_fetch says
+        # why), whichever pass waits for it
+        with span("sched.decode.request", step=self.passes):
+            arrays = [next_tokens, lps, top_vals, top_ids]
+            return _StepInFlight(
+                rows=[(er, er.slot) for er in live], arrays=arrays,
+                k_steps=k_steps, t_dispatch=t_dispatch,
+                prefetched=_request_transfer(arrays), read_bytes=read_bytes,
+                ahead=prev is not None, behind_chunk=self._chunk_unread,
+            )
 
+    async def _decode_land(self, loop, step: _StepInFlight) -> None:
+        """The host's half of a decode step: wait for its tokens
+        (``_fetch``: the frontend's turn first, unless the pass has
+        taken it) and walk the rows. A row that has finished since the
+        dispatch (it ended at the step before, which was read after
+        this one went out; or its client left) has its token dropped:
+        never emitted, never counted. Its KV went to a position past
+        everything committed, in a block the row still owned at the
+        dispatch; its slot's record is overwritten at the next
+        admission; and the device runs programs in dispatch order, so a
+        later prefill into the freed slot or page lands after it."""
+        k_steps = step.k_steps
         (toks, lpn, tv, ti), t_ready = await self._fetch(
-            loop, "decode", [next_tokens, lps, top_vals, top_ids],
-            chaos="decode_burst_hang")  # chaos site (see _apply_burst)
-        with span("sched.decode.emit", step=self.passes, rows=len(active)):
+            loop, "decode", step.arrays,
+            chaos="decode_burst_hang",  # chaos site (see _apply_burst)
+            prefetched=step.prefetched)
+        with span("sched.decode.emit", step=self.passes,
+                  rows=len(step.rows)):
             self._last_burst_done_t = t_ready
             if self.device_time is not None:
                 self.device_time.observe(
                     "decode_burst" if k_steps > 1 else "decode", "decode",
-                    t_dispatch, t_ready,
-                    read_bytes=self.device_time.decode_read_bytes(
-                        k_steps, sum(er.context_len for er in active),
-                    ),
-                    tokens=k_steps * len(active),
+                    step.t_dispatch, t_ready, read_bytes=step.read_bytes,
+                    tokens=k_steps * len(step.rows),
                 )
             self.steps += 1
             if k_steps == 1:
                 # [B] → [1, B] so the emit loop below is one shape
                 toks, lpn = toks[None], lpn[None]
                 tv, ti = tv[None], ti[None]
+            dropped = sum(er.finish is not None for er, _ in step.rows)
+            if step.ahead and dropped:
+                self._ahead_discarded_ctr.inc(dropped)
 
             # emit in step order; a request that finishes at step j has its
             # trailing burst tokens (sampled ahead on device) discarded —
@@ -4030,16 +4256,16 @@ class Scheduler:
             # over-allocated blocks, which are freed with the request, so
             # nothing another sequence can observe was touched
             for j in range(k_steps):
-                for er in active:
+                for er, slot in step.rows:
                     if er.finish is not None:
                         continue
-                    token = int(toks[j, er.slot])
+                    token = int(toks[j, slot])
                     self._advance_row(er, token)
                     self._guided_after_token(er)
                     self._emit(
                         er, token,
-                        float(lpn[j, er.slot]) if er.want_logprobs else None,
-                        self._top_row(er, tv[j], ti[j], er.slot),
+                        float(lpn[j, slot]) if er.want_logprobs else None,
+                        self._top_row(er, tv[j], ti[j], slot),
                     )
                     if er.finish is not None:
                         self._finish(er, er.finish, emit=False)

@@ -20,6 +20,12 @@ only:
 
 A bit pattern crosses unchanged: ``ndarray.view`` on the host,
 ``lax.bitcast_convert_type`` on the device; no cast runs on the chip.
+
+A decode step's token may be ``FED``: the host had not read the token
+the step before sampled for that row when it packed this one, and the
+program takes it from that step's ``next_tokens``, still on the device
+(``ModelRunner._build_step``). No vocabulary has a negative id, so the
+mark needs no column and the layout is what it was.
 """
 
 from __future__ import annotations
@@ -31,6 +37,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .sampling import SamplingParams
+
+# in place of a decode row's token: "the one the step before sampled"
+FED = -1
 
 _INT = ("context_lens", "last_idx", "sample_slots", "counters", "commit",
         "top_k")

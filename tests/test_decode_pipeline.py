@@ -18,6 +18,7 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.scheduler import EngineRequest, Scheduler
+from dynamo_tpu.engine.step_inputs import FED
 from dynamo_tpu.protocols.common import (
     PreprocessedRequest,
     SamplingOptions,
@@ -41,6 +42,10 @@ class FakeRunner:
     """
 
     spec_burst_ready = True
+    # ``step(prev_tokens=)`` is honoured, and ``FedRunner`` says so to the
+    # scheduler (``ModelRunner.feeds_tokens``): the paths of this file
+    # that predate the step ahead run as they ran
+    feeds_tokens = False
 
     def __init__(self, config: EngineConfig):
         self.config = config
@@ -105,9 +110,16 @@ class FakeRunner:
         return True
 
     def step(self, tokens, positions, btab, slot_map, ctx_lens, last_idx,
-             *args, sample_slots=None, want_greedy=False, **kw):
+             *args, sample_slots=None, want_greedy=False, prev_tokens=None,
+             **kw):
         self.step_calls += 1
         tokens = np.asarray(tokens)
+        if prev_tokens is not None:
+            # the device reads its own array, whenever the host does: a
+            # test's wrapper around it is looked through (``raw``)
+            fed = np.asarray(getattr(prev_tokens, "raw", prev_tokens))
+            tokens = np.where(tokens == FED, fed[:, None], tokens)
+        assert (tokens >= 0).all(), "a fed row and no step to feed it"
         b = tokens.shape[0]
         rows = np.arange(b)
         last_idx = np.asarray(last_idx)
@@ -358,11 +370,18 @@ def _request(prompt, max_tokens, eos=None, sampling=None):
     )
 
 
-def _run(config, requests, hooks=None):
+class FedRunner(FakeRunner):
+    """The fake runner behind a scheduler that runs its decode step one
+    step ahead of the host."""
+
+    feeds_tokens = True
+
+
+def _run(config, requests, hooks=None, runner_cls=FakeRunner):
     """Drive the scheduler over a FakeRunner; returns (streams, sched)."""
 
     async def go():
-        runner = FakeRunner(config)
+        runner = runner_cls(config)
         sched = Scheduler(runner, config)
         if hooks:
             hooks(sched)
@@ -1202,3 +1221,428 @@ def test_mixed_workload_chains_with_attributed_fallbacks():
     # every counted fallback reason is named (no empty labels)
     assert all(r for r in _fallback_reasons(sched))
     assert sched.allocator.used == 0
+
+
+# --------------------------------------------------------------------------
+# the decode step one step ahead of the host (ISSUE 57): step k goes to the
+# device before step k-1 is read, fed k-1's tokens on the device; the host
+# still decides every finish, one step later
+# --------------------------------------------------------------------------
+
+
+class TapeRunner(FedRunner):
+    """A fed runner that writes each ``step`` it is given on ``tape``:
+    ("decode", tokens out, live slots, fed slots, {slot: position}, the
+    step it was fed) or ("prefill", tokens out, the rows' slots); a test
+    adds ("fetch", kind, tokens read) through ``_tape_fetches``. The
+    arrays themselves are kept (compared with ``is``), so no id is used
+    twice."""
+
+    def __init__(self, config):
+        super().__init__(config)
+        self.tape = []
+
+    def step(self, tokens, positions, btab, slot_map, ctx_lens, last_idx,
+             *args, commit=None, sample_slots=None, prev_tokens=None, **kw):
+        out = super().step(
+            tokens, positions, btab, slot_map, ctx_lens, last_idx, *args,
+            commit=commit, sample_slots=sample_slots,
+            prev_tokens=prev_tokens, **kw)
+        tokens = np.asarray(tokens)
+        if tokens.shape[1] == 1:
+            live = [int(i) for i in np.flatnonzero(np.asarray(commit))]
+            self.tape.append((
+                "decode", out[0], live,
+                [i for i in live if tokens[i, 0] == FED],
+                {i: int(np.asarray(positions)[i, 0]) for i in live},
+                prev_tokens))
+        else:
+            self.tape.append(
+                ("prefill", out[0], [int(i) for i in sample_slots]))
+        return out
+
+
+def _tape_fetches(sched):
+    fetch = sched._fetch
+
+    async def _fetch(loop, kind, arrays, *a, tokens_at=0, **kw):
+        sched.runner.tape.append(("fetch", kind, arrays[tokens_at]))
+        return await fetch(loop, kind, arrays, *a, tokens_at=tokens_at, **kw)
+
+    sched._fetch = _fetch
+
+
+def _at(tape, what, array):
+    """Where on the tape ``array`` was made (``what`` a dispatch) or read
+    (``what`` "fetch")."""
+    return next(i for i, e in enumerate(tape)
+                if (e[0] == "fetch") == (what == "fetch")
+                and e[1 if what != "fetch" else 2] is array)
+
+
+def _ahead_run(reqs, hooks=None, **cfg_kw):
+    """The requests over a ``TapeRunner`` with the step ahead; returns
+    (streams, scheduler)."""
+    box = {}
+
+    def both(sched):
+        box["sched"] = sched
+        _tape_fetches(sched)
+        if hooks:
+            hooks(sched)
+
+    out = _run(_config(1, k=1, **cfg_kw), reqs, hooks=both,
+               runner_cls=TapeRunner)
+    return out, box["sched"]
+
+
+def _count(counter):
+    return sum(counter.values.values())
+
+
+def test_ahead_step_is_dispatched_before_the_step_before_is_read():
+    """Every decode step but the first goes out before the step before
+    it is fetched, its rows fed on the device; the streams are the
+    synchronous scheduler's."""
+    want = _streams(1, k=1)
+    got, sched = _ahead_run([_request(p, 21) for p in PROMPTS])
+    assert got == want
+    tape = sched.runner.tape
+    steps = [e for e in tape if e[0] == "decode"]
+    assert len(steps) == 20       # 21 tokens a row, the first a prefill's
+    first, rest = steps[0], steps[1:]
+    assert first[3] == [] and first[5] is None
+    for before, step in zip(steps, rest):
+        assert step[5] is before[1]                   # fed by the step before
+        assert step[3] == step[2] == before[2]        # every row, on the device
+        assert _at(tape, "decode", step[1]) < _at(tape, "fetch", before[1])
+        assert all(step[4][i] == before[4][i] + 1 for i in step[2])
+    assert _count(sched._ahead_ctr) == 19
+    assert _count(sched._ahead_discarded_ctr) == 0
+    assert not _fallback_reasons(sched)
+    # one fetch a step, each after its own dispatch
+    assert _count(sched._fetches_ctr) == 20 + 1       # and the prefill's
+    assert sched._ahead is None and sched.allocator.used == 0
+
+
+def test_ahead_row_that_stops_has_its_next_row_dropped():
+    """A row that ends at step k-1 by a stop token was already in step
+    k: that row of k is read and dropped, never emitted or counted, and
+    the request's usage is what the synchronous pass gives."""
+    plain = _streams(1, k=1)
+    eos = plain[0][0][6]           # the first row stops at its 7th token
+    assert eos not in plain[0][0][:6]
+    want = _streams(1, k=1, eos=[eos])
+    assert want[0] == (plain[0][0][:7], "eos")
+    reqs = [_request(p, 21, eos=[eos]) for p in PROMPTS]
+    got, sched = _ahead_run(reqs)
+    assert got == want
+    stopped = [er for er, (toks, fin) in zip(reqs, got) if fin == "eos"]
+    assert reqs[0] in stopped
+    assert _count(sched._ahead_discarded_ctr) == len(stopped)
+    for er, (toks, _) in zip(reqs, got):
+        # usage: what was emitted, and nothing of the dropped row
+        assert er.generated == er.decode_tokens + 1 == len(toks)
+        assert er.ctx.counts["decode_tokens"] == len(toks) - 1
+    # the first row's slot: in the step after its last (fed, dropped),
+    # in none after that
+    steps = [e for e in sched.runner.tape if e[0] == "decode"]
+    with_row = [i for i, e in enumerate(steps) if 0 in e[2]]
+    assert with_row == list(range(7))    # 6 of its own after the prefill's + 1
+    assert 0 in steps[6][3]
+    assert sched.allocator.used == 0
+
+
+def test_ahead_row_that_ends_by_length_is_not_in_the_next_step():
+    """The host knows a row's last step by its count (and by the model's
+    length): the row is left out of the step after, so nothing is
+    dropped."""
+    reqs = [_request(PROMPTS[0], 5), _request(PROMPTS[1], 9)]
+    want = _run(_config(1, k=1), [_request(PROMPTS[0], 5),
+                                  _request(PROMPTS[1], 9)])
+    got, sched = _ahead_run(reqs)
+    assert got == want and [len(t) for t, _ in got] == [5, 9]
+    steps = [e for e in sched.runner.tape if e[0] == "decode"]
+    assert [e[2] for e in steps] == [[0, 1]] * 4 + [[1]] * 4
+    assert _count(sched._ahead_discarded_ctr) == 0
+    assert _count(sched._ahead_ctr) == 7
+    # and by the horizon: a prompt that ends two short of max_model_len
+    horizon = _config(1, k=1, max_model_len=16)
+    long = list(range(1, 12))
+    want = _run(horizon, [_request(long, 40)])
+    got, sched = _ahead_run([_request(long, 40)], max_model_len=16)
+    assert got == want and got[0][1] == "length"
+    assert _count(sched._ahead_discarded_ctr) == 0
+    assert _count(sched._ahead_ctr) == len(got[0][0]) - 2
+
+
+def test_ahead_freed_slots_prefill_follows_the_step_that_wrote_to_it():
+    """Five requests on four slots: the fifth takes the slot of the row
+    that stopped, whose dropped row of the step ahead still wrote there.
+    Its prefill chunk is dispatched after that step (the device runs
+    programs in dispatch order), and every stream is the synchronous
+    scheduler's."""
+    plain = _streams(1, k=1)
+    eos = plain[0][0][6]
+
+    def reqs():
+        return [_request(p, 21, eos=[eos])
+                for p in (*PROMPTS, [4, 4], [8, 1, 8])]
+
+    want = _run(_config(1, k=1), reqs())
+    got, sched = _ahead_run(reqs())
+    assert got == want
+    tape = sched.runner.tape
+    # the last step that ran slot 0's first occupant: its dropped row
+    dropped = next(i for i, e in enumerate(tape) if e[0] == "decode"
+                   and 0 in e[3] and sum(
+                       1 for f in tape[:i + 1]
+                       if f[0] == "decode" and 0 in f[2]) == 7)
+    late = [i for i, e in enumerate(tape) if e[0] == "prefill"
+            and 0 in e[2] and i > 4]
+    assert late and late[0] > dropped
+    assert _count(sched._ahead_discarded_ctr) >= 1
+    assert sched.allocator.used == 0
+
+
+def test_ahead_kv_oom_drains_the_step_then_preempts():
+    """A block that cannot be had for a continuing row: the step in
+    flight is read and applied first (``kv_oom``), and preemption then
+    runs from committed state, as it always did."""
+    want = _streams(1, max_tokens=24, k=1, num_kv_blocks=64)
+    preempts = []
+
+    def hooks(sched):
+        orig = sched._preempt
+
+        def spy(er):
+            tape = sched.runner.tape
+            made = [e[1] for e in tape if e[0] == "decode"]
+            read = [e[2] for e in tape if e[0] == "fetch"]
+            assert sched._ahead is None and all(
+                any(m is r for r in read) for m in made), (
+                "preempted with a step still in flight")
+            assert er.context_len == len(er.seq.token_ids)
+            preempts.append(er.request_id)
+            return orig(er)
+
+        sched._preempt = spy
+
+    got, sched = _ahead_run([_request(p, 24) for p in PROMPTS], hooks=hooks,
+                            num_kv_blocks=10)
+    assert preempts, "test is vacuous: no preemption happened"
+    assert [(len(t), f) for t, f in got] == [(24, "length")] * 3
+    assert got == want
+    assert "kv_oom" in _fallback_reasons(sched)
+    assert _count(sched._ahead_ctr) > 0
+    assert sched.allocator.used == 0
+
+
+def test_ahead_guided_row_holds_the_pass_to_the_host():
+    """The host rewrites a guided row's mask from each token: while one
+    is active no step is dispatched ahead, under the reason ``guided``;
+    the others go ahead again once it has ended."""
+    def reqs():
+        return [_request(PROMPTS[0], 30),
+                _guided_request(PROMPTS[2], 20, CHOICES)]
+
+    want = _run(_config(1, k=1), reqs())
+    got, sched = _ahead_run(reqs())
+    assert got == want
+    assert _fallback_reasons(sched) == {"guided"}
+    steps = [e for e in sched.runner.tape if e[0] == "decode"]
+    assert all(e[5] is None and not e[3] for e in steps if 1 in e[2])
+    assert _count(sched._ahead_ctr) > 10
+    assert all(e[5] is not None for e in steps[-10:])
+
+
+def _ahead_interrupted(after, interrupt):
+    """Two requests over a ``TapeRunner``; once the first has
+    ``after`` tokens, ``interrupt(sched, reqs)`` (a coroutine) runs with
+    a decode step in flight. Returns (scheduler, requests, what each
+    request's queue held up to then or to its end)."""
+
+    async def go():
+        config = _config(1, k=1)
+        sched = Scheduler(TapeRunner(config), config)
+        _tape_fetches(sched)
+        reqs = [_request(PROMPTS[0], 40), _request(PROMPTS[1], 40)]
+        sched.start()
+        for er in reqs:
+            sched.add_request(er)
+        got = [[], []]
+        try:
+            while len(got[0]) < after:
+                out = await reqs[0].out_queue.get()
+                got[0].extend(out.token_ids)
+            assert sched._ahead is not None, "no step in flight"
+            await interrupt(sched, reqs)
+            for er, toks in zip(reqs, got):
+                while not er.out_queue.empty():
+                    out = er.out_queue.get_nowait()
+                    if out is not None:
+                        toks.extend(out.token_ids)
+        finally:
+            await sched.stop()
+        return sched, reqs, got
+
+    loop = asyncio.new_event_loop()
+    try:
+        return loop.run_until_complete(go())
+    finally:
+        loop.close()
+
+
+def _every_step_was_read(sched):
+    tape = sched.runner.tape
+    read = [e[2] for e in tape if e[0] == "fetch"]
+    return all(any(e[1] is r for r in read)
+               for e in tape if e[0] == "decode")
+
+
+def test_ahead_seize_and_extract_find_committed_state():
+    """A graceful ``seize`` lets the loop end on its barrier: the step in
+    flight is read and applied (``stop``), so what ``extract_requests``
+    hands to a migration is committed: every token that was emitted, and
+    no other, lies in the host's mirror or is the pending one."""
+    want = _run(_config(1, k=1), [_request(PROMPTS[0], 40),
+                                  _request(PROMPTS[1], 40)])
+
+    async def interrupt(sched, reqs):
+        await sched.seize()
+        assert sched._ahead is None
+
+    sched, reqs, got = _ahead_interrupted(6, interrupt)
+    assert _every_step_was_read(sched)
+    assert "stop" in _fallback_reasons(sched)
+    out = sched.extract_requests()
+    assert {id(er) for er in out} == {id(er) for er in reqs}
+    for er, toks, (full, _) in zip(reqs, got, want):
+        assert toks == full[:len(toks)] and len(toks) == er.generated >= 6
+        assert er.context_len == len(er.seq.token_ids)
+        assert er.seq.token_ids[len(er.prompt):] + [er.pending_token] == toks
+    # a hard seize abandons the step: nothing of it was emitted
+    async def hard(sched, reqs):
+        await sched.seize(hard=True)
+        assert sched._ahead is None
+
+    sched, reqs, got = _ahead_interrupted(6, hard)
+    for er, toks, (full, _) in zip(reqs, got, want):
+        assert toks == full[:len(toks)] and len(toks) == er.generated
+        assert er.seq.token_ids[len(er.prompt):] + [er.pending_token] == toks
+
+
+def test_ahead_stop_reads_the_step_in_flight():
+    async def interrupt(sched, reqs):
+        await sched.stop()
+
+    sched, reqs, got = _ahead_interrupted(6, interrupt)
+    assert sched._ahead is None and _every_step_was_read(sched)
+    assert "stop" in _fallback_reasons(sched)
+    for er, toks in zip(reqs, got):
+        assert len(toks) == er.generated
+
+
+def test_ahead_cancel_drops_the_row_and_leaves_the_others():
+    """A client that leaves with a step in flight: its rows of the steps
+    already dispatched are read and dropped, its slot and blocks go back
+    at the next admit, and the other stream is untouched."""
+    want = _run(_config(1, k=1), [_request(PROMPTS[0], 40),
+                                  _request(PROMPTS[1], 40)])
+
+    async def interrupt(sched, reqs):
+        reqs[0].ctx.stop_generating()
+        while True:
+            out = await reqs[1].out_queue.get()
+            if out is None:
+                break
+            got1.extend(out.token_ids)
+
+    got1 = []
+    sched, reqs, got = _ahead_interrupted(6, interrupt)
+    assert reqs[0].finish == "cancelled"
+    assert got[1] + got1 == want[1][0]
+    assert got[0] == want[0][0][:len(got[0])]
+    assert _count(sched._ahead_discarded_ctr) >= 1
+    assert _every_step_was_read(sched) and sched.allocator.used == 0
+
+
+def test_ahead_every_fallback_names_its_reason():
+    """Over a mixed run (a guided row, a stop token, a short pool, more
+    requests than slots): a pass that had a step in flight and did not
+    dispatch ahead of it either dispatched nothing (every row known to
+    end) or counted a reason; and the streams are the synchronous
+    scheduler's."""
+    plain = _streams(1, k=1)
+    eos = plain[1][0][9]
+
+    def reqs():
+        return [_request(PROMPTS[0], 24, eos=[eos]),
+                _request(PROMPTS[1], 24, eos=[eos]),
+                _guided_request(PROMPTS[2], 20, CHOICES),
+                _request([4, 4], 24, eos=[eos]),
+                _request([8, 1, 8], 7, eos=[eos])]
+
+    seen = []
+
+    def hooks(sched):
+        decode = sched._decode
+
+        async def spy(loop, active, k_steps=1):
+            had = sched._ahead is not None
+            before = (_count(sched._ahead_ctr),
+                      _count(sched._sync_fallback_ctr),
+                      sum(e[0] == "decode" for e in sched.runner.tape))
+            await decode(loop, active, k_steps)
+            after = (_count(sched._ahead_ctr),
+                     _count(sched._sync_fallback_ctr),
+                     sum(e[0] == "decode" for e in sched.runner.tape))
+            seen.append((had, *(b - a for a, b in zip(before, after))))
+
+        sched._decode = spy
+
+    want = _run(_config(1, k=1, num_kv_blocks=12), reqs())
+    got, sched = _ahead_run(reqs(), hooks=hooks, num_kv_blocks=12)
+    assert got == want
+    for had, ahead, fell, dispatched in seen:
+        assert ahead + fell <= 1 and dispatched <= 1
+        if had and not ahead:
+            assert fell == 1 or dispatched == 0, (had, ahead, fell, dispatched)
+        if not had:
+            assert not ahead
+    reasons = _fallback_reasons(sched)
+    assert reasons and reasons <= {"guided", "kv_oom"} and all(reasons)
+    assert sum(a for _, a, _, _ in seen) == _count(sched._ahead_ctr) > 0
+    assert sched.allocator.used == 0
+
+
+def test_ahead_step_behind_a_chunk_is_read_before_the_prompts_last_chunk():
+    """A prompt of three chunks beside a row that decodes: the decode
+    step of a middle chunk's pass stands behind that chunk in the
+    device's queue, and the next pass, whose chunk ends the prompt, waits
+    for its chunk. The step in flight is read before that wait
+    (``behind_chunk``): its tokens were ready a chunk earlier. A prompt
+    of one chunk leaves the step in flight, as ever."""
+    cfg = dict(max_prefill_tokens_per_step=8, prefill_buckets=[8, 16, 32])
+
+    def reqs(long):
+        return [_request(PROMPTS[0], 30), _request(long, 12)]
+
+    long = list(range(3, 23))             # 20 tokens: chunks of 8, 8, 4
+    want = _run(_config(1, k=1, **cfg), reqs(long))
+    got, sched = _ahead_run(reqs(long), **cfg)
+    assert got == want
+    assert "behind_chunk" in _fallback_reasons(sched)
+    tape = sched.runner.tape
+    chunks = [i for i, e in enumerate(tape) if e[0] == "prefill"]
+    # (the short prompt's one chunk, then the long one's three)
+    assert len(chunks) == 4
+    last = tape[chunks[-1]]
+    behind = next(e for e in reversed(tape[:chunks[-1]])
+                  if e[0] == "decode")          # dispatched behind chunk two
+    assert _at(tape, "decode", behind[1]) > chunks[-2]
+    assert _at(tape, "fetch", behind[1]) < _at(tape, "fetch", last[1])
+    # one chunk a prompt: nothing to read early, no such reason
+    got, sched = _ahead_run(reqs([5, 6, 7, 8]), **cfg)
+    assert got == _run(_config(1, k=1, **cfg), reqs([5, 6, 7, 8]))
+    assert "behind_chunk" not in _fallback_reasons(sched)

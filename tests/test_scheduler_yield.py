@@ -26,9 +26,10 @@ from dynamo_tpu.protocols.common import OutputOptions, SamplingOptions
 STEP_S = 0.04   # a device step of the slow runner
 
 # of the decode paths tests/test_trace_spans.py enumerates: those that
-# fetch every step's result before the next dispatch, and those that take
-# a mixed batch (which is not eligible for speculation)
-SYNC_PATHS = ("sync", "burst", "spec_sync")
+# fetch a step's result a pass (their own step's before the next
+# dispatch, or, on ``ahead``, the step before's behind it), and those
+# that take a mixed batch (which is not eligible for speculation)
+SYNC_PATHS = ("ahead", "sync", "burst", "spec_sync")
 MIXED_PATHS = sorted(p for p in PATHS if not p.startswith("spec"))
 
 
@@ -38,6 +39,7 @@ class _Later:
 
     def __init__(self, value, ready_at, log, n):
         self.value, self.ready_at, self.log, self.n = value, ready_at, log, n
+        self.raw = value    # the device reads it without waiting
 
     def __array__(self, dtype=None, copy=None):
         time.sleep(max(0.0, self.ready_at - time.monotonic()))
@@ -56,12 +58,15 @@ class SlowRunner(dp.FakeRunner):
         super().__init__(config)
         self.log = []
         self.n = 0
+        self.busy_until = 0.0
 
     def _later(self, outs):
+        # one device: a step starts when the one before it has ended
         self.n += 1
         t = time.monotonic()
         self.log.append(("dispatch", self.n, t))
-        return tuple(_Later(o, t + STEP_S, self.log, self.n)
+        self.busy_until = max(t, self.busy_until) + STEP_S
+        return tuple(_Later(o, self.busy_until, self.log, self.n)
                      if isinstance(o, np.ndarray) else o for o in outs)
 
     def step(self, tokens, *a, **kw):
@@ -126,6 +131,18 @@ def _drive(config, reqs, runner_cls=dp.FakeRunner, on_output=None, hooks=None,
         loop.close()
 
 
+class SlowFedRunner(SlowRunner):
+    feeds_tokens = True
+
+
+def _path_runner(path, slow=False):
+    """``ahead``: the runner that feeds a step its tokens on the device
+    (the scheduler then runs one step ahead of the host)."""
+    if PATHS[path].get("ahead"):
+        return SlowFedRunner if slow else dp.FedRunner
+    return SlowRunner if slow else dp.FakeRunner
+
+
 def _path_config(path):
     kw = PATHS[path]
     if kw.get("spec"):
@@ -151,7 +168,7 @@ def _path_requests(path, max_tokens=21):
 def test_token_is_streamed_while_the_next_step_computes(path, monkeypatch):
     spans = _record_spans(monkeypatch)
     got, sched = _drive(_path_config(path), _path_requests(path),
-                        runner_cls=SlowRunner)
+                        runner_cls=_path_runner(path, slow=True))
     log = sched.runner.log
     dispatch = {n: t for kind, n, t in log if kind == "dispatch"}
     ready = {}
@@ -167,8 +184,12 @@ def test_token_is_streamed_while_the_next_step_computes(path, monkeypatch):
         n = max(k for k, t in dispatch.items() if t <= t_recv)
         if n == max(dispatch) and t_recv >= ready[n]:
             continue
-        assert dispatch[n] <= t_recv < dispatch[n] + STEP_S <= ready[n], (
-            path, n, t_recv - dispatch[n])
+        assert dispatch[n] + STEP_S <= ready[n], (path, n)
+        # one step ahead, the device has a queue: the run's last step but
+        # one is delivered a whole step after the newest dispatch, while
+        # that step, queued behind it, computes
+        still = ready[n] if path == "ahead" else dispatch[n] + STEP_S
+        assert dispatch[n] <= t_recv < still, (path, n, t_recv - dispatch[n])
         hidden += 1
     assert hidden >= 3
     # and the turn that delivered them says so
@@ -238,7 +259,7 @@ def test_cancelled_request_is_dropped_within_one_pass(path):
 
     reqs = _path_requests(path, 40)
     got, _ = _drive(_path_config(path), reqs, on_output=on_output,
-                    hooks=hooks)
+                    hooks=hooks, runner_cls=_path_runner(path))
     assert cancelled_at and finished_at
     (p_fin, reason), = finished_at
     assert reason == "cancelled"
@@ -305,9 +326,16 @@ PARENT_STREAMS = [
 
 @pytest.mark.parametrize("path", MIXED_PATHS)
 def test_mixed_batch_streams_what_the_parent_streamed(path):
-    got, sched = _drive(_path_config(path), _mixed_requests())
+    got, sched = _drive(_path_config(path), _mixed_requests(),
+                        runner_cls=_path_runner(path))
     assert _streams_of(got) == PARENT_STREAMS
     assert sched.allocator.used == 0
+    if path == "ahead":
+        # the guided row holds every pass it is in to the host's pace,
+        # under its reason; once it has gone, the steps run ahead
+        reasons = {dict(k)["reason"] for k in sched._sync_fallback_ctr.values}
+        assert reasons == {"guided"}
+        assert sum(sched._ahead_ctr.values.values()) > 0
 
 
 # ---------------------------------------------------------------------
@@ -323,7 +351,7 @@ def test_yield_counters_add_up(path):
                      sum(sched._yield_inflight_ctr.values.values())))
 
     _, sched = _drive(_path_config(path), _path_requests(path),
-                      on_output=on_output)
+                      on_output=on_output, runner_cls=_path_runner(path))
     assert len(seen) > 5
     for (t0, i0), (t1, i1) in zip(seen, seen[1:]):
         assert t1 >= t0 and i1 >= i0                # both monotone
@@ -338,3 +366,51 @@ def test_yield_counters_add_up(path):
     text = sched.registry.render()
     assert "dynamo_scheduler_yield_seconds_total" in text
     assert "dynamo_scheduler_yield_inflight_seconds_total" in text
+
+
+# ---------------------------------------------------------------------
+# (5) with a step in flight across passes, the turn is still one a pass
+# ---------------------------------------------------------------------
+
+def test_the_turn_is_taken_once_a_pass_with_a_step_in_flight(monkeypatch):
+    """ISSUE 57: the decode step is left in flight at the end of its
+    pass and read by the next, behind that pass's dispatch. Every pass
+    that progressed still takes exactly one turn; a pass with a step in
+    flight takes it with ``inflight=1``, between its dispatch and the
+    wait for the step before; and a token is delivered while the device
+    still has a step queued."""
+    spans = _record_spans(monkeypatch)
+    got, sched = _drive(_path_config("ahead"), _path_requests("ahead"),
+                        runner_cls=SlowFedRunner)
+    by_pass = {}
+    for name, stats, t0, t1 in spans:
+        if name.startswith("sched."):       # (not sync.fetch's parts)
+            by_pass.setdefault(stats["step"], []).append(
+                (name, stats, t0, t1))
+    ahead = 0
+    for n, mine in by_pass.items():
+        names = [s[0] for s in mine]
+        if "sched.wait" in names:
+            assert "sched.yield" not in names, names
+            continue
+        assert names.count("sched.yield") == 1, (n, names)
+        i = names.index("sched.yield")
+        dispatch = [s for s in mine if s[0] == "sched.decode.dispatch"]
+        if dispatch and dispatch[-1][1]["ahead"]:
+            ahead += 1
+            assert mine[i][1]["inflight"] == 1
+            assert names[i - 2:] == [
+                "sched.decode.dispatch", "sched.decode.request",
+                "sched.yield", "sched.decode.sync", "sched.decode.emit"]
+    assert ahead == sum(sched._ahead_ctr.values.values()) >= 15
+    # the device never waited for the host between two such steps: each
+    # was dispatched before the one before it was ready
+    log = sched.runner.log
+    dispatch = {n: t for kind, n, t in log if kind == "dispatch"}
+    ready = {}
+    for kind, n, t in log:
+        if kind == "ready":
+            ready.setdefault(n, t)
+    queued = sum(dispatch[n] < ready[n - 1] for n in dispatch if n - 1 in ready)
+    assert queued >= ahead - 1
+    assert all(sum(len(o.token_ids) for _, o in g) == 21 for g in got)

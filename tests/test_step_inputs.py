@@ -206,14 +206,15 @@ def test_the_guard_catches_a_host_array_handed_to_the_program(runner):
         *args[:4], keys=kw["seed_keys"], want_top=False,
         context_lens=args[4], last_idx=args[5], temperature=args[6],
         top_k=args[7], top_p=args[8])
+    prev = jnp.zeros(b, jnp.int32)   # (the step before's tokens)
     lowered = runner._decode_step.lower(
-        runner.params, *runner.kv_cache, *runner.sample_state, buf)
+        runner.params, *runner.kv_cache, *runner.sample_state, buf, prev)
     compiled = lowered.compile()
     state = jax.tree.map(jnp.copy, (runner.params, *runner.kv_cache,
                                     *runner.sample_state))
     with jax.transfer_guard_host_to_device("disallow"):
         with pytest.raises(Exception, match="[Dd]isallowed host-to-device"):
-            compiled(*state, buf)
+            compiled(*state, buf, prev)
 
 
 def _per_array_reference(runner, args, kw):

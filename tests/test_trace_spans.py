@@ -84,6 +84,7 @@ class Recorded:
 
     def __init__(self, arr, log):
         self.arr, self.log, self.nbytes = arr, log, arr.nbytes
+        self.raw = arr      # what the fake runner's device reads
 
     def copy_to_host_async(self):
         self.log.append(("request", id(self)))
@@ -111,7 +112,7 @@ OUTPUTS = {"step": (0, 1, 2, 3, 5), "decode_burst": (0, 1, 2, 3),
 def _record(sched):
     """Wrap the outputs of ``sched.runner`` in ``Recorded`` and write
     the scheduler's own moments beside theirs: ("dispatch", method,
-    ids), ("turn",) where ``sched.yield`` is about to open, ("fetch",
+    ids, is it a decode step), ("turn",) where ``sched.yield`` is about to open, ("fetch",
     kind, ids) where ``_fetch`` is entered. Returns the log; the
     wrapped objects are kept alive in it so that no id is used twice."""
     log = []
@@ -120,10 +121,16 @@ def _record(sched):
         real = getattr(sched.runner, name)
 
         def method(*a, **kw):
+            fed = kw.get("prev_tokens")
+            if isinstance(fed, Recorded):
+                # the program reads the array itself, not the stand-in
+                kw["prev_tokens"] = fed.arr
             out = list(real(*a, **kw))
             for i in which:
                 out[i] = Recorded(out[i], log)
-            log.append(("dispatch", name, [out[i] for i in which]))
+            # (the fourth: is it a decode step, one token a row?)
+            log.append(("dispatch", name, [out[i] for i in which],
+                        name == "step" and a[0].shape[1] == 1))
             return tuple(out)
 
         setattr(sched.runner, name, method)
@@ -148,11 +155,15 @@ def _record(sched):
 
 def _assert_requested_early(log, what):
     """Every array a fetch read had its copy requested exactly once,
-    before the first read; a chained burst's at its own dispatch, with
-    nothing of the scheduler's in between, every other one's where
-    ``_fetch`` is entered, before the frontend's turn. Returns (fetches,
-    arrays fetched, arrays requested at a chained dispatch, fetches
-    whose requests the turn followed at once)."""
+    before the first read, and where the scheduler says: a decode
+    step's or burst's at its own dispatch, with nothing of the
+    scheduler's in between (``_decode_dispatch`` since ISSUE 57, the
+    chain's bursts before it), so that a step read a pass later has it
+    made already; a prefill chunk's and a verify step's where ``_fetch``
+    is entered, before the frontend's turn. Returns (fetches, arrays
+    fetched, arrays requested at a chained dispatch, fetches that the
+    turn followed with nothing but their requests between, arrays of a
+    decode step requested at its dispatch)."""
     made_by, at = {}, {}
     for i, entry in enumerate(log):
         if entry[0] == "dispatch":
@@ -161,25 +172,34 @@ def _assert_requested_early(log, what):
         elif entry[0] in ("request", "read"):
             at.setdefault((entry[0], entry[1]), []).append(i)
     fetches = [(i, e) for i, e in enumerate(log) if e[0] == "fetch"]
-    arrays = chained = turned = 0
+    arrays = chained = turned = stepped = 0
     for i, (_, kind, ids) in fetches:
         ids = [x for x in ids if x in made_by]   # not the sliced rows
         assert ids, (what, kind)
-        turned += log[i + len(ids) + 1][0] == "turn"
+        inside = 0      # of this fetch's requests, those made in it
         for x in ids:
             arrays += 1
             requests, reads = at.get(("request", x)), at.get(("read", x))
             assert requests and len(requests) == 1, (what, kind, requests)
             assert reads and requests[0] < reads[0], (what, kind)
             method, made, n = made_by[x]
-            if method in ("decode_burst_chained", "decode_burst_spec"):
-                chained += 1
-                assert made < requests[0] <= made + n < i, (what, method)
+            if requests[0] < i:
+                # at the dispatch: behind it at once, before the fetch
+                assert made < requests[0] <= made + n, (what, method)
+                assert kind == "decode", (what, method)
+                if method in ("decode_burst_chained", "decode_burst_spec"):
+                    chained += 1
+                else:
+                    stepped += 1
             else:
                 # right behind the fetch's entry: the turn, where this
                 # fetch takes it, opens after every request
                 assert i < requests[0] <= i + len(ids), (what, method)
-    return len(fetches), arrays, chained, turned
+                assert method == "step", (what, method)
+                inside += 1
+        assert inside in (0, len(ids)), (what, kind)
+        turned += log[i + inside + 1][0] == "turn"
+    return len(fetches), arrays, chained, turned, stepped
 
 
 # ---------------------------------------------------------------------
@@ -220,12 +240,19 @@ async def _served_scenario(tmp):
     handler.emit = lambda r: lines.append(r.getMessage())
     serving_log = logging.getLogger("dynamo_tpu.engine.serving")
     serving_log.addHandler(handler)
-    level, serving_log.level = serving_log.level, logging.INFO
+    # setLevel, not an assignment to ``level``: a logger remembers what
+    # ``isEnabledFor`` last said a level (``Logger._cache``), and only
+    # setLevel forgets it. An engine that an earlier test of this worker
+    # built has asked this logger about INFO under the root's WARNING;
+    # with the level merely assigned, it went on answering no and the
+    # lines below were never written (the driver's run of PR 56's tree)
+    level = serving_log.level
+    serving_log.setLevel(logging.INFO)
     try:
         engine, mdc = await build_engine("jax", flags)
     finally:
         serving_log.removeHandler(handler)
-        serving_log.level = level
+        serving_log.setLevel(level)
     task = asyncio.ensure_future(run_http(flags, engine, mdc))
     base = f"http://127.0.0.1:{port}"
     out = {"runner": engine.core_engine.runner, "serving_log": lines,
@@ -415,7 +442,12 @@ def _assert_one_turn_a_pass(events, sync_path, what):
     """sched.yield once a pass that progressed and never twice; on a
     synchronous decode path right after the pass's last dispatch (and
     the request for its result's copy, ``sched.decode.request``) and
-    before the wait for its result, with ``inflight=1`` (ISSUE 32)."""
+    before the wait for a result, with ``inflight=1`` (ISSUE 32). The
+    wait is for the pass's own step, or, where the step runs ahead
+    (ISSUE 57), for the step of the pass before, this pass's being left
+    in flight; the first step of a run is waited for by no pass of its
+    own, and that pass ends on its turn. Returns the passes whose turn
+    came between a dispatch and a wait."""
     by_pass = _passes(events, _loop_tid(events))
     first, last = min(by_pass), max(by_pass)
     hidden = 0
@@ -433,10 +465,10 @@ def _assert_one_turn_a_pass(events, sync_path, what):
             # that stands between the dispatch and the turn
             assert names[i - 2:i] == ["sched.decode.dispatch",
                                       "sched.decode.request"], (what, names)
-            assert names[i + 1:] == ["sched.decode.sync",
-                                     "sched.decode.emit"], (what, names)
+            assert names[i + 1:] in (["sched.decode.sync",
+                                      "sched.decode.emit"], []), (what, names)
             assert spans[i]["stats"]["inflight"] == 1, (what, n)
-            hidden += 1
+            hidden += bool(names[i + 1:])
     return hidden
 
 
@@ -528,13 +560,14 @@ def test_a_fetch_is_written_in_its_parts(served):
 
 
 def test_every_result_is_requested_at_its_dispatch(served):
-    """ISSUE 36: the four arrays of a step are asked for where ``_fetch``
-    is entered, straight after the dispatch and before ``sched.yield``
-    opens; the executor thread's ``np.asarray`` finds the request made."""
-    fetches, arrays, chained, turned = _assert_requested_early(
+    """ISSUE 36: the four arrays of a step are asked for straight after
+    its dispatch (a decode step's there, a prefill chunk's where
+    ``_fetch`` is entered) and before ``sched.yield`` opens; the executor
+    thread's ``np.asarray`` finds the request made."""
+    fetches, arrays, chained, turned, stepped = _assert_requested_early(
         served["log"], "served")
     assert fetches > 20 and arrays == 4 * fetches and chained == 0
-    assert turned > 15
+    assert turned > 15 and stepped > 60
     stats = [e["stats"]["prefetched"]
              for e in _named(served["events"], "sync.fetch")]
     assert len(stats) == fetches and set(stats) == {4}
@@ -819,6 +852,7 @@ def test_request_record_has_the_new_fields(served, field):
 # ---------------------------------------------------------------------
 
 PATHS = {
+    "ahead": dict(depth=1, ahead=True),
     "sync": dict(depth=1),
     "burst": dict(depth=1, k=4),
     "chained": dict(depth=2),
@@ -835,12 +869,18 @@ def _path_run(path, record):
     import test_decode_pipeline as dp
 
     kw = dict(PATHS[path])
-    if kw.pop("spec", False):
+    kw_spec = kw.pop("spec", False)
+    if kw_spec:
         # an 8-token vocabulary and a repetitive prompt, so that the
         # ngram proposer has matches and the verify path runs
         config = dp._spec_config(kw.pop("depth"))
         reqs = [dp._request([1, 2, 1, 2, 1, 2], 24)]
     else:
+        # ``ahead``: the runner feeds a step its tokens on the device, so
+        # the scheduler dispatches each decode step before it has read the
+        # one before (the other paths' runner does not, and they run as
+        # they ran before ISSUE 57)
+        runner_cls = dp.FedRunner if kw.pop("ahead", False) else dp.FakeRunner
         config = dp._config(kw.pop("depth"), k=kw.pop("k", 1), **kw)
         reqs = [dp._request(p, 21) for p in dp.PROMPTS]
     box = {}
@@ -848,7 +888,8 @@ def _path_run(path, record):
     def hooks(sched):
         box.update(sched=sched, log=_record(sched) if record else None)
 
-    box["streams"] = dp._run(config, reqs, hooks=hooks)
+    box["streams"] = dp._run(config, reqs, hooks=hooks,
+                             **({} if kw_spec else {"runner_cls": runner_cls}))
     return box
 
 
@@ -934,10 +975,53 @@ def test_every_decode_path_keeps_sched_spans_apart(path_events):
 
 def test_every_decode_path_takes_one_turn_a_pass(path_events):
     path, events, _ = path_events
-    sync_path = path in ("sync", "burst", "spec_sync")
+    sync_path = path in ("ahead", "sync", "burst", "spec_sync")
     hidden = _assert_one_turn_a_pass(events, sync_path, path)
     if sync_path:
         assert hidden > 3, path
+
+
+def test_the_step_ahead_is_dispatched_before_the_step_before_is_read(
+        path_run):
+    """ISSUE 57, the spans of a pass in their new order: build(k),
+    dispatch(k) with ``ahead=1``, request(k), yield, sync(k-1),
+    emit(k-1). By the log, the arrays a decode fetch reads were made one
+    dispatch of ``step`` before the newest; on every other path by the
+    newest, and no dispatch says ``ahead``."""
+    path, log = path_run["path"], path_run["log"]
+    flags = [int(e["stats"]["ahead"])
+             for e in _named(path_run["events"], "sched.decode.dispatch")
+             if "ahead" in e["stats"]]
+    made = {id(x): i for i, e in enumerate(log) if e[0] == "dispatch"
+            for x in e[2]}
+    steps = [i for i, e in enumerate(log) if e[0] == "dispatch" and e[3]]
+    behind = []     # decode dispatches between a result's own and its fetch
+    for i, e in enumerate(log):
+        if e[0] == "fetch" and e[1] == "decode" and e[2][0] in made:
+            behind.append(sum(made[e[2][0]] < j < i for j in steps))
+    sched = path_run["sched"]
+    ahead = sum(sched._ahead_ctr.values.values())
+    if path != "ahead":
+        assert not any(flags) and ahead == 0, path
+        if not path.startswith("chained"):
+            assert set(behind) == {0}, path
+        return
+    # all but the run's first step, and the steps after a pass in which a
+    # prompt's last chunk was read first (none here: one prefill pass)
+    assert sum(flags) == ahead == len(flags) - 1 > 15
+    assert behind.count(1) == ahead and behind.count(0) == 1
+    by_pass = _passes(path_run["events"], _loop_tid(path_run["events"]))
+    full = [[e["name"] for e in spans] for spans in by_pass.values()
+            if sum(e["name"] == "sched.decode.dispatch" for e in spans)
+            and any(e["name"] == "sched.decode.sync" for e in spans)]
+    assert len(full) >= ahead - 1
+    for names in full:
+        assert names[-6:] == [
+            "sched.decode.build", "sched.decode.dispatch",
+            "sched.decode.request", "sched.yield", "sched.decode.sync",
+            "sched.decode.emit"], names
+    assert not sched._sync_fallback_ctr.values
+    assert sum(sched._ahead_discarded_ctr.values.values()) == 0
 
 
 def _fetched(sched):
@@ -952,13 +1036,15 @@ def test_every_decode_path_requests_each_result_once_and_early(path_run):
     entered, before the turn; a chained burst's at the burst's dispatch,
     one or more bursts before its fetch."""
     path, sched = path_run["path"], path_run["sched"]
-    fetches, arrays, chained, turned = _assert_requested_early(
+    fetches, arrays, chained, turned, stepped = _assert_requested_early(
         path_run["log"], path)
     assert (fetches, arrays) == _fetched(sched), path
     if path in ("chained", "chained_k4", "spec_chained"):
         assert chained > 0, path
     else:
         assert chained == 0 and turned > 3, path
+    if path in ("ahead", "sync", "burst"):
+        assert stepped > 12, path
     stats = [e["stats"]["prefetched"]
              for e in _named(path_run["events"], "sync.fetch")]
     assert len(stats) == fetches and sum(stats) == arrays, path
@@ -1010,7 +1096,8 @@ def _lower_tiny_step(strip):
             keys=np.zeros((b, 2), np.uint32), want_top=False,
             context_lens=1, last_idx=0, top_k=0, temperature=0.0, top_p=1.0)
         lowered = r._decode_step.lower(
-            r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state, packed)
+            r.params, r.kv_cache[0], r.kv_cache[1], *r.sample_state, packed,
+            np.zeros(b, np.int32))
         return lowered.as_text(debug_info=True), lowered.compile()
     finally:
         jax.named_scope = real
