@@ -63,17 +63,15 @@ async def drive(plan: dict, work: str,
     """Write the plan, run ``harness.loadgen`` on it, and return
     (what it recorded, the window's start, what ``on_window`` returned).
     ``on_window(t0)`` is awaited as soon as the window opens (the traced
-    run's capture). The child never sees ``DYN_TRACE_JSONL``: that is
-    the server's."""
+    run's capture)."""
     plan_path = os.path.join(work, "plan.json")
     with open(plan_path, "w") as f:
         json.dump(plan, f)
     loop = asyncio.get_running_loop()
     events = {k: loop.create_future() for k in EVENTS}
-    env = {k: v for k, v in os.environ.items() if k != "DYN_TRACE_JSONL"}
     child = await asyncio.create_subprocess_exec(
         sys.executable, "-m", "harness.loadgen", plan_path, cwd=BENCH_DIR,
-        env=env, stdout=asyncio.subprocess.PIPE, stderr=sys.stderr)
+        stdout=asyncio.subprocess.PIPE, stderr=sys.stderr)
     log: list = []
     pump = asyncio.ensure_future(_pump(child.stdout, events, log))
     side = None

@@ -22,7 +22,6 @@ class RunData:
     prom_start: Dict                   # /metrics at the window's two ends
     prom_end: Dict
     prom_samples: List[Tuple[float, Dict]] = dataclasses.field(default_factory=list)
-    request_traces: Dict[str, dict] = dataclasses.field(default_factory=dict)
     device_trace: Any = None           # harness.trace.DeviceTrace
     trace_slice: Optional[Tuple[float, float]] = None   # monotonic, of the capture
     device_kind: str = ""

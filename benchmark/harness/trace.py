@@ -11,7 +11,14 @@ execution of a compiled program (``jit_<function>(<fingerprint>)``) and
 the line ``XLA Ops`` one event per HLO operation, named by its whole HLO
 line and nested (a ``while`` spans the operations of its body); the
 line ``Async XLA Ops`` holds copies that run beside them and is left
-out. Host threads are lines of the plane ``/host:CPU``.
+out. Host threads are lines of the plane ``/host:CPU``. An execution
+carries the stat ``run_id``, and so do two of the runtime's own host
+events (seen on every capture of PR 58):
+``DoEnqueueProgram`` (a ``pjrt-tpu-tasks`` thread hands the program to
+the chip) and ``CompleteCallbacks`` (the host learns that it ended),
+with the chip as ``device_ordinal``. They are the one place where an
+event of the host plane and one of a device plane are the same thing
+by name and not by time (``Event.run``).
 
 - busy time of a device = the union of its ``XLA Ops`` intervals;
 - a program's time = the durations of its ``XLA Modules`` events;
@@ -39,6 +46,9 @@ MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 # the program's own spans that can hold an idle gap (docs/observability.md)
 PROGRAM_SPANS = ("sched.", "sync.", "dispatch.")
+# the runtime's host events that name the execution they belong to
+ENQUEUED, COMPLETED = "DoEnqueueProgram", "CompleteCallbacks"
+RUN_EVENTS = (ENQUEUED, COMPLETED)
 
 
 @dataclasses.dataclass
@@ -48,6 +58,7 @@ class Event:
     dur: float
     own: float = 0.0  # dur minus nested events (ops only)
     detail: str = ""  # a longer name where the trace has one
+    run: Optional[Tuple[int, int]] = None   # (chip, run_id): RUN_EVENTS, executions
 
 
 @dataclasses.dataclass
@@ -87,10 +98,27 @@ def short_name(name: str) -> str:
     return name
 
 
-def _events(line) -> List[Event]:
-    out = [Event(short_name(e.name), e.start_ns * 1e-9, e.duration_ns * 1e-9,
-                 detail=e.name[:300])
-           for e in line.events]
+def _run_of(e, device: Optional[int]) -> Optional[Tuple[int, int]]:
+    """(chip, run_id) of an execution (``device`` given) or of one of
+    RUN_EVENTS; None where the event does not say."""
+    stats = {k: v for k, v in e.stats if k in ("run_id", "device_ordinal")}
+    if "run_id" not in stats:
+        return None
+    if device is None:
+        device = stats.get("device_ordinal")
+    return None if device is None else (int(device), int(stats["run_id"]))
+
+
+def _events(line, device: Optional[int] = None) -> List[Event]:
+    """A line's events by start. ``Event.run`` is read for every event
+    of chip ``device``'s ``XLA Modules`` line and for RUN_EVENTS."""
+    out = []
+    for e in line.events:
+        ev = Event(short_name(e.name), e.start_ns * 1e-9,
+                   e.duration_ns * 1e-9, detail=e.name[:300])
+        if device is not None or ev.name in RUN_EVENTS:
+            ev.run = _run_of(e, device)
+        out.append(ev)
     out.sort(key=lambda ev: (ev.start, -ev.dur))
     return out
 
@@ -120,8 +148,8 @@ def load(path: str) -> DeviceTrace:
         for line in plane.lines:
             evs = None
             if m and line.name in (OPS_LINE, MODULES_LINE):
-                evs = _events(line)
                 d = int(m.group(1))
+                evs = _events(line, d if line.name == MODULES_LINE else None)
                 if line.name == OPS_LINE:
                     _set_own_time(evs)
                     ops[d] = evs

@@ -27,7 +27,7 @@ from harness import prom
 from harness.manifest import architecture_module
 from harness.peaks import peaks_for
 from harness.rundata import RunData
-from readers import expert_costs, moe_scopes
+from readers import expert_costs
 from readers.device_trace import _mean_decode_step_bytes
 from readers.moe_scopes import _device, _slice_counts
 from readers.scope_ops import scope_seconds
@@ -54,9 +54,6 @@ def read(run: RunData, args: dict, path: str = None):
     stat = args["stat"]
     if stat == "counter_ratio":      # the counters' ratio over the window
         return _counter_ratio(run, args)
-    if stat == "program_ms_per_execution":
-        return moe_scopes.read(run, args, path)
-
     device = _device(run, path)
     if device is None:
         return None

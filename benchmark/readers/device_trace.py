@@ -111,27 +111,14 @@ def read(run: RunData, args: dict):
         own = sum(o.own for o in trace.ops[d0] if _matches(args["op"], o))
         return 100.0 * own / trace.busy_s[d0] if trace.busy_s[d0] else None
 
+    if stat != "decode_kernel_roofline_pct":     # HBM-bound
+        raise ValueError(f"device_trace reader: unknown stat {stat!r}")
     mods, kernel_ops = _modules_with(trace, d0, args["with_op"])
-    if not mods:
-        return None
-    if stat == "program_ms_per_execution":
-        return 1e3 * sum(m.dur for m in mods) / len(mods), len(mods)
-    if stat == "program_ms_per_1000_prompt_tokens":
-        tokens = sum(r["prompt_tokens"] for r in _slice_requests(run))
-        return (1e6 * sum(m.dur for m in mods) / tokens, len(mods)) if tokens else None
-
-    peaks = peaks_for(run.device_kind)
     kernel_s = sum(o.own for o in kernel_ops)
     if not kernel_s:
         return None
     cost = architecture_module(run.cell.config, run.cell.config_name,
                                "attention_cost")
-    if stat == "decode_kernel_roofline_pct":     # HBM-bound
-        per_step = _mean_decode_step_bytes(run, cost)
-        least_s = len(mods) * per_step / peaks["hbm_bytes_per_s"]
-        return 100.0 * least_s / kernel_s, len(mods)
-    if stat == "prefill_kernel_roofline_pct":    # FLOP-bound
-        flops = cost.prefill_flops(run.hf, _tp(run), _computed_chunks(run))
-        least_s = flops / peaks["flops_bf16"]
-        return 100.0 * least_s / kernel_s, len(mods)
-    raise ValueError(f"device_trace reader: unknown stat {stat!r}")
+    per_step = _mean_decode_step_bytes(run, cost)
+    least_s = len(mods) * per_step / peaks_for(run.device_kind)["hbm_bytes_per_s"]
+    return 100.0 * least_s / kernel_s, len(mods)

@@ -22,8 +22,8 @@ by ``num_local_experts`` (``readers/granite_costs.py``), and the joint
 share of two scopes in the decode program. A scope's milliseconds a
 step and the counters' ratios are the same quantities as in any other
 cell and are read by ``readers/ssm_scopes.py`` and
-``readers/moe_scopes.py`` with the arguments their own metrics give
-(``layer_metrics/granite_*.json`` name them).
+``readers/moe_scopes.py`` under the one entry every cell reads them by
+(PR 58; this trunk's cell is on those entries' lists).
 
 What the state and the held experts must move and multiply is in
 ``readers/granite_costs.py``, counted from the configuration's keys;

@@ -14,10 +14,12 @@ commit from before them) gives every reader here nothing to read.
 from __future__ import annotations
 
 import bisect
-from typing import List, Tuple
+import re
+from typing import List, Optional, Tuple
 
 from harness.rundata import RunData
 from harness.stats import gaps_of
+from harness.trace import COMPLETED, ENQUEUED
 
 Interval = Tuple[float, float]
 
@@ -85,20 +87,98 @@ def idle_covered_pct(trace, prefixes) -> float:
     return 100.0 * _overlap(gaps, cover) / idle
 
 
-def sync_tails(trace, span: str) -> List[float]:
-    """For each ``span`` event inside which a device operation ended:
-    seconds from the end of the last such operation (any device) to the
-    span's end. The device's result is ready at the first and the
-    scheduler runs again at the second: the copy to the host and the
-    hop back from the executor thread."""
-    ends = sorted(o.start + o.dur for d in trace.devices for o in trace.ops[d])
+def plane_shift(trace) -> Tuple[float, Optional[float]]:
+    """(late, room), in seconds: how late the capture's host plane runs
+    against its device planes, and how well that is known.
+
+    The two planes' clocks are set apart by the profiler, and not alike
+    in every capture: the first capture a machine takes shows the host
+    plane ~1 ms later than its next ones do (PR 36 saw tails 0.7-0.9 ms
+    high in a machine's first capture; PR 58's twenty captures read the
+    same tail in two groups, 1.5-1.8 and 2.4-2.8 ms, the higher in the
+    first traced run of each chip call). Two of the runtime's host
+    events name their execution (``harness/trace.py``: RUN_EVENTS), so
+    each side has a bound that needs no guess at what belongs to what:
+
+    - an execution cannot begin before the host has begun to enqueue
+      it: ``late`` = the most by which one seems to (0 where none does,
+      and where the capture has no such events). Every time read from
+      a device event to a host event is too long by at least that;
+    - the host cannot learn that an execution ended before it did:
+      ``room`` = the least of (``CompleteCallbacks`` start - execution
+      end) once ``late`` is taken off, None without such events. The
+      planes' true offset lies within ``room`` of ``late``; under zero,
+      no one shift puts both sides in order and the planes disagree
+      within the capture."""
+    host = {(h.name, h.run): h.start for h in trace.host if h.run}
+    early, learnt = [], []
+    for d in trace.devices:
+        for m in trace.modules[d]:
+            if (ENQUEUED, m.run) in host:
+                early.append(m.start - host[ENQUEUED, m.run])
+            if (COMPLETED, m.run) in host:
+                learnt.append(host[COMPLETED, m.run] - (m.start + m.dur))
+    late = max(0.0, -min(early)) if early else 0.0
+    return late, (min(learnt) - late if learnt else None)
+
+
+def step_ends(trace, program: str) -> List[float]:
+    """For every execution in the capture (any device) of a program
+    whose name matches ``program``, the end of the last operation inside
+    it, sorted: when that step's result exists on the device, on the
+    host plane's clock (later by ``plane_shift``'s ``late``), so that it
+    can be held against a span. An execution without an operation of
+    its own ends where its ``XLA Modules`` event does."""
+    late, _ = plane_shift(trace)
+    out = []
+    for d in trace.devices:
+        ops, i = trace.ops[d], 0
+        for m in trace.modules[d]:           # both lists are sorted by start
+            if not re.search(program, m.name):
+                continue
+            end = m.start + m.dur
+            while i < len(ops) and ops[i].start < m.start:
+                i += 1
+            last = None
+            while i < len(ops) and ops[i].start < end:
+                last = max(last or 0.0, ops[i].start + ops[i].dur)
+                i += 1
+            out.append((end if last is None else last) + late)
+    return sorted(out)
+
+
+def step_end_inside(ends: List[float], start: float, end: float):
+    """The last of ``ends`` (``step_ends``) inside [start, end], or None."""
+    i = bisect.bisect_right(ends, end)
+    return ends[i - 1] if i and ends[i - 1] >= start else None
+
+
+def sync_tails(trace, span: str, program: str) -> List[float]:
+    """For each ``span`` event inside which an execution of ``program``
+    ended (``step_ends``): seconds from the end of the last such
+    execution's last operation (any device, on the host plane's clock)
+    to the span's end. The device's result is ready at the first and
+    the scheduler runs again at the second: the copy to the host and
+    the hop back from the executor thread.
+
+    It is the execution that counts, not the operation: since PR 57 the
+    next decode step is on the device while the host waits for this
+    one, so an operation ends inside the wait every few microseconds,
+    and all of them but the waited step's last belong to the step in
+    flight, which ends after the wait does. A metric's file names the
+    program the span waits for (``^jit_decode_`` for
+    ``sched.decode.sync``): the small programs the host sends once a
+    wait is over (a cast, a row set) end microseconds after they start,
+    and on the late host plane they seem to end inside the wait that
+    came before them."""
+    ends = step_ends(trace, program)
     out = []
     for h in trace.host:
         if h.name != span:
             continue
-        i = bisect.bisect_right(ends, h.start + h.dur)
-        if i and ends[i - 1] >= h.start:
-            out.append(h.start + h.dur - ends[i - 1])
+        device_end = step_end_inside(ends, h.start, h.start + h.dur)
+        if device_end is not None:
+            out.append(h.start + h.dur - device_end)
     return out
 
 
@@ -111,7 +191,7 @@ def read(run: RunData, args: dict):
         durs = [h.dur for h in trace.host if h.name == args["span"]]
         return (1e3 * sum(durs) / len(durs), len(durs)) if durs else None
     if stat == "sync_tail_mean_ms":
-        tails = sync_tails(trace, args["span"])
+        tails = sync_tails(trace, args["span"], args["program"])
         return (1e3 * sum(tails) / len(tails), len(tails)) if tails else None
     if stat == "idle_covered_pct":
         if not _spans(trace, ("sched.",)):

@@ -25,8 +25,9 @@ whose costs count KDA layers and expert layers from the configuration's
 lists (``readers/kimi_costs.py``), and the joint share of two scopes in
 the decode program. The other cells' quantities (a fine scope of the
 experts, the counters' ratios, the latent kernel's share through the
-configuration's attention-cost module) are read by
-``readers/moe_scopes.py`` with the arguments their metric files give.
+configuration's attention-cost module, the latent sublayer's time) are
+read by ``readers/moe_scopes.py`` and ``readers/scope_ops.py`` under
+the one entry every cell reads them by (PR 58).
 
 Which sequences were running is taken from the client's records as the
 attention rooflines take it (``readers/device_trace.py``).

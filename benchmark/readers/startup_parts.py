@@ -39,8 +39,6 @@ def read(run: RunData, args: dict):
     before = imported - (run.window[0] - run.setup_seconds)
     after = run.window[0] - listening
     stat = args["stat"]
-    if stat == "before_program_s":
-        return before
     if stat == "probes_ramp_s":
         return after
     if stat == "unnamed_s":

@@ -1,27 +1,36 @@
 """The sync tail in its three parts (ISSUE 35).
 
 The sync tail of ``readers/host_spans.py`` (``sync_tail_mean_ms``) is
-one lump: end of the device's last operation to end of
+one lump: end of the waited step's last operation on the device
+(``host_spans.step_ends``: the last execution of the program the span
+waits for that ended inside it; since PR 57 the next step is on the
+device meanwhile, and its operations are not this step's) to end of
 ``sched.decode.sync``. Since PR 35 the program writes where the lump
 divides, inside every wait for a device result (``Scheduler._fetch``):
 under ``sync.fetch``, on the executor thread, ``sync.ready`` ends when
 the tokens are on the host and ``sync.copy`` covers the arrays fetched
 after them; what is left of the ``sched.*.sync`` span after
-``sync.fetch`` has ended is the hop back to the scheduler's loop. So, over the passes the lump is read from (a
-device operation ended inside the span):
+``sync.fetch`` has ended is the hop back to the scheduler's loop. So,
+over the passes the lump is read from (the waited step ended inside
+the span):
 
-- ready = ``sync.ready`` end - end of the last device operation
+- ready = ``sync.ready`` end - end of the waited step's last operation
 - copy  = duration of ``sync.copy`` (0 where only the tokens were fetched)
 - hop   = ``sched.*.sync`` end - ``sync.fetch`` end
 
 and ready + copy + hop is the lump, but for the microsecond between the
 two inner spans. Only *ready* crosses from the device plane's clock to
-the host plane's; its minimum over the passes is the check on the two:
-a result cannot reach the host before the device made it, so a negative
-minimum says the planes disagree by at least that much, and ready and
-the lump with it are off by it (copy and hop, differences on one plane,
-are not). ``counter_tail_ms`` is copy + hop again from the program's
-counters, by the host's clock alone, over a stretch of the capture.
+the host plane's, which runs late by an amount that differs from
+capture to capture: ``host_spans.plane_shift`` bounds it from both
+sides by the runtime's own events, the step's end is taken later by
+its ``late``, and the clock's slack is what is then left of the
+tighter side: the least ready part, or the ``room`` between an
+execution's end and the host's learning of it. The planes' offset is
+known to within that much, and ready and the lump are good to it; under
+zero they disagree within the capture and both are void (copy and hop,
+differences on one plane, stand). ``counter_tail_ms`` is copy + hop
+again from the program's counters, by the host's clock alone, over a
+stretch of the capture.
 
 ``DeviceTrace.host`` holds a name, a start and a duration an event, so a
 fetch is found in its pass by time: the loop waits for one fetch at a
@@ -36,15 +45,14 @@ from typing import List, NamedTuple
 
 from harness import prom
 from harness.rundata import RunData
-from readers.host_spans import _merged, _overlap
+from readers.host_spans import plane_shift, step_end_inside, step_ends
 
-FRONTEND = ("http.", "pre.", "detok.")
 FETCH_SECONDS = "dynamo_scheduler_fetch_seconds_total"
 FETCHES = "dynamo_scheduler_fetches_total"
 
 
 class Pass(NamedTuple):
-    device_end: float   # end of the last device operation inside the span
+    device_end: float   # end of the waited step on the device (step_ends)
     ready_end: float    # sync.ready: the tokens are on the host
     copy_s: float       # sync.copy: the arrays after them
     fetch_end: float    # sync.fetch: the executor thread is done
@@ -70,11 +78,12 @@ def _inside(events, starts, lo: float, hi: float) -> list:
     return out
 
 
-def passes(trace, span: str) -> List[Pass]:
-    """One entry for each ``span`` event inside which a device operation
-    ended (any device: the passes ``host_spans.sync_tails`` reads) and
-    which holds a ``sync.fetch`` written in its parts."""
-    ends = sorted(o.start + o.dur for d in trace.devices for o in trace.ops[d])
+def passes(trace, span: str, program: str) -> List[Pass]:
+    """One entry for each ``span`` event inside which an execution of
+    ``program`` ended (any device: the passes ``host_spans.sync_tails``
+    reads, from the waited step's last operation and not the step in
+    flight's) and which holds a ``sync.fetch`` written in its parts."""
+    ends = step_ends(trace, program)
     by_name = {}
     for name in ("sync.fetch", "sync.ready", "sync.copy"):
         evs = sorted((h for h in trace.host if h.name == name),
@@ -85,8 +94,8 @@ def passes(trace, span: str) -> List[Pass]:
         if h.name != span:
             continue
         end = h.start + h.dur
-        i = bisect.bisect_right(ends, end)
-        if not i or ends[i - 1] < h.start:
+        device_end = step_end_inside(ends, h.start, end)
+        if device_end is None:
             continue
         fetches = _inside(*by_name["sync.fetch"], h.start, end)
         if len(fetches) != 1:
@@ -96,23 +105,9 @@ def passes(trace, span: str) -> List[Pass]:
         if len(ready) != 1:
             continue
         copies = _inside(*by_name["sync.copy"], f.start, f.start + f.dur)
-        out.append(Pass(ends[i - 1], ready[0].start + ready[0].dur,
+        out.append(Pass(device_end, ready[0].start + ready[0].dur,
                         sum(c.dur for c in copies), f.start + f.dur, end))
     return out
-
-
-def hop_frontend_pct(trace, found: List[Pass]) -> float:
-    """Share of the hops' time that lies inside the union of the
-    frontend's leaf spans, all written on the scheduler's loop: the hop
-    that waits for a frontend task to give the loop back."""
-    hops = _merged([(p.fetch_end, p.span_end) for p in found
-                    if p.span_end > p.fetch_end])
-    total = sum(e - s for s, e in hops)
-    if not total:
-        return 0.0
-    busy = _merged([(h.start, h.start + h.dur) for h in trace.host
-                    if h.name.startswith(FRONTEND)])
-    return 100.0 * _overlap(hops, busy) / total
 
 
 def _counter_stretch(run: RunData):
@@ -151,7 +146,7 @@ def read(run: RunData, args: dict):
     trace = run.device_trace
     if trace is None:
         return None
-    found = passes(trace, args["span"])
+    found = passes(trace, args["span"], args["program"])
     if not found:
         return None
     n = len(found)
@@ -161,8 +156,8 @@ def read(run: RunData, args: dict):
         return 1e3 * sum(p.copy_s for p in found) / n, n
     if stat == "hop_mean_ms":
         return 1e3 * sum(p.hop_s for p in found) / n, n
-    if stat == "hop_frontend_pct":
-        return hop_frontend_pct(trace, found), n
     if stat == "clock_slack_min_ms":
-        return 1e3 * min(p.ready_s for p in found), n
+        room = plane_shift(trace)[1]
+        least = min(p.ready_s for p in found)
+        return 1e3 * (least if room is None else min(least, room)), n
     raise ValueError(f"sync_parts reader: unknown stat {stat!r}")

@@ -15,10 +15,10 @@ Everything about a cell, a configuration, a traffic mix or a metric is
 read from the files ``BENCHMARK.json`` names (``harness/manifest.py``).
 
 ``--trace 0`` turns nothing extra on and reports the end-to-end metrics.
-``--trace 1`` is a run of its own with the same traffic: request stamps
-to ``DYN_TRACE_JSONL``, ``X-Request-Id`` on every request, ``/metrics``
-sampled each second and one profiler capture from the middle of the
-window; it reports the per-layer metrics and ``breakdown``.
+``--trace 1`` is a run of its own with the same traffic: ``X-Request-Id``
+on every request, ``/metrics`` sampled each second and one profiler
+capture from the middle of the window; it reports the per-layer metrics
+and ``breakdown``.
 
 Without a TPU the command exits non-zero and prints no result line.
 ``--cpu-rehearsal`` (for ``benchmark/tests`` only) runs the whole path at
@@ -79,21 +79,6 @@ def _capture(trace_dir: str, at: float, seconds: float) -> tuple:
     return t0, t1
 
 
-def _read_jsonl(path: str) -> dict:
-    out = {}
-    try:
-        with open(path) as f:
-            for line in f:
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue
-                out[rec.get("request_id")] = rec
-    except FileNotFoundError:
-        pass
-    return out
-
-
 def _usage_ok(r: dict) -> bool:
     u = r.get("usage") or {}
     return (u.get("prompt_tokens") == r["prompt_tokens"]
@@ -116,9 +101,6 @@ async def amain(args) -> int:
     work = os.path.join(WORK, cell.name)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
-    trace_jsonl = os.path.join(work, "request_traces.jsonl")
-    if args.trace:
-        os.environ["DYN_TRACE_JSONL"] = trace_jsonl
 
     from harness import server
 
@@ -180,8 +162,7 @@ async def amain(args) -> int:
     run = RunData.from_client(
         got, cell=cell, hf=hf, serve=vars(flags), seconds=args.seconds,
         setup_seconds=t0 - T_START, trace_slice=trace_slice, device_kind=kind,
-        cache_itemsize=cache_itemsize,
-        request_traces=_read_jsonl(trace_jsonl) if args.trace else {})
+        cache_itemsize=cache_itemsize)
     device = {"platform": platform, "kind": kind, "count": cell.chips,
               "memory_peak_bytes": peak}
     breakdown = None

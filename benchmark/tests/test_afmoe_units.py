@@ -32,6 +32,10 @@ METRICS = {
     "kv_window_usage_max": ("prom_sample", "block allocator"),
     "kv_full_usage_max": ("prom_sample", "block allocator"),
 }
+# the two pools, the release, the two kinds' sublayers and their joint
+# share are read in every cell with pages of two kinds; the share of a
+# roofline is this family's cost module's
+SHARED = set(METRICS) - {"window_full_decode_roofline"}
 PAGE_ROW = 4 * 128 * 2      # a key or a value of every kv head, bfloat16
 
 
@@ -79,7 +83,10 @@ def test_the_cell_lists_the_seven_metrics_and_only_there():
         assert got[name].reader == reader and got[name].moves == "itl_p50_ms"
     for m in load_manifest()["per_layer"]:
         if m["name"] in METRICS:
-            assert m["workloads"] == ["trinity-longdoc"]
+            # its own readings here alone; a reading another family's
+            # cell makes too lists that cell as well (PR 58)
+            assert m["workloads"] == ["trinity-longdoc"] or (
+                m["name"] in SHARED and "trinity-longdoc" in m["workloads"])
             assert m["layer"] == METRICS[m["name"]][1]
     # the configuration as the catalog has it, but for the three cuts
     assert TRINITY["reduced"] == ["num_hidden_layers", "layer_types",

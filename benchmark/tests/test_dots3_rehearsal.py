@@ -40,7 +40,7 @@ def test_dots3_rehearsal(trace):
         assert not got & device
         assert got == {m.name for m in want.per_layer} - device
         # 64 keys of 280-420: a fifth
-        assert 12 < res["metrics"]["dots3_kept_share"]["value"] < 30
+        assert 12 < res["metrics"]["sparse_kept_share"]["value"] < 30
         assert 0 < res["metrics"]["kv_block_usage_max"]["value"] <= 100
     else:
         assert set(res["metrics"]) == {m.name for m in want.end_to_end}

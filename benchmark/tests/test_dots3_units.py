@@ -18,26 +18,36 @@ from harness import prom, trace
 from harness.manifest import ROOT, Cell, load_cell, load_manifest
 from harness.rundata import RunData
 from harness.trace import Event
-from readers import dots3_costs, dots3_scopes, moe_scopes, prom_sample
+from readers import (dots3_costs, dots3_scopes, moe_scopes, prom_sample,
+                     sala_scopes, window_scopes)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 CUT = os.path.join(DATA, "v5e-spans.xplane.pb")
 DOTS3 = load_cell("dots3-longdoc").config
 INDEXER = "learned sparse latent attention (indexer)"
-# metric -> (its layer, its reader). Eight: BENCHMARK.json holds at most
-# 128 per-layer metrics and had 120. What the other readings of PERF.md
-# section 5 came from is in READ_BY_HAND: the same readers and stats,
-# given by hand to a capture and not listed in the manifest.
+# metric -> (its layer, its reader): the trunk's own five under its
+# prefix (``OWN``), the rest under the one name every cell reads it by.
+# PR 54 could list eight (BENCHMARK.json holds at most 128 per-layer
+# metrics and had 120) and read the others by hand; PR 58 merged the
+# twins of every cell and appended this cell to the general entries.
 METRICS = {
     "dots3_select_ms_per_step": (INDEXER, "dots3_scopes"),
-    "dots3_attn_share_of_decode_step": ("compiled programs", "dots3_scopes"),
-    "dots3_kept_share": (INDEXER, "moe_scopes"),
-    "dots3_decode_program_ms_per_step": ("compiled programs", "moe_scopes"),
+    "window_full_share_of_decode_step": ("compiled programs", "window_scopes"),
+    "sparse_kept_share": ("block-sparse attention (InfLLM-V2)", "sala_scopes"),
+    "decode_program_ms_per_step": ("compiled programs", "moe_scopes"),
     "dots3_index_roofline": (INDEXER, "dots3_scopes"),
     "dots3_picked_attn_roofline": (INDEXER, "dots3_scopes"),
     "dots3_window_decode_roofline": ("latent attention", "dots3_scopes"),
     "dots3_experts_roofline": ("routed experts", "dots3_scopes"),
+    "moe_experts_ms_per_step": ("routed experts", "moe_scopes"),
+    "moe_route_ms_per_step": ("routed experts", "moe_scopes"),
+    "moe_active_expert_share": ("routed experts", "moe_scopes"),
+    "moe_held_pick_share": ("routed experts", "moe_scopes"),
+    "window_pages_released_share": ("block allocator", "window_scopes"),
+    "kv_window_usage_max": ("block allocator", "prom_sample"),
+    "kv_full_usage_max": ("block allocator", "prom_sample"),
 }
+OWN = {n for n in METRICS if n.startswith("dots3_")}
 
 
 def _ratio(numerator, denominator, **labels):
@@ -46,14 +56,6 @@ def _ratio(numerator, denominator, **labels):
     return {**args, "labels": labels} if labels else args
 
 
-READ_BY_HAND = {
-    "window_pages_released_share": _ratio(
-        "dynamo_kv_window_pages_released_total",
-        "dynamo_kv_window_pages_allocated_total"),
-    "active_expert_share": _ratio(
-        "dynamo_moe_active_experts_total", "dynamo_moe_expert_slots_total",
-        phase="decode"),
-}
 INDEX_KEY = 128 * 2                 # an indexer's key a full layer
 FULL_KEY = (512 + 128) * 2          # latent and rope key a full layer
 WINDOW_KEY = (1024 + 128) * 2       # a window layer
@@ -106,11 +108,15 @@ def test_dots3_cell_configuration_and_metrics_as_the_manifest_has_them():
     got = {m.name: m for m in cell.per_layer}
     man = load_manifest()
     listed = {m["name"]: m for m in man["per_layer"]}
-    assert {n for n in listed if n.startswith("dots3_")} == set(METRICS)
+    assert {n for n in listed if n.startswith("dots3_")} == OWN and len(OWN) == 5
     for name, (layer, reader) in METRICS.items():
         assert got[name].reader == reader
         assert got[name].moves == "itl_p50_ms"
-        assert listed[name]["workloads"] == ["dots3-longdoc"]
+        cells = listed[name].get("workloads")
+        if name in OWN:
+            assert cells == ["dots3-longdoc"]
+        else:
+            assert cells is None or "dots3-longdoc" in cells
         assert listed[name]["layer"] == layer
     assert [w["name"] for w in man["workloads"]][-1] == "dots3-longdoc"
     assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
@@ -175,9 +181,8 @@ def test_dots3_reader_gives_nothing_without_the_scopes_or_the_counters():
     assert dots3_scopes.read(_run(), _args(
         "scope_ms_per_execution", ["swa_latent"])) is None
     kept = next(m for m in load_cell("dots3-longdoc").per_layer
-                if m.name == "dots3_kept_share")
-    for args in (kept.args, *READ_BY_HAND.values()):
-        assert moe_scopes.read(_run(), args) is None
+                if m.name == "sparse_kept_share")
+    assert sala_scopes.read(_run(), kept.args) is None
 
 
 def _device(steps):
@@ -247,10 +252,14 @@ def test_dots3_decode_metrics_from_scope_time_live_keys_and_counters(monkeypatch
     monkeypatch.setattr(moe_scopes, "load_op_events",
                         lambda path: {0: _device(steps)})
     by_file = {m.name: m for m in load_cell("dots3-longdoc").per_layer}
-    readers = {"dots3_scopes": dots3_scopes, "moe_scopes": moe_scopes}
+    readers = {"dots3_scopes": dots3_scopes, "moe_scopes": moe_scopes,
+               "window_scopes": window_scopes, "sala_scopes": sala_scopes,
+               "prom_sample": prom_sample}
 
     def read(metric):
         m = by_file[metric]
+        if m.reader == "prom_sample":
+            return prom_sample.read(run, m.args)
         return readers[m.reader].read(run, m.args, path=CUT)
 
     def scope_ms(scope):
@@ -263,11 +272,11 @@ def test_dots3_decode_metrics_from_scope_time_live_keys_and_counters(monkeypatch
     assert read("dots3_select_ms_per_step")[0] == pytest.approx(0.5)
     assert scope_ms("dsa_attend")[0] == pytest.approx(2.0)
     assert scope_ms("swa_latent")[0] == pytest.approx(0.4)
-    assert scope_ms("moe_experts")[0] == pytest.approx(5.0)
-    assert scope_ms("moe_route")[0] == pytest.approx(0.3)
-    assert read("dots3_decode_program_ms_per_step") == (pytest.approx(20.0), steps)
+    assert read("moe_experts_ms_per_step")[0] == pytest.approx(5.0)
+    assert read("moe_route_ms_per_step")[0] == pytest.approx(0.3)
+    assert read("decode_program_ms_per_step") == (pytest.approx(20.0), steps)
     # both kinds' sublayers: 1.0 + 0.8 + 0.1 + 0.2 + 0.5 + 2.0 and 1.2 + 0.4
-    assert read("dots3_attn_share_of_decode_step")[0] == \
+    assert read("window_full_share_of_decode_step")[0] == \
         pytest.approx(100 * 6.2 / 20)
     # thirty sequences of 14 000 keys (the prompt and the first token)
     pct, n = read("dots3_index_roofline")
@@ -284,15 +293,12 @@ def test_dots3_decode_metrics_from_scope_time_live_keys_and_counters(monkeypatch
                  "dots3_window_decode_roofline", "dots3_experts_roofline"):
         assert 0 < read(name)[0] < 100
     # the counters' ratios over the window, and the pools' fullest sample
-    assert read("dots3_kept_share") == pytest.approx(100 * 2048 / 14000)
-    by_hand = {name: moe_scopes.read(run, args, path=CUT)
-               for name, args in READ_BY_HAND.items()}
-    assert by_hand == {"window_pages_released_share": pytest.approx(90.0),
-                       "active_expert_share": pytest.approx(100 * 70 / 128)}
-    for kind, share in (("full", 50.0), ("window", 25.0)):
-        assert prom_sample.read(run, {
-            "metric": "dynamo_kv_pool_usage_ratio", "labels": {"kind": kind},
-            "at": "max", "scale": 100})[0] == pytest.approx(share)
+    assert read("sparse_kept_share") == pytest.approx(100 * 2048 / 14000)
+    assert read("window_pages_released_share") == pytest.approx(90.0)
+    assert read("moe_active_expert_share") == pytest.approx(100 * 70 / 128)
+    assert read("moe_held_pick_share") == pytest.approx(100 * 15 / 240)
+    assert read("kv_full_usage_max")[0] == pytest.approx(50.0)
+    assert read("kv_window_usage_max")[0] == pytest.approx(25.0)
     with pytest.raises(ValueError, match="unknown stat"):
         dots3_scopes.read(run, _args("nothing", ["dsa_index"]), path=CUT)
     # a configuration whose cost module has no such part: nothing to read
@@ -310,4 +316,4 @@ def test_dots3_no_metric_of_the_cell_reads_a_prefill_program():
         if m.name.startswith("dots3_"):
             names.add(m.name)
             assert m.args.get("program", "^jit_decode_") == "^jit_decode_", m.name
-    assert names == set(METRICS)
+    assert names == OWN

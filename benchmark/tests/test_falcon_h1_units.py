@@ -21,6 +21,11 @@ FALCON = load_cell("falcon-h1-chat").config
 METRICS = ("ssm_mixer_ms_per_step", "ssm_state_ms_per_step",
            "ssm_decode_roofline", "ssm_prefill_scan_roofline",
            "ssm_share_of_decode_step")
+# the mixer's and the state's time a step and the mixer's share of it
+# are read in every cell with a Mamba-2 mixer; the two shares of a
+# roofline count this family's layers
+SHARED = {"ssm_mixer_ms_per_step", "ssm_state_ms_per_step",
+          "ssm_share_of_decode_step"}
 
 
 def test_state_record_of_falcon_h1_34b():
@@ -51,7 +56,10 @@ def test_the_cell_lists_the_five_metrics_and_only_there():
         assert got[name].reader == "ssm_scopes" and got[name].moves == "itl_p50_ms"
     for m in load_manifest()["per_layer"]:
         if m["name"] in METRICS:
-            assert m["workloads"] == ["falcon-h1-chat"]
+            # its own readings here alone; a reading another family's
+            # cell makes too lists that cell as well (PR 58)
+            assert m["workloads"] == ["falcon-h1-chat"] or (
+                m["name"] in SHARED and "falcon-h1-chat" in m["workloads"])
             assert m["layer"] == "state-space mixer"
     # the configuration as the catalog has it, but for the two cuts
     assert FALCON["reduced"] == ["num_hidden_layers", "max_position_embeddings"]

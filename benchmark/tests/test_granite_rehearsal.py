@@ -39,7 +39,7 @@ def test_granite_rehearsal(trace):
         assert not got & device
         assert got == {m.name for m in want.per_layer} - device
         # rank 0 holds half of the experts: about half of the picks
-        assert 30 < res["metrics"]["granite_held_pick_share"]["value"] < 70
-        assert 0 < res["metrics"]["granite_active_expert_share"]["value"] <= 100
+        assert 30 < res["metrics"]["moe_held_pick_share"]["value"] < 70
+        assert 0 < res["metrics"]["moe_active_expert_share"]["value"] <= 100
     else:
         assert set(res["metrics"]) == {m.name for m in want.end_to_end}

@@ -23,22 +23,24 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 CUT = os.path.join(DATA, "v5e-spans.xplane.pb")
 GRANITE = load_cell("granite-batch").config
 # metric -> (its layer, its reader): the three shares of a roofline and
-# the joint share are this trunk's own; the rest is read as in any cell
+# the joint share are this trunk's own (``OWN``); the rest is read as in
+# any cell, under the one name every cell reads it by (PR 58 merged the
+# ``granite_`` twins into them)
 METRICS = {
-    "granite_ssm_ms_per_step": ("state-space mixer", "ssm_scopes"),
-    "granite_ssm_state_ms_per_step": ("state-space mixer", "ssm_scopes"),
+    "ssm_mixer_ms_per_step": ("state-space mixer", "ssm_scopes"),
+    "ssm_state_ms_per_step": ("state-space mixer", "ssm_scopes"),
     "granite_ssm_decode_roofline": ("state-space mixer", "granite_scopes"),
     "granite_ssm_prefill_scan_roofline": ("state-space mixer", "granite_scopes"),
-    "granite_experts_ms_per_step": ("routed experts", "moe_scopes"),
+    "moe_experts_ms_per_step": ("routed experts", "moe_scopes"),
     "granite_experts_roofline": ("routed experts", "granite_scopes"),
-    "granite_route_ms_per_step": ("routed experts", "moe_scopes"),
-    "granite_held_pick_share": ("routed experts", "moe_scopes"),
-    "granite_active_expert_share": ("routed experts", "moe_scopes"),
+    "moe_route_ms_per_step": ("routed experts", "moe_scopes"),
+    "moe_held_pick_share": ("routed experts", "moe_scopes"),
+    "moe_active_expert_share": ("routed experts", "moe_scopes"),
     "granite_state_experts_share_of_decode_step": ("compiled programs",
                                                    "granite_scopes"),
-    "granite_decode_program_ms_per_step": ("compiled programs", "moe_scopes"),
-    "granite_output_tokens_per_s": ("client (whole served path)", "client"),
-    "granite_ttft_from_send_p50_ms": ("client (whole served path)", "client"),
+    "decode_program_ms_per_step": ("compiled programs", "moe_scopes"),
+    "output_tokens_per_s": ("client (whole served path)", "client"),
+    "ttft_from_send_p50_ms": ("client (whole served path)", "client"),
 }
 RECORD = 128 * 64 * 128 * 4 + 3 * 8448 * 2      # a mixer layer a sequence
 EXPERT = 3 * 4096 * 768 * 2                     # one expert's three matrices
@@ -90,8 +92,13 @@ def test_the_cell_the_configuration_and_the_metrics_as_the_manifest_has_them():
     for name, (layer, reader) in METRICS.items():
         assert got[name].reader == reader
         assert got[name].moves == "itl_p50_ms"
-        assert listed[name]["workloads"] == ["granite-batch"]
+        cells = listed[name].get("workloads")
+        if name.startswith("granite_"):
+            assert cells == ["granite-batch"]
+        else:
+            assert cells is None or "granite-batch" in cells
         assert listed[name]["layer"] == layer
+    assert sum(n.startswith("granite_") for n in got) == 4
     # the configuration as the catalog has it, but for the four cuts
     assert GRANITE["reduced"] == ["num_hidden_layers", "layer_types",
                                   "num_local_experts", "max_position_embeddings"]
@@ -207,11 +214,11 @@ def test_granite_decode_metrics_from_scope_time_live_sequences_and_counters(monk
         return readers[m.reader].read(run, m.args, path=CUT)
 
     # projection 1.0 + conv 0.2 + the unnamed copy 0.1 + state 8.0
-    assert ms_of("granite_ssm_ms_per_step") == (pytest.approx(9.3), steps)
-    assert ms_of("granite_ssm_state_ms_per_step")[0] == pytest.approx(8.0)
-    assert ms_of("granite_experts_ms_per_step")[0] == pytest.approx(10.0)
-    assert ms_of("granite_route_ms_per_step")[0] == pytest.approx(0.7)
-    assert ms_of("granite_decode_program_ms_per_step") == (pytest.approx(30.0), steps)
+    assert ms_of("ssm_mixer_ms_per_step") == (pytest.approx(9.3), steps)
+    assert ms_of("ssm_state_ms_per_step")[0] == pytest.approx(8.0)
+    assert ms_of("moe_experts_ms_per_step")[0] == pytest.approx(10.0)
+    assert ms_of("moe_route_ms_per_step")[0] == pytest.approx(0.7)
+    assert ms_of("decode_program_ms_per_step") == (pytest.approx(30.0), steps)
     pct, _ = granite_scopes.read(run, _args(
         "scope_share_of_program_pct", ["ssm_state", "moe_experts"]), path=CUT)
     assert pct == pytest.approx(100 * 18.0 / 30)
@@ -227,9 +234,9 @@ def test_granite_decode_metrics_from_scope_time_live_sequences_and_counters(monk
     assert n == steps and pct == pytest.approx(100 * least / 0.010)
     assert 0 < pct < 100
     # the counters' ratios over the window
-    assert by_file["granite_held_pick_share"].args == RATIO
-    assert ms_of("granite_held_pick_share") == pytest.approx(50.0)
-    assert ms_of("granite_active_expert_share") == pytest.approx(100.0)
+    assert by_file["moe_held_pick_share"].args == RATIO
+    assert ms_of("moe_held_pick_share") == pytest.approx(50.0)
+    assert ms_of("moe_active_expert_share") == pytest.approx(100.0)
     with pytest.raises(ValueError, match="unknown stat"):
         granite_scopes.read(run, _args("nothing", ["ssm"]), path=CUT)
 

@@ -17,13 +17,15 @@ from readers import host_spans
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 ALL_SPANS = ["sched.", "sync.", "dispatch.", "http.", "pre.", "detok."]
+SYNC, DECODE = "sched.decode.sync", "^jit_decode_"
 
 
 EDGES = [("sched.admit", 0.0, 0.0), ("sched.admit", 10.0, 10.0)]
 
 
 def _trace(ops, host, window=(0.0, 10.0), devices=(0,), edges=True):
-    """A capture of ``window`` with the same operations on every device;
+    """A capture of ``window`` with the same operations on every device,
+    each the one operation of an execution of the decode program;
     ``edges`` puts an empty ``sched.admit`` at both ends, so that the
     program's spans are on record over the whole window."""
     if edges and any(n.startswith("sched.") for n, _, _ in host):
@@ -34,7 +36,9 @@ def _trace(ops, host, window=(0.0, 10.0), devices=(0,), edges=True):
         window=window, devices=list(devices),
         busy_s={d: busy for d in devices},
         span={d: (ops[0][0], ops[-1][1]) for d in devices},
-        modules={d: [] for d in devices}, ops={d: list(evs) for d in devices},
+        modules={d: [Event("jit_decode_step(1)", s, e - s) for s, e in ops]
+                 for d in devices},
+        ops={d: list(evs) for d in devices},
         host=[Event(n, s, e - s) for n, s, e in host])
 
 
@@ -101,7 +105,8 @@ def test_no_work_share_is_zero_when_the_loop_never_waited():
 @pytest.mark.parametrize("args", [
     {"stat": "idle_covered_pct", "spans": ALL_SPANS},
     {"stat": "span_mean_ms", "span": "sched.decode.build"},
-    {"stat": "sync_tail_mean_ms", "span": "sched.decode.sync"},
+    {"stat": "sync_tail_mean_ms", "span": "sched.decode.sync",
+     "program": "^jit_decode_"},
 ])
 def test_a_program_without_spans_gives_nothing_to_read(args):
     """The parent commit of PR 23 writes none: the line leaves the
@@ -121,26 +126,109 @@ def test_span_mean_is_over_the_events_of_that_name_only():
     assert n == 2 and ms == pytest.approx(3.0)
 
 
-def test_sync_tail_runs_from_the_last_operation_that_ended_inside():
+def test_sync_tail_runs_from_the_last_execution_that_ended_inside():
     host = [
-        ("sched.decode.sync", 0.5, 1.25),    # op ends at 1.0: tail 0.25
-        ("sched.decode.sync", 2.5, 3.5),     # op ends at 3.0: tail 0.5
+        ("sched.decode.sync", 0.5, 1.25),    # step ends at 1.0: tail 0.25
+        ("sched.decode.sync", 2.5, 3.5),     # step ends at 3.0: tail 0.5
         ("sched.decode.sync", 3.6, 4.0),     # nothing ended inside: left out
         ("sched.decode.sync", 4.5, 9.5),     # 6.0 is the last inside (10.0 is after)
         ("sched.prefill.sync", 5.5, 6.75),   # another span's
     ]
     t = _trace(OPS, host)
-    assert host_spans.sync_tails(t, "sched.decode.sync") == pytest.approx(
+    assert host_spans.sync_tails(t, SYNC, DECODE) == pytest.approx(
         [0.25, 0.5, 3.5])
     ms, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
-                                      "span": "sched.decode.sync"})
+                                      "span": SYNC, "program": DECODE})
     assert n == 3 and ms == pytest.approx(1e3 * (0.25 + 0.5 + 3.5) / 3)
 
 
 def test_sync_tail_waits_for_the_last_chip():
     t = _trace(OPS, [("sched.decode.sync", 2.5, 3.5)], devices=(0, 1))
     t.ops[1] = [Event("fusion.1", 2.0, 1.25, own=1.25)]   # chip 1 ends at 3.25
-    assert host_spans.sync_tails(t, "sched.decode.sync") == pytest.approx([0.25])
+    t.modules[1] = [Event("jit_decode_step(1)", 2.0, 1.25)]
+    assert host_spans.sync_tails(t, SYNC, DECODE) == pytest.approx([0.25])
+
+
+def _step_in_flight():
+    """Since PR 57: step k is on the device while the host waits for
+    step k-1. Steps of 1 s back to back from 0.0, ten operations each;
+    the wait for step 1 (ends 1.0) runs 0.4-1.3 and the wait for step 2
+    (ends 2.0) 1.6-2.25, and inside each the step in flight has already
+    ended operations of its own."""
+    steps = [(float(k), k + 1.0) for k in range(4)]
+    t = _trace(steps, [("sched.decode.sync", 0.4, 1.3),
+                       ("sched.decode.sync", 1.6, 2.25)])
+    t.ops[0] = [Event("fusion.%d" % j, k + j / 10, 0.1, own=0.1)
+                for k in range(4) for j in range(10)]
+    return t
+
+
+def test_sync_tail_with_a_step_in_flight_is_the_waited_steps():
+    """The last operation that ended inside the first wait is the step
+    in flight's third (ends 1.3: a tail of 0.0, what PR 57's ledger
+    lines read); the last *execution* that ended inside it is the step
+    waited for (1.0: 0.3)."""
+    t = _step_in_flight()
+    assert host_spans.sync_tails(t, SYNC, DECODE) == pytest.approx([0.3, 0.25])
+    assert host_spans.step_ends(t, DECODE) == pytest.approx([1.0, 2.0, 3.0, 4.0])
+
+
+def test_sync_tail_counts_only_the_program_the_span_waits_for():
+    """A cast the host sent once the first wait was over, on a host
+    plane that runs late: it seems to end inside the wait, and its name
+    says that it is not the step. The metric's file names the program;
+    a reader is not asked without one."""
+    t = _step_in_flight()
+    t.modules[0] = sorted(t.modules[0] + [Event("jit_convert_element_type(7)",
+                                                1.25, 0.001)],
+                          key=lambda m: m.start)
+    assert host_spans.sync_tails(t, SYNC, "^jit_")[0] == pytest.approx(
+        0.05, abs=2e-3)
+    assert host_spans.sync_tails(t, SYNC, DECODE) == pytest.approx([0.3, 0.25])
+    ms, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
+                                      "span": SYNC, "program": DECODE})
+    assert n == 2 and ms == pytest.approx(1e3 * 0.275)
+    with pytest.raises(KeyError, match="program"):
+        host_spans.read(_run(t), {"stat": "sync_tail_mean_ms", "span": SYNC})
+
+
+def _with_runs(t, enqueued, completed):
+    """``t`` with its executions numbered and the runtime's two events an
+    execution: its enqueue ``enqueued`` s before it starts and the
+    host's learning of its end ``completed`` s after."""
+    host = list(t.host)
+    for d in t.devices:
+        for i, m in enumerate(t.modules[d]):
+            m.run = (d, i)
+            host.append(Event(trace.ENQUEUED, m.start - enqueued, 1e-4, run=m.run))
+            host.append(Event(trace.COMPLETED, m.start + m.dur + completed,
+                              1e-4, run=m.run))
+    return dataclasses.replace(t, host=host)
+
+
+def test_a_late_host_plane_is_seen_by_the_runtimes_own_events():
+    """An execution cannot begin before its own enqueue has: where one
+    seems to, the host plane is late by at least that much, the step's
+    end is taken that much later, and the tail is what it is on a host
+    plane that runs on time, whichever way it was recorded."""
+    on_time = _with_runs(_step_in_flight(), enqueued=0.01, completed=0.02)
+    assert host_spans.plane_shift(on_time) == pytest.approx((0.0, 0.02))
+    assert host_spans.sync_tails(on_time, SYNC, DECODE) == pytest.approx(
+        [0.3, 0.25])
+    late = dataclasses.replace(on_time, host=[
+        dataclasses.replace(h, start=h.start + 0.1) for h in on_time.host])
+    # 0.1 late, of which the enqueue's own 0.01 cannot be told from the
+    # time an enqueue takes: seen late by 0.09, known to within 0.03
+    assert host_spans.plane_shift(late) == pytest.approx((0.09, 0.03))
+    assert host_spans.sync_tails(late, SYNC, DECODE) == pytest.approx(
+        [0.31, 0.26])
+    # an early host plane cannot be told from a quick one on this side;
+    # the other side says it: the host learnt of an end before the end
+    early = dataclasses.replace(on_time, host=[
+        dataclasses.replace(h, start=h.start - 0.1) for h in on_time.host])
+    assert host_spans.plane_shift(early) == pytest.approx((0.0, -0.08))
+    # a capture without the runtime's events (the cuts of PR 23 and 35)
+    assert host_spans.plane_shift(_step_in_flight()) == (0.0, None)
 
 
 def test_unknown_stat_is_an_error():
@@ -170,7 +258,7 @@ def test_recorded_span_means(recorded_spans, span):
 def test_recorded_sync_tail(recorded_spans):
     t, want = recorded_spans
     ms, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
-                                      "span": "sched.decode.sync"})
+                                      "span": SYNC, "program": DECODE})
     assert [ms, n] == pytest.approx(want["sync_tail_mean_ms"], rel=1e-6)
     # ready -> running again is a few milliseconds, not the whole fetch
     assert 1.0 < ms < 10.0

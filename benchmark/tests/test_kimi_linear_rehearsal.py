@@ -40,7 +40,7 @@ def test_kimi_linear_rehearsal(trace):
         assert not got & device
         assert got == {m.name for m in want.per_layer} - device
         # rank 0 holds a quarter of the experts: about a quarter of the picks
-        assert 10 < res["metrics"]["kimi_held_pick_share"]["value"] < 45
-        assert 0 < res["metrics"]["kimi_active_expert_share"]["value"] <= 100
+        assert 10 < res["metrics"]["moe_held_pick_share"]["value"] < 45
+        assert 0 < res["metrics"]["moe_active_expert_share"]["value"] <= 100
     else:
         assert set(res["metrics"]) == {m.name for m in want.end_to_end}

@@ -170,15 +170,11 @@ def test_a_cell_a_mix_a_kind_a_configuration_and_a_metric_are_added_as_files_onl
                              "better": "lower", "source": "program_counter",
                              "layer": "scheduler", "moves": "throwaway_ttft_max_ms",
                              "workloads": list(cells)})
-    # the metrics on the shelf (a file each, no manifest entry: nothing
-    # judged today is moved by them) come back by entries alone
-    shelf = ({f[:-5] for f in os.listdir(os.path.join(b, "layer_metrics"))}
-             - {m["name"] for m in man["per_layer"]})
-    assert shelf
-    man["per_layer"] += [{"name": n, "unit": "ms", "better": "lower",
-                          "source": "host_clock", "layer": "shelf",
-                          "moves": "throwaway_ttft_max_ms",
-                          "workloads": ["throwaway-cell"]} for n in sorted(shelf)]
+    # every file of layer_metrics/ has its entry since PR 58: the shelf
+    # of files without one went, with the code they alone used
+    assert ({f[:-5] for f in os.listdir(os.path.join(b, "layer_metrics"))}
+            - {"throwaway_steps"}
+            == {m["name"] for m in man["per_layer"]} - {"throwaway_steps"})
     json.dump(man, open(os.path.join(root, "BENCHMARK.json"), "w"))
     assert _files_only(root) == []
 
@@ -200,10 +196,6 @@ def test_a_cell_a_mix_a_kind_a_configuration_and_a_metric_are_added_as_files_onl
     res = run("throwaway-cell", 1)
     # with whatever the manifest reports in every cell
     assert res["metrics"]["throwaway_steps"]["value"] > 0
-    # ... and the shelf's, but for those only a device trace can give
-    from_trace = {n for n in shelf if json.load(open(os.path.join(
-        b, "layer_metrics", n + ".json")))["reader"] == "device_trace"}
-    assert shelf - from_trace <= set(res["metrics"]) and not from_trace & set(res["metrics"])
     res = run("throwaway-kind-cell", 0)
     assert res["attempted"] == 5 and res["failed"] == 0 and res["correct"] is True
     # 1.5 req/s in threes: 2 sessions in 4 s; a late session's last turns fall due after it
@@ -334,15 +326,15 @@ def test_an_architecture_is_added_as_files_only(tmp_path):
                                  "chips": 1, "why": "test"})
     # a metric classified by a kernel lists the cells that run the kernel
     # (the trunk's list the trunk's cells), so the new family brings its own
-    for name, stat in (("throwaway_latent_decode_roofline", "decode_kernel_roofline_pct"),
-                       ("throwaway_latent_decode_ms", "program_ms_per_execution")):
-        put(f"layer_metrics/{name}.json", {
-            "reader": "device_trace",
-            "args": {"stat": stat, "with_op": "paged_decode_attention"}})
-        man["per_layer"].append({
-            "name": name, "unit": "%", "better": "higher", "source": "device_trace",
-            "layer": "Pallas kernels", "moves": "itl_p50_ms",
-            "workloads": ["throwaway-mla-moe-cell"]})
+    name = "throwaway_latent_decode_roofline"
+    put(f"layer_metrics/{name}.json", {
+        "reader": "device_trace",
+        "args": {"stat": "decode_kernel_roofline_pct",
+                 "with_op": "paged_decode_attention"}})
+    man["per_layer"].append({
+        "name": name, "unit": "%", "better": "higher", "source": "device_trace",
+        "layer": "Pallas kernels", "moves": "itl_p50_ms",
+        "workloads": ["throwaway-mla-moe-cell"]})
     json.dump(man, open(os.path.join(root, "BENCHMARK.json"), "w"))
     assert _files_only(root) == []
 
@@ -381,8 +373,6 @@ def test_an_architecture_is_added_as_files_only(tmp_path):
     assert got == {
         "throwaway_latent_decode_roofline": pytest.approx(
             100 * least_s / sum(o.own for o in kernel), rel=1e-9),
-        "throwaway_latent_decode_ms": pytest.approx(
-            1e3 * sum(m.dur for m in executions) / len(executions)),
         "device_idle_share": pytest.approx(got["device_idle_share"])}
 
 

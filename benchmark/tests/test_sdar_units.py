@@ -20,15 +20,20 @@ from references import sdar as reference
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 CUT = os.path.join(DATA, "v5e-spans.xplane.pb")
 SDAR = load_cell("sdar-reasoning").config
+# the block family's own seven (``block_scopes``), and the program's time
+# and the experts' under the names every cell reads them by (PR 58:
+# ``readers/moe_scopes.py`` reads what ``block_scopes`` read under the
+# cell's own two names)
+GENERAL = {"decode_program_ms_per_step", "moe_experts_ms_per_step"}
 METRICS = {
     "block_passes_per_block": "scheduler",
     "block_tokens_per_row_pass": "scheduler",
     "block_commit_pass_share": "scheduler",
-    "block_pass_program_ms": "compiled programs",
+    "decode_program_ms_per_step": "compiled programs",
     "block_attn_ms_per_step": "Pallas kernels",
     "block_attn_roofline": "Pallas kernels",
     "block_select_ms_per_step": "compiled programs",
-    "sdar_experts_ms_per_step": "routed experts",
+    "moe_experts_ms_per_step": "routed experts",
     "sdar_experts_roofline": "routed experts",
 }
 PASSES = "dynamo_scheduler_block_row_passes_total"
@@ -43,11 +48,16 @@ def test_the_cell_lists_the_nine_metrics_and_only_there():
     assert cell.cell["limits"] == load_cell("trinity-longdoc").cell["limits"]
     got = {m.name: m for m in cell.per_layer}
     for name in METRICS:
-        assert got[name].reader == "block_scopes"
+        assert got[name].reader == ("moe_scopes" if name in GENERAL
+                                    else "block_scopes")
         assert got[name].moves == "itl_p50_ms"
     for m in load_manifest()["per_layer"]:
         if m["name"] in METRICS:
-            assert m["workloads"] == ["sdar-reasoning"]
+            cells = m.get("workloads")
+            if m["name"] in GENERAL:
+                assert cells is None or "sdar-reasoning" in cells
+            else:
+                assert cells == ["sdar-reasoning"]
             assert m["layer"] == METRICS[m["name"]]
     # every metric that lists no cells is read here too
     everywhere = {m["name"] for m in load_manifest()["per_layer"]
@@ -121,7 +131,6 @@ def test_a_program_without_block_scopes_gives_nothing_and_does_not_raise():
             ("scope_ms_per_execution", ["block_select"], "^jit_decode_"),
             ("block_attn_roofline_pct", ["block_attn"], "^jit_decode_"),
             ("experts_decode_roofline_pct", ["moe_experts"], "^jit_decode_"),
-            ("program_ms_per_execution", [], "^jit_nothing"),
             ("scope_ms_per_execution", ["block_attn"], "^jit_nothing")):
         assert block_scopes.read(
             run, _args(stat, scopes, program, phase="decode"), path=CUT) is None
@@ -182,7 +191,9 @@ def test_block_metrics_from_scope_time_live_sequences_and_counters(monkeypatch):
                                         50 * 7 * 1024))])
     monkeypatch.setattr(moe_scopes, "load_op_events",
                         lambda path: {0: _device(steps)})
-    ms, n = block_scopes.read(run, _args("program_ms_per_execution"), path=CUT)
+    by_file = {m.name: m for m in load_cell("sdar-reasoning").per_layer}
+    program = by_file["decode_program_ms_per_step"]
+    ms, n = moe_scopes.read(run, program.args, path=CUT)
     assert n == steps and ms == pytest.approx(20.0)
     ms, n = block_scopes.read(run, _args("scope_ms_per_execution",
                                          ["block_attn"]), path=CUT)
@@ -194,6 +205,10 @@ def test_block_metrics_from_scope_time_live_sequences_and_counters(monkeypatch):
     ms, _ = block_scopes.read(run, _args("scope_ms_per_execution",
                                          ["moe_experts"]), path=CUT)
     assert ms == pytest.approx(11.0)
+    # and under the name every cell reads it by, through the general reader
+    experts = by_file["moe_experts_ms_per_step"]
+    assert moe_scopes.read(run, experts.args, path=CUT) == (
+        pytest.approx(11.0), steps)
     # 1204 tokens of context (the prompt and one block) a sequence: K and V
     # of every key once a pass, whatever the block's four queries
     pct, n = block_scopes.read(run, _args("block_attn_roofline_pct",

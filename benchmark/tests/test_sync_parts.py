@@ -3,7 +3,10 @@ arithmetic on hand-made traces, then two cuts of traced v5e runs of
 PR 35 that hold the parts (``data/v5e-sync-parts*.xplane.pb``; how each
 was cut is in its ``.expected.json``): one whose planes agree, read as
 it is and with its host plane shifted 2 ms early, and one whose planes
-disagree as the profiler recorded them."""
+disagree as the profiler recorded them; a cut of PR 58 with a step in
+flight; and two cuts of PR 58 that hold the runtime's own events, by
+which a late host plane is seen: one cell's first and a later capture
+of a machine."""
 
 import dataclasses
 import json
@@ -19,22 +22,25 @@ from readers import host_spans, sync_parts
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 SPAN = "sched.decode.sync"
-STATS = ("ready_mean_ms", "copy_mean_ms", "hop_mean_ms", "hop_frontend_pct",
-         "clock_slack_min_ms")
+PROGRAM = "^jit_decode_"
+STATS = ("ready_mean_ms", "copy_mean_ms", "hop_mean_ms", "clock_slack_min_ms")
 METRICS = {"step_sync_ready_ms": "ready_mean_ms",
            "step_sync_copy_ms": "copy_mean_ms",
            "step_sync_hop_ms": "hop_mean_ms",
-           "sync_hop_busy_share": "hop_frontend_pct",
            "trace_clock_slack_ms": "clock_slack_min_ms"}
 
 
 def _trace(ops, host, window=(0.0, 10.0), devices=(0,)):
+    """Every (start, end) of ``ops`` is one execution of the decode
+    program with one operation inside it."""
     evs = [Event("fusion.1", s, e - s, own=e - s) for s, e in ops]
+    mods = [Event("jit_decode_step(1)", s, e - s) for s, e in ops]
     return DeviceTrace(
         window=window, devices=list(devices),
         busy_s={d: sum(e - s for s, e in ops) for d in devices},
         span={d: (ops[0][0], ops[-1][1]) for d in devices},
-        modules={d: [] for d in devices}, ops={d: list(evs) for d in devices},
+        modules={d: list(mods) for d in devices},
+        ops={d: list(evs) for d in devices},
         host=[Event(n, s, e - s) for n, s, e in host])
 
 
@@ -46,7 +52,8 @@ def _run(t, start=None, end=None):
 
 
 def _read(t, stat):
-    return sync_parts.read(_run(t), {"stat": stat, "span": SPAN})
+    return sync_parts.read(_run(t), {"stat": stat, "span": SPAN,
+                                     "program": PROGRAM})
 
 
 # three steps end at 1.0, 3.0 and 6.0 s; times in seconds, so a part of
@@ -81,7 +88,6 @@ HOST = (
     ("copy_mean_ms", 1e3 * (0.2 + 0.0 + 0.1) / 3, 3),
     ("hop_mean_ms", 1e3 * (0.3 + 0.1 + 0.6) / 3, 3),
     ("clock_slack_min_ms", 1e3 * 0.1, 3),
-    ("hop_frontend_pct", 0.0, 3),
 ])
 def test_parts_over_the_passes_the_lump_is_read_from(stat, want, n):
     got, samples = _read(_trace(OPS, HOST), stat)
@@ -91,7 +97,7 @@ def test_parts_over_the_passes_the_lump_is_read_from(stat, want, n):
 def test_the_three_parts_are_the_lump():
     t = _trace(OPS, HOST)
     tail, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
-                                        "span": SPAN})
+                                        "span": SPAN, "program": PROGRAM})
     parts = sum(_read(t, s)[0] for s in STATS[:3])
     assert n == 3 and parts == pytest.approx(tail)
 
@@ -99,21 +105,9 @@ def test_the_three_parts_are_the_lump():
 def test_ready_runs_from_the_last_chip():
     t = _trace(OPS, HOST, devices=(0, 1))
     t.ops[1] = [Event("fusion.1", 0.0, 1.05, own=1.05)]   # chip 1 ends at 1.05
+    t.modules[1] = [Event("jit_decode_step(1)", 0.0, 1.05)]
     got, _ = _read(t, "ready_mean_ms")
     assert got == pytest.approx(1e3 * (0.05 + 0.3 + 0.2) / 3)
-
-
-def test_hop_share_inside_the_frontends_leaves():
-    """Hops: 1.3-1.6, 3.3-3.4, 6.3-6.9 (1.0 s). The union of the
-    frontend's leaves covers 0.1 + 0.1 + 0.25 of it; the runtime's and
-    the scheduler's own events count nothing."""
-    host = HOST + [
-        ("http.sse_write", 1.2, 1.4), ("detok.step", 1.35, 1.4),   # 1.3-1.4
-        ("pre.tokenize", 3.0, 3.5),                                  # 3.3-3.4
-        ("http.ingress", 6.5, 6.7), ("http.sse_write", 6.65, 6.75),  # 6.5-6.75
-        ("PjitFunction(decode_step)", 6.3, 6.9), ("sched.admit", 6.3, 6.9)]
-    got, n = _read(_trace(OPS, host), "hop_frontend_pct")
-    assert n == 3 and got == pytest.approx(100 * 0.45 / 1.0)
 
 
 def test_a_host_plane_that_runs_early_reads_a_negative_slack():
@@ -127,10 +121,40 @@ def test_a_host_plane_that_runs_early_reads_a_negative_slack():
     # copy and hop are differences on one plane: the shift leaves them
     assert _read(t, "copy_mean_ms")[0] == pytest.approx(1e3 * 0.1)
     assert _read(t, "hop_mean_ms")[0] == pytest.approx(1e3 * 1.0 / 3)
-    # a host plane that runs late only adds to the slack: it cannot be seen
+    # a host plane that runs late only adds to the ready parts: without
+    # the runtime's events that name an execution it cannot be seen
     late = [(n, s + 0.15, e + 0.15) for n, s, e in HOST]
     assert _read(_trace(OPS, late), "clock_slack_min_ms")[0] == pytest.approx(
         1e3 * (0.1 + 0.15))
+
+
+def test_a_host_plane_that_runs_late_is_taken_off_the_ready_part():
+    """With the runtime's events (each execution enqueued 0.01 s before
+    it starts, its end learnt 0.02 s after): the host plane 0.15 s late
+    is seen late by 0.14, every ready part is read 0.14 shorter, and
+    the slack is the tighter side's room: 0.02 + 0.01, the time an
+    enqueue and a callback take, within which the offset is not known.
+    Copy and hop stand. On time, nothing is taken off."""
+    def runs(t):
+        for i, m in enumerate(t.modules[0]):
+            m.run = (0, i)
+            t.host.append(Event(trace.ENQUEUED, m.start - 0.01, 1e-4, run=m.run))
+            t.host.append(Event(trace.COMPLETED, m.start + m.dur + 0.02, 1e-4,
+                                run=m.run))
+        return t
+
+    on_time = runs(_trace(OPS, HOST))
+    assert _read(on_time, "ready_mean_ms")[0] == pytest.approx(1e3 * 0.2)
+    assert _read(on_time, "clock_slack_min_ms")[0] == pytest.approx(1e3 * 0.02)
+    late = dataclasses.replace(on_time, host=[
+        dataclasses.replace(h, start=h.start + 0.15) for h in on_time.host])
+    assert _read(late, "ready_mean_ms") == (pytest.approx(1e3 * 0.21), 3)
+    assert _read(late, "clock_slack_min_ms")[0] == pytest.approx(1e3 * 0.03)
+    assert _read(late, "copy_mean_ms")[0] == pytest.approx(1e3 * 0.1)
+    assert _read(late, "hop_mean_ms")[0] == pytest.approx(1e3 * 1.0 / 3)
+    tail, n = host_spans.read(_run(late), {"stat": "sync_tail_mean_ms",
+                                           "span": SPAN, "program": PROGRAM})
+    assert n == 3 and tail == pytest.approx(1e3 * (0.6 + 0.4 + 0.9 + 0.03) / 3)
 
 
 @pytest.mark.parametrize("stat", STATS)
@@ -140,7 +164,8 @@ def test_a_program_without_the_parts_gives_nothing_to_read(stat):
     whole = [e for e in HOST if e[0] not in ("sync.ready", "sync.copy")]
     assert _read(_trace(OPS, whole), stat) is None
     run = _run(None)                              # an untraced run
-    assert sync_parts.read(run, {"stat": stat, "span": SPAN}) is None
+    assert sync_parts.read(run, {"stat": stat, "span": SPAN,
+                                 "program": PROGRAM}) is None
 
 
 PROM = """\
@@ -195,17 +220,19 @@ def test_an_unknown_stat_of_the_parts_is_an_error():
 
 @pytest.mark.parametrize("cell_name", [w["name"] for w in
                                        manifest.load_manifest()["workloads"]])
-def test_every_cell_lists_the_six_metrics_of_the_scheduler(cell_name):
+def test_every_cell_lists_the_five_metrics_of_the_scheduler(cell_name):
     """No ``workloads`` list: every cell that reports the gap reports
-    them, from the manifest's entries and the metric's file alone."""
+    them, from the manifest's entries and the metric's file alone, and
+    each names the program the wait is for (the step in flight since
+    PR 57 is another execution of it, and ends after the wait)."""
     cell = manifest.load_cell(cell_name)
     by_name = {m.name: m for m in cell.per_layer}
+    assert "sync_hop_busy_share" not in by_name       # 0.0 in every cell: PR 58
     for name, stat in METRICS.items():
         m = by_name[name]
-        assert (m.reader, m.args["stat"], m.args["span"]) == (
-            "sync_parts", stat, SPAN)
-        assert (m.unit, m.moves) == ("%" if "share" in name else "ms",
-                                     "itl_p50_ms")
+        assert (m.reader, m.args["stat"], m.args["span"], m.args["program"]) == (
+            "sync_parts", stat, SPAN, PROGRAM)
+        assert (m.unit, m.moves) == ("ms", "itl_p50_ms")
         assert m.better == ("higher" if name == "trace_clock_slack_ms"
                             else "lower")
     m = by_name["fetch_tail_ms_per_pass"]
@@ -215,7 +242,9 @@ def test_every_cell_lists_the_six_metrics_of_the_scheduler(cell_name):
     t = _trace(OPS, HOST)
     run = dataclasses.replace(_run(t), cell=cell)
     got = {name: read_metric(by_name[name], run)[0] for name in METRICS}
-    tail = host_spans.read(run, {"stat": "sync_tail_mean_ms", "span": SPAN})[0]
+    tail = read_metric(by_name["step_sync_tail_ms"], run)[0]
+    assert by_name["step_sync_tail_ms"].args == {
+        "stat": "sync_tail_mean_ms", "span": SPAN, "program": PROGRAM}
     assert (got["step_sync_ready_ms"] + got["step_sync_copy_ms"]
             + got["step_sync_hop_ms"]) == pytest.approx(tail)
 
@@ -251,7 +280,8 @@ def test_recorded_parts(recorded_parts, kind, stat, key):
     assert (len(t.ops[0]), len(t.modules[0]), len(t.host)) == (
         want["n_ops"], want["n_modules"], want["n_host"])
     got, n = sync_parts.read(_run(t), {"stat": stat,
-                                       "span": "sched.%s.sync" % kind})
+                                       "span": "sched.%s.sync" % kind,
+                                       "program": "^jit_%s_" % kind})
     assert n == len(want[kind]) > 0
     assert got == pytest.approx(_mean(want[kind], key), abs=1e-5)
 
@@ -262,20 +292,16 @@ def test_recorded_parts_are_the_recorded_lump(recorded_parts, kind):
     same passes: apart by the two seams between the inner spans, some
     tens of microseconds while a capture runs."""
     t, want = recorded_parts
-    span = "sched.%s.sync" % kind
-    tail, n = host_spans.read(_run(t), {"stat": "sync_tail_mean_ms",
-                                        "span": span})
+    args = {"span": "sched.%s.sync" % kind, "program": "^jit_%s_" % kind}
+    tail, n = host_spans.read(_run(t), dict(args, stat="sync_tail_mean_ms"))
     assert n == len(want[kind])
     assert tail == pytest.approx(_mean(want[kind], "tail_ms"), abs=1e-5)
-    parts = sum(sync_parts.read(_run(t), {"stat": s, "span": span})[0]
-                for s in STATS[:3])
-    assert 0.0 <= tail - parts < 0.05
+    by = {s: sync_parts.read(_run(t), dict(args, stat=s))[0] for s in STATS}
     # each part is what the issue expected to find, or is not: the hop is
     # the smallest, the copies cost as much as the transfer they follow
-    by = {s: sync_parts.read(_run(t), {"stat": s, "span": span})[0]
-          for s in STATS}
+    assert 0.0 <= tail - sum(by[s] for s in STATS[:3]) < 0.05
     assert 1.0 < by["ready_mean_ms"] < 1.6 and 1.0 < by["copy_mean_ms"] < 2.0
-    assert 0.1 < by["hop_mean_ms"] < 0.3 and by["hop_frontend_pct"] == 0.0
+    assert 0.1 < by["hop_mean_ms"] < 0.3
     assert by["clock_slack_min_ms"] == pytest.approx(
         min(r["ready_ms"] for r in want[kind]), abs=1e-5)
     assert by["clock_slack_min_ms"] > 1.0
@@ -289,7 +315,7 @@ def test_recorded_capture_with_its_host_plane_2_ms_early(recorded_parts):
     t, want = recorded_parts
     early = dataclasses.replace(t, host=[
         dataclasses.replace(h, start=h.start - 2e-3) for h in t.host])
-    args = {"span": SPAN}
+    args = {"span": SPAN, "program": PROGRAM}
     slack, n = sync_parts.read(_run(early), dict(args, stat="clock_slack_min_ms"))
     assert n == len(want["decode"])
     assert slack == pytest.approx(
@@ -302,29 +328,164 @@ def test_recorded_capture_with_its_host_plane_2_ms_early(recorded_parts):
         assert got == pytest.approx(_mean(want["decode"], key), abs=1e-5)
 
 
-def test_recorded_capture_whose_planes_disagree(recorded_parts_late_host,
-                                                recorded_parts):
+def test_recorded_capture_whose_host_plane_runs_late(recorded_parts_late_host,
+                                                     recorded_parts):
     """The first traced run of the same chip call, as the profiler wrote
-    it: its host plane runs about 1.4 ms late, the next step's first
-    operations seem to end inside the wait that came before their
-    dispatch, and the check reads negative. The lump reads 0.01-0.07 ms
-    in those passes and 4.4 ms in the others (2.6-3.0 ms in the capture
-    whose planes agree); copy and hop read what they read there."""
+    it: its host plane runs about 1.4 ms late. Read from the last
+    *operation* inside a wait (until PR 58), the next step's first
+    operations seemed to end inside the wait that came before their
+    dispatch, and three of five passes read a ready part of -1.4 ms.
+    Read from the waited program's last *execution*, no pass does: each
+    ready part is 2.6-2.8 ms where the capture whose planes agree reads
+    1.2-1.4 (the cut holds none of the runtime's events by which a late
+    host plane is seen, so its 1.4 ms stay in), copy and hop read what
+    they read there, and the first wait drops out (its step began
+    before the cut)."""
     t, want = recorded_parts_late_host
     agree, want_agree = recorded_parts
     assert (len(t.ops[0]), len(t.modules[0]), len(t.host)) == (
         want["n_ops"], want["n_modules"], want["n_host"])
-    args = {"span": SPAN}
+    rows = want["decode_by_execution"]
+    args = {"span": SPAN, "program": PROGRAM}
     slack, n = sync_parts.read(_run(t), dict(args, stat="clock_slack_min_ms"))
-    assert n == len(want["decode"]) == 5
-    assert slack == pytest.approx(min(r["ready_ms"] for r in want["decode"]),
-                                  abs=1e-5)
-    assert slack < -1.0
-    tails = sorted(r["tail_ms"] for r in want["decode"])
-    assert tails[2] < 0.1 and tails[3] > 4.0
-    for stat, key, lo, hi in (("copy_mean_ms", "copy_ms", 1.0, 2.0),
+    assert n == len(rows) == 4
+    assert slack == pytest.approx(min(r["ready_ms"] for r in rows), abs=1e-5)
+    assert 2.5 < slack < 2.8
+    tail, n = host_spans.read(_run(t), dict(args, stat="sync_tail_mean_ms"))
+    assert n == 4 and tail == pytest.approx(_mean(rows, "tail_ms"), abs=1e-5)
+    for stat, key, lo, hi in (("ready_mean_ms", "ready_ms", 2.6, 2.8),
+                              ("copy_mean_ms", "copy_ms", 1.0, 2.0),
                               ("hop_mean_ms", "hop_ms", 0.1, 0.3)):
         got, _ = sync_parts.read(_run(t), dict(args, stat=stat))
-        assert got == pytest.approx(_mean(want["decode"], key), abs=1e-5)
+        assert got == pytest.approx(_mean(rows, key), abs=1e-5)
         assert lo < got < hi
-        assert abs(got - _mean(want_agree["decode"], key)) < 0.25
+        if key != "ready_ms":
+            assert abs(got - _mean(want_agree["decode"], key)) < 0.25
+    assert host_spans.plane_shift(t) == (0.0, None)
+
+
+# ---- a step in flight: six waits of phi3-chat on the v5e, PR 57's program ----
+
+@pytest.fixture(scope="module")
+def recorded_in_flight():
+    return _recorded("v5e-step-in-flight")
+
+
+@pytest.mark.parametrize("stat, key", [("ready_mean_ms", "ready_ms"),
+                                       ("copy_mean_ms", "copy_ms"),
+                                       ("hop_mean_ms", "hop_ms")])
+def test_recorded_parts_with_a_step_in_flight(recorded_in_flight, stat, key):
+    """Step k is on the device while the host waits for step k-1 (every
+    dispatch of the cut carries ``ahead`` = 1): the parts are read from
+    the end of the step waited for, as they were before PR 57."""
+    t, want = recorded_in_flight
+    assert (len(t.ops[0]), len(t.modules[0]), len(t.host)) == (
+        want["n_ops"], want["n_modules"], want["n_host"])
+    got, n = sync_parts.read(_run(t), {"stat": stat, "span": SPAN,
+                                       "program": PROGRAM})
+    assert n == len(want["decode"]) == 6
+    assert got == pytest.approx(_mean(want["decode"], key), abs=1e-5)
+
+
+def test_recorded_lump_with_a_step_in_flight(recorded_in_flight):
+    """The tail is 2.3-2.7 ms a wait, its ready part 2.0-2.3, and the
+    slack is positive: the capture's planes agree. By the last operation
+    that ended inside the wait, the reading until PR 58 and the ledger's
+    at PR 57, the same six waits gave a tail of 0.01-0.06 ms and a ready
+    part under zero: an operation of the step in flight, microseconds
+    before the wait's end."""
+    t, want = recorded_in_flight
+    args = {"span": SPAN, "program": PROGRAM}
+    tail, n = host_spans.read(_run(t), dict(args, stat="sync_tail_mean_ms"))
+    assert n == 6 and tail == pytest.approx(_mean(want["decode"], "tail_ms"),
+                                            abs=1e-5)
+    assert 2.3 < tail < 2.5
+    by = {s: sync_parts.read(_run(t), dict(args, stat=s))[0] for s in STATS}
+    assert 0.0 <= tail - sum(by[s] for s in STATS[:3]) < 0.05
+    assert by["clock_slack_min_ms"] == pytest.approx(
+        min(r["ready_ms"] for r in want["decode"]), abs=1e-5)
+    assert 1.9 < by["clock_slack_min_ms"] < by["ready_mean_ms"] < 2.3
+    was = want["decode_by_last_operation"]
+    assert len(was) == 6 and max(r["ready_ms"] for r in was) < 0
+    assert max(r["tail_ms"] for r in was) < 0.06
+    # the waits are for the executions in their order: the last one of the
+    # cut is the step in flight during the sixth wait, and no wait is its
+    ends = host_spans.step_ends(t, PROGRAM)
+    assert len(ends) == 7
+    syncs = sorted((h for h in t.host if h.name == SPAN), key=lambda h: h.start)
+    assert [host_spans.step_end_inside(ends, h.start, h.start + h.dur)
+            for h in syncs] == ends[:6]
+
+
+# ---- the runtime's own events: one cell's first and a later capture of
+# a machine, sala-longdoc on the v5e, PR 57's program ----
+
+CAPTURES = ("v5e-planes-first-capture", "v5e-planes-second-capture")
+
+
+@pytest.fixture(scope="module", params=CAPTURES)
+def recorded_planes(request):
+    return _recorded(request.param)
+
+
+def test_recorded_planes_are_apart_by_what_the_runtimes_events_say(
+        recorded_planes):
+    """One of the six executions began on an idle device, and before the
+    runtime's event that enqueued it had begun: the host plane is late
+    by at least that, 1.29 ms in the machine's first capture and 0.38 in
+    a later one, and what is left between an execution's end and the
+    host's learning of it is the room the offset is known within."""
+    t, want = recorded_planes
+    assert (len(t.ops[0]), len(t.modules[0]), len(t.host)) == (
+        want["n_ops"], want["n_modules"], want["n_host"])
+    assert all(m.run is not None for m in t.modules[0])
+    by_name = {name: sum(h.name == name and h.run is not None for h in t.host)
+               for name in trace.RUN_EVENTS}
+    assert by_name == {trace.ENQUEUED: 6, trace.COMPLETED: 6}
+    late, room = host_spans.plane_shift(t)
+    assert 1e3 * late == pytest.approx(want["late_ms"], abs=1e-5)
+    assert 1e3 * room == pytest.approx(want["room_ms"], abs=1e-5)
+    assert sum(x < 0 for x in want["starts_before_its_enqueue_ms"]) == 1
+    assert late > 0 and 0.4 < 1e3 * room < 0.6
+
+
+@pytest.mark.parametrize("stat, key", [("ready_mean_ms", "ready_ms"),
+                                       ("copy_mean_ms", "copy_ms"),
+                                       ("hop_mean_ms", "hop_ms")])
+def test_recorded_parts_on_a_late_host_plane(recorded_planes, stat, key):
+    t, want = recorded_planes
+    got, n = sync_parts.read(_run(t), {"stat": stat, "span": SPAN,
+                                       "program": PROGRAM})
+    assert n == len(want["decode"]) == 5
+    assert got == pytest.approx(_mean(want["decode"], key), abs=1e-5)
+
+
+def test_recorded_lump_on_a_late_host_plane(recorded_planes):
+    t, want = recorded_planes
+    args = {"span": SPAN, "program": PROGRAM}
+    tail, n = host_spans.read(_run(t), dict(args, stat="sync_tail_mean_ms"))
+    assert n == 5 and tail == pytest.approx(_mean(want["decode"], "tail_ms"),
+                                            abs=1e-5)
+    by = {s: sync_parts.read(_run(t), dict(args, stat=s))[0] for s in STATS}
+    assert 0.0 <= tail - sum(by[s] for s in STATS[:3]) < 0.05
+    # the slack is the tighter side: here the room, under the least ready
+    assert by["clock_slack_min_ms"] == pytest.approx(want["room_ms"], abs=1e-5)
+    assert by["clock_slack_min_ms"] < min(r["ready_ms"] for r in want["decode"])
+
+
+def test_a_machines_first_capture_reads_what_its_later_ones_read():
+    """As recorded the same cell's waits read in two groups, 2.4-2.5 ms
+    in the machine's first capture and 1.6-1.8 in a later one (the first
+    wait of each cut aside: the pass after a prompt's last chunk), the
+    ready part 2.2-2.3 against 1.3-1.5. With each capture's own
+    lateness taken off both read 1.1-1.4 and 0.9-1.1."""
+    first, second = (_recorded(name)[1] for name in CAPTURES)
+    for key, lo_1, hi_1, lo_2, hi_2, lo, hi in (
+            ("tail_ms", 2.4, 2.55, 1.55, 1.8, 1.1, 1.4),
+            ("ready_ms", 2.15, 2.3, 1.3, 1.55, 0.85, 1.15)):
+        for rows, a, b in ((first["decode_as_recorded"][1:], lo_1, hi_1),
+                           (second["decode_as_recorded"][1:], lo_2, hi_2),
+                           (first["decode"][1:], lo, hi),
+                           (second["decode"][1:], lo, hi)):
+            assert all(a < r[key] < b for r in rows), (key, a, b, rows)
+    assert first["late_ms"] - second["late_ms"] == pytest.approx(0.915, abs=1e-3)

@@ -1,6 +1,7 @@
 """The yardstick's arithmetic: statistics, traffic, ops/bytes, peaks,
 the manifest's shape. No jax, no server."""
 
+import functools
 import json
 import os
 import re
@@ -192,13 +193,13 @@ def test_manifest_names_files_and_keeps_code_free_of_names():
         assert NAME.match(n), n
     for m in man["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.1
-    # one file per metric, cell and configuration. A metric's file with no
-    # manifest entry is on the shelf: a time to first token and what moves
-    # it, read by nothing until a cell's runs repeat it closely enough to
-    # judge it (test_rehearsal runs them from entries alone)
+    # one file per metric, cell and configuration. Every per-layer file
+    # has its entry (PR 58 emptied the shelf of files without one); an
+    # end-to-end file without an entry is a time to first token, judged
+    # by nothing until a cell's runs repeat it closely enough
     b = manifest.BENCH_DIR
     listed = lambda d: {f[:-5] for f in os.listdir(os.path.join(b, d))}  # noqa: E731
-    assert listed("layer_metrics") >= {m["name"] for m in man["per_layer"]}
+    assert listed("layer_metrics") == {m["name"] for m in man["per_layer"]}
     assert listed("end_to_end") >= e2e and listed("cells") == cells
     names |= listed("layer_metrics") | listed("end_to_end")
     assert listed("configs") == {c["name"] for c in man["configs"]}
@@ -265,3 +266,177 @@ def test_a_per_layer_metric_listed_where_its_target_is_not_reported_is_refused(t
     json.dump(man, open(tmp_path / "BENCHMARK.json", "w"))
     with pytest.raises(manifest.ManifestError, match="does not report"):
         manifest.load_cell(first, root=str(tmp_path))
+
+
+# ---- PR 58: one entry a reading, and room for the next architecture ----
+
+PER_LAYER_LIMIT = 128       # the most entries a PR may hand in
+OWN_ENTRIES_A_PR = 12       # what a model_config or tracing PR may add
+PARENT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                      "per-layer-pr57.json")
+# retired by PR 58: under 1 % of set-up (0.0 of the hops) in every cell
+# on the ledger's PR 57 lines
+RETIRED = {"setup_device_init_s", "setup_runner_s", "setup_kv_cache_s",
+           "setup_model_card_s", "warmup_compile_s", "setup_before_program_s",
+           "warmup_wait_s", "sync_hop_busy_share"}
+# a merged entry whose reader or args are not the surviving one's: what
+# it read then -> what reads it now. Both read the same number from the
+# same capture (test_scope_ops.py, test_sync_parts.py, test_host_spans.py).
+DECODE = {"program": "^jit_decode_"}
+KEPT = {"stat": "counter_ratio_pct",
+        "numerator": "dynamo_sparse_attention_kept_tokens_total",
+        "denominator": "dynamo_sparse_attention_context_tokens_total"}
+BY_NAME = ("moe_scopes", dict(DECODE, stat="program_ms_per_execution"))
+MERGED_READERS = [
+    (("device_trace", {"stat": "program_ms_per_execution",
+                       "with_op": "paged_decode_attention"}), BY_NAME),
+    (("block_scopes", dict(DECODE, stat="program_ms_per_execution")), BY_NAME),
+    (("block_scopes", dict(DECODE, stat="scope_ms_per_execution",
+                           scopes=["moe_experts"])),
+     ("moe_scopes", dict(DECODE, stat="scope_ms_per_execution",
+                         scope="moe_experts"))),
+    (("kimi_scopes", dict(DECODE, stat="scope_ms_per_execution",
+                          scopes=["attn"])),
+     ("scope_ops", dict(DECODE, stat="scope_ms_per_execution", scope="attn"))),
+    (("dots3_scopes", dict(DECODE, stat="scope_share_of_program_pct",
+                           scopes=["attn_full", "attn_window"])),
+     ("window_scopes", dict(DECODE, stat="scope_share_of_program_pct",
+                            scopes=["attn_window", "attn_full"]))),
+    (("moe_scopes", KEPT), ("sala_scopes", KEPT)),
+]
+
+
+def _reading(reader, args):
+    return reader, json.dumps(args, sort_keys=True)
+
+
+SAME_READING = {_reading(*old): _reading(*new) for old, new in MERGED_READERS}
+
+
+def _entries():
+    """The manifest's per-layer entries, each with its file's reading."""
+    out = []
+    for m in manifest.load_manifest()["per_layer"]:
+        spec = json.load(open(os.path.join(
+            manifest.BENCH_DIR, "layer_metrics", m["name"] + ".json")))
+        out.append((m, _reading(spec["reader"], spec.get("args", {}))))
+    return out
+
+
+def _cells_of(entry, cells):
+    return entry.get("workloads", cells)
+
+
+def test_the_manifest_has_room_and_reads_nothing_under_two_names(capsys):
+    """128 entries is the most a PR may hand in (PR 54's first hand-in
+    listed 138 and was refused before any run). The manifest filled up
+    because a reading scoped to one cell got a twin for the next cell; so
+    no two entries may make the same reading (reader and args) in a cell
+    both list, and the room left is printed where the next builder sees
+    it: a model_config or tracing PR adds entries only for the scopes and
+    counters that are new with it, under its own prefix, twelve at most
+    (PERF.md section 3), and the next benchmark PR appends its cell to
+    the general entries' lists."""
+    man = manifest.load_manifest()
+    cells = [w["name"] for w in man["workloads"]]
+    entries = _entries()
+    assert len(entries) <= PER_LAYER_LIMIT
+    by_reading = {}
+    for m, reading in entries:
+        assert m["source"] in ("device_trace", "program_span", "program_counter",
+                               "host_clock")
+        # a list is the manifest's cells in the manifest's order, each once
+        assert _cells_of(m, cells) == [c for c in cells if c in _cells_of(m, cells)]
+        for other in by_reading.setdefault(reading, []):
+            both = set(_cells_of(m, cells)) & set(_cells_of(other, cells))
+            assert not both, (m["name"], other["name"], sorted(both))
+        by_reading[reading].append(m)
+    room = PER_LAYER_LIMIT - len(entries)
+    with capsys.disabled():
+        print(f"\nBENCHMARK.json: {len(entries)} per-layer entries, room for "
+              f"{room} more ({room // OWN_ENTRIES_A_PR} PRs of "
+              f"{OWN_ENTRIES_A_PR})")
+
+
+@pytest.mark.parametrize("cell_name", [w["name"] for w in
+                                       manifest.load_manifest()["workloads"]])
+def test_what_a_cell_read_at_pr57_it_reads_under_a_surviving_name(cell_name):
+    """Over the parent's manifest, kept as data with each entry's reader
+    and args: every reading the cell made then it makes now, under one
+    name, but for the retired entries and for the sync tail, which names
+    the program it waits for since PR 58 (its ``program`` argument; the
+    reading with a step in flight is test_sync_parts.py's)."""
+    parent = json.load(open(PARENT))
+    assert cell_name in parent["workloads"] and len(parent["per_layer"]) == 128
+    now = {}
+    for m in manifest.load_cell(cell_name).per_layer:
+        reading = _reading(m.reader, m.args)
+        assert reading not in now, (m.name, now[reading])
+        now[reading] = m.name
+    seen = set()
+    for e in parent["per_layer"]:
+        if cell_name not in _cells_of(e, parent["workloads"]):
+            continue
+        if e["name"] in RETIRED:
+            assert e["name"] not in now.values()
+            continue
+        reading = _reading(e["reader"], e["args"])
+        if reading not in now and "span" in e["args"]:
+            reading = _reading(e["reader"], dict(e["args"], **DECODE))
+        reading = SAME_READING.get(reading, reading)
+        assert reading in now, (e["name"], reading)
+        seen.add(now[reading])
+    # and under as many names as it made readings
+    assert len(seen) == len({e["name"] for e in parent["per_layer"]
+                             if cell_name in _cells_of(e, parent["workloads"])
+                             and e["name"] not in RETIRED})
+
+
+def _listed():
+    man = manifest.load_manifest()
+    cells = [w["name"] for w in man["workloads"]]
+    return [pytest.param(m, c, id=f"{m['name']}-{c}")
+            for m in man["per_layer"] if "workloads" in m
+            for c in _cells_of(m, cells)]
+
+
+@pytest.mark.parametrize("entry, cell_name", _listed())
+def test_a_listed_entry_is_loaded_in_each_cell_of_its_list(entry, cell_name):
+    """One case an (entry, cell of its list): the cell loads and holds the
+    entry with its unit, direction and the end-to-end metric it moves.
+    (The cases of the entries PR 58 merged away are here under the
+    surviving names.)"""
+    found = [m for m in _load_cell(cell_name).per_layer if m.name == entry["name"]]
+    assert len(found) == 1
+    m = found[0]
+    assert (m.unit, m.better, m.moves) == (
+        entry["unit"], entry["better"], entry["moves"])
+
+
+READINGS = os.path.join(os.path.dirname(PARENT), "per-layer-readings.json")
+
+
+@pytest.mark.parametrize("cell_name", sorted(json.load(open(READINGS))))
+def test_every_entry_a_cell_lists_has_a_reading_from_the_chip(cell_name):
+    """A listed entry that a cell cannot read is ``null`` on the ledger
+    and blocks the next benchmark PR, so a cell goes onto a list only
+    once a traced run of it on the v5e printed a value. The file holds
+    one such value for every entry and every cell that lists it, with
+    where it came from (the plain command's result line, or
+    ``benchmark/reread.py`` over a kept run); no time reads under zero.
+    An entry a later PR brings (in no cell's readings) is that PR's to
+    show."""
+    readings = json.load(open(READINGS))
+    known = {name for cell in readings.values() for name in cell["metrics"]}
+    got = readings[cell_name]["metrics"]
+    assert readings[cell_name]["origin"]
+    listed = [m for m in _load_cell(cell_name).per_layer if m.name in known]
+    assert len(listed) >= 40
+    for m in listed:
+        assert m.name in got, m.name
+        if m.unit in ("ms", "s") and m.name != "setup_unnamed_s":
+            assert got[m.name] >= 0, (m.name, got[m.name])
+    assert abs(got["setup_unnamed_s"]) < 1e-4      # set-up's parts add up
+
+
+_load_cell = functools.lru_cache(maxsize=None)(manifest.load_cell)
