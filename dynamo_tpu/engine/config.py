@@ -205,6 +205,20 @@ class ModelConfig:
     swa_rope_theta: float = 0.0
     mla_lora_rescale: bool = False
     attention_gate: str = ""
+    # MiMo-V2 (models/mimo_v2.py, model_type "mimo_v2"): layer_types names
+    # each layer's attention, both kinds grouped-query over pages a kv
+    # head. A "sliding_attention" layer has swa_num_kv_heads kv heads
+    # (num_kv_heads is a full layer's), its own rope base
+    # (swa_rope_theta), sees the last sliding_window keys and, with
+    # swa_sink_bias, a learned sink logit a query head; keys are head_dim
+    # wide and values v_head_dim; the first int(head_dim *
+    # partial_rotary_factor) lanes of a head are rotated; the values are
+    # multiplied by attention_value_scale before the product.
+    swa_num_kv_heads: int = 0
+    partial_rotary_factor: float = 1.0
+    attention_value_scale: float = 1.0
+    swa_sink_bias: bool = False
+    full_sink_bias: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:

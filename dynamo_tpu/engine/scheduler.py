@@ -88,6 +88,20 @@ def ngram_propose(history: List[int], match: int, k: int) -> List[int]:
     return [int(t) for t in history[i + match: i + match + k]]
 
 
+def prefill_pairs(start: int, end: int, window: int = 0):
+    """(pairs under a causal mask, pairs under a window of ``window``
+    keys) of the queries at positions ``[start, end)``: the query at
+    ``p`` sees ``p + 1`` keys, or the last ``min(p + 1, window)``."""
+    def triangle(lo, hi):       # lo + (lo + 1) + ... + (hi - 1)
+        return (hi - lo) * (lo + hi - 1) // 2
+    lo, hi = start + 1, end + 1                 # keys visible
+    full = triangle(lo, hi)
+    if not window:
+        return full, 0
+    under = triangle(lo, min(hi, window)) if lo < window else 0
+    return full, under + window * max(0, hi - max(lo, window))
+
+
 def prefill_bucket_cap(cfg: EngineConfig, rows: int = 1) -> Optional[int]:
     """Largest prefill bucket such that ``rows * bucket`` fits the
     per-step token budget (the ITL bound counts padded positions, so the
@@ -978,6 +992,22 @@ class Scheduler:
             "The part of dynamo_scheduler_yield_seconds_total spent "
             "while a dispatched program's result was not yet fetched: "
             "frontend work the device's own step hides",
+        )
+
+        self._prefill_pairs_ctr = reg.counter(
+            "dynamo_attention_prefill_pairs_total",
+            "Query-key pairs the attention mask allows one layer, summed "
+            "over the rows of every prefill chunk dispatched: kind=\"full\" "
+            "a causal layer's (the query at position p sees p + 1 keys: a "
+            "triangle), kind=\"window\" a layer's that sees the last "
+            "sliding_window keys (min(p + 1, sliding_window): a band; "
+            "counted where the model has such a window). What a prefill "
+            "attention roofline divides by, times the layers of the kind",
+        )
+        self._prefill_chunks_ctr = reg.counter(
+            "dynamo_attention_prefill_chunks_total",
+            "Prefill programs dispatched, counted with "
+            "dynamo_attention_prefill_pairs_total: pairs a program",
         )
 
         self._decode_rows_ctr = reg.counter(
@@ -3485,6 +3515,13 @@ class Scheduler:
                     targets[i, : len(nxt)] = nxt
                     n_tgts[i] = max(0, min(take, len(er.prompt) - 1 - start))
 
+        window = cfg.model.sliding_window
+        for _, start, end, *_ in plan:
+            full, band = prefill_pairs(start, end, window)
+            self._prefill_pairs_ctr.inc(full, kind="full")
+            if window:
+                self._prefill_pairs_ctr.inc(band, kind="window")
+        self._prefill_chunks_ctr.inc()
         with span("sched.prefill.dispatch", step=self.passes,
                   rows=rows, tokens=rows * bucket):
             t0 = time.monotonic()

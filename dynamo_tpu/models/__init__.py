@@ -191,6 +191,12 @@ FAMILIES = (
            unserved="layer_types ({n} entries) needs a family that keeps "
                     "pages a kind of layer; model_family {family!r} has none "
                     "(models/afmoe.py is selected by model_type afmoe)"),
+    Family("mimo_v2", model_types=("mimo_v2",),
+           field="swa_num_kv_heads", reads=("layer_types",),
+           unserved="swa_num_kv_heads={value} needs a family whose window "
+                    "layers keep pages of a kv-head count of their own; "
+                    "model_family {family!r} keeps one page shape "
+                    "(models/mimo_v2.py is selected by model_type mimo_v2)"),
     Family("granite_hybrid", model_types=("granitemoehybrid",),
            field="residual_multiplier", reads=("mamba_d_ssm", "layer_types"),
            unserved="residual_multiplier={value} needs a family that scales "

@@ -385,10 +385,6 @@ class LatentKinds(KindCache):
     and donates them alike)."""
     counts: Any = None
 
-    @property
-    def dtype(self):
-        return jax.tree.leaves(self.full)[0].dtype
-
 
 CACHE_SPEC = LatentKinds(full=P(), window=P(), counts=P())
 
@@ -432,9 +428,8 @@ def _layer_norm(x, weight, bias, eps=LN_EPS):
 def _rope_front(x, positions, cfg: ModelConfig):
     """The first ``qk_rope_head_dim`` of the last axis rotated ([B, S, H,
     d]), the rest as projected."""
-    rd = cfg.qk_rope_head_dim
-    return jnp.concatenate(
-        [apply_rope(x[..., :rd], positions, cfg.rope_theta), x[..., rd:]], -1)
+    return apply_rope(x, positions, cfg.rope_theta,
+                      rotary_dim=cfg.qk_rope_head_dim)
 
 
 def index_projections(cfg: ModelConfig, lp, x, cq, positions):

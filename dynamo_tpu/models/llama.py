@@ -191,13 +191,22 @@ def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float,
     scaling: Optional[dict] = None,
     seq_basis=None,  # [B] covered context per row (longrope profile choice)
+    rotary_dim: Optional[int] = None,  # lanes rotated; None: the whole head
 ) -> jax.Array:
     """x: [B, S, H, D]; positions: [B, S]. HF-style half-rotation RoPE.
 
     The yarn attention factor rides on cos/sin (as in transformers), so
     q·k scores carry its square without touching the softmax scale.
+
+    ``rotary_dim`` r < D (``partial_rotary_factor``, models/mimo_v2.py):
+    lanes [0, r) of every head are rotated (pairs (i, i + r / 2), the
+    frequencies those of a head of r), lanes [r, D) carried as projected.
     """
     d = x.shape[-1]
+    if rotary_dim is not None and rotary_dim < d:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, theta, scaling,
+                        seq_basis), x[..., rotary_dim:]], axis=-1)
     kind = (scaling or {}).get("rope_type", (scaling or {}).get("type"))
     if kind in ("longrope", "su"):
         # [B, 1, D/2] — per-row profile; broadcasts with positions below

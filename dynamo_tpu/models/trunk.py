@@ -71,14 +71,16 @@ class SlotCache:
 class KindCache:
     """A side of the cache: the full layers' pages and the window
     layers', ``[layers of the kind, pages of its pool, block, KVH, D]``
-    each."""
+    each, the page's shape the kind's own (models/mimo_v2.py: kv heads a
+    kind, lanes a side); a kind's pages may be several such stacks
+    (models/dots3.py, mimo_v2's keys)."""
     full: Any
     window: Any
 
     @property
     def dtype(self):
         """benchmark/run.py reads ``runner.kv_cache[0].dtype``."""
-        return self.full.dtype
+        return jax.tree.leaves(self.full)[0].dtype
 
     @property
     def pages(self):

@@ -139,6 +139,44 @@ def pad_minor(x: jax.Array, d: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
+def split_lanes(x: jax.Array):
+    """``x [..., D]`` as ``ceil(D / LANE)`` parts of ``LANE`` lanes each,
+    the last zero-padded: how keys wider than a lane tile are kept where
+    a page holds fewer kv heads than a tile has rows (4 of 256 lanes,
+    models/mimo_v2.py). The decode kernels read a page as its (token,
+    head) rows, ``[page * KVH, lanes]``, which is the same bytes as
+    ``[page, KVH, lanes]`` only while a row is one lane tile wide: XLA
+    tiles ``[4, 256]`` as two ``(4, 128)`` tiles a token, the rows' view
+    as ``(8, 128)`` tiles of two tokens, and the reshape between them
+    copied the whole stack every layer of every step (2.4 GB). A stack a
+    lane tile keeps every reshape a bitcast; a score is the sum of the
+    parts' products."""
+    d = x.shape[-1]
+    x = pad_minor(x, lane_pad(d))
+    return tuple(x[..., lo:lo + LANE] for lo in range(0, lane_pad(d), LANE))
+
+
+def scatter_stacked(stacks, news, slot_mapping: jax.Array,
+                    layer_idx: jax.Array):
+    """``scatter_kv_stacked`` over any number of stacks ``[L, N, block,
+    heads, lanes]`` (each with its own heads and lanes) written at the
+    same slots: ``news[i] [B, S, heads, <= lanes]`` into ``stacks[i]``."""
+    l, n_blocks, block_size = stacks[0].shape[:3]
+    idx = slot_mapping.reshape(-1)
+    per_layer = n_blocks * block_size
+    flat_idx = jnp.where((idx < 0) | (idx >= per_layer), l * per_layer,
+                         layer_idx * per_layer + idx)
+    out = []
+    for stack, new in zip(stacks, news):
+        heads, lanes = stack.shape[-2:]
+        new = pad_minor(new, lanes).astype(stack.dtype)
+        flat = stack.reshape(l * per_layer, heads, lanes)
+        flat = flat.at[flat_idx].set(new.reshape(-1, heads, lanes),
+                                     mode="drop")
+        out.append(flat.reshape(stack.shape))
+    return tuple(out)
+
+
 def scatter_kv(
     k_cache: jax.Array,  # [N_blocks, block_size, KVH, D] (one layer)
     v_cache: jax.Array,
@@ -232,9 +270,15 @@ def paged_attention(
     ``sinks``: a learned per-head logit that joins the softmax as a
     virtual key contributing NO value — its only effect is the extra
     exp(sink) term in the denominator (GPT-OSS attention sinks).
+
+    The values may be narrower than the keys (``v_cache``'s own lanes):
+    the output is ``[B, S, H, Dv]``. ``k_cache`` may be a tuple of
+    stacks, each a part of the keys' lanes (``split_lanes``).
     """
     b, s, h, d = q.shape
-    _, block_size, kvh, _ = k_cache.shape
+    parts = k_cache if isinstance(k_cache, (tuple, list)) else None
+    _, block_size, kvh, _ = (k_cache if parts is None else parts[0]).shape
+    dv = v_cache.shape[-1]
     w = block_tables.shape[1]
     groups = h // kvh
     if scale is None:
@@ -242,8 +286,10 @@ def paged_attention(
 
     # gather: [B, W, bs, KVH, D] → [B, W*bs, KVH, D]; upcast from the
     # cache storage dtype (fp8 serving) to the compute dtype
-    k = k_cache[block_tables].reshape(b, w * block_size, kvh, d).astype(q.dtype)
-    v = v_cache[block_tables].reshape(b, w * block_size, kvh, d).astype(q.dtype)
+    k = (k_cache[block_tables] if parts is None else jnp.concatenate(
+        [part[block_tables] for part in parts], axis=-1))
+    k = k.reshape(b, w * block_size, kvh, d).astype(q.dtype)
+    v = v_cache[block_tables].reshape(b, w * block_size, kvh, dv).astype(q.dtype)
 
     # [B, S, H, D] x [B, T, KVH, D] with GQA: fold H → (KVH, G)
     qg = q.reshape(b, s, kvh, groups, d)
@@ -278,7 +324,7 @@ def paged_attention(
             logits.astype(jnp.float32), axis=-1
         ).astype(q.dtype)
     out = jnp.einsum("bskgt,btkd->bskgd", probs, v)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, dv)
 
 
 def block_causal(key_pos, q_pos, block_len: int):
@@ -322,15 +368,16 @@ def mosaic_rejects(route: str, has_sinks: bool, kv_dtype,
                    kv_heads: int) -> bool:
     """Kernel specializations Mosaic rejects on v5e (jax 0.9.0 / libtpu
     0.0.34 — the compiler's errors are in PERF.md's kernel table and
-    under ROADMAP Design 3): the sink-bias finalize of the flash and
-    verify kernels, and the fp8-cache page copies of the decode and
-    verify kernels when one device's kv heads are not a multiple of
-    fp8's sublane tiling of 4 (kvh 8 compiles and agrees with XLA, kvh 2
-    does not). ``auto`` never selects these — the XLA route serves and
-    the route counter says so; an explicit ``pallas`` compiles them and
-    raises the compiler's error."""
+    under ROADMAP Design 3): the sink-bias finalize of the verify
+    kernel (the flash kernel takes the sink as its running softmax's
+    first term and compiles), and the fp8-cache page copies of the
+    decode and verify kernels when one device's kv heads are not a
+    multiple of fp8's sublane tiling of 4 (kvh 8 compiles and agrees
+    with XLA, kvh 2 does not). ``auto`` never selects these — the XLA
+    route serves and the route counter says so; an explicit ``pallas``
+    compiles them and raises the compiler's error."""
     fp8 = jnp.dtype(kv_dtype) == jnp.float8_e4m3fn
-    return ((has_sinks and route in ("flash", "verify"))
+    return ((has_sinks and route == "verify")
             or (fp8 and kv_heads % 4 != 0 and route in ("decode", "verify")))
 
 
@@ -374,8 +421,14 @@ def attention(
     sinks=None,                     # [H] attention-sink logits (GPT-OSS)
     live_rows=None,                 # decode_live_rows of the step, or None
     block_len: int = 1,             # static; > 1: block-causal (block_causal)
+    v_dim: Optional[int] = None,    # the values' true width; None: the query's
 ) -> jax.Array:
     """Paged-attention dispatch: XLA gather path or the Pallas kernels.
+
+    Returns ``[B, S, H, v_dim]``: the values' width, which is the
+    query's unless the family says otherwise (``v_dim``: keys of 192 and
+    values of 128, models/mimo_v2.py; every route reads the values'
+    lanes off ``v_cache``, so the two sides of the cache may differ).
 
     ``block_len`` B > 1: the mask is causal over blocks of B positions and
     full inside one, on the XLA route, the verify kernel and the flash
@@ -387,8 +440,9 @@ def attention(
     computes every row.
 
     ``sinks`` (GPT-OSS): a per-head logit joining every softmax as a
-    virtual key with no value — both Pallas kernels fold it into their
-    finalize denominator; the XLA path appends a softmax column.
+    virtual key with no value — the decode and verify kernels fold it
+    into their finalize denominator, the flash kernel starts its running
+    softmax from it; the XLA path appends a softmax column.
 
     Accepts the engine's full stacked-by-layer cache plus a runtime
     ``layer_idx`` — the Pallas kernels index the layer inside HBM, so the
@@ -400,13 +454,24 @@ def attention(
     batch over "dp", KV heads over "tp" (no collectives — attention is
     head/batch parallel).
     """
+    # keys kept as several stacks of lanes (split_lanes): every route
+    # takes the tuple; ``k_cache`` below is the first part, for what is
+    # read of its shape and dtype
+    k_parts = tuple(k_cache) if isinstance(k_cache, (tuple, list)) else None
+    if k_parts is not None:
+        k_cache = k_parts[0]
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                "keys in parts of lanes are not sharded over a mesh")
     stacked = k_cache.ndim == 5
     li = jnp.asarray(0 if layer_idx is None else layer_idx, jnp.int32)
     # scale from the TRUE head dim; the cache may carry lane padding
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
-    dk = k_cache.shape[-1]
+    out_d = d if v_dim is None else v_dim
+    dk = (k_cache.shape[-1] if k_parts is None
+          else sum(part.shape[-1] for part in k_parts))
     q = pad_minor(q, dk)  # zero pad lanes score 0 against zero cache pad
     # small-S tails (the speculative verify's K+1 positions; follows the
     # flash kernel's affine base_pos contract, so small custom prefill
@@ -419,8 +484,10 @@ def attention(
     has_sinks = sinks is not None
     resolved = resolve_attention_impl(impl)
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    if impl == "auto" and mosaic_rejects(
-            route, has_sinks, k_cache.dtype, k_cache.shape[-2] // tp):
+    if impl == "auto" and (mosaic_rejects(
+            route, has_sinks, k_cache.dtype, k_cache.shape[-2] // tp)
+            # the verify kernel reads one stack of keys
+            or (k_parts is not None and route == "verify")):
         resolved = "xla"
     if block_len > 1 and s_q == 1:
         raise ValueError(
@@ -437,14 +504,25 @@ def attention(
             k_cache = k_cache.reshape((l * n_blocks,) + k_cache.shape[2:])
             v_cache = v_cache.reshape((l * n_blocks,) + v_cache.shape[2:])
             block_tables = block_tables + li * n_blocks
+            if k_parts is not None:
+                k_cache = tuple(part.reshape((l * n_blocks,) + part.shape[2:])
+                                for part in k_parts)
+        elif k_parts is not None:
+            k_cache = k_parts
         record_route("xla")
         return paged_attention(q, k_cache, v_cache, block_tables, positions,
                                context_lens, scale=scale, softcap=softcap,
                                sliding_window=sliding_window,
-                               sinks=sinks, block_len=block_len)[..., :d]
+                               sinks=sinks, block_len=block_len)[..., :out_d]
 
     interpret = interpret or pallas_interpret()
-    if not stacked:
+    if k_parts is not None:
+        if not stacked or route == "verify":
+            raise NotImplementedError(
+                "keys in parts of lanes: stacked by layer, and on the decode "
+                "and flash kernels and the XLA route only")
+        k_cache = k_parts
+    elif not stacked:
         k_cache, v_cache = k_cache[None], v_cache[None]
     # the window may be a traced scalar (Gemma-2 alternates windowed/full
     # layers inside its layer scan) — it rides as a [1] operand so the
@@ -520,7 +598,7 @@ def attention(
             out_specs=P(dp, None, "tp", None),
             check_vma=False,  # pallas out_shape carries no vma annotation
         )
-    return call(*args)[..., :d]
+    return call(*args)[..., :out_d]
 
 
 def prefill_attention(

@@ -12,6 +12,16 @@ page DMA is one contiguous [bs, KVH, D] burst.
 Reference analog: the vLLM/SGLang GPU paged-attention kernels the
 reference delegated to (SURVEY.md §2.4, §7 hard-part #1).
 
+A block of queries is as many positions as its bytes leave room for in
+VMEM (``q_block_rows``: 128 at 32 heads of 128 lanes, 64 at 64 heads of
+256 / 128). The values' lanes and the output's are ``v_cache``'s own; the
+keys may be several stacks, each a part of their lanes
+(ops/attention.split_lanes), and a score is then the sum of the parts'
+products. A sink (a learned logit a head, a key with no value) is the
+running softmax's first term: the statistics start at ``m = sink, l =
+1`` with an empty accumulator, and the finalize has no branch for it
+(Mosaic refuses the broadcast a finalize-time sink needs).
+
 API contract (matches the engine's scheduler): query positions of a step
 are affine — token s of the q block sits at absolute position
 ``base_pos + s``. Pad rows past the true suffix produce garbage rows the
@@ -28,7 +38,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_decode import MASK_VALUE, _out_struct
+from .pallas_decode import MASK_VALUE, _out_struct, _pow2_floor
+
+# what a block of queries keeps in VMEM while its pages stream by: the
+# query block and the output block (two buffers each: the pipeline's), the
+# float32 accumulator and the two lane-broadcast running statistics. The
+# block's rows are sized so that these stay within this much (128 rows
+# at 32 heads of 128 lanes, 10.5 MB; 64 heads of 256 / 128 lanes take
+# 24 MB at 128 rows and Mosaic refuses the kernel for its scoped VMEM)
+Q_BLOCK_BYTES = 12 << 20
+
+
+def q_block_rows(heads: int, d: int, dv: int, itemsize: int,
+                 most: int = 128) -> int:
+    """Query positions a block of the flash kernel holds, from the bytes
+    a position keeps resident (``Q_BLOCK_BYTES``): ``most`` wherever that
+    fits, else the largest power of two that does."""
+    row = heads * (2 * d * itemsize + 2 * dv * itemsize + 4 * dv
+                   + 2 * 128 * 4)
+    return min(most, _pow2_floor(Q_BLOCK_BYTES // row))
 
 
 def _kernel(
@@ -38,15 +66,18 @@ def _kernel(
     li_ref,     # scalar prefetch: layer index [1] (consumed by index_maps)
     win_ref,    # scalar prefetch: sliding window [1] (>= ctx disables)
     q_ref,      # [1, Sc, KVH, G, D] (VMEM block)
-    k_ref,      # [1, 1, bs, KVH, D] — one cache page of one layer
-    v_ref,
-    *rest,      # ([sinks_ref [1, KVH, G] when has_sinks], o_ref, m/l/acc scratch)
+    *rest,      # k_refs (a part of the keys' lanes each), v_ref:
+                # [1, 1, bs, KVH, lanes], one cache page of one layer;
+                # ([sinks_ref [KVH * Sc * G, 128] when has_sinks],
+                # o_ref, m/l/acc scratch)
     scale: float,
     block_size: int,
     softcap: float,
     has_sinks: bool = False,
     block_len: int = 1,
+    k_parts: int = 1,
 ):
+    k_refs, v_ref, rest = rest[:k_parts], rest[k_parts], rest[k_parts + 1:]
     if has_sinks:
         sinks_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -57,12 +88,21 @@ def _kernel(
     num_w = pl.num_programs(2)
 
     _, sc, kvh, g, d = q_ref.shape
+    dv = v_ref.shape[-1]    # the values' lanes: a side of the cache has its own
     rows = sc * g
 
     @pl.when(w == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, MASK_VALUE)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if has_sinks:
+            # the sink is the running softmax's first term: a key of
+            # logit sink and no value (weight 1 at the maximum it sets,
+            # nothing in the accumulator), so every later page folds in
+            # as after any other and the finalize knows nothing of it
+            m_scr[:] = sinks_ref[...]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, MASK_VALUE)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     ctx = ctx_ref[b]
@@ -102,14 +142,22 @@ def _kernel(
             lo = h * rows
             q = q_ref[0, :, h, :, :].reshape(rows, d)          # [rows, D]
             # upcast from the cache storage dtype (fp8 serving)
-            k = k_ref[0, 0, :, h, :].astype(q.dtype)            # [bs, D]
+            ks = [ref[0, 0, :, h, :].astype(q.dtype)            # [bs, lanes]
+                  for ref in k_refs]
             v = v_ref[0, 0, :, h, :].astype(q.dtype)
 
-            s_log = jax.lax.dot_general(
-                q, k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                           # [rows, bs]
+            # (keys kept as several stacks of lanes: a product a part,
+            # each over its own lanes of the query)
+            lanes = [0]
+            for k in ks:
+                lanes.append(lanes[-1] + k.shape[-1])
+            s_log = functools.reduce(jnp.add, [
+                jax.lax.dot_general(
+                    q if k_parts == 1 else q[:, a:b], k,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for k, a, b in zip(ks, lanes, lanes[1:])
+            ]) * scale                                          # [rows, bs]
             if softcap:
                 s_log = softcap * jnp.tanh(s_log / softcap)
             s_log = jnp.where(mask, s_log, MASK_VALUE)
@@ -136,16 +184,9 @@ def _kernel(
         for h in range(kvh):
             lo = h * rows
             l = l_scr[lo : lo + rows, 0:1]
-            if has_sinks:
-                # virtual sink key: denominator-only (any shared exp
-                # shift cancels, so the keys-only running max serves)
-                sk = jnp.broadcast_to(
-                    sinks_ref[0, h][None, :], (sc, g)
-                ).reshape(rows, 1)
-                l = l + jnp.exp(sk - m_scr[lo : lo + rows, 0:1])
             l = jnp.where(l == 0.0, 1.0, l)
             out = (acc_scr[lo : lo + rows, :] / l).astype(o_ref.dtype)
-            o_ref[0, :, h, :, :] = out.reshape(sc, g, d)
+            o_ref[0, :, h, :, :] = out.reshape(sc, g, dv)
 
 
 @functools.partial(
@@ -170,9 +211,18 @@ def paged_flash_attention(
                              # positions, full inside one (models/sdar.py)
 ) -> jax.Array:
     b, s, h, d = q.shape
-    if k_cache.ndim == 4:
-        k_cache, v_cache = k_cache[None], v_cache[None]
+    # the keys may be kept as several stacks, each a part of their lanes
+    # (ops/attention.split_lanes); one stack is every family's but one
+    k_caches = list(k_cache) if isinstance(k_cache, (tuple, list)) else None
+    if k_caches is None:
+        if k_cache.ndim == 4:
+            k_cache, v_cache = k_cache[None], v_cache[None]
+        k_caches = [k_cache]
+    else:
+        assert sum(k.shape[-1] for k in k_caches) == d, "q spans the parts"
+    k_cache = k_caches[0]
     _, n_blocks, block_size, kvh, _ = k_cache.shape
+    dv = v_cache.shape[-1]   # the output's width: the v side's own lanes
     li = (
         jnp.zeros((1,), jnp.int32)
         if layer_idx is None
@@ -189,7 +239,9 @@ def paged_flash_attention(
         scale = d ** -0.5
 
     # largest divisor of S that fits the chunk budget (buckets are usually
-    # powers of two, giving sc == q_chunk; odd max_model_len still works)
+    # powers of two, giving sc == q_chunk; odd max_model_len still works),
+    # the budget what the block's bytes leave of it (q_block_rows)
+    q_chunk = q_block_rows(h, d, dv, q.dtype.itemsize, q_chunk)
     sc = next(c for c in range(min(s, q_chunk), 0, -1) if s % c == 0)
     num_chunks = s // sc
 
@@ -228,23 +280,24 @@ def paged_flash_attention(
     has_sinks = sinks is not None
     in_specs = [
         pl.BlockSpec((1, sc, kvh, g, d), q_map),
-        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
-        pl.BlockSpec((1, 1, block_size, kvh, d), kv_map),
+        *(pl.BlockSpec((1, 1, block_size, kvh, k.shape[-1]), kv_map)
+          for k in k_caches),
+        pl.BlockSpec((1, 1, block_size, kvh, dv), kv_map),
     ]
     if has_sinks:
         in_specs.append(
-            pl.BlockSpec((1, kvh, g), lambda *_: (0, 0, 0))
+            pl.BlockSpec((kvh * sc * g, 128), lambda *_: (0, 0))
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b, num_chunks, w),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, sc, kvh, g, d), q_map),
+        out_specs=pl.BlockSpec((1, sc, kvh, g, dv), q_map),
         scratch_shapes=[
             pltpu.VMEM((kvh * sc * g, 128), jnp.float32),
             pltpu.VMEM((kvh * sc * g, 128), jnp.float32),
-            pltpu.VMEM((kvh * sc * g, d), jnp.float32),
+            pltpu.VMEM((kvh * sc * g, dv), jnp.float32),
         ],
     )
 
@@ -255,25 +308,30 @@ def paged_flash_attention(
         li,
         win,
         qg,
-        k_cache,
+        *k_caches,
         v_cache,
     ]
     if has_sinks:
-        operands.append(jnp.asarray(sinks, jnp.float32).reshape(1, kvh, g))
+        # a head's logit at each of its (s, g) rows of the statistics,
+        # lane-broadcast as they are: rows ordered (kv head, s, g)
+        operands.append(jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32).reshape(kvh, 1, g, 1),
+            (kvh, sc, g, 128)).reshape(kvh * sc * g, 128))
 
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, block_size=block_size, softcap=softcap,
             has_sinks=has_sinks,
             **({} if block_len == 1 else {"block_len": block_len}),
+            **({} if len(k_caches) == 1 else {"k_parts": len(k_caches)}),
         ),
         grid_spec=grid_spec,
         out_shape=_out_struct(
-            (b * num_chunks, sc, kvh, g, d), q.dtype, q, k_cache,
+            (b * num_chunks, sc, kvh, g, dv), q.dtype, q, k_cache,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, dv)

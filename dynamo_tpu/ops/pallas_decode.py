@@ -265,15 +265,16 @@ def _decode_kernel(
     li_ref,    # scalar prefetch: layer index [1]
     win_ref,   # scalar prefetch: sliding window [1] (>= ctx disables)
     q_ref,     # [1, KVH, G, D] VMEM block
-    k_hbm,     # [L, N, page * KVH, D] in HBM (ANY): a page's (token, head) rows
-    v_hbm,
-    *rest,     # ([sinks_ref [1, rows] when has_sinks], o_ref, scratch...)
+    *rest,     # k_hbm a part of the keys' lanes, v_hbm: [L, N, page * KVH,
+               # lanes] in HBM (ANY), a page's (token, head) rows;
+               # ([sinks_ref [1, rows] when has_sinks], o_ref, scratch...)
     scale: float,
     block_size: int,
     wide_pages: int,
     tail_pages: int,
     softcap: float,
     has_sinks: bool = False,
+    k_parts: int = 1,
 ):
     """One grid step = one live batch row (``rows_ref`` names it);
     ``_walk`` copies and folds only its LIVE pages: wide chunks sized by
@@ -301,10 +302,11 @@ def _decode_kernel(
     as a virtual key with no value — one exp(sink - m) term added to
     the denominator at finalize.
     """
+    k_hbms, (v_hbm, *rest) = rest[:k_parts], rest[k_parts:]
     if has_sinks:
-        sinks_ref, o_ref, k_buf, v_buf, sem = rest
+        sinks_ref, o_ref, *k_bufs, v_buf, sem = rest
     else:
-        o_ref, k_buf, v_buf, sem = rest
+        o_ref, *k_bufs, v_buf, sem = rest
     b = rows_ref[pl.program_id(0)]
     ctx = ctx_ref[b]
     li = li_ref[0]
@@ -313,6 +315,7 @@ def _decode_kernel(
     win_start = jnp.maximum(ctx - win_ref[0], 0)
 
     _, kvh, g, d = q_ref.shape
+    dv = v_buf.shape[-1]    # the values' lanes: a side of the cache has its own
     rows = kvh * g
     q = q_ref[0].reshape(rows, d)  # [KVH*G, D], rows ordered (head, group)
 
@@ -332,8 +335,9 @@ def _decode_kernel(
         cols = pages * block_size * kvh
         # upcast from the cache storage dtype (fp8 serving stores e4m3;
         # the dots and the p·V product must run at the compute dtype)
-        k = k_buf[slot, :pages].reshape(cols, d).astype(q.dtype)
-        v = v_buf[slot, :pages].reshape(cols, d).astype(q.dtype)
+        ks = [buf[slot, :pages].reshape(cols, buf.shape[-1]).astype(q.dtype)
+              for buf in k_bufs]
+        v = v_buf[slot, :pages].reshape(cols, dv).astype(q.dtype)
 
         # decode causality: the query is the newest token, so every key
         # with position < ctx is visible — a pure validity mask (plus the
@@ -341,11 +345,18 @@ def _decode_kernel(
         key_pos = first_page * block_size + col_tok
         mask = head_match & (key_pos < ctx) & (key_pos >= win_start)
 
-        s_log = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                         # [rows, cols]
+        # (keys kept as several stacks of lanes: a product a part, each
+        # over its own lanes of the query)
+        lanes = [0]
+        for k in ks:
+            lanes.append(lanes[-1] + k.shape[-1])
+        s_log = functools.reduce(jnp.add, [
+            jax.lax.dot_general(
+                q if k_parts == 1 else q[:, lo:hi], k,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for k, lo, hi in zip(ks, lanes, lanes[1:])
+        ]) * scale                                        # [rows, cols]
         if softcap:
             s_log = softcap * jnp.tanh(s_log / softcap)
         return _fold(carry, jnp.where(mask, s_log, MASK_VALUE), v)
@@ -355,11 +366,12 @@ def _decode_kernel(
     # accumulators in pallas_attention.py)
     m0 = jnp.full((rows, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((rows, 128), jnp.float32)
-    acc0 = jnp.zeros((rows, d), jnp.float32)
+    acc0 = jnp.zeros((rows, dv), jnp.float32)
     m, l, acc = _walk(
         b, bt_ref, sem,
-        [(lambda n: k_hbm.at[li, n], k_buf, False),
-         (lambda n: v_hbm.at[li, n], v_buf, True)],
+        [(lambda n, k_hbm=k_hbm: k_hbm.at[li, n], k_buf, False)
+         for k_hbm, k_buf in zip(k_hbms, k_bufs)]
+        + [(lambda n: v_hbm.at[li, n], v_buf, True)],
         first_page=win_start // block_size, npages=npages,
         wide=wide_pages, tail=tail_pages, attend=attend,
         carry=(m0, l0, acc0),
@@ -373,7 +385,7 @@ def _decode_kernel(
             sinks_ref[0][:, None].astype(jnp.float32) - m[:, 0:1]
         )
     l1 = jnp.where(l1 == 0.0, 1.0, l1)
-    o_ref[0] = (acc / l1).astype(o_ref.dtype).reshape(kvh, g, d)
+    o_ref[0] = (acc / l1).astype(o_ref.dtype).reshape(kvh, g, dv)
 
 
 def _mla_decode_kernel(
@@ -625,7 +637,7 @@ def _verify_kernel(
     # the earliest key ANY query can see (query 0's window lower bound)
     win_start = jnp.maximum(base + 1 - win_ref[0], 0)
 
-    _, products, rows, d = q_ref.shape
+    _, products, rows, _ = q_ref.shape
     kvh = k_buf.shape[2] // block_size
     per = kvh // products          # kv heads a product
     group = rows // (per * s_q)    # query heads a kv head
@@ -678,6 +690,7 @@ def _verify_kernel(
         its second are the packed rows of the even head (tokens in the
         order ``tokens`` names), the high halves the odd head's. Any
         other word's heads stay together as the rows they are."""
+        d = buf.shape[-1]       # a side's own lanes
         if per == kvh:
             return [buf[slot, :pages].reshape(
                 pages * block_size * kvh, d).astype(dtype)]
@@ -718,7 +731,7 @@ def _verify_kernel(
 
     m0 = jnp.full((rows, 128), MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((rows, 128), jnp.float32)
-    acc0 = jnp.zeros((rows, d), jnp.float32)
+    acc0 = jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)
     heads = _walk(
         b, bt_ref, sem,
         [(lambda n: k_hbm.at[li, n], k_buf, False),
@@ -769,7 +782,7 @@ def paged_verify_attention(
     live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
     """S-token verify attention over the paged cache; returns
-    [B, S, H, D]. The flash kernel's affine contract: query s of row b
+    [B, S, H, Dv] (``v_cache``'s lanes). The flash kernel's affine contract: query s of row b
     sits at ``base_pos[b] + s``; rows past ``context_lens`` (a padded
     chunk) produce garbage the caller discards. A row is walked as
     ``paged_decode_attention`` walks it (``chunk_pages`` over the page's
@@ -784,10 +797,11 @@ def paged_verify_attention(
     if k_cache.ndim == 4:
         k_cache, v_cache = k_cache[None], v_cache[None]
     _, _, block_size, kvh, _ = k_cache.shape
-    # a page as its (token, head) rows: see paged_decode_attention
-    page_shape = (block_size * kvh, d)
-    k_cache = k_cache.reshape(k_cache.shape[:2] + page_shape)
-    v_cache = v_cache.reshape(v_cache.shape[:2] + page_shape)
+    # a page as its (token, head) rows, the values' lanes the v side's
+    # own: see paged_decode_attention
+    dv = v_cache.shape[-1]
+    k_cache = k_cache.reshape(k_cache.shape[:2] + (block_size * kvh, d))
+    v_cache = v_cache.reshape(v_cache.shape[:2] + (block_size * kvh, dv))
     g = h // kvh
     # a chunk is folded a product at a time: a kv head's own rows where
     # the kernel can read them apart from the others' (32-bit rows; 16-bit
@@ -812,7 +826,7 @@ def paged_verify_attention(
     )
     wide, tail = _chunks(
         "paged_verify_attention", pages_per_chunk, 16,
-        2 * block_size * kvh * d * k_cache.dtype.itemsize,
+        block_size * kvh * (d + dv) * k_cache.dtype.itemsize,
         per * s * g * block_size * per * 4, block_tables.shape[1])
     # a product's query rows together, ordered (head, s, g)
     by_product = (b, products, per * s * g, d)
@@ -839,10 +853,10 @@ def paged_verify_attention(
         num_scalar_prefetch=6,
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1,) + by_product[1:], by_row),
+        out_specs=pl.BlockSpec((1,) + by_product[1:3] + (dv,), by_row),
         scratch_shapes=[
-            pltpu.VMEM((2, wide) + page_shape, k_cache.dtype),
-            pltpu.VMEM((2, wide) + page_shape, v_cache.dtype),
+            pltpu.VMEM((2, wide) + k_cache.shape[2:], k_cache.dtype),
+            pltpu.VMEM((2, wide) + v_cache.shape[2:], v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -876,14 +890,14 @@ def paged_verify_attention(
             **({} if block_len == 1 else {"block_len": block_len}),
         ),
         grid_spec=grid_spec,
-        out_shape=_out_struct(by_product, q.dtype, q, k_cache),
+        out_shape=_out_struct(by_product[:3] + (dv,), q.dtype, q, k_cache),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(*operands)
-    out = out.reshape(b, products, per, s, g, d).transpose(
-        0, 3, 1, 2, 4, 5).reshape(b, s, h, d)
+    out = out.reshape(b, products, per, s, g, dv).transpose(
+        0, 3, 1, 2, 4, 5).reshape(b, s, h, dv)
     return _zero_unwalked(out, live_rows)
 
 
@@ -908,7 +922,9 @@ def paged_decode_attention(
     one_head: bool = False,  # the caches are [L, N, page, D]: a kv head a page
     live_rows: Optional[LiveRows] = None,  # the rows that hold a token
 ) -> jax.Array:
-    """Single-token paged attention; returns [B, 1, H, D].
+    """Single-token paged attention; returns [B, 1, H, Dv], ``Dv`` the
+    lanes of ``v_cache`` (the keys' ``D`` wherever the two sides are one
+    width).
 
     ``window`` may be traced (Gemma-2 alternates windowed/full layers
     inside its layer scan), so it rides as a scalar-prefetch operand; the
@@ -923,6 +939,12 @@ def paged_decode_attention(
     of the tail; a caller has nothing to set."""
     b, s, h, d = q.shape
     assert s == 1, "decode kernel is specialized to one query token"
+    # the keys may be kept as several stacks, each a part of their lanes
+    # (ops/attention.split_lanes says why); one stack is every family's
+    # but that one
+    k_parts = tuple(k_cache) if isinstance(k_cache, (tuple, list)) else None
+    if k_parts is not None:
+        k_cache = k_parts[0]
     if one_head:
         # [L, N, page, D]: a page holds one kv head and has no head axis
         # (a unit axis there is a slice Mosaic's tiling refuses); every
@@ -931,16 +953,25 @@ def paged_decode_attention(
         kvh = 1
     else:
         if k_cache.ndim == 4:
+            assert k_parts is None, "keys in parts are stacked by layer"
             k_cache, v_cache = k_cache[None], v_cache[None]
         _, _, block_size, kvh, _ = k_cache.shape
     # a page as its (token, head) rows: the same bytes (XLA's tiles of
     # [KVH, D] for KVH of 2 or 4 laid end to end are its tiles of eight
     # rows, so the reshape is a bitcast), and a chunk in VMEM is
     # [chunk_t * KVH, D] as the dots want it, where a buffer of
-    # [.., KVH, D] tiles had to be repacked row group by row group
-    page_shape = (block_size * kvh, d)
-    k_cache = k_cache.reshape(k_cache.shape[:2] + page_shape)
-    v_cache = v_cache.reshape(v_cache.shape[:2] + page_shape)
+    # [.., KVH, D] tiles had to be repacked row group by row group.
+    # The values' width is the v side's own (a family whose values are
+    # narrower than its keys: models/mimo_v2.py), and so is the output's
+    dv = v_cache.shape[-1]
+    if k_parts is None:
+        k_caches = [k_cache.reshape(k_cache.shape[:2] + (block_size * kvh, d))]
+    else:
+        k_caches = [k.reshape(k.shape[:2] + (block_size * kvh, k.shape[-1]))
+                    for k in k_parts]
+        assert sum(k.shape[-1] for k in k_caches) == d, "q spans the parts"
+    k_cache = k_caches[0]
+    v_cache = v_cache.reshape(v_cache.shape[:2] + (block_size * kvh, dv))
     g = h // kvh
     if scale is None:
         scale = d ** -0.5
@@ -956,7 +987,7 @@ def paged_decode_attention(
     )
     wide, tail = _chunks(
         "paged_decode_attention", pages_per_chunk, 8,
-        2 * block_size * kvh * d * k_cache.dtype.itemsize,
+        block_size * kvh * (d + dv) * k_cache.dtype.itemsize,
         h * block_size * kvh * 4, block_tables.shape[1])
 
     qs = q.reshape(b, kvh, g, d)
@@ -969,7 +1000,7 @@ def paged_decode_attention(
 
     in_specs = [
         pl.BlockSpec((1, kvh, g, d), by_row),
-        pl.BlockSpec(memory_space=pl.ANY),
+        *(pl.BlockSpec(memory_space=pl.ANY) for _ in k_caches),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     if has_sinks:
@@ -981,10 +1012,10 @@ def paged_decode_attention(
         num_scalar_prefetch=5,
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, g, d), by_row),
+        out_specs=pl.BlockSpec((1, kvh, g, dv), by_row),
         scratch_shapes=[
-            pltpu.VMEM((2, wide) + page_shape, k_cache.dtype),
-            pltpu.VMEM((2, wide) + page_shape, v_cache.dtype),
+            *(pltpu.VMEM((2, wide) + k.shape[2:], k.dtype) for k in k_caches),
+            pltpu.VMEM((2, wide) + v_cache.shape[2:], v_cache.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
@@ -996,7 +1027,7 @@ def paged_decode_attention(
         li,
         win,
         qs,
-        k_cache,
+        *k_caches,
         v_cache,
     ]
     if has_sinks:
@@ -1013,12 +1044,13 @@ def paged_decode_attention(
             tail_pages=tail,
             softcap=softcap,
             has_sinks=has_sinks,
+            **({} if k_parts is None else {"k_parts": len(k_caches)}),
         ),
         grid_spec=grid_spec,
-        out_shape=_out_struct((b, kvh, g, d), q.dtype, q, k_cache),
+        out_shape=_out_struct((b, kvh, g, dv), q.dtype, q, k_cache),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(*operands)
-    return _zero_unwalked(out.reshape(b, 1, h, d), live_rows)
+    return _zero_unwalked(out.reshape(b, 1, h, dv), live_rows)

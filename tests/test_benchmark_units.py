@@ -23,7 +23,10 @@ FILES = ("test_units.py", "test_trace_reduction.py", "test_host_spans.py",
          "test_falcon_h1_units.py", "test_xing4_units.py",
          "test_minicpm_sala_units.py", "test_afmoe_units.py",
          "test_sync_parts.py", "test_granite_units.py",
-         "test_kimi_linear_units.py", "test_dots3_units.py")
+         "test_kimi_linear_units.py", "test_dots3_units.py",
+         # (its rehearsal too: one run of under a minute; the other
+         # cells' take two to four and stay out)
+         "test_mimo_units.py", "test_mimo_rehearsal.py")
 
 
 def _adopt(filename: str) -> None:
@@ -51,15 +54,56 @@ for _file in FILES:
     _adopt(_file)
 
 
-# ---------------------------------------------------------------------
-# a metric that arrived as files only (ISSUE 32): its case is here, its
-# two /metrics samples are data under benchmark/tests/data
-# ---------------------------------------------------------------------
-
 def _cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return [w["name"] for w in json.load(f)["workloads"]]
 
+
+# Two cases of benchmark/tests were written against the manifest as it
+# stood and fail on one with a thirteenth cell (PR 59; a file the
+# benchmark has is a `benchmark` PR's to edit: PERF.md section 7, "Left
+# by PR 59" (5)): PR 54's asserts that its cell is the manifest's last,
+# and PR 58's is a case a cell of today's manifest over data that holds
+# PR 57's twelve. Both run here as they are, on the manifest as it is,
+# and are expected to fail where they do until that PR mends them; what
+# the first held of the whole manifest is held below.
+_STALE = "asserts the manifest as it stood before PR 59: a `benchmark` PR's to mend"
+
+
+def _expected_to_fail_on_a_later_cell(test):
+    module = sys.modules[test.__module__]
+    with open(module.PARENT) as f:
+        kept = json.load(f)["workloads"]
+    test.pytestmark = [pytest.mark.parametrize("cell_name", [
+        c if c in kept else pytest.param(c, marks=pytest.mark.xfail(
+            reason=_STALE, raises=AssertionError))
+        for c in _cells()]).mark]
+
+
+pytest.mark.xfail(reason=_STALE, raises=AssertionError)(
+    test_dots3_cell_configuration_and_metrics_as_the_manifest_has_them)  # noqa: F821
+_expected_to_fail_on_a_later_cell(
+    test_what_a_cell_read_at_pr57_it_reads_under_a_surviving_name)  # noqa: F821
+
+
+def test_one_cell_on_four_chips_and_each_cells_own_entries_list_it_alone():
+    """Over the whole manifest, as it is: one cell takes four chips, and
+    an entry under a cell's own prefix lists that cell alone."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        man = json.load(f)
+    assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
+    assert {"dots3-longdoc", "mimo-longdoc"} <= set(_cells())
+    for prefix, cell, own in (("dots3_", "dots3-longdoc", 5),
+                              ("mimo_", "mimo-longdoc", 8)):
+        lists = [m.get("workloads") for m in man["per_layer"]
+                 if m["name"].startswith(prefix)]
+        assert lists == [[cell]] * own
+
+
+# ---------------------------------------------------------------------
+# a metric that arrived as files only (ISSUE 32): its case is here, its
+# two /metrics samples are data under benchmark/tests/data
+# ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("cell_name", _cells())
 def test_yield_inflight_share_from_two_metrics_samples(cell_name):

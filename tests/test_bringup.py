@@ -126,8 +126,19 @@ def test_auto_routes_rejected_specializations_to_xla(monkeypatch):
         return routes[-1]
 
     assert trace("auto") == "flash"
-    assert trace("auto", sinks=sinks) == "xla"
-    assert trace("pallas", sinks=sinks) == "flash"
+    # the flash kernel takes a sink as its running softmax's first term
+    # and compiles; the verify kernel's finalize with one does not
+    assert trace("auto", sinks=sinks) == "flash"
+    q8 = q[:, :8]
+    pos8 = pos[:, :8]
+    jax.make_jaxpr(lambda *a: attn.attention(
+        *a, impl="auto", layer_idx=jnp.int32(0), sinks=sinks))(
+            q8, k, v, bt, pos8, ctx)
+    assert routes[-1] == "xla"
+    jax.make_jaxpr(lambda *a: attn.attention(
+        *a, impl="pallas", layer_idx=jnp.int32(0), sinks=sinks))(
+            q8, k, v, bt, pos8, ctx)
+    assert routes[-1] == "verify"
     # fp8 page copies: rejected at the toy's 2 kv heads, fine at 8
     for shape, want in (("toy", "xla"), ("llama-3.2-1b", "decode")):
         q, k, v, bt, pos, ctx = kernel_matrix._paged_inputs(

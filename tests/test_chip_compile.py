@@ -1069,6 +1069,131 @@ def test_dots3_step_keeps_every_page_stack_in_place(
     assert mem.temp_size_in_bytes < (0.75 if tokens == 1 else 1.6) * 2 ** 30
 
 
+# MiMo-V2.5 as one chip serves it (benchmark/configs/mimo-v2.5-ep16.json):
+# 64 query heads over 4 kv heads in a full layer and 8 in a window layer,
+# keys of 192 in 256 lanes and values of 128, pages of 16, a window of 128
+# under a learned sink, 32 rows, a table of 1152
+@pytest.mark.parametrize("kvh,sink", [(8, True), (4, False)])
+@pytest.mark.parametrize("rows,tokens", [(32, 1), (1, 2048)])
+def test_decode_and_flash_kernels_compile_at_mimos_pages(
+        one_chip, no_compile_cache, kvh, sink, rows, tokens):
+    """The decode kernel at 32 rows and the flash kernel on a 2048-token
+    chunk, at both kinds' shapes: K of 256 lanes (two stacks of 128) and V of
+    128, the window
+    layers' sink (in the flash kernel the running softmax's first term),
+    the query block sized from its bytes (64 heads of 256 lanes do not
+    fit at 128 rows)."""
+    from dynamo_tpu.ops.attention import attention
+    from dynamo_tpu.ops.pallas_attention import q_block_rows
+
+    assert q_block_rows(64, 256, 128, 2) == 64
+    assert q_block_rows(32, 128, 128, 2) == 128     # Phi-3's, as it was
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, blocks, page, h, width = 5 if sink else 2, 417, 16, 64, 1152
+
+    def f(q, k, v, bt, pos, ctx, li, *sinks):
+        return attention(q, k, v, bt, pos, ctx, impl="pallas", layer_idx=li,
+                         sliding_window=128 if sink else None,
+                         sinks=sinks[0] if sinks else None, v_dim=128)
+
+    args = [s((rows, tokens, h, 192), jnp.bfloat16),
+            (s((layers, blocks, page, kvh, 128), jnp.bfloat16),) * 2,
+            s((layers, blocks, page, kvh, 128), jnp.bfloat16),
+            s((rows, width), jnp.int32), s((rows, tokens), jnp.int32),
+            s((rows,), jnp.int32), s((), jnp.int32)]
+    if sink:
+        args.append(s((h,), jnp.float32))
+    compiled = jax.jit(f).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (rows, tokens, h, 128)
+
+
+@pytest.mark.parametrize("rows", [256, 16384])
+@pytest.mark.parametrize("k,n", [(4096, 2048), (2048, 4096)])
+def test_grouped_products_compile_at_mimos_expert_shapes(
+        one_chip, no_compile_cache, monkeypatch, rows, k, n):
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        s((rows, k), jnp.bfloat16), s((6, 16, k, n), jnp.bfloat16),
+        s((16,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mimo_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+    from dynamo_tpu.models import mimo_v2
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+    pool = EngineConfig(
+        model=cfg, **{k: serve[k] for k in (
+            "max_model_len", "max_batch_size", "num_kv_blocks",
+            "prefill_buckets", "max_prefill_tokens_per_step",
+            "max_prefill_batch")}).window_pool_pages()
+    assert pool == 1 + 32 * 9 + 128
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: mimo_v2.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    k_side, v_side = jax.tree.map(s, jax.eval_shape(
+        lambda: mimo_v2.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
+                                      jnp.bfloat16, window_blocks=pool)))
+    assert [k.shape for k in k_side.full] == [(2, 36864, 16, 4, 128)] * 2
+    assert v_side.full.shape == (2, 36864, 16, 4, 128)
+    assert [k.shape for k in k_side.window] == [(5, 417, 16, 8, 128)] * 2
+    assert v_side.window.shape == (5, 417, 16, 8, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, toks, positions, bt, slots, ctx):
+        return mimo_v2.forward_counted(params, cfg, toks, positions,
+                                       (k_side, v_side), bt, slots, ctx)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, k_side, v_side, i32(rows, tokens), i32(rows, tokens),
+        i32(rows, 2 * width), i32(rows, tokens), i32(rows)).compile()
+
+
+@pytest.mark.parametrize("rows,tokens,width", [
+    (32, 1, 1152), (1, 2048, 1152)])
+def test_mimo_step_keeps_both_page_stacks_in_place(
+        one_chip, no_compile_cache, monkeypatch, rows, tokens, width):
+    """A decode step of 32 rows and a 2048-token prefill chunk at the
+    benchmark's size on the routes the chip takes: the paged decode (or
+    flash) kernel once a kind of layer, the three grouped products of
+    the held experts, neither the full kind's pages (3.62 GB) nor the
+    window kind's (0.20 GB) copied, and the prefill chunk's temporaries
+    fit beside what is held."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _mimo_step(one_chip, rows, tokens, width)
+    text = compiled.as_text()
+    for scope in ("kv_window", "kv_full", "moe_experts"):
+        assert re.search(rf"tpu_custom_call[^\n]*{scope}", text), scope
+    mem = compiled.memory_analysis()
+    print(f"mimo step {rows}x{tokens}: arguments",
+          mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes)
+    # weights 6.86 GB without the head's 0.16 (the trunk ends at the
+    # hidden state) + full pages 3.62 + window pages 0.20
+    assert 10.4e9 < mem.argument_size_in_bytes < 10.8e9
+    # a copy of the window stacks would be 0.2 GB, of the full 3.6
+    assert mem.temp_size_in_bytes < (64 if tokens == 1 else 1024) * 2 ** 20
+
+
 def _decode_trunk(ll, topo, config):
     """The decode trunk of a benchmark configuration compiled for the
     described chips (the real tp mesh where ``serve`` asks for one), once

@@ -270,7 +270,7 @@ OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
             for name in _NAME.findall(bullet.split(":")[0])}
 FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
                 "residual_multiplier": 0.22, "kda_num_heads": 4,
-                "index_topk": 32,
+                "index_topk": 32, "swa_num_kv_heads": 8,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
@@ -451,6 +451,49 @@ def test_a_latent_config_with_an_indexer_reaches_its_own_row():
             "num_hidden_layers": 2, "num_attention_heads": 2,
             "layer_types": ["sliding_attention", "full_attention"],
             "sliding_window": 8, "sliding_window_size": 9})
+
+
+def test_a_window_and_full_config_with_its_own_kv_heads_reaches_its_own_row():
+    """``mimo_v2`` has routed experts, mixtral's shape rule, and the
+    ``swa_*`` keys, ``sliding_window_size`` and ``expert_share`` that
+    dots3 (and Granite, Kimi) claim: its row stands before the rows told
+    by shape and takes it by name, those keys its own under this
+    ``model_type``; its ``hybrid_layer_pattern`` becomes the
+    ``layer_types`` the engine's window pool reads, and a config of
+    another family with a stray ``swa_num_kv_heads`` is refused."""
+    hf = {"model_type": "mimo_v2", "vocab_size": 64, "hidden_size": 32,
+          "intermediate_size": 48, "moe_intermediate_size": 16,
+          "num_hidden_layers": 3, "hybrid_layer_pattern": [0, 1, 1],
+          "moe_layer_freq": [0, 1, 1], "num_attention_heads": 4,
+          "num_key_value_heads": 1, "swa_num_key_value_heads": 2,
+          "swa_num_attention_heads": 4, "head_dim": 24, "swa_head_dim": 24,
+          "v_head_dim": 16, "swa_v_head_dim": 16, "sliding_window": 8,
+          "sliding_window_size": 8, "partial_rotary_factor": 0.334,
+          "attention_value_scale": 0.707, "swa_rope_theta": 10000,
+          "add_swa_attention_sink_bias": True, "n_routed_experts": 2,
+          "expert_share": {"of_experts": 8, "rank": 3},
+          "num_experts_per_tok": 2, "scoring_func": "sigmoid",
+          "topk_method": "noaux_tc", "layernorm_epsilon": 1e-6}
+    cfg = ModelConfig.from_hf_config(hf)
+    assert cfg.model_family == "mimo_v2" and models.family(cfg).name == "mimo_v2"
+    assert models.FAMILIES.index(models.family(cfg)) < next(
+        i for i, r in enumerate(models.FAMILIES) if r.name == "mixtral")
+    assert cfg.layer_types == ("full_attention", "sliding_attention",
+                               "sliding_attention")
+    assert (cfg.num_kv_heads, cfg.swa_num_kv_heads, cfg.first_k_dense_replace,
+            cfg.rms_norm_eps) == (1, 2, 1, 1e-6)
+    assert (cfg.num_experts, cfg.experts_of, cfg.expert_rank) == (2, 8, 3)
+    assert cfg.index_topk == 0 and cfg.kv_lora_rank == 0
+    assert models.family(cfg).module.SEQUENCE_STATE.window_pool
+    import dataclasses
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        models.resolve(dataclasses.replace(cfg, model_family=""))
+    with pytest.raises(NotImplementedError, match="swa_num_kv_heads"):
+        models.resolve(dataclasses.replace(cfg, model_family="",
+                                           layer_types=()))
+    # dots3's own config keeps its swa_* keys: this family claims them
+    # under its own model_type only
+    assert ModelConfig.from_hf_config(_DOTS3).model_family == "dots3"
 
 
 @pytest.mark.parametrize("keys,named", [

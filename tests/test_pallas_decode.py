@@ -1170,3 +1170,125 @@ def test_decode_table_width_that_is_no_power_of_two():
                                  layer_idx=jnp.int32(1), interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------- keys wider than values, keys in parts of lanes ----------
+
+def _wide_key_case(rng, kvh, s, d=192, dv=128, h=16, bs=16, w=8, b=3,
+                   layers=2):
+    """Keys of ``d`` lanes over values of ``dv`` (models/mimo_v2.py): the
+    cache as ``scatter_stacked`` and ``split_lanes`` write it, one stack
+    a lane tile of the keys, and the same keys as one padded stack."""
+    from dynamo_tpu.ops.attention import (lane_pad, pad_minor,
+                                          scatter_stacked, split_lanes)
+    n_blocks = b * w + 2
+    ctx = np.asarray([s + 5, s + 40, bs * w - 1][:b], np.int32)
+    bt = jnp.asarray(
+        1 + rng.permutation(n_blocks - 1)[: b * w].reshape(b, w), jnp.int32)
+    stacks = tuple(
+        jnp.zeros((layers, n_blocks, bs, kvh, lanes), jnp.float32)
+        for lanes in (128,) * (lane_pad(d) // 128) + (lane_pad(dv),))
+    # every context's keys and values, written through the table
+    t = int(ctx.max())
+    k = jnp.asarray(rng.standard_normal((b, t, kvh, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, t, kvh, dv)), jnp.float32)
+    pos = np.arange(t)[None, :].repeat(b, 0)
+    slots = np.asarray(bt)[np.arange(b)[:, None], pos // bs] * bs + pos % bs
+    slots = jnp.asarray(np.where(pos < ctx[:, None], slots, -1), jnp.int32)
+    *k_parts, v_all = scatter_stacked(stacks, (*split_lanes(k), v), slots,
+                                      jnp.int32(1))
+    k_one = jnp.concatenate(k_parts, -1)
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    base = jnp.asarray(ctx - s, jnp.int32)
+    return (q, tuple(k_parts), k_one, v_all, bt, base, jnp.asarray(ctx),
+            pad_minor)
+
+
+@pytest.mark.parametrize("sinks", [False, True])
+@pytest.mark.parametrize("kvh,window", [(4, None), (8, 24)])
+@pytest.mark.parametrize("s", [1, 64])
+def test_keys_in_parts_and_narrower_values_on_every_route(s, kvh, window,
+                                                          sinks):
+    """The decode kernel (S = 1) and the flash kernel (S = 64, a sink as
+    its running softmax's first term) over keys of 192 lanes kept as two
+    stacks of 128 and values of 128, at 4 and 8 kv heads: the XLA route
+    over the same tuple, and the XLA route over the keys as one stack of
+    256 lanes, give the same ``[B, S, H, 128]``."""
+    rng = np.random.default_rng(7 + s + kvh)
+    q, k_parts, k_one, v_all, bt, base, ctx, pad_minor = _wide_key_case(
+        rng, kvh, s)
+    sk = (jnp.asarray(rng.standard_normal(q.shape[2]) + 2.0, jnp.float32)
+          if sinks else None)
+    positions = base[:, None] + jnp.arange(s)[None, :]
+    want = paged_attention(
+        pad_minor(q, 256), k_one[1], v_all[1], bt, positions, ctx,
+        scale=192 ** -0.5, sliding_window=window, sinks=sk)
+    assert want.shape == q.shape[:3] + (128,)
+    for impl in ("xla", "pallas"):
+        got = attention(q, k_parts, v_all, bt, positions, ctx, impl=impl,
+                        interpret=True, layer_idx=jnp.int32(1),
+                        sliding_window=window, sinks=sk, v_dim=128)
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5, err_msg=impl)
+
+
+def test_narrower_values_on_the_verify_kernel_and_parts_refused_there():
+    """One stack of keys of 256 lanes over values of 128 on the verify
+    kernel (S = 8); keys in parts are the decode and flash kernels' and
+    the XLA route's, refused on the verify kernel and sent to the XLA
+    route by ``auto``."""
+    from dynamo_tpu.ops.pallas_decode import paged_verify_attention
+    rng = np.random.default_rng(3)
+    q, k_parts, k_one, v_all, bt, base, ctx, pad_minor = _wide_key_case(
+        rng, 8, 8)
+    positions = base[:, None] + jnp.arange(8)[None, :]
+    want = paged_attention(pad_minor(q, 256), k_one[1], v_all[1], bt,
+                           positions, ctx, scale=192 ** -0.5)
+    got = paged_verify_attention(
+        pad_minor(q, 256), k_one, v_all, bt, base, ctx,
+        layer_idx=jnp.int32(1), scale=192 ** -0.5, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(NotImplementedError, match="parts"):
+        attention(q, k_parts, v_all, bt, positions, ctx, impl="pallas",
+                  interpret=True, layer_idx=jnp.int32(1), v_dim=128)
+
+
+def test_the_flash_kernels_query_block_follows_its_bytes():
+    from dynamo_tpu.ops.pallas_attention import q_block_rows
+    # every accepted cell's heads keep 128 rows: Phi-3's 32 of 128 lanes
+    # (96 padded), a tp=4 shard's 8, Falcon-H1's 20, Trinity's and SDAR's 32
+    for heads in (8, 20, 32):
+        assert q_block_rows(heads, 128, 128, 2) == 128
+    # 64 heads of 256 / 128 lanes (models/mimo_v2.py) fit at 64 rows, of
+    # 64 lanes padded to 128 (GPT-OSS) too, and never more than asked
+    assert q_block_rows(64, 256, 128, 2) == 64
+    assert q_block_rows(64, 128, 128, 2) == 64
+    assert q_block_rows(8, 128, 128, 2, most=32) == 32
+
+
+@pytest.mark.parametrize("kvh,window,sinks", [(4, None, False), (8, 24, True),
+                                              (8, 70, True)])
+def test_the_flash_kernel_over_keys_in_parts_in_several_query_blocks(
+        kvh, window, sinks):
+    """Keys in parts under two blocks of 16 queries that start past
+    position 0 (``base_pos`` > 0), over a table of seven or of eleven
+    pages: a window's first page and a block's last lie inside the
+    table, and a sink starts each block's statistics anew."""
+    rng = np.random.default_rng(11 + kvh)
+    q, k_parts, k_one, v_all, bt, base, ctx, pad_minor = _wide_key_case(
+        rng, kvh, 32, w=7 if kvh == 4 else 11)
+    sk = (jnp.asarray(rng.standard_normal(q.shape[2]) + 2.0, jnp.float32)
+          if sinks else None)
+    positions = base[:, None] + jnp.arange(32)[None, :]
+    want = paged_attention(
+        pad_minor(q, 256), k_one[1], v_all[1], bt, positions, ctx,
+        scale=192 ** -0.5, sliding_window=window, sinks=sk)
+    from dynamo_tpu.ops.pallas_attention import paged_flash_attention
+    got = paged_flash_attention(
+        pad_minor(q, 256), k_parts, v_all, bt, base, ctx,
+        layer_idx=jnp.int32(1), scale=192 ** -0.5, interpret=True,
+        q_chunk=16, window=window, sinks=sk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
