@@ -851,8 +851,9 @@ class ModelRunner:
                          self.state_sharding) + counters
         self._packed_sharding = batch2_spec
         self._tokens_sharding = batch_spec
-        # what a decode step is fed where no step ran before it, a batch size
-        self._no_prev: Dict[int, jax.Array] = {}
+        # what a decode step (a block pass) is fed where none ran before
+        # it, by its shape: [B] ([B, L])
+        self._no_prev: Dict[Tuple[int, ...], jax.Array] = {}
         self._decode_step = jax.jit(
             decode_step, donate_argnums=(1, 2, 3, 4, 5),
             in_shardings=state + (batch_spec,) + counters,
@@ -889,7 +890,11 @@ class ModelRunner:
         overwrites them. The packed input is the step's (step_inputs.py)
         at ``S = 2B``, its ``last_idx`` column carrying the row's quota
         (0: a row that holds nothing) and ``counters`` the pass's number
-        within its block."""
+        within its block. After it the program takes the pass before's
+        ``new_ids`` (``[rows, B]``, not donated: the scheduler has yet to
+        fetch it), from which a row's first block is read where the host
+        packed ``step_inputs.FED``, as ``jit_decode_step`` takes its
+        ``prev_tokens``."""
         unit = self.unit
         self._decode_block = None
         if unit is None:
@@ -904,8 +909,16 @@ class ModelRunner:
         forward, head = self._make_forward(counted=moe)
         blen = unit.length
 
-        def decode_block(params, k_cache, v_cache, packed, *moe_counts):
+        def decode_block(params, k_cache, v_cache, packed, prev_ids,
+                         *moe_counts):
             inp = step_inputs.unpack(packed, 2 * blen)
+            # a row's first block where the host had not read the pass
+            # before when it packed this one (step_inputs.FED) is that
+            # pass's ``new_ids``, which never left the device: the block
+            # it denoised, to be denoised again or, whole, to be kept
+            inp = inp._replace(tokens=inp.tokens.at[:, :blen].set(jnp.where(
+                inp.tokens[:, :blen] == step_inputs.FED, prev_ids,
+                inp.tokens[:, :blen])))
             hidden, (k_cache, v_cache), *moe_step = forward(
                 params, (k_cache, v_cache), inp.tokens, inp.positions,
                 inp.block_tables, inp.slot_mapping, inp.context_lens,
@@ -942,12 +955,20 @@ class ModelRunner:
         self._decode_block = jax.jit(
             decode_block, donate_argnums=(1, 2),
             in_shardings=(self.param_shardings, self.cache_sharding,
-                          self.cache_sharding, batch2_spec)
+                          self.cache_sharding, batch2_spec, batch2_spec)
             + ((repl,) if moe else ()),
             out_shardings=(batch2_spec, batch2_spec, batch3_spec,
                            batch3_spec, batch_spec, self.cache_sharding,
                            self.cache_sharding) + ((repl,) if moe else ()),
         )
+
+    def _unfed(self, shape: Tuple[int, ...], sharding) -> jax.Array:
+        """Zeros on the device in place of the output of a step (a
+        block pass) before, where none is fed; kept a shape."""
+        if shape not in self._no_prev:
+            self._no_prev[shape] = jax.device_put(
+                np.zeros(shape, np.int32), sharding)
+        return self._no_prev[shape]
 
     def decode_block(
         self,
@@ -965,12 +986,16 @@ class ModelRunner:
         seed_keys: Optional[np.ndarray] = None,
         counters: Optional[np.ndarray] = None,    # [B] the pass within its block
         want_top: bool = False,
+        prev_ids: Optional[jax.Array] = None,  # [B, L] the pass before's
     ) -> Tuple[jax.Array, ...]:
         """Run one block pass (``_build_block_step`` says what a row's
         ``2L`` positions hold); returns device arrays, all of the block
         being denoised (its new ids [B, L], the log-probabilities of the
         positions unmasked in this pass [B, L] and 0 elsewhere, top
-        alternatives [B, L, K] twice, the count still masked [B])."""
+        alternatives [B, L, K] twice, the count still masked [B]).
+        ``prev_ids``: the ``new_ids`` the pass before returned, still on
+        the device; a row whose first ``L`` entries of ``tokens`` are
+        ``step_inputs.FED`` reads them from its row of it."""
         b, s = tokens.shape
         width = block_tables.shape[1]
         if seed_keys is None:
@@ -983,10 +1008,13 @@ class ModelRunner:
         )
         with self._track("decode_block", f"b{b}_s{s}_w{width}", arrays=1):
             moe = () if self.moe_counts is None else (self.moe_counts,)
+            if prev_ids is None:
+                prev_ids = self._unfed((b, s // 2), self._packed_sharding)
             new_ids, lps, top_vals, top_ids, left, k, v, *moe = (
                 self._decode_block(
                     self.params, *self.kv_cache,
-                    jax.device_put(buf, self._packed_sharding), *moe))
+                    jax.device_put(buf, self._packed_sharding), prev_ids,
+                    *moe))
         self.kv_cache = (k, v)
         if moe:
             self.moe_counts = moe[0]
@@ -1875,10 +1903,7 @@ class ModelRunner:
             fed = ()
             if s == 1:
                 if prev_tokens is None:
-                    if b not in self._no_prev:
-                        self._no_prev[b] = jax.device_put(
-                            np.zeros(b, np.int32), self._tokens_sharding)
-                    prev_tokens = self._no_prev[b]
+                    prev_tokens = self._unfed((b,), self._tokens_sharding)
                 fed = (prev_tokens,)
             args = (self.params, *self.kv_cache, *self.sample_state,
                     jax.device_put(buf, self._packed_sharding), *fed, *moe)
@@ -2362,13 +2387,17 @@ class ModelRunner:
             # the block pass is the family's one decode program (inert:
             # every slot is the drop sentinel, every quota 0)
             zb = np.zeros((b, 2 * self.unit.length), np.int32)
+            inert = (np.full_like(zb, -1), np.ones(b, np.int32),
+                     np.zeros(b, np.int32), np.zeros(b, np.float32),
+                     np.zeros(b, np.int32), np.ones(b, np.float32))
             for w in self._warm_widths("decode_block"):
-                self.decode_block(
-                    zb, zb, np.zeros((b, w), np.int32), np.full_like(zb, -1),
-                    np.ones(b, np.int32), np.zeros(b, np.int32),
-                    np.zeros(b, np.float32), np.zeros(b, np.int32),
-                    np.ones(b, np.float32),
-                )
+                ids, *_ = self.decode_block(
+                    zb, zb, np.zeros((b, w), np.int32), *inert)
+            # once more fed with its own output, as the scheduler feeds it
+            # a pass ahead (the same program, as the decode step's below)
+            self.decode_block(
+                zb, zb, np.zeros((b, self.config.blocks_per_seq), np.int32),
+                *inert, prev_ids=ids)
         fed = None
         for w in self._warm_widths("decode") if self.unit is None else ():
             fed, *_ = self.step(

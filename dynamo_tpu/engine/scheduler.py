@@ -530,15 +530,17 @@ class _InflightBurst:
 @dataclasses.dataclass
 class _StepInFlight:
     """One decode dispatch (``Scheduler._decode``) whose tokens the host
-    has not read: what ``_decode_land`` needs to apply it, fixed at the
-    dispatch. While it is in flight the next step can be built and
-    dispatched on top of it (``ahead``), its continuing rows fed from
-    ``arrays[0]`` on the device."""
+    has not read: what ``_decode_land`` (a block pass: ``_block_land``)
+    needs to apply it, fixed at the dispatch. While it is in flight the
+    next step can be built and dispatched on top of it (``ahead``), its
+    continuing rows fed from ``arrays[0]`` on the device."""
 
     # the rows, each with the slot it had at dispatch (a row that has
     # finished since may have lost it to another)
     rows: List[Tuple["EngineRequest", int]]
-    arrays: list                   # device next_tokens, lps, top_vals, top_ids
+    # device next_tokens, lps, top_vals, top_ids (a block pass: new_ids,
+    # lps, top_vals, top_ids, the count still masked)
+    arrays: list
     k_steps: int
     t_dispatch: float
     prefetched: int                # arrays whose copy to the host was requested
@@ -576,6 +578,7 @@ class Scheduler:
         # the family's decode unit where it is a block of positions
         # (models.BlockUnit): the block pass is then the decode pass
         self.unit = getattr(runner, "unit", None)
+        self._quotas = self.unit.quotas() if self.unit is not None else ()
         self.private_pages = keeps.private
         # (the two counters of a family with records by slot)
         self.recurrent = keeps.slots
@@ -719,13 +722,19 @@ class Scheduler:
         # fetched / has taken its turn for the frontend (sched.yield)
         self._inflight = False
         self._turn_taken = False
-        # the decode step that runs one step ahead of the host
-        # (``_decode``): dispatched, its tokens not read. Only a runner
-        # whose decode program takes the step before's tokens on the
-        # device can be run so (``ModelRunner.step(prev_tokens=)``; a
-        # test's stand-in says so itself)
+        # the decode step (a block family's block pass) that runs one
+        # step ahead of the host (``_decode``): dispatched, its tokens
+        # not read. Only a runner whose decode program takes the step
+        # before's tokens on the device can be run so
+        # (``ModelRunner.step(prev_tokens=)``, ``decode_block(prev_ids=)``;
+        # a test's stand-in says so itself)
         self._ahead: Optional[_StepInFlight] = None
         self._feeds_tokens = getattr(runner, "feeds_tokens", False)
+        # the step's two halves: the block pass's where the family's
+        # decode unit is a block, one token a row otherwise
+        self._halves = ((self._decode_dispatch, self._decode_land)
+                        if self.unit is None
+                        else (self._block_dispatch, self._block_land))
         # this pass dispatched a prefill chunk and did not wait for it
         self._chunk_unread = False
         self._build_instruments()
@@ -818,14 +827,17 @@ class Scheduler:
         )
         self._ahead_ctr = reg.counter(
             "dynamo_scheduler_decode_ahead_total",
-            "Decode steps dispatched before the step before them was "
-            "read, their continuing rows fed that step's tokens on the "
+            "Decode steps (block passes of a family whose decode unit "
+            "is a block) dispatched before the step before them was "
+            "read, their continuing rows fed that step's tokens (that "
+            "pass's block) on the "
             "device: over dynamo_scheduler_fetches_total{kind=\"decode\"} "
             "it is the share of steps the device did not wait for",
         )
         self._ahead_discarded_ctr = reg.counter(
             "dynamo_scheduler_decode_ahead_discarded_total",
-            "Rows of such steps whose token was dropped: the row had "
+            "Rows of such steps whose token (a block pass's row: its "
+            "ids) was dropped: the row had "
             "ended (a stop token or string, a cancel) at the step before, "
             "which the host read only after this one was dispatched",
         )
@@ -1912,8 +1924,6 @@ class Scheduler:
                     active = [er for er in active if er.finish is None]
                     if not active:
                         pass
-                    elif self.unit is not None:
-                        await self._decode_block(loop, active)
                     elif spec_now:
                         # speculative verify (ngram or draft-model
                         # proposals) on the host sync path
@@ -3998,7 +4008,15 @@ class Scheduler:
         token before its decode dispatch, as ever (``_prefill_chunk``);
         where the step in flight stands behind the chunk of the pass
         before, it is read before that wait (``behind_chunk``), so that
-        its tokens are not held for a chunk they did not wait for."""
+        its tokens are not held for a chunk they did not wait for.
+
+        A family whose decode unit is a block runs the same way through
+        the same slot (``_halves``: ``_block_dispatch`` / ``_block_land``
+        in place of ``_decode_dispatch`` / ``_decode_land``): pass k+1
+        goes out before pass k's ids are read, a row's block fed k's
+        ``new_ids`` on the device (``_block_next``), unless the rule
+        lets the confidences decide how many positions a pass unmasks
+        (``dynamic_unmask``)."""
         cfg = self.config
         # a K-step burst writes K tokens of KV per row before the host
         # sees any of them, so every row needs blocks for all K positions
@@ -4027,28 +4045,29 @@ class Scheduler:
             # than the amortization it saves at serving batch sizes.
             k_steps = 1
 
+        dispatch, land = self._halves
         hold = (self._ahead_block_reason(active, k_steps)
                 if self._feeds_tokens else None)
         prev, step = self._ahead, None
         if prev is not None and hold is None:
-            step = self._decode_dispatch(active, 1, prev)
+            step = dispatch(active, 1, prev)
         fell = "kv_oom" if step is False else hold
         if fell is not None:
             self._note_sync_fallback(fell)
         if prev is not None and fell is not None:
             # k−1 is read and applied before k is built
             self._ahead = None
-            await self._decode_land(loop, prev)
+            await land(loop, prev)
             prev = None
             active = [er for er in active if er.finish is None]
         if prev is None:
-            step = self._decode_dispatch(active, k_steps, None)
+            step = dispatch(active, k_steps, None)
         self._ahead = step
         if prev is not None:
-            await self._decode_land(loop, prev)   # sync(k−1), emit(k−1): under k
+            await land(loop, prev)   # sync(k−1), emit(k−1): under k
         if step is not None and (hold is not None or not self._feeds_tokens):
             self._ahead = None
-            await self._decode_land(loop, step)
+            await land(loop, step)
 
     def _ahead_block_reason(self, active: List[EngineRequest],
                             k_steps: int) -> Optional[str]:
@@ -4064,6 +4083,11 @@ class Scheduler:
         if k_steps > 1:
             # a fused burst feeds itself; the host reads K tokens a row
             return "multi_step"
+        if (self.unit is not None
+                and self.unit.strategy == "low_confidence_dynamic"):
+            # how many positions a pass unmasks depends on the
+            # confidences: the host cannot build the next pass unread
+            return "dynamic_unmask"
         return None
 
     def _ends_at_next(self, er: EngineRequest) -> bool:
@@ -4082,7 +4106,7 @@ class Scheduler:
         step, self._ahead = self._ahead, None
         if step is not None:
             self._note_sync_fallback(reason)
-            await self._decode_land(loop, step)
+            await self._halves[1](loop, step)
 
     def _decode_dispatch(self, active: List[EngineRequest], k_steps: int,
                          prev: Optional[_StepInFlight]):
@@ -4180,22 +4204,7 @@ class Scheduler:
             # asked for alternatives (ADVICE r2: fixed decode-path cost)
             want_top = any(er.logprobs_n > 0 for er in live)
 
-            # synchronous path: the device has been idle since the previous
-            # burst's tokens reached the host (t_ready) — that gap IS the
-            # bubble running ahead (or the chain) exists to close; a step
-            # dispatched behind one in flight left the device none
-            if prev is not None:
-                self._bubble_hist.observe(0.0)
-            elif self._last_burst_done_t is not None:
-                self._bubble_hist.observe(
-                    time.monotonic() - self._last_burst_done_t
-                )
-            self._last_burst_done_t = None
-
-            self.flight.record(
-                "scheduler.burst_dispatch", k_steps=k_steps, rows=len(rows),
-                requests=[er.request_id for er in live[:8]],
-            )
+            self._note_dispatch(live, k_steps, prev)
             read_bytes = (self.device_time.decode_read_bytes(
                 k_steps, sum(pos for _, pos, *_ in rows))
                 if self.device_time is not None else 0.0)
@@ -4239,13 +4248,39 @@ class Scheduler:
             self._count_decode_rows(
                 "decode_burst" if k_steps > 1 else "decode", len(rows),
                 k_steps)
-            self._inflight = True
-            if prev is not None:
-                self._ahead_ctr.inc()
-        # the copy to the host is asked for at the dispatch (_fetch says
-        # why), whichever pass waits for it
+        return self._in_flight(live, [next_tokens, lps, top_vals, top_ids],
+                               k_steps, t_dispatch, read_bytes, prev)
+
+    def _note_dispatch(self, live: List[EngineRequest], k_steps: int,
+                       prev: Optional[_StepInFlight]) -> None:
+        """A decode step's dispatch on the bubble histogram and the
+        flight record."""
+        # synchronous path: the device has been idle since the previous
+        # burst's tokens reached the host (t_ready) — that gap IS the
+        # bubble running ahead (or the chain) exists to close; a step
+        # dispatched behind one in flight left the device none
+        if prev is not None:
+            self._bubble_hist.observe(0.0)
+        elif self._last_burst_done_t is not None:
+            self._bubble_hist.observe(
+                time.monotonic() - self._last_burst_done_t
+            )
+        self._last_burst_done_t = None
+        self.flight.record(
+            "scheduler.burst_dispatch", k_steps=k_steps, rows=len(live),
+            requests=[er.request_id for er in live[:8]],
+        )
+
+    def _in_flight(self, live: List[EngineRequest], arrays: list,
+                   k_steps: int, t_dispatch: float, read_bytes: float,
+                   prev: Optional[_StepInFlight]) -> _StepInFlight:
+        """The step just dispatched as ``_decode`` keeps it. The copy to
+        the host is asked for here, at the dispatch (``_fetch`` says
+        why), whichever pass waits for it."""
+        self._inflight = True
+        if prev is not None:
+            self._ahead_ctr.inc()
         with span("sched.decode.request", step=self.passes):
-            arrays = [next_tokens, lps, top_vals, top_ids]
             return _StepInFlight(
                 rows=[(er, er.slot) for er in live], arrays=arrays,
                 k_steps=k_steps, t_dispatch=t_dispatch,
@@ -4367,43 +4402,94 @@ class Scheduler:
             er.unkept = er.block
             self._open_block(er, [])
 
-    async def _decode_block(self, loop, active: List[EngineRequest]) -> None:
-        """One block pass over every decoding row (``jit_decode_block``,
-        ``2L`` consecutive positions a row from its kept context's end).
-        Every row's pass is a denoise pass of its block in flight: it
-        unmasks the pass's quota of positions, and where that leaves no
-        mask the block is whole and its tokens leave (``_block_whole``).
+    def _block_next(self, er: EngineRequest, on: bool):
+        """What ``er``'s next block pass carries: (the kept context's
+        end, the ids it writes (``L`` or ``2L``), the masks in the block
+        it denoises, that block's pass number). ``on``: a pass of the row
+        is in flight and the row is taken as that pass leaves it, which
+        the host knows without reading it under the rules that unmask
+        the quota it gave and no more (``_ahead_block_reason``): the
+        block it denoised is ``FED`` (the program reads it off that
+        pass's ``new_ids``), to be denoised again where masks are left,
+        else whole, carried as ``unkept`` is with the next block's masks
+        behind it. None where the host knows that pass to be the row's
+        last: its block comes out whole and ``_block_whole`` ends the row
+        there by the count or by the model's length (a stop token or
+        string it cannot know)."""
+        unit, length = self.unit, self.unit.length
+        masked = er.block.count(unit.mask_id)
+        if not on:
+            return (er.context_len, er.unkept + er.block, masked,
+                    er.block_pass)
+        n = er.context_len + len(er.unkept)
+        left = masked - self._block_quota(masked, er.block_pass)
+        if left:
+            return n, [FED] * length, left, er.block_pass + 1
+        if (er.generated + length - er.block_first >= er.fin_max_new
+                or n + 2 * length > self.config.max_model_len):
+            return None
+        return n, [FED] * length + [unit.mask_id] * length, length, 0
+
+    def _block_quota(self, masked: int, block_pass: int) -> int:
+        """Positions pass ``block_pass`` of a block with ``masked`` masks
+        unmasks at least; a pass past the schedule's end (the dynamic
+        rule never needs one) takes what is left."""
+        quotas = self._quotas
+        return min(masked, quotas[block_pass]
+                   if block_pass < len(quotas) else masked)
+
+    def _block_dispatch(self, active: List[EngineRequest], k_steps: int,
+                        prev: Optional[_StepInFlight]):
+        """Build and dispatch one block pass over every decoding row
+        (``jit_decode_block``, ``2L`` consecutive positions a row from
+        its kept context's end), the block family's ``_decode_dispatch``
+        and with its returns. Every row's pass is a denoise pass of its
+        block in flight: it unmasks the pass's quota of positions, and
+        where that leaves no mask the block is whole and its tokens
+        leave (``_block_whole``).
         A row that holds a whole block not yet kept carries it in the
         first half of its positions and the block in flight in the
         second, all with slots: the pass writes the whole block's final
-        keys and values, and only when it returns does the block pass
+        keys and values, and only when it is read does the block pass
         ``_commit_kv`` (kept; a page it completes is registered). Any
         other row (a request's first block, a later pass of a block) has
         its block in the first half and a dead second half: no slot, so
         no write and no expert row, and nothing read of it. Rows of one
         pass are at different phases. Pages are taken for what the pass
         writes; a block never straddles a page, two blocks may lie on
-        two."""
+        two. ``prev``: the pass in flight this one is built ahead of; a
+        row of it is built as ``_block_next`` says."""
         cfg, unit = self.config, self.unit
         b, bs, length = cfg.max_batch_size, cfg.kv_block_size, unit.length
-        quotas = unit.quotas()
 
+        inflight = {id(er) for er, _ in prev.rows} if prev is not None else ()
+        nexts = []         # (row, what _block_next says of it)
+        for er in active:
+            nxt = self._block_next(er, id(er) in inflight)
+            if nxt is not None:        # else the pass in flight ends it
+                nexts.append((er, *nxt))
         with span("sched.decode.build", step=self.passes, rows=len(active),
-                  denoise_rows=len(active), commit_rows=0,
-                  fold_rows=sum(bool(er.unkept) for er in active)):
-            for er in list(active):
-                if not self._ensure_block_for(
-                        er, er.context_len + len(er.unkept) + length - 1):
+                  denoise_rows=len(nexts), commit_rows=0,
+                  fold_rows=sum(len(ids) > length for _, _, ids, *_ in nexts)):
+            rows = []
+            for row in nexts:
+                er, n, ids, *_ = row
+                if self._ensure_block_for(er, n + len(ids) - 1):
+                    rows.append(row)
+                elif prev is not None:
+                    self.allocator.flush_offload()
+                    return False
+                else:
                     # out of memory: back to waiting, between blocks (the
                     # block in flight is dropped, none of it was emitted;
                     # a whole block not yet kept goes with what was sent)
                     logger.warning("KV OOM: preempting %s", er.request_id)
                     self._preempt(er)
-                    active.remove(er)
             self.allocator.flush_offload()
-            if not active:
-                return
-            w = self._table_width(active, (self.runner, "decode_block"))
+            if not rows:
+                return None
+            live = [er for er, *_ in rows]
+            w = self._table_width(live, (self.runner, "decode_block"))
 
             hs = self._host
             tokens = np.zeros((b, 2 * length), np.int32)
@@ -4413,59 +4499,67 @@ class Scheduler:
             quota = np.zeros(b, np.int32)
             passes = np.zeros(b, np.int32)
             offs = np.arange(2 * length)
-            for er in active:
-                i, n = er.slot, er.context_len
+            for er, n, ids, masked, block_pass in rows:
+                i = er.slot
                 hs.sync_blocks(er)
-                ids = er.unkept + er.block      # what the pass writes
-                tokens[i, :len(ids)] = ids
+                tokens[i, :len(ids)] = ids     # what the pass writes
                 positions[i] = n + offs
                 for at in range(0, len(ids), length):
                     p = n + at
                     slot_map[i, at:at + length] = (
                         er.block_ids[p // bs] * bs + p % bs + offs[:length])
                 ctx_lens[i] = n + len(ids)
-                masked = er.block.count(unit.mask_id)
-                # a pass past the schedule's end (the dynamic rule never
-                # needs one) takes what is left
-                quota[i] = min(masked, quotas[er.block_pass]
-                               if er.block_pass < len(quotas) else masked)
-                passes[i] = er.block_pass
+                quota[i] = self._block_quota(masked, block_pass)
+                passes[i] = block_pass
             btab = hs.btab[:, :w].copy()
-            want_top = any(er.logprobs_n > 0 for er in active)
-            if self._last_burst_done_t is not None:
-                self._bubble_hist.observe(
-                    time.monotonic() - self._last_burst_done_t)
-                self._last_burst_done_t = None
-            self.flight.record(
-                "scheduler.burst_dispatch", k_steps=1, rows=len(active),
-                requests=[er.request_id for er in active[:8]],
-            )
+            want_top = any(er.logprobs_n > 0 for er in live)
+            self._note_dispatch(live, 1, prev)
+            read_bytes = (self.device_time.decode_read_bytes(
+                1, sum(n for _, n, *_ in rows))
+                if self.device_time is not None else 0.0)
         with span("sched.decode.dispatch", step=self.passes,
-                  rows=len(active)):
+                  rows=len(rows), ahead=int(prev is not None)):
             t_dispatch = time.monotonic()
             outs = self.runner.decode_block(
                 tokens, positions, btab, slot_map, ctx_lens, quota,
                 hs.temp, hs.top_k, hs.top_p, min_p=hs.min_p,
                 seed_keys=hs.keys, counters=passes, want_top=want_top,
+                **({} if prev is None else {"prev_ids": prev.arrays[0]}),
             )
-            self._count_decode_rows("decode_block", len(active))
-            self._block_row_passes.inc(len(active), kind="denoise")
-            self._inflight = True
+            self._count_decode_rows("decode_block", len(rows))
+        # new_ids, lps, top_vals, top_ids, left
+        return self._in_flight(live, list(outs), 1, t_dispatch, read_bytes,
+                               prev)
 
+    async def _block_land(self, loop, step: _StepInFlight) -> None:
+        """The host's half of a block pass, the block family's
+        ``_decode_land``: wait for the pass's ids and walk the rows. A
+        row's state is the one its pass was built on (the pass before it
+        was applied first), so what it held as ``unkept`` is what the
+        pass wrote for good. A row that has finished since the dispatch
+        has its row of the pass dropped, as ``_decode_land`` says:
+        nothing of it is kept, emitted or counted, and what it wrote
+        lies past everything committed, in pages the row owned at the
+        dispatch."""
+        unit, length = self.unit, self.unit.length
         (new_ids, lpn, tv, ti, left), t_ready = await self._fetch(
-            loop, "decode", list(outs), chaos="decode_burst_hang")
-        with span("sched.decode.emit", step=self.passes, rows=len(active)):
+            loop, "decode", step.arrays, chaos="decode_burst_hang",
+            prefetched=step.prefetched)
+        with span("sched.decode.emit", step=self.passes,
+                  rows=len(step.rows)):
             self._last_burst_done_t = t_ready
+            rows = [(er, i) for er, i in step.rows if er.finish is None]
+            if step.ahead and len(rows) < len(step.rows):
+                self._ahead_discarded_ctr.inc(len(step.rows) - len(rows))
             if self.device_time is not None:
                 self.device_time.observe(
-                    "decode_block", "decode", t_dispatch, t_ready,
-                    read_bytes=self.device_time.decode_read_bytes(
-                        1, sum(er.context_len for er in active)),
-                    tokens=length * sum(not left[er.slot] for er in active),
+                    "decode_block", "decode", step.t_dispatch, t_ready,
+                    read_bytes=step.read_bytes,
+                    tokens=length * sum(not left[i] for _, i in rows),
                 )
             self.steps += 1
-            for er in active:
-                i = er.slot
+            self._block_row_passes.inc(len(rows), kind="denoise")
+            for er, i in rows:
                 if er.unkept:
                     # the pass wrote the whole block's final keys and
                     # values: kept, here and only here

@@ -14,7 +14,10 @@ prints one JSON object, over the window:
   (``dynamo_scheduler_decode_ahead_discarded_total``), and
   ``discarded_share`` of the rows dispatched that held a sequence (the
   tokens emitted past a request's first, by the inter-token histogram's
-  count, plus the dropped ones);
+  count, plus the dropped ones; for a family whose decode unit is a
+  block, whose pass is ``jit_decode_block`` and runs ahead as the step
+  does, the rows its passes landed,
+  ``dynamo_scheduler_block_row_passes_total``, plus the dropped ones);
 - ``fallbacks``: ``dynamo_engine_sync_fallback_total`` by reason, and
   ``unnamed``: fetches that neither went ahead nor fell back under a
   reason (the first step after the device ran out of rows).
@@ -43,7 +46,10 @@ def shares(start_text: str, end_text: str) -> dict:
     fetches = moved("dynamo_scheduler_fetches_total", {"kind": "decode"})
     ahead = moved("dynamo_scheduler_decode_ahead_total")
     discarded = moved("dynamo_scheduler_decode_ahead_discarded_total")
-    tokens = moved("dynamo_scheduler_inter_token_latency_seconds_count")
+    # rows that held a sequence and were applied: one a token, or one a
+    # row pass of a block family (a chunk of k tokens is k gaps there)
+    rows = (moved("dynamo_scheduler_block_row_passes_total")
+            or moved("dynamo_scheduler_inter_token_latency_seconds_count"))
     name = "dynamo_engine_sync_fallback_total"
     reasons = {dict(lab).get("reason", ""): v - start.get((name, lab), 0.0)
                for lab, v in prom.rows(end, name).items()}
@@ -52,8 +58,8 @@ def shares(start_text: str, end_text: str) -> dict:
         "decode_fetches": fetches, "ahead": ahead,
         "ahead_share": ahead / fetches if fetches else None,
         "discarded": discarded,
-        "discarded_share": (discarded / (tokens + discarded)
-                            if tokens + discarded else None),
+        "discarded_share": (discarded / (rows + discarded)
+                            if rows + discarded else None),
         "fallbacks": reasons,
         "unnamed": fetches - ahead - sum(reasons.values()),
         "preemptions": moved("dynamo_scheduler_preemptions_total"),

@@ -355,16 +355,20 @@ def _request(prompt, max_tokens, logprobs=0, sampling=None, **stops):
     )
 
 
-def _drive(runner, config, requests, hook=None, staggered=False):
+def _drive(runner, config, requests, hook=None, staggered=False,
+           leaves=None):
     """(scheduler, [(tokens, log-probabilities, tokens a chunk, finish
     reason)] a request, {request id: the pass that unmasked each emitted
-    token})."""
+    token}). ``leaves``: {request's index: its client leaves once it has
+    that many tokens}."""
     async def go():
         flight = FlightRecorder(capacity=4096)
         sched = Scheduler(runner, config, flight=flight)
         if hook is not None:
             hook(sched)
         sched.start()
+
+        limit = {id(requests[i]): n for i, n in (leaves or {}).items()}
 
         async def collect(er):
             toks, lps, chunks, finish = [], [], [], None
@@ -377,6 +381,8 @@ def _drive(runner, config, requests, hook=None, staggered=False):
                     chunks.append(len(out.token_ids))
                 lps.extend(lp.logprob for lp in out.logprobs or [])
                 finish = out.finish_reason or finish
+                if len(toks) >= limit.get(id(er), 1e9):
+                    er.ctx.stop_generating()
         try:
             got = []
             if staggered:
@@ -671,6 +677,151 @@ def test_sampled_rows_draw_a_key_a_position_and_a_pass():
     assert all(0 <= t < 256 and t != MASK for g in got for t in g[0])
 
 
+# ---------- the block pass one pass ahead of the host (ISSUE 60) ----------
+
+def _held(sched):
+    """Every pass is landed before the next is built, under a reason of
+    the test's: the program has no switch."""
+    sched._ahead_block_reason = lambda active, k_steps: "test"
+
+
+def _total(counter, **labels):
+    return sum(v for k, v in counter.values.items()
+               if labels.items() <= dict(k).items())
+
+
+def _reasons(sched):
+    return {dict(k)["reason"] for k in sched._sync_fallback_ctr.values}
+
+
+def _ahead_traffic(stop_on=None):
+    """Six requests on four slots, so that a slot is taken again with a
+    pass in flight: prompts' tails of 1, 2, 3, 0, 2 and 2 tokens, answers
+    that end inside a block and on its edge, greedy and seeded rows, two
+    top alternatives a token. ``stop_on``: {request: a hidden stop id}."""
+    prompts = _prompts((37, 50, 3, 64, 18, 26), seed=4)
+    tokens = (13, 24, 17, 30, 11, 21)
+    sampling = (None, dict(temperature=0.8, seed=7), None,
+                dict(temperature=0.7, seed=3, top_k=40), None, None)
+    requests = []
+    for i, (p, n, sm) in enumerate(zip(prompts, tokens, sampling)):
+        stops = ({"stop_token_ids_hidden": [stop_on[i]]}
+                 if i in (stop_on or {}) else {})
+        requests.append(_request(p, n, logprobs=2, sampling=sm, **stops))
+    return requests
+
+
+def _usage(requests, got):
+    """What a response's ``usage`` is counted from, a request."""
+    return [(len(er.prompt), er.generated, er.decode_tokens, len(toks),
+             er.cached_tokens) for er, (toks, *_) in zip(requests, got)]
+
+
+def _both(runner, config, make, **kw):
+    """``make()``'s requests with every pass landed before the next is
+    built, then with the pass ahead: the two runs' (scheduler, streams,
+    usage), the streams' finish reasons as their values."""
+    out = []
+    for hook in (_held, None):
+        requests = make()
+        sched, got, _ = _drive(runner, config, requests, hook=hook, **kw)
+        got = [(t, l, c, getattr(f, "value", f)) for t, l, c, f in got]
+        out.append((sched, got, _usage(requests, got)))
+        assert sched.allocator.used == 0 and sched._ahead is None
+    return out
+
+
+@pytest.mark.parametrize("case", ["max_tokens", "stop", "tail", "cancel",
+                                  "kv_oom"])
+@pytest.mark.parametrize("strategy", ["sequential", "low_confidence_static"])
+def test_the_pass_ahead_streams_what_the_landed_passes_stream(strategy, case):
+    """Pass k+1 goes out before pass k is read, a row's block fed k's
+    ``new_ids`` on the device: tokens, log-probabilities, the tokens a
+    chunk, finish reasons and what ``usage`` is counted from are those
+    of the same run with every pass landed first, bit for bit, under
+    both rules whose pass unmasks the quota the host gave. The cases:
+    answers that end by ``max_tokens`` inside a block (a row the host
+    knows to end is left out of the next pass, nothing is dropped); a
+    stop id inside a block (the row's next pass was dispatched and is
+    dropped, never counted); first blocks that open with a prompt's tail
+    while a pass is in flight; a client that leaves mid-stream; and a
+    pool too small, where the pass that cannot have a page reads the
+    pass in flight first (``kv_oom``) and preempts from committed
+    state."""
+    runner = _runner(2, strategy)
+    config, make, kw = runner.config, _ahead_traffic, {}
+    if case == "stop":
+        # a stop id a stream: one it gives once, inside a block
+        (_, free, _), _ = _both(runner, config, make)
+        stop_on = {}
+        for i, tail in ((0, 1), (2, 3), (4, 2)):
+            toks = free[i][0]
+            stop_on[i] = next(
+                toks[j] for j in range(2, len(toks))
+                if toks[j] not in toks[:j] and (tail + j) % 4 != 3)
+        make = lambda: _ahead_traffic(stop_on)        # noqa: E731
+    elif case == "tail":
+        make = lambda: [_request(p, 19, logprobs=2) for p in _prompts(  # noqa: E731
+            (5, 33, 18, 7, 35, 50, 3), seed=6)]
+    elif case == "cancel":
+        kw = {"leaves": {1: 8, 3: 4}}
+    elif case == "kv_oom":
+        config = dataclasses.replace(config, num_kv_blocks=14)
+        make = lambda: [_request(p, 60, logprobs=2) for p in _prompts(  # noqa: E731
+            (37, 50, 3, 64))]
+
+    (held, want, want_usage), (sched, got, usage) = _both(
+        runner, config, make, **kw)
+    gone = sorted(kw.get("leaves", ()))
+    for i, (w, g) in enumerate(zip(want, got)):
+        if i in gone:
+            # where a client's leaving is seen depends on the host's pace
+            assert g[3] == w[3] == "cancelled"
+            short, long = sorted((w[0], g[0]), key=len)
+            assert long[:len(short)] == short and len(short) >= kw["leaves"][i]
+        else:
+            assert g == w, i
+            assert usage[i] == want_usage[i], i
+    passes = _total(sched._fetches_ctr, kind="decode")
+    assert _total(held._ahead_ctr) == 0 and _reasons(held) == {"test"}
+    assert _total(sched._ahead_ctr) >= 0.8 * passes > 10
+    # the counters of a block family: a dropped row counts for nothing
+    for name in ("_blocks_completed", "_block_tokens", "_block_keeps_folded",
+                 "_block_row_passes"):
+        if not gone:
+            assert _total(getattr(sched, name)) == _total(getattr(held, name))
+    dropped = _total(sched._ahead_discarded_ctr)
+    if case in ("max_tokens", "tail"):
+        assert [f for *_, f in got] == ["length"] * len(got)
+        assert dropped == 0 and not _reasons(sched)
+    elif case == "stop":
+        for i, token in stop_on.items():
+            assert got[i][3] == "stop" and got[i][0][-1] == token
+            assert len(got[i][0]) < len(free[i][0])
+        assert 1 <= dropped <= 3 and not _reasons(sched)
+    elif case == "cancel":
+        assert dropped >= 1
+    else:
+        assert _total(held._preemptions) > 0, "vacuous: nothing was preempted"
+        assert "kv_oom" in _reasons(sched)
+        assert _total(sched._preemptions) > 0
+
+
+def test_the_dynamic_rule_reads_every_pass_before_the_next():
+    """Under ``low_confidence_dynamic`` the count a pass unmasks depends
+    on the confidences, which the host has not read: no pass goes ahead,
+    every pass says ``dynamic_unmask``, and the streams are the plain
+    loop's as ever."""
+    runner = _runner(2, "low_confidence_dynamic")
+    requests = _ahead_traffic()
+    sched, got, _ = _drive(runner, runner.config, requests)
+    assert [len(t) for t, *_ in got] == [13, 24, 17, 30, 11, 21]
+    assert _total(sched._ahead_ctr) == 0 and sched._ahead is None
+    assert _reasons(sched) == {"dynamic_unmask"}
+    assert (_total(sched._sync_fallback_ctr, reason="dynamic_unmask")
+            == _total(sched._fetches_ctr, kind="decode") > 10)
+
+
 # ---------- (g) what assumes one token a row a pass is refused ----------
 
 @pytest.mark.parametrize("setting,path", [
@@ -799,7 +950,8 @@ def test_scopes_in_the_lowered_block_pass():
                            top_k=np.zeros(b), temperature=np.zeros(b),
                            top_p=np.ones(b))
     text = runner._decode_block.lower(
-        runner.params, *runner.kv_cache, buf, runner.moe_counts).as_text(
+        runner.params, *runner.kv_cache, buf, zb[:, :4],
+        runner.moe_counts).as_text(
         debug_info=True)
     assert "jit_decode_block" in text or "decode_block" in text
     for scope in ("attn/block_attn", "mlp", "moe_route", "moe_experts",

@@ -253,3 +253,36 @@ def test_warm_up_leaves_no_compile_for_the_step_fed_from_the_device(tp):
     assert 'phase="late"' not in "".join(
         ln for ln in text.splitlines()
         if ln.startswith("dynamo_engine_xla_compiles_total"))
+
+
+def test_warm_up_leaves_no_compile_for_the_block_pass_fed_from_the_device():
+    """``jit_decode_block`` takes the pass before's ``new_ids`` as one
+    more argument, its own output's sharding (ISSUE 60): fed a pass's
+    output or the zeros that stand in where no pass ran before, it is
+    one executable a table width. After warm-up a served run of a block
+    family, whose passes are nearly all fed from the device, compiles
+    no block pass: no program in the jitted pass's cache that warm-up
+    did not leave there, no first dispatch of ``decode_block``, no
+    compile that jax reports outside a first dispatch."""
+    import test_block_decode as t
+
+    runner = ModelRunner(t._engine_config(prefill_buckets=[32]))
+    runner.warmup()
+    programs = runner._decode_block._cache_size()
+    dispatched = len(runner.compiles.records)
+    assert programs == len(runner.warmed_widths["decode_block"]["widths"])
+
+    def untracked():
+        return sum(v for k, v in runner.compiles._parts.values.items()
+                   if dict(k)["phase"] == "late"
+                   and dict(k)["part"] in ("trace", "lower", "compile"))
+
+    prompts = _prompts(255, [12, 7, 21])
+    got, sched = _serve(runner, [_request(p, 16) for p in prompts],
+                        fall_back=False)
+    assert [len(t) for t, *_ in got] == [16] * 3
+    assert _total(sched._ahead_ctr) >= 6
+    assert runner.compiles.late_compiles == 0
+    assert len(runner.compiles.records) == dispatched
+    assert runner._decode_block._cache_size() == programs
+    assert untracked() == 0

@@ -182,7 +182,10 @@ async def test_kv_router_end_to_end_over_hub(tmp_path):
     # worker death → index purged via aggregator on_remove
     await s2.stop()
     hub.expire_lease((await w2_drt.discovery.primary_lease()).id)
-    await asyncio.sleep(0.1)
+    for _ in range(50):     # the purge crosses two tasks: poll, up to 5 s
+        await asyncio.sleep(0.1)
+        if "w-two" not in router.indexer.find_matches(hashes).scores:
+            break
     assert "w-two" not in router.indexer.find_matches(hashes).scores
 
     await router.stop()
