@@ -36,8 +36,8 @@ from .device import check_serving_device
 from . import step_inputs
 from .sampling import (SamplingParams, block_select, over_all_rows,
                        over_live_rows, sample, sample_block_positions,
-                       tile_rows, top_k_width, top_logprobs_for,
-                       walks_live_rows)
+                       short_search, tile_rows, top_k_width,
+                       top_logprobs_for, walks_live_rows)
 
 logger = logging.getLogger(__name__)
 
@@ -96,6 +96,17 @@ class _DeviceFedCounter(Counter):
 # tiles of rows, else 0 (``_sample_and_logprobs`` sets it,
 # ``ModelRunner._track`` clears it before a dispatch and reads it after)
 _tiles_traced = 0
+# whether the program being traced may find its cutoffs by the short search
+# (``sampling.short_search`` of its head's logits; set and read likewise)
+_short_traced = False
+
+
+def _record_search(logits, mesh=None) -> None:
+    """Stamp how the program being traced, whose head made ``logits``,
+    may search for its cutoffs. Trace-time, like
+    ops/attention.record_row_list."""
+    global _short_traced
+    _short_traced = short_search(logits.dtype, mesh)
 
 
 def _sample_and_logprobs(cfg, mesh, last_logits, samp, counts, seen, bias,
@@ -109,6 +120,7 @@ def _sample_and_logprobs(cfg, mesh, last_logits, samp, counts, seen, bias,
     are a quarter of a second of every one's warm start); elsewhere its
     operations lie in the program as they always did."""
     global _tiles_traced
+    _record_search(last_logits, mesh)
     tile = tile_rows(last_logits, mesh, live)
     args = (mesh, top_k_width(cfg.vocab_size), tile, last_logits, samp,
             counts, seen, bias, sample_slots, commit, want_top, extra_bias,
@@ -473,6 +485,10 @@ class ModelRunner:
         # and those whose trace walks the sampling tail in tiles of rows,
         # with the rows a tile: the scheduler counts whole tiles for them
         self.sampling_tile_programs: dict = {}
+        # and those whose head hands the tail bfloat16 logits on one
+        # device: a tile of untouched rows finds its cutoffs in half the
+        # passes (``sampling.short_search``), which the scheduler counts
+        self.sampling_short_programs: set = set()
         # the decode-shaped programs whose trace did work in proportion
         # to the block table's width (ops/attention.record_table_width):
         # these alone exist at the narrower rungs of
@@ -682,11 +698,11 @@ class ModelRunner:
         (ops/attention.record_row_list), whether anything read the block
         table at its width (ops/attention.record_table_width; filed
         under ``shape`` where one tracked program has several shapes of
-        table), and the tile its sampling tail walks
-        (``_sample_and_logprobs``)."""
-        global _tiles_traced
+        table), the tile its sampling tail walks and whether its search
+        may be the short one (``_sample_and_logprobs``)."""
+        global _tiles_traced, _short_traced
         with self.compiles.track(program, key, **stats) as first:
-            _tiles_traced = 0
+            _tiles_traced, _short_traced = 0, False
             yield first
             if first and row_list_traced():
                 self.row_list_programs.add(program)
@@ -694,6 +710,8 @@ class ModelRunner:
                 self.width_programs.add(shape or program)
             if first and _tiles_traced:
                 self.sampling_tile_programs[program] = _tiles_traced
+            if first and _short_traced:
+                self.sampling_short_programs.add(program)
 
     def _make_forward(self, counted: bool = False):
         """(trunk, head) closures both compiled programs trace: the trunk
@@ -937,6 +955,7 @@ class ModelRunner:
 
             ids, positions = denoised(inp.tokens), denoised(inp.positions)
             logits = head(denoised(hidden).reshape(rows * blen, -1), params)
+            _record_search(logits)
             x0, lps, top_vals, top_ids = sample_block_positions(
                 cfg, logits, inp.samp, positions, inp.want_top,
                 unit.mask_id)

@@ -28,7 +28,10 @@ value a row and no sort (nor argsort, [B, V] gather or scatter): the
 cutoff is searched for, a few bits of its float32 image a pass, each pass
 a count or a masked sum along the unsorted row, so the cost is a function
 of B · V alone (1.07 ms at [64, 163840] on a v5e where the sort it
-replaced was 6.6 ms and the filter around it 7.4; PERF.md §6, PR 29).
+replaced was 6.6 ms and the filter around it 7.4; PERF.md §6, PR 29) and
+of the bits two entries of a row can differ in: sixteen where the rows
+are the head's bfloat16 logits with nothing added, and then half the
+passes (``_largest_threshold``; PERF.md §6, PR 61).
 Entries exactly equal to the cutoff are all kept, by top-p as by top-k.
 """
 
@@ -122,21 +125,62 @@ _SIGN = np.uint32(0x80000000)
 _KEY_NEG_INF = np.uint32(0x007FFFFF)
 
 # bits of the cutoff's image settled by one pass over the row: a pass
-# tests 2**bits - 1 thresholds, so a search takes 32 / bits passes. Timed
-# alone on a v5e at [64, 163840], top-p rows (PERF.md §6, PR 29): 1 bit
-# 2.09 ms (a pass streams the row at 590 GB/s), 2 bits 1.19, 4 bits 1.07
-# (fifteen thresholds a pass are bound by the vector unit, no longer by
-# memory); with top-k rows too 3.91 / 2.12 / 1.77
+# tests 2**bits - 1 thresholds, so a search takes 32 / bits passes, 16 /
+# bits where sixteen bits tell the row's entries apart. Timed alone on a
+# v5e at [64, 163840], top-p rows (PERF.md §6, PR 29): 1 bit 2.09 ms (a
+# pass streams the row at 590 GB/s), 2 bits 1.19, 4 bits 1.07 (fifteen
+# thresholds a pass are bound by the vector unit, no longer by memory);
+# with top-k rows too 3.91 / 2.12 / 1.77. Timed again for the short search
+# (scripts/pad_row_cost.py --tail --search-bits 4,2; PERF.md §5 "Since
+# PR 61"): the served tail's tile of 16 untouched rows takes 0.88 / 0.50 /
+# 0.43 ms at V = 261 120 / 163 840 / 131 072 with 4 bits a pass and 0.91 /
+# 0.53 / 0.46 with 2, a tile that holds a touched row 1.05 / 0.61 / 0.52
+# and 1.12 / 0.66 / 0.57, where the parent's tile takes 1.00 / 0.60 / 0.50
+# (at 2 bits a request with a penalty would pay for the others' gain; that
+# call looked at the rows' bits, 0.05 ms a tile at 261 120: with the look
+# at the bias rows instead, ``_adds_nothing``, 4 bits read 0.83 / 0.50 /
+# 0.43 and 1.00 / 0.60 / 0.51); the block pass over [128, 151 936] alone
+# goes the other way, 1.80 with 4 bits and 1.21 with 2 (a pass of fifteen
+# thresholds costs an entry 16 ps there and 10 in a tile of [16, 261 120];
+# PERF.md §7 "Left by PR 61" (1)). One constant: 4 stays
 _SEARCH_BITS = 4
 
 
-def _key_value(key: jax.Array) -> jax.Array:
+def _key_value(key: jax.Array, low=None) -> jax.Array:
     """The float32 whose image is ``key``. Keys under -inf's read -inf, so
     a predicate "x >= value" stays monotone over all 2**32 keys; keys over
-    +inf's read NaN, which no entry reaches."""
+    +inf's read NaN, which no entry reaches. ``low`` (a uint32 mask of low
+    bits) is set in a negative value's key first: its image is the
+    complement of its bits, so that is the key of the value whose bits
+    under the mask are zero."""
+    if low is not None:
+        key = jnp.where(key >= _SIGN, key, key | low)
     bits = jnp.where(key >= _SIGN, key ^ _SIGN, ~key)
     value = jax.lax.bitcast_convert_type(bits, jnp.float32)
     return jnp.where(key <= _KEY_NEG_INF, -jnp.inf, value)
+
+
+# a bfloat16 widened to float32 has these bits zero
+_BELOW_BFLOAT16 = np.uint32(0xFFFF)
+
+
+def _where(known, a, b):
+    """``a`` where ``known`` else ``b``, for a bool that the trace knows or
+    a scalar that only the device does."""
+    if isinstance(known, (bool, np.bool_)):
+        return a if known else b
+    return jnp.where(known, a, b)
+
+
+def short_search(head_dtype, mesh: Optional[Mesh] = None) -> bool:
+    """Whether ``sample`` may find the cutoffs of logits that the head made
+    in ``head_dtype`` by the short search (``_largest_threshold``): the
+    head's values are bfloat16. Where a bias or penalty rows go with them
+    the rows at hand are looked at on the device, which takes one device:
+    several would have to agree on the loop's trip count, an all-reduce a
+    step for four passes of their sixteen rows."""
+    return (jnp.dtype(head_dtype) == jnp.bfloat16
+            and (mesh is None or mesh.size == 1))
 
 
 def _pin(x: jax.Array, mesh: Mesh, *spec) -> jax.Array:
@@ -155,7 +199,8 @@ def _whole_rows(logits: jax.Array, mesh: Optional[Mesh]) -> bool:
     return devices > 1 and logits.shape[0] % devices == 0
 
 
-def _largest_threshold(holds, rows: tuple) -> jax.Array:
+def _largest_threshold(holds, rows: tuple, short=False,
+                       divisor: Optional[jax.Array] = None) -> jax.Array:
     """The largest float32 ``t`` a row for which ``holds(t)`` is true, an
     array of shape ``rows``.
 
@@ -163,12 +208,29 @@ def _largest_threshold(holds, rows: tuple) -> jax.Array:
     returns bools of that shape; it has to be monotone: true
     up to some value, false above it. The image of ``t`` is built from its
     top bit down, ``_SEARCH_BITS`` at a time: the next digit is the number
-    of candidates that hold."""
+    of candidates that hold.
+
+    ``short`` (a bool, the trace's or a scalar of the device's) says that
+    ``holds`` changes only at values ``b / divisor`` [*rows], ``b`` a
+    bfloat16: then the candidates are those quotients, ``b`` built from the
+    sixteen bits of its image that can differ, in half the passes, and
+    the answer is such a quotient. The loop is one either way, its trip
+    count read on the device where ``short`` is."""
     digits = jnp.arange(1, 1 << _SEARCH_BITS, dtype=jnp.uint32)
+    passes = 32 // _SEARCH_BITS
+    low = (None if short is False
+           else _where(short, _BELOW_BFLOAT16, np.uint32(0)))
+
+    def value(key):
+        t = _key_value(key, low)
+        if short is False:
+            return t
+        by = divisor.reshape(rows + (1,) * (t.ndim - len(rows)))
+        return _where(short, t / by, t)
 
     def settle(i, key):
-        shift = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
-        ok = holds(_key_value(key[..., None] | (digits << shift)))
+        shift = jnp.asarray(32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
+        ok = holds(value(key[..., None] | (digits << shift)))
         return key | (ok.sum(-1).astype(jnp.uint32) << shift)
 
     # a loop and not its 32 / bits copies: the loop's operand is the row as
@@ -176,8 +238,9 @@ def _largest_threshold(holds, rows: tuple) -> jax.Array:
     # penalties, temperature) into its fusion and read their inputs too;
     # unrolled it is 0.05 ms faster at [64, 163840], alone
     key = jax.lax.fori_loop(
-        0, 32 // _SEARCH_BITS, settle, jnp.zeros(rows, jnp.uint32))
-    return _key_value(key)
+        0, _where(short, passes // 2, passes), settle,
+        jnp.zeros(rows, jnp.uint32))
+    return value(key)
 
 
 def _each(reduce, thresholds: jax.Array) -> jax.Array:
@@ -208,6 +271,8 @@ def filter_logits(
     top_k: jax.Array,   # [...] i32; 0 → disabled
     top_p: jax.Array,   # [...] f32; 1.0 → disabled
     min_p: jax.Array,   # [...] f32; 0.0 → disabled
+    bfloat16_over=None,  # [...] f32: see "the short search"
+    short=False,
 ) -> jax.Array:
     """top-k → min-p → top-p: ``scaled`` with every dropped entry at -inf.
 
@@ -236,17 +301,35 @@ def filter_logits(
     sum, took 7.41 (1.77 with top-k rows too), under 0.2 against 0.74 at
     ``[32, 32064]`` and under 0.2 against 0.30 at ``[16, 32768]``
     (PERF.md §6, PR 29).
+
+    The short search. A pass at fifteen thresholds is bound by the vector
+    unit, so a search costs its passes, and those follow the bits in which
+    two entries of a row can differ. ``short`` (a bool, the trace's or the
+    device's) says that every entry of ``scaled`` is a bfloat16 value over
+    its row's ``bfloat16_over`` (``sample``: the head's logits untouched,
+    over the temperature): both searches then try only such quotients,
+    which sixteen bits tell apart, in four passes where a float32 row
+    takes eight. The kept set is the same. Both predicates change only at
+    an entry's value, so the full search's answer is the smallest kept
+    entry ``s``, itself such a quotient, which the short one tries; and
+    dividing by a positive number is monotone, so the largest candidate
+    that holds divides to ``s`` again. Two distinct logits whose
+    quotients round to one float32 are one candidate here as they are
+    one value there: both stay or both go, as the order of the filters
+    and the ties are decided on ``scaled`` in either search.
     """
     v = scaled.shape[-1]
     row_max = scaled.max(axis=-1)
+
+    def largest_threshold(holds):
+        return _largest_threshold(holds, row_max.shape, short, bfloat16_over)
 
     def kth_largest():
         def count(t):
             return (scaled >= t[..., None]).sum(-1, dtype=jnp.int32)
 
         k = jnp.clip(top_k, 1, v)[..., None]
-        t = _largest_threshold(
-            lambda ts: _each(count, ts) >= k, row_max.shape)
+        t = largest_threshold(lambda ts: _each(count, ts) >= k)
         return jnp.where(top_k > 0, t, -jnp.inf)
 
     floor = jax.lax.cond(
@@ -263,23 +346,54 @@ def filter_logits(
             jnp.maximum(ts, floor[..., None]))
 
     need = top_p[..., None] * alive_mass(floor[..., None])
-    nucleus = _largest_threshold(
-        lambda ts: alive_mass(ts) >= need, row_max.shape)
+    nucleus = largest_threshold(lambda ts: alive_mass(ts) >= need)
     cutoff = jnp.where(top_p >= 1.0, floor, jnp.maximum(nucleus, floor))
     # the top token stays whatever top_p says
     cutoff = jnp.where(cutoff <= row_max, cutoff, row_max)
     return jnp.where(scaled >= cutoff[..., None], scaled, -jnp.inf)
 
 
+def _adds_nothing(params: SamplingParams, bias: Optional[jax.Array],
+                  penalised: bool) -> jax.Array:
+    """Whether ``sample`` leaves every row at hand as the head made it: a
+    bias row (``bias`` [B, V] or None) of zeros, and -inf, which only
+    replaces a value by another bfloat16; and, where count rows are
+    handed in (``penalised``), every row's penalties neutral. The bias
+    rows are looked at one maximum a row, a reduction of the row
+    maximum's form that reads ``bias`` alone: a test of the sum's own
+    bits makes the compiler write the summed rows out for it (0.05 ms a
+    tile of [16, 261 120] on a v5e; PERF.md §6, PR 61)."""
+    nothing = jnp.asarray(True)
+    if bias is not None:
+        nothing &= jnp.all(jnp.where(
+            jnp.isneginf(bias), 0.0, jnp.abs(bias)).max(axis=-1) == 0.0)
+    if penalised:
+        nothing &= jnp.all((params.repetition_penalty == 1.0)
+                           & (params.frequency_penalty == 0.0)
+                           & (params.presence_penalty == 0.0))
+    return nothing
+
+
 def sample(
-    logits: jax.Array,  # [B, V] f32
+    logits: jax.Array,  # [B, V] as the head made them, or f32
     params: SamplingParams,
     counts: Optional[jax.Array] = None,   # [B, V] i32 generated-token counts
     seen: Optional[jax.Array] = None,     # [B, V] bool prompt-token presence
     bias: Optional[jax.Array] = None,     # [B, V] f32 OpenAI logit_bias rows
     mesh: Optional[Mesh] = None,          # the step's mesh, where it has one
+    head_dtype=None,   # the head's, where ``logits`` were widened since
 ) -> jax.Array:
-    """Returns sampled token ids [B]."""
+    """Returns sampled token ids [B].
+
+    The filter's search is the short one (``filter_logits``) where the
+    rows are bfloat16 values still: known to the trace where the head
+    made bfloat16 and neither a bias nor penalty rows are handed in (the
+    block family), looked up on the device where they are (the served
+    tail: a tile whose rows all have a bias row of zeros, or of zeros and
+    -inf, and neutral penalties; ``_adds_nothing``), never where the head
+    made float32 or on a mesh of several devices (``short_search``)."""
+    narrow = short_search(
+        logits.dtype if head_dtype is None else head_dtype, mesh)
     logits = logits.astype(jnp.float32)
     # The filter's search reduces along the vocabulary in every pass, and
     # along a vocabulary that stays sharded each pass would all-reduce. So
@@ -296,6 +410,8 @@ def sample(
             lambda x: _pin(x, mesh, "dp", *[None] * (x.ndim - 1)),
             (params, counts, seen, bias))        # as they come; None stays
         logits = _pin(_pin(logits, mesh, "dp", "tp"), mesh, ("dp", "tp"), None)
+    if narrow and (bias is not None or counts is not None):
+        narrow = _adds_nothing(params, bias, counts is not None)
     if bias is not None:
         logits = logits + bias
 
@@ -316,7 +432,8 @@ def sample(
     temp = jnp.maximum(params.temperature, 1e-6)[:, None]
     scaled = logits / temp
 
-    scaled = filter_logits(scaled, params.top_k, params.top_p, params.min_p)
+    scaled = filter_logits(scaled, params.top_k, params.top_p, params.min_p,
+                           bfloat16_over=temp[:, 0], short=narrow)
 
     row_keys = _row_keys(params)
     sampled = jax.vmap(lambda k, l: jax.random.categorical(k, l))(row_keys, scaled)
@@ -335,7 +452,9 @@ def sample(
 # 1.71 ms where all 64 rows at once take 2.94, at [64, 163840] 0.81 /
 # 0.60 / 0.98 of 1.68, at [64, 131072] 0.70 / 0.50 / 0.85 of 1.53. Eight
 # rows a tile pay the gathers twice for the same rows; 32 are one coarse
-# step for a batch a quarter full.
+# step for a batch a quarter full. (All with the full search; a tile of 16
+# untouched rows takes 0.83 / 0.50 / 0.43 since PR 61, the gathers what
+# they were.)
 ROW_TILE = 16
 
 
@@ -371,6 +490,29 @@ def tiled_rows(live: int, rows: int, tile: int) -> int:
     if walks_live_rows(live, rows, tile):
         return -(-live // tile) * tile
     return rows
+
+
+def short_search_rows(live, plain, tile: int) -> int:
+    """Of the rows ``tiled_rows`` counts, those whose tile (or whole
+    batch, ``tile`` 0) holds no live row but a ``plain`` one (both [R]
+    bool, host arrays): a request with no bias row and neutral penalties,
+    whose logits are the head's. Where the head makes bfloat16
+    (``short_search``) those are the rows whose search ran the short form
+    (``dynamo_scheduler_sampling_short_search_rows_total``), as far as the
+    host knows: the device looks at the bias rows themselves
+    (``_adds_nothing``), so a guided mask of 0 and -inf runs short
+    uncounted, and a row without a sequence is taken as plain where its
+    slot may hold the bias row of the request before."""
+    live, plain = np.asarray(live, bool), np.asarray(plain, bool)
+    r, n = live.size, int(live.sum())
+    if not tile:
+        return r if plain[live].all() else 0
+    if walks_live_rows(n, r, tile):
+        listed = np.concatenate([plain[live], np.ones(-n % tile, bool)])
+        return int(listed.reshape(-1, tile).all(axis=1).sum()) * tile
+    ok = plain | ~live
+    return sum(min(tile, r - at) for at in range(0, r, tile)
+               if ok[min(at, r - tile):][:tile].all())
 
 
 def _walk_tiles(r: int, trips, of_tile, tail) -> tuple:
@@ -654,6 +796,8 @@ def sample_block_positions(cfg, logits: jax.Array, params: SamplingParams,
     unless ``want_top``)."""
     length = positions.shape[1]
     v = logits.shape[-1]
+    # -inf is a bfloat16 too: the rows stay what ``head_dtype`` says
+    head_dtype = logits.dtype
     logits = jnp.where(jnp.arange(v) == mask_id, -jnp.inf,
                        logits.astype(jnp.float32))
     at_position = jax.tree_util.tree_map(
@@ -661,7 +805,7 @@ def sample_block_positions(cfg, logits: jax.Array, params: SamplingParams,
     at_position = dataclasses.replace(
         at_position, counters=positions.reshape(-1) * _PASS_STRIDE
         + at_position.counters)
-    tokens = sample(logits, at_position)
+    tokens = sample(logits, at_position, head_dtype=head_dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
     lps = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
     kw = top_k_width(cfg.vocab_size)

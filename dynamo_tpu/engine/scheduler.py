@@ -45,6 +45,7 @@ from .sampling import (
     host_row,
     ring_init,
     seed_to_key,
+    short_search_rows,
     stop_id_row,
     stop_seq_rows,
     tiled_rows,
@@ -1052,6 +1053,16 @@ class Scheduler:
             "tail walks tiles has a tile of rows free, all of them "
             "otherwise (a fuller batch, a mesh of several devices, a "
             "block pass, fewer rows than two tiles)",
+        )
+        self._sampling_short_rows_ctr = reg.counter(
+            "dynamo_scheduler_sampling_short_search_rows_total",
+            "The part of dynamo_scheduler_sampling_rows_run_total whose "
+            "cutoff search ran its short form, half the passes: rows of a "
+            "tile (a whole batch where the tail walks none) in which "
+            "every request is without logit_bias, guided mask and "
+            "penalties, in a program whose head makes bfloat16 logits on "
+            "one device; as the host knows it, the device looks at the "
+            "tile's bias rows and penalties (sampling.short_search_rows)",
         )
 
         self._fetch_ctr = reg.counter(
@@ -2519,7 +2530,7 @@ class Scheduler:
                 stop_hash=hs.stop_hash, stop_hlen=hs.stop_hlen,
                 gtable=gtable_dev, want_top=want_top,
             )
-            self._count_decode_rows("decode_burst_df", len(live), k_steps)
+            self._count_decode_rows("decode_burst_df", live, k_steps)
             self._chain_carry = carry
             self._chain_dispatched += 1
             self.steps += 1
@@ -3357,7 +3368,7 @@ class Scheduler:
                     seed_keys=hs.keys, counters=ctrs, commit=commit,
                     want_top=er.logprobs_n > 0,
                 )
-                self._count_decode_rows("decode_burst", 1, k_steps)
+                self._count_decode_rows("decode_burst", [er], k_steps)
                 self.steps += 1
                 self._sp_exposed_h.observe(t_burst - st.final_dispatch_t)
                 self.flight.record(
@@ -3956,25 +3967,41 @@ class Scheduler:
             else self.config.kv_width_bucket(nblocks)
             for r, program in asked if r is not None)
 
-    def _count_decode_rows(self, program: str, live: int,
+    def _count_decode_rows(self, program: str, live: List[EngineRequest],
                            steps: int = 1) -> None:
         """Count one dispatch of the decode program ``program`` (the
-        runner's name for it) over ``live`` rows that hold a sequence:
+        runner's name for it) over the rows ``live`` that hold a
+        sequence, each in the row of its slot:
         the batch's rows a step, and the pad rows among them where the
         program's attention kernels walk a list of live rows and so
         take no grid step for them (``ModelRunner.row_list_programs``,
         recorded when the program was traced: call this after the
         dispatch). Likewise the rows its sampling tail ran on
         (``sampling_tile_programs``, ``sampling.tiled_rows``; a row that
-        freezes inside a burst still counts for all its steps)."""
+        freezes inside a burst still counts for all its steps), and those
+        of them whose search was the short one
+        (``sampling_short_programs``, ``sampling.short_search_rows``)."""
         b = self.config.max_batch_size
         self._decode_rows_ctr.inc(b * steps)
         if program in getattr(self.runner, "row_list_programs", ()):
-            self._decode_rows_skipped_ctr.inc((b - live) * steps)
+            self._decode_rows_skipped_ctr.inc((b - len(live)) * steps)
         self._sampling_rows_ctr.inc(b * steps)
         tile = getattr(self.runner, "sampling_tile_programs", {}).get(program)
-        self._sampling_rows_run_ctr.inc(
-            (tiled_rows(live, b, tile) if tile else b) * steps)
+        run = tiled_rows(len(live), b, tile) if tile else b
+        self._sampling_rows_run_ctr.inc(run * steps)
+        if program in getattr(self.runner, "sampling_short_programs", ()):
+            touched = [
+                er.slot for er in live
+                if er.guided is not None
+                or er.req.sampling_options.logit_bias
+                or (er.presence_penalty, er.frequency_penalty,
+                    er.repetition_penalty) != (0.0, 0.0, 1.0)]
+            if touched:          # by tile; else every row run, as is usual
+                held, plain = np.zeros(b, bool), np.ones(b, bool)
+                held[[er.slot for er in live]] = True
+                plain[touched] = False
+                run = short_search_rows(held, plain, tile or 0)
+            self._sampling_short_rows_ctr.inc(run * steps)
 
     async def _decode(self, loop, active: List[EngineRequest],
                       k_steps: int = 1) -> None:
@@ -4246,8 +4273,7 @@ class Scheduler:
                         commit=np.zeros(b, bool), want_top=False, **dkw,
                     )
             self._count_decode_rows(
-                "decode_burst" if k_steps > 1 else "decode", len(rows),
-                k_steps)
+                "decode_burst" if k_steps > 1 else "decode", live, k_steps)
         return self._in_flight(live, [next_tokens, lps, top_vals, top_ids],
                                k_steps, t_dispatch, read_bytes, prev)
 
@@ -4526,7 +4552,7 @@ class Scheduler:
                 seed_keys=hs.keys, counters=passes, want_top=want_top,
                 **({} if prev is None else {"prev_ids": prev.arrays[0]}),
             )
-            self._count_decode_rows("decode_block", len(rows))
+            self._count_decode_rows("decode_block", live)
         # new_ids, lps, top_vals, top_ids, left
         return self._in_flight(live, list(outs), 1, t_dispatch, read_bytes,
                                prev)

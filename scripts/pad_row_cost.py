@@ -35,10 +35,21 @@ row at once (``against_one_pass``: another program, whose sums along the
 vocabulary are tiled otherwise): whether every token is the same, and
 the largest difference of a log-probability in units in the last place.
 This is the timing ``ROW_TILE`` was fixed from (PERF.md §5 "Since
-PR 47"). ``--tail --hash`` needs no chip and times nothing: it prints
-the sha256 of the tail's lowered text at every cell's ``[rows,
-vocabulary]``, the mask handed where the checkout takes one, to tell
-under two checkouts which configurations' tails a change reaches.
+PR 47"). Since PR 61 a tile of bfloat16 rows with nothing added finds its
+cutoffs by the short search (``sampling.filter_logits``): every time is
+taken twice, as the mix sends the rows (``ms``, ``all rows``) and with
+one entry of bias a row, which makes every row a general float32 and
+the same program run the full search (``ms_full``, ``all rows full``);
+``short_equals_full`` says whether ``filter_logits`` kept the same set
+both ways on those rows; the block family's pass over ``[128, 151936]``
+(``sample_block_positions``, the short search known to its trace) is
+timed beside the same values handed over as float32, which its trace
+takes as a general row; and ``--search-bits 2,4`` repeats all of it with
+``sampling._SEARCH_BITS`` set to each. ``--tail --hash`` needs no chip
+and times nothing: it prints the sha256 of the tail's lowered text at
+every cell's ``[rows, vocabulary]``, the mask handed where the checkout
+takes one, to tell under two checkouts which configurations' tails a
+change reaches.
 """
 
 from __future__ import annotations
@@ -65,6 +76,9 @@ ap.add_argument("--always", action="store_true",
                 help="--tail: walk the list of live rows however full the "
                 "batch is, not only while the program would "
                 "(sampling.walks_live_rows)")
+ap.add_argument("--search-bits", default="",
+                help="--tail: values to stand in for sampling._SEARCH_BITS, "
+                "each timed in turn (default: the one the checkout has)")
 ap.add_argument("--hash", action="store_true",
                 help="--tail: print the lowered tail's sha256 a shape and "
                 "stop (no chip)")
@@ -186,6 +200,8 @@ TAIL_CASES = {
     "xing4": (64, 131072),
     "phi3": (32, 32064),
 }
+# sdar-30b-a3b: 32 rows of a block of 4, every position sampled
+BLOCK_CASES = {"sdar-block": (32, 4, 151936)}
 # the shapes no walk is traced at, for --hash: minicpm-sala-9b,
 # trinity-mini-26b-a3b
 UNTILED_CASES = {"sala": (24, 73448), "trinity": (16, 200192)}
@@ -292,10 +308,25 @@ def tail_case(name, rng, reps, tiles):
     row = {"rows": b, "vocabulary": v}
     plain = program(False)
     wanted = {n: operands(n) for n in lives}
+    short = hasattr(sampling, "short_search")
+
+    def general(ops):
+        """``ops`` with an entry of bias a row: no row is bfloat16 any
+        more, and the program searches all 32 bits."""
+        logits, samp, counts, seen, bias, *rest = ops
+        return (logits, samp, counts, seen, bias.at[:, 0].set(1e-3), *rest)
+
     seconds, one_pass = _time_tail(plain, wanted[b], reps)
     row["all rows"] = 1e3 * seconds
     print(f"{name:12s} [{b}, {v}] every row    {row['all rows']:.3f} ms",
           flush=True)
+    if short:
+        row["all rows full"] = 1e3 * _time_tail(
+            plain, general(wanted[b]), reps)[0]
+        row["short_equals_full"] = _short_equals_full(sampling, logits)
+        print(f"{name:12s} [{b}, {v}] every row, 32 bits "
+              f"{row['all rows full']:.3f} ms  short equals full "
+              f"{row['short_equals_full']}", flush=True)
     if "live" not in inspect.signature(tail).parameters:
         return row
     if args.always:
@@ -308,6 +339,10 @@ def tail_case(name, rng, reps, tiles):
         for n in lives:
             seconds, got[n] = _time_tail(tiled, wanted[n], reps)
             cell["ms"][str(n)] = 1e3 * seconds
+        if short:
+            cell["ms_full"] = {
+                str(n): 1e3 * _time_tail(tiled, general(wanted[n]), reps)[0]
+                for n in lives}
         for against in ("across_loads", "against_one_pass"):
             cell[against] = {"tokens_equal": True, "logprob_ulps": 0}
         for n in lives:
@@ -316,8 +351,66 @@ def tail_case(name, rng, reps, tiles):
             _compared(cell["against_one_pass"], got[n], one_pass, live)
         print(f"{name:12s} [{b}, {v}] tiles of {t:2d}  " + "  ".join(
             f"{n} live {ms:.3f} ms" for n, ms in cell["ms"].items())
+            + ("  32 bits " + "  ".join(
+                f"{ms:.3f}" for ms in cell["ms_full"].values())
+               if short else "")
             + f"  across loads {cell['across_loads']}"
             + f"  against one pass {cell['against_one_pass']}", flush=True)
+    return row
+
+
+def _short_equals_full(sampling, logits):
+    """Whether ``filter_logits`` keeps the same entries of the bfloat16
+    ``logits`` over a few temperatures by the short search and by the
+    full one: top-p rows, top-k and min-p rows among them."""
+    b = logits.shape[0]
+    rows = np.arange(b)
+    top_p = jnp.asarray(np.where(rows % 4 == 3, 1.0, 0.9), jnp.float32)
+    top_k = jnp.asarray(np.where(rows % 3 == 0, 40, 0), jnp.int32)
+    min_p = jnp.asarray(np.where(rows % 5 == 0, 0.02, 0.0), jnp.float32)
+    keep = jax.jit(lambda short, *a: jnp.isfinite(
+        sampling.filter_logits(*a, short=short)))
+    same = True
+    for temperature in (0.7, 1.0, 0.3, 1.9):
+        temp = jnp.full((b,), temperature, jnp.float32)
+        operands = (logits.astype(jnp.float32) / temp[:, None], top_k, top_p,
+                    min_p, temp)
+        same &= bool(jnp.array_equal(keep(jnp.asarray(True), *operands),
+                                     keep(jnp.asarray(False), *operands)))
+    return same
+
+
+def block_case(name, rng, reps):
+    """The block family's sampling at every position of a pass,
+    ``[rows * length, V]``: the head's bfloat16 logits (since PR 61 the
+    short search, known to the trace) and the same values as float32 (a
+    general row: the full search, and two bytes an entry more to read)."""
+    import types
+
+    from dynamo_tpu.engine import sampling
+
+    r, length, v = BLOCK_CASES[name]
+    cfg = types.SimpleNamespace(vocab_size=v)
+    logits = _normal(0, (r * length, v)) * 3.0
+    samp = dataclasses.replace(
+        sampling.SamplingParams.zeros(r),
+        keys=jnp.asarray(rng.integers(0, 2 ** 32, (r, 2)), jnp.uint32),
+        temperature=jnp.full((r,), 0.7, jnp.float32),
+        top_p=jnp.full((r,), 0.9, jnp.float32))
+    positions = jnp.asarray(
+        rng.integers(0, 2048, (r, 1)) + np.arange(length), jnp.int32)
+    step = jax.jit(lambda x: sampling.sample_block_positions(
+        cfg, x, samp, positions, jnp.asarray(False), v - 1)[:2])
+    row = {"rows": r * length, "vocabulary": v}
+    toks = {}
+    for dtype in (jnp.bfloat16, jnp.float32):
+        x = logits.astype(dtype)
+        row[jnp.dtype(dtype).name] = 1e3 * _time(step, (x,), reps)
+        toks[dtype] = np.asarray(step(x)[0])
+    row["tokens_equal"] = bool(np.array_equal(*toks.values()))
+    print(f"{name:12s} [{r * length}, {v}] bfloat16 {row['bfloat16']:.3f} ms"
+          f"  float32 {row['float32']:.3f} ms  tokens equal "
+          f"{row['tokens_equal']}", flush=True)
     return row
 
 
@@ -330,13 +423,23 @@ def main():
                  f"here is {jax.default_backend()!r}: nothing measured")
     device = jax.devices()[0]
     if args.tail:
+        from dynamo_tpu.engine import sampling
+
         tiles = [int(t) for t in args.tiles.split(",")]
         table = {"repo": os.path.abspath(args.repo),
                  "device": device.device_kind, "always": args.always,
-                 "tail": {
-                     name: tail_case(name, np.random.default_rng(7),
-                                     args.reps, tiles)
-                     for name in TAIL_CASES}}
+                 "tail": {}}
+        for bits in [int(n) for n in args.search_bits.split(",") if n] or [
+                sampling._SEARCH_BITS]:
+            if bits != sampling._SEARCH_BITS:
+                sampling._SEARCH_BITS = bits
+                jax.clear_caches()
+            print(f"{bits} bits a pass", flush=True)
+            table["tail"][f"{bits} bits a pass"] = {
+                **{name: tail_case(name, np.random.default_rng(7),
+                                   args.reps, tiles) for name in TAIL_CASES},
+                **{name: block_case(name, np.random.default_rng(7), args.reps)
+                   for name in BLOCK_CASES}}
         return _finish(table)
     table = {"repo": os.path.abspath(args.repo), "device": device.device_kind,
              "live_rows": _takes_live_rows(pallas_decode.paged_decode_attention),

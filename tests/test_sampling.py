@@ -11,7 +11,9 @@ threshold and the cases assert that their data keeps clear of them.
 """
 
 import collections
+import dataclasses
 import re
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -383,30 +385,292 @@ def _walk(jaxpr, times=1):
             yield from _walk(sub, inner)
 
 
+# ---- the short search: bfloat16 rows with nothing added (PR 61) ----
+
+_SHORT_V = 1031
+_FILTER_SHORT = jax.jit(lambda *a: S.filter_logits(*a, short=True))
+_FILTER_EITHER = jax.jit(lambda short, *a: S.filter_logits(*a, short=short))
+# rows of bfloat16 values: what a search over sixteen bits has to get
+# right that one over thirty-two gets right by brute force
+_SHORT_ROWS = {
+    "positive": lambda rng, x: np.abs(x) + 1.0,
+    "negative": lambda rng, x: -np.abs(x) - 1.0,
+    "mixed_signs": lambda rng, x: x,
+    # a few dozen distinct values a row: the cutoff always sits in a tie
+    "ties_at_the_cutoff": lambda rng, x: np.round(x * 2.0) / 2.0,
+    "neg_inf_entries": lambda rng, x: np.where(
+        rng.random(x.shape) < 0.3, -np.inf, x),
+    # a block family's rows: the mask id is never a prediction
+    "mask_id_at_neg_inf": lambda rng, x: np.where(
+        np.arange(x.shape[1]) == x.shape[1] - 1, -np.inf, x),
+    # subnormal and huge magnitudes on both sides of zero
+    "wide_exponents": lambda rng, x: x * np.exp2(
+        rng.integers(-140, 100, size=x.shape)),
+}
+_SHORT_REGIMES = dict(
+    _REGIMES, top_p_one_and_over=[
+        (0.7, 0, 1.0, 0.0, 0.0, 0.0, 1.0), (1.0, 5, 1.0, 0.0, 0.0, 0.0, 1.0),
+        (1.3, 0, 1.5, 0.1, 0.0, 0.0, 1.0), (0.9, 0, 1.0, 0.2, 0.0, 0.0, 1.0),
+        (1.0, 0, 0.999, 0.0, 0.0, 0.0, 1.0), (2.0, 3, 2.0, 0.0, 0.0, 0.0, 1.0)])
+del _SHORT_REGIMES["penalties"]           # what takes a row out of the case
+
+
+def _short_case(kind, regime):
+    rng = np.random.default_rng(zlib.crc32(f"{kind}/{regime}".encode()))
+    x = _SHORT_ROWS[kind](rng, rng.normal(size=(_B, _SHORT_V)) * 3.0)
+    rows = [tuple(r[:4]) + (0.0, 0.0, 1.0) for r in _SHORT_REGIMES[regime]]
+    params = _params(rows, rng.integers(0, 2**32, size=(_B, 2)),
+                     rng.integers(0, 1000, size=_B))
+    with np.errstate(over="ignore"):
+        return jnp.asarray(x.astype(np.float32), jnp.bfloat16), params
+
+
+@pytest.mark.parametrize("regime", sorted(_SHORT_REGIMES))
+@pytest.mark.parametrize("kind", sorted(_SHORT_ROWS))
+def test_short_search_keeps_the_full_searchs_set_and_draws_its_token(
+        kind, regime):
+    """On rows of bfloat16 values the search over the sixteen bits that
+    can differ gives ``filter_logits`` the very array the search over
+    all thirty-two gives it, known to the trace or told on the device,
+    and ``sample`` the same tokens whether it is handed the head's
+    bfloat16 (the short search by the trace), the same with a bias of
+    zeros and no count (short, by the look of them), or the values as
+    float32 (the full search, the program from before there were two)."""
+    logits, params = _short_case(kind, regime)
+    wide = logits.astype(jnp.float32)
+    temp = jnp.maximum(params.temperature, 1e-6)
+    filters = (wide / temp[:, None], params.top_k, params.top_p, params.min_p)
+    full = np.asarray(_FILTER(*filters))
+    for name, got in (
+            ("trace", _FILTER_SHORT(*filters, temp)),
+            ("device", _FILTER_EITHER(jnp.asarray(True), *filters, temp)),
+            ("device, full", _FILTER_EITHER(jnp.asarray(False), *filters,
+                                            temp))):
+        np.testing.assert_array_equal(np.asarray(got), full, err_msg=name)
+    assert np.isfinite(full).any(axis=1).all()      # the top token stays
+
+    tokens = np.asarray(_SAMPLE(wide, params))
+    np.testing.assert_array_equal(np.asarray(_SAMPLE(logits, params)), tokens)
+    nothing = (jnp.zeros(wide.shape, jnp.int32),
+               jnp.zeros(wide.shape, jnp.bool_), jnp.zeros_like(wide))
+    np.testing.assert_array_equal(
+        np.asarray(_SAMPLE(logits, params, *nothing)), tokens)
+    np.testing.assert_array_equal(
+        np.asarray(_SAMPLE(wide, params, *nothing)), tokens)
+
+
+def _passes(monkeypatch, logits, params, *state, **kw):
+    """The passes of the top-p search of one eager ``sample``: calls of
+    ``_each`` but the one that sums the alive mass."""
+    calls, each = [], S._each
+    monkeypatch.setattr(
+        S, "_each", lambda *a: calls.append(1) or each(*a))
+    with jax.disable_jit():
+        S.sample(logits, params, *state, **kw)
+    return len(calls) - 1
+
+
+_TOUCHES = {
+    # (what goes with the bfloat16 logits, the search's passes)
+    "nothing_handed_in": (None, 4),
+    "zero_bias_neutral_penalties": ({}, 4),
+    "guided_mask_of_neg_inf": ({"bias": -np.inf}, 4),
+    "a_bias_entry": ({"bias": 0.3}, 8),
+    "a_repetition_penalty": ({"repetition": 1.3}, 8),
+    "a_frequency_penalty": ({"frequency": 0.2}, 8),
+    "a_presence_penalty": ({"presence": 0.7}, 8),
+}
+
+
+@pytest.mark.parametrize("touch", sorted(_TOUCHES))
+def test_search_length_follows_the_bits_that_differ(touch, monkeypatch):
+    """Four passes where every row at hand is still the head's bfloat16
+    (nothing handed in: known to the trace; a zero bias and neutral
+    penalties, or a mask of -inf: looked up on the device), eight as soon as
+    ONE entry of ONE row is a general float32, and the drawn tokens are
+    those of the program handed the same values as float32, bit for bit.
+    A float32 head searches eight passes whatever goes with it."""
+    with_, passes = _TOUCHES[touch]
+    logits, params = _short_case("mixed_signs", "top_p")
+    wide, state = logits.astype(jnp.float32), ()
+    if with_ is not None:
+        counts = np.zeros(wide.shape, np.int32)
+        counts[2, 5] = 2
+        bias = np.zeros(wide.shape, np.float32)
+        bias[4, 9] = with_.get("bias", 0.0)
+        at_row_2 = lambda x, neutral: jnp.full(_B, neutral).at[2].set(x)
+        params = dataclasses.replace(
+            params,
+            repetition_penalty=at_row_2(with_.get("repetition", 1.0), 1.0),
+            frequency_penalty=at_row_2(with_.get("frequency", 0.0), 0.0),
+            presence_penalty=at_row_2(with_.get("presence", 0.0), 0.0))
+        state = (jnp.asarray(counts), jnp.zeros(wide.shape, jnp.bool_),
+                 jnp.asarray(bias))
+    assert _passes(monkeypatch, logits, params, *state) == passes
+    assert _passes(monkeypatch, wide, params, *state) == 8
+    assert _passes(monkeypatch, wide, params, *state,
+                   head_dtype=jnp.bfloat16) == passes
+    np.testing.assert_array_equal(
+        np.asarray(_SAMPLE(logits, params, *state)),
+        np.asarray(_SAMPLE(wide, params, *state)))
+
+
+def test_short_search_is_for_bfloat16_on_one_device():
+    from jax.sharding import Mesh
+
+    devices = np.array(jax.devices()[:4])
+    assert S.short_search(jnp.bfloat16)
+    assert S.short_search(jnp.bfloat16, Mesh(devices[:1].reshape(1, 1),
+                                             ("dp", "tp")))
+    assert not S.short_search(jnp.bfloat16, Mesh(devices.reshape(1, 4),
+                                                 ("dp", "tp")))
+    assert not S.short_search(jnp.float32)
+    assert not S.short_search(jnp.float16)      # eleven bits of fraction
+
+
+# (rows, the live ones, those of them that are not plain, rows a tile,
+#  rows counted): ``short_search_rows`` beside ``tiled_rows``
+_SHORT_COUNTS = {
+    "no_tiles_all_plain": (24, range(5), [], 0, 24),
+    "no_tiles_one_touched": (24, range(5), [3], 0, 0),
+    "no_tiles_nothing_live": (24, [], [], 0, 24),
+    "listed_all_plain": (64, range(0, 60, 3), [], 16, 32),
+    # 20 live rows by their list: 16 and 4 (and the pad); the 17th of them
+    "listed_touched_in_the_last_tile": (64, range(0, 60, 3), [48], 16, 16),
+    "listed_touched_in_the_first_tile": (64, range(0, 60, 3), [0], 16, 16),
+    "listed_touched_in_both": (64, range(0, 60, 3), [0, 57], 16, 0),
+    # past 48 of 64 live every row as it lies, four tiles
+    "as_they_lie_all_plain": (64, range(60), [], 16, 64),
+    "as_they_lie_one_tile_touched": (64, range(60), [17], 16, 48),
+    # 72 rows: the fifth tile is moved back to rows 56-71 and counts 8
+    "as_they_lie_moved_back_tile": (72, range(72), [60], 16, 48),
+    "as_they_lie_touched_under_the_overlap": (72, range(72), [70], 16, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SHORT_COUNTS))
+def test_short_search_rows_counts_the_tiles_without_a_touched_row(case):
+    r, live, touched, tile, counted = _SHORT_COUNTS[case]
+    held, plain = np.zeros(r, bool), np.ones(r, bool)
+    held[list(live)] = True
+    plain[touched] = False
+    assert S.short_search_rows(held, plain, tile) == counted
+    run = S.tiled_rows(int(held.sum()), r, tile) if tile else r
+    assert counted <= run
+    if not touched:
+        assert counted == run
+
+
+@pytest.mark.parametrize("touched", ["none", "biased", "penalised"])
+def test_tile_with_a_touched_row_agrees_with_the_full_search_bit_for_bit(
+        touched):
+    """The served tail over 72 rows in tiles of 16, bfloat16 logits, all
+    rows plain but one (none; one with a bias row; one with penalties):
+    every row's token and log-probability are those of the same tail
+    handed the values as float32, which searches all 32 bits in every
+    tile as the program did before: the plain tiles' rows draw the same
+    tokens by the short search, and the tile that holds the touched row
+    (which takes the full one) agrees bit for bit."""
+    import types
+
+    from dynamo_tpu.engine import model_runner as mr
+
+    case = _tail_case()
+    b, v, n = _TAIL_B, _TAIL_V, _TAIL_SLOTS
+    rows = [(0.7, 0, 0.9, 0.0, 0.0, 0.0, 1.0)] * b
+    bias = np.zeros((n, v), np.float32)
+    at = 37
+    if touched == "biased":
+        bias[int(case["slots"][at])] = np.asarray(case["bias"][0])
+    if touched == "penalised":
+        rows[at] = (0.7, 0, 0.9, 0.0, 0.5, 0.3, 1.2)
+    samp = dataclasses.replace(
+        _params(rows, np.zeros((b, 2)), np.zeros(b)),
+        keys=case["samp"].keys, counters=case["samp"].counters)
+    live = jnp.ones(b, bool).at[::5].set(False)    # 57 live: by the list
+
+    def run(logits):
+        return jax.jit(lambda x: mr._sample_and_logprobs(
+            types.SimpleNamespace(vocab_size=v), None, x, samp,
+            case["counts"], case["seen"], jnp.asarray(bias), case["slots"],
+            live, jnp.asarray(False), live=live)[:2])(logits)
+
+    got, ref = run(case["logits"]), run(case["logits"].astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    assert len(set(np.asarray(got[0])[np.asarray(live)].tolist())) > 8
+
+
+def test_sample_holds_one_body_a_search_whatever_its_length():
+    """With bfloat16 logits and a bias the search's length is known on
+    the device only, and it is the trip count of the ONE loop each search
+    is: no ``cond`` between a short copy and a full one (a second body in
+    every program's warm-up). Traced: one loop for top-k's search behind
+    its gate and one for top-p's, the only loops that reduce over
+    ``[b, v]``, fifteen reductions a pass each, one ``exp`` of the row in
+    all of them; compiled for the CPU: as many ``while`` instructions
+    as the program of a float32 head has, which knows one length."""
+    b, v = 8, 512
+    sd = jax.ShapeDtypeStruct
+    params = jax.tree_util.tree_map(
+        lambda a: sd((b,) + a.shape[1:], a.dtype), S.SamplingParams.zeros(1))
+    operands = (sd((b, v), jnp.bfloat16), params, sd((b, v), jnp.int32),
+                sd((b, v), jnp.bool_), sd((b, v), jnp.float32))
+    jaxpr = jax.make_jaxpr(S.sample)(*operands).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns].count("cond") == 1
+    loops = [e for e, _ in _walk(jaxpr)
+             if e.primitive.name in ("while", "scan")
+             and any(x.primitive.name.startswith(_REDUCTIONS)
+                     and x.invars[0].aval.size == b * v
+                     for x, _ in _walk(e.params["body_jaxpr"].jaxpr
+                                       if e.primitive.name == "while"
+                                       else e.params["jaxpr"].jaxpr))]
+    assert [e.primitive.name for e in loops] == ["while", "while"]
+    for loop in loops:
+        inside = list(_walk(loop.params["body_jaxpr"].jaxpr))
+        assert not [e for e, _ in inside if e.primitive.name == "cond"]
+        assert sum(1 for e, _ in inside
+                   if e.primitive.name.startswith(_REDUCTIONS)
+                   and e.invars[0].aval.size == b * v) == 15
+    assert sum(1 for loop in loops
+               for e, _ in _walk(loop.params["body_jaxpr"].jaxpr)
+               if e.primitive.name == "exp"
+               and e.outvars[0].aval.size == b * v) == 1
+    whiles = [len(re.findall(r" while\(", jax.jit(S.sample).lower(
+        sd((b, v), dtype), *operands[1:]).compile().as_text()))
+        for dtype in (jnp.bfloat16, jnp.float32)]
+    assert whiles[0] == whiles[1] >= 2, whiles
+
+
+
 # the search settles 4 bits of the cutoff's image a pass with fifteen
 # thresholds (one fusion on the chip): 8 passes x 15 reductions for top-p,
 # as many for top-k behind its cond, and the row maximum, the alive mass,
-# the greedy argmax and the draw's argmax beside them
-_MAX_FULL_REDUCTIONS = 2 * 8 * 15 + 8
+# the greedy argmax and the draw's argmax beside them; 4 passes where the
+# trace knows the rows for bfloat16 values
+_MAX_FULL_REDUCTIONS = {"float32": 2 * 8 * 15 + 8, "bfloat16": 2 * 4 * 15 + 8}
 
 
+@pytest.mark.parametrize("head", ["float32", "bfloat16"])
 @pytest.mark.parametrize("b,v", [(32, 32064), (64, 163840)])
 def test_sample_sorts_nothing_and_reads_the_logits_a_bounded_number_of_times(
-        b, v):
+        b, v, head):
     """What made the pass 19 ms of every decode step on a v5e, and then
     6.6 ms at a 164 k vocabulary, cannot come back unseen by the CPU-only
     tests: at the served shapes ``sample`` traces to NO sort, to no gather
     that fetches and no scatter that writes as many elements as the
     logits have (a TPU does those one element at a time), and the
     reductions over the whole ``[b, v]`` array, each times the trips of
-    the loops around it, stay under a stated number. Trace only."""
+    the loops around it, stay under a stated number: of a float32 head
+    with its penalty and bias rows, and of a bfloat16 head's logits alone
+    (a block family's pass), half the passes. Trace only."""
     sd = jax.ShapeDtypeStruct
     params = jax.tree_util.tree_map(
         lambda a: sd((b,) + a.shape[1:], a.dtype), S.SamplingParams.zeros(1))
-    jaxpr = jax.make_jaxpr(S.sample)(
-        sd((b, v), jnp.float32), params, sd((b, v), jnp.int32),
-        sd((b, v), jnp.bool_), sd((b, v), jnp.float32),
-    )
+    state = (sd((b, v), jnp.int32), sd((b, v), jnp.bool_),
+             sd((b, v), jnp.float32)) if head == "float32" else ()
+    jaxpr = jax.make_jaxpr(S.sample)(sd((b, v), jnp.dtype(head)), params,
+                                     *state)
     full_reductions = 0
     for e, times in _walk(jaxpr.jaxpr):
         name = e.primitive.name
@@ -420,7 +684,8 @@ def test_sample_sorts_nothing_and_reads_the_logits_a_bounded_number_of_times(
         if name.startswith(_REDUCTIONS) and e.invars[0].aval.size >= b * v:
             assert times is not None, f"in a loop of unknown length: {e}"
             full_reductions += times
-    assert 0 < full_reductions <= _MAX_FULL_REDUCTIONS, full_reductions
+    assert (_MAX_FULL_REDUCTIONS[head] - 4 * 15 < full_reductions
+            <= _MAX_FULL_REDUCTIONS[head]), full_reductions
 
 
 # ---- the tail over the rows that hold a token (model_runner) ----
@@ -578,6 +843,13 @@ def test_decode_tail_runs_in_a_loop_over_tiles_with_a_traced_bound():
         for e, _ in _walk(walk):
             if e.primitive.name.startswith("scatter"):
                 assert e.invars[2].aval.size <= t, e
+        # and in a tile one loop a search, its length the tile's own (a
+        # trip count read on the device): no branch between two copies
+        searches = [e for e, _ in _walk(bodies[-1])
+                    if e.primitive.name in ("while", "scan")]
+        assert [e.primitive.name for e in searches] == ["while", "while"]
+        assert [e.primitive.name for e, _ in _walk(bodies[-1])].count(
+            "cond") == 1                        # the top-k search's gate
     # by the list: the tile's own rows are all that is ever gathered
     for e, _ in _walk(listed):
         if e.primitive.name == "gather":
@@ -678,6 +950,37 @@ def _collectives(hlo):
     return found
 
 
+def _tp4_step_collectives(dtype):
+    """The collectives of the whole decode step of a small model at tp=4
+    (four of the virtual devices), as the runner compiles it."""
+    from dynamo_tpu.engine import model_runner as mr
+    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+
+    b, v = 64, 4096
+    runner = mr.ModelRunner(EngineConfig(
+        model=ModelConfig(vocab_size=v, hidden_size=256,
+                          intermediate_size=512, num_layers=2,
+                          num_heads=8, num_kv_heads=4),
+        max_batch_size=b, max_model_len=128, kv_block_size=8,
+        num_kv_blocks=256, dtype=dtype, allow_random_weights=True,
+        prefill_buckets=[8, 16], tp_size=4))
+    step, called = runner._decode_step, {}
+    runner._decode_step = lambda *args: called.setdefault(
+        "out", step(*called.setdefault("args", args)))
+    w = runner.config.kv_width_buckets()[0]
+    zeros = lambda *shape: np.zeros(shape, np.int32)
+    runner.step(
+        zeros(b, 1), zeros(b, 1), zeros(b, w), zeros(b, 1),
+        np.ones(b, np.int32), zeros(b), np.full(b, 0.7, np.float32),
+        zeros(b), np.full(b, 0.9, np.float32),
+        seed_keys=np.zeros((b, 2), np.uint32), counters=zeros(b),
+        sample_slots=np.arange(b, dtype=np.int32), commit=np.ones(b, bool))
+    hlo = step.lower(*jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        called["args"])).compile().as_text()
+    return _collectives(hlo)
+
+
 def test_decode_step_at_tp4_moves_the_logits_once():
     """The whole decode step of a small model at tp=4 (four of the virtual
     devices), as the runner compiles it: the head makes the logits with
@@ -695,35 +998,24 @@ def test_decode_step_at_tp4_moves_the_logits_once():
     all-to-all of a device's logits, B·V / 4, and the top-logprobs
     branch's gather of B·V; the one-sort step this replaced moved 2.9
     B·V here, counted on its lowering of this same call)."""
-    from dynamo_tpu.engine import model_runner as mr
-    from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-
     b, v = 64, 4096
-    runner = mr.ModelRunner(EngineConfig(
-        model=ModelConfig(vocab_size=v, hidden_size=256,
-                          intermediate_size=512, num_layers=2,
-                          num_heads=8, num_kv_heads=4),
-        max_batch_size=b, max_model_len=128, kv_block_size=8,
-        num_kv_blocks=256, dtype="float32", allow_random_weights=True,
-        prefill_buckets=[8, 16], tp_size=4))
-    step, called = runner._decode_step, {}
-    runner._decode_step = lambda *args: called.setdefault(
-        "out", step(*called.setdefault("args", args)))
-    w = runner.config.kv_width_buckets()[0]
-    zeros = lambda *shape: np.zeros(shape, np.int32)
-    runner.step(
-        zeros(b, 1), zeros(b, 1), zeros(b, w), zeros(b, 1),
-        np.ones(b, np.int32), zeros(b), np.full(b, 0.7, np.float32),
-        zeros(b), np.full(b, 0.9, np.float32),
-        seed_keys=np.zeros((b, 2), np.uint32), counters=zeros(b),
-        sample_slots=np.arange(b, dtype=np.int32), commit=np.ones(b, bool))
-    hlo = step.lower(*jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
-        called["args"])).compile().as_text()
-
-    found = _collectives(hlo)
+    found = _tp4_step_collectives("float32")
     sampling = [c for c in found if "/sampling/" in c.op_name]
     assert sampling, "the all-to-all of the logits carries the scope"
     assert not [c for c in sampling if c.looped]
     assert any(c.looped for c in found)     # the layers' all-reduces are
     assert sum(c.elements for c in found) < 1.5 * b * v, found
+
+
+def test_decode_step_at_tp4_of_a_bfloat16_head_has_no_new_collective(
+        monkeypatch):
+    """The same step of a bfloat16 model, whose head hands the tail
+    bfloat16 logits: the search's length would be a trip count that four
+    devices have to agree on, so on a mesh of several the rows keep the
+    full search (``short_search``) and the compiled step holds the very
+    collectives of the program that knows no short search at all, none of
+    sampling's in a loop."""
+    found = _tp4_step_collectives("bfloat16")
+    assert not [c for c in found if "/sampling/" in c.op_name and c.looped]
+    monkeypatch.setattr(S, "short_search", lambda *a: False)
+    assert found == _tp4_step_collectives("bfloat16")
