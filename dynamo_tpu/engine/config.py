@@ -219,6 +219,20 @@ class ModelConfig:
     attention_value_scale: float = 1.0
     swa_sink_bias: bool = False
     full_sink_bias: bool = False
+    # Nemotron-H (models/nemotron_h.py, model_type "nemotron_h"):
+    # layer_types names each layer's one sublayer, "mamba" (Falcon-H1's
+    # mixer alone: state by slot), "attention" (GQA with no positional
+    # term: pages) or "moe" (routed experts and a shared expert); a layer
+    # is a norm, that sublayer and one residual add. moe_latent_size > 0:
+    # the routed experts work in a latent of that width (the token is
+    # projected into it once before the dispatch and the gated sum out
+    # of it once; the router and the shared expert read the hidden
+    # stream). mlp_hidden_act "relu2": an expert, routed or shared, is
+    # two matrices around relu(.)^2 and has no gate matrix ("silu": the
+    # SwiGLU of every other family). The shared expert's width is
+    # shared_intermediate_size.
+    moe_latent_size: int = 0
+    mlp_hidden_act: str = "silu"
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -241,6 +255,15 @@ class ModelConfig:
                 f"a share of {self.num_experts} experts, rank "
                 f"{self.expert_rank}, does not divide the published "
                 f"{self.experts_of}")
+
+    def num_moe_layers(self) -> int:
+        """Layers that hold routed experts: those ``layer_types`` names
+        ``moe`` where it names any (a trunk whose layers are one
+        sublayer each: models/nemotron_h.py), else every layer past the
+        dense prefix."""
+        if "moe" in self.layer_types:
+            return self.layer_types.count("moe")
+        return self.num_layers - min(self.first_k_dense_replace, self.num_layers)
 
     @classmethod
     def from_hf_config(cls, config: dict) -> "ModelConfig":

@@ -610,8 +610,7 @@ class ModelRunner:
             return
         self.moe_counts = jax.device_put(
             np.zeros((2, 4), np.int32), NamedSharding(self.mesh, P()))
-        n_moe = cfg.num_layers - min(cfg.first_k_dense_replace, cfg.num_layers)
-        slots_a_step = cfg.num_experts * n_moe     # the experts held
+        slots_a_step = cfg.num_experts * cfg.num_moe_layers()   # those held
         last = np.zeros((2, 4), np.int64)
         lock = threading.Lock()
 

@@ -14,7 +14,8 @@ mixer and the records by slot, ``mixtral`` the router and the grouped
 experts. A family whose layers are of more than one kind takes from
 ``models/trunk.py`` the walk over them (``walk_runs`` for weights
 stacked by run, ``walk_periods`` for weights stacked by kind behind a
-dense prefix), its ``forward`` over its ``forward_counted``
+dense prefix, ``walk_kinds`` for layers of one sublayer each stacked by
+kind), its ``forward`` over its ``forward_counted``
 (``forward_over``) and a side of its cache (``SlotCache``,
 ``KindCache``), and writes no loop over layers of its own; a family
 imports a sibling only for what it declares over it (its
@@ -203,6 +204,13 @@ FAMILIES = (
                     "what every sublayer adds; model_family {family!r} does "
                     "not (models/granite_hybrid.py is selected by model_type "
                     "granitemoehybrid)"),
+    Family("nemotron_h", model_types=("nemotron_h",),
+           field="moe_latent_size", reads=("mamba_d_ssm", "layer_types"),
+           unserved="moe_latent_size={value} needs a family whose routed "
+                    "experts work in a latent the token is projected into "
+                    "once; model_family {family!r} dispatches the hidden "
+                    "stream (models/nemotron_h.py is selected by model_type "
+                    "nemotron_h)"),
     Family("sdar", model_types=("sdar_moe",), field="block_length",
            unserved="block_length={value} needs a family whose decode unit "
                     "is a block of masked positions; model_family {family!r} "

@@ -97,8 +97,8 @@ from . import afmoe
 from .deepseek import (mla_attention, mla_project, mla_softmax_scale,
                        scatter_rows_stacked)
 from .llama import apply_rope, lm_logits, rms_norm
-from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
-                      split_expert_stacks)
+from .mixtral import (expert_share_fields, make_moe_mlp_fn,
+                      random_expert_stacks, split_expert_stacks)
 from .quant import dense
 from .trunk import KindCache, forward_over, walk_periods, window_slots
 
@@ -191,7 +191,6 @@ def config_fields(config: dict) -> dict:
             "from the query's latent) or without a shared expert "
             "(models/dots3.py computes both)")
     held = int(config.get("n_routed_experts", 0) or 0)
-    share = config.get("expert_share") or {}
     swa = {name: int(config[f"swa_{key}"]) for name, key in (
         ("swa_num_heads", "num_attention_heads"),
         ("swa_q_lora_rank", "q_lora_rank"),
@@ -208,10 +207,7 @@ def config_fields(config: dict) -> dict:
         mla_lora_rescale=bool(config.get("apply_mla_qkv_lora_rescale")),
         attention_gate="headwise",
         n_group=1, topk_group=1,
-        # ModelConfig refuses a share that does not divide the published
-        # count, or a rank past the last share
-        experts_of=int(share.get("of_experts", held)) if share else 0,
-        expert_rank=int(share.get("rank", 0)),
+        **expert_share_fields(config, held),
     )
 
 

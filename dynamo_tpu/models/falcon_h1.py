@@ -99,6 +99,9 @@ SEQUENCE_STATE = SequenceState(
 
 # published keys only this family computes (models.published):
 # its own, and those of other published trunks with recurrent layers
+# (hybrid_override_pattern, conv_kernel and the two prefixes are
+# nemotron_h's under its own model_type, as the mixer's keys are
+# Granite's under granitemoehybrid; under a third they are refused here)
 CLAIMED_KEYS = (
     "layers_block_type", "hybrid_override_pattern", "linear_num_value_heads",
     "linear_conv_kernel_dim", "state_size", "time_step_rank", "rwkv_version",
@@ -111,7 +114,9 @@ CLAIM = ("recurrent-layer keys ({keys}, ...) and no family here implements "
          "it (falcon_h1 is the state-space family with attention beside the "
          "mixer in every layer, models/falcon_h1.py; granite_hybrid the one "
          "whose layers are a mixer or attention by layer_types, "
-         "models/granite_hybrid.py; minicpm_sala the linear-attention one, "
+         "models/granite_hybrid.py; nemotron_h the one whose layers are a "
+         "mixer, attention or experts alone by hybrid_override_pattern, "
+         "models/nemotron_h.py; minicpm_sala the linear-attention one, "
          "models/minicpm_sala.py)")
 
 
@@ -222,6 +227,26 @@ def init_mixer(cfg: ModelConfig, keys, l: int, dtype, in_gain=1.0,
         "ssm_out": (jax.random.normal(k_out, (l, d_ssm, d), jnp.float32)
                     * (out_gain * d_ssm ** -0.5)).astype(dtype),
     }
+
+
+def remember_long(cfg: ModelConfig, run: Params, k_horizon, k_bias,
+                  horizon: Tuple[float, float],
+                  bc_bias: Tuple[float, float]) -> Params:
+    """``init_mixer``'s layers redrawn so that the state counts in a
+    comparison (models/granite_hybrid.py ``STATE_HORIZON`` and
+    ``BC_CONV_BIAS`` say why): a head forgets after ``1 / (Δ · A)``
+    tokens, drawn log-uniform in ``horizon``, ``A = 1 / (Δ · horizon)``
+    with ``Δ = softplus(dt_bias)``; the conv's bias under the B and C
+    channels (those past x) uniform in ``bc_bias``."""
+    lo, hi = horizon
+    tokens = jnp.exp(jax.random.uniform(
+        k_horizon, run["A_log"].shape, jnp.float32, jnp.log(lo), jnp.log(hi)))
+    run["A_log"] = -jnp.log(tokens * jax.nn.softplus(run["dt_bias"]))
+    bc = run["conv_b"][:, cfg.mamba_d_ssm:]
+    run["conv_b"] = run["conv_b"].at[:, cfg.mamba_d_ssm:].set(
+        jax.random.uniform(k_bias, bc.shape, jnp.float32,
+                           *bc_bias).astype(run["conv_b"].dtype))
+    return run
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:

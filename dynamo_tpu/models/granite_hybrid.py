@@ -64,8 +64,8 @@ from . import falcon_h1
 from .falcon_h1 import conv_dim, make_ssm_fn, ssm_record_shape
 from .llama import (layer_runs, lm_logits, make_gqa_attn_fn, rms_norm,
                     run_specs)
-from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
-                      split_expert_stacks)
+from .mixtral import (expert_share_fields, make_moe_mlp_fn,
+                      random_expert_stacks, split_expert_stacks)
 from .trunk import SlotCache, forward_over, scaled, walk_runs
 
 Params = Dict[str, Any]
@@ -152,7 +152,6 @@ def config_fields(config: dict) -> dict:
         raise NotImplementedError(
             "granitemoehybrid without routed experts or without a shared "
             "expert (models/granite_hybrid.py computes both in every layer)")
-    share = config.get("expert_share") or {}
     return dict(
         layer_types=kinds,
         mamba_d_ssm=d_ssm, mamba_n_heads=heads, mamba_d_head=d_head,
@@ -169,10 +168,7 @@ def config_fields(config: dict) -> dict:
         moe_intermediate_size=int(config["intermediate_size"]),
         shared_intermediate_size=int(config["shared_intermediate_size"]),
         moe_scoring_func="softmax", norm_topk_prob=True,
-        # ModelConfig refuses a share that does not divide the published
-        # count, or a rank past the last share
-        experts_of=int(share.get("of_experts", held)) if share else 0,
-        expert_rank=int(share.get("rank", 0)),
+        **expert_share_fields(config, held),
     )
 
 
@@ -273,17 +269,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         if kind == MAMBA:
             run = falcon_h1.init_mixer(cfg, keys[:6], n, dtype,
                                        out_gain=1.0 / res)
-            lo, hi = STATE_HORIZON
-            horizon = jnp.exp(jax.random.uniform(
-                keys[13], run["A_log"].shape, jnp.float32,
-                jnp.log(lo), jnp.log(hi)))
-            # A = 1 / (Δ · horizon), Δ = softplus(dt_bias)
-            run["A_log"] = -jnp.log(horizon * jax.nn.softplus(run["dt_bias"]))
-            # the conv's bias under B and C (the channels past x)
-            bc = run["conv_b"][:, cfg.mamba_d_ssm:]
-            run["conv_b"] = run["conv_b"].at[:, cfg.mamba_d_ssm:].set(
-                jax.random.uniform(keys[14], bc.shape, jnp.float32,
-                                   *BC_CONV_BIAS).astype(dtype))
+            run = falcon_h1.remember_long(cfg, run, keys[13], keys[14],
+                                          STATE_HORIZON, BC_CONV_BIAS)
         else:
             run = {
                 "wq": w(keys[0], (n, d, h * hd), d,

@@ -80,8 +80,8 @@ from . import falcon_h1
 from .deepseek import make_mla_attn_fn, mla_softmax_scale
 from .falcon_h1 import slot_records
 from .llama import lm_logits, rms_norm
-from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
-                      split_expert_stacks)
+from .mixtral import (expert_share_fields, make_moe_mlp_fn,
+                      random_expert_stacks, split_expert_stacks)
 from .quant import QuantizedWeight, dense
 from .trunk import SlotCache, forward_over, walk_periods
 
@@ -157,7 +157,6 @@ def config_fields(config: dict) -> dict:
             "kimi_linear without routed experts or without a shared expert "
             "(models/kimi_linear.py computes both behind every layer past "
             "first_k_dense_replace)")
-    share = config.get("expert_share") or {}
     return dict(
         layer_types=tuple(KDA if i in kda else MLA
                           for i in range(1, layers + 1)),
@@ -172,10 +171,7 @@ def config_fields(config: dict) -> dict:
         # one group: use_grouped_topk is then a plain top-k; the
         # correction bias steers the pick only (DeepSeek-V3's router)
         n_group=1, topk_group=1, topk_method="noaux_tc",
-        # ModelConfig refuses a share that does not divide the published
-        # count, or a rank past the last share
-        experts_of=int(share.get("of_experts", held)) if share else 0,
-        expert_rank=int(share.get("rank", 0)),
+        **expert_share_fields(config, held),
     )
 
 

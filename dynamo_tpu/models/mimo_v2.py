@@ -73,8 +73,8 @@ from ..ops.attention import (LANE, attention, lane_pad, scatter_stacked,
 from ..ops.live_rows import decode_live_rows
 from . import afmoe
 from .llama import apply_rope, lm_logits, rms_norm
-from .mixtral import (make_moe_mlp_fn, random_expert_stacks,
-                      split_expert_stacks)
+from .mixtral import (expert_share_fields, make_moe_mlp_fn,
+                      random_expert_stacks, split_expert_stacks)
 from .quant import dense
 from .trunk import KindCache, forward_over, scaled, walk_periods, window_slots
 
@@ -208,7 +208,6 @@ def config_fields(config: dict) -> dict:
         raise NotImplementedError(
             "mimo_v2 without routed experts (models/mimo_v2.py computes "
             "them behind every layer past the dense prefix)")
-    share = config.get("expert_share") or {}
     return dict(
         layer_types=tuple(WINDOW if p else FULL for p in pattern),
         sliding_window=window,
@@ -226,10 +225,7 @@ def config_fields(config: dict) -> dict:
         n_shared_experts=0, moe_scoring_func="sigmoid", norm_topk_prob=True,
         routed_scaling_factor=1.0, n_group=1, topk_group=1,
         topk_method="noaux_tc",
-        # ModelConfig refuses a share that does not divide the published
-        # count, or a rank past the last share
-        experts_of=int(share.get("of_experts", held)) if share else 0,
-        expert_rank=int(share.get("rank", 0)),
+        **expert_share_fields(config, held),
     )
 
 

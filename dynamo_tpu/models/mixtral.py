@@ -43,7 +43,7 @@ KVCache = Tuple[jax.Array, jax.Array]
 
 __all__ = [
     "init_params", "init_kv_cache", "forward", "param_specs", "moe_mlp",
-    "make_moe_mlp_fn", "routed_experts", "route_top_k",
+    "make_moe_mlp_fn", "routed_experts", "route_top_k", "expert_share_fields",
 ]
 
 
@@ -138,6 +138,20 @@ def held_experts(n_experts: int, held=None, ep_axis: Optional[str] = None):
         count = count // jax.lax.axis_size(ep_axis)
         first = first + lax.axis_index(ep_axis) * count
     return first, count
+
+
+def expert_share_fields(config: dict, held: int) -> dict:
+    """ModelConfig's ``experts_of`` and ``expert_rank`` from a
+    configuration's ``expert_share`` (``{"of_experts", "rank"}``: the one
+    key a published config lacks, for a configuration that holds one
+    expert-parallel rank's ``held`` experts of the published count).
+    Without it every expert is held. ModelConfig refuses a share that
+    does not divide the published count, or a rank past the last
+    share."""
+    share = config.get("expert_share") or {}
+    return dict(
+        experts_of=int(share.get("of_experts", held)) if share else 0,
+        expert_rank=int(share.get("rank", 0)))
 
 
 def routed_experts(x, gate_vals, gate_idx, valid, n_experts: int, experts,
@@ -248,6 +262,19 @@ def _swiglu_experts(xs, eid, group_sizes, layer, w_gate, w_up, w_down):
 _SWIGLU_SPECS = (("ep", None, "tp"), ("ep", None, "tp"), ("ep", "tp", None))
 
 
+def _relu2_experts(xs, eid, group_sizes, layer, w_up, w_down):
+    """Two matrices around a squared ReLU, no gate matrix
+    (``mlp_hidden_act: relu2``, models/nemotron_h.py)."""
+    h = jnp.square(jax.nn.relu(expert_matmul(xs, w_up, group_sizes, eid, layer)))
+    return expert_matmul(h, w_down, group_sizes, eid, layer)
+
+
+# activation -> (the expert body, its weights' specs): ``moe_mlp``'s
+# ``w_gate`` is None where the body has no gate matrix
+_EXPERT_BODIES = {"silu": (_swiglu_experts, _SWIGLU_SPECS),
+                  "relu2": (_relu2_experts, _SWIGLU_SPECS[1:])}
+
+
 def moe_mlp(
     x: jax.Array,         # [T, D] flattened tokens
     router_w: jax.Array,  # [D, E]
@@ -266,9 +293,15 @@ def moe_mlp(
     mesh=None,                          # GSPMD caller's mesh
     layer=None,                         # w_* are every layer's stacks [L, E, ..]
     held=None,                          # (first, count): w_* are a share of E
+    rows: Optional[jax.Array] = None,   # [T, K]: what is dispatched, if not x
+    activation: str = "silu",           # "silu" (SwiGLU) | "relu2" (w_gate None)
 ):
-    """Top-k routed SwiGLU experts, drop-free (routed_experts) ->
-    (y [T, D], routing_stats).
+    """Top-k routed experts, drop-free (routed_experts) -> (y [T, D],
+    routing_stats): SwiGLU experts, or with ``activation="relu2"`` two
+    matrices around a squared ReLU (``w_gate`` is then None). ``rows``:
+    the router reads ``x`` and the experts ``rows``, a token's row in
+    the width the experts work in (a latent: models/nemotron_h.py), and
+    ``y`` is that wide.
 
     ``ep_axis``: inside a manual shard_map where the expert stacks are
     sharded over that mesh axis (the pipelined pp x ep program), the
@@ -288,14 +321,18 @@ def moe_mlp(
             routed_scaling=routed_scaling, router_bias=router_bias,
             n_group=n_group, topk_group=topk_group)
 
+    experts, specs = _EXPERT_BODIES[activation]
+    weights = tuple(w for w in (w_gate, w_up, w_down) if w is not None)
+
     def fn(x, gate_vals, gate_idx, valid, layer, weights, ep_axis, tp_axis):
         del tp_axis  # bias-free stacks: the output is a genuine tp-partial
         return routed_experts(x, gate_vals, gate_idx, valid, e,
-                              _swiglu_experts, weights, ep_axis=ep_axis,
+                              experts, weights, ep_axis=ep_axis,
                               layer=layer, held=held)
 
-    y = _dispatch(mesh, ep_axis, None, (w_gate, w_up, w_down), _SWIGLU_SPECS,
-                  fn, x, gate_vals, gate_idx, valid, layer).astype(x.dtype)
+    y = _dispatch(mesh, ep_axis, None, weights, specs, fn,
+                  x if rows is None else rows, gate_vals, gate_idx, valid,
+                  layer).astype(x.dtype)
     return y, routing_stats(gate_idx, valid, e, held)
 
 
@@ -542,7 +579,11 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
     already a genuine tp-partial. ``mesh``: the GSPMD caller's mesh.
     ``stacks`` (split_expert_stacks): every
     layer's expert weights, whole; the layer's parameters then carry
-    ``moe_layer`` in their place and the kernel indexes the layer."""
+    ``moe_layer`` in their place and the kernel indexes the layer.
+    ``cfg.moe_latent_size`` (models/nemotron_h.py): the experts work in
+    a latent, ``w_latent_in`` before the dispatch and ``w_latent_out``
+    behind the combine (scope ``moe_latent``); ``cfg.mlp_hidden_act``
+    ``relu2``: experts, routed and shared, of two matrices."""
     del tp_axis
     valid = (slot_mapping.reshape(b * s) >= 0).astype(jnp.float32)
     # one rank's share of the published experts (ModelConfig.experts_of)
@@ -551,10 +592,16 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
 
     def mlp(x, layer_params):
         w = layer_params if stacks is None else stacks
+        flat, rows = x.reshape(b * s, -1), None
+        if cfg.moe_latent_size:
+            # the experts work in a latent: a token goes into it once,
+            # before the dispatch; the router reads the stream itself
+            with jax.named_scope("moe_latent"):
+                rows = dense(flat, layer_params["w_latent_in"])
         y, stats = moe_mlp(
-            x.reshape(b * s, -1),
+            flat,
             layer_params["router"],
-            w["w_gate"], w["w_up"], w["w_down"],
+            w.get("w_gate"), w["w_up"], w["w_down"],
             cfg.num_experts_per_tok, valid=valid,
             scoring=cfg.moe_scoring_func, norm_topk=cfg.norm_topk_prob,
             routed_scaling=cfg.routed_scaling_factor,
@@ -562,17 +609,25 @@ def make_moe_mlp_fn(cfg: ModelConfig, b: int, s: int, slot_mapping: jax.Array,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             ep_axis=ep_axis, mesh=mesh,
             layer=None if stacks is None else layer_params["moe_layer"],
-            held=held,
+            held=held, rows=rows, activation=cfg.mlp_hidden_act,
         )
+        if rows is not None:
+            # the gated sum leaves the latent once
+            with jax.named_scope("moe_latent"):
+                y = dense(y, layer_params["w_latent_out"])
         y = y.reshape(b, s, -1)
-        if "w_sh_gate" in layer_params:
-            # always-on shared expert(s) alongside the routed ones
+        if "w_sh_up" in layer_params:
+            # always-on shared expert(s) alongside the routed ones: a
+            # SwiGLU, or without a gate matrix two matrices around a
+            # squared ReLU (mlp_hidden_act relu2), on the stream itself
             with jax.named_scope("moe_shared"):
-                gate = jax.nn.silu(dense(x, layer_params["w_sh_gate"]))
-                sh = dense(
-                    gate * dense(x, layer_params["w_sh_up"]),
-                    layer_params["w_sh_down"],
-                )
+                if "w_sh_gate" in layer_params:
+                    gate = jax.nn.silu(dense(x, layer_params["w_sh_gate"]))
+                    hidden = gate * dense(x, layer_params["w_sh_up"])
+                else:
+                    hidden = jnp.square(jax.nn.relu(
+                        dense(x, layer_params["w_sh_up"])))
+                sh = dense(hidden, layer_params["w_sh_down"])
                 if ep_axis is not None:
                     # the caller psums the routed PARTIAL over ep (and
                     # tp); the shared expert's weights replicate across

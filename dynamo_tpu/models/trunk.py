@@ -3,7 +3,9 @@ that is not a bare page stack is made of, the two walks over a trunk's
 layers, and the ``forward`` over a ``forward_counted``.
 
 A family whose layers are of more than one kind stacks its weights one
-of two ways, and takes the walk that goes with it:
+of two ways, and takes the walk that goes with it (``walk_kinds`` is
+``walk_periods``' sibling for a trunk whose layers are one sublayer
+each, no feed-forward behind a mixer: models/nemotron_h.py):
 
 - **by kind** (``params[kind]``, ``params["dense"]``, ``params["moe"]``:
   models/kimi_linear.py, models/dots3.py): ``walk_periods``. The dense
@@ -189,6 +191,72 @@ def walk_periods(params: Params, cfg: ModelConfig, pair, mixer, experts,
         carry, _ = jax.lax.scan(period, carry, periods)
     hidden, cache, stats, _ = carry
     return hidden, cache, stats
+
+
+def kind_periods(kinds, template):
+    """``kinds`` (one a layer) as periods of ``template`` (the kinds in
+    the order a period holds them): each period takes, kind by kind, as
+    many of the next layers as are of that kind, none included. ->
+    a row a period, ``(first, count)`` a kind of the template flattened:
+    the kind's first layer of the period, counted among its kind, and
+    how many follow. Any sequence of the template's kinds parses (at
+    worst a layer a period)."""
+    unknown = sorted(set(kinds) - set(template))
+    if unknown:
+        raise ValueError(f"layers of kinds {unknown}: not of {template}")
+    seen = {kind: 0 for kind in template}
+    periods, i = [], 0
+    while i < len(kinds):
+        row = []
+        for kind in template:
+            n = 0
+            while i + n < len(kinds) and kinds[i + n] == kind:
+                n += 1
+            row += [seen[kind], n]
+            seen[kind] += n
+            i += n
+        periods.append(row)
+    return periods
+
+
+def walk_kinds(kinds, template, stacks: Params, body, carry):
+    """A trunk whose layers are one sublayer each, stacked by kind
+    (``stacks[kind]``: models/nemotron_h.py), walked: -> carry.
+
+    One ``lax.scan`` over the periods of ``template``
+    (``kind_periods``); inside a period each kind's layers in turn, so a
+    program holds one body a kind whatever the pattern says. A kind that
+    has the same count in every period is unrolled in the period's body
+    (``M E`` of a pattern ``M E M * E``: no loop around them); one whose
+    count differs is a ``fori_loop`` of traced length over its stack
+    (the ``*``: none or one). ``body(kind, layer_params, carry, i) ->
+    carry``: layer ``i`` of ``kind``, with its norm, its residual add
+    and its named scope; the carry is any pytree and is carried as it
+    is handed in."""
+    periods = kind_periods(kinds, template)
+    if not periods:
+        return carry
+    columns = list(zip(*periods))
+
+    def period(carry, p):
+        for at, kind in enumerate(template):
+            first, n = p[2 * at], p[2 * at + 1]
+
+            def layer(j, carry, kind=kind, first=first):
+                return body(kind, layer_at(stacks[kind], first + j), carry,
+                            first + j)
+
+            counts = set(columns[2 * at + 1])
+            if len(counts) == 1:
+                for j in range(counts.pop()):
+                    carry = layer(j, carry)
+            else:
+                carry = jax.lax.fori_loop(0, n, layer, carry)
+        return carry, None
+
+    carry, _ = jax.lax.scan(
+        period, carry, tuple(jnp.asarray(c, jnp.int32) for c in columns))
+    return carry
 
 
 def walk_runs(runs, stacked, layer_of, hidden, cache: Dict, stats=None):

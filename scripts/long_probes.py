@@ -54,7 +54,8 @@ layers' keys and values in fp8; Kimi Linear's ``state``,
 ``scalar_decay``, ``rope_on_mla`` and ``beta_one``, the four faults above
 made in the reference; dots3-note's ``half_topk``, ``no_relu`` (the
 indexer's scores without their ReLU), ``window_short``, ``window_long``
-and ``no_gate`` (the gate a head on the attention output left out)):
+and ``no_gate`` (the gate a head on the attention output left out);
+Nemotron-H's ``state``, ``router`` and ``pages`` as Granite's, PR 62):
 the control's log-probabilities
 stand in the served program's place in ``check_probes``, each probe on
 its own and all together as the harness compares them. Nothing is

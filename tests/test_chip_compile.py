@@ -1194,6 +1194,141 @@ def test_mimo_step_keeps_both_page_stacks_in_place(
     assert mem.temp_size_in_bytes < (64 if tokens == 1 else 1024) * 2 ** 20
 
 
+# Nemotron 3 Super as one chip serves it (benchmark/configs/
+# nemotron-3-super-ep4.json): the stage's eleven letters MEMEMEM*EME,
+# five of them a mixer of 128 heads of 64 over a state of 128 with
+# **eight** groups of B and C (sixteen heads = 8 tiles a group: a block
+# of the state kernel is 32 tiles, four whole groups), one NoPE attention
+# layer of 32 query heads over 2 kv heads, five expert layers of 128 of
+# 512 experts held, two matrices each in a latent of 1024 (contractions
+# of 1024 and 2688), at 128 decode rows
+def test_ssm_decode_kernel_compiles_at_nemotrons_eight_groups(
+        one_chip, no_compile_cache, monkeypatch):
+    from dynamo_tpu.ops import ssm
+    from dynamo_tpu.ops.live_rows import live_row_list
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssm.lane_heads(64, 16) == 2
+    # 64 tiles of 64 KiB a row, 8 a group: a block of 32 holds four groups
+    assert ssm._tile_block(64, 8, 128 * 128 * 4) == 32
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    layers, slots, h, p, n, g = 5, 128, 128, 64, 128, 8
+    f32, act = jnp.float32, jnp.bfloat16
+    record = ssm.record_shape(h, p, n, h // g)
+    assert record == (64, 128, 128)
+
+    def f(x, dt, a, bm, cm, d, records, li, live):
+        return ssm.ssm_decode_step(x, dt, a, bm, cm, d, records, li,
+                                   live_row_list(live))
+
+    compiled = jax.jit(f, donate_argnums=(6,)).lower(
+        s((slots, h, p), act), s((slots, h), f32), s((h,), f32),
+        s((slots, g, n), act), s((slots, g, n), act), s((h,), f32),
+        s((layers, slots) + record, f32), s((), jnp.int32),
+        s((slots,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    traced, = [t for t in ssm.blocks_traced() if {
+        "heads": h, "p": p, "n": n, "heads_per_group": 16}.items() <= t.items()]
+    assert (traced["lane_heads"], traced["tiles_per_block"],
+            traced["groups_per_block"], traced["block_bytes"]) == (
+        2, 32, 4, 2 << 20)
+
+
+@pytest.mark.parametrize("rows", [2816, 22528])
+@pytest.mark.parametrize("k,n", [(1024, 2688), (2688, 1024)])
+def test_grouped_products_compile_at_nemotrons_latent_experts(
+        one_chip, no_compile_cache, monkeypatch, rows, k, n):
+    """A decode step's 128 x 22 picks and a 1024-token chunk's, over the
+    128 experts held of the five expert layers: 2688 columns are five
+    tiles of 512 and one of 128, a contraction of 2688 is whole."""
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    assert gm._tiling(rows, 1024, 2688) == (128, 1024, 512)
+    assert gm._tiling(rows, 2688, 1024) == (128, 2688, 512)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(gm.grouped_matmul).lower(
+        s((rows, k), jnp.bfloat16), s((5, 128, k, n), jnp.bfloat16),
+        s((128,), jnp.int32), s((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _nemotron_step(one_chip, rows, tokens, width):
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.models import nemotron_h
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nemotron-3-super-ep4.json")) as f:
+        hf = json.load(f)
+    cfg = ModelConfig.from_hf_config(hf)
+    serve = hf["serve"]
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(s, jax.eval_shape(
+        lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0),
+                                       jnp.bfloat16)))
+    cache = jax.tree.map(s, jax.eval_shape(
+        lambda: nemotron_h.init_kv_cache(
+            cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+            num_slots=serve["max_batch_size"])))
+    # pages over the attention layer alone, records over the five mixers
+    assert cache[0].kv.shape == (1, 24576, 16, 2, 128)
+    assert cache[0].state.shape == (5, 128, 64, 128, 128)
+    assert params["moe"]["router"].shape == (5, 4096, 512)
+    assert params["moe"]["w_up"].shape == (5, 128, 1024, 2688)
+    assert params["moe"]["w_down"].shape == (5, 128, 2688, 1024)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, k_side, v_side, tokens, positions, bt, slots, ctx, ss):
+        return nemotron_h.forward_counted(
+            params, cfg, tokens, positions, (k_side, v_side), bt, slots, ctx,
+            state_slots=ss)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, *cache, i32(rows, tokens), i32(rows, tokens), i32(rows, width),
+        i32(rows, tokens), i32(rows), i32(rows)).compile()
+
+
+def test_nemotron_decode_step_at_128_rows_updates_state_and_pages_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole trunk of a decode step at the benchmark's size, 128
+    rows: the state kernel, the attention decode kernel and the two
+    grouped products are in it once each (one body a kind, whatever the
+    pattern), and neither the recurrent state (2.68 GB at 128 slots) nor
+    the expert stacks (7.05 GB) are copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _nemotron_step(one_chip, 128, 1, 192)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_decode_step" in text
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) <= 6
+    for held in ("f32[5,128,64,128,128]", "bf16[5,128,1024,2688]",
+                 "bf16[5,128,2688,1024]", "bf16[640,1024,2688]"):
+        ops = [(re.search(r" ([a-z][a-z\-]*)\(", ln.split(" = ", 1)[1]).group(1), ln)
+               for ln in text.splitlines()[1:] if held in ln and " = " in ln]
+        assert {op for op, _ in ops} <= {
+            "parameter", "get-tuple-element", "tuple", "while", "bitcast",
+            "custom-call"}, (held, {op for op, _ in ops})
+    mem = compiled.memory_analysis()
+    print("decode step: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    # (the head is not the trunk's: 12.42 GB held less its 0.27)
+    assert mem.argument_size_in_bytes > 12.1e9
+    assert mem.temp_size_in_bytes < 2 ** 28
+    # (a 1024-token prefill chunk compiled the same way leaves 0.28 GB of
+    # temporaries: _nemotron_step(one_chip, 1, 1024, 192), 45 s, by hand)
+
+
 def _decode_trunk(ll, topo, config):
     """The decode trunk of a benchmark configuration compiled for the
     described chips (the real tp mesh where ``serve`` asks for one), once

@@ -271,6 +271,7 @@ OPTIONAL = {name for bullet in _OPTIONAL_DOC.split("\n- ")[1:]
 FIELD_VALUES = {"hc_mult": 4, "mamba_d_ssm": 64, "block_length": 4,
                 "residual_multiplier": 0.22, "kda_num_heads": 4,
                 "index_topk": 32, "swa_num_kv_heads": 8,
+                "moe_latent_size": 32,
                 "mixer_types": ("minicpm4", "lightning-attn"),
                 "layer_types": ("sliding_attention", "full_attention")}
 
@@ -496,8 +497,53 @@ def test_a_window_and_full_config_with_its_own_kv_heads_reaches_its_own_row():
     assert ModelConfig.from_hf_config(_DOTS3).model_family == "dots3"
 
 
+def test_a_pattern_of_single_sublayers_reaches_its_own_row():
+    """``nemotron_h`` has routed experts, mixtral's shape rule, and the
+    keys Falcon-H1 claims for every published trunk with recurrent layers
+    (``hybrid_override_pattern``, ``conv_kernel``, ``mamba_*``, ``ssm_*``)
+    and ``expert_share`` (Granite's, Kimi's): its row stands before the
+    rows told by shape and takes it by name, those keys its own under
+    this ``model_type``; the letters become the ``layer_types`` of one
+    sublayer a layer, and a config of another family with a stray
+    ``moe_latent_size`` is refused."""
+    hf = {"model_type": "nemotron_h", "vocab_size": 64, "hidden_size": 32,
+          "num_hidden_layers": 5, "hybrid_override_pattern": "MEM*E",
+          "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 16,
+          "mamba_num_heads": 4, "mamba_head_dim": 16, "ssm_state_size": 8,
+          "n_groups": 2, "conv_kernel": 4, "chunk_size": 16, "expand": 2,
+          "mlp_hidden_act": "relu2", "moe_latent_size": 16,
+          "moe_intermediate_size": 24, "intermediate_size": 24,
+          "moe_shared_expert_intermediate_size": 48, "n_shared_experts": 1,
+          "n_routed_experts": 2, "expert_share": {"of_experts": 8, "rank": 3},
+          "num_experts_per_tok": 3, "routed_scaling_factor": 5,
+          "num_nextn_predict_layers": 1, "mtp_hybrid_override_pattern": "*E"}
+    cfg = ModelConfig.from_hf_config(hf)
+    assert cfg.model_family == "nemotron_h"
+    assert models.family(cfg).name == "nemotron_h"
+    assert models.FAMILIES.index(models.family(cfg)) < next(
+        i for i, r in enumerate(models.FAMILIES) if r.name == "mixtral")
+    assert cfg.layer_types == ("mamba", "moe", "mamba", "attention", "moe")
+    assert (cfg.mamba_d_ssm, cfg.mamba_n_groups, cfg.mamba_d_state) == (64, 2, 8)
+    assert (cfg.moe_latent_size, cfg.mlp_hidden_act,
+            cfg.shared_intermediate_size) == (16, "relu2", 48)
+    assert (cfg.num_experts, cfg.experts_of, cfg.expert_rank) == (2, 8, 3)
+    assert cfg.residual_multiplier == 1.0 and cfg.kv_lora_rank == 0
+    assert models.family(cfg).module.SEQUENCE_STATE.slots
+    import dataclasses
+    # mixtral's row would take it by shape, and is refused its fields
+    with pytest.raises(NotImplementedError, match="mamba_d_ssm"):
+        models.resolve(dataclasses.replace(cfg, model_family=""))
+    with pytest.raises(NotImplementedError, match="moe_latent_size"):
+        models.resolve(dataclasses.replace(cfg, model_family="",
+                                           mamba_d_ssm=0, layer_types=()))
+    # Granite's own config keeps its mamba_* keys and its expert_share
+    assert ModelConfig.from_hf_config(_GRANITE).model_family == "granite_hybrid"
+    assert ModelConfig.from_hf_config(_KIMI).model_family == "kimi_linear"
+
+
 @pytest.mark.parametrize("keys,named", [
     (_MIXER, "mamba_"),
+    ({"hybrid_override_pattern": "MEM*E"}, "hybrid_override_pattern"),
     ({"layer_types": ["mamba", "attention"]}, "layer_types"),
     ({"layer_types": ["sliding_attention", "full_attention"]}, "layer_types"),
     ({"residual_multiplier": 0.22}, "residual_multiplier"),
@@ -511,8 +557,9 @@ def test_a_third_model_type_with_shared_keys_is_refused_by_name(keys, named):
                        match=f"some_other_trunk.*{named}") as e:
         ModelConfig.from_hf_config({**PLAIN_HF, **keys})
     assert "granite_hybrid" in str(e.value)
-    if named == "mamba_":
+    if named in ("mamba_", "hybrid_override_pattern"):
         assert "falcon_h1" in str(e.value) and "minicpm_sala" in str(e.value)
+        assert "nemotron_h" in str(e.value)
     # and under a family that does not compute them
     with pytest.raises(NotImplementedError, match=named):
         ModelConfig.from_hf_config(
