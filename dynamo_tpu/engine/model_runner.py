@@ -528,13 +528,15 @@ class ModelRunner:
             1, config.num_kv_blocks * config.kv_block_size
         )
         if self.keeps.window_pool:
+            window = _leaf_bytes(tuple(s.window for s in self.kv_cache))
             logger.info(
                 "two kinds of page: full %d pages (%.3f GB), window %d "
                 "pages (%.3f GB; %d a decoding row, %d a row in prefill)",
                 config.num_kv_blocks, _leaf_bytes(pages) / 1e9,
-                config.window_pool_pages(), rest / 1e9,
+                config.window_pool_pages(), window / 1e9,
                 config.window_pages_a_row(),
                 config.window_pages_a_row(config.prefill_chunk_tokens()))
+            rest -= window      # (what is left is by slot: models/dots3.py)
         if self.keeps.slots:
             self.compiles.registry.gauge(
                 "dynamo_engine_recurrent_state_bytes",
@@ -2321,6 +2323,7 @@ class ModelRunner:
                 cfg.model, cfg.num_kv_blocks, cfg.kv_block_size,
                 self.kv_dtype, num_slots=cfg.max_batch_size,
                 window_blocks=cfg.window_pool_pages(),
+                max_len=cfg.max_model_len,
             ))
             if cfg.pp_size > 1:
                 from ..parallel.pipeline import stage_cache

@@ -27,8 +27,9 @@ family's served path (docs/models.md, Adding a family).
 **Required** of every module, called by ``ModelRunner``:
 ``init_params(cfg, key, dtype)``, ``param_specs(params)``,
 ``init_kv_cache(cfg, num_blocks, block_size, dtype, num_slots=,
-window_blocks=)`` (the engine offers every family its decode slots and
-the window pool's pages; a family takes what it keeps),
+window_blocks=, max_len=)`` (the engine offers every family its decode
+slots, the window pool's pages and the longest sequence it admits; a
+family takes what it keeps),
 ``forward(params, cfg, tokens, positions, kv_cache, block_tables,
 slot_mapping, context_lens, mesh=, return_hidden=, state_slots=)``
 (each row's slot, for records by slot) and

@@ -268,7 +268,7 @@ param_specs = run_specs    # tp > 1 and ep > 1 are refused for the family
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(KindCache(k full, k window), KindCache(v full, v window))``:
     ``num_blocks`` pages a full layer, ``window_blocks`` a window layer
     (page 0 of those is the one no sequence holds)."""

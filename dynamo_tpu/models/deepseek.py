@@ -92,7 +92,7 @@ def refuse_staged(config) -> None:
 
 def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
-    num_slots: int = 1, window_blocks: int = 1,
+    num_slots: int = 1, window_blocks: int = 1, max_len: int = 0,
 ) -> KVCache:
     """Compressed cache: c_kv [L,N,1,bs,r] + k_rope [L,N,1,bs,rd].
 

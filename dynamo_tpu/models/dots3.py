@@ -38,15 +38,15 @@ The served program absorbs ``W_uk`` into the query and applies ``W_uv``
 after attention (models/deepseek.py), so a token's cache line is the
 latent and the rope key (and the indexer's key in a full layer).
 
-**Two kinds of page, a page shape a kind.** A side of the cache is a
-``LatentKinds`` (``trunk.KindCache`` with the step's counters): the k
-side ``(full [Lf, N, 1, page, r' + rd'], window [Lw, Nw, 1, page,
-r_w'])``, the v side ``(full (indexer keys [Lf, N, 1, page, di'],),
-window rope keys [Lw, Nw, 1, page, rd_w'])``, a primed width its
-``lane_pad``. **A full layer's page holds a token's row whole**: the
-latent in lanes ``[:r]``, the rotated key in ``[r':r' + rd]``, zeros
-between and behind (640 lanes at rank 512 and a rope key of 64; with
-the indexer's 128, 1536 B a token in bfloat16).
+**Two kinds of page, a page shape a kind, and the indexer's keys by
+slot.** A side of the cache is a ``LatentKinds`` (``trunk.KindCache``
+with the step's counters and the records by slot): the k side ``(full
+[Lf, N, 1, page, r' + rd'], window [Lw, Nw, 1, page, r_w'])``, the v
+side ``(full nothing, window rope keys [Lw, Nw, 1, page, rd_w'], index
+[Lf, slots, T, di'])``, a primed width its ``lane_pad``. **A full
+layer's page holds a token's row whole**: the latent in lanes ``[:r]``,
+the rotated key in ``[r':r' + rd]``, zeros between and behind (640 lanes
+at rank 512 and a rope key of 64: 1280 B a token in bfloat16).
 Nothing walks a full layer's pages: decode looks the picked tokens' rows
 up one by one and a gather costs the chip by the index far more than by
 the byte (scripts/gather_sweep.py), so a row is one lookup, not a latent
@@ -55,11 +55,25 @@ and a rope key apart. The window kind keeps the two stacks
 The window kind's pages come from the allocator's second pool behind a
 table of their own and go back as they fall behind the window
 (models/afmoe.py says how the engine serves that; this family inherits
-its ``SEQUENCE_STATE``); the indexer's keys lie in the full kind's
-pages' geometry and are written through the same slots.
+its ``SEQUENCE_STATE``).
 
-**Routes.** Decode, full layer: the indexer's scores of the table's
-keys, the pick, one gather of the picked tokens' rows and one dense
+**The indexer's keys are records by slot, in sequence order**
+(``ops/latent_select.py`` says why): ``index[li, slot, p]`` is the key
+of the token at position ``p`` of the sequence in ``slot``, ``T``
+positions a slot (``record_len`` of the longest sequence the engine
+admits). The family's pages are private to a sequence already, so the
+keys need no page: a decode step's row *i* is slot *i* and reads ``[li,
+:b]`` as the product's operand; a prefill row names its slot
+(``state_slots``, as a family with recurrent state is told;
+``SEQUENCE_STATE.slots``). A step writes a token's key at its position;
+a slot is never cleared, because a key at or past ``context_len`` is
+never read. The records do not grow with the context: they are counted
+with the window kind's pages (``LatentKinds.rest``), 256 B a position a
+full layer in bfloat16, for every slot at its longest whatever the pool
+holds.
+
+**Routes.** Decode, full layer: the indexer's scores of the row's
+record under the table's width, the pick, one gather of the picked tokens' rows and one dense
 absorbed product over them (``ops/latent_select.picked_decode_attention``;
 XLA on every backend, a program of the width ladder). Decode, window layer: the
 latent decode kernel from the window's first page
@@ -91,7 +105,7 @@ from jax.sharding import PartitionSpec as P
 from ..engine.config import ModelConfig
 from ..ops.attention import lane_pad, pad_minor
 from ..ops.latent_select import (Indexer, blocked_latent_attention,
-                                 picked_decode_attention)
+                                 picked_decode_attention, record_len)
 from ..ops.live_rows import decode_live_rows
 from . import afmoe
 from .deepseek import (mla_attention, mla_project, mla_softmax_scale,
@@ -107,12 +121,15 @@ Params = Dict[str, Any]
 FULL, WINDOW = afmoe.GLOBAL, afmoe.LOCAL
 
 # afmoe's: the window kind's pages in a pool and behind a table of their
-# own, and every path refused for that; the share is stated, so the
-# mesh's ep axis stays refused
+# own, and every path refused for that (the paths a family with records
+# by slot refuses: falcon_h1's); records by slot beside them, so the
+# trunk is told each row's slot; the share is stated, so the mesh's ep
+# axis stays refused
 SEQUENCE_STATE = dataclasses.replace(
-    afmoe.SEQUENCE_STATE,
+    afmoe.SEQUENCE_STATE, slots=True,
     keeps="its window layers' latent pages in a pool and a table of their "
-          "own, and an indexer's key a token beside its full layers' pages",
+          "own, and an indexer's key a token by slot beside its full "
+          "layers' pages",
     refused={
         **afmoe.SEQUENCE_STATE.refused,
         "ep_size": "the expert stacks are kept whole and not sharded; one "
@@ -375,26 +392,38 @@ def step_counts(kv_cache):
 @dataclasses.dataclass
 class LatentKinds(KindCache):
     """A side of the cache: the full layers' pages and the window
-    layers', each kind with a page shape of its own, and the step's
-    counters (int32, wrapping: a reader takes differences; the k side's
-    are not used, the two sides have one structure as the engine shards
-    and donates them alike)."""
+    layers', each kind with a page shape of its own, the step's
+    counters (int32, wrapping: a reader takes differences) and the full
+    layers' indexer keys by slot (``index``). The v side has the
+    counters that count and the records, the k side neither's use (the
+    two sides have one structure as the engine shards and donates them
+    alike)."""
     counts: Any = None
+    index: Any = ()
+
+    @property
+    def rest(self):
+        """What does not grow with the context: the window kind's
+        pages and the records by slot."""
+        return self.window, self.index
 
 
-CACHE_SPEC = LatentKinds(full=P(), window=P(), counts=P())
+CACHE_SPEC = LatentKinds(full=P(), window=P(), counts=P(), index=P())
 
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(LatentKinds(rows of the full kind (latent ‖ rope key), latents
-    of the window kind, -), LatentKinds((indexer keys,) of the full kind,
-    rope keys of the window kind, counters))``: ``num_blocks`` pages a
-    full layer, ``window_blocks`` a window layer (page 0 of those is the
-    one no sequence holds), the one "head" in front of the page
-    (deepseek.init_kv_cache); a page's lanes are its parts' widths, each
-    in whole lanes."""
+    of the window kind, -), LatentKinds(nothing of the full kind, rope
+    keys of the window kind, counters, the indexer's keys by slot))``:
+    ``num_blocks`` pages a full layer, ``window_blocks`` a window layer
+    (page 0 of those is the one no sequence holds), the one "head" in
+    front of the page (deepseek.init_kv_cache); a page's lanes are its
+    parts' widths, each in whole lanes. The records: ``num_slots`` slots
+    of ``record_len`` positions for sequences of up to ``max_len``
+    tokens (the engine's ``max_model_len``; left out, the model's
+    own)."""
     n_full = cfg.layer_types.count(FULL)
     wcfg = kind_cfg(cfg, WINDOW)
 
@@ -408,8 +437,10 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return (
         LatentKinds(pages(*full, cfg.kv_lora_rank, cfg.qk_rope_head_dim),
                     pages(*window, wcfg.kv_lora_rank), counts),
-        LatentKinds((pages(*full, cfg.index_head_dim),),
-                    pages(*window, wcfg.qk_rope_head_dim), counts))
+        LatentKinds((), pages(*window, wcfg.qk_rope_head_dim), counts,
+                    jnp.zeros((n_full, num_slots, record_len(
+                        max_len or cfg.max_position_embeddings, block_size),
+                        lane_pad(cfg.index_head_dim)), dtype)))
 
 
 def _layer_norm(x, weight, bias, eps=LN_EPS):
@@ -444,12 +475,30 @@ def index_projections(cfg: ModelConfig, lp, x, cq, positions):
     return qi, ki, wi
 
 
+def write_index_keys(records, new, li, state_slots, positions, valid):
+    """The new tokens' indexer keys ``new`` [B, S, di] into layer ``li``
+    of ``records`` [L, slots, T, di'], in place: row ``r``'s at ``[li,
+    state_slots[r], positions[r]]``, by the scatter that writes the
+    pages (``scatter_rows_stacked``: a slot's record is one page of
+    ``T`` positions to it). A position that is no token (``valid`` [B,
+    S] false: a chunk's pad repeats its last position) or lies past
+    ``T`` (there is none: the engine admits no such token) writes
+    nothing."""
+    t = records.shape[2]
+    at = jnp.where(valid & (positions < t),
+                   state_slots[:, None] * t + positions, -1)
+    return scatter_rows_stacked(
+        (records[:, :, None],), (new,), at, li)[0][:, :, 0]
+
+
 def make_mixer_fn(cfg: ModelConfig, kind: str, b: int, s: int, positions,
-                  slots, table, valid, context_lens, live_rows):
+                  slots, table, valid, context_lens, live_rows,
+                  state_slots):
     """The latent mixer of one layer of ``kind``: ``fn(n1, layer_params,
-    caches, li) -> (delta, caches)`` over that kind's page stacks (a full
-    layer's rows and its indexer's keys; a window layer's latents and
-    rope keys), ``slots`` and ``table`` that kind's."""
+    caches, li) -> (delta, caches)`` over what that kind keeps (a full
+    layer's rows in pages and its indexer's keys by slot; a window
+    layer's latents and rope keys), ``slots`` and ``table`` that kind's,
+    ``state_slots`` [B] each row's slot."""
     kcfg = kind_cfg(cfg, kind)
     sq, skv = lora_rescale(kcfg)
     scale = mla_softmax_scale(kcfg)
@@ -461,19 +510,21 @@ def make_mixer_fn(cfg: ModelConfig, kind: str, b: int, s: int, positions,
         cq, q_nope, q_rope, c_kv, kr = mla_project(
             kcfg, x, lp, b, s, positions, q_scale=sq, kv_scale=skv,
             hold_heads=True)
-        new = (c_kv, kr)
+        # what a key is read from: the kind's page stacks, and a full
+        # layer's indexer keys by slot behind them
+        new, stacks, records, index = (c_kv, kr), caches, (), None
         if kind == FULL:
             with jax.named_scope("dsa_index"):
                 qi, ki, wi = index_projections(cfg, lp, x, cq, positions)
             # a token's row: the latent in whole lanes, then the rotated key
-            new = (jnp.concatenate([pad_minor(c_kv, lat), kr], -1), ki)
-        caches = scatter_rows_stacked(caches, new, slots, li)
-        stacks, index = caches, None     # what a key is read from
-        if kind == FULL:
-            stacks, keys = caches[:1], caches[1]
+            new = (jnp.concatenate([pad_minor(c_kv, lat), kr], -1),)
+            stacks, records = caches[:1], (write_index_keys(
+                caches[1], ki, li, state_slots, positions, valid),)
             # (zero lanes of a padded query score 0 against the pad)
-            index = Indexer(pad_minor(qi, keys.shape[-1]), wi, keys,
-                            cfg.index_topk)
+            index = Indexer(pad_minor(qi, records[0].shape[-1]), wi,
+                            records[0], state_slots, cfg.index_topk)
+        stacks = scatter_rows_stacked(stacks, new, slots, li)
+        caches = (*stacks, *records)
 
         # absorb W_uk into the query, attend over the latent cache
         q_lat = pad_minor(jnp.einsum("bshn,hnr->bshr", q_nope, lp["w_uk"]),
@@ -514,10 +565,11 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     summed over the expert layers, the experts counted those held).
     ``block_tables`` is ``[B, 2 W]``: the full kind's table, then the
     window kind's (models/afmoe.py)."""
-    # one device: tp, ep, pp and sp are refused for the family; no
-    # records by slot
-    del mesh, state_slots
+    # one device: tp, ep, pp and sp are refused for the family
+    del mesh
     b, s = tokens.shape
+    if state_slots is None or s == 1:   # a decode step's row i is slot i
+        state_slots = jnp.arange(b, dtype=jnp.int32)
     w = block_tables.shape[1] // 2
     tables = {FULL: block_tables[:, :w], WINDOW: block_tables[:, w:]}
     k_side, v_side = kv_cache
@@ -533,7 +585,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     live_rows = decode_live_rows(slot_mapping)
     mixers = {kind: make_mixer_fn(cfg, kind, b, s, positions, slots[kind],
                                   tables[kind], valid, context_lens,
-                                  live_rows)
+                                  live_rows, state_slots)
               for kind in (FULL, WINDOW)}
 
     def mixer(kind, lp, hidden, pages, i):
@@ -550,7 +602,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
 
     hidden, pages, stats = walk_periods(
         params, cfg, (FULL, WINDOW), mixer, experts, hidden,
-        {FULL: (k_side.full, *v_side.full),
+        {FULL: (k_side.full, v_side.index),
          WINDOW: (k_side.window, v_side.window)})
 
     counts = v_side.counts
@@ -564,7 +616,7 @@ def forward_counted(params, cfg, tokens, positions, kv_cache, block_tables,
     rows_f, ki_f = pages[FULL]
     c_w, kr_w = pages[WINDOW]
     cache = (LatentKinds(rows_f, c_w, k_side.counts),
-             LatentKinds((ki_f,), kr_w, counts))
+             LatentKinds((), kr_w, counts, ki_f))
     return hidden, cache, stats
 
 

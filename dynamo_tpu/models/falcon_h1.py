@@ -326,7 +326,7 @@ def ssm_record_shape(cfg: ModelConfig):
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(SlotCache(k pages, SSM state [L, slots, H / k, N, k P] float32:
     ``ops/ssm.record_shape``), SlotCache(v pages, conv window [L, slots,
     d_conv − 1, conv_dim]))``. The conv window keeps the trunk's dtype

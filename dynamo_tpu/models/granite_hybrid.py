@@ -324,7 +324,7 @@ CACHE_SPEC = SlotCache(kv=P(), state=P())
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(SlotCache(k pages [A, ...], SSM state [M, slots, H / 2, N,
     2 P] float32: Falcon-H1's ``ssm_record_shape``, two heads of 64 side
     by side), SlotCache(v pages, conv window [M, slots, d_conv − 1,

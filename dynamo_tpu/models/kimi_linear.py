@@ -310,7 +310,7 @@ CACHE_SPEC = SlotCache(kv=P(), state=P())
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(SlotCache(latent pages [A, N, 1, block, r], KDA state [M,
     slots, H, K, K] float32), SlotCache(rope-key pages [A, N, 1, block,
     rd], conv window [M, slots, taps − 1, 3 H K]))``: ``A`` latent

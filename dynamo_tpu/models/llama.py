@@ -299,10 +299,10 @@ def param_specs(params: Params) -> Dict:
 
 def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
-    num_slots: int = 1, window_blocks: int = 1,
+    num_slots: int = 1, window_blocks: int = 1, max_len: int = 0,
 ) -> KVCache:
-    # (num_slots, window_blocks: what the engine offers every family; one
-    # kind of page takes neither)
+    # (num_slots, window_blocks, max_len: what the engine offers every
+    # family; one kind of page takes none of them)
     # minor dim lane-padded: physically free (XLA tiles HBM to 128 lanes)
     # and required by the manual-DMA decode kernel (ops/attention.lane_pad)
     shape = (

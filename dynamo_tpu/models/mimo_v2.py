@@ -317,7 +317,7 @@ CACHE_SPEC = KindCache(full=P(), window=P())
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(KindCache(k full, k window), KindCache(v full, v window))``:
     ``num_blocks`` pages a full layer, ``window_blocks`` a window layer
     (page 0 of those is the one no sequence holds); a stack's kv heads

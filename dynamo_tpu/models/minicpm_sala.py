@@ -270,7 +270,7 @@ param_specs = run_specs    # tp > 1 is refused for state by slot
 
 def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 1,
-                  window_blocks: int = 1):
+                  window_blocks: int = 1, max_len: int = 0):
     """``(SalaCache(k pages, lightning state, -), SalaCache(v pages,
     page means, counters))``; see the module docstring."""
     kinds = cfg.mixer_types

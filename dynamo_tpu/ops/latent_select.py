@@ -3,7 +3,15 @@ attention in blocks (models/dots3.py).
 
 A full layer of ``dots3_note`` keeps one ``index_head_dim``-wide key a
 token beside the token's row (the latent and behind it the rope key, one
-row of one page stack: models/dots3.py says why). A query scores every
+row of one page stack: models/dots3.py says why). The rows lie in pages;
+**the indexer's keys lie by slot, in sequence order**: records ``[full
+layers, slots, T, di']``, a token's key at its row's slot and its own
+position (``record_len``: ``T``). The family's pages are private to a
+sequence (models/afmoe.py), so nothing needs these keys in pages, and
+every decode step reads all of a row's: where they lie one after the
+other they are the product's operand as they are; in pages of 4 KB the
+step gathered 36.8 k pages a layer at 19-23 ns a page into a copy the
+product then read back (PERF.md section 6, PR 63). A query scores every
 earlier key with ``J`` small heads,
 
     I(t, s) = Σ_j w_t,j · ReLU(q^I_t,j · k^I_s)          float32
@@ -16,11 +24,13 @@ entries equal to it the earliest, so that a row keeps ``min(k, keys)``
 keys whatever ties there are.
 
 - **decode** (one query a row, ``picked_decode_attention``): the scores
-  of the table's keys, the pick, the picked tokens' rows gathered out of
-  the pages (``[B, k, r' + rd']``, one lookup a key), one dense absorbed
-  product over them. The work follows the block table's width (the gather
-  of the indexer's keys), so the program is one of the width ladder's
-  (``record_table_width``).
+  of the keys of each row's record under the table's width (row *i* is
+  slot *i*; what an earlier sequence left past ``context_len`` is hidden
+  by the pick's mask, so a slot is never cleared), the pick, the picked
+  tokens' rows gathered out of the pages (``[B, k, r' + rd']``, one
+  lookup a key), one dense absorbed product over them. The work follows
+  the block table's width (the scores and the pick), so the program is
+  one of the width ladder's (``record_table_width``).
 - **prefill** (``blocked_latent_attention``): a block of ``QUERY_BLOCK``
   queries at a time against blocks of ``KEY_BLOCK`` keys with a running
   softmax, from the first block a query of the block can see (the
@@ -29,7 +39,8 @@ keys whatever ties there are.
   there, not the table's width. A key block is the pages of whichever
   stacks the kind keeps, side by side (a full layer's one, a window
   layer's latents and rope keys). A full layer's query block first
-  scores the same key blocks with the indexer and makes its pick a mask.
+  scores the same blocks of positions with the indexer, each a slice of
+  the row's record, and makes its pick a mask.
 
 Scopes: ``dsa_index`` (scores), ``dsa_select`` (cutoff, mask, the picked
 tokens' list), ``dsa_attend`` (the gather of the picked rows and the
@@ -55,7 +66,8 @@ class Indexer(NamedTuple):
     """A full layer's indexer for the step's queries."""
     q: jax.Array        # [B, S, J, di] the indexer's queries
     w: jax.Array        # [B, S, J] float32 head weights
-    keys: jax.Array     # [L, N, 1, page, di] the indexer's key pages
+    keys: jax.Array     # [L, slots, T, di] the indexer's keys by slot
+    slots: jax.Array    # [B] int32 each row's slot
     topk: int
 
 
@@ -177,6 +189,32 @@ def _gather_pages(cache, li, table):
     return got.reshape(table.shape[0], -1, got.shape[-1])
 
 
+def record_len(max_len: int, page: int) -> int:
+    """Positions of a slot's record for sequences of up to ``max_len``
+    tokens: whole key blocks of the blocked prefill, so that the slice
+    that is a key block never overhangs the record (a
+    ``dynamic_slice`` that does moves its start, and would score other
+    positions' keys)."""
+    kb = KEY_BLOCK // page * page
+    return -(-max_len // kb) * kb
+
+
+def _flat_records(records, li, slots):
+    """Records [L, slots, T, d] as ``[L slots, T, d]`` and where layer
+    ``li``'s record of each of ``slots`` [B] is in it (``_layer_pages``:
+    an index into the flat view takes no slice of the stack)."""
+    l, n = records.shape[:2]
+    flat = records.reshape((l * n,) + records.shape[2:])
+    return flat, jnp.asarray(li, jnp.int32) * n + slots
+
+
+def _under_table(records, t: int):
+    if t > records.shape[2]:
+        raise ValueError(
+            f"a block table of {t} positions over records of "
+            f"{records.shape[2]} a slot (record_len)")
+
+
 def picked_decode_attention(q_lat, q_rope, rows_all, li, table,
                             context_lens, scale: float, index: Indexer):
     """One query a row (q_lat [B, 1, H, r'], q_rope [B, 1, H, rd'], padded
@@ -190,9 +228,18 @@ def picked_decode_attention(q_lat, q_rope, rows_all, li, table,
     page = rows_all.shape[3]
     t = table.shape[1] * page
     k = min(index.topk, t)
+    _under_table(index.keys, t)
     with jax.named_scope("dsa_index"):
-        scores = index_scores(index.q, index.w,
-                              _gather_pages(index.keys, li, table))[:, 0]
+        # row i is slot i: the b records' first t positions where they lie
+        # (the offset held as a value: where the compiler knows the layer,
+        # the dense prefix's, it would make the slice a static one and
+        # copy it, 151 MB a step at 32 x 18 k keys, where the loop's
+        # dynamic slice is the product's operand in place)
+        flat, first = _flat_records(index.keys, li, 0)
+        keys = jax.lax.dynamic_slice(
+            flat, (jax.lax.optimization_barrier(first), 0, 0),
+            (b, t, flat.shape[-1]))
+        scores = index_scores(index.q, index.w, keys)[:, 0]
     with jax.named_scope("dsa_select"):
         valid = jnp.arange(t)[None] < context_lens[:, None]
         # a key's row of the flat [L N page, d] view of the cache
@@ -244,6 +291,17 @@ def blocked_latent_attention(q_lat, q_rope, stacks, li, table,
         pages = jax.lax.dynamic_slice_in_dim(table, j * kp, kp, axis=1)
         return _gather_pages(cache, li, pages)
 
+    if index is not None:
+        _under_table(index.keys, t)
+        records, record = _flat_records(index.keys, li, index.slots)
+
+    def index_keys_of(j):
+        """Positions [j kb, (j + 1) kb) of each row's record: a prefill
+        step has few rows, a slice each (falcon_h1.slot_records)."""
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            records, (record[i], j * kb, 0), (1, kb, records.shape[-1]))
+            for i in range(b)])
+
     def visible(pos, key_pos):
         """[B, qb, kb']: key positions a query may see, the pick apart."""
         see = ((key_pos[None, None] <= pos[:, :, None])
@@ -264,7 +322,7 @@ def blocked_latent_attention(q_lat, q_rope, stacks, li, table,
         if index is not None:
             with jax.named_scope("dsa_index"):
                 def score(j, acc):
-                    part = index_scores(*iq_iw, keys_of(index.keys, j))
+                    part = index_scores(*iq_iw, index_keys_of(j))
                     return jax.lax.dynamic_update_slice_in_dim(
                         acc, part, j * kb, axis=2)
                 scores = jax.lax.fori_loop(

@@ -78,7 +78,8 @@ def lower_trunk(config_path, devices, tokens=1, width=256):
     params = placed(shapes, arch.param_specs(shapes))
     rows = serve["max_batch_size"] if tokens == 1 else 1
     # what the engine offers every family (ModelRunner._init_device_state):
-    # its decode slots and the window pool's pages as it derives them
+    # its decode slots, the window pool's pages as it derives them and
+    # the longest sequence it admits
     from dynamo_tpu.engine.config import EngineConfig
 
     pool = EngineConfig(model=cfg, **{
@@ -89,7 +90,8 @@ def lower_trunk(config_path, devices, tokens=1, width=256):
         width *= 2      # two kinds of page: a table a kind, side by side
     cache = jax.eval_shape(lambda: arch.init_kv_cache(
         cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
-        num_slots=serve["max_batch_size"], window_blocks=pool))
+        num_slots=serve["max_batch_size"], window_blocks=pool,
+        max_len=serve["max_model_len"]))
     spec = getattr(arch, "CACHE_SPEC", CACHE_SPEC)
     cache = tuple(placed(side, spec) for side in cache)
 

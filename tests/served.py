@@ -116,8 +116,9 @@ class Served:
     unbound on a stand-in that has what they read), released before
     every pass by that pass's first query. ``poison``: after every pass,
     every page that no sequence holds (both kinds' free pages and the
-    two pages 0) is filled with ``poison[0]`` in K and ``poison[1]`` in
-    V. ``state_dtype``: the k side's records in another dtype, a
+    two pages 0) and every position of a record by slot past its slot's
+    tokens is filled with ``poison[0]`` in K and ``poison[1]`` in V.
+    ``state_dtype``: the k side's records in another dtype, a
     deliberately wrong program. ``fresh``: ``program``'s."""
 
     def __init__(self, family, cfg, params, dtype, *, block, width, slots=4,
@@ -190,9 +191,20 @@ class Served:
                                for s, n in enumerate(self.tokens)])
         free = {"full": np.setdiff1d(np.arange(self.pages), held),
                 "window": np.asarray([0] + self.pool.free)}
+        # records along a slot's positions (models/dots3.py's ``index``
+        # [L, slots, T, d]): every position no token of the slot has
+        past = (np.arange(self.cache[1].index.shape[2])[None]
+                >= np.asarray(self.tokens)[:, None]
+                if hasattr(self.cache[1], "index") else None)
+
+        def by_slot(side, value):
+            return {"index": jax.tree.map(
+                lambda x: jnp.where(past[None, :, :, None], value, x),
+                side.index)} if past is not None else {}
+
         # (a kind's pages may be several stacks: models/dots3.py)
         self.cache = tuple(
-            dataclasses.replace(side, **{
+            dataclasses.replace(side, **by_slot(side, value), **{
                 kind: jax.tree.map(lambda x: x.at[:, ids].set(value),
                                    getattr(side, kind))
                 for kind, ids in free.items()})

@@ -999,13 +999,15 @@ def _dots3_step(one_chip, rows, tokens, width):
     params = jax.tree.map(s, jax.eval_shape(
         lambda: dots3.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
     k_side, v_side = jax.tree.map(s, jax.eval_shape(
-        lambda: dots3.init_kv_cache(cfg, serve["num_kv_blocks"], 16,
-                                    jnp.bfloat16, window_blocks=pool)))
+        lambda: dots3.init_kv_cache(
+            cfg, serve["num_kv_blocks"], 16, jnp.bfloat16,
+            num_slots=serve["max_batch_size"], window_blocks=pool,
+            max_len=serve["max_model_len"])))
     # a page shape a kind: a full layer's page a token's row whole (512
-    # lanes of latent, 128 of rope key), the indexer's keys beside it
+    # lanes of latent, 128 of rope key); the indexer's keys by slot
     assert k_side.full.shape == (3, 36864, 1, 16, 640)
     assert k_side.window.shape == (6, 1216, 1, 16, 1024)
-    assert [x.shape for x in v_side.full] == [(3, 36864, 1, 16, 128)]
+    assert v_side.index.shape == (3, 32, 18432, 128)
     assert v_side.window.shape == (6, 1216, 1, 16, 128)
     assert params["moe"]["router"].shape == (8, 5120, 256)
     assert params["moe"]["w_gate"].shape == (8, 16, 5120, 1536)
@@ -1034,13 +1036,18 @@ def test_dots3_step_keeps_every_page_stack_in_place(
         one_chip, no_compile_cache, monkeypatch, rows, tokens, width):
     """A decode step of 32 rows and a 2048-token prefill chunk at the
     benchmark's size: the window layers' latent kernel (decode) and the
-    grouped products are in it, none of the four page stacks (2.27 GB of
-    the full kind's rows, 0.45 GB of indexer keys, 0.24 + 0.03 GB of the
-    window kind) is copied, and the blocked prefill's temporaries stay
-    under a tenth of the chip. A full layer's body looks a picked key up
-    once (decode: one gather of ``[32, 2048, 640]`` under ``dsa_attend``)
-    and a key block's pages once (prefill: ``[64, 16, 640]``), and makes
-    no copy of what it gathered."""
+    grouped products are in it, none of the three page stacks (2.27 GB of
+    the full kind's rows, 0.24 + 0.03 GB of the window kind) nor the
+    0.45 GB of indexer keys by slot is copied, not a layer's 151 MB of
+    them either (until PR 63 a decode step gathered that much a full
+    layer out of pages; the dense prefix's layer, whose index the
+    compiler knows, would still copy it as a static slice were its
+    offset not held as a value), and the blocked prefill's temporaries
+    stay under a tenth of the chip. A full layer's body looks a picked
+    key up once (decode: one gather of ``[32, 2048, 640]`` under
+    ``dsa_attend``) and a key block's pages once (prefill: ``[64, 16,
+    640]``), and makes no copy of what it gathered; nothing under
+    ``dsa_index`` gathers."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = _dots3_step(one_chip, rows, tokens, width)
     text = compiled.as_text()
@@ -1056,6 +1063,7 @@ def test_dots3_step_keeps_every_page_stack_in_place(
     copied = [math.prod(map(int, dims.split(","))) for dims in re.findall(
         r"= \w+\[([0-9,]+)\]\S* copy\([^\n]*dsa_attend", text)]
     assert max(copied, default=0) < 32 * 2048 * 512     # the queries, at most
+    assert not re.search(r" gather\([^\n]*dsa_index", text)
     mem = compiled.memory_analysis()
     before = _DOTS3_BEFORE_PR55[rows, tokens]
     print(f"dots3 step {rows}x{tokens}: arguments",
@@ -1064,9 +1072,10 @@ def test_dots3_step_keeps_every_page_stack_in_place(
     # weights 9.21 GB without the head's 0.19 (the trunk ends at the
     # hidden state) + pages 2.99
     assert 11.9e9 < mem.argument_size_in_bytes < 12.1e9
-    # a copy of the smallest full-kind stack would be 0.45 GB, of the
-    # rows' 2.27
-    assert mem.temp_size_in_bytes < (0.75 if tokens == 1 else 1.6) * 2 ** 30
+    # 55.5 MB and 997.7 MB; a copy of one layer's indexer keys would
+    # add 151 MB (the gathered pages did until PR 63: 177.9 MB a decode
+    # step), of all three 0.45 GB, of the rows 2.27
+    assert mem.temp_size_in_bytes < (0.12 if tokens == 1 else 1.0) * 2 ** 30
 
 
 # MiMo-V2.5 as one chip serves it (benchmark/configs/mimo-v2.5-ep16.json):

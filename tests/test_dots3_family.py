@@ -15,7 +15,9 @@ import pytest
 from dynamo_tpu import models
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.models import afmoe, deepseek, dots3, mixtral, trunk
+from dynamo_tpu.ops import latent_select
 
+import served
 from dots3_tiny import (FULL, HF, PAGE, SWA, TOPK, WINDOW, WRONG, Served,
                         _cfg, _params, _reference_logprobs, _seqs,
                         _serve_case, reference)
@@ -206,27 +208,39 @@ def test_the_published_config_reaches_the_family():
     assert prefix == [(FULL, 0, 0)]
     assert [p.tolist() for p in periods] == [[1, 2], [1, 1], [0, 3], [3, 3]]
     assert dots3.SEQUENCE_STATE.window_pool and dots3.SEQUENCE_STATE.private
+    assert dots3.SEQUENCE_STATE.slots
     # a page shape a kind: a full layer's page a token's row whole (the
-    # latent of 16 and the rope key of 8, each in whole lanes) with the
-    # indexer's keys in its geometry, a window layer's latents of 32 and
-    # rope keys apart
+    # latent of 16 and the rope key of 8, each in whole lanes), a window
+    # layer's latents of 32 and rope keys apart; the indexer's keys by
+    # slot, whole key blocks of positions a slot (2 slots of sequences
+    # of up to 100 tokens: one block of 1024), counted with what does
+    # not grow with the context
     k_side, v_side = jax.eval_shape(
-        lambda: dots3.init_kv_cache(cfg, 9, PAGE, jnp.bfloat16, window_blocks=5))
+        lambda: dots3.init_kv_cache(cfg, 9, PAGE, jnp.bfloat16, num_slots=2,
+                                    window_blocks=5, max_len=100))
     assert k_side.full.shape == (3, 9, 1, PAGE, 256)
     assert k_side.window.shape == (6, 5, 1, PAGE, 128)
-    assert [x.shape for x in v_side.full] == [(3, 9, 1, PAGE, 128)]
+    assert (k_side.index, v_side.full) == ((), ())
+    assert v_side.index.shape == (3, 2, 1024, 128)
     assert v_side.window.shape == (6, 5, 1, PAGE, 128)
-    assert k_side.dtype == jnp.bfloat16
+    assert v_side.rest == (v_side.window, v_side.index)
+    assert k_side.dtype == v_side.index.dtype == jnp.bfloat16
+    # left out, the positions the model itself can have (512)
+    assert jax.eval_shape(lambda: dots3.init_kv_cache(
+        cfg, 9, PAGE, jnp.bfloat16))[1].index.shape == (3, 1, 1024, 128)
+    assert [latent_select.record_len(n, PAGE)
+            for n in (1, 1024, 1025, 18432)] == [1024, 1024, 2048, 18432]
 
 
 def test_a_decode_step_looks_a_picked_key_up_once():
     """The lowered decode step holds, under ``dsa_attend``, exactly one
     ``gather`` a full layer's body (the dense prefix's layer 0 and the
-    period's loop: as many as gather the indexer's key pages under
-    ``dsa_index``): a picked key's row is one lookup in the one stack
+    period's loop): a picked key's row is one lookup in the one stack
     that holds the latent and the rope key side by side. Two stacks were
     two lookups, and a lookup costs the chip by the index
-    (scripts/gather_sweep.py)."""
+    (scripts/gather_sweep.py). Under ``dsa_index`` there is none: the
+    indexer's keys are scored where they lie, by slot (until PR 63 a
+    gather of their pages a body)."""
     cfg, params = _params(jnp.float32)
     rows, width = 2, 4
     cache = jax.eval_shape(lambda: dots3.init_kv_cache(
@@ -247,7 +261,7 @@ def test_a_decode_step_looks_a_picked_key_up_once():
         r'stablehlo\.gather.*loc\((#loc\d+)\)', text)]
     under = {scope: sum(f"/{scope}/" in name for name in gathers)
              for scope in ("dsa_index", "dsa_attend", "dsa_select")}
-    assert under == {"dsa_index": 2, "dsa_attend": 2, "dsa_select": 0}
+    assert under == {"dsa_index": 0, "dsa_attend": 2, "dsa_select": 0}
 
 
 @pytest.mark.parametrize("key,value,error", [
@@ -307,3 +321,225 @@ def test_paths_refused_for_the_family_by_name(path, setting):
         ModelRunner(EngineConfig(model=_cfg(), max_batch_size=2,
                                  max_model_len=64, kv_block_size=PAGE,
                                  num_kv_blocks=16, dtype="float32", **setting))
+
+
+# ---------- the indexer's keys by slot (PR 63) ----------
+
+_LI = 1      # the layer the op-level cases read, of two
+
+
+def _paged_full_layer(seed, lens, slots, n_slots=4, topk=16):
+    """One full layer's cache both ways, float32: token rows in pages
+    behind a table a row (pages in shuffled order), and the indexer's
+    keys (a) in pages of the same geometry, the form before PR 63, and
+    (b) by slot, row ``r``'s at ``[LI, slots[r], position]`` with large
+    stale keys at every position past its length and in every other
+    slot. -> (rows_all, key_pages, records, table, context_lens)."""
+    rs = np.random.RandomState(seed)
+    layers, page, width, d_row, di = 2, PAGE, 8, 256, 128
+    n = 1 + n_slots * width
+    t_rec = latent_select.record_len(width * page, page)
+    rows_all = rs.randn(layers, n, 1, page, d_row).astype(np.float32)
+    key_pages = np.zeros((layers, n, 1, page, di), np.float32)
+    records = 50.0 * rs.randn(layers, n_slots, t_rec, di).astype(np.float32)
+    table = 1 + rs.permutation(n_slots * width).reshape(n_slots, width)
+    table = table[:len(lens)].astype(np.int32)
+    for r, (length, slot) in enumerate(zip(lens, slots)):
+        keys = rs.randn(length, di).astype(np.float32)
+        at = np.arange(length)
+        key_pages[_LI, table[r, at // page], 0, at % page] = keys
+        records[_LI, slot, :length] = keys
+    return (jnp.asarray(rows_all), jnp.asarray(key_pages),
+            jnp.asarray(records), jnp.asarray(table),
+            jnp.asarray(lens, jnp.int32), topk)
+
+
+def _plain_picked_attention(q, rows, scores, see, topk, scale):
+    """Queries [B, S, H, d] over rows [B, T, d] of which each query
+    keeps ``pick_mask`` of its ``scores`` [B, S, T] among ``see``."""
+    keep = latent_select.pick_mask(scores, see, topk)
+    s_log = jnp.einsum("bshd,btd->bsht", q, rows) * scale
+    probs = jax.nn.softmax(jnp.where(keep[:, :, None], s_log, -jnp.inf), -1)
+    return jnp.einsum("bsht,btd->bshd", probs, rows), np.asarray(keep)
+
+
+def test_decode_with_keys_by_slot_equals_the_paged_form():
+    """A decode step's picks and outputs from the records where they lie
+    (row i is slot i, an idle row among them, tables in shuffled page
+    order) are those of the paged form: the same keys gathered out of
+    pages by the table, scored, picked and attended to plainly. What
+    lies past a row's length (large stale keys) is never picked."""
+    lens = [100, 37, 1, 128]
+    rows_all, key_pages, records, table, ctx, topk = _paged_full_layer(
+        5, lens, slots=range(4))
+    rs = np.random.RandomState(6)
+    b, h, j = len(lens), 2, 4
+    q_lat, q_rope = (jnp.asarray(rs.randn(b, 1, h, 128), jnp.float32)
+                     for _ in range(2))
+    iq = jnp.asarray(rs.randn(b, 1, j, 128), jnp.float32)
+    iw = jnp.asarray(rs.randn(b, 1, j), jnp.float32)
+    got = latent_select.picked_decode_attention(
+        q_lat, q_rope, rows_all, _LI, table, ctx, 0.1,
+        latent_select.Indexer(iq, iw, records, jnp.arange(b), topk))
+    gathered = latent_select._gather_pages(key_pages, _LI, table)
+    t = gathered.shape[1]
+    see = (jnp.arange(t)[None] < ctx[:, None])[:, None]
+    want, keep = _plain_picked_attention(
+        jnp.concatenate([q_lat, q_rope], -1),
+        latent_select._gather_pages(rows_all, _LI, table),
+        latent_select.index_scores(iq, iw, gathered), see, topk, 0.1)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    by_slot = latent_select.pick_mask(
+        latent_select.index_scores(iq, iw, records[_LI, :b, :t]), see, topk)
+    assert (np.asarray(by_slot) == keep).all()
+    assert keep.sum(-1)[:, 0].tolist() == [16, 16, 1, 16]
+
+
+def test_prefill_with_keys_by_slot_equals_the_paged_form():
+    """A prefill chunk's rows name their slots, not in the rows' order:
+    every query's pick and output from slices of its row's record are
+    those of the paged form, a pad row and a row's pad queries apart."""
+    lens, slots = [100, 37, 128], [3, 0, 2]
+    rows_all, key_pages, records, table, ctx, topk = _paged_full_layer(
+        7, lens, slots)
+    rs = np.random.RandomState(8)
+    b, s, h, j = len(lens), 32, 2, 4
+    q_lat, q_rope = (jnp.asarray(rs.randn(b, s, h, 128), jnp.float32)
+                     for _ in range(2))
+    iq = jnp.asarray(rs.randn(b, s, j, 128), jnp.float32)
+    iw = jnp.asarray(rs.randn(b, s, j), jnp.float32)
+    # each row's chunk ends at its length; the second has 5 tokens only
+    n_valid = np.asarray([32, 5, 32])
+    start = np.asarray(lens) - n_valid
+    pos = np.minimum(start[:, None] + np.arange(s), np.asarray(lens)[:, None] - 1)
+    valid = np.arange(s)[None] < n_valid[:, None]
+    got = latent_select.blocked_latent_attention(
+        q_lat, q_rope, (rows_all,), _LI, table, jnp.asarray(pos, jnp.int32),
+        jnp.asarray(valid), ctx, 0.1, index=latent_select.Indexer(
+            iq, iw, records, jnp.asarray(slots, jnp.int32), topk))
+    gathered = latent_select._gather_pages(key_pages, _LI, table)
+    key_pos = np.arange(gathered.shape[1])
+    see = jnp.asarray(key_pos[None, None] <= pos[:, :, None])
+    want, keep = _plain_picked_attention(
+        jnp.concatenate([q_lat, q_rope], -1),
+        latent_select._gather_pages(rows_all, _LI, table),
+        latent_select.index_scores(iq, iw, gathered), see, topk, 0.1)
+    np.testing.assert_allclose(np.asarray(got)[valid],
+                               np.asarray(want)[..., :128][valid], atol=2e-5)
+    by_slot = latent_select.pick_mask(latent_select.index_scores(
+        iq, iw, records[_LI, jnp.asarray(slots), :len(key_pos)]), see, topk)
+    assert (np.asarray(by_slot) == keep)[valid].all()
+
+
+def test_a_slots_next_sequence_never_reads_what_the_last_one_left():
+    """A slot is never cleared: a shorter sequence that takes a slot
+    after a longer one (whose keys, made a thousand times larger, would
+    win every pick they entered) writes its own positions and reads no
+    other, through prefill in chunks and decode."""
+    cfg, params = _params(jnp.float32)
+    drive = Served(cfg, params, jnp.float32)
+    a, b = _seqs([300 + 4, 90 + 20], seed=12)
+    _serve_case(drive, [a], [2], 4, [128, 256], 128)
+    k_side, v_side = drive.cache
+    left = np.asarray(v_side.index)
+    assert (np.abs(left[:, 2, :304]).max(-1) > 0).all()
+    drive.cache = (k_side, dataclasses.replace(
+        v_side, index=v_side.index.at[:, 2].multiply(1e3)))
+    got = _serve_case(drive, [b], [2], 20, [40, 77], 64)[0]
+    np.testing.assert_allclose(got, _reference_logprobs(params, b), rtol=0,
+                               atol=1e-3)
+    now = np.asarray(drive.cache[1].index)
+    np.testing.assert_array_equal(now[:, 2, 110:], 1e3 * left[:, 2, 110:])
+    np.testing.assert_array_equal(now[:, [0, 1, 3]], left[:, [0, 1, 3]])
+
+
+def test_a_last_chunk_whose_bucket_overhangs_the_record(monkeypatch):
+    """Records of 48 positions a slot (key blocks of 48, a model of 48
+    positions) and a last chunk of 16 tokens from position 30 in a
+    bucket of 32: positions 30-61 overhang the record. The chunk writes
+    its 16 keys, leaves every earlier key as it was, and the sequence
+    reads as the reference says to its last position. A table wider
+    than the records is refused when the step is traced."""
+    monkeypatch.setattr(latent_select, "KEY_BLOCK", 48)
+    cfg, params = _params(jnp.float32, max_position_embeddings=48)
+    drive = served.Served(dots3, cfg, params, jnp.float32, block=PAGE,
+                          width=3, slots=4, fresh=True)
+    assert drive.cache[1].index.shape == (3, 4, 48, 128)
+    seq = _seqs([48], seed=13)[0]
+    want = _reference_logprobs(params, seq)
+    got = drive.prefill([(1, seq[:30], 0)], 32)[0]
+    np.testing.assert_allclose(got, want[:30], rtol=0, atol=1e-3)
+    first = np.asarray(drive.cache[1].index)
+    got = drive.prefill([(1, seq[30:46], 30)], 32)[0]
+    np.testing.assert_allclose(got, want[30:46], rtol=0, atol=1e-3)
+    then = np.asarray(drive.cache[1].index)
+    np.testing.assert_array_equal(then[:, 1, :30], first[:, 1, :30])
+    assert (np.abs(then[:, 1, 30:46]).max(-1) > 0).all()
+    assert not then[:, 1, 46:].any() and not then[:, [0, 2, 3]].any()
+    for p in (46, 47):
+        got = drive.decode({1: (seq[p], p)})[1]
+        np.testing.assert_allclose(got, want[p], rtol=0, atol=1e-3)
+    # (positions that run on past the record, as a chunk's pads might)
+    keys = jnp.ones((1, 8, 16))
+    put = dots3.write_index_keys(
+        jnp.zeros((2, 3, 48, 128)), keys, 1, jnp.asarray([2]),
+        jnp.arange(44, 52)[None], jnp.ones((1, 8), bool))
+    assert np.asarray(put).sum() == 4 * 16
+    assert np.asarray(put)[1, 2, 44:, :16].all()
+    wide = served.Served(dots3, cfg, params, jnp.float32, block=PAGE,
+                         width=4, slots=4, fresh=True)
+    with pytest.raises(ValueError, match="96 positions over records of 48"):
+        wide.prefill([(1, seq[:30], 0)], 32)
+
+
+def test_the_served_path_through_a_preemption_and_a_resume():
+    """A sequence dropped after 10 decoded tokens, its slot taken by
+    another meanwhile, and prefilled again from position 0 (prompt and
+    the 10) into another slot and then into its old one, continues as
+    the reference says: the keys by slot are written anew by the resume
+    and nothing of the slot's last user is read."""
+    cfg, params = _params(jnp.float32)
+    drive = Served(cfg, params, jnp.float32)
+    a, b = _seqs([70 + 30, 50 + 8], seed=14)
+    want_a, want_b = (_reference_logprobs(params, q) for q in (a, b))
+    got = _serve_case(drive, [a[:80]], [1], 10, [40], 64)[0]
+    np.testing.assert_allclose(got, want_a[:80], rtol=0, atol=1e-3)
+    got = _serve_case(drive, [b], [1], 8, [], 64)[0]       # preempted: b in 1
+    np.testing.assert_allclose(got, want_b, rtol=0, atol=1e-3)
+    for slot in (3, 1):     # a resumes: prefill of 80 from 0, 20 more
+        got = _serve_case(drive, [a], [slot], 20, [64], 64)[0]
+        np.testing.assert_allclose(got, want_a, rtol=0, atol=1e-3)
+
+
+def test_the_refusals_are_the_window_pools_with_records_by_slot_too():
+    """``slots=True`` beside ``window_pool=True``: the paths refused are
+    afmoe's (and this family's two), every path a family with records
+    by slot refuses among them, and ``ModelRunner`` is told the rows'
+    slots for such a family."""
+    from dynamo_tpu.models import falcon_h1
+
+    state = dots3.SEQUENCE_STATE
+    assert state.slots and state.window_pool and state.private
+    assert set(state.refused) == set(afmoe.SEQUENCE_STATE.refused)
+    assert set(falcon_h1.SEQUENCE_STATE.refused) <= set(state.refused)
+    same = {p: why for p, why in state.refused.items()
+            if p not in ("ep_size", "prefix_pull")}
+    assert same == {p: afmoe.SEQUENCE_STATE.refused[p] for p in same}
+    assert "by slot" in state.keeps
+
+
+@pytest.mark.parametrize("path,setting", [
+    ("sp_size", dict(sp_size=2)), ("pp_size", dict(pp_size=2)),
+    ("prefix_pull", dict(prefix_pull=True)),
+    ("decode_pipeline_depth", dict(decode_pipeline_depth=2)),
+    ("spec_draft_model", dict(spec_draft_model="draft", spec_draft_tokens=2)),
+])
+def test_paths_refused_for_records_by_slot_are_refused_by_name(path, setting):
+    from dynamo_tpu.engine.model_runner import ModelRunner
+
+    with pytest.raises(ValueError, match=f"{path} is refused for the "
+                                         "dots3 family.*by slot"):
+        ModelRunner(EngineConfig(model=_cfg(), max_batch_size=2,
+                                 max_model_len=64, kv_block_size=PAGE,
+                                 num_kv_blocks=16, dtype="float32",
+                                 prefill_buckets=[32], **setting))

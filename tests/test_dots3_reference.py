@@ -133,8 +133,9 @@ def test_small_blocks_of_queries_and_keys(monkeypatch):
 # every page no sequence holds, after every pass: a large finite value in
 # the k side (the window kind's latents, the full kind's rows: a latent
 # is the value too, and every route multiplies it by a weight of exactly
-# 0) and NaN in the v side (the window kind's rope keys and the indexer's
-# keys, whose scores every route masks by a select)
+# 0) and NaN in the v side (the window kind's rope keys and, at every
+# position of a slot past its tokens, the indexer's keys by slot, whose
+# scores every route masks by a select)
 POISON = (1e3, float("nan"))
 
 
@@ -236,18 +237,18 @@ def test_pick_mask_keeps_k_whatever_ties_there_are():
 
 def test_the_indexers_key_is_written_once_a_token_and_read_by_decode():
     """After a prefill of 70 tokens and 10 decode steps the indexer's
-    cache holds a key at each of the sequence's 80 positions in every
-    full layer and nothing behind them, layer 0's the key
-    ``index_projections`` gives; with the cache's keys zeroed the next
-    decode step picks other keys and says something else."""
+    records hold, in the sequence's slot, a key at each of its 80
+    positions in every full layer and nothing behind them or in another
+    slot, layer 0's the key ``index_projections`` gives; with the keys
+    zeroed the next decode step picks other keys and says something
+    else."""
     cfg, params, lp, seq, x = _layer0(t=81)
     served = Served(cfg, params, jnp.float32)
     _serve_case(served, [seq[:80]], [1], 10, [], 128)
-    ki_all, = map(np.asarray, served.cache[1].full)     # [3, N, 1, page, 128]
-    pages = served.btab[1, :80 // PAGE]
-    held = ki_all[:, pages, 0].reshape(3, 80, -1)
+    ki_all = np.asarray(served.cache[1].index)          # [3, slots, T, 128]
+    held = ki_all[:, 1, :80]
     assert (np.abs(held).max(-1) > 0).all()
-    assert not ki_all[:, served.btab[1, 80 // PAGE:]].any()
+    assert not ki_all[:, 1, 80:].any() and not ki_all[:, [0, 2, 3]].any()
     pos = jnp.arange(81, dtype=jnp.int32)[None]
     _, ki, _ = dots3.index_projections(cfg, lp, x, mla_cq(cfg, lp, x, pos), pos)
     np.testing.assert_allclose(held[0, :, :16], np.asarray(ki)[0, :80],
@@ -255,7 +256,7 @@ def test_the_indexers_key_is_written_once_a_token_and_read_by_decode():
     before = served.cache
     sound = served.decode({1: (seq[80], 80)})[1]
     served.cache = (before[0], dataclasses.replace(
-        before[1], full=jax.tree.map(jnp.zeros_like, before[1].full)))
+        before[1], index=jnp.zeros_like(before[1].index)))
     assert np.abs(served.decode({1: (seq[80], 80)})[1] - sound).max() > WRONG
 
 
